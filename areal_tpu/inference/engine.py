@@ -292,12 +292,11 @@ class GenerationEngine:
     ):
         self.config = config
         self.dtype = _DTYPES[config.dtype]
-        if getattr(config, "compilation_cache_dir", ""):
-            from areal_tpu.utils.compile_cache import (
-                enable_compilation_cache,
-            )
+        from areal_tpu.utils.compile_cache import enable_compilation_cache
 
-            enable_compilation_cache(config.compilation_cache_dir)
+        # an inherited JAX_COMPILATION_CACHE_DIR wins over the config's
+        # directory; with neither the cache stays off
+        enable_compilation_cache(getattr(config, "compilation_cache_dir", ""))
         if model_config is None:
             model_config = load_hf_config(config.model_path)
         self.model_config = model_config
@@ -490,6 +489,10 @@ class GenerationEngine:
             self._attn_impl = "kernel" if (tp == 1 and on_tpu) else "jnp"
         else:
             self._attn_impl = config.attn_impl
+        logger.info(
+            f"paged attention: attn_impl={config.attn_impl!r} resolved to "
+            f"{self._attn_impl!r} on {jax.devices()[0].platform} (tp={tp})"
+        )
         self.model_version = 0
         self._rng_key = jax.random.PRNGKey(config.seed)
 
@@ -858,6 +861,12 @@ class GenerationEngine:
             )
         sh = self._leaf_shardings.get(name)
         return jax.device_put(x, sh) if sh is not None else x
+
+    @property
+    def attn_impl(self) -> str:
+        """The paged-attention backend in use ("kernel" | "jnp"): the
+        config's ``attn_impl`` with "auto" resolved against the device."""
+        return self._attn_impl
 
     # ------------------------------------------------------------------
     # Lifecycle

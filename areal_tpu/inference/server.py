@@ -582,11 +582,22 @@ class _Handler(BaseHTTPRequestHandler):
                 pass
             self._send_json(body)
         elif url.path == "/get_model_info":
+            import jax
+
+            dev = jax.devices()
             self._send_json(
                 {
                     "model_version": eng.model_version,
                     "model_path": eng.config.model_path,
                     "max_model_len": eng.config.max_model_len,
+                    # what actually runs: the resolved paged-attention
+                    # backend and the device jax reports
+                    "attn_impl": getattr(eng, "attn_impl", None),
+                    "device": {
+                        "platform": dev[0].platform,
+                        "kind": dev[0].device_kind,
+                        "count": len(dev),
+                    },
                 }
             )
         elif url.path == "/metrics":

@@ -1,21 +1,25 @@
-"""Opt-in persistent XLA compilation cache.
+"""Persistent XLA compilation cache: where it lives, and turning it on.
 
 The serving engine's compiled-program surface is a LADDER — prefill wave
 shapes × kv page buckets × decode row buckets × sampling modes — and a
-cold engine pays for all of it at warmup (the r5 bench capture burned
-378 s across 191 backend compiles before the first measured step). The
-programs are deterministic functions of (jaxlib, flags, HLO), so a
-persistent on-disk cache replays warmup from disk on every engine after
-the first.
+cold engine pays for all of it at warmup; the trainer adds its grad and
+update programs. The programs are deterministic functions of (jaxlib,
+flags, HLO), so a persistent on-disk cache replays them on every process
+after the first.
 
-Wiring: ``JaxGenConfig.compilation_cache_dir`` (engine init calls
-``enable_compilation_cache`` before the first jit), the generation
-server's ``--compilation-cache-dir`` flag, the local launcher (exports
-``JAX_COMPILATION_CACHE_DIR`` to server subprocesses so the cache is
-active from interpreter start), and ``bench.py`` (which also counts
-cache hit/miss events into the bench record).
+Where the cache lives is ONE rule, here (``resolve_cache_dir``):
 
-Kept separate from the engine so trainers/tools can reuse it.
+1. ``JAX_COMPILATION_CACHE_DIR``, when the process inherited it. jax reads
+   the variable itself; this module then sets no directory in code, so
+   whoever runs the program can place the cache from outside.
+2. else the directory a config names (``JaxGenConfig.compilation_cache_dir``
+   / the server's ``--compilation-cache-dir``);
+3. else ``<checkout>/.jax_compile_cache`` for the entry points that always
+   cache (``examples/gsm8k_grpo.py``, ``bench.py``, ``chip_smoke.py``).
+
+The directory is part of the cache key's world: it is never a temporary
+name, a pid or a time. The local launcher exports the config's value to
+server subprocesses only when nothing was inherited.
 """
 
 import os
@@ -26,70 +30,72 @@ from areal_tpu.utils import logging as logging_util
 
 logger = logging_util.getLogger("CompileCache")
 
+CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
+
 _lock = threading.Lock()
 _enabled_dir: Optional[str] = None
 
 
+def default_cache_dir() -> str:
+    """``<checkout>/.jax_compile_cache`` (git ignores it)."""
+    checkout = os.path.dirname(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    )
+    return os.path.join(checkout, ".jax_compile_cache")
+
+
+def resolve_cache_dir(configured: str = "") -> str:
+    """The one placement rule (module docstring): inherited environment,
+    else the configured directory, else the checkout's default."""
+    return os.environ.get(CACHE_DIR_ENV) or configured or default_cache_dir()
+
+
 def enable_compilation_cache(cache_dir: str) -> bool:
-    """Point jax's persistent compilation cache at ``cache_dir``.
+    """Turn jax's persistent compilation cache on.
 
-    Thresholds are dropped to zero: the decode bucket ladder is many
-    SMALL programs (default jax only persists compiles > 1 s), and the
-    warmup cost is their sum, not any single entry. Returns True when
-    the cache is active; failures (old jax, read-only fs) are logged and
-    reported as False — the cache is an optimization, never a hard
-    dependency. Idempotent per directory."""
+    With ``JAX_COMPILATION_CACHE_DIR`` inherited, that directory is used
+    and no directory is set in code; otherwise jax is pointed at
+    ``cache_dir`` (empty = nothing asked for: returns False, cache stays
+    off). Thresholds are dropped to zero either way: the decode bucket
+    ladder is many SMALL programs (default jax only persists compiles
+    > 1 s), and the warmup cost is their sum, not any single entry.
+
+    Returns True once the cache is active. A cache that was asked for and
+    cannot be enabled (unwritable directory, a variable exported after jax
+    was imported) RAISES: a run that silently recompiles everything is not
+    the run that was asked for. Idempotent per directory."""
     global _enabled_dir
-    if not cache_dir:
+    inherited = os.environ.get(CACHE_DIR_ENV, "")
+    if not (inherited or cache_dir):
         return False
-    cache_dir = os.path.abspath(os.path.expanduser(cache_dir))
+    target = os.path.abspath(os.path.expanduser(inherited or cache_dir))
     with _lock:
-        if _enabled_dir == cache_dir:
+        if _enabled_dir == target:
             return True
-        try:
-            os.makedirs(cache_dir, exist_ok=True)
-            import jax
+        os.makedirs(target, exist_ok=True)
+        import jax
+        from jax._src import compilation_cache as _cc
 
-            jax.config.update("jax_compilation_cache_dir", cache_dir)
-            jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs", 0.0
-            )
-            jax.config.update(
-                "jax_persistent_cache_min_entry_size_bytes", -1
-            )
-            # jax initializes its cache object ONCE, at the first
-            # compile — a process that compiled anything before this
-            # call (param init, another engine) would silently keep the
-            # cache off forever. reset_cache() forces re-initialization
-            # against the directory just configured.
-            try:
-                from jax._src import compilation_cache as _cc
-
-                _cc.reset_cache()
-            except Exception:  # pragma: no cover - private-API drift
-                logger.warning(
-                    "jax compilation_cache.reset_cache unavailable; "
-                    "the cache only applies if nothing compiled yet"
+        if inherited:
+            seen = jax.config.jax_compilation_cache_dir or ""
+            if os.path.abspath(os.path.expanduser(seen)) != target:
+                raise RuntimeError(
+                    f"{CACHE_DIR_ENV}={inherited!r} but jax holds "
+                    f"{seen!r}: the variable must be in the environment "
+                    "before jax is imported"
                 )
-            # the zero thresholds are the load-bearing part (the ladder
-            # is many SMALL programs) — verify they survived this jax
-            # version's config plumbing instead of assuming
-            if (
-                float(
-                    jax.config.jax_persistent_cache_min_compile_time_secs
-                )
-                != 0.0
-            ):
-                logger.warning(
-                    "jax_persistent_cache_min_compile_time_secs did not "
-                    "take 0.0 on this jax version — small ladder "
-                    "programs will not persist"
-                )
-        except Exception as e:  # noqa: BLE001 — optimization, not a dep
-            logger.warning(f"compilation cache disabled: {e}")
-            return False
-        _enabled_dir = cache_dir
-        logger.info(f"persistent compilation cache at {cache_dir}")
+        else:
+            jax.config.update("jax_compilation_cache_dir", target)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+        # jax initializes its cache object ONCE, at the first compile — a
+        # process that compiled anything before this call (param init,
+        # another engine) would silently keep the cache off forever.
+        # reset_cache() forces re-initialization against the directory
+        # just configured.
+        _cc.reset_cache()
+        _enabled_dir = target
+        logger.info(f"persistent compilation cache at {target}")
         return True
 
 
@@ -101,25 +107,18 @@ def enabled_dir() -> Optional[str]:
 def disable_compilation_cache() -> None:
     """Turn the persistent cache back off (tests; a process that
     enabled it for one engine must be able to restore the default).
-    The enable is process-global jax config — on this jax version some
-    TRAINER-side programs (donation-heavy sharded train steps on the
-    CPU backend) have been observed to misbehave with the cache
-    enabled, so test suites that exercise both planes in one process
-    must scope the enable to the serving tests."""
+    The enable is process-global jax config — on the CPU backend some
+    TRAINER-side programs (donation-heavy sharded train steps) have been
+    observed to misbehave with the cache enabled, so test suites that
+    exercise both planes in one process must scope the enable to the
+    serving tests."""
     global _enabled_dir
     with _lock:
-        try:
-            import jax
+        import jax
+        from jax._src import compilation_cache as _cc
 
-            jax.config.update("jax_compilation_cache_dir", None)
-            try:
-                from jax._src import compilation_cache as _cc
-
-                _cc.reset_cache()
-            except Exception as e:  # pragma: no cover - API drift
-                logger.warning(f"cache reset unavailable: {e}")
-        except Exception as e:  # noqa: BLE001 — best-effort restore
-            logger.warning(f"compilation cache disable failed: {e}")
+        jax.config.update("jax_compilation_cache_dir", None)
+        _cc.reset_cache()
         _enabled_dir = None
 
 

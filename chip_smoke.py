@@ -1,0 +1,742 @@
+"""chip_smoke.py — does the system still start on the chip?
+
+    python chip_smoke.py              # one TPU chip: grpo, parity, server
+    python chip_smoke.py --chips 4    # one 4-chip host: the sharded trainer only
+    python chip_smoke.py --rehearse   # the same phases, tiny model, on the CPU
+
+The orchestrator (this process, when run without ``--child``) never imports
+jax: a chip belongs to one process at a time, so every phase is a child
+process, and the next starts only after the last one exited.
+
+Phases at Qwen2-0.5B, published width (vocab 151936), random weights from
+``--seed``:
+
+- ``grpo``    3 steps of ``examples/gsm8k_grpo.main`` colocated (trainer and
+              generation engine share the chip), bf16 compute / f32 params,
+              Adam, ``actor.attn_impl=flash``, server defaults;
+- ``parity``  the same greedy generations from an engine on the Pallas
+              paged-decode kernel and one on the jnp gather;
+- ``server``  ``python -m areal_tpu.inference.server`` over HTTP, started
+              twice: the second start must find the first one's programs in
+              the persistent compile cache;
+- ``fsdp4``   (``--chips 4`` only) one PPO update on one device against the
+              same on an ``fsdp=4`` mesh, then one Qwen2-1.5B step on it.
+
+Without ``--rehearse`` a phase that does not see platform ``tpu`` fails; there
+is no CPU fallback. The last line of stdout is exactly
+``{"ok": ..., "device": {"platform": ..., "kind": ..., "count": ...}}``.
+"""
+
+import argparse
+import json
+import os
+import signal
+import socket
+import subprocess
+import sys
+import time
+import urllib.request
+
+ROOT = os.path.dirname(os.path.abspath(__file__))
+
+# Qwen/Qwen2-0.5B config.json, nothing trimmed
+QWEN2_0P5B = dict(
+    vocab_size=151936, hidden_size=896, intermediate_size=4864,
+    num_layers=24, num_heads=14, num_kv_heads=2, head_dim=64,
+    max_position_embeddings=32768, rope_theta=1e6, rms_norm_eps=1e-6,
+    tie_word_embeddings=True, attention_bias=True, family="qwen2",
+)
+
+# the whole run must end inside the driver's 1200 s, compilation included
+TOTAL_LIMIT_S = 1140
+PHASE_LIMIT_S = {"grpo": 660, "parity": 360, "server": 360, "fsdp4": 1500}
+GRPO_STEPS = 3
+PARITY_LOGPROB_TOL = 2e-2
+FSDP_REL_TOL = 2e-2
+FSDP_SHARE_MAX = 0.3
+
+
+# ---------------------------------------------------------------------------
+# orchestrator (no jax here)
+# ---------------------------------------------------------------------------
+def _child_env(args):
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.pathsep.join(
+        [ROOT] + [p for p in env.get("PYTHONPATH", "").split(os.pathsep) if p]
+    )
+    env.setdefault("TOKENIZERS_PARALLELISM", "false")
+    if args.rehearse:
+        env["JAX_PLATFORMS"] = "cpu"
+        if args.chips == 4:
+            env["XLA_FLAGS"] = (
+                env.get("XLA_FLAGS", "")
+                + " --xla_force_host_platform_device_count=4"
+            ).strip()
+    return env
+
+
+def _run_child(phase, args, limit):
+    """One phase as its own process; returns its result line (a dict)."""
+    log_path = os.path.join(args.out, f"{phase}.log")
+    cmd = [
+        sys.executable, os.path.abspath(__file__), "--child", phase,
+        "--seed", str(args.seed), "--out", args.out,
+        "--chips", str(args.chips),
+    ] + (["--rehearse"] if args.rehearse else [])
+    with open(log_path, "w") as log:
+        proc = subprocess.Popen(
+            cmd, stdout=subprocess.PIPE, stderr=log, env=_child_env(args),
+            text=True, start_new_session=True, cwd=ROOT,
+        )
+        try:
+            out, _ = proc.communicate(timeout=limit)
+        except subprocess.TimeoutExpired:
+            _kill_group(proc)
+            out, _ = proc.communicate()
+            log.write(out or "")
+            return {"phase": phase, "ok": False,
+                    "error": f"timed out after {limit:.0f} s"}
+        finally:
+            _kill_group(proc)  # nothing a phase started outlives it
+        log.write(out or "")
+    result = None
+    for line in (out or "").splitlines():
+        if line.startswith('{"phase"'):
+            result = json.loads(line)
+    if result is None:
+        result = {"phase": phase, "ok": False,
+                  "error": f"no result line (exit code {proc.returncode})"}
+    elif proc.returncode != 0:
+        result["ok"] = False
+        result.setdefault("error", f"exit code {proc.returncode}")
+    return result
+
+
+def _kill_group(proc):
+    try:
+        os.killpg(proc.pid, signal.SIGKILL)
+    except (ProcessLookupError, PermissionError):
+        pass
+
+
+def _tail(path, n=60):
+    try:
+        with open(path, errors="replace") as f:
+            return "".join(f.readlines()[-n:])
+    except OSError:
+        return ""
+
+
+def orchestrate(args):
+    phases = ["fsdp4"] if args.chips == 4 else ["grpo", "parity", "server"]
+    if args.phase:
+        unknown = [p for p in args.phase if p not in phases]
+        if unknown:
+            raise SystemExit(f"--phase {unknown}: this mode runs {phases}")
+        phases = [p for p in phases if p in args.phase]
+    os.makedirs(args.out, exist_ok=True)
+    want_platform = "cpu" if args.rehearse else "tpu"
+    deadline = time.monotonic() + (
+        PHASE_LIMIT_S["fsdp4"] if args.chips == 4 else TOTAL_LIMIT_S
+    )
+    ok, device, results = True, None, []
+    if not os.path.isdir(os.path.join(ROOT, "areal_tpu")):
+        print(f"chip_smoke: no areal_tpu package beside {__file__}",
+              file=sys.stderr)
+        ok, phases = False, []
+    for phase in phases:
+        limit = min(PHASE_LIMIT_S[phase], deadline - time.monotonic())
+        t0 = time.monotonic()
+        if limit <= 0:
+            res = {"phase": phase, "ok": False, "error": "no time left"}
+        elif phase == "server":
+            res = phase_server(args, limit)
+        else:
+            res = _run_child(phase, args, limit)
+        res["seconds"] = round(time.monotonic() - t0, 1)
+        dev = res.get("device") or {}
+        if res["ok"] and (
+            dev.get("platform") != want_platform
+            or (not args.rehearse and dev.get("count") != args.chips)
+        ):
+            res["ok"] = False
+            res["error"] = (
+                f"ran on {dev}: wanted {args.chips} x {want_platform}"
+            )
+        device = device or res.get("device")
+        results.append(res)
+        print(json.dumps(res), flush=True)
+        if not res["ok"]:
+            ok = False
+            sys.stderr.write(
+                f"--- {phase} failed; end of its log in {args.out} ---\n"
+                + _tail(os.path.join(args.out, f"{phase}.log"))
+            )
+            break  # a later phase would only hide the first failure
+    print(json.dumps({
+        "compile": {
+            r["phase"]: r.get("compile") for r in results if "compile" in r
+        }
+    }), flush=True)
+    print(json.dumps({"ok": ok, "device": device}), flush=True)
+    return 0 if ok else 1
+
+
+# ---------------------------------------------------------------------------
+# children (each owns the chip for its lifetime)
+# ---------------------------------------------------------------------------
+def _result(phase, ok, device, **fields):
+    return {"phase": phase, "ok": bool(ok), "device": device, **fields}
+
+
+class _CompileWatch:
+    """Backend compiles, their seconds, and persistent-cache hits of this
+    process (jax.monitoring; a hit still fires a backend-compile event, so
+    ``compiles - cache_hits`` is what XLA really compiled)."""
+
+    def __init__(self):
+        from jax import monitoring
+
+        self.compiles, self.seconds, self.cache_hits = 0, 0.0, 0
+        monitoring.register_event_duration_secs_listener(self._on_duration)
+        monitoring.register_event_listener(self._on_event)
+
+    def _on_duration(self, event, duration, **kw):
+        if event.endswith("backend_compile_duration"):
+            self.compiles += 1
+            self.seconds += duration
+
+    def _on_event(self, event, **kw):
+        if event.endswith("compilation_cache/cache_hits"):
+            self.cache_hits += 1
+
+    def report(self, cache_dir):
+        return {
+            "backend_compiles": self.compiles,
+            "compile_seconds": round(self.seconds, 1),
+            "cache_hits": self.cache_hits,
+            "cache_dir": cache_dir,
+            "cache_entries": len(os.listdir(cache_dir)),
+        }
+
+
+def _model_config(args, which="0p5b"):
+    from areal_tpu.models.config import ModelConfig, tiny_config
+
+    if args.rehearse:
+        return tiny_config("qwen2")
+    if which == "1p5b":
+        from areal_tpu.parallel.feasibility import qwen2_1p5b_config
+
+        return qwen2_1p5b_config()
+    return ModelConfig(**QWEN2_0P5B)
+
+
+def _fabricate(args, cfg):
+    """Checkpoint, tokenizer and data under the output directory, made from
+    the seed (the chip's machine has no network). Reused if already there."""
+    import jax
+    import jax.numpy as jnp
+
+    from areal_tpu.models import hf_io
+    from areal_tpu.models.transformer import init_params
+    from tests.fixtures import make_gsm8k_jsonl, make_tiny_tokenizer
+
+    model_dir = os.path.join(args.out, "model")
+    tok_dir = os.path.join(args.out, "tok")
+    data = os.path.join(args.out, "data", "train.jsonl")
+    if not os.path.exists(os.path.join(model_dir, "model.safetensors")):
+        params = init_params(
+            cfg, jax.random.PRNGKey(args.seed), dtype=jnp.bfloat16
+        )
+        hf_io.save_params(params, cfg, model_dir)
+        del params
+    if not os.path.isdir(tok_dir):
+        # every id the model can emit must decode
+        make_tiny_tokenizer(tok_dir, vocab_size=cfg.vocab_size)
+    if not os.path.exists(data):
+        make_gsm8k_jsonl(data, n=64, seed=args.seed)
+    return model_dir, tok_dir, data
+
+
+def child_grpo(args, device, watch):
+    import math
+
+    import examples.gsm8k_grpo as example
+    from areal_tpu.utils import compile_cache
+
+    # Random weights never solve a problem: every real reward would be 0,
+    # every group-normalised advantage 0 and every gradient exactly 0 — a
+    # run that a broken loss passes. So the smoke scores something the
+    # samples of a group differ in; the GRPO settings stay the yaml's.
+    real_reward = example.gsm8k_reward_fn
+
+    def smoke_reward(prompt, completion, prompt_ids, completion_ids, **kw):
+        solved = real_reward(
+            prompt, completion, prompt_ids, completion_ids, **kw
+        )
+        return float(solved) or float(sum(completion_ids) % 2)
+
+    example.gsm8k_reward_fn = smoke_reward
+    grpo_main = example.main
+    cfg = _model_config(args)
+    model_dir, tok_dir, data = _fabricate(args, cfg)
+    fileroot = os.path.join(args.out, "grpo")
+    # the kernel runs on the chip only: the rehearsal takes the XLA paths
+    attn = "auto" if args.rehearse else "flash"
+    new_tokens = 8 if args.rehearse else 128
+    argv = [
+        "--config", os.path.join(ROOT, "examples", "gsm8k_grpo.yaml"),
+        "experiment_name=chip-smoke", "trial_name=grpo",
+        f"seed={args.seed}",
+        f"cluster.fileroot={fileroot}",
+        f"cluster.name_resolve.nfs_record_root={fileroot}/name_resolve",
+        f"tokenizer_path={tok_dir}",
+        f"actor.path={model_dir}",
+        f"train_dataset.path={data}",
+        "async_training=true",
+        f"total_train_steps={GRPO_STEPS}",
+        "train_dataset.batch_size=4",
+        "rollout.consumer_batch_size=4",
+        "gconfig.n_samples=4",
+        f"gconfig.max_new_tokens={new_tokens}",
+        f"actor.max_new_tokens={new_tokens}",
+        f"actor.attn_impl={attn}",
+        "actor.dtype=bfloat16", "actor.param_dtype=float32",
+        "actor.mb_spec.max_tokens_per_mb=4096",
+        "server.max_num_seqs=16", "server.max_model_len=1024",
+        "recover.mode=disabled", "saver.freq_steps=null",
+    ]
+    summary = grpo_main(argv)
+
+    stats_file = os.path.join(fileroot, "chip-smoke", "grpo", "stats.jsonl")
+    with open(stats_file) as f:
+        lines = [json.loads(l) for l in f]
+    checks = {
+        "steps": len(lines) == GRPO_STEPS,
+        "updates_successful": all(
+            r.get("ppo_actor/update_successful") == 1.0 for r in lines
+        ),
+        "grad_norm_finite": all(
+            math.isfinite(r.get("ppo_actor/grad_norm", math.nan))
+            for r in lines
+        ),
+        "grad_norm_positive": all(
+            r.get("ppo_actor/grad_norm", 0.0) > 0.0 for r in lines
+        ),
+        "version_advanced": summary["model_version"] == GRPO_STEPS,
+        "vocab": cfg.vocab_size == (128 if args.rehearse else 151936),
+    }
+    if not args.rehearse:
+        checks["server_attn_is_kernel"] = (
+            summary["server_attn_impl"] == "kernel"
+        )
+        checks["splash_edge_ge_1024"] = (summary["splash_block"] or 0) >= 1024
+    return _result(
+        "grpo", all(checks.values()), device, checks=checks,
+        vocab_size=cfg.vocab_size, num_layers=cfg.num_layers,
+        actor_attn_impl=summary["actor_attn_impl"],
+        server_attn_impl=summary["server_attn_impl"],
+        splash_block=summary["splash_block"],
+        model_version=summary["model_version"],
+        grad_norm=[r.get("ppo_actor/grad_norm") for r in lines],
+        n_tokens=[r.get("ppo_actor/n_tokens") for r in lines],
+        reward_mean=[r.get("reward/mean") for r in lines],
+        compile=watch.report(compile_cache.enabled_dir()),
+    )
+
+
+def child_parity(args, device, watch):
+    """The Pallas paged-decode kernel against the jnp gather, through the
+    engine: same weights, same greedy requests."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from areal_tpu.api.cli_args import JaxGenConfig
+    from areal_tpu.inference import model_runner
+    from areal_tpu.inference.engine import GenerationEngine
+    from areal_tpu.models.transformer import init_params
+    from areal_tpu.utils import compile_cache
+
+    cfg = _model_config(args)
+    if args.rehearse:
+        # no chip: the same kernel runs in the Pallas interpreter
+        import functools
+
+        model_runner.paged_decode_attention = functools.partial(
+            model_runner.paged_decode_attention, interpret=True
+        )
+    dtype = "float32" if args.rehearse else "bfloat16"
+    params = init_params(
+        cfg, jax.random.PRNGKey(0),
+        dtype=jnp.float32 if args.rehearse else jnp.bfloat16,
+    )
+    rng = np.random.default_rng(args.seed)
+    n_prompts, new_tokens = 8, (8 if args.rehearse else 64)
+    prompts = [
+        rng.integers(0, cfg.vocab_size, size=int(n)).tolist()
+        for n in rng.integers(8, 48 if args.rehearse else 200, size=n_prompts)
+    ]
+
+    def run(attn_impl):
+        gcfg = JaxGenConfig(
+            dtype=dtype, max_num_seqs=8, attn_impl=attn_impl,
+            max_model_len=128 if args.rehearse else 512,
+            **(dict(page_size=16, prefill_chunk=16) if args.rehearse else {}),
+        )
+        engine = GenerationEngine(gcfg, model_config=cfg, params=params)
+        assert engine.attn_impl == attn_impl
+        engine.start()
+        try:
+            futs = [
+                engine.submit({
+                    "input_ids": p,
+                    "sampling_params": {
+                        "max_new_tokens": new_tokens, "greedy": True,
+                    },
+                })
+                for p in prompts
+            ]
+            return [f.result(timeout=PHASE_LIMIT_S["parity"]) for f in futs]
+        finally:
+            engine.stop()
+
+    kernel, ref = run("kernel"), run("jnp")
+    equal, prefix_lens, worst = 0, [], 0.0
+    for a, b in zip(kernel, ref):
+        ta, tb = a["output_ids"], b["output_ids"]
+        n = next(
+            (i for i, (x, y) in enumerate(zip(ta, tb)) if x != y),
+            min(len(ta), len(tb)),
+        )
+        equal += int(ta == tb)
+        prefix_lens.append(n)
+        # the common prefix plus the step that split: each side's argmax
+        # there, so a bf16 tie shows as two near-equal logprobs
+        m = min(n + 1, len(ta), len(tb))
+        la = np.asarray(a["output_logprobs"][:m], np.float64)
+        lb = np.asarray(b["output_logprobs"][:m], np.float64)
+        if m:
+            worst = max(worst, float(np.max(np.abs(la - lb))))
+    finite = all(
+        np.all(np.isfinite(r["output_logprobs"])) for r in kernel + ref
+    )
+    checks = {
+        "all_answered": all(
+            len(r["output_ids"]) == new_tokens for r in kernel + ref
+        ),
+        "finite": finite,
+        "first_token_equal": min(prefix_lens) >= 1,
+        "logprobs_agree": worst <= PARITY_LOGPROB_TOL,
+    }
+    return _result(
+        "parity", all(checks.values()), device, checks=checks,
+        prompts=n_prompts, new_tokens=new_tokens, streams_equal=equal,
+        common_prefix_min=min(prefix_lens),
+        max_abs_logprob_diff=worst, tolerance=PARITY_LOGPROB_TOL,
+        compile=watch.report(compile_cache.enabled_dir()),
+    )
+
+
+def _http(url, payload=None, timeout=300):
+    data = None if payload is None else json.dumps(payload).encode()
+    req = urllib.request.Request(
+        url, data=data, headers={"Content-Type": "application/json"}
+    )
+    with urllib.request.urlopen(req, timeout=timeout) as r:
+        return r.read()
+
+
+def _serve_once(args, model_dir, cache_dir, tag, limit):
+    """Start the server CLI, 4 requests, /metrics, SIGTERM. stdlib only."""
+    with socket.socket() as s:
+        s.bind(("127.0.0.1", 0))
+        port = s.getsockname()[1]
+    new_tokens = 8 if args.rehearse else 32
+    cmd = [
+        sys.executable, "-m", "areal_tpu.inference.server",
+        "--model-path", model_dir, "--port", str(port),
+        "--max-num-seqs", "16", "--max-model-len", "1024",
+        f"--compilation-cache-dir={cache_dir}",
+    ] + (["--dtype", "float32", "--page-size", "16", "--prefill-chunk", "16",
+          "--max-model-len", "128"] if args.rehearse else [])
+    deadline = time.monotonic() + limit
+    base = f"http://127.0.0.1:{port}"
+    with open(os.path.join(args.out, "server.log"), "a") as log:
+        log.write(f"--- {tag} start: {' '.join(cmd)}\n")
+        log.flush()
+        proc = subprocess.Popen(
+            cmd, stdout=log, stderr=subprocess.STDOUT, env=_child_env(args),
+            cwd=ROOT, start_new_session=True,
+        )
+        try:
+            while True:
+                if proc.poll() is not None:
+                    raise RuntimeError(
+                        f"server exited with {proc.returncode} before /health"
+                    )
+                try:
+                    _http(base + "/health", timeout=5)
+                    break
+                except OSError:
+                    if time.monotonic() > deadline:
+                        raise RuntimeError("server never answered /health")
+                    time.sleep(0.5)
+            info = json.loads(_http(base + "/get_model_info"))
+            answers = [
+                json.loads(_http(base + "/generate", {
+                    "input_ids": [3 + i, 5, 7, 11, 13, 17, 19, 23],
+                    "sampling_params": {
+                        "max_new_tokens": new_tokens, "greedy": True,
+                    },
+                }, timeout=max(1.0, deadline - time.monotonic())))
+                for i in range(4)
+            ]
+            metrics = {}
+            for line in _http(base + "/metrics").decode().splitlines():
+                if line.startswith("areal_tpu_gen_compile"):
+                    name, value = line.rsplit(" ", 1)
+                    metrics[name[len("areal_tpu_gen_"):]] = float(value)
+            os.killpg(proc.pid, signal.SIGTERM)
+            proc.wait(timeout=30)
+        finally:
+            _kill_group(proc)
+            proc.wait()
+    return {
+        "answered": sum(
+            len(a.get("output_ids", [])) == new_tokens for a in answers
+        ),
+        "attn_impl": info["attn_impl"],
+        "device": info["device"],
+        "metrics": metrics,
+    }
+
+
+def phase_server(args, limit):
+    """Runs in the orchestrator (stdlib only): the server child owns the
+    chip. Two starts over one cache directory: after a restart, traffic
+    pays no XLA compile for the programs the first start left on disk."""
+    model_dir = os.path.join(args.out, "model")
+    deadline = time.monotonic() + limit
+    try:
+        if not os.path.exists(os.path.join(model_dir, "model.safetensors")):
+            # --phase server alone: fabricate in a process that exits first
+            res = _run_child("fabricate", args, limit)
+            if not res["ok"]:
+                return {**res, "phase": "server"}
+        cache_dir = os.environ.get(
+            "JAX_COMPILATION_CACHE_DIR"
+        ) or os.path.join(ROOT, ".jax_compile_cache")
+        first = _serve_once(
+            args, model_dir, cache_dir, "first", deadline - time.monotonic()
+        )
+        second = _serve_once(
+            args, model_dir, cache_dir, "second", deadline - time.monotonic()
+        )
+    except Exception as e:  # noqa: BLE001 — reported as this phase's failure
+        return _result("server", False, None,
+                       error=f"{type(e).__name__}: {e}")
+    hits = second["metrics"].get("compile_cache_hits_total", 0.0)
+    checks = {
+        "answered_4_of_4": first["answered"] == 4 and second["answered"] == 4,
+        "same_device": first["device"] == second["device"],
+        # the CPU backend of the installed jaxlib cannot read its own
+        # persisted programs back, so only the chip is held to this
+        "second_start_cache_hits": args.rehearse or hits > 0,
+    }
+    if not args.rehearse:
+        checks["attn_is_kernel"] = second["attn_impl"] == "kernel"
+    return _result(
+        "server", all(checks.values()), second["device"], checks=checks,
+        attn_impl=second["attn_impl"],
+        compile={
+            "first_start": first["metrics"],
+            "second_start": second["metrics"],
+            "cache_dir": cache_dir,
+        },
+    )
+
+
+def _ppo_batch(vocab, n_seqs, lo, hi, seed):
+    import numpy as np
+
+    from areal_tpu.utils import data as data_utils
+
+    rng = np.random.default_rng(seed)
+    seqs = [
+        rng.integers(0, vocab, size=int(rng.integers(lo, hi)))
+        for _ in range(n_seqs)
+    ]
+    batch = data_utils.pad_sequences_to_tensors(seqs)
+    lm = np.zeros_like(batch["input_ids"], np.int32)
+    for i, s in enumerate(seqs):
+        lm[i, len(s) // 4: len(s)] = 1
+    batch["loss_mask"] = lm
+    batch["logprobs"] = (
+        rng.standard_normal(lm.shape).astype(np.float32) * 0.1 - 1.0
+    ) * lm
+    batch["versions"] = np.where(lm > 0, 0, -1).astype(np.int32)
+    # siblings of a group must disagree, or every advantage is zero
+    batch["rewards"] = np.asarray(
+        [float(i % 2) for i in range(n_seqs)], np.float32
+    )
+    return batch
+
+
+def _one_update(args, cfg, fsdp, batch, attn):
+    """Fresh engine → one ppo_update → (stats, bytes per device, peak)."""
+    import gc
+
+    import jax
+
+    from areal_tpu.api.cli_args import (
+        MicroBatchSpec, OptimizerConfig, ParallelismConfig, PPOActorConfig,
+    )
+    from areal_tpu.api.io_struct import FinetuneSpec
+    from areal_tpu.engine.ppo.actor import PPOActor
+    from areal_tpu.engine.spmd_engine import SPMDTrainEngine
+
+    acfg = PPOActorConfig(
+        dtype="float32" if args.rehearse else "bfloat16",
+        param_dtype="float32", gradient_checkpointing=True, attn_impl=attn,
+        mb_spec=MicroBatchSpec(max_tokens_per_mb=4096),
+        optimizer=OptimizerConfig(lr=1e-6, warmup_steps_proportion=0.0),
+        parallel=ParallelismConfig(fsdp_parallel_size=fsdp),
+        group_size=2, ppo_n_minibatches=1, group_reward_norm=True,
+        recompute_logprob=True, use_decoupled_loss=True,
+    )
+    engine = SPMDTrainEngine(acfg)
+    engine.initialize(
+        ft_spec=FinetuneSpec(1, 16, len(batch["rewards"])),
+        model_config=cfg, seed=args.seed,
+    )
+    actor = PPOActor(acfg, engine)
+    with_adv = actor.compute_advantages(dict(batch))
+    stats = dict(actor.ppo_update(with_adv)[0])
+    lm = with_adv["loss_mask"].astype(bool)
+    stats["adv_scale"] = float(abs(with_adv["advantages"][lm]).mean())
+    # what each device really holds: the shards, not the sharding's name
+    held = {}
+    for leaf in jax.tree_util.tree_leaves((engine.params, engine.opt_state)):
+        for sh in leaf.addressable_shards:
+            held[sh.device.id] = held.get(sh.device.id, 0) + sh.data.nbytes
+    peak = {
+        d.id: (d.memory_stats() or {}).get("peak_bytes_in_use")
+        for d in jax.devices()
+    }
+    del actor, engine
+    gc.collect()
+    return stats, held, peak
+
+
+def child_fsdp4(args, device, watch):
+    import math
+
+    from areal_tpu.utils import compile_cache
+
+    attn = "auto" if args.rehearse else "flash"
+    cfg = _model_config(args)
+    lo, hi = (8, 16) if args.rehearse else (256, 512)
+    batch = _ppo_batch(cfg.vocab_size, 8, lo, hi, args.seed)
+    s1, held1, _ = _one_update(args, cfg, 1, batch, attn)
+    s4, held4, _ = _one_update(args, cfg, 4, batch, attn)
+
+    def rel(a, b, floor=1e-12):
+        return abs(a - b) / max(abs(a), abs(b), floor)
+
+    total1 = sum(held1.values())
+    shares = {d: b / total1 for d, b in sorted(held4.items())}
+    # (b) the model one chip cannot train: Qwen2-1.5B, f32 params + Adam
+    big = _model_config(args, "1p5b")
+    sb, _, peak = _one_update(
+        args, big, 4, _ppo_batch(big.vocab_size, 8, lo, hi, args.seed + 1),
+        attn,
+    )
+    checks = {
+        "one_device_on_one_device": len(held1) == 1,
+        # the loss is a mean of +-advantages that nearly cancel: held to the
+        # advantages' scale, not to its own near-zero size
+        "loss_agrees": (
+            rel(s1["loss"], s4["loss"], s1["adv_scale"]) <= FSDP_REL_TOL
+        ),
+        "grad_norm_agrees": (
+            rel(s1["grad_norm"], s4["grad_norm"]) <= FSDP_REL_TOL
+        ),
+        "four_devices_hold_shards": len(held4) == 4,
+        "every_share_small": max(shares.values()) <= FSDP_SHARE_MAX,
+        "big_step_finite": (
+            sb["update_successful"] == 1.0
+            and math.isfinite(sb["loss"]) and math.isfinite(sb["grad_norm"])
+        ),
+    }
+    return _result(
+        "fsdp4", all(checks.values()), device, checks=checks,
+        attn_impl=attn,
+        one_device={"loss": s1["loss"], "grad_norm": s1["grad_norm"],
+                    "adv_scale": s1["adv_scale"], "state_bytes": total1},
+        fsdp4={"loss": s4["loss"], "grad_norm": s4["grad_norm"],
+               "state_share_per_device": shares},
+        qwen2_1p5b={"vocab_size": big.vocab_size,
+                    "num_layers": big.num_layers, "loss": sb["loss"],
+                    "grad_norm": sb["grad_norm"],
+                    "peak_bytes_in_use_per_device": peak},
+        compile=watch.report(compile_cache.enabled_dir()),
+    )
+
+
+def run_child(args):
+    sys.path.insert(0, ROOT)
+    # first: puts the scoped-VMEM flag into LIBTPU_INIT_ARGS before any
+    # backend starts (the splash probe depends on it)
+    import areal_tpu  # noqa: F401
+    import jax
+
+    from areal_tpu.utils import compile_cache
+
+    devs = jax.devices()
+    device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
+              "count": len(devs)}
+    if not args.rehearse and device["platform"] != "tpu":
+        res = _result(args.child, False, device,
+                      error="no TPU: this phase has no CPU fallback")
+    elif len(devs) < args.chips:
+        res = _result(args.child, False, device,
+                      error=f"needs {args.chips} devices")
+    else:
+        watch = _CompileWatch()
+        compile_cache.enable_compilation_cache(
+            compile_cache.resolve_cache_dir()
+        )
+        if args.child == "fabricate":
+            _fabricate(args, _model_config(args))
+            res = _result("fabricate", True, device)
+        else:
+            child = {"grpo": child_grpo, "parity": child_parity,
+                     "fsdp4": child_fsdp4}[args.child]
+            res = child(args, device, watch)
+    print(json.dumps(res), flush=True)
+    return 0 if res["ok"] else 1
+
+
+def main(argv=None):
+    p = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    p.add_argument("--rehearse", action="store_true",
+                   help="tiny model on the CPU (chosen here, never detected)")
+    p.add_argument("--chips", type=int, choices=(1, 4), default=1,
+                   help="4: run only the sharded-trainer phase")
+    p.add_argument("--phase", action="append",
+                   help="run only this phase (repeatable)")
+    p.add_argument("--seed", type=int, default=0)
+    p.add_argument("--out", default=os.path.join(ROOT, "chip_smoke_out"),
+                   help="checkpoint, data, logs (git ignores the default)")
+    p.add_argument("--child", help=argparse.SUPPRESS)
+    args = p.parse_args(argv)
+    args.out = os.path.abspath(args.out)
+    if args.child:
+        return run_child(args)
+    return orchestrate(args)
+
+
+if __name__ == "__main__":
+    sys.exit(main())

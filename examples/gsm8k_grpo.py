@@ -63,6 +63,12 @@ def main(argv):
     import jax
 
     from areal_tpu.parallel.distributed import broadcast_pytree
+    from areal_tpu.utils import compile_cache
+
+    # before the first jit: the train programs are the longest compiles of
+    # a cold run (JAX_COMPILATION_CACHE_DIR when inherited, else the
+    # checkout's .jax_compile_cache)
+    compile_cache.enable_compilation_cache(compile_cache.resolve_cache_dir())
 
     is_main = jax.process_index() == 0
     multi_process = jax.process_count() > 1
@@ -362,9 +368,22 @@ def main(argv):
         step = step.next()
 
     stats_logger.close()
+    # what actually ran, for callers that check it (chip_smoke.py): the
+    # configs say "auto"/"flash", these are what the engines resolved
+    summary = {
+        "steps": step.global_step,
+        "model_version": engine.get_version(),
+        "actor_attn_impl": config.actor.attn_impl,
+        "splash_block": getattr(engine, "_splash_block", None),
+        "server_attn_impl": (
+            rollout.engine.attn_impl if colocated and rollout else None
+        ),
+        "compile_cache_dir": compile_cache.enabled_dir(),
+    }
     if rollout is not None:
         rollout.destroy()
-    logger.info("training complete")
+    logger.info(f"training complete: {summary}")
+    return summary
 
 
 if __name__ == "__main__":

@@ -1,0 +1,142 @@
+"""The main path's Pallas kernels, compiled for a v5e that is described and
+not attached (on-chip-measurement guide, section 2).
+
+Nothing runs: these say that the chip's compiler accepts the kernels at the
+published widths of Qwen2-0.5B (Hq 14, Hkv 2, D 64) and Qwen2-1.5B (Hq 12,
+Hkv 2, D 128), with the scoped-VMEM flag ``import areal_tpu`` puts into
+``LIBTPU_INIT_ARGS``. Results and times come only from ``chip_smoke.py`` on
+the chip.
+
+The topology is described inside a module-scoped fixture — never while a
+module is imported — and every compile happens in the test's own process,
+with the persistent compile cache off (a described-chip entry can be written
+but not read back without the chip).
+"""
+
+import os
+
+import pytest
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    # the flag must be in the environment before the TPU library loads
+    import areal_tpu  # noqa: F401
+
+    assert "xla_tpu_scoped_vmem_limit_kib" in os.environ["LIBTPU_INIT_ARGS"]
+    import jax
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 — no TPU compiler in this image
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was_enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was_enabled)
+    cc.reset_cache()
+
+
+def _sds(shape, dtype, sharding):
+    import jax
+
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+# (head_dim, num_heads, layout, rows, chunk buffer length or 0) at the
+# config's defaults: page 256, 4 pages per compute block, 8 slots per block
+_PAGED_CASES = [
+    pytest.param(64, 14, "token_packed", 1, 32, id="d64-packed-rows1"),
+    pytest.param(64, 14, "token_packed", 64, 32, id="d64-packed-rows64"),
+    pytest.param(64, 14, "head_merged", 1, 32, id="d64-merged-rows1"),
+    pytest.param(64, 14, "head_merged", 64, 32, id="d64-merged-rows64"),
+    pytest.param(64, 14, "head_merged", 16, 0, id="d64-merged-nochunk"),
+    pytest.param(128, 12, "token_packed", 16, 32, id="d128-packed-rows16"),
+]
+
+
+@pytest.mark.parametrize("d,hq,layout,rows,chunk", _PAGED_CASES)
+def test_paged_decode_kernel_compiles_for_v5e(
+    one_chip, d, hq, layout, rows, chunk
+):
+    import jax.numpy as jnp
+
+    from areal_tpu.api.cli_args import JaxGenConfig
+    from areal_tpu.ops import paged_attention as pa
+
+    gen = JaxGenConfig()
+    hkv, layers, num_pages, pages_per_seq = 2, 24, 64, 4
+    pool = pa.packed_pool_shape(
+        layers, hkv, num_pages, gen.page_size, d,
+        head_merge=layout == "head_merged",
+    )
+    args = [
+        _sds((rows, hq, d), jnp.bfloat16, one_chip),
+        _sds(pool, jnp.bfloat16, one_chip),
+        _sds(pool, jnp.bfloat16, one_chip),
+        _sds((), jnp.int32, one_chip),
+        _sds((rows,), jnp.int32, one_chip),
+        _sds((rows, pages_per_seq), jnp.int32, one_chip),
+    ]
+    if chunk:
+        args += [
+            _sds((rows, hkv, chunk, d), jnp.bfloat16, one_chip),
+            _sds((rows, hkv, chunk, d), jnp.bfloat16, one_chip),
+            _sds((rows,), jnp.int32, one_chip),
+        ]
+    compiled = pa.paged_decode_attention.lower(
+        *args,
+        pages_per_compute_block=gen.pages_per_compute_block,
+        slots_per_block=gen.slots_per_block,
+        num_kv_heads=hkv,
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize(
+    "d,hq", [pytest.param(64, 14, id="d64"), pytest.param(128, 12, id="d128")]
+)
+def test_splash_fwd_bwd_compiles_at_edge_2048(one_chip, monkeypatch, d, hq):
+    """fwd+bwd of the trainer's attention at the block edge
+    ``probe_block_size`` should choose on a chip whose scoped-VMEM limit
+    was raised. ``jax.default_backend()`` is the CPU here, so the edge is
+    set in the test rather than probed."""
+    import jax
+    import jax.numpy as jnp
+
+    from areal_tpu.ops import flash
+
+    monkeypatch.setattr(flash, "_PROBED_BLOCK", 2048)
+    flash._make_kernel.cache_clear()
+    t, hkv = 2048, 2
+
+    def loss(q, k, v, seg):
+        return flash.flash_segment_attention(q, k, v, seg).astype(
+            jnp.float32
+        ).sum()
+
+    try:
+        compiled = (
+            jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
+            .lower(
+                _sds((1, t, hq, d), jnp.bfloat16, one_chip),
+                _sds((1, t, hkv, d), jnp.bfloat16, one_chip),
+                _sds((1, t, hkv, d), jnp.bfloat16, one_chip),
+                _sds((1, t), jnp.int32, one_chip),
+            )
+            .compile()
+        )
+    finally:
+        # the kernel objects cached under the steered edge must not leak
+        # into other tests of this worker
+        flash._make_kernel.cache_clear()
+    assert flash._block_size(t) == 2048
+    assert "tpu_custom_call" in compiled.as_text()

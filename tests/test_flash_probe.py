@@ -1,7 +1,8 @@
 """Splash block probing (ops/flash.probe_block_size): override, fallback
-cascade on compile failure, and the degraded-loudly path. The probe exists
-because round 3's env-gated block size silently lost 5x when the flag
-didn't take — so its failure behavior is itself load-bearing."""
+cascade on compile failure, and the refusal when no large edge compiles.
+The probe exists because an env-gated block size silently lost the fast
+path when the flag didn't take — so its failure behavior is itself
+load-bearing."""
 
 import numpy as np
 import pytest
@@ -34,9 +35,10 @@ def test_cpu_backend_disables_big_blocks(monkeypatch):
 
 
 def test_fallback_cascade_on_compile_failure(monkeypatch):
-    """If 1024 fails to compile/run, the probe steps down and keeps the
-    largest edge that works; a total failure degrades to kernel defaults
-    (0) instead of crashing."""
+    """If 2048 fails to compile/run, the probe steps down and keeps the
+    largest edge that works; when no edge >= 512 works on a TPU it raises
+    with the compiler's message instead of going on with the kernel's
+    defaults."""
     monkeypatch.delenv("AREAL_TPU_SPLASH_BLOCK", raising=False)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
@@ -44,22 +46,25 @@ def test_fallback_cascade_on_compile_failure(monkeypatch):
 
     def fake_attention(q, k, v, seg, window=0):
         attempts.append(F._PROBED_BLOCK)
-        if F._PROBED_BLOCK > 256:
+        if F._PROBED_BLOCK > 512:
             raise RuntimeError("RESOURCE_EXHAUSTED: scoped vmem")
         return (q.astype(np.float32) * 0).sum()
 
     monkeypatch.setattr(F, "flash_segment_attention", fake_attention)
-    assert F.probe_block_size() == 256
-    assert attempts == [2048, 1024, 512, 256]  # r5: max edge raised to 2048
+    assert F.probe_block_size() == 512
+    assert attempts == [2048, 1024, 512]
 
-    # total failure: every candidate raises -> 0, loudly (log), no crash
+    # total failure: every candidate raises -> an error that carries the
+    # compiler's words, and nothing cached (a retry probes again)
     F._PROBED_BLOCK = None
 
     def always_fail(q, k, v, seg, window=0):
-        raise RuntimeError("no")
+        raise RuntimeError("RESOURCE_EXHAUSTED: vmem says no")
 
     monkeypatch.setattr(F, "flash_segment_attention", always_fail)
-    assert F.probe_block_size() == 0
+    with pytest.raises(RuntimeError, match="vmem says no"):
+        F.probe_block_size()
+    assert F._PROBED_BLOCK is None
 
 
 def test_block_size_divisibility():

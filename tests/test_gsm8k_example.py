@@ -18,7 +18,19 @@ from tests.fixtures import (
 )
 
 
-def test_gsm8k_grpo_example_runs(tmp_path):
+@pytest.fixture
+def scoped_compile_cache(monkeypatch):
+    """The example turns the persistent compile cache on (process-global
+    jax config); keep that to this test. The placement rule under test is
+    the unset-variable branch: <checkout>/.jax_compile_cache."""
+    from areal_tpu.utils import compile_cache
+
+    monkeypatch.delenv(compile_cache.CACHE_DIR_ENV, raising=False)
+    yield compile_cache
+    compile_cache.disable_compilation_cache()
+
+
+def test_gsm8k_grpo_example_runs(tmp_path, scoped_compile_cache):
     from examples.gsm8k_grpo import main
 
     model_dir = str(tmp_path / "model")
@@ -58,7 +70,15 @@ def test_gsm8k_grpo_example_runs(tmp_path):
         "recover.mode=disabled",
         "saver.freq_steps=null",
     ]
-    main(argv)
+    summary = main(argv)
+    assert summary["steps"] == 2 and summary["model_version"] == 2
+    # on the CPU "auto" resolves to the jnp gather; the trainer entry
+    # enabled the cache at the checkout's fixed directory
+    assert summary["server_attn_impl"] == "jnp"
+    assert (
+        summary["compile_cache_dir"]
+        == scoped_compile_cache.default_cache_dir()
+    )
 
     stats_file = os.path.join(fileroot, "grpo-e2e", "t0", "stats.jsonl")
     assert os.path.exists(stats_file)
