@@ -320,8 +320,7 @@ class JaxGenConfig:
     decode_chunk: int = 8
     # decode chunks kept in flight while the previous chunk's results are
     # fetched/processed on host (0 = fully synchronous). Overlapping hides
-    # the host round-trip — essential over a driver tunnel, still worth a
-    # dispatch latency on a local chip
+    # the host round-trip, at the price of one dispatch of latency
     decode_pipeline: int = 1
     # --- decode tail compaction (r6) ---
     # dispatch decode over a pow2 bucket of ACTIVE slots instead of the

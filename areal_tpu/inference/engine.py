@@ -3337,7 +3337,7 @@ class GenerationEngine:
     def _decode(self) -> bool:
         """Pipelined decode: dispatch chunk N+1, then process chunk N's
         results while N+1 executes on device — the result fetch (a full
-        round-trip over a driver tunnel) overlaps device compute.
+        round-trip) overlaps device compute.
 
         Speculation composition (r7): a verify dispatch needs drafts, and
         drafts need CURRENT host history — so verify chunks are only

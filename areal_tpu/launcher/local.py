@@ -152,11 +152,15 @@ def launch_servers(
         # server forwards to the engine): jax reads
         # JAX_COMPILATION_CACHE_DIR at interpreter start, so every
         # restart of a server replays its compiles from disk instead of
-        # re-paying the decode bucket-ladder warmup
+        # re-paying the decode bucket-ladder warmup. An INHERITED value
+        # stays: whoever started the launcher placed the cache
+        # (utils/compile_cache.resolve_cache_dir), and the children
+        # inherit it with the rest of the environment.
+        from areal_tpu.utils.compile_cache import CACHE_DIR_ENV
+
         base_env = dict(base_env or {})
-        base_env["JAX_COMPILATION_CACHE_DIR"] = (
-            gen_config.compilation_cache_dir
-        )
+        if not os.environ.get(CACHE_DIR_ENV):
+            base_env[CACHE_DIR_ENV] = gen_config.compilation_cache_dir
         seed = getattr(
             getattr(gen_config, "precompile", None), "seed_artifact", ""
         )
@@ -167,9 +171,14 @@ def launch_servers(
             # restarts warm from disk within the spike instead of
             # re-paying the compile storm. Idempotent — existing
             # entries are never clobbered.
-            from areal_tpu.utils.compile_cache import ensure_seeded
+            from areal_tpu.utils.compile_cache import (
+                ensure_seeded,
+                resolve_cache_dir,
+            )
 
-            ensure_seeded(gen_config.compilation_cache_dir, seed)
+            ensure_seeded(
+                resolve_cache_dir(gen_config.compilation_cache_dir), seed
+            )
     for i in range(n_servers):
         host = gen_config.host or "127.0.0.1"
         cmd = JaxGenConfig.build_cmd(
@@ -324,6 +333,22 @@ def local_main(
         alloc is None or alloc.type_ != AllocationType.LLM_SERVER_ONLY
     )
     n_trainers = max(1, getattr(config.launcher, "trainer_processes", 1))
+    if wants_servers and wants_trainer:
+        # a chip belongs to one process at a time: on a one-chip host the
+        # second process to reach it hangs or dies, so refuse here, in the
+        # parent (which itself never starts a jax backend)
+        from areal_tpu.platforms import local_chip_count
+
+        chips = local_chip_count()
+        wanted = alloc.gen.data_parallel_size + n_trainers
+        if chips == 1:
+            raise ValueError(
+                f"allocation {config.allocation_mode!r} asks for {wanted} processes "
+                "(generation servers + trainer) on a host with one TPU "
+                "chip, and a chip serves one process. Use colocated mode: "
+                "run the trainer entry alone with no allocation_mode / "
+                "AREAL_LLM_SERVER_ADDRS, and it generates in-process."
+            )
     trainer_names = [
         f"trainer_{r}" if r else "trainer" for r in range(n_trainers)
     ]

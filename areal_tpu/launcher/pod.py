@@ -89,9 +89,11 @@ class PodLauncher:
         explicit = os.environ.get("AREAL_POD_HOSTS", "")
         if explicit:
             return [h for h in explicit.split(",") if h]
-        from areal_tpu.platforms import current_platform
+        # the launcher parent must not start a jax backend (it would hold
+        # the chip its trainers need): pod discovery is environment-only
+        from areal_tpu.platforms import TpuPlatform
 
-        return current_platform().pod_worker_hosts() or ["localhost"]
+        return TpuPlatform().pod_worker_hosts() or ["localhost"]
 
     def launch_trainers(
         self,

@@ -41,12 +41,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -2.3819763e38
 
-# jax renamed TPUCompilerParams → CompilerParams across the versions this
-# repo meets (0.4.x CPU CI vs the TPU image); take whichever exists
-_CompilerParams = getattr(
-    pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-)
-
 
 def pack_factor(head_dim: int) -> int:
     """Tokens per 128-lane pool row (1 for D>=128; D must divide 128)."""
@@ -556,8 +550,8 @@ def paged_decode_attention(
                 pl.BlockSpec(
                     (sb, hkv, t, d), lambda b, *_: (b, 0, 0, 0)
                 ),
-                pl.BlockSpec(memory_space=pltpu.ANY),
-                pl.BlockSpec(memory_space=pltpu.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
             ],
             out_specs=pl.BlockSpec(
                 (sb, hkv, gp, d), lambda b, *_: (b, 0, 0, 0)
@@ -573,7 +567,7 @@ def paged_decode_attention(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((s, hkv, gp, d), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)
         ),
         interpret=interpret,

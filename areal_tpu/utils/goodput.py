@@ -163,15 +163,8 @@ def _install_listener() -> bool:
         monitoring.register_event_duration_secs_listener(
             _on_monitoring_event
         )
-        try:
-            # plain count events carry the compilation-cache outcome;
-            # older jax without the hook just loses the hit/miss split
-            monitoring.register_event_listener(_on_count_event)
-        except Exception:  # pragma: no cover - version skew guard
-            logger.warning(
-                "jax.monitoring has no plain-event listener hook; "
-                "compile-cache hit/miss counters will read 0"
-            )
+        # plain count events carry the compilation-cache outcome
+        monitoring.register_event_listener(_on_count_event)
         _LISTENER_INSTALLED = True
         return True
 

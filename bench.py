@@ -529,9 +529,15 @@ def _scaleup_phase() -> dict:
 
     Per-cell graceful degradation: one failed cell nulls its numbers
     and the others still report."""
-    import tempfile
+    import shutil
 
-    cache_dir = tempfile.mkdtemp(prefix="bench_scaleup_cache_")
+    from areal_tpu.utils.compile_cache import resolve_cache_dir
+
+    # a fixed sub-directory of the one compile-cache location, emptied
+    # here: the "cold" cell must start from nothing
+    cache_dir = os.path.join(resolve_cache_dir(), "bench_scaleup")
+    shutil.rmtree(cache_dir, ignore_errors=True)
+    os.makedirs(cache_dir)
     cells = {}
     aot_env = {
         "AREAL_WORKER_COMPILE_CACHE": cache_dir,
@@ -1828,45 +1834,49 @@ def _selfplay_phase() -> dict:
     }
 
 
+def _require_tpu() -> None:
+    """The device phases measure the chip: refuse to run them anywhere
+    else (a time taken on the CPU backend is not a device metric)."""
+    import jax
+
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        raise SystemExit(
+            f"bench.py: jax reports platform {dev.platform!r} "
+            f"({dev.device_kind}), not 'tpu' — nothing measured"
+        )
+
+
 def main():
     import jax
     import jax.numpy as jnp
 
-    # persistent XLA compile cache (r6): the warmup bill is the compiled
-    # bucket ladder (r5: 191 backend compiles / 378 s before the first
-    # measured step) — deterministic programs, so a repo-local disk cache
-    # replays them on every run after the first. BENCH_COMPILE_CACHE=""
-    # disables. The hit count lands in ``extra``: warm run → hits ~=
-    # warmup_compiles of a cold run; cold run → hits 0 (jax only emits a
-    # monitoring event for cache HITS — misses are log-only, so a miss
-    # counter would be a dead always-zero field).
+    _require_tpu()
+    # persistent XLA compile cache: the warmup bill is the compiled bucket
+    # ladder — deterministic programs, so a disk cache replays them on
+    # every run after the first. Placement is utils/compile_cache's one
+    # rule (JAX_COMPILATION_CACHE_DIR if inherited, else the checkout's
+    # .jax_compile_cache). The hit count lands in ``extra``: warm run →
+    # hits ~= warmup_compiles of a cold run; cold run → hits 0 (jax only
+    # emits a monitoring event for cache HITS — misses are log-only, so a
+    # miss counter would be a dead always-zero field).
+    from areal_tpu.utils import compile_cache
+
     cache_events = {"hits": 0}
-    cache_dir = os.environ.get(
-        "BENCH_COMPILE_CACHE",
-        os.path.join(
-            os.path.dirname(os.path.abspath(__file__)), ".jax_compile_cache"
-        ),
-    )
-    if cache_dir:
-        from areal_tpu.utils.compile_cache import enable_compilation_cache
+    cache_dir = compile_cache.resolve_cache_dir()
+    compile_cache.enable_compilation_cache(cache_dir)
 
-        if not enable_compilation_cache(cache_dir):
-            cache_dir = ""
+    def _on_cache_event(event, **kw):
+        if "cache_hit" in event:
+            cache_events["hits"] += 1
 
-        def _on_cache_event(event, **kw):
-            if "cache_hit" in event:
-                cache_events["hits"] += 1
-
-        try:
-            jax.monitoring.register_event_listener(_on_cache_event)
-        except Exception:
-            pass
+    jax.monitoring.register_event_listener(_on_cache_event)
 
     # count backend compilations: a measured step that compiles is a
     # methodology bug, and the counter proves (or rules out) it post-hoc.
-    # Traces are counted separately — they are cheap (~2 ms) and frequent,
-    # while each backend compile costs ~2 s on the remote compile service;
-    # lumping them (round-3's mistake) made the counts unreadable.
+    # Traces are counted separately — they are cheap and frequent, while
+    # each backend compile costs seconds; lumping them made the counts
+    # unreadable.
     compile_events = {"count": 0, "secs": 0.0, "traces": 0}
 
     def _on_event(event: str, duration: float, **kw):
@@ -2409,7 +2419,13 @@ def main():
     def train_on(prompts, results):
         batch = to_train_batch(prompts, results)
         out = actor.compute_advantages(dict(batch))
-        actor.ppo_update(out)
+        for s in actor.ppo_update(out):
+            # the main GRPO phase: a skipped or non-finite update must end
+            # the run non-zero, not be timed as a step
+            if s["update_successful"] != 1.0 or not np.isfinite(
+                s["grad_norm"]
+            ):
+                raise RuntimeError(f"GRPO update failed: {s}")
         tokens = int(batch["attention_mask"].sum())
         lens = [len(p) + len(r["output_ids"]) for p, r in zip(prompts, results)]
         return tokens, lens
@@ -3104,6 +3120,7 @@ def _kv_tiers_standalone(tiny: bool) -> None:
             max_num_seqs=8, max_model_len=512, prefill_chunk=64,
         )
     else:
+        _require_tpu()
         cfg = ModelConfig(
             vocab_size=32768, hidden_size=896, intermediate_size=4864,
             num_layers=24, num_heads=14, num_kv_heads=2, head_dim=64,

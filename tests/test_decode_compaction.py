@@ -207,11 +207,13 @@ def test_compact_disabled_dispatches_full_width(model):
     assert metrics["decode_rows_active"] <= 1
 
 
-def test_compilation_cache_helper(tmp_path):
-    """enable_compilation_cache points jax at the directory (and is an
-    optimization: empty dir string is a no-op returning False)."""
+def test_compilation_cache_helper(tmp_path, monkeypatch):
+    """enable_compilation_cache points jax at the directory (and with
+    nothing asked for — empty dir string, no inherited
+    JAX_COMPILATION_CACHE_DIR — it is a no-op returning False)."""
     from areal_tpu.utils import compile_cache
 
+    monkeypatch.delenv(compile_cache.CACHE_DIR_ENV, raising=False)
     assert not compile_cache.enable_compilation_cache("")
     d = str(tmp_path / "xla_cache")
     assert compile_cache.enable_compilation_cache(d)
