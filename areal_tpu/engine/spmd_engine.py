@@ -374,7 +374,26 @@ class SPMDTrainEngine(TrainEngine):
         if impl == "flash":
             from areal_tpu.ops.flash import flash_segment_attention
 
-            return functools.partial(flash_segment_attention, window=window)
+            attend = functools.partial(flash_segment_attention, window=window)
+            if self.mesh.size == 1:
+                return attend
+            # GSPMD cannot partition a Mosaic kernel ("wrap the call in a
+            # shard_map"): each device runs it on its own rows (and heads).
+            # Attention never crosses a row, so this is exact; only a
+            # sequence split would need a cross-device kernel.
+            if self.config.parallel.seq_parallel_size > 1:
+                raise ValueError(
+                    "attn_impl='flash' has no sequence-parallel form: use "
+                    "'ring' or 'ulysses' with seq_parallel_size > 1"
+                )
+            qkv_spec = P(("data", "fsdp"), None, "tensor", None)
+            return jax.shard_map(
+                attend,
+                mesh=self.mesh,
+                in_specs=(qkv_spec, qkv_spec, qkv_spec, P(("data", "fsdp"), None)),
+                out_specs=qkv_spec,
+                check_vma=False,
+            )
         if impl == "auto" or self.config.parallel.seq_parallel_size == 1:
             return None
         if not hasattr(self, "_cached_attend"):

@@ -14,7 +14,14 @@ import numpy as np
 
 try:  # optional C++ fast path (areal_tpu/csrc/interval_ops.cpp)
     from areal_tpu.csrc import ffd_allocate as _ffd_allocate_cc
-except Exception:  # pragma: no cover - extension not built
+except Exception as _e:  # pragma: no cover - extension not built
+    # the .so is built by g++ on first import and never committed: a
+    # machine that cannot build it takes the numpy path, and says so
+    import logging
+
+    logging.getLogger("areal_tpu.datapack").warning(
+        "native interval ops unavailable (%s): using the numpy path", _e
+    )
     _ffd_allocate_cc = None
 
 

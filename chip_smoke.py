@@ -51,7 +51,13 @@ QWEN2_0P5B = dict(
 TOTAL_LIMIT_S = 1140
 PHASE_LIMIT_S = {"grpo": 660, "parity": 360, "server": 360, "fsdp4": 1500}
 GRPO_STEPS = 3
-PARITY_LOGPROB_TOL = 2e-2
+# kernel vs jnp, bf16: the op is held to the tolerance the repo's own
+# interpret-mode test uses (tests/test_paged_kernel_parity.py, rtol = atol =
+# 3e-2); through 24 layers and a bf16 LM head the greedy logprobs are held to
+# 2e-2 on the mean and to two bf16 ulps of a logit in [4, 8) on the worst one
+PARITY_OP_TOL = 3e-2
+PARITY_LOGPROB_MEAN_TOL = 2e-2
+PARITY_LOGPROB_MAX_TOL = 2 * 2.0 ** -5
 FSDP_REL_TOL = 2e-2
 FSDP_SHARE_MAX = 0.3
 
@@ -346,9 +352,67 @@ def child_grpo(args, device, watch):
     )
 
 
+def _kernel_vs_jnp_op(cfg, gen, layout, seed, interpret):
+    """The op alone on unit-normal q/k/v (a peaky softmax: one position
+    masked wrongly moves the output by O(1)), at the model's head geometry
+    and the config's page/block defaults: ragged lengths with an empty slot,
+    page-exact ones and a full window, plus the in-flight chunk buffer."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from areal_tpu.ops import paged_attention as pa
+
+    rng = np.random.default_rng(seed)
+    d, hq, hkv = cfg.head_dim, cfg.num_heads, cfg.num_kv_heads
+    page, pps, layers, chunk_t = gen.page_size, 4, 2, 32
+    lengths = [0, 1, page - 1, page, page + 1, 2 * page, 3 * page + 7,
+               pps * page, 5, page + page // 6, page // 3, 2 * page + 1,
+               page // 4, 3 * page - 1, 2, 2 * page + page // 2]
+    counts = [3, 0, 32, 1, 5, 0, 2, 7, 0, 31, 8, 0, 1, 16, 0, 4]
+    rows, num_pages = len(lengths), len(lengths) * pps
+    merged = layout == "head_merged"
+    shape = pa.packed_pool_shape(layers, hkv, num_pages, page, d, merged)
+
+    def pool():
+        tok = rng.standard_normal((layers, hkv, num_pages, page, d))
+        if merged:  # row = tokens x all kv heads (inverse of unpacked_view)
+            tok = tok.transpose(0, 2, 3, 1, 4)
+        return jnp.asarray(tok.reshape(shape), jnp.bfloat16)
+
+    k_pages, v_pages = pool(), pool()
+    tables = jnp.asarray(
+        rng.permutation(num_pages).reshape(rows, pps), jnp.int32
+    )
+    q = jnp.asarray(rng.standard_normal((rows, hq, d)), jnp.bfloat16)
+    ck, cv = (
+        jnp.asarray(rng.standard_normal((rows, hkv, chunk_t, d)), jnp.bfloat16)
+        for _ in range(2)
+    )
+    common = (q, k_pages, v_pages, jnp.int32(1), jnp.asarray(lengths, jnp.int32),
+              tables, ck, cv, jnp.asarray(counts, jnp.int32))
+    got = pa.paged_decode_attention(
+        *common, pages_per_compute_block=gen.pages_per_compute_block,
+        slots_per_block=gen.slots_per_block, num_kv_heads=hkv,
+        interpret=interpret,
+    )
+    want = pa.paged_decode_attention_jnp(*common, num_kv_heads=hkv)
+    # a slot with nothing to attend to is undefined (the engine never
+    # reads it)
+    defined = (np.asarray(lengths) > 0) | (np.asarray(counts) > 0)
+    got = np.asarray(got, np.float32)[defined]
+    want = np.asarray(want, np.float32)[defined]
+    excess = np.abs(got - want) - PARITY_OP_TOL * np.abs(want)
+    return {
+        "max_abs_diff": float(np.abs(got - want).max()),
+        "within_tolerance": bool(
+            np.isfinite(got).all() and excess.max() <= PARITY_OP_TOL
+        ),
+    }
+
+
 def child_parity(args, device, watch):
-    """The Pallas paged-decode kernel against the jnp gather, through the
-    engine: same weights, same greedy requests."""
+    """The Pallas paged-decode kernel against the jnp gather: the op alone,
+    then through the engine — same weights, same greedy requests."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -367,6 +431,13 @@ def child_parity(args, device, watch):
         model_runner.paged_decode_attention = functools.partial(
             model_runner.paged_decode_attention, interpret=True
         )
+    op = {
+        layout: _kernel_vs_jnp_op(
+            cfg, JaxGenConfig(page_size=32) if args.rehearse
+            else JaxGenConfig(), layout, args.seed, args.rehearse,
+        )
+        for layout in ("token_packed", "head_merged")
+    }
     dtype = "float32" if args.rehearse else "bfloat16"
     params = init_params(
         cfg, jax.random.PRNGKey(0),
@@ -403,38 +474,48 @@ def child_parity(args, device, watch):
             engine.stop()
 
     kernel, ref = run("kernel"), run("jnp")
-    equal, prefix_lens, worst = 0, [], 0.0
+    # Random weights make a near-flat distribution over 151936 tokens, so
+    # greedy ties split the streams early; what is comparable is the common
+    # prefix: same history, same token, two attention implementations.
+    prefix_lens, diffs, split_gaps = [], [], []
     for a, b in zip(kernel, ref):
         ta, tb = a["output_ids"], b["output_ids"]
         n = next(
             (i for i, (x, y) in enumerate(zip(ta, tb)) if x != y),
             min(len(ta), len(tb)),
         )
-        equal += int(ta == tb)
         prefix_lens.append(n)
-        # the common prefix plus the step that split: each side's argmax
-        # there, so a bf16 tie shows as two near-equal logprobs
-        m = min(n + 1, len(ta), len(tb))
-        la = np.asarray(a["output_logprobs"][:m], np.float64)
-        lb = np.asarray(b["output_logprobs"][:m], np.float64)
-        if m:
-            worst = max(worst, float(np.max(np.abs(la - lb))))
+        la = np.asarray(a["output_logprobs"], np.float64)
+        lb = np.asarray(b["output_logprobs"], np.float64)
+        diffs.extend(np.abs(la[:n] - lb[:n]).tolist())
+        if n < min(len(ta), len(tb)):
+            # where they split each side took its own argmax: a tie shows
+            # as two near-equal logprobs (reported, not held to a limit)
+            split_gaps.append(float(abs(la[n] - lb[n])))
     finite = all(
         np.all(np.isfinite(r["output_logprobs"])) for r in kernel + ref
     )
+    worst, mean = float(np.max(diffs)), float(np.mean(diffs))
     checks = {
+        "op_token_packed": op["token_packed"]["within_tolerance"],
+        "op_head_merged": op["head_merged"]["within_tolerance"],
         "all_answered": all(
             len(r["output_ids"]) == new_tokens for r in kernel + ref
         ),
         "finite": finite,
         "first_token_equal": min(prefix_lens) >= 1,
-        "logprobs_agree": worst <= PARITY_LOGPROB_TOL,
+        "logprobs_agree_mean": mean <= PARITY_LOGPROB_MEAN_TOL,
+        "logprobs_agree_max": worst <= PARITY_LOGPROB_MAX_TOL,
     }
     return _result(
-        "parity", all(checks.values()), device, checks=checks,
-        prompts=n_prompts, new_tokens=new_tokens, streams_equal=equal,
-        common_prefix_min=min(prefix_lens),
-        max_abs_logprob_diff=worst, tolerance=PARITY_LOGPROB_TOL,
+        "parity", all(checks.values()), device, checks=checks, op=op,
+        op_tolerance=PARITY_OP_TOL, prompts=n_prompts, new_tokens=new_tokens,
+        streams_equal=sum(n == new_tokens for n in prefix_lens),
+        common_prefix_lens=prefix_lens, compared_logprobs=len(diffs),
+        mean_abs_logprob_diff=mean, max_abs_logprob_diff=worst,
+        mean_tolerance=PARITY_LOGPROB_MEAN_TOL,
+        max_tolerance=PARITY_LOGPROB_MAX_TOL,
+        logprob_gap_at_split=split_gaps,
         compile=watch.report(compile_cache.enabled_dir()),
     )
 
