@@ -127,6 +127,9 @@ class SPMDTrainEngine(TrainEngine):
         self.compute_dtype = _DTYPES[config.dtype]
         self.param_dtype = _DTYPES[config.param_dtype]
         self._jit_cache: Dict[Any, Callable] = {}
+        # splash block edge probed at initialize() when attn_impl="flash"
+        # runs on a TPU; None = the splash kernel is not in use
+        self.splash_block: Optional[int] = None
         self.initialized = False
 
     # ------------------------------------------------------------------
@@ -179,6 +182,10 @@ class SPMDTrainEngine(TrainEngine):
             host_params,
             self._param_shardings,
         )
+        # a from-scratch init sits whole on the default device: release it
+        # before the optimizer state is made, or device 0 carries a second
+        # full copy of the model through the largest allocation of start-up
+        del host_params
         if cfg.optimizer is not None:
             total_steps = ft_spec.total_train_steps if ft_spec else 10000
             self.lr_schedule = _lr_schedule(cfg, total_steps)
@@ -196,7 +203,7 @@ class SPMDTrainEngine(TrainEngine):
             # (round-3 driver capture silently lost 5x on the opt-in)
             from areal_tpu.ops import flash as flash_ops
 
-            self._splash_block = flash_ops.probe_block_size()
+            self.splash_block = flash_ops.probe_block_size()
         n = count_params(self.params)
         logger.info(
             f"initialized {mc.family} model: {n/1e6:.1f}M params on mesh "

@@ -266,6 +266,26 @@ def qwen2_7b_config() -> ModelConfig:
     )
 
 
+def qwen2_0p5b_config() -> ModelConfig:
+    """Qwen2-0.5B at its published width, nothing trimmed (the one-chip
+    model of chip_smoke.py)."""
+    return ModelConfig(
+        vocab_size=151936,
+        hidden_size=896,
+        intermediate_size=4864,
+        num_layers=24,
+        num_heads=14,
+        num_kv_heads=2,
+        head_dim=64,
+        max_position_embeddings=32768,
+        rope_theta=1e6,
+        rms_norm_eps=1e-6,
+        tie_word_embeddings=True,
+        attention_bias=True,
+        family="qwen2",
+    )
+
+
 def qwen2_1p5b_config() -> ModelConfig:
     """Qwen2-1.5B geometry (the async-RL 1.5B recipe)."""
     return ModelConfig(
@@ -306,6 +326,7 @@ def qwen2_32b_config() -> ModelConfig:
 
 
 MODEL_CONFIGS = {
+    "qwen2_0p5b": qwen2_0p5b_config,
     "qwen2_7b": qwen2_7b_config,
     "qwen2_1p5b": qwen2_1p5b_config,
     "qwen2_32b": qwen2_32b_config,

@@ -374,7 +374,7 @@ def main(argv):
         "steps": step.global_step,
         "model_version": engine.get_version(),
         "actor_attn_impl": config.actor.attn_impl,
-        "splash_block": getattr(engine, "_splash_block", None),
+        "splash_block": engine.splash_block,
         "server_attn_impl": (
             rollout.engine.attn_impl if colocated and rollout else None
         ),
