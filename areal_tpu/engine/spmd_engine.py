@@ -192,11 +192,7 @@ class SPMDTrainEngine(TrainEngine):
             self.optimizer = _make_optimizer(
                 cfg.optimizer, self.lr_schedule
             )
-            # jit without out_shardings: XLA's sharding propagation gives the
-            # adam moments their params' shardings (they are elementwise maps
-            # of the params) — the ZeRO "shard optimizer state" property for
-            # free.
-            self.opt_state = jax.jit(self.optimizer.init)(self.params)
+            self.opt_state = self._init_opt_state()
         if cfg.attn_impl == "flash" and jax.default_backend() != "cpu":
             # probe the splash block edge once per process so the fast
             # long-context path is the default, not an env-var opt-in
@@ -227,8 +223,33 @@ class SPMDTrainEngine(TrainEngine):
         finally:
             cfg.optimizer = old_opt
         self.optimizer = _make_optimizer(opt_config, self.lr_schedule)
-        self.opt_state = jax.jit(self.optimizer.init)(self.params)
+        self.opt_state = self._init_opt_state()
         self._jit_cache.clear()
+
+    def _init_opt_state(self):
+        """Fresh optimizer state with every moment laid out like its param
+        (the ZeRO "shard the optimizer state" property), scalars replicated.
+
+        The layout is STATED, not left to sharding propagation: the moments
+        are zeros with no data dependence on the params, so a bare
+        ``jit(optimizer.init)`` has nothing to propagate from and puts them
+        whole on the first device — on a four-chip host, 2x the model on
+        device 0 until the first update re-lays them out (and no first
+        update for a model that needs the sharding to fit)."""
+        pdef = jax.tree_util.tree_structure(self.params)
+
+        def like_params(node) -> bool:
+            return jax.tree_util.tree_structure(node) == pdef
+
+        rep = sharding_lib.replicated(self.mesh)
+        out_shardings = jax.tree_util.tree_map(
+            lambda n: self._param_shardings if like_params(n) else rep,
+            jax.eval_shape(self.optimizer.init, self.params),
+            is_leaf=like_params,
+        )
+        return jax.jit(self.optimizer.init, out_shardings=out_shardings)(
+            self.params
+        )
 
     def destroy(self):
         self.params = None

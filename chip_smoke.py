@@ -648,8 +648,12 @@ def _ppo_batch(vocab, n_seqs, lo, hi, seed):
     for i, s in enumerate(seqs):
         lm[i, len(s) // 4: len(s)] = 1
     batch["loss_mask"] = lm
+    # behaviour logprobs where random weights put them (about -log vocab):
+    # far from the policy's own, the decoupled loss's importance weight
+    # would vanish and the gradient with it
     batch["logprobs"] = (
-        rng.standard_normal(lm.shape).astype(np.float32) * 0.1 - 1.0
+        rng.standard_normal(lm.shape).astype(np.float32) * 0.1
+        - np.log(vocab).astype(np.float32)
     ) * lm
     batch["versions"] = np.where(lm > 0, 0, -1).astype(np.int32)
     # siblings of a group must disagree, or every advantage is zero
@@ -659,8 +663,22 @@ def _ppo_batch(vocab, n_seqs, lo, hi, seed):
     return batch
 
 
+def _state_bytes_per_device(engine):
+    """Parameter + optimizer bytes each device really holds: summed over
+    the shards, not read off the sharding's name."""
+    import jax
+
+    held = {}
+    for leaf in jax.tree_util.tree_leaves((engine.params, engine.opt_state)):
+        for sh in leaf.addressable_shards:
+            held[sh.device.id] = held.get(sh.device.id, 0) + sh.data.nbytes
+    return held
+
+
 def _one_update(args, cfg, fsdp, batch, attn):
-    """Fresh engine → one ppo_update → (stats, bytes per device, peak)."""
+    """Fresh engine → one ppo_update → (stats, bytes per device, peak).
+    The bytes are the larger of before and after the update: a fresh
+    optimizer state and an updated one are laid out by different programs."""
     import gc
 
     import jax
@@ -686,16 +704,16 @@ def _one_update(args, cfg, fsdp, batch, attn):
         ft_spec=FinetuneSpec(1, 16, len(batch["rewards"])),
         model_config=cfg, seed=args.seed,
     )
+    fresh = _state_bytes_per_device(engine)
     actor = PPOActor(acfg, engine)
     with_adv = actor.compute_advantages(dict(batch))
     stats = dict(actor.ppo_update(with_adv)[0])
     lm = with_adv["loss_mask"].astype(bool)
     stats["adv_scale"] = float(abs(with_adv["advantages"][lm]).mean())
-    # what each device really holds: the shards, not the sharding's name
-    held = {}
-    for leaf in jax.tree_util.tree_leaves((engine.params, engine.opt_state)):
-        for sh in leaf.addressable_shards:
-            held[sh.device.id] = held.get(sh.device.id, 0) + sh.data.nbytes
+    held = {
+        d: max(b, fresh.get(d, 0))
+        for d, b in _state_bytes_per_device(engine).items()
+    }
     peak = {
         d.id: (d.memory_stats() or {}).get("peak_bytes_in_use")
         for d in jax.devices()

@@ -101,6 +101,29 @@ def test_sharded_matches_single_device():
     )
 
 
+def test_fresh_optimizer_state_is_sharded_like_params():
+    """Before the first update: on an fsdp=4 mesh no device holds more than
+    its quarter of Adam's moments (plus replicated scalars). Checked on the
+    shards each device really holds, not on the sharding's name — a bare
+    ``jit(optimizer.init)`` left every moment whole on the first device."""
+    eng = _engine(parallel=ParallelismConfig(fsdp_parallel_size=4))
+    held = {}
+    for leaf in jax.tree_util.tree_leaves(eng.opt_state):
+        for sh in leaf.addressable_shards:
+            held[sh.device.id] = held.get(sh.device.id, 0) + sh.data.nbytes
+    moments = 2 * sum(
+        p.nbytes for p in jax.tree_util.tree_leaves(eng.params)
+    )
+    assert len(held) == 4
+    # tiny_config has leaves fsdp cannot split 4 ways; they replicate
+    assert max(held.values()) <= 0.3 * moments, held
+    for p, mu in zip(
+        jax.tree_util.tree_leaves(eng.params),
+        jax.tree_util.tree_leaves(eng.opt_state[1][0].mu),
+    ):
+        assert mu.sharding == p.sharding
+
+
 @pytest.mark.parametrize("impl", ["ring", "ulysses"])
 def test_sharded_attn_impl_matches_single_device(impl):
     """Training with the explicit ring/Ulysses SP kernels must produce the
