@@ -134,7 +134,6 @@ def orchestrate(args):
         if unknown:
             raise SystemExit(f"--phase {unknown}: this mode runs {phases}")
         phases = [p for p in phases if p in args.phase]
-    os.makedirs(args.out, exist_ok=True)
     want_platform = "cpu" if args.rehearse else "tpu"
     deadline = time.monotonic() + (
         PHASE_LIMIT_S["fsdp4"] if args.chips == 4 else TOTAL_LIMIT_S
@@ -144,6 +143,8 @@ def orchestrate(args):
         print(f"chip_smoke: no areal_tpu package beside {__file__}",
               file=sys.stderr)
         ok, phases = False, []
+    else:
+        os.makedirs(args.out, exist_ok=True)
     for phase in phases:
         limit = min(PHASE_LIMIT_S[phase], deadline - time.monotonic())
         t0 = time.monotonic()
