@@ -1,4 +1,4 @@
-"""The yardstick's arithmetic: operations, bytes, peaks, percentiles.
+"""The yardstick's arithmetic: operations, bytes, peaks, live contexts.
 
 Kept with the benchmark so that no later PR can change how a number is
 worked out. The FLOP counts follow ``areal_tpu/utils/flops.py`` (matmul
@@ -8,7 +8,6 @@ file's own keys and do not import the program.
 """
 
 import json
-import math
 import os
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -80,46 +79,7 @@ def load_peaks(device_kind, path=None):
     return table[device_kind]
 
 
-# ---------------------------------------------------------- percentiles --
-def percentile(values, q):
-    """Linear-interpolated percentile (q in [0, 100]) of a non-empty list."""
-    v = sorted(float(x) for x in values)
-    if not v:
-        raise ValueError("percentile of nothing")
-    pos = (len(v) - 1) * q / 100.0
-    lo = int(math.floor(pos))
-    hi = min(lo + 1, len(v) - 1)
-    return v[lo] + (v[hi] - v[lo]) * (pos - lo)
-
-
-def ranked_with_misses(measured, misses):
-    """Values for a tail over ALL requests: every request that failed, was
-    refused or did not finish is ranked above every measured value (it
-    takes the largest value seen, or its own wait so far if that is larger)."""
-    measured = [float(x) for x in measured]
-    top = max(measured + [float(m) for m in misses], default=0.0)
-    return measured + [top] * len(misses)
-
-
-def histogram_quantile(bounds, counts, q):
-    """Quantile (q in [0, 1]) of a bucketed histogram, interpolated inside
-    its bucket. ``counts`` has one more entry than ``bounds`` (+Inf); a
-    quantile that falls into +Inf returns the last bound. None if empty."""
-    total = sum(counts)
-    if total <= 0:
-        return None
-    target = q * total
-    seen = 0.0
-    for i, c in enumerate(counts):
-        if c > 0 and seen + c >= target:
-            if i >= len(bounds):
-                return float(bounds[-1])
-            lo = float(bounds[i - 1]) if i > 0 else 0.0
-            return lo + (float(bounds[i]) - lo) * (target - seen) / c
-        seen += c
-    return float(bounds[-1])
-
-
+# ------------------------------------------------------- live contexts --
 def interp_count(t, t_first, t_end, n_end):
     """Tokens a request had produced by time ``t`` if it produced ``n_end``
     evenly between its first token and ``t_end`` (decode steps are shared by

@@ -58,19 +58,13 @@ def payload(input_ids, max_new_tokens, sampling, rid):
 
 
 def snapshot(engine):
-    """Counters, histogram counts and host-ledger seconds, now."""
-    hists = {
-        name: (h.bounds, list(h.counts))
-        for name, h in engine.latency_histograms().items()
-    }
+    """The engine's counters, now."""
     return {
         "t": time.monotonic(),
         "counters": {
             k: float(v) for k, v in engine.metrics().items()
             if isinstance(v, (int, float))
         },
-        "hists": hists,
-        "ledger": dict(engine.ledger.seconds()),
     }
 
 
@@ -82,23 +76,11 @@ def program_names(engine):
 
 
 def delta(after, before):
-    hists = {}
-    for name, (bounds, counts) in after["hists"].items():
-        prev = before["hists"].get(name, (bounds, [0] * len(counts)))[1]
-        hists[name] = {
-            "bounds": list(bounds),
-            "counts": [a - b for a, b in zip(counts, prev)],
-        }
     return {
         "window_s": after["t"] - before["t"],
         "counters": harness.counter_delta(
             after["counters"], before["counters"]
         ),
-        "histograms": hists,
-        "ledger": {
-            k: v - before["ledger"].get(k, 0.0)
-            for k, v in after["ledger"].items()
-        },
     }
 
 
@@ -318,7 +300,7 @@ def reference_compare(ctx, params, checked):
         want = reference.token_logprobs(ctx.reference_config, params, seq)
         ref.append(want[len(p) - 1:])
         system.append(np.asarray(o["output_logprobs"], np.float64))
-    return harness.compare_logprobs(system, ref, ctx.rehearse)
+    return harness.compare_logprobs(system, ref, ctx.logprob_limits)
 
 
 def request_ok(res, max_new_tokens):

@@ -209,7 +209,7 @@ def _measure(ctx, engine, stream, sampling):
     genlib.check_alive(engine)
     time.sleep(p.get("settle_s", 1.0))
 
-    compiles0, compiled0 = ctx.watch.compiles, ctx.watch.compiled
+    compile_mark = ctx.watch.mark()
     programs0 = genlib.program_names(engine)
     ctx.mark_window_start()
     before = genlib.snapshot(engine)
@@ -224,8 +224,7 @@ def _measure(ctx, engine, stream, sampling):
     time.sleep(max(0.0, t_end - time.monotonic()))
     genlib.check_alive(engine)
     after = genlib.snapshot(engine)
-    compiles_in_window = ctx.watch.compiled - compiled0
-    loads_in_window = ctx.watch.compiles - compiles0 - compiles_in_window
+    compiles_in_window, loads_in_window = ctx.watch.since(compile_mark)
     new_programs = sorted(
         map(str, genlib.program_names(engine) - programs0)
     )[:12]
@@ -261,8 +260,7 @@ def _measure(ctx, engine, stream, sampling):
     ]
     obs = harness.Observation(
         window_s=d["window_s"], setup_s=ctx.setup_s,
-        counters=d["counters"], histograms=d["histograms"],
-        ledger=d["ledger"], requests=finished,
+        counters=d["counters"],
         values={
             "decode_chunk": float(engine.config.decode_chunk),
         },

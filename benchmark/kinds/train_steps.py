@@ -5,14 +5,17 @@ section; the cell sets sizes only). One step is what
 ``compute_advantages`` then ``ppo_update`` (which recomputes the proximal
 logprobs first).
 
-A few seeded batches are replayed round-robin. Their sequence lengths are the
-length law's pool in a FIXED arrangement (``layout_seed``), so every seed
-packs to the same microbatch shapes and trains the same number of tokens in
-the same order; ``--seed`` draws the weights, the token ids, the rewards and
-the behaviour logprobs. (Letting the seed pick the first batch made seeds
-differ by 1.7% where two runs of one seed differ by 0.04%: PERF.md, PR 24.) Warm-up is one pass
-over all the batches; the window runs whole steps and ends at the end of the
-first step that passes ``--seconds``.
+A few seeded batches are replayed. Their sequence lengths are the length
+law's pool in a FIXED arrangement (``layout_seed``), so every seed packs to
+the same microbatch shapes and finds every program in the cache; ``--seed``
+draws the weights, the token ids, the rewards, the behaviour logprobs and the
+order of the batches in each pass. The batches differ in tokens and padding,
+so the window runs WHOLE PASSES over them and ends at the end of the first
+pass that passes ``--seconds``: every seed and every run then trains the same
+multiset of batches, in another order, and the rate divides by the actual
+elapsed time. (With whole steps only, six steps over four batches made seeds
+differ by 1.7% where two runs of one seed differed by 0.04%: PERF.md, PR 24.)
+Warm-up is one pass in the file's order.
 """
 
 import math
@@ -73,6 +76,12 @@ def make_batches(p, vocab, seed):
     return batches
 
 
+def pass_order(n_batches, seed, k):
+    """The batches of pass ``k`` of the window, in the seed's order."""
+    return [int(b) for b in
+            np.random.default_rng([int(seed), int(k)]).permutation(n_batches)]
+
+
 def actor_config(p, rehearse):
     """The trainer settings of the program's shipped recipe."""
     from areal_tpu.api import cli_args
@@ -116,21 +125,22 @@ def run(ctx):
     )
     vocab = ctx.model_config.vocab_size
     batches = make_batches(p, vocab, ctx.seed)
-    first = 0  # every seed runs the same batches in the same order
-    padded = {"tokens": 0}
-    pack = getattr(engine, "_pack_for_device", None)
-    if pack is not None:
-        # count what is dispatched, padding included, at the one place every
-        # microbatch passes (forward and train alike)
-        def counting_pack(mb, pad_to=None):
-            packed, arrays = pack(mb, pad_to=pad_to)
-            padded["tokens"] += int(np.asarray(packed.tokens).size)
-            return packed, arrays
+    # a span of the benchmark's around the one place every microbatch is
+    # packed (logprob recompute and train alike): what is dispatched, real
+    # tokens and padding, counted from the packed rows themselves
+    dispatched = {"padded": 0, "real": 0}
+    pack = engine._pack_for_device
 
-        engine._pack_for_device = counting_pack
+    def counting_pack(mb, pad_to=None):
+        packed, arrays = pack(mb, pad_to=pad_to)
+        seg = np.asarray(packed.segment_ids)  # 0 = padding
+        dispatched["padded"] += int(seg.size)
+        dispatched["real"] += int(np.count_nonzero(seg))
+        return packed, arrays
 
-    def step(i):
-        b = (first + i) % len(batches)
+    engine._pack_for_device = counting_pack
+
+    def step(b):
         batch = {k: v for k, v in batches[b].items() if not k.startswith("_")}
         # the minibatch split inside ppo_update draws from numpy's global
         # generator: the same split for batch b in every pass and every seed
@@ -138,53 +148,65 @@ def run(ctx):
         with harness.span("train.compute_advantages"):
             batch = actor.compute_advantages(batch)
         with harness.span("train.ppo_update"):
-            stats = actor.ppo_update(batch)
-        return b, stats
+            return actor.ppo_update(batch)
 
-    for i in range(len(batches)):  # warm-up: every packed shape compiles
-        step(i)
-    padded["tokens"] = 0
+    for b in range(len(batches)):  # warm-up: every packed shape compiles
+        step(b)
+    dispatched.update(padded=0, real=0)
 
-    compiles0, compiled0 = ctx.watch.compiles, ctx.watch.compiled
+    compile_mark = ctx.watch.mark()
     ctx.mark_window_start()
     traced = harness.TracedWindow(ctx.trace, ctx.trace_dir)
-    trace_steps = p.get("trace_steps", 1)
     t0 = time.monotonic()
-    n_steps, tokens, flops, all_stats = 0, 0, 0.0, []
+    n_steps, tokens, flops, all_stats, step_s = 0, 0, 0.0, [], []
 
-    def measured_step():
+    def window_steps():
+        k = 0
+        while k == 0 or time.monotonic() - t0 < ctx.seconds:
+            yield from pass_order(len(batches), ctx.seed, k)
+            k += 1
+
+    def measured_step(b):
         nonlocal n_steps, tokens, flops
-        b, stats = step(len(batches) + n_steps)
-        all_stats.extend(stats)
+        t = time.monotonic()
+        all_stats.extend(step(b))
+        step_s.append(round(time.monotonic() - t, 3))
         n_steps += 1
         tokens += int(batches[b]["_seq_lens"].sum())
         flops += arith.train_step_flops(
             ctx.reference_config, batches[b]["_seq_lens"]
         )
 
+    steps = window_steps()
     with traced:  # a traced run profiles its first steps only
-        while n_steps < trace_steps or (
-            not ctx.trace and time.monotonic() - t0 < ctx.seconds
-        ):
-            measured_step()
-    traced_s = time.monotonic() - t0
-    while time.monotonic() - t0 < ctx.seconds:
-        measured_step()
-    window_s = time.monotonic() - t0
-    compiles_in_window = ctx.watch.compiled - compiled0
-    loads_in_window = ctx.watch.compiles - compiles0 - compiles_in_window
+        for _ in range(p["trace_steps"] if ctx.trace else 0):
+            measured_step(next(steps))
+    for b in steps:
+        measured_step(b)
+    # a traced run's window leaves out the profiler's own start and stop
+    # (about 11 s for two steps), in which no step runs
+    window_s = time.monotonic() - t0 - traced.profiler_s
+    compiles_in_window, loads_in_window = ctx.watch.since(compile_mark)
+    if not dispatched["padded"]:
+        raise RuntimeError(
+            "no microbatch went through SPMDTrainEngine._pack_for_device: "
+            "pad_share.train has lost its hook"
+        )
+    padded_tokens, real_tokens = dispatched["padded"], dispatched["real"]
     bad = [
         s for s in all_stats
         if not (math.isfinite(s["loss"]) and math.isfinite(s["grad_norm"])
                 and s["update_successful"] == 1.0)
     ]
 
-    # (a) compute_logp against the plain reference, outside the window
+    # (a) compute_logp against the plain reference, outside the window. The
+    # lengths are the traffic file's, the same for every seed (the reference
+    # compiles per length: a seed that drew new ones spent minutes on it
+    # after its window); the seed draws the tokens
     rng = np.random.default_rng(ctx.seed + 11)
-    total = 24 if ctx.rehearse else 128
     seqs = [
         rng.integers(0, vocab, size=int(n)).astype(np.int32)
-        for n in rng.integers(total // 2, total + 1, size=4)
+        for n in p["check_lens"]
     ]
     from areal_tpu.utils import data as data_utils
 
@@ -199,7 +221,7 @@ def run(ctx):
         ref.append(reference.token_logprobs(
             ctx.reference_config, params, s.tolist(), acfg.temperature
         ))
-    cmp = harness.compare_logprobs(system, ref, ctx.rehearse)
+    cmp = harness.compare_logprobs(system, ref, ctx.logprob_limits)
     mem_peak = harness.memory_peak_bytes()
     engine.destroy()
     del actor, engine, params
@@ -209,13 +231,10 @@ def run(ctx):
         window_s=window_s, setup_s=ctx.setup_s,
         counters={"trained_tokens": float(tokens), "train_flops": flops,
                   "steps": float(n_steps),
-                  "padded_tokens": float(padded["tokens"]),
-                  "dispatched_real_tokens": 2.0 * tokens},
-        values={"traced_s": traced_s},
+                  "padded_tokens": float(padded_tokens),
+                  "dispatched_real_tokens": float(real_tokens)},
         trace=traced.reduce(), peaks=ctx.peaks,
     )
-    if pack is None:
-        del obs.counters["padded_tokens"]
     return harness.Outcome(
         obs=obs, attempted=n_steps, failed=len(bad) and n_steps,
         checks={
@@ -223,9 +242,14 @@ def run(ctx):
             "no_compile_in_window": compiles_in_window == 0,
             "reference_agrees": cmp["ok"],
         },
-        notes={"steps": n_steps, "minibatch_updates": len(all_stats),
+        notes={"steps": n_steps, "passes": n_steps // len(batches),
+               "step_s": step_s,
+               "minibatch_updates": len(all_stats),
                "compiles_in_window": compiles_in_window,
                "cache_loads_in_window": loads_in_window, "reference": cmp,
+               "trained_tokens": tokens, "dispatched_real_tokens": real_tokens,
+               "dispatched_padded_tokens": padded_tokens,
+               "profiler_s": traced.profiler_s,
                "memory_peak_before_reference": mem_peak,
                "grad_norm_last": all_stats[-1]["grad_norm"] if all_stats else None,
                "live_arrays_after_destroy": len(jax.live_arrays())},

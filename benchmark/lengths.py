@@ -54,36 +54,5 @@ def length_biased(values, n):
     return v[np.minimum(np.searchsorted(cum, qs), len(v) - 1)]
 
 
-def poisson_due_times(rate, seconds, rng):
-    """Arrival instants in [0, seconds): ``round(rate * seconds)`` of them, so
-    every seed offers the same number of requests; the gaps are the seed's
-    exponential draws, rescaled to fill the window."""
-    n = max(1, int(round(rate * seconds)))
-    gaps = rng.exponential(1.0, size=n + 1)
-    t = np.cumsum(gaps)[:-1]
-    return t * (seconds / np.sum(gaps))
-
-
-def gamma_due_times(rate, seconds, cv, rng):
-    """As ``poisson_due_times`` with gamma gaps of coefficient of variation
-    ``cv`` (cv = 1 is Poisson; cv = 3 is bursty)."""
-    n = max(1, int(round(rate * seconds)))
-    shape = 1.0 / (cv * cv)
-    gaps = rng.gamma(shape, 1.0 / shape, size=n + 1)
-    t = np.cumsum(gaps)[:-1]
-    return t * (seconds / np.sum(gaps))
-
-
-def due_times(arrivals, seconds, rng):
-    kind = arrivals.get("process", "poisson")
-    if kind == "poisson":
-        return poisson_due_times(arrivals["rate"], seconds, rng)
-    if kind == "gamma":
-        return gamma_due_times(
-            arrivals["rate"], seconds, arrivals["cv"], rng
-        )
-    raise ValueError(f"unknown arrival process {kind!r}")
-
-
 def tokens(rng, vocab, n):
     return rng.integers(0, vocab, size=int(n)).tolist()

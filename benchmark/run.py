@@ -36,9 +36,6 @@ def parse(argv):
     p.add_argument("--seed", type=int, default=0)
     p.add_argument("--seconds", type=float, default=None)
     p.add_argument("--trace", type=int, choices=(0, 1), default=0)
-    p.add_argument("--bench", default=None,
-                   help="another file of BENCHMARK.json's form (the tests' "
-                   "list of cells that are built but not yet proven)")
     p.add_argument("--rehearse", action="store_true",
                    help="tiny configuration on the CPU; never a result")
     return p.parse_args(argv)
@@ -54,13 +51,14 @@ def _merge(base, over):
     return out
 
 
-def prepare(argv=None, t_start=None):
+def prepare(argv=None, t_start=None, bench=None):
     """Everything before the traffic kind runs: the cell's files, the device
     check, the compile cache, the context. (None, None, None) where the
-    device check refuses."""
+    device check refuses. ``bench`` is ``BENCHMARK.json`` unless a test hands
+    in a dict of the same form."""
     t_start = _T_START if t_start is None else t_start
     args = parse(argv)
-    bench = harness.load_bench(args.bench)
+    bench = bench or harness.load_bench()
     cell = harness.load_cell(args.workload, bench)
     traffic = cell.traffic
     if args.rehearse:
@@ -96,6 +94,7 @@ def prepare(argv=None, t_start=None):
         trace=bool(args.trace), rehearse=args.rehearse, watch=watch,
         model_config=harness.model_config(cell, args.rehearse),
         reference_config=harness.reference_config(cell, args.rehearse),
+        logprob_limits=harness.logprob_limits(cell, args.rehearse),
         peaks=None if args.rehearse else arith.load_peaks(device["kind"]),
         trace_dir=os.path.join(harness.ROOT, "benchmark_out", "trace",
                                cell.name),
@@ -111,8 +110,8 @@ def prepare(argv=None, t_start=None):
     return ctx, harness.load_kind(traffic["kind"]), bench
 
 
-def main(argv=None):
-    ctx, kind, bench = prepare(argv)
+def main(argv=None, bench=None):
+    ctx, kind, bench = prepare(argv, bench=bench)
     if ctx is None:
         return 3
     args, cell, device = ctx.args, ctx.cell, ctx.device

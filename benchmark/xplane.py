@@ -22,6 +22,7 @@ DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 SPAN_PREFIX = "bench:"
+WINDOW_SPAN = SPAN_PREFIX + "window"  # harness.TracedWindow's own span
 
 
 def find_xplane(trace_dir):
@@ -110,11 +111,21 @@ def _clip(s, e, lo, hi):
     return max(s, lo), min(e, hi)
 
 
+def span_window(events, name=WINDOW_SPAN):
+    """(start_ns, end_ns) of the host span ``name``: the traced window as the
+    benchmark drew it, idle at its edges included. None without that span."""
+    found = [(e[3], e[3] + e[4]) for e in events if e[2] == name]
+    if not found:
+        return None
+    return min(s for s, _ in found), max(e for _, e in found)
+
+
 def reduce(events, window=None, top=10):
     """Busy and idle seconds, per-op seconds and the longest idle gaps.
 
-    ``window`` = (start_ns, end_ns) restricts everything to that interval; by
-    default it runs from the first to the last device operation. Busy time
+    ``window`` = (start_ns, end_ns) restricts everything to that interval
+    (a run passes ``span_window``); without one it runs from the first to
+    the last device operation, which leaves out idle at the edges. Busy time
     is the union of the ``XLA Ops`` intervals, per device, averaged over the
     devices that ran anything. A gap is an interval with no operation on a
     device; it is attributed to the ``bench:`` host span that covers most of

@@ -125,3 +125,32 @@ def test_peaks_table_refuses_an_unknown_device():
     assert v5e["flops_per_s"] == 197e12 and v5e["bytes_per_s"] == 819e9
     with pytest.raises(KeyError):
         arith.load_peaks("TPU v9 imaginary")
+
+
+def _stems(sub, ext):
+    d = os.path.join(ROOT, "benchmark", sub)
+    return sorted(f[:-len(ext)] for f in os.listdir(d)
+                  if f.endswith(ext) and not f.startswith("__"))
+
+
+# nothing under benchmark/ that no cell reaches: a file for a cell that is
+# not there yet comes with the PR that proves that cell
+@pytest.mark.parametrize("name", _stems("metrics", ".json"))
+def test_every_metric_file_belongs_to_a_listed_metric(name):
+    assert name in {m["name"] for m in METRICS}
+
+
+@pytest.mark.parametrize("name", _stems("readers", ".py"))
+def test_every_reader_is_named_by_a_metric_file(name):
+    used = {
+        _load(os.path.join(ROOT, "benchmark", "metrics", m + ".json"))["reader"]
+        for m in _stems("metrics", ".json")
+    }
+    assert name in used
+
+
+@pytest.mark.parametrize("name", _stems("traffic", ".json"))
+def test_every_traffic_file_belongs_to_a_cell_and_names_a_kind(name):
+    assert name in {w["traffic"] for _, w in CELLS}
+    kind = _load(os.path.join(ROOT, "benchmark", "traffic", name + ".json"))["kind"]
+    assert kind in _stems("kinds", ".py")

@@ -5,7 +5,7 @@ import numpy as np
 import pytest
 
 from benchmark import lengths
-from benchmark.kinds import closed_rollout, open_serve, train_steps
+from benchmark.kinds import closed_rollout, train_steps
 
 REASON = {"law": "lognormal", "median": 600, "sigma": 0.9, "lo": 32, "hi": 3584}
 CHAT_IN = {"law": "lognormal", "median": 256, "sigma": 1.0, "lo": 16, "hi": 2048}
@@ -39,16 +39,6 @@ def test_length_biased_mix_matches_the_law():
     assert biased.mean() == pytest.approx(want, rel=0.03)
     assert biased.mean() > 1.5 * pool.mean()
     assert set(biased) <= set(pool)
-
-
-def test_due_times_offer_the_same_count_for_every_seed():
-    for seed in (0, 7, 2 ** 31 + 11):
-        t = lengths.poisson_due_times(8.0, 40, np.random.default_rng(seed))
-        assert len(t) == 320 and t[0] >= 0 and t[-1] < 40
-        assert np.all(np.diff(t) >= 0)
-    g = lengths.gamma_due_times(8.0, 40, 3.0, np.random.default_rng(3))
-    p = lengths.poisson_due_times(8.0, 40, np.random.default_rng(3))
-    assert len(g) == 320 and np.diff(g).std() > 1.5 * np.diff(p).std()
 
 
 def _rollout_params():
@@ -96,21 +86,6 @@ def test_stationary_start_is_in_progress_and_inside_the_context():
     assert [n for _, n in again[0]] == [n for _, n in long]
 
 
-def test_open_schedule_same_lengths_every_seed():
-    p = {"arrivals": {"process": "poisson", "rate": 8.0},
-         "prompt_len": CHAT_IN,
-         "output_len": {"law": "lognormal", "median": 128, "sigma": 0.8,
-                        "lo": 8, "hi": 512}}
-    a = open_serve.schedule(p, 1000, 1, 40)
-    b = open_serve.schedule(p, 1000, 2, 40)
-    assert len(a) == len(b) == 320
-    assert sorted(len(x[1]) for x in a) == sorted(len(x[1]) for x in b)
-    assert sorted(x[2] for x in a) == sorted(x[2] for x in b)
-    assert [x[0] for x in a] != [x[0] for x in b]
-    assert open_serve.schedule(p, 1000, 1, 40) == a
-    assert len(open_serve.schedule(p, 1000, 1, 40, rate=10.0)) == 400
-
-
 def test_train_batches_same_shapes_every_seed_other_tokens():
     p = {"batch_seqs": 8, "group_size": 4, "n_batches": 2, "layout_seed": 5,
          "prompt_len": {"law": "uniform", "lo": 4, "hi": 12},
@@ -127,3 +102,13 @@ def test_train_batches_same_shapes_every_seed_other_tokens():
         assert np.array_equal(x["input_ids"][0, :first_completion],
                               x["input_ids"][1, :first_completion])
         assert set(x["rewards"][:4]) == {0.0, 1.0}
+
+
+@pytest.mark.parametrize("seed", [0, 401, 2 ** 31 + 354])
+def test_every_pass_holds_every_batch_once_in_the_seeds_order(seed):
+    passes = [train_steps.pass_order(4, seed, k) for k in range(6)]
+    assert all(sorted(p) == [0, 1, 2, 3] for p in passes)
+    assert passes == [train_steps.pass_order(4, seed, k) for k in range(6)]
+    assert len({tuple(p) for p in passes}) > 1  # a pass is drawn anew
+    other = [train_steps.pass_order(4, seed + 1, k) for k in range(6)]
+    assert other != passes

@@ -14,21 +14,23 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 
 
 def _bench_of(cell):
-    """BENCHMARK.json, or the tests' list of cells not yet proven."""
+    """None for a cell of BENCHMARK.json, else the tests' list of cells that
+    are built but not yet proven, as a dict of the same form."""
     with open(os.path.join(os.path.dirname(os.path.dirname(HERE)),
                            "BENCHMARK.json")) as f:
-        proven = {w["name"] for w in json.load(f)["workloads"]}
-    return [] if cell in proven else [
-        "--bench", os.path.join(HERE, "unproven_cells.json")
-    ]
+        if cell in {w["name"] for w in json.load(f)["workloads"]}:
+            return None
+    with open(os.path.join(HERE, "unproven_cells.json")) as f:
+        return json.load(f)
+
 
 KEYS = {"correct", "attempted", "failed", "metrics", "device"}
 
 
 def _rehearse(capsys, cell, trace):
     rc = run.main(["--workload", cell, "--seed", str(2 ** 31 + 17),
-                   "--seconds", "3", "--trace", str(trace), "--rehearse"]
-                  + _bench_of(cell))
+                   "--seconds", "3", "--trace", str(trace), "--rehearse"],
+                  bench=_bench_of(cell))
     lines = [l for l in capsys.readouterr().out.splitlines() if l.startswith("{")]
     assert rc == 0 and len(lines) >= 2
     return json.loads(lines[-2]), json.loads(lines[-1])
@@ -37,7 +39,6 @@ def _rehearse(capsys, cell, trace):
 @pytest.mark.parametrize("cell,metric", [
     ("rollout_1p5b_reason", "gen_tokens_per_s"),
     ("train_0p5b_grpo", "trained_tokens_per_s"),
-    ("serve_0p5b_chat", "ttft_p95_ms"),
 ])
 def test_rehearsal_ends_in_the_contracts_line(capsys, cell, metric):
     notes, line = _rehearse(capsys, cell, trace=0)
@@ -66,13 +67,33 @@ def test_traced_rehearsal_reports_per_layer_metrics(capsys):
         "pad_share.train"
     }  # no peak table off the chip, so no MFU; padding is a count
     assert 0 <= line["metrics"]["pad_share.train"]["value"] < 100
+    # whole passes: both batches, each once, however short the window
+    assert notes["notes"]["steps"] == 2 * notes["notes"]["passes"] >= 2
     assert "busy_s" in line["device"] or "breakdown" not in line
 
 
 def test_refuses_to_measure_off_the_chip(capsys):
     rc = run.main(["--workload", "train_0p5b_grpo", "--seed", "1",
-                   "--seconds", "1", "--trace", "0"]
-                  + _bench_of("train_0p5b_grpo"))
+                   "--seconds", "1", "--trace", "0"])
     out = capsys.readouterr()
     assert rc != 0 and not any(l.startswith("{") for l in out.out.splitlines())
     assert "not a TPU" in out.err
+
+
+def test_traced_window_is_its_own_span_in_the_profilers_trace(tmp_path):
+    import time
+
+    from benchmark import harness, xplane
+
+    traced = harness.TracedWindow(True, str(tmp_path))
+    with traced:
+        time.sleep(0.02)  # idle before the first call still counts
+        with harness.span("wait"):
+            time.sleep(0.05)
+    events = xplane.load(xplane.find_xplane(str(tmp_path)))
+    lo, hi = xplane.span_window(events)
+    assert 0.07 <= (hi - lo) / 1e9 <= traced.host_s + 0.01
+    wait = [e for e in events if e[2] == "bench:wait"]
+    assert len(wait) == 1 and lo <= wait[0][3] and wait[0][3] + wait[0][4] <= hi
+    # no device operation on the CPU: nothing to reduce, and it says so
+    assert traced.reduce()["busy_s"] == 0.0

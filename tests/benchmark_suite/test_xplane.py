@@ -54,6 +54,23 @@ def test_window_clips_and_unattributed_gaps():
     }
 
 
+def test_span_window_is_the_benchmarks_own_span_idle_edges_included():
+    ev = _events()
+    assert xplane.span_window(ev) == (0, 100 * MS)
+    r = xplane.reduce(ev, xplane.span_window(ev))
+    # the 40 ms after the last operation count as idle: 60% idle, not 33%
+    assert r["window_s"] == pytest.approx(0.100)
+    assert r["busy_s"] == pytest.approx(0.040)
+    assert dict(r["gaps"]) == {"wait": pytest.approx(0.020),
+                               "window": pytest.approx(0.040)}
+    late = [(HOST, "main", "bench:window", 30 * MS, 50 * MS)] + ev[:5]
+    r = xplane.reduce(late, xplane.span_window(late))
+    # idle before the first operation inside the window counts too
+    assert r["window_s"] == pytest.approx(0.050)
+    assert r["busy_s"] == pytest.approx(0.020)  # 40-60 of 30-80
+    assert xplane.span_window(ev[:5]) is None  # no span: the caller decides
+
+
 def test_two_devices_are_averaged_and_nothing_is_nothing():
     ev = _events() + [(D1, OPS, "fusion.1", 0, 60 * MS)]
     r = xplane.reduce(ev)
