@@ -26,7 +26,7 @@ from areal_tpu.api.cli_args import PPOActorConfig
 from areal_tpu.api.engine_api import TrainEngine
 from areal_tpu.engine.spmd_engine import target_aligned_logprobs
 from areal_tpu.ops import functional as F
-from areal_tpu.utils import stats_tracker
+from areal_tpu.utils import stats_tracker, tracing
 from areal_tpu.utils.data import Batch, batch_select, batch_size
 
 
@@ -71,6 +71,17 @@ class PPOActor:
         """Reward shaping + GAE + advantage normalization (reference
         ppo/actor.py:67-159). Returns `data` with added keys: advantages,
         kl_rewards, tot_rewards (all [B, L] target-aligned)."""
+        with tracing.span(
+            "train.compute_advantages", step=self._engine_step()
+        ):
+            return self._compute_advantages(data)
+
+    def _engine_step(self) -> int:
+        # the engine's optimizer-step count stamps the trainer's spans;
+        # an engine without one (a remote stub) reads 0
+        return int(getattr(self.engine, "step_count", 0))
+
+    def _compute_advantages(self, data: Batch) -> Batch:
         cfg = self.config
         mask = np.asarray(data["attention_mask"]).astype(bool)
         loss_mask = np.asarray(data["loss_mask"]).astype(np.float32)
@@ -177,6 +188,10 @@ class PPOActor:
     # ------------------------------------------------------------------
     def ppo_update(self, data: Batch) -> List[Dict[str, float]]:
         """Minibatched decoupled-PPO update (reference ppo/actor.py:161)."""
+        with tracing.span("train.ppo_update", step=self._engine_step()):
+            return self._ppo_update(data)
+
+    def _ppo_update(self, data: Batch) -> List[Dict[str, float]]:
         cfg = self.config
         if cfg.recompute_logprob and "prox_logp" not in data:
             # proximal policy = current weights before this update

@@ -45,7 +45,7 @@ from areal_tpu.parallel import distributed as distributed_lib
 from areal_tpu.parallel import mesh as mesh_lib
 from areal_tpu.parallel import sharding as sharding_lib
 from areal_tpu.utils import data as data_utils
-from areal_tpu.utils import goodput
+from areal_tpu.utils import goodput, stats_tracker, tracing
 from areal_tpu.utils import logging as logging_util
 from areal_tpu.utils.data import Batch
 
@@ -123,6 +123,9 @@ class SPMDTrainEngine(TrainEngine):
         self.optimizer = None
         self.lr_schedule = None
         self.step_count = 0
+        # cumulative tokens dispatched (counters()), counted where every
+        # microbatch is packed, from its segment ids
+        self._counters = {"real_tokens": 0, "padded_tokens": 0}
         self._version = 0
         self.compute_dtype = _DTYPES[config.dtype]
         self.param_dtype = _DTYPES[config.param_dtype]
@@ -268,6 +271,14 @@ class SPMDTrainEngine(TrainEngine):
     def data_parallel_world_size(self) -> int:
         return jax.process_count()
 
+    def counters(self) -> Dict[str, int]:
+        """Cumulative counts since construction (as
+        ``GenerationEngine.metrics()`` is for the engine): tokens
+        dispatched, real and padded, over recompute and train
+        microbatches. Their per-step increments are the stats plane's
+        ``spmd/real_tokens`` and ``spmd/padded_tokens``."""
+        return dict(self._counters)
+
     def get_version(self) -> int:
         return self._version
 
@@ -312,54 +323,70 @@ class SPMDTrainEngine(TrainEngine):
     def _pack_for_device(
         self, mb: Batch, pad_to: Optional[int] = None
     ) -> Tuple[data_utils.PackedRows, Dict[str, jnp.ndarray]]:
-        rows = self._dp_rows()
-        seq_mult = self.config.parallel.seq_parallel_size
-        # bucket quantum must divide evenly across the seq axis
-        try:
-            packed = data_utils.pack_batch_rows(
-                mb, n_rows=rows, quantum=256 * seq_mult, pad_to=pad_to
-            )
-        except ValueError:
-            # a row outgrew the static pad (one very long sequence);
-            # fall back to the dynamic bucket for this microbatch
-            packed = data_utils.pack_batch_rows(
-                mb, n_rows=rows, quantum=256 * seq_mult
-            )
-        arrays: Dict[str, Any] = dict(
-            tokens=packed.tokens,
-            segment_ids=packed.segment_ids,
-            positions=packed.positions,
-        )
-        for k, v in packed.per_token.items():
-            arrays[f"t_{k}"] = v
-        for k, v in packed.per_seq.items():
-            arrays[f"s_{k}"] = v
-        bsh = self._batch_sharding()
-        rep = sharding_lib.replicated(self.mesh)
-        shardings = {}
-        for k, v in arrays.items():
-            shardings[k] = bsh if (
-                v.ndim >= 2 and v.shape[:2] == packed.tokens.shape
-            ) else (
-                NamedSharding(self.mesh, P(("data", "fsdp")))
-                if v.ndim >= 1 and v.shape[0] == packed.tokens.shape[0]
-                else rep
-            )
-        if jax.process_count() == 1:
-            # ONE tree-wide transfer instead of a host round-trip per key
-            dev = jax.device_put(
-                {k: np.asarray(v) for k, v in arrays.items()}, shardings
-            )
-        else:
-            # multi-host: every process holds the identical full batch (the
-            # DP-head broadcast guarantees it) and contributes only its
-            # addressable shards to the global array
-            dev = {
-                k: distributed_lib.make_global_array(
-                    np.asarray(v), shardings[k]
+        """One microbatch packed into rows and put on the device: the one
+        place every dispatched token passes (logprob recompute and train
+        alike), so the one place they are counted, real and padded."""
+        with tracing.span(
+            "train.pack", step=self.step_count, pad_to=pad_to or 0
+        ) as sp:
+            rows = self._dp_rows()
+            seq_mult = self.config.parallel.seq_parallel_size
+            # bucket quantum must divide evenly across the seq axis
+            try:
+                packed = data_utils.pack_batch_rows(
+                    mb, n_rows=rows, quantum=256 * seq_mult, pad_to=pad_to
                 )
-                for k, v in arrays.items()
-            }
+            except ValueError:
+                # a row outgrew the static pad (one very long sequence);
+                # fall back to the dynamic bucket for this microbatch
+                packed = data_utils.pack_batch_rows(
+                    mb, n_rows=rows, quantum=256 * seq_mult
+                )
+            arrays: Dict[str, Any] = dict(
+                tokens=packed.tokens,
+                segment_ids=packed.segment_ids,
+                positions=packed.positions,
+            )
+            for k, v in packed.per_token.items():
+                arrays[f"t_{k}"] = v
+            for k, v in packed.per_seq.items():
+                arrays[f"s_{k}"] = v
+            bsh = self._batch_sharding()
+            rep = sharding_lib.replicated(self.mesh)
+            shardings = {}
+            for k, v in arrays.items():
+                shardings[k] = bsh if (
+                    v.ndim >= 2 and v.shape[:2] == packed.tokens.shape
+                ) else (
+                    NamedSharding(self.mesh, P(("data", "fsdp")))
+                    if v.ndim >= 1 and v.shape[0] == packed.tokens.shape[0]
+                    else rep
+                )
+            if jax.process_count() == 1:
+                # ONE tree-wide transfer instead of a host round-trip per key
+                dev = jax.device_put(
+                    {k: np.asarray(v) for k, v in arrays.items()}, shardings
+                )
+            else:
+                # multi-host: every process holds the identical full batch (the
+                # DP-head broadcast guarantees it) and contributes only its
+                # addressable shards to the global array
+                dev = {
+                    k: distributed_lib.make_global_array(
+                        np.asarray(v), shardings[k]
+                    )
+                    for k, v in arrays.items()
+                }
+            real = int(np.count_nonzero(packed.segment_ids))  # 0 = padding
+            padded = int(packed.segment_ids.size)
+            self._counters["real_tokens"] += real
+            self._counters["padded_tokens"] += padded
+            sp.set(real_tokens=real, padded_tokens=padded)
+            # per step through the stats plane (increments: the step's
+            # export sums them over its recompute and train microbatches)
+            stats_tracker.counter(**{
+                "spmd/real_tokens": real, "spmd/padded_tokens": padded,
+            })
         return packed, dev
 
     # ------------------------------------------------------------------
@@ -453,12 +480,13 @@ class SPMDTrainEngine(TrainEngine):
                     return_router_loss=True, return_hidden=lazy_head,
                     act_sharding=act_sh,
                 )
-                loss, stats = loss_fn(logits, arrays)
-                if mc.is_moe and mc.router_aux_loss_coef:
-                    loss = loss + mc.router_aux_loss_coef * router_aux
-                    stats = dict(stats, router_aux_loss=router_aux)
-                w = loss_weight_fn(arrays).astype(jnp.float32)
-                return loss * w, (loss, stats, w)
+                with tracing.device_scope("loss"):
+                    loss, stats = loss_fn(logits, arrays)
+                    if mc.is_moe and mc.router_aux_loss_coef:
+                        loss = loss + mc.router_aux_loss_coef * router_aux
+                        stats = dict(stats, router_aux_loss=router_aux)
+                    w = loss_weight_fn(arrays).astype(jnp.float32)
+                    return loss * w, (loss, stats, w)
 
             def grad_step(params, grad_accum, arrays):
                 grads, (loss, stats, w) = jax.grad(fwd_loss, has_aux=True)(
@@ -477,28 +505,29 @@ class SPMDTrainEngine(TrainEngine):
         if key not in self._jit_cache:
 
             def apply_step(params, opt_state, grad_accum, total_weight):
-                grads = jax.tree_util.tree_map(
-                    lambda g: g / total_weight, grad_accum
-                )
-                grad_norm = optax.global_norm(grads)
-                updates, new_opt = self.optimizer.update(
-                    grads, opt_state, params
-                )
-                new_params = optax.apply_updates(params, updates)
-                # keep the declared param dtype: f32 updates would silently
-                # promote bf16 params (breaking donation every step)
-                new_params = jax.tree_util.tree_map(
-                    lambda n, o: n.astype(o.dtype), new_params, params
-                )
-                # skip non-finite updates (reference base_hf_engine.py:474)
-                ok = jnp.isfinite(grad_norm)
-                new_params = jax.tree_util.tree_map(
-                    lambda n, o: jnp.where(ok, n, o), new_params, params
-                )
-                new_opt = jax.tree_util.tree_map(
-                    lambda n, o: jnp.where(ok, n, o), new_opt, opt_state
-                )
-                return new_params, new_opt, grad_norm, ok
+                with tracing.device_scope("optimizer"):
+                    grads = jax.tree_util.tree_map(
+                        lambda g: g / total_weight, grad_accum
+                    )
+                    grad_norm = optax.global_norm(grads)
+                    updates, new_opt = self.optimizer.update(
+                        grads, opt_state, params
+                    )
+                    new_params = optax.apply_updates(params, updates)
+                    # keep the declared param dtype: f32 updates would silently
+                    # promote bf16 params (breaking donation every step)
+                    new_params = jax.tree_util.tree_map(
+                        lambda n, o: n.astype(o.dtype), new_params, params
+                    )
+                    # skip non-finite updates (reference base_hf_engine.py:474)
+                    ok = jnp.isfinite(grad_norm)
+                    new_params = jax.tree_util.tree_map(
+                        lambda n, o: jnp.where(ok, n, o), new_params, params
+                    )
+                    new_opt = jax.tree_util.tree_map(
+                        lambda n, o: jnp.where(ok, n, o), new_opt, opt_state
+                    )
+                    return new_params, new_opt, grad_norm, ok
 
             self._jit_cache[key] = jax.jit(apply_step, donate_argnums=(0, 1, 2))
         return self._jit_cache[key]
@@ -522,70 +551,79 @@ class SPMDTrainEngine(TrainEngine):
     ) -> Dict[str, float]:
         if self.optimizer is None:
             raise RuntimeError("no optimizer configured")
-        t_start = time.perf_counter()
-        mbs = data_utils.split_padded_batch_into_mb_list(
-            input_, self.config.mb_spec.max_tokens_per_mb,
-            min_n_mbs=self.config.mb_spec.n_mbs,
-        )
-        window = self._flash_window(input_)
-        grad_fn = self._get_grad_fn(loss_fn, loss_weight_fn, window)
-        grad_accum = self._zero_grads()
-        pad_to = self._mb_pad_to(mbs.mbs)
+        step = self.step_count
+        with tracing.span("train.minibatch", step=step):
+            return self._train_minibatch(input_, loss_fn, loss_weight_fn, step)
+
+    def _train_minibatch(
+        self, input_: Batch, loss_fn: Callable, loss_weight_fn: Callable,
+        step: int,
+    ) -> Dict[str, float]:
+        with tracing.span("train.split", step=step):
+            mbs = data_utils.split_padded_batch_into_mb_list(
+                input_, self.config.mb_spec.max_tokens_per_mb,
+                min_n_mbs=self.config.mb_spec.n_mbs,
+            )
+            window = self._flash_window(input_)
+            grad_fn = self._get_grad_fn(loss_fn, loss_weight_fn, window)
+            grad_accum = self._zero_grads()
+            pad_to = self._mb_pad_to(mbs.mbs)
         losses, weights, all_stats = [], [], []
-        pack_s, grad_call_s = 0.0, []
         # goodput attribution: host packing books data_h2d, the grad
         # dispatches book fwd_bwd (minus any compile, which the trainer
         # CompileTracker carves into the compile bucket with this step's
-        # shape signature), the apply + scalar fetch books optim
+        # shape signature), the apply + scalar fetch books optim. Where
+        # the device's time goes is the profile's to say (programs
+        # grad_step / apply_step); these are the host's intervals in it
         gp_sig = f"mbs{len(mbs.mbs)}|pad{pad_to}|window{window}"
         for mb in mbs.mbs:
-            t0 = time.perf_counter()
             with goodput.trainer_bucket("data_h2d"):
                 _, arrays = self._pack_for_device(mb, pad_to=pad_to)
-            t1 = time.perf_counter()
-            pack_s += t1 - t0
-            with goodput.trainer_bucket("fwd_bwd"), goodput.dispatch_scope(
+            with goodput.trainer_bucket("fwd_bwd"), tracing.span(
+                "train.grad_dispatch", step=step
+            ), goodput.dispatch_scope(
                 goodput.trainer_tracker(), "fwd_bwd", gp_sig
             ):
                 grad_accum, loss, stats, w = grad_fn(
                     self.params, grad_accum, arrays
                 )
-            # wall time of the (async) dispatch: a multi-second outlier on
-            # one call = that call traced/compiled a fresh program
-            grad_call_s.append(round(time.perf_counter() - t1, 3))
             losses.append(loss)
             weights.append(w)
             all_stats.append(stats)
         total_w = functools.reduce(lambda a, b: a + b, weights)
         apply_fn = self._get_apply_fn()
-        t_apply = time.perf_counter()
         # the optim bucket spans apply THROUGH the blocking scalar
         # fetch: the fetch is where every async dispatch's device
         # compute actually lands on the wall clock
         with goodput.trainer_bucket("optim"):
-            with goodput.dispatch_scope(
+            with tracing.span(
+                "train.apply_dispatch", step=step
+            ), goodput.dispatch_scope(
                 goodput.trainer_tracker(), "optim", gp_sig
             ):
                 self.params, self.opt_state, grad_norm, ok = apply_fn(
                     self.params, self.opt_state, grad_accum, total_w
                 )
-            lr = float(self.lr_schedule(self.step_count))
-            self.step_count += 1
-            t_fetch = time.perf_counter()
-            # ONE packed host fetch for every scalar this step produced —
-            # each separate float() is a full device round-trip
-            stat_keys = sorted(all_stats[0])
-            scalars = [ok, grad_norm, total_w] + losses + weights + [
-                s[k] for s in all_stats for k in stat_keys
-            ]
-            blob = np.asarray(
-                jnp.stack(
-                    [
-                        jnp.asarray(x, jnp.float32).reshape(())
-                        for x in scalars
-                    ]
+            # everything from here to the blob is the host fetching scalars:
+            # the learning rate is a device value too, and its float() is
+            # the first fetch that waits for the queued device work
+            with tracing.span("train.scalar_fetch", step=step):
+                lr = float(self.lr_schedule(self.step_count))
+                self.step_count += 1
+                # ONE packed host fetch for every scalar this step produced
+                # — each separate float() is a full device round-trip
+                stat_keys = sorted(all_stats[0])
+                scalars = [ok, grad_norm, total_w] + losses + weights + [
+                    s[k] for s in all_stats for k in stat_keys
+                ]
+                blob = np.asarray(
+                    jnp.stack(
+                        [
+                            jnp.asarray(x, jnp.float32).reshape(())
+                            for x in scalars
+                        ]
+                    )
                 )
-            )
         n_mb = len(mbs.mbs)
         h_ok, h_gnorm, h_total_w = blob[0], blob[1], blob[2]
         h_losses = blob[3 : 3 + n_mb]
@@ -600,30 +638,6 @@ class SPMDTrainEngine(TrainEngine):
         }
         for j, k in enumerate(stat_keys):
             out[k] = float((h_stats[:, j] * h_weights).sum() / h_total_w)
-        t_end = time.perf_counter()
-        # diagnostics for bench/driver post-hoc analysis: where did this
-        # step's wall time go, and did any dispatch compile?
-        self.last_timing = {
-            "total_s": round(t_end - t_start, 3),
-            "pack_s": round(pack_s, 3),
-            "grad_dispatch_s": grad_call_s,
-            "apply_fetch_s": round(t_end - t_apply, 3),
-            "fetch_s": round(t_end - t_fetch, 3),
-            "n_mbs": n_mb,
-            "pad_to": pad_to,
-            "window": window,
-        }
-        # same breakdown through the stats plane: StatsLogger.commit
-        # persists it per step alongside the rollout/staleness telemetry
-        from areal_tpu.utils import stats_tracker
-
-        stats_tracker.scalar(**{
-            "spmd/train_batch_s": t_end - t_start,
-            "spmd/pack_s": pack_s,
-            "spmd/grad_dispatch_s": float(sum(grad_call_s)),
-            "spmd/apply_fetch_s": t_end - t_apply,
-            "spmd/n_mbs": float(n_mb),
-        })
         return out
 
     def eval_batch(
@@ -688,6 +702,13 @@ class SPMDTrainEngine(TrainEngine):
         `post_hook(logits, arrays) -> [R, T] array` must be jittable; default
         returns target-aligned logprobs.
         """
+        step = self.step_count
+        with tracing.span("train.logp_recompute", step=step):
+            return self._forward(input_, post_hook, step)
+
+    def _forward(
+        self, input_: Batch, post_hook: Optional[Callable], step: int
+    ) -> np.ndarray:
         mbs = data_utils.split_padded_batch_into_mb_list(
             input_, self.config.mb_spec.max_tokens_per_mb,
             min_n_mbs=self.config.mb_spec.n_mbs,
@@ -702,7 +723,7 @@ class SPMDTrainEngine(TrainEngine):
             lazy_head = self._lazy_head()
             act_sh = self._act_sharding()
 
-            def fwd(params, arrays):
+            def logp_forward(params, arrays):
                 cparams = jax.tree_util.tree_map(
                     lambda p: p.astype(compute_dtype), params
                 )
@@ -710,20 +731,25 @@ class SPMDTrainEngine(TrainEngine):
                     cparams, mc, arrays, remat=False, attend_fn=attend,
                     return_hidden=lazy_head, act_sharding=act_sh,
                 )
-                return hook(logits, arrays)
+                with tracing.device_scope("loss"):
+                    return hook(logits, arrays)
 
             # replicated output: under multi-process the per-token result
             # must be fully addressable for the host np.asarray fetch
             self._jit_cache[key] = jax.jit(
-                fwd,
+                logp_forward,
                 out_shardings=sharding_lib.replicated(self.mesh),
             )
         pad_to = self._mb_pad_to(mbs.mbs)
         outs = []
         for mb in mbs.mbs:
             packed, arrays = self._pack_for_device(mb, pad_to=pad_to)
-            vals = np.asarray(self._jit_cache[key](self.params, arrays))
-            outs.append(data_utils.unpack_rows_per_token(packed, vals))
+            with tracing.span("train.logp_dispatch", step=step):
+                vals = self._jit_cache[key](self.params, arrays)
+            with tracing.span("train.logp_fetch", step=step):
+                vals = np.asarray(vals)  # blocks on the device here
+            with tracing.span("train.unpack", step=step):
+                outs.append(data_utils.unpack_rows_per_token(packed, vals))
         # scatter back to original order at the input's padded width
         bsz = data_utils.batch_size(input_)
         width = np.asarray(input_["attention_mask"]).shape[1]

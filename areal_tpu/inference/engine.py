@@ -45,6 +45,7 @@ registry serves the already-cached prefix back without recompute.
 """
 
 import dataclasses
+import functools
 import os
 import queue
 import threading
@@ -72,7 +73,7 @@ from areal_tpu.models import hf_io
 from areal_tpu.models.config import ModelConfig, load_hf_config
 from areal_tpu.models.transformer import Params
 from areal_tpu.utils import data as data_utils
-from areal_tpu.utils import goodput
+from areal_tpu.utils import goodput, tracing
 from areal_tpu.utils import logging as logging_util
 from areal_tpu.utils.tracing import Histogram, SpanTracer
 
@@ -85,6 +86,52 @@ _DTYPES = {"float32": jnp.float32, "bfloat16": jnp.bfloat16}
 # throughput rollouts. Unknown labels degrade to bulk — the shed/preempt
 # machinery must never promote unlabeled traffic.
 SCHED_CLASSES = ("interactive", "bulk")
+# the rid the loop thread's own spans share in the tracer's ring
+_LOOP_RID = "__engine__"
+
+
+def _loop_phase(name: str, counter: str):
+    """The decorated method of the loop thread is one span ``name``, and
+    its host seconds add to ``metrics()["total_loop_<counter>_s"]`` — for
+    every caller, so nothing is counted twice or left out."""
+
+    def deco(fn):
+        @functools.wraps(fn)
+        def timed(self, *args, **kwargs):
+            with self._loop_span(name) as sp:
+                out = fn(self, *args, **kwargs)
+            self._loop_s[counter] += sp.duration
+            return out
+
+        return timed
+
+    return deco
+
+
+class _Phases:
+    """Consecutive sibling spans over one long linear stretch of the loop
+    thread (``_admit``): each ``enter`` closes the phase before it;
+    leaving the block (an early ``return`` too) closes the last."""
+
+    __slots__ = ("_engine", "_open")
+
+    def __init__(self, engine: "GenerationEngine"):
+        self._engine = engine
+        self._open = None
+
+    def __enter__(self):
+        return self
+
+    def enter(self, name: str) -> None:
+        self.__exit__(None, None, None)
+        self._open = self._engine._loop_span(name)
+        self._open.__enter__()
+
+    def __exit__(self, *exc):
+        if self._open is not None:
+            self._open.__exit__(*exc)
+            self._open = None
+        return False
 
 
 class AdmissionRejectedError(RuntimeError):
@@ -783,6 +830,13 @@ class GenerationEngine:
             jsonl_path=getattr(gp, "jsonl_path", "") or "",
             compile_tracker=self.compiles,
         )
+        # cumulative host seconds of the loop thread per phase, read off
+        # the same spans (metrics(): total_loop_*_s, total_loop_iterations)
+        self._loop_s = dict.fromkeys(
+            ("admit", "decode_dispatch", "decode_fetch", "decode_process",
+             "idle"), 0.0,
+        )
+        self.total_loop_iterations = 0
         self._ready_quiet_s = float(getattr(gp, "ready_quiet_s", 3.0))
         self._ready_min_requests = int(
             getattr(gp, "ready_min_requests", 1)
@@ -1292,6 +1346,16 @@ class GenerationEngine:
             decode_rows_dispatched=self._decode_rows_dispatched,
             decode_rows_active=self._decode_rows_active,
             total_decode_chunks=self.total_decode_chunks,
+            total_loop_iterations=self.total_loop_iterations,
+            total_loop_admit_s=round(self._loop_s["admit"], 6),
+            total_loop_decode_dispatch_s=round(
+                self._loop_s["decode_dispatch"], 6
+            ),
+            total_loop_decode_fetch_s=round(self._loop_s["decode_fetch"], 6),
+            total_loop_decode_process_s=round(
+                self._loop_s["decode_process"], 6
+            ),
+            total_loop_idle_s=round(self._loop_s["idle"], 6),
             total_rows_dispatched=self.total_rows_dispatched,
             total_rows_active=self.total_rows_active,
             decode_occupancy=round(
@@ -1470,6 +1534,7 @@ class GenerationEngine:
         goodput.set_thread_tracker(self.compiles, phase="engine")
         led = self.ledger
         while self._running:
+            self.total_loop_iterations += 1
             self._maybe_start_profile()
             did_flip = False
             if self.weights.flip_pending:
@@ -1507,8 +1572,14 @@ class GenerationEngine:
                     or self._active
                     or not self._admit_queue.empty()
                 ):
-                    with led.bucket("prefill"):
+                    ls = self._loop_s
+                    retire_s = ls["decode_fetch"] + ls["decode_process"]
+                    with led.bucket("prefill") as b:
                         did_work |= self._admit()
+                    # chunks retired inside an admission (a deadline
+                    # preemption drains the pipeline) stay decode's
+                    retire_s -= ls["decode_fetch"] + ls["decode_process"]
+                    ls["admit"] += b.duration + retire_s
                 else:
                     did_work |= self._admit()
                 did_work |= self._decode()  # buckets decode/spec inside
@@ -1519,9 +1590,12 @@ class GenerationEngine:
                 # and crater the gauge)
                 self._last_decode_mark = None
                 with led.bucket(
-                    "weight_pause" if self._paused.is_set() else "idle"
-                ):
+                    "weight_pause" if self._paused.is_set() else "idle",
+                    poll=True,  # one span per quiet stretch, not per sleep
+                ) as b:
                     time.sleep(0.001)
+                self._loop_s["idle"] += b.duration
+        led.end_poll()
         self._maybe_stop_profile(did_work=True, force=True)
         goodput.set_thread_tracker(None)
 
@@ -1687,6 +1761,14 @@ class GenerationEngine:
             for slot in default_slots:
                 self._finish(slot, "abort")
 
+    def _loop_span(self, name: str, seq: Optional[int] = None, **attrs):
+        """A span of the loop thread (``utils.tracing.span``), stamped with
+        the decode-chunk sequence number it belongs to."""
+        return tracing.span(
+            name, self.tracer, _LOOP_RID,
+            seq=self.total_decode_chunks if seq is None else seq, **attrs,
+        )
+
     def _drain_commands(self) -> bool:
         did = False
         while True:
@@ -1696,6 +1778,8 @@ class GenerationEngine:
                 return did
             did = True
             t_cmd = time.monotonic()
+            cmd_span = self._loop_span("engine.commands", command=cmd)
+            cmd_span.__enter__()
             try:
                 # every command needs a quiesced device pipeline: aborts
                 # must not race in-flight chunks, and weight swaps would
@@ -1792,6 +1876,8 @@ class GenerationEngine:
                     )
             except Exception as e:  # surface errors to the caller
                 done.set_exception(e)
+            finally:
+                cmd_span.__exit__(None, None, None)
 
     # ------------------------------------------------------------------
     # Page accounting
@@ -2251,7 +2337,7 @@ class GenerationEngine:
         """Process every in-flight decode chunk (and release deferrals)."""
         while self._inflight:
             self._process_chunk(self._inflight.pop(0))
-        self._flush_deferred()
+        self._flush_deferred()  # nothing was in flight: release anyway
 
     # ------------------------------------------------------------------
     # Admission
@@ -2303,7 +2389,15 @@ class GenerationEngine:
         behind ONE prefill row, sharing full prompt pages and copying at
         most one partial tail page; unique prompts prefill as one batched
         [N, Tp] dispatch, each row resuming from its registry-claimed
-        prefix (offset)."""
+        prefix (offset). Its stretches are consecutive spans of the loop
+        thread: ``admit.schedule`` (selection, prefix claims, pages),
+        ``admit.prefill_dispatch``, ``admit.install`` (publish, sibling
+        fan-out, per-slot state), ``admit.first_token_fetch`` (sampling
+        and the device→host wait for the first tokens)."""
+        with _Phases(self) as phase:
+            return self._admit_wave(phase)
+
+    def _admit_wave(self, phase: _Phases) -> bool:
         got_new = 0
         while True:
             try:
@@ -2341,6 +2435,7 @@ class GenerationEngine:
         )
         if not self._pending:
             return False
+        phase.enter("admit.schedule")
         if not self._free_slots and not self._has_chunkable_pending():
             # slotless chunk work may still proceed: a mid-prefill
             # prompt's next chunk needs no slot until its FINAL chunk,
@@ -2809,6 +2904,7 @@ class GenerationEngine:
                 jnp.asarray(pw), jnp.asarray(ords),
             )
             pf_pos3 = jnp.asarray(pos3)
+        phase.enter("admit.prefill_dispatch")
         t_pf_start = time.monotonic()
         # host→device conversions hoisted OUT of the dispatch scope:
         # their tiny eager-op compiles belong to the ("engine", "")
@@ -2836,6 +2932,7 @@ class GenerationEngine:
                 embeds=pf_embeds,
                 pos3=pf_pos3,
             )
+        phase.enter("admit.install")
         if self._radix:
             # publish-at-prefill-commit: the wave's prompt pages enter
             # the radix tree NOW (the merge dispatch is already ordered
@@ -3070,6 +3167,7 @@ class GenerationEngine:
 
         # --- first token for every admitted slot: siblings share the
         # representative's last-token logits row ---
+        phase.enter("admit.first_token_fetch")
         rows = jnp.asarray([r for (_, _, r) in admitted])
         full = jnp.zeros(
             (self.config.max_num_seqs, wave_logits.shape[-1]),
@@ -3077,6 +3175,7 @@ class GenerationEngine:
         ).at[sl].set(wave_logits[rows])
         self._sample_and_append(full, only_slots=[int(x) for x in slots_np])
         t_pf_end = time.monotonic()
+        phase.enter("admit.install")
         pf_tokens = int(true_lens.sum())
         if t_pf_end > t_pf_start:
             # EWMA over waves: the dispatch wall time includes the logits
@@ -3182,6 +3281,7 @@ class GenerationEngine:
     # ------------------------------------------------------------------
     # Decode
     # ------------------------------------------------------------------
+    @_loop_phase("decode.ensure_pages", "decode_dispatch")
     def _ensure_decode_pages(self, margin_tokens: int) -> bool:
         """Grow every active slot's page table to cover its cached length
         plus ``margin_tokens`` (the host view lags in-flight chunks, so
@@ -3384,7 +3484,6 @@ class GenerationEngine:
                     "spec_verify" if spec_chunk else "decode"
                 ):
                     self._process_chunk(chunk)
-                    self._flush_deferred()
                 return True
         if self._active and len(self._inflight) <= depth:
             if drafts:
@@ -3445,7 +3544,6 @@ class GenerationEngine:
             spec_chunk = chunk.get("spec_draft_lens") is not None
             with led.bucket("spec_verify" if spec_chunk else "decode"):
                 self._process_chunk(chunk)
-                self._flush_deferred()
             did = True
         return did
 
@@ -3475,6 +3573,7 @@ class GenerationEngine:
             self._compact_shrink_streak = 0
         return self._compact_rows
 
+    @_loop_phase("decode.dispatch", "decode_dispatch")
     def _dispatch_chunk(
         self,
         steps: int,
@@ -3763,6 +3862,7 @@ class GenerationEngine:
         self._inflight.append(
             {
                 "packed": packed,
+                "seq": self.total_decode_chunks,
                 "steps": steps,
                 # worst-case token growth of this chunk (for later
                 # dispatches' page margins — verify and regular chunk
@@ -3781,11 +3881,22 @@ class GenerationEngine:
         )
 
     def _process_chunk(self, chunk: Dict[str, Any]):
+        """Retire one in-flight chunk: wait for its packed result
+        (``decode.fetch``), then book it and release what it deferred
+        (``decode.process``)."""
+        with self._loop_span("decode.fetch", chunk["seq"]) as sp:
+            packed = np.asarray(chunk["packed"])  # blocks on the device here
+        self._loop_s["decode_fetch"] += sp.duration
+        with self._loop_span("decode.process", chunk["seq"]) as sp:
+            self._book_chunk(chunk, packed)
+            self._flush_deferred()
+        self._loop_s["decode_process"] += sp.duration
+
+    def _book_chunk(self, chunk: Dict[str, Any], packed: np.ndarray):
         steps = chunk["steps"]
         row_slots = chunk["row_slots"]
         s = self.config.max_num_seqs
         r = len(row_slots)
-        packed = np.asarray(chunk["packed"])  # blocks on the device here
         n = steps * r
         h_toks = packed[:n].reshape(steps, r).astype(np.int64)
         h_logps = packed[n : 2 * n].reshape(steps, r)

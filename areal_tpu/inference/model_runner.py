@@ -46,6 +46,7 @@ from areal_tpu.ops.paged_attention import (
     paged_decode_attention_jnp,
     unpacked_view,
 )
+from areal_tpu.utils.tracing import device_scope
 
 NEG_INF = -2.3819763e38
 
@@ -68,6 +69,7 @@ def _project_qkv(cfg: ModelConfig, lp: Params, h: jnp.ndarray):
     return q, k, v
 
 
+@device_scope("mlp")
 def _mlp(
     cfg: ModelConfig,
     lp: Params,
@@ -124,6 +126,7 @@ def _layer_post(
     return x + _mlp(cfg, lp, h2, valid=valid)
 
 
+@device_scope("lm_head")
 def _final_logits(params: Params, cfg: ModelConfig, x: jnp.ndarray):
     x = rms_norm(
         x, params["final_norm"], cfg.rms_norm_eps,
@@ -292,6 +295,7 @@ def assemble_rows(
 
 
 @functools.partial(jax.jit, donate_argnames=("cache",))
+@device_scope("kv_write")
 def write_rows(
     cache: Dict[str, jnp.ndarray],
     dest: jnp.ndarray,  # [M] flat row ids (0 = engine trash page)
@@ -720,6 +724,7 @@ def _gather_recent_kv(
     return gather(cache["k"]), gather(cache["v"])
 
 
+@device_scope("attention")
 def _attend(
     cfg: ModelConfig,
     cache: Dict[str, jnp.ndarray],
@@ -1415,6 +1420,7 @@ def spec_verify(
 # ---------------------------------------------------------------------------
 # Sampling
 # ---------------------------------------------------------------------------
+@device_scope("sample")
 def _sample_impl(
     logits: jnp.ndarray,  # [S, V] fp32
     key: jax.Array,

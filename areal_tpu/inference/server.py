@@ -120,6 +120,18 @@ _METRIC_HELP = {
     "decode_rows_active": "rows carrying live requests in the last chunk",
     "decode_occupancy": "lifetime active/dispatched decode-row ratio",
     "total_decode_chunks": "decode chunks dispatched",
+    "total_loop_iterations": "iterations of the engine's loop thread",
+    "total_loop_admit_s": "loop-thread host seconds inside admission",
+    "total_loop_decode_dispatch_s": (
+        "loop-thread host seconds walking pages and dispatching chunks"
+    ),
+    "total_loop_decode_fetch_s": (
+        "loop-thread host seconds waiting for a chunk's results"
+    ),
+    "total_loop_decode_process_s": (
+        "loop-thread host seconds booking a chunk's results"
+    ),
+    "total_loop_idle_s": "loop-thread host seconds idling",
     "total_rows_dispatched": "decode rows dispatched (lifetime)",
     "total_rows_active": "decode rows that carried live requests",
     "total_generated_tokens": "completion tokens emitted",
@@ -351,6 +363,9 @@ _METRIC_HELP = {
 # metrics-hygiene lint enforces full coverage)
 _ENGINE_COUNTERS = (
     "total_decode_chunks", "total_rows_dispatched", "total_rows_active",
+    "total_loop_iterations", "total_loop_admit_s",
+    "total_loop_decode_dispatch_s", "total_loop_decode_fetch_s",
+    "total_loop_decode_process_s", "total_loop_idle_s",
     "total_generated_tokens", "total_prompt_tokens",
     "total_cached_prompt_tokens", "total_requests", "total_aborted",
     "total_preemptions", "prefix_cached_tokens_total",

@@ -25,6 +25,7 @@ import numpy as np
 from jax import ad_checkpoint as _ad_checkpoint
 
 from areal_tpu.models.config import ModelConfig
+from areal_tpu.utils.tracing import device_scope
 from areal_tpu.ops.basic import (
     apply_mrope,
     apply_rope,
@@ -206,10 +207,13 @@ def _layer_body(
     else:
         q = apply_rope(q, positions, cos, sin)
         k = apply_rope(k, positions, cos, sin)
-    if attend_fn is None:
-        attn = segment_attention(q, k, v, segment_ids, causal=True)
-    else:  # explicit SP kernel (ring / ulysses shard_map)
-        attn = attend_fn(q, k, v, segment_ids)
+    # device scopes (utils/tracing.DEVICE_SCOPES): names only — what a
+    # profile's per-op shares are read by; no number and no fusion changes
+    with device_scope("attention"):
+        if attend_fn is None:
+            attn = segment_attention(q, k, v, segment_ids, causal=True)
+        else:  # explicit SP kernel (ring / ulysses shard_map)
+            attn = attend_fn(q, k, v, segment_ids)
     # named so a remat policy can SAVE attention outputs: recomputing the
     # flash forward inside the backward costs ~14ms/layer at 24k (measured,
     # tools/microbench_attn_v2.py) for [B,T,Hq,D] bf16 of storage
@@ -225,13 +229,18 @@ def _layer_body(
         )
 
         # padding tokens (segment 0) must not consume expert capacity
-        ffn, aux = moe_ffn_from_params(cfg, lp, h, valid=segment_ids > 0)
-        if cfg.shared_expert_size:
-            ffn = ffn + shared_expert_from_params(cfg, lp, h)
+        with device_scope("mlp"):
+            ffn, aux = moe_ffn_from_params(
+                cfg, lp, h, valid=segment_ids > 0
+            )
+            if cfg.shared_expert_size:
+                ffn = ffn + shared_expert_from_params(cfg, lp, h)
         return x + ffn, aux
-    ffn = (
-        hidden_act_fn(cfg.hidden_act)(h @ lp["w_gate"]) * (h @ lp["w_up"])
-    ) @ lp["w_down"]
+    with device_scope("mlp"):
+        ffn = (
+            hidden_act_fn(cfg.hidden_act)(h @ lp["w_gate"])
+            * (h @ lp["w_up"])
+        ) @ lp["w_down"]
     return x + ffn, jnp.zeros((), jnp.float32)
 
 
@@ -272,9 +281,10 @@ def apply(
     cos, sin = rope_frequencies(
         cfg.head_dim, cfg.max_position_embeddings, cfg.rope_theta
     )
-    x = params["embedding"][tokens]
-    if cfg.scale_embeddings:  # gemma: sqrt(d)-scaled embeddings
-        x = x * jnp.asarray(cfg.hidden_size**0.5, x.dtype)
+    with device_scope("embed"):
+        x = params["embedding"][tokens]
+        if cfg.scale_embeddings:  # gemma: sqrt(d)-scaled embeddings
+            x = x * jnp.asarray(cfg.hidden_size**0.5, x.dtype)
     if mm_embeds is not None and mm_index is not None:
         gathered = jnp.take_along_axis(
             mm_embeds,
@@ -322,7 +332,8 @@ def apply(
 
         logits = ChunkedLogits(x, head)
     else:
-        logits = (x.astype(jnp.float32)) @ head.astype(jnp.float32)
+        with device_scope("lm_head"):
+            logits = (x.astype(jnp.float32)) @ head.astype(jnp.float32)
     if return_router_loss:
         return logits, jnp.mean(aux)
     return logits

@@ -26,6 +26,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from areal_tpu.utils.tracing import device_scope
+
 
 @dataclasses.dataclass(frozen=True)
 class ChunkedLogits:
@@ -48,9 +50,10 @@ class ChunkedLogits:
         return ChunkedLogits(self.hidden[idx], self.head)
 
     def full(self) -> jnp.ndarray:
-        return self.hidden.astype(jnp.float32) @ self.head.astype(
-            jnp.float32
-        )
+        with device_scope("lm_head"):
+            return self.hidden.astype(jnp.float32) @ self.head.astype(
+                jnp.float32
+            )
 
 
 def chunked_gather_logprobs(
@@ -76,7 +79,8 @@ def chunked_gather_logprobs(
 
     def body(carry, inp):
         hc, lc = inp
-        logits = hc.astype(jnp.float32) @ head.astype(jnp.float32)
+        with device_scope("lm_head"):
+            logits = hc.astype(jnp.float32) @ head.astype(jnp.float32)
         if temperature != 1.0:
             logits = logits / temperature
         logz = jax.nn.logsumexp(logits, axis=-1)

@@ -47,6 +47,7 @@ import time
 from typing import Any, Dict, List, Optional, Tuple
 
 from areal_tpu.utils import logging as logging_util
+from areal_tpu.utils import tracing
 
 logger = logging_util.getLogger("goodput")
 
@@ -486,20 +487,45 @@ class CompileTracker:
 # --------------------------------------------------------------------------
 class _LedgerTLS(threading.local):
     depth = 0
+    held = None  # the span of a poll stretch still open on this thread
 
 
 class _BucketCtx:
-    __slots__ = ("_ledger", "_name", "_t0", "_c0", "_outer")
+    __slots__ = (
+        "_ledger", "_name", "_poll", "_t0", "_c0", "_outer", "_span",
+        "duration",
+    )
 
-    def __init__(self, ledger: "GoodputLedger", name: str):
+    def __init__(self, ledger: "GoodputLedger", name: str, poll: bool):
         self._ledger = ledger
         self._name = name
+        self._poll = poll
+        # host seconds of this entry; stays 0 for a nested (no-op) one
+        self.duration = 0.0
 
     def __enter__(self):
         led = self._ledger
-        self._outer = led._tls.depth == 0
-        led._tls.depth += 1
+        tls = led._tls
+        self._outer = tls.depth == 0
+        tls.depth += 1
         if self._outer:
+            # the bucket IS a span of the one primitive: in the profiler's
+            # trace whenever a profile runs, as areal:<role>.<bucket>. Never
+            # in a SpanTracer's ring. A poll (an idle or paused loop enters
+            # one every millisecond) opens its span once per stretch: it
+            # stays open over the polls that follow and ends when the
+            # thread enters a bucket of another name
+            name = f"{led.role}.{self._name}"
+            held = tls.held
+            if held is not None and held.name != name:
+                held.__exit__(None, None, None)
+                held = tls.held = None
+            self._span = None  # inside an open stretch: that one covers it
+            if held is None:
+                self._span = tracing.span(name)
+                self._span.__enter__()
+                if self._poll:
+                    tls.held = self._span
             self._t0 = led._time()
             tr = led.compile_tracker
             self._c0 = tr.thread_seconds() if tr is not None else 0.0
@@ -509,7 +535,7 @@ class _BucketCtx:
         led = self._ledger
         led._tls.depth -= 1
         if self._outer:
-            dt = led._time() - self._t0
+            self.duration = dt = led._time() - self._t0
             dc = 0.0
             tr = led.compile_tracker
             if tr is not None and "compile" in led._acc:
@@ -518,6 +544,8 @@ class _BucketCtx:
                 if dc:
                     led._acc["compile"] += dc
                 led._acc[self._name] += dt - dc
+            if self._span is not None and self._span is not led._tls.held:
+                self._span.__exit__(*exc)
         return False
 
 
@@ -528,9 +556,11 @@ class GoodputLedger:
     observed on the same thread is carved out into ``compile`` when a
     tracker is attached). Reentrant entries on the same thread are
     no-ops — the outermost bucket wins — so layered code can self-wrap
-    freely. ``fractions()`` divides by observed wall time since the
-    ledger started, with the remainder bucket absorbing unclaimed time:
-    the fractions sum to 1.0 by construction."""
+    freely. That outermost entry is also one ``tracing.span``
+    (``<role>.<bucket>``), so the buckets are intervals in the profiler's
+    trace, not only sums. ``fractions()`` divides by observed wall time
+    since the ledger started, with the remainder bucket absorbing
+    unclaimed time: the fractions sum to 1.0 by construction."""
 
     def __init__(
         self,
@@ -561,10 +591,19 @@ class GoodputLedger:
         self._epoch_unix = time.time()
 
     # -- recording -----------------------------------------------------
-    def bucket(self, name: str) -> _BucketCtx:
+    def bucket(self, name: str, poll: bool = False) -> _BucketCtx:
+        """``poll``: this entry is one tick of a loop's top-level wait
+        (sleep, look again). Its seconds are booked per entry like any
+        other; its span is one per stretch of such entries (``end_poll``
+        closes a stretch that nothing followed)."""
         if name not in self._acc:
             raise KeyError(f"unknown goodput bucket {name!r}")
-        return _BucketCtx(self, name)
+        return _BucketCtx(self, name, poll)
+
+    def end_poll(self) -> None:
+        held, self._tls.held = self._tls.held, None
+        if held is not None:
+            held.__exit__(None, None, None)
 
     def add(self, name: str, seconds: float) -> None:
         """Direct credit (for windows measured elsewhere)."""

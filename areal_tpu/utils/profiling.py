@@ -94,12 +94,3 @@ class PhaseProfiler:
                     logger.info(f"trace written: {out}")
                 except Exception as e:
                     logger.warning(f"profiler stop failed: {e}")
-
-
-@contextlib.contextmanager
-def annotate(name: str):
-    """Named region in the device trace (reference `time_mark` analog)."""
-    import jax
-
-    with jax.profiler.TraceAnnotation(name):
-        yield

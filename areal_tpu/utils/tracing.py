@@ -10,11 +10,15 @@ wants onto the same timeline.
 
 Design constraints, in order:
 
-1. **Disabled must be free.** The scheduler loop calls into the tracer
+1. **Disabled must be cheap.** The scheduler loop calls into the tracer
    per admission wave and per finished request; `bench.py` showed the
-   loop is host-bound at high slot counts. So `span()` on a disabled
-   tracer returns a cached singleton (no generator, no Span allocation)
-   and `record()` returns before touching the lock.
+   loop is host-bound at high slot counts. So `record()` on a disabled
+   tracer returns before touching the lock. In a process that has jax
+   loaded a `span()` costs one small object, two clock reads and an inert
+   `TraceAnnotation` (about a microsecond) — nothing is recorded per
+   token or per row. In a process that has not (an env worker, the
+   router) no span ever imports it, and a disabled tracer's `span()` is
+   one shared null object.
 2. **Bounded memory.** Spans live in a `deque(maxlen=max_spans)`; a
    long-running server drops the oldest and counts them (`dropped`).
 3. **Two export formats.** JSONL (one span per line — what
@@ -28,6 +32,20 @@ the unix time of its monotonic epoch (``epoch_unix_s``) so traces from
 DIFFERENT processes — each with its own monotonic zero — can be shifted
 onto one shared timeline (``utils/telemetry.stitch_chrome_traces``).
 
+One primitive (:func:`span`): every layer boundary of the trainer and of the
+engine's loop thread, and every request-keyed ``SpanTracer.span``, is one
+``with span(name, **attrs)``. It always enters a
+``jax.profiler.TraceAnnotation("areal:<name>", **attrs)`` — inert unless a
+profile is running, and then an event on the device trace's own clock with
+its attributes as stats — and, when a tracer is handed in and enabled,
+records the same interval into that tracer's ring with the id of the span
+that encloses it on this thread (``parent``), so ``/trace`` shows the tree
+the profile shows (a span's self time is its duration minus its
+children's). ``DEVICE_SCOPES`` is the one vocabulary of
+names for device work (:func:`device_scope`): they land in every HLO
+instruction's ``op_name`` and so survive a recompile that renumbers the
+fusions.
+
 Cross-process trace context (r9): requests propagate a trace id over
 HTTP via the ``X-Areal-Trace`` / ``X-Areal-Rid`` headers. A receiving
 process calls ``bind_trace(rid, trace_id)`` and every span it records
@@ -35,7 +53,10 @@ for that rid carries a ``trace`` attr — the join key that stitches
 client, router, and server spans into one end-to-end timeline.
 """
 
+import contextlib
+import itertools
 import json
+import sys
 import threading
 import time
 import uuid
@@ -63,7 +84,11 @@ def trace_headers(trace_id: str, rid: str = "") -> Dict[str, str]:
 
 
 class Span:
-    __slots__ = ("name", "rid", "t_start", "t_end", "attrs")
+    """``sid``/``parent`` are process-wide span ids (0 = recorded after the
+    fact through ``SpanTracer.record``, outside any tree / no enclosing
+    span on its thread)."""
+
+    __slots__ = ("name", "rid", "t_start", "t_end", "attrs", "sid", "parent")
 
     def __init__(
         self,
@@ -72,12 +97,16 @@ class Span:
         t_start: float,
         t_end: float,
         attrs: Optional[Dict[str, Any]] = None,
+        sid: int = 0,
+        parent: int = 0,
     ):
         self.name = name
         self.rid = rid
         self.t_start = t_start
         self.t_end = t_end
         self.attrs = attrs or {}
+        self.sid = sid
+        self.parent = parent
 
     @property
     def duration(self) -> float:
@@ -92,6 +121,9 @@ class Span:
         }
         if self.attrs:
             d["attrs"] = self.attrs
+        if self.sid:
+            d["id"] = self.sid
+            d["parent"] = self.parent
         return d
 
     def __repr__(self):  # pragma: no cover
@@ -101,42 +133,141 @@ class Span:
         )
 
 
+# --------------------------------------------------------------------------
+# The one span primitive
+# --------------------------------------------------------------------------
+SPAN_PREFIX = "areal:"  # every span's name in the profiler's trace
+
+# jax.named_scope names for device work, each entered at the one place that
+# work is computed (models/transformer.py, engine/spmd_engine.py,
+# inference/model_runner.py, around the ops/ kernels)
+DEVICE_SCOPES = (
+    "embed", "attention", "mlp", "lm_head", "loss", "optimizer", "sample",
+    "kv_write",
+)
+
+
+def device_scope(name: str):
+    """The device work computed inside is ``name``, one of
+    ``DEVICE_SCOPES``: a ``jax.named_scope`` (the HLO's ``op_name``, which
+    a profile's events carry) and the frontend attribute ``areal_scope``
+    on the same operations. Neither changes a number or a fusion (the
+    optimized v5e program of the trainer's ``grad_step`` is the same text
+    with and without them, PERF.md PR 25). The attribute is there for the
+    persistent compile cache: its key is the program WITHOUT debug
+    metadata, so with the named scope alone a program compiled before a
+    scope was added or moved would be loaded from the cache under its old
+    names; the attribute puts the names into the key. A context manager,
+    or a decorator over a whole function body (the function keeps its
+    name, so a jitted one keeps its program's)."""
+    if name not in DEVICE_SCOPES:
+        raise ValueError(f"{name!r} is not one of {DEVICE_SCOPES}")
+    return _device_scope(name)
+
+
+@contextlib.contextmanager
+def _device_scope(name: str):
+    import jax
+    from jax.experimental.xla_metadata import set_xla_metadata
+
+    with jax.named_scope(name), set_xla_metadata(areal_scope=name):
+        yield
+
+
+class _SpanTLS(threading.local):
+    def __init__(self):
+        self.stack: List[int] = []  # ids of the spans open on this thread
+
+
+_TLS = _SpanTLS()
+_IDS = itertools.count(1)
+# jax.profiler.TraceAnnotation, once this process has jax loaded: a span
+# never imports jax itself (a process without it has no profile to be in)
+_ANNOTATION = None
+
+
+class _SpanCtx:
+    __slots__ = (
+        "name", "tracer", "rid", "attrs", "t_start", "t_end", "sid",
+        "parent", "_ann",
+    )
+
+    def __init__(self, name, tracer, rid, attrs):
+        self.name = name
+        self.tracer = tracer
+        self.rid = rid
+        self.attrs = attrs
+
+    def __enter__(self):
+        global _ANNOTATION
+        if _ANNOTATION is None and "jax" in sys.modules:
+            from jax.profiler import TraceAnnotation as _ANNOTATION
+        self._ann = None
+        if _ANNOTATION is not None:
+            self._ann = _ANNOTATION(SPAN_PREFIX + self.name, **self.attrs)
+            self._ann.__enter__()
+        stack = _TLS.stack
+        self.parent = stack[-1] if stack else 0
+        self.sid = next(_IDS)
+        stack.append(self.sid)
+        self.t_start = time.monotonic()
+        return self
+
+    def __exit__(self, *exc):
+        self.t_end = time.monotonic()
+        _TLS.stack.pop()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        tr = self.tracer
+        if tr is not None and tr.config.enabled:
+            tr._append(
+                self.name,
+                self.rid or threading.current_thread().name,
+                self.t_start, self.t_end, self.attrs, self.sid, self.parent,
+            )
+        return False
+
+    def set(self, **attrs) -> None:
+        """Attributes known only inside the body (what a pack counted):
+        stats of the profile's event and attrs of the ring's span alike."""
+        self.attrs = {**self.attrs, **attrs}
+        if self._ann is not None:
+            self._ann.set_metadata(**attrs)
+
+    @property
+    def duration(self) -> float:
+        """Host seconds between entry and exit (``time.monotonic``)."""
+        return self.t_end - self.t_start
+
+
+def span(name: str, tracer: Optional["SpanTracer"] = None, rid: str = "",
+         **attrs):
+    """``with span("train.pack", real_tokens=5):`` — see the module
+    docstring. ``tracer`` is the ring to record into when it is enabled
+    (none: the profile only); ``rid`` groups a request's spans (default:
+    the thread's name, one row per thread). The context object keeps
+    ``duration`` for callers that also count host seconds."""
+    return _SpanCtx(name, tracer, rid, attrs)
+
+
 class _NullSpanCtx:
-    """Shared do-nothing context manager for the disabled path — one
-    module-level instance, so `with tracer.span(...):` on the hot loop
-    allocates nothing."""
+    """What a disabled tracer's ``span()`` hands back in a process without
+    jax: nothing to time, nothing to annotate, nothing allocated."""
 
     __slots__ = ()
+    duration = 0.0
 
     def __enter__(self):
         return self
 
     def __exit__(self, *exc):
         return False
+
+    def set(self, **attrs) -> None:
+        pass
 
 
 _NULL_CTX = _NullSpanCtx()
-
-
-class _LiveSpanCtx:
-    __slots__ = ("_tracer", "_name", "_rid", "_attrs", "_t0")
-
-    def __init__(self, tracer, name, rid, attrs):
-        self._tracer = tracer
-        self._name = name
-        self._rid = rid
-        self._attrs = attrs
-
-    def __enter__(self):
-        self._t0 = time.monotonic()
-        return self
-
-    def __exit__(self, *exc):
-        self._tracer.record(
-            self._name, self._rid, self._t0, time.monotonic(),
-            **self._attrs,
-        )
-        return False
 
 
 class SpanTracer:
@@ -201,6 +332,9 @@ class SpanTracer:
         """Append one finished span (times are time.monotonic seconds)."""
         if not self.config.enabled:
             return
+        self._append(name, rid, t_start, t_end, attrs)
+
+    def _append(self, name, rid, t_start, t_end, attrs, sid=0, parent=0):
         with self._lock:
             tr = self._trace_ids.get(rid)
             if tr is not None and "trace" not in attrs:
@@ -210,7 +344,9 @@ class SpanTracer:
                 # make a truncated trace read as a complete one — count it
                 # (exported as tracing_dropped_spans_total on /metrics)
                 self.dropped += 1
-            self._spans.append(Span(name, rid, t_start, t_end, attrs))
+            self._spans.append(
+                Span(name, rid, t_start, t_end, attrs, sid, parent)
+            )
 
     def instant(self, name: str, rid: str, **attrs) -> None:
         """Zero-duration event (e.g. a preemption)."""
@@ -218,11 +354,13 @@ class SpanTracer:
         self.record(name, rid, now, now, **attrs)
 
     def span(self, name: str, rid: str, **attrs):
-        """Context manager measuring its body. Disabled: returns a shared
-        null object — callers on hot paths pay one attribute read."""
-        if not self.config.enabled:
+        """Context manager measuring its body: the module's :func:`span`
+        keyed by ``rid`` (in the profile whenever one runs; in the ring
+        when this tracer is enabled). Disabled and with no profiler in the
+        process, there is neither: a shared null object."""
+        if not self.config.enabled and "jax" not in sys.modules:
             return _NULL_CTX
-        return _LiveSpanCtx(self, name, rid, attrs)
+        return _SpanCtx(name, self, rid, attrs)
 
     # ------------------------------------------------------------------
     # Access / export

@@ -2513,7 +2513,6 @@ def main():
                 "compiles": c1["count"] - c0["count"],
                 "compile_s": round(c1["secs"] - c0["secs"], 1),
                 "traces": c1["traces"] - c0["traces"],
-                "train_timing": getattr(trainer, "last_timing", None),
             }
         )
     gen_after = gen.metrics()

@@ -232,7 +232,8 @@ def test_phase_profiler(tmp_path):
     """Selected steps run under jax.profiler.trace and produce a trace
     directory; unselected steps are no-ops."""
     from areal_tpu.api.cli_args import ProfilingConfig
-    from areal_tpu.utils.profiling import PhaseProfiler, annotate
+    from areal_tpu.utils import tracing
+    from areal_tpu.utils.profiling import PhaseProfiler
 
     import jax.numpy as jnp
 
@@ -244,7 +245,7 @@ def test_phase_profiler(tmp_path):
         pass  # no-op
     assert not os.path.exists(os.path.join(prof.trace_root, "step1"))
     with prof.step(2):
-        with annotate("tiny"):
+        with tracing.span("tiny"):
             (jnp.ones(8) * 2).sum().block_until_ready()
     d = os.path.join(prof.trace_root, "step2")
     assert os.path.isdir(d)

@@ -1,0 +1,229 @@
+"""Device work keeps its names: every scope of ``tracing.DEVICE_SCOPES`` is in
+the ``op_name`` metadata of the program that computes it, every program of
+the contract (PERF.md, "Spans and counters") lowers under its name, the
+scopes change no number, and the trainer counts what it dispatches."""
+
+import contextlib
+import re
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from areal_tpu.api.cli_args import (
+    MicroBatchSpec,
+    OptimizerConfig,
+    PPOActorConfig,
+)
+from areal_tpu.api.io_struct import FinetuneSpec
+from areal_tpu.engine.ppo.actor import PPOActor, _ppo_loss_weight_fn
+from areal_tpu.engine.spmd_engine import SPMDTrainEngine
+from areal_tpu.inference import model_runner
+from areal_tpu.inference.cache import CacheConfig, init_kv_pool
+from areal_tpu.models.config import tiny_config
+from areal_tpu.utils import data as data_utils
+from areal_tpu.utils import tracing
+
+S, BS, PPS = 4, 16, 4
+
+
+def _actor(remat=True):
+    cfg = PPOActorConfig(
+        dtype="float32", param_dtype="float32",
+        gradient_checkpointing=remat,
+        mb_spec=MicroBatchSpec(max_tokens_per_mb=64),
+        optimizer=OptimizerConfig(
+            type="adamw", lr=1e-2, weight_decay=0.0,
+            warmup_steps_proportion=0.0, lr_scheduler_type="constant",
+        ),
+        ppo_n_minibatches=1, group_size=2, recompute_logprob=True,
+        use_decoupled_loss=True,
+    )
+    eng = SPMDTrainEngine(cfg)
+    eng.initialize(
+        ft_spec=FinetuneSpec(1, 64, 8), model_config=tiny_config("qwen2"),
+        seed=0,
+    )
+    return PPOActor(cfg, eng), eng
+
+
+def _batch(lens=(5, 9, 12, 7), vocab=128, seed=0):
+    rng = np.random.default_rng(seed)
+    seqs = [rng.integers(0, vocab, size=n).astype(np.int32) for n in lens]
+    batch = data_utils.pad_sequences_to_tensors(seqs)
+    lm = np.array(batch["attention_mask"], np.int32)
+    lm[:, :2] = 0
+    batch["loss_mask"] = lm
+    batch["logprobs"] = (
+        rng.standard_normal(lm.shape).astype(np.float32) * 0.1 - 4.8
+    ) * lm
+    batch["versions"] = np.where(lm > 0, 0, -1).astype(np.int32)
+    batch["rewards"] = np.asarray([0.0, 1.0] * (len(lens) // 2), np.float32)
+    return batch
+
+
+def _op_names(lowered):
+    """(HLO text, the op_name metadata of its instructions) as compiled:
+    what the profiler's trace carries per operation."""
+    text = lowered.compile().as_text()
+    return text, set(re.findall(r'op_name="([^"]+)"', text))
+
+
+def _scopes_in(names):
+    return {
+        s for s in tracing.DEVICE_SCOPES
+        if any(re.search(rf"(^|[/(]){s}([/)]|$)", n) for n in names)
+    }
+
+
+def _train_programs():
+    """(name, lowered) of the trainer's three programs at the tiny config."""
+    actor, eng = _actor()
+    batch = actor.compute_advantages(_batch())
+    batch["prox_logp"] = actor.compute_logp(batch) * batch["loss_mask"]
+    _, arrays = eng._pack_for_device(batch)
+    grad_fn = eng._get_grad_fn(actor._loss_fn, _ppo_loss_weight_fn)
+    accum = eng._zero_grads()
+    logp_fn = next(
+        f for k, f in eng._jit_cache.items()
+        if isinstance(k, tuple) and k[0] == "fwd"
+    )
+    w = jnp.ones((), jnp.float32)
+    return [
+        ("grad_step", grad_fn.lower(eng.params, accum, arrays)),
+        ("logp_forward", logp_fn.lower(eng.params, arrays)),
+        ("apply_step", eng._get_apply_fn().lower(
+            eng.params, eng.opt_state, accum, w)),
+    ]
+
+
+def _decode_programs():
+    cfg = tiny_config("qwen2")
+    from areal_tpu.models.transformer import init_params
+
+    params = init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
+    ccfg = CacheConfig(num_pages=S * PPS + 1, page_size=BS, max_model_len=64)
+    cache = init_kv_pool(cfg, ccfg, dtype=jnp.float32)
+    tables = jnp.asarray(
+        1 + np.arange(S)[:, None] * PPS + np.arange(PPS)[None], jnp.int32
+    )
+    zi, ones = jnp.zeros(S, jnp.int32), jnp.ones(S)
+    fwd = model_runner._decode_multi_forward.lower(
+        params, cfg, cache, tables, zi + 3, zi, jnp.ones(S, bool),
+        zi + 100, zi, jnp.full((S, 4), -1, jnp.int32),
+        jax.random.PRNGKey(0), ones, ones, zi, jnp.zeros(S, bool),
+        steps=3, slot_ids=jnp.arange(S, dtype=jnp.int32),
+    )
+    nl, hkv = cfg.num_layers, cache["k"].shape[1]
+    fd = cache["k"].shape[-1]
+    rows = jnp.zeros((8, nl, hkv, fd), jnp.float32)
+    write = model_runner.write_rows.lower(
+        cache, jnp.zeros(8, jnp.int32), rows, rows
+    )
+    return [("_decode_multi_forward", fwd), ("write_rows", write)]
+
+
+WANT = {
+    "grad_step": {"embed", "attention", "mlp", "lm_head", "loss"},
+    "logp_forward": {"embed", "attention", "mlp", "lm_head", "loss"},
+    "apply_step": {"optimizer"},
+    "_decode_multi_forward": {"attention", "mlp", "lm_head", "sample"},
+    "write_rows": {"kv_write"},
+}
+
+
+@pytest.fixture(scope="module")
+def lowered():
+    return {
+        name: _op_names(low)
+        for name, low in _train_programs() + _decode_programs()
+    }
+
+
+@pytest.mark.parametrize("program", sorted(WANT))
+def test_program_lowers_under_its_contract_name_with_its_scopes(
+    lowered, program
+):
+    text, names = lowered[program]
+    assert re.search(rf"^HloModule jit_{program}[, ]", text, re.M)
+    assert WANT[program] <= _scopes_in(names), sorted(names)[:40]
+
+
+def test_every_scope_of_the_vocabulary_is_computed_somewhere(lowered):
+    seen = set()
+    for _text, names in lowered.values():
+        seen |= _scopes_in(names)
+    assert seen == set(tracing.DEVICE_SCOPES)
+    from benchmark import program_trace
+
+    # the benchmark's reader takes the program's own vocabulary
+    assert program_trace.device_scopes() == tracing.DEVICE_SCOPES
+
+
+def test_backward_and_remat_keep_the_scope_on_the_path(lowered):
+    from benchmark import program_trace
+
+    _, names = lowered["grad_step"]
+    attn = [n for n in names if program_trace.scope_of(n) == "attention"]
+    assert {program_trace.direction_of(n) for n in attn} == {
+        "fwd", "remat", "bwd"
+    }
+    assert program_trace.scope_of("jit(loss)/jit(main)/mul") is None
+    assert program_trace.scope_of(
+        "jit(grad_step)/transpose(jvp(loss))/while/body/lm_head/dot"
+    ) == "lm_head"
+    assert program_trace.scope_of("jit(g)/transpose(jvp(loss))/mul") == "loss"
+
+
+def _loss_and_logp(scoped, monkeypatch):
+    if not scoped:
+        from areal_tpu.models import transformer
+        from areal_tpu.ops import chunked_head
+
+        null = lambda name: contextlib.nullcontext()  # noqa: E731
+        for mod in (tracing, transformer, chunked_head):
+            monkeypatch.setattr(mod, "device_scope", null)
+    actor, eng = _actor()
+    batch = actor.compute_advantages(_batch())
+    np.random.seed(3)
+    logp = actor.compute_logp(batch)
+    stats = actor.ppo_update(batch)
+    return logp, stats, jax.device_get(eng.params["final_norm"])
+
+
+def test_scopes_change_no_bit(monkeypatch):
+    logp_a, stats_a, norm_a = _loss_and_logp(True, monkeypatch)
+    logp_b, stats_b, norm_b = _loss_and_logp(False, monkeypatch)
+    np.testing.assert_array_equal(logp_a, logp_b)
+    assert stats_a == stats_b  # loss, grad norm, every stat: same floats
+    np.testing.assert_array_equal(norm_a, norm_b)
+
+
+def test_trainer_counts_what_it_packs_from_the_segment_ids():
+    actor, eng = _actor(remat=False)
+    assert eng.counters() == {"real_tokens": 0, "padded_tokens": 0}
+    lens = (5, 9, 12, 7)
+    batch = _batch(lens)
+    packed, _ = eng._pack_for_device(batch)
+    seg = np.asarray(packed.segment_ids)
+    assert eng.counters()["real_tokens"] == sum(lens) == np.count_nonzero(seg)
+    assert eng.counters()["padded_tokens"] == seg.size
+    # one step: the recompute and the train microbatches both pass the pack
+    from areal_tpu.utils import stats_tracker
+
+    stats_tracker.export_all()  # drop what the pack above exported
+    before = eng.counters()
+    batch = actor.compute_advantages(batch)
+    actor.ppo_update(batch)
+    after = eng.counters()
+    assert after["real_tokens"] - before["real_tokens"] == 2 * sum(lens)
+    assert eng.step_count == 1
+    step = stats_tracker.export_all()
+    assert step["spmd/real_tokens"] == 2 * sum(lens)
+    assert step["spmd/padded_tokens"] == (
+        after["padded_tokens"] - before["padded_tokens"]
+    )
+    assert not hasattr(eng, "last_timing")
+    assert not [k for k in step if k.endswith(("_dispatch_s", "apply_fetch_s"))]

@@ -640,3 +640,164 @@ class TestEngineGoodput:
             )
         finally:
             httpd.shutdown()
+
+
+# --------------------------------------------------------------------------
+# Buckets are spans (utils/tracing.span): in the profile, in the ring
+# --------------------------------------------------------------------------
+class TestBucketsAreSpans:
+    def test_bucket_is_in_a_cpu_profile_under_role_and_name(self, tmp_path):
+        from tests.test_tracing import _profile_events
+
+        led = goodput.GoodputLedger(
+            "trainer", goodput.TRAINER_BUCKETS, remainder="other"
+        )
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 2
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            with led.bucket("rollout_wait"):
+                time.sleep(0.002)
+            with led.bucket("fwd_bwd") as outer:
+                with led.bucket("optim") as nested:  # reentrant: a no-op
+                    from areal_tpu.utils import tracing
+
+                    with tracing.span("train.grad_dispatch", step=7):
+                        time.sleep(0.002)
+        finally:
+            jax.profiler.stop_trace()
+        found = _profile_events(str(tmp_path))
+        # (an engine of an earlier test may still be idling in its own
+        # thread: its areal:engine.* spans are in the same profile)
+        assert {n for n in found if n.startswith("areal:train")} == {
+            "areal:trainer.rollout_wait", "areal:trainer.fwd_bwd",
+            "areal:train.grad_dispatch",
+        }
+        assert found["areal:train.grad_dispatch"] == [{"step": 7}]
+        assert nested.duration == 0.0 < outer.duration
+        assert led.seconds()["fwd_bwd"] == pytest.approx(
+            outer.duration, abs=2e-3
+        )
+
+    def test_bucket_is_the_parent_of_the_spans_inside_it_never_in_a_ring(self):
+        from areal_tpu.api.cli_args import TracingConfig
+        from areal_tpu.utils.tracing import SpanTracer, span
+
+        tr = SpanTracer(TracingConfig(enabled=True))
+        led = goodput.GoodputLedger(
+            "engine", goodput.ENGINE_BUCKETS, remainder="idle"
+        )
+        with led.bucket("weight_pause"):  # an idle or paused poll: 1 kHz
+            pass
+        with led.bucket("decode") as b:
+            with span("decode.fetch", tr, "__engine__", seq=3):
+                pass
+        spans = tr.snapshot()
+        assert [s.name for s in spans] == ["decode.fetch"]
+        # the bucket's span is in a profile only; in the ring its id reads
+        # as a root's parent
+        assert spans[0].parent == b._span.sid != 0
+
+    def test_a_poll_stretch_is_one_span_until_other_work_enters(
+        self, tmp_path
+    ):
+        """An idle or paused loop sleeps a millisecond at a time: the
+        seconds are booked per poll, the span is one per quiet stretch."""
+        from areal_tpu.utils import tracing
+        from tests.test_tracing import _profile_events
+
+        led = goodput.GoodputLedger(
+            "poller", ("idle", "weight_pause", "decode", "other"),
+            remainder="other",
+        )
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 2
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            polls = []
+            for _ in range(5):
+                with led.bucket("idle", poll=True) as b:
+                    time.sleep(0.001)
+                polls.append(b.duration)
+            held = led._tls.held
+            assert held is not None and tracing._TLS.stack == [held.sid]
+            with led.bucket("decode"):  # work arrives: the stretch ends
+                assert led._tls.held is None
+            for _ in range(3):  # a pause: the command poll and the sleep
+                with led.bucket("weight_pause"):
+                    pass
+                with led.bucket("weight_pause", poll=True):
+                    time.sleep(0.001)
+            with led.bucket("idle", poll=True) as b:  # another name ends it
+                pass
+            polls.append(b.duration)
+            led.end_poll()
+            led.end_poll()  # nothing open: a no-op
+        finally:
+            jax.profiler.stop_trace()
+        assert led._tls.held is None and tracing._TLS.stack == []
+        found = _profile_events(str(tmp_path), prefix="areal:poller.")
+        assert {k: len(v) for k, v in found.items()} == {
+            "areal:poller.idle": 2, "areal:poller.decode": 1,
+            # one command poll before the first sleep, then one stretch
+            "areal:poller.weight_pause": 2,
+        }
+        # seconds are still booked per poll
+        assert all(d >= 0.001 for d in polls[:5])
+        assert led.seconds()["idle"] == pytest.approx(sum(polls), abs=1e-4)
+
+    def test_loop_seconds_add_up_to_the_ledgers_non_idle_wall(self):
+        from areal_tpu.api.cli_args import JaxGenConfig
+        from areal_tpu.inference.engine import GenerationEngine
+        from areal_tpu.models.config import tiny_config
+        from areal_tpu.models.transformer import init_params
+
+        cfg = tiny_config("qwen2")
+        params = init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
+        gcfg = JaxGenConfig(
+            dtype="float32", max_num_seqs=4, max_model_len=64,
+            prefill_chunk=16, decode_chunk=4,
+        )
+        gcfg.tracing.enabled = True
+        eng = GenerationEngine(gcfg, model_config=cfg, params=params).start()
+        try:
+            for i in range(3):
+                eng.generate({
+                    "rid": f"loop-{i}", "input_ids": [1, 2, 3, 4 + i],
+                    "sampling_params": {"max_new_tokens": 9},
+                })
+            m = eng.metrics()
+            secs = eng.ledger.seconds()
+        finally:
+            eng.stop()
+        loop = sum(
+            m[f"total_loop_{k}_s"] for k in
+            ("admit", "decode_dispatch", "decode_fetch", "decode_process")
+        )
+        non_idle = sum(v for b, v in secs.items() if b != "idle")
+        assert m["total_loop_iterations"] > 0 and m["total_loop_idle_s"] >= 0
+        assert loop == pytest.approx(non_idle, rel=0.05)
+        # the same intervals are spans of the loop thread, chunk-stamped
+        names = {s.name for s in eng.tracer.snapshot()}
+        assert {
+            "admit.schedule",
+            "admit.prefill_dispatch", "admit.first_token_fetch",
+            "admit.install", "decode.ensure_pages", "decode.dispatch",
+            "decode.fetch", "decode.process",
+        } <= names
+        fetch = [s for s in eng.tracer.snapshot() if s.name == "decode.fetch"]
+        assert all(s.rid == "__engine__" and "seq" in s.attrs for s in fetch)
+        assert all(s.parent for s in fetch)
+        # one fetch and one process per chunk, whoever retired it (the
+        # decode path or a drain): every chunk is counted once
+        # (but for one still in flight when the engine stopped)
+        seqs = sorted(s.attrs["seq"] for s in fetch)
+        assert seqs == sorted(set(seqs))
+        assert 0 <= m["total_decode_chunks"] - len(seqs) <= gcfg.decode_pipeline
+        process = [
+            s.attrs["seq"] for s in eng.tracer.snapshot()
+            if s.name == "decode.process"
+        ]
+        assert sorted(process) == seqs

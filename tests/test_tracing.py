@@ -49,13 +49,13 @@ class TestSpanTracer:
     def test_disabled_is_noop(self):
         t = SpanTracer(TracingConfig(enabled=False))
         assert not t.enabled
-        # span() hands back ONE shared null object — the hot-loop guard:
-        # no generator, no Span, no dict is allocated per call
-        cm1 = t.span("decode", "r1", tokens=1)
-        cm2 = t.span("decode", "r2", tokens=2)
-        assert cm1 is cm2 is tracing._NULL_CTX
-        with cm1:
+        # span() is the module's one primitive: in this process (jax is
+        # loaded, a profile may run) it still times its body, callers count
+        # host seconds from it, but the ring stays untouched. Without jax
+        # it is the shared null object: TestWithoutJax below
+        with t.span("decode", "r1", tokens=1) as cm1:
             pass
+        assert cm1.duration >= 0.0
         t.record("x", "r", 0.0, 1.0)
         t.instant("y", "r")
         assert len(t) == 0
@@ -271,3 +271,261 @@ class TestTraceReport:
         empty = str(tmp_path / "bare.jsonl")
         bare.export_jsonl(empty)
         assert trace_report.main([empty, "--occupancy"]) == 1
+
+
+# --------------------------------------------------------------------------
+# The one span primitive: tree, threads, profile
+# --------------------------------------------------------------------------
+def _profile_events(trace_dir, prefix="areal:"):
+    """{event name: [stats dict, ...]} of the host spans in a CPU profile."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    path = max(
+        glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
+                               "*.xplane.pb")),
+        key=os.path.getmtime,
+    )
+    found = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith(prefix):
+                    found.setdefault(ev.name, []).append(dict(ev.stats))
+    return found
+
+
+class TestSpanPrimitive:
+    def test_nesting_records_parents_and_children_fit_inside(self):
+        t = _enabled_tracer()
+        with tracing.span("outer", t, "row", step=1) as outer:
+            with tracing.span("a", t, "row"):
+                time.sleep(0.01)
+            with tracing.span("b", t, "row") as b:
+                with tracing.span("leaf", t, "row") as leaf:
+                    leaf.set(real_tokens=5)
+                    time.sleep(0.01)
+        by = {s.name: s for s in t.snapshot()}
+        assert by["outer"].parent == 0 and by["outer"].sid == outer.sid
+        assert by["a"].parent == by["b"].parent == outer.sid
+        assert by["leaf"].parent == b.sid
+        assert by["leaf"].attrs == {"real_tokens": 5}
+        assert by["outer"].attrs == {"step": 1}
+        # self time (duration minus children) is never negative: children
+        # run one after the other inside their parent
+        assert by["a"].duration + by["b"].duration <= by["outer"].duration
+        assert by["leaf"].duration <= by["b"].duration
+        assert by["a"].t_end <= by["b"].t_start
+        d = by["leaf"].to_dict()
+        assert d["id"] == leaf.sid and d["parent"] == b.sid
+        # after the fact (record): outside any tree
+        t.record("old", "row", 0.0, 1.0)
+        assert t.snapshot()[-1].sid == 0
+        assert "id" not in t.snapshot()[-1].to_dict()
+
+    def test_each_thread_has_its_own_stack(self):
+        t = _enabled_tracer()
+        started, release = threading.Event(), threading.Event()
+
+        def worker():
+            with tracing.span("w.outer", t):
+                started.set()
+                release.wait(5)
+                with tracing.span("w.inner", t):
+                    pass
+
+        th = threading.Thread(target=worker, name="worker-7")
+        with tracing.span("main.outer", t):
+            th.start()
+            started.wait(5)
+            with tracing.span("main.inner", t):
+                release.set()
+                th.join()
+        by = {s.name: s for s in t.snapshot()}
+        assert by["w.outer"].parent == 0 and by["main.outer"].parent == 0
+        assert by["w.inner"].parent == by["w.outer"].sid
+        assert by["main.inner"].parent == by["main.outer"].sid
+        # no rid given: one row per thread
+        assert by["w.inner"].rid == "worker-7"
+        assert by["main.inner"].rid == threading.current_thread().name
+
+    @pytest.mark.parametrize("tracer", [None, "disabled"])
+    def test_ring_untouched_without_an_enabled_tracer(self, tracer):
+        t = SpanTracer(TracingConfig(enabled=False))
+        with tracing.span("x", t if tracer else None, n=1) as sp:
+            with tracing.span("p1", t if tracer else None):
+                pass
+        assert len(t) == 0 and sp.duration >= 0.0
+        assert tracing._TLS.stack == []  # every span closed
+
+    def test_phases_are_consecutive_siblings_closed_on_return(self):
+        """The engine's admission is one long linear function: its
+        stretches are sibling spans, each closed by the next."""
+        from areal_tpu.inference.engine import _Phases
+
+        t = _enabled_tracer()
+
+        class Loop:  # what _Phases needs of an engine
+            def _loop_span(self, name):
+                return tracing.span(name, t, "loop", seq=4)
+
+        def admit(stop_early):
+            with _Phases(Loop()) as ph:
+                ph.enter("admit.schedule")
+                if stop_early:
+                    return False
+                ph.enter("admit.prefill_dispatch")
+                ph.enter("admit.install")
+            return True
+
+        with tracing.span("engine.prefill", t, "loop") as outer:
+            assert admit(True) is False
+            assert admit(False) is True
+        spans = t.snapshot()
+        assert [s.name for s in spans] == [
+            "admit.schedule", "admit.schedule", "admit.prefill_dispatch",
+            "admit.install", "engine.prefill",
+        ]
+        assert all(s.parent == outer.sid for s in spans[:4])
+        assert all(s.attrs == {"seq": 4} for s in spans[:4])
+        for a, b in zip(spans[1:3], spans[2:4]):
+            assert a.t_end <= b.t_start
+        assert tracing._TLS.stack == []
+
+    def test_chrome_trace_nests_a_threads_spans_on_one_row(self):
+        t = _enabled_tracer()
+        with tracing.span("outer", t):
+            with tracing.span("inner", t, k=2):
+                pass
+        ev = [e for e in t.to_chrome_trace()["traceEvents"] if e["ph"] == "X"]
+        assert {e["name"] for e in ev} == {"outer", "inner"}
+        assert len({e["tid"] for e in ev}) == 1
+        inner = next(e for e in ev if e["name"] == "inner")
+        assert inner["args"]["k"] == 2
+
+    def test_device_scope_takes_the_vocabulary_only(self):
+        import jax
+        import jax.numpy as jnp
+
+        with pytest.raises(ValueError):
+            tracing.device_scope("fusion.530")
+
+        @tracing.device_scope("mlp")
+        def f(x):
+            with tracing.device_scope("attention"):
+                y = x * 2
+            return y + 1
+
+        assert f.__name__ == "f"
+        lowered = jax.jit(f).lower(jnp.ones(4))
+        text = lowered.as_text(debug_info=True)
+        assert "mlp/attention/mul" in text and "mlp/add" in text
+        # and in the program itself, not only its debug metadata: the
+        # persistent compile cache keys on the text without locations
+        bare = lowered.as_text()
+        assert 'areal_scope = "attention"' in bare
+        assert 'areal_scope = "mlp"' in bare and "loc(" not in bare
+
+    def test_spans_are_in_a_cpu_profile_with_their_attrs_as_stats(
+        self, tmp_path
+    ):
+        import jax
+
+        t = SpanTracer(TracingConfig(enabled=False))  # no switch of its own
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 2
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            with tracing.span("train.pack", step=3, pad_to=256) as sp:
+                sp.set(real_tokens=5, padded_tokens=8)
+                with t.span("decode", "rid-1", tokens=7):
+                    time.sleep(0.002)
+        finally:
+            jax.profiler.stop_trace()
+        found = _profile_events(str(tmp_path))
+        assert found["areal:train.pack"] == [
+            {"step": 3, "pad_to": 256, "real_tokens": 5, "padded_tokens": 8}
+        ]
+        # a request-keyed span goes through the same primitive
+        assert found["areal:decode"] == [{"tokens": 7}]
+        assert len(t) == 0
+
+
+# --------------------------------------------------------------------------
+# A process that has not loaded jax (an env worker, the router)
+# --------------------------------------------------------------------------
+_WITHOUT_JAX = r"""
+import asyncio, json, sys
+from areal_tpu.api.cli_args import EnvServiceConfig, TracingConfig
+from areal_tpu.env import service as ES
+from areal_tpu.utils import tracing
+from areal_tpu.utils.tracing import SpanTracer
+
+off = SpanTracer(TracingConfig(enabled=False))
+# disabled and no profiler in the process: ONE shared null object, nothing
+# allocated per call
+assert off.span("a", "r1") is off.span("b", "r2", k=1) is tracing._NULL_CTX
+with off.span("a", "r1") as sp:
+    sp.set(k=2)
+assert sp.duration == 0.0 and len(off) == 0 and tracing._TLS.stack == []
+# the module's span still times its body (a ledger counts seconds from it)
+with tracing.span("x", off, n=1) as sp:
+    pass
+assert sp.duration >= 0.0 and len(off) == 0
+
+on = SpanTracer(TracingConfig(enabled=True))
+httpd = ES.serve_env(ES.countdown_env, background=True, tracer=on)
+addr = "127.0.0.1:%d" % httpd.server_address[1]
+
+async def run():
+    env = ES.RemoteEnv(addrs=[addr], config=EnvServiceConfig(
+        call_retries=2, call_timeout_s=10.0, reset_timeout_s=10.0))
+    await env.areset(numbers=[3, 5, 2], target=21)
+    o, r, d, _ = await env.astep({
+        "name": "eval_expression",
+        "arguments": json.dumps({"expression": "3*7"}),
+    })
+    assert o == "21"
+    await env.aclose()
+
+asyncio.run(run())
+httpd.shutdown()
+names = [s.name for s in on.snapshot()]
+assert "env_reset" in names and "env_step" in names, names
+assert all(s.sid for s in on.snapshot() if s.name == "env_step")
+assert "jax" not in sys.modules, "a span imported jax"
+print("WITHOUT_JAX_OK")
+"""
+
+
+class TestWithoutJax:
+    def test_env_worker_serves_a_request_without_importing_jax(self):
+        import subprocess
+
+        root = os.path.join(os.path.dirname(__file__), "..")
+        env = dict(os.environ, PYTHONPATH=root)
+        out = subprocess.run(
+            [sys.executable, "-c", _WITHOUT_JAX], env=env, cwd=root,
+            capture_output=True, text=True, timeout=120,
+        )
+        assert out.returncode == 0, out.stderr[-2000:]
+        assert "WITHOUT_JAX_OK" in out.stdout
+
+    def test_a_span_opened_before_jax_loads_closes_cleanly(self, monkeypatch):
+        # the annotation is decided at entry: a span entered while jax was
+        # absent has none to close, whatever was imported since
+        monkeypatch.setattr(tracing, "_ANNOTATION", None)
+        monkeypatch.delitem(sys.modules, "jax")
+        t = _enabled_tracer()
+        sp = tracing.span("x", t, "r", k=1)
+        sp.__enter__()
+        assert sp._ann is None
+        monkeypatch.undo()  # jax is back in sys.modules
+        sp.set(n=2)
+        sp.__exit__(None, None, None)
+        assert t.snapshot()[-1].attrs == {"k": 1, "n": 2}
+        with tracing.span("y", t, "r") as sp2:
+            pass
+        assert sp2._ann is not None
