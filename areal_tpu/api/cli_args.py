@@ -120,11 +120,17 @@ class TrainEngineConfig:
     disable_dropout: bool = True
     gradient_checkpointing: bool = True
     # with remat on, SAVE each layer's attention output instead of
-    # recomputing the flash kernel in the backward (~14ms/layer at 24k for
-    # [B,T,Hq,D] bf16 of HBM); disable for memory-tight shapes
+    # recomputing the attention forward in the backward ([B,T,Hq,D] bf16 of
+    # HBM a layer); disable for memory-tight shapes
     remat_save_attn: bool = True
-    # attention kernel when seq_parallel_size > 1: "auto" lets GSPMD shard
-    # the XLA kernel; "ring"/"ulysses" use the explicit shard_map kernels
+    # the attention core. "auto" is resolved once at initialize() from what
+    # the process observes (SPMDTrainEngine.attn_impl holds the result): on
+    # a TPU with seq_parallel_size == 1 and a head dimension the kernel
+    # takes, the fused Pallas splash kernel (one chip: the kernel itself; a
+    # mesh: under shard_map, rows and heads split); on the CPU backend, or
+    # with seq_parallel_size > 1, the XLA form, which GSPMD shards. "flash"
+    # names the kernel outright; "ring"/"ulysses" are the explicit
+    # sequence-parallel shard_map kernels (the XLA form with nothing to split)
     attn_impl: str = "auto"
     # lazy chunked LM head: loss paths never materialize [T, vocab] logits
     # (the largest train activation — 3.2 GB for one 24k row at 32k vocab);

@@ -130,8 +130,11 @@ class SPMDTrainEngine(TrainEngine):
         self.compute_dtype = _DTYPES[config.dtype]
         self.param_dtype = _DTYPES[config.param_dtype]
         self._jit_cache: Dict[Any, Callable] = {}
-        # splash block edge probed at initialize() when attn_impl="flash"
-        # runs on a TPU; None = the splash kernel is not in use
+        # the attention core in use: config.attn_impl with "auto" resolved at
+        # initialize() against the backend, the mesh and the model ("flash",
+        # "ring", "ulysses" or "xla"), and the largest splash block edge the
+        # backend ran when that is "flash" on a TPU (None: kernel not in use)
+        self.attn_impl: Optional[str] = None
         self.splash_block: Optional[int] = None
         self.initialized = False
 
@@ -196,20 +199,47 @@ class SPMDTrainEngine(TrainEngine):
                 cfg.optimizer, self.lr_schedule
             )
             self.opt_state = self._init_opt_state()
-        if cfg.attn_impl == "flash" and jax.default_backend() != "cpu":
-            # probe the splash block edge once per process so the fast
-            # long-context path is the default, not an env-var opt-in
-            # (round-3 driver capture silently lost 5x on the opt-in)
+        self.attn_impl = self._resolve_attn_impl()
+        if self.attn_impl == "flash" and jax.default_backend() != "cpu":
+            # what the scoped-VMEM limit of this process allows, once
             from areal_tpu.ops import flash as flash_ops
 
             self.splash_block = flash_ops.probe_block_size()
         n = count_params(self.params)
         logger.info(
             f"initialized {mc.family} model: {n/1e6:.1f}M params on mesh "
-            f"{dict(zip(self.mesh.axis_names, self.mesh.devices.shape))}"
+            f"{dict(zip(self.mesh.axis_names, self.mesh.devices.shape))}; "
+            f"attention: attn_impl={cfg.attn_impl!r} resolved to "
+            f"{self.attn_impl!r} on {jax.default_backend()}"
+            + (
+                f", splash block edge <= {self.splash_block}"
+                if self.splash_block else ""
+            )
         )
         self.initialized = True
         return self
+
+    def _resolve_attn_impl(self) -> str:
+        """``config.attn_impl`` against what this process can observe (the
+        way ``GenerationEngine`` resolves its own ``auto``). On a TPU
+        without sequence parallelism ``auto`` is the fused splash kernel
+        wherever it takes the model's head dimension: the XLA form writes
+        float32 scores [heads, T, T] per layer and pass. Elsewhere ``auto``
+        is that XLA form, which GSPMD shards; so is an explicit SP kernel
+        with nothing to split."""
+        cfg = self.config
+        no_sp = cfg.parallel.seq_parallel_size == 1
+        if cfg.attn_impl == "auto":
+            from areal_tpu.ops import flash as flash_ops
+
+            fused = (
+                jax.default_backend() == "tpu" and no_sp
+                and flash_ops.takes_head_dim(self.model_config.head_dim)
+            )
+            return "flash" if fused else "xla"
+        if cfg.attn_impl in ("ring", "ulysses") and no_sp:
+            return "xla"
+        return cfg.attn_impl
 
     def rebuild_optimizer(
         self, opt_config, total_steps: int = 10000
@@ -393,17 +423,22 @@ class SPMDTrainEngine(TrainEngine):
     # Train
     # ------------------------------------------------------------------
     def _flash_window(self, input_: Batch) -> int:
-        """Pow2-bucketed max sequence length: the splash kernel's
-        block-sparse local window (full causal over a long packed stream is
-        T² block iteration; sequences only need their own length)."""
-        if self.config.attn_impl != "flash":
+        """The splash kernel's block-sparse local window: the longest
+        sequence, in power-of-two buckets from 1024 (full causal over a long
+        packed stream of short sequences is T² block iteration; sequences
+        only need their own length). 0 = full causal, which is also what a
+        window above a quarter of a whole microbatch gets: every (pad
+        bucket, window) pair is one more grad program to compile and to
+        load, a window saves only whole tiles, and at half a 4096-token row
+        that was 7% of the core's time against 22% at a quarter (v5e,
+        PERF.md PR 26)."""
+        if self.attn_impl != "flash":
             return 0
-        lens = np.asarray(input_["attention_mask"]).sum(1)
-        m = max(1, int(lens.max()))
-        w = 256
-        while w < m:
+        longest = int(np.asarray(input_["attention_mask"]).sum(1).max())
+        w = 1024
+        while w < longest:
             w *= 2
-        return w
+        return w if 4 * w <= self.config.mb_spec.max_tokens_per_mb else 0
 
     def _act_sharding(self):
         """[B, T, D] activation constraint: rows over (data, fsdp), tokens
@@ -425,7 +460,7 @@ class SPMDTrainEngine(TrainEngine):
         """Attention kernel override: "flash" (Pallas splash, TPU-only),
         "ring"/"ulysses" (explicit SP shard_map), or None for the XLA kernel
         with GSPMD auto-sharding."""
-        impl = self.config.attn_impl
+        impl = self.attn_impl
         if impl == "flash":
             from areal_tpu.ops.flash import flash_segment_attention
 
@@ -449,7 +484,7 @@ class SPMDTrainEngine(TrainEngine):
                 out_specs=qkv_spec,
                 check_vma=False,
             )
-        if impl == "auto" or self.config.parallel.seq_parallel_size == 1:
+        if impl == "xla":
             return None
         if not hasattr(self, "_cached_attend"):
             from areal_tpu.ops.ring_attention import make_sharded_attention
@@ -580,7 +615,8 @@ class SPMDTrainEngine(TrainEngine):
             with goodput.trainer_bucket("data_h2d"):
                 _, arrays = self._pack_for_device(mb, pad_to=pad_to)
             with goodput.trainer_bucket("fwd_bwd"), tracing.span(
-                "train.grad_dispatch", step=step
+                "train.grad_dispatch", step=step,
+                attn_impl=self.attn_impl, window=window,
             ), goodput.dispatch_scope(
                 goodput.trainer_tracker(), "fwd_bwd", gp_sig
             ):
@@ -744,7 +780,10 @@ class SPMDTrainEngine(TrainEngine):
         outs = []
         for mb in mbs.mbs:
             packed, arrays = self._pack_for_device(mb, pad_to=pad_to)
-            with tracing.span("train.logp_dispatch", step=step):
+            with tracing.span(
+                "train.logp_dispatch", step=step,
+                attn_impl=self.attn_impl, window=window,
+            ):
                 vals = self._jit_cache[key](self.params, arrays)
             with tracing.span("train.logp_fetch", step=step):
                 vals = np.asarray(vals)  # blocks on the device here

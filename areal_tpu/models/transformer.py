@@ -215,8 +215,9 @@ def _layer_body(
         else:  # explicit SP kernel (ring / ulysses shard_map)
             attn = attend_fn(q, k, v, segment_ids)
     # named so a remat policy can SAVE attention outputs: recomputing the
-    # flash forward inside the backward costs ~14ms/layer at 24k (measured,
-    # tools/microbench_attn_v2.py) for [B,T,Hq,D] bf16 of storage
+    # forward inside the backward costs 0.6 ms a layer with the splash
+    # kernel and 3.8 ms with the XLA form at 4096 tokens (v5e, PERF.md
+    # PR 26), against [B,T,Hq,D] bf16 of storage
     attn = _ad_checkpoint.checkpoint_name(attn, "attn_out")
     x = x + attn.reshape(b, t, cfg.q_dim) @ lp["wo"]
     h = rms_norm(

@@ -9,25 +9,32 @@ segment-id support that matches our packed layout exactly.
 
 This wrapper adapts splash's [H, T, D] MQA-grouped convention to the
 framework's [B, T, H, D] packed-stream convention and masks padding
-(segment id 0) on the way out. TPU-only: callers gate on backend (the
-engine's attn_impl="flash" config) — CPU tests use the XLA kernel.
+(segment id 0) on the way out. It is the trainer's attention core on a TPU
+(``SPMDTrainEngine`` resolves ``attn_impl="auto"`` to it there); the XLA
+``segment_attention`` is the plain form it is held equal to, the CPU's path,
+and what a row shorter than one tile takes. The kernel keeps float32 scores,
+softmax and accumulation, as the XLA form does. On the CPU it runs only in
+Pallas interpret mode (``interpret=True``: ``tests/test_flash_parity.py``).
 """
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
 
+from areal_tpu.ops.basic import segment_attention
 from jax.experimental.pallas.ops.tpu.splash_attention import (
     splash_attention_kernel as _sk,
     splash_attention_mask as _sm,
 )
 
 
-# Probed splash block edge: None = not probed yet, 0 = kernel defaults (CPU
-# backend: the kernel never runs there), else the largest edge that compiled
-# AND ran on this process's TPU backend.
+# what a block edge must be a multiple of, and a row a whole number of
+_LANES = 128
+
+# The largest block edge this process's TPU backend compiled AND ran
+# (``probe_block_size``): None = not probed, 0 = nothing to cap (the CPU
+# backend, where the kernel runs only in Pallas interpret mode).
 _PROBED_BLOCK: "int | None" = None
 
 # below this edge the per-grid-step overhead dominates long-context
@@ -35,27 +42,33 @@ _PROBED_BLOCK: "int | None" = None
 _MIN_PROBED_EDGE = 512
 
 
+def _tiles(edge: int) -> "_sk.BlockSizes":
+    """One edge for every tile of forward and backward; what else
+    ``_block_sizes`` found best at every shape it measured."""
+    kvc = min(512, edge)
+    return _sk.BlockSizes(
+        block_q=edge, block_kv=edge, block_kv_compute=kvc,
+        block_q_dkv=edge, block_kv_dkv=edge, block_kv_dkv_compute=kvc,
+        use_fused_bwd_kernel=True,
+    )
+
+
 def probe_block_size(max_block: int = 2048, probe_t: int = 2048) -> int:
     """Find the largest splash block edge this backend can actually run.
 
-    Per-grid-step overhead dominates this stack's pallas kernels, so at long
-    contexts the kernel's small default blocks cost several times the large
-    ones — but the large ones need the scoped-VMEM limit raised
+    The large edges need the scoped-VMEM limit raised
     (LIBTPU_INIT_ARGS=--xla_tpu_scoped_vmem_limit_kib=65536, appended by
     ``areal_tpu/__init__`` when it runs before jax backend init; without it
-    the 2048 edge is refused and 1024 compiles). The choice is PROBED:
-    compile+run a small fwd+bwd at each candidate edge and keep the largest
-    that works. On a TPU, if no edge >= 512 compiles the probe RAISES with
-    the compiler's message — an entry point that lost the flag must not go
-    on to train on the kernel's defaults. Result is cached process-wide;
-    call once from engine init (TPU backends only — never inside a trace).
+    the 2048 edge is refused and 1024 compiles). What the limit allows is
+    PROBED: compile+run a small fwd+bwd at each candidate edge and keep the
+    largest that works; ``_block_sizes`` never chooses above it. On a TPU,
+    if no edge >= 512 compiles the probe RAISES with the compiler's message
+    — an entry point that lost the flag must not go on to train on tiles a
+    quarter the size. Result is cached process-wide; call once from engine
+    init (TPU backends only — never inside a trace).
     """
     global _PROBED_BLOCK
     if _PROBED_BLOCK is not None:
-        return _PROBED_BLOCK
-    override = os.environ.get("AREAL_TPU_SPLASH_BLOCK", "")
-    if override:
-        _PROBED_BLOCK = int(override)
         return _PROBED_BLOCK
     if jax.default_backend() == "cpu":
         _PROBED_BLOCK = 0
@@ -63,19 +76,22 @@ def probe_block_size(max_block: int = 2048, probe_t: int = 2048) -> int:
     import logging
 
     log = logging.getLogger("areal_tpu.flash")
-    q = jnp.ones((1, probe_t, 4, 128), jnp.bfloat16)
-    k = jnp.ones((1, probe_t, 1, 128), jnp.bfloat16)
-    seg = jnp.ones((1, probe_t), jnp.int32)
+    q = jnp.ones((4, probe_t, 128), jnp.bfloat16)
+    k = jnp.ones((probe_t, 128), jnp.bfloat16)
+    mask = _sm.MultiHeadMask([_sm.CausalMask((probe_t, probe_t))] * 4)
     b = max_block
     refused = None
     while b >= _MIN_PROBED_EDGE:
-        _PROBED_BLOCK = b
         try:
+            kernel = _sk.make_splash_mqa_single_device(
+                mask, block_sizes=_tiles(b)
+            )
             out = jax.grad(
-                lambda q_: flash_segment_attention(q_, k, k, seg).sum()
+                lambda q_: kernel(q_, k, k).astype(jnp.float32).sum()
             )(q)
             jax.block_until_ready(out)
             log.info("splash block edge probed: %d", b)
+            _PROBED_BLOCK = b
             return b
         except Exception as e:  # noqa: BLE001 — mosaic raises various types
             log.warning(
@@ -83,8 +99,6 @@ def probe_block_size(max_block: int = 2048, probe_t: int = 2048) -> int:
                 b, type(e).__name__, str(e)[:200],
             )
             refused = e
-            _PROBED_BLOCK = None
-            _make_kernel.cache_clear()
             b //= 2
     raise RuntimeError(
         f"no splash block edge in [{_MIN_PROBED_EDGE}, {max_block}] "
@@ -94,20 +108,42 @@ def probe_block_size(max_block: int = 2048, probe_t: int = 2048) -> int:
     ) from refused
 
 
-def _block_size(t: int) -> int:
-    """Largest probed-safe block edge that divides the sequence length
-    (>=128, else 0 = kernel defaults)."""
-    want = _PROBED_BLOCK or 0
-    if want <= 0:
-        return 0
-    b = 1
-    while b * 2 <= min(want, t) and t % (b * 2) == 0:
-        b *= 2
-    return b if b >= 128 else 0
+def takes_head_dim(d: int) -> bool:
+    """Head dimensions the trainer hands to the kernel: multiples of 64.
+    64 (half a lane row) and 128 have trained on the v5e (Qwen2-0.5B and
+    -1.5B, PERF.md PR 22 and PR 26); the installed Mosaic compiles others
+    too, but none has ever run on a chip, so they stay on the XLA form
+    until one has."""
+    return d > 0 and d % 64 == 0
+
+
+def _block_sizes(t: int, window: int, d: int) -> "_sk.BlockSizes":
+    """The kernel's tiles, from the shape it is given. Measured on the v5e
+    with ``tools/microbench_attn_v2.py`` on the trainer's packed rows, 14/2
+    heads of 64 and 12/2 of 128 (PERF.md, PR 26, has the table):
+
+    - edge 1024 at T = 4096 (forward + backward 1.86 ms a layer; 512: 2.05,
+      2048: 1.92 — causal skips 6 of 16 tiles at 1024 and 1 of 4 at 2048,
+      and 512 pays per-step overhead), edge 2048 at T = 16384 (22.7 ms
+      against 25.2 at 1024); 8192 was not measured and takes 2048;
+    - a local window skips only whole tiles, so a window of at most 1024
+      takes edge 512 (1.39 against 1.44 ms at T = 4096);
+    - ``block_kv_compute`` 512 rather than the edge (the smaller compute
+      tile overlaps the kv DMA; 256 loses), and the FUSED dq+dkv backward:
+      one pass over the data, 10-25% under the split kernels everywhere.
+
+    The head dimension moved none of these choices. Never above what
+    ``probe_block_size`` found to run, and always a divisor of ``t``."""
+    want = 512 if 0 < window <= 1024 else 2048 if t >= 8192 else 1024
+    want = min(want, _PROBED_BLOCK or want)
+    edge = _LANES
+    while edge * 2 <= min(want, t) and t % (edge * 2) == 0:
+        edge *= 2
+    return _tiles(edge)
 
 
 @functools.lru_cache(maxsize=32)
-def _make_kernel(t: int, rep: int, window: int):
+def _make_kernel(t: int, rep: int, window: int, d: int, interpret: bool):
     # ensure_compile_time_eval: this may be reached inside a jit trace, but
     # the kernel object (and the mask arrays it processes) must be concrete —
     # it is cached across traces, and a tracer captured here would escape.
@@ -122,30 +158,12 @@ def _make_kernel(t: int, rep: int, window: int):
         else:
             head = _sm.CausalMask((t, t))
         mask = _sm.MultiHeadMask([head for _ in range(rep)])
-        b = _block_size(t)
-        if b:
-            # recipe from tools/microbench_attn_v2.py (not re-measured on
-            # the current machine):
-            # - block_kv_compute 512 rather than the full edge: smaller
-            #   inner compute tiles overlap the kv DMA
-            # - the FUSED dq+dkv backward kernel at 2048-edge blocks: one
-            #   data pass instead of two (bwd matmuls contract over T, so
-            #   they do not pay the head_dim-64 MXU lane tax the forward
-            #   does)
-            bs = _sk.BlockSizes(
-                block_q=b, block_kv=b, block_kv_compute=min(512, b),
-                block_q_dkv=b, block_kv_dkv=b,
-                block_kv_dkv_compute=min(512, b),
-                use_fused_bwd_kernel=True,
-            )
-            # residual_checkpoint_name marks out+logsumexp so a remat
-            # policy saving "attn_out" skips the forward-kernel recompute
-            # in the backward (models/transformer.apply remat_save_attn)
-            return _sk.make_splash_mqa_single_device(
-                mask, block_sizes=bs, residual_checkpoint_name="attn_out"
-            )
+        # residual_checkpoint_name marks out+logsumexp so a remat policy
+        # saving "attn_out" skips the forward-kernel recompute in the
+        # backward (models/transformer.apply remat_save_attn)
         return _sk.make_splash_mqa_single_device(
-            mask, residual_checkpoint_name="attn_out"
+            mask, block_sizes=_block_sizes(t, window, d),
+            residual_checkpoint_name="attn_out", interpret=interpret,
         )
 
 
@@ -156,13 +174,19 @@ def flash_segment_attention(
     segment_ids: jnp.ndarray,  # [B, T]
     causal: bool = True,
     window: int = 0,  # 0 = full causal; else >= max segment length
+    interpret: bool = False,  # Pallas interpret mode: the CPU's tests
 ) -> jnp.ndarray:
     """Drop-in replacement for ops.basic.segment_attention on TPU."""
     assert causal, "splash path is causal-only (decoder models)"
     b, t, hq, d = q.shape
+    if t % _LANES:
+        # the kernel's smallest tile is 128 tokens: a row that is not a
+        # whole number of them (the trainer packs to multiples of 256) is
+        # short, and its quadratic scores are small
+        return segment_attention(q, k, v, segment_ids, causal=True)
     hkv = k.shape[2]
     rep = hq // hkv
-    kernel = _make_kernel(t, rep, int(window))
+    kernel = _make_kernel(t, rep, int(window), d, interpret)
     scale = d**-0.5
     qg = (q.astype(jnp.float32) * scale).astype(q.dtype)
     qg = qg.transpose(0, 2, 1, 3).reshape(b, hkv, rep, t, d)

@@ -13,7 +13,8 @@ Phases at Qwen2-0.5B, published width (vocab 151936), random weights from
 
 - ``grpo``    3 steps of ``examples/gsm8k_grpo.main`` colocated (trainer and
               generation engine share the chip), bf16 compute / f32 params,
-              Adam, ``actor.attn_impl=flash``, server defaults;
+              Adam, the recipe's ``attn_impl: auto`` (the splash kernel on
+              the chip), server defaults;
 - ``parity``  the same greedy generations from an engine on the Pallas
               paged-decode kernel and one on the jnp gather;
 - ``server``  ``python -m areal_tpu.inference.server`` over HTTP, started
@@ -282,8 +283,8 @@ def child_grpo(args, device, watch):
     cfg = _model_config(args)
     model_dir, tok_dir, data = _fabricate(args, cfg)
     fileroot = os.path.join(args.out, "grpo")
-    # the kernel runs on the chip only: the rehearsal takes the XLA paths
-    attn = "auto" if args.rehearse else "flash"
+    # the recipe's own attn_impl, "auto": the splash kernel on the chip,
+    # the XLA form in the rehearsal (checked below, from what main resolved)
     new_tokens = 8 if args.rehearse else 128
     argv = [
         "--config", os.path.join(ROOT, "examples", "gsm8k_grpo.yaml"),
@@ -301,7 +302,6 @@ def child_grpo(args, device, watch):
         "gconfig.n_samples=4",
         f"gconfig.max_new_tokens={new_tokens}",
         f"actor.max_new_tokens={new_tokens}",
-        f"actor.attn_impl={attn}",
         "actor.dtype=bfloat16", "actor.param_dtype=float32",
         "actor.mb_spec.max_tokens_per_mb=4096",
         "server.max_num_seqs=16", "server.max_model_len=1024",
@@ -331,6 +331,7 @@ def child_grpo(args, device, watch):
         checks["server_attn_is_kernel"] = (
             summary["server_attn_impl"] == "kernel"
         )
+        checks["actor_attn_is_flash"] = summary["actor_attn_impl"] == "flash"
         checks["splash_edge_ge_1024"] = (summary["splash_block"] or 0) >= 1024
     return _result(
         "grpo", all(checks.values()), device, checks=checks,

@@ -373,7 +373,7 @@ def main(argv):
     summary = {
         "steps": step.global_step,
         "model_version": engine.get_version(),
-        "actor_attn_impl": config.actor.attn_impl,
+        "actor_attn_impl": engine.attn_impl,
         "splash_block": engine.splash_block,
         "server_attn_impl": (
             rollout.engine.attn_impl if colocated and rollout else None

@@ -1,0 +1,186 @@
+"""``TrainEngineConfig.attn_impl`` resolved once at ``initialize()`` from what
+the process observes (backend, mesh, the model's head dimension), and read
+from there by the window, the kernel override and the dispatch spans.
+
+The backend is the CPU here; "reported as a TPU" means ``jax.default_backend``
+is steered in the test (and the block probe with it: it would run the kernel).
+Nothing is compiled for the kernel in this file; its numbers are in
+``test_flash_parity.py``, its compile for the chip in ``test_chip_compile.py``.
+"""
+
+import dataclasses
+import functools
+
+import numpy as np
+import pytest
+
+import jax
+
+from areal_tpu.api.cli_args import (
+    MicroBatchSpec,
+    OptimizerConfig,
+    ParallelismConfig,
+    TrainEngineConfig,
+)
+from areal_tpu.api.io_struct import FinetuneSpec
+from areal_tpu.engine.sft.lm_engine import sft_loss_fn, sft_loss_weight_fn
+from areal_tpu.engine.spmd_engine import SPMDTrainEngine
+from areal_tpu.models.config import tiny_config
+from areal_tpu.ops import flash
+from areal_tpu.utils import data as data_utils
+from areal_tpu.utils import tracing
+
+
+def _engine(attn_impl="auto", parallel=None, head_dim=64, cap=4096):
+    cfg = TrainEngineConfig(
+        dtype="float32", param_dtype="float32", gradient_checkpointing=False,
+        attn_impl=attn_impl,
+        mb_spec=MicroBatchSpec(max_tokens_per_mb=cap),
+        optimizer=OptimizerConfig(
+            type="adamw", lr=1e-2, weight_decay=0.0,
+            warmup_steps_proportion=0.0, lr_scheduler_type="constant",
+        ),
+        parallel=parallel or ParallelismConfig(),
+    )
+    eng = SPMDTrainEngine(cfg)
+    eng.initialize(
+        ft_spec=FinetuneSpec(1, 64, 8),
+        model_config=dataclasses.replace(
+            tiny_config("qwen2"), head_dim=head_dim
+        ),
+        seed=0,
+    )
+    return eng
+
+
+@pytest.fixture
+def probes(monkeypatch):
+    """The block probe, counted and answered without a kernel."""
+    calls = []
+    monkeypatch.setattr(
+        flash, "probe_block_size", lambda: calls.append(1) or 1024
+    )
+    return calls
+
+
+@pytest.fixture
+def as_tpu(monkeypatch, probes):
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    return probes
+
+
+def _batch(lens):
+    rng = np.random.default_rng(0)
+    batch = data_utils.pad_sequences_to_tensors(
+        [rng.integers(0, 128, size=n) for n in lens]
+    )
+    batch["loss_mask"] = batch["attention_mask"].astype(np.int32)
+    return batch
+
+
+def test_auto_on_the_cpu_backend_is_the_xla_form(probes):
+    eng = _engine()
+    assert eng.attn_impl == "xla"
+    assert eng._attend_fn() is None
+    assert eng._flash_window(_batch([700, 30])) == 0
+    assert eng.splash_block is None and not probes
+
+
+def test_auto_on_a_tpu_is_the_kernel_on_one_device(as_tpu):
+    eng = _engine()
+    assert eng.attn_impl == "flash"
+    assert eng.splash_block == 1024 and len(as_tpu) == 1
+    attend = eng._attend_fn(2048)
+    assert isinstance(attend, functools.partial)
+    assert attend.func is flash.flash_segment_attention
+    assert attend.keywords == {"window": 2048}
+
+
+def test_auto_on_a_tpu_mesh_is_the_kernel_under_shard_map(
+    as_tpu, monkeypatch
+):
+    eng = _engine(parallel=ParallelismConfig(
+        fsdp_parallel_size=2, tensor_parallel_size=2
+    ))
+    assert eng.attn_impl == "flash" and eng.mesh.size == 4
+    seen = {}
+
+    def shard_map(fn, **kw):
+        seen.update(kw, fn=fn)
+        return "sharded"
+
+    monkeypatch.setattr(jax, "shard_map", shard_map)
+    assert eng._attend_fn(0) == "sharded"
+    assert seen["fn"].func is flash.flash_segment_attention
+    assert seen["mesh"] is eng.mesh
+    # rows over (data, fsdp), heads over tensor, the sequence whole
+    assert tuple(seen["out_specs"]) == (("data", "fsdp"), None, "tensor", None)
+
+
+@pytest.mark.parametrize(
+    "attn_impl,parallel,head_dim,resolved",
+    [
+        pytest.param("auto", ParallelismConfig(seq_parallel_size=2), 64, "xla",
+                     id="auto-seq2-as-before"),
+        pytest.param("auto", None, 16, "xla", id="auto-head16-refused"),
+        pytest.param("auto", None, 96, "xla", id="auto-head96-refused"),
+        pytest.param("auto", None, 128, "flash", id="auto-head128"),
+        pytest.param("ulysses", None, 64, "xla", id="ulysses-nothing-to-split"),
+        pytest.param("ring", ParallelismConfig(seq_parallel_size=2), 64, "ring",
+                     id="ring-seq2-kept"),
+        pytest.param("flash", None, 16, "flash", id="flash-explicit-kept"),
+    ],
+)
+def test_resolution_on_a_tpu(as_tpu, attn_impl, parallel, head_dim, resolved):
+    eng = _engine(attn_impl, parallel, head_dim)
+    assert eng.attn_impl == resolved
+    assert len(as_tpu) == (resolved == "flash")
+    if resolved == "xla":
+        assert eng._attend_fn() is None and eng.splash_block is None
+    if resolved == "ring":
+        assert callable(eng._attend_fn())
+
+
+def test_explicit_flash_on_the_cpu_backend_keeps_its_name_unprobed(probes):
+    eng = _engine("flash")
+    assert eng.attn_impl == "flash"
+    assert eng.splash_block is None and not probes
+
+
+@pytest.mark.parametrize(
+    "longest,cap,window",
+    [
+        pytest.param(30, 16384, 1024, id="short-floor"),
+        pytest.param(1024, 16384, 1024, id="edge"),
+        pytest.param(1025, 16384, 2048, id="next-bucket"),
+        pytest.param(3000, 16384, 4096, id="quarter-of-the-cap"),
+        pytest.param(5000, 16384, 0, id="half-the-cap-is-full-causal"),
+        pytest.param(9000, 16384, 0, id="cap-is-full-causal"),
+        pytest.param(900, 4096, 1024, id="cell-cap-quarter"),
+        pytest.param(1500, 4096, 0, id="cell-cap-half-is-full-causal"),
+    ],
+)
+def test_flash_window_buckets(as_tpu, longest, cap, window):
+    eng = _engine(cap=cap)
+    assert eng._flash_window(_batch([longest, 17])) == window
+
+
+def test_dispatch_spans_carry_the_resolved_name_and_the_window(monkeypatch):
+    eng = _engine()
+    seen = []
+    span = tracing.span
+
+    def recording(name, *a, **attrs):
+        seen.append((name, attrs))
+        return span(name, *a, **attrs)
+
+    monkeypatch.setattr(tracing, "span", recording)
+    batch = _batch([9, 5, 7])
+    eng.train_batch(batch, sft_loss_fn, sft_loss_weight_fn)
+    eng.forward(batch)
+    for name in ("train.grad_dispatch", "train.logp_dispatch"):
+        attrs = [a for n, a in seen if n == name]
+        assert attrs, name
+        assert all(
+            a["attn_impl"] == "xla" and a["window"] == 0 for a in attrs
+        ), attrs

@@ -101,14 +101,27 @@ def test_paged_decode_kernel_compiles_for_v5e(
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize(
-    "d,hq", [pytest.param(64, 14, id="d64"), pytest.param(128, 12, id="d128")]
-)
-def test_splash_fwd_bwd_compiles_at_edge_2048(one_chip, monkeypatch, d, hq):
-    """fwd+bwd of the trainer's attention at the block edge
-    ``probe_block_size`` should choose on a chip whose scoped-VMEM limit
-    was raised. ``jax.default_backend()`` is the CPU here, so the edge is
-    set in the test rather than probed."""
+# (head_dim, q heads, T, window): the benchmark cell's packed row (Qwen2-0.5B,
+# 4096 tokens, full causal and the 2048 window its minibatches bucket to),
+# the recipe's 16384-token row, Qwen2-1.5B's heads
+_SPLASH_CASES = [
+    pytest.param(64, 14, 4096, 0, 1024, id="d64-t4096-causal"),
+    pytest.param(64, 14, 4096, 2048, 1024, id="d64-t4096-window2048"),
+    pytest.param(64, 14, 4096, 1024, 512, id="d64-t4096-window1024"),
+    pytest.param(64, 14, 16384, 0, 2048, id="d64-t16384-causal"),
+    pytest.param(128, 12, 4096, 0, 1024, id="d128-t4096-causal"),
+    pytest.param(128, 12, 2048, 0, 1024, id="d128-t2048-causal"),
+]
+
+
+@pytest.mark.parametrize("d,hq,t,window,edge", _SPLASH_CASES)
+def test_splash_fwd_bwd_compiles_for_v5e(
+    one_chip, monkeypatch, d, hq, t, window, edge
+):
+    """fwd+bwd of the trainer's attention with the tiles ``_block_sizes``
+    chooses on a chip whose scoped-VMEM limit was raised (the probe reads
+    2048 there; ``jax.default_backend()`` is the CPU here, so the cap is
+    set in the test rather than probed)."""
     import jax
     import jax.numpy as jnp
 
@@ -116,12 +129,12 @@ def test_splash_fwd_bwd_compiles_at_edge_2048(one_chip, monkeypatch, d, hq):
 
     monkeypatch.setattr(flash, "_PROBED_BLOCK", 2048)
     flash._make_kernel.cache_clear()
-    t, hkv = 2048, 2
+    hkv = 2
 
     def loss(q, k, v, seg):
-        return flash.flash_segment_attention(q, k, v, seg).astype(
-            jnp.float32
-        ).sum()
+        return flash.flash_segment_attention(
+            q, k, v, seg, window=window
+        ).astype(jnp.float32).sum()
 
     try:
         compiled = (
@@ -135,8 +148,34 @@ def test_splash_fwd_bwd_compiles_at_edge_2048(one_chip, monkeypatch, d, hq):
             .compile()
         )
     finally:
-        # the kernel objects cached under the steered edge must not leak
+        # the kernel objects cached under the steered cap must not leak
         # into other tests of this worker
         flash._make_kernel.cache_clear()
-    assert flash._block_size(t) == 2048
+    assert flash._block_sizes(t, window, d).block_q == edge
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_the_probes_first_candidate_compiles_for_v5e(one_chip):
+    """What ``probe_block_size`` tries first: edge 2048, 4 heads of 128
+    over one kv head, T = 2048. It needs the raised scoped-VMEM limit."""
+    import jax
+    import jax.numpy as jnp
+
+    from areal_tpu.ops import flash
+
+    t = 2048
+    mask = flash._sm.MultiHeadMask([flash._sm.CausalMask((t, t))] * 4)
+    kernel = flash._sk.make_splash_mqa_single_device(
+        mask, block_sizes=flash._tiles(2048)
+    )
+    compiled = (
+        jax.jit(jax.grad(
+            lambda q, k: kernel(q, k, k).astype(jnp.float32).sum()
+        ))
+        .lower(
+            _sds((4, t, 128), jnp.bfloat16, one_chip),
+            _sds((t, 128), jnp.bfloat16, one_chip),
+        )
+        .compile()
+    )
     assert "tpu_custom_call" in compiled.as_text()

@@ -1,132 +1,157 @@
-"""Attention sweep. Protocol: a warmup call ended by a REAL scalar fetch (so
-no compile bleeds into the first timed window), then 5 chained dispatches
-with one final fetch. Not re-run on the current machine."""
+"""The trainer's attention core alone, on the chip: ``ops/flash.py``'s wrapper
+(relayouts included) against the XLA ``segment_attention``, forward and
+forward + backward, over block edges, backward forms and windows, on packed
+rows of the benchmark's own GRPO batches (``benchmark/traffic``).
 
+    python -m tools.microbench_attn_v2 [--t 4096] [--heads 14 2 64] \
+        [--out chiprun_out/attn_sweep]
+
+Every variant is one jitted program with a name of its own, all run under
+one ``jax.profiler`` capture; the times are device times of those programs
+from ``benchmark.program_trace`` (what ``attn_time_share.train`` is read by),
+not host clocks. (The persistent compile cache keys a program without its
+name: two variants that lower to the same program run under the first one's
+name, and are marked.) ``ops/flash._block_sizes`` holds what this found (PERF.md,
+PR 26); run it again when the kernel, the chip or the shape changes.
+"""
+
+import argparse
+import functools
+import itertools
+import json
 import os
-import sys
-import time
 
-_flag = "--xla_tpu_scoped_vmem_limit_kib=65536"
-if _flag not in os.environ.get("LIBTPU_INIT_ARGS", ""):
-    os.environ["LIBTPU_INIT_ARGS"] = (
-        os.environ.get("LIBTPU_INIT_ARGS", "") + " " + _flag
-    ).strip()
+import areal_tpu  # noqa: F401 — the scoped-VMEM flag, before the backend
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from jax.experimental.pallas.ops.tpu.splash_attention import (
-    splash_attention_kernel as sk,
-    splash_attention_mask as sm,
-)
+from areal_tpu.ops import flash
+from areal_tpu.ops.flash import _sk as sk
+from areal_tpu.ops.basic import segment_attention
+from areal_tpu.utils import data as data_utils
+from areal_tpu.utils import tracing
+from benchmark import harness, program_trace, xplane
+from benchmark.kinds import train_steps
 
-HQ, HKV, D = 14, 2, 64
-REP = HQ // HKV
-ITERS = 5
-SEQ = sk.QKVLayout.SEQ_MINOR
-
-
-def fetch(x):
-    leaf = jax.tree_util.tree_leaves(x)[0]
-    return float(jnp.asarray(leaf).astype(jnp.float32).ravel()[0])
+RUNS = 3
 
 
-def run(T, window=0):
+def packed_rows(t, traffic="grpo_steps32", n=2):
+    """Segment ids of the first ``n`` microbatches of the cell's first batch,
+    packed as the trainer packs them (one row of ``t`` tokens each)."""
+    with open(os.path.join(harness.ROOT, "benchmark", "traffic",
+                           traffic + ".json")) as f:
+        p = json.load(f)
+    batch = train_steps.make_batches(p, 1000, 0)[0]
+    fits = batch["_seq_lens"] <= t
+    batch = {k: v[fits] for k, v in batch.items() if not k.startswith("_")}
+    mbs = data_utils.split_padded_batch_into_mb_list(batch, t).mbs[:n]
+    return [
+        data_utils.pack_batch_rows(mb, n_rows=1, pad_to=t).segment_ids
+        for mb in mbs
+    ]
+
+
+def variants(t, windows, edges):
+    for edge, window, fused, kvc in itertools.product(
+        edges, windows, (True, False), (256, 512, 1024)
+    ):
+        if kvc > edge or (kvc == 256 and edge > 512):
+            continue
+        name = f"e{edge}_w{window or 'full'}_{'fused' if fused else 'split'}_c{kvc}"
+        dq = {} if fused else dict(block_q_dq=edge, block_kv_dq=edge)
+        yield name, window, sk.BlockSizes(
+            block_q=edge, block_kv=edge, block_kv_compute=kvc,
+            block_q_dkv=edge, block_kv_dkv=edge, block_kv_dkv_compute=kvc,
+            use_fused_bwd_kernel=fused, **dq,
+        )
+
+
+def main():
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--t", type=int, default=4096)
+    ap.add_argument("--heads", type=int, nargs=3, default=(14, 2, 64),
+                    metavar=("HQ", "HKV", "D"))
+    ap.add_argument("--windows", type=int, nargs="*", default=(0, 2048, 1024))
+    ap.add_argument("--edges", type=int, nargs="*", default=(512, 1024, 2048))
+    ap.add_argument("--out", default="chiprun_out/attn_sweep")
+    args = ap.parse_args()
+    t, (hq, hkv, d) = args.t, args.heads
+    print("device", jax.devices()[0].device_kind,
+          "probed edge", flash.probe_block_size(), flush=True)
     key = jax.random.PRNGKey(0)
-    q = jax.random.normal(key, (1, T, HQ, D), jnp.bfloat16)
-    k = jax.random.normal(key, (1, T, HKV, D), jnp.bfloat16)
-    v = jax.random.normal(key, (1, T, HKV, D), jnp.bfloat16)
-    seg = jnp.ones((1, T), jnp.int32)
-    fwd_flops = 2 * 2 * T * T * (HQ * D) * 0.5
-    if window:
-        fwd_flops = 2 * 2 * T * window * (HQ * D)
-
-    def make(**kw):
-        with jax.ensure_compile_time_eval():
-            if 0 < window < T:
-                head = sm.LocalMask((T, T), (window, 0), 0)
-            else:
-                head = sm.CausalMask((T, T))
-            mask = sm.MultiHeadMask([head for _ in range(REP)])
-            bs = sk.BlockSizes(**kw) if kw else None
-            kernel = sk.make_splash_mqa_single_device(mask, block_sizes=bs)
-
-        def attend(q_, k_, v_):
-            qg = q_.transpose(0, 2, 1, 3).reshape(1, HKV, REP, T, D)
-            kt = k_.transpose(0, 2, 1, 3)
-            vt = v_.transpose(0, 2, 1, 3)
-
-            def per_batch(q__, k__, v__, seg_row):
-                ids = sk.SegmentIds(q=seg_row, kv=seg_row)
-                return jax.vmap(kernel, in_axes=(0, 0, 0, None))(
-                    q__, k__, v__, ids
-                )
-
-            out = jax.vmap(per_batch)(qg, kt, vt, seg)
-            return out.reshape(1, HQ, T, D).transpose(0, 2, 1, 3)
-
-        return attend
-
-    def bench(name, attend, grad=False):
-        try:
-            if grad:
-                fn = jax.jit(
-                    jax.grad(
-                        lambda q_, k_, v_: attend(q_, k_, v_)
-                        .astype(jnp.float32)
-                        .sum(),
-                        argnums=(0, 1, 2),
-                    )
-                )
-                flops = fwd_flops * 3.5
-            else:
-                fn = jax.jit(attend)
-                flops = fwd_flops
-            fetch(fn(q, k, v))  # warmup incl. real compile completion
-            t0 = time.perf_counter()
-            for _ in range(ITERS):
-                out = fn(q, k, v)
-            jax.block_until_ready(out)
-            fetch(out)
-            dt = (time.perf_counter() - t0) / ITERS
-            print(
-                f"T={T} w={window} {name:44s} {dt*1e3:8.2f} ms "
-                f"{flops/dt/1e12:6.2f} TF/s",
-                flush=True,
-            )
-            return dt
-        except Exception as e:
-            print(f"T={T} w={window} {name:44s} FAIL "
-                  f"{type(e).__name__}: {str(e)[:100]}", flush=True)
-            return None
-
-    b = min(1024, T)
-    base = dict(
-        block_q=b, block_kv=b, block_kv_compute=b,
-        block_q_dkv=b, block_kv_dkv=b, block_kv_dkv_compute=b,
-        block_q_dq=b, block_kv_dq=b,
+    q, k, v = (
+        jax.random.normal(kk, (1, t, h, d), jnp.bfloat16)
+        for kk, h in zip(jax.random.split(key, 3), (hq, hkv, hkv))
     )
-    bench("fwd all-1024 (r4 prod)", make(**base))
-    bench("fwd kvc512", make(block_q=b, block_kv=b, block_kv_compute=512))
-    bench("fwd kSEQ kvc512",
-          make(block_q=b, block_kv=b, block_kv_compute=512, k_layout=SEQ))
-    bench("grad all-1024 unfused (r4 prod)", make(**base), grad=True)
-    fused = dict(
-        block_q=b, block_kv=b, block_kv_compute=512,
-        block_q_dkv=b, block_kv_dkv=min(2048, T),
-        block_kv_dkv_compute=min(2048, T),
-        use_fused_bwd_kernel=True,
-    )
-    bench("grad fused q1024 dkv2048 kvc512", make(**fused), grad=True)
-    f2 = dict(fused)
-    f2.update(block_kv_dkv=b, block_kv_dkv_compute=b)
-    bench("grad fused q1024 dkv1024 kvc512", make(**f2), grad=True)
-    f3 = dict(fused)
-    f3.update(block_q_dkv=min(2048, T))
-    bench("grad fused q2048 dkv2048 kvc512", make(**f3), grad=True)
+    segs = [jnp.asarray(s) for s in packed_rows(t)]
+
+    def programs(name, attend):
+        def fwd(q, k, v, seg):
+            with tracing.device_scope("attention"):
+                return attend(q, k, v, seg)
+
+        def grad(q, k, v, seg):
+            return jax.grad(
+                lambda *a: fwd(*a, seg).astype(jnp.float32).sum(),
+                argnums=(0, 1, 2),
+            )(q, k, v)
+
+        fwd.__name__, grad.__name__ = f"fwd_{name}", f"grad_{name}"
+        return jax.jit(fwd), jax.jit(grad)
+
+    todo = []
+    chosen = flash._block_sizes
+    named = [("xla", 0, None)] + list(
+        variants(t, args.windows, args.edges)
+    ) + [("chosen", 0, None)]
+    for name, window, tiles in named:
+        flash._block_sizes = (lambda *_, tiles=tiles: tiles) if tiles else chosen
+        flash._make_kernel.cache_clear()
+        fns = programs(name, segment_attention if name == "xla" else
+                       functools.partial(flash.flash_segment_attention,
+                                         window=window))
+        try:  # trace + compile now, while this variant's tiles are in place
+            for fn in fns:
+                jax.block_until_ready(fn(q, k, v, segs[0]))
+            todo.append((name, *fns))
+        except Exception as e:  # noqa: BLE001 — mosaic raises various types
+            print(f"{name}: refused ({type(e).__name__}: {str(e)[:160]})",
+                  flush=True)
+    flash._block_sizes = chosen
+    flash._make_kernel.cache_clear()
+    ref = None
+    for name, fwd, grad in todo:  # warm, and hold the kernel to the XLA form
+        outs = [np.asarray(fwd(q, k, v, segs[0]), np.float32)] + [
+            np.asarray(g, np.float32) for g in grad(q, k, v, segs[0])
+        ]
+        if name == "xla":
+            ref = outs
+        elif ref and ("full" in name or name == "chosen"):
+            print(name, "max |delta| out, dq, dk, dv:",
+                  [float(np.abs(a - b).max()) for a, b in zip(outs, ref)],
+                  flush=True)
+    os.makedirs(args.out, exist_ok=True)
+    jax.profiler.start_trace(args.out)
+    for _, fwd, grad in todo:
+        for i in range(RUNS):
+            jax.block_until_ready(fwd(q, k, v, segs[i % len(segs)]))
+            jax.block_until_ready(grad(q, k, v, segs[i % len(segs)]))
+    jax.profiler.stop_trace()
+    r = program_trace.reduce(program_trace.load(xplane.find_xplane(args.out)))
+    print(f"T={t} heads={hq}/{hkv}x{d}: device ms a run; '=' marks a program "
+          "the compile cache found equal to an earlier one, which has its runs")
+    print(f"{'variant':34s} {'fwd':>8s} {'fwd+bwd':>8s}")
+    for name, _, _ in todo:
+        cells = []
+        for kind in ("fwd", "grad"):
+            p = r["programs"].get(f"{kind}_{name}")
+            cells.append(f"{1e3 * p['seconds'] / p['runs']:8.3f}" if p
+                         else f"{'=':>8s}")
+        print(f"{name:34s} {cells[0]} {cells[1]}", flush=True)
 
 
-run(24576)
-run(16384)
-run(16384, window=2176)
-run(8192)
+if __name__ == "__main__":
+    main()
