@@ -51,6 +51,14 @@ from areal_tpu.utils.data import Batch
 
 logger = logging_util.getLogger("SPMDTrainEngine")
 
+# grad_step's stats that are counts of a sparse model's routing (summed over
+# microbatches, where every other stat is a weighted mean): the key in the
+# stats, in ``counters()`` and on the stats plane, and its ``MoEStats`` field
+_MOE_COUNTS = {
+    "moe_assignments": "assignments",
+    "moe_max_expert_assignments": "max_expert_assignments",
+}
+
 _DTYPES = {
     "float32": jnp.float32,
     "bfloat16": jnp.bfloat16,
@@ -124,7 +132,9 @@ class SPMDTrainEngine(TrainEngine):
         self.lr_schedule = None
         self.step_count = 0
         # cumulative tokens dispatched (counters()), counted where every
-        # microbatch is packed, from its segment ids
+        # microbatch is packed, from its segment ids; a sparse model adds
+        # (initialize) the (token, expert) pairs its train microbatches
+        # routed and the busiest expert's share of them, summed over layers
         self._counters = {"real_tokens": 0, "padded_tokens": 0}
         self._version = 0
         self.compute_dtype = _DTYPES[config.dtype]
@@ -136,6 +146,10 @@ class SPMDTrainEngine(TrainEngine):
         # backend ran when that is "flash" on a TPU (None: kernel not in use)
         self.attn_impl: Optional[str] = None
         self.splash_block: Optional[int] = None
+        # the grouped matmul of a sparse model's experts, as ops/moe.py
+        # resolves it from the platform ("megablox" | "ragged_dot"); None
+        # for a dense model
+        self.moe_impl: Optional[str] = None
         self.initialized = False
 
     # ------------------------------------------------------------------
@@ -176,22 +190,25 @@ class SPMDTrainEngine(TrainEngine):
                     * 0.02
                 ).astype(self.param_dtype)
                 host_params.pop("lm_head", None)
-        else:
-            host_params = init_params(
-                mc, jax.random.PRNGKey(seed), dtype=self.param_dtype,
-                value_head=is_critic,
+            self.params = jax.tree_util.tree_map(
+                lambda a, sh: distributed_lib.make_global_array(
+                    np.asarray(a), sh
+                ),
+                host_params,
+                self._param_shardings,
             )
-        self.params = jax.tree_util.tree_map(
-            lambda a, sh: distributed_lib.make_global_array(
-                np.asarray(a), sh
-            ),
-            host_params,
-            self._param_shardings,
-        )
-        # a from-scratch init sits whole on the default device: release it
-        # before the optimizer state is made, or device 0 carries a second
-        # full copy of the model through the largest allocation of start-up
-        del host_params
+            del host_params
+        else:
+            # drawn where it lives: every leaf comes out of one program in
+            # its own sharding, so no device (and no host) ever holds the
+            # whole model — a from-scratch model may exist only across chips
+            self.params = jax.jit(
+                functools.partial(
+                    init_params, mc, dtype=self.param_dtype,
+                    value_head=is_critic,
+                ),
+                out_shardings=self._param_shardings,
+            )(jax.random.PRNGKey(seed))
         if cfg.optimizer is not None:
             total_steps = ft_spec.total_train_steps if ft_spec else 10000
             self.lr_schedule = _lr_schedule(cfg, total_steps)
@@ -205,6 +222,11 @@ class SPMDTrainEngine(TrainEngine):
             from areal_tpu.ops import flash as flash_ops
 
             self.splash_block = flash_ops.probe_block_size()
+        if mc.is_moe:
+            from areal_tpu.ops import moe as moe_ops
+
+            self.moe_impl = moe_ops.grouped_impl()
+            self._counters.update(dict.fromkeys(_MOE_COUNTS, 0))
         n = count_params(self.params)
         logger.info(
             f"initialized {mc.family} model: {n/1e6:.1f}M params on mesh "
@@ -215,6 +237,7 @@ class SPMDTrainEngine(TrainEngine):
                 f", splash block edge <= {self.splash_block}"
                 if self.splash_block else ""
             )
+            + (f"; experts: {self.moe_impl}" if self.moe_impl else "")
         )
         self.initialized = True
         return self
@@ -305,8 +328,10 @@ class SPMDTrainEngine(TrainEngine):
         """Cumulative counts since construction (as
         ``GenerationEngine.metrics()`` is for the engine): tokens
         dispatched, real and padded, over recompute and train
-        microbatches. Their per-step increments are the stats plane's
-        ``spmd/real_tokens`` and ``spmd/padded_tokens``."""
+        microbatches; for a sparse model also ``moe_assignments`` and
+        ``moe_max_expert_assignments`` (the routed pairs of the train
+        microbatches and the busiest expert's, summed over layers). Their
+        per-step increments are the stats plane's ``spmd/<name>``."""
         return dict(self._counters)
 
     def get_version(self) -> int:
@@ -492,6 +517,54 @@ class SPMDTrainEngine(TrainEngine):
             self._cached_attend = make_sharded_attention(self.mesh, impl=impl)
         return self._cached_attend
 
+    def _moe_fn(self):
+        """The sparse layer's call under a mesh: ``ops.moe`` per device
+        inside a ``shard_map`` (None: the plain call, one device or a dense
+        model). Its dispatch sorts the (token, expert) pairs, and a sort
+        over a token axis that GSPMD has sharded would gather it; a Mosaic
+        grouped matmul cannot be partitioned either. Each device routes its
+        own tokens through ALL experts: the layer's expert weights come in
+        as the rules table shards them and are gathered whole inside (as
+        ZeRO-3 gathers a dense layer's; the transpose reduce-scatters their
+        gradients), tokens never leave their device, and devices that hold
+        the same tokens (tensor, expert axes) repeat the work. The
+        statistics are summed over the axes that split tokens."""
+        mc = self.model_config
+        if not mc.is_moe or self.mesh.size == 1:
+            return None
+        from areal_tpu.ops.moe import moe_ffn_from_params
+
+        token_axes = ("data", "fsdp", "seq")
+        logical = param_logical_axes(mc)["layers"]
+        # one layer's leaves: the scanned layer axis is gone
+        specs = {
+            k: sharding_lib.logical_to_spec(logical[k][1:])
+            for k in ("w_router", "w_gate", "w_up", "w_down")
+        }
+
+        def per_device(weights, h, valid):
+            whole = {}
+            for k, w in weights.items():
+                for dim, axis in enumerate(specs[k]):
+                    if axis is not None and self.mesh.shape[axis] > 1:
+                        w = jax.lax.all_gather(w, axis, axis=dim, tiled=True)
+                whole[k] = w
+            return moe_ffn_from_params(
+                mc, whole, h, valid=valid, token_axes=token_axes
+            )
+
+        x_spec = P(("data", "fsdp"), "seq", None)
+        sharded = jax.shard_map(
+            per_device,
+            mesh=self.mesh,
+            in_specs=(specs, x_spec, P(("data", "fsdp"), "seq")),
+            out_specs=(x_spec, P()),
+            check_vma=False,
+        )
+        return lambda lp, h, valid: sharded(
+            {k: lp[k] for k in specs}, h, valid
+        )
+
     def _get_grad_fn(
         self, loss_fn: Callable, loss_weight_fn: Callable, window: int = 0
     ):
@@ -501,6 +574,7 @@ class SPMDTrainEngine(TrainEngine):
             remat = self.config.gradient_checkpointing
             compute_dtype = self.compute_dtype
             attend = self._attend_fn(window)
+            moe = self._moe_fn()
             lazy_head = self._lazy_head()
             act_sh = self._act_sharding()
 
@@ -508,18 +582,25 @@ class SPMDTrainEngine(TrainEngine):
                 cparams = jax.tree_util.tree_map(
                     lambda p: p.astype(compute_dtype), params
                 )
-                logits, router_aux = packed_forward(
+                logits, moe_stats = packed_forward(
                     cparams, mc, arrays, remat=remat,
                     remat_save_attn=self.config.remat_save_attn,
-                    attend_fn=attend,
-                    return_router_loss=True, return_hidden=lazy_head,
+                    attend_fn=attend, moe_fn=moe,
+                    return_moe_stats=True, return_hidden=lazy_head,
                     act_sharding=act_sh,
                 )
                 with tracing.device_scope("loss"):
                     loss, stats = loss_fn(logits, arrays)
-                    if mc.is_moe and mc.router_aux_loss_coef:
-                        loss = loss + mc.router_aux_loss_coef * router_aux
-                        stats = dict(stats, router_aux_loss=router_aux)
+                    if mc.is_moe:
+                        if mc.router_aux_loss_coef:
+                            loss = loss + (
+                                mc.router_aux_loss_coef * moe_stats.aux_loss
+                            )
+                        stats = dict(
+                            stats, router_aux_loss=moe_stats.aux_loss,
+                            **{k: getattr(moe_stats, v)
+                               for k, v in _MOE_COUNTS.items()},
+                        )
                     w = loss_weight_fn(arrays).astype(jnp.float32)
                     return loss * w, (loss, stats, w)
 
@@ -617,6 +698,7 @@ class SPMDTrainEngine(TrainEngine):
             with goodput.trainer_bucket("fwd_bwd"), tracing.span(
                 "train.grad_dispatch", step=step,
                 attn_impl=self.attn_impl, window=window,
+                **({"moe_impl": self.moe_impl} if self.moe_impl else {}),
             ), goodput.dispatch_scope(
                 goodput.trainer_tracker(), "fwd_bwd", gp_sig
             ):
@@ -673,7 +755,12 @@ class SPMDTrainEngine(TrainEngine):
             "n_mbs": float(n_mb),
         }
         for j, k in enumerate(stat_keys):
-            out[k] = float((h_stats[:, j] * h_weights).sum() / h_total_w)
+            if k in _MOE_COUNTS:  # a count: summed, counted, and per step
+                out[k] = float(h_stats[:, j].sum())
+                self._counters[k] += int(out[k])
+                stats_tracker.counter(**{f"spmd/{k}": int(out[k])})
+            else:
+                out[k] = float((h_stats[:, j] * h_weights).sum() / h_total_w)
         return out
 
     def eval_batch(
@@ -689,6 +776,7 @@ class SPMDTrainEngine(TrainEngine):
             mc = self.model_config
             compute_dtype = self.compute_dtype
             attend = self._attend_fn(window)
+            moe = self._moe_fn()
             lazy_head = self._lazy_head()
             act_sh = self._act_sharding()
 
@@ -698,7 +786,8 @@ class SPMDTrainEngine(TrainEngine):
                 )
                 logits = packed_forward(
                     cparams, mc, arrays, remat=False, attend_fn=attend,
-                    return_hidden=lazy_head, act_sharding=act_sh,
+                    moe_fn=moe, return_hidden=lazy_head,
+                    act_sharding=act_sh,
                 )
                 loss, stats = loss_fn(logits, arrays)
                 return loss, stats, loss_weight_fn(arrays).astype(jnp.float32)
@@ -756,6 +845,7 @@ class SPMDTrainEngine(TrainEngine):
             mc = self.model_config
             compute_dtype = self.compute_dtype
             attend = self._attend_fn(window)
+            moe = self._moe_fn()
             lazy_head = self._lazy_head()
             act_sh = self._act_sharding()
 
@@ -765,7 +855,8 @@ class SPMDTrainEngine(TrainEngine):
                 )
                 logits = packed_forward(
                     cparams, mc, arrays, remat=False, attend_fn=attend,
-                    return_hidden=lazy_head, act_sharding=act_sh,
+                    moe_fn=moe, return_hidden=lazy_head,
+                    act_sharding=act_sh,
                 )
                 with tracing.device_scope("loss"):
                     return hook(logits, arrays)
@@ -783,6 +874,7 @@ class SPMDTrainEngine(TrainEngine):
             with tracing.span(
                 "train.logp_dispatch", step=step,
                 attn_impl=self.attn_impl, window=window,
+                **({"moe_impl": self.moe_impl} if self.moe_impl else {}),
             ):
                 vals = self._jit_cache[key](self.params, arrays)
             with tracing.span("train.logp_fetch", step=step):

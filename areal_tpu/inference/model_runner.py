@@ -60,10 +60,13 @@ def _project_qkv(cfg: ModelConfig, lp: Params, h: jnp.ndarray):
         q = q + lp["bq"]
         k = k + lp["bk"]
         v = v + lp["bv"]
+    if cfg.qk_norm == "projection":  # olmoe: before the split into heads
+        q = rms_norm(q, lp["q_norm"], cfg.rms_norm_eps)
+        k = rms_norm(k, lp["k_norm"], cfg.rms_norm_eps)
     q = q.reshape(h.shape[:-1] + (cfg.num_heads, cfg.head_dim))
     k = k.reshape(h.shape[:-1] + (cfg.num_kv_heads, cfg.head_dim))
     v = v.reshape(h.shape[:-1] + (cfg.num_kv_heads, cfg.head_dim))
-    if cfg.use_qk_norm:
+    if cfg.qk_norm == "head":  # qwen3: each head by itself
         q = rms_norm(q, lp["q_norm"], cfg.rms_norm_eps)
         k = rms_norm(k, lp["k_norm"], cfg.rms_norm_eps)
     return q, k, v

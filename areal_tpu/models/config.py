@@ -28,7 +28,11 @@ class ModelConfig:
     rms_norm_eps: float = 1e-6
     tie_word_embeddings: bool = False
     attention_bias: bool = False  # qwen2: QKV bias, no O bias
-    use_qk_norm: bool = False  # qwen3: per-head RMSNorm on q and k
+    # RMSNorm on q and k before the rotation: None; "head" (qwen3: each
+    # head normed by itself, weight [head_dim]); "projection" (olmoe: the
+    # whole q or k projection normed at once, before the split into heads,
+    # weight [q_dim] / [kv_dim])
+    qk_norm: Optional[str] = None
     family: str = "llama"
     # --- MoE (0 experts = dense; reference realhf/impl/model/modules/moe) ---
     num_experts: int = 0
@@ -36,7 +40,6 @@ class ModelConfig:
     moe_intermediate_size: int = 0  # 0 → intermediate_size
     norm_topk_prob: bool = True
     router_aux_loss_coef: float = 0.001
-    moe_capacity_factor: float = 1.25
     # qwen2_moe-style shared expert: a dense SiLU-gated FFN of this size
     # runs on EVERY token alongside the routed experts, scaled by a
     # sigmoid gate (0 = no shared expert)
@@ -72,13 +75,18 @@ class ModelConfig:
 # Supported HF `model_type`s. The llama-style decoder block (RMSNorm +
 # SiLU-gated MLP + rotary GQA attention) is the baseline; gemma layers on
 # GeLU(tanh), (1+w) norms and sqrt(d) embedding scaling via config knobs.
-# qwen3_moe/mixtral are expert-only sparse; qwen2_moe adds the shared
-# expert + sigmoid gate. gemma2/gpt2 remain out (interleaved local
+# qwen3_moe/mixtral/olmoe are expert-only sparse (olmoe: 64 experts of
+# which 8 a token, weights not renormalised, q/k normed over the whole
+# projection); qwen2_moe adds the shared expert + sigmoid gate.
+# gemma2/gpt2 remain out (interleaved local
 # attention / learned positions need architecture changes).
 _HF_FAMILIES = (
     "llama", "qwen2", "qwen3", "mistral", "qwen3_moe", "mixtral",
-    "qwen2_vl", "qwen2_moe", "gemma",
+    "qwen2_vl", "qwen2_moe", "gemma", "olmoe",
 )
+
+
+_QK_NORM = {"qwen3": "head", "qwen3_moe": "head", "olmoe": "projection"}
 
 
 def _vision_from_hf(d: dict, lm_hidden: int) -> VisionConfig:
@@ -155,7 +163,7 @@ def from_hf_config(d: dict) -> ModelConfig:
             "attention_bias",
             model_type in ("qwen2", "qwen2_vl", "qwen2_moe"),
         ),
-        use_qk_norm=(model_type in ("qwen3", "qwen3_moe")),
+        qk_norm=_QK_NORM.get(model_type),
         family=model_type,
         vision=vision,
         mrope_sections=mrope_sections,
@@ -174,12 +182,15 @@ def from_hf_config(d: dict) -> ModelConfig:
         num_experts_per_tok=d.get(
             "num_experts_per_tok", d.get("top_k", 2)
         ),
+        # olmoe's config has no key of its own for an expert's width: its
+        # intermediate_size IS that width (there is no dense MLP), which
+        # expert_ffn_size falls back to
         moe_intermediate_size=d.get("moe_intermediate_size", 0),
         # HF Mixtral renormalizes top-k routing weights unconditionally
         # and qwen3_moe's config ships norm_topk_prob=true; qwen2_moe
         # ships FALSE (unnormalized top-k + shared expert)
         norm_topk_prob=d.get(
-            "norm_topk_prob", model_type != "qwen2_moe"
+            "norm_topk_prob", model_type not in ("qwen2_moe", "olmoe")
         ),
         # HF Qwen2MoeConfig defaults the shared expert to 5632 and always
         # builds it — a missing key must not silently drop the expert
@@ -220,7 +231,16 @@ def tiny_vlm_config(vocab_size: int = 128) -> ModelConfig:
 
 def tiny_config(family: str = "qwen2", vocab_size: int = 128) -> ModelConfig:
     """Small config for tests."""
-    moe = family in ("qwen3_moe", "mixtral", "qwen2_moe")
+    moe = family in ("qwen3_moe", "mixtral", "qwen2_moe", "olmoe")
+    if family == "olmoe":  # 8 experts of which 2, MHA, as the family is
+        return ModelConfig(
+            vocab_size=vocab_size, hidden_size=64, intermediate_size=32,
+            num_layers=2, num_heads=4, num_kv_heads=4, head_dim=16,
+            max_position_embeddings=512, rope_theta=10000.0,
+            rms_norm_eps=1e-5, qk_norm="projection", family=family,
+            num_experts=8, num_experts_per_tok=2, norm_topk_prob=False,
+            router_aux_loss_coef=0.01,
+        )
     return ModelConfig(
         vocab_size=vocab_size,
         hidden_size=64,
@@ -234,7 +254,7 @@ def tiny_config(family: str = "qwen2", vocab_size: int = 128) -> ModelConfig:
         rms_norm_eps=1e-6,
         tie_word_embeddings=False,
         attention_bias=(family in ("qwen2", "qwen2_moe")),
-        use_qk_norm=(family in ("qwen3", "qwen3_moe")),
+        qk_norm=_QK_NORM.get(family),
         family=family,
         hidden_act="gelu_tanh" if family == "gemma" else "silu",
         norm_add_unit_offset=(family == "gemma"),

@@ -28,7 +28,8 @@ def packed_forward(
     remat: bool = True,
     remat_save_attn: bool = True,
     attend_fn: Optional[Any] = None,
-    return_router_loss: bool = False,
+    moe_fn: Optional[Any] = None,
+    return_moe_stats: bool = False,
     return_hidden: bool = False,
     act_sharding: Optional[Any] = None,
 ):
@@ -74,7 +75,8 @@ def packed_forward(
         remat=remat,
         remat_save_attn=remat_save_attn,
         attend_fn=attend_fn,
-        return_router_loss=return_router_loss,
+        moe_fn=moe_fn,
+        return_moe_stats=return_moe_stats,
         return_hidden=return_hidden,
         act_sharding=act_sharding,
         **kwargs,

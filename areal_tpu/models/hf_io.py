@@ -75,6 +75,14 @@ _MOE_MAPS = {
         "w_up": ("mlp.experts.{e}.up_proj.weight", True),
         "w_down": ("mlp.experts.{e}.down_proj.weight", True),
     },
+    # olmoe names its router and experts as qwen3_moe does; its q_norm and
+    # k_norm (in _LAYER_MAP) are [q_dim] and [kv_dim] wide, not [head_dim]
+    "olmoe": {
+        "w_router": ("mlp.gate.weight", True),
+        "w_gate": ("mlp.experts.{e}.gate_proj.weight", True),
+        "w_up": ("mlp.experts.{e}.up_proj.weight", True),
+        "w_down": ("mlp.experts.{e}.down_proj.weight", True),
+    },
     "qwen2_moe": {
         "w_router": ("mlp.gate.weight", True),
         "w_gate": ("mlp.experts.{e}.gate_proj.weight", True),
@@ -309,6 +317,7 @@ def default_hf_config_dict(cfg: ModelConfig) -> dict:
             "mixtral": ["MixtralForCausalLM"],
             "qwen2_vl": ["Qwen2VLForConditionalGeneration"],
             "gemma": ["GemmaForCausalLM"],
+            "olmoe": ["OlmoeForCausalLM"],
         }.get(cfg.family, ["LlamaForCausalLM"]),
         **(
             {"hidden_act": "gelu_pytorch_tanh",
@@ -343,7 +352,12 @@ def default_hf_config_dict(cfg: ModelConfig) -> dict:
                 "num_experts": cfg.num_experts,
                 "num_local_experts": cfg.num_experts,
                 "num_experts_per_tok": cfg.num_experts_per_tok,
-                "moe_intermediate_size": cfg.expert_ffn_size,
+                # olmoe and mixtral have no such key: an expert's width is
+                # their intermediate_size
+                **(
+                    {"moe_intermediate_size": cfg.moe_intermediate_size}
+                    if cfg.moe_intermediate_size else {}
+                ),
                 "norm_topk_prob": cfg.norm_topk_prob,
                 "router_aux_loss_coef": cfg.router_aux_loss_coef,
                 **(

@@ -25,6 +25,7 @@ import numpy as np
 from jax import ad_checkpoint as _ad_checkpoint
 
 from areal_tpu.models.config import ModelConfig
+from areal_tpu.ops.moe import MoEStats
 from areal_tpu.utils.tracing import device_scope
 from areal_tpu.ops.basic import (
     apply_mrope,
@@ -94,9 +95,14 @@ def init_params(
         layers["bq"] = jnp.zeros((L, Qd), dtype)
         layers["bk"] = jnp.zeros((L, KVd), dtype)
         layers["bv"] = jnp.zeros((L, KVd), dtype)
-    if cfg.use_qk_norm:
-        layers["q_norm"] = jnp.ones((L, cfg.head_dim), dtype)
-        layers["k_norm"] = jnp.ones((L, cfg.head_dim), dtype)
+    if cfg.qk_norm:  # one weight a head, or one over the whole projection
+        per_head = cfg.qk_norm == "head"
+        layers["q_norm"] = jnp.ones(
+            (L, cfg.head_dim if per_head else Qd), dtype
+        )
+        layers["k_norm"] = jnp.ones(
+            (L, cfg.head_dim if per_head else KVd), dtype
+        )
     params: Params = {
         "embedding": nrm(keys[7], (cfg.vocab_size, D), std),
         "layers": layers,
@@ -153,7 +159,7 @@ def param_logical_axes(cfg: ModelConfig, value_head: bool = False) -> Params:
         layers["bq"] = ("layer", "heads")
         layers["bk"] = ("layer", "heads")
         layers["bv"] = ("layer", "heads")
-    if cfg.use_qk_norm:
+    if cfg.qk_norm:
         layers["q_norm"] = ("layer", None)
         layers["k_norm"] = ("layer", None)
     axes: Params = {
@@ -184,6 +190,7 @@ def _layer_body(
     cos: jnp.ndarray,
     sin: jnp.ndarray,
     attend_fn: Optional[Any] = None,
+    moe_fn: Optional[Any] = None,
 ):
     b, t, d = x.shape
     uo = cfg.norm_add_unit_offset
@@ -195,10 +202,13 @@ def _layer_body(
         q = q + lp["bq"]
         k = k + lp["bk"]
         v = v + lp["bv"]
+    if cfg.qk_norm == "projection":  # olmoe: before the split into heads
+        q = rms_norm(q, lp["q_norm"], cfg.rms_norm_eps)
+        k = rms_norm(k, lp["k_norm"], cfg.rms_norm_eps)
     q = q.reshape(b, t, cfg.num_heads, cfg.head_dim)
     k = k.reshape(b, t, cfg.num_kv_heads, cfg.head_dim)
     v = v.reshape(b, t, cfg.num_kv_heads, cfg.head_dim)
-    if cfg.use_qk_norm:
+    if cfg.qk_norm == "head":  # qwen3: each head by itself
         q = rms_norm(q, lp["q_norm"], cfg.rms_norm_eps)
         k = rms_norm(k, lp["k_norm"], cfg.rms_norm_eps)
     if positions.ndim == 3:  # [B, T, 3] multimodal (t, h, w) positions
@@ -229,20 +239,23 @@ def _layer_body(
             shared_expert_from_params,
         )
 
-        # padding tokens (segment 0) must not consume expert capacity
+        # padding tokens (segment 0) are no expert's load
         with device_scope("mlp"):
-            ffn, aux = moe_ffn_from_params(
-                cfg, lp, h, valid=segment_ids > 0
-            )
+            if moe_fn is None:
+                ffn, stats = moe_ffn_from_params(
+                    cfg, lp, h, valid=segment_ids > 0
+                )
+            else:  # per device under a mesh (a shard_map of the same)
+                ffn, stats = moe_fn(lp, h, segment_ids > 0)
             if cfg.shared_expert_size:
                 ffn = ffn + shared_expert_from_params(cfg, lp, h)
-        return x + ffn, aux
+        return x + ffn, stats
     with device_scope("mlp"):
         ffn = (
             hidden_act_fn(cfg.hidden_act)(h @ lp["w_gate"])
             * (h @ lp["w_up"])
         ) @ lp["w_down"]
-    return x + ffn, jnp.zeros((), jnp.float32)
+    return x + ffn, MoEStats(*(jnp.zeros((), jnp.float32),) * 3)
 
 
 def apply(
@@ -254,15 +267,22 @@ def apply(
     remat: bool = True,
     remat_save_attn: bool = True,
     attend_fn: Optional[Any] = None,
-    return_router_loss: bool = False,
+    moe_fn: Optional[Any] = None,
+    return_moe_stats: bool = False,
     mm_embeds: Optional[jnp.ndarray] = None,  # [B, N, D] vision embeds
     mm_index: Optional[jnp.ndarray] = None,  # [B, T] int32; -1 = text
     return_hidden: bool = False,  # lazy ChunkedLogits instead of [B,T,V]
     act_sharding: Optional[Any] = None,  # NamedSharding for [B, T, D] acts
 ):
     """Forward to logits [B, T, vocab] (fp32); with
-    ``return_router_loss=True`` returns (logits, mean per-layer MoE
-    load-balancing loss — 0.0 for dense models).
+    ``return_moe_stats=True`` returns (logits, ``ops.moe.MoEStats`` of the
+    whole stack: the mean per-layer load-balancing loss, and the
+    assignments and the busiest experts' loads summed over the layers —
+    zeros for dense models).
+
+    `moe_fn(lp, h, valid)` overrides the sparse layer's call the way
+    `attend_fn` overrides attention: the engine's per-device ``shard_map``
+    of ``moe_ffn_from_params`` under a mesh.
 
     `attend_fn(q, k, v, segment_ids)` overrides the attention kernel (e.g.
     ring / Ulysses shard_map from ops/ring_attention.py); default is the
@@ -299,7 +319,8 @@ def apply(
 
     def body(carry, lp):
         out, aux = _layer_body(
-            cfg, carry, lp, segment_ids, positions, cos, sin, attend_fn
+            cfg, carry, lp, segment_ids, positions, cos, sin, attend_fn,
+            moe_fn,
         )
         if act_sharding is not None:
             out = jax.lax.with_sharding_constraint(out, act_sharding)
@@ -335,8 +356,12 @@ def apply(
     else:
         with device_scope("lm_head"):
             logits = (x.astype(jnp.float32)) @ head.astype(jnp.float32)
-    if return_router_loss:
-        return logits, jnp.mean(aux)
+    if return_moe_stats:
+        return logits, MoEStats(
+            aux_loss=jnp.mean(aux.aux_loss),
+            assignments=jnp.sum(aux.assignments),
+            max_expert_assignments=jnp.sum(aux.max_expert_assignments),
+        )
     return logits
 
 

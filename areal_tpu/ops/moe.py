@@ -1,26 +1,61 @@
-"""Mixture-of-Experts FFN: top-k routing with blocked capacity dispatch.
+"""Mixture-of-Experts FFN: top-k routing, every (token, expert) pair computed.
 
 Role of reference realhf/impl/model/modules/moe/{router,experts,
-token_dispatcher,layer}.py (top-k router + grouped GEMM + all-to-all token
-dispatcher), re-designed TPU-first: instead of a device-side all-to-all of
-ragged token groups, tokens dispatch into fixed-capacity per-expert slots
-via one-hot einsums — every shape static, XLA lowers the dispatch/combine
-einsums to gathers/scatters and, with expert weights sharded on the
-"expert" mesh axis, inserts the EP collectives itself.
+token_dispatcher,layer}.py (top-k router + grouped GEMM + token
+dispatcher), TPU-first and dropless: the pairs of one call are sorted by
+expert, the three expert matmuls run as GROUPED matmuls over the ragged
+groups (one group an expert, as long as that expert's load), and the result
+is un-permuted and summed with the routing weights. There is no capacity and
+nothing is dropped, so a token's output never depends on what it was packed
+with: the recomputed logprobs, the gradient pass and the generation engine
+agree whatever their batches (a capped dispatch made them differ by
+construction). Every shape is static: the sorted stream always has
+tokens x k rows, and the group sizes are data.
 
-Capacity is enforced per fixed-size token BLOCK (the dispatch tensor is
-[block, k, E, C]; blocking keeps it ~MBs instead of GBs for long packed
-streams). Tokens over a block's per-expert capacity are dropped (standard
-Switch/GShard semantics — the residual stream carries them unchanged).
-A Pallas ragged-dispatch kernel (megablox analog) can slot in behind this
-same interface later for dropless MoE.
+Padding (``valid`` false) takes no part: its pairs sort behind every group,
+are zeroed on the way in and on the way out, and count in no statistic.
+
+Which grouped matmul runs is resolved from the platform (``grouped_impl``),
+as the trainer's ``attn_impl: auto`` is: the Pallas ``megablox`` kernel on a
+TPU, ``jax.lax.ragged_dot`` elsewhere (the CPU's path and the form the
+kernel is held equal to). Under a device mesh the caller runs ``moe_ffn``
+per device inside a ``shard_map`` (``SPMDTrainEngine._moe_fn``): a sort over
+a token axis that GSPMD has sharded would gather it.
+
+Device scopes (``utils/tracing.DEVICE_SCOPES``), nested inside the layer's
+``mlp``: ``moe_route`` (router matmul, softmax, top-k, load statistics),
+``moe_dispatch`` (sort, permute, un-permute, weighted sum), ``moe_experts``
+(the grouped matmuls and the gate).
 """
 
-import functools
-from typing import Dict, Optional, Tuple
+from typing import Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+
+from areal_tpu.utils.tracing import device_scope
+
+# megablox tiles (m, k, n). m must divide the sorted stream, so a stream
+# that is not a whole number of tiles takes the XLA form. Measured on the
+# v5e at OLMoE's widths (4096 tokens x 8 of 64 experts, D 2048, F 1024;
+# ``tools/microbench_moe.py``, PERF.md PR 27), the three matmuls forward /
+# forward + backward in ms: (256, 1024, 1024) 4.00 / 12.2; (128, 1024, 1024)
+# 5.09 / 14.4; (512, 1024, 1024) 4.40 / 13.7; (512, 512, 1024) 4.79 / 15.6;
+# (512, 2048, 1024) 5.55 / 18.6; (1024, 1024, 1024) 6.45 / 19.7;
+# ``ragged_dot`` 5.6 / 24.2 (outside any scope). A group is 440 rows on
+# average: a row tile of 256 wastes less of each group's last tile than 512,
+# and 128 pays more steps than it saves.
+_GMM_TILE_M = 256
+_GMM_TILING = (_GMM_TILE_M, 1024, 1024)
+
+
+class MoEStats(NamedTuple):
+    """What one MoE layer reports beside its output (all float32 scalars,
+    so a ``scan`` over layers stacks them and a sum makes the step's)."""
+
+    aux_loss: jnp.ndarray  # Switch-style load-balancing loss
+    assignments: jnp.ndarray  # valid tokens x k
+    max_expert_assignments: jnp.ndarray  # the busiest expert's count
 
 
 def router_topk(
@@ -43,20 +78,130 @@ def load_balancing_loss(
     topk_idx: jnp.ndarray,  # [G, k]
     num_experts: int,
     valid: Optional[jnp.ndarray] = None,  # [G] bool
+    token_axes: Optional[Tuple[str, ...]] = None,
 ) -> jnp.ndarray:
     """Switch-style aux loss: E * Σ_e f_e · P_e, where f_e is the fraction
     of (valid) tokens routed to e and P_e their mean router prob
-    (reference modules/moe/router.py aux losses)."""
+    (reference modules/moe/router.py aux losses). ``token_axes``: the mesh
+    axes the tokens are split over when called per device, so that the
+    fractions are the whole batch's."""
     assign = jax.nn.one_hot(topk_idx, num_experts, dtype=jnp.float32)
-    if valid is None:
-        f = assign.sum(1).mean(0)  # [E] fraction (sums to k)
-        p = probs.mean(0)  # [E]
-    else:
-        w = valid.astype(jnp.float32)[:, None]
-        denom = jnp.maximum(w.sum(), 1.0)
-        f = (assign.sum(1) * w).sum(0) / denom
-        p = (probs * w).sum(0) / denom
+    w = (
+        jnp.ones((probs.shape[0], 1), jnp.float32) if valid is None
+        else valid.astype(jnp.float32)[:, None]
+    )
+    sums = ((assign.sum(1) * w).sum(0), (probs * w).sum(0), w.sum())
+    if token_axes:
+        sums = jax.lax.psum(sums, token_axes)
+    denom = jnp.maximum(sums[2], 1.0)
+    f, p = sums[0] / denom, sums[1] / denom
     return num_experts * jnp.sum(f * p) / topk_idx.shape[-1]
+
+
+def grouped_impl(shape: Optional[Tuple[int, int, int]] = None) -> str:
+    """The grouped matmul this process runs: ``"megablox"`` on a TPU,
+    ``"ragged_dot"`` elsewhere, and for a ``shape`` (rows of the sorted
+    stream, model width, expert width) the kernel's tiles do not divide (a
+    decode step's few rows, a test-sized model)."""
+    if jax.default_backend() != "tpu":
+        return "ragged_dot"
+    if shape is not None and (
+        shape[0] % _GMM_TILE_M or shape[1] % 128 or shape[2] % 128
+    ):
+        return "ragged_dot"
+    return "megablox"
+
+
+def _grouped_matmul(
+    lhs: jnp.ndarray,  # [M, K], rows sorted by group
+    rhs: jnp.ndarray,  # [E, K, N]
+    group_sizes: jnp.ndarray,  # [E] int32, sum <= M
+    impl: str,
+) -> jnp.ndarray:
+    """[M, N] in ``lhs``'s dtype, float32 accumulation. Rows behind the
+    last group are the caller's to zero (the kernel leaves them unwritten)."""
+    if impl == "megablox":
+        from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
+
+        return megablox.gmm(
+            lhs, rhs, group_sizes, lhs.dtype, _GMM_TILING
+        )
+    return jax.lax.ragged_dot(
+        lhs, rhs, group_sizes, preferred_element_type=jnp.float32
+    ).astype(lhs.dtype)
+
+
+# The two permutations of the dispatch, each with its own differentiation
+# rule: the transpose of a row gather is a scatter-add, which the TPU runs
+# row by row, and for a permutation it is just the gather by the inverse.
+# ``order[i]`` is the pair at sorted position i, ``inv[j]`` the sorted
+# position of pair j (pair j = token j // k, slot j % k), ``pair_ok[j]``
+# whether pair j's token is real. Real pairs sort before padding's, so
+# sorted position i lies in a group iff i < pair_ok.sum(). Rows behind the
+# groups come out of the grouped matmul unwritten: they are never read
+# forward (zeros go in, a select takes them out) nor backward.
+@jax.custom_vjp
+def _dispatch(xf, order, inv, pair_ok):
+    """[G, D] tokens -> [G*k, D] rows sorted by expert, zeros behind the
+    groups (gathered from one zero row: no pass of its own)."""
+    g, k = xf.shape[0], order.shape[0] // xf.shape[0]
+    in_group = jnp.arange(order.shape[0]) < pair_ok.sum()
+    src = jnp.concatenate([xf, jnp.zeros((1, xf.shape[1]), xf.dtype)])
+    return src[jnp.where(in_group, order // k, g)]
+
+
+def _dispatch_fwd(xf, order, inv, pair_ok):
+    return _dispatch(xf, order, inv, pair_ok), (inv, pair_ok, xf.shape[0])
+
+
+def _dispatch_bwd(res, g_xs):
+    inv, pair_ok, g = res
+    g_pairs = jnp.where(pair_ok[:, None], g_xs[inv], 0)
+    g_xf = g_pairs.reshape(g, -1, g_xs.shape[-1]).sum(1, dtype=jnp.float32)
+    return g_xf.astype(g_xs.dtype), None, None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+def _weighted_sum(ys, w, inv, pair_ok):
+    """(out [G, D], each token's k un-permuted rows [G, k, D])."""
+    y = jnp.where(pair_ok[:, None], ys[inv], 0).reshape(
+        w.shape[0], -1, ys.shape[-1]
+    )
+    out = jnp.einsum(
+        "gkd,gk->gd", y, w.astype(y.dtype), preferred_element_type=jnp.float32
+    )
+    return out.astype(ys.dtype), y
+
+
+@jax.custom_vjp
+def _combine(ys, w, order, inv, pair_ok):
+    """[G*k, D] sorted expert outputs and [G, k] float32 routing weights ->
+    [G, D]: each token's k rows, un-permuted and summed with its weights."""
+    return _weighted_sum(ys, w, inv, pair_ok)[0]
+
+
+def _combine_fwd(ys, w, order, inv, pair_ok):
+    out, y = _weighted_sum(ys, w, inv, pair_ok)
+    return out, (y, w, order, pair_ok)
+
+
+def _combine_bwd(res, g_out):
+    y, w, order, pair_ok = res
+    k = w.shape[1]
+    g_w = jnp.einsum(
+        "gd,gkd->gk", g_out, y, preferred_element_type=jnp.float32
+    ).astype(w.dtype)
+    # row i of the sorted stream belongs to pair order[i]: its token's
+    # cotangent times that pair's weight (0 for padding's pairs, which are
+    # exactly the rows behind the groups)
+    wm = jnp.where(pair_ok, w.reshape(-1), 0).astype(g_out.dtype)
+    g_ys = g_out[order // k] * wm[order][:, None]
+    return g_ys, g_w, None, None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
 
 
 def moe_ffn(
@@ -67,99 +212,71 @@ def moe_ffn(
     w_down: jnp.ndarray,  # [E, F, D]
     num_experts_per_tok: int,
     norm_topk_prob: bool = True,
-    capacity_factor: float = 1.25,
-    block: int = 1024,
     valid: Optional[jnp.ndarray] = None,  # [B, T] bool
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Returns (output [B, T, D], aux_loss scalar fp32).
-
-    ``valid`` masks padding / inactive tokens OUT of dispatch entirely —
-    they consume no expert capacity (identical padding embeddings would
-    otherwise all route to the same experts and displace real tokens)."""
+    token_axes: Optional[Tuple[str, ...]] = None,
+) -> Tuple[jnp.ndarray, MoEStats]:
+    """Returns (output [B, T, D], ``MoEStats``). ``valid`` masks padding
+    and inactive slots out: zero output, no load, no statistic.
+    ``token_axes`` names the mesh axes the tokens are split over when this
+    runs per device inside a ``shard_map``: the statistics (and the
+    auxiliary loss) are then the whole batch's, summed over those axes."""
     b, t, d = x.shape
     e = w_router.shape[-1]
     k = num_experts_per_tok
     xf = x.reshape(-1, d)  # [G, D]
     g = xf.shape[0]
-    logits = xf.astype(jnp.float32) @ w_router.astype(jnp.float32)
-    topk_p, topk_i, probs = router_topk(logits, k, norm_topk_prob)
     vf = None if valid is None else valid.reshape(-1)
-    aux = load_balancing_loss(probs, topk_i, e, valid=vf)
-    vmask = (
-        jnp.ones((g,), jnp.float32)
-        if vf is None
-        else vf.astype(jnp.float32)
-    )
-
-    blk = min(block, g)
-    pad = (-g) % blk
-    if pad:
-        xf = jnp.concatenate([xf, jnp.zeros((pad, d), xf.dtype)])
-        topk_p = jnp.concatenate(
-            [topk_p, jnp.zeros((pad, k), topk_p.dtype)]
+    with device_scope("moe_route"):
+        # float32 and full precision: with near-equal probabilities a
+        # bf16-pass product can swap a token's k-th and (k+1)-th expert
+        logits = jnp.dot(
+            xf.astype(jnp.float32), w_router.astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST,
         )
-        # padding routes to expert 0 with zero combine weight
-        topk_i = jnp.concatenate(
-            [topk_i, jnp.zeros((pad, k), topk_i.dtype)]
+        topk_p, topk_i, probs = router_topk(logits, k, norm_topk_prob)
+        aux = load_balancing_loss(
+            probs, topk_i, e, valid=vf, token_axes=token_axes
         )
-        vmask = jnp.concatenate([vmask, jnp.zeros((pad,), jnp.float32)])
-    nb = xf.shape[0] // blk
-    cap = max(8, int(blk * k * capacity_factor / e + 0.5))
-    cap = min(cap, blk * k)
-
-    def per_block(xb, ib, pb, vb):
-        # xb [blk, D], ib [blk, k], pb [blk, k], vb [blk]
-        # invalid tokens get a zero routing mask: no capacity, no output
-        mask = (
-            jax.nn.one_hot(ib, e, dtype=jnp.float32) * vb[:, None, None]
-        )  # [blk, k, E]
-        # position of each (token, slot) within its expert's capacity:
-        # exclusive cumulative count in (token-major, slot-minor) order
-        flat = mask.reshape(blk * k, e)
-        pos = (jnp.cumsum(flat, axis=0) - flat).reshape(blk, k, e)
-        keep = mask * (pos < cap)
-        disp = (
-            jax.nn.one_hot(pos.astype(jnp.int32), cap, dtype=jnp.float32)
-            * keep[..., None]
-        )  # [blk, k, E, C]
-        dd = disp.astype(xb.dtype)
-        exp_in = jnp.einsum(
-            "skec,sd->ecd", dd, xb, preferred_element_type=jnp.float32
-        ).astype(xb.dtype)  # [E, C, D]
+        # a pair of a padding token belongs to no expert: id E sorts last
+        pair_expert = topk_i.reshape(-1).astype(jnp.int32)  # [G*k]
+        if vf is not None:
+            pair_expert = jnp.where(jnp.repeat(vf, k), pair_expert, e)
+        group_sizes = jnp.sum(
+            pair_expert[:, None] == jnp.arange(e, dtype=jnp.int32)[None],
+            axis=0, dtype=jnp.int32,
+        )
+        load = (
+            jax.lax.psum(group_sizes, token_axes) if token_axes
+            else group_sizes
+        )
+        stats = MoEStats(
+            aux_loss=aux,
+            assignments=load.sum().astype(jnp.float32),
+            max_expert_assignments=load.max().astype(jnp.float32),
+        )
+    impl = grouped_impl((g * k, d, w_gate.shape[-1]))
+    pair_ok = pair_expert < e
+    with device_scope("moe_dispatch"):
+        order = jnp.argsort(pair_expert, stable=True)  # pairs by expert
+        inv = jnp.argsort(order)
+        xs = _dispatch(xf, order, inv, pair_ok)
+    with device_scope("moe_experts"):
         h = jax.nn.silu(
-            jnp.einsum(
-                "ecd,edf->ecf", exp_in, w_gate,
-                preferred_element_type=jnp.float32,
-            )
-        ) * jnp.einsum(
-            "ecd,edf->ecf", exp_in, w_up,
-            preferred_element_type=jnp.float32,
+            _grouped_matmul(xs, w_gate, group_sizes, impl)
+        ) * _grouped_matmul(xs, w_up, group_sizes, impl)
+        in_group = (jnp.arange(g * k) < group_sizes.sum())[:, None]
+        ys = _grouped_matmul(
+            jnp.where(in_group, h, 0), w_down, group_sizes, impl
         )
-        out_e = jnp.einsum(
-            "ecf,efd->ecd", h.astype(xb.dtype), w_down,
-            preferred_element_type=jnp.float32,
-        )  # [E, C, D] fp32
-        comb = dd * pb[:, :, None, None].astype(xb.dtype)
-        out = jnp.einsum(
-            "skec,ecd->sd", comb, out_e.astype(xb.dtype),
-            preferred_element_type=jnp.float32,
-        )
-        return out.astype(xb.dtype)
-
-    out = jax.vmap(per_block)(
-        xf.reshape(nb, blk, d),
-        topk_i.reshape(nb, blk, k),
-        topk_p.reshape(nb, blk, k),
-        vmask.reshape(nb, blk),
-    ).reshape(-1, d)
-    if pad:
-        out = out[:g]
-    return out.reshape(b, t, d), aux
+    with device_scope("moe_dispatch"):
+        out = _combine(ys, topk_p, order, inv, pair_ok).astype(x.dtype)
+    return out.reshape(b, t, d), stats
 
 
 def moe_ffn_from_params(
-    cfg, lp: Dict, h: jnp.ndarray, valid: Optional[jnp.ndarray] = None
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    cfg, lp: Dict, h: jnp.ndarray, valid: Optional[jnp.ndarray] = None,
+    token_axes: Optional[Tuple[str, ...]] = None,
+) -> Tuple[jnp.ndarray, MoEStats]:
     """Shared dispatch for training and serving layer bodies — one place
     to evolve routing arguments."""
     return moe_ffn(
@@ -170,8 +287,8 @@ def moe_ffn_from_params(
         lp["w_down"],
         num_experts_per_tok=cfg.num_experts_per_tok,
         norm_topk_prob=cfg.norm_topk_prob,
-        capacity_factor=cfg.moe_capacity_factor,
         valid=valid,
+        token_axes=token_axes,
     )
 
 

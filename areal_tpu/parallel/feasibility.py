@@ -325,7 +325,36 @@ def qwen2_32b_config() -> ModelConfig:
     )
 
 
+def olmoe_1b_7b_config(num_layers: int = 5) -> ModelConfig:
+    """OLMoE-1B-7B-0125-Instruct geometry (allenai; 64 experts of width
+    1024 of which 8 a token, no shared expert, q/k normed over the whole
+    projection). Every width as published; the published depth is 16, and
+    the default here is the cut whose f32 + Adam trainer state fills one
+    four-chip v5e host (benchmark/configs/olmoe-1b-7b.json)."""
+    return ModelConfig(
+        vocab_size=50304,
+        hidden_size=2048,
+        intermediate_size=1024,
+        num_layers=num_layers,
+        num_heads=16,
+        num_kv_heads=16,
+        head_dim=128,
+        max_position_embeddings=4096,
+        rope_theta=10000,
+        rms_norm_eps=1e-5,
+        tie_word_embeddings=False,
+        attention_bias=False,
+        qk_norm="projection",
+        family="olmoe",
+        num_experts=64,
+        num_experts_per_tok=8,
+        norm_topk_prob=False,
+        router_aux_loss_coef=0.01,
+    )
+
+
 MODEL_CONFIGS = {
+    "olmoe_1b_7b": olmoe_1b_7b_config,
     "qwen2_0p5b": qwen2_0p5b_config,
     "qwen2_7b": qwen2_7b_config,
     "qwen2_1p5b": qwen2_1p5b_config,

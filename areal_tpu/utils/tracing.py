@@ -144,6 +144,8 @@ SPAN_PREFIX = "areal:"  # every span's name in the profiler's trace
 DEVICE_SCOPES = (
     "embed", "attention", "mlp", "lm_head", "loss", "optimizer", "sample",
     "kv_write",
+    # inside ``mlp`` where the layer is sparse (ops/moe.py); innermost wins
+    "moe_route", "moe_dispatch", "moe_experts",
 )
 
 

@@ -179,3 +179,40 @@ def test_the_probes_first_candidate_compiles_for_v5e(one_chip):
         .compile()
     )
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_sparse_layer_fwd_bwd_compiles_for_v5e(one_chip, monkeypatch):
+    """``ops/moe.moe_ffn`` forward and backward at OLMoE-1B-7B's published
+    widths on one 4096-token row (the four-chip cell's row a chip) through
+    the megablox grouped matmul with ``_GMM_TILING``: three ``gmm`` forward,
+    and three ``gmm`` + three ``tgmm`` backward. ``jax.default_backend()``
+    is the CPU here, so the platform's choice is set in the test."""
+    import jax
+    import jax.numpy as jnp
+
+    from areal_tpu.ops import moe
+
+    monkeypatch.setattr(moe, "grouped_impl", lambda shape=None: "megablox")
+    t, d, f, e, k = 4096, 2048, 1024, 64, 8
+    assert (t * k) % moe._GMM_TILE_M == 0
+
+    def loss(x, wr, wg, wu, wd, valid):
+        out, stats = moe.moe_ffn(
+            x, wr, wg, wu, wd, num_experts_per_tok=k, norm_topk_prob=False,
+            valid=valid,
+        )
+        return out.astype(jnp.float32).sum() + stats.aux_loss
+
+    compiled = (
+        jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4)))
+        .lower(
+            _sds((1, t, d), jnp.bfloat16, one_chip),
+            _sds((d, e), jnp.bfloat16, one_chip),
+            _sds((e, d, f), jnp.bfloat16, one_chip),
+            _sds((e, d, f), jnp.bfloat16, one_chip),
+            _sds((e, f, d), jnp.bfloat16, one_chip),
+            _sds((1, t), jnp.bool_, one_chip),
+        )
+        .compile()
+    )
+    assert compiled.as_text().count("tpu_custom_call") >= 9

@@ -29,7 +29,7 @@ from areal_tpu.utils import tracing
 S, BS, PPS = 4, 16, 4
 
 
-def _actor(remat=True):
+def _actor(remat=True, family="qwen2"):
     cfg = PPOActorConfig(
         dtype="float32", param_dtype="float32",
         gradient_checkpointing=remat,
@@ -43,7 +43,7 @@ def _actor(remat=True):
     )
     eng = SPMDTrainEngine(cfg)
     eng.initialize(
-        ft_spec=FinetuneSpec(1, 64, 8), model_config=tiny_config("qwen2"),
+        ft_spec=FinetuneSpec(1, 64, 8), model_config=tiny_config(family),
         seed=0,
     )
     return PPOActor(cfg, eng), eng
@@ -78,9 +78,9 @@ def _scopes_in(names):
     }
 
 
-def _train_programs():
+def _train_programs(family="qwen2"):
     """(name, lowered) of the trainer's three programs at the tiny config."""
-    actor, eng = _actor()
+    actor, eng = _actor(family=family)
     batch = actor.compute_advantages(_batch())
     batch["prox_logp"] = actor.compute_logp(batch) * batch["loss_mask"]
     _, arrays = eng._pack_for_device(batch)
@@ -131,15 +131,19 @@ WANT = {
     "apply_step": {"optimizer"},
     "_decode_multi_forward": {"attention", "mlp", "lm_head", "sample"},
     "write_rows": {"kv_write"},
+    # a sparse layer's three, inside its mlp (innermost wins in a profile)
+    "sparse_grad_step": {"mlp", "moe_route", "moe_dispatch", "moe_experts"},
 }
 
 
 @pytest.fixture(scope="module")
 def lowered():
-    return {
+    out = {
         name: _op_names(low)
         for name, low in _train_programs() + _decode_programs()
     }
+    out["sparse_grad_step"] = _op_names(_train_programs("olmoe")[0][1])
+    return out
 
 
 @pytest.mark.parametrize("program", sorted(WANT))
@@ -147,7 +151,8 @@ def test_program_lowers_under_its_contract_name_with_its_scopes(
     lowered, program
 ):
     text, names = lowered[program]
-    assert re.search(rf"^HloModule jit_{program}[, ]", text, re.M)
+    jitted = program.removeprefix("sparse_")
+    assert re.search(rf"^HloModule jit_{jitted}[, ]", text, re.M)
     assert WANT[program] <= _scopes_in(names), sorted(names)[:40]
 
 

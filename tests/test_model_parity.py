@@ -156,7 +156,7 @@ def test_llama_logits_match_hf(tmp_path):
 
 def test_qwen3_qk_norm_forward():
     cfg = tiny_config("qwen3")
-    assert cfg.use_qk_norm and not cfg.attention_bias
+    assert cfg.qk_norm == "head" and not cfg.attention_bias
     params = init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
     t = jnp.asarray(np.arange(8)[None] % cfg.vocab_size, jnp.int32)
     seg = jnp.ones((1, 8), jnp.int32)

@@ -41,94 +41,143 @@ def _ref_moe(x, w_router, w_gate, w_up, w_down, k, norm):
     return out
 
 
+def _weights(rng, d, f, e, scale=0.2):
+    return (
+        rng.standard_normal((d, e)).astype(np.float32) * 0.5,
+        rng.standard_normal((e, d, f)).astype(np.float32) * scale,
+        rng.standard_normal((e, d, f)).astype(np.float32) * scale,
+        rng.standard_normal((e, f, d)).astype(np.float32) * scale,
+    )
+
+
 def test_moe_ffn_matches_per_token_reference():
     rng = np.random.default_rng(0)
     b, t, d, f, e, k = 2, 12, 8, 16, 4, 2
     x = rng.standard_normal((b, t, d)).astype(np.float32)
-    wr = rng.standard_normal((d, e)).astype(np.float32) * 0.5
-    wg = rng.standard_normal((e, d, f)).astype(np.float32) * 0.2
-    wu = rng.standard_normal((e, d, f)).astype(np.float32) * 0.2
-    wd = rng.standard_normal((e, f, d)).astype(np.float32) * 0.2
-    out, aux = jax.jit(
+    wr, wg, wu, wd = _weights(rng, d, f, e)
+    out, stats = jax.jit(
         lambda *a: moe_ffn(
             *a, num_experts_per_tok=k, norm_topk_prob=True,
-            capacity_factor=8.0,  # generous: no drops → exact
         )
     )(jnp.asarray(x), jnp.asarray(wr), jnp.asarray(wg), jnp.asarray(wu),
       jnp.asarray(wd))
     ref = _ref_moe(x, wr, wg, wu, wd, k, norm=True)
     np.testing.assert_allclose(np.asarray(out), ref, rtol=2e-4, atol=2e-4)
-    assert np.isfinite(float(aux)) and float(aux) >= 1.0  # ≥1 by Cauchy-Schwarz
+    aux = float(stats.aux_loss)
+    assert np.isfinite(aux) and aux >= 1.0  # ≥1 by Cauchy-Schwarz
+    assert float(stats.assignments) == b * t * k
 
 
-def test_moe_capacity_drops_tokens():
-    """Tokens routed beyond an expert's per-block capacity contribute
-    ZERO (the residual stream carries them, Switch/GShard semantics);
-    tokens inside capacity are bit-identical to the uncapped run."""
+# how the router's load falls: nothing is ever dropped, so the dispatch
+# equals the per-token reference whatever the imbalance. ``router`` turns the
+# drawn router weights into the case's.
+_LOADS = {
+    "near_uniform": (2, lambda wr: wr * 0.01),
+    "skewed": (2, lambda wr: wr + np.array([6.0, 2.0, 0, 0, 0, 0], np.float32)),
+    # every token's first choice is expert 0: one group holds half the pairs
+    "one_expert_in_every_pair": (
+        2, lambda wr: wr * 0.1 + np.array([50.0, 0, 0, 0, 0, 0], np.float32)
+    ),
+    # k = 1 and one expert takes EVERY token: five groups are empty (the
+    # capped dispatch this replaces dropped all but its capacity here)
+    "all_tokens_on_one_expert": (
+        1, lambda wr: wr * 0.1 + np.array([0, 0, 0, 50.0, 0, 0], np.float32)
+    ),
+}
+
+
+@pytest.mark.parametrize("load", sorted(_LOADS))
+@pytest.mark.parametrize("norm", [False, True], ids=["raw", "renormalised"])
+def test_moe_dispatch_drops_nothing_under_any_load(load, norm):
+    k, router = _LOADS[load]
     rng = np.random.default_rng(1)
-    d, f, e = 8, 16, 2
-    x = rng.standard_normal((1, 16, d)).astype(np.float32)
-    wr = rng.standard_normal((d, e)).astype(np.float32)
-    wg = rng.standard_normal((e, d, f)).astype(np.float32)
-    wu = rng.standard_normal((e, d, f)).astype(np.float32)
-    wd = rng.standard_normal((e, f, d)).astype(np.float32)
-
-    def run(cf):
-        out, _ = moe_ffn(
-            jnp.asarray(x), jnp.asarray(wr), jnp.asarray(wg),
-            jnp.asarray(wu), jnp.asarray(wd),
-            num_experts_per_tok=1, capacity_factor=cf,
-        )
-        return np.asarray(out)[0]
-
-    small, big = run(0.5), run(8.0)  # caps: 8/expert vs unbounded
-    dropped = np.abs(small).sum(-1) < 1e-6
-    assert dropped.any(), "low capacity must drop some tokens"
-    assert not (np.abs(big).sum(-1) < 1e-6).any()
-    np.testing.assert_allclose(small[~dropped], big[~dropped], rtol=1e-4)
-    # dropped tokens are exactly the tail of the over-capacity expert
-    logits = x[0] @ wr
-    chosen = np.argmax(
-        np.exp(logits) / np.exp(logits).sum(-1, keepdims=True), axis=-1
-    )
-    for ei in range(e):
-        idx = np.nonzero(chosen == ei)[0]
-        assert not dropped[idx[:8]].any()  # first 8 per expert kept
-        assert dropped[idx[8:]].all()
-
-
-def test_moe_padding_does_not_steal_capacity():
-    """Invalid (padding / inactive-slot) tokens must consume NO expert
-    capacity: identical padding embeddings would otherwise all route to
-    the same experts and displace real tokens under tight capacity."""
-    rng = np.random.default_rng(2)
-    d, f, e = 8, 16, 2
-    real = rng.standard_normal((1, 8, d)).astype(np.float32)
-    pad = np.zeros((1, 24, d), np.float32)  # identical padding embeddings
-    x = np.concatenate([pad, real], axis=1)  # padding FIRST in flat order
-    valid = np.concatenate(
-        [np.zeros((1, 24), bool), np.ones((1, 8), bool)], axis=1
-    )
-    wr = rng.standard_normal((d, e)).astype(np.float32)
-    wg = rng.standard_normal((e, d, f)).astype(np.float32)
-    wu = rng.standard_normal((e, d, f)).astype(np.float32)
-    wd = rng.standard_normal((e, f, d)).astype(np.float32)
-
-    kw = dict(num_experts_per_tok=1, capacity_factor=1.0)
-    # capacity 1.0 on 32 tokens = 16/expert; 24 identical padding tokens
-    # would overflow one expert without masking
-    out_masked, _ = moe_ffn(
+    d, f, e = 8, 16, 6
+    # positive features, so a constant added to a router column moves
+    # every token the same way
+    x = np.abs(rng.standard_normal((2, 20, d))).astype(np.float32)
+    wr, wg, wu, wd = _weights(rng, d, f, e)
+    wr = router(wr).astype(np.float32)
+    out, stats = moe_ffn(
         jnp.asarray(x), jnp.asarray(wr), jnp.asarray(wg), jnp.asarray(wu),
-        jnp.asarray(wd), valid=jnp.asarray(valid), **kw,
+        jnp.asarray(wd), num_experts_per_tok=k, norm_topk_prob=norm,
     )
-    ref, _ = moe_ffn(
-        jnp.asarray(real), jnp.asarray(wr), jnp.asarray(wg),
-        jnp.asarray(wu), jnp.asarray(wd), **kw,
+    ref = _ref_moe(x, wr, wg, wu, wd, k, norm=norm)
+    np.testing.assert_allclose(np.asarray(out), ref, rtol=2e-4, atol=2e-4)
+    assert float(stats.assignments) == 40 * k
+    busiest = float(stats.max_expert_assignments)
+    if load == "all_tokens_on_one_expert":
+        assert busiest == 40.0
+    elif load == "one_expert_in_every_pair":
+        assert busiest == 40.0  # once in each of the 40 pairs of two
+    else:
+        assert 40 * k / e <= busiest < 40 * k
+
+
+def test_moe_dispatch_gradients_match_the_dense_form():
+    """The sorted, grouped form is differentiated through a custom
+    permutation rule and the grouped matmul's own: every gradient equals
+    that of the same sum written densely (all experts, zero weights off
+    the chosen ones)."""
+    rng = np.random.default_rng(3)
+    d, f, e, k = 8, 16, 6, 2
+    x = jnp.asarray(rng.standard_normal((1, 24, d)), jnp.float32)
+    ws = tuple(jnp.asarray(w) for w in _weights(rng, d, f, e))
+    valid = jnp.asarray(np.arange(24) % 5 != 0)[None]
+
+    def dense(x, wr, wg, wu, wd):
+        p = jax.nn.softmax(x @ wr, axis=-1)
+        chosen = jax.nn.one_hot(jax.lax.top_k(p, k)[1], e).sum(-2)
+        y = jnp.einsum(
+            "btef,efd->bted",
+            jax.nn.silu(jnp.einsum("btd,edf->btef", x, wg))
+            * jnp.einsum("btd,edf->btef", x, wu), wd,
+        )
+        return jnp.einsum("bte,bted->btd", p * chosen, y) * valid[..., None]
+
+    target = jnp.asarray(rng.standard_normal((1, 24, d)), jnp.float32)
+    got = jax.grad(
+        lambda *a: (moe_ffn(
+            *a, num_experts_per_tok=k, norm_topk_prob=False, valid=valid
+        )[0] * target).sum(), argnums=(0, 1, 2, 3, 4),
+    )(x, *ws)
+    want = jax.grad(
+        lambda *a: (dense(*a) * target).sum(), argnums=(0, 1, 2, 3, 4)
+    )(x, *ws)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(
+            np.asarray(g), np.asarray(w), rtol=2e-4, atol=2e-5
+        )
+
+
+@pytest.mark.parametrize("where", ["first", "last", "interleaved"])
+def test_moe_padding_gets_zero_and_moves_no_real_token(where):
+    """Invalid (padding / inactive-slot) tokens are no expert's load: they
+    come out exactly zero, count in no statistic, and a real token's output
+    is what it is alone — wherever the padding sits in the flat order, and
+    though identical padding embeddings all route to the same experts."""
+    rng = np.random.default_rng(2)
+    d, f, e, k = 8, 16, 4, 2
+    real = rng.standard_normal((1, 8, d)).astype(np.float32)
+    n = 32
+    at = {
+        "first": np.arange(24, 32), "last": np.arange(8),
+        "interleaved": np.arange(1, 32, 4),
+    }[where]
+    x = np.full((1, n, d), 0.7, np.float32)  # identical padding embeddings
+    x[0, at] = real[0]
+    valid = np.zeros((1, n), bool)
+    valid[0, at] = True
+    wr, wg, wu, wd = _weights(rng, d, f, e)
+    args = [jnp.asarray(w) for w in (wr, wg, wu, wd)]
+    kw = dict(num_experts_per_tok=k, norm_topk_prob=False)
+    out, stats = moe_ffn(jnp.asarray(x), *args, valid=jnp.asarray(valid), **kw)
+    alone, alone_stats = moe_ffn(jnp.asarray(real), *args, **kw)
+    np.testing.assert_allclose(
+        np.asarray(out)[0, at], np.asarray(alone)[0], rtol=1e-5, atol=1e-6
     )
-    got = np.asarray(out_masked)[0, 24:]
-    np.testing.assert_allclose(got, np.asarray(ref)[0], rtol=1e-4, atol=1e-5)
-    # and masked-out tokens contribute exactly nothing
-    assert np.abs(np.asarray(out_masked)[0, :24]).max() == 0.0
+    assert np.abs(np.asarray(out)[0, ~valid[0]]).max() == 0.0
+    for got, want in zip(stats, alone_stats):
+        np.testing.assert_allclose(float(got), float(want), rtol=1e-6)
 
 
 def test_moe_model_forward_and_ep_parity():
@@ -206,23 +255,52 @@ def test_moe_training_step_with_aux_loss():
     assert losses[-1] < losses[0]
 
 
-def test_moe_hf_io_roundtrip(tmp_path):
+@pytest.mark.parametrize("family", ["qwen3_moe", "olmoe"])
+def test_moe_hf_io_roundtrip(tmp_path, family):
     from areal_tpu.models import hf_io
     from areal_tpu.models.config import load_hf_config
 
-    cfg = tiny_config("qwen3_moe")
+    cfg = tiny_config(family)
     params = init_params(cfg, jax.random.PRNGKey(3), dtype=jnp.float32)
+    # q/k norms off their initial ones, so that a dropped or misshapen
+    # norm (olmoe's is as wide as the projection, qwen3's as a head) shows
+    rng = np.random.default_rng(3)
+    for key in ("q_norm", "k_norm"):
+        params["layers"][key] = jnp.asarray(
+            1.0 + 0.1 * rng.standard_normal(params["layers"][key].shape),
+            jnp.float32,
+        )
     path = str(tmp_path / "moe_ckpt")
     hf_io.save_params(params, cfg, path)
     cfg2 = load_hf_config(path)
-    assert cfg2.is_moe and cfg2.num_experts == cfg.num_experts
+    assert cfg2 == cfg  # family, widths, router settings, the q/k norm's kind
     loaded = hf_io.load_params(path, cfg2, dtype=jnp.float32)
-    for key in ("w_router", "w_gate", "w_up", "w_down"):
+    assert set(loaded["layers"]) == set(params["layers"])
+    for key in params["layers"]:
         np.testing.assert_allclose(
             np.asarray(loaded["layers"][key]),
             np.asarray(params["layers"][key]),
             rtol=1e-6,
         )
+    if family == "olmoe":
+        import json
+        import os
+
+        from safetensors import safe_open
+
+        with open(os.path.join(path, "config.json")) as f:
+            assert json.load(f)["architectures"] == ["OlmoeForCausalLM"]
+        with safe_open(os.path.join(path, "model.safetensors"), "numpy") as f:
+            names = set(f.keys())
+        assert {
+            "model.layers.0.mlp.gate.weight",
+            "model.layers.1.mlp.experts.7.down_proj.weight",
+            "model.layers.0.self_attn.q_norm.weight",
+            "model.layers.0.self_attn.k_norm.weight",
+            "lm_head.weight",
+        } <= names
+        assert f"model.layers.0.mlp.experts.{cfg.num_experts}.up_proj.weight" \
+            not in names
 
 
 def test_moe_generation_matches_full_forward():

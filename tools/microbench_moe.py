@@ -1,0 +1,139 @@
+"""The sparse layer alone, on one chip: ``ops/moe.moe_ffn`` (route, sort,
+grouped matmuls, un-permute) forward and forward + backward at a model's
+published widths, once through each grouped matmul (``megablox`` over a few
+tilings, ``ragged_dot``), all under one ``jax.profiler`` capture.
+
+    python -m tools.microbench_moe [--tokens 4096] [--out chiprun_out/moe_sweep]
+
+The times are device times of the jitted programs and of the device scopes
+``moe_route`` / ``moe_dispatch`` / ``moe_experts`` from
+``benchmark.program_trace`` (what the ``moe_*`` metrics are read by), not
+host clocks; the operation names of each variant are printed, since
+``moe_experts_roofline.train`` matches the kernels by name. ``ops/moe.py``'s
+``_GMM_TILING`` and ``grouped_impl`` hold what this found (PERF.md, PR 27).
+"""
+
+import argparse
+import json
+import os
+
+import areal_tpu  # noqa: F401 — the scoped-VMEM flag, before the backend
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from areal_tpu.ops import moe
+from benchmark import harness, program_trace, xplane
+
+RUNS = 3
+TILINGS = ((256, 1024, 1024), (128, 1024, 1024), (512, 1024, 1024),
+           (512, 512, 1024), (512, 2048, 1024), (1024, 1024, 1024))
+
+
+def main():
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--tokens", type=int, default=4096)
+    ap.add_argument("--widths", type=int, nargs=4, default=(2048, 1024, 64, 8),
+                    metavar=("D", "F", "E", "K"))
+    ap.add_argument("--out", default="chiprun_out/moe_sweep")
+    ap.add_argument("--tilings", type=int, default=len(TILINGS),
+                    help="the first so many of TILINGS")
+    args = ap.parse_args()
+    t, (d, f, e, k) = args.tokens, args.widths
+    rng = np.random.default_rng(0)
+    bf = jnp.bfloat16
+    x = jnp.asarray(rng.standard_normal((1, t, d)), bf)
+    ws = (
+        jnp.asarray(rng.standard_normal((d, e)) * 0.02, bf),
+        jnp.asarray(rng.standard_normal((e, d, f)) * 0.02, bf),
+        jnp.asarray(rng.standard_normal((e, d, f)) * 0.02, bf),
+        jnp.asarray(rng.standard_normal((e, f, d)) * 0.02, bf),
+    )
+    valid = jnp.asarray(np.arange(t) < int(t * 0.86))[None]  # 14% padding
+    flops = 2.0 * int(t * 0.86) * k * 3 * d * f
+
+    def variant(name, impl, tiling):
+        def fwd(x, *ws):
+            return moe.moe_ffn(x, *ws, num_experts_per_tok=k,
+                               norm_topk_prob=False, valid=valid)[0]
+
+        def loss(x, *ws):
+            return fwd(x, *ws).astype(jnp.float32).sum()
+
+        fwd.__name__ = f"fwd_{name}"
+        bwd = jax.grad(loss, argnums=(0, 1, 2, 3, 4))
+        both = lambda *a: bwd(*a)  # noqa: E731
+        both.__name__ = f"fwdbwd_{name}"
+        return impl, tiling, jax.jit(fwd), jax.jit(both)
+
+    todo = [variant("ragged_dot", "ragged_dot", None)] + [
+        variant("gmm_%d_%d_%d" % tl, "megablox", tl)
+        for tl in TILINGS[:args.tilings]
+    ]
+    real_impl = moe.grouped_impl
+    outs, ok = {}, []
+    for impl, tiling, fwd, both in todo:  # compile and check, untraced
+        moe.grouped_impl = lambda shape=None, impl=impl: impl
+        if tiling:
+            moe._GMM_TILING = tiling
+        try:
+            outs[fwd.__name__] = np.asarray(fwd(x, *ws), np.float32)
+            jax.block_until_ready(both(x, *ws))
+            ok.append((impl, tiling, fwd, both))
+        except Exception as err:  # noqa: BLE001 — a tiling the chip refuses
+            harness.log(f"{fwd.__name__}: refused: {type(err).__name__}: "
+                        f"{str(err)[:300]}")
+    base = outs["fwd_ragged_dot"]
+    for name, o in outs.items():
+        harness.log(f"{name}: max |out - ragged_dot| = "
+                    f"{np.abs(o - base).max():.4g}, finite={np.isfinite(o).all()}")
+    traced = harness.TracedWindow(True, os.path.join(harness.ROOT, args.out))
+    with traced:
+        for impl, tiling, fwd, both in ok:
+            moe.grouped_impl = lambda shape=None, impl=impl: impl
+            if tiling:
+                moe._GMM_TILING = tiling
+            for _ in range(RUNS):
+                jax.block_until_ready(fwd(x, *ws))
+                jax.block_until_ready(both(x, *ws))
+    moe.grouped_impl = real_impl
+    path = xplane.find_xplane(traced.dir)
+    events = program_trace.load(path)
+    r = program_trace.reduce(events)
+    rows = {}
+    for prog, p in sorted(r["programs"].items()):
+        if not prog.startswith(("fwd_", "fwdbwd_")):
+            continue
+        ms = 1e3 * p["seconds"] / max(p["runs"], 1)
+        sc = {s: round(1e3 * v / max(p["runs"], 1), 3)
+              for s, v in r["program_scopes"].get(prog, {}).items()}
+        passes = 1 if prog.startswith("fwd_") else 3
+        ex = sc.get("moe_experts", 0.0)
+        rows[prog] = {"ms": round(ms, 3), "scopes_ms": sc,
+                      "experts_tflops": round(passes * flops / (ex * 1e-3) / 1e12, 1)
+                      if ex else None}
+        print(prog, json.dumps(rows[prog]), flush=True)
+    # the names the kernels' events carry, per program
+    names = {}
+    for ev in events:
+        if ev[1] == xplane.OPS_LINE and ev[5].get("program", "").startswith("fwd"):
+            if program_trace.scope_of(ev[5].get("op_name")) == "moe_experts":
+                names.setdefault(ev[5]["program"], set()).add(ev[2].split(" ")[0])
+    for prog, ns in sorted(names.items()):
+        print("ops under moe_experts in", prog, sorted(ns)[:12], flush=True)
+    # does the device's peak_bytes_in_use count a program's temporaries?
+    dev = jax.devices()[0]
+    before = (dev.memory_stats() or {}).get("peak_bytes_in_use", 0)
+    big = jax.jit(lambda a: jnp.cumsum(  # a 4.3 GB product, materialised
+        jnp.dot(a, a.T, preferred_element_type=jnp.float32), axis=0).sum())
+    jax.block_until_ready(big(jnp.ones((32768, 512), bf)))
+    after = (dev.memory_stats() or {}).get("peak_bytes_in_use", 0)
+    print("peak_bytes_in_use before/after a program with large temporaries:",
+          before, after, flush=True)
+    with open(os.path.join(harness.ROOT, args.out, "sweep.json"), "w") as fh:
+        json.dump(rows, fh, indent=1)
+
+
+if __name__ == "__main__":
+    main()
