@@ -344,16 +344,18 @@ def apply(
     if "value_head" in params:
         # critic: scalar head — "logits" [B, T, 1] (value per position);
         # tiny, never worth the lazy view
-        head = params["value_head"]
-    elif cfg.tie_word_embeddings:
-        head = params["embedding"].T
+        head, vocab_axis = params["value_head"], 1
+    elif cfg.tie_word_embeddings:  # the [V, D] table as it is stored
+        head, vocab_axis = params["embedding"], 0
     else:
-        head = params["lm_head"]
+        head, vocab_axis = params["lm_head"], 1
     if return_hidden and "value_head" not in params:
         from areal_tpu.ops.chunked_head import ChunkedLogits
 
-        logits = ChunkedLogits(x, head)
+        logits = ChunkedLogits(x, head, vocab_axis)
     else:
+        if vocab_axis == 0:
+            head = head.T
         with device_scope("lm_head"):
             logits = (x.astype(jnp.float32)) @ head.astype(jnp.float32)
     if return_moe_stats:

@@ -27,7 +27,8 @@ def gather_logprobs(
 
     if isinstance(logits, ChunkedLogits):
         return chunked_gather_logprobs(
-            logits.hidden, logits.head, labels, temperature=temperature
+            logits.hidden, logits.head, labels, temperature=temperature,
+            vocab_axis=logits.vocab_axis,
         )
     if temperature != 1.0:
         logits = logits / temperature
@@ -48,8 +49,8 @@ def gather_logprobs_entropy(
 
     if isinstance(logits, ChunkedLogits):
         return chunked_gather_logprobs(
-            logits.hidden, logits.head, labels,
-            temperature=temperature, with_entropy=True,
+            logits.hidden, logits.head, labels, temperature=temperature,
+            with_entropy=True, vocab_axis=logits.vocab_axis,
         )
     if temperature != 1.0:
         logits = logits / temperature

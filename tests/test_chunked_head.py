@@ -2,6 +2,7 @@
 engine's loss paths run on the lazy view (ops/chunked_head.py)."""
 
 import numpy as np
+import pytest
 
 import jax
 import jax.numpy as jnp
@@ -54,6 +55,81 @@ def test_matches_full_logits_and_grads():
                                rtol=1e-4, atol=1e-5)
 
 
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("tied", [False, True], ids=["head_DV", "table_VD"])
+@pytest.mark.parametrize("with_entropy", [False, True], ids=["logp", "entropy"])
+@pytest.mark.parametrize("temperature", [1.0, 0.7])
+def test_one_op_matches_full_logits_values_and_gradients(
+    temperature, with_entropy, tied, dtype
+):
+    """The hand-written backward against JAX's differentiation of
+    ``gather_logprobs(_entropy)`` on full float32-accumulated logits: T = 10
+    in chunks of 4 (a padded last chunk), the head as an untied [D, V] matrix
+    and as a tied [V, D] table, operands in float32 and in bf16."""
+    rng = np.random.default_rng(3)
+    x, head, labels = _case(rng)
+    x, head = x.astype(dtype), (0.3 * head).astype(dtype)
+    stored = head.T if tied else head  # as the model keeps it
+    w_lp = jnp.asarray(rng.standard_normal(labels.shape), jnp.float32)
+    w_ent = jnp.asarray(rng.standard_normal(labels.shape), jnp.float32)
+
+    def full(x_, stored_):
+        h = stored_.T if tied else stored_
+        logits = jnp.dot(x_, h, preferred_element_type=jnp.float32)
+        if with_entropy:
+            return gather_logprobs_entropy(logits, labels, temperature)
+        return gather_logprobs(logits, labels, temperature), 0.0 * w_ent
+
+    def one_op(x_, stored_):
+        out = chunked_gather_logprobs(
+            x_, stored_, labels, temperature=temperature, chunk=4,
+            with_entropy=with_entropy, vocab_axis=0 if tied else 1,
+        )
+        return out if with_entropy else (out, 0.0 * w_ent)
+
+    def loss(fn):
+        def f(x_, stored_):
+            lp, ent = fn(x_, stored_)
+            return jnp.sum(lp * w_lp) + jnp.sum(ent * w_ent)
+        return f
+
+    for got, want in zip(one_op(x, stored), full(x, stored)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-5, atol=1e-5)
+    got = jax.grad(loss(one_op), argnums=(0, 1))(x, stored)
+    want = jax.grad(loss(full), argnums=(0, 1))(x, stored)
+    # float32: the file's tolerances; bf16: the gradients come out in bf16
+    # (eps 2**-8) and dlogits enters the two backward matmuls in bf16, so
+    # a few ulp at the gradients' size (up to 8 here)
+    tol = dict(rtol=1e-4, atol=1e-5) if dtype == "float32" else dict(
+        rtol=1e-2, atol=1e-2)
+    for g, w in zip(got, want):
+        assert g.dtype == w.dtype == jnp.dtype(dtype) and g.shape == w.shape
+        np.testing.assert_allclose(np.asarray(g, np.float32),
+                                   np.asarray(w, np.float32), **tol)
+
+
+def test_chunk_comes_from_the_shape():
+    """No chunk given: as few equal chunks as stay within the swept size,
+    tile-aligned; the result does not depend on it."""
+    from areal_tpu.ops import chunked_head
+
+    cap = chunked_head._CHUNK_TOKENS
+    for t in (10, 1023, 2049, 4095, 4096, 5000, 16383):
+        c = chunked_head._chunk_tokens(t)
+        nc = -(-t // c)
+        assert c % 8 == 0 and c <= cap and nc == -(-t // cap)
+        assert nc * c - t < nc * 128  # padding: the tile's rounding only
+    assert chunked_head._chunk_tokens(4095) == 2048  # the cells' rows
+    rng = np.random.default_rng(4)
+    x, head, labels = _case(rng)
+    np.testing.assert_allclose(
+        np.asarray(chunked_gather_logprobs(x, head, labels)),
+        np.asarray(chunked_gather_logprobs(x, head, labels, chunk=3)),
+        rtol=1e-5, atol=1e-5,
+    )
+
+
 def test_view_dispatch_and_slicing():
     rng = np.random.default_rng(1)
     x, head, labels = _case(rng)
@@ -67,6 +143,16 @@ def test_view_dispatch_and_slicing():
                                rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(
         np.asarray(view.full()), np.asarray(x @ head), rtol=1e-5, atol=1e-5
+    )
+    # a tied model's view holds the [V, D] table and says so
+    tied = ChunkedLogits(x, head.T, vocab_axis=0)
+    assert tied.shape == view.shape and tied[:, :-1].vocab_axis == 0
+    np.testing.assert_allclose(
+        np.asarray(gather_logprobs(tied[:, :-1], labels[:, 1:])),
+        np.asarray(want), rtol=1e-5, atol=1e-5,
+    )
+    np.testing.assert_allclose(
+        np.asarray(tied.full()), np.asarray(x @ head), rtol=1e-5, atol=1e-5
     )
 
 
@@ -95,7 +181,11 @@ def test_engine_sft_same_loss_with_and_without_chunked_head():
             dtype="float32", param_dtype="float32",
             gradient_checkpointing=False, chunked_lm_head=chunked,
             mb_spec=MicroBatchSpec(max_tokens_per_mb=4096),
-            optimizer=OptimizerConfig(lr=1e-2, warmup_steps_proportion=0.0),
+            # plain SGD, lr 1, no decay, no clipping: the step IS the gradient
+            optimizer=OptimizerConfig(
+                type="sgd", lr=1.0, weight_decay=0.0, gradient_clipping=1e9,
+                warmup_steps_proportion=0.0,
+            ),
             parallel=ParallelismConfig(),
         )
         eng = SPMDTrainEngine(cfg)
@@ -104,6 +194,7 @@ def test_engine_sft_same_loss_with_and_without_chunked_head():
         return eng
 
     e1, e2 = make(False), make(True)
+    p0 = jax.device_get(e1.params)
     r1 = e1.train_batch(dict(batch), sft_loss_fn, sft_loss_weight_fn)
     r2 = e2.train_batch(dict(batch), sft_loss_fn, sft_loss_weight_fn)
     np.testing.assert_allclose(r1["loss"], r2["loss"], rtol=1e-5)
@@ -112,6 +203,16 @@ def test_engine_sft_same_loss_with_and_without_chunked_head():
     jax.tree_util.tree_map(
         lambda a, b: np.testing.assert_allclose(a, b, rtol=1e-3, atol=1e-5),
         p1, p2,
+    )
+    # and the gradients themselves (the step taken), element for element.
+    # Through Adam this comparison is ill-conditioned, not loose: the first
+    # step is lr * g / (|g| + eps), and layers.w_up[1, 43, 90] has
+    # |g| = 1.8e-9 under eps = 1e-8, where the two engines' 6e-11 of float32
+    # summation order came out as 5e-5 of a parameter.
+    jax.tree_util.tree_map(
+        lambda a, b, z: np.testing.assert_allclose(
+            a - z, b - z, rtol=1e-4, atol=1e-7),
+        p1, p2, p0,
     )
     # logp recompute path agrees too
     lp1 = e1.forward(dict(batch))

@@ -182,6 +182,47 @@ def test_backward_and_remat_keep_the_scope_on_the_path(lowered):
     assert program_trace.scope_of("jit(g)/transpose(jvp(loss))/mul") == "loss"
 
 
+def test_the_heads_hand_written_backward_keeps_lm_head_and_loss(lowered):
+    """``ops/chunked_head.py`` differentiates itself (``jax.custom_vjp``):
+    its matmuls read ``lm_head`` forward and backward and the rest of it
+    ``loss``, so ``lm_head_time_share.train`` reads what it read."""
+    from benchmark import program_trace
+
+    def under(program, scope, word):
+        _, names = lowered[program]
+        return {
+            program_trace.direction_of(n) for n in names
+            if program_trace.scope_of(n) == scope and word in n
+        }
+
+    assert under("grad_step", "lm_head", "dot_general") == {"fwd", "bwd"}
+    assert under("logp_forward", "lm_head", "dot_general") == {"fwd"}
+    # the softmax: logsumexp forward, exp(logits - logz) in dlogits
+    assert under("grad_step", "loss", "exp") == {"fwd", "bwd"}
+    assert under("logp_forward", "loss", "exp") == {"fwd"}
+
+
+def test_the_head_gathers_and_scatters_nothing(lowered):
+    """The label's logit is a select against an iota, forward and backward:
+    the only gather and scatter that see the vocabulary are the embedding's
+    lookup and its gradient."""
+    from benchmark import program_trace
+
+    vocab = str(tiny_config("qwen2").vocab_size)
+    for program in ("grad_step", "logp_forward"):
+        text, _ = lowered[program]
+        found = re.findall(
+            r'= \w+\[([\d,]*)\]\S* (gather|scatter)\(.*op_name="([^"]+)"', text
+        )
+        assert {"embed"} <= {program_trace.scope_of(n) for _, _, n in found}
+        for dims, op, name in found:
+            scope = program_trace.scope_of(name)
+            assert scope not in ("lm_head", "loss"), (program, op, name)
+            assert scope == "embed" or vocab not in dims.split(","), (
+                program, op, dims, name
+            )
+
+
 def _loss_and_logp(scoped, monkeypatch):
     if not scoped:
         from areal_tpu.models import transformer

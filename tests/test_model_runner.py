@@ -136,9 +136,10 @@ def test_greedy_decode_matches_full_forward(setup):
         active = jnp.zeros((NSLOTS,), bool).at[0].set(True)
         cache, logits = h.decode_step(
             params, cfg, cache, jnp.asarray(_tables()),
-            # a copy: the CPU backend may alias a numpy buffer, the step is
-            # dispatched asynchronously, and pos0 is advanced right after
-            jnp.array(pos0), tokens, active,
+            # a numpy copy: the step is dispatched asynchronously and pos0
+            # is advanced right after; on the CPU backend jnp.array reads
+            # the buffer late too (jax 0.9: wrong in ~47% of 3000 tries)
+            jnp.asarray(pos0.copy()), tokens, active,
         )
         pos0[0] += 1
         ref_tok, ref_logits = _full_forward_argmax(params, cfg, seq)
@@ -246,7 +247,7 @@ def test_decode_multi_matches_stepwise(setup):
     toks_b = []
     for _ in range(steps):
         cache_b, logits = hb.decode_step(
-            params, cfg, cache_b, tb, jnp.array(pos_b), cur, active
+            params, cfg, cache_b, tb, jnp.asarray(pos_b.copy()), cur, active
         )
         pos_b[0] += 1
         pos_b[1] += 1
