@@ -119,10 +119,6 @@ class TrainEngineConfig:
     param_dtype: str = "float32"  # parameter/optimizer storage (master weights)
     disable_dropout: bool = True
     gradient_checkpointing: bool = True
-    # with remat on, SAVE each layer's attention output instead of
-    # recomputing the attention forward in the backward ([B,T,Hq,D] bf16 of
-    # HBM a layer); disable for memory-tight shapes
-    remat_save_attn: bool = True
     # the attention core. "auto" is resolved once at initialize() from what
     # the process observes (SPMDTrainEngine.attn_impl holds the result): on
     # a TPU with seq_parallel_size == 1 and a head dimension the kernel
@@ -132,10 +128,6 @@ class TrainEngineConfig:
     # names the kernel outright; "ring"/"ulysses" are the explicit
     # sequence-parallel shard_map kernels (the XLA form with nothing to split)
     attn_impl: str = "auto"
-    # lazy chunked LM head: loss paths never materialize [T, vocab] logits
-    # (the largest train activation — 3.2 GB for one 24k row at 32k vocab);
-    # disable for custom loss fns that index the vocab axis directly
-    chunked_lm_head: bool = True
     mb_spec: MicroBatchSpec = dataclasses.field(default_factory=MicroBatchSpec)
     optimizer: Optional[OptimizerConfig] = dataclasses.field(default_factory=OptimizerConfig)
     parallel: ParallelismConfig = dataclasses.field(default_factory=ParallelismConfig)

@@ -24,7 +24,6 @@ import numpy as np
 
 from areal_tpu.api.cli_args import PPOActorConfig
 from areal_tpu.api.engine_api import TrainEngine
-from areal_tpu.engine.spmd_engine import target_aligned_logprobs
 from areal_tpu.ops import functional as F
 from areal_tpu.utils import stats_tracker, tracing
 from areal_tpu.utils.data import Batch, batch_select, batch_size
@@ -61,7 +60,9 @@ class PPOActor:
         if temp not in self._logp_hooks:
 
             def hook(logits, arrays, _temp=temp):
-                return target_aligned_logprobs(logits, arrays, temperature=_temp)
+                return F.target_aligned_logprobs(
+                    logits, arrays, temperature=_temp
+                )
 
             self._logp_hooks[temp] = hook
         return self.engine.forward(data, post_hook=self._logp_hooks[temp])
@@ -229,7 +230,7 @@ class PPOActor:
 
             def grpo_loss_fn(logits, arrays):
                 """reference ppo/actor.py:292 `grpo_loss_fn`."""
-                newlogp = target_aligned_logprobs(
+                newlogp = F.target_aligned_logprobs(
                     logits, arrays, temperature=cfg.temperature
                 )
                 old_logp = arrays["t_logprobs"].astype(jnp.float32)

@@ -7,6 +7,8 @@ plans + NCCL process groups, here ONE jitted train step over a
 derived from logical-axis rules, XLA inserts the collectives (all-gather for
 fsdp params, psum for grads — the ZeRO-3 schedule falls out of sharding
 propagation), and microbatch gradient accumulation happens on device.
+The device programs themselves are built in ``engine/programs.py``; this
+class keeps lifecycle, the cache of jitted programs and the host path.
 
 Contracts:
 - ``loss_fn(logits, arrays) -> (loss, stats_dict)`` — pure, jit-traced.
@@ -33,14 +35,18 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from areal_tpu.api.cli_args import TrainEngineConfig
 from areal_tpu.api.engine_api import TrainEngine
 from areal_tpu.api.io_struct import FinetuneSpec, SaveLoadMeta, WeightUpdateMeta
+from areal_tpu.engine import programs
 from areal_tpu.models import hf_io
 from areal_tpu.models.config import ModelConfig, load_hf_config
-from areal_tpu.models.forward import packed_forward
 from areal_tpu.models.transformer import (
     count_params,
     init_params,
     param_logical_axes,
 )
+from areal_tpu.ops import flash as flash_ops
+from areal_tpu.ops import moe as moe_ops
+from areal_tpu.ops.functional import target_aligned_logprobs
+from areal_tpu.ops.ring_attention import attention_fn
 from areal_tpu.parallel import distributed as distributed_lib
 from areal_tpu.parallel import mesh as mesh_lib
 from areal_tpu.parallel import sharding as sharding_lib
@@ -50,14 +56,6 @@ from areal_tpu.utils import logging as logging_util
 from areal_tpu.utils.data import Batch
 
 logger = logging_util.getLogger("SPMDTrainEngine")
-
-# grad_step's stats that are counts of a sparse model's routing (summed over
-# microbatches, where every other stat is a weighted mean): the key in the
-# stats, in ``counters()`` and on the stats plane, and its ``MoEStats`` field
-_MOE_COUNTS = {
-    "moe_assignments": "assignments",
-    "moe_max_expert_assignments": "max_expert_assignments",
-}
 
 _DTYPES = {
     "float32": jnp.float32,
@@ -216,17 +214,15 @@ class SPMDTrainEngine(TrainEngine):
                 cfg.optimizer, self.lr_schedule
             )
             self.opt_state = self._init_opt_state()
-        self.attn_impl = self._resolve_attn_impl()
+        self.attn_impl = flash_ops.resolve_attn_impl(
+            cfg.attn_impl, cfg.parallel.seq_parallel_size, mc.head_dim
+        )
         if self.attn_impl == "flash" and jax.default_backend() != "cpu":
             # what the scoped-VMEM limit of this process allows, once
-            from areal_tpu.ops import flash as flash_ops
-
             self.splash_block = flash_ops.probe_block_size()
         if mc.is_moe:
-            from areal_tpu.ops import moe as moe_ops
-
             self.moe_impl = moe_ops.grouped_impl()
-            self._counters.update(dict.fromkeys(_MOE_COUNTS, 0))
+            self._counters.update(dict.fromkeys(programs.MOE_COUNTS, 0))
         n = count_params(self.params)
         logger.info(
             f"initialized {mc.family} model: {n/1e6:.1f}M params on mesh "
@@ -241,28 +237,6 @@ class SPMDTrainEngine(TrainEngine):
         )
         self.initialized = True
         return self
-
-    def _resolve_attn_impl(self) -> str:
-        """``config.attn_impl`` against what this process can observe (the
-        way ``GenerationEngine`` resolves its own ``auto``). On a TPU
-        without sequence parallelism ``auto`` is the fused splash kernel
-        wherever it takes the model's head dimension: the XLA form writes
-        float32 scores [heads, T, T] per layer and pass. Elsewhere ``auto``
-        is that XLA form, which GSPMD shards; so is an explicit SP kernel
-        with nothing to split."""
-        cfg = self.config
-        no_sp = cfg.parallel.seq_parallel_size == 1
-        if cfg.attn_impl == "auto":
-            from areal_tpu.ops import flash as flash_ops
-
-            fused = (
-                jax.default_backend() == "tpu" and no_sp
-                and flash_ops.takes_head_dim(self.model_config.head_dim)
-            )
-            return "flash" if fused else "xla"
-        if cfg.attn_impl in ("ring", "ulysses") and no_sp:
-            return "xla"
-        return cfg.attn_impl
 
     def rebuild_optimizer(
         self, opt_config, total_steps: int = 10000
@@ -369,8 +343,10 @@ class SPMDTrainEngine(TrainEngine):
             int(np.asarray(mb["attention_mask"]).sum()) for mb in mbs
         )
         # coarse quantum: every distinct bucket compiles the (expensive)
-        # grad program once, and FFD-packed sizes jitter step to step —
-        # a 1k quantum trades ~4% padding for a handful of compiles total
+        # grad program once, and FFD-packed sizes jitter step to step, so a
+        # 1k quantum keeps it to a handful of compiles. Its price is part of
+        # pad_share.train (14.32% in train_0p5b_grpo, all padding together:
+        # ledger, PR 28)
         return data_utils.next_bucket_size(
             -(-biggest // rows), 1024 * seq_mult
         )
@@ -447,206 +423,25 @@ class SPMDTrainEngine(TrainEngine):
     # ------------------------------------------------------------------
     # Train
     # ------------------------------------------------------------------
-    def _flash_window(self, input_: Batch) -> int:
-        """The splash kernel's block-sparse local window: the longest
-        sequence, in power-of-two buckets from 1024 (full causal over a long
-        packed stream of short sequences is T² block iteration; sequences
-        only need their own length). 0 = full causal, which is also what a
-        window above a quarter of a whole microbatch gets: every (pad
-        bucket, window) pair is one more grad program to compile and to
-        load, a window saves only whole tiles, and at half a 4096-token row
-        that was 7% of the core's time against 22% at a quarter (v5e,
-        PERF.md PR 26)."""
+    def _attn_window(self, input_: Batch) -> int:
+        """The splash kernel's local window for this batch (0 = full
+        causal, and what every other attention gets): the rule is
+        ``ops.flash.flash_window``'s, from the batch's longest sequence."""
         if self.attn_impl != "flash":
             return 0
         longest = int(np.asarray(input_["attention_mask"]).sum(1).max())
-        w = 1024
-        while w < longest:
-            w *= 2
-        return w if 4 * w <= self.config.mb_spec.max_tokens_per_mb else 0
-
-    def _act_sharding(self):
-        """[B, T, D] activation constraint: rows over (data, fsdp), tokens
-        over seq. Pinning this stops GSPMD from propagating the embedding
-        table's column sharding onto the batch (which replicates every
-        layer activation across fsdp — measured 81 GB/device of layer
-        temps on a 7B/16-device lowering)."""
-        return NamedSharding(self.mesh, P(("data", "fsdp"), "seq", None))
-
-    def _lazy_head(self) -> bool:
-        """Whether loss paths get the lazy ChunkedLogits view (critics
-        always get real values — their head is [D, 1])."""
-        return bool(
-            getattr(self.config, "chunked_lm_head", True)
-            and not getattr(self.config, "is_critic", False)
+        return flash_ops.flash_window(
+            longest, self.config.mb_spec.max_tokens_per_mb
         )
 
-    def _attend_fn(self, window: int = 0):
-        """Attention kernel override: "flash" (Pallas splash, TPU-only),
-        "ring"/"ulysses" (explicit SP shard_map), or None for the XLA kernel
-        with GSPMD auto-sharding."""
-        impl = self.attn_impl
-        if impl == "flash":
-            from areal_tpu.ops.flash import flash_segment_attention
-
-            attend = functools.partial(flash_segment_attention, window=window)
-            if self.mesh.size == 1:
-                return attend
-            # GSPMD cannot partition a Mosaic kernel ("wrap the call in a
-            # shard_map"): each device runs it on its own rows (and heads).
-            # Attention never crosses a row, so this is exact; only a
-            # sequence split would need a cross-device kernel.
-            if self.config.parallel.seq_parallel_size > 1:
-                raise ValueError(
-                    "attn_impl='flash' has no sequence-parallel form: use "
-                    "'ring' or 'ulysses' with seq_parallel_size > 1"
-                )
-            qkv_spec = P(("data", "fsdp"), None, "tensor", None)
-            return jax.shard_map(
-                attend,
-                mesh=self.mesh,
-                in_specs=(qkv_spec, qkv_spec, qkv_spec, P(("data", "fsdp"), None)),
-                out_specs=qkv_spec,
-                check_vma=False,
-            )
-        if impl == "xla":
-            return None
-        if not hasattr(self, "_cached_attend"):
-            from areal_tpu.ops.ring_attention import make_sharded_attention
-
-            self._cached_attend = make_sharded_attention(self.mesh, impl=impl)
-        return self._cached_attend
-
-    def _moe_fn(self):
-        """The sparse layer's call under a mesh: ``ops.moe`` per device
-        inside a ``shard_map`` (None: the plain call, one device or a dense
-        model). Its dispatch sorts the (token, expert) pairs, and a sort
-        over a token axis that GSPMD has sharded would gather it; a Mosaic
-        grouped matmul cannot be partitioned either. Each device routes its
-        own tokens through ALL experts: the layer's expert weights come in
-        as the rules table shards them and are gathered whole inside (as
-        ZeRO-3 gathers a dense layer's; the transpose reduce-scatters their
-        gradients), tokens never leave their device, and devices that hold
-        the same tokens (tensor, expert axes) repeat the work. The
-        statistics are summed over the axes that split tokens."""
-        mc = self.model_config
-        if not mc.is_moe or self.mesh.size == 1:
-            return None
-        from areal_tpu.ops.moe import moe_ffn_from_params
-
-        token_axes = ("data", "fsdp", "seq")
-        logical = param_logical_axes(mc)["layers"]
-        # one layer's leaves: the scanned layer axis is gone
-        specs = {
-            k: sharding_lib.logical_to_spec(logical[k][1:])
-            for k in ("w_router", "w_gate", "w_up", "w_down")
-        }
-
-        def per_device(weights, h, valid):
-            whole = {}
-            for k, w in weights.items():
-                for dim, axis in enumerate(specs[k]):
-                    if axis is not None and self.mesh.shape[axis] > 1:
-                        w = jax.lax.all_gather(w, axis, axis=dim, tiled=True)
-                whole[k] = w
-            return moe_ffn_from_params(
-                mc, whole, h, valid=valid, token_axes=token_axes
-            )
-
-        x_spec = P(("data", "fsdp"), "seq", None)
-        sharded = jax.shard_map(
-            per_device,
-            mesh=self.mesh,
-            in_specs=(specs, x_spec, P(("data", "fsdp"), "seq")),
-            out_specs=(x_spec, P()),
-            check_vma=False,
+    def _forward_values(self, window: int) -> Dict[str, Any]:
+        """What every forward program of ``engine/programs.py`` is built
+        from: this engine's model, mesh, compute dtype and attention."""
+        return dict(
+            model_config=self.model_config, mesh=self.mesh,
+            compute_dtype=self.compute_dtype,
+            attend_fn=attention_fn(self.attn_impl, self.mesh, window),
         )
-        return lambda lp, h, valid: sharded(
-            {k: lp[k] for k in specs}, h, valid
-        )
-
-    def _get_grad_fn(
-        self, loss_fn: Callable, loss_weight_fn: Callable, window: int = 0
-    ):
-        key = ("grad", loss_fn, loss_weight_fn, window)
-        if key not in self._jit_cache:
-            mc = self.model_config
-            remat = self.config.gradient_checkpointing
-            compute_dtype = self.compute_dtype
-            attend = self._attend_fn(window)
-            moe = self._moe_fn()
-            lazy_head = self._lazy_head()
-            act_sh = self._act_sharding()
-
-            def fwd_loss(params, arrays):
-                cparams = jax.tree_util.tree_map(
-                    lambda p: p.astype(compute_dtype), params
-                )
-                logits, moe_stats = packed_forward(
-                    cparams, mc, arrays, remat=remat,
-                    remat_save_attn=self.config.remat_save_attn,
-                    attend_fn=attend, moe_fn=moe,
-                    return_moe_stats=True, return_hidden=lazy_head,
-                    act_sharding=act_sh,
-                )
-                with tracing.device_scope("loss"):
-                    loss, stats = loss_fn(logits, arrays)
-                    if mc.is_moe:
-                        if mc.router_aux_loss_coef:
-                            loss = loss + (
-                                mc.router_aux_loss_coef * moe_stats.aux_loss
-                            )
-                        stats = dict(
-                            stats, router_aux_loss=moe_stats.aux_loss,
-                            **{k: getattr(moe_stats, v)
-                               for k, v in _MOE_COUNTS.items()},
-                        )
-                    w = loss_weight_fn(arrays).astype(jnp.float32)
-                    return loss * w, (loss, stats, w)
-
-            def grad_step(params, grad_accum, arrays):
-                grads, (loss, stats, w) = jax.grad(fwd_loss, has_aux=True)(
-                    params, arrays
-                )
-                new_accum = jax.tree_util.tree_map(
-                    lambda a, g: a + g.astype(jnp.float32), grad_accum, grads
-                )
-                return new_accum, loss, stats, w
-
-            self._jit_cache[key] = jax.jit(grad_step, donate_argnums=(1,))
-        return self._jit_cache[key]
-
-    def _get_apply_fn(self):
-        key = "apply"
-        if key not in self._jit_cache:
-
-            def apply_step(params, opt_state, grad_accum, total_weight):
-                with tracing.device_scope("optimizer"):
-                    grads = jax.tree_util.tree_map(
-                        lambda g: g / total_weight, grad_accum
-                    )
-                    grad_norm = optax.global_norm(grads)
-                    updates, new_opt = self.optimizer.update(
-                        grads, opt_state, params
-                    )
-                    new_params = optax.apply_updates(params, updates)
-                    # keep the declared param dtype: f32 updates would silently
-                    # promote bf16 params (breaking donation every step)
-                    new_params = jax.tree_util.tree_map(
-                        lambda n, o: n.astype(o.dtype), new_params, params
-                    )
-                    # skip non-finite updates (reference base_hf_engine.py:474)
-                    ok = jnp.isfinite(grad_norm)
-                    new_params = jax.tree_util.tree_map(
-                        lambda n, o: jnp.where(ok, n, o), new_params, params
-                    )
-                    new_opt = jax.tree_util.tree_map(
-                        lambda n, o: jnp.where(ok, n, o), new_opt, opt_state
-                    )
-                    return new_params, new_opt, grad_norm, ok
-
-            self._jit_cache[key] = jax.jit(apply_step, donate_argnums=(0, 1, 2))
-        return self._jit_cache[key]
 
     def _zero_grads(self):
         key = "zeros"
@@ -680,8 +475,15 @@ class SPMDTrainEngine(TrainEngine):
                 input_, self.config.mb_spec.max_tokens_per_mb,
                 min_n_mbs=self.config.mb_spec.n_mbs,
             )
-            window = self._flash_window(input_)
-            grad_fn = self._get_grad_fn(loss_fn, loss_weight_fn, window)
+            window = self._attn_window(input_)
+            key = ("grad", loss_fn, loss_weight_fn, window)
+            if key not in self._jit_cache:
+                self._jit_cache[key] = programs.build_grad_step(
+                    **self._forward_values(window),
+                    remat=self.config.gradient_checkpointing,
+                    loss_fn=loss_fn, loss_weight_fn=loss_weight_fn,
+                )
+            grad_fn = self._jit_cache[key]
             grad_accum = self._zero_grads()
             pad_to = self._mb_pad_to(mbs.mbs)
         losses, weights, all_stats = [], [], []
@@ -709,7 +511,11 @@ class SPMDTrainEngine(TrainEngine):
             weights.append(w)
             all_stats.append(stats)
         total_w = functools.reduce(lambda a, b: a + b, weights)
-        apply_fn = self._get_apply_fn()
+        if "apply" not in self._jit_cache:
+            self._jit_cache["apply"] = programs.build_apply_step(
+                self.optimizer
+            )
+        apply_fn = self._jit_cache["apply"]
         # the optim bucket spans apply THROUGH the blocking scalar
         # fetch: the fetch is where every async dispatch's device
         # compute actually lands on the wall clock
@@ -755,7 +561,8 @@ class SPMDTrainEngine(TrainEngine):
             "n_mbs": float(n_mb),
         }
         for j, k in enumerate(stat_keys):
-            if k in _MOE_COUNTS:  # a count: summed, counted, and per step
+            # a count: summed, counted, and per step
+            if k in programs.MOE_COUNTS:
                 out[k] = float(h_stats[:, j].sum())
                 self._counters[k] += int(out[k])
                 stats_tracker.counter(**{f"spmd/{k}": int(out[k])})
@@ -770,29 +577,13 @@ class SPMDTrainEngine(TrainEngine):
             input_, self.config.mb_spec.max_tokens_per_mb,
             min_n_mbs=self.config.mb_spec.n_mbs,
         )
-        window = self._flash_window(input_)
+        window = self._attn_window(input_)
         key = ("eval", loss_fn, loss_weight_fn, window)
         if key not in self._jit_cache:
-            mc = self.model_config
-            compute_dtype = self.compute_dtype
-            attend = self._attend_fn(window)
-            moe = self._moe_fn()
-            lazy_head = self._lazy_head()
-            act_sh = self._act_sharding()
-
-            def eval_step(params, arrays):
-                cparams = jax.tree_util.tree_map(
-                    lambda p: p.astype(compute_dtype), params
-                )
-                logits = packed_forward(
-                    cparams, mc, arrays, remat=False, attend_fn=attend,
-                    moe_fn=moe, return_hidden=lazy_head,
-                    act_sharding=act_sh,
-                )
-                loss, stats = loss_fn(logits, arrays)
-                return loss, stats, loss_weight_fn(arrays).astype(jnp.float32)
-
-            self._jit_cache[key] = jax.jit(eval_step)
+            self._jit_cache[key] = programs.build_eval_step(
+                **self._forward_values(window),
+                loss_fn=loss_fn, loss_weight_fn=loss_weight_fn,
+            )
         pad_to = self._mb_pad_to(mbs.mbs)
         losses, weights = [], []
         for mb in mbs.mbs:
@@ -838,34 +629,12 @@ class SPMDTrainEngine(TrainEngine):
             input_, self.config.mb_spec.max_tokens_per_mb,
             min_n_mbs=self.config.mb_spec.n_mbs,
         )
-        hook = post_hook or _default_logprob_hook
-        window = self._flash_window(input_)
+        hook = post_hook or target_aligned_logprobs
+        window = self._attn_window(input_)
         key = ("fwd", hook, window)
         if key not in self._jit_cache:
-            mc = self.model_config
-            compute_dtype = self.compute_dtype
-            attend = self._attend_fn(window)
-            moe = self._moe_fn()
-            lazy_head = self._lazy_head()
-            act_sh = self._act_sharding()
-
-            def logp_forward(params, arrays):
-                cparams = jax.tree_util.tree_map(
-                    lambda p: p.astype(compute_dtype), params
-                )
-                logits = packed_forward(
-                    cparams, mc, arrays, remat=False, attend_fn=attend,
-                    moe_fn=moe, return_hidden=lazy_head,
-                    act_sharding=act_sh,
-                )
-                with tracing.device_scope("loss"):
-                    return hook(logits, arrays)
-
-            # replicated output: under multi-process the per-token result
-            # must be fully addressable for the host np.asarray fetch
-            self._jit_cache[key] = jax.jit(
-                logp_forward,
-                out_shardings=sharding_lib.replicated(self.mesh),
+            self._jit_cache[key] = programs.build_logp_forward(
+                **self._forward_values(window), hook=hook
             )
         pad_to = self._mb_pad_to(mbs.mbs)
         outs = []
@@ -1108,30 +877,3 @@ class SPMDTrainEngine(TrainEngine):
             "spmd/upload_weights_s": time.perf_counter() - t_upload
         })
 
-
-def target_aligned_logprobs(
-    logits: jnp.ndarray, arrays: Dict, temperature: float = 1.0
-) -> jnp.ndarray:
-    """Logprobs aligned to the TARGET token: out[t] = log p(token_t | <t),
-    0 at each sequence's first token and on padding. This matches the
-    per-generated-token logprobs the rollout engine reports, so behavior /
-    proximal / new logprobs line up index-for-index (reference
-    ppo/actor.py compute_logp + utils/functional.py:29)."""
-    from areal_tpu.ops.functional import gather_logprobs
-
-    tokens = arrays["tokens"]
-    seg = arrays["segment_ids"]
-    logp_shift = gather_logprobs(
-        logits[:, :-1], tokens[:, 1:], temperature=temperature
-    )
-    out = jnp.concatenate(
-        [jnp.zeros_like(logp_shift[:, :1]), logp_shift], axis=1
-    )
-    prev_same = jnp.concatenate(
-        [jnp.zeros_like(seg[:, :1], bool), seg[:, 1:] == seg[:, :-1]], axis=1
-    ) & (seg > 0)
-    return jnp.where(prev_same, out, 0.0)
-
-
-def _default_logprob_hook(logits: jnp.ndarray, arrays: Dict) -> jnp.ndarray:
-    return target_aligned_logprobs(logits, arrays)

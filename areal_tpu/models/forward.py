@@ -26,7 +26,6 @@ def packed_forward(
     cfg: ModelConfig,
     arrays: dict,
     remat: bool = True,
-    remat_save_attn: bool = True,
     attend_fn: Optional[Any] = None,
     moe_fn: Optional[Any] = None,
     return_moe_stats: bool = False,
@@ -73,7 +72,6 @@ def packed_forward(
         arrays["segment_ids"],
         positions,
         remat=remat,
-        remat_save_attn=remat_save_attn,
         attend_fn=attend_fn,
         moe_fn=moe_fn,
         return_moe_stats=return_moe_stats,

@@ -265,7 +265,6 @@ def apply(
     segment_ids: jnp.ndarray,  # [B, T] int32; 0 = padding
     positions: jnp.ndarray,  # [B, T] int32 (or [B, T, 3] mrope)
     remat: bool = True,
-    remat_save_attn: bool = True,
     attend_fn: Optional[Any] = None,
     moe_fn: Optional[Any] = None,
     return_moe_stats: bool = False,
@@ -327,15 +326,13 @@ def apply(
         return out, aux
 
     if remat:
-        # save_attn keeps each layer's attention output across the
-        # forward->backward boundary (skips the flash-kernel recompute);
-        # everything else still remats. Off for memory-tight AOT shapes.
-        policy = (
-            jax.checkpoint_policies.save_only_these_names("attn_out")
-            if remat_save_attn
-            else None
+        # each layer's attention output is kept across the forward->backward
+        # boundary (no recompute of the attention forward, [B,T,Hq,D] of
+        # storage a layer); everything else remats
+        body = jax.checkpoint(
+            body, prevent_cse=False,
+            policy=jax.checkpoint_policies.save_only_these_names("attn_out"),
         )
-        body = jax.checkpoint(body, prevent_cse=False, policy=policy)
     x, aux = jax.lax.scan(body, x, params["layers"])
     x = rms_norm(
         x, params["final_norm"], cfg.rms_norm_eps,

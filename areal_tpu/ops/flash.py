@@ -10,7 +10,7 @@ segment-id support that matches our packed layout exactly.
 This wrapper adapts splash's [H, T, D] MQA-grouped convention to the
 framework's [B, T, H, D] packed-stream convention and masks padding
 (segment id 0) on the way out. It is the trainer's attention core on a TPU
-(``SPMDTrainEngine`` resolves ``attn_impl="auto"`` to it there); the XLA
+(``resolve_attn_impl`` makes ``attn_impl="auto"`` this kernel there); the XLA
 ``segment_attention`` is the plain form it is held equal to, the CPU's path,
 and what a row shorter than one tile takes. The kernel keeps float32 scores,
 softmax and accumulation, as the XLA form does. On the CPU it runs only in
@@ -117,6 +117,44 @@ def takes_head_dim(d: int) -> bool:
     return d > 0 and d % 64 == 0
 
 
+def resolve_attn_impl(
+    attn_impl: str, seq_parallel_size: int, head_dim: int
+) -> str:
+    """A train engine's ``attn_impl`` against what the process can observe
+    (the way ``GenerationEngine`` resolves its own ``auto``). On a TPU
+    without sequence parallelism ``auto`` is the fused splash kernel
+    wherever it takes the model's head dimension: the XLA form writes
+    float32 scores [heads, T, T] per layer and pass. Elsewhere ``auto``
+    is that XLA form, which GSPMD shards; so is an explicit SP kernel
+    with nothing to split."""
+    no_sp = seq_parallel_size == 1
+    if attn_impl == "auto":
+        fused = (
+            jax.default_backend() == "tpu" and no_sp
+            and takes_head_dim(head_dim)
+        )
+        return "flash" if fused else "xla"
+    if attn_impl in ("ring", "ulysses") and no_sp:
+        return "xla"
+    return attn_impl
+
+
+def flash_window(longest: int, tokens_per_mb: int) -> int:
+    """The kernel's block-sparse local window for a batch whose longest
+    sequence is ``longest``, in power-of-two buckets from 1024 (full causal
+    over a long packed stream of short sequences is T² block iteration;
+    sequences only need their own length). 0 = full causal, which is also
+    what a window above a quarter of a whole microbatch (``tokens_per_mb``)
+    gets: every (pad bucket, window) pair is one more grad program to
+    compile and to load, a window saves only whole tiles (``_block_sizes``),
+    and at half a 4096-token row that was 7% of the core's time against 22%
+    at a quarter (v5e, PERF.md PR 26)."""
+    w = 1024
+    while w < longest:
+        w *= 2
+    return w if 4 * w <= tokens_per_mb else 0
+
+
 def _block_sizes(t: int, window: int, d: int) -> "_sk.BlockSizes":
     """The kernel's tiles, from the shape it is given. Measured on the v5e
     with ``tools/microbench_attn_v2.py`` on the trainer's packed rows, 14/2
@@ -160,7 +198,7 @@ def _make_kernel(t: int, rep: int, window: int, d: int, interpret: bool):
         mask = _sm.MultiHeadMask([head for _ in range(rep)])
         # residual_checkpoint_name marks out+logsumexp so a remat policy
         # saving "attn_out" skips the forward-kernel recompute in the
-        # backward (models/transformer.apply remat_save_attn)
+        # backward (what models/transformer.apply's remat saves)
         return _sk.make_splash_mqa_single_device(
             mask, block_sizes=_block_sizes(t, window, d),
             residual_checkpoint_name="attn_out", interpret=interpret,

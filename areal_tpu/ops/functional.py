@@ -8,7 +8,7 @@ reverse scan replaces the CUDA `cugae` kernel (csrc/cugae/gae.cu) with a
 segment-boundary gating.
 """
 
-from typing import Optional, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -61,6 +61,29 @@ def gather_logprobs_entropy(
         -1
     )
     return logp, entropy
+
+
+def target_aligned_logprobs(
+    logits, arrays: Dict, temperature: float = 1.0
+) -> jnp.ndarray:
+    """Logprobs aligned to the TARGET token: out[t] = log p(token_t | <t),
+    0 at each sequence's first token and on padding. This matches the
+    per-generated-token logprobs the rollout engine reports, so behavior /
+    proximal / new logprobs line up index-for-index (reference
+    ppo/actor.py compute_logp + utils/functional.py:29). ``arrays`` is a
+    train engine's packed microbatch (``tokens``, ``segment_ids``)."""
+    tokens = arrays["tokens"]
+    seg = arrays["segment_ids"]
+    logp_shift = gather_logprobs(
+        logits[:, :-1], tokens[:, 1:], temperature=temperature
+    )
+    out = jnp.concatenate(
+        [jnp.zeros_like(logp_shift[:, :1]), logp_shift], axis=1
+    )
+    prev_same = jnp.concatenate(
+        [jnp.zeros_like(seg[:, :1], bool), seg[:, 1:] == seg[:, :-1]], axis=1
+    ) & (seg > 0)
+    return jnp.where(prev_same, out, 0.0)
 
 
 def masked_normalization(

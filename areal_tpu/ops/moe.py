@@ -18,9 +18,9 @@ are zeroed on the way in and on the way out, and count in no statistic.
 Which grouped matmul runs is resolved from the platform (``grouped_impl``),
 as the trainer's ``attn_impl: auto`` is: the Pallas ``megablox`` kernel on a
 TPU, ``jax.lax.ragged_dot`` elsewhere (the CPU's path and the form the
-kernel is held equal to). Under a device mesh the caller runs ``moe_ffn``
-per device inside a ``shard_map`` (``SPMDTrainEngine._moe_fn``): a sort over
-a token axis that GSPMD has sharded would gather it.
+kernel is held equal to). Under a device mesh the layer runs per device
+inside a ``shard_map`` (``make_sharded_moe_ffn``, the layer's mesh form): a
+sort over a token axis that GSPMD has sharded would gather it.
 
 Device scopes (``utils/tracing.DEVICE_SCOPES``), nested inside the layer's
 ``mlp``: ``moe_route`` (router matmul, softmax, top-k, load statistics),
@@ -32,6 +32,7 @@ from typing import Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import Mesh, PartitionSpec as P
 
 from areal_tpu.utils.tracing import device_scope
 
@@ -290,6 +291,51 @@ def moe_ffn_from_params(
         valid=valid,
         token_axes=token_axes,
     )
+
+
+# the leaves of one layer that the sparse FFN reads
+EXPERT_WEIGHTS = ("w_router", "w_gate", "w_up", "w_down")
+
+
+def make_sharded_moe_ffn(cfg, mesh: Mesh, weight_specs: Dict[str, P]):
+    """The sparse layer's mesh form: ``moe_fn(lp, h, valid)`` for
+    ``transformer.apply``, running ``moe_ffn_from_params`` per device inside
+    a ``shard_map`` (None on one device: the plain call serves). Its
+    dispatch sorts the (token, expert) pairs, and a sort over a token axis
+    that GSPMD has sharded would gather it; a Mosaic grouped matmul cannot
+    be partitioned either. Each device routes its own tokens through ALL
+    experts: the layer's expert weights come in as ``weight_specs`` says
+    they are sharded (one ``PartitionSpec`` for each of ``EXPERT_WEIGHTS``,
+    the layer axis gone) and are gathered whole inside (as ZeRO-3 gathers a
+    dense layer's; the transpose reduce-scatters their gradients), tokens
+    never leave their device, and devices that hold the same tokens
+    (tensor, expert axes) repeat the work. The statistics are summed over
+    the axes that split tokens."""
+    if mesh.size == 1:
+        return None
+    token_axes = ("data", "fsdp", "seq")
+    specs = {k: weight_specs[k] for k in EXPERT_WEIGHTS}
+
+    def per_device(weights, h, valid):
+        whole = {}
+        for k, w in weights.items():
+            for dim, axis in enumerate(specs[k]):
+                if axis is not None and mesh.shape[axis] > 1:
+                    w = jax.lax.all_gather(w, axis, axis=dim, tiled=True)
+            whole[k] = w
+        return moe_ffn_from_params(
+            cfg, whole, h, valid=valid, token_axes=token_axes
+        )
+
+    x_spec = P(("data", "fsdp"), "seq", None)
+    sharded = jax.shard_map(
+        per_device,
+        mesh=mesh,
+        in_specs=(specs, x_spec, P(("data", "fsdp"), "seq")),
+        out_specs=(x_spec, P()),
+        check_vma=False,
+    )
+    return lambda lp, h, valid: sharded({k: lp[k] for k in specs}, h, valid)
 
 
 def shared_expert_from_params(cfg, lp: Dict, h: jnp.ndarray) -> jnp.ndarray:

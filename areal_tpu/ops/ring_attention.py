@@ -19,6 +19,11 @@ Both operate on PACKED streams (segment_ids carry sequence boundaries) and
 are written as per-shard functions to be wrapped in `shard_map` (see
 `make_sharded_attention`), composing with the (data, fsdp, seq, tensor)
 mesh: XLA still shards heads over "tensor" inside the shard_map body.
+
+`attention_fn` is the one place that turns a resolved attention name
+("flash" | "ring" | "ulysses" | "xla"), a mesh and a window into the
+callable `transformer.apply` takes: each kernel's mesh form lives here,
+beside the kernels, and a train engine only asks for it.
 """
 
 import functools
@@ -234,3 +239,37 @@ def make_sharded_attention(
         return fn(q, k, v, segment_ids)
 
     return attend
+
+
+def attention_fn(impl: str, mesh: Mesh, window: int = 0):
+    """The attention callable ``attend(q, k, v, segment_ids)`` for a
+    resolved name (``ops.flash.resolve_attn_impl``) on ``mesh``: "flash"
+    (the Pallas splash kernel with its local ``window``, TPU-only),
+    "ring"/"ulysses" (the explicit sequence-parallel ``shard_map``), or
+    None for "xla", the XLA kernel with GSPMD auto-sharding."""
+    if impl == "xla":
+        return None
+    if impl != "flash":
+        return make_sharded_attention(mesh, impl=impl)
+    from areal_tpu.ops.flash import flash_segment_attention
+
+    attend = functools.partial(flash_segment_attention, window=window)
+    if mesh.size == 1:
+        return attend
+    # GSPMD cannot partition a Mosaic kernel ("wrap the call in a
+    # shard_map"): each device runs it on its own rows (and heads).
+    # Attention never crosses a row, so this is exact; only a sequence
+    # split would need a cross-device kernel.
+    if mesh.shape["seq"] > 1:
+        raise ValueError(
+            "attn_impl='flash' has no sequence-parallel form: use "
+            "'ring' or 'ulysses' with seq_parallel_size > 1"
+        )
+    qkv_spec = P(("data", "fsdp"), None, "tensor", None)
+    return jax.shard_map(
+        attend,
+        mesh=mesh,
+        in_specs=(qkv_spec, qkv_spec, qkv_spec, P(("data", "fsdp"), None)),
+        out_specs=qkv_spec,
+        check_vma=False,
+    )

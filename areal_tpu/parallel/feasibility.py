@@ -13,9 +13,7 @@ TPU layouts differ slightly (lane padding), so treat them as a ~5%-accurate
 feasibility bound, not a byte-exact HBM plan.
 """
 
-import dataclasses
-import functools
-from typing import Any, Dict, Optional
+from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
@@ -23,9 +21,17 @@ import numpy as np
 import optax
 
 from areal_tpu.api.cli_args import ParallelismConfig
+# the one import from engine/ below it: this module is an entry point (it has
+# a ``main``) that lowers the engine's builders, and lives under parallel/
+# only because MODEL_CONFIGS is imported from here
+from areal_tpu.engine import programs
 from areal_tpu.models.config import ModelConfig
-from areal_tpu.models.forward import packed_forward
 from areal_tpu.models.transformer import init_params, param_logical_axes
+from areal_tpu.ops.blockwise_attention import blockwise_segment_attention
+from areal_tpu.ops.functional import (
+    ppo_actor_loss_fn,
+    target_aligned_logprobs,
+)
 from areal_tpu.parallel import mesh as mesh_lib
 from areal_tpu.parallel import sharding as sharding_lib
 
@@ -54,6 +60,25 @@ def _mem(compiled) -> Dict[str, float]:
     return out
 
 
+def _grpo_loss_fn(logits, arrays):
+    """The decoupled PPO loss (behavior + proximal logprobs) at the
+    defaults of ``PPOActorConfig``: what the priced grad program ends in."""
+    loss, _ = ppo_actor_loss_fn(
+        logprobs=target_aligned_logprobs(logits, arrays),
+        old_logprobs=arrays["t_logprobs"],
+        advantages=arrays["t_advantages"],
+        eps_clip=0.2,
+        loss_mask=arrays["t_loss_mask"] > 0,
+        proximal_logprobs=arrays["t_prox_logp"],
+        behav_imp_weight_cap=5.0,
+    )
+    return loss, {}
+
+
+def _grpo_loss_weight_fn(arrays):
+    return jnp.maximum(arrays["t_loss_mask"].astype(jnp.float32).sum(), 1.0)
+
+
 def grpo_step_memory(
     model_cfg: ModelConfig,
     parallel: ParallelismConfig,
@@ -62,17 +87,15 @@ def grpo_step_memory(
     param_dtype=jnp.float32,
     compute_dtype=jnp.bfloat16,
     hbm_limit_gb: float = 16.0,
-    remat_save_attn: bool = True,
 ) -> Dict[str, Any]:
     """AOT-lower the decoupled-GRPO grad step + adam apply for the given
     mesh factoring; returns per-device memory numbers + a fits verdict.
 
-    The grad program is the engine's real shape: packed [rows, bucket]
-    streams, remat'd scanned layers, chunked LM head, decoupled PPO loss
-    (behavior + proximal logprobs), f32 grad accumulation with donation.
-    ``remat_save_attn`` mirrors TrainEngineConfig.remat_save_attn (default
-    True, like the engine) so the verdict prices the same remat policy the
-    real train step uses; pass False to price the memory-lean policy.
+    The programs are the engine's own (``engine/programs.py``, the builders
+    ``SPMDTrainEngine`` dispatches from), lowered on abstract shapes: packed
+    [rows, bucket] streams, remat'd scanned layers that save their attention
+    outputs, chunked LM head, a sparse model's layers in their mesh form,
+    f32 grad accumulation with donation, the guarded adam update.
     """
     mesh = mesh_lib.make_mesh(parallel)
     logical = param_logical_axes(model_cfg)
@@ -115,52 +138,18 @@ def grpo_step_memory(
         ),
     }
 
-    from areal_tpu.engine.spmd_engine import target_aligned_logprobs
-    from areal_tpu.ops.functional import ppo_actor_loss_fn
-
-    # memory-faithful attention: the TPU path runs the splash kernel
-    # (O(T·block) live memory); AOT-lowering the naive XLA kernel would
-    # report the [T, T] logits it materializes. The blockwise XLA kernel
-    # has the splash kernel's memory profile with identical numerics.
-    from areal_tpu.ops.blockwise_attention import blockwise_segment_attention
-
-    act_sh = jax.sharding.NamedSharding(
-        mesh, jax.sharding.PartitionSpec(("data", "fsdp"), "seq", None)
-    )
-
-    def fwd_loss(params, arrays):
-        cparams = jax.tree_util.tree_map(
-            lambda p: p.astype(compute_dtype), params
-        )
-        logits = packed_forward(
-            cparams, model_cfg, arrays, remat=True,
-            remat_save_attn=remat_save_attn,
-            return_hidden=True,
-            attend_fn=blockwise_segment_attention, act_sharding=act_sh,
-        )
-        newlogp = target_aligned_logprobs(logits, arrays)
-        loss, _ = ppo_actor_loss_fn(
-            logprobs=newlogp,
-            old_logprobs=arrays["t_logprobs"],
-            advantages=arrays["t_advantages"],
-            eps_clip=0.2,
-            loss_mask=arrays["t_loss_mask"] > 0,
-            proximal_logprobs=arrays["t_prox_logp"],
-            behav_imp_weight_cap=5.0,
-        )
-        w = jnp.maximum(
-            arrays["t_loss_mask"].astype(jnp.float32).sum(), 1.0
-        )
-        return loss * w
-
-    def grad_step(params, grad_accum, arrays):
-        grads = jax.grad(fwd_loss)(params, arrays)
-        return jax.tree_util.tree_map(
-            lambda a, g: a + g.astype(jnp.float32), grad_accum, grads
-        )
-
     grad_compiled = (
-        jax.jit(grad_step, donate_argnums=(1,))
+        programs.build_grad_step(
+            model_cfg, mesh, compute_dtype,
+            # memory-faithful attention: the TPU path runs the splash kernel
+            # (O(T·block) live memory); AOT-lowering the naive XLA kernel
+            # would report the [T, T] scores it materializes and the chip
+            # never holds. The blockwise XLA kernel has the splash kernel's
+            # memory profile with identical numerics.
+            attend_fn=blockwise_segment_attention,
+            remat=True,
+            loss_fn=_grpo_loss_fn, loss_weight_fn=_grpo_loss_weight_fn,
+        )
         .lower(params_sds, accum_sds, arrays_sds)
         .compile()
     )
@@ -186,18 +175,8 @@ def grpo_step_memory(
         ),
         opt_shape,
     )
-
-    def apply_step(params, opt_state, grad_accum, total_w):
-        grads = jax.tree_util.tree_map(lambda g: g / total_w, grad_accum)
-        updates, new_opt = optimizer.update(grads, opt_state, params)
-        new_params = optax.apply_updates(params, updates)
-        new_params = jax.tree_util.tree_map(
-            lambda n, o: n.astype(o.dtype), new_params, params
-        )
-        return new_params, new_opt
-
     apply_compiled = (
-        jax.jit(apply_step, donate_argnums=(0, 1, 2))
+        programs.build_apply_step(optimizer)
         .lower(
             params_sds,
             opt_sds,
@@ -239,7 +218,6 @@ def grpo_step_memory(
         },
         "n_devices": n_dev,
         "bucket_tokens_per_row": bucket,
-        "remat_save_attn": bool(remat_save_attn),
         "grad_step": grad_mem,
         "apply_step": apply_mem,
         "peak_per_device_gb": worst,
@@ -398,13 +376,6 @@ def main(argv=None):
     p.add_argument("--bucket", type=int, default=4096)
     p.add_argument("--seqs-per-row", type=int, default=8)
     p.add_argument("--hbm-gb", type=float, default=16.0)
-    p.add_argument(
-        "--remat-save-attn",
-        action=argparse.BooleanOptionalAction,
-        default=True,  # engine parity: TrainEngineConfig.remat_save_attn
-        help="price the engine's default remat policy (saved attention "
-        "outputs); --no-remat-save-attn prices the memory-lean one",
-    )
     p.add_argument("--topo", action="append", required=True)
     p.add_argument(
         "--devices", type=int, default=0,
@@ -447,7 +418,6 @@ def main(argv=None):
                 bucket=args.bucket,
                 seqs_per_row=args.seqs_per_row,
                 hbm_limit_gb=args.hbm_gb,
-                remat_save_attn=args.remat_save_attn,
             )
         except Exception as e:  # record, keep sweeping
             rep = {"error": f"{type(e).__name__}: {str(e)[:200]}"}

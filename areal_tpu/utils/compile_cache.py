@@ -15,7 +15,7 @@ Where the cache lives is ONE rule, here (``resolve_cache_dir``):
 2. else the directory a config names (``JaxGenConfig.compilation_cache_dir``
    / the server's ``--compilation-cache-dir``);
 3. else ``<checkout>/.jax_compile_cache`` for the entry points that always
-   cache (``examples/gsm8k_grpo.py``, ``bench.py``, ``chip_smoke.py``).
+   cache (``examples/gsm8k_grpo.py``, ``chip_smoke.py``).
 
 The directory is part of the cache key's world: it is never a temporary
 name, a pid or a time. The local launcher exports the config's value to

@@ -11,8 +11,8 @@ wants onto the same timeline.
 Design constraints, in order:
 
 1. **Disabled must be cheap.** The scheduler loop calls into the tracer
-   per admission wave and per finished request; `bench.py` showed the
-   loop is host-bound at high slot counts. So `record()` on a disabled
+   per admission wave and per finished request, and that loop is
+   host-bound at high slot counts. So `record()` on a disabled
    tracer returns before touching the lock. In a process that has jax
    loaded a `span()` costs one small object, two clock reads and an inert
    `TraceAnnotation` (about a microsecond) — nothing is recorded per

@@ -1,6 +1,7 @@
 """``TrainEngineConfig.attn_impl`` resolved once at ``initialize()`` from what
 the process observes (backend, mesh, the model's head dimension), and read
-from there by the window, the kernel override and the dispatch spans.
+from there by the window, the kernel override (``ops.ring_attention.
+attention_fn``, where each kernel's mesh form lives) and the dispatch spans.
 
 The backend is the CPU here; "reported as a TPU" means ``jax.default_backend``
 is steered in the test (and the block probe with it: it would run the kernel).
@@ -27,6 +28,7 @@ from areal_tpu.engine.sft.lm_engine import sft_loss_fn, sft_loss_weight_fn
 from areal_tpu.engine.spmd_engine import SPMDTrainEngine
 from areal_tpu.models.config import tiny_config
 from areal_tpu.ops import flash
+from areal_tpu.ops.ring_attention import attention_fn
 from areal_tpu.utils import data as data_utils
 from areal_tpu.utils import tracing
 
@@ -81,8 +83,8 @@ def _batch(lens):
 def test_auto_on_the_cpu_backend_is_the_xla_form(probes):
     eng = _engine()
     assert eng.attn_impl == "xla"
-    assert eng._attend_fn() is None
-    assert eng._flash_window(_batch([700, 30])) == 0
+    assert attention_fn(eng.attn_impl, eng.mesh) is None
+    assert eng._attn_window(_batch([700, 30])) == 0
     assert eng.splash_block is None and not probes
 
 
@@ -90,7 +92,7 @@ def test_auto_on_a_tpu_is_the_kernel_on_one_device(as_tpu):
     eng = _engine()
     assert eng.attn_impl == "flash"
     assert eng.splash_block == 1024 and len(as_tpu) == 1
-    attend = eng._attend_fn(2048)
+    attend = attention_fn(eng.attn_impl, eng.mesh, 2048)
     assert isinstance(attend, functools.partial)
     assert attend.func is flash.flash_segment_attention
     assert attend.keywords == {"window": 2048}
@@ -110,7 +112,7 @@ def test_auto_on_a_tpu_mesh_is_the_kernel_under_shard_map(
         return "sharded"
 
     monkeypatch.setattr(jax, "shard_map", shard_map)
-    assert eng._attend_fn(0) == "sharded"
+    assert attention_fn(eng.attn_impl, eng.mesh, 0) == "sharded"
     assert seen["fn"].func is flash.flash_segment_attention
     assert seen["mesh"] is eng.mesh
     # rows over (data, fsdp), heads over tensor, the sequence whole
@@ -136,9 +138,10 @@ def test_resolution_on_a_tpu(as_tpu, attn_impl, parallel, head_dim, resolved):
     assert eng.attn_impl == resolved
     assert len(as_tpu) == (resolved == "flash")
     if resolved == "xla":
-        assert eng._attend_fn() is None and eng.splash_block is None
+        assert attention_fn(resolved, eng.mesh) is None
+        assert eng.splash_block is None
     if resolved == "ring":
-        assert callable(eng._attend_fn())
+        assert callable(attention_fn(resolved, eng.mesh))
 
 
 def test_explicit_flash_on_the_cpu_backend_keeps_its_name_unprobed(probes):
@@ -161,8 +164,10 @@ def test_explicit_flash_on_the_cpu_backend_keeps_its_name_unprobed(probes):
     ],
 )
 def test_flash_window_buckets(as_tpu, longest, cap, window):
+    assert flash.flash_window(longest, cap) == window
+    # and the engine hands the rule its batch's longest sequence and its cap
     eng = _engine(cap=cap)
-    assert eng._flash_window(_batch([longest, 17])) == window
+    assert eng._attn_window(_batch([longest, 17])) == window
 
 
 def test_dispatch_spans_carry_the_resolved_name_and_the_window(monkeypatch):

@@ -166,7 +166,10 @@ def test_engine_sft_same_loss_with_and_without_chunked_head():
     from areal_tpu.api.io_struct import FinetuneSpec
     from areal_tpu.engine.sft.lm_engine import sft_loss_fn, sft_loss_weight_fn
     from areal_tpu.engine.spmd_engine import SPMDTrainEngine
+    from areal_tpu.models import transformer
     from areal_tpu.models.config import tiny_config
+    from areal_tpu.ops.functional import target_aligned_logprobs
+    from areal_tpu.utils import data as data_utils
 
     rng = np.random.default_rng(2)
     L = 24
@@ -176,45 +179,54 @@ def test_engine_sft_same_loss_with_and_without_chunked_head():
         "loss_mask": np.ones((4, L), np.int64),
     }
 
-    def make(chunked):
-        cfg = TrainEngineConfig(
-            dtype="float32", param_dtype="float32",
-            gradient_checkpointing=False, chunked_lm_head=chunked,
-            mb_spec=MicroBatchSpec(max_tokens_per_mb=4096),
-            # plain SGD, lr 1, no decay, no clipping: the step IS the gradient
-            optimizer=OptimizerConfig(
-                type="sgd", lr=1.0, weight_decay=0.0, gradient_clipping=1e9,
-                warmup_steps_proportion=0.0,
-            ),
-            parallel=ParallelismConfig(),
-        )
-        eng = SPMDTrainEngine(cfg)
-        eng.initialize(FinetuneSpec(1, 8, 4),
-                       model_config=tiny_config("qwen2"), seed=0)
-        return eng
-
-    e1, e2 = make(False), make(True)
-    p0 = jax.device_get(e1.params)
-    r1 = e1.train_batch(dict(batch), sft_loss_fn, sft_loss_weight_fn)
-    r2 = e2.train_batch(dict(batch), sft_loss_fn, sft_loss_weight_fn)
-    np.testing.assert_allclose(r1["loss"], r2["loss"], rtol=1e-5)
-    p1 = jax.device_get(e1.params)
-    p2 = jax.device_get(e2.params)
-    jax.tree_util.tree_map(
-        lambda a, b: np.testing.assert_allclose(a, b, rtol=1e-3, atol=1e-5),
-        p1, p2,
+    cfg = TrainEngineConfig(
+        dtype="float32", param_dtype="float32",
+        gradient_checkpointing=False,
+        mb_spec=MicroBatchSpec(max_tokens_per_mb=4096),
+        # plain SGD, lr 1, no decay, no clipping: the step IS the gradient
+        optimizer=OptimizerConfig(
+            type="sgd", lr=1.0, weight_decay=0.0, gradient_clipping=1e9,
+            warmup_steps_proportion=0.0,
+        ),
+        parallel=ParallelismConfig(),
     )
-    # and the gradients themselves (the step taken), element for element.
+    mc = tiny_config("qwen2")
+    eng = SPMDTrainEngine(cfg)
+    eng.initialize(FinetuneSpec(1, 8, 4), model_config=mc, seed=0)
+
+    # the plain reference: transformer.apply's real [B, T, V] logits, and
+    # jax.grad of the same loss through them, on the arrays the engine packs
+    mbs = data_utils.split_padded_batch_into_mb_list(dict(batch), 4096)
+    (mb,), (group,) = mbs.mbs, mbs.groups
+    packed, arrays = eng._pack_for_device(mb)
+
+    def real_logits(params):
+        return transformer.apply(
+            params, mc, arrays["tokens"], arrays["segment_ids"],
+            arrays["positions"], remat=False,
+        )
+
+    p0 = jax.device_get(eng.params)
+    loss_ref, g_ref = jax.value_and_grad(
+        lambda p: sft_loss_fn(real_logits(p), arrays)[0]
+    )(p0)
+    r = eng.train_batch(dict(batch), sft_loss_fn, sft_loss_weight_fn)
+    np.testing.assert_allclose(r["loss"], loss_ref, rtol=1e-5)
+    p1 = jax.device_get(eng.params)
+    # the gradients themselves (the step taken), element for element.
     # Through Adam this comparison is ill-conditioned, not loose: the first
     # step is lr * g / (|g| + eps), and layers.w_up[1, 43, 90] has
-    # |g| = 1.8e-9 under eps = 1e-8, where the two engines' 6e-11 of float32
+    # |g| = 1.8e-9 under eps = 1e-8, where the two forms' 6e-11 of float32
     # summation order came out as 5e-5 of a parameter.
     jax.tree_util.tree_map(
-        lambda a, b, z: np.testing.assert_allclose(
-            a - z, b - z, rtol=1e-4, atol=1e-7),
-        p1, p2, p0,
+        lambda a, z, g: np.testing.assert_allclose(
+            a - z, -g, rtol=1e-4, atol=1e-7),
+        p1, p0, g_ref,
     )
     # logp recompute path agrees too
-    lp1 = e1.forward(dict(batch))
-    lp2 = e2.forward(dict(batch))
-    np.testing.assert_allclose(lp1, lp2, rtol=1e-4, atol=1e-5)
+    lp = eng.forward(dict(batch))
+    lp_ref = np.zeros_like(lp)
+    lp_ref[np.asarray(group)] = data_utils.unpack_rows_per_token(
+        packed, np.asarray(target_aligned_logprobs(real_logits(p1), arrays))
+    )
+    np.testing.assert_allclose(lp, lp_ref, rtol=1e-4, atol=1e-5)

@@ -18,6 +18,7 @@ from areal_tpu.api.cli_args import (
     PPOActorConfig,
 )
 from areal_tpu.api.io_struct import FinetuneSpec
+from areal_tpu.engine import programs
 from areal_tpu.engine.ppo.actor import PPOActor, _ppo_loss_weight_fn
 from areal_tpu.engine.spmd_engine import SPMDTrainEngine
 from areal_tpu.inference import model_runner
@@ -84,7 +85,10 @@ def _train_programs(family="qwen2"):
     batch = actor.compute_advantages(_batch())
     batch["prox_logp"] = actor.compute_logp(batch) * batch["loss_mask"]
     _, arrays = eng._pack_for_device(batch)
-    grad_fn = eng._get_grad_fn(actor._loss_fn, _ppo_loss_weight_fn)
+    grad_fn = programs.build_grad_step(
+        **eng._forward_values(0), remat=True,
+        loss_fn=actor._loss_fn, loss_weight_fn=_ppo_loss_weight_fn,
+    )
     accum = eng._zero_grads()
     logp_fn = next(
         f for k, f in eng._jit_cache.items()
@@ -94,7 +98,7 @@ def _train_programs(family="qwen2"):
     return [
         ("grad_step", grad_fn.lower(eng.params, accum, arrays)),
         ("logp_forward", logp_fn.lower(eng.params, arrays)),
-        ("apply_step", eng._get_apply_fn().lower(
+        ("apply_step", programs.build_apply_step(eng.optimizer).lower(
             eng.params, eng.opt_state, accum, w)),
     ]
 

@@ -40,3 +40,43 @@ def test_limit_verdict_flips():
 def test_flagship_configs_shapes():
     assert F.qwen2_7b_config().hidden_size == 3584
     assert F.qwen2_1p5b_config().tie_word_embeddings
+
+
+@pytest.mark.parametrize(
+    "family,mesh_forms",
+    [
+        pytest.param("olmoe", ["make_sharded_moe_ffn"], id="sparse"),
+        pytest.param("qwen2", [], id="dense"),
+    ],
+)
+def test_the_priced_program_is_the_engines_with_each_layers_mesh_form(
+    monkeypatch, family, mesh_forms
+):
+    """``grpo_step_memory`` lowers the builders the engine dispatches from
+    (``engine/programs.py``), so under a mesh a sparse model's layers run
+    per device inside their ``shard_map`` as they do in the engine: a copy
+    of the step that passed no ``moe_fn`` priced GSPMD over the sort, a
+    program no engine runs."""
+    from areal_tpu.ops import moe
+
+    built = []
+    shard_map = jax.shard_map
+
+    def recording(fn, **kw):
+        built.append((fn.__qualname__.split(".")[0], kw))
+        return shard_map(fn, **kw)
+
+    monkeypatch.setattr(jax, "shard_map", recording)
+    rep = F.grpo_step_memory(
+        tiny_config(family),
+        ParallelismConfig(fsdp_parallel_size=4),
+        bucket=256,
+        seqs_per_row=4,
+    )
+    assert [name for name, _ in built] == mesh_forms
+    for _, kw in built:
+        weights, tokens, valid = kw["in_specs"]
+        assert set(weights) == set(moe.EXPERT_WEIGHTS)
+        assert tuple(tokens) == (("data", "fsdp"), "seq", None)
+    assert rep["mesh"] == {"fsdp": 4} and rep["fits"]
+    assert "remat_save_attn" not in rep
