@@ -24,7 +24,7 @@ import functools
 import os
 import time
 import urllib.request
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -329,50 +329,46 @@ class SPMDTrainEngine(TrainEngine):
     def _batch_sharding(self):
         return sharding_lib.batch_sharding(self.mesh)
 
-    def _mb_pad_to(self, mbs: List[Batch]) -> Optional[int]:
-        """Static per-row token pad for multi-microbatch steps: every
-        microbatch pads to ONE shared bucket (sized from the largest
-        microbatch, not the cap — min_n_mbs-forced splits of small batches
-        must not pay near-cap compute), so the expensive grad program
-        compiles once instead of per FFD-packed size."""
-        if len(mbs) <= 1:
-            return None
-        rows = self._dp_rows()
-        seq_mult = self.config.parallel.seq_parallel_size
-        biggest = max(
-            int(np.asarray(mb["attention_mask"]).sum()) for mb in mbs
+    def _row_quantum(self) -> int:
+        """The step of the ladder of row lengths: a row divides evenly
+        across the seq axis at every rung."""
+        return data_utils.ROW_QUANTUM * self.config.parallel.seq_parallel_size
+
+    def _split(
+        self, input_: Batch, backward: bool
+    ) -> data_utils.MicroBatchList:
+        """A batch as microbatches of rows, the one split ``train_batch``,
+        ``eval_batch`` and ``forward`` share: rows first
+        (``data_utils.split_padded_batch_into_mb_list``). A train step runs
+        each microbatch at its own row length: its minibatches end in a
+        short microbatch every time, and a 4096-token row of ``grad_step``
+        is 155 ms against 80 ms at 2048 (Qwen2-0.5B on a v5e). A pass with no
+        ``backward`` runs a call's microbatches at ONE length, the longest's:
+        its rows cost a third of that, it ends in one short microbatch among
+        many, and a length of its own is one more program to compile and,
+        from the cache, to trace and load (1.2 s; PERF.md, PR 30)."""
+        mbs = data_utils.split_padded_batch_into_mb_list(
+            input_, self.config.mb_spec.max_tokens_per_mb,
+            min_n_mbs=self.config.mb_spec.n_mbs,
+            n_rows=self._dp_rows(), quantum=self._row_quantum(),
         )
-        # coarse quantum: every distinct bucket compiles the (expensive)
-        # grad program once, and FFD-packed sizes jitter step to step, so a
-        # 1k quantum keeps it to a handful of compiles. Its price is part of
-        # pad_share.train (14.32% in train_0p5b_grpo, all padding together:
-        # ledger, PR 28)
-        return data_utils.next_bucket_size(
-            -(-biggest // rows), 1024 * seq_mult
-        )
+        if not backward:
+            mbs.row_lens = [max(mbs.row_lens, default=0)] * len(mbs.mbs)
+        return mbs
 
     def _pack_for_device(
         self, mb: Batch, pad_to: Optional[int] = None
     ) -> Tuple[data_utils.PackedRows, Dict[str, jnp.ndarray]]:
         """One microbatch packed into rows and put on the device: the one
         place every dispatched token passes (logprob recompute and train
-        alike), so the one place they are counted, real and padded."""
-        with tracing.span(
-            "train.pack", step=self.step_count, pad_to=pad_to or 0
-        ) as sp:
-            rows = self._dp_rows()
-            seq_mult = self.config.parallel.seq_parallel_size
-            # bucket quantum must divide evenly across the seq axis
-            try:
-                packed = data_utils.pack_batch_rows(
-                    mb, n_rows=rows, quantum=256 * seq_mult, pad_to=pad_to
-                )
-            except ValueError:
-                # a row outgrew the static pad (one very long sequence);
-                # fall back to the dynamic bucket for this microbatch
-                packed = data_utils.pack_batch_rows(
-                    mb, n_rows=rows, quantum=256 * seq_mult
-                )
+        alike), so the one place they are counted, real and padded.
+        ``pad_to`` is the row length the split gave the microbatch; without
+        it the pack finds the same length by the same rule."""
+        with tracing.span("train.pack", step=self.step_count) as sp:
+            packed = data_utils.pack_batch_rows(
+                mb, n_rows=self._dp_rows(), quantum=self._row_quantum(),
+                pad_to=pad_to,
+            )
             arrays: Dict[str, Any] = dict(
                 tokens=packed.tokens,
                 segment_ids=packed.segment_ids,
@@ -412,7 +408,9 @@ class SPMDTrainEngine(TrainEngine):
             padded = int(packed.segment_ids.size)
             self._counters["real_tokens"] += real
             self._counters["padded_tokens"] += padded
-            sp.set(real_tokens=real, padded_tokens=padded)
+            sp.set(
+                real_tokens=real, padded_tokens=padded, pad_to=packed.bucket
+            )
             # per step through the stats plane (increments: the step's
             # export sums them over its recompute and train microbatches)
             stats_tracker.counter(**{
@@ -443,6 +441,22 @@ class SPMDTrainEngine(TrainEngine):
             attend_fn=attention_fn(self.attn_impl, self.mesh, window),
         )
 
+    def _program(
+        self, key: tuple, build: Callable, window: int, row_len: int,
+        **values,
+    ) -> Tuple[Callable, int]:
+        """The cached device program ``build`` makes for ``key`` (what the
+        caller handed in: loss, hook) and the attention's window. A window
+        that reaches over the whole row is full causal, so it is 0 there:
+        a short row does not compile once for every window above it."""
+        window = window if window < row_len else 0
+        key = key + (window,)
+        if key not in self._jit_cache:
+            self._jit_cache[key] = build(
+                **self._forward_values(window), **values
+            )
+        return self._jit_cache[key], window
+
     def _zero_grads(self):
         key = "zeros"
         if key not in self._jit_cache:
@@ -471,38 +485,32 @@ class SPMDTrainEngine(TrainEngine):
         step: int,
     ) -> Dict[str, float]:
         with tracing.span("train.split", step=step):
-            mbs = data_utils.split_padded_batch_into_mb_list(
-                input_, self.config.mb_spec.max_tokens_per_mb,
-                min_n_mbs=self.config.mb_spec.n_mbs,
-            )
+            mbs = self._split(input_, backward=True)
             window = self._attn_window(input_)
-            key = ("grad", loss_fn, loss_weight_fn, window)
-            if key not in self._jit_cache:
-                self._jit_cache[key] = programs.build_grad_step(
-                    **self._forward_values(window),
-                    remat=self.config.gradient_checkpointing,
-                    loss_fn=loss_fn, loss_weight_fn=loss_weight_fn,
-                )
-            grad_fn = self._jit_cache[key]
             grad_accum = self._zero_grads()
-            pad_to = self._mb_pad_to(mbs.mbs)
         losses, weights, all_stats = [], [], []
         # goodput attribution: host packing books data_h2d, the grad
         # dispatches book fwd_bwd (minus any compile, which the trainer
-        # CompileTracker carves into the compile bucket with this step's
-        # shape signature), the apply + scalar fetch books optim. Where
-        # the device's time goes is the profile's to say (programs
-        # grad_step / apply_step); these are the host's intervals in it
-        gp_sig = f"mbs{len(mbs.mbs)}|pad{pad_to}|window{window}"
-        for mb in mbs.mbs:
+        # CompileTracker carves into the compile bucket with this
+        # microbatch's shape signature), the apply + scalar fetch books
+        # optim. Where the device's time goes is the profile's to say
+        # (programs grad_step / apply_step); these are the host's intervals
+        # in it
+        for mb, row_len in zip(mbs.mbs, mbs.row_lens):
             with goodput.trainer_bucket("data_h2d"):
-                _, arrays = self._pack_for_device(mb, pad_to=pad_to)
+                _, arrays = self._pack_for_device(mb, pad_to=row_len)
+            grad_fn, mb_window = self._program(
+                ("grad", loss_fn, loss_weight_fn), programs.build_grad_step,
+                window, row_len, remat=self.config.gradient_checkpointing,
+                loss_fn=loss_fn, loss_weight_fn=loss_weight_fn,
+            )
             with goodput.trainer_bucket("fwd_bwd"), tracing.span(
                 "train.grad_dispatch", step=step,
-                attn_impl=self.attn_impl, window=window,
+                attn_impl=self.attn_impl, window=mb_window,
                 **({"moe_impl": self.moe_impl} if self.moe_impl else {}),
             ), goodput.dispatch_scope(
-                goodput.trainer_tracker(), "fwd_bwd", gp_sig
+                goodput.trainer_tracker(), "fwd_bwd",
+                f"pad{row_len}|window{mb_window}",
             ):
                 grad_accum, loss, stats, w = grad_fn(
                     self.params, grad_accum, arrays
@@ -523,7 +531,7 @@ class SPMDTrainEngine(TrainEngine):
             with tracing.span(
                 "train.apply_dispatch", step=step
             ), goodput.dispatch_scope(
-                goodput.trainer_tracker(), "optim", gp_sig
+                goodput.trainer_tracker(), "optim"
             ):
                 self.params, self.opt_state, grad_norm, ok = apply_fn(
                     self.params, self.opt_state, grad_accum, total_w
@@ -573,22 +581,17 @@ class SPMDTrainEngine(TrainEngine):
     def eval_batch(
         self, input_: Batch, loss_fn: Callable, loss_weight_fn: Callable
     ) -> Dict[str, float]:
-        mbs = data_utils.split_padded_batch_into_mb_list(
-            input_, self.config.mb_spec.max_tokens_per_mb,
-            min_n_mbs=self.config.mb_spec.n_mbs,
-        )
+        mbs = self._split(input_, backward=False)
         window = self._attn_window(input_)
-        key = ("eval", loss_fn, loss_weight_fn, window)
-        if key not in self._jit_cache:
-            self._jit_cache[key] = programs.build_eval_step(
-                **self._forward_values(window),
-                loss_fn=loss_fn, loss_weight_fn=loss_weight_fn,
-            )
-        pad_to = self._mb_pad_to(mbs.mbs)
         losses, weights = [], []
-        for mb in mbs.mbs:
-            _, arrays = self._pack_for_device(mb, pad_to=pad_to)
-            loss, stats, w = self._jit_cache[key](self.params, arrays)
+        for mb, row_len in zip(mbs.mbs, mbs.row_lens):
+            _, arrays = self._pack_for_device(mb, pad_to=row_len)
+            eval_fn, _ = self._program(
+                ("eval", loss_fn, loss_weight_fn), programs.build_eval_step,
+                window, row_len, loss_fn=loss_fn,
+                loss_weight_fn=loss_weight_fn,
+            )
+            loss, stats, w = eval_fn(self.params, arrays)
             losses.append(loss)
             weights.append(w)
         blob = np.asarray(
@@ -625,27 +628,22 @@ class SPMDTrainEngine(TrainEngine):
     def _forward(
         self, input_: Batch, post_hook: Optional[Callable], step: int
     ) -> np.ndarray:
-        mbs = data_utils.split_padded_batch_into_mb_list(
-            input_, self.config.mb_spec.max_tokens_per_mb,
-            min_n_mbs=self.config.mb_spec.n_mbs,
-        )
+        mbs = self._split(input_, backward=False)
         hook = post_hook or target_aligned_logprobs
         window = self._attn_window(input_)
-        key = ("fwd", hook, window)
-        if key not in self._jit_cache:
-            self._jit_cache[key] = programs.build_logp_forward(
-                **self._forward_values(window), hook=hook
-            )
-        pad_to = self._mb_pad_to(mbs.mbs)
         outs = []
-        for mb in mbs.mbs:
-            packed, arrays = self._pack_for_device(mb, pad_to=pad_to)
+        for mb, row_len in zip(mbs.mbs, mbs.row_lens):
+            packed, arrays = self._pack_for_device(mb, pad_to=row_len)
+            logp_fn, mb_window = self._program(
+                ("fwd", hook), programs.build_logp_forward,
+                window, row_len, hook=hook,
+            )
             with tracing.span(
                 "train.logp_dispatch", step=step,
-                attn_impl=self.attn_impl, window=window,
+                attn_impl=self.attn_impl, window=mb_window,
                 **({"moe_impl": self.moe_impl} if self.moe_impl else {}),
             ):
-                vals = self._jit_cache[key](self.params, arrays)
+                vals = logp_fn(self.params, arrays)
             with tracing.span("train.logp_fetch", step=step):
                 vals = np.asarray(vals)  # blocks on the device here
             with tracing.span("train.unpack", step=step):

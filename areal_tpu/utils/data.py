@@ -15,7 +15,7 @@ attention (reference areal/utils/data.py:245-300).
 """
 
 import dataclasses
-from typing import Any, Dict, List, Optional, Sequence
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -26,6 +26,12 @@ Batch = Dict[str, np.ndarray]
 # Default bucket ladder: multiples of 256 up to 8k then powers of two. Static
 # shapes are what lets XLA tile the MXU without recompiling per batch.
 _BUCKET_QUANTUM = 256
+# The trainer's ladder of row lengths: this times 1, 2, 4, ... (times the
+# sequence-parallel degree). Every rung a run meets is one more grad program
+# to compile (20-35 s on a v5e) and, from the cache, to trace and load
+# (1.2 s each, measured: PERF.md, PR 30), so the ladder is coarse: two rungs
+# to a 4096-token row.
+ROW_QUANTUM = 2048
 
 
 def next_bucket_size(n: int, quantum: int = _BUCKET_QUANTUM) -> int:
@@ -287,24 +293,92 @@ class MicroBatchList:
     mbs: List[Batch]
     groups: List[List[int]]
     forward_indices: List[int]
+    row_lens: List[int]  # the row length each microbatch is dispatched at
 
     def __len__(self):
         return len(self.mbs)
 
 
+def _ladder(quantum: int, top: Optional[int]):
+    """The row lengths there are, ascending: the quantum times 1, 2, 4, ...
+    and ``top`` (a row's share of the token budget, which need not be one of
+    them) where it falls between two."""
+    t = quantum
+    while True:
+        if top is not None and t // 2 < top < t:
+            yield top
+        yield t
+        t *= 2
+
+
+def rows_on_ladder(
+    lens: np.ndarray, n_rows: int, quantum: int,
+    pad_to: Optional[int] = None, top: Optional[int] = None,
+) -> Tuple[int, List[List[int]]]:
+    """Sequences into at most ``n_rows`` rows, whole, first-fit-decreasing:
+    ``(row length, the indices in each row)``. The length is the shortest
+    rung of the ladder at which they fit (``pad_to``: that length and no
+    other; ``ValueError`` if they do not fit it). A pure function of its
+    arguments: the split sizes a microbatch with it, the pack lays the same
+    rows out at the length the split found, and every process of a
+    multi-host run derives the same."""
+    lens = np.asarray(lens, np.int64)
+    longest = int(lens.max(initial=0))
+    least = max(longest, -(-int(lens.sum()) // n_rows))
+    for t in [pad_to] if pad_to else _ladder(quantum, top):
+        if t < least:
+            continue
+        rows = datapack.ffd_allocate(lens, t)
+        if len(rows) <= n_rows:
+            rows = [sorted(r) for r in rows]
+            return t, rows + [[] for _ in range(n_rows - len(rows))]
+    raise ValueError(
+        f"pad_to={pad_to}: {len(lens)} sequences, longest {longest}, do not"
+        f" fit {n_rows} rows of it"
+    )
+
+
 def split_padded_batch_into_mb_list(
-    batch: Batch, max_tokens_per_mb: int, min_n_mbs: int = 1
+    batch: Batch, max_tokens_per_mb: int, min_n_mbs: int = 1,
+    n_rows: int = 1, quantum: int = ROW_QUANTUM,
 ) -> MicroBatchList:
-    """FFD-pack sequences into micro-batches of <= max_tokens_per_mb tokens
-    (reference data.py:401 `split_padded_tensor_dict_into_mb_list`)."""
+    """Rows first: first-fit-decreasing of the sequences into rows of a
+    microbatch's share of the token budget (``max_tokens_per_mb // n_rows``;
+    the longest sequence's length where that is longer, so a sequence is
+    never cut), then every ``n_rows`` consecutive rows are one microbatch and
+    the rows left over the last. Each microbatch has its OWN row length
+    (``row_lens``: ``rows_on_ladder``; log2(budget / quantum) + 1 distinct
+    ones at most, whatever a run draws), so the short last one need not pay
+    the longest one's row. Rows stay in the order first-fit opened them: the
+    last ones hold the short sequences, which spread over ``n_rows`` rows at
+    a lower length than any other choice of leftover (rows by fill: 23.6%
+    padding against 20.7% over random 16-sequence minibatches of the
+    reasoning length law in four rows, 28.6 against 21.3 in eight; PERF.md,
+    PR 30). At least ``min_n_mbs`` microbatches (balanced by tokens where the
+    rows make fewer). With one row this is the reference's split of sequences
+    under a token budget (data.py:401
+    `split_padded_tensor_dict_into_mb_list`)."""
     mask = np.asarray(batch["attention_mask"])
     lens = mask.sum(1).astype(np.int64)
-    groups = datapack.ffd_allocate(lens, max_tokens_per_mb, min_groups=min_n_mbs)
-    # keep deterministic order: sort groups by smallest original index
-    groups = sorted([sorted(g) for g in groups], key=lambda g: g[0])
+    capacity = max(max_tokens_per_mb // n_rows, int(lens.max(initial=0)))
+    rows = datapack.ffd_allocate(lens, capacity)
+    groups = [
+        datapack.flat2d(rows[i : i + n_rows])
+        for i in range(0, len(rows), n_rows)
+    ]
+    if len(groups) < min(min_n_mbs, len(lens)):
+        groups = datapack.partition_balanced(lens, min(min_n_mbs, len(lens)))
+    groups = [sorted(g) for g in groups]
+    top = -(-capacity // quantum) * quantum
+    row_lens = [
+        rows_on_ladder(lens[g], n_rows, quantum, top=top)[0] for g in groups
+    ]
     mbs = [trim_batch(batch_select(batch, g)) for g in groups]
     forward_indices = datapack.flat2d(groups)
-    return MicroBatchList(mbs=mbs, groups=groups, forward_indices=forward_indices)
+    return MicroBatchList(
+        mbs=mbs, groups=groups, forward_indices=forward_indices,
+        row_lens=row_lens,
+    )
 
 
 @dataclasses.dataclass
@@ -343,28 +417,20 @@ def pack_batch_rows(
     n_rows: int,
     pad_to: Optional[int] = None,
     pad_seqs_to: Optional[int] = None,
-    quantum: int = _BUCKET_QUANTUM,
+    quantum: int = ROW_QUANTUM,
 ) -> PackedRows:
-    """Pack a padded [B, L] batch into R balanced packed streams.
+    """Pack a padded [B, L] batch into R packed streams.
 
     The device-facing layout for SPMD training: rows shard over data
-    parallelism, tokens over sequence parallelism, every shape static.
-    `quantum` sets the bucket granularity (callers pass 256×seq_parallel so
-    the token axis splits evenly across the seq mesh axis).
+    parallelism, tokens over sequence parallelism, every shape static. The
+    rows and their length are ``rows_on_ladder``'s: no row exceeds the
+    length, which is ``pad_to`` or the shortest rung of `quantum` x 1, 2, 4,
+    ... (callers pass ``ROW_QUANTUM`` x seq_parallel so the token axis splits
+    evenly across the seq mesh axis) at which the sequences fit.
     """
     mask = np.asarray(batch["attention_mask"]).astype(bool)
-    bsz = mask.shape[0]
     lens = mask.sum(1).astype(np.int32)
-    row_groups = datapack.partition_balanced(lens, n_rows)
-    row_groups = [sorted(g) for g in row_groups]
-    row_tokens = [int(lens[g].sum()) for g in row_groups]
-    t_pad = (
-        pad_to
-        if pad_to is not None
-        else next_bucket_size(max(row_tokens + [1]), quantum)
-    )
-    if t_pad < max(row_tokens + [0]):
-        raise ValueError(f"pad_to={t_pad} < max row tokens {max(row_tokens)}")
+    t_pad, row_groups = rows_on_ladder(lens, n_rows, quantum, pad_to)
     # bucketed (multiples of 8) so the per-seq dim doesn't force a fresh
     # compile for every distinct sequence count
     s_pad = pad_seqs_to if pad_seqs_to is not None else next_bucket_size(

@@ -111,6 +111,10 @@ _SPLASH_CASES = [
     pytest.param(64, 14, 16384, 0, 2048, id="d64-t16384-causal"),
     pytest.param(128, 12, 4096, 0, 1024, id="d128-t4096-causal"),
     pytest.param(128, 12, 2048, 0, 1024, id="d128-t2048-causal"),
+    # the shorter rung a train step's last microbatch runs at since PR 30,
+    # at the two cells' heads (OLMoE: 16 of 128)
+    pytest.param(64, 14, 2048, 0, 1024, id="d64-t2048-causal"),
+    pytest.param(128, 16, 2048, 0, 1024, id="d128-h16-t2048-causal"),
 ]
 
 
@@ -181,9 +185,11 @@ def test_the_probes_first_candidate_compiles_for_v5e(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_sparse_layer_fwd_bwd_compiles_for_v5e(one_chip, monkeypatch):
+@pytest.mark.parametrize("t", [4096, 2048])
+def test_sparse_layer_fwd_bwd_compiles_for_v5e(one_chip, monkeypatch, t):
     """``ops/moe.moe_ffn`` forward and backward at OLMoE-1B-7B's published
-    widths on one 4096-token row (the four-chip cell's row a chip) through
+    widths on one 4096-token row (the four-chip cell's row a chip; 2048 is
+    the rung a train step's last microbatch runs at) through
     the megablox grouped matmul with ``_GMM_TILING``: three ``gmm`` forward,
     and three ``gmm`` + three ``tgmm`` backward. ``jax.default_backend()``
     is the CPU here, so the platform's choice is set in the test."""
@@ -193,7 +199,7 @@ def test_sparse_layer_fwd_bwd_compiles_for_v5e(one_chip, monkeypatch):
     from areal_tpu.ops import moe
 
     monkeypatch.setattr(moe, "grouped_impl", lambda shape=None: "megablox")
-    t, d, f, e, k = 4096, 2048, 1024, 64, 8
+    d, f, e, k = 2048, 1024, 64, 8
     assert (t * k) % moe._GMM_TILE_M == 0
 
     def loss(x, wr, wg, wu, wd, valid):

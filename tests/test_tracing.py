@@ -529,3 +529,27 @@ class TestWithoutJax:
         with tracing.span("y", t, "r") as sp2:
             pass
         assert sp2._ann is not None
+
+
+def test_trace_rungs_names_each_run_by_the_row_length_its_pack_gave():
+    """``tools/trace_rungs``: the k-th ``train.pack`` span's ``pad_to`` is
+    the k-th ``grad_step`` / ``logp_forward`` run's row length (the recorded
+    slice of a chip trace the benchmark's tests keep: two packs at 4096)."""
+    import os
+
+    from benchmark import program_trace
+    from tools import trace_rungs
+
+    events = program_trace.read_events(os.path.join(
+        os.path.dirname(__file__), "benchmark_suite",
+        "recorded_program_trace.tsv",
+    ))
+    table = trace_rungs.by_row_length(events)
+    assert list(table) == [("grad_step", 4096)]
+    runs, seconds = table[("grad_step", 4096)]
+    assert runs == 2 and 0.04 < seconds < 0.4
+    # a window cut in the middle of a step is refused, not misread
+    packs = [e for e in events if e[2] == "areal:train.pack"]
+    cut = [e for e in events if e is not packs[0]]
+    with pytest.raises(SystemExit):
+        trace_rungs.by_row_length(cut)

@@ -82,6 +82,76 @@ def test_microbatching_matches_single_batch():
     )
 
 
+def _split_engine(family, rows, max_tokens_per_mb):
+    """Float32, plain SGD at lr 1 with no clipping: the step IS the summed
+    gradient, so two splits of one minibatch can be held to each other."""
+    import dataclasses
+
+    cfg = TrainEngineConfig(
+        dtype="float32", param_dtype="float32",
+        gradient_checkpointing=False,
+        mb_spec=MicroBatchSpec(max_tokens_per_mb=max_tokens_per_mb),
+        optimizer=OptimizerConfig(
+            type="sgd", lr=1.0, weight_decay=0.0, gradient_clipping=1e9,
+            warmup_steps_proportion=0.0, lr_scheduler_type="constant",
+        ),
+        parallel=ParallelismConfig(fsdp_parallel_size=rows),
+    )
+    # the balancing loss is a product of two means over a microbatch's
+    # tokens: it alone depends on the split, by design
+    mc = dataclasses.replace(tiny_config(family), router_aux_loss_coef=0.0)
+    eng = SPMDTrainEngine(cfg)
+    eng.initialize(ft_spec=FinetuneSpec(1, 64, 8), model_config=mc, seed=0)
+    return eng
+
+
+@pytest.mark.parametrize("family,rows", [("qwen2", 1), ("olmoe", 4)])
+def test_same_step_under_two_splits(family, rows, monkeypatch):
+    """One minibatch through ``train_batch`` as rows-first microbatches of
+    several row lengths and as ONE whole microbatch: the same loss, gradient
+    norm and updated parameters to float32 summation order, and ``forward``
+    the same logprobs in the original order. The ladder is shrunk to steps of
+    16 tokens so that tiny sequences reach several rungs."""
+    monkeypatch.setattr(data_utils, "ROW_QUANTUM", 16)
+    rng = np.random.default_rng(3)
+    lens = [60, 9, 3, 14, 58, 30, 61, 7, 56, 12, 5]
+    seqs = [rng.integers(0, 128, size=n) for n in lens]
+    batch = data_utils.pad_sequences_to_tensors(seqs)
+    batch["loss_mask"] = batch["attention_mask"].astype(np.int32)
+
+    whole = _split_engine(family, rows, max_tokens_per_mb=1 << 20)
+    split = _split_engine(family, rows, max_tokens_per_mb=64 * rows)
+    mbl = split._split(batch, backward=True)
+    assert len(mbl.mbs) > 1 and len(set(mbl.row_lens)) > 1, mbl.row_lens
+    assert len(whole._split(batch, backward=True).mbs) == 1
+    # a pass with no backward: the same microbatches at one length, the
+    # longest's
+    fwd = split._split(batch, backward=False)
+    assert fwd.groups == mbl.groups
+    assert fwd.row_lens == [max(mbl.row_lens)] * len(mbl.mbs)
+
+    np.testing.assert_allclose(
+        split.forward(dict(batch)), whole.forward(dict(batch)),
+        rtol=1e-4, atol=1e-5,
+    )
+    r_whole = whole.train_batch(dict(batch), sft_loss_fn, sft_loss_weight_fn)
+    r_split = split.train_batch(dict(batch), sft_loss_fn, sft_loss_weight_fn)
+    assert r_split["n_mbs"] == len(mbl.mbs) and r_whole["n_mbs"] == 1
+    np.testing.assert_allclose(r_split["loss"], r_whole["loss"], rtol=1e-5)
+    np.testing.assert_allclose(
+        r_split["grad_norm"], r_whole["grad_norm"], rtol=1e-4
+    )
+    jax.tree_util.tree_map(
+        lambda a, b: np.testing.assert_allclose(a, b, rtol=1e-3, atol=2e-5),
+        jax.device_get(split.params), jax.device_get(whole.params),
+    )
+    # every token once in each pass, whatever the split
+    assert (
+        split.counters()["real_tokens"] == whole.counters()["real_tokens"]
+        == 2 * sum(lens)
+    )
+
+
 def test_sharded_matches_single_device():
     """The same batch must produce the same update on a 1-device and an
     8-device (fsdp=2, seq=2, tensor=2) mesh — sharding is semantics-free."""
