@@ -37,15 +37,21 @@ MOE_COUNTS = {
     "moe_assignments": "assignments",
     "moe_max_expert_assignments": "max_expert_assignments",
 }
+# the same for a sparse-attention model's selection (``DSAStats`` fields):
+# int32 [L] a microbatch, which the host sums exactly
+DSA_COUNTS = {
+    "dsa_kept_pairs": "kept_pairs",
+    "dsa_causal_pairs": "causal_pairs",
+}
 
 
 def _forward_fn(
     model_config: ModelConfig, mesh: Mesh, compute_dtype,
-    attend_fn: Optional[Callable], remat: bool, moe_stats: bool = False,
+    attend_fn: Optional[Callable], remat: bool, stats: bool = False,
 ):
     """``forward(params, arrays)``: the model over one packed microbatch,
     giving the lazy ``ChunkedLogits`` view (a critic's value head: real
-    values), with ``MoEStats`` beside it when ``moe_stats``."""
+    values), with the stack's ``LayerStats`` beside it when ``stats``."""
     mc = model_config
     moe = None
     if mc.is_moe:
@@ -69,7 +75,7 @@ def _forward_fn(
         )
         return packed_forward(
             cparams, mc, arrays, remat=remat, attend_fn=attend_fn,
-            moe_fn=moe, return_moe_stats=moe_stats, return_hidden=True,
+            moe_fn=moe, return_stats=stats, return_hidden=True,
             act_sharding=act_sh,
         )
 
@@ -86,13 +92,17 @@ def build_grad_step(
     accumulator, which is donated."""
     mc = model_config
     forward = _forward_fn(
-        mc, mesh, compute_dtype, attend_fn, remat, moe_stats=True
+        mc, mesh, compute_dtype, attend_fn, remat, stats=True
     )
 
     def fwd_loss(params, arrays):
-        logits, moe_stats = forward(params, arrays)
+        logits, (moe_stats, dsa_stats) = forward(params, arrays)
         with tracing.device_scope("loss"):
             loss, stats = loss_fn(logits, arrays)
+            if dsa_stats is not None:
+                stats = dict(stats, **{
+                    k: getattr(dsa_stats, v) for k, v in DSA_COUNTS.items()
+                })
             if mc.is_moe:
                 if mc.router_aux_loss_coef:
                     loss = loss + (

@@ -215,7 +215,8 @@ class SPMDTrainEngine(TrainEngine):
             )
             self.opt_state = self._init_opt_state()
         self.attn_impl = flash_ops.resolve_attn_impl(
-            cfg.attn_impl, cfg.parallel.seq_parallel_size, mc.head_dim
+            cfg.attn_impl, cfg.parallel.seq_parallel_size, mc.head_dim,
+            has_indexer=mc.has_indexer,
         )
         if self.attn_impl == "flash" and jax.default_backend() != "cpu":
             # what the scoped-VMEM limit of this process allows, once
@@ -223,6 +224,8 @@ class SPMDTrainEngine(TrainEngine):
         if mc.is_moe:
             self.moe_impl = moe_ops.grouped_impl()
             self._counters.update(dict.fromkeys(programs.MOE_COUNTS, 0))
+        if mc.has_indexer:
+            self._counters.update(dict.fromkeys(programs.DSA_COUNTS, 0))
         n = count_params(self.params)
         logger.info(
             f"initialized {mc.family} model: {n/1e6:.1f}M params on mesh "
@@ -304,8 +307,11 @@ class SPMDTrainEngine(TrainEngine):
         dispatched, real and padded, over recompute and train
         microbatches; for a sparse model also ``moe_assignments`` and
         ``moe_max_expert_assignments`` (the routed pairs of the train
-        microbatches and the busiest expert's, summed over layers). Their
-        per-step increments are the stats plane's ``spmd/<name>``."""
+        microbatches and the busiest expert's, summed over layers; of the
+        experts held, where the model holds a share); for a model with an
+        indexer ``dsa_kept_pairs`` and ``dsa_causal_pairs`` (the selected and
+        the causal (query, key) pairs, likewise). Their per-step increments
+        are the stats plane's ``spmd/<name>``."""
         return dict(self._counters)
 
     def get_version(self) -> int:
@@ -438,7 +444,10 @@ class SPMDTrainEngine(TrainEngine):
         return dict(
             model_config=self.model_config, mesh=self.mesh,
             compute_dtype=self.compute_dtype,
-            attend_fn=attention_fn(self.attn_impl, self.mesh, window),
+            attend_fn=attention_fn(
+                self.attn_impl, self.mesh, window,
+                topk=self.model_config.dsa_topk,
+            ),
         )
 
     def _program(
@@ -544,7 +553,13 @@ class SPMDTrainEngine(TrainEngine):
                 self.step_count += 1
                 # ONE packed host fetch for every scalar this step produced
                 # — each separate float() is a full device round-trip
-                stat_keys = sorted(all_stats[0])
+                # a sparse-attention model's pair counts are int32 [L] a
+                # microbatch (a float32 cannot count a 16k-token row's):
+                # their own small fetch, summed here
+                dsa_keys = [
+                    k for k in programs.DSA_COUNTS if k in all_stats[0]
+                ]
+                stat_keys = sorted(set(all_stats[0]) - set(dsa_keys))
                 scalars = [ok, grad_norm, total_w] + losses + weights + [
                     s[k] for s in all_stats for k in stat_keys
                 ]
@@ -556,6 +571,9 @@ class SPMDTrainEngine(TrainEngine):
                         ]
                     )
                 )
+                dsa_counts = np.asarray(jnp.stack(
+                    [s[k] for k in dsa_keys for s in all_stats]
+                )).reshape(len(dsa_keys), -1) if dsa_keys else ()
         n_mb = len(mbs.mbs)
         h_ok, h_gnorm, h_total_w = blob[0], blob[1], blob[2]
         h_losses = blob[3 : 3 + n_mb]
@@ -576,6 +594,11 @@ class SPMDTrainEngine(TrainEngine):
                 stats_tracker.counter(**{f"spmd/{k}": int(out[k])})
             else:
                 out[k] = float((h_stats[:, j] * h_weights).sum() / h_total_w)
+        for k, counts in zip(dsa_keys, dsa_counts):
+            n = int(counts.astype(np.int64).sum())
+            out[k] = float(n)
+            self._counters[k] += n
+            stats_tracker.counter(**{f"spmd/{k}": n})
         return out
 
     def eval_batch(

@@ -347,6 +347,14 @@ class GenerationEngine:
         if model_config is None:
             model_config = load_hf_config(config.model_path)
         self.model_config = model_config
+        if model_config.has_indexer:
+            from areal_tpu.ops.sparse_attention import MECHANISM
+
+            raise NotImplementedError(
+                f"the generation engine cannot run {MECHANISM}: it has no "
+                "indexer-key cache beside its KV pages and no top-k decode "
+                "(ROADMAP B); the trainer can (attn_impl 'sparse_topk')"
+            )
         if params is None:
             params = hf_io.load_params(
                 config.model_path, model_config, dtype=self.dtype

@@ -54,6 +54,18 @@ class ModelConfig:
     vision: Optional[VisionConfig] = None
     mrope_sections: Optional[Tuple[int, ...]] = None
     image_token_id: int = -1
+    # --- learned sparse attention (DeepSeek-Sparse-Attention indexer, the
+    # KeyeVL2 family's ``sa_config``; ops/sparse_attention.py): each query
+    # attends to the ``dsa_topk`` keys of its own sequence that a small
+    # indexer scores highest (0 = full causal attention, no indexer) ---
+    dsa_topk: int = 0
+    dsa_index_heads: int = 0
+    dsa_index_head_dim: int = 0
+    # --- the experts this process holds of ``num_experts`` (0 = all): the
+    # chip's share of an expert-parallel deployment. The router keeps its
+    # published width; the layer computes the part of the result that
+    # experts [0, experts_held) give (ops/moe.py) ---
+    experts_held: int = 0
 
     @property
     def q_dim(self) -> int:
@@ -71,6 +83,15 @@ class ModelConfig:
     def expert_ffn_size(self) -> int:
         return self.moe_intermediate_size or self.intermediate_size
 
+    @property
+    def num_held_experts(self) -> int:
+        """How many experts' weights the model stores."""
+        return self.experts_held or self.num_experts
+
+    @property
+    def has_indexer(self) -> bool:
+        return self.dsa_topk > 0
+
 
 # Supported HF `model_type`s. The llama-style decoder block (RMSNorm +
 # SiLU-gated MLP + rotary GQA attention) is the baseline; gemma layers on
@@ -78,15 +99,21 @@ class ModelConfig:
 # qwen3_moe/mixtral/olmoe are expert-only sparse (olmoe: 64 experts of
 # which 8 a token, weights not renormalised, q/k normed over the whole
 # projection); qwen2_moe adds the shared expert + sigmoid gate.
+# KeyeVL2 (the language model of Keye-VL-2.0) is a qwen3_moe block whose
+# attention reads only the ``sa_config.topk`` keys a learned indexer picks
+# (ops/sparse_attention.py); its vision tower is not built.
 # gemma2/gpt2 remain out (interleaved local
 # attention / learned positions need architecture changes).
 _HF_FAMILIES = (
     "llama", "qwen2", "qwen3", "mistral", "qwen3_moe", "mixtral",
-    "qwen2_vl", "qwen2_moe", "gemma", "olmoe",
+    "qwen2_vl", "qwen2_moe", "gemma", "olmoe", "KeyeVL2",
 )
 
 
-_QK_NORM = {"qwen3": "head", "qwen3_moe": "head", "olmoe": "projection"}
+_QK_NORM = {
+    "qwen3": "head", "qwen3_moe": "head", "olmoe": "projection",
+    "KeyeVL2": "head",
+}
 
 
 def _vision_from_hf(d: dict, lm_hidden: int) -> VisionConfig:
@@ -145,6 +172,21 @@ def from_hf_config(d: dict) -> ModelConfig:
             s = (half * 3) // 8
             mrope_sections = (half - 2 * s, s, s)
         image_token_id = d.get("image_token_id", 151655)
+    sa = {}
+    experts_held = 0
+    if model_type == "KeyeVL2":
+        sa = d["sa_config"]
+        if sa.get("indexer_num_kv_heads", 1) != 1:
+            raise ValueError(
+                "KeyeVL2: the indexer has one key head "
+                f"(indexer_num_kv_heads={sa['indexer_num_kv_heads']})"
+            )
+        mrope_sections = tuple(d["rope_scaling"]["mrope_section"])
+        # `num_experts` is the router's width, `num_local_experts` what
+        # is held here (the published file gives both as 128)
+        held = d.get("num_local_experts", num_experts)
+        experts_held = 0 if held == d["num_experts"] else held
+        num_experts = d["num_experts"]
     return ModelConfig(
         vocab_size=d["vocab_size"],
         hidden_size=hidden,
@@ -200,6 +242,10 @@ def from_hf_config(d: dict) -> ModelConfig:
             else 0
         ),
         router_aux_loss_coef=d.get("router_aux_loss_coef", 0.001),
+        dsa_topk=sa.get("topk", 0),
+        dsa_index_heads=sa.get("indexer_num_heads", 0),
+        dsa_index_head_dim=sa.get("indexer_head_dim", 0),
+        experts_held=experts_held,
     )
 
 
@@ -232,6 +278,18 @@ def tiny_vlm_config(vocab_size: int = 128) -> ModelConfig:
 def tiny_config(family: str = "qwen2", vocab_size: int = 128) -> ModelConfig:
     """Small config for tests."""
     moe = family in ("qwen3_moe", "mixtral", "qwen2_moe", "olmoe")
+    if family == "KeyeVL2":
+        # a qwen3_moe block over the 8 keys an indexer of two heads picks;
+        # 8 experts held of 16 routed over, two a token
+        return ModelConfig(
+            vocab_size=vocab_size, hidden_size=64, intermediate_size=128,
+            num_layers=2, num_heads=4, num_kv_heads=2, head_dim=16,
+            max_position_embeddings=512, rope_theta=10000.0,
+            rms_norm_eps=1e-6, qk_norm="head", family=family,
+            num_experts=16, num_experts_per_tok=2, moe_intermediate_size=32,
+            norm_topk_prob=True, experts_held=8, mrope_sections=(4, 2, 2),
+            dsa_topk=8, dsa_index_heads=2, dsa_index_head_dim=8,
+        )
     if family == "olmoe":  # 8 experts of which 2, MHA, as the family is
         return ModelConfig(
             vocab_size=vocab_size, hidden_size=64, intermediate_size=32,

@@ -28,7 +28,7 @@ def packed_forward(
     remat: bool = True,
     attend_fn: Optional[Any] = None,
     moe_fn: Optional[Any] = None,
-    return_moe_stats: bool = False,
+    return_stats: bool = False,
     return_hidden: bool = False,
     act_sharding: Optional[Any] = None,
 ):
@@ -74,7 +74,7 @@ def packed_forward(
         remat=remat,
         attend_fn=attend_fn,
         moe_fn=moe_fn,
-        return_moe_stats=return_moe_stats,
+        return_stats=return_stats,
         return_hidden=return_hidden,
         act_sharding=act_sharding,
         **kwargs,

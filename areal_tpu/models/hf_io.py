@@ -134,12 +134,24 @@ class _ShardReader:
         return self._handles[file].get_tensor(name)
 
 
+def _refuse_indexer(cfg: ModelConfig):
+    """A family with an indexer trains from seeded weights only: the names
+    its checkpoints give the indexer's projections (and whether a share of
+    the experts is a file of its own) are not known here."""
+    if cfg.has_indexer:
+        raise NotImplementedError(
+            f"no checkpoint layout for family {cfg.family!r} (its indexer's "
+            "weight names are unknown): initialise from scratch"
+        )
+
+
 def load_params(
     path: str, cfg: Optional[ModelConfig] = None, dtype=jnp.bfloat16
 ) -> Params:
     """Load an HF checkpoint directory into the stacked-layer pytree."""
     if cfg is None:
         cfg = load_hf_config(path)
+    _refuse_indexer(cfg)
     reader = _ShardReader(_open_shards(path))
 
     def g(name: str) -> np.ndarray:
@@ -219,6 +231,7 @@ def save_params(
 ) -> None:
     """Write the pytree back out as a single-file HF safetensors checkpoint
     (reference: fsdp_engine HF save path; used by disk weight updates)."""
+    _refuse_indexer(cfg)
     os.makedirs(path, exist_ok=True)
     tensors: Dict[str, np.ndarray] = {}
 

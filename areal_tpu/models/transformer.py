@@ -17,7 +17,7 @@ from-scratch parallel causal LM — re-designed TPU-first:
 """
 
 import functools
-from typing import Any, Dict, Optional
+from typing import Any, Dict, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -26,6 +26,7 @@ from jax import ad_checkpoint as _ad_checkpoint
 
 from areal_tpu.models.config import ModelConfig
 from areal_tpu.ops.moe import MoEStats
+from areal_tpu.ops.sparse_attention import DSAStats, sparse_attention
 from areal_tpu.utils.tracing import device_scope
 from areal_tpu.ops.basic import (
     apply_mrope,
@@ -37,6 +38,15 @@ from areal_tpu.ops.basic import (
 )
 
 Params = Dict[str, Any]
+
+
+class LayerStats(NamedTuple):
+    """What a layer reports beside its output; ``apply`` hands back the
+    stack's. ``dsa`` is None (no leaf at all) for a model without an
+    indexer, which therefore lowers as it did before the field existed."""
+
+    moe: MoEStats  # zeros for a dense model
+    dsa: Optional[DSAStats] = None  # sparse-attention models only
 
 
 # --------------------------------------------------------------------------
@@ -68,8 +78,9 @@ def init_params(
         "wo": nrm(keys[3], (L, Qd, D), std),
     }
     if cfg.is_moe:
-        E, Fe = cfg.num_experts, cfg.expert_ffn_size
-        layers["w_router"] = nrm(keys[8], (L, D, E), std)
+        # the router scores every expert; the weights are of those held
+        E, Fe = cfg.num_held_experts, cfg.expert_ffn_size
+        layers["w_router"] = nrm(keys[8], (L, D, cfg.num_experts), std)
         layers["w_gate"] = nrm(keys[4], (L, E, D, Fe), std)
         layers["w_up"] = nrm(keys[5], (L, E, D, Fe), std)
         layers["w_down"] = nrm(keys[6], (L, E, Fe, D), std)
@@ -91,6 +102,12 @@ def init_params(
         layers["w_gate"] = nrm(keys[4], (L, D, F), std)
         layers["w_up"] = nrm(keys[5], (L, D, F), std)
         layers["w_down"] = nrm(keys[6], (L, F, D), std)
+    if cfg.has_indexer:  # query heads, one key head, a weight a head
+        Hi, Di = cfg.dsa_index_heads, cfg.dsa_index_head_dim
+        ikeys = jax.random.split(jax.random.fold_in(rng, 41), 3)
+        layers["w_idx_q"] = nrm(ikeys[0], (L, D, Hi * Di), std)
+        layers["w_idx_k"] = nrm(ikeys[1], (L, D, Di), std)
+        layers["w_idx_w"] = nrm(ikeys[2], (L, D, Hi), std)
     if cfg.attention_bias:
         layers["bq"] = jnp.zeros((L, Qd), dtype)
         layers["bk"] = jnp.zeros((L, KVd), dtype)
@@ -155,6 +172,10 @@ def param_logical_axes(cfg: ModelConfig, value_head: bool = False) -> Params:
         layers["w_gate"] = ("layer", "embed", "mlp")
         layers["w_up"] = ("layer", "embed", "mlp")
         layers["w_down"] = ("layer", "mlp", "embed")
+    if cfg.has_indexer:  # small, and every device selects for its own rows
+        layers["w_idx_q"] = ("layer", "embed", None)
+        layers["w_idx_k"] = ("layer", "embed", None)
+        layers["w_idx_w"] = ("layer", "embed", None)
     if cfg.attention_bias:
         layers["bq"] = ("layer", "heads")
         layers["bk"] = ("layer", "heads")
@@ -219,11 +240,27 @@ def _layer_body(
         k = apply_rope(k, positions, cos, sin)
     # device scopes (utils/tracing.DEVICE_SCOPES): names only — what a
     # profile's per-op shares are read by; no number and no fusion changes
-    with device_scope("attention"):
-        if attend_fn is None:
-            attn = segment_attention(q, k, v, segment_ids, causal=True)
-        else:  # explicit SP kernel (ring / ulysses shard_map)
-            attn = attend_fn(q, k, v, segment_ids)
+    dsa = None
+    if cfg.has_indexer:
+        # the indexer reads the layer's normed input, detached: it picks
+        # keys, and the policy loss has nothing to tell it (ops/
+        # sparse_attention.py, which opens the scopes of its own steps)
+        with device_scope("dsa_index"):
+            hi = jax.lax.stop_gradient(h)
+            qi = (hi @ lp["w_idx_q"]).reshape(
+                b, t, cfg.dsa_index_heads, cfg.dsa_index_head_dim
+            )
+            ki = hi @ lp["w_idx_k"]
+            wi = hi @ lp["w_idx_w"]
+        attn, dsa = (
+            attend_fn or functools.partial(sparse_attention, topk=cfg.dsa_topk)
+        )(q, k, v, segment_ids, qi, ki, wi)
+    else:
+        with device_scope("attention"):
+            if attend_fn is None:
+                attn = segment_attention(q, k, v, segment_ids, causal=True)
+            else:  # explicit SP kernel (ring / ulysses shard_map)
+                attn = attend_fn(q, k, v, segment_ids)
     # named so a remat policy can SAVE attention outputs: recomputing the
     # forward inside the backward costs 0.6 ms a layer with the splash
     # kernel and 3.8 ms with the XLA form at 4096 tokens (v5e, PERF.md
@@ -249,13 +286,15 @@ def _layer_body(
                 ffn, stats = moe_fn(lp, h, segment_ids > 0)
             if cfg.shared_expert_size:
                 ffn = ffn + shared_expert_from_params(cfg, lp, h)
-        return x + ffn, stats
+        return x + ffn, LayerStats(stats, dsa)
     with device_scope("mlp"):
         ffn = (
             hidden_act_fn(cfg.hidden_act)(h @ lp["w_gate"])
             * (h @ lp["w_up"])
         ) @ lp["w_down"]
-    return x + ffn, MoEStats(*(jnp.zeros((), jnp.float32),) * 3)
+    return x + ffn, LayerStats(
+        MoEStats(*(jnp.zeros((), jnp.float32),) * 3), dsa
+    )
 
 
 def apply(
@@ -267,17 +306,22 @@ def apply(
     remat: bool = True,
     attend_fn: Optional[Any] = None,
     moe_fn: Optional[Any] = None,
-    return_moe_stats: bool = False,
+    return_stats: bool = False,
     mm_embeds: Optional[jnp.ndarray] = None,  # [B, N, D] vision embeds
     mm_index: Optional[jnp.ndarray] = None,  # [B, T] int32; -1 = text
     return_hidden: bool = False,  # lazy ChunkedLogits instead of [B,T,V]
     act_sharding: Optional[Any] = None,  # NamedSharding for [B, T, D] acts
 ):
-    """Forward to logits [B, T, vocab] (fp32); with
-    ``return_moe_stats=True`` returns (logits, ``ops.moe.MoEStats`` of the
-    whole stack: the mean per-layer load-balancing loss, and the
-    assignments and the busiest experts' loads summed over the layers —
-    zeros for dense models).
+    """Forward to logits [B, T, vocab] (fp32); with ``return_stats=True``
+    returns (logits, ``LayerStats`` of the whole stack): ``moe`` the mean
+    per-layer load-balancing loss, and the assignments and the busiest
+    experts' loads summed over the layers (zeros for dense models); ``dsa``,
+    for a model with an indexer, the selected and the causal (query, key)
+    pairs of each layer, int32 [L] (exact: the host sums them).
+
+    For a model with an indexer ``attend_fn`` takes the indexer's query,
+    key and head weights after ``segment_ids`` and returns ``DSAStats``
+    beside the output (``ops/sparse_attention.make_attend``).
 
     `moe_fn(lp, h, valid)` overrides the sparse layer's call the way
     `attend_fn` overrides attention: the engine's per-device ``shard_map``
@@ -328,10 +372,14 @@ def apply(
     if remat:
         # each layer's attention output is kept across the forward->backward
         # boundary (no recompute of the attention forward, [B,T,Hq,D] of
-        # storage a layer); everything else remats
+        # storage a layer); everything else remats. A sparse-attention
+        # layer also keeps what pins its selection ([T] thresholds), so the
+        # recomputed forward and the backward read the forward's keys
+        # without selecting again
+        saved = ("attn_out",) + (("dsa_tau",) if cfg.has_indexer else ())
         body = jax.checkpoint(
             body, prevent_cse=False,
-            policy=jax.checkpoint_policies.save_only_these_names("attn_out"),
+            policy=jax.checkpoint_policies.save_only_these_names(*saved),
         )
     x, aux = jax.lax.scan(body, x, params["layers"])
     x = rms_norm(
@@ -355,11 +403,15 @@ def apply(
             head = head.T
         with device_scope("lm_head"):
             logits = (x.astype(jnp.float32)) @ head.astype(jnp.float32)
-    if return_moe_stats:
-        return logits, MoEStats(
-            aux_loss=jnp.mean(aux.aux_loss),
-            assignments=jnp.sum(aux.assignments),
-            max_expert_assignments=jnp.sum(aux.max_expert_assignments),
+    if return_stats:
+        moe = aux.moe
+        return logits, LayerStats(
+            MoEStats(
+                aux_loss=jnp.mean(moe.aux_loss),
+                assignments=jnp.sum(moe.assignments),
+                max_expert_assignments=jnp.sum(moe.max_expert_assignments),
+            ),
+            aux.dsa,
         )
     return logits
 

@@ -118,16 +118,29 @@ def takes_head_dim(d: int) -> bool:
 
 
 def resolve_attn_impl(
-    attn_impl: str, seq_parallel_size: int, head_dim: int
+    attn_impl: str, seq_parallel_size: int, head_dim: int,
+    has_indexer: bool = False,
 ) -> str:
     """A train engine's ``attn_impl`` against what the process can observe
-    (the way ``GenerationEngine`` resolves its own ``auto``). On a TPU
+    (the way ``GenerationEngine`` resolves its own ``auto``). A model with
+    an indexer (``ModelConfig.has_indexer``) has one attention, whatever
+    was asked: ``sparse_topk`` (``ops/sparse_attention.py``, which picks its
+    own core from the platform), and no sequence-parallel form. On a TPU
     without sequence parallelism ``auto`` is the fused splash kernel
     wherever it takes the model's head dimension: the XLA form writes
     float32 scores [heads, T, T] per layer and pass. Elsewhere ``auto``
     is that XLA form, which GSPMD shards; so is an explicit SP kernel
     with nothing to split."""
     no_sp = seq_parallel_size == 1
+    if has_indexer:
+        if not no_sp:
+            from areal_tpu.ops.sparse_attention import MECHANISM
+
+            raise ValueError(
+                f"{MECHANISM} has no sequence-parallel form "
+                f"(seq_parallel_size={seq_parallel_size}): ROADMAP B"
+            )
+        return "sparse_topk"
     if attn_impl == "auto":
         fused = (
             jax.default_backend() == "tpu" and no_sp

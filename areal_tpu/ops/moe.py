@@ -15,6 +15,15 @@ tokens x k rows, and the group sizes are data.
 Padding (``valid`` false) takes no part: its pairs sort behind every group,
 are zeroed on the way in and on the way out, and count in no statistic.
 
+The layer may HOLD fewer experts than the router scores (one chip's share of
+an expert-parallel deployment: ``w_gate`` has fewer experts than ``w_router``
+has outputs). It then routes over all of them, as the whole layer would,
+and computes the part of the result that its own experts give: a pair
+routed to an expert held elsewhere is treated as padding's are. The shares
+of all the holders add up to the whole layer's output; the exchange that
+would bring each holder the other chips' tokens is not here (ROADMAP B.2).
+The statistics then count the pairs routed to experts held.
+
 Which grouped matmul runs is resolved from the platform (``grouped_impl``),
 as the trainer's ``attn_impl: auto`` is: the Pallas ``megablox`` kernel on a
 TPU, ``jax.lax.ragged_dot`` elsewhere (the CPU's path and the form the
@@ -205,56 +214,43 @@ def _combine_bwd(res, g_out):
 _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
-def moe_ffn(
-    x: jnp.ndarray,  # [B, T, D]
-    w_router: jnp.ndarray,  # [D, E]
-    w_gate: jnp.ndarray,  # [E, D, F]
-    w_up: jnp.ndarray,  # [E, D, F]
-    w_down: jnp.ndarray,  # [E, F, D]
-    num_experts_per_tok: int,
-    norm_topk_prob: bool = True,
-    valid: Optional[jnp.ndarray] = None,  # [B, T] bool
-    token_axes: Optional[Tuple[str, ...]] = None,
-) -> Tuple[jnp.ndarray, MoEStats]:
-    """Returns (output [B, T, D], ``MoEStats``). ``valid`` masks padding
-    and inactive slots out: zero output, no load, no statistic.
-    ``token_axes`` names the mesh axes the tokens are split over when this
-    runs per device inside a ``shard_map``: the statistics (and the
-    auxiliary loss) are then the whole batch's, summed over those axes."""
-    b, t, d = x.shape
-    e = w_router.shape[-1]
-    k = num_experts_per_tok
-    xf = x.reshape(-1, d)  # [G, D]
-    g = xf.shape[0]
-    vf = None if valid is None else valid.reshape(-1)
-    with device_scope("moe_route"):
-        # float32 and full precision: with near-equal probabilities a
-        # bf16-pass product can swap a token's k-th and (k+1)-th expert
-        logits = jnp.dot(
-            xf.astype(jnp.float32), w_router.astype(jnp.float32),
-            precision=jax.lax.Precision.HIGHEST,
-        )
-        topk_p, topk_i, probs = router_topk(logits, k, norm_topk_prob)
-        aux = load_balancing_loss(
-            probs, topk_i, e, valid=vf, token_axes=token_axes
-        )
-        # a pair of a padding token belongs to no expert: id E sorts last
-        pair_expert = topk_i.reshape(-1).astype(jnp.int32)  # [G*k]
-        if vf is not None:
-            pair_expert = jnp.where(jnp.repeat(vf, k), pair_expert, e)
-        group_sizes = jnp.sum(
-            pair_expert[:, None] == jnp.arange(e, dtype=jnp.int32)[None],
-            axis=0, dtype=jnp.int32,
-        )
-        load = (
-            jax.lax.psum(group_sizes, token_axes) if token_axes
-            else group_sizes
-        )
-        stats = MoEStats(
-            aux_loss=aux,
-            assignments=load.sum().astype(jnp.float32),
-            max_expert_assignments=load.max().astype(jnp.float32),
-        )
+# rows of the sorted stream (tokens x k) above which the dispatch and the
+# grouped matmuls run in token chunks of at most this many rows. A layer
+# that holds a share of the experts still sorts every pair (a static shape
+# cannot know how many are its own): one 16,384-token row of 8 a token is
+# 131,072 rows, 0.5 GB in bf16 for each of the stream's half-dozen arrays,
+# forward and backward. 32,768 is the four-chip sparse cell's stream (one
+# 4096-token row a chip), which stays one chunk.
+_STREAM_ROWS = 32768
+
+
+def _token_chunks(g: int, k: int) -> int:
+    """How many equal token chunks a stream of ``g`` x ``k`` rows runs in."""
+    n = 1
+    while g * k > n * _STREAM_ROWS and g % (2 * n) == 0:
+        n *= 2
+    return n
+
+
+def _group_sizes(pair_expert, e):
+    """[E] int32: how many of the pairs go to each of the ``e`` experts."""
+    return jnp.sum(
+        pair_expert[:, None] == jnp.arange(e, dtype=jnp.int32)[None],
+        axis=0, dtype=jnp.int32,
+    )
+
+
+def _run_experts(xf, pair_expert, topk_p, group_sizes, w_gate, w_up, w_down):
+    """[G, D] tokens through their pairs' experts: sort the pairs by
+    expert, the three grouped matmuls, un-permute and sum with the routing
+    weights. ``pair_expert`` [G*k] holds E for a pair that is nobody's here
+    (padding; an expert held elsewhere); ``group_sizes`` [E] the count of
+    each expert's pairs (None: counted here)."""
+    g, d = xf.shape
+    e, k = w_gate.shape[0], topk_p.shape[1]
+    if group_sizes is None:
+        with device_scope("moe_dispatch"):
+            group_sizes = _group_sizes(pair_expert, e)
     impl = grouped_impl((g * k, d, w_gate.shape[-1]))
     pair_ok = pair_expert < e
     with device_scope("moe_dispatch"):
@@ -270,7 +266,84 @@ def moe_ffn(
             jnp.where(in_group, h, 0), w_down, group_sizes, impl
         )
     with device_scope("moe_dispatch"):
-        out = _combine(ys, topk_p, order, inv, pair_ok).astype(x.dtype)
+        return _combine(ys, topk_p, order, inv, pair_ok)
+
+
+def moe_ffn(
+    x: jnp.ndarray,  # [B, T, D]
+    w_router: jnp.ndarray,  # [D, E]
+    w_gate: jnp.ndarray,  # [E, D, F]
+    w_up: jnp.ndarray,  # [E, D, F]
+    w_down: jnp.ndarray,  # [E, F, D]
+    num_experts_per_tok: int,
+    norm_topk_prob: bool = True,
+    valid: Optional[jnp.ndarray] = None,  # [B, T] bool
+    token_axes: Optional[Tuple[str, ...]] = None,
+    first_expert: int = 0,
+) -> Tuple[jnp.ndarray, MoEStats]:
+    """Returns (output [B, T, D], ``MoEStats``). ``valid`` masks padding
+    and inactive slots out: zero output, no load, no statistic.
+    ``token_axes`` names the mesh axes the tokens are split over when this
+    runs per device inside a ``shard_map``: the statistics (and the
+    auxiliary loss) are then the whole batch's, summed over those axes.
+    Where ``w_gate`` holds fewer experts than ``w_router`` scores, they are
+    experts ``first_expert`` and following, and the output is their part."""
+    b, t, d = x.shape
+    n_routed = w_router.shape[-1]
+    e = w_gate.shape[0]  # experts held: the groups of the grouped matmuls
+    k = num_experts_per_tok
+    xf = x.reshape(-1, d)  # [G, D]
+    g = xf.shape[0]
+    vf = None if valid is None else valid.reshape(-1)
+    with device_scope("moe_route"):
+        # float32 and full precision: with near-equal probabilities a
+        # bf16-pass product can swap a token's k-th and (k+1)-th expert
+        logits = jnp.dot(
+            xf.astype(jnp.float32), w_router.astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST,
+        )
+        topk_p, topk_i, probs = router_topk(logits, k, norm_topk_prob)
+        aux = load_balancing_loss(
+            probs, topk_i, n_routed, valid=vf, token_axes=token_axes
+        )
+        # a pair of a padding token belongs to no expert: id E sorts last
+        pair_expert = topk_i.reshape(-1).astype(jnp.int32)  # [G*k]
+        if e < n_routed:  # so does a pair routed to an expert held elsewhere
+            pair_expert = pair_expert - first_expert
+            pair_expert = jnp.where(
+                (pair_expert >= 0) & (pair_expert < e), pair_expert, e
+            )
+        if vf is not None:
+            pair_expert = jnp.where(jnp.repeat(vf, k), pair_expert, e)
+        group_sizes = _group_sizes(pair_expert, e)
+        load = (
+            jax.lax.psum(group_sizes, token_axes) if token_axes
+            else group_sizes
+        )
+        stats = MoEStats(
+            aux_loss=aux,
+            assignments=load.sum().astype(jnp.float32),
+            max_expert_assignments=load.max().astype(jnp.float32),
+        )
+    n_chunks = _token_chunks(g, k)
+    if n_chunks == 1:
+        out = _run_experts(
+            xf, pair_expert, topk_p, group_sizes, w_gate, w_up, w_down
+        )
+    else:
+        # a long stream in token chunks, one after the other, each chunk's
+        # intermediates dropped after it (recomputed in its backward): the
+        # stream's buffers are a chunk's. Dropless, so a token's output does
+        # not depend on its chunk-mates
+        def chunk(args):
+            return _run_experts(*args, None, w_gate, w_up, w_down)
+
+        out = jax.lax.map(jax.checkpoint(chunk), (
+            xf.reshape(n_chunks, -1, d),
+            pair_expert.reshape(n_chunks, -1),
+            topk_p.reshape(n_chunks, -1, k),
+        )).reshape(g, d)
+    out = out.astype(x.dtype)
     return out.reshape(b, t, d), stats
 
 

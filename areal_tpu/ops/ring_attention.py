@@ -241,14 +241,20 @@ def make_sharded_attention(
     return attend
 
 
-def attention_fn(impl: str, mesh: Mesh, window: int = 0):
+def attention_fn(impl: str, mesh: Mesh, window: int = 0, topk: int = 0):
     """The attention callable ``attend(q, k, v, segment_ids)`` for a
     resolved name (``ops.flash.resolve_attn_impl``) on ``mesh``: "flash"
     (the Pallas splash kernel with its local ``window``, TPU-only),
-    "ring"/"ulysses" (the explicit sequence-parallel ``shard_map``), or
-    None for "xla", the XLA kernel with GSPMD auto-sharding."""
+    "ring"/"ulysses" (the explicit sequence-parallel ``shard_map``),
+    "sparse_topk" (a model with an indexer: ``topk`` selected keys a query,
+    ``ops.sparse_attention.make_attend``, which also takes the indexer's
+    arrays), or None for "xla", the XLA kernel with GSPMD auto-sharding."""
     if impl == "xla":
         return None
+    if impl == "sparse_topk":
+        from areal_tpu.ops.sparse_attention import make_attend
+
+        return make_attend(mesh, topk)
     if impl != "flash":
         return make_sharded_attention(mesh, impl=impl)
     from areal_tpu.ops.flash import flash_segment_attention

@@ -28,6 +28,7 @@ from areal_tpu.engine import programs
 from areal_tpu.models.config import ModelConfig
 from areal_tpu.models.transformer import init_params, param_logical_axes
 from areal_tpu.ops.blockwise_attention import blockwise_segment_attention
+from areal_tpu.ops.ring_attention import attention_fn
 from areal_tpu.ops.functional import (
     ppo_actor_loss_fn,
     target_aligned_logprobs,
@@ -87,6 +88,7 @@ def grpo_step_memory(
     param_dtype=jnp.float32,
     compute_dtype=jnp.bfloat16,
     hbm_limit_gb: float = 16.0,
+    devices=None,
 ) -> Dict[str, Any]:
     """AOT-lower the decoupled-GRPO grad step + adam apply for the given
     mesh factoring; returns per-device memory numbers + a fits verdict.
@@ -96,8 +98,10 @@ def grpo_step_memory(
     [rows, bucket] streams, remat'd scanned layers that save their attention
     outputs, chunked LM head, a sparse model's layers in their mesh form,
     f32 grad accumulation with donation, the guarded adam update.
+    ``devices``: the mesh's, where they are not ``jax.devices()`` (a
+    described TPU topology's: the chip's own compiler and its analysis).
     """
-    mesh = mesh_lib.make_mesh(parallel)
+    mesh = mesh_lib.make_mesh(parallel, devices)
     logical = param_logical_axes(model_cfg)
     param_sh = sharding_lib.tree_shardings(mesh, logical)
     params_shape = jax.eval_shape(
@@ -146,7 +150,11 @@ def grpo_step_memory(
             # would report the [T, T] scores it materializes and the chip
             # never holds. The blockwise XLA kernel has the splash kernel's
             # memory profile with identical numerics.
-            attend_fn=blockwise_segment_attention,
+            # (a model with an indexer has one attention, its own)
+            attend_fn=(
+                attention_fn("sparse_topk", mesh, topk=model_cfg.dsa_topk)
+                if model_cfg.has_indexer else blockwise_segment_attention
+            ),
             remat=True,
             loss_fn=_grpo_loss_fn, loss_weight_fn=_grpo_loss_weight_fn,
         )
@@ -331,7 +339,45 @@ def olmoe_1b_7b_config(num_layers: int = 5) -> ModelConfig:
     )
 
 
+def keye_vl_2p0_30b_a3b_config(num_layers: int = 5) -> ModelConfig:
+    """The language model of Keye-VL-2.0-30B-A3B (Kwai-Keye; a qwen3-moe
+    block, 128 experts of width 768 of which 8 a token, whose attention
+    reads the 2048 keys an indexer of 16 heads of 64 picks), as ONE chip of
+    eight that share each layer holds it: 16 of the 128 experts, an eighth
+    of the vocabulary, and the cut in depth of
+    benchmark/configs/keye-vl-2.0-30b-a3b.json (published: 48 layers,
+    151,936 rows). Every width, the router's 128 outputs and top-8 are as
+    published."""
+    return ModelConfig(
+        vocab_size=18992,
+        hidden_size=2048,
+        intermediate_size=6144,
+        num_layers=num_layers,
+        num_heads=32,
+        num_kv_heads=4,
+        head_dim=128,
+        max_position_embeddings=262144,
+        rope_theta=10000000,
+        rms_norm_eps=1e-6,
+        tie_word_embeddings=False,
+        attention_bias=False,
+        qk_norm="head",
+        family="KeyeVL2",
+        num_experts=128,
+        num_experts_per_tok=8,
+        moe_intermediate_size=768,
+        norm_topk_prob=True,
+        router_aux_loss_coef=0.0,
+        mrope_sections=(16, 24, 24),
+        dsa_topk=2048,
+        dsa_index_heads=16,
+        dsa_index_head_dim=64,
+        experts_held=16,
+    )
+
+
 MODEL_CONFIGS = {
+    "keye_vl_2p0_30b_a3b": keye_vl_2p0_30b_a3b_config,
     "olmoe_1b_7b": olmoe_1b_7b_config,
     "qwen2_0p5b": qwen2_0p5b_config,
     "qwen2_7b": qwen2_7b_config,

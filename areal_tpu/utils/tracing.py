@@ -146,6 +146,10 @@ DEVICE_SCOPES = (
     "kv_write",
     # inside ``mlp`` where the layer is sparse (ops/moe.py); innermost wins
     "moe_route", "moe_dispatch", "moe_experts",
+    # learned sparse attention (ops/sparse_attention.py): the indexer's
+    # projections and scores, and the top-k selection; the core it feeds
+    # stays under ``attention``
+    "dsa_index", "dsa_select",
 )
 
 

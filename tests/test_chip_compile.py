@@ -222,3 +222,44 @@ def test_sparse_layer_fwd_bwd_compiles_for_v5e(one_chip, monkeypatch, t):
         .compile()
     )
     assert compiled.as_text().count("tpu_custom_call") >= 9
+
+
+def test_sparse_attention_fwd_bwd_compiles_for_v5e(one_chip):
+    """``ops/sparse_attention.sparse_attention`` forward and backward at
+    Keye-VL-2.0's published widths (32 / 4 heads of 128, an indexer of 16
+    heads of 64, topk 2048) on one 8192-token row: the index and the
+    selection in XLA, then the three Pallas kernels of the core
+    (``dsa_core_fwd``, ``dsa_core_dq``, ``dsa_core_dkv``) over int8 tiles
+    of the selection, all the query heads of a kv head a grid step. No
+    [T, T] float32 array in the compiled program."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from areal_tpu.ops import sparse_attention as sa
+
+    t, bf = 8192, jnp.bfloat16
+
+    def loss(q, k, v, seg, qi, ki, w):
+        out, stats = sa.sparse_attention(
+            q, k, v, seg, qi, ki, w, topk=2048, core="kernel"
+        )
+        return out.astype(jnp.float32).sum(), stats
+
+    compiled = (
+        jax.jit(jax.grad(loss, argnums=(0, 1, 2), has_aux=True))
+        .lower(
+            _sds((1, t, 32, 128), bf, one_chip),
+            _sds((1, t, 4, 128), bf, one_chip),
+            _sds((1, t, 4, 128), bf, one_chip),
+            _sds((1, t), jnp.int32, one_chip),
+            _sds((1, t, 16, 64), bf, one_chip),
+            _sds((1, t, 64), bf, one_chip),
+            _sds((1, t, 16), bf, one_chip),
+        )
+        .compile()
+    )
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 3
+    assert not re.search(rf"f32\[[0-9,]*{t},{t}[0-9,]*\]", text)

@@ -137,6 +137,9 @@ WANT = {
     "write_rows": {"kv_write"},
     # a sparse layer's three, inside its mlp (innermost wins in a profile)
     "sparse_grad_step": {"mlp", "moe_route", "moe_dispatch", "moe_experts"},
+    # learned sparse attention: the indexer and the selection before the
+    # core, which stays under attention
+    "dsa_grad_step": {"attention", "dsa_index", "dsa_select", "moe_experts"},
 }
 
 
@@ -147,6 +150,7 @@ def lowered():
         for name, low in _train_programs() + _decode_programs()
     }
     out["sparse_grad_step"] = _op_names(_train_programs("olmoe")[0][1])
+    out["dsa_grad_step"] = _op_names(_train_programs("KeyeVL2")[0][1])
     return out
 
 
@@ -155,7 +159,7 @@ def test_program_lowers_under_its_contract_name_with_its_scopes(
     lowered, program
 ):
     text, names = lowered[program]
-    jitted = program.removeprefix("sparse_")
+    jitted = program.removeprefix("sparse_").removeprefix("dsa_")
     assert re.search(rf"^HloModule jit_{jitted}[, ]", text, re.M)
     assert WANT[program] <= _scopes_in(names), sorted(names)[:40]
 

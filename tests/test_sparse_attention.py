@@ -1,0 +1,416 @@
+"""Learned sparse attention (the KeyeVL2 family: a qwen3-moe block over the
+top-k keys an indexer picks, one chip's share of the experts) through the
+program against the tests' twin of its plain reference
+(``tests/keye_reference.py``), float32 against float32 on the CPU at the
+family's tiny configuration: 2 layers, topk 8 (the sequences here are 24 to
+48 tokens, so most queries select), two indexer heads, 8 experts held of 16.
+
+Tolerances. Both sides compute in float32 and select the same keys (an index
+score would have to tie to 1e-7 relative for a swap), so what differs is the
+order of sums: logits and logprobs of magnitude ~1 are held to 1e-3 absolute
+(a bf16 forward reads ~1e-2 and fails; measured here ~1e-6), gradients to
+1e-2 of each leaf's largest entry (sums over up to 40 positions of such
+terms; measured ~1e-5). The kernel (Pallas interpret mode) against the XLA
+core is float32 against float32 with another blocking of the softmax: 1e-5.
+"""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from areal_tpu.api.cli_args import ParallelismConfig
+from areal_tpu.models.config import tiny_config
+from areal_tpu.models.transformer import apply, init_params
+from areal_tpu.ops import sparse_attention as sa
+from areal_tpu.ops.basic import (
+    apply_mrope, apply_rope, rope_frequencies, segment_attention,
+)
+from areal_tpu.ops.moe import moe_ffn
+from areal_tpu.parallel import mesh as mesh_lib
+from benchmark.kinds import train_steps_dsa
+
+import keye_reference as twin
+
+LOGIT_TOL, GRAD_TOL, KERNEL_TOL = 1e-3, 1e-2, 1e-5
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    # the family's tiny configuration and the reference's keys for it
+    cfg, ref_cfg = train_steps_dsa.tiny_configs()
+    params = init_params(cfg, jax.random.PRNGKey(11), dtype=jnp.float32)
+    rng = np.random.default_rng(11)
+    lay = params["layers"]
+    for k in ("q_norm", "k_norm", "input_norm", "post_attn_norm"):
+        lay[k] = lay[k] * jnp.asarray(
+            1.0 + 0.3 * rng.standard_normal(lay[k].shape), jnp.float32
+        )
+    # weights large enough that routing and selection are decided
+    lay["w_router"] = lay["w_router"] * 20.0
+    for k in ("w_idx_q", "w_idx_k", "w_idx_w"):
+        lay[k] = lay[k] * 10.0
+    return cfg, ref_cfg, params
+
+
+def _alone(cfg, params, tokens, **kw):
+    t = jnp.asarray(tokens, jnp.int32)[None]
+    return apply(
+        params, cfg, t, jnp.ones_like(t),
+        jnp.arange(t.shape[1], dtype=jnp.int32)[None], remat=False, **kw
+    )
+
+
+def _random_op_inputs(rng, t, hq=4, hkv=2, d=64, hi=2, di=8):
+    f = lambda *s: jnp.asarray(rng.standard_normal(s), jnp.float32)  # noqa: E731
+    return (f(1, t, hq, d), f(1, t, hkv, d), f(1, t, hkv, d)), (
+        f(1, t, hi, di), f(1, t, di), f(1, t, hi)
+    )
+
+
+def test_tiny_config_is_the_family(tiny):
+    cfg = tiny[0]
+    assert cfg.family == "KeyeVL2" and cfg.qk_norm == "head" and cfg.is_moe
+    assert cfg.has_indexer and (cfg.dsa_topk, cfg.dsa_index_heads) == (8, 2)
+    assert (cfg.num_experts, cfg.num_held_experts) == (16, 8)
+    assert cfg.norm_topk_prob and not cfg.shared_expert_size
+    lay = tiny[2]["layers"]
+    assert lay["w_router"].shape[-1] == 16 and lay["w_gate"].shape[1] == 8
+    assert lay["w_idx_q"].shape[-1] == 2 * 8 and lay["w_idx_w"].shape[-1] == 2
+
+
+def test_logits_match_the_reference_with_sequences_longer_than_topk(tiny):
+    cfg, ref_cfg, params = tiny
+    tokens = np.random.default_rng(0).integers(0, cfg.vocab_size, 40)
+    got, stats = _alone(cfg, params, tokens, return_stats=True)
+    want = np.asarray(twin.logits(ref_cfg, params, tokens))
+    assert np.abs(want).max() > 0.1
+    np.testing.assert_allclose(np.asarray(got[0]), want, atol=LOGIT_TOL, rtol=0)
+    # each layer kept sum_t min(t + 1, 8) of the 40 * 41 / 2 causal pairs
+    assert np.asarray(stats.dsa.kept_pairs).tolist() == [36 + 32 * 8] * 2
+    assert np.asarray(stats.dsa.causal_pairs).tolist() == [820] * 2
+    # and it is not full attention under another name
+    full = _alone(dataclasses.replace(cfg, dsa_topk=0), {
+        **params, "layers": {k: v for k, v in params["layers"].items()
+                             if not k.startswith("w_idx")},
+    }, tokens)
+    assert np.abs(np.asarray(full[0]) - want).max() > 10 * LOGIT_TOL
+
+
+def test_loss_and_every_gradient_match_and_the_indexers_is_zero(tiny):
+    cfg, ref_cfg, params = tiny
+    tokens = np.random.default_rng(1).integers(0, cfg.vocab_size, 40)
+
+    def summed(p):
+        lp = jax.nn.log_softmax(_alone(cfg, p, tokens)[0, :-1], axis=-1)
+        tgt = jnp.asarray(tokens[1:], jnp.int32)
+        return jnp.take_along_axis(lp, tgt[:, None], axis=-1).sum()
+
+    loss, got = jax.value_and_grad(summed)(params)
+    assert float(loss) == pytest.approx(
+        float(twin.summed_logprob(ref_cfg, params, tokens)), abs=LOGIT_TOL * 40
+    )
+    want = twin.summed_logprob_grad(ref_cfg, params, tokens)
+    flat_got = jax.tree_util.tree_leaves_with_path(got)
+    flat_want = jax.tree_util.tree_leaves(want)
+    assert len(flat_got) == len(flat_want) == 3 + len(params["layers"])
+    for (path, g), w in zip(flat_got, flat_want):
+        name = jax.tree_util.keystr(path)
+        if "w_idx" in name:  # chosen by index: exactly zero on both sides
+            assert not np.asarray(g).any() and not np.asarray(w).any(), name
+            continue
+        scale = float(jnp.abs(w).max())
+        assert scale > 0, name
+        np.testing.assert_allclose(
+            np.asarray(g), np.asarray(w), atol=GRAD_TOL * scale, rtol=0,
+            err_msg=name,
+        )
+
+
+def test_two_sequences_in_one_row_select_nothing_across_the_border(tiny):
+    cfg, _, params = tiny
+    rng = np.random.default_rng(2)
+    seqs = [rng.integers(0, cfg.vocab_size, n) for n in (30, 24)]
+    alone = [np.asarray(_alone(cfg, params, s)[0]) for s in seqs]
+    pad = 10
+    row = np.concatenate(seqs + [np.zeros(pad, np.int64)])
+    seg = np.concatenate([np.full(30, 1), np.full(24, 2), np.zeros(pad)])
+    pos = np.concatenate([np.arange(30), np.arange(24), np.zeros(pad)])
+    as_row = lambda a: jnp.asarray(a[None], jnp.int32)  # noqa: E731
+    packed, stats = apply(
+        params, cfg, as_row(row), as_row(seg), as_row(pos), remat=False,
+        return_stats=True,
+    )
+    np.testing.assert_allclose(packed[0, :30], alone[0], atol=LOGIT_TOL, rtol=0)
+    np.testing.assert_allclose(packed[0, 30:54], alone[1], atol=LOGIT_TOL, rtol=0)
+    kept = 36 + 22 * 8 + 36 + 16 * 8  # padding counts nowhere
+    assert np.asarray(stats.dsa.kept_pairs).tolist() == [kept] * 2
+    # the op's own mask: no (query, key) pair joins two segments
+    (q, k, v), (qi, ki, w) = _random_op_inputs(rng, 64)
+    mask, _ = sa.select_keys(qi[0], ki[0], w[0], as_row(seg)[0], 8)
+    mask = np.asarray(mask)
+    assert not mask[30:54, :30].any() and not mask[:54, 54:].any()
+    assert (mask[:54].sum(1) == np.minimum(pos[:54] + 1, 8)).all()
+
+
+def test_selection_is_exact_against_a_sort_and_breaks_ties_to_the_lower_index():
+    rng = np.random.default_rng(3)
+    t, topk = 200, 16
+    _, (qi, ki, w) = _random_op_inputs(rng, t)
+    seg = np.zeros(t, np.int32)
+    seg[:90], seg[90:190] = 1, 2
+    scores = np.einsum(
+        "hts,th->ts",
+        np.maximum(np.einsum("thd,sd->hts", qi[0], ki[0]), 0), w[0],
+    )
+    want = np.zeros((t, t), bool)
+    for q in range(190):
+        own = [s for s in range(q + 1) if seg[s] == seg[q]]
+        best = np.argsort(-scores[q, own], kind="stable")[:topk]
+        want[q, [own[i] for i in best]] = True
+    select = jax.jit(lambda *a: sa.select_keys(*a, topk))
+    mask, stats = select(qi[0], ki[0], w[0], jnp.asarray(seg))
+    assert (np.asarray(mask)[:190] == want[:190]).all()
+    assert int(stats.kept_pairs) == want.sum()
+    assert int(stats.causal_pairs) == 90 * 91 // 2 + 100 * 101 // 2
+    # every score equal (0): the first topk keys of the sequence win
+    tied, _ = select(qi[0] * 0, ki[0], w[0], jnp.asarray(seg))
+    assert np.nonzero(np.asarray(tied)[150])[0].tolist() == list(range(90, 106))
+
+
+def test_a_sequence_within_topk_ignores_the_indexer_and_is_causal_attention(tiny):
+    cfg, _, params = tiny
+    tokens = np.random.default_rng(4).integers(0, cfg.vocab_size, 8)  # = topk
+    base = np.asarray(_alone(cfg, params, tokens))
+    other = dict(params, layers=dict(params["layers"]))
+    for k in ("w_idx_q", "w_idx_k", "w_idx_w"):
+        other["layers"][k] = -3.0 * params["layers"][k][::-1]
+    assert (np.asarray(_alone(cfg, other, tokens)) == base).all()  # to the bit
+    rng = np.random.default_rng(5)
+    (q, k, v), (qi, ki, w) = _random_op_inputs(rng, 128)
+    seg = jnp.asarray(np.repeat([1, 2, 0], [70, 50, 8])[None], jnp.int32)
+    want = segment_attention(q, k, v, seg)
+    for core in ("xla", "kernel"):
+        got, stats = sa.sparse_attention(
+            q, k, v, seg, qi, ki, w, topk=128, core=core, interpret=True
+        )
+        np.testing.assert_allclose(got, want, atol=KERNEL_TOL, rtol=0)
+        assert int(stats.kept_pairs) == int(stats.causal_pairs)
+
+
+def test_three_equal_mrope_coordinates_are_plain_rope():
+    cfg = tiny_config("KeyeVL2")
+    rng = np.random.default_rng(6)
+    x = jnp.asarray(rng.standard_normal((2, 24, 4, cfg.head_dim)), jnp.float32)
+    pos = jnp.asarray(rng.integers(0, 400, (2, 24)), jnp.int32)
+    cos, sin = rope_frequencies(cfg.head_dim, 512, cfg.rope_theta)
+    three = jnp.repeat(pos[..., None], 3, axis=-1)
+    np.testing.assert_array_equal(
+        apply_mrope(x, three, cos, sin, cfg.mrope_sections),
+        apply_rope(x, pos, cos, sin),
+    )
+
+
+@pytest.mark.parametrize("blocks", [(128, 256), (512, 1024)])
+def test_the_kernel_in_interpret_mode_equals_the_xla_core_both_ways(
+    blocks, monkeypatch
+):
+    """1024 tokens, two sequences and padding: with 128 x 256 tiles the grid
+    is 8 x 4 of which the tiles above the diagonal and the first sequence's
+    keys under the second's queries hold nothing and are skipped; with the
+    chip's own tile sizes a row this short is 2 x 1."""
+    monkeypatch.setattr(sa, "_BLOCK_Q", blocks[0])
+    monkeypatch.setattr(sa, "_BLOCK_KV", blocks[1])
+    rng = np.random.default_rng(7)
+    (q, k, v), (qi, ki, w) = _random_op_inputs(rng, 1024)
+    seg = jnp.asarray(np.repeat([1, 2, 0], [300, 600, 124])[None], jnp.int32)
+    mask, _ = sa.select_keys(qi[0], ki[0], w[0], seg[0], 16)
+    tiles = np.asarray(sa._kernel_selection(mask)[2])
+    assert tiles.shape == (1024 // sa._core_blocks(1024)[0],
+                           1024 // sa._core_blocks(1024)[1])
+    assert (tiles.size > 2) == (not tiles.all())  # something is skipped
+    weight = jnp.cos(jnp.arange(q.size, dtype=jnp.float32)).reshape(q.shape)
+
+    def run(core):
+        def f(q, k, v):
+            out, _ = sa.sparse_attention(
+                q, k, v, seg, qi, ki, w, topk=16, core=core, interpret=True
+            )
+            return (out * weight).sum(), out
+        return jax.jit(jax.value_and_grad(f, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+
+    (_, out_x), grads_x = run("xla")
+    (_, out_s), grads_s = run("kernel")
+    assert np.abs(np.asarray(out_x)).max() > 0.1
+    np.testing.assert_allclose(out_s, out_x, atol=KERNEL_TOL, rtol=0)
+    for a, b in zip(grads_s, grads_x):
+        np.testing.assert_allclose(a, b, atol=10 * KERNEL_TOL, rtol=0)
+    assert not np.asarray(out_s)[0, 900:].any()  # padding reads nothing
+
+
+def test_the_op_runs_per_chip_on_its_own_rows_under_an_fsdp_mesh():
+    rng = np.random.default_rng(8)
+    ins = [_random_op_inputs(rng, 64) for _ in range(4)]
+    qkv = [jnp.concatenate([i[0][j] for i in ins]) for j in range(3)]
+    idx = [jnp.concatenate([i[1][j] for i in ins]) for j in range(3)]
+    seg = jnp.asarray(
+        np.stack([np.repeat([1, 2, 0], [n, 60 - n, 4]) for n in (20, 30, 40, 50)]),
+        jnp.int32,
+    )
+    want, want_stats = sa.sparse_attention(*qkv, seg, *idx, topk=8)
+    mesh = mesh_lib.make_mesh(ParallelismConfig(fsdp_parallel_size=4))
+    got, got_stats = jax.jit(sa.make_attend(mesh, 8))(*qkv, seg, *idx)
+    np.testing.assert_allclose(got, want, atol=KERNEL_TOL, rtol=0)
+    assert int(got_stats.kept_pairs) == int(want_stats.kept_pairs) > 0
+    assert int(got_stats.causal_pairs) == int(want_stats.causal_pairs)
+
+
+def test_sequence_parallelism_and_the_generation_engine_refuse_by_name(tiny):
+    from areal_tpu.api.cli_args import JaxGenConfig
+    from areal_tpu.inference.engine import GenerationEngine
+    from areal_tpu.ops.flash import resolve_attn_impl
+
+    cfg, _, params = tiny
+    assert resolve_attn_impl("auto", 1, cfg.head_dim, has_indexer=True) == "sparse_topk"
+    with pytest.raises(ValueError, match="sparse attention.*sequence-parallel"):
+        resolve_attn_impl("auto", 2, cfg.head_dim, has_indexer=True)
+    mesh = mesh_lib.make_mesh(ParallelismConfig(seq_parallel_size=2))
+    with pytest.raises(ValueError, match="sparse attention.*sequence-parallel"):
+        sa.make_attend(mesh, 8)
+    with pytest.raises(NotImplementedError, match="sparse attention.*indexer-key cache"):
+        GenerationEngine(JaxGenConfig(), model_config=cfg, params=params)
+
+
+# ------------------------------------------------ the share of the experts --
+def _expert_weights(rng, d=32, f=16, e=16):
+    n = lambda *s: jnp.asarray(rng.standard_normal(s) * 0.3, jnp.float32)  # noqa: E731
+    return n(d, e) * 5, n(e, d, f), n(e, d, f), n(e, f, d)
+
+
+@pytest.mark.parametrize("shares", [2, 4])
+def test_the_shares_of_the_experts_add_up_to_the_whole_layer(shares):
+    """What ties the share to the model: each holder routes over all 16
+    experts with the same router and computes its own experts' part; the
+    parts add up to the uncut reference's layer output."""
+    rng = np.random.default_rng(9)
+    wr, wg, wu, wd = _expert_weights(rng)
+    x = jnp.asarray(rng.standard_normal((2, 12, 32)), jnp.float32)
+    valid = jnp.asarray(rng.random((2, 12)) > 0.2)
+    c = {"num_experts_per_tok": 3, "norm_topk_prob": True}
+    lp = {"w_router": wr, "w_gate": wg, "w_up": wu, "w_down": wd}
+    whole = np.asarray(twin.experts(c, x.reshape(-1, 32), lp)).reshape(x.shape)
+    whole = np.where(np.asarray(valid)[..., None], whole, 0)
+    n = 16 // shares
+    total, pairs = 0, 0
+    for i in range(shares):
+        held = slice(i * n, (i + 1) * n)
+        part, stats = moe_ffn(
+            x, wr, wg[held], wu[held], wd[held], 3, True, valid=valid,
+            first_expert=i * n,
+        )
+        want = twin.experts(
+            c, x.reshape(-1, 32),
+            dict(lp, w_gate=wg[held], w_up=wu[held], w_down=wd[held]),
+            first=i * n,
+        )
+        np.testing.assert_allclose(
+            part, np.where(np.asarray(valid)[..., None],
+                           np.asarray(want).reshape(x.shape), 0),
+            atol=1e-5, rtol=0,
+        )
+        total, pairs = total + np.asarray(part), pairs + int(stats.assignments)
+    np.testing.assert_allclose(total, whole, atol=1e-5, rtol=0)
+    assert pairs == int(valid.sum()) * 3  # every routed pair has one holder
+
+
+def test_a_long_stream_in_token_chunks_is_the_same_layer(monkeypatch):
+    """A stream longer than ``_STREAM_ROWS`` runs its dispatch and grouped
+    matmuls a token chunk at a time (a held share still sorts every pair:
+    131,072 rows a 16k-token row); output, gradients and statistics are
+    the one-chunk layer's."""
+    from areal_tpu.ops import moe
+
+    rng = np.random.default_rng(13)
+    wr, wg, wu, wd = _expert_weights(rng)
+    x = jnp.asarray(rng.standard_normal((1, 64, 32)), jnp.float32)
+    valid = jnp.asarray(rng.random((1, 64)) > 0.1)
+
+    def run(x, wg):
+        out, stats = moe_ffn(x, wr, wg[:8], wu[:8], wd[:8], 2, True, valid=valid)
+        return (out * jnp.sin(jnp.arange(out.size).reshape(out.shape))).sum(), stats
+
+    whole = jax.value_and_grad(run, argnums=(0, 1), has_aux=True)(x, wg)
+    monkeypatch.setattr(moe, "_STREAM_ROWS", 32)  # 64 x 2 rows: 4 chunks
+    assert moe._token_chunks(64, 2) == 4
+    chunked = jax.value_and_grad(run, argnums=(0, 1), has_aux=True)(x, wg)
+    for a, b in zip(jax.tree_util.tree_leaves(chunked),
+                    jax.tree_util.tree_leaves(whole)):
+        np.testing.assert_allclose(a, b, atol=1e-5, rtol=0)
+
+
+def test_a_share_of_all_the_experts_is_the_layer_as_it_was_to_the_bit():
+    rng = np.random.default_rng(10)
+    wr, wg, wu, wd = _expert_weights(rng)
+    x = jnp.asarray(rng.standard_normal((1, 20, 32)), jnp.float32)
+    f = lambda **kw: jax.jit(  # noqa: E731
+        lambda x: moe_ffn(x, wr, wg, wu, wd, 2, True, **kw)
+    )
+    assert f().lower(x).as_text() == f(first_expert=0).lower(x).as_text()
+    a, b = f()(x), f(first_expert=0)(x)
+    assert all((np.asarray(p) == np.asarray(q)).all()
+               for p, q in zip(jax.tree_util.tree_leaves(a),
+                               jax.tree_util.tree_leaves(b)))
+
+
+# ------------------------------------------------------------ the trainer --
+def test_the_trainer_trains_the_family_and_counts_its_pairs_exactly(tiny):
+    from areal_tpu.api import cli_args
+    from areal_tpu.api.io_struct import FinetuneSpec
+    from areal_tpu.engine.ppo.actor import PPOActor
+    from areal_tpu.engine.spmd_engine import SPMDTrainEngine
+    from areal_tpu.utils import data as data_utils
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    full, _ = cli_args.load_expr_config(
+        ["--config", os.path.join(root, "examples", "gsm8k_grpo.yaml")],
+        cli_args.GRPOConfig,
+    )
+    acfg = full.actor
+    acfg.path, acfg.dtype = "", "float32"
+    engine = SPMDTrainEngine(acfg)
+    engine.initialize(
+        ft_spec=FinetuneSpec(1, 1000, 8), model_config=tiny[0], seed=3
+    )
+    assert engine.attn_impl == "sparse_topk" and engine.moe_impl == "ragged_dot"
+    actor = PPOActor(acfg, engine)
+    rng = np.random.default_rng(12)
+    lens = [30, 12, 44, 9, 25, 18, 40, 7]
+    seqs = [rng.integers(0, 128, n).astype(np.int32) for n in lens]
+    batch = data_utils.pad_sequences_to_tensors(seqs)
+    lm = batch["attention_mask"].astype(np.int32)
+    lm[:, :3] = 0
+    batch["loss_mask"] = lm
+    batch["logprobs"] = (
+        rng.standard_normal(lm.shape).astype(np.float32) * 0.1 - 4.8
+    ) * lm
+    batch["versions"] = np.where(lm > 0, 0, -1).astype(np.int32)
+    batch["rewards"] = np.tile(np.array([0, 1], np.float32), 4)
+    before = jax.tree_util.tree_map(np.asarray, engine.params["layers"])
+    stats = actor.ppo_update(actor.compute_advantages(batch))
+    assert all(s["update_successful"] == 1.0 for s in stats)
+    layers = tiny[0].num_layers
+    kept = sum(min(p + 1, 8) for n in lens for p in range(n)) * layers
+    causal = sum(n * (n + 1) // 2 for n in lens) * layers
+    counters = engine.counters()
+    assert counters["dsa_kept_pairs"] == kept
+    assert counters["dsa_causal_pairs"] == causal
+    assert sum(s["dsa_kept_pairs"] for s in stats) == kept
+    # the pairs routed to the 8 experts held, not all 2 a token
+    assert 0 < counters["moe_assignments"] < sum(lens) * 2 * layers
+    after = engine.params["layers"]
+    assert (np.asarray(after["w_idx_q"]) == before["w_idx_q"]).all()
+    assert not (np.asarray(after["wq"]) == before["wq"]).all()
+    engine.destroy()
