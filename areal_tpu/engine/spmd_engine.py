@@ -45,6 +45,7 @@ from areal_tpu.models.transformer import (
 )
 from areal_tpu.ops import flash as flash_ops
 from areal_tpu.ops import moe as moe_ops
+from areal_tpu.ops import sparse_attention
 from areal_tpu.ops.functional import target_aligned_logprobs
 from areal_tpu.ops.ring_attention import attention_fn
 from areal_tpu.parallel import distributed as distributed_lib
@@ -148,6 +149,10 @@ class SPMDTrainEngine(TrainEngine):
         # resolves it from the platform ("megablox" | "ragged_dot"); None
         # for a dense model
         self.moe_impl: Optional[str] = None
+        # the form of the learned selection's counting passes, as
+        # ops/sparse_attention.py resolves it from the platform ("kernel" |
+        # "xla"); None for a model without an indexer
+        self.dsa_select_impl: Optional[str] = None
         self.initialized = False
 
     # ------------------------------------------------------------------
@@ -225,6 +230,7 @@ class SPMDTrainEngine(TrainEngine):
             self.moe_impl = moe_ops.grouped_impl()
             self._counters.update(dict.fromkeys(programs.MOE_COUNTS, 0))
         if mc.has_indexer:
+            self.dsa_select_impl = sparse_attention.select_impl()
             self._counters.update(dict.fromkeys(programs.DSA_COUNTS, 0))
         n = count_params(self.params)
         logger.info(
@@ -438,6 +444,14 @@ class SPMDTrainEngine(TrainEngine):
             longest, self.config.mb_spec.max_tokens_per_mb
         )
 
+    def _impl_attrs(self) -> Dict[str, str]:
+        """What a dispatch span says beside ``attn_impl``: the forms the
+        ops resolved for this model's experts and learned selection."""
+        named = {
+            "moe_impl": self.moe_impl, "dsa_select_impl": self.dsa_select_impl,
+        }
+        return {k: v for k, v in named.items() if v}
+
     def _forward_values(self, window: int) -> Dict[str, Any]:
         """What every forward program of ``engine/programs.py`` is built
         from: this engine's model, mesh, compute dtype and attention."""
@@ -516,7 +530,7 @@ class SPMDTrainEngine(TrainEngine):
             with goodput.trainer_bucket("fwd_bwd"), tracing.span(
                 "train.grad_dispatch", step=step,
                 attn_impl=self.attn_impl, window=mb_window,
-                **({"moe_impl": self.moe_impl} if self.moe_impl else {}),
+                **self._impl_attrs(),
             ), goodput.dispatch_scope(
                 goodput.trainer_tracker(), "fwd_bwd",
                 f"pad{row_len}|window{mb_window}",
@@ -664,7 +678,7 @@ class SPMDTrainEngine(TrainEngine):
             with tracing.span(
                 "train.logp_dispatch", step=step,
                 attn_impl=self.attn_impl, window=mb_window,
-                **({"moe_impl": self.moe_impl} if self.moe_impl else {}),
+                **self._impl_attrs(),
             ):
                 vals = logp_fn(self.params, arrays)
             with tracing.span("train.logp_fetch", step=step):

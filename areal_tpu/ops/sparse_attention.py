@@ -18,10 +18,18 @@ Three steps, each under its own device scope (``utils/tracing``):
   (``models/transformer._layer_body``), under the same scope.
 - ``dsa_select``: an EXACT selection without a sort. A float32's bit pattern,
   sign folded, orders as the float does, so the ``topk``-th largest score of a
-  query is found bit by bit in 32 counting passes (``_kth_largest``); a query
-  then keeps what lies above that threshold and, of the scores equal to it,
-  the lowest key indices (``_tie_cutoff``, run only when some query has more
-  ties than places). The selection of a query chunk is the pair (threshold,
+  query is found bit by bit in 32 counting passes; a query then keeps what
+  lies above that threshold and, of the scores equal to it, the lowest key
+  indices (``_tie_cutoff``, run only when some query has more ties than
+  places). ``select_impl`` picks the form of the counting as ``core_impl``
+  picks the core: on a TPU, for keys that are a whole number of lane tiles,
+  one Pallas kernel (``dsa_select_kth``) that holds a block of queries' keys
+  in VMEM through all 32 passes and the two counts after them (above the
+  threshold, equal to it), reading the keys from HBM once; elsewhere (the
+  CPU, a row that is no whole number of tiles) ``_kth_largest``, the same
+  bisection as an XLA loop whose every pass reads the keys from HBM, which
+  is what the kernel is held equal to, bit for bit, in interpret mode. The
+  selection of a query chunk is the pair (threshold,
   cutoff), [T] each: named ``dsa_tau`` so that a remat policy keeps it with
   ``attn_out``, and the recomputed forward and the backward use the forward's
   keys without selecting again. Queries go in chunks of ``topk`` rows (at
@@ -49,7 +57,7 @@ form and says so.
 """
 
 import functools
-from typing import NamedTuple, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -70,6 +78,11 @@ _INDEX_Q_BLOCK = 512
 _SELECT_Q_CHUNK = 512
 # query rows of one block of the XLA core: [heads, block, T] float32 scores
 _CORE_Q_BLOCK = 512
+# query rows of one block of the selection kernel ([block, keys] uint32 in
+# VMEM, twice: 4 MB a buffer at 16,384 keys) and the lane tiles of one
+# unrolled step of its counting loops (tools/microbench_select.py)
+_KTH_BLOCK_Q = 64
+_KTH_TILE_GROUP = 8
 # the kernel's smallest tile edge
 _LANES = 128
 _MASKED = -2.3819763e38  # ops/basic.segment_attention's
@@ -82,6 +95,28 @@ class DSAStats(NamedTuple):
 
     kept_pairs: jnp.ndarray  # selected (query, key) pairs
     causal_pairs: jnp.ndarray  # (query, key) pairs of full causal attention
+
+
+def _fit_edge(n: int, want: int, least: int) -> int:
+    """The largest ``least`` x 2^i that is at most ``want`` and divides
+    ``n`` (``least`` itself if nothing larger does)."""
+    edge = least
+    while edge * 2 <= want and n % (edge * 2) == 0:
+        edge *= 2
+    return edge
+
+
+def select_impl(shape: Optional[Tuple[int, int]] = None) -> str:
+    """The form of the selection's counting passes this process runs:
+    ``"kernel"`` (``dsa_select_kth``: a query block's keys stay in VMEM
+    through the 32 passes) on a TPU, ``"xla"`` (``_kth_largest``) elsewhere,
+    and for a query chunk's keys of ``shape`` (queries, keys) that are not
+    whole sublane and lane tiles."""
+    if jax.default_backend() != "tpu":
+        return "xla"
+    if shape is not None and (shape[0] % 8 or shape[1] % _LANES):
+        return "xla"
+    return "kernel"
 
 
 def core_impl(t: int, head_dim: int) -> str:
@@ -134,7 +169,11 @@ def _order_keys(scores: jnp.ndarray) -> jnp.ndarray:
 def _kth_largest(keys: jnp.ndarray, k: int) -> jnp.ndarray:
     """[C] uint32: per row of ``keys`` [C, K] the largest value v with at
     least ``k`` keys >= v (0 where the row has fewer than ``k`` keys above
-    0), built from the top bit down in 32 counting passes."""
+    0), built from the top bit down in 32 counting passes. The XLA form:
+    each pass is a fusion that reads ``keys`` from HBM (25.6 ms a 16k-token
+    row and layer on a v5e, where ``_kth_counts_kernel`` takes 6.8 and runs
+    instead). It stays as the CPU's form, the form of a chunk that is no
+    whole number of tiles, and what the kernel is held equal to."""
 
     def body(i, v):
         cand = v | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
@@ -144,6 +183,85 @@ def _kth_largest(keys: jnp.ndarray, k: int) -> jnp.ndarray:
     return jax.lax.fori_loop(
         0, 32, body, jnp.zeros(keys.shape[0], jnp.uint32)
     )
+
+
+def _kth_counts_xla(keys: jnp.ndarray, topk: int):
+    """(threshold, keys above it, keys equal to it), [C] each, of ``keys``
+    [C, K]: ``_kth_largest`` and two more passes."""
+    tau = _kth_largest(keys, topk)
+    return (
+        tau,
+        jnp.sum(keys > tau[:, None], axis=1, dtype=jnp.int32),
+        jnp.sum(keys == tau[:, None], axis=1, dtype=jnp.int32),
+    )
+
+
+def _kth_kernel(keys_ref, tau_ref, above_ref, eq_ref, *, topk, group):
+    """``_kth_counts_xla`` of one block of queries, on keys that stay where
+    they are. A count is a [bq, 128] partial sum over the lane tiles, then
+    one cross-lane sum."""
+    bq, n_keys = keys_ref.shape
+    n_tiles = n_keys // _LANES
+
+    def wide(x):  # [bq, 1] -> [bq, 128]
+        return jnp.broadcast_to(x, (bq, _LANES))
+
+    def count(*preds):
+        """Per predicate [bq, 1] int32: the keys of each query it holds for."""
+
+        def tiles(j, n, accs):
+            for d in range(n):
+                x = keys_ref[
+                    :, pl.ds(pl.multiple_of((j + d) * _LANES, _LANES), _LANES)
+                ]
+                accs = tuple(
+                    a + p(x).astype(jnp.int32) for a, p in zip(accs, preds)
+                )
+            return accs
+
+        accs = tuple(jnp.zeros((bq, _LANES), jnp.int32) for _ in preds)
+        accs = jax.lax.fori_loop(
+            0, n_tiles // group, lambda g, a: tiles(g * group, group, a), accs
+        )
+        accs = tiles(n_tiles // group * group, n_tiles % group, accs)
+        return tuple(jnp.sum(a, axis=1, keepdims=True) for a in accs)
+
+    def step(i, v):
+        cand = v | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
+        wide_cand = wide(cand)
+        (n,) = count(lambda x: x >= wide_cand)
+        return jnp.where(n >= topk, cand, v)
+
+    tau = wide(jax.lax.fori_loop(0, 32, step, jnp.zeros((bq, 1), jnp.uint32)))
+    above, eq = count(lambda x: x > tau, lambda x: x == tau)
+    tau_ref[...] = tau
+    above_ref[...] = wide(above)
+    eq_ref[...] = wide(eq)
+
+
+def _kth_counts_kernel(keys, topk: int, interpret: bool = False):
+    """``_kth_counts_xla``, to the bit, as one kernel over blocks of queries:
+    the keys are read from HBM once.
+    (A block could stop at the lane tile of its own last query, 10% fewer
+    keys a 16k row: measured on the chip, no faster: PERF.md, PR 32.)"""
+    c, k = keys.shape
+    bq = _fit_edge(c, _KTH_BLOCK_Q, 8)
+    out_spec = pl.BlockSpec((bq, _LANES), lambda i: (i, 0))
+    out_shape = [
+        jax.ShapeDtypeStruct((c, _LANES), dtype)
+        for dtype in (jnp.uint32, jnp.int32, jnp.int32)
+    ]
+    tau, above, eq = pl.pallas_call(
+        functools.partial(_kth_kernel, topk=topk, group=_KTH_TILE_GROUP),
+        grid=(c // bq,),
+        in_specs=[pl.BlockSpec((bq, k), lambda i: (i, 0))],
+        out_specs=[out_spec] * 3, out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+        ),
+        name="dsa_select_kth", interpret=interpret,
+    )(keys)
+    return tau[:, 0], above[:, 0], eq[:, 0]
 
 
 def _tie_cutoff(eq: jnp.ndarray, need: jnp.ndarray) -> jnp.ndarray:
@@ -164,20 +282,23 @@ def _tie_cutoff(eq: jnp.ndarray, need: jnp.ndarray) -> jnp.ndarray:
     )
 
 
-def _select_chunk(keys: jnp.ndarray, topk: int):
+def _select_chunk(keys: jnp.ndarray, topk: int, interpret: bool = False):
     """(threshold [C] uint32, cutoff [C] int32) of one query chunk's
     ``keys`` [C, K] (0 = not a key of this query): a query keeps the keys
     above its threshold and, of those equal to it, the ones at or before
     its cutoff."""
-    tau = _kth_largest(keys, topk)
-    above = jnp.sum(keys > tau[:, None], axis=1, dtype=jnp.int32)
-    eq = keys == tau[:, None]
+    if select_impl(keys.shape) == "kernel":
+        tau, above, n_eq = _kth_counts_kernel(keys, topk, interpret)
+    else:
+        tau, above, n_eq = _kth_counts_xla(keys, topk)
     need = topk - above
-    contested = (tau > 0) & (jnp.sum(eq, axis=1, dtype=jnp.int32) > need)
+    contested = (tau > 0) & (n_eq > need)
     everything = jnp.full(keys.shape[0], keys.shape[1], jnp.int32)
     cutoff = jax.lax.cond(
         jnp.any(contested),
-        lambda: jnp.where(contested, _tie_cutoff(eq, need), everything),
+        lambda: jnp.where(
+            contested, _tie_cutoff(keys == tau[:, None], need), everything
+        ),
         lambda: everything,
     )
     return tau, cutoff
@@ -189,6 +310,7 @@ def select_keys(
     w: jnp.ndarray,  # [T, Hi]
     segment_ids: jnp.ndarray,  # [T]; 0 = padding
     topk: int,
+    interpret: bool = False,  # Pallas interpret mode: the CPU's tests
 ) -> Tuple[jnp.ndarray, DSAStats]:
     """The selection of one packed row as a mask [T, T] bool (query, key),
     with its counts. A padding token keeps itself alone (a query with no
@@ -212,7 +334,7 @@ def select_keys(
                 keys = jnp.where(own, _order_keys(scores), jnp.uint32(0))
                 tau, cutoff = (
                     _ad_checkpoint.checkpoint_name(x, "dsa_tau")
-                    for x in _select_chunk(keys, topk)
+                    for x in _select_chunk(keys, topk, interpret)
                 )
                 chosen = own & (
                     (keys > tau[:, None])
@@ -288,13 +410,7 @@ _NT = (((1,), (1,)), ((), ()))  # a @ b.T
 
 
 def _core_blocks(t: int) -> Tuple[int, int]:
-    def fit(want):
-        edge = _LANES
-        while edge * 2 <= min(want, t) and t % (edge * 2) == 0:
-            edge *= 2
-        return edge
-
-    return fit(_BLOCK_Q), fit(_BLOCK_KV)
+    return _fit_edge(t, _BLOCK_Q, _LANES), _fit_edge(t, _BLOCK_KV, _LANES)
 
 
 def _lane_tile(x, width):
@@ -583,7 +699,9 @@ def sparse_attention(
     outs, stats = [], []
     for r in range(q.shape[0]):  # rows of a microbatch: few, and each its
         # own mask, which a kernel's scalar-prefetched tile table cannot batch
-        mask, st = select_keys(qi[r], ki[r], w[r], segment_ids[r], topk)
+        mask, st = select_keys(
+            qi[r], ki[r], w[r], segment_ids[r], topk, interpret
+        )
         with device_scope("attention"):
             if core == "kernel":
                 o = _masked_attention_kernel(q[r], k[r], v[r], mask, interpret)

@@ -224,14 +224,18 @@ def test_sparse_layer_fwd_bwd_compiles_for_v5e(one_chip, monkeypatch, t):
     assert compiled.as_text().count("tpu_custom_call") >= 9
 
 
-def test_sparse_attention_fwd_bwd_compiles_for_v5e(one_chip):
+def test_sparse_attention_fwd_bwd_compiles_for_v5e(one_chip, monkeypatch):
     """``ops/sparse_attention.sparse_attention`` forward and backward at
     Keye-VL-2.0's published widths (32 / 4 heads of 128, an indexer of 16
-    heads of 64, topk 2048) on one 8192-token row: the index and the
-    selection in XLA, then the three Pallas kernels of the core
-    (``dsa_core_fwd``, ``dsa_core_dq``, ``dsa_core_dkv``) over int8 tiles
-    of the selection, all the query heads of a kv head a grid step. No
-    [T, T] float32 array in the compiled program."""
+    heads of 64, topk 2048) on one 8192-token row: the index in XLA, the
+    selection's counting kernel (``dsa_select_kth``, once for each of the
+    three query chunks that end past 2048 keys: 4096, 6144 and 8192 keys
+    wide, 64 queries' keys in VMEM), then the three Pallas kernels of the
+    core (``dsa_core_fwd``, ``dsa_core_dq``, ``dsa_core_dkv``) over int8
+    tiles of the selection, all the query heads of a kv head a grid step.
+    No [T, T] float32 array in the compiled program.
+    ``jax.default_backend()`` is the CPU here, so the platform's choice of
+    the selection's form is set in the test."""
     import re
 
     import jax
@@ -239,6 +243,7 @@ def test_sparse_attention_fwd_bwd_compiles_for_v5e(one_chip):
 
     from areal_tpu.ops import sparse_attention as sa
 
+    monkeypatch.setattr(sa, "select_impl", lambda shape=None: "kernel")
     t, bf = 8192, jnp.bfloat16
 
     def loss(q, k, v, seg, qi, ki, w):
@@ -261,5 +266,6 @@ def test_sparse_attention_fwd_bwd_compiles_for_v5e(one_chip):
         .compile()
     )
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") >= 3
+    assert text.count("tpu_custom_call") >= 6
+    assert len(re.findall(r"dsa_select_kth", text)) >= 3
     assert not re.search(rf"f32\[[0-9,]*{t},{t}[0-9,]*\]", text)

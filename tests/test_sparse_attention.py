@@ -12,6 +12,8 @@ order of sums: logits and logprobs of magnitude ~1 are held to 1e-3 absolute
 1e-2 of each leaf's largest entry (sums over up to 40 positions of such
 terms; measured ~1e-5). The kernel (Pallas interpret mode) against the XLA
 core is float32 against float32 with another blocking of the softmax: 1e-5.
+The selection's kernel (``dsa_select_kth``, interpret mode) against
+``_kth_largest`` is integers against integers: equal, to the bit.
 """
 
 import dataclasses
@@ -364,6 +366,122 @@ def test_a_share_of_all_the_experts_is_the_layer_as_it_was_to_the_bit():
                                jax.tree_util.tree_leaves(b)))
 
 
+# ------------------------------------------------- the selection's kernel --
+def _causal_keys(scores, seg, first):
+    """A query chunk's ordered keys as ``select_keys`` builds them: queries
+    are tokens ``first``.. of a row with segments ``seg``, keys its tokens
+    up to the chunk's end."""
+    c, k = scores.shape
+    idx = np.arange(k)
+    own = (
+        (seg[first:first + c, None] == seg[None, :k])
+        & (seg[first:first + c, None] > 0)
+        & (idx[None] <= idx[first:first + c, None])
+    )
+    return jnp.where(
+        jnp.asarray(own), sa._order_keys(jnp.asarray(scores)), jnp.uint32(0)
+    )
+
+
+def _kth_case(name):
+    """(keys [C, K] uint32, topk) of one case of the kernel's test."""
+    rng = np.random.default_rng(sum(map(ord, name)))
+    c, k, topk = 64, 640, 16
+    scores = rng.standard_normal((c, k)).astype(np.float32)
+    seg = np.ones(k, np.int32)
+    if name == "more-ties-than-places":
+        # a few distinct values, so the threshold is shared by many keys
+        scores = np.round(scores).astype(np.float32)
+    elif name == "fewer-keys-than-topk":
+        c, topk = 16, 48  # queries 0..15 of the row: at most 16 keys each
+        scores = scores[:c]
+    elif name == "negative-and-minus-zero":
+        scores = -np.abs(scores)
+        scores[:, ::5] = -0.0
+        scores[:, 1::5] = 0.0
+    elif name == "two-segments-and-padding":
+        seg = np.repeat([1, 2, 0], [300, 320, 20]).astype(np.int32)
+    first = 0 if name == "fewer-keys-than-topk" else k - c
+    return _causal_keys(scores, seg, first), topk
+
+
+@pytest.mark.parametrize("block,group", [(16, 2), (64, 8)])
+@pytest.mark.parametrize("case", [
+    "more-ties-than-places", "fewer-keys-than-topk",
+    "negative-and-minus-zero", "two-segments-and-padding", "plain",
+])
+def test_the_selection_kernel_in_interpret_mode_equals_the_xla_form_to_the_bit(
+    case, block, group, monkeypatch
+):
+    """640 keys are five lane tiles: with groups of 2 the last tile is left
+    over, with groups of 8 all five are (the tiles that do not fill a group
+    run one by one); 16-row blocks make a grid of 4 (1 where a chunk has 16
+    queries), 64-row blocks a grid of 1."""
+    monkeypatch.setattr(sa, "_KTH_BLOCK_Q", block)
+    monkeypatch.setattr(sa, "_KTH_TILE_GROUP", group)
+    keys, topk = _kth_case(case)
+    want = sa._kth_counts_xla(keys, topk)
+    got = sa._kth_counts_kernel(keys, topk, interpret=True)
+    for g, w in zip(got, want):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        np.testing.assert_array_equal(g, w)
+    # what each case is for
+    tau, above, eq = (np.asarray(x) for x in want)
+    if case == "more-ties-than-places":
+        assert ((tau > 0) & (eq > topk - above)).any()
+    if case == "fewer-keys-than-topk":
+        assert not tau.any() and (above == np.arange(1, 17)).all()
+    if case == "negative-and-minus-zero":
+        assert tau.min() > 0 and tau.max() <= 0x80000000
+    if case == "two-segments-and-padding":
+        assert not tau[-20:].any() and not above[-20:].any()
+
+
+@pytest.mark.parametrize("ties", [False, True])
+def test_select_keys_gives_the_same_mask_and_counts_through_either_form(
+    ties, monkeypatch
+):
+    """1024 tokens, two sequences and padding, topk 128: the chunks end at
+    512 keys and at 1024. With every score equal the threshold is contested
+    in every query past 128 keys, and ``_tie_cutoff`` runs on the kernel's
+    counts."""
+    rng = np.random.default_rng(21)
+    _, (qi, ki, w) = _random_op_inputs(rng, 1024)
+    if ties:
+        qi = qi * 0
+    seg = jnp.asarray(np.repeat([1, 2, 0], [300, 600, 124]), jnp.int32)
+    calls = []
+    kernel = sa._kth_counts_kernel
+    monkeypatch.setattr(
+        sa, "_kth_counts_kernel",
+        lambda *a, **kw: calls.append(a[0].shape) or kernel(*a, **kw),
+    )
+    mask_x, stats_x = sa.select_keys(qi[0], ki[0], w[0], seg, 128)
+    assert not calls  # the CPU's own form
+    monkeypatch.setattr(
+        sa, "select_impl",
+        lambda shape=None: "kernel" if shape is None or not shape[1] % 128
+        else "xla",
+    )
+    mask_k, stats_k = sa.select_keys(
+        qi[0], ki[0], w[0], seg, 128, interpret=True
+    )
+    assert calls == [(512, 512), (512, 1024)]
+    np.testing.assert_array_equal(mask_k, mask_x)
+    assert [int(x) for x in stats_k] == [int(x) for x in stats_x]
+    assert int(stats_x.kept_pairs) < int(stats_x.causal_pairs)
+
+
+def test_select_impl_is_the_kernel_on_a_tpu_for_whole_tiles_only(monkeypatch):
+    assert sa.select_impl() == "xla" and sa.select_impl((2048, 4096)) == "xla"
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert sa.select_impl() == "kernel"
+    assert sa.select_impl((2048, 16384)) == "kernel"
+    assert sa.select_impl((256, 2304)) == "kernel"
+    assert sa.select_impl((2048, 4099)) == "xla"  # no whole lane tile
+    assert sa.select_impl((3, 4096)) == "xla"  # no whole sublane tile
+
+
 # ------------------------------------------------------------ the trainer --
 def test_the_trainer_trains_the_family_and_counts_its_pairs_exactly(tiny):
     from areal_tpu.api import cli_args
@@ -414,3 +532,51 @@ def test_the_trainer_trains_the_family_and_counts_its_pairs_exactly(tiny):
     assert (np.asarray(after["w_idx_q"]) == before["w_idx_q"]).all()
     assert not (np.asarray(after["wq"]) == before["wq"]).all()
     engine.destroy()
+
+
+def test_dispatch_spans_name_the_selection_form_for_an_indexer_model_only(
+    tiny, monkeypatch
+):
+    from areal_tpu.api.cli_args import (
+        MicroBatchSpec, OptimizerConfig, TrainEngineConfig,
+    )
+    from areal_tpu.api.io_struct import FinetuneSpec
+    from areal_tpu.engine.sft.lm_engine import sft_loss_fn, sft_loss_weight_fn
+    from areal_tpu.engine.spmd_engine import SPMDTrainEngine
+    from areal_tpu.utils import data as data_utils
+    from areal_tpu.utils import tracing
+
+    seen = []
+    span = tracing.span
+    monkeypatch.setattr(
+        tracing, "span",
+        lambda name, *a, **attrs: seen.append((name, attrs))
+        or span(name, *a, **attrs),
+    )
+    rng = np.random.default_rng(13)
+    batch = data_utils.pad_sequences_to_tensors(
+        [rng.integers(0, 128, n).astype(np.int32) for n in (9, 5, 7)]
+    )
+    batch["loss_mask"] = batch["attention_mask"].astype(np.int32)
+    for model_config, want in ((tiny[0], "xla"), (tiny_config("qwen2"), None)):
+        engine = SPMDTrainEngine(TrainEngineConfig(
+            dtype="float32", param_dtype="float32",
+            mb_spec=MicroBatchSpec(max_tokens_per_mb=4096),
+            optimizer=OptimizerConfig(
+                type="adamw", lr=1e-2, warmup_steps_proportion=0.0,
+                lr_scheduler_type="constant",
+            ),
+        ))
+        engine.initialize(
+            ft_spec=FinetuneSpec(1, 64, 8), model_config=model_config, seed=0
+        )
+        assert engine.dsa_select_impl == want
+        del seen[:]
+        engine.train_batch(batch, sft_loss_fn, sft_loss_weight_fn)
+        engine.forward(batch)
+        for name in ("train.grad_dispatch", "train.logp_dispatch"):
+            attrs = [a for n, a in seen if n == name]
+            assert attrs, name
+            assert all(a.get("dsa_select_impl") == want for a in attrs), attrs
+            assert all(("dsa_select_impl" in a) == bool(want) for a in attrs)
+        engine.destroy()
