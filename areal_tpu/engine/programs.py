@@ -26,6 +26,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from areal_tpu.models.config import ModelConfig
 from areal_tpu.models.forward import packed_forward
 from areal_tpu.models.transformer import param_logical_axes
+from areal_tpu.ops import kda as kda_ops
 from areal_tpu.ops import moe as moe_ops
 from areal_tpu.parallel import sharding as sharding_lib
 from areal_tpu.utils import tracing
@@ -43,6 +44,15 @@ DSA_COUNTS = {
     "dsa_kept_pairs": "kept_pairs",
     "dsa_causal_pairs": "causal_pairs",
 }
+# the same for a model with gated delta-rule layers (``KDAStats`` fields):
+# int32 a microbatch, summed over those layers on the device
+KDA_COUNTS = {
+    "kda_tokens": "tokens",
+    "kda_chunk_slots": "chunk_slots",
+    "kda_segments": "segments",
+}
+# the counts the host fetches as integers and sums exactly
+INT_COUNTS = {**DSA_COUNTS, **KDA_COUNTS}
 
 
 def _forward_fn(
@@ -54,14 +64,18 @@ def _forward_fn(
     values), with the stack's ``LayerStats`` beside it when ``stats``."""
     mc = model_config
     moe = None
-    if mc.is_moe:
-        # one layer's expert leaves as the rules table shards them (the
-        # scanned layer axis gone): what the mesh form gathers from
-        axes = param_logical_axes(mc)["layers"]
+    # one layer's sparse leaves as the rules table shards them (the scanned
+    # layer axis gone): what the mesh form gathers from. The sparse kinds
+    # of a stack of block kinds hold the same leaves
+    axes = param_logical_axes(mc)["layers"]
+    if mc.layer_kinds:
+        axes = next((v for k, v in axes.items() if k.endswith("+moe")), None)
+    if mc.is_moe and axes is not None:
         moe = moe_ops.make_sharded_moe_ffn(mc, mesh, {
             k: sharding_lib.logical_to_spec(axes[k][1:])
-            for k in moe_ops.EXPERT_WEIGHTS
+            for k in moe_ops.sparse_leaves(mc)
         })
+    kda = kda_ops.make_kda_scan(mesh) if mc.num_kda_layers else None
     # [B, T, D] activation constraint: rows over (data, fsdp), tokens over
     # seq. Pinning this stops GSPMD from propagating the embedding table's
     # column sharding onto the batch, which replicates every layer
@@ -75,7 +89,7 @@ def _forward_fn(
         )
         return packed_forward(
             cparams, mc, arrays, remat=remat, attend_fn=attend_fn,
-            moe_fn=moe, return_stats=stats, return_hidden=True,
+            moe_fn=moe, kda_fn=kda, return_stats=stats, return_hidden=True,
             act_sharding=act_sh,
         )
 
@@ -96,12 +110,16 @@ def build_grad_step(
     )
 
     def fwd_loss(params, arrays):
-        logits, (moe_stats, dsa_stats) = forward(params, arrays)
+        logits, (moe_stats, dsa_stats, kda_stats) = forward(params, arrays)
         with tracing.device_scope("loss"):
             loss, stats = loss_fn(logits, arrays)
             if dsa_stats is not None:
                 stats = dict(stats, **{
                     k: getattr(dsa_stats, v) for k, v in DSA_COUNTS.items()
+                })
+            if kda_stats is not None:
+                stats = dict(stats, **{
+                    k: getattr(kda_stats, v) for k, v in KDA_COUNTS.items()
                 })
             if mc.is_moe:
                 if mc.router_aux_loss_coef:

@@ -44,6 +44,7 @@ from areal_tpu.models.transformer import (
     param_logical_axes,
 )
 from areal_tpu.ops import flash as flash_ops
+from areal_tpu.ops import kda as kda_ops
 from areal_tpu.ops import moe as moe_ops
 from areal_tpu.ops import sparse_attention
 from areal_tpu.ops.functional import target_aligned_logprobs
@@ -153,6 +154,10 @@ class SPMDTrainEngine(TrainEngine):
         # ops/sparse_attention.py resolves it from the platform ("kernel" |
         # "xla"); None for a model without an indexer
         self.dsa_select_impl: Optional[str] = None
+        # the gated delta rule's state pass, as ops/kda.py resolves it from
+        # the platform ("kernel" | "xla"); None for a model without such
+        # layers
+        self.kda_impl: Optional[str] = None
         self.initialized = False
 
     # ------------------------------------------------------------------
@@ -220,8 +225,9 @@ class SPMDTrainEngine(TrainEngine):
             )
             self.opt_state = self._init_opt_state()
         self.attn_impl = flash_ops.resolve_attn_impl(
-            cfg.attn_impl, cfg.parallel.seq_parallel_size, mc.head_dim,
-            has_indexer=mc.has_indexer,
+            cfg.attn_impl, cfg.parallel.seq_parallel_size,
+            mc.attn_head_dims[0], has_indexer=mc.has_indexer,
+            head_dim_v=mc.attn_head_dims[1],
         )
         if self.attn_impl == "flash" and jax.default_backend() != "cpu":
             # what the scoped-VMEM limit of this process allows, once
@@ -232,6 +238,11 @@ class SPMDTrainEngine(TrainEngine):
         if mc.has_indexer:
             self.dsa_select_impl = sparse_attention.select_impl()
             self._counters.update(dict.fromkeys(programs.DSA_COUNTS, 0))
+        if mc.num_kda_layers:
+            self.kda_impl = kda_ops.scan_impl(
+                mc.kda.head_dim, mc.kda.head_dim
+            )
+            self._counters.update(dict.fromkeys(programs.KDA_COUNTS, 0))
         n = count_params(self.params)
         logger.info(
             f"initialized {mc.family} model: {n/1e6:.1f}M params on mesh "
@@ -316,8 +327,12 @@ class SPMDTrainEngine(TrainEngine):
         microbatches and the busiest expert's, summed over layers; of the
         experts held, where the model holds a share); for a model with an
         indexer ``dsa_kept_pairs`` and ``dsa_causal_pairs`` (the selected and
-        the causal (query, key) pairs, likewise). Their per-step increments
-        are the stats plane's ``spmd/<name>``."""
+        the causal (query, key) pairs, likewise); for a model with gated
+        delta-rule layers ``kda_tokens``, ``kda_chunk_slots`` and
+        ``kda_segments`` (the real tokens its scans ran over, the chunk slots
+        they ran, 64 a chunk, and the sequence starts they reset at, each
+        summed over those layers). Their per-step increments are the stats
+        plane's ``spmd/<name>``."""
         return dict(self._counters)
 
     def get_version(self) -> int:
@@ -449,6 +464,7 @@ class SPMDTrainEngine(TrainEngine):
         ops resolved for this model's experts and learned selection."""
         named = {
             "moe_impl": self.moe_impl, "dsa_select_impl": self.dsa_select_impl,
+            "kda_impl": self.kda_impl,
         }
         return {k: v for k, v in named.items() if v}
 
@@ -568,12 +584,13 @@ class SPMDTrainEngine(TrainEngine):
                 # ONE packed host fetch for every scalar this step produced
                 # — each separate float() is a full device round-trip
                 # a sparse-attention model's pair counts are int32 [L] a
-                # microbatch (a float32 cannot count a 16k-token row's):
-                # their own small fetch, summed here
-                dsa_keys = [
-                    k for k in programs.DSA_COUNTS if k in all_stats[0]
+                # microbatch (a float32 cannot count a 16k-token row's), a
+                # delta-rule model's token counts int32: their own small
+                # fetch, summed here
+                int_keys = [
+                    k for k in programs.INT_COUNTS if k in all_stats[0]
                 ]
-                stat_keys = sorted(set(all_stats[0]) - set(dsa_keys))
+                stat_keys = sorted(set(all_stats[0]) - set(int_keys))
                 scalars = [ok, grad_norm, total_w] + losses + weights + [
                     s[k] for s in all_stats for k in stat_keys
                 ]
@@ -585,9 +602,9 @@ class SPMDTrainEngine(TrainEngine):
                         ]
                     )
                 )
-                dsa_counts = np.asarray(jnp.stack(
-                    [s[k] for k in dsa_keys for s in all_stats]
-                )).reshape(len(dsa_keys), -1) if dsa_keys else ()
+                int_counts = np.asarray(jnp.stack([
+                    jnp.atleast_1d(s[k]) for k in int_keys for s in all_stats
+                ])).reshape(len(int_keys), -1) if int_keys else ()
         n_mb = len(mbs.mbs)
         h_ok, h_gnorm, h_total_w = blob[0], blob[1], blob[2]
         h_losses = blob[3 : 3 + n_mb]
@@ -608,7 +625,7 @@ class SPMDTrainEngine(TrainEngine):
                 stats_tracker.counter(**{f"spmd/{k}": int(out[k])})
             else:
                 out[k] = float((h_stats[:, j] * h_weights).sum() / h_total_w)
-        for k, counts in zip(dsa_keys, dsa_counts):
+        for k, counts in zip(int_keys, int_counts):
             n = int(counts.astype(np.int64).sum())
             out[k] = float(n)
             self._counters[k] += n

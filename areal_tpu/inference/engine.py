@@ -355,6 +355,13 @@ class GenerationEngine:
                 "indexer-key cache beside its KV pages and no top-k decode "
                 "(ROADMAP B); the trainer can (attn_impl 'sparse_topk')"
             )
+        if model_config.layer_kinds:
+            raise NotImplementedError(
+                "the generation engine cannot run a stack of block kinds "
+                f"({', '.join(dict.fromkeys(model_config.layer_kinds))}): it "
+                "has no recurrent state beside its KV pages and no latent "
+                "paged cache (ROADMAP B); the trainer can"
+            )
         if params is None:
             params = hf_io.load_params(
                 config.model_path, model_config, dtype=self.dtype

@@ -80,19 +80,13 @@ def _mlp(
     valid: Optional[jnp.ndarray] = None,  # [...] matching h[..., 0]
 ) -> jnp.ndarray:
     if cfg.is_moe:
-        from areal_tpu.ops.moe import (
-            moe_ffn_from_params,
-            shared_expert_from_params,
-        )
+        from areal_tpu.ops.moe import moe_ffn_from_params
 
         flat = h.reshape(1, -1, h.shape[-1])
         # padding / inactive-slot tokens must not consume expert capacity
         vflat = None if valid is None else valid.reshape(1, -1)
         out, _ = moe_ffn_from_params(cfg, lp, flat, valid=vflat)
-        out = out.reshape(h.shape)
-        if cfg.shared_expert_size:
-            out = out + shared_expert_from_params(cfg, lp, h)
-        return out
+        return out.reshape(h.shape)
     act = hidden_act_fn(cfg.hidden_act)
     return (act(h @ lp["w_gate"]) * (h @ lp["w_up"])) @ lp["w_down"]
 

@@ -1,9 +1,17 @@
-"""Model architecture config for the Llama/Qwen2/Qwen3 decoder family.
+"""Model architecture config for the decoder-only families the trainer runs.
 
 Role of reference realhf/api/core/model_api.py `ReaLModelConfig` + the HF
 config conversion in realhf/api/from_hf/: one dataclass describes every
-supported dense decoder-only family; per-family differences (QKV bias, tied
-embeddings, head_dim override, q/k norm) are fields, not subclasses.
+supported decoder-only family. A model whose layers are all one block
+(llama, qwen2/3, gemma, the sparse-expert families, the one with a learned
+indexer) differs from its siblings in fields (QKV bias, tied embeddings,
+head_dim override, q/k norm, experts). A model whose layers are of more
+than one kind names them: ``layer_kinds`` holds a block kind a layer
+(``"<mixer>+<ffn>"``: the mixers ``attn`` (rotary GQA), ``kda`` (gated
+delta-rule linear attention, ``KDAConfig``) and ``mla`` (latent attention,
+``MLAConfig``); the FFNs ``dense`` and ``moe``), and the block kinds, not
+further per-family scalars, are the unit ``models/transformer.py`` builds
+its stacks from.
 """
 
 import dataclasses
@@ -12,6 +20,35 @@ import os
 from typing import Optional, Tuple
 
 from areal_tpu.models.vision import VisionConfig
+
+
+@dataclasses.dataclass(frozen=True)
+class KDAConfig:
+    """The gated delta-rule mixer's sizes (``ops/kda.py``): ``num_heads``
+    heads of ``head_dim`` (key and value alike), a depthwise causal
+    convolution of ``conv_size`` taps on q, k and v; the decay and the
+    output gate are low rank through ``head_dim``."""
+
+    num_heads: int
+    head_dim: int
+    conv_size: int = 4
+
+
+@dataclasses.dataclass(frozen=True)
+class MLAConfig:
+    """The latent-attention mixer's sizes: keys and values come from one
+    ``kv_lora_rank``-wide compression of the token, a head's query and key
+    are ``qk_nope_head_dim`` wide plus ``qk_rope_head_dim`` that all heads'
+    keys share (not rotated here: NoPE), its value ``v_head_dim``."""
+
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
 
 
 @dataclasses.dataclass(frozen=True)
@@ -66,6 +103,20 @@ class ModelConfig:
     # published width; the layer computes the part of the result that
     # experts [0, experts_held) give (ops/moe.py) ---
     experts_held: int = 0
+    # --- a stack of more than one block kind: the kind of every layer,
+    # ``"<mixer>+<ffn>"`` (() = every layer the one block the fields above
+    # describe), and the sizes of the mixers that are not rotary GQA ---
+    layer_kinds: Tuple[str, ...] = ()
+    kda: Optional[KDAConfig] = None
+    mla: Optional[MLAConfig] = None
+    # the router's two forms (ops/moe.router_topk): "softmax", or "sigmoid"
+    # (scores chosen with a correction bias, weighted without it, then
+    # times ``routed_scaling_factor``)
+    router_act: str = "softmax"
+    routed_scaling_factor: float = 1.0
+    # the shared expert's output times a per-token sigmoid gate (qwen2_moe)
+    # or added as it is
+    shared_expert_gate: bool = True
 
     @property
     def q_dim(self) -> int:
@@ -92,6 +143,21 @@ class ModelConfig:
     def has_indexer(self) -> bool:
         return self.dsa_topk > 0
 
+    @property
+    def attn_head_dims(self) -> Tuple[int, int]:
+        """(query/key, value) head width of the softmax attention core."""
+        if self.mla is not None:
+            return self.mla.qk_head_dim, self.mla.v_head_dim
+        return self.head_dim, self.head_dim
+
+    @property
+    def num_kda_layers(self) -> int:
+        return sum(kind.startswith("kda+") for kind in self.layer_kinds)
+
+    def kind_layers(self, kind: str) -> int:
+        """How many layers are of block ``kind``."""
+        return sum(k == kind for k in self.layer_kinds)
+
 
 # Supported HF `model_type`s. The llama-style decoder block (RMSNorm +
 # SiLU-gated MLP + rotary GQA attention) is the baseline; gemma layers on
@@ -102,11 +168,14 @@ class ModelConfig:
 # KeyeVL2 (the language model of Keye-VL-2.0) is a qwen3_moe block whose
 # attention reads only the ``sa_config.topk`` keys a learned indexer picks
 # (ops/sparse_attention.py); its vision tower is not built.
-# gemma2/gpt2 remain out (interleaved local
-# attention / learned positions need architecture changes).
+# kimi_linear is a stack of block kinds (``_kimi_linear_from_hf``): gated
+# delta-rule layers and NoPE latent-attention layers, a leading dense FFN
+# and sigmoid-routed experts with an ungated shared one after it.
+# gemma2/gpt2 remain out (interleaved windowed attention / learned
+# positions: a window is one more mixer kind, positions a new embedding).
 _HF_FAMILIES = (
     "llama", "qwen2", "qwen3", "mistral", "qwen3_moe", "mixtral",
-    "qwen2_vl", "qwen2_moe", "gemma", "olmoe", "KeyeVL2",
+    "qwen2_vl", "qwen2_moe", "gemma", "olmoe", "KeyeVL2", "kimi_linear",
 )
 
 
@@ -140,12 +209,93 @@ def _vision_from_hf(d: dict, lm_hidden: int) -> VisionConfig:
     )
 
 
+def _kimi_linear_from_hf(d: dict) -> ModelConfig:
+    """``model_type`` kimi_linear: ``linear_attn_config`` lists the layers
+    (1-indexed, as published) that are gated delta-rule (``kda_layers``) and
+    full attention (``full_attn_layers``, latent attention without
+    positions); the first ``first_k_dense_replace`` layers have a dense FFN
+    of ``intermediate_size``, the others ``num_experts`` sigmoid-routed
+    experts (``num_local_experts`` of them held here, where the file says
+    so) and ``num_shared_experts`` shared ones of the same width."""
+    la = d["linear_attn_config"]
+    n_layers = d["num_hidden_layers"]
+    kda_at, full_at = set(la["kda_layers"]), set(la["full_attn_layers"])
+    if (kda_at | full_at) != set(range(1, n_layers + 1)) or kda_at & full_at:
+        raise ValueError(
+            "kimi_linear: kda_layers and full_attn_layers must name every "
+            f"layer 1..{n_layers} once (got {sorted(kda_at)}, "
+            f"{sorted(full_at)})"
+        )
+    refused = {
+        "mla_use_nope false (a rotated key part)": not d.get("mla_use_nope"),
+        "q_lora_rank (a compressed query)": d.get("q_lora_rank"),
+        "num_expert_group > 1 (grouped top-k)": (
+            d.get("num_expert_group", 1) != 1 or d.get("topk_group", 1) != 1
+        ),
+        "moe_layer_freq != 1": d.get("moe_layer_freq", 1) != 1,
+        "a router that is not sigmoid": (
+            d.get("moe_router_activation_func", "sigmoid") != "sigmoid"
+        ),
+    }
+    for what, present in refused.items():
+        if present:
+            raise ValueError(f"kimi_linear with {what} is not supported")
+    dense_first = d.get("first_k_dense_replace", 0)
+    kinds = tuple(
+        ("kda" if i in kda_at else "mla")
+        + ("+dense" if i <= dense_first else "+moe")
+        for i in range(1, n_layers + 1)
+    )
+    num_experts = d["num_experts"]
+    held = d.get("num_local_experts", num_experts)
+    return ModelConfig(
+        vocab_size=d["vocab_size"],
+        hidden_size=d["hidden_size"],
+        intermediate_size=d["intermediate_size"],
+        num_layers=n_layers,
+        num_heads=d["num_attention_heads"],
+        num_kv_heads=d.get("num_key_value_heads", d["num_attention_heads"]),
+        # the file's own ``head_dim`` (72) is read by neither mixer
+        head_dim=d["qk_nope_head_dim"] + d["qk_rope_head_dim"],
+        max_position_embeddings=d.get("model_max_length", 32768),
+        rope_theta=d.get("rope_theta", 10000.0),
+        rms_norm_eps=d.get("rms_norm_eps", 1e-5),
+        tie_word_embeddings=d.get("tie_word_embeddings", False),
+        family="kimi_linear",
+        num_experts=num_experts,
+        num_experts_per_tok=d["num_experts_per_token"],
+        moe_intermediate_size=d["moe_intermediate_size"],
+        norm_topk_prob=d.get("moe_renormalize", True),
+        router_aux_loss_coef=d.get("router_aux_loss_coef", 0.0),
+        shared_expert_size=(
+            d.get("num_shared_experts", 0) * d["moe_intermediate_size"]
+        ),
+        shared_expert_gate=False,
+        router_act="sigmoid",
+        routed_scaling_factor=d.get("routed_scaling_factor", 1.0),
+        experts_held=0 if held == num_experts else held,
+        layer_kinds=kinds,
+        kda=KDAConfig(
+            num_heads=la["num_heads"], head_dim=la["head_dim"],
+            conv_size=la.get("short_conv_kernel_size", 4),
+        ),
+        mla=MLAConfig(
+            kv_lora_rank=d["kv_lora_rank"],
+            qk_nope_head_dim=d["qk_nope_head_dim"],
+            qk_rope_head_dim=d["qk_rope_head_dim"],
+            v_head_dim=d["v_head_dim"],
+        ),
+    )
+
+
 def from_hf_config(d: dict) -> ModelConfig:
     """Build from a parsed HF config.json dict (families mirror the
     reference's from_hf registry: realhf/api/from_hf/)."""
     model_type = d.get("model_type", "llama")
     if model_type not in _HF_FAMILIES:
         raise ValueError(f"unsupported model family {model_type!r}")
+    if model_type == "kimi_linear":
+        return _kimi_linear_from_hf(d)
     num_heads = d["num_attention_heads"]
     hidden = d["hidden_size"]
     head_dim = d.get("head_dim") or hidden // num_heads
@@ -278,6 +428,23 @@ def tiny_vlm_config(vocab_size: int = 128) -> ModelConfig:
 def tiny_config(family: str = "qwen2", vocab_size: int = 128) -> ModelConfig:
     """Small config for tests."""
     moe = family in ("qwen3_moe", "mixtral", "qwen2_moe", "olmoe")
+    if family == "kimi_linear":
+        # all three block kinds; two KDA heads of 16; latent attention of
+        # two heads (8 + 4 | 8) over a rank of 16; 8 experts held of 16
+        # sigmoid-routed ones, two a token, and a shared one
+        return ModelConfig(
+            vocab_size=vocab_size, hidden_size=32, intermediate_size=64,
+            num_layers=4, num_heads=2, num_kv_heads=2, head_dim=12,
+            max_position_embeddings=512, rms_norm_eps=1e-5, family=family,
+            num_experts=16, num_experts_per_tok=2, moe_intermediate_size=16,
+            norm_topk_prob=True, router_aux_loss_coef=0.0, experts_held=8,
+            shared_expert_size=16, shared_expert_gate=False,
+            router_act="sigmoid", routed_scaling_factor=2.446,
+            layer_kinds=("kda+dense", "kda+moe", "mla+moe", "kda+moe"),
+            kda=KDAConfig(num_heads=2, head_dim=16),
+            mla=MLAConfig(kv_lora_rank=16, qk_nope_head_dim=8,
+                          qk_rope_head_dim=4, v_head_dim=8),
+        )
     if family == "KeyeVL2":
         # a qwen3_moe block over the 8 keys an indexer of two heads picks;
         # 8 experts held of 16 routed over, two a token

@@ -30,6 +30,7 @@ def packed_forward(
     moe_fn: Optional[Any] = None,
     return_stats: bool = False,
     return_hidden: bool = False,
+    kda_fn: Optional[Any] = None,
     act_sharding: Optional[Any] = None,
 ):
     """``transformer.apply`` over engine-packed arrays (tokens /
@@ -74,6 +75,7 @@ def packed_forward(
         remat=remat,
         attend_fn=attend_fn,
         moe_fn=moe_fn,
+        kda_fn=kda_fn,
         return_stats=return_stats,
         return_hidden=return_hidden,
         act_sharding=act_sharding,

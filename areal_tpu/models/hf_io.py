@@ -102,6 +102,96 @@ _MOE_MAPS = {
 }
 
 
+# A stack of block kinds (kimi_linear): our key -> (hf suffix under
+# model.layers.{i}., how it is stored). "T": a torch linear [out, in];
+# "conv": a depthwise conv1d [C, 1, K]; "": as it is. A key's suffix may
+# depend on the block kind (the dense FFN and the experts share our names).
+_KIMI_LINEAR_MAP = {
+    "input_norm": ("input_layernorm.weight", ""),
+    "post_attn_norm": ("post_attention_layernorm.weight", ""),
+    "wq": ("self_attn.q_proj.weight", "T"),
+    "wk": ("self_attn.k_proj.weight", "T"),
+    "wv": ("self_attn.v_proj.weight", "T"),
+    "wo": ("self_attn.o_proj.weight", "T"),
+    "conv_q": ("self_attn.q_conv1d.weight", "conv"),
+    "conv_k": ("self_attn.k_conv1d.weight", "conv"),
+    "conv_v": ("self_attn.v_conv1d.weight", "conv"),
+    "w_f_a": ("self_attn.f_a_proj.weight", "T"),
+    "w_f_b": ("self_attn.f_b_proj.weight", "T"),
+    "a_log": ("self_attn.A_log", ""),
+    "dt_bias": ("self_attn.dt_bias", ""),
+    "w_beta": ("self_attn.b_proj.weight", "T"),
+    "w_g_a": ("self_attn.g_a_proj.weight", "T"),
+    "w_g_b": ("self_attn.g_b_proj.weight", "T"),
+    "o_norm": ("self_attn.o_norm.weight", ""),
+    "w_kv_a": ("self_attn.kv_a_proj_with_mqa.weight", "T"),
+    "kv_norm": ("self_attn.kv_a_layernorm.weight", ""),
+    "w_kv_b": ("self_attn.kv_b_proj.weight", "T"),
+    "w_router": ("block_sparse_moe.gate.weight", "T"),
+    "router_bias": ("block_sparse_moe.gate.e_score_correction_bias", ""),
+    "w_shared_gate": ("block_sparse_moe.shared_experts.gate_proj.weight", "T"),
+    "w_shared_up": ("block_sparse_moe.shared_experts.up_proj.weight", "T"),
+    "w_shared_down": ("block_sparse_moe.shared_experts.down_proj.weight", "T"),
+}
+_KIMI_LINEAR_FFN = {  # (dense suffix, expert suffix with {e})
+    "w_gate": ("mlp.gate_proj.weight", "block_sparse_moe.experts.{e}.w1.weight"),
+    "w_up": ("mlp.up_proj.weight", "block_sparse_moe.experts.{e}.w3.weight"),
+    "w_down": ("mlp.down_proj.weight", "block_sparse_moe.experts.{e}.w2.weight"),
+}
+
+
+def _kind_tensor_names(cfg: ModelConfig):
+    """For a stack of block kinds: (kind, our key, index in the kind's
+    stack, expert or None, hf name, how stored) of every layer tensor."""
+    from areal_tpu.models.transformer import _kind_leaves
+
+    if cfg.experts_held:
+        raise NotImplementedError(
+            f"family {cfg.family!r} holding {cfg.experts_held} of "
+            f"{cfg.num_experts} experts has no checkpoint layout (a share "
+            "of the experts is no file of its own): initialise from scratch"
+        )
+    seen: Dict[str, int] = {}
+    for i, kind in enumerate(cfg.layer_kinds):
+        at = seen.get(kind, 0)
+        seen[kind] = at + 1
+        sparse = kind.endswith("+moe")
+        for key in _kind_leaves(cfg, kind):
+            prefix = f"model.layers.{i}."
+            if key in _KIMI_LINEAR_FFN:
+                dense, expert = _KIMI_LINEAR_FFN[key]
+                if sparse:
+                    for e in range(cfg.num_experts):
+                        yield (kind, key, at, e,
+                               prefix + expert.format(e=e), "T")
+                else:
+                    yield kind, key, at, None, prefix + dense, "T"
+            else:
+                suffix, how = _KIMI_LINEAR_MAP[key]
+                yield kind, key, at, None, prefix + suffix, how
+
+
+def _load_kinds(cfg: ModelConfig, g, dtype) -> Dict[str, Dict]:
+    from areal_tpu.models.transformer import _kind_leaves, block_kinds
+
+    groups = {
+        kind: {
+            key: np.zeros((cfg.kind_layers(kind),) + leaf[0], np.float32)
+            for key, leaf in _kind_leaves(cfg, kind).items()
+        }
+        for kind in block_kinds(cfg)
+    }
+    for kind, key, at, e, name, how in _kind_tensor_names(cfg):
+        w = np.asarray(g(name), np.float32)
+        w = w.T if how == "T" else w[:, 0, :] if how == "conv" else w
+        where = (at,) if e is None else (at, e)
+        groups[kind][key][where] = w.reshape(groups[kind][key][where].shape)
+    return {
+        kind: {k: jnp.asarray(v, dtype=dtype) for k, v in group.items()}
+        for kind, group in groups.items()
+    }
+
+
 def _open_shards(path: str) -> Dict[str, str]:
     """tensor name -> shard file path."""
     index_file = os.path.join(path, "model.safetensors.index.json")
@@ -164,7 +254,11 @@ def load_params(
 
     layers: Dict[str, np.ndarray] = {}
     moe_map = _MOE_MAPS.get(cfg.family) if cfg.is_moe else None
+    if cfg.layer_kinds:  # one stack a block kind, its own names
+        layers, moe_map = _load_kinds(cfg, g, dtype), None
     for our_key, (suffix, transpose) in _LAYER_MAP.items():
+        if cfg.layer_kinds:
+            break
         if moe_map and our_key in moe_map:
             continue  # expert-shaped in MoE families (handled below)
         name0 = f"model.layers.0.{suffix}"
@@ -245,6 +339,17 @@ def save_params(
     if not cfg.tie_word_embeddings:
         tensors["lm_head.weight"] = as_np32(params["lm_head"]).T.copy()
     moe_map = _MOE_MAPS.get(cfg.family) if cfg.is_moe else None
+    if cfg.layer_kinds:
+        moe_map = None
+        host = {
+            kind: {k: as_np32(v) for k, v in group.items()}
+            for kind, group in params["layers"].items()
+        }
+        for kind, key, at, e, name, how in _kind_tensor_names(cfg):
+            w = host[kind][key][at] if e is None else host[kind][key][at, e]
+            tensors[name] = (
+                w.T if how == "T" else w[:, None, :] if how == "conv" else w
+            ).copy()
     for our_key, (suffix, transpose) in _LAYER_MAP.items():
         if our_key not in params["layers"]:
             continue
@@ -304,7 +409,52 @@ def save_params(
         json.dump(hf_config_dict, f, indent=2)
 
 
+def _kimi_linear_config_dict(cfg: ModelConfig) -> dict:
+    """The keys ``models/config._kimi_linear_from_hf`` reads."""
+    at = {m: [i + 1 for i, k in enumerate(cfg.layer_kinds)
+              if k.startswith(m)] for m in ("kda", "mla")}
+    return {
+        "model_type": cfg.family,
+        "architectures": ["KimiLinearForCausalLM"],
+        "vocab_size": cfg.vocab_size,
+        "hidden_size": cfg.hidden_size,
+        "intermediate_size": cfg.intermediate_size,
+        "num_hidden_layers": cfg.num_layers,
+        "num_attention_heads": cfg.num_heads,
+        "num_key_value_heads": cfg.num_kv_heads,
+        "model_max_length": cfg.max_position_embeddings,
+        "rope_theta": cfg.rope_theta,
+        "rms_norm_eps": cfg.rms_norm_eps,
+        "tie_word_embeddings": cfg.tie_word_embeddings,
+        "torch_dtype": "float32",
+        "linear_attn_config": {
+            "kda_layers": at["kda"], "full_attn_layers": at["mla"],
+            "num_heads": cfg.kda.num_heads, "head_dim": cfg.kda.head_dim,
+            "short_conv_kernel_size": cfg.kda.conv_size,
+        },
+        "kv_lora_rank": cfg.mla.kv_lora_rank, "q_lora_rank": None,
+        "qk_nope_head_dim": cfg.mla.qk_nope_head_dim,
+        "qk_rope_head_dim": cfg.mla.qk_rope_head_dim,
+        "v_head_dim": cfg.mla.v_head_dim, "mla_use_nope": True,
+        "first_k_dense_replace": sum(
+            k.endswith("+dense") for k in cfg.layer_kinds
+        ),
+        "num_experts": cfg.num_experts,
+        "num_local_experts": cfg.num_held_experts,
+        "num_experts_per_token": cfg.num_experts_per_tok,
+        "moe_intermediate_size": cfg.moe_intermediate_size,
+        "num_shared_experts": cfg.shared_expert_size
+        // max(cfg.moe_intermediate_size, 1),
+        "moe_renormalize": cfg.norm_topk_prob,
+        "moe_router_activation_func": cfg.router_act,
+        "routed_scaling_factor": cfg.routed_scaling_factor,
+        "num_expert_group": 1, "topk_group": 1, "moe_layer_freq": 1,
+    }
+
+
 def default_hf_config_dict(cfg: ModelConfig) -> dict:
+    if cfg.family == "kimi_linear":
+        return _kimi_linear_config_dict(cfg)
     return {
         "model_type": cfg.family,
         "vocab_size": cfg.vocab_size,

@@ -14,6 +14,12 @@ from-scratch parallel causal LM — re-designed TPU-first:
   the TPU analog of the reference's cu_seqlens varlen batches.
 - `jax.checkpoint` (remat) on the scanned layer body trades FLOPs for HBM,
   replacing torch gradient checkpointing (reference base_hf_engine.py).
+- A model whose layers are of more than one kind (``ModelConfig.layer_kinds``:
+  gated delta-rule, latent-attention and rotary mixers over dense or sparse
+  FFNs) holds ONE STACK A BLOCK KIND under ``params["layers"][kind]`` and
+  ``apply`` walks the pattern: every run of consecutive layers of one kind is
+  a scan over that kind's slice, under the same checkpoint policy. A model
+  of one kind keeps the flat ``params["layers"]`` and the one scan.
 """
 
 import functools
@@ -25,7 +31,8 @@ import numpy as np
 from jax import ad_checkpoint as _ad_checkpoint
 
 from areal_tpu.models.config import ModelConfig
-from areal_tpu.ops.moe import MoEStats
+from areal_tpu.ops.kda import KDAStats, kda_scan
+from areal_tpu.ops.moe import MoEStats, moe_ffn_from_params
 from areal_tpu.ops.sparse_attention import DSAStats, sparse_attention
 from areal_tpu.utils.tracing import device_scope
 from areal_tpu.ops.basic import (
@@ -47,6 +54,117 @@ class LayerStats(NamedTuple):
 
     moe: MoEStats  # zeros for a dense model
     dsa: Optional[DSAStats] = None  # sparse-attention models only
+    kda: Optional[KDAStats] = None  # gated delta-rule layers only
+
+
+# --------------------------------------------------------------------------
+# Block kinds (``ModelConfig.layer_kinds``): one table says what a kind holds
+# --------------------------------------------------------------------------
+def _kind_leaves(cfg: ModelConfig, kind: str) -> Dict[str, tuple]:
+    """One layer of block ``kind``: leaf -> (shape, how it is drawn, logical
+    axes). Drawn: "normal" (std 0.02), "ones", "zeros", or an array."""
+    mixer, ffn = kind.split("+")
+    D = cfg.hidden_size
+    leaves = {
+        "input_norm": ((D,), "ones", (None,)),
+        "post_attn_norm": ((D,), "ones", (None,)),
+    }
+    if mixer == "kda":
+        kc = cfg.kda
+        H, dh = kc.num_heads, kc.head_dim
+        for n in "qkv":
+            leaves[f"w{n}"] = ((D, H * dh), "normal", ("embed", "heads"))
+            leaves[f"conv_{n}"] = (
+                (H * dh, kc.conv_size), "normal", ("heads", None)
+            )
+        # the decay: -exp(a_log) softplus(low rank + dt_bias), a head's
+        # rates spread over 1..16 and the steps over 1e-3..1e-1 (the
+        # family's initial ranges, laid out evenly instead of drawn)
+        dt = jnp.exp(jnp.linspace(jnp.log(1e-3), jnp.log(1e-1), dh))
+        leaves.update({
+            "w_f_a": ((D, dh), "normal", ("embed", None)),
+            "w_f_b": ((dh, H * dh), "normal", (None, "heads")),
+            "a_log": ((H,), jnp.log(jnp.linspace(1.0, 16.0, H)), (None,)),
+            "dt_bias": (
+                (H * dh,), jnp.tile(dt + jnp.log(-jnp.expm1(-dt)), H),
+                ("heads",),
+            ),
+            "w_beta": ((D, H), "normal", ("embed", None)),
+            "w_g_a": ((D, dh), "normal", ("embed", None)),
+            "w_g_b": ((dh, H * dh), "normal", (None, "heads")),
+            "o_norm": ((dh,), "ones", (None,)),
+            "wo": ((H * dh, D), "normal", ("heads", "embed")),
+        })
+    elif mixer == "mla":
+        m, H = cfg.mla, cfg.num_heads
+        leaves.update({
+            "wq": ((D, H * m.qk_head_dim), "normal", ("embed", "heads")),
+            "w_kv_a": (
+                (D, m.kv_lora_rank + m.qk_rope_head_dim), "normal",
+                ("embed", None),
+            ),
+            "kv_norm": ((m.kv_lora_rank,), "ones", (None,)),
+            "w_kv_b": (
+                (m.kv_lora_rank, H * (m.qk_nope_head_dim + m.v_head_dim)),
+                "normal", (None, "heads"),
+            ),
+            "wo": ((H * m.v_head_dim, D), "normal", ("heads", "embed")),
+        })
+    else:
+        raise ValueError(f"no mixer {mixer!r} among the block kinds")
+    if ffn == "moe":
+        E, Fe = cfg.num_held_experts, cfg.expert_ffn_size
+        leaves.update({
+            "w_router": ((D, cfg.num_experts), "normal", ("embed", None)),
+            "w_gate": ((E, D, Fe), "normal", ("expert", "embed", "mlp")),
+            "w_up": ((E, D, Fe), "normal", ("expert", "embed", "mlp")),
+            "w_down": ((E, Fe, D), "normal", ("expert", "mlp", "embed")),
+        })
+        if cfg.router_act == "sigmoid":  # the choice's correction bias
+            leaves["router_bias"] = ((cfg.num_experts,), "zeros", (None,))
+        if cfg.shared_expert_size:
+            Fs = cfg.shared_expert_size
+            leaves.update({
+                "w_shared_gate": ((D, Fs), "normal", ("embed", "mlp")),
+                "w_shared_up": ((D, Fs), "normal", ("embed", "mlp")),
+                "w_shared_down": ((Fs, D), "normal", ("mlp", "embed")),
+            })
+            if cfg.shared_expert_gate:
+                leaves["w_shared_router"] = (
+                    (D, 1), "normal", ("embed", None)
+                )
+    else:
+        F = cfg.intermediate_size
+        leaves.update({
+            "w_gate": ((D, F), "normal", ("embed", "mlp")),
+            "w_up": ((D, F), "normal", ("embed", "mlp")),
+            "w_down": ((F, D), "normal", ("mlp", "embed")),
+        })
+    return leaves
+
+
+def block_kinds(cfg: ModelConfig) -> tuple:
+    """The model's block kinds, in the order they first appear."""
+    return tuple(dict.fromkeys(cfg.layer_kinds))
+
+
+def _init_kinds(cfg: ModelConfig, rng: jax.Array, dtype) -> Params:
+    groups = {}
+    for g, kind in enumerate(block_kinds(cfg)):
+        n = cfg.kind_layers(kind)
+        leaves = _kind_leaves(cfg, kind)
+        keys = jax.random.split(jax.random.fold_in(rng, 200 + g), len(leaves))
+        group = {}
+        for key, (name, (shape, how, _)) in zip(keys, leaves.items()):
+            if isinstance(how, str) and how == "normal":
+                w = jax.random.normal(key, (n,) + shape, jnp.float32) * 0.02
+            elif isinstance(how, str):
+                w = (jnp.ones if how == "ones" else jnp.zeros)((n,) + shape)
+            else:
+                w = jnp.broadcast_to(how, (n,) + shape)
+            group[name] = w.astype(dtype)
+        groups[kind] = group
+    return groups
 
 
 # --------------------------------------------------------------------------
@@ -120,6 +238,8 @@ def init_params(
         layers["k_norm"] = jnp.ones(
             (L, cfg.head_dim if per_head else KVd), dtype
         )
+    if cfg.layer_kinds:  # one stack a block kind, none of the above
+        layers = _init_kinds(cfg, rng, dtype)
     params: Params = {
         "embedding": nrm(keys[7], (cfg.vocab_size, D), std),
         "layers": layers,
@@ -183,6 +303,14 @@ def param_logical_axes(cfg: ModelConfig, value_head: bool = False) -> Params:
     if cfg.qk_norm:
         layers["q_norm"] = ("layer", None)
         layers["k_norm"] = ("layer", None)
+    if cfg.layer_kinds:
+        layers = {
+            kind: {
+                name: ("layer",) + leaf[2]
+                for name, leaf in _kind_leaves(cfg, kind).items()
+            }
+            for kind in block_kinds(cfg)
+        }
     axes: Params = {
         "embedding": ("vocab", "embed"),
         "layers": layers,
@@ -267,15 +395,20 @@ def _layer_body(
     # PR 26), against [B,T,Hq,D] bf16 of storage
     attn = _ad_checkpoint.checkpoint_name(attn, "attn_out")
     x = x + attn.reshape(b, t, cfg.q_dim) @ lp["wo"]
+    return _ffn_residual(cfg, x, lp, segment_ids, moe_fn, cfg.is_moe, dsa)
+
+
+def _ffn_residual(
+    cfg, x, lp, segment_ids, moe_fn, sparse: bool, dsa=None, kda=None,
+):
+    """The second half of every block: (x + FFN(norm(x)), ``LayerStats``).
+    ``sparse``: routed experts (with the shared one, where the family has
+    it), else the dense gated MLP."""
+    uo = cfg.norm_add_unit_offset
     h = rms_norm(
         x, lp["post_attn_norm"], cfg.rms_norm_eps, add_unit_offset=uo
     )
-    if cfg.is_moe:
-        from areal_tpu.ops.moe import (
-            moe_ffn_from_params,
-            shared_expert_from_params,
-        )
-
+    if sparse:
         # padding tokens (segment 0) are no expert's load
         with device_scope("mlp"):
             if moe_fn is None:
@@ -284,16 +417,216 @@ def _layer_body(
                 )
             else:  # per device under a mesh (a shard_map of the same)
                 ffn, stats = moe_fn(lp, h, segment_ids > 0)
-            if cfg.shared_expert_size:
-                ffn = ffn + shared_expert_from_params(cfg, lp, h)
-        return x + ffn, LayerStats(stats, dsa)
+        return x + ffn, LayerStats(stats, dsa, kda)
     with device_scope("mlp"):
         ffn = (
             hidden_act_fn(cfg.hidden_act)(h @ lp["w_gate"])
             * (h @ lp["w_up"])
         ) @ lp["w_down"]
     return x + ffn, LayerStats(
-        MoEStats(*(jnp.zeros((), jnp.float32),) * 3), dsa
+        MoEStats(*(jnp.zeros((), jnp.float32),) * 3), dsa, kda
+    )
+
+
+def _short_conv(x, w, segment_ids):
+    """Depthwise causal convolution over the tokens of ONE sequence:
+    y_t = sum_s w[:, K-1-s] x_{t-s}, over the s for which token t - s is of
+    token t's sequence. ``x`` [B, T, C]; ``w`` [C, K], tap K-1 the token
+    itself. Float32."""
+    taps = w.shape[-1]
+    x = x.astype(jnp.float32)
+    w = w.astype(jnp.float32)
+    y = x * w[:, taps - 1]
+    for s in range(1, taps):
+        back = jnp.pad(x, ((0, 0), (s, 0), (0, 0)))[:, :-s]
+        seg_back = jnp.pad(segment_ids, ((0, 0), (s, 0)))[:, :-s]
+        same = (seg_back == segment_ids) & (segment_ids > 0)
+        y = y + jnp.where(same[..., None], back, 0.0) * w[:, taps - 1 - s]
+    return y
+
+
+def _kda_mixer(cfg, h, lp, segment_ids, kda_fn):
+    """The gated delta-rule mixer on the normed input ``h`` [B, T, D]:
+    (y [B, T, D], ``KDAStats``). ``ops/kda.py`` has the rule; here is what
+    feeds it and what follows it (scope ``kda_prep``)."""
+    b, t, _ = h.shape
+    kc = cfg.kda
+    H, dh = kc.num_heads, kc.head_dim
+    proj = {n: h @ lp[f"w{n}"] for n in "qkv"}
+    with device_scope("kda_prep"):
+        def conv(n):
+            y = jax.nn.silu(_short_conv(proj[n], lp[f"conv_{n}"], segment_ids))
+            return y.reshape(b, t, H, dh)
+
+        def l2norm(y):
+            return y * jax.lax.rsqrt(
+                jnp.sum(y * y, axis=-1, keepdims=True) + 1e-6
+            )
+
+        q = l2norm(conv("q")).astype(h.dtype)
+        k = l2norm(conv("k")).astype(h.dtype)
+        v = conv("v").astype(h.dtype)
+        f = ((h @ lp["w_f_a"]) @ lp["w_f_b"]).astype(jnp.float32)
+        rate = jnp.exp(lp["a_log"].astype(jnp.float32))[:, None]
+        g = -rate * jax.nn.softplus(
+            f + lp["dt_bias"].astype(jnp.float32)
+        ).reshape(b, t, H, dh)
+        beta = jax.nn.sigmoid((h @ lp["w_beta"]).astype(jnp.float32))
+    o, stats = (kda_fn or kda_scan)(q, k, v, g, beta, segment_ids)
+    # kept across forward -> backward as an attention output is: the
+    # backward recomputes the rule's chunk states from q, k, v, g, beta,
+    # and the rule's forward is not run a second time for its output
+    o = _ad_checkpoint.checkpoint_name(o, "attn_out")
+    with device_scope("kda_prep"):
+        gate = jax.nn.sigmoid(
+            ((h @ lp["w_g_a"]) @ lp["w_g_b"]).astype(jnp.float32)
+        ).reshape(b, t, H, dh)
+        y = rms_norm(o, lp["o_norm"], cfg.rms_norm_eps)
+        y = (y.astype(jnp.float32) * gate).astype(h.dtype)
+    return y.reshape(b, t, H * dh) @ lp["wo"], stats
+
+
+def _mla_mixer(cfg, h, lp, segment_ids, attend_fn):
+    """Latent attention without positions on the normed input ``h``: the
+    keys' and values' one compression, a key part all heads share, and a
+    causal softmax core whose query/key width differs from its value's."""
+    b, t, _ = h.shape
+    m, H = cfg.mla, cfg.num_heads
+    q = (h @ lp["wq"]).reshape(b, t, H, m.qk_head_dim)
+    c, k_shared = jnp.split(h @ lp["w_kv_a"], [m.kv_lora_rank], axis=-1)
+    c = rms_norm(c, lp["kv_norm"], cfg.rms_norm_eps)
+    k_nope, v = jnp.split(
+        (c @ lp["w_kv_b"]).reshape(
+            b, t, H, m.qk_nope_head_dim + m.v_head_dim
+        ),
+        [m.qk_nope_head_dim], axis=-1,
+    )
+    k = jnp.concatenate([
+        k_nope,
+        jnp.broadcast_to(
+            k_shared[:, :, None, :], (b, t, H, m.qk_rope_head_dim)
+        ),
+    ], axis=-1)
+    with device_scope("attention"):
+        if attend_fn is None:
+            attn = segment_attention(q, k, v, segment_ids, causal=True)
+        else:
+            attn = attend_fn(q, k, v, segment_ids)
+    attn = _ad_checkpoint.checkpoint_name(attn, "attn_out")
+    return attn.reshape(b, t, H * m.v_head_dim) @ lp["wo"]
+
+
+def _block_body(
+    cfg, kind, x, lp, segment_ids, attend_fn=None, moe_fn=None, kda_fn=None,
+):
+    """One layer of block ``kind`` (``ModelConfig.layer_kinds``)."""
+    mixer, ffn = kind.split("+")
+    h = rms_norm(x, lp["input_norm"], cfg.rms_norm_eps)
+    kda = None
+    if mixer == "kda":
+        y, kda = _kda_mixer(cfg, h, lp, segment_ids, kda_fn)
+    else:
+        y = _mla_mixer(cfg, h, lp, segment_ids, attend_fn)
+    return _ffn_residual(
+        cfg, x + y, lp, segment_ids, moe_fn, ffn == "moe", kda=kda
+    )
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _split_stack(a, sizes):
+    """A kind's stacked leaf [L, ...] as the pieces its runs scan over. The
+    transpose of a slice is a zero-padded array of the WHOLE leaf's size, a
+    run each, and their sum; the pieces' cotangents are just the leaf's,
+    side by side."""
+    out, at = [], 0
+    for n in sizes:
+        out.append(a[at:at + n])
+        at += n
+    return tuple(out)
+
+
+_split_stack.defvjp(
+    lambda a, sizes: (_split_stack(a, sizes), None),
+    lambda sizes, _, cts: (jnp.concatenate(cts, axis=0),),
+)
+
+
+def _runs(kinds):
+    """[(kind, length)] of the consecutive runs of one block kind."""
+    out = []
+    for kind in kinds:
+        if out and out[-1][0] == kind:
+            out[-1][1] += 1
+        else:
+            out.append([kind, 1])
+    return [(k, n) for k, n in out]
+
+
+def _apply_kinds(
+    cfg, params, x, segment_ids, remat, attend_fn, moe_fn, kda_fn, act_sharding,
+):
+    """The stack of a model of several block kinds: every run of consecutive
+    layers of one kind is a scan over that kind's slice of its stack.
+    Returns (x, ``LayerStats`` summed over the layers: the load-balancing
+    loss the mean of the sparse layers')."""
+    runs = _runs(cfg.layer_kinds)
+    pieces = {
+        kind: iter(zip(*(
+            _split_stack(a, tuple(n for k, n in runs if k == kind))
+            for a in jax.tree_util.tree_leaves(stack)
+        )))
+        for kind, stack in params["layers"].items()
+    }
+    moe, kda = [], []
+    for kind, n in runs:
+        stack = jax.tree_util.tree_unflatten(
+            jax.tree_util.tree_structure(params["layers"][kind]),
+            next(pieces[kind]),
+        )
+
+        def body(carry, lp, kind=kind):
+            out, aux = _block_body(
+                cfg, kind, carry, lp, segment_ids, attend_fn, moe_fn, kda_fn
+            )
+            if act_sharding is not None:
+                out = jax.lax.with_sharding_constraint(out, act_sharding)
+            return out, aux
+
+        if remat:
+            # as the one-kind stack: only mixer outputs are kept. A run of
+            # one layer is no loop: there XLA would merge the recomputed
+            # forward with the first one (and keep every activation), which
+            # a loop's boundary prevents and prevent_cse must
+            body = jax.checkpoint(
+                body, prevent_cse=n == 1,
+                policy=jax.checkpoint_policies.save_only_these_names(
+                    "attn_out"
+                ),
+            )
+        if n == 1:
+            x, aux = body(x, jax.tree_util.tree_map(lambda a: a[0], stack))
+            aux = jax.tree_util.tree_map(lambda a: a[None], aux)
+        else:
+            x, aux = jax.lax.scan(body, x, stack)
+        if kind.endswith("+moe"):
+            moe.append(aux.moe)
+        if aux.kda is not None:
+            kda.append(aux.kda)
+
+    def total(stats, field, reduce=jnp.sum):
+        return reduce(jnp.concatenate([getattr(s, field) for s in stats]))
+
+    zero = jnp.zeros((), jnp.float32)
+    return x, LayerStats(
+        MoEStats(
+            aux_loss=total(moe, "aux_loss", jnp.mean) if moe else zero,
+            assignments=total(moe, "assignments") if moe else zero,
+            max_expert_assignments=(
+                total(moe, "max_expert_assignments") if moe else zero
+            ),
+        ),
+        None,
+        KDAStats(*(total(kda, f) for f in KDAStats._fields)) if kda else None,
     )
 
 
@@ -307,6 +640,7 @@ def apply(
     attend_fn: Optional[Any] = None,
     moe_fn: Optional[Any] = None,
     return_stats: bool = False,
+    kda_fn: Optional[Any] = None,
     mm_embeds: Optional[jnp.ndarray] = None,  # [B, N, D] vision embeds
     mm_index: Optional[jnp.ndarray] = None,  # [B, T] int32; -1 = text
     return_hidden: bool = False,  # lazy ChunkedLogits instead of [B,T,V]
@@ -325,7 +659,11 @@ def apply(
 
     `moe_fn(lp, h, valid)` overrides the sparse layer's call the way
     `attend_fn` overrides attention: the engine's per-device ``shard_map``
-    of ``moe_ffn_from_params`` under a mesh.
+    of ``moe_ffn_from_params`` under a mesh. `kda_fn(q, k, v, g, beta,
+    segment_ids)` does the same for the gated delta rule
+    (``ops/kda.make_kda_scan``); a model with such layers also reports
+    ``kda``: the tokens, chunk slots and sequence starts its scans saw,
+    int32, summed over those layers.
 
     `attend_fn(q, k, v, segment_ids)` overrides the attention kernel (e.g.
     ring / Ulysses shard_map from ops/ring_attention.py); default is the
@@ -360,6 +698,14 @@ def apply(
     if act_sharding is not None:
         x = jax.lax.with_sharding_constraint(x, act_sharding)
 
+    if cfg.layer_kinds:
+        x, stats = _apply_kinds(
+            cfg, params, x, segment_ids, remat, attend_fn, moe_fn, kda_fn,
+            act_sharding,
+        )
+        logits = _head(params, cfg, x, return_hidden)
+        return (logits, stats) if return_stats else logits
+
     def body(carry, lp):
         out, aux = _layer_body(
             cfg, carry, lp, segment_ids, positions, cos, sin, attend_fn,
@@ -382,6 +728,23 @@ def apply(
             policy=jax.checkpoint_policies.save_only_these_names(*saved),
         )
     x, aux = jax.lax.scan(body, x, params["layers"])
+    logits = _head(params, cfg, x, return_hidden)
+    if return_stats:
+        moe = aux.moe
+        return logits, LayerStats(
+            MoEStats(
+                aux_loss=jnp.mean(moe.aux_loss),
+                assignments=jnp.sum(moe.assignments),
+                max_expert_assignments=jnp.sum(moe.max_expert_assignments),
+            ),
+            aux.dsa,
+        )
+    return logits
+
+
+def _head(params, cfg, x, return_hidden):
+    """The final norm and the output head over the stack's output: logits
+    (or their lazy view)."""
     x = rms_norm(
         x, params["final_norm"], cfg.rms_norm_eps,
         add_unit_offset=cfg.norm_add_unit_offset,
@@ -403,16 +766,6 @@ def apply(
             head = head.T
         with device_scope("lm_head"):
             logits = (x.astype(jnp.float32)) @ head.astype(jnp.float32)
-    if return_stats:
-        moe = aux.moe
-        return logits, LayerStats(
-            MoEStats(
-                aux_loss=jnp.mean(moe.aux_loss),
-                assignments=jnp.sum(moe.assignments),
-                max_expert_assignments=jnp.sum(moe.max_expert_assignments),
-            ),
-            aux.dsa,
-        )
     return logits
 
 

@@ -152,4 +152,4 @@ def segment_attention(
         "bgrqk,bkgd->bqgrd", probs, v.astype(jnp.float32),
         preferred_element_type=jnp.float32,
     )
-    return out.reshape(b, t, hq, d).astype(q.dtype)
+    return out.reshape(b, t, hq, v.shape[-1]).astype(q.dtype)

@@ -56,7 +56,8 @@ def blockwise_segment_attention(
 
     qg = (q.astype(jnp.float32) * scale).reshape(b, nq, cq, hkv, rep, d)
     kr = k.astype(jnp.float32).reshape(b, nk, ck, hkv, d)
-    vr = v.astype(jnp.float32).reshape(b, nk, ck, hkv, d)
+    dv = v.shape[-1]  # latent attention's values are narrower
+    vr = v.astype(jnp.float32).reshape(b, nk, ck, hkv, dv)
     seg_q = segment_ids.reshape(b, nq, cq)
     seg_k = segment_ids.reshape(b, nk, ck)
     qpos = jnp.arange(t).reshape(nq, cq)
@@ -95,7 +96,7 @@ def blockwise_segment_attention(
             )
             return (acc, m_new, l), None
 
-        acc0 = jnp.zeros((b, hkv, rep, cq, d), jnp.float32)
+        acc0 = jnp.zeros((b, hkv, rep, cq, dv), jnp.float32)
         m0 = jnp.full((b, hkv, rep, cq), NEG_INF, jnp.float32)
         l0 = jnp.zeros((b, hkv, rep, cq), jnp.float32)
         (acc, m, l), _ = jax.lax.scan(
@@ -121,6 +122,6 @@ def blockwise_segment_attention(
         ),
     )
     # outs: [nq, B, Hkv, rep, cq, D] -> [B, T, Hq, D]
-    out = outs.transpose(1, 0, 4, 2, 3, 5).reshape(b, t, hq, d)
+    out = outs.transpose(1, 0, 4, 2, 3, 5).reshape(b, t, hq, dv)
     valid = (segment_ids > 0)[:, :, None, None]
     return jnp.where(valid, out, 0.0).astype(q.dtype)

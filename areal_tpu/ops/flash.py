@@ -108,18 +108,20 @@ def probe_block_size(max_block: int = 2048, probe_t: int = 2048) -> int:
     ) from refused
 
 
-def takes_head_dim(d: int) -> bool:
+def takes_head_dim(d: int, dv: "int | None" = None) -> bool:
     """Head dimensions the trainer hands to the kernel: multiples of 64.
     64 (half a lane row) and 128 have trained on the v5e (Qwen2-0.5B and
-    -1.5B, PERF.md PR 22 and PR 26); the installed Mosaic compiles others
-    too, but none has ever run on a chip, so they stay on the XLA form
-    until one has."""
-    return d > 0 and d % 64 == 0
+    -1.5B, PERF.md PR 22 and PR 26), and a query/key width of 192 over a
+    value width ``dv`` of 128 (latent attention, PERF.md PR 33); the
+    installed Mosaic compiles others too, but none has ever run on a chip,
+    so they stay on the XLA form until one has."""
+    dv = d if dv is None else dv
+    return d > 0 and d % 64 == 0 and (dv == d or (d, dv) == (192, 128))
 
 
 def resolve_attn_impl(
     attn_impl: str, seq_parallel_size: int, head_dim: int,
-    has_indexer: bool = False,
+    has_indexer: bool = False, head_dim_v: "int | None" = None,
 ) -> str:
     """A train engine's ``attn_impl`` against what the process can observe
     (the way ``GenerationEngine`` resolves its own ``auto``). A model with
@@ -144,7 +146,7 @@ def resolve_attn_impl(
     if attn_impl == "auto":
         fused = (
             jax.default_backend() == "tpu" and no_sp
-            and takes_head_dim(head_dim)
+            and takes_head_dim(head_dim, head_dim_v)
         )
         return "flash" if fused else "xla"
     if attn_impl in ("ring", "ulysses") and no_sp:
@@ -227,7 +229,9 @@ def flash_segment_attention(
     window: int = 0,  # 0 = full causal; else >= max segment length
     interpret: bool = False,  # Pallas interpret mode: the CPU's tests
 ) -> jnp.ndarray:
-    """Drop-in replacement for ops.basic.segment_attention on TPU."""
+    """Drop-in replacement for ops.basic.segment_attention on TPU. ``v``
+    may be narrower than ``q`` and ``k`` (latent attention's 192 | 128): the
+    kernel takes the value width from ``v``, nothing is padded."""
     assert causal, "splash path is causal-only (decoder models)"
     b, t, hq, d = q.shape
     if t % _LANES:
@@ -249,6 +253,6 @@ def flash_segment_attention(
         return jax.vmap(kernel, in_axes=(0, 0, 0, None))(q_, k_, v_, ids)
 
     out = jax.vmap(per_batch)(qg, kt, vt, segment_ids)
-    out = out.reshape(b, hq, t, d).transpose(0, 2, 1, 3)
+    out = out.reshape(b, hq, t, v.shape[-1]).transpose(0, 2, 1, 3)
     valid = (segment_ids > 0)[:, :, None, None]
     return jnp.where(valid, out, 0).astype(q.dtype)

@@ -31,6 +31,15 @@ kernel is held equal to). Under a device mesh the layer runs per device
 inside a ``shard_map`` (``make_sharded_moe_ffn``, the layer's mesh form): a
 sort over a token axis that GSPMD has sharded would gather it.
 
+The router has two forms (``router_topk``): a softmax over all experts whose
+``k`` largest probabilities are the weights (renormalised or not), and
+sigmoid scores of which the ``k`` largest AFTER a correction bias are chosen,
+weighted by the scores without the bias, renormalised, times a scaling
+factor. A family's shared expert (a dense gated FFN on every token, times a
+per-token sigmoid gate where the family has one) is part of the sparse layer
+(``moe_ffn_from_params``), so under a mesh it runs inside the same
+``shard_map``.
+
 Device scopes (``utils/tracing.DEVICE_SCOPES``), nested inside the layer's
 ``mlp``: ``moe_route`` (router matmul, softmax, top-k, load statistics),
 ``moe_dispatch`` (sort, permute, un-permute, weighted sum), ``moe_experts``
@@ -72,14 +81,32 @@ def router_topk(
     logits: jnp.ndarray,  # [G, E] fp32
     k: int,
     norm_topk_prob: bool,
+    act: str = "softmax",
+    bias: Optional[jnp.ndarray] = None,  # [E], the sigmoid form's
+    scale: float = 1.0,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Returns (topk_probs [G,k], topk_idx [G,k], full probs [G,E])."""
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    topk_p, topk_i = jax.lax.top_k(probs, k)
+    """Returns (topk_probs [G,k], topk_idx [G,k], full probs [G,E]).
+    ``act`` "softmax": the ``k`` largest probabilities. "sigmoid": the
+    experts of the ``k`` largest ``sigmoid(logits) + bias`` (the bias moves
+    the choice only, and no gradient reaches it), weighted by their scores
+    WITHOUT the bias. Either renormalised where ``norm_topk_prob``, then
+    times ``scale``."""
+    if act == "sigmoid":
+        probs = jax.nn.sigmoid(logits.astype(jnp.float32))
+        ranked = probs if bias is None else probs + jax.lax.stop_gradient(
+            bias.astype(jnp.float32)
+        )
+        topk_i = jax.lax.top_k(ranked, k)[1]
+        topk_p = jnp.take_along_axis(probs, topk_i, axis=-1)
+    else:
+        probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+        topk_p, topk_i = jax.lax.top_k(probs, k)
     if norm_topk_prob:
         topk_p = topk_p / jnp.maximum(
             topk_p.sum(-1, keepdims=True), 1e-9
         )
+    if scale != 1.0:
+        topk_p = topk_p * scale
     return topk_p, topk_i, probs
 
 
@@ -280,6 +307,9 @@ def moe_ffn(
     valid: Optional[jnp.ndarray] = None,  # [B, T] bool
     token_axes: Optional[Tuple[str, ...]] = None,
     first_expert: int = 0,
+    router_act: str = "softmax",
+    router_bias: Optional[jnp.ndarray] = None,
+    routed_scaling_factor: float = 1.0,
 ) -> Tuple[jnp.ndarray, MoEStats]:
     """Returns (output [B, T, D], ``MoEStats``). ``valid`` masks padding
     and inactive slots out: zero output, no load, no statistic.
@@ -302,7 +332,10 @@ def moe_ffn(
             xf.astype(jnp.float32), w_router.astype(jnp.float32),
             precision=jax.lax.Precision.HIGHEST,
         )
-        topk_p, topk_i, probs = router_topk(logits, k, norm_topk_prob)
+        topk_p, topk_i, probs = router_topk(
+            logits, k, norm_topk_prob, router_act, router_bias,
+            routed_scaling_factor,
+        )
         aux = load_balancing_loss(
             probs, topk_i, n_routed, valid=vf, token_axes=token_axes
         )
@@ -351,9 +384,9 @@ def moe_ffn_from_params(
     cfg, lp: Dict, h: jnp.ndarray, valid: Optional[jnp.ndarray] = None,
     token_axes: Optional[Tuple[str, ...]] = None,
 ) -> Tuple[jnp.ndarray, MoEStats]:
-    """Shared dispatch for training and serving layer bodies — one place
-    to evolve routing arguments."""
-    return moe_ffn(
+    """The sparse layer, for training and serving layer bodies alike: the
+    routed experts, and the family's shared expert where it has one."""
+    out, stats = moe_ffn(
         h,
         lp["w_router"],
         lp["w_gate"],
@@ -363,11 +396,29 @@ def moe_ffn_from_params(
         norm_topk_prob=cfg.norm_topk_prob,
         valid=valid,
         token_axes=token_axes,
+        router_act=cfg.router_act,
+        router_bias=lp.get("router_bias"),
+        routed_scaling_factor=cfg.routed_scaling_factor,
     )
+    if cfg.shared_expert_size:
+        out = out + shared_expert_from_params(cfg, lp, h)
+    return out, stats
 
 
-# the leaves of one layer that the sparse FFN reads
+# the leaves of one layer that the routed experts read
 EXPERT_WEIGHTS = ("w_router", "w_gate", "w_up", "w_down")
+
+
+def sparse_leaves(cfg) -> Tuple[str, ...]:
+    """The leaves of one layer that ``moe_ffn_from_params`` reads."""
+    names = EXPERT_WEIGHTS
+    if cfg.router_act == "sigmoid":
+        names += ("router_bias",)
+    if cfg.shared_expert_size:
+        names += ("w_shared_gate", "w_shared_up", "w_shared_down")
+        if cfg.shared_expert_gate:
+            names += ("w_shared_router",)
+    return names
 
 
 def make_sharded_moe_ffn(cfg, mesh: Mesh, weight_specs: Dict[str, P]):
@@ -378,7 +429,7 @@ def make_sharded_moe_ffn(cfg, mesh: Mesh, weight_specs: Dict[str, P]):
     that GSPMD has sharded would gather it; a Mosaic grouped matmul cannot
     be partitioned either. Each device routes its own tokens through ALL
     experts: the layer's expert weights come in as ``weight_specs`` says
-    they are sharded (one ``PartitionSpec`` for each of ``EXPERT_WEIGHTS``,
+    they are sharded (one ``PartitionSpec`` for each of ``sparse_leaves``,
     the layer axis gone) and are gathered whole inside (as ZeRO-3 gathers a
     dense layer's; the transpose reduce-scatters their gradients), tokens
     never leave their device, and devices that hold the same tokens
@@ -387,7 +438,7 @@ def make_sharded_moe_ffn(cfg, mesh: Mesh, weight_specs: Dict[str, P]):
     if mesh.size == 1:
         return None
     token_axes = ("data", "fsdp", "seq")
-    specs = {k: weight_specs[k] for k in EXPERT_WEIGHTS}
+    specs = {k: weight_specs[k] for k in sparse_leaves(cfg)}
 
     def per_device(weights, h, valid):
         whole = {}
@@ -412,12 +463,14 @@ def make_sharded_moe_ffn(cfg, mesh: Mesh, weight_specs: Dict[str, P]):
 
 
 def shared_expert_from_params(cfg, lp: Dict, h: jnp.ndarray) -> jnp.ndarray:
-    """qwen2_moe shared expert: a dense SiLU-gated FFN on EVERY token,
-    scaled by a per-token sigmoid gate (HF Qwen2MoeSparseMoeBlock). One
-    implementation for both the training stack and the serving runner."""
+    """The shared expert: a dense SiLU-gated FFN on EVERY token, added as
+    it is, or (``cfg.shared_expert_gate``: qwen2_moe, HF
+    Qwen2MoeSparseMoeBlock) scaled by a per-token sigmoid gate."""
     shared = (
         jax.nn.silu(h @ lp["w_shared_gate"]) * (h @ lp["w_shared_up"])
     ) @ lp["w_shared_down"]
+    if not cfg.shared_expert_gate:
+        return shared
     gate = jax.nn.sigmoid(
         (h @ lp["w_shared_router"]).astype(jnp.float32)
     ).astype(h.dtype)

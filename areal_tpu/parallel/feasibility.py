@@ -376,7 +376,48 @@ def keye_vl_2p0_30b_a3b_config(num_layers: int = 5) -> ModelConfig:
     )
 
 
+def kimi_linear_48b_a3b_config() -> ModelConfig:
+    """Kimi-Linear-48B-A3B-Instruct (moonshotai) as ONE chip of 32 that
+    share each layer holds it: the leading dense layer and one whole period
+    (gated delta-rule layers 1, 2, 3 and 5, latent attention layer 4), 8 of
+    the 256 sigmoid-routed experts and the shared one, an eighth of the
+    vocabulary: the cut of benchmark/configs/kimi-linear-48b-a3b.json
+    (published: 27 layers, 163,840 rows). Every width, the router's 256
+    outputs and 8 a token are as published."""
+    from areal_tpu.models.config import KDAConfig, MLAConfig
+
+    return ModelConfig(
+        vocab_size=20480,
+        hidden_size=2304,
+        intermediate_size=9216,
+        num_layers=5,
+        num_heads=32,
+        num_kv_heads=32,
+        head_dim=192,
+        max_position_embeddings=1048576,
+        rope_theta=10000,
+        rms_norm_eps=1e-5,
+        tie_word_embeddings=False,
+        family="kimi_linear",
+        num_experts=256,
+        num_experts_per_tok=8,
+        moe_intermediate_size=1024,
+        norm_topk_prob=True,
+        router_aux_loss_coef=0.0,
+        shared_expert_size=1024,
+        shared_expert_gate=False,
+        router_act="sigmoid",
+        routed_scaling_factor=2.446,
+        experts_held=8,
+        layer_kinds=("kda+dense", "kda+moe", "kda+moe", "mla+moe", "kda+moe"),
+        kda=KDAConfig(num_heads=32, head_dim=128, conv_size=4),
+        mla=MLAConfig(kv_lora_rank=512, qk_nope_head_dim=128,
+                      qk_rope_head_dim=64, v_head_dim=128),
+    )
+
+
 MODEL_CONFIGS = {
+    "kimi_linear_48b_a3b": kimi_linear_48b_a3b_config,
     "keye_vl_2p0_30b_a3b": keye_vl_2p0_30b_a3b_config,
     "olmoe_1b_7b": olmoe_1b_7b_config,
     "qwen2_0p5b": qwen2_0p5b_config,

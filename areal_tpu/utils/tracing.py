@@ -150,6 +150,10 @@ DEVICE_SCOPES = (
     # projections and scores, and the top-k selection; the core it feeds
     # stays under ``attention``
     "dsa_index", "dsa_select",
+    # gated delta-rule linear attention (ops/kda.py): the chunked rule, all
+    # passes; and what the layer computes around it (the short convolution,
+    # SiLU, l2norm, decay, beta, the gated output norm)
+    "kda_scan", "kda_prep",
 )
 
 

@@ -1,0 +1,316 @@
+"""Trainer steps of a model whose layers are of more than one kind: gated
+delta-rule linear attention (KDA) three layers in four, latent attention the
+fourth, on one chip's share of its experts and vocabulary.
+
+The traffic of ``kinds/train_steps.py`` (imported, not copied: the batches of
+``make_batches``, the whole-pass order of ``pass_order``, the recipe's
+settings through ``actor_config``, and the same window rule: whole passes in
+the seed's order, ending at the end of the first pass that passes
+``--seconds``; a traced run profiles its first ``trace_steps`` steps and
+leaves the profiler's own start and stop out of the window), as
+``kinds/train_steps_dsa.py`` takes it. ``run`` is one function there and
+cannot be imported in parts, so the window loop is restated here in the same
+order. What differs:
+
+- the reference and the arithmetic are this configuration's
+  (``benchmark/reference_kimi.py``, ``benchmark/arith_kimi.py``); the check's
+  sequences are thousands of tokens long, so the engine's state leaves the
+  chip (the weights go to the host) before the reference runs;
+- a rehearsal builds the program's tiny configuration of the family itself;
+- ``correct`` gains ``packing_invariant`` (the check's sequences read the
+  same alone and among a batch's: a state or a convolution that leaks across
+  a segment border fails it) and ``scan_exact``: given each KDA layer's q, k,
+  v, g, beta as the REFERENCE has them, rounded to the dtype the program
+  computes in, the program's timed scan (``ops/kda.kda_scan``) agrees with the
+  reference's token-by-token rule within the configuration's ``scan_limits``
+  (mean and worst absolute difference over the mean and the largest absolute
+  output); and the window's ``kda_tokens`` and ``kda_segments`` are exactly
+  what the trained lengths make them;
+- counters of the scans and of the routing to the experts held (``kda_*``,
+  ``moe_*`` from ``SPMDTrainEngine.counters()``; a program without them
+  leaves them out) and what the traced steps ask of the kernels
+  (``kda_kernel_bytes_traced``, ``moe_expert_flops_traced_per_chip``).
+"""
+
+import gc
+import math
+import time
+
+import numpy as np
+
+from benchmark import arith_kimi, harness, reference_kimi
+from benchmark.kinds.train_steps import actor_config, make_batches, pass_order
+
+FAMILY = "kimi_linear"
+REHEARSE_SCAN = {"mean": 1e-4, "worst": 1e-4}  # float32 against float32
+
+
+def tiny_configs():
+    """(program's ModelConfig, the reference's keys) of the family's tiny
+    test configuration, for a rehearsal: the keys are the program's own
+    rendering of that configuration as a ``config.json``."""
+    from areal_tpu.models.config import tiny_config
+    from areal_tpu.models.hf_io import default_hf_config_dict
+
+    t = tiny_config(FAMILY)
+    return t, default_hf_config_dict(t)
+
+
+def scan_check(ref_config, params, seqs, compute_dtype):
+    """The program's timed scan against the reference's token-by-token rule,
+    KDA layer by KDA layer on the reference's own q, k, v, g, beta (q, k, v
+    rounded to the compute dtype for both). Returns what ``scan_exact``
+    decides on."""
+    import jax
+    import jax.numpy as jnp
+
+    from areal_tpu.ops import kda
+
+    program = jax.jit(kda.kda_scan)
+    rule = jax.jit(reference_kimi.kda_rule)
+    abs_diff, abs_want, worst, largest, n = 0.0, 0.0, 0.0, 0.0, 0
+    for s in seqs:
+        for q, k, v, g, beta in reference_kimi.kda_layers(
+            ref_config, params, s.tolist()
+        ):
+            q, k, v = (jnp.asarray(a).astype(compute_dtype) for a in (q, k, v))
+            got, _ = program(
+                q[None], k[None], v[None], jnp.asarray(g)[None],
+                jnp.asarray(beta)[None], jnp.ones((1, len(s)), jnp.int32),
+            )
+            with jax.default_matmul_precision("highest"):
+                want = np.asarray(rule(
+                    *(a.astype(jnp.float32) for a in (q, k, v)), g, beta
+                ), np.float64)
+            diff = np.abs(np.asarray(got[0], np.float64) - want)
+            abs_diff += float(diff.sum())
+            abs_want += float(np.abs(want).sum())
+            worst = max(worst, float(diff.max()))
+            largest = max(largest, float(np.abs(want).max()))
+            n += diff.size
+    return {
+        "compared": n, "layers": arith_kimi.kda_layers(ref_config),
+        "mean_rel": abs_diff / abs_want if abs_want else float("nan"),
+        "worst_rel": worst / largest if largest else float("nan"),
+        "impl": kda.scan_impl(q.shape[-1], v.shape[-1]),
+    }
+
+
+def run(ctx):
+    import jax
+
+    from areal_tpu.api.io_struct import FinetuneSpec
+    from areal_tpu.engine.ppo.actor import PPOActor
+    from areal_tpu.engine.spmd_engine import SPMDTrainEngine
+    from areal_tpu.utils import data as data_utils
+
+    p = ctx.traffic
+    model_config, ref_config = (
+        tiny_configs() if ctx.rehearse
+        else (ctx.model_config, ctx.reference_config)
+    )
+    acfg = actor_config(p, ctx.rehearse)
+    engine = SPMDTrainEngine(acfg)
+    engine.initialize(
+        ft_spec=FinetuneSpec(1, p["batch_seqs"] * 10000, p["batch_seqs"]),
+        model_config=model_config, seed=ctx.seed % (2 ** 31),
+    )
+    actor = PPOActor(acfg, engine)
+    chips = int(engine.mesh.size)
+    harness.log(
+        f"trainer up: dtype={acfg.dtype} param_dtype={acfg.param_dtype} "
+        f"attn_impl={engine.attn_impl} moe_impl={engine.moe_impl} "
+        f"kda_impl={getattr(engine, 'kda_impl', None)} mesh={chips} "
+        f"max_tokens_per_mb={acfg.mb_spec.max_tokens_per_mb} "
+        f"minibatches={acfg.ppo_n_minibatches}"
+    )
+    vocab = model_config.vocab_size
+    n_kda = arith_kimi.kda_layers(ref_config)
+    batches = make_batches(p, vocab, ctx.seed)
+    # as train_steps: what is dispatched, real tokens and padding, counted
+    # from the packed rows themselves where every microbatch is packed
+    dispatched = {"padded": 0, "real": 0}
+    pack = engine._pack_for_device
+
+    def counting_pack(mb, pad_to=None):
+        packed, arrays = pack(mb, pad_to=pad_to)
+        seg = np.asarray(packed.segment_ids)  # 0 = padding
+        dispatched["padded"] += int(seg.size)
+        dispatched["real"] += int(np.count_nonzero(seg))
+        return packed, arrays
+
+    engine._pack_for_device = counting_pack
+
+    def step(b):
+        batch = {k: v for k, v in batches[b].items() if not k.startswith("_")}
+        np.random.seed(p["layout_seed"] + b)  # the same minibatch split
+        with harness.span("train.compute_advantages"):
+            batch = actor.compute_advantages(batch)
+        with harness.span("train.ppo_update"):
+            return actor.ppo_update(batch)
+
+    # the check's sequences: lengths the traffic file's, tokens the seed's
+    rng = np.random.default_rng(ctx.seed + 11)
+    seqs = [
+        rng.integers(0, vocab, size=int(n)).astype(np.int32)
+        for n in p["check_lens"]
+    ]
+
+    def logp_of(sequences):
+        batch = data_utils.pad_sequences_to_tensors(sequences)
+        batch["loss_mask"] = batch["attention_mask"].astype(np.int32)
+        return actor.compute_logp(batch)
+
+    def among_a_batch():
+        """The check's sequences among the first batch's, in full rows."""
+        others = [
+            np.asarray(batches[0]["input_ids"][i, :n], np.int32)
+            for i, n in enumerate(batches[0]["_seq_lens"])
+        ]
+        at = [(i + 1) * len(others) // (len(seqs) + 1) for i in range(len(seqs))]
+        mixed = list(others)
+        for k, i in enumerate(at):
+            mixed.insert(i + k, seqs[k])
+        got = logp_of(mixed)
+        return [got[i + k, 1:len(seqs[k])] for k, i in enumerate(at)]
+
+    for b in range(len(batches)):  # warm-up: every packed shape compiles
+        step(b)
+    dispatched.update(padded=0, real=0)
+
+    counters0 = engine.counters()
+    compile_mark = ctx.watch.mark()
+    ctx.mark_window_start()
+    traced = harness.TracedWindow(ctx.trace, ctx.trace_dir)
+    t0 = time.monotonic()
+    n_steps, tokens, flops, all_stats, step_s = 0, 0, 0.0, [], []
+    expected = {"kda_tokens": 0, "kda_segments": 0}  # from the lengths
+    at_trace_end = {}
+
+    def window_steps():
+        k = 0
+        while k == 0 or time.monotonic() - t0 < ctx.seconds:
+            yield from pass_order(len(batches), ctx.seed, k)
+            k += 1
+
+    def measured_step(b):
+        nonlocal n_steps, tokens, flops
+        t = time.monotonic()
+        all_stats.extend(step(b))
+        step_s.append(round(time.monotonic() - t, 3))
+        lens = batches[b]["_seq_lens"]
+        n_steps += 1
+        tokens += int(lens.sum())
+        flops += arith_kimi.train_step_flops(ref_config, lens)
+        expected["kda_tokens"] += n_kda * int(lens.sum())
+        expected["kda_segments"] += n_kda * len(lens)
+
+    steps = window_steps()
+    with traced:  # a traced run profiles its first steps only
+        for _ in range(p["trace_steps"] if ctx.trace else 0):
+            measured_step(next(steps))
+        at_trace_end = harness.counter_delta(engine.counters(), counters0)
+    for b in steps:
+        measured_step(b)
+    window_s = time.monotonic() - t0 - traced.profiler_s
+    compiles_in_window, loads_in_window = ctx.watch.since(compile_mark)
+    counted = harness.counter_delta(engine.counters(), counters0)
+    if not dispatched["padded"]:
+        raise RuntimeError(
+            "no microbatch went through SPMDTrainEngine._pack_for_device: "
+            "pad_share.train has lost its hook"
+        )
+    bad = [
+        s for s in all_stats
+        if not (math.isfinite(s["loss"]) and math.isfinite(s["grad_norm"])
+                and s["update_successful"] == 1.0)
+    ]
+
+    # (a) compute_logp against the plain reference and (b) against itself in
+    # other company, outside the window, on the weights the window left;
+    # then the state leaves the chip and the reference has it to itself
+    alone = logp_of(seqs)
+    packed_logp = among_a_batch()
+    mem_peak = harness.memory_peak_bytes()
+    params = jax.device_get(engine.params)
+    compute_dtype = engine.compute_dtype
+    engine.destroy()
+    del actor, engine
+    gc.collect()
+    system, ref = [], []
+    for i, s in enumerate(seqs):
+        # target-aligned: position t holds log p(token t | tokens < t)
+        system.append(np.asarray(alone[i, 1:len(s)], np.float64))
+        ref.append(reference_kimi.token_logprobs(
+            ref_config, params, s.tolist(), acfg.temperature
+        ))
+    cmp = harness.compare_logprobs(system, ref, ctx.logprob_limits)
+    packing = harness.compare_logprobs(packed_logp, system, ctx.logprob_limits)
+    scan = scan_check(ref_config, params, seqs, compute_dtype)
+    scan["limits"] = (
+        REHEARSE_SCAN if ctx.rehearse else ctx.cell.config["scan_limits"]
+    )
+    scan["window_expected"] = expected
+    scan_ok = (
+        scan["mean_rel"] <= scan["limits"]["mean"]
+        and scan["worst_rel"] <= scan["limits"]["worst"]
+        and all(counted.get(k) == v for k, v in expected.items())
+    )
+    del params
+    gc.collect()
+
+    counters = {
+        "trained_tokens": float(tokens), "train_flops": flops,
+        "train_flops_per_chip": flops / chips, "steps": float(n_steps),
+        "padded_tokens": float(dispatched["padded"]),
+        "dispatched_real_tokens": float(dispatched["real"]),
+    }
+    for k in ("kda_tokens", "kda_chunk_slots", "kda_segments",
+              "moe_assignments", "moe_max_expert_assignments"):
+        if counted.get(k):
+            counters[k] = float(counted[k])
+    if counted.get("moe_assignments"):
+        counters["moe_mean_expert_assignments"] = (
+            counted["moe_assignments"] / ref_config["num_local_experts"]
+        )
+    values = {}
+    if at_trace_end.get("kda_tokens"):
+        values["kda_kernel_bytes_traced"] = arith_kimi.kda_kernel_bytes(
+            ref_config, at_trace_end["kda_tokens"],
+            itemsize=np.dtype(compute_dtype).itemsize,
+        )
+    if at_trace_end.get("moe_assignments"):
+        values["moe_expert_flops_traced_per_chip"] = (
+            arith_kimi.expert_kernel_flops(
+                ref_config, at_trace_end["moe_assignments"],
+                acfg.gradient_checkpointing,
+            ) / chips
+        )
+    obs = harness.Observation(
+        window_s=window_s, setup_s=ctx.setup_s, counters=counters,
+        values=values, trace=traced.reduce(), peaks=ctx.peaks,
+    )
+    return harness.Outcome(
+        obs=obs, attempted=n_steps, failed=len(bad) and n_steps,
+        checks={
+            "finite_loss_and_grad_norm": not bad and n_steps > 0,
+            "no_compile_in_window": compiles_in_window == 0,
+            "reference_agrees": cmp["ok"],
+            "packing_invariant": packing["ok"],
+            "scan_exact": bool(scan_ok),
+        },
+        notes={"steps": n_steps, "passes": n_steps // len(batches),
+               "step_s": step_s, "chips": chips,
+               "minibatch_updates": len(all_stats),
+               "compiles_in_window": compiles_in_window,
+               "cache_loads_in_window": loads_in_window, "reference": cmp,
+               "packing": packing, "scan": scan,
+               "counted": counted, "counted_at_trace_end": at_trace_end,
+               "trained_tokens": tokens,
+               "dispatched_real_tokens": dispatched["real"],
+               "dispatched_padded_tokens": dispatched["padded"],
+               "profiler_s": traced.profiler_s,
+               "memory_peak_before_reference": mem_peak,
+               "grad_norm_last": all_stats[-1]["grad_norm"] if all_stats else None,
+               "live_arrays_after_destroy": len(jax.live_arrays())},
+    )

@@ -269,3 +269,77 @@ def test_sparse_attention_fwd_bwd_compiles_for_v5e(one_chip, monkeypatch):
     assert text.count("tpu_custom_call") >= 6
     assert len(re.findall(r"dsa_select_kth", text)) >= 3
     assert not re.search(rf"f32\[[0-9,]*{t},{t}[0-9,]*\]", text)
+
+
+@pytest.mark.parametrize("t", [2048, 8192])
+def test_kda_scan_fwd_bwd_compiles_for_v5e(one_chip, t):
+    """``ops/kda.kda_scan`` forward and backward at Kimi-Linear's published
+    widths (32 heads of 128 | 128) on one packed row: the chunk operands in
+    XLA, then the three state-pass kernels (``kda_fwd_h`` in the forward;
+    ``kda_fwd_h_states`` and ``kda_bwd_dh`` in the backward, which recomputes
+    the chunk states), 8 chunks a grid step, the state in VMEM.
+    ``jax.default_backend()`` is the CPU here, so the platform's choice of
+    the state pass is named in the call."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from areal_tpu.ops import kda
+
+    bf, f32 = jnp.bfloat16, jnp.float32
+
+    def loss(q, k, v, g, beta, seg):
+        out, stats = kda.kda_scan(q, k, v, g, beta, seg, impl="kernel")
+        return out.astype(f32).sum(), stats
+
+    compiled = (
+        jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4), has_aux=True))
+        .lower(
+            *[_sds((1, t, 32, 128), bf, one_chip)] * 3,
+            _sds((1, t, 32, 128), f32, one_chip),
+            _sds((1, t, 32), f32, one_chip),
+            _sds((1, t), jnp.int32, one_chip),
+        )
+        .compile()
+    )
+    text = compiled.as_text()
+    for name in ("kda_fwd_h", "kda_fwd_h_states", "kda_bwd_dh"):
+        assert re.search(rf"{name}[^_a-z]", text), name
+    # no chunk-start states in float32, and none kept beside the inputs
+    assert not re.search(rf"f32\[{t // 64},32,128,128\]", text)
+
+
+def test_latent_attention_core_compiles_for_v5e(one_chip, monkeypatch):
+    """The splash kernel at a query/key width of 192 over a value width of
+    128, 32 heads each with its own keys, on an 8192-token row, forward and
+    backward: the width pair ``ops/flash.takes_head_dim`` admits."""
+    import jax
+    import jax.numpy as jnp
+
+    from areal_tpu.ops import flash
+
+    monkeypatch.setattr(flash, "_PROBED_BLOCK", 2048)
+    flash._make_kernel.cache_clear()
+    t, bf = 8192, jnp.bfloat16
+
+    def loss(q, k, v, seg):
+        return flash.flash_segment_attention(q, k, v, seg).astype(
+            jnp.float32
+        ).sum()
+
+    try:
+        compiled = (
+            jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
+            .lower(
+                _sds((1, t, 32, 192), bf, one_chip),
+                _sds((1, t, 32, 192), bf, one_chip),
+                _sds((1, t, 32, 128), bf, one_chip),
+                _sds((1, t), jnp.int32, one_chip),
+            )
+            .compile()
+        )
+    finally:
+        flash._make_kernel.cache_clear()
+    assert flash.takes_head_dim(192, 128)
+    assert "tpu_custom_call" in compiled.as_text()

@@ -140,6 +140,10 @@ WANT = {
     # learned sparse attention: the indexer and the selection before the
     # core, which stays under attention
     "dsa_grad_step": {"attention", "dsa_index", "dsa_select", "moe_experts"},
+    # a stack of block kinds: the delta rule and what the layer computes
+    # around it, beside the latent layer's attention and the experts
+    "kda_grad_step": {"kda_scan", "kda_prep", "attention", "moe_experts",
+                      "mlp"},
 }
 
 
@@ -151,6 +155,7 @@ def lowered():
     }
     out["sparse_grad_step"] = _op_names(_train_programs("olmoe")[0][1])
     out["dsa_grad_step"] = _op_names(_train_programs("KeyeVL2")[0][1])
+    out["kda_grad_step"] = _op_names(_train_programs("kimi_linear")[0][1])
     return out
 
 
@@ -160,6 +165,7 @@ def test_program_lowers_under_its_contract_name_with_its_scopes(
 ):
     text, names = lowered[program]
     jitted = program.removeprefix("sparse_").removeprefix("dsa_")
+    jitted = jitted.removeprefix("kda_")
     assert re.search(rf"^HloModule jit_{jitted}[, ]", text, re.M)
     assert WANT[program] <= _scopes_in(names), sorted(names)[:40]
 

@@ -1,0 +1,194 @@
+"""``ops/kda.py``: the gated delta rule in chunks against the rule token by
+token (``benchmark/reference_kimi.kda_rule``), its hand-written backward
+against XLA's differentiation of the plain chunked form, the state-pass
+kernels in Pallas interpret mode against the ``lax.scan`` they are held
+equal to, segment borders inside a chunk, decays near 0 and near 1."""
+
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from areal_tpu.ops import kda
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+from benchmark import reference_kimi  # noqa: E402
+
+
+def _inputs(t, seg_lens=None, h=2, dk=16, dv=8, decay=1.0, seed=0):
+    r = np.random.default_rng(seed + t)
+    q = r.standard_normal((1, t, h, dk)).astype(np.float32)
+    k = r.standard_normal((1, t, h, dk)).astype(np.float32)
+    q /= np.linalg.norm(q, axis=-1, keepdims=True)
+    k /= np.linalg.norm(k, axis=-1, keepdims=True)
+    v = r.standard_normal((1, t, h, dv)).astype(np.float32)
+    g = -decay * np.abs(r.standard_normal((1, t, h, dk))).astype(np.float32)
+    beta = (1 / (1 + np.exp(-r.standard_normal((1, t, h))))).astype(np.float32)
+    seg = np.zeros((1, t), np.int32)
+    at = 0
+    for i, n in enumerate(seg_lens or [t]):
+        seg[0, at:at + n] = i + 1
+        at += n
+    return [jnp.asarray(x) for x in (q, k, v, g, beta, seg)]
+
+
+def _rule(q, k, v, g, beta, seg):
+    """The reference's rule, one sequence of the row after the other."""
+    seg = np.asarray(seg[0])
+    out = np.zeros(q.shape[1:3] + v.shape[-1:], np.float32)
+    for s in np.unique(seg[seg > 0]):
+        at = np.flatnonzero(seg == s)
+        out[at] = reference_kimi.kda_rule(
+            *(x[0, at[0]:at[-1] + 1] for x in (q, k, v, g, beta))
+        )
+    return out
+
+
+CASES = [
+    pytest.param(1, None, id="t1"),
+    pytest.param(63, None, id="t63"),
+    pytest.param(64, None, id="t64"),
+    pytest.param(65, None, id="t65"),
+    pytest.param(200, None, id="t200"),
+    pytest.param(200, [70, 90], id="two-segments-and-padding"),
+    pytest.param(200, [30, 45, 100], id="three-segments-borders-in-a-chunk"),
+]
+
+
+@pytest.mark.parametrize("impl", ["xla", "kernel"])
+@pytest.mark.parametrize("t,seg_lens", CASES)
+def test_chunked_form_equals_the_rule_token_by_token(t, seg_lens, impl):
+    a = _inputs(t, seg_lens)
+    got, stats = kda.kda_scan(*a, impl=impl, interpret=True)
+    np.testing.assert_allclose(got[0], _rule(*a), atol=2e-6)
+    real = sum(seg_lens or [t])
+    assert [int(x) for x in stats] == [
+        real, -(-t // kda.CHUNK) * kda.CHUNK, len(seg_lens or [t])
+    ]
+    assert not np.asarray(got[0, real:]).any()  # padding reads zero
+
+
+def _loss(o):
+    return (o * jnp.cos(jnp.arange(o.size).reshape(o.shape) * 0.1)).sum()
+
+
+@pytest.mark.parametrize("t,seg_lens", [CASES[0], CASES[3], CASES[6]])
+def test_hand_written_backward_equals_xlas_of_the_plain_chunked_form(
+    t, seg_lens
+):
+    a = _inputs(t, seg_lens)
+
+    def grads(**kw):
+        return jax.grad(
+            lambda *x: _loss(kda.kda_scan(*x, a[5], **kw)[0]),
+            argnums=(0, 1, 2, 3, 4),
+        )(*a[:5])
+
+    want = grads(impl="xla", custom_backward=False)
+    rule = jax.grad(
+        lambda *x: _loss(jnp.asarray(_rule_traced(*x, a[5]))),
+        argnums=(0, 1, 2, 3, 4),
+    )(*a[:5])
+    for name, kw in (("scan", dict(impl="xla")),
+                     ("kernel", dict(impl="kernel", interpret=True))):
+        for got, w, r in zip(grads(**kw), want, rule):
+            scale = float(jnp.abs(w).max()) + 1e-9
+            assert float(jnp.abs(got - w).max()) / scale < 1e-5, name
+            assert float(jnp.abs(got - r).max()) / scale < 1e-4, name
+
+
+def _rule_traced(q, k, v, g, beta, seg):
+    """``_rule`` in traceable form: the state reset where the segment
+    changes, for ``jax.grad`` of the token-by-token rule."""
+    hh, dk = q.shape[2:]
+
+    def step(carry, x):
+        s, prev = carry
+        q_t, k_t, v_t, g_t, b_t, seg_t = x
+        s = jnp.where(seg_t != prev, 0.0, s) * jnp.exp(g_t)[:, :, None]
+        read = jnp.einsum("hk,hkv->hv", k_t, s)
+        s = s + (b_t[:, None] * k_t)[:, :, None] * (v_t - read)[:, None, :]
+        o = jnp.einsum("hkv,hk->hv", s, q_t) * dk ** -0.5
+        return (s, seg_t), jnp.where(seg_t > 0, o, 0.0)
+
+    s0 = jnp.zeros((hh, dk, v.shape[-1]), jnp.float32)
+    xs = tuple(x[0] for x in (q, k, v, g, beta, seg))
+    return jax.lax.scan(step, (s0, jnp.int32(-1)), xs)[1][None]
+
+
+@pytest.mark.parametrize("decay", [1e-4, 20.0, 80.0])
+def test_decays_near_one_and_near_zero_neither_overflow_nor_clamp(decay):
+    """exp(g) from 0.9999 down to exp(-80 |n|), which underflows to an
+    exact zero: every exponent the chunked form takes is a difference that
+    is <= 0, so nothing overflows, and nothing is clamped: the result is
+    the rule's."""
+    a = _inputs(130, decay=decay)
+    for impl in ("xla", "kernel"):
+        got, _ = kda.kda_scan(*a, impl=impl, interpret=True)
+        assert np.isfinite(np.asarray(got)).all()
+        np.testing.assert_allclose(got[0], _rule(*a), atol=5e-6)
+    grads = jax.grad(
+        lambda *x: _loss(kda.kda_scan(*x, a[5], impl="xla")[0]),
+        argnums=(0, 1, 2, 3, 4),
+    )(*a[:5])
+    assert all(np.isfinite(np.asarray(x)).all() for x in grads)
+
+
+def test_a_long_row_runs_its_operands_in_blocks_of_chunks(monkeypatch):
+    """More chunks than ``_PREP_CHUNKS`` go through ``_in_blocks``' map,
+    forward and backward: the same numbers as in one piece."""
+    a = _inputs(4 * kda.CHUNK, [100, 140], h=1, dk=8, dv=8)
+
+    def run():
+        return jax.value_and_grad(
+            lambda *x: _loss(kda.kda_scan(*x, a[5], impl="xla")[0]),
+            argnums=(0, 1, 2, 3, 4),
+        )(*a[:5])
+
+    whole = run()
+    monkeypatch.setattr(kda, "_PREP_CHUNKS", 2)
+    blocked = run()
+    for x, y in zip(jax.tree_util.tree_leaves(whole),
+                    jax.tree_util.tree_leaves(blocked)):
+        np.testing.assert_allclose(x, y, rtol=1e-5, atol=1e-6)
+
+
+def test_unit_lower_inverse_is_exact_where_powers_would_not_be():
+    """Entries near 1 (neighbouring keys nearly parallel, beta near 1): the
+    substitution stays at rounding error where the Neumann series' powers
+    grow like binomials."""
+    r = np.random.default_rng(0)
+    n = np.tril(0.9 + 0.1 * r.random((kda.CHUNK, kda.CHUNK)), -1)
+    inv = np.asarray(kda._unit_lower_inverse(jnp.asarray(n, jnp.float32)))
+    want = np.linalg.inv(np.eye(kda.CHUNK) + n)
+    assert np.abs(inv - want).max() < 1e-4 * np.abs(want).max()
+
+
+def test_scan_impl_is_the_kernel_on_a_tpu_for_whole_lane_tiles(monkeypatch):
+    assert kda.scan_impl(128, 128) == "xla"  # the CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert kda.scan_impl(128, 128) == "kernel"
+    assert kda.scan_impl(16, 16) == "xla"  # a test-sized head
+    assert kda.scan_impl() == "kernel"
+
+
+def test_the_op_runs_per_chip_on_its_own_rows_under_an_fsdp_mesh():
+    from areal_tpu.api.cli_args import ParallelismConfig
+    from areal_tpu.parallel import mesh as mesh_lib
+
+    n = min(4, jax.device_count())
+    mesh = mesh_lib.make_mesh(ParallelismConfig(fsdp_parallel_size=n))
+    rows = [_inputs(70, [30, 25], seed=i) for i in range(n)]
+    a = [jnp.concatenate(x, axis=0) for x in zip(*rows)]
+    want, want_stats = kda.kda_scan(*a)
+    got, stats = jax.jit(kda.make_kda_scan(mesh) or kda.kda_scan)(*a)
+    np.testing.assert_allclose(got, want, atol=1e-6)
+    assert [int(x) for x in stats] == [int(x) for x in want_stats]
+    assert kda.make_kda_scan(mesh_lib.make_mesh(ParallelismConfig())) is None
+    if n > 1:
+        seq = mesh_lib.make_mesh(ParallelismConfig(seq_parallel_size=n))
+        with pytest.raises(ValueError, match="no sequence-parallel form"):
+            kda.make_kda_scan(seq)

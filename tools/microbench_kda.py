@@ -1,0 +1,121 @@
+"""The gated delta rule's chunked scan alone, on one chip: the state pass as
+the Pallas kernels (``kda_fwd_h``, ``kda_fwd_h_states``, ``kda_bwd_dh``)
+against the ``lax.scan`` they are held equal to (``ops/kda.py``), forward and
+forward + backward, on one packed row at the published widths (32 heads of
+128 | 128); the chunk operands (``_prep``, shared by both) alone; and the two
+forms' agreement on the chip.
+
+    python -m tools.microbench_kda [--tokens 8192] [--seqs 3]
+
+Host clock around ``block_until_ready``, the best of ``RUNS``. ``ops/kda.py``
+resolves ``scan_impl`` to what this found faster (PERF.md, PR 33).
+"""
+
+import argparse
+import json
+import time
+
+import areal_tpu  # noqa: F401 — the scoped-VMEM flag, before the backend
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from areal_tpu.ops import kda
+
+RUNS = 3
+
+
+def _best_ms(fn):
+    out = jax.block_until_ready(fn())  # compiles
+    best = float("inf")
+    for _ in range(RUNS):
+        t0 = time.perf_counter()
+        jax.block_until_ready(fn())
+        best = min(best, time.perf_counter() - t0)
+    return out, best * 1e3
+
+
+def main():
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--tokens", type=int, default=8192)
+    ap.add_argument("--seqs", type=int, default=3)
+    ap.add_argument("--heads", type=int, default=32)
+    ap.add_argument("--dim", type=int, default=128)
+    args = ap.parse_args()
+    t, h, d = args.tokens, args.heads, args.dim
+    print(json.dumps({"device": str(jax.devices()[0].device_kind)}))
+    ks = jax.random.split(jax.random.PRNGKey(0), 5)
+
+    def unit(key):
+        x = jax.random.normal(key, (1, t, h, d), jnp.float32)
+        return x * jax.lax.rsqrt((x * x).sum(-1, keepdims=True))
+
+    q, k = unit(ks[0]).astype(jnp.bfloat16), unit(ks[1]).astype(jnp.bfloat16)
+    v = (jax.random.normal(ks[2], (1, t, h, d)) * 0.1).astype(jnp.bfloat16)
+    g = -jnp.exp(jax.random.uniform(ks[3], (1, t, h, d), minval=-7, maxval=0.5))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (1, t, h)))
+    # a few sequences and a padded tail, borders inside chunks
+    real = t - 200
+    cuts = np.linspace(0, real, args.seqs + 1).astype(int) + np.arange(
+        args.seqs + 1
+    ) * 7 % 61
+    seg = np.zeros((1, t), np.int32)
+    for i in range(args.seqs):
+        seg[0, cuts[i]:min(cuts[i + 1], real)] = i + 1
+    seg = jnp.asarray(seg)
+    a = (q, k, v, g, beta, seg)
+    # off the chip the kernels run in Pallas interpret mode: the control
+    # flow's rehearsal, never a time
+    interp = jax.default_backend() != "tpu"
+
+    def forward(impl):
+        return jax.jit(lambda *x: kda.kda_scan(*x, impl=impl, interpret=interp)[0])
+
+    def both(impl):
+        return jax.jit(jax.grad(
+            lambda *x: kda.kda_scan(*x, seg, impl=impl, interpret=interp)[0].astype(
+                jnp.float32
+            ).sum(),
+            argnums=(0, 1, 2, 3, 4),
+        ))
+
+    out = {}
+    for impl in ("kernel", "xla"):
+        fn = forward(impl)
+        out[impl], ms = _best_ms(lambda: fn(*a))
+        print(json.dumps({"pass": "forward", "impl": impl, "ms": ms}))
+        gfn = both(impl)
+        grads, ms = _best_ms(lambda: gfn(*a[:5]))
+        out[impl + "_grads"] = grads
+        print(json.dumps({"pass": "forward+backward", "impl": impl, "ms": ms}))
+    diff = jnp.abs(
+        out["kernel"].astype(jnp.float32) - out["xla"].astype(jnp.float32)
+    )
+    worst_grad = max(
+        float(jnp.abs(x.astype(jnp.float32) - y.astype(jnp.float32)).max()
+              / (jnp.abs(y.astype(jnp.float32)).max() + 1e-9))
+        for x, y in zip(out["kernel_grads"], out["xla_grads"])
+    )
+    print(json.dumps({
+        "kernel_vs_xla_worst_abs": float(diff.max()),
+        "output_abs_mean": float(jnp.abs(out["xla"].astype(jnp.float32)).mean()),
+        "grads_worst_rel": worst_grad,
+    }))
+    chunks = [kda._to_chunks(x) for x in (q, k, v, g)]
+    beta_c = kda._to_chunks(beta[..., None])[..., 0]
+    seg_c = seg.reshape(1, -1, kda.CHUNK).transpose(1, 0, 2)
+    prep = jax.jit(lambda *x: kda._operands(*x))
+    ops, ms = _best_ms(lambda: prep(*chunks, beta_c, seg_c))
+    print(json.dumps({"pass": "chunk operands (_prep)", "ms": ms}))
+    for impl, fn in (
+        ("kernel", lambda o: kda._state_fwd_kernel(o, interpret=interp)),
+        ("xla", kda._state_fwd_xla),
+    ):
+        jit = jax.jit(fn)
+        _, ms = _best_ms(lambda: jit(ops))
+        print(json.dumps({"pass": "state pass forward", "impl": impl, "ms": ms}))
+
+
+if __name__ == "__main__":
+    main()
