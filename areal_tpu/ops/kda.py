@@ -18,20 +18,37 @@ running sum of ``g`` inside a chunk and ``S0`` the state the chunk starts on,
     O   = (q * exp(G) / sqrt(dk)) S0 + Aqk U
     S'  = Diag(exp(G_last)) S0 + (k * exp(G_last - G))^T U
 
-Two steps. ``_prep`` makes a chunk's ``Wk, Wv, Qb, Kt, Aqk, d`` from its own
-tokens, every chunk at once (plain XLA: batched products and elementwise
-work). The STATE PASS then walks the chunks of a row in order, the only
-sequential part: ``U, O, S'`` above, five small matmuls a chunk. It has two
-forms, resolved from the platform as ``ops/moe.grouped_impl`` is: a
-``lax.scan`` over chunks (the CPU's path and the form the kernels are held
-equal to) and the Pallas kernels ``kda_fwd_h`` / ``kda_bwd_dh`` (a TPU's: the
-state stays in VMEM over a row, float32; a scan's carry goes through HBM
-every chunk). Both have the same hand-written backward (``_state_bwd_*``):
-the op is one ``jax.custom_vjp`` that keeps its INPUTS only, and the backward
-recomputes the chunk-start states (in the compute dtype, as the forward's
-matmuls read them) instead of keeping [T / 64, H, dk, dv] a layer across the
-step; ``_prep``'s own backward is XLA's, run over blocks of chunks so that
-its [16, 16, dk] temporaries a sub-block stay bounded.
+Two steps. The OPERANDS: a chunk's ``Wk, Wv, Qb, Kt, Aqk, d`` from its own
+tokens, every chunk independent of the others. The STATE PASS then walks the
+chunks of a row in order, the only sequential part: ``U, O, S'`` above, five
+small matmuls a chunk. Each step has two forms, resolved together from the
+platform as ``ops/moe.grouped_impl`` is (``scan_impl``). ``"xla"``: ``_prep``
+(batched products and elementwise work, differentiated by XLA over blocks of
+chunks so that its [16, 16, dk] temporaries a sub-block stay bounded) and a
+``lax.scan`` over chunks: the CPU's path and the form the kernels are held
+equal to. ``"kernel"``, a TPU's: ``chunk_ops_fwd`` / ``chunk_ops_bwd`` make the
+operands and pull their cotangents back with a chunk's exponent differences
+in VMEM and nowhere else, each exponential evaluated once and shared by every
+product and cotangent (as XLA tensors they are 8 MB a head and 16 chunks, and
+XLA's differentiation of them is one reduction a cotangent, the exponentials
+evaluated again in each); ``kda_fwd_h`` / ``kda_bwd_dh`` walk the chunks with
+the state in VMEM over a row, float32 (a scan's carry goes through HBM every
+chunk). The op is one ``jax.custom_vjp`` that keeps its INPUTS only: the
+backward makes the operands again, recomputes the chunk-start states (in the
+compute dtype, as the forward's matmuls read them) instead of keeping
+[T / 64, H, dk, dv] a layer across the step, runs the state pass's
+hand-written backward (``_state_bwd_*``) and hands its cotangents to the
+operands' backward. Only the three state-pass kernels are named ``kda_*``:
+the benchmark's roofline of the state pass reads that prefix.
+
+What a kernel costs a program's SET-UP (PERF.md, PR 34 and PR 35): a Pallas
+call is traced and lowered to its Mosaic module in Python at every place it
+occurs in a program, before the compile cache is asked, on a warm start as on
+a cold one. So each kernel call sits behind a jitted function (a module lowers
+one once for equal arguments, however many layers call it), and the loops
+inside the operand kernels that run 16 times are ``lax.fori_loop``s of four
+trips: a body of a few hundred operations. ``tools/setup_cost.py`` reads
+both off the chip, and a test counts the bodies in a lowered ``grad_step``.
 
 Exponents: per-channel decay makes the usual factorisation ``k_j exp(-G_j)``
 unbounded inside a chunk. Every exponent here is a DIFFERENCE that is <= 0:
@@ -40,9 +57,9 @@ from ``G_i - G_j`` itself; between sub-blocks they factor through the
 row sub-block's start (``exp(G_i - G_start) * exp(G_start - G_j)``, both
 <= 0). No clamp anywhere.
 
-Packed rows: a sequence's first token resets the state. ``_prep`` folds that
-into what it emits: ``A`` and ``Aqk`` are cut where the segment ids differ,
-``S0`` reaches only the tokens of the sequence the last chunk ended in
+Packed rows: a sequence's first token resets the state. The operands fold
+that into what they hold: ``A`` and ``Aqk`` are cut where the segment ids
+differ, ``S0`` reaches only the tokens of the sequence the last chunk ended in
 (``Wk``, ``Qb`` zeroed elsewhere), and ``S'`` keeps only what belongs to the
 chunk's last token's sequence (``Kt``, ``d``). Padding (segment 0) takes no
 part: its ``q, k, v, beta, g`` are zeroed on the way in. So the state pass
@@ -73,6 +90,18 @@ _PREP_CHUNKS = 16
 # chunks one grid step of a kernel walks (512 tokens: a grid step costs
 # ~0.35 us, a chunk's arithmetic ~0.1 us)
 _KERNEL_CHUNKS = 8
+# chunks one grid step of an operand kernel takes TOGETHER, as one leading
+# axis of every value: a chunk's operands are chains of dependent steps (15
+# rows of substitution, 5 products at ``HIGHEST``), and alone a chunk waits
+# out each step's latency
+_OPS_CHUNKS = 8
+# steps of an operand kernel's 16-step loops (a sub-block's tokens, the
+# substitution's rows) taken in one straight line a trip. A layer's scan,
+# forward + backward at 8,192 tokens (PERF.md, PR 35): 29.9 ms at 1, 24.7 at
+# 2, 22.3 at 4, 20.9 at 8, 19.1 at 16, where the line is the whole loop and
+# a kernel costs three times as long to trace and lower (Mosaic takes a
+# ``fori_loop``'s own ``unroll`` only as 1 or all)
+_UNROLL = 4
 _HI = jax.lax.Precision.HIGHEST
 
 
@@ -85,9 +114,10 @@ class KDAStats(NamedTuple):
 
 
 def scan_impl(dk: Optional[int] = None, dv: Optional[int] = None) -> str:
-    """The state pass this process runs: ``"kernel"`` (``kda_fwd_h`` /
+    """The form this process runs, operands and state pass alike:
+    ``"kernel"`` (``chunk_ops_fwd`` / ``chunk_ops_bwd``, ``kda_fwd_h`` /
     ``kda_bwd_dh``) on a TPU for head widths that are whole lane tiles,
-    ``"xla"`` (the ``lax.scan``) elsewhere."""
+    ``"xla"`` (``_prep`` and the ``lax.scan``) elsewhere."""
     if jax.default_backend() != "tpu":
         return "xla"
     if dk is not None and (dk % 128 or dv % 128):
@@ -231,15 +261,19 @@ def _in_blocks(fn, arrays):
 
 
 def _seg_in(seg):
-    """[N, B]: the segment of the token before each chunk."""
-    first = jnp.full_like(seg[:1, :, -1], -1)
-    return jnp.concatenate([first, seg[:-1, :, -1]], axis=0)
+    """[N, B]: the segment of the token before each chunk (-1 before a row's
+    first). Made from the input alone: a value that depends on nothing is
+    hoisted out of a scan over layers, and the jitted kernel call it sits in
+    is then split there and lowered once more."""
+    last = jax.lax.index_in_dim(seg, CHUNK - 1, axis=2, keepdims=False)
+    return jnp.concatenate([last[:1] * 0 - 1, last[:-1]], axis=0)
 
 
 # ------------------------------------------------------------ state pass --
-def _dot(a, b, dims):
+def _dot(a, b, dims, precision=None):
     return jax.lax.dot_general(
-        a, b, (dims, ((), ())), preferred_element_type=jnp.float32
+        a, b, (dims, ((), ())), precision=precision,
+        preferred_element_type=jnp.float32,
     )
 
 
@@ -312,8 +346,7 @@ def _state_bwd_xla(ops, states, do):
     return grads
 
 
-def _kernel_chunks(n: int) -> int:
-    nb = _KERNEL_CHUNKS
+def _kernel_chunks(n: int, nb: int = _KERNEL_CHUNKS) -> int:
     while n % nb:
         nb //= 2
     return nb
@@ -369,6 +402,7 @@ def _flat(x):  # [N, B, H, ...] -> [N, B*H, ...]
     return x.reshape(x.shape[0], -1, *x.shape[3:])
 
 
+@functools.partial(jax.jit, static_argnames=("save_states", "interpret"))
 def _state_fwd_kernel(ops, save_states=False, interpret=False):
     wk, wv = ops[0], ops[1]
     n, b, h, c, dk = wk.shape
@@ -399,6 +433,7 @@ def _state_fwd_kernel(ops, save_states=False, interpret=False):
     return tuple(out) if save_states else out[0]
 
 
+@functools.partial(jax.jit, static_argnames="interpret")
 def _state_bwd_kernel(ops, states, do, interpret=False):
     wk, wv = ops[0], ops[1]
     n, b, h, c, dk = wk.shape
@@ -434,12 +469,374 @@ def _state_bwd_kernel(ops, states, do, interpret=False):
 
 def _state_fwd(ops, impl, interpret, save_states=False):
     if impl == "kernel":
-        return _state_fwd_kernel(ops, save_states, interpret)
+        return _state_fwd_kernel(
+            ops, save_states=save_states, interpret=interpret
+        )
     return _state_fwd_xla(ops, save_states)
 
 
+# ------------------------------------------- the operands as kernels --
+def _bdot(a, b, dims, precision=None):
+    """``_dot`` a chunk: the chunks' matmuls are independent of each other."""
+    return jnp.stack([
+        _dot(a[i], b[i], dims, precision) for i in range(a.shape[0])
+    ])
+
+
+def _loop(body, carry):
+    """``carry = body(i, carry)`` for the ``SUB`` tokens of a sub-block:
+    ``_UNROLL`` steps a trip in one straight line, ``SUB / _UNROLL`` trips."""
+
+    def trip(i, carry):
+        for u in range(_UNROLL):
+            carry = body(i * _UNROLL + u, carry)
+        return carry
+
+    return jax.lax.fori_loop(0, SUB // _UNROLL, trip, carry)
+
+
+def _lane(x, t):
+    """Lane ``t`` (traced) of ``x`` [.., C] as [.., 1]."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape[-2:], 1)
+    return jnp.sum(jnp.where(lane == t, x, 0.0), axis=-1, keepdims=True)
+
+
+def _rows_of(x):  # each sub-block's SUB rows: [nb, C, d] -> NSUB x [nb, SUB, d]
+    return [x[:, a * SUB:(a + 1) * SUB] for a in range(_NSUB)]
+
+
+def _block_parts(q, k, v, g, beta, marks, g_scr, k_scr, n_scr, e_scr=None):
+    """``nb`` chunks of one head inside a kernel: ``_prep``'s values
+    in VMEM, with the chunks as a leading axis, so that each step of a chain
+    of dependent steps (the substitution's rows, the block products) is
+    taken for all of them at once: alone a chunk waits out every step's
+    latency. The refs: ``q, k, g`` [nb, 1, C, dk], ``v`` [.., dv]; ``beta``
+    [nb, 1, 1, C]; ``marks`` [nb, 1, 4, C] float32 rows: the segment ids,
+    and as 0 | 1 the tokens S0 reaches, the tokens that reach S', whether
+    the state carries (``_marks``). Tokens lie on sublanes and channels on
+    lanes, so a token's row is read back from scratch broadcast over the
+    sublanes (``g_scr``, ``k_scr`` [nb, C, dk], ``n_scr`` [nb, C, C]
+    float32). ``e_scr`` [nb, C * SUB, dk] keeps every exp(G_i - G_j) of the
+    sub-blocks for the backward."""
+    f32, dt = jnp.float32, q.dtype
+    nb, _, c, dk = q.shape
+    scale = dk ** -0.5
+    ri = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    ci = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    eye = ri == ci
+
+    def column(row):  # [nb, 1, C] -> [nb, C, 1]
+        return jnp.sum(jnp.where(eye, row, 0.0), axis=-1, keepdims=True)
+
+    marks = marks[:, 0]
+    seg_row = marks[:, 0:1]
+    seg_col = column(seg_row)
+    valid = seg_col > 0
+    from_in = column(marks[:, 1:2]) > 0
+    to_out = column(marks[:, 2:3]) > 0
+    qf = jnp.where(valid, q[:, 0].astype(f32), 0.0)
+    kf = jnp.where(valid, k[:, 0].astype(f32), 0.0)
+    vf = jnp.where(valid, v[:, 0].astype(f32), 0.0)
+    g = jnp.where(valid, g[:, 0], 0.0)
+    beta = jnp.where(valid, column(beta[:, 0]), 0.0)  # [nb, C, 1]
+    tril = jnp.broadcast_to(jnp.where(ri >= ci, 1.0, 0.0), (nb, c, c))
+    big_g = _bdot(tril, g, _NN, _HI)  # the running sum
+    g_scr[...] = big_g
+    k_scr[...] = kf
+
+    # --- pairs: inside a sub-block the differences themselves, a column of
+    # the pair matrices a token; between sub-blocks through the start
+    tok = jax.lax.broadcasted_iota(jnp.int32, (c, 1), 0)
+    sub_i = jax.lax.broadcasted_iota(jnp.int32, (SUB, 1), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (SUB, c), 1)
+    g_a, k_a, q_a = _rows_of(big_g), _rows_of(kf), _rows_of(qf)
+    rows, cols, kk, qk = [], [None], [], []
+    for a in range(_NSUB):
+        start = big_g[:, a * SUB:a * SUB + 1] - g[:, a * SUB:a * SUB + 1]
+        row = jnp.exp(g_a[a] - start)  # [nb, SUB, dk], <= 1
+        rows.append(row)
+        if a:
+            col = jnp.exp(jnp.where(tok < a * SUB, start - big_g, -jnp.inf))
+            cols.append(col)
+            off = _bdot(
+                jnp.concatenate([k_a[a] * row, q_a[a] * row], 1).astype(dt),
+                (kf * col).astype(dt), _NT,
+            )  # [nb, 2 SUB, C]
+            kk_a, qk_a = off[:, :SUB], off[:, SUB:]
+        else:
+            kk_a = qk_a = jnp.zeros((nb, SUB, c), f32)
+
+        def pair(j, acc, a=a):
+            t = a * SUB + j
+            e = jnp.exp(jnp.where(
+                sub_i >= j, g_a[a] - g_scr[:, pl.ds(t, 1), :], -jnp.inf
+            ))  # [nb, SUB, dk]: exp(G_i - G_t), rows i >= t
+            if e_scr is not None:
+                e_scr[:, pl.ds(pl.multiple_of(t * SUB, SUB), SUB), :] = e
+            kt_e = k_scr[:, pl.ds(t, 1), :] * e
+            return tuple(
+                jnp.where(
+                    lane == t, jnp.sum(x * kt_e, axis=-1, keepdims=True), y
+                )
+                for x, y in zip((k_a[a], q_a[a]), acc)
+            )
+
+        kk_a, qk_a = _loop(pair, (kk_a, qk_a))
+        kk.append(kk_a)
+        qk.append(qk_a)
+    same = seg_col == seg_row
+    strict = same & (ri > ci)
+    lower = same & (ri >= ci)
+    a_kk = jnp.where(strict, jnp.concatenate(kk, 1), 0.0)
+    a_qk = jnp.where(lower, jnp.concatenate(qk, 1), 0.0) * scale
+
+    # --- T^T = (I + Diag(beta) A)^-T: ``_unit_lower_inverse`` transposed.
+    # The diagonal sub-blocks a row a step, all four at once (x[k, i] is
+    # the inverse's [i, k]); then the blocks under them: with D the
+    # diagonal blocks' inverse and L the rest of N, T = D - D L T three
+    # times over is the block substitution (L's fourth power is zero)
+    n = beta * a_kk
+    n_scr[...] = n
+    blk = (ri // SUB) == (ci // SUB)
+    eye_f = jnp.broadcast_to(jnp.where(eye, 1.0, 0.0), (nb, c, c))
+
+    def substitute(i, x):
+        n_row = jnp.concatenate([
+            jnp.broadcast_to(
+                n_scr[:, pl.ds(a * SUB + i, 1), :], (nb, SUB, c)
+            )
+            for a in range(_NSUB)
+        ], 1)
+        hit = jnp.sum(n_row * x, axis=-1, keepdims=True)  # x is 0 off blk
+        return jnp.where(blk & (ci % SUB == i), eye_f - hit, x)
+
+    x = _loop(substitute, eye_f)  # row 0 finds nothing above it
+    m_t = _bdot(jnp.where(blk, 0.0, n), x, _TN, _HI)  # (D L)^T
+    t_t = x
+    for _ in range(_NSUB - 1):
+        t_t = x - _bdot(t_t, m_t, _NN, _HI)
+
+    e_g = jnp.exp(big_g)
+    g_last = big_g[:, c - 1:c]
+    e_out = jnp.exp(g_last - big_g)
+    kbar = jnp.where(from_in, kf * e_g, 0.0)
+    t_c = t_t.astype(dt)
+    return dict(
+        qf=qf, kf=kf, vf=vf, beta=beta, valid=valid, from_in=from_in,
+        to_out=to_out, strict=strict, eye=eye, rows=rows, cols=cols,
+        a_kk=a_kk, a_qk=a_qk, t_c=t_c, e_g=e_g, e_out=e_out, kbar=kbar,
+        wk=_bdot(t_c, (beta * kbar).astype(dt), _TN),
+        wv=_bdot(t_c, (beta * vf).astype(dt), _TN),
+        qb=jnp.where(from_in, qf * e_g, 0.0) * scale,
+        kt=jnp.where(to_out, kf * e_out, 0.0),
+        d=jnp.where(column(marks[:, 3:4]) > 0, e_g, 0.0)[:, c - 1:c],
+    )
+
+
+def _store_rows(ref, x):
+    """[nb, 1, d] into a ref [nb, 1, 1, d], a chunk at a time (all at once
+    is more than the compiler takes)."""
+    for i in range(x.shape[0]):
+        ref[i, 0] = x[i]
+
+
+def _ops_fwd_kernel(q, k, v, g, beta, marks, wk, wv, qb, kt, aqk, d,
+                    g_scr, k_scr, n_scr):
+    p = _block_parts(q, k, v, g, beta, marks, g_scr, k_scr, n_scr)
+    for ref, name in ((wk, "wk"), (wv, "wv"), (qb, "qb"), (kt, "kt"),
+                      (aqk, "a_qk")):
+        ref[:, 0] = p[name].astype(ref.dtype)
+    _store_rows(d, p["d"])
+
+
+def _ops_bwd_kernel(q, k, v, g, beta, d_wk, d_wv, d_qb, d_kt, d_aqk, d_d,
+                    marks, d_q, d_k, d_v, d_g, d_beta,
+                    g_scr, k_scr, n_scr, e_scr, kj_scr):
+    """``_prep``'s backward by hand, on ``_block_parts``' values: from the
+    operands' cotangents to those of ``q, k, v, g, beta``, every exponential
+    as the forward part left it (``e_scr``, ``rows``, ``cols``, ``e_g``,
+    ``e_out``). ``kj_scr`` [nb, C, dk] gathers a token's sums as a key."""
+    f32, dt = jnp.float32, q.dtype
+    p = _block_parts(q, k, v, g, beta, marks, g_scr, k_scr, n_scr, e_scr)
+    nb, c, dk = p["kf"].shape
+    scale = dk ** -0.5
+    kf, qf, beta_c = p["kf"], p["qf"], p["beta"]
+    # through Wk = T (beta kbar), Wv = T (beta v) to N = Diag(beta) A, with
+    # T = (I + N)^-1: dT = dWk (beta kbar)^T + dWv (beta v)^T and
+    # dN = -T^T dT T^T, so dN = -(T^T dWk) Wk^T - (T^T dWv) Wv^T
+    d_xk = _bdot(p["t_c"], d_wk[:, 0], _NN)
+    d_xv = _bdot(p["t_c"], d_wv[:, 0], _NN)
+    d_n = -jnp.where(
+        p["strict"],
+        _bdot(jnp.concatenate([d_xk, d_xv], -1),
+              jnp.concatenate([p["wk"], p["wv"]], -1), _NT, _HI),
+        0.0,
+    )
+    g_beta = (
+        jnp.sum(d_n * p["a_kk"], axis=-1, keepdims=True)
+        + jnp.sum(d_xk * p["kbar"], axis=-1, keepdims=True)
+        + jnp.sum(d_xv * p["vf"], axis=-1, keepdims=True)
+    )
+    d_kbar = beta_c * d_xk
+    pm = beta_c * d_n  # dA, j < i
+    d_aqk_f = d_aqk[:, 0].astype(f32)
+    rm = jnp.where(p["strict"], d_aqk_f, 0.0) * scale  # dAqk, j < i
+    r_ii = jnp.sum(
+        jnp.where(p["eye"], d_aqk_f, 0.0), axis=-1, keepdims=True
+    ) * scale
+    # the pairs, from the forward's one evaluation of each exponential
+    lane = jax.lax.broadcasted_iota(jnp.int32, (SUB, c), 1)
+    k_a, q_a = _rows_of(kf), _rows_of(qf)
+    p_a, r_a = _rows_of(pm), _rows_of(rm)
+    dk_i, dq_i = [], []
+    dk_off = jnp.zeros_like(kf)
+    for a in range(_NSUB):
+        row = p["rows"][a]
+        if a:
+            col = p["cols"][a]
+            before = lane < a * SUB
+            pr = jnp.concatenate([
+                jnp.where(before, p_a[a], 0.0), jnp.where(before, r_a[a], 0.0)
+            ], 1).astype(dt)  # [nb, 2 SUB, C]
+            at_row = _bdot(pr, (kf * col).astype(dt), _NN)  # [nb, 2 SUB, dk]
+            dk_a, dq_a = at_row[:, :SUB] * row, at_row[:, SUB:] * row
+            dk_off += col * _bdot(
+                pr,
+                jnp.concatenate([k_a[a] * row, q_a[a] * row], 1).astype(dt),
+                _TN,
+            )
+        else:
+            dk_a = dq_a = jnp.zeros((nb, SUB, dk), f32)
+
+        def pair(j, acc, a=a):
+            t = a * SUB + j
+            e = e_scr[:, pl.ds(pl.multiple_of(t * SUB, SUB), SUB), :]
+            kt_e = k_scr[:, pl.ds(t, 1), :] * e
+            p_t, r_t = _lane(p_a[a], t), _lane(r_a[a], t)
+            kj_scr[:, pl.ds(t, 1), :] = jnp.sum(
+                (p_t * k_a[a] + r_t * q_a[a]) * e, axis=1, keepdims=True
+            )
+            return acc[0] + p_t * kt_e, acc[1] + r_t * kt_e
+
+        dk_a, dq_a = _loop(pair, (dk_a, dq_a))
+        dk_i.append(dk_a)
+        dq_i.append(dq_a)
+    dk_i, dq_i = jnp.concatenate(dk_i, 1), jnp.concatenate(dq_i, 1)
+    dk_j = kj_scr[...] + dk_off
+    e_g, e_out = p["e_g"], p["e_out"]
+    from_in, to_out, valid = p["from_in"], p["to_out"], p["valid"]
+    d_qb_f = jnp.where(from_in, d_qb[:, 0].astype(f32) * e_g, 0.0) * scale
+    d_kt_f = jnp.where(to_out, d_kt[:, 0].astype(f32) * e_out, 0.0)
+    g_q = dq_i + r_ii * kf + d_qb_f
+    g_k = (
+        dk_i + dk_j + r_ii * qf + jnp.where(from_in, d_kbar * e_g, 0.0)
+        + d_kt_f
+    )
+    kt_term = d_kt_f * kf
+    g_big = (
+        kf * (dk_i - dk_j) + qf * dq_i + d_kbar * p["kbar"] + d_qb_f * qf
+        - kt_term
+    )
+    g_last = jnp.sum(kt_term, axis=1, keepdims=True) + d_d[:, 0] * p["d"]
+    tok = jax.lax.broadcasted_iota(jnp.int32, (c, 1), 0)
+    g_big = g_big + jnp.where(tok == c - 1, g_last, 0.0)
+    ri = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    ci = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    triu = jnp.broadcast_to(jnp.where(ri <= ci, 1.0, 0.0), (nb, c, c))
+    g_g = _bdot(triu, g_big, _NN, _HI)  # the running sum from the end
+    d_q[:, 0] = jnp.where(valid, g_q, 0.0).astype(d_q.dtype)
+    d_k[:, 0] = jnp.where(valid, g_k, 0.0).astype(d_k.dtype)
+    d_v[:, 0] = jnp.where(valid, beta_c * d_xv, 0.0).astype(d_v.dtype)
+    d_g[:, 0] = jnp.where(valid, g_g, 0.0)
+    _store_rows(d_beta, jnp.sum(
+        jnp.where(p["eye"] & valid, g_beta, 0.0), axis=1, keepdims=True
+    ))
+
+
+def _marks(seg):
+    """[N, B, 4, C] float32 for the operand kernels, a row each: the segment
+    ids; 1 where S0 reaches the token; 1 where the token reaches S'; 1
+    where the state carries over the chunk."""
+    valid = seg > 0
+    seg_in, seg_last = _seg_in(seg)[..., None], seg[..., -1:]
+    return jnp.stack([
+        seg,
+        valid & (seg == seg_in),
+        valid & (seg == seg_last),
+        jnp.broadcast_to((seg_last == seg_in) & (seg_last > 0), seg.shape),
+    ], axis=2).astype(jnp.float32)
+
+
+def _ops_call(backward, arrays, out_like, interpret):
+    """``chunk_ops_bwd`` | ``chunk_ops_fwd`` over (head, block of chunks).
+    ``arrays``: [N, B, H, ...] a head, except the last, the marks
+    [N, B, 4, C]; ``out_like``: (trailing shape, dtype) of each output."""
+    n, b, h, c, dk = arrays[0].shape
+    nb = _kernel_chunks(n, _OPS_CHUNKS)
+    flat = [_flat(x) for x in arrays[:-1]] + [arrays[-1]]
+    f32 = jnp.float32
+    scratch = [pltpu.VMEM((nb, c, dk), f32), pltpu.VMEM((nb, c, dk), f32),
+               pltpu.VMEM((nb, c, c), f32)]
+    if backward:  # every exp(G_i - G_j) of the sub-blocks; a token's sums
+        scratch += [pltpu.VMEM((nb, c * SUB, dk), f32),
+                    pltpu.VMEM((nb, c, dk), f32)]
+
+    def spec(shape, at=lambda i, j: (j, i, 0, 0)):
+        return pl.BlockSpec((nb, 1) + tuple(shape), at)
+
+    out_shape = [
+        jax.ShapeDtypeStruct((n, b * h) + tuple(shape), dtype)
+        for shape, dtype in out_like
+    ]
+    out = pl.pallas_call(
+        _ops_bwd_kernel if backward else _ops_fwd_kernel,
+        grid=(b * h, n // nb),
+        in_specs=[spec(x.shape[2:]) for x in flat[:-1]]
+        + [spec(flat[-1].shape[2:], lambda i, j: (j, i // h, 0, 0))],
+        out_specs=[spec(x.shape[2:]) for x in out_shape],
+        out_shape=out_shape,
+        scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")
+        ),
+        interpret=interpret,
+        name="chunk_ops_bwd" if backward else "chunk_ops_fwd",
+    )(*flat)
+    return tuple(x.reshape(n, b, h, *x.shape[2:]) for x in out)
+
+
+@functools.partial(jax.jit, static_argnames="interpret")
+def _operands_kernel(q, k, v, g, beta, seg, interpret=False):
+    """``_prep`` as the kernel ``chunk_ops_fwd``."""
+    c, dk, dv = q.shape[-2], q.shape[-1], v.shape[-1]
+    dt = q.dtype
+    return _ops_call(
+        False, (q, k, v, g, beta[..., None, :], _marks(seg)),
+        [((c, dk), dt), ((c, dv), dt), ((c, dk), dt), ((c, dk), dt),
+         ((c, c), dt), ((1, dk), jnp.float32)],
+        interpret,
+    )
+
+
+@functools.partial(jax.jit, static_argnames="interpret")
+def _operands_bwd_kernel(q, k, v, g, beta, seg, d_ops, interpret=False):
+    """``jax.vjp(_prep)`` as the kernel ``chunk_ops_bwd``: from the state
+    pass's cotangents of the six operands to those of q, k, v, g, beta."""
+    c, dk, dv = q.shape[-2], q.shape[-1], v.shape[-1]
+    f32 = jnp.float32
+    *grads, d_beta = _ops_call(
+        True, (q, k, v, g, beta[..., None, :]) + tuple(d_ops) + (_marks(seg),),
+        [((c, dk), q.dtype), ((c, dk), k.dtype), ((c, dv), v.dtype),
+         ((c, dk), f32), ((1, c), f32)],
+        interpret,
+    )
+    return tuple(grads) + (d_beta[..., 0, :],)
+
+
 # -------------------------------------------------------------- the op --
-def _operands(q, k, v, g, beta, seg):
+def _operands(q, k, v, g, beta, seg, impl="xla", interpret=False):
+    if impl == "kernel":
+        return _operands_kernel(q, k, v, g, beta, seg, interpret=interpret)
     return _in_blocks(_prep, (q, k, v, g, beta, seg, _seg_in(seg)))
 
 
@@ -451,7 +848,8 @@ def _chunked_plain(q, k, v, g, beta, seg):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
 def _chunked(q, k, v, g, beta, seg, impl, interpret):
-    return _state_fwd(_operands(q, k, v, g, beta, seg), impl, interpret)
+    ops = _operands(q, k, v, g, beta, seg, impl, interpret)
+    return _state_fwd(ops, impl, interpret)
 
 
 def _chunked_fwd(q, k, v, g, beta, seg, impl, interpret):
@@ -459,14 +857,9 @@ def _chunked_fwd(q, k, v, g, beta, seg, impl, interpret):
     return out, (q, k, v, g, beta, seg)
 
 
-def _chunked_bwd(impl, interpret, res, do):
-    q, k, v, g, beta, seg = res
-    ops = _operands(q, k, v, g, beta, seg)
-    _, states = _state_fwd(ops, impl, interpret, save_states=True)
-    if impl == "kernel":
-        d_ops = _state_bwd_kernel(ops, states, do, interpret)
-    else:
-        d_ops = _state_bwd_xla(ops, states, do)
+def _operands_bwd_xla(q, k, v, g, beta, seg, d_ops):
+    """XLA's own differentiation of ``_prep``, a block of chunks at a time:
+    the CPU's path, and what ``chunk_ops_bwd`` is held equal to."""
 
     def prep_bwd(q, k, v, g, beta, seg, seg_in, *cts):
         _, vjp = jax.vjp(
@@ -474,9 +867,23 @@ def _chunked_bwd(impl, interpret, res, do):
         )
         return vjp(cts)
 
-    grads = _in_blocks(
+    return _in_blocks(
         prep_bwd, (q, k, v, g, beta, seg, _seg_in(seg)) + tuple(d_ops)
     )
+
+
+def _chunked_bwd(impl, interpret, res, do):
+    q, k, v, g, beta, seg = res
+    ops = _operands(q, k, v, g, beta, seg, impl, interpret)
+    _, states = _state_fwd(ops, impl, interpret, save_states=True)
+    if impl == "kernel":
+        d_ops = _state_bwd_kernel(ops, states, do, interpret=interpret)
+        grads = _operands_bwd_kernel(
+            q, k, v, g, beta, seg, d_ops, interpret=interpret
+        )
+    else:
+        d_ops = _state_bwd_xla(ops, states, do)
+        grads = _operands_bwd_xla(q, k, v, g, beta, seg, d_ops)
     return tuple(grads) + (None,)
 
 
@@ -487,6 +894,23 @@ def _to_chunks(x):  # [B, T, H, ...] -> [N, B, H, C, ...]
     b, t, h = x.shape[:3]
     x = x.reshape(b, t // CHUNK, CHUNK, h, *x.shape[3:])
     return x.transpose((1, 0, 3, 2) + tuple(range(4, x.ndim)))
+
+
+def _chunk_inputs(q, k, v, g, beta, seg):
+    """A batch of rows as the op takes it: padded to whole chunks (segment
+    0), ``q, k, v, g`` [N, B, H, C, d] with ``g`` float32, ``beta`` float32
+    [N, B, H, C], ``seg`` int32 [N, B, C]."""
+    b, t = seg.shape
+    pad = -t % CHUNK
+    if pad:  # a row that is no whole number of chunks
+        q, k, v, g, beta, seg = (
+            jnp.pad(x, [(0, 0), (0, pad)] + [(0, 0)] * (x.ndim - 2))
+            for x in (q, k, v, g, beta, seg)
+        )
+    chunks = [_to_chunks(x) for x in (q, k, v, g.astype(jnp.float32))]
+    beta_c = _to_chunks(beta.astype(jnp.float32)[..., None])[..., 0]
+    seg_c = seg.astype(jnp.int32).reshape(b, -1, CHUNK).transpose(1, 0, 2)
+    return (*chunks, beta_c, seg_c)
 
 
 def kda_scan(
@@ -508,20 +932,12 @@ def kda_scan(
     b, t, h, dk = q.shape
     impl = impl or scan_impl(dk, v.shape[-1])
     with device_scope("kda_scan"):
-        seg = segment_ids.astype(jnp.int32)
+        a = _chunk_inputs(q, k, v, g, beta, segment_ids)
         pad = -t % CHUNK
-        if pad:  # a row that is no whole number of chunks
-            q, k, v, g, beta, seg = (
-                jnp.pad(x, [(0, 0), (0, pad)] + [(0, 0)] * (x.ndim - 2))
-                for x in (q, k, v, g, beta, seg)
-            )
-        chunks = [_to_chunks(x) for x in (q, k, v, g.astype(jnp.float32))]
-        beta_c = _to_chunks(beta.astype(jnp.float32)[..., None])[..., 0]
-        seg_c = seg.reshape(b, -1, CHUNK).transpose(1, 0, 2)
         if custom_backward:
-            o = _chunked(*chunks, beta_c, seg_c, impl, interpret)
+            o = _chunked(*a, impl, interpret)
         else:
-            o = _chunked_plain(*chunks, beta_c, seg_c)
+            o = _chunked_plain(*a)
         o = o.transpose(1, 0, 3, 2, 4).reshape(b, t + pad, h, -1)[:, :t]
         real = segment_ids > 0
         starts = real & (

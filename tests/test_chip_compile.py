@@ -274,12 +274,16 @@ def test_sparse_attention_fwd_bwd_compiles_for_v5e(one_chip, monkeypatch):
 @pytest.mark.parametrize("t", [2048, 8192])
 def test_kda_scan_fwd_bwd_compiles_for_v5e(one_chip, t):
     """``ops/kda.kda_scan`` forward and backward at Kimi-Linear's published
-    widths (32 heads of 128 | 128) on one packed row: the chunk operands in
-    XLA, then the three state-pass kernels (``kda_fwd_h`` in the forward;
+    widths (32 heads of 128 | 128) on one packed row: the chunk operands and
+    their cotangents as ``chunk_ops_fwd`` / ``chunk_ops_bwd`` (8 chunks a grid
+    step taken together, every exponent difference in VMEM, the loops over a
+    sub-block's tokens and the substitution's rows rolled: dynamic sublane
+    reads, which interpret mode lets through and only this compiler judges),
+    then the three state-pass kernels (``kda_fwd_h`` in the forward;
     ``kda_fwd_h_states`` and ``kda_bwd_dh`` in the backward, which recomputes
     the chunk states), 8 chunks a grid step, the state in VMEM.
-    ``jax.default_backend()`` is the CPU here, so the platform's choice of
-    the state pass is named in the call."""
+    ``jax.default_backend()`` is the CPU here, so the platform's choice is
+    named in the call."""
     import re
 
     import jax
@@ -304,10 +308,53 @@ def test_kda_scan_fwd_bwd_compiles_for_v5e(one_chip, t):
         .compile()
     )
     text = compiled.as_text()
-    for name in ("kda_fwd_h", "kda_fwd_h_states", "kda_bwd_dh"):
+    for name in ("kda_fwd_h", "kda_fwd_h_states", "kda_bwd_dh",
+                 "chunk_ops_fwd", "chunk_ops_bwd"):
         assert re.search(rf"{name}[^_a-z]", text), name
+    # the operands' pairwise terms never leave VMEM: no [.., 16, 16, 128]
+    assert not re.search(r"f32\[[0-9,]*16,16,128\]", text)
     # no chunk-start states in float32, and none kept beside the inputs
     assert not re.search(rf"f32\[{t // 64},32,128,128\]", text)
+
+
+def test_kimi_grad_step_lowers_each_operand_kernel_a_bounded_number_of_times(
+    one_chip, monkeypatch
+):
+    """A Pallas kernel is lowered to its Mosaic module in Python at every
+    place its call occurs in the lowered text, before the compile cache is
+    asked, so a warm start pays for every occurrence (PERF.md, PR 34: nine
+    operand kernels a ``grad_step`` cost the cell 95 s of ``setup_s``). The
+    kernels sit behind jitted functions, which a module lowers once for
+    equal arguments: the tiny ``kimi_linear`` stack laid out as the cell's
+    (a leading layer, a scanned run of two, latent attention, a last layer;
+    checkpointed) holds ``chunk_ops_fwd`` at most three times (the runs of
+    one layer, the scan's body, the backward's recomputation) and
+    ``chunk_ops_bwd`` once, however many layers there are. Counted in the
+    lowered text (``tools/setup_cost.py``), nothing compiled, nothing timed."""
+    import dataclasses
+
+    import jax
+
+    from areal_tpu.api.cli_args import ParallelismConfig
+    from areal_tpu.models.config import tiny_config
+    from areal_tpu.ops import kda
+    from areal_tpu.parallel import mesh as mesh_lib
+    from tools import setup_cost
+
+    monkeypatch.setattr(kda, "scan_impl", lambda *a: "kernel")
+    mc = dataclasses.replace(
+        tiny_config("kimi_linear"), num_layers=5,
+        layer_kinds=("kda+dense", "kda+moe", "kda+moe", "mla+moe", "kda+moe"),
+    )
+    mesh = mesh_lib.make_mesh(ParallelismConfig(), list(one_chip.device_set))
+    grad_step, args = setup_cost.trainer_programs(mc, mesh, 256, None)[
+        "grad_step"
+    ]
+    calls = setup_cost.custom_calls(grad_step.trace(*args).lower().as_text())
+    assert 1 <= calls["chunk_ops_fwd"] <= 3, calls
+    assert calls["chunk_ops_bwd"] == 1, calls
+    for name in ("kda_fwd_h", "kda_fwd_h_states", "kda_bwd_dh"):
+        assert 1 <= calls[name] <= 2, calls
 
 
 def test_latent_attention_core_compiles_for_v5e(one_chip, monkeypatch):

@@ -2,8 +2,13 @@
 token (``benchmark/reference_kimi.kda_rule``), its hand-written backward
 against XLA's differentiation of the plain chunked form, the state-pass
 kernels in Pallas interpret mode against the ``lax.scan`` they are held
-equal to, segment borders inside a chunk, decays near 0 and near 1."""
+equal to, segment borders inside a chunk, decays near 0 and near 1; and the
+operand kernels (``chunk_ops_fwd`` / ``chunk_ops_bwd``, interpret mode, at
+the lane-tile widths they run at on the chip) against ``_prep`` and XLA's
+differentiation of it. Every comparison runs as ONE jitted program a shape:
+op by op the CPU compiles each primitive apart."""
 
+import functools
 import os
 import sys
 
@@ -47,6 +52,11 @@ def _rule(q, k, v, g, beta, seg):
     return out
 
 
+@functools.lru_cache(None)
+def _scan(impl):
+    return jax.jit(functools.partial(kda.kda_scan, impl=impl, interpret=True))
+
+
 CASES = [
     pytest.param(1, None, id="t1"),
     pytest.param(63, None, id="t63"),
@@ -62,7 +72,7 @@ CASES = [
 @pytest.mark.parametrize("t,seg_lens", CASES)
 def test_chunked_form_equals_the_rule_token_by_token(t, seg_lens, impl):
     a = _inputs(t, seg_lens)
-    got, stats = kda.kda_scan(*a, impl=impl, interpret=True)
+    got, stats = _scan(impl)(*a)
     np.testing.assert_allclose(got[0], _rule(*a), atol=2e-6)
     real = sum(seg_lens or [t])
     assert [int(x) for x in stats] == [
@@ -82,16 +92,16 @@ def test_hand_written_backward_equals_xlas_of_the_plain_chunked_form(
     a = _inputs(t, seg_lens)
 
     def grads(**kw):
-        return jax.grad(
+        return jax.jit(jax.grad(
             lambda *x: _loss(kda.kda_scan(*x, a[5], **kw)[0]),
             argnums=(0, 1, 2, 3, 4),
-        )(*a[:5])
+        ))(*a[:5])
 
     want = grads(impl="xla", custom_backward=False)
-    rule = jax.grad(
+    rule = jax.jit(jax.grad(
         lambda *x: _loss(jnp.asarray(_rule_traced(*x, a[5]))),
         argnums=(0, 1, 2, 3, 4),
-    )(*a[:5])
+    ))(*a[:5])
     for name, kw in (("scan", dict(impl="xla")),
                      ("kernel", dict(impl="kernel", interpret=True))):
         for got, w, r in zip(grads(**kw), want, rule):
@@ -127,13 +137,13 @@ def test_decays_near_one_and_near_zero_neither_overflow_nor_clamp(decay):
     the rule's."""
     a = _inputs(130, decay=decay)
     for impl in ("xla", "kernel"):
-        got, _ = kda.kda_scan(*a, impl=impl, interpret=True)
+        got, _ = _scan(impl)(*a)
         assert np.isfinite(np.asarray(got)).all()
         np.testing.assert_allclose(got[0], _rule(*a), atol=5e-6)
-    grads = jax.grad(
+    grads = jax.jit(jax.grad(
         lambda *x: _loss(kda.kda_scan(*x, a[5], impl="xla")[0]),
         argnums=(0, 1, 2, 3, 4),
-    )(*a[:5])
+    ))(*a[:5])
     assert all(np.isfinite(np.asarray(x)).all() for x in grads)
 
 
@@ -142,11 +152,11 @@ def test_a_long_row_runs_its_operands_in_blocks_of_chunks(monkeypatch):
     forward and backward: the same numbers as in one piece."""
     a = _inputs(4 * kda.CHUNK, [100, 140], h=1, dk=8, dv=8)
 
-    def run():
-        return jax.value_and_grad(
+    def run():  # a new jit a call: the block size is read while tracing
+        return jax.jit(jax.value_and_grad(
             lambda *x: _loss(kda.kda_scan(*x, a[5], impl="xla")[0]),
             argnums=(0, 1, 2, 3, 4),
-        )(*a[:5])
+        ))(*a[:5])
 
     whole = run()
     monkeypatch.setattr(kda, "_PREP_CHUNKS", 2)
@@ -192,3 +202,95 @@ def test_the_op_runs_per_chip_on_its_own_rows_under_an_fsdp_mesh():
         seq = mesh_lib.make_mesh(ParallelismConfig(seq_parallel_size=n))
         with pytest.raises(ValueError, match="no sequence-parallel form"):
             kda.make_kda_scan(seq)
+
+
+# ------------------------------------------- the operands as kernels --
+# One row of three chunks, two heads of 128 | 128 (whole lane tiles, as on
+# the chip), every case at that one shape: a program a comparison, compiled
+# once. (t, segment lengths, decay): what the row holds.
+_T = 3 * kda.CHUNK
+OPERAND_CASES = [
+    pytest.param(_T, None, 1.0, id="one-sequence"),
+    # ends inside a chunk (40), at a chunk border (64), at a sub-block
+    # border (80), and the row's end
+    pytest.param(_T, [40, 24, 16, 112], 1.0, id="segment-borders"),
+    pytest.param(_T, [70, 50], 1.0, id="trailing-padding"),
+    pytest.param(_T - 20, [100, 72], 1.0, id="no-whole-number-of-chunks"),
+    # exp(g) near exp(-20) a token: G_i - G_j over a chunk is -1,000 and
+    # more, which only differences survive
+    pytest.param(_T, [150, 42], 20.0, id="strong-decay"),
+]
+
+
+def _chunked_inputs(t, seg_lens, decay):
+    """The row as ``kda_scan`` hands it to the op."""
+    return list(kda._chunk_inputs(
+        *_inputs(t, seg_lens, dk=128, dv=128, decay=decay)
+    ))
+
+
+def _cotangents(a):
+    r = np.random.default_rng(7)
+    return tuple(
+        jnp.asarray(r.standard_normal(o.shape).astype(np.float32))
+        for o in jax.eval_shape(_prep, *a)
+    )
+
+
+def _close(got, want, tol, decay=1.0):
+    """Within ``tol`` of the largest entry; a running sum ``G`` of size
+    ``decay`` x 64 rounds at ``decay`` times the float32 step, in the
+    kernel's order of summation and in ``cumsum``'s alike."""
+    tol *= max(1.0, decay)
+    for x, w in zip(got, want):
+        assert np.isfinite(np.asarray(x)).all()
+        scale = float(jnp.abs(w).max()) + 1e-9
+        assert float(jnp.abs(x - w).max()) / scale < tol
+
+
+@jax.jit
+def _prep(*a):
+    return kda._prep(*a, kda._seg_in(a[5]))
+
+
+@jax.jit
+def _prep_vjp(a, cts):
+    return kda._operands_bwd_xla(*a, cts)
+
+
+@pytest.mark.parametrize("t,seg_lens,decay", OPERAND_CASES)
+def test_operand_kernel_equals_prep(t, seg_lens, decay):
+    a = _chunked_inputs(t, seg_lens, decay)
+    _close(kda._operands_kernel(*a, interpret=True), _prep(*a), 1e-5, decay)
+
+
+@pytest.mark.parametrize("t,seg_lens,decay", OPERAND_CASES)
+def test_operand_cotangent_kernel_equals_xlas_vjp_of_prep(t, seg_lens, decay):
+    """The six cotangents' pull-back to q, k, v, g, beta."""
+    a = _chunked_inputs(t, seg_lens, decay)
+    cts = _cotangents(a)
+    _close(
+        kda._operands_bwd_kernel(*a, cts, interpret=True), _prep_vjp(a, cts),
+        2e-5, decay,
+    )
+
+
+@functools.lru_cache(None)
+def _op_grads(t, kernels):
+    kw = (
+        dict(impl="kernel", interpret=True) if kernels
+        else dict(impl="xla", custom_backward=False)
+    )
+    return jax.jit(jax.grad(
+        lambda *x: _loss(kda.kda_scan(*x, **kw)[0]), argnums=(0, 1, 2, 3, 4)
+    ))
+
+
+@pytest.mark.parametrize("t,seg_lens,decay", OPERAND_CASES)
+def test_whole_op_gradients_with_the_kernels_equal_the_plain_chunked_forms(
+    t, seg_lens, decay
+):
+    """``kda_scan`` with all five kernels, its backward by hand, against
+    ``_chunked_plain`` with XLA's differentiation throughout."""
+    a = _inputs(t, seg_lens, dk=128, dv=128, decay=decay)
+    _close(_op_grads(t, True)(*a), _op_grads(t, False)(*a), 2e-5, decay)

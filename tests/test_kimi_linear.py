@@ -107,7 +107,9 @@ def test_each_block_kind_matches_the_reference(tiny, kind):
     rng = np.random.default_rng(1)
     x = jnp.asarray(rng.standard_normal((1, 37, cfg.hidden_size)), jnp.float32)
     seg = jnp.ones((1, 37), jnp.int32)
-    got, stats = tr._block_body(cfg, kind, x, lp, seg)
+    got, stats = jax.jit(
+        lambda x, lp: tr._block_body(cfg, kind, x, lp, seg)
+    )(x, lp)
     with jax.default_matmul_precision("highest"):
         want, _ = ref.layer(keys, kind, x[0], lp)
     np.testing.assert_allclose(got[0], want, atol=2e-5)
@@ -117,7 +119,9 @@ def test_each_block_kind_matches_the_reference(tiny, kind):
 def test_logits_match_alone_and_packed_with_padding(tiny):
     cfg, keys, params = tiny
     tok, seg, pos, seqs = _row([70, 33, 21], 130, cfg.vocab_size)
-    got, stats = tr.apply(params, cfg, tok, seg, pos, return_stats=True)
+    got, stats = jax.jit(
+        lambda p: tr.apply(p, cfg, tok, seg, pos, return_stats=True)
+    )(params)
     at = 0
     for s in seqs:  # borders fall inside a chunk and inside the conv's reach
         np.testing.assert_allclose(
@@ -138,7 +142,7 @@ def test_loss_and_every_gradient_match_the_references(tiny):
     def reference(p):
         return (ref.logits(keys, p, seqs[0]) * w).sum()
 
-    got_v, got = jax.value_and_grad(program)(params)
+    got_v, got = jax.jit(jax.value_and_grad(program))(params)
     want_v, want = jax.value_and_grad(reference)(params)
     assert abs(float(got_v - want_v)) < 1e-3 * abs(float(want_v)) + 1e-4
     flat_got = jax.tree_util.tree_leaves_with_path(got)
@@ -359,13 +363,17 @@ def test_the_generation_engine_refuses_a_stack_of_block_kinds(tiny):
         GenerationEngine(JaxGenConfig(), model_config=tiny[0], params=tiny[2])
 
 
-def test_the_trainer_trains_the_family_and_counts_its_scans_exactly(tiny):
+def test_the_trainer_trains_the_family_and_counts_its_scans_exactly(
+    tiny, monkeypatch
+):
     from areal_tpu.api import cli_args
     from areal_tpu.api.io_struct import FinetuneSpec
     from areal_tpu.engine.ppo.actor import PPOActor
     from areal_tpu.engine.spmd_engine import SPMDTrainEngine
     from areal_tpu.utils import data as data_utils
 
+    # rows of 256 tokens for the batch's 185 (the ladder's own step is 2048)
+    monkeypatch.setattr(data_utils, "ROW_QUANTUM", 256)
     full, _ = cli_args.load_expr_config(
         ["--config", os.path.join(ROOT, "examples", "gsm8k_grpo.yaml")],
         cli_args.GRPOConfig,

@@ -1,14 +1,17 @@
-"""The gated delta rule's chunked scan alone, on one chip: the state pass as
-the Pallas kernels (``kda_fwd_h``, ``kda_fwd_h_states``, ``kda_bwd_dh``)
-against the ``lax.scan`` they are held equal to (``ops/kda.py``), forward and
-forward + backward, on one packed row at the published widths (32 heads of
-128 | 128); the chunk operands (``_prep``, shared by both) alone; and the two
-forms' agreement on the chip.
+"""The gated delta rule's chunked scan alone, on one chip, on one packed row
+at the published widths (32 heads of 128 | 128): the whole op forward and
+forward + backward, as the Pallas kernels against the ``jax.numpy`` forms
+they are held equal to (``ops/kda.py``); then its three parts apart, kernel
+against ``jax.numpy``: the chunk operands forward (``chunk_ops_fwd`` |
+``_prep``), the operands' cotangents (``chunk_ops_bwd`` | XLA's own
+differentiation of ``_prep``, a block of 16 chunks at a time), the state
+pass (``kda_fwd_h`` | the ``lax.scan``); and the forms' agreement on the
+chip.
 
     python -m tools.microbench_kda [--tokens 8192] [--seqs 3]
 
 Host clock around ``block_until_ready``, the best of ``RUNS``. ``ops/kda.py``
-resolves ``scan_impl`` to what this found faster (PERF.md, PR 33).
+resolves ``scan_impl`` to what this found faster (PERF.md, PR 33, PR 35).
 """
 
 import argparse
@@ -34,6 +37,15 @@ def _best_ms(fn):
         jax.block_until_ready(fn())
         best = min(best, time.perf_counter() - t0)
     return out, best * 1e3
+
+
+def _worst_rel(xs, ys):
+    """The largest difference of a pair, over the second's largest value."""
+    return max(
+        float(jnp.abs(x.astype(jnp.float32) - y.astype(jnp.float32)).max()
+              / (jnp.abs(y.astype(jnp.float32)).max() + 1e-9))
+        for x, y in zip(xs, ys)
+    )
 
 
 def main():
@@ -92,28 +104,45 @@ def main():
     diff = jnp.abs(
         out["kernel"].astype(jnp.float32) - out["xla"].astype(jnp.float32)
     )
-    worst_grad = max(
-        float(jnp.abs(x.astype(jnp.float32) - y.astype(jnp.float32)).max()
-              / (jnp.abs(y.astype(jnp.float32)).max() + 1e-9))
-        for x, y in zip(out["kernel_grads"], out["xla_grads"])
-    )
     print(json.dumps({
         "kernel_vs_xla_worst_abs": float(diff.max()),
         "output_abs_mean": float(jnp.abs(out["xla"].astype(jnp.float32)).mean()),
-        "grads_worst_rel": worst_grad,
+        "grads_worst_rel": _worst_rel(out["kernel_grads"], out["xla_grads"]),
     }))
-    chunks = [kda._to_chunks(x) for x in (q, k, v, g)]
-    beta_c = kda._to_chunks(beta[..., None])[..., 0]
-    seg_c = seg.reshape(1, -1, kda.CHUNK).transpose(1, 0, 2)
-    prep = jax.jit(lambda *x: kda._operands(*x))
-    ops, ms = _best_ms(lambda: prep(*chunks, beta_c, seg_c))
-    print(json.dumps({"pass": "chunk operands (_prep)", "ms": ms}))
+    ins = kda._chunk_inputs(*a)
+
+    ops = {}
+    for impl in ("kernel", "xla"):
+        fn = jax.jit(lambda *x, impl=impl: kda._operands(*x, impl, interp))
+        ops[impl], ms = _best_ms(lambda: fn(*ins))
+        print(json.dumps({"pass": "operands forward", "impl": impl, "ms": ms}))
+    cts = jax.tree_util.tree_map(
+        lambda x: (jax.random.normal(ks[0], x.shape) * 0.1).astype(x.dtype),
+        ops["xla"],
+    )
+    bwd = {
+        "kernel": lambda *x: kda._operands_bwd_kernel(
+            *x[:6], x[6:], interpret=interp
+        ),
+        "xla": lambda *x: kda._operands_bwd_xla(*x[:6], x[6:]),
+    }
+    d_ins = {}
+    for impl, fn in bwd.items():
+        jit = jax.jit(fn)
+        d_ins[impl], ms = _best_ms(lambda: jit(*ins, *cts))
+        print(json.dumps({"pass": "operands cotangents", "impl": impl, "ms": ms}))
+    print(json.dumps({
+        "operands_kernel_vs_xla_worst_rel": _worst_rel(ops["kernel"], ops["xla"]),
+        "cotangents_kernel_vs_xla_worst_rel": _worst_rel(
+            d_ins["kernel"], d_ins["xla"]
+        ),
+    }))
     for impl, fn in (
         ("kernel", lambda o: kda._state_fwd_kernel(o, interpret=interp)),
         ("xla", kda._state_fwd_xla),
     ):
         jit = jax.jit(fn)
-        _, ms = _best_ms(lambda: jit(ops))
+        _, ms = _best_ms(lambda: jit(ops["xla"]))
         print(json.dumps({"pass": "state pass forward", "impl": impl, "ms": ms}))
 
 

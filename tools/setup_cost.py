@@ -1,0 +1,147 @@
+"""What a trainer program costs the host BEFORE the compile cache is asked:
+tracing it and lowering it, off the chip, for a described v5e.
+
+    python -m tools.setup_cost [--model kimi_linear_48b_a3b] [--tokens 8192]
+
+A warm start pays this for every program it runs (the cache's key is the
+lowered module), and a Pallas kernel is lowered to its Mosaic module in
+Python at every place its call occurs in the lowered text, cache hit or
+miss. One JSON line a program (``grad_step``, ``logp_forward``: the
+engine's own builders, gradient checkpointing on, one packed row of
+``--tokens``): seconds to trace, seconds to lower, the ``tpu_custom_call``s
+of the lowered text by kernel name, the text's size. The seconds are this
+sandbox's CPU's and compare one tree with another on the same machine; no
+number here is a device's.
+
+``jax.default_backend()`` is the CPU here, so the choices the ops make from
+the platform (``ops/kda.scan_impl``, ``ops/moe.grouped_impl``, the splash
+kernel, ...) are steered to a TPU's for the length of the script.
+"""
+
+import argparse
+import collections
+import json
+import os
+import re
+import time
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import areal_tpu  # noqa: E402,F401 — the scoped-VMEM flag, before the backend
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+
+_KERNEL = re.compile(r'kernel_name\s*=\s*"([^"]+)"')
+
+
+def custom_calls(text: str) -> dict:
+    """The ``tpu_custom_call``s of a lowered module's text by kernel name:
+    each is one lowering of that kernel's body in Python."""
+    names = collections.Counter()
+    for line in text.splitlines():
+        if "tpu_custom_call" not in line:
+            continue
+        m = _KERNEL.search(line)
+        names[m.group(1) if m else "?"] += 1
+    return dict(sorted(names.items()))
+
+
+def program_cost(jitted, *args) -> dict:
+    t0 = time.perf_counter()
+    traced = jitted.trace(*args)
+    t1 = time.perf_counter()
+    lowered = traced.lower()
+    t2 = time.perf_counter()
+    text = lowered.as_text()
+    return {
+        "trace_s": round(t1 - t0, 2),
+        "lower_s": round(t2 - t1, 2),
+        "custom_calls": custom_calls(text),
+        "text_bytes": len(text),
+    }
+
+
+def trainer_programs(mc, mesh, tokens: int, attend, rows: int = 1) -> dict:
+    """``{name: (jitted program, abstract arguments)}``: the engine's
+    ``grad_step`` (gradient checkpointing on, the GRPO loss) and
+    ``logp_forward`` for ``rows`` packed rows of ``tokens`` on ``mesh``."""
+    from areal_tpu.engine import programs
+    from areal_tpu.models.transformer import init_params
+    from areal_tpu.ops.functional import target_aligned_logprobs
+    from areal_tpu.parallel import feasibility
+
+    one = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
+
+    def sds(tree, dtype=None):
+        return jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct(
+                s.shape, dtype or s.dtype, sharding=one
+            ),
+            tree,
+        )
+
+    params = sds(jax.eval_shape(
+        lambda: init_params(mc, jax.random.PRNGKey(0))
+    ))
+    row = jax.ShapeDtypeStruct((rows, tokens), jnp.int32)
+    arrays = sds({
+        **{k: row for k in
+           ("tokens", "segment_ids", "positions", "t_loss_mask")},
+        **{k: jax.ShapeDtypeStruct(row.shape, jnp.float32) for k in
+           ("t_logprobs", "t_prox_logp", "t_advantages")},
+        "s_rewards": jax.ShapeDtypeStruct((rows, 8), jnp.float32),
+    })
+    return {
+        "grad_step": (
+            programs.build_grad_step(
+                mc, mesh, jnp.bfloat16, attend, remat=True,
+                loss_fn=feasibility._grpo_loss_fn,
+                loss_weight_fn=feasibility._grpo_loss_weight_fn,
+            ),
+            (params, sds(params, jnp.float32), arrays),
+        ),
+        "logp_forward": (
+            programs.build_logp_forward(
+                mc, mesh, jnp.bfloat16, attend, hook=target_aligned_logprobs
+            ),
+            (params, arrays),
+        ),
+    }
+
+
+def main(argv=None):
+    from jax.experimental import topologies
+
+    from areal_tpu.api.cli_args import ParallelismConfig
+    from areal_tpu.ops import flash
+    from areal_tpu.ops.ring_attention import attention_fn
+    from areal_tpu.parallel import feasibility
+    from areal_tpu.parallel import mesh as mesh_lib
+
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--model", default="kimi_linear_48b_a3b",
+                    choices=sorted(feasibility.MODEL_CONFIGS))
+    ap.add_argument("--tokens", type=int, default=8192)
+    args = ap.parse_args(argv)
+
+    topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    jax.default_backend = lambda: "tpu"  # the ops' platform rules
+    flash._PROBED_BLOCK = 2048  # what the chip's probe settles on
+    mc = feasibility.MODEL_CONFIGS[args.model]()
+    mesh = mesh_lib.make_mesh(ParallelismConfig(), topo.devices[:1])
+    attend = attention_fn(
+        "sparse_topk" if mc.has_indexer else "flash", mesh,
+        topk=mc.dsa_topk if mc.has_indexer else 0,
+    )
+    for name, (jitted, a) in trainer_programs(
+        mc, mesh, args.tokens, attend
+    ).items():
+        print(json.dumps({
+            "model": args.model, "tokens": args.tokens, "program": name,
+            **program_cost(jitted, *a),
+        }), flush=True)
+
+
+if __name__ == "__main__":
+    main()
