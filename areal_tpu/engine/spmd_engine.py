@@ -170,6 +170,11 @@ class SPMDTrainEngine(TrainEngine):
         seed: int = 0,
     ):
         cfg = self.config
+        # every program this thread makes from here on is in the trainer's
+        # compile log by name (init_params, the optimizer state, the eager
+        # scalars of a step, ...), with no line at its call site; the two
+        # dispatch scopes of a step add their phase and shape
+        goodput.set_thread_tracker(goodput.trainer_tracker(), phase="trainer")
         self.mesh = mesh_lib.make_mesh(cfg.parallel)
         if model_config is not None:
             self.model_config = model_config
@@ -210,12 +215,14 @@ class SPMDTrainEngine(TrainEngine):
             # drawn where it lives: every leaf comes out of one program in
             # its own sharding, so no device (and no host) ever holds the
             # whole model — a from-scratch model may exist only across chips
+            draw = functools.partial(
+                init_params, mc, dtype=self.param_dtype, value_head=is_critic
+            )
+            # a partial has no name of its own, and the module would be
+            # jit(<unknown>) in the compile log and in a profile
+            draw.__name__ = init_params.__name__
             self.params = jax.jit(
-                functools.partial(
-                    init_params, mc, dtype=self.param_dtype,
-                    value_head=is_critic,
-                ),
-                out_shardings=self._param_shardings,
+                draw, out_shardings=self._param_shardings
             )(jax.random.PRNGKey(seed))
         if cfg.optimizer is not None:
             total_steps = ft_spec.total_train_steps if ft_spec else 10000
@@ -302,6 +309,8 @@ class SPMDTrainEngine(TrainEngine):
         )
 
     def destroy(self):
+        # what this thread makes from here on is no trainer program
+        goodput.set_thread_tracker(None)
         self.params = None
         self.opt_state = None
         self._jit_cache.clear()
@@ -499,11 +508,13 @@ class SPMDTrainEngine(TrainEngine):
     def _zero_grads(self):
         key = "zeros"
         if key not in self._jit_cache:
-            self._jit_cache[key] = jax.jit(
-                lambda params: jax.tree_util.tree_map(
+            def zero_grads(params):
+                return jax.tree_util.tree_map(
                     lambda p: jnp.zeros(p.shape, jnp.float32), params
-                ),
-                out_shardings=self._param_shardings,
+                )
+
+            self._jit_cache[key] = jax.jit(
+                zero_grads, out_shardings=self._param_shardings
             )
         return self._jit_cache[key](self.params)
 

@@ -179,7 +179,24 @@ _METRIC_HELP = {
     "goodput_wall_s": "observed wall seconds since the ledger started",
     # recompile attribution (r11) + cold-start elimination (r14)
     "compile_events_total": "XLA backend compilations observed",
-    "compile_seconds_total": "wall seconds spent in XLA compilation",
+    "compile_seconds_total": (
+        "wall seconds spent making programs: the four phases below, a "
+        "function traced inside another's trace counted once"
+    ),
+    "compile_trace_seconds_total": (
+        "seconds of Python tracing to jaxprs (paid seeded as cold)"
+    ),
+    "compile_lower_seconds_total": (
+        "seconds of lowering jaxprs to MLIR modules (paid seeded as cold)"
+    ),
+    "compile_backend_seconds_total": (
+        "seconds of backend compilation the persistent cache did not "
+        "serve (about 0 on a seeded start)"
+    ),
+    "compile_cache_load_seconds_total": (
+        "seconds of backend events the persistent cache served: "
+        "reading, deserialising and loading executables"
+    ),
     "compiled_shapes": "distinct (phase, shape signature) programs compiled",
     "shape_ladder_size": (
         "exact enumerated programs for a fully-warm engine"
@@ -377,6 +394,8 @@ _ENGINE_COUNTERS = (
     "spec_chunks_total", "spec_draft_tokens_total",
     "spec_accepted_tokens_total",
     "compile_events_total", "compile_seconds_total",
+    "compile_trace_seconds_total", "compile_lower_seconds_total",
+    "compile_backend_seconds_total", "compile_cache_load_seconds_total",
     "compile_cache_hits_total", "compile_cache_misses_total",
     "compile_uncached_total",
     "weight_staging_aborts_total", "weight_flips_total",

@@ -21,9 +21,15 @@ both sides of the system:
 
 2. :class:`CompileTracker` — every XLA compilation is recorded with the
    dispatch that triggered it. A ``jax.monitoring`` listener (installed
-   once per process) attributes ``/jax/core/compile/*`` event durations
-   to the thread's current :func:`dispatch_scope` (phase + shape
-   signature, e.g. ``rows8|steps8|pps16``), appends one line per
+   once per process) attributes each ``/jax/core/compile/*`` event to
+   the thread's current :func:`dispatch_scope` (phase + shape
+   signature, e.g. ``rows8|steps8|pps16``) and keeps it as one entry of
+   a bounded log: which program (the event's ``fun_name``), which phase
+   of making it (``trace`` | ``lower`` | ``compile`` | ``cache_load``),
+   how long, and whether it ran inside another entry (a jitted function
+   traced inside a program's trace), so that the seconds add up once.
+   ``phase_seconds`` / ``programs`` / ``program_table`` read the log —
+   set-up by program, measured where it happens. It appends one line per
    backend compile to a ``compile_events.jsonl`` stream — the exact
    input a shape-ladder AOT precompiler consumes — and feeds the
    ``shape_ladder_coverage`` gauge (compiled shapes / ladder size) that
@@ -40,6 +46,8 @@ spans many layers (workflow executor, SPMD engine, recover handler)
 that should not all thread a ledger handle through their APIs.
 """
 
+import collections
+import itertools
 import json
 import os
 import threading
@@ -77,10 +85,26 @@ ENGINE_BUCKETS = (
 TRAINER_PRODUCTIVE = ("data_h2d", "fwd_bwd", "optim")
 ENGINE_PRODUCTIVE = ("prefill", "decode", "spec_verify")
 
-# jax.monitoring event prefix for XLA compilation phases; the
-# backend-compile event is the one counted as "a compile happened"
-_COMPILE_EVENT_PREFIX = "/jax/core/compile"
+# the jax.monitoring duration events of making a program, and the kind
+# each is logged as; the backend event is the one counted as "a compile
+# happened", and is a ``cache_load`` when the persistent cache served it
 _BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_EVENT_KINDS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    _BACKEND_COMPILE_EVENT: "compile",
+}
+PHASE_KINDS = ("trace", "lower", "compile", "cache_load")
+_BACKEND_KINDS = ("compile", "cache_load")
+# program_table's columns of each kind: (count, seconds)
+_TABLE_COLUMNS = {
+    "trace": ("traces", "trace_s"), "lower": (None, "lower_s"),
+    "compile": ("compiles", "compile_s"), "cache_load": ("loads", "load_s"),
+}
+# entries the log keeps: a trainer process of the benchmark's cells makes
+# 2.8 thousand (dense) to 16.9 thousand (Kimi), one a jitted function
+# traced, most of them nested (PERF.md section 5)
+_LOG_ENTRIES = 65536
 # persistent-compilation-cache outcome events (plain count events, not
 # durations). On this jax a cache HIT still fires a backend_compile
 # event for the retrieval, so the hit/miss event that precedes it on
@@ -120,7 +144,7 @@ def _current_tracker() -> Optional[Tuple["CompileTracker", str, str]]:
 
 
 def _on_monitoring_event(event: str, duration: float, **kw) -> None:
-    if not event.startswith(_COMPILE_EVENT_PREFIX):
+    if event not in _EVENT_KINDS:
         return
     cur = _current_tracker()
     if cur is None:
@@ -130,7 +154,10 @@ def _on_monitoring_event(event: str, duration: float, **kw) -> None:
     if event == _BACKEND_COMPILE_EVENT:
         cached = _TLS.cache_pending == "hit"
         _TLS.cache_pending = None
-    tracker._observe(phase, signature, float(duration), event, cached)
+    tracker._observe(
+        phase, signature, float(duration), event, cached,
+        fun_name=str(kw.get("fun_name", "")),
+    )
 
 
 def _on_count_event(event: str, **kw) -> None:
@@ -202,11 +229,61 @@ def set_thread_tracker(
     _TLS.default = None if tracker is None else (tracker, phase)
 
 
+def _program_name(fun_name: str) -> str:
+    """The function's name for all of a program's events: the trace event
+    carries it bare, the lower and backend events as the module's
+    ``jit(<function>)``."""
+    if fun_name.startswith("jit(") and fun_name.endswith(")"):
+        return fun_name[4:-1]
+    return fun_name
+
+
+class CompileEntry:
+    """One compile-phase event of a tracker's log. ``t_end`` is
+    ``time.monotonic()`` when the listener ran, so the event covered
+    (``t_end - seconds``, ``t_end``); ``nested`` says that interval lies
+    inside a later-ending entry's on the same thread (``parent``), whose
+    ``seconds`` already hold these. ``self_seconds`` is ``seconds`` less
+    the entries that ran directly inside this one: summed over ALL
+    entries they count every second once, under the kind that spent it
+    (an eager program compiled inside a trace is ``compile``, not
+    ``trace``)."""
+
+    __slots__ = (
+        "t_end", "kind", "fun_name", "seconds", "self_seconds", "tid",
+        "parent",
+    )
+
+    def __init__(self, t_end, kind, fun_name, seconds, tid):
+        self.t_end, self.kind, self.fun_name = t_end, kind, fun_name
+        self.seconds = self.self_seconds = seconds
+        self.tid, self.parent = tid, None
+
+    @property
+    def nested(self) -> bool:
+        return self.parent is not None
+
+    @property
+    def t_start(self) -> float:
+        return self.t_end - self.seconds
+
+    def root(self) -> "CompileEntry":
+        e = self
+        while e.parent is not None:
+            e = e.parent
+        return e
+
+
 class CompileTracker:
     """Per-owner recompile ledger fed by the jax.monitoring listener.
 
-    Tracks total compiles / compile seconds, a per-``(phase, signature)``
-    breakdown (the shape ladder actually paid for), per-thread compile
+    Keeps one :class:`CompileEntry` per compile-phase event in a bounded
+    log (``phase_seconds``, ``programs``, ``program_table`` read it; the
+    oldest entries fall out of the table and stay in the totals;
+    ``dropped_total`` counts them: the table says so in a row, and a cut
+    at a time the log no longer reaches raises), total compiles / compile
+    seconds with nested entries counted once, the distinct ``(phase, signature)`` shapes
+    compiled (the shape ladder actually paid for), per-thread compile
     seconds (the ledger carve-out input), persistent-compile-cache
     hit/miss counters, and optionally appends one JSONL line per backend
     compile to ``events_path`` — a stream that starts with a HEADER line
@@ -234,16 +311,22 @@ class CompileTracker:
         self._lock = threading.Lock()
         self._events_lock = threading.Lock()
         self.compiles_total = 0
-        self.compile_seconds_total = 0.0
+        # every entry's self_seconds by kind: each second once
+        self._seconds = dict.fromkeys(PHASE_KINDS, 0.0)
         # backend compiles NOT served by the persistent cache: the true
         # XLA bill (a seeded engine's "compiles" are disk retrievals)
         self.uncached_compiles_total = 0
         self.cache_hits_total = 0
         self.cache_misses_total = 0
-        # (phase, signature) -> {"count", "seconds"}
-        self.signatures: Dict[Tuple[str, str], Dict[str, float]] = {}
+        # (phase, signature) -> {"count", "uncached"}
+        self.signatures: Dict[Tuple[str, str], Dict[str, int]] = {}
         self.last_compile_t: Optional[float] = None
         self._thread_seconds: Dict[int, float] = {}
+        self._log: "collections.deque[CompileEntry]" = collections.deque()
+        # per thread, the entries no later one has enclosed yet, oldest
+        # first: what the next event's interval is held against
+        self._open: Dict[int, "collections.deque[CompileEntry]"] = {}
+        self.dropped_total = 0  # entries the log no longer holds
         self._epoch_unix = time.time()
         self._epoch_mono = time.monotonic()
         _install_listener()
@@ -297,41 +380,75 @@ class CompileTracker:
         duration: float,
         event: str,
         cached: Optional[bool] = None,
+        fun_name: str = "",
     ) -> None:
         tid = threading.get_ident()
+        kind = _EVENT_KINDS[event]
         is_backend = event == _BACKEND_COMPILE_EVENT
+        if is_backend and cached:
+            kind = "cache_load"
+        entry = CompileEntry(
+            self._time(), kind, _program_name(fun_name), duration, tid
+        )
+        made = {"trace": 0.0, "lower": 0.0}  # this program's, for its line
         with self._lock:
-            self.compile_seconds_total += duration
-            self.last_compile_t = self._time()
+            self.last_compile_t = entry.t_end
+            open_ = self._open.setdefault(tid, collections.deque())
+            # whatever began after this event did ran inside it: its
+            # seconds are this entry's already
+            inner_s = 0.0
+            while open_ and open_[-1].t_start >= entry.t_start:
+                inner = open_.pop()
+                inner.parent = entry
+                inner_s += inner.seconds
+            # the two clocks (jax times with time.time()) may disagree by
+            # microseconds: never below zero
+            entry.self_seconds = own = max(0.0, duration - inner_s)
+            if is_backend:
+                # the same program's lowering and tracing ended just
+                # before, in that order, where this process made them
+                for e in itertools.islice(reversed(open_), 2):
+                    if e.fun_name == entry.fun_name and e.kind in made:
+                        made[e.kind] += e.seconds
+            open_.append(entry)
+            if len(self._log) >= _LOG_ENTRIES:
+                self._drop_oldest()
+            self._log.append(entry)
+            self._seconds[kind] += own
             self._thread_seconds[tid] = (
-                self._thread_seconds.get(tid, 0.0) + duration
+                self._thread_seconds.get(tid, 0.0) + own
             )
             if is_backend:
                 self.compiles_total += 1
-                if not cached:
-                    self.uncached_compiles_total += 1
                 sig = self.signatures.setdefault(
-                    (phase, signature),
-                    {"count": 0, "seconds": 0.0, "uncached": 0},
+                    (phase, signature), {"count": 0, "uncached": 0}
                 )
                 sig["count"] += 1
                 if not cached:
-                    sig["uncached"] = sig.get("uncached", 0) + 1
-            else:
-                sig = self.signatures.get((phase, signature))
-            if sig is not None:
-                sig["seconds"] += duration
+                    self.uncached_compiles_total += 1
+                    sig["uncached"] += 1
         if is_backend and self.events_path:
             self.append_event(
                 {
                     "kind": "compile",
                     "phase": phase,
                     "signature": signature,
+                    "fun_name": entry.fun_name,
+                    "backend": kind,
                     "duration_s": round(duration, 6),
+                    "trace_s": round(made["trace"], 6),
+                    "lower_s": round(made["lower"], 6),
                     "cached": bool(cached),
                     "event": event,
                 }
             )
+
+    def _drop_oldest(self) -> None:
+        old = self._log.popleft()
+        self.dropped_total += 1
+        open_ = self._open.get(old.tid)
+        if open_ and open_[0] is old:  # nothing later can enclose it now
+            open_.popleft()
 
     def _observe_cache(self, kind: str) -> None:
         with self._lock:
@@ -348,7 +465,7 @@ class CompileTracker:
         ladder is just as warm)."""
         with self._lock:
             self.signatures.setdefault(
-                (phase, signature), {"count": 0, "seconds": 0.0}
+                (phase, signature), {"count": 0, "uncached": 0}
             )
 
     # -- events stream -------------------------------------------------
@@ -403,10 +520,114 @@ class CompileTracker:
 
     # -- carve-out support ---------------------------------------------
     def thread_seconds(self) -> float:
-        """Cumulative compile seconds observed on THIS thread (the
-        ledger bucket carve-out reads the delta across its window)."""
+        """Cumulative compile seconds observed on THIS thread, nested
+        entries counted once (the ledger bucket carve-out reads the
+        delta across its window)."""
         with self._lock:
             return self._thread_seconds.get(threading.get_ident(), 0.0)
+
+    # -- the log's readers ---------------------------------------------
+    @property
+    def compile_seconds_total(self) -> float:
+        with self._lock:
+            return sum(self._seconds.values())
+
+    def _after(self, until: Optional[float]) -> List[CompileEntry]:
+        """The log's entries that ended after ``until``, newest first (the
+        log is in the order of ending); the lock is held. LookupError
+        where the log has dropped entries and no longer reaches back to
+        ``until``: what ended after it can then not be told from what
+        ended before."""
+        out = []
+        if until is not None:
+            if self.dropped_total and self._log[0].t_end > until:
+                raise LookupError(
+                    f"the compile log dropped {self.dropped_total} entries "
+                    f"and starts after the time asked for"
+                )
+            for e in reversed(self._log):
+                if e.t_end <= until:
+                    break
+                out.append(e)
+        return out
+
+    def phase_seconds(
+        self, until: Optional[float] = None
+    ) -> Dict[str, float]:
+        """Seconds by kind (``PHASE_KINDS``) over the events that ended
+        by ``until`` (``time.monotonic()``; None: all): each entry's
+        ``self_seconds``, so the four add up to the wall time spent and a
+        second inside two entries is counted once, for the inner one.
+        LookupError where the log no longer reaches back to ``until``."""
+        with self._lock:
+            out = dict(self._seconds)
+            for e in self._after(until):
+                out[e.kind] -= e.self_seconds
+        return out
+
+    def programs(self, until: Optional[float] = None) -> int:
+        """Backend events by ``until``: programs compiled or loaded
+        (LookupError as ``phase_seconds``)."""
+        with self._lock:
+            return self.compiles_total - sum(
+                e.kind in _BACKEND_KINDS for e in self._after(until)
+            )
+
+    def program_table(
+        self, until: Optional[float] = None
+    ) -> List[Dict[str, Any]]:
+        """Set-up by program, most expensive first: one row a
+        ``fun_name`` over its un-nested entries (a program at several
+        shapes is one row with its counts), and under ``nested`` the
+        entries that ran inside those, by name and kind. Every second
+        here is INCLUSIVE, as ``jax_log_compiles`` would print it: a
+        nested entry's are part of its row's already, and of each
+        other's where they nest deeper. ``traces`` counts events, and
+        jax fires one, of microseconds, for a call that finds its jaxpr
+        in the Python-side cache. Where the log has dropped entries
+        the table is of the newest only, and says so in a last row
+        ``(dropped)`` whose ``entries`` is their number."""
+        rows: Dict[str, Dict[str, Any]] = {}
+
+        def row(name):
+            return rows.setdefault(name, {
+                "fun_name": name, "traces": 0, "trace_s": 0.0,
+                "lower_s": 0.0, "compiles": 0, "compile_s": 0.0,
+                "loads": 0, "load_s": 0.0, "nested": {},
+            })
+
+        with self._lock:
+            dropped = self.dropped_total
+            for e in self._log:
+                if until is not None and e.t_end > until:
+                    continue
+                if e.nested:
+                    inner = row(e.root().fun_name)["nested"].setdefault(
+                        (e.fun_name, e.kind),
+                        {"fun_name": e.fun_name, "kind": e.kind,
+                         "count": 0, "seconds": 0.0},
+                    )
+                    inner["count"] += 1
+                    inner["seconds"] += e.seconds
+                    continue
+                r = row(e.fun_name)
+                count, seconds = _TABLE_COLUMNS[e.kind]
+                r[seconds] += e.seconds
+                if count:
+                    r[count] += 1
+        out = []
+        for r in rows.values():
+            r["nested"] = sorted(
+                r["nested"].values(), key=lambda n: -n["seconds"]
+            )
+            r["seconds"] = sum(r[col] for _, col in _TABLE_COLUMNS.values())
+            out.append(r)
+        out.sort(key=lambda r: -r["seconds"])
+        if dropped:
+            out.append(dict(
+                row("(dropped)"), nested=[], seconds=0.0, entries=dropped
+            ))
+        return out
 
     # -- derived gauges ------------------------------------------------
     def compiled_shapes(self) -> int:
@@ -424,7 +645,7 @@ class CompileTracker:
         with self._lock:
             if not self.compiles_total:
                 return 0.0
-            return self.compile_seconds_total / self.compiles_total
+            return sum(self._seconds.values()) / self.compiles_total
 
     def warmup_eta_s(self) -> float:
         """Estimated seconds of compilation left to full ladder
@@ -444,11 +665,10 @@ class CompileTracker:
 
     def metrics(self) -> Dict[str, float]:
         with self._lock:
+            secs = dict(self._seconds)
             out = {
                 "compile_events_total": float(self.compiles_total),
-                "compile_seconds_total": round(
-                    self.compile_seconds_total, 4
-                ),
+                "compile_seconds_total": round(sum(secs.values()), 4),
                 "compiled_shapes": float(len(self.signatures)),
                 "shape_ladder_size": float(self.ladder_size),
                 # cold vs seeded is diagnosable from /metrics alone:
@@ -462,24 +682,14 @@ class CompileTracker:
                 ),
             }
         out["shape_ladder_coverage"] = round(self.coverage(), 4)
+        # where the seconds went: a seeded start pays trace, lower and
+        # cache_load, a cold one backend on top
+        for name, kind in (
+            ("trace", "trace"), ("lower", "lower"), ("backend", "compile"),
+            ("cache_load", "cache_load"),
+        ):
+            out[f"compile_{name}_seconds_total"] = round(secs[kind], 4)
         return out
-
-    def signature_table(self, top: int = 0) -> List[Dict[str, Any]]:
-        """Per-shape compile bill, most expensive first — what the AOT
-        precompiler (and ``trace_report --goodput``) consume."""
-        with self._lock:
-            rows = [
-                {
-                    "phase": ph,
-                    "signature": sig,
-                    "count": int(v["count"]),
-                    "uncached": int(v.get("uncached", 0)),
-                    "seconds": round(v["seconds"], 4),
-                }
-                for (ph, sig), v in self.signatures.items()
-            ]
-        rows.sort(key=lambda r: -r["seconds"])
-        return rows[:top] if top else rows
 
 
 # --------------------------------------------------------------------------

@@ -187,17 +187,242 @@ class TestCompileTracker:
         finally:
             goodput.set_thread_tracker(None)
 
-    def test_signature_table_sorted_by_cost(self):
+    def test_events_stream_names_the_program_and_its_phases(self, tmp_path):
+        """A backend line carries the function's name, whether the compiler
+        or the cache made it, and the tracing and lowering seconds of the
+        same program; ``trace_report --coldstart`` builds the table by
+        program from such lines."""
+        from tools import trace_report
+
+        events = str(tmp_path / "compile_events.jsonl")
+        tracker = goodput.CompileTracker(events_path=events)
+
+        def halve_then_add(x):
+            return x / 2 + 3
+
+        with goodput.dispatch_scope(tracker, "fwd_bwd", "pad13"):
+            jax.jit(halve_then_add)(jnp.ones(13)).block_until_ready()
+        recs = [json.loads(line) for line in open(events) if line.strip()]
+        mine = [r for r in recs if r.get("fun_name") == "halve_then_add"]
+        assert len(mine) == 1 and mine[0]["kind"] == "compile"
+        assert mine[0]["backend"] in ("compile", "cache_load")
+        assert mine[0]["trace_s"] > 0 and mine[0]["lower_s"] > 0
+        row = next(
+            r for r in tracker.program_table()
+            if r["fun_name"] == "halve_then_add"
+        )
+        assert row["trace_s"] == pytest.approx(mine[0]["trace_s"], abs=1e-5)
+        assert row["lower_s"] == pytest.approx(mine[0]["lower_s"], abs=1e-5)
+        cw = trace_report.coldstart_summary(trace_report.load_coldstart(events))
+        prow = next(
+            r for r in cw["programs"] if r["fun_name"] == "halve_then_add"
+        )
+        assert prow["count"] == 1
+        assert prow["trace_s"] == pytest.approx(mine[0]["trace_s"], abs=1e-3)
+        assert "halve_then_add" in trace_report.format_coldstart(cw)
+
+
+# ==========================================================================
+# CompileTracker's log: set-up by program and by phase
+# ==========================================================================
+TRACE = "/jax/core/compile/jaxpr_trace_duration"
+LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+BACKEND = "/jax/core/compile/backend_compile_duration"
+
+
+class TestCompileLog:
+    def _tracked(self, tracker):
+        goodput.set_thread_tracker(tracker, phase="trainer")
+
+    def teardown_method(self):
+        goodput.set_thread_tracker(None)
+
+    def test_a_function_traced_inside_anothers_trace_counts_once(self):
         tracker = goodput.CompileTracker()
-        tracker._observe(
-            "a", "s1", 1.0, "/jax/core/compile/backend_compile_duration"
+        self._tracked(tracker)
+
+        @jax.jit
+        def inner_helper(x):
+            return jnp.tanh(x) * 3
+
+        def outer_program(x):
+            return inner_helper(x) + inner_helper(x * 2)
+
+        jax.jit(outer_program)(jnp.ones(17)).block_until_ready()
+        with tracker._lock:
+            log = list(tracker._log)
+        inner = [e for e in log if e.fun_name == "inner_helper"]
+        outer = [e for e in log if e.fun_name == "outer_program"]
+        assert inner and all(e.nested and e.kind == "trace" for e in inner)
+        assert {e.parent.fun_name for e in inner} == {"outer_program"}
+        assert [e.kind for e in outer] == ["trace", "lower", "compile"]
+        assert not any(e.nested for e in outer)
+        secs = tracker.phase_seconds()
+        by_hand = sum(
+            e.seconds for e in log if e.kind == "trace" and not e.nested
         )
-        tracker._observe(
-            "b", "s2", 5.0, "/jax/core/compile/backend_compile_duration"
+        assert secs["trace"] == pytest.approx(by_hand)
+        assert secs["trace"] < sum(
+            e.seconds for e in log if e.kind == "trace"
         )
-        rows = tracker.signature_table()
-        assert rows[0]["phase"] == "b" and rows[0]["seconds"] == 5.0
+        # the totals every other reader uses are the same seconds
+        assert tracker.compile_seconds_total == pytest.approx(
+            sum(secs.values())
+        )
+        assert tracker.thread_seconds() == pytest.approx(sum(secs.values()))
+        table = {r["fun_name"]: r for r in tracker.program_table()}
+        assert "inner_helper" not in table  # never made on its own
+        row = table["outer_program"]
+        assert row["traces"] == 1 and row["compiles"] + row["loads"] == 1
+        nested = {n["fun_name"]: n for n in row["nested"]}
+        assert nested["inner_helper"]["kind"] == "trace"
+        assert nested["inner_helper"]["seconds"] <= row["trace_s"]
+
+    def test_a_cache_miss_is_compile_and_a_hit_is_cache_load(self, tmp_path):
+        from areal_tpu.utils import compile_cache
+
+        inherited = os.environ.pop(compile_cache.CACHE_DIR_ENV, None)
+        x = jnp.ones(19)  # made before the tracker listens: one program
+        tracker = goodput.CompileTracker()
+        self._tracked(tracker)
+
+        def cached_program(x):
+            return jnp.cos(x) * 5 + 1
+
+        try:
+            # scoped: the enable is process-global (tests/test_precompile.py)
+            assert compile_cache.enable_compilation_cache(str(tmp_path))
+            jax.jit(cached_program)(x).block_until_ready()
+            miss = tracker.phase_seconds()
+            assert miss["compile"] > 0 and miss["cache_load"] == 0
+            jax.clear_caches()  # the in-memory executable goes, the file stays
+            jax.jit(cached_program)(x).block_until_ready()
+        finally:
+            compile_cache.disable_compilation_cache()
+            if inherited is not None:
+                os.environ[compile_cache.CACHE_DIR_ENV] = inherited
+        hit = tracker.phase_seconds()
+        assert hit["compile"] == miss["compile"] and hit["cache_load"] > 0
+        row = next(
+            r for r in tracker.program_table()
+            if r["fun_name"] == "cached_program"
+        )
+        assert (row["compiles"], row["loads"], row["traces"]) == (1, 1, 2)
+        assert row["load_s"] == pytest.approx(hit["cache_load"])
+        assert tracker.programs() == 2 == tracker.compiles_total
+        m = tracker.metrics()
+        assert m["compile_backend_seconds_total"] == pytest.approx(
+            hit["compile"], abs=1e-4
+        )
+        assert m["compile_cache_load_seconds_total"] == pytest.approx(
+            hit["cache_load"], abs=1e-4
+        )
+        assert m["compile_trace_seconds_total"] > 0
+        assert m["compile_lower_seconds_total"] > 0
+
+    def _fed(self, clk):
+        """trace f 1 s, lower f 0.5 s, compile f 2 s, then g from the cache
+        in 1 s: ends at 1001, 1003, 1006, 1008 on the fake clock."""
+        tracker = goodput.CompileTracker(time_fn=clk)
+        clk.tick(1.0)
+        tracker._observe("trainer", "", 1.0, TRACE, fun_name="f")
+        clk.tick(2.0)
+        tracker._observe("trainer", "", 0.5, LOWER, fun_name="jit(f)")
+        clk.tick(3.0)
+        tracker._observe("trainer", "", 2.0, BACKEND, False, fun_name="jit(f)")
+        clk.tick(2.0)
+        tracker._observe("trainer", "", 1.0, BACKEND, True, fun_name="jit(g)")
+        return tracker
+
+    def test_until_cuts_between_two_events(self):
+        tracker = self._fed(FakeClock())
+        assert tracker.phase_seconds(1004.0) == {
+            "trace": 1.0, "lower": 0.5, "compile": 0.0, "cache_load": 0.0,
+        }
+        assert tracker.programs(1004.0) == 0
+        assert tracker.programs(1007.0) == 1 and tracker.programs() == 2
+        assert tracker.phase_seconds(1007.0)["compile"] == 2.0
+        assert tracker.phase_seconds() == {
+            "trace": 1.0, "lower": 0.5, "compile": 2.0, "cache_load": 1.0,
+        }
+        assert [r["fun_name"] for r in tracker.program_table(1007.0)] == ["f"]
+
+    def test_program_table_is_one_row_a_function_most_expensive_first(self):
+        clk = FakeClock()
+        tracker = self._fed(clk)
+        clk.tick(4.0)  # f again, at another shape: the same row
+        tracker._observe("trainer", "", 3.0, BACKEND, False, fun_name="jit(f)")
+        rows = tracker.program_table()
+        assert [r["fun_name"] for r in rows] == ["f", "g"]
+        assert rows[0] == {
+            "fun_name": "f", "traces": 1, "trace_s": 1.0, "lower_s": 0.5,
+            "compiles": 2, "compile_s": 5.0, "loads": 0, "load_s": 0.0,
+            "nested": [], "seconds": 6.5,
+        }
+        assert (rows[1]["loads"], rows[1]["load_s"]) == (1, 1.0)
         assert tracker.warmup_eta_s() == 0.0  # ladder unknown
+
+    def test_the_log_is_bounded_and_what_it_drops_stays_in_the_totals(
+        self, monkeypatch
+    ):
+        monkeypatch.setattr(goodput, "_LOG_ENTRIES", 3)
+        tracker = self._fed(FakeClock())  # four events into a log of three
+        assert tracker.dropped_total == 1 and len(tracker._log) == 3
+        assert tracker.phase_seconds() == {
+            "trace": 1.0, "lower": 0.5, "compile": 2.0, "cache_load": 1.0,
+        }
+        assert tracker.programs() == 2
+        assert tracker.compile_seconds_total == pytest.approx(4.5)
+        # a cut the log still reaches back to is answered as before (the
+        # entry dropped ended before it), one it does not reach raises
+        assert tracker.programs(1007.0) == 1
+        assert tracker.phase_seconds(1007.0)["trace"] == 1.0
+        with pytest.raises(LookupError):
+            tracker.phase_seconds(1000.5)
+        with pytest.raises(LookupError):
+            tracker.programs(1000.5)
+        # and the table says that it is of the newest entries only
+        last = tracker.program_table()[-1]
+        assert (last["fun_name"], last["entries"]) == ("(dropped)", 1)
+
+    def test_a_second_goes_to_the_innermost_entrys_kind(self):
+        """An eager program loaded while a program is being traced: its
+        second is ``cache_load``'s and leaves the trace's, so the backend
+        kinds equal what a count from outside sums (``CompileWatch``)."""
+        clk = FakeClock()
+        tracker = goodput.CompileTracker(time_fn=clk)
+        clk.tick(1.0)
+        tracker._observe("trainer", "", 0.25, BACKEND, True, fun_name="jit(ones)")
+        clk.tick(1.0)
+        tracker._observe("trainer", "", 2.0, TRACE, fun_name="grad_step")
+        assert tracker.phase_seconds() == {
+            "trace": 1.75, "lower": 0.0, "compile": 0.0, "cache_load": 0.25,
+        }
+        assert tracker.programs() == 1
+        assert tracker.compile_seconds_total == pytest.approx(2.0)
+        (row,) = tracker.program_table()
+        assert row["fun_name"] == "grad_step" and row["trace_s"] == 2.0
+        assert row["nested"] == [{"fun_name": "ones", "kind": "cache_load",
+                                  "count": 1, "seconds": 0.25}]
+
+    def test_the_ledgers_compile_carve_out_counts_nested_seconds_once(self):
+        clk = FakeClock()
+        tracker = goodput.CompileTracker(time_fn=clk)
+        led = goodput.GoodputLedger(
+            "trainer", goodput.TRAINER_BUCKETS, compile_tracker=tracker,
+            time_fn=clk,
+        )
+        with led.bucket("fwd_bwd"):
+            clk.tick(1.0)  # a kernel wrapper traced inside grad_step's trace
+            tracker._observe("fwd_bwd", "", 0.4, TRACE, fun_name="wrapper")
+            clk.tick(0.5)
+            tracker._observe("fwd_bwd", "", 1.5, TRACE, fun_name="grad_step")
+            clk.tick(1.0)  # the program running
+        secs = led.seconds()
+        assert secs["compile"] == pytest.approx(1.5)  # not 1.9
+        assert secs["fwd_bwd"] == pytest.approx(1.0)
+        assert secs["compile"] <= 2.5  # the bucket's wall time
+        assert tracker.thread_seconds() == pytest.approx(1.5)
 
 
 # ==========================================================================

@@ -294,3 +294,38 @@ def test_whole_op_gradients_with_the_kernels_equal_the_plain_chunked_forms(
     ``_chunked_plain`` with XLA's differentiation throughout."""
     a = _inputs(t, seg_lens, dk=128, dv=128, decay=decay)
     _close(_op_grads(t, True)(*a), _op_grads(t, False)(*a), 2e-5, decay)
+
+
+def test_kernel_wrappers_are_nested_beneath_the_program_that_traces_them():
+    """Each kernel call sits behind a jitted function (PR 35), whose trace
+    fires inside the trace of the program that calls it: the compile log
+    lists it beneath that program and counts its seconds once."""
+    from areal_tpu.utils import goodput
+
+    a = _inputs(130, h=3)  # a shape no other test of this file traces
+    tracker = goodput.CompileTracker()
+    goodput.set_thread_tracker(tracker, phase="trainer")
+    try:
+        def scan_program(*x):
+            return kda.kda_scan(*x, impl="kernel", interpret=True)[0]
+
+        jax.jit(scan_program)(*a).block_until_ready()
+    finally:
+        goodput.set_thread_tracker(None)
+    table = {r["fun_name"]: r for r in tracker.program_table()}
+    wrappers = {"_operands_kernel", "_state_fwd_kernel"}
+    assert not wrappers & set(table)  # no rows of their own
+    nested = {n["fun_name"]: n for n in table["scan_program"]["nested"]
+              if n["kind"] == "trace"}
+    assert wrappers <= set(nested)
+    assert all(nested[w]["seconds"] <= table["scan_program"]["trace_s"]
+               for w in wrappers)
+    with tracker._lock:
+        log = list(tracker._log)
+    every_trace = sum(e.seconds for e in log if e.kind == "trace")
+    secs = tracker.phase_seconds()
+    assert secs["trace"] < every_trace  # the wrappers' seconds once
+    # every second once: the four kinds sum to the outermost intervals
+    assert sum(secs.values()) == pytest.approx(
+        sum(e.seconds for e in log if not e.nested)
+    )

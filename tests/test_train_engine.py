@@ -63,6 +63,43 @@ def test_sft_loss_descends():
     assert all(s == 1.0 for s in [lm.train_lm(batch)["update_successful"]])
 
 
+def test_every_trainer_program_is_in_the_compile_log_by_name():
+    """From ``initialize`` on the trainer's compile tracker is current for
+    the thread: ``init_params``, the optimizer state, the zeroed accumulator
+    and ``logp_forward`` are in its table with no line at their call sites,
+    and it counts what the benchmark's own watch counts from outside."""
+    from areal_tpu.utils import goodput
+    from benchmark import harness
+
+    goodput.reset_trainer_ledger()
+    watch = harness.CompileWatch()  # before the engine, as benchmark/run.py
+    eng = _engine()
+    batch = _toy_batch()
+    eng.forward(batch)
+    eng.train_batch(batch, sft_loss_fn, sft_loss_weight_fn)
+    tracker = goodput.trainer_tracker()
+    table = {r["fun_name"]: r for r in tracker.program_table()}
+    assert {"init_params", "init_fn", "zero_grads", "logp_forward",
+            "grad_step", "apply_step"} <= set(table)
+    for name in ("init_params", "logp_forward", "grad_step", "apply_step"):
+        row = table[name]
+        assert row["traces"] == 1 and row["compiles"] + row["loads"] == 1
+        assert row["trace_s"] > 0 and row["lower_s"] > 0
+    assert tracker.programs() == watch.compiles > 0
+    secs = tracker.phase_seconds()
+    assert secs["compile"] + secs["cache_load"] == pytest.approx(watch.seconds)
+    # the two dispatch scopes keep their phase and shape; the rest is the
+    # thread's default
+    assert any(ph == "fwd_bwd" and sig.startswith("pad")
+               for ph, sig in tracker.signatures)
+    assert {("optim", ""), ("trainer", "")} <= set(tracker.signatures)
+    # a destroyed engine takes the thread's default with it
+    eng.destroy()
+    n = tracker.programs()
+    jax.jit(lambda x: x * 3 + 1)(jnp.ones(7))
+    assert tracker.programs() == n
+
+
 def test_microbatching_matches_single_batch():
     """Grad accumulation over token-budget microbatches must equal one big
     batch (reference base_hf_engine train_batch weighting semantics)."""

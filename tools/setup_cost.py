@@ -13,6 +13,12 @@ of the lowered text by kernel name, the text's size. The seconds are this
 sandbox's CPU's and compare one tree with another on the same machine; no
 number here is a device's.
 
+This is the ESTIMATE made before a chip call. The measurement is the chip
+host's own: every benchmark run logs each program's seconds to trace, to
+lower, to compile and to load (``utils/goodput.CompileTracker``:
+``program_table()``, the traced line's ``setup_trace_s.train`` and its
+five siblings), and reads two to four times this tool's seconds.
+
 ``jax.default_backend()`` is the CPU here, so the choices the ops make from
 the platform (``ops/kda.scan_impl``, ``ops/moe.grouped_impl``, the splash
 kernel, ...) are steered to a TPU's for the length of the script.

@@ -1479,6 +1479,34 @@ def coldstart_summary(cs: Dict[str, Any]) -> Dict[str, Any]:
         for (ph, sig), v in shapes.items()
     ]
     shape_rows.sort(key=lambda r: -r["seconds"])
+    # set-up by program, from the backend lines' fun_name / backend /
+    # trace_s / lower_s (a stream written before those keys existed has no
+    # such rows). The stream holds one line a PROGRAM, so this is less than
+    # CompileTracker.program_table() holds in the process: no nested rows,
+    # and a function that was traced and never compiled (a jitted helper
+    # inside another's trace) is nowhere in it, its seconds part of the
+    # trace_s of the program that traced it. What the stream has over the
+    # log: it outlives the process, and never drops a line
+    programs: Dict[str, Dict[str, Any]] = {}
+    for ev in compiles:
+        if "fun_name" not in ev:
+            continue
+        row = programs.setdefault(ev["fun_name"], {
+            "fun_name": ev["fun_name"], "count": 0, "trace_s": 0.0,
+            "lower_s": 0.0, "compiles": 0, "compile_s": 0.0, "loads": 0,
+            "load_s": 0.0,
+        })
+        row["count"] += 1
+        row["trace_s"] += float(ev.get("trace_s", 0.0))
+        row["lower_s"] += float(ev.get("lower_s", 0.0))
+        made = "load" if ev.get("backend") == "cache_load" else "compile"
+        row[made + "s"] += 1
+        row[made + "_s"] += float(ev.get("duration_s", 0.0))
+    program_rows = sorted(
+        programs.values(),
+        key=lambda r: -(r["trace_s"] + r["lower_s"] + r["compile_s"]
+                        + r["load_s"]),
+    )
     ready = cs["lifecycle"].get("ready") or {}
     return {
         "fingerprint": header.get("fingerprint"),
@@ -1497,6 +1525,7 @@ def coldstart_summary(cs: Dict[str, Any]) -> Dict[str, Any]:
         ),
         "precompile": cs["precompile"],
         "shapes": shape_rows,
+        "programs": program_rows,
     }
 
 
@@ -1539,6 +1568,20 @@ def format_coldstart(cw: Dict[str, Any]) -> str:
             rows.append(
                 f"  {r['phase']:<12}{r['signature']:<34}"
                 f"{r['count']:>6d}{r['cached']:>5d}{r['seconds']:>9.3f}"
+            )
+    if cw.get("programs"):
+        header = (
+            f"  {'program':<28}{'count':>6}{'trace s':>9}{'lower s':>9}"
+            f"{'compiled':>9}{'s':>9}{'loaded':>7}{'s':>8}"
+        )
+        rows.append(header)
+        rows.append("  " + "-" * (len(header) - 2))
+        for r in cw["programs"][:20]:
+            rows.append(
+                f"  {r['fun_name'][:27]:<28}{r['count']:>6d}"
+                f"{r['trace_s']:>9.3f}{r['lower_s']:>9.3f}"
+                f"{r['compiles']:>9d}{r['compile_s']:>9.3f}"
+                f"{r['loads']:>7d}{r['load_s']:>8.3f}"
             )
     return "\n".join(rows)
 
