@@ -38,6 +38,13 @@ MOE_COUNTS = {
     "moe_assignments": "assignments",
     "moe_max_expert_assignments": "max_expert_assignments",
 }
+# the same of a model that HOLDS A SHARE of its experts, and of no other:
+# the rows its expert paths ran over and the token chunks whose held pairs
+# filled more than one slab of the bounded buffer (``ops/moe._run_held``)
+MOE_HELD_COUNTS = {
+    "moe_stream_rows": "stream_rows",
+    "moe_overflow_chunks": "overflow_chunks",
+}
 # the same for a sparse-attention model's selection (``DSAStats`` fields):
 # int32 [L] a microbatch, which the host sums exactly
 DSA_COUNTS = {
@@ -131,6 +138,11 @@ def build_grad_step(
                     **{k: getattr(moe_stats, v)
                        for k, v in MOE_COUNTS.items()},
                 )
+                if mc.experts_held:
+                    stats.update({
+                        k: getattr(moe_stats, v)
+                        for k, v in MOE_HELD_COUNTS.items()
+                    })
             w = loss_weight_fn(arrays).astype(jnp.float32)
             return loss * w, (loss, stats, w)
 

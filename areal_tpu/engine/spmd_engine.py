@@ -242,6 +242,10 @@ class SPMDTrainEngine(TrainEngine):
         if mc.is_moe:
             self.moe_impl = moe_ops.grouped_impl()
             self._counters.update(dict.fromkeys(programs.MOE_COUNTS, 0))
+            if mc.experts_held:
+                self._counters.update(
+                    dict.fromkeys(programs.MOE_HELD_COUNTS, 0)
+                )
         if mc.has_indexer:
             self.dsa_select_impl = sparse_attention.select_impl()
             self._counters.update(dict.fromkeys(programs.DSA_COUNTS, 0))
@@ -334,7 +338,11 @@ class SPMDTrainEngine(TrainEngine):
         microbatches; for a sparse model also ``moe_assignments`` and
         ``moe_max_expert_assignments`` (the routed pairs of the train
         microbatches and the busiest expert's, summed over layers; of the
-        experts held, where the model holds a share); for a model with an
+        experts held, where the model holds a share, and then also
+        ``moe_stream_rows`` and ``moe_overflow_chunks``: the rows of the
+        sorted stream its expert paths ran over, a slab of the bounded
+        buffer's rows times the slabs each token chunk's held pairs filled,
+        and the chunks where they filled more than one); for a model with an
         indexer ``dsa_kept_pairs`` and ``dsa_causal_pairs`` (the selected and
         the causal (query, key) pairs, likewise); for a model with gated
         delta-rule layers ``kda_tokens``, ``kda_chunk_slots`` and
@@ -630,7 +638,7 @@ class SPMDTrainEngine(TrainEngine):
         }
         for j, k in enumerate(stat_keys):
             # a count: summed, counted, and per step
-            if k in programs.MOE_COUNTS:
+            if k in programs.MOE_COUNTS or k in programs.MOE_HELD_COUNTS:
                 out[k] = float(h_stats[:, j].sum())
                 self._counters[k] += int(out[k])
                 stats_tracker.counter(**{f"spmd/{k}": int(out[k])})

@@ -616,15 +616,13 @@ def _apply_kinds(
     def total(stats, field, reduce=jnp.sum):
         return reduce(jnp.concatenate([getattr(s, field) for s in stats]))
 
-    zero = jnp.zeros((), jnp.float32)
     return x, LayerStats(
         MoEStats(
-            aux_loss=total(moe, "aux_loss", jnp.mean) if moe else zero,
-            assignments=total(moe, "assignments") if moe else zero,
-            max_expert_assignments=(
-                total(moe, "max_expert_assignments") if moe else zero
-            ),
-        ),
+            total(moe, "aux_loss", jnp.mean),
+            # the counts; a held share's two are None otherwise
+            *(None if getattr(moe[0], f) is None else total(moe, f)
+              for f in MoEStats._fields[1:]),
+        ) if moe else MoEStats(*(jnp.zeros((), jnp.float32),) * 3),
         None,
         KDAStats(*(total(kda, f) for f in KDAStats._fields)) if kda else None,
     )
@@ -733,9 +731,9 @@ def apply(
         moe = aux.moe
         return logits, LayerStats(
             MoEStats(
-                aux_loss=jnp.mean(moe.aux_loss),
-                assignments=jnp.sum(moe.assignments),
-                max_expert_assignments=jnp.sum(moe.max_expert_assignments),
+                jnp.mean(moe.aux_loss),
+                # the counts; a held share's two are None otherwise
+                *(None if a is None else jnp.sum(a) for a in moe[1:]),
             ),
             aux.dsa,
         )

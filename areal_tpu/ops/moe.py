@@ -9,8 +9,9 @@ is un-permuted and summed with the routing weights. There is no capacity and
 nothing is dropped, so a token's output never depends on what it was packed
 with: the recomputed logprobs, the gradient pass and the generation engine
 agree whatever their batches (a capped dispatch made them differ by
-construction). Every shape is static: the sorted stream always has
-tokens x k rows, and the group sizes are data.
+construction). Every shape is static: the sorted stream has tokens x k rows
+(a share's: slabs of a bounded buffer's rows, below), and the group sizes
+are data.
 
 Padding (``valid`` false) takes no part: its pairs sort behind every group,
 are zeroed on the way in and on the way out, and count in no statistic.
@@ -18,11 +19,20 @@ are zeroed on the way in and on the way out, and count in no statistic.
 The layer may HOLD fewer experts than the router scores (one chip's share of
 an expert-parallel deployment: ``w_gate`` has fewer experts than ``w_router``
 has outputs). It then routes over all of them, as the whole layer would,
-and computes the part of the result that its own experts give: a pair
-routed to an expert held elsewhere is treated as padding's are. The shares
-of all the holders add up to the whole layer's output; the exchange that
-would bring each holder the other chips' tokens is not here (ROADMAP B.2).
-The statistics then count the pairs routed to experts held.
+and computes the part of the result that its own experts give. A pair routed
+to an expert held elsewhere sorts behind the held ones, as padding's do, and
+the expert path never touches it: the dispatch gathers, the grouped matmuls
+read and the combine un-permutes SLABS of the sorted stream, each a bounded
+buffer of static size (the share's expected pairs times ``_HELD_SLACK``, in
+whole row tiles). The first slab always runs; a chunk whose held pairs pass
+it runs as many more as they fill, in a loop (forward, and again backward:
+no loop is differentiated through), so there is still no capacity and no
+drop at any load. The shares of all the holders add up to the whole layer's
+output; the exchange that would bring each holder the other chips' tokens
+is not here (ROADMAP B.2): it fills this buffer from the wire and inherits
+its rule for the batch that overflows. The statistics then count the pairs
+routed to experts held, and two more say how the buffer fared: the rows the
+expert path ran over and the chunks that needed more than one slab.
 
 Which grouped matmul runs is resolved from the platform (``grouped_impl``),
 as the trainer's ``attn_impl: auto`` is: the Pallas ``megablox`` kernel on a
@@ -42,10 +52,13 @@ per-token sigmoid gate where the family has one) is part of the sparse layer
 
 Device scopes (``utils/tracing.DEVICE_SCOPES``), nested inside the layer's
 ``mlp``: ``moe_route`` (router matmul, softmax, top-k, load statistics),
-``moe_dispatch`` (sort, permute, un-permute, weighted sum), ``moe_experts``
-(the grouped matmuls and the gate).
+``moe_dispatch`` (sort, permute, un-permute, weighted sum; of a share: the
+sort of every pair, a slab's row gather, the [tokens, slab] product that
+sums its rows back onto their tokens), ``moe_experts`` (the grouped matmuls
+and the gate).
 """
 
+import functools
 from typing import Dict, NamedTuple, Optional, Tuple
 
 import jax
@@ -75,6 +88,12 @@ class MoEStats(NamedTuple):
     aux_loss: jnp.ndarray  # Switch-style load-balancing loss
     assignments: jnp.ndarray  # valid tokens x k
     max_expert_assignments: jnp.ndarray  # the busiest expert's count
+    # of a layer that holds a share of its experts only (None, no leaf at
+    # all, otherwise): the rows its expert path ran over (a slab's, times
+    # the slabs each chunk's held pairs filled) and the chunks whose held
+    # pairs filled more than one
+    stream_rows: Optional[jnp.ndarray] = None
+    overflow_chunks: Optional[jnp.ndarray] = None
 
 
 def router_topk(
@@ -242,12 +261,12 @@ _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
 # rows of the sorted stream (tokens x k) above which the dispatch and the
-# grouped matmuls run in token chunks of at most this many rows. A layer
-# that holds a share of the experts still sorts every pair (a static shape
-# cannot know how many are its own): one 16,384-token row of 8 a token is
-# 131,072 rows, 0.5 GB in bf16 for each of the stream's half-dozen arrays,
-# forward and backward. 32,768 is the four-chip sparse cell's stream (one
-# 4096-token row a chip), which stays one chunk.
+# grouped matmuls run in token chunks of at most this many rows: one
+# 16,384-token row of 8 a token is 131,072 rows, 0.5 GB in bf16 for each of
+# the stream's half-dozen arrays, forward and backward. A layer that holds a
+# share of the experts sorts every pair of a chunk and runs the rest over
+# slabs of it (``_HELD_SLACK``). 32,768 is the four-chip sparse cell's
+# stream (one 4096-token row a chip), which stays one chunk.
 _STREAM_ROWS = 32768
 
 
@@ -294,6 +313,243 @@ def _run_experts(xf, pair_expert, topk_p, group_sizes, w_gate, w_up, w_down):
         )
     with device_scope("moe_dispatch"):
         return _combine(ys, topk_p, order, inv, pair_ok)
+
+
+# A layer that HOLDS A SHARE of the experts it routes over (``e`` of
+# ``n_routed``) expects rows x e / n_routed of a stream's pairs. Its expert
+# path runs over SLABS of the sorted stream of that many rows times this
+# slack, in whole ``_GMM_TILE_M`` tiles: the first slab always, and one more
+# for every further slab's worth of held pairs (``_run_held``). Nothing is
+# capped and nothing dropped; every shape is the slab's. Read on the chip
+# (PERF.md, PR 37, over the two cells' own batches, held pairs a 32,768-row
+# chunk against the even share): Kimi's sigmoid router over 256, 8 held, mean
+# 888, largest 1,127 of 1,024 even (1.1 x); Keye's random softmax router over
+# 128, 16 held, mean 4,141, median 3,585, 99th percentile 13,299, largest
+# 16,082 of 4,096 even (3.9 x), 42 of 440 chunks past twice the even share.
+# At 2 a Kimi chunk is one slab always and a Keye chunk one slab nine times
+# in ten; at 1 a second slab (three more grouped matmuls that each read all
+# the held weights) would run in 15% and 42% of the chunks; at 3 the [G, C]
+# product below costs half as much again in every chunk to save 7% of them
+# a second slab.
+_HELD_SLACK = 2
+
+
+def _held_rows(rows: int, e: int, n_routed: int) -> int:
+    """Rows of a slab of a ``rows``-row stream of which the layer holds
+    ``e`` of ``n_routed`` experts (``rows`` where that would be no fewer)."""
+    c = -(-rows * e * _HELD_SLACK // n_routed)
+    return min(-(-c // _GMM_TILE_M) * _GMM_TILE_M, rows)
+
+
+def _onto_tokens(rows, tok, w, g):
+    """[C, D] rows -> [G, D]: token t's sum of ``w[i] * rows[i]`` over
+    the rows i with ``tok[i] == t`` (``w`` None: 1). The transpose of a
+    row gather, as a product with the [G, C] matrix that holds ``w[i]`` at
+    (tok[i], i): float32 accumulation of bf16 products, as the whole form's
+    weighted sum, and no scatter. A row of no token (``tok[i] == g``) must
+    come in as zeros: 0 x what the grouped matmul left unwritten is not 0.
+    On the chip (``tools/microbench_moe --held``, PR 37, G 4,096): 0.22 ms
+    at the Kimi chunk's C 2,048 x D 2,304, where gathering every pair's row
+    clamped to one zero row and summing k a token reads 0.95 ms; 0.78 ms
+    against 0.65 at the long-row chunk's C 8,192 x D 2,048."""
+    onto = jnp.arange(g, dtype=tok.dtype)[:, None] == tok[None]
+    m = onto.astype(rows.dtype) if w is None else jnp.where(
+        onto, w.astype(rows.dtype)[None], 0
+    )
+    return jnp.dot(m, rows, preferred_element_type=jnp.float32).astype(
+        rows.dtype
+    )
+
+
+# The two permutations again, over the ``c`` rows of a slab: ``tok[i]`` is
+# the token of the pair at the slab's position i, ``g`` behind the held
+# pairs; ``w_c[i]`` that pair's routing weight, 0 there.
+@jax.custom_vjp
+def _dispatch_held(xf, tok):
+    """[G, D] tokens -> [C, D] rows sorted by expert, zeros behind them."""
+    return jnp.concatenate([xf, jnp.zeros((1, xf.shape[1]), xf.dtype)])[tok]
+
+
+def _dispatch_held_fwd(xf, tok):
+    return _dispatch_held(xf, tok), (tok, xf.shape[0])
+
+
+def _dispatch_held_bwd(res, g_xs):
+    tok, g = res
+    g_xs = jnp.where((tok < g)[:, None], g_xs, 0)
+    return _onto_tokens(g_xs, tok, None, g), None
+
+
+_dispatch_held.defvjp(_dispatch_held_fwd, _dispatch_held_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _combine_held(ys, w_c, tok, g):
+    """[C, D] sorted expert outputs and their [C] float32 routing weights
+    -> [G, D]: what the slab's pairs add to their tokens."""
+    ys = jnp.where((tok < g)[:, None], ys, 0)
+    return _onto_tokens(ys, tok, w_c, g)
+
+
+def _combine_held_fwd(ys, w_c, tok, g):
+    return _combine_held(ys, w_c, tok, g), (ys, w_c, tok)
+
+
+def _combine_held_bwd(g, res, g_out):
+    ys, w_c, tok = res
+    # row i of the slab: its token's cotangent (zeros behind the held
+    # pairs), times that pair's weight for the row, dotted with the row for
+    # the weight
+    gg = jnp.concatenate(
+        [g_out, jnp.zeros((1, g_out.shape[1]), g_out.dtype)]
+    )[tok]
+    g_wc = jnp.where(tok < g, jnp.einsum(
+        "cd,cd->c", gg, ys, preferred_element_type=jnp.float32
+    ), 0)
+    return gg * w_c.astype(ys.dtype)[:, None], g_wc.astype(w_c.dtype), None
+
+
+_combine_held.defvjp(_combine_held_fwd, _combine_held_bwd)
+
+
+def _slab(lo, c, order, w, group_sizes):
+    """The slab at sorted positions [lo, lo + c): (each expert's pairs in it
+    [E], the token of each position [C], its routing weight [C]).
+    ``order`` is the sort, padded to a whole number of slabs."""
+    g, k = w.shape
+    ends = jnp.cumsum(group_sizes)
+    sizes = jnp.clip(ends, lo, lo + c) - jnp.clip(
+        ends - group_sizes, lo, lo + c
+    )
+    live = lo + jnp.arange(c) < ends[-1]
+    pair = jax.lax.dynamic_slice_in_dim(order, lo, c)
+    tok = jnp.where(live, pair // k, g)
+    w_c = jnp.where(live, w.reshape(-1)[pair], 0)
+    return sizes, tok, w_c
+
+
+def _slab_experts(xf, w_c, w_gate, w_up, w_down, sizes, tok):
+    """[G, D]: what one slab's pairs add to their tokens' outputs.
+    Differentiable in the first five."""
+    g, d = xf.shape
+    c = tok.shape[0]
+    impl = grouped_impl((c, d, w_gate.shape[-1]))
+    with device_scope("moe_dispatch"):
+        xs = _dispatch_held(xf, tok)
+    with device_scope("moe_experts"):
+        h = jax.nn.silu(
+            _grouped_matmul(xs, w_gate, sizes, impl)
+        ) * _grouped_matmul(xs, w_up, sizes, impl)
+        ys = _grouped_matmul(
+            jnp.where((tok < g)[:, None], h, 0), w_down, sizes, impl
+        )
+    with device_scope("moe_dispatch"):
+        return _combine_held(ys, w_c, tok, g)
+
+
+def _sorted_pairs(pair_expert, c):
+    """The pairs by expert, held ones first, padded to a whole number of
+    ``c``-row slabs."""
+    order = jnp.argsort(pair_expert, stable=True)
+    return jnp.pad(order, (0, -order.shape[0] % c))
+
+
+def _slabs(group_sizes, c):
+    """How many ``c``-row slabs the held pairs fill: one at least."""
+    return jnp.maximum(-(-group_sizes.sum() // c), 1)
+
+
+def _add(a, b):
+    """A further slab's part onto the sum so far, summed in float32."""
+    return (a.astype(jnp.float32) + b.astype(jnp.float32)).astype(a.dtype)
+
+
+def _slabs_forward(xf, pair_expert, topk_p, group_sizes, ws, c):
+    """``_run_experts`` over ``c``-row slabs of the sorted stream, in a loop
+    of as many trips as the held pairs fill slabs (one, where they fit).
+    The backward (``_run_slabs_bwd``) runs the first slab in line and the
+    others in a loop of the same kind, so that no loop is differentiated
+    through and an expert stack's cotangent is made once a slab that ran and
+    added to nothing where one did; the same here would lower every kernel
+    twice for the sake of one add of a [tokens, D] sum to zeros. ``ws``: the
+    three expert stacks."""
+    with device_scope("moe_dispatch"):
+        order = _sorted_pairs(pair_expert, c)
+
+    def slab(carry):
+        s, out = carry
+        with device_scope("moe_dispatch"):
+            sizes, tok, w_c = _slab(s * c, c, order, topk_p, group_sizes)
+        return s + 1, _add(out, _slab_experts(xf, w_c, *ws, sizes, tok))
+
+    n = _slabs(group_sizes, c)
+    with device_scope("moe_dispatch"):
+        return jax.lax.while_loop(
+            lambda sc: sc[0] < n, slab, (0, jnp.zeros_like(xf))
+        )[1]
+
+
+_run_slabs = jax.custom_vjp(_slabs_forward, nondiff_argnums=(5,))
+
+
+def _run_slabs_fwd(xf, pair_expert, topk_p, group_sizes, ws, c):
+    return _slabs_forward(xf, pair_expert, topk_p, group_sizes, ws, c), (
+        xf, pair_expert, topk_p, group_sizes, ws
+    )
+
+
+def _run_slabs_bwd(c, res, g_out):
+    xf, pair_expert, topk_p, group_sizes, ws = res
+    with device_scope("moe_dispatch"):
+        order = _sorted_pairs(pair_expert, c)
+        # pair j sits at sorted position inv[j]
+        inv = jnp.argsort(order[:pair_expert.shape[0]])
+
+    def grads(lo):
+        with device_scope("moe_dispatch"):
+            sizes, tok, w_c = _slab(lo, c, order, topk_p, group_sizes)
+        g_xf, g_wc, *g_ws = jax.vjp(
+            lambda *a: _slab_experts(*a, sizes, tok), xf, w_c, *ws
+        )[1](g_out)
+        with device_scope("moe_dispatch"):
+            # back to the pairs' own order: every pair outside the slab
+            # reads the one zero
+            at = inv - lo
+            g_w = jnp.concatenate([g_wc, jnp.zeros((1,), g_wc.dtype)])[
+                jnp.where((at >= 0) & (at < c), at, c)
+            ]
+        return (g_xf, g_w.reshape(topk_p.shape).astype(topk_p.dtype), *g_ws)
+
+    def more(carry):
+        s, acc = carry
+        return s + 1, tuple(map(_add, acc, grads(s * c)))
+
+    n = _slabs(group_sizes, c)
+    with device_scope("moe_dispatch"):
+        _, (g_xf, g_w, *g_ws) = jax.lax.while_loop(
+            lambda sc: sc[0] < n, more, (1, grads(0))
+        )
+    return g_xf, None, g_w, None, tuple(g_ws)
+
+
+_run_slabs.defvjp(_run_slabs_fwd, _run_slabs_bwd)
+
+
+def _run_held(xf, pair_expert, topk_p, group_sizes, w_gate, w_up, w_down, c):
+    """``_run_experts`` of a layer that holds a share, over slabs of ``c``
+    rows: (out [G, D], how many slabs ran)."""
+    if group_sizes is None:
+        with device_scope("moe_dispatch"):
+            group_sizes = _group_sizes(pair_expert, w_gate.shape[0])
+    if c == pair_expert.shape[0]:  # a slab no shorter than the stream
+        return _run_experts(
+            xf, pair_expert, topk_p, group_sizes, w_gate, w_up, w_down
+        ), jnp.ones((), jnp.int32)
+    with device_scope("moe_dispatch"):
+        n = _slabs(group_sizes, c)
+    return _run_slabs(
+        xf, pair_expert, topk_p, group_sizes, (w_gate, w_up, w_down), c
+    ), n
 
 
 def moe_ffn(
@@ -359,24 +615,35 @@ def moe_ffn(
             max_expert_assignments=load.max().astype(jnp.float32),
         )
     n_chunks = _token_chunks(g, k)
+    rows = g * k // n_chunks  # of one chunk's stream
+    run = _run_experts
+    if e < n_routed:  # a share: a chunk's expert path runs over a buffer
+        c = _held_rows(rows, e, n_routed)
+        run = functools.partial(_run_held, c=c)
     if n_chunks == 1:
-        out = _run_experts(
-            xf, pair_expert, topk_p, group_sizes, w_gate, w_up, w_down
-        )
+        out = run(xf, pair_expert, topk_p, group_sizes, w_gate, w_up, w_down)
     else:
         # a long stream in token chunks, one after the other, each chunk's
         # intermediates dropped after it (recomputed in its backward): the
         # stream's buffers are a chunk's. Dropless, so a token's output does
         # not depend on its chunk-mates
         def chunk(args):
-            return _run_experts(*args, None, w_gate, w_up, w_down)
+            return run(*args, None, w_gate, w_up, w_down)
 
         out = jax.lax.map(jax.checkpoint(chunk), (
             xf.reshape(n_chunks, -1, d),
             pair_expert.reshape(n_chunks, -1),
             topk_p.reshape(n_chunks, -1, k),
-        )).reshape(g, d)
-    out = out.astype(x.dtype)
+        ))
+    if e < n_routed:
+        out, slabs = out
+        with device_scope("moe_route"):
+            ran = (c * slabs.sum()).astype(jnp.float32)
+            over = (slabs > 1).sum().astype(jnp.float32)
+            if token_axes:
+                ran, over = jax.lax.psum((ran, over), token_axes)
+            stats = stats._replace(stream_rows=ran, overflow_chunks=over)
+    out = out.reshape(g, d).astype(x.dtype)
     return out.reshape(b, t, d), stats
 
 

@@ -14,6 +14,7 @@ but not read back without the chip).
 """
 
 import os
+import re
 
 import pytest
 
@@ -222,6 +223,78 @@ def test_sparse_layer_fwd_bwd_compiles_for_v5e(one_chip, monkeypatch, t):
         .compile()
     )
     assert compiled.as_text().count("tpu_custom_call") >= 9
+
+
+# (model width, expert width, experts routed over, experts held, a token):
+# one 4,096-token chunk of the two cells whose layer holds a share
+_HELD_CASES = [
+    pytest.param(2048, 768, 128, 16, 8, id="keye-16-of-128"),
+    pytest.param(2304, 1024, 256, 8, 8, id="kimi-8-of-256"),
+]
+
+
+@pytest.mark.parametrize("d,f,n_routed,e,k", _HELD_CASES)
+def test_held_share_layer_fwd_bwd_compiles_for_v5e(
+    one_chip, monkeypatch, d, f, n_routed, e, k
+):
+    """``ops/moe.moe_ffn`` of a layer that HOLDS A SHARE, forward and
+    backward at one token chunk of the long-row and the Kimi cell: the
+    expert path runs over slabs of twice the even share (8,192 and 2,048
+    rows of the 32,768-row stream): a loop over the slabs forward, the
+    first slab in line and a loop over the others backward. No array of the
+    whole stream's rows by the model's width is left, the kernels keep the
+    names ``moe_experts_roofline.train`` matches, and their number is held
+    (each is lowered in Python at every occurrence), not only bounded
+    below."""
+    import jax
+    import jax.numpy as jnp
+
+    from areal_tpu.ops import moe
+    from benchmark import program_trace
+
+    monkeypatch.setattr(moe, "grouped_impl", lambda shape=None: "megablox")
+    t = 4096
+    c = moe._held_rows(t * k, e, n_routed)
+    assert c == 2 * t * k * e // n_routed
+
+    def loss(x, wr, wg, wu, wd, valid):
+        out, stats = moe.moe_ffn(
+            x, wr, wg, wu, wd, num_experts_per_tok=k, norm_topk_prob=True,
+            valid=valid,
+        )
+        return out.astype(jnp.float32).sum(), stats
+
+    compiled = (
+        jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4), has_aux=True))
+        .lower(
+            _sds((1, t, d), jnp.bfloat16, one_chip),
+            _sds((d, n_routed), jnp.bfloat16, one_chip),
+            _sds((e, d, f), jnp.bfloat16, one_chip),
+            _sds((e, d, f), jnp.bfloat16, one_chip),
+            _sds((e, f, d), jnp.bfloat16, one_chip),
+            _sds((1, t), jnp.bool_, one_chip),
+        )
+        .compile()
+    )
+    text = compiled.as_text()
+    # the forward loop's body (3 gmm), the backward's first slab and its
+    # loop's body (each 3 gmm again, 3 gmm transposed, 3 tgmm), less what
+    # the compiler shares
+    kernels = re.findall(r"%(t?gmm)[.\d]* = \S+ custom-call\(", text)
+    assert sorted(kernels) == ["gmm"] * 12 + ["tgmm"] * 6
+    assert text.count("tpu_custom_call") == 18
+    assert f"[{c},{d}]" in text and f"[{t * k},{d}]" not in text
+    # both transposes are hand-written: the only scatters are megablox's
+    # own, into its [groups + tiles] metadata
+    scattered = re.findall(r"= \w+\[([0-9,]*)\]\S* scatter\(", text)
+    assert scattered and all("," not in dims for dims in scattered), scattered
+    # the loops' bodies under the layer's scopes, as the first slab is
+    looped = {
+        n for n in re.findall(r'op_name="([^"]+)"', text) if "/while/" in n
+    }
+    assert {program_trace.scope_of(n) for n in looped} == {
+        "moe_dispatch", "moe_experts"
+    }
 
 
 def test_sparse_attention_fwd_bwd_compiles_for_v5e(one_chip, monkeypatch):

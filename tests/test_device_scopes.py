@@ -287,3 +287,42 @@ def test_trainer_counts_what_it_packs_from_the_segment_ids():
     )
     assert not hasattr(eng, "last_timing")
     assert not [k for k in step if k.endswith(("_dispatch_s", "apply_fetch_s"))]
+
+
+def test_a_held_shares_slabs_sit_under_the_layers_scopes():
+    """A layer that holds a share runs a chunk's expert path over slabs of
+    the sorted stream, forward in a loop, backward the first in line and the
+    others in a loop (``ops/moe.py``): every operation the layer adds, in the
+    loops' bodies as in line, is under ``moe_route``, ``moe_dispatch`` or
+    ``moe_experts``, so ``moe_dispatch_time_share.train`` and
+    ``moe_experts_time_share.train`` read all of it however many slabs ran
+    (a ``while`` itself is a container to the trace's reader)."""
+    from areal_tpu.ops import moe
+    from benchmark import program_trace
+
+    t, d, f, routed, held, k = 512, 16, 16, 16, 2, 2
+    assert moe._held_rows(t * k, held, routed) < t * k  # more than one slab
+
+    def loss(x, wr, wg, wu, wd):
+        out, _ = moe.moe_ffn(
+            x, wr, wg, wu, wd, num_experts_per_tok=k, norm_topk_prob=True
+        )
+        return out.sum()
+
+    z = jnp.zeros
+    _, names = _op_names(jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        z((1, t, d)), z((d, routed)), z((held, d, f)), z((held, d, f)),
+        z((held, f, d)),
+    ))
+    looped = {n for n in names if "/while/" in n}
+    assert {program_trace.scope_of(n) for n in looped} == {
+        "moe_dispatch", "moe_experts"
+    }
+    scoped = {program_trace.scope_of(n) for n in names}
+    assert {"moe_route", "moe_dispatch", "moe_experts"} <= scoped
+    stray = {
+        n for n in names
+        if program_trace.scope_of(n) is None and "/" in n  # a traced op
+        and any(w in n for w in ("gather", "sort", "dot_general", "ragged"))
+    }
+    assert not stray, sorted(stray)[:10]

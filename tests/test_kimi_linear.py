@@ -412,6 +412,16 @@ def test_the_trainer_trains_the_family_and_counts_its_scans_exactly(
     assert counters["kda_chunk_slots"] >= counters["kda_tokens"]
     assert sum(s["kda_tokens"] for s in stats) == counters["kda_tokens"]
     assert 0 < counters["moe_assignments"] < sum(lens) * 2 * 3
+    # a share of the experts: the rows its three sparse layers' expert
+    # paths ran over (at this size a slab is the whole stream: the two
+    # pairs of every token of the train microbatches' rows, padding's too)
+    # and no chunk past one slab
+    assert counters["moe_stream_rows"] % (2 * 3) == 0
+    assert counters["moe_stream_rows"] >= sum(lens) * 2 * 3
+    assert counters["moe_overflow_chunks"] == 0
+    assert sum(s["moe_stream_rows"] for s in stats) == (
+        counters["moe_stream_rows"]
+    )
     after = engine.params["layers"]["kda+moe"]
     was = before["kda+moe"]
     assert (np.asarray(after["router_bias"]) == was["router_bias"]).all()

@@ -3,6 +3,8 @@ moe/): routing correctness vs a per-token reference, dense-equivalence,
 EP sharding parity, training, HF IO roundtrip, honest PP rejection.
 """
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -176,8 +178,152 @@ def test_moe_padding_gets_zero_and_moves_no_real_token(where):
         np.asarray(out)[0, at], np.asarray(alone)[0], rtol=1e-5, atol=1e-6
     )
     assert np.abs(np.asarray(out)[0, ~valid[0]]).max() == 0.0
-    for got, want in zip(stats, alone_stats):
+    assert stats.stream_rows is None and stats.overflow_chunks is None
+    for got, want in zip(stats[:3], alone_stats[:3]):  # every expert held
         np.testing.assert_allclose(float(got), float(want), rtol=1e-6)
+
+
+# A layer that HOLDS A SHARE (experts 4 and 5 of the 16 it routes over, two
+# a token): 512 tokens are a stream of 1,024 pairs and a slab of
+# 1024 x 2 / 16 x the slack of 2 = 256 rows. Each case: (tokens whose two
+# pairs are both held here, tokens with one, which tokens are real, chunks,
+# the rows the expert path must have run over, the chunks whose held pairs
+# filled more than one slab).
+_SHARE = dict(first=4, held=2, routed=16, k=2, tokens=512, buffer=256)
+_HELD_LOADS = {
+    "no_pair_held_here": (0, 0, None, 1, 256, 0),
+    "under_the_buffer": (100, 3, None, 1, 256, 0),
+    "exactly_the_buffer": (128, 0, None, 1, 256, 0),
+    "one_past_the_buffer": (128, 1, None, 1, 512, 1),  # a second slab
+    "every_pair_held_here": (512, 0, None, 1, 1024, 1),  # four
+    # every token routes to the held experts, a fifth of them real
+    "padding_first": (512, 0, np.arange(512) >= 410, 1, 256, 0),
+    "padding_last": (512, 0, np.arange(512) < 102, 1, 256, 0),
+    "padding_interleaved": (512, 0, np.arange(512) % 5 == 0, 1, 256, 0),
+    # 1,024 tokens in two chunks: the first fills four slabs, the second one
+    "two_chunks_one_overflows": (562, 7, None, 2, 1024 + 256, 1),
+}
+
+
+def _held_case(n_two, n_one, chunks):
+    """Tokens whose routing is decided by two large features: the first
+    ``n_two`` send both pairs to the held experts, the next ``n_one`` one,
+    the others none."""
+    s = _SHARE
+    rng = np.random.default_rng(11)
+    g, d, f = s["tokens"] * chunks, s["routed"], 16
+    x = 0.05 * rng.standard_normal((1, g, d)).astype(np.float32)
+    top = np.tile(np.array([9, 13]), (g, 1))  # held elsewhere
+    top[:n_two] = (s["first"] + 1, s["first"])
+    top[n_two:n_two + n_one, 0] = s["first"]
+    x[0, np.arange(g), top[:, 0]] += 1.0
+    x[0, np.arange(g), top[:, 1]] += 0.9
+    wr = (10 * np.eye(d) + 0.1 * rng.standard_normal((d, d))).astype(np.float32)
+    _, wg, wu, wd = _weights(rng, d, f, s["held"])
+    return x, wr, wg, wu, wd
+
+
+def _ref_share(x, wr, wg, wu, wd, k, first, valid):
+    """``_ref_moe`` (renormalised) of the experts ``first`` and following."""
+    out = np.zeros(x.shape, np.float32)
+    for ti in np.flatnonzero(valid):
+        h = x[0, ti]
+        p = np.exp(h @ wr - (h @ wr).max())
+        idx = np.argsort(-p)[:k]
+        for ei in idx:
+            if first <= ei < first + wg.shape[0]:
+                gate = h @ wg[ei - first]
+                out[0, ti] += p[ei] / p[idx].sum() * (
+                    (gate / (1 + np.exp(-gate)) * (h @ wu[ei - first]))
+                    @ wd[ei - first]
+                )
+    return out
+
+
+@pytest.mark.parametrize("load", sorted(_HELD_LOADS))
+def test_a_held_share_runs_over_a_bounded_buffer_and_drops_nothing(
+    load, monkeypatch
+):
+    """The expert path over slabs of the sorted stream against the whole
+    stream's and the per-token reference: output, the gradients of the
+    tokens, the router, the three expert stacks and the routing weights,
+    and the two counters. A chunk whose held pairs pass one slab runs as
+    many as they fill: nothing is dropped at any load."""
+    from areal_tpu.ops import moe
+
+    n_two, n_one, valid, chunks, ran, overflowed = _HELD_LOADS[load]
+    s = _SHARE
+    if chunks > 1:  # a stream of 1,024 rows is a chunk
+        monkeypatch.setattr(moe, "_STREAM_ROWS", s["tokens"] * s["k"])
+    np_args = _held_case(n_two, n_one, chunks)
+    args = tuple(jnp.asarray(a) for a in np_args)
+    g = np_args[0].shape[1]
+    valid = np.ones(g, bool) if valid is None else valid
+    target = jnp.cos(jnp.arange(g * s["routed"], dtype=jnp.float32)).reshape(
+        1, g, -1
+    )
+    assert moe._held_rows(s["tokens"] * s["k"], s["held"], s["routed"]) == (
+        s["buffer"]
+    )
+
+    def layer(*a):
+        out, stats = moe_ffn(
+            *a, num_experts_per_tok=s["k"], norm_topk_prob=True,
+            valid=jnp.asarray(valid)[None], first_expert=s["first"],
+        )
+        return (out * target).sum(), (out, stats)
+
+    grad = jax.jit(jax.grad(layer, argnums=(0, 1, 2, 3, 4), has_aux=True))
+    grads, (out, stats) = grad(*args)
+    # the whole stream: a buffer as long as the stream itself
+    monkeypatch.setattr(moe, "_held_rows", lambda rows, e, n: rows)
+    whole_grads, (whole, whole_stats) = jax.jit(
+        jax.grad(layer, argnums=(0, 1, 2, 3, 4), has_aux=True)
+    )(*args)
+    monkeypatch.undo()
+    held = 2 * min(n_two, g) + n_one if valid.all() else 2 * int(valid.sum())
+    assert float(stats.assignments) == float(whole_stats.assignments) == held
+    assert float(stats.stream_rows) == ran
+    assert float(stats.overflow_chunks) == overflowed
+    assert float(whole_stats.stream_rows) == g * s["k"]
+    assert float(whole_stats.overflow_chunks) == 0
+    np.testing.assert_allclose(
+        np.asarray(out),
+        _ref_share(*np_args, s["k"], s["first"], valid), rtol=2e-4, atol=2e-5,
+    )
+    for got, want in zip((out,) + grads, (whole,) + whole_grads):
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-6
+        )
+    assert np.abs(np.asarray(out)[0, ~valid]).max(initial=0.0) == 0.0
+
+    # the routing weights' gradient: one chunk's expert path on its own
+    xf = args[0][0, :s["tokens"]]
+    logits = xf @ args[1]
+    topk_p, topk_i, _ = moe.router_topk(logits, s["k"], True)
+    pair_expert = topk_i.reshape(-1) - s["first"]
+    pair_expert = jnp.where(
+        (pair_expert >= 0) & (pair_expert < s["held"])
+        & jnp.repeat(jnp.asarray(valid[:s["tokens"]]), s["k"]),
+        pair_expert, s["held"],
+    ).astype(jnp.int32)
+
+    def path(run):
+        def f(xf, topk_p, *w):
+            out = run(xf, pair_expert, topk_p, None, *w)
+            out = out[0] if isinstance(out, tuple) else out
+            return (out * target[0, :s["tokens"]]).sum()
+        return jax.jit(jax.grad(f, argnums=(0, 1, 2, 3, 4)))(
+            xf, topk_p, *args[2:]
+        )
+
+    for got, want in zip(
+        path(functools.partial(moe._run_held, c=s["buffer"])),
+        path(moe._run_experts),
+    ):
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-6
+        )
 
 
 def test_moe_model_forward_and_ep_parity():
@@ -253,6 +399,12 @@ def test_moe_training_step_with_aux_loss():
         assert np.isfinite(stats["router_aux_loss"])
         losses.append(stats["loss"])
     assert losses[-1] < losses[0]
+    # every expert is held: the two counts of a held share do not exist
+    assert set(engine.counters()) == {
+        "real_tokens", "padded_tokens", "moe_assignments",
+        "moe_max_expert_assignments",
+    }
+    assert "moe_stream_rows" not in stats
 
 
 @pytest.mark.parametrize("family", ["qwen3_moe", "olmoe"])

@@ -528,6 +528,9 @@ def test_the_trainer_trains_the_family_and_counts_its_pairs_exactly(tiny):
     assert sum(s["dsa_kept_pairs"] for s in stats) == kept
     # the pairs routed to the 8 experts held, not all 2 a token
     assert 0 < counters["moe_assignments"] < sum(lens) * 2 * layers
+    assert counters["moe_stream_rows"] >= counters["moe_assignments"]
+    assert counters["moe_stream_rows"] % (2 * layers) == 0
+    assert counters["moe_overflow_chunks"] == 0
     after = engine.params["layers"]
     assert (np.asarray(after["w_idx_q"]) == before["w_idx_q"]).all()
     assert not (np.asarray(after["wq"]) == before["wq"]).all()

@@ -4,6 +4,16 @@ published widths, once through each grouped matmul (``megablox`` over a few
 tilings, ``ragged_dot``), all under one ``jax.profiler`` capture.
 
     python -m tools.microbench_moe [--tokens 4096] [--out chiprun_out/moe_sweep]
+    python -m tools.microbench_moe --held 16 --widths 2048 768 128 8
+
+``--held N``: the layer HOLDS the first N of the E experts it routes over
+(one token chunk of a cell: 4,096 tokens of 16 of 128 in the long-row cell,
+``--widths 2304 1024 256 8 --held 8`` in the Kimi cell). The sweep is then
+the expert path over the whole sorted stream against the one over the
+bounded buffer (``ops/moe._run_held``), and the two ways the buffer's rows
+can get back to their tokens, alone: the product with the [G, C] matrix of
+routing weights (``ops/moe._onto_tokens``, the form kept) and the gather of
+every pair's row clamped to one zero row (the form it was weighed against).
 
 The times are device times of the jitted programs and of the device scopes
 ``moe_route`` / ``moe_dispatch`` / ``moe_experts`` from
@@ -39,6 +49,8 @@ def main():
     ap.add_argument("--out", default="chiprun_out/moe_sweep")
     ap.add_argument("--tilings", type=int, default=len(TILINGS),
                     help="the first so many of TILINGS")
+    ap.add_argument("--held", type=int, default=0,
+                    help="the layer holds the first so many of the E experts")
     args = ap.parse_args()
     t, (d, f, e, k) = args.tokens, args.widths
     rng = np.random.default_rng(0)
@@ -52,6 +64,8 @@ def main():
     )
     valid = jnp.asarray(np.arange(t) < int(t * 0.86))[None]  # 14% padding
     flops = 2.0 * int(t * 0.86) * k * 3 * d * f
+    if args.held:
+        return held_sweep(args, x, ws, valid, flops * args.held / e)
 
     def variant(name, impl, tiling):
         def fwd(x, *ws):
@@ -98,22 +112,7 @@ def main():
                 jax.block_until_ready(fwd(x, *ws))
                 jax.block_until_ready(both(x, *ws))
     moe.grouped_impl = real_impl
-    path = xplane.find_xplane(traced.dir)
-    events = program_trace.load(path)
-    r = program_trace.reduce(events)
-    rows = {}
-    for prog, p in sorted(r["programs"].items()):
-        if not prog.startswith(("fwd_", "fwdbwd_")):
-            continue
-        ms = 1e3 * p["seconds"] / max(p["runs"], 1)
-        sc = {s: round(1e3 * v / max(p["runs"], 1), 3)
-              for s, v in r["program_scopes"].get(prog, {}).items()}
-        passes = 1 if prog.startswith("fwd_") else 3
-        ex = sc.get("moe_experts", 0.0)
-        rows[prog] = {"ms": round(ms, 3), "scopes_ms": sc,
-                      "experts_tflops": round(passes * flops / (ex * 1e-3) / 1e12, 1)
-                      if ex else None}
-        print(prog, json.dumps(rows[prog]), flush=True)
+    events, rows = report(traced, flops, ("fwd_", "fwdbwd_"))
     # the names the kernels' events carry, per program
     names = {}
     for ev in events:
@@ -133,6 +132,124 @@ def main():
           before, after, flush=True)
     with open(os.path.join(harness.ROOT, args.out, "sweep.json"), "w") as fh:
         json.dump(rows, fh, indent=1)
+
+
+def report(traced, flops, prefixes, longest=0):
+    """One line a traced program whose name starts with one of
+    ``prefixes``: device ms a run, ms by device scope, the experts' TFLOP/s;
+    ``longest``: and so many of its longest operations."""
+    events = program_trace.load(xplane.find_xplane(traced.dir))
+    r = program_trace.reduce(events)
+    rows = {}
+    for prog, p in sorted(r["programs"].items()):
+        if not prog.startswith(prefixes):
+            continue
+        runs = max(p["runs"], 1)
+        sc = {s: round(1e3 * v / runs, 3)
+              for s, v in r["program_scopes"].get(prog, {}).items()}
+        passes = 3 if prog.startswith("fwdbwd_") else 1
+        ex = sc.get("moe_experts", 0.0)
+        rows[prog] = {"ms": round(1e3 * p["seconds"] / runs, 3), "scopes_ms": sc,
+                      "experts_tflops": round(passes * flops / (ex * 1e-3) / 1e12, 1)
+                      if ex else None}
+        if longest:
+            ops = {}
+            for ev in events:
+                if ev[1] == xplane.OPS_LINE and ev[5].get("program") == prog:
+                    key = (ev[2][:64],
+                           program_trace.scope_of(ev[5].get("op_name")))
+                    ops[key] = ops.get(key, 0) + ev[4]  # ns
+            rows[prog]["longest_ops_ms"] = [
+                (n, sc_, round(v / 1e6 / runs, 3)) for (n, sc_), v in
+                sorted(ops.items(), key=lambda kv: -kv[1])[:longest]
+            ]
+        print(prog, json.dumps(rows[prog]), flush=True)
+    return events, rows
+
+
+def held_sweep(args, x, ws, valid, flops):
+    """A layer that holds ``--held`` of its experts: the whole stream
+    against the bounded buffer, and the two candidates for the token side."""
+    t, (d, f, e, k) = args.tokens, args.widths
+    held = tuple(w[:args.held] for w in ws[1:])
+    c = moe._held_rows(t * k // moe._token_chunks(t, k), args.held, e)
+    bf = jnp.bfloat16
+
+    def variant(name):
+        def fwd(x, wr, *held):
+            return moe.moe_ffn(x, wr, *held, num_experts_per_tok=k,
+                               norm_topk_prob=True, valid=valid)
+
+        def loss(*a):
+            return fwd(*a)[0].astype(jnp.float32).sum()
+
+        fwd.__name__ = f"fwd_{name}"
+        bwd = jax.grad(loss, argnums=(0, 1, 2, 3, 4))
+        both = lambda *a: bwd(*a)  # noqa: E731
+        both.__name__ = f"fwdbwd_{name}"
+        return jax.jit(fwd), jax.jit(both)
+
+    # the token side alone: [C, D] rows back to [G, D] tokens
+    rng = np.random.default_rng(1)
+    g = t // moe._token_chunks(t, k)
+    rows = jnp.asarray(rng.standard_normal((c, d)), bf)
+    order = jnp.asarray(rng.permutation(g * k), jnp.int32)
+    tok = order[:c] // k
+    w = jnp.asarray(rng.random((g, k)), jnp.float32)
+
+    def side_product(rows, w, order, tok):
+        return moe._onto_tokens(
+            rows, tok, w.reshape(-1)[order[:c]], g
+        ).astype(bf)
+
+    def side_gather(rows, w, order, tok):
+        inv = jnp.minimum(jnp.argsort(order), c)
+        y = jnp.concatenate([rows, jnp.zeros((1, d), bf)])[inv]
+        return jnp.einsum(
+            "gkd,gk->gd", y.reshape(g, k, d), w.astype(bf),
+            preferred_element_type=jnp.float32,
+        ).astype(bf)
+
+    def side_gather_rows(rows, w, order, tok):  # the buffer's own gather
+        return jnp.concatenate([rows, jnp.zeros((1, d), bf)])[tok]
+
+    sides = [jax.jit(fn) for fn in
+             (side_product, side_gather, side_gather_rows)]
+    a, b = (np.asarray(fn(rows, w, order, tok), np.float32)
+            for fn in sides[:2])
+    harness.log(f"token side, C={c} G={g}: max |product - gather| = "
+                f"{np.abs(a - b).max():.4g} of max {np.abs(b).max():.4g}")
+
+    real_rows = moe._held_rows
+    progs, outs = {}, {}
+    for name, rows_fn in (("whole", lambda rows_, e_, n_: rows_),
+                          ("compact", real_rows)):
+        moe._held_rows = rows_fn
+        progs[name] = variant(name)
+        out, stats = progs[name][0](x, ws[0], *held)  # traces here
+        jax.block_until_ready(progs[name][1](x, ws[0], *held))
+        outs[name] = np.asarray(out, np.float32)
+        harness.log(f"{name}: " + ", ".join(
+            f"{fld}={float(v):.0f}" for fld, v in zip(stats._fields, stats)
+            if fld != "aux_loss"
+        ))
+    moe._held_rows = real_rows
+    harness.log(
+        f"max |compact - whole| = "
+        f"{np.abs(outs['compact'] - outs['whole']).max():.4g} of max "
+        f"{np.abs(outs['whole']).max():.4g}"
+    )
+    traced = harness.TracedWindow(True, os.path.join(harness.ROOT, args.out))
+    with traced:
+        for _ in range(RUNS):
+            for fwd, both in progs.values():
+                jax.block_until_ready(fwd(x, ws[0], *held))
+                jax.block_until_ready(both(x, ws[0], *held))
+            for fn in sides:
+                jax.block_until_ready(fn(rows, w, order, tok))
+    _, out = report(traced, flops, ("fwd_", "fwdbwd_", "side_"), longest=12)
+    with open(os.path.join(harness.ROOT, args.out, "sweep.json"), "w") as fh:
+        json.dump(out, fh, indent=1)
 
 
 if __name__ == "__main__":
