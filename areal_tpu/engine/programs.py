@@ -58,8 +58,14 @@ KDA_COUNTS = {
     "kda_chunk_slots": "chunk_slots",
     "kda_segments": "segments",
 }
+# the same for a model with window-attention layers (``SWAStats`` fields):
+# int32 [those layers] a microbatch
+SWA_COUNTS = {
+    "swa_pairs": "pairs",
+    "swa_causal_pairs": "causal_pairs",
+}
 # the counts the host fetches as integers and sums exactly
-INT_COUNTS = {**DSA_COUNTS, **KDA_COUNTS}
+INT_COUNTS = {**DSA_COUNTS, **KDA_COUNTS, **SWA_COUNTS}
 
 
 def _forward_fn(
@@ -117,17 +123,18 @@ def build_grad_step(
     )
 
     def fwd_loss(params, arrays):
-        logits, (moe_stats, dsa_stats, kda_stats) = forward(params, arrays)
+        logits, layer_stats = forward(params, arrays)
+        moe_stats = layer_stats.moe
         with tracing.device_scope("loss"):
             loss, stats = loss_fn(logits, arrays)
-            if dsa_stats is not None:
-                stats = dict(stats, **{
-                    k: getattr(dsa_stats, v) for k, v in DSA_COUNTS.items()
-                })
-            if kda_stats is not None:
-                stats = dict(stats, **{
-                    k: getattr(kda_stats, v) for k, v in KDA_COUNTS.items()
-                })
+            for counted, names in (
+                (layer_stats.dsa, DSA_COUNTS), (layer_stats.kda, KDA_COUNTS),
+                (layer_stats.swa, SWA_COUNTS),
+            ):
+                if counted is not None:
+                    stats = dict(stats, **{
+                        k: getattr(counted, v) for k, v in names.items()
+                    })
             if mc.is_moe:
                 if mc.router_aux_loss_coef:
                     loss = loss + (

@@ -234,7 +234,7 @@ class SPMDTrainEngine(TrainEngine):
         self.attn_impl = flash_ops.resolve_attn_impl(
             cfg.attn_impl, cfg.parallel.seq_parallel_size,
             mc.attn_head_dims[0], has_indexer=mc.has_indexer,
-            head_dim_v=mc.attn_head_dims[1],
+            head_dim_v=mc.attn_head_dims[1], layer_window=mc.layer_window,
         )
         if self.attn_impl == "flash" and jax.default_backend() != "cpu":
             # what the scoped-VMEM limit of this process allows, once
@@ -254,6 +254,8 @@ class SPMDTrainEngine(TrainEngine):
                 mc.kda.head_dim, mc.kda.head_dim
             )
             self._counters.update(dict.fromkeys(programs.KDA_COUNTS, 0))
+        if mc.layer_window:
+            self._counters.update(dict.fromkeys(programs.SWA_COUNTS, 0))
         n = count_params(self.params)
         logger.info(
             f"initialized {mc.family} model: {n/1e6:.1f}M params on mesh "
@@ -348,8 +350,11 @@ class SPMDTrainEngine(TrainEngine):
         delta-rule layers ``kda_tokens``, ``kda_chunk_slots`` and
         ``kda_segments`` (the real tokens its scans ran over, the chunk slots
         they ran, 64 a chunk, and the sequence starts they reset at, each
-        summed over those layers). Their per-step increments are the stats
-        plane's ``spmd/<name>``."""
+        summed over those layers); for a model with window-attention layers
+        ``swa_pairs`` and ``swa_causal_pairs`` (the (query, key) pairs inside
+        the window, and what the same layers would attend without one,
+        summed over those layers and the train microbatches). Their per-step
+        increments are the stats plane's ``spmd/<name>``."""
         return dict(self._counters)
 
     def get_version(self) -> int:
@@ -466,22 +471,35 @@ class SPMDTrainEngine(TrainEngine):
     # Train
     # ------------------------------------------------------------------
     def _attn_window(self, input_: Batch) -> int:
-        """The splash kernel's local window for this batch (0 = full
+        """The splash kernel's PACKING window for this batch (0 = full
         causal, and what every other attention gets): the rule is
-        ``ops.flash.flash_window``'s, from the batch's longest sequence."""
+        ``ops.flash.flash_window``'s, from the batch's longest sequence. It
+        is what the full-attention layers' kernel gets, so it may never be
+        shorter than that sequence; a window-attention layer's own window is
+        the model's (``ModelConfig.layer_window``), not this one."""
         if self.attn_impl != "flash":
             return 0
         longest = int(np.asarray(input_["attention_mask"]).sum(1).max())
-        return flash_ops.flash_window(
+        window = flash_ops.flash_window(
             longest, self.config.mb_spec.max_tokens_per_mb
         )
+        if window and window < longest:
+            raise RuntimeError(
+                f"a packing window of {window} keys is shorter than the "
+                f"batch's longest sequence ({longest}): it would change the "
+                "full-attention layers' result"
+            )
+        return window
 
-    def _impl_attrs(self) -> Dict[str, str]:
-        """What a dispatch span says beside ``attn_impl``: the forms the
-        ops resolved for this model's experts and learned selection."""
+    def _impl_attrs(self) -> Dict[str, Any]:
+        """What a dispatch span says beside ``attn_impl`` and ``window``
+        (the packing window, the full-attention layers'): the forms the ops
+        resolved for this model's experts and learned selection, and the
+        window of its window-attention layers."""
         named = {
             "moe_impl": self.moe_impl, "dsa_select_impl": self.dsa_select_impl,
             "kda_impl": self.kda_impl,
+            "layer_window": self.model_config.layer_window,
         }
         return {k: v for k, v in named.items() if v}
 

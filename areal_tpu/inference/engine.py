@@ -355,6 +355,15 @@ class GenerationEngine:
                 "indexer-key cache beside its KV pages and no top-k decode "
                 "(ROADMAP B); the trainer can (attn_impl 'sparse_topk')"
             )
+        if model_config.layer_window:
+            raise NotImplementedError(
+                "the generation engine cannot run window attention (family "
+                f"{model_config.family!r}: layers of a "
+                f"{model_config.layer_window}-key window among full ones, "
+                "each kind with its own heads): window layers want pages "
+                "that stop growing and an allocator keyed by layer kind "
+                "(ROADMAP B.3); the trainer can"
+            )
         if model_config.layer_kinds:
             raise NotImplementedError(
                 "the generation engine cannot run a stack of block kinds "

@@ -7,11 +7,12 @@ supported decoder-only family. A model whose layers are all one block
 indexer) differs from its siblings in fields (QKV bias, tied embeddings,
 head_dim override, q/k norm, experts). A model whose layers are of more
 than one kind names them: ``layer_kinds`` holds a block kind a layer
-(``"<mixer>+<ffn>"``: the mixers ``attn`` (rotary GQA), ``kda`` (gated
-delta-rule linear attention, ``KDAConfig``) and ``mla`` (latent attention,
-``MLAConfig``); the FFNs ``dense`` and ``moe``), and the block kinds, not
-further per-family scalars, are the unit ``models/transformer.py`` builds
-its stacks from.
+(``"<mixer>+<ffn>"``: the mixers ``kda`` (gated delta-rule linear
+attention, ``KDAConfig``), ``mla`` (latent attention, ``MLAConfig``) and the
+two rotary GQA ones, ``full`` and ``swa`` (all of a sequence's earlier keys,
+or a window of them: a ``RotaryConfig`` each); the FFNs ``dense`` and
+``moe``), and the block kinds, not further per-family scalars, are the unit
+``models/transformer.py`` builds its stacks from.
 """
 
 import dataclasses
@@ -49,6 +50,29 @@ class MLAConfig:
     @property
     def qk_head_dim(self) -> int:
         return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+
+@dataclasses.dataclass(frozen=True)
+class RotaryConfig:
+    """One rotary GQA mixer of a stack of block kinds: ``num_heads`` query
+    heads over the model's ``num_kv_heads`` of ``head_dim``; a query sees the
+    ``window`` latest keys of its sequence, its own among them (0 = all of
+    them); the first ``rotary_dim`` channels of a head are rotated (0 = the
+    whole head) at ``rope_theta``, by yarn where ``yarn_factor`` > 1
+    (``ops/basic.rope_inv_freq``), cos and sin times ``attention_factor``;
+    ``head_gate``: each head's output times ``sigmoid(h Wg)`` of the layer's
+    normed input, one number a head and token."""
+
+    num_heads: int
+    window: int = 0
+    rotary_dim: int = 0
+    rope_theta: float = 10000.0
+    yarn_factor: float = 1.0
+    yarn_original_len: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    attention_factor: float = 1.0
+    head_gate: bool = False
 
 
 @dataclasses.dataclass(frozen=True)
@@ -109,6 +133,11 @@ class ModelConfig:
     layer_kinds: Tuple[str, ...] = ()
     kda: Optional[KDAConfig] = None
     mla: Optional[MLAConfig] = None
+    # the rotary GQA mixers of such a stack, ``full`` and ``swa``: each its
+    # own heads, rotation and (``swa``) window; ``num_kv_heads`` and
+    # ``head_dim`` above are theirs alike
+    full: Optional[RotaryConfig] = None
+    swa: Optional[RotaryConfig] = None
     # the router's two forms (ops/moe.router_topk): "softmax", or "sigmoid"
     # (scores chosen with a correction bias, weighted without it, then
     # times ``routed_scaling_factor``)
@@ -154,6 +183,15 @@ class ModelConfig:
     def num_kda_layers(self) -> int:
         return sum(kind.startswith("kda+") for kind in self.layer_kinds)
 
+    @property
+    def layer_window(self) -> int:
+        """The window of the model's window-attention layers (0 = it has
+        none)."""
+        has = self.swa is not None and any(
+            kind.startswith("swa+") for kind in self.layer_kinds
+        )
+        return self.swa.window if has else 0
+
     def kind_layers(self, kind: str) -> int:
         """How many layers are of block ``kind``."""
         return sum(k == kind for k in self.layer_kinds)
@@ -171,11 +209,20 @@ class ModelConfig:
 # kimi_linear is a stack of block kinds (``_kimi_linear_from_hf``): gated
 # delta-rule layers and NoPE latent-attention layers, a leading dense FFN
 # and sigmoid-routed experts with an ungated shared one after it.
-# gemma2/gpt2 remain out (interleaved windowed attention / learned
-# positions: a window is one more mixer kind, positions a new embedding).
+# laguna is a stack of block kinds too (``_laguna_from_hf``): rotary GQA
+# layers of two kinds, ``full`` (every earlier key; yarn over the first half
+# of a head) and ``swa`` (a window of ``sliding_window`` keys; plain rope),
+# each with its own number of query heads and a per-head output gate, over a
+# leading dense FFN and softmax-routed experts with an ungated shared one.
+# The trainer runs it; the generation engine (window layers want pages that
+# stop growing, ROADMAP B.3), ``hf_io`` (no published checkpoint names) and
+# sequence parallelism refuse it by name.
+# gemma2/gpt2 remain out (gemma2: soft-capped scores and post-norms beside
+# its windows; gpt2: learned positions, a new embedding).
 _HF_FAMILIES = (
     "llama", "qwen2", "qwen3", "mistral", "qwen3_moe", "mixtral",
     "qwen2_vl", "qwen2_moe", "gemma", "olmoe", "KeyeVL2", "kimi_linear",
+    "laguna",
 )
 
 
@@ -288,6 +335,115 @@ def _kimi_linear_from_hf(d: dict) -> ModelConfig:
     )
 
 
+def _rotary_from_hf(d: dict, rope: dict, heads: int, window: int):
+    """One rotary mixer's ``RotaryConfig`` from its ``rope_parameters``
+    group: ``rope_type`` default or yarn, ``partial_rotary_factor`` of the
+    head rotated."""
+    kind = rope.get("rope_type", "default")
+    if kind not in ("default", "yarn"):
+        raise ValueError(f"laguna: rope_type {kind!r} is not supported")
+    rotary = int(d["head_dim"] * rope.get("partial_rotary_factor", 1.0))
+    yarn = kind == "yarn"
+    return RotaryConfig(
+        num_heads=heads, window=window,
+        rotary_dim=0 if rotary == d["head_dim"] else rotary,
+        rope_theta=float(rope.get("rope_theta", 10000.0)),
+        yarn_factor=float(rope["factor"]) if yarn else 1.0,
+        yarn_original_len=(
+            rope["original_max_position_embeddings"] if yarn else 0
+        ),
+        yarn_beta_fast=float(rope.get("beta_fast", 32.0)),
+        yarn_beta_slow=float(rope.get("beta_slow", 1.0)),
+        attention_factor=(
+            float(rope.get("attention_factor", 1.0)) if yarn else 1.0
+        ),
+        head_gate=True,
+    )
+
+
+def _laguna_from_hf(d: dict) -> ModelConfig:
+    """``model_type`` laguna: ``layer_types`` says which layers attend to
+    the whole sequence and which to ``sliding_window`` keys,
+    ``num_attention_heads_per_layer`` how many query heads each has (one
+    number a kind), ``rope_parameters`` each kind's rotation,
+    ``mlp_layer_types`` which FFNs are dense (``intermediate_size``) and
+    which ``num_experts`` softmax-routed experts (``num_local_experts`` of
+    them held here, where the file says so) with a shared one."""
+    n_layers = d["num_hidden_layers"]
+    mixers = {"full_attention": "full", "sliding_attention": "swa"}
+    ffns = {"dense": "dense", "sparse": "moe"}
+    types, mlps = d["layer_types"], d["mlp_layer_types"]
+    heads_at = d["num_attention_heads_per_layer"]
+    if not len(types) == len(mlps) == len(heads_at) == n_layers:
+        raise ValueError(
+            "laguna: layer_types, mlp_layer_types and "
+            "num_attention_heads_per_layer must have one entry a layer "
+            f"({len(types)}, {len(mlps)}, {len(heads_at)} for {n_layers})"
+        )
+    unknown = (set(types) - set(mixers)) | (set(mlps) - set(ffns))
+    if unknown:
+        raise ValueError(f"laguna: unknown layer types {sorted(unknown)}")
+    refused = {
+        f"gating {d.get('gating')!r} (only true / 'per-head': one gate a "
+        "head)": d.get("gating") not in (True, "per-head"),
+        "gating_types other than per_head": any(
+            g != "per_head" for g in d.get("gating_types", ())
+        ),
+        "moe_apply_router_weight_on_input true": d.get(
+            "moe_apply_router_weight_on_input", False
+        ),
+        "a soft-capped router (moe_router_logit_softcapping)": d.get(
+            "moe_router_logit_softcapping", 0
+        ),
+        "attention_bias": d.get("attention_bias", False),
+    }
+    for what, present in refused.items():
+        if present:
+            raise ValueError(f"laguna with {what} is not supported")
+    heads = {}
+    for kind, n in zip(types, heads_at):
+        if heads.setdefault(kind, n) != n:
+            raise ValueError(
+                f"laguna: {kind} layers of {heads[kind]} and of {n} heads "
+                "(one stack a kind holds one shape)"
+            )
+    rope = d["rope_parameters"]
+    num_experts = d["num_experts"]
+    held = d.get("num_local_experts", num_experts)
+    return ModelConfig(
+        vocab_size=d["vocab_size"],
+        hidden_size=d["hidden_size"],
+        intermediate_size=d["intermediate_size"],
+        num_layers=n_layers,
+        num_heads=d["num_attention_heads"],
+        num_kv_heads=d["num_key_value_heads"],
+        head_dim=d["head_dim"],
+        max_position_embeddings=d.get("max_position_embeddings", 32768),
+        rms_norm_eps=d.get("rms_norm_eps", 1e-6),
+        tie_word_embeddings=d.get("tie_word_embeddings", False),
+        family="laguna",
+        num_experts=num_experts,
+        num_experts_per_tok=d["num_experts_per_tok"],
+        moe_intermediate_size=d["moe_intermediate_size"],
+        norm_topk_prob=d.get("norm_topk_prob", True),
+        router_aux_loss_coef=d.get("router_aux_loss_coef", 0.0),
+        shared_expert_size=d.get("shared_expert_intermediate_size", 0),
+        shared_expert_gate=False,
+        routed_scaling_factor=d.get("moe_routed_scaling_factor", 1.0),
+        experts_held=0 if held == num_experts else held,
+        layer_kinds=tuple(
+            f"{mixers[t]}+{ffns[m]}" for t, m in zip(types, mlps)
+        ),
+        full=_rotary_from_hf(
+            d, rope["full_attention"], heads["full_attention"], 0
+        ) if "full_attention" in heads else None,
+        swa=_rotary_from_hf(
+            d, rope["sliding_attention"], heads["sliding_attention"],
+            d["sliding_window"],
+        ) if "sliding_attention" in heads else None,
+    )
+
+
 def from_hf_config(d: dict) -> ModelConfig:
     """Build from a parsed HF config.json dict (families mirror the
     reference's from_hf registry: realhf/api/from_hf/)."""
@@ -296,6 +452,8 @@ def from_hf_config(d: dict) -> ModelConfig:
         raise ValueError(f"unsupported model family {model_type!r}")
     if model_type == "kimi_linear":
         return _kimi_linear_from_hf(d)
+    if model_type == "laguna":
+        return _laguna_from_hf(d)
     num_heads = d["num_attention_heads"]
     hidden = d["hidden_size"]
     head_dim = d.get("head_dim") or hidden // num_heads
@@ -428,6 +586,28 @@ def tiny_vlm_config(vocab_size: int = 128) -> ModelConfig:
 def tiny_config(family: str = "qwen2", vocab_size: int = 128) -> ModelConfig:
     """Small config for tests."""
     moe = family in ("qwen3_moe", "mixtral", "qwen2_moe", "olmoe")
+    if family == "laguna":
+        # all three block kinds; 6 (full) and 8 (window of 8 keys) query
+        # heads of 16 over 2 key heads, a gate a head; the full layers' yarn
+        # over the first half of a head; 4 experts held of 16, two a token
+        return ModelConfig(
+            vocab_size=vocab_size, hidden_size=32, intermediate_size=64,
+            num_layers=5, num_heads=6, num_kv_heads=2, head_dim=16,
+            max_position_embeddings=512, rms_norm_eps=1e-6, family=family,
+            num_experts=16, num_experts_per_tok=2, moe_intermediate_size=16,
+            norm_topk_prob=True, router_aux_loss_coef=0.0, experts_held=4,
+            shared_expert_size=16, shared_expert_gate=False,
+            routed_scaling_factor=2.5,
+            layer_kinds=("full+dense", "swa+moe", "swa+moe", "swa+moe",
+                         "full+moe"),
+            full=RotaryConfig(
+                num_heads=6, rotary_dim=8, rope_theta=500000.0,
+                yarn_factor=64.0, yarn_original_len=64, yarn_beta_fast=8.0,
+                yarn_beta_slow=1.0, attention_factor=1.4158883083359672,
+                head_gate=True,
+            ),
+            swa=RotaryConfig(num_heads=8, window=8, head_gate=True),
+        )
     if family == "kimi_linear":
         # all three block kinds; two KDA heads of 16; latent attention of
         # two heads (8 + 4 | 8) over a rank of 16; 8 experts held of 16

@@ -145,6 +145,12 @@ def _kind_tensor_names(cfg: ModelConfig):
     stack, expert or None, hf name, how stored) of every layer tensor."""
     from areal_tpu.models.transformer import _kind_leaves
 
+    if cfg.family == "laguna":
+        raise NotImplementedError(
+            "family 'laguna' has no checkpoint layout here: its tensor "
+            "names are in no public source this repo was written from (the "
+            "catalog gives the config.json only); initialise from scratch"
+        )
     if cfg.experts_held:
         raise NotImplementedError(
             f"family {cfg.family!r} holding {cfg.experts_held} of "
@@ -452,9 +458,73 @@ def _kimi_linear_config_dict(cfg: ModelConfig) -> dict:
     }
 
 
+def _laguna_config_dict(cfg: ModelConfig) -> dict:
+    """The keys ``models/config._laguna_from_hf`` reads."""
+    mixers = {"full": "full_attention", "swa": "sliding_attention"}
+    kinds = [k.split("+") for k in cfg.layer_kinds]
+
+    def rope(rc):
+        out = {
+            "rope_type": "yarn" if rc.yarn_factor > 1.0 else "default",
+            "rope_theta": rc.rope_theta,
+            "partial_rotary_factor": (
+                (rc.rotary_dim or cfg.head_dim) / cfg.head_dim
+            ),
+        }
+        if rc.yarn_factor > 1.0:
+            out.update({
+                "factor": rc.yarn_factor,
+                "original_max_position_embeddings": rc.yarn_original_len,
+                "beta_fast": rc.yarn_beta_fast,
+                "beta_slow": rc.yarn_beta_slow,
+                "attention_factor": rc.attention_factor,
+            })
+        return out
+
+    return {
+        "model_type": cfg.family,
+        "vocab_size": cfg.vocab_size,
+        "hidden_size": cfg.hidden_size,
+        "intermediate_size": cfg.intermediate_size,
+        "num_hidden_layers": cfg.num_layers,
+        "num_attention_heads": cfg.num_heads,
+        "num_key_value_heads": cfg.num_kv_heads,
+        "head_dim": cfg.head_dim,
+        "max_position_embeddings": cfg.max_position_embeddings,
+        "attention_bias": False,
+        "rms_norm_eps": cfg.rms_norm_eps,
+        "tie_word_embeddings": cfg.tie_word_embeddings,
+        "torch_dtype": "float32",
+        "num_experts": cfg.num_experts,
+        "num_local_experts": cfg.num_held_experts,
+        "num_experts_per_tok": cfg.num_experts_per_tok,
+        "moe_intermediate_size": cfg.moe_intermediate_size,
+        "shared_expert_intermediate_size": cfg.shared_expert_size,
+        "norm_topk_prob": cfg.norm_topk_prob,
+        "moe_routed_scaling_factor": cfg.routed_scaling_factor,
+        "moe_apply_router_weight_on_input": False,
+        "router_aux_loss_coef": cfg.router_aux_loss_coef,
+        "gating": True,
+        "sliding_window": cfg.swa.window if cfg.swa else 0,
+        "layer_types": [mixers[m] for m, _ in kinds],
+        "mlp_layer_types": [
+            "dense" if f == "dense" else "sparse" for _, f in kinds
+        ],
+        "num_attention_heads_per_layer": [
+            getattr(cfg, m).num_heads for m, _ in kinds
+        ],
+        "rope_parameters": {
+            mixers[m]: rope(getattr(cfg, m))
+            for m in mixers if getattr(cfg, m) is not None
+        },
+    }
+
+
 def default_hf_config_dict(cfg: ModelConfig) -> dict:
     if cfg.family == "kimi_linear":
         return _kimi_linear_config_dict(cfg)
+    if cfg.family == "laguna":
+        return _laguna_config_dict(cfg)
     return {
         "model_type": cfg.family,
         "vocab_size": cfg.vocab_size,

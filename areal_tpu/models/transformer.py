@@ -15,11 +15,12 @@ from-scratch parallel causal LM — re-designed TPU-first:
 - `jax.checkpoint` (remat) on the scanned layer body trades FLOPs for HBM,
   replacing torch gradient checkpointing (reference base_hf_engine.py).
 - A model whose layers are of more than one kind (``ModelConfig.layer_kinds``:
-  gated delta-rule, latent-attention and rotary mixers over dense or sparse
-  FFNs) holds ONE STACK A BLOCK KIND under ``params["layers"][kind]`` and
-  ``apply`` walks the pattern: every run of consecutive layers of one kind is
-  a scan over that kind's slice, under the same checkpoint policy. A model
-  of one kind keeps the flat ``params["layers"]`` and the one scan.
+  gated delta-rule, latent-attention and rotary mixers, the last with or
+  without a window, over dense or sparse FFNs) holds ONE STACK A BLOCK KIND
+  under ``params["layers"][kind]`` and ``apply`` walks the pattern: every run
+  of consecutive layers of one kind is a scan over that kind's slice, under
+  the same checkpoint policy. A model of one kind keeps the flat
+  ``params["layers"]`` and the one scan.
 """
 
 import functools
@@ -40,11 +41,18 @@ from areal_tpu.ops.basic import (
     apply_rope,
     hidden_act_fn,
     rms_norm,
+    rope_at,
     rope_frequencies,
+    rope_inv_freq,
+    rotate_leading,
     segment_attention,
 )
 
 Params = Dict[str, Any]
+
+# the rotary GQA mixers among the block kinds: a ``RotaryConfig`` each, under
+# ``ModelConfig.full`` / ``.swa``
+_ROTARY_MIXERS = ("full", "swa")
 
 
 class LayerStats(NamedTuple):
@@ -55,6 +63,17 @@ class LayerStats(NamedTuple):
     moe: MoEStats  # zeros for a dense model
     dsa: Optional[DSAStats] = None  # sparse-attention models only
     kda: Optional[KDAStats] = None  # gated delta-rule layers only
+    swa: Optional["SWAStats"] = None  # window-attention layers only
+
+
+class SWAStats(NamedTuple):
+    """The (query, key) pairs of a model's window-attention layers, int32
+    [those layers] (exact: the host sums them): inside the window (a
+    sequence of length L gives ``sum_t min(t + 1, window)`` a layer), and
+    what the same layers would attend without one (``L (L + 1) / 2``)."""
+
+    pairs: jnp.ndarray
+    causal_pairs: jnp.ndarray
 
 
 # --------------------------------------------------------------------------
@@ -110,6 +129,19 @@ def _kind_leaves(cfg: ModelConfig, kind: str) -> Dict[str, tuple]:
             ),
             "wo": ((H * m.v_head_dim, D), "normal", ("heads", "embed")),
         })
+    elif mixer in _ROTARY_MIXERS:  # rotary GQA, the kind's own heads
+        rc = getattr(cfg, mixer)
+        Qd, KVd = rc.num_heads * cfg.head_dim, cfg.kv_dim
+        leaves.update({
+            "wq": ((D, Qd), "normal", ("embed", "heads")),
+            "wk": ((D, KVd), "normal", ("embed", "heads")),
+            "wv": ((D, KVd), "normal", ("embed", "heads")),
+            "wo": ((Qd, D), "normal", ("heads", "embed")),
+        })
+        if rc.head_gate:  # one number a head and token
+            leaves["w_head_gate"] = (
+                (D, rc.num_heads), "normal", ("embed", None)
+            )
     else:
         raise ValueError(f"no mixer {mixer!r} among the block kinds")
     if ffn == "moe":
@@ -516,15 +548,51 @@ def _mla_mixer(cfg, h, lp, segment_ids, attend_fn):
     return attn.reshape(b, t, H * m.v_head_dim) @ lp["wo"]
 
 
+def _rotary_mixer(cfg, rc, h, lp, segment_ids, cos, sin, attend_fn):
+    """Rotary GQA attention on the normed input ``h``, as ``rc`` (a
+    ``RotaryConfig``) describes this kind: its own query heads, rotation
+    (``cos``/``sin`` of the tokens' positions, made once a kind in
+    ``apply``) and window, each head's output gated. A window layer's core
+    is scope ``attn_window``, not ``attention``; the rotation and the gate
+    are outside both, as the projections are."""
+    b, t, _ = h.shape
+    H, dh = rc.num_heads, cfg.head_dim
+    q = rotate_leading((h @ lp["wq"]).reshape(b, t, H, dh), cos, sin)
+    k = rotate_leading(
+        (h @ lp["wk"]).reshape(b, t, cfg.num_kv_heads, dh), cos, sin
+    )
+    v = (h @ lp["wv"]).reshape(b, t, cfg.num_kv_heads, dh)
+    with device_scope("attn_window" if rc.window else "attention"):
+        if attend_fn is None:
+            attn = segment_attention(
+                q, k, v, segment_ids, causal=True, window=rc.window
+            )
+        else:  # only a window layer hands a window in
+            own = {"layer_window": rc.window} if rc.window else {}
+            attn = attend_fn(q, k, v, segment_ids, **own)
+    attn = _ad_checkpoint.checkpoint_name(attn, "attn_out")
+    if rc.head_gate:
+        gate = jax.nn.sigmoid((h @ lp["w_head_gate"]).astype(jnp.float32))
+        attn = (attn.astype(jnp.float32) * gate[..., None]).astype(h.dtype)
+    return attn.reshape(b, t, H * dh) @ lp["wo"]
+
+
 def _block_body(
     cfg, kind, x, lp, segment_ids, attend_fn=None, moe_fn=None, kda_fn=None,
+    rotation=None,
 ):
-    """One layer of block ``kind`` (``ModelConfig.layer_kinds``)."""
+    """One layer of block ``kind`` (``ModelConfig.layer_kinds``).
+    ``rotation``: a rotary kind's (cos, sin)."""
     mixer, ffn = kind.split("+")
     h = rms_norm(x, lp["input_norm"], cfg.rms_norm_eps)
     kda = None
     if mixer == "kda":
         y, kda = _kda_mixer(cfg, h, lp, segment_ids, kda_fn)
+    elif mixer in _ROTARY_MIXERS:
+        y = _rotary_mixer(
+            cfg, getattr(cfg, mixer), h, lp, segment_ids, *rotation,
+            attend_fn,
+        )
     else:
         y = _mla_mixer(cfg, h, lp, segment_ids, attend_fn)
     return _ffn_residual(
@@ -562,14 +630,48 @@ def _runs(kinds):
     return [(k, n) for k, n in out]
 
 
+def _rotations(cfg, positions):
+    """{rotary mixer: (cos, sin) of the tokens' ``positions``}: each rotary
+    kind's own frequencies (``RotaryConfig``), made once a kind."""
+    out = {}
+    for mixer in _ROTARY_MIXERS:
+        rc = getattr(cfg, mixer)
+        if rc is not None:
+            out[mixer] = rope_at(
+                positions,
+                rope_inv_freq(
+                    rc.rotary_dim or cfg.head_dim, rc.rope_theta,
+                    rc.yarn_factor, rc.yarn_original_len, rc.yarn_beta_fast,
+                    rc.yarn_beta_slow,
+                ),
+                rc.attention_factor,
+            )
+    return out
+
+
+def _swa_stats(cfg, segment_ids, positions):
+    """``SWAStats`` of the model's window layers on these rows (the same
+    count every such layer: the window is the kind's)."""
+    n = sum(kind.startswith("swa+") for kind in cfg.layer_kinds)
+    if not n:
+        return None
+    seen = jnp.where(segment_ids > 0, positions.astype(jnp.int32) + 1, 0)
+    return SWAStats(
+        jnp.full((n,), jnp.minimum(seen, cfg.swa.window).sum(), jnp.int32),
+        jnp.full((n,), seen.sum(), jnp.int32),
+    )
+
+
 def _apply_kinds(
-    cfg, params, x, segment_ids, remat, attend_fn, moe_fn, kda_fn, act_sharding,
+    cfg, params, x, segment_ids, positions, remat, attend_fn, moe_fn, kda_fn,
+    act_sharding,
 ):
     """The stack of a model of several block kinds: every run of consecutive
     layers of one kind is a scan over that kind's slice of its stack.
     Returns (x, ``LayerStats`` summed over the layers: the load-balancing
     loss the mean of the sparse layers')."""
     runs = _runs(cfg.layer_kinds)
+    rotations = _rotations(cfg, positions)
     pieces = {
         kind: iter(zip(*(
             _split_stack(a, tuple(n for k, n in runs if k == kind))
@@ -586,7 +688,8 @@ def _apply_kinds(
 
         def body(carry, lp, kind=kind):
             out, aux = _block_body(
-                cfg, kind, carry, lp, segment_ids, attend_fn, moe_fn, kda_fn
+                cfg, kind, carry, lp, segment_ids, attend_fn, moe_fn, kda_fn,
+                rotations.get(kind.split("+")[0]),
             )
             if act_sharding is not None:
                 out = jax.lax.with_sharding_constraint(out, act_sharding)
@@ -625,6 +728,7 @@ def _apply_kinds(
         ) if moe else MoEStats(*(jnp.zeros((), jnp.float32),) * 3),
         None,
         KDAStats(*(total(kda, f) for f in KDAStats._fields)) if kda else None,
+        _swa_stats(cfg, segment_ids, positions),
     )
 
 
@@ -661,7 +765,10 @@ def apply(
     segment_ids)` does the same for the gated delta rule
     (``ops/kda.make_kda_scan``); a model with such layers also reports
     ``kda``: the tokens, chunk slots and sequence starts its scans saw,
-    int32, summed over those layers.
+    int32, summed over those layers; one with window-attention layers
+    ``swa`` (``SWAStats``), and its ``attend_fn`` is called with
+    ``layer_window=`` by those layers (``ops.flash.flash_segment_attention``
+    takes it; the XLA form when ``attend_fn`` is None).
 
     `attend_fn(q, k, v, segment_ids)` overrides the attention kernel (e.g.
     ring / Ulysses shard_map from ops/ring_attention.py); default is the
@@ -698,8 +805,8 @@ def apply(
 
     if cfg.layer_kinds:
         x, stats = _apply_kinds(
-            cfg, params, x, segment_ids, remat, attend_fn, moe_fn, kda_fn,
-            act_sharding,
+            cfg, params, x, segment_ids, positions, remat, attend_fn, moe_fn,
+            kda_fn, act_sharding,
         )
         logits = _head(params, cfg, x, return_hidden)
         return (logits, stats) if return_stats else logits

@@ -72,6 +72,72 @@ def apply_rope(
     return out.astype(dtype)
 
 
+def rope_inv_freq(
+    rotary_dim: int, theta: float, yarn_factor: float = 1.0,
+    yarn_original_len: int = 0, beta_fast: float = 32.0,
+    beta_slow: float = 1.0,
+) -> np.ndarray:
+    """The ``rotary_dim // 2`` rotation frequencies of a head whose first
+    ``rotary_dim`` channels are rotated, float32. Plain rope:
+    ``f_j = theta^(-2j / rotary_dim)``. Yarn (``yarn_factor`` > 1; the HF
+    ``_compute_yarn_parameters``): the channels that turn more than
+    ``beta_fast`` times within ``yarn_original_len`` positions keep ``f_j``,
+    those that turn less than ``beta_slow`` times take ``f_j / yarn_factor``,
+    a linear ramp between: with ``lo``, ``hi`` the floor and the ceiling of
+    ``rotary_dim ln(yarn_original_len / (2 pi b)) / (2 ln theta)`` at the two
+    ``b``, clipped to [0, rotary_dim - 1], ``g_j = clip((j - lo) / (hi -
+    lo), 0, 1)`` and ``inv_freq_j = f_j (1 - g_j) + (f_j / yarn_factor)
+    g_j``."""
+    j = np.arange(0, rotary_dim, 2, dtype=np.float64)
+    f = 1.0 / theta ** (j / rotary_dim)
+    if yarn_factor > 1.0:
+        def turns_at(b):
+            return rotary_dim * np.log(
+                yarn_original_len / (b * 2 * np.pi)
+            ) / (2 * np.log(theta))
+
+        lo = max(np.floor(turns_at(beta_fast)), 0)
+        hi = min(np.ceil(turns_at(beta_slow)), rotary_dim - 1)
+        if lo == hi:
+            hi += 0.001  # the family's guard against a ramp of no width
+        g = np.clip((np.arange(rotary_dim // 2) - lo) / (hi - lo), 0, 1)
+        f = f * (1 - g) + f / yarn_factor * g
+    return f.astype(np.float32)
+
+
+def rope_at(
+    positions: jnp.ndarray, inv_freq: np.ndarray, scale: float = 1.0
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """cos and sin [..., T, R] of per-token ``positions`` [..., T] at the R
+    frequencies ``inv_freq``, float32, each times ``scale`` (yarn's
+    attention factor): what a table ``rope_frequencies``-style would hold at
+    those rows, without the table (a model of 262,144 positions)."""
+    ang = positions.astype(jnp.float32)[..., None] * jnp.asarray(inv_freq)
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
+    return cos, sin
+
+
+def rotate_leading(
+    x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray
+) -> jnp.ndarray:
+    """Rotate the first 2R channels of ``x`` [..., T, H, D] by per-token
+    ``cos``/``sin`` [..., T, R] (``rope_at``), rotate-half pairing over
+    those 2R as ``apply_rope`` has it over a whole head; channels 2R..D pass
+    as they are (a partly rotated head)."""
+    dtype = x.dtype
+    rot = 2 * cos.shape[-1]
+    c, s = cos[..., None, :], sin[..., None, :]
+    xr = x[..., :rot].astype(jnp.float32)
+    x1, x2 = jnp.split(xr, 2, axis=-1)
+    out = jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
+    out = out.astype(dtype)
+    if rot == x.shape[-1]:
+        return out
+    return jnp.concatenate([out, x[..., rot:]], axis=-1)
+
+
 def apply_mrope(
     x: jnp.ndarray,  # [B, T, H, D]
     positions3: jnp.ndarray,  # [B, T, 3] int32 (t, h, w) position ids
@@ -100,12 +166,16 @@ def apply_mrope(
 
 
 def make_segment_mask(
-    q_seg: jnp.ndarray, kv_seg: jnp.ndarray, causal: bool = True
+    q_seg: jnp.ndarray, kv_seg: jnp.ndarray, causal: bool = True,
+    window: int = 0,
 ) -> jnp.ndarray:
     """Boolean attention mask [..., Tq, Tk] for packed streams.
 
     Token i may attend to token j iff both are real (segment id > 0), they
-    belong to the same sequence, and j <= i (causal).
+    belong to the same sequence, and j <= i (causal); with a layer's
+    ``window`` also i - j < window (the ``window`` latest keys, i's own
+    among them; a sequence's tokens are contiguous in a row, so a distance
+    in the row is a distance in the sequence).
     """
     same = (q_seg[..., :, None] == kv_seg[..., None, :]) & (
         q_seg[..., :, None] > 0
@@ -115,6 +185,8 @@ def make_segment_mask(
         qi = jnp.arange(tq)[:, None]
         kj = jnp.arange(tk)[None, :]
         same = same & (kj <= qi + (tk - tq))
+        if window:
+            same = same & (qi + (tk - tq) - kj < window)
     return same
 
 
@@ -124,6 +196,7 @@ def segment_attention(
     v: jnp.ndarray,  # [B, T, Hkv, D]
     segment_ids: jnp.ndarray,  # [B, T]
     causal: bool = True,
+    window: int = 0,  # a window layer's: the keys a query sees (0 = all)
 ) -> jnp.ndarray:
     """Packed-varlen causal attention with GQA; fp32 softmax.
 
@@ -141,7 +214,9 @@ def segment_attention(
     logits = jnp.einsum(
         "bqgrd,bkgd->bgrqk", qg, k, preferred_element_type=jnp.float32
     ) * scale
-    mask = make_segment_mask(segment_ids, segment_ids, causal=causal)
+    mask = make_segment_mask(
+        segment_ids, segment_ids, causal=causal, window=window
+    )
     logits = jnp.where(mask[:, None, None, :, :], logits, -2.3819763e38)
     probs = jax.nn.softmax(logits, axis=-1)
     # fully-masked (padding) rows: softmax of all -inf → near-uniform garbage;

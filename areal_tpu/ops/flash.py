@@ -42,15 +42,33 @@ _PROBED_BLOCK: "int | None" = None
 _MIN_PROBED_EDGE = 512
 
 
-def _tiles(edge: int) -> "_sk.BlockSizes":
+def _tiles(edge: int, fused: bool = True) -> "_sk.BlockSizes":
     """One edge for every tile of forward and backward; what else
-    ``_block_sizes`` found best at every shape it measured."""
+    ``_block_sizes`` found best at every shape it measured. ``fused``: one
+    backward kernel for dq, dk and dv, or the two split ones."""
     kvc = min(512, edge)
+    dq = {} if fused else dict(block_q_dq=edge, block_kv_dq=edge)
     return _sk.BlockSizes(
         block_q=edge, block_kv=edge, block_kv_compute=kvc,
         block_q_dkv=edge, block_kv_dkv=edge, block_kv_dkv_compute=kvc,
-        use_fused_bwd_kernel=True,
+        use_fused_bwd_kernel=fused, **dq,
     )
+
+
+# A window-attention layer's band (``layer_window`` under 1024 keys; a
+# packing window is 1024 or more): its tile edge, and the SPLIT backward. The
+# fused backward writes a partial dq for every kv block, [T / edge, heads, T,
+# d] in the compute dtype, over a grid it does not shrink to the band: at
+# T = 16384, edge 512 and 64/8 heads of 128 that is 8.6 GB a layer, most of
+# it zeros (the compiler's memory_analysis() of grad_step read 14.9 GB of
+# temporaries with it and 7.6 GB without); the split kernels iterate the
+# band's blocks only and write dq once. Measured on the v5e with
+# ``tools/microbench_attn_v2.py --layer-window 512`` on the cell's packed
+# rows, 64/8 heads of 128, forward + backward ms a layer (PERF.md, PR 39):
+# T = 16384: edge 512 split 25.7, edge 512 fused 63.6, edge 256 split 37.5,
+# edge 256 fused refused (16.6 GB); T = 8192: 12.0, 19.8, 16.8, 44.9.
+# ``block_kv_compute`` 512 (256: 26.9 and 12.6).
+BAND_EDGE = 512
 
 
 def probe_block_size(max_block: int = 2048, probe_t: int = 2048) -> int:
@@ -122,6 +140,7 @@ def takes_head_dim(d: int, dv: "int | None" = None) -> bool:
 def resolve_attn_impl(
     attn_impl: str, seq_parallel_size: int, head_dim: int,
     has_indexer: bool = False, head_dim_v: "int | None" = None,
+    layer_window: int = 0,
 ) -> str:
     """A train engine's ``attn_impl`` against what the process can observe
     (the way ``GenerationEngine`` resolves its own ``auto``). A model with
@@ -132,8 +151,16 @@ def resolve_attn_impl(
     wherever it takes the model's head dimension: the XLA form writes
     float32 scores [heads, T, T] per layer and pass. Elsewhere ``auto``
     is that XLA form, which GSPMD shards; so is an explicit SP kernel
-    with nothing to split."""
+    with nothing to split. A model with window-attention layers
+    (``ModelConfig.layer_window``) has the splash kernel and the XLA form,
+    and no sequence-parallel one."""
     no_sp = seq_parallel_size == 1
+    if layer_window and (attn_impl in ("ring", "ulysses") or not no_sp):
+        raise ValueError(
+            f"window attention (a layer's window of {layer_window} keys) "
+            f"has no sequence-parallel form (attn_impl={attn_impl!r}, "
+            f"seq_parallel_size={seq_parallel_size}): ROADMAP B.3"
+        )
     if has_indexer:
         if not no_sp:
             from areal_tpu.ops.sparse_attention import MECHANISM
@@ -155,10 +182,17 @@ def resolve_attn_impl(
 
 
 def flash_window(longest: int, tokens_per_mb: int) -> int:
-    """The kernel's block-sparse local window for a batch whose longest
-    sequence is ``longest``, in power-of-two buckets from 1024 (full causal
-    over a long packed stream of short sequences is T² block iteration;
-    sequences only need their own length). 0 = full causal, which is also
+    """The PACKING window: the kernel's block-sparse local window for a
+    batch whose longest sequence is ``longest``, in power-of-two buckets
+    from 1024 (full causal over a long packed stream of short sequences is
+    T² block iteration; sequences only need their own length). It is never
+    shorter than ``longest``, so it is exact by construction: it removes
+    only pairs the segment ids mask anyway, and the engine asserts so before
+    it hands one to a program (``SPMDTrainEngine._attn_window``). Not to be
+    confused with a LAYER's window (``RotaryConfig.window``, shorter than
+    the sequences: it changes the result and is the model's), which
+    ``flash_segment_attention`` takes as ``layer_window`` and which this
+    function knows nothing of. 0 = full causal, which is also
     what a window above a quarter of a whole microbatch (``tokens_per_mb``)
     gets: every (pad bucket, window) pair is one more grad program to
     compile and to load, a window saves only whole tiles (``_block_sizes``),
@@ -180,19 +214,26 @@ def _block_sizes(t: int, window: int, d: int) -> "_sk.BlockSizes":
       and 512 pays per-step overhead), edge 2048 at T = 16384 (22.7 ms
       against 25.2 at 1024); 8192 was not measured and takes 2048;
     - a local window skips only whole tiles, so a window of at most 1024
-      takes edge 512 (1.39 against 1.44 ms at T = 4096);
+      takes edge 512 (1.39 against 1.44 ms at T = 4096); a LAYER's window
+      of 512 keys (``window`` 511: the band of a window-attention layer,
+      64/8 heads of 128 at T = 16384 and 8192) takes ``BAND_EDGE`` and
+      the split backward (above; PERF.md, PR 39, has the sweep);
     - ``block_kv_compute`` 512 rather than the edge (the smaller compute
       tile overlaps the kv DMA; 256 loses), and the FUSED dq+dkv backward:
       one pass over the data, 10-25% under the split kernels everywhere.
 
     The head dimension moved none of these choices. Never above what
     ``probe_block_size`` found to run, and always a divisor of ``t``."""
-    want = 512 if 0 < window <= 1024 else 2048 if t >= 8192 else 1024
+    band = 0 < window < 1024  # a layer's window, never the packing's
+    want = (
+        BAND_EDGE if band else 512 if 0 < window <= 1024
+        else 2048 if t >= 8192 else 1024
+    )
     want = min(want, _PROBED_BLOCK or want)
     edge = _LANES
     while edge * 2 <= min(want, t) and t % (edge * 2) == 0:
         edge *= 2
-    return _tiles(edge)
+    return _tiles(edge, fused=not band)
 
 
 @functools.lru_cache(maxsize=32)
@@ -206,7 +247,9 @@ def _make_kernel(t: int, rep: int, window: int, d: int, interpret: bool):
             # sequences must NOT pay full-causal T² block iteration — any
             # same-segment pair is within (max segment length - 1)
             # positions, so a causal local window >= that bound plus the
-            # runtime segment-id mask is exact
+            # runtime segment-id mask is exact. A window-attention layer's
+            # band is the same mask, ``window`` = its keys less the query's
+            # own (511), under the same run-time segment ids
             head = _sm.LocalMask((t, t), (window, 0), 0)
         else:
             head = _sm.CausalMask((t, t))
@@ -226,19 +269,34 @@ def flash_segment_attention(
     v: jnp.ndarray,
     segment_ids: jnp.ndarray,  # [B, T]
     causal: bool = True,
-    window: int = 0,  # 0 = full causal; else >= max segment length
+    # the PACKING window (``flash_window``): 0 = full causal; else a bound
+    # on the row's longest sequence, >= max segment length, so the result
+    # is full causal attention all the same
+    window: int = 0,
     interpret: bool = False,  # Pallas interpret mode: the CPU's tests
+    # a window-attention LAYER's: a query sees its ``layer_window`` latest
+    # keys, its own among them (0 = no window). Shorter than the sequences:
+    # it changes the result, and is the model's, not the engine's
+    layer_window: int = 0,
 ) -> jnp.ndarray:
     """Drop-in replacement for ops.basic.segment_attention on TPU. ``v``
     may be narrower than ``q`` and ``k`` (latent attention's 192 | 128): the
-    kernel takes the value width from ``v``, nothing is padded."""
+    kernel takes the value width from ``v``, nothing is padded. The kernel's
+    local mask reaches ``window`` keys back (the packing's, beside the
+    query's own) or ``layer_window - 1`` (the layer's), the shorter where
+    both apply."""
     assert causal, "splash path is causal-only (decoder models)"
     b, t, hq, d = q.shape
     if t % _LANES:
         # the kernel's smallest tile is 128 tokens: a row that is not a
         # whole number of them (the trainer packs to multiples of 256) is
         # short, and its quadratic scores are small
-        return segment_attention(q, k, v, segment_ids, causal=True)
+        return segment_attention(
+            q, k, v, segment_ids, causal=True, window=layer_window
+        )
+    if layer_window:
+        back = layer_window - 1
+        window = min(window, back) if window else back
     hkv = k.shape[2]
     rep = hq // hkv
     kernel = _make_kernel(t, rep, int(window), d, interpret)

@@ -244,7 +244,8 @@ def make_sharded_attention(
 def attention_fn(impl: str, mesh: Mesh, window: int = 0, topk: int = 0):
     """The attention callable ``attend(q, k, v, segment_ids)`` for a
     resolved name (``ops.flash.resolve_attn_impl``) on ``mesh``: "flash"
-    (the Pallas splash kernel with its local ``window``, TPU-only),
+    (the Pallas splash kernel with its packing ``window``, TPU-only; it
+    also takes ``layer_window=`` from a window-attention layer),
     "ring"/"ulysses" (the explicit sequence-parallel ``shard_map``),
     "sparse_topk" (a model with an indexer: ``topk`` selected keys a query,
     ``ops.sparse_attention.make_attend``, which also takes the indexer's
@@ -261,6 +262,7 @@ def attention_fn(impl: str, mesh: Mesh, window: int = 0, topk: int = 0):
 
     attend = functools.partial(flash_segment_attention, window=window)
     if mesh.size == 1:
+        # (a window-attention layer calls it with ``layer_window=``)
         return attend
     # GSPMD cannot partition a Mosaic kernel ("wrap the call in a
     # shard_map"): each device runs it on its own rows (and heads).
@@ -272,10 +274,16 @@ def attention_fn(impl: str, mesh: Mesh, window: int = 0, topk: int = 0):
             "'ring' or 'ulysses' with seq_parallel_size > 1"
         )
     qkv_spec = P(("data", "fsdp"), None, "tensor", None)
-    return jax.shard_map(
-        attend,
-        mesh=mesh,
-        in_specs=(qkv_spec, qkv_spec, qkv_spec, P(("data", "fsdp"), None)),
-        out_specs=qkv_spec,
-        check_vma=False,
-    )
+
+    def sharded(q, k, v, segment_ids, layer_window: int = 0):
+        return jax.shard_map(
+            functools.partial(attend, layer_window=layer_window),
+            mesh=mesh,
+            in_specs=(
+                qkv_spec, qkv_spec, qkv_spec, P(("data", "fsdp"), None)
+            ),
+            out_specs=qkv_spec,
+            check_vma=False,
+        )(q, k, v, segment_ids)
+
+    return sharded

@@ -416,7 +416,56 @@ def kimi_linear_48b_a3b_config() -> ModelConfig:
     )
 
 
+def laguna_xsp2_config() -> ModelConfig:
+    """Laguna-XS.2 (poolside, 33.4B-A3B) as ONE chip of 16 that share each
+    layer holds it: the leading dense layer and one whole period (published
+    layers 0-4: full attention over the dense FFN, three window layers, full
+    attention again), 16 of the 256 softmax-routed experts and the shared
+    one, an eighth of the vocabulary: the cut of
+    benchmark/configs/laguna-xs.2.json (published: 40 layers, 100,352 rows).
+    Every width, both kinds' heads (48 full, 64 window), the window of 512,
+    both rotations, the router's 256 outputs and 8 a token are as published.
+    The trainer runs it; the generation engine, ``hf_io`` and sequence
+    parallelism refuse it by name (ROADMAP B.3)."""
+    from areal_tpu.models.config import RotaryConfig
+
+    return ModelConfig(
+        vocab_size=12544,
+        hidden_size=2048,
+        intermediate_size=8192,
+        num_layers=5,
+        num_heads=48,
+        num_kv_heads=8,
+        head_dim=128,
+        max_position_embeddings=262144,
+        rms_norm_eps=1e-6,
+        tie_word_embeddings=False,
+        family="laguna",
+        num_experts=256,
+        num_experts_per_tok=8,
+        moe_intermediate_size=512,
+        norm_topk_prob=True,
+        router_aux_loss_coef=0.0,
+        shared_expert_size=512,
+        shared_expert_gate=False,
+        routed_scaling_factor=2.5,
+        experts_held=16,
+        layer_kinds=("full+dense", "swa+moe", "swa+moe", "swa+moe",
+                     "full+moe"),
+        full=RotaryConfig(
+            num_heads=48, rotary_dim=64, rope_theta=500000.0,
+            yarn_factor=64.0, yarn_original_len=4096, yarn_beta_fast=64.0,
+            yarn_beta_slow=1.0, attention_factor=1.4158883083359672,
+            head_gate=True,
+        ),
+        swa=RotaryConfig(
+            num_heads=64, window=512, rope_theta=10000.0, head_gate=True
+        ),
+    )
+
+
 MODEL_CONFIGS = {
+    "laguna_xsp2": laguna_xsp2_config,
     "kimi_linear_48b_a3b": kimi_linear_48b_a3b_config,
     "keye_vl_2p0_30b_a3b": keye_vl_2p0_30b_a3b_config,
     "olmoe_1b_7b": olmoe_1b_7b_config,

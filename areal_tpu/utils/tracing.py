@@ -154,6 +154,11 @@ DEVICE_SCOPES = (
     # passes; and what the layer computes around it (the short convolution,
     # SiLU, l2norm, decay, beta, the gated output norm)
     "kda_scan", "kda_prep",
+    # the core of a window-attention layer (models/transformer.
+    # _rotary_mixer): the splash kernel over the window's band and the
+    # relayouts around it. NOT inside ``attention``, which keeps the
+    # full-attention layers' core
+    "attn_window",
 )
 
 

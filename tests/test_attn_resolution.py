@@ -109,11 +109,14 @@ def test_auto_on_a_tpu_mesh_is_the_kernel_under_shard_map(
 
     def shard_map(fn, **kw):
         seen.update(kw, fn=fn)
-        return "sharded"
+        return lambda *arrays: "sharded"
 
     monkeypatch.setattr(jax, "shard_map", shard_map)
-    assert attention_fn(eng.attn_impl, eng.mesh, 0) == "sharded"
+    # the shard_map is made where the layer calls (a window-attention layer
+    # hands its own window in there)
+    assert attention_fn(eng.attn_impl, eng.mesh, 0)(*"qkvs") == "sharded"
     assert seen["fn"].func is flash.flash_segment_attention
+    assert seen["fn"].keywords == {"window": 0, "layer_window": 0}
     assert seen["mesh"] is eng.mesh
     # rows over (data, fsdp), heads over tensor, the sequence whole
     assert tuple(seen["out_specs"]) == (("data", "fsdp"), None, "tensor", None)

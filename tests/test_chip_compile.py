@@ -160,6 +160,60 @@ def test_splash_fwd_bwd_compiles_for_v5e(
     assert "tpu_custom_call" in compiled.as_text()
 
 
+# (q heads, T, the layer's window, edge, fused backward): Laguna-XS.2's two
+# rotary kinds over 8 key heads of 128 at the cell's 16384-token row and the
+# 8192-token rung: the window layers' band (its own tiles and the split
+# backward, ``flash.BAND_EDGE``) and the full layers' causal core
+_LAGUNA_CASES = [
+    pytest.param(64, 16384, 512, True, id="swa-h64-t16384"),
+    pytest.param(64, 8192, 512, True, id="swa-h64-t8192"),
+    pytest.param(48, 16384, 0, False, id="full-h48-t16384"),
+]
+
+
+@pytest.mark.parametrize("hq,t,layer_window,band", _LAGUNA_CASES)
+def test_window_and_full_cores_compile_for_v5e(
+    one_chip, monkeypatch, hq, t, layer_window, band
+):
+    """fwd+bwd of both of Laguna's attention cores at the published heads:
+    the band's kernel instance is its own (``LocalMask`` 511 back, the split
+    backward: no partial dq a kv block), the full core's is today's."""
+    import jax
+    import jax.numpy as jnp
+
+    from areal_tpu.ops import flash
+
+    monkeypatch.setattr(flash, "_PROBED_BLOCK", 2048)
+    flash._make_kernel.cache_clear()
+    bf = jnp.bfloat16
+
+    def loss(q, k, v, seg):
+        return flash.flash_segment_attention(
+            q, k, v, seg, layer_window=layer_window
+        ).astype(jnp.float32).sum()
+
+    try:
+        lowered = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            _sds((1, t, hq, 128), bf, one_chip),
+            _sds((1, t, 8, 128), bf, one_chip),
+            _sds((1, t, 8, 128), bf, one_chip),
+            _sds((1, t), jnp.int32, one_chip),
+        )
+        compiled = lowered.compile()
+    finally:
+        flash._make_kernel.cache_clear()
+    tiles = flash._block_sizes(t, layer_window - 1 if band else 0, 128)
+    assert tiles.block_q == (flash.BAND_EDGE if band else 2048)
+    assert tiles.use_fused_bwd_kernel == (not band)
+    text = lowered.as_text()
+    assert ("splash_mqa_dq" in text) == band  # the split backward's own dq
+    assert "tpu_custom_call" in compiled.as_text()
+    # the fused backward's partial dq a kv block would be [T / edge, rep, T,
+    # 128] bf16 a key head: 8.6 GB at the cell's row; the split one has none
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < (3e9 if band else 6e9), temp
+
+
 def test_the_probes_first_candidate_compiles_for_v5e(one_chip):
     """What ``probe_block_size`` tries first: edge 2048, 4 heads of 128
     over one kv head, T = 2048. It needs the raised scoped-VMEM limit."""

@@ -144,6 +144,9 @@ WANT = {
     # around it, beside the latent layer's attention and the experts
     "kda_grad_step": {"kda_scan", "kda_prep", "attention", "moe_experts",
                       "mlp"},
+    # two rotary kinds: the window layers' core under its own scope, NOT
+    # inside attention, which keeps the full layers' core
+    "swa_grad_step": {"attn_window", "attention", "moe_experts", "mlp"},
 }
 
 
@@ -156,6 +159,7 @@ def lowered():
     out["sparse_grad_step"] = _op_names(_train_programs("olmoe")[0][1])
     out["dsa_grad_step"] = _op_names(_train_programs("KeyeVL2")[0][1])
     out["kda_grad_step"] = _op_names(_train_programs("kimi_linear")[0][1])
+    out["swa_grad_step"] = _op_names(_train_programs("laguna")[0][1])
     return out
 
 
@@ -165,9 +169,15 @@ def test_program_lowers_under_its_contract_name_with_its_scopes(
 ):
     text, names = lowered[program]
     jitted = program.removeprefix("sparse_").removeprefix("dsa_")
-    jitted = jitted.removeprefix("kda_")
+    jitted = jitted.removeprefix("kda_").removeprefix("swa_")
     assert re.search(rf"^HloModule jit_{jitted}[, ]", text, re.M)
     assert WANT[program] <= _scopes_in(names), sorted(names)[:40]
+
+
+def test_the_window_core_is_not_nested_in_attention(lowered):
+    _text, names = lowered["swa_grad_step"]
+    window = [n for n in names if "attn_window" in n]
+    assert window and not [n for n in window if "/attention/" in n]
 
 
 def test_every_scope_of_the_vocabulary_is_computed_somewhere(lowered):

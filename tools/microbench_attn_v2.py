@@ -6,6 +6,19 @@ rows of the benchmark's own GRPO batches (``benchmark/traffic``).
     python -m tools.microbench_attn_v2 [--t 4096] [--heads 14 2 64] \
         [--out chiprun_out/attn_sweep]
 
+A window-attention layer's band (PERF.md, PR 39: the tile table of
+``ops/flash.BAND_EDGE``) is ``--layer-window 512`` on the rows of the cell
+that holds it, at both of its row lengths and both kinds' heads:
+
+    python -m tools.microbench_attn_v2 --t 16384 --heads 64 8 128 \
+        --traffic grpo_steps16_mixed16k --layer-window 512 --windows 0 \
+        --edges 256 512 --no-xla
+    ... --t 8192 ...;  ... --heads 48 8 128 --layer-window 0 --edges 1024 2048
+
+(``--no-xla``: the XLA form's float32 scores [heads, T, T] do not fit a
+chip at these sizes; the kernel is then held to nothing here, and to the
+reference by the cell's ``window_exact``.)
+
 Every variant is one jitted program with a name of its own, all run under
 one ``jax.profiler`` capture; the times are device times of those programs
 from ``benchmark.program_trace`` (what ``attn_time_share.train`` is read by),
@@ -77,6 +90,12 @@ def main():
     ap.add_argument("--windows", type=int, nargs="*", default=(0, 2048, 1024))
     ap.add_argument("--edges", type=int, nargs="*", default=(512, 1024, 2048))
     ap.add_argument("--out", default="chiprun_out/attn_sweep")
+    ap.add_argument("--traffic", default="grpo_steps32",
+                    help="the traffic file whose packed rows are attended")
+    ap.add_argument("--layer-window", type=int, default=0,
+                    help="a window-attention layer's window (0 = none)")
+    ap.add_argument("--no-xla", action="store_true",
+                    help="leave the XLA form out (its scores do not fit)")
     args = ap.parse_args()
     t, (hq, hkv, d) = args.t, args.heads
     print("device", jax.devices()[0].device_kind,
@@ -86,7 +105,8 @@ def main():
         jax.random.normal(kk, (1, t, h, d), jnp.bfloat16)
         for kk, h in zip(jax.random.split(key, 3), (hq, hkv, hkv))
     )
-    segs = [jnp.asarray(s) for s in packed_rows(t)]
+    segs = [jnp.asarray(s) for s in packed_rows(t, args.traffic)]
+    lw = args.layer_window
 
     def programs(name, attend):
         def fwd(q, k, v, seg):
@@ -104,15 +124,19 @@ def main():
 
     todo = []
     chosen = flash._block_sizes
-    named = [("xla", 0, None)] + list(
+    named = ([] if args.no_xla else [("xla", 0, None)]) + list(
         variants(t, args.windows, args.edges)
     ) + [("chosen", 0, None)]
     for name, window, tiles in named:
         flash._block_sizes = (lambda *_, tiles=tiles: tiles) if tiles else chosen
         flash._make_kernel.cache_clear()
-        fns = programs(name, segment_attention if name == "xla" else
-                       functools.partial(flash.flash_segment_attention,
-                                         window=window))
+        fns = programs(
+            name,
+            functools.partial(segment_attention, window=lw) if name == "xla"
+            else functools.partial(
+                flash.flash_segment_attention, window=window, layer_window=lw
+            ),
+        )
         try:  # trace + compile now, while this variant's tiles are in place
             for fn in fns:
                 jax.block_until_ready(fn(q, k, v, segs[0]))
@@ -141,7 +165,8 @@ def main():
             jax.block_until_ready(grad(q, k, v, segs[i % len(segs)]))
     jax.profiler.stop_trace()
     r = program_trace.reduce(program_trace.load(xplane.find_xplane(args.out)))
-    print(f"T={t} heads={hq}/{hkv}x{d}: device ms a run; '=' marks a program "
+    print(f"T={t} heads={hq}/{hkv}x{d} layer_window={lw} rows of "
+          f"{args.traffic}: device ms a run; '=' marks a program "
           "the compile cache found equal to an earlier one, which has its runs")
     print(f"{'variant':34s} {'fwd':>8s} {'fwd+bwd':>8s}")
     for name, _, _ in todo:
