@@ -6,7 +6,7 @@ single-host↔pod SPMD (here) swaps are config changes.
 """
 
 import abc
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, Iterable, List, Optional
 
 from areal_tpu.api.io_struct import (
     FinetuneSpec,
@@ -67,6 +67,20 @@ class TrainEngine(abc.ABC):
         loss_weight_fn: Callable,
     ) -> Dict[str, float]:
         raise NotImplementedError()
+
+    def train_batches(
+        self,
+        inputs: Iterable[Dict[str, Any]],
+        loss_fn: Callable,
+        loss_weight_fn: Callable,
+    ) -> List[Dict[str, float]]:
+        """``train_batch`` over the minibatches of a step, in order. An
+        engine may draw and dispatch a later one before it has the stats of
+        an earlier one; every one's are there when this returns."""
+        return [
+            self.train_batch(input_, loss_fn, loss_weight_fn)
+            for input_ in inputs
+        ]
 
     def eval_batch(
         self,

@@ -212,16 +212,13 @@ class PPOActor:
         n_mbs = min(cfg.ppo_n_minibatches, max(bsz, 1))
         perm = np.random.permutation(bsz)
         groups = np.array_split(perm, n_mbs)
-        all_stats = []
-        for g in groups:
-            if len(g) == 0:
-                continue
-            mb = batch_select(data, g)
-            stats = self.engine.train_batch(
-                mb, self._loss_fn, _ppo_loss_weight_fn
-            )
-            all_stats.append(stats)
-        return all_stats
+        # a generator: the engine draws minibatch k + 1 (its selection too)
+        # while the device still works on minibatch k, and returns when the
+        # last one's stats are on the host
+        return self.engine.train_batches(
+            (batch_select(data, g) for g in groups if len(g)),
+            self._loss_fn, _ppo_loss_weight_fn,
+        )
 
     @property
     def _loss_fn(self):

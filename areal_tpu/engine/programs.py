@@ -205,12 +205,18 @@ def build_eval_step(
     return jax.jit(eval_step)
 
 
-def build_apply_step(optimizer: optax.GradientTransformation):
+def build_apply_step(optimizer: optax.GradientTransformation, accum_shardings):
     """``apply_step(params, opt_state, grad_accum, total_weight) ->
-    (params', opt_state', grad_norm, ok)``: the accumulated gradient over
-    the step's total weight through the optimizer; the first three are
-    donated. A non-finite gradient norm leaves params and state as they
-    were (``ok`` false)."""
+    (params', opt_state', grad_accum', grad_norm, ok)``: the accumulated
+    gradient over the step's total weight through the optimizer; the first
+    three are donated. A non-finite gradient norm leaves params and state as
+    they were (``ok`` false). ``grad_accum'`` is the accumulator zeroed, in
+    the donated one's buffers: the next minibatch's ``grad_step`` takes it,
+    so a step holds ONE accumulator through its minibatches and no program
+    runs between two of them to make one. ``accum_shardings`` is its layout
+    (the parameters'), stated: zeros depend on no input, so the compiler
+    would have nothing to lay them out by, and an output laid out otherwise
+    than the donated input cannot take its buffers."""
 
     def apply_step(params, opt_state, grad_accum, total_weight):
         with tracing.device_scope("optimizer"):
@@ -233,6 +239,10 @@ def build_apply_step(optimizer: optax.GradientTransformation):
             new_opt = jax.tree_util.tree_map(
                 lambda n, o: jnp.where(ok, n, o), new_opt, opt_state
             )
-            return new_params, new_opt, grad_norm, ok
+            zeroed = jax.tree_util.tree_map(jnp.zeros_like, grad_accum)
+            return new_params, new_opt, zeroed, grad_norm, ok
 
-    return jax.jit(apply_step, donate_argnums=(0, 1, 2))
+    return jax.jit(
+        apply_step, donate_argnums=(0, 1, 2),
+        out_shardings=(None, None, accum_shardings, None, None),
+    )

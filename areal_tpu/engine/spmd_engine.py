@@ -20,11 +20,14 @@ Contracts:
   base_hf_engine.py:423-486 train_batch).
 """
 
+import dataclasses
 import functools
 import os
 import time
 import urllib.request
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import (
+    Any, Callable, Dict, Iterable, Iterator, List, Optional, Tuple,
+)
 
 import jax
 import jax.numpy as jnp
@@ -66,22 +69,25 @@ _DTYPES = {
 }
 
 
-def _lr_schedule(cfg, total_steps: int) -> optax.Schedule:
-    opt = cfg.optimizer
+def _schedule_shape(opt, total_steps: int) -> Tuple[int, int, float]:
+    """(warm-up steps, steps of the main schedule, its last value)."""
     # proportion 0 means NO warmup: the first step must run at full lr
     # (max(1, ...) here made step 0 a silent no-op update)
     warmup = int(opt.warmup_steps_proportion * total_steps)
     if opt.warmup_steps_proportion > 0:
         warmup = max(1, warmup)
-    end = opt.lr * opt.min_lr_ratio
+    return warmup, max(1, total_steps - warmup), opt.lr * opt.min_lr_ratio
+
+
+def _lr_schedule(cfg, total_steps: int) -> optax.Schedule:
+    opt = cfg.optimizer
+    warmup, main_steps, end = _schedule_shape(opt, total_steps)
     if opt.lr_scheduler_type == "cosine":
         main = optax.cosine_decay_schedule(
-            opt.lr, max(1, total_steps - warmup), alpha=opt.min_lr_ratio
+            opt.lr, main_steps, alpha=opt.min_lr_ratio
         )
     elif opt.lr_scheduler_type == "linear":
-        main = optax.linear_schedule(
-            opt.lr, end, max(1, total_steps - warmup)
-        )
+        main = optax.linear_schedule(opt.lr, end, main_steps)
     else:
         main = optax.constant_schedule(opt.lr)
     if warmup == 0:
@@ -89,6 +95,68 @@ def _lr_schedule(cfg, total_steps: int) -> optax.Schedule:
     return optax.join_schedules(
         [optax.linear_schedule(0.0, opt.lr, warmup), main], [warmup]
     )
+
+
+def _host_lr(cfg, total_steps: int) -> Callable[[int], float]:
+    """``_lr_schedule``'s value at a step, computed on the host in float32
+    as optax computes it on the device: what a step REPORTS as its learning
+    rate (the optimizer reads its own count inside ``apply_step``). Asking
+    the optax schedule from Python runs eager device programs and waits
+    for the device's queue behind them; a test holds the two equal."""
+    opt = cfg.optimizer
+    warmup, main_steps, end = _schedule_shape(opt, total_steps)
+    f32 = np.float32
+
+    def linear(init, last, steps, count):
+        frac = f32(1) - f32(min(max(count, 0), steps)) / f32(steps)
+        return f32(init - last) * frac + f32(last)
+
+    def main(count):
+        if opt.lr_scheduler_type == "cosine":
+            cos = f32(0.5) * (f32(1) + np.cos(
+                f32(np.pi) * f32(min(count, main_steps)) / f32(main_steps)
+            ))
+            alpha = opt.min_lr_ratio
+            return f32(opt.lr) * (f32(1 - alpha) * cos + f32(alpha))
+        if opt.lr_scheduler_type == "linear":
+            return linear(opt.lr, end, main_steps, count)
+        return opt.lr  # optax hands back the Python number it was given
+
+    def lr_at(step: int) -> float:
+        if step < warmup:
+            return float(linear(0.0, opt.lr, warmup, step))
+        value = main(step - warmup)
+        # joined to a warm-up, optax selects between the two in float32
+        return float(f32(value) if warmup else value)
+
+    return lr_at
+
+
+@dataclasses.dataclass
+class _PendingUpdate:
+    """A minibatch update that is dispatched and not yet fetched: what
+    ``_resolve_minibatch`` needs to turn the device's scalars into the
+    update's stats."""
+
+    step: int
+    lr: float
+    # ok, grad_norm, total_weight, then a list a microbatch of losses,
+    # weights and stats dicts: device arrays, fetched together
+    scalars: Tuple[Any, ...]
+
+
+def _one_behind(dispatched: Iterable) -> Iterator[Tuple[Any, bool]]:
+    """Each item of ``dispatched`` after the NEXT one has been made, with
+    whether a next one was: the one way the host path fetches a result, so
+    that between two dispatches it never waits for the device. Making an
+    item queues device work; one item is in flight beside the one being
+    made, no more, which bounds what is queued."""
+    items = iter(dispatched)
+    for held in items:
+        for made in items:
+            yield held, True
+            held = made
+        yield held, False
 
 
 def _make_optimizer(opt, schedule) -> optax.GradientTransformation:
@@ -130,12 +198,17 @@ class SPMDTrainEngine(TrainEngine):
         self.opt_state = None
         self.optimizer = None
         self.lr_schedule = None
+        self._host_lr: Optional[Callable[[int], float]] = None
         self.step_count = 0
         # cumulative tokens dispatched (counters()), counted where every
         # microbatch is packed, from its segment ids; a sparse model adds
         # (initialize) the (token, expert) pairs its train microbatches
         # routed and the busiest expert's share of them, summed over layers
         self._counters = {"real_tokens": 0, "padded_tokens": 0}
+        # results fetched from the device (a train minibatch's scalars, a
+        # recompute microbatch's logprobs) and those of them fetched after
+        # a later dispatch was queued (fetch_counters())
+        self._fetches = {"fetches": 0, "fetches_behind": 0}
         self._version = 0
         self.compute_dtype = _DTYPES[config.dtype]
         self.param_dtype = _DTYPES[config.param_dtype]
@@ -171,9 +244,9 @@ class SPMDTrainEngine(TrainEngine):
     ):
         cfg = self.config
         # every program this thread makes from here on is in the trainer's
-        # compile log by name (init_params, the optimizer state, the eager
-        # scalars of a step, ...), with no line at its call site; the two
-        # dispatch scopes of a step add their phase and shape
+        # compile log by name (init_params, the optimizer state, the sum of
+        # a minibatch's weights, ...), with no line at its call site; the
+        # two dispatch scopes of a step add their phase and shape
         goodput.set_thread_tracker(goodput.trainer_tracker(), phase="trainer")
         self.mesh = mesh_lib.make_mesh(cfg.parallel)
         if model_config is not None:
@@ -227,6 +300,7 @@ class SPMDTrainEngine(TrainEngine):
         if cfg.optimizer is not None:
             total_steps = ft_spec.total_train_steps if ft_spec else 10000
             self.lr_schedule = _lr_schedule(cfg, total_steps)
+            self._host_lr = _host_lr(cfg, total_steps)
             self.optimizer = _make_optimizer(
                 cfg.optimizer, self.lr_schedule
             )
@@ -283,6 +357,7 @@ class SPMDTrainEngine(TrainEngine):
         cfg.optimizer = opt_config
         try:
             self.lr_schedule = _lr_schedule(cfg, total_steps)
+            self._host_lr = _host_lr(cfg, total_steps)
         finally:
             cfg.optimizer = old_opt
         self.optimizer = _make_optimizer(opt_config, self.lr_schedule)
@@ -356,6 +431,18 @@ class SPMDTrainEngine(TrainEngine):
         summed over those layers and the train microbatches). Their per-step
         increments are the stats plane's ``spmd/<name>``."""
         return dict(self._counters)
+
+    def fetch_counters(self) -> Dict[str, int]:
+        """Cumulative since construction: ``fetches``, the results the host
+        fetched from the device (one a train minibatch, one a recompute
+        microbatch), and ``fetches_behind``, those it fetched after queueing
+        a later dispatch, so with the device at work through the wait: 3 of
+        a GRPO step's 4 minibatches, all but the last microbatch of a
+        recompute pass. A share near 0 says the host waits between
+        dispatches again. (Beside ``counters()`` and not in it: a test of
+        the benchmark's holds that one's keys, and the benchmark's files
+        change in a PR of their own.)"""
+        return dict(self._fetches)
 
     def get_version(self) -> int:
         return self._version
@@ -550,109 +637,148 @@ class SPMDTrainEngine(TrainEngine):
         loss_fn: Callable,
         loss_weight_fn: Callable,
     ) -> Dict[str, float]:
+        return self.train_batches([input_], loss_fn, loss_weight_fn)[0]
+
+    def train_batches(
+        self,
+        inputs: Iterable[Batch],
+        loss_fn: Callable,
+        loss_weight_fn: Callable,
+    ) -> List[Dict[str, float]]:
+        """One optimizer step a minibatch of ``inputs``, in order, and each
+        one's stats. ``inputs`` is taken lazily: minibatch k + 1 is drawn
+        from it, split, packed and dispatched BEFORE minibatch k's scalars
+        are fetched, so at a minibatch border the device has its next work
+        queued while the host fetches and prepares (``_one_behind``). It
+        returns when the last minibatch's scalars are on the host: no device
+        work of the call is left queued."""
         if self.optimizer is None:
             raise RuntimeError("no optimizer configured")
+
+        def dispatched():
+            # ONE float32 accumulator a call: zeroed at its first minibatch,
+            # then each apply_step hands it back zeroed for the next, so a
+            # minibatch queued ahead allocates none beside the one still in
+            # the queue; it goes when the last minibatch is dispatched, and
+            # between calls the engine holds none (as before)
+            grad_accum = None
+            for input_ in inputs:
+                pending, grad_accum = self._dispatch_minibatch(
+                    input_, loss_fn, loss_weight_fn, grad_accum
+                )
+                yield pending
+
+        return [
+            self._resolve_minibatch(pending, behind)
+            for pending, behind in _one_behind(dispatched())
+        ]
+
+    def _fetch(self, tree, behind: bool):
+        """``tree``'s device arrays on the host, in one go (every leaf's
+        copy is started before the first is waited for). ``behind``: a later
+        dispatch is already queued, so the device works through the wait."""
+        self._fetches["fetches"] += 1
+        self._fetches["fetches_behind"] += int(behind)
+        return jax.device_get(tree)
+
+    def _dispatch_minibatch(
+        self, input_: Batch, loss_fn: Callable, loss_weight_fn: Callable,
+        grad_accum,
+    ) -> Tuple[_PendingUpdate, Any]:
+        """The first half of a minibatch's update: split, pack, every
+        ``grad_step``, ``apply_step``, all queued and none waited for.
+        ``grad_accum`` is the zeroed accumulator the minibatch before handed
+        on (None: make one); the one ``apply_step`` hands back is returned
+        beside the pending record."""
         step = self.step_count
         with tracing.span("train.minibatch", step=step):
-            return self._train_minibatch(input_, loss_fn, loss_weight_fn, step)
-
-    def _train_minibatch(
-        self, input_: Batch, loss_fn: Callable, loss_weight_fn: Callable,
-        step: int,
-    ) -> Dict[str, float]:
-        with tracing.span("train.split", step=step):
-            mbs = self._split(input_, backward=True)
-            window = self._attn_window(input_)
-            grad_accum = self._zero_grads()
-        losses, weights, all_stats = [], [], []
-        # goodput attribution: host packing books data_h2d, the grad
-        # dispatches book fwd_bwd (minus any compile, which the trainer
-        # CompileTracker carves into the compile bucket with this
-        # microbatch's shape signature), the apply + scalar fetch books
-        # optim. Where the device's time goes is the profile's to say
-        # (programs grad_step / apply_step); these are the host's intervals
-        # in it
-        for mb, row_len in zip(mbs.mbs, mbs.row_lens):
-            with goodput.trainer_bucket("data_h2d"):
-                _, arrays = self._pack_for_device(mb, pad_to=row_len)
-            grad_fn, mb_window = self._program(
-                ("grad", loss_fn, loss_weight_fn), programs.build_grad_step,
-                window, row_len, remat=self.config.gradient_checkpointing,
-                loss_fn=loss_fn, loss_weight_fn=loss_weight_fn,
-            )
-            with goodput.trainer_bucket("fwd_bwd"), tracing.span(
-                "train.grad_dispatch", step=step,
-                attn_impl=self.attn_impl, window=mb_window,
-                **self._impl_attrs(),
-            ), goodput.dispatch_scope(
-                goodput.trainer_tracker(), "fwd_bwd",
-                f"pad{row_len}|window{mb_window}",
-            ):
-                grad_accum, loss, stats, w = grad_fn(
-                    self.params, grad_accum, arrays
+            with tracing.span("train.split", step=step):
+                mbs = self._split(input_, backward=True)
+                window = self._attn_window(input_)
+                if grad_accum is None:
+                    grad_accum = self._zero_grads()
+            losses, weights, all_stats = [], [], []
+            # goodput attribution: host packing books data_h2d, the grad
+            # dispatches book fwd_bwd (minus any compile, which the trainer
+            # CompileTracker carves into the compile bucket with this
+            # microbatch's shape signature), the apply and the scalar fetch
+            # book optim. Where the device's time goes is the profile's to
+            # say (programs grad_step / apply_step); these are the host's
+            # intervals in it
+            for mb, row_len in zip(mbs.mbs, mbs.row_lens):
+                with goodput.trainer_bucket("data_h2d"):
+                    _, arrays = self._pack_for_device(mb, pad_to=row_len)
+                grad_fn, mb_window = self._program(
+                    ("grad", loss_fn, loss_weight_fn),
+                    programs.build_grad_step, window, row_len,
+                    remat=self.config.gradient_checkpointing,
+                    loss_fn=loss_fn, loss_weight_fn=loss_weight_fn,
                 )
-            losses.append(loss)
-            weights.append(w)
-            all_stats.append(stats)
-        total_w = functools.reduce(lambda a, b: a + b, weights)
-        if "apply" not in self._jit_cache:
-            self._jit_cache["apply"] = programs.build_apply_step(
-                self.optimizer
-            )
-        apply_fn = self._jit_cache["apply"]
-        # the optim bucket spans apply THROUGH the blocking scalar
-        # fetch: the fetch is where every async dispatch's device
-        # compute actually lands on the wall clock
-        with goodput.trainer_bucket("optim"):
-            with tracing.span(
+                with goodput.trainer_bucket("fwd_bwd"), tracing.span(
+                    "train.grad_dispatch", step=step,
+                    attn_impl=self.attn_impl, window=mb_window,
+                    **self._impl_attrs(),
+                ), goodput.dispatch_scope(
+                    goodput.trainer_tracker(), "fwd_bwd",
+                    f"pad{row_len}|window{mb_window}",
+                ):
+                    grad_accum, loss, stats, w = grad_fn(
+                        self.params, grad_accum, arrays
+                    )
+                losses.append(loss)
+                weights.append(w)
+                all_stats.append(stats)
+            total_w = functools.reduce(lambda a, b: a + b, weights)
+            if "apply" not in self._jit_cache:
+                self._jit_cache["apply"] = programs.build_apply_step(
+                    self.optimizer, self._param_shardings
+                )
+            with goodput.trainer_bucket("optim"), tracing.span(
                 "train.apply_dispatch", step=step
-            ), goodput.dispatch_scope(
-                goodput.trainer_tracker(), "optim"
-            ):
-                self.params, self.opt_state, grad_norm, ok = apply_fn(
+            ), goodput.dispatch_scope(goodput.trainer_tracker(), "optim"):
+                (
+                    self.params, self.opt_state, grad_accum, grad_norm, ok,
+                ) = self._jit_cache["apply"](
                     self.params, self.opt_state, grad_accum, total_w
                 )
-            # everything from here to the blob is the host fetching scalars:
-            # the learning rate is a device value too, and its float() is
-            # the first fetch that waits for the queued device work
-            with tracing.span("train.scalar_fetch", step=step):
-                lr = float(self.lr_schedule(self.step_count))
-                self.step_count += 1
-                # ONE packed host fetch for every scalar this step produced
-                # — each separate float() is a full device round-trip
-                # a sparse-attention model's pair counts are int32 [L] a
-                # microbatch (a float32 cannot count a 16k-token row's), a
-                # delta-rule model's token counts int32: their own small
-                # fetch, summed here
-                int_keys = [
-                    k for k in programs.INT_COUNTS if k in all_stats[0]
-                ]
-                stat_keys = sorted(set(all_stats[0]) - set(int_keys))
-                scalars = [ok, grad_norm, total_w] + losses + weights + [
-                    s[k] for s in all_stats for k in stat_keys
-                ]
-                blob = np.asarray(
-                    jnp.stack(
-                        [
-                            jnp.asarray(x, jnp.float32).reshape(())
-                            for x in scalars
-                        ]
-                    )
-                )
-                int_counts = np.asarray(jnp.stack([
-                    jnp.atleast_1d(s[k]) for k in int_keys for s in all_stats
-                ])).reshape(len(int_keys), -1) if int_keys else ()
-        n_mb = len(mbs.mbs)
-        h_ok, h_gnorm, h_total_w = blob[0], blob[1], blob[2]
-        h_losses = blob[3 : 3 + n_mb]
-        h_weights = blob[3 + n_mb : 3 + 2 * n_mb]
-        h_stats = blob[3 + 2 * n_mb :].reshape(n_mb, len(stat_keys))
+            self.step_count += 1
+        return _PendingUpdate(
+            step=step, lr=self._host_lr(step),
+            scalars=(ok, grad_norm, total_w, losses, weights, all_stats),
+        ), grad_accum
+
+    def _resolve_minibatch(
+        self, pending: _PendingUpdate, behind: bool
+    ) -> Dict[str, float]:
+        """The second half: the one fetch of every scalar the update
+        produced (each separate ``float()`` would be a device round-trip of
+        its own), and the host's arithmetic on them."""
+        # the optim bucket takes the fetch as it takes the apply: a fetch
+        # with nothing queued behind it is where the device's work lands on
+        # the wall clock
+        with goodput.trainer_bucket("optim"), tracing.span(
+            "train.scalar_fetch", step=pending.step, behind=int(behind)
+        ):
+            ok, grad_norm, total_w, losses, weights, all_stats = self._fetch(
+                pending.scalars, behind
+            )
+        # a sparse-attention model's pair counts are int32 [L] a microbatch
+        # (a float32 cannot count a 16k-token row's), a delta-rule model's
+        # token counts int32: summed here as integers
+        int_keys = [k for k in programs.INT_COUNTS if k in all_stats[0]]
+        stat_keys = sorted(set(all_stats[0]) - set(int_keys))
+        h_total_w = np.float32(total_w)
+        h_losses = np.asarray(losses, np.float32)
+        h_weights = np.asarray(weights, np.float32)
+        h_stats = np.asarray(
+            [[s[k] for k in stat_keys] for s in all_stats], np.float32
+        )
         out = {
-            "update_successful": float(h_ok),
-            "grad_norm": float(h_gnorm),
-            "lr": lr,
+            "update_successful": float(ok),
+            "grad_norm": float(grad_norm),
+            "lr": pending.lr,
             "loss": float((h_losses * h_weights).sum() / h_total_w),
-            "n_mbs": float(n_mb),
+            "n_mbs": float(len(losses)),
         }
         for j, k in enumerate(stat_keys):
             # a count: summed, counted, and per step
@@ -662,8 +788,8 @@ class SPMDTrainEngine(TrainEngine):
                 stats_tracker.counter(**{f"spmd/{k}": int(out[k])})
             else:
                 out[k] = float((h_stats[:, j] * h_weights).sum() / h_total_w)
-        for k, counts in zip(int_keys, int_counts):
-            n = int(counts.astype(np.int64).sum())
+        for k in int_keys:
+            n = sum(int(np.asarray(s[k], np.int64).sum()) for s in all_stats)
             out[k] = float(n)
             self._counters[k] += n
             stats_tracker.counter(**{f"spmd/{k}": n})
@@ -722,21 +848,30 @@ class SPMDTrainEngine(TrainEngine):
         mbs = self._split(input_, backward=False)
         hook = post_hook or target_aligned_logprobs
         window = self._attn_window(input_)
+
+        def dispatched():
+            for mb, row_len in zip(mbs.mbs, mbs.row_lens):
+                packed, arrays = self._pack_for_device(mb, pad_to=row_len)
+                logp_fn, mb_window = self._program(
+                    ("fwd", hook), programs.build_logp_forward,
+                    window, row_len, hook=hook,
+                )
+                with tracing.span(
+                    "train.logp_dispatch", step=step,
+                    attn_impl=self.attn_impl, window=mb_window,
+                    **self._impl_attrs(),
+                ):
+                    vals = logp_fn(self.params, arrays)
+                yield packed, vals
+
+        # microbatch k + 1 is packed and queued before k's result is
+        # fetched and unpacked: the device computes through both
         outs = []
-        for mb, row_len in zip(mbs.mbs, mbs.row_lens):
-            packed, arrays = self._pack_for_device(mb, pad_to=row_len)
-            logp_fn, mb_window = self._program(
-                ("fwd", hook), programs.build_logp_forward,
-                window, row_len, hook=hook,
-            )
+        for (packed, vals), behind in _one_behind(dispatched()):
             with tracing.span(
-                "train.logp_dispatch", step=step,
-                attn_impl=self.attn_impl, window=mb_window,
-                **self._impl_attrs(),
+                "train.logp_fetch", step=step, behind=int(behind)
             ):
-                vals = logp_fn(self.params, arrays)
-            with tracing.span("train.logp_fetch", step=step):
-                vals = np.asarray(vals)  # blocks on the device here
+                vals = self._fetch(vals, behind)
             with tracing.span("train.unpack", step=step):
                 outs.append(data_utils.unpack_rows_per_token(packed, vals))
         # scatter back to original order at the input's padded width

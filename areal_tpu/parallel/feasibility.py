@@ -184,7 +184,7 @@ def grpo_step_memory(
         opt_shape,
     )
     apply_compiled = (
-        programs.build_apply_step(optimizer)
+        programs.build_apply_step(optimizer, param_sh)
         .lower(
             params_sds,
             opt_sds,

@@ -98,7 +98,9 @@ def _train_programs(family="qwen2"):
     return [
         ("grad_step", grad_fn.lower(eng.params, accum, arrays)),
         ("logp_forward", logp_fn.lower(eng.params, arrays)),
-        ("apply_step", programs.build_apply_step(eng.optimizer).lower(
+        ("apply_step", programs.build_apply_step(
+            eng.optimizer, eng._param_shardings
+        ).lower(
             eng.params, eng.opt_state, accum, w)),
     ]
 

@@ -23,18 +23,18 @@ from areal_tpu.models.config import tiny_config
 from areal_tpu.utils import data as data_utils
 
 
-def _actor(group_size=2, **kw):
+def _actor(group_size=2, ppo_n_minibatches=2, max_tokens_per_mb=32768, **kw):
     cfg = PPOActorConfig(
         dtype="float32",
         param_dtype="float32",
         gradient_checkpointing=False,
-        mb_spec=MicroBatchSpec(max_tokens_per_mb=32768),
+        mb_spec=MicroBatchSpec(max_tokens_per_mb=max_tokens_per_mb),
         optimizer=OptimizerConfig(lr=1e-3, weight_decay=0.0,
                                   warmup_steps_proportion=0.0,
                                   gradient_clipping=10.0),
         parallel=ParallelismConfig(),
         group_size=group_size,
-        ppo_n_minibatches=2,
+        ppo_n_minibatches=ppo_n_minibatches,
         group_reward_norm=True,
         adv_norm=AdvNormConfig(mean_level="batch", std_level="batch"),
         **kw,
