@@ -64,8 +64,14 @@ SWA_COUNTS = {
     "swa_pairs": "pairs",
     "swa_causal_pairs": "causal_pairs",
 }
+# the same for a model with ``cca`` mixers (``CCAStats`` fields): int32
+# [those layers] a microbatch
+CCA_COUNTS = {
+    "cca_causal_pairs": "causal_pairs",
+    "cca_starts": "starts",
+}
 # the counts the host fetches as integers and sums exactly
-INT_COUNTS = {**DSA_COUNTS, **KDA_COUNTS, **SWA_COUNTS}
+INT_COUNTS = {**DSA_COUNTS, **KDA_COUNTS, **SWA_COUNTS, **CCA_COUNTS}
 
 
 def _forward_fn(
@@ -129,7 +135,7 @@ def build_grad_step(
             loss, stats = loss_fn(logits, arrays)
             for counted, names in (
                 (layer_stats.dsa, DSA_COUNTS), (layer_stats.kda, KDA_COUNTS),
-                (layer_stats.swa, SWA_COUNTS),
+                (layer_stats.swa, SWA_COUNTS), (layer_stats.cca, CCA_COUNTS),
             ):
                 if counted is not None:
                     stats = dict(stats, **{

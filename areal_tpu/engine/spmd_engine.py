@@ -309,6 +309,7 @@ class SPMDTrainEngine(TrainEngine):
             cfg.attn_impl, cfg.parallel.seq_parallel_size,
             mc.attn_head_dims[0], has_indexer=mc.has_indexer,
             head_dim_v=mc.attn_head_dims[1], layer_window=mc.layer_window,
+            cca=mc.cca is not None,
         )
         if self.attn_impl == "flash" and jax.default_backend() != "cpu":
             # what the scoped-VMEM limit of this process allows, once
@@ -330,6 +331,8 @@ class SPMDTrainEngine(TrainEngine):
             self._counters.update(dict.fromkeys(programs.KDA_COUNTS, 0))
         if mc.layer_window:
             self._counters.update(dict.fromkeys(programs.SWA_COUNTS, 0))
+        if mc.cca is not None:
+            self._counters.update(dict.fromkeys(programs.CCA_COUNTS, 0))
         n = count_params(self.params)
         logger.info(
             f"initialized {mc.family} model: {n/1e6:.1f}M params on mesh "
@@ -428,8 +431,13 @@ class SPMDTrainEngine(TrainEngine):
         summed over those layers); for a model with window-attention layers
         ``swa_pairs`` and ``swa_causal_pairs`` (the (query, key) pairs inside
         the window, and what the same layers would attend without one,
-        summed over those layers and the train microbatches). Their per-step
-        increments are the stats plane's ``spmd/<name>``."""
+        summed over those layers and the train microbatches); for a model
+        with ``cca`` mixers ``cca_causal_pairs`` and ``cca_starts`` (the
+        (query, key) pairs their cores attended, L (L + 1) / 2 a sequence of
+        L tokens, and the tokens at which their convolutions and value shift
+        reset, one a sequence, each summed over those layers and the train
+        microbatches). Their per-step increments are the stats plane's
+        ``spmd/<name>``."""
         return dict(self._counters)
 
     def fetch_counters(self) -> Dict[str, int]:
@@ -581,12 +589,14 @@ class SPMDTrainEngine(TrainEngine):
     def _impl_attrs(self) -> Dict[str, Any]:
         """What a dispatch span says beside ``attn_impl`` and ``window``
         (the packing window, the full-attention layers'): the forms the ops
-        resolved for this model's experts and learned selection, and the
-        window of its window-attention layers."""
+        resolved for this model's experts and learned selection, the
+        window of its window-attention layers, and its router where that is
+        not ``h @ w_router``."""
         named = {
             "moe_impl": self.moe_impl, "dsa_select_impl": self.dsa_select_impl,
             "kda_impl": self.kda_impl,
             "layer_window": self.model_config.layer_window,
+            "router": self.model_config.router and "mlp_carried",
         }
         return {k: v for k, v in named.items() if v}
 

@@ -364,6 +364,15 @@ class GenerationEngine:
                 "that stop growing and an allocator keyed by layer kind "
                 "(ROADMAP B.3); the trainer can"
             )
+        if model_config.cca is not None:
+            raise NotImplementedError(
+                "the generation engine cannot run compressed convolutional "
+                f"attention (family {model_config.family!r}): a decode step "
+                "needs the last tokens' pre-convolution q and k and the last "
+                "token's second value beside the KV pages, recurrent state "
+                "beside paged state (ROADMAP B.4), and its router's state "
+                "passes from layer to layer; the trainer can"
+            )
         if model_config.layer_kinds:
             raise NotImplementedError(
                 "the generation engine cannot run a stack of block kinds "

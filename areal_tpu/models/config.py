@@ -8,11 +8,14 @@ indexer) differs from its siblings in fields (QKV bias, tied embeddings,
 head_dim override, q/k norm, experts). A model whose layers are of more
 than one kind names them: ``layer_kinds`` holds a block kind a layer
 (``"<mixer>+<ffn>"``: the mixers ``kda`` (gated delta-rule linear
-attention, ``KDAConfig``), ``mla`` (latent attention, ``MLAConfig``) and the
+attention, ``KDAConfig``), ``mla`` (latent attention, ``MLAConfig``), the
 two rotary GQA ones, ``full`` and ``swa`` (all of a sequence's earlier keys,
-or a window of them: a ``RotaryConfig`` each); the FFNs ``dense`` and
-``moe``), and the block kinds, not further per-family scalars, are the unit
-``models/transformer.py`` builds its stacks from.
+or a window of them: a ``RotaryConfig`` each) and ``cca`` (attention inside
+a compressed latent that two causal convolutions mix, ``CCAConfig``); the
+FFNs ``dense`` and ``moe``, the latter routed by ``h @ w_router`` or, where
+the model has a ``RouterConfig``, by a small MLP whose input passes from
+layer to layer), and the block kinds, not further per-family scalars, are
+the unit ``models/transformer.py`` builds its stacks from.
 """
 
 import dataclasses
@@ -73,6 +76,36 @@ class RotaryConfig:
     yarn_beta_slow: float = 1.0
     attention_factor: float = 1.0
     head_gate: bool = False
+
+
+@dataclasses.dataclass(frozen=True)
+class CCAConfig:
+    """The compressed-convolutional-attention mixer's sizes
+    (``models/transformer._cca_mixer``): ``num_heads`` query heads over
+    ``num_kv_heads`` key heads of the model's ``head_dim``, narrower
+    together than the residual stream; q and k pass two causal convolutions
+    over a sequence's own tokens, ``conv_taps[0]`` taps a channel and then
+    ``conv_taps[1]`` taps of a [head_dim, head_dim] matrix a head; the
+    first ``rotary_dim`` channels of a head are rotated (0 = the whole
+    head) at ``rope_theta``. The values are two heads always: the token's
+    own and the previous token's."""
+
+    num_heads: int
+    num_kv_heads: int
+    conv_taps: Tuple[int, int] = (2, 2)
+    rotary_dim: int = 0
+    rope_theta: float = 10000.0
+
+
+@dataclasses.dataclass(frozen=True)
+class RouterConfig:
+    """A router whose scores are an MLP's (``ops/moe.router_mlp``): the
+    token's normed input projected to ``hidden_size``, normed, two GELU
+    layers of that width, one score an expert. A layer's projected input
+    takes the previous layer's (after ITS addition) times a learned scalar,
+    so the stack's scan carries it beside ``x``."""
+
+    hidden_size: int
 
 
 @dataclasses.dataclass(frozen=True)
@@ -146,6 +179,10 @@ class ModelConfig:
     # the shared expert's output times a per-token sigmoid gate (qwen2_moe)
     # or added as it is
     shared_expert_gate: bool = True
+    # the ``cca`` mixer of such a stack, and the sparse layers' router where
+    # it is an MLP (None: ``h @ w_router``)
+    cca: Optional[CCAConfig] = None
+    router: Optional[RouterConfig] = None
 
     @property
     def q_dim(self) -> int:
@@ -217,12 +254,24 @@ class ModelConfig:
 # The trainer runs it; the generation engine (window layers want pages that
 # stop growing, ROADMAP B.3), ``hf_io`` (no published checkpoint names) and
 # sequence parallelism refuse it by name.
+# zaya is a stack of ONE block kind, ``cca+moe`` (``_zaya_from_hf``):
+# attention inside a compressed latent (8 query and 2 key heads on half the
+# residual width, two causal convolutions and a q-k mean on the way in, half
+# the values a token late) over experts as wide as the residual stream of
+# which a token takes ONE, chosen by a small MLP whose input passes from
+# layer to layer; both sublayers scale their residual and their output. The
+# trainer runs it; the generation engine (a decode step needs the last
+# tokens' pre-convolution q, k and the last token's second value beside the
+# pages: recurrent state beside KV pages, ROADMAP B.4), ``hf_io`` (no
+# published checkpoint names) and sequence parallelism (a convolution and a
+# shift across a ring's borders are not built) refuse it by name, as
+# ``_zaya_from_hf`` does the 74B sibling's window layers.
 # gemma2/gpt2 remain out (gemma2: soft-capped scores and post-norms beside
 # its windows; gpt2: learned positions, a new embedding).
 _HF_FAMILIES = (
     "llama", "qwen2", "qwen3", "mistral", "qwen3_moe", "mixtral",
     "qwen2_vl", "qwen2_moe", "gemma", "olmoe", "KeyeVL2", "kimi_linear",
-    "laguna",
+    "laguna", "zaya",
 )
 
 
@@ -444,6 +493,79 @@ def _laguna_from_hf(d: dict) -> ModelConfig:
     )
 
 
+def _zaya_from_hf(d: dict) -> ModelConfig:
+    """``model_type`` zaya: every ``layer_types`` entry ``hybrid`` = one
+    ``cca`` attention sublayer (``cca_time0`` / ``cca_time1`` the two
+    convolutions' taps, ``rope_parameters.hybrid`` the rotation) and one
+    expert sublayer of ``num_experts`` experts (``num_local_experts`` of
+    them held here, where the file says so), ONE a token by an MLP router of
+    ``router_hidden_size`` whose input is carried from layer to layer."""
+    n_layers = d["num_hidden_layers"]
+    types = d["layer_types"]
+    if len(types) != n_layers:
+        raise ValueError(
+            f"zaya: layer_types has {len(types)} entries for {n_layers} "
+            "layers"
+        )
+    other = sorted(set(types) - {"hybrid"})
+    refused = {
+        f"layer_types {other} (only 'hybrid'; the 74B sibling's window "
+        "layers, 'hybrid_sliding', are not built)": other,
+        "a sliding_window": d.get("sliding_window"),
+        f"num_experts_per_tok {d['num_experts_per_tok']} (one a token takes "
+        "the unnormalised probability; nobody has stated this router's "
+        "renormalisation over more)": d["num_experts_per_tok"] != 1,
+        "attention_bias": d.get("attention_bias", False),
+        "lm_head_bias": d.get("lm_head_bias", False),
+        f"hidden_act {d.get('hidden_act')!r}": (
+            d.get("hidden_act", "silu") != "silu"
+        ),
+    }
+    for what, present in refused.items():
+        if present:
+            raise ValueError(f"zaya with {what} is not supported")
+    rope = d["rope_parameters"]["hybrid"]
+    if rope.get("rope_type", "default") != "default":
+        raise ValueError(
+            f"zaya: rope_type {rope['rope_type']!r} is not supported"
+        )
+    head_dim = d["head_dim"]
+    rotary = int(head_dim * rope.get("partial_rotary_factor", 1.0))
+    num_experts = d["num_experts"]
+    held = d.get("num_local_experts", num_experts)
+    return ModelConfig(
+        vocab_size=d["vocab_size"],
+        hidden_size=d["hidden_size"],
+        # no dense FFN anywhere: the experts' width stands in
+        intermediate_size=d["moe_intermediate_size"],
+        num_layers=n_layers,
+        num_heads=d["num_attention_heads"],
+        num_kv_heads=d["num_key_value_heads"],
+        head_dim=head_dim,
+        max_position_embeddings=d.get("max_position_embeddings", 32768),
+        rope_theta=float(rope.get("rope_theta", 10000.0)),
+        rms_norm_eps=d.get("rms_norm_eps", 1e-5),
+        tie_word_embeddings=d.get("tie_word_embeddings", True),
+        family="zaya",
+        num_experts=num_experts,
+        num_experts_per_tok=1,
+        moe_intermediate_size=d["moe_intermediate_size"],
+        # one a token: the weight is the chosen probability as it is
+        norm_topk_prob=False,
+        router_aux_loss_coef=d.get("router_aux_loss_coef", 0.0),
+        experts_held=0 if held == num_experts else held,
+        layer_kinds=("cca+moe",) * n_layers,
+        cca=CCAConfig(
+            num_heads=d["num_attention_heads"],
+            num_kv_heads=d["num_key_value_heads"],
+            conv_taps=(d["cca_time0"], d["cca_time1"]),
+            rotary_dim=0 if rotary == head_dim else rotary,
+            rope_theta=float(rope.get("rope_theta", 10000.0)),
+        ),
+        router=RouterConfig(hidden_size=d["router_hidden_size"]),
+    )
+
+
 def from_hf_config(d: dict) -> ModelConfig:
     """Build from a parsed HF config.json dict (families mirror the
     reference's from_hf registry: realhf/api/from_hf/)."""
@@ -454,6 +576,8 @@ def from_hf_config(d: dict) -> ModelConfig:
         return _kimi_linear_from_hf(d)
     if model_type == "laguna":
         return _laguna_from_hf(d)
+    if model_type == "zaya":
+        return _zaya_from_hf(d)
     num_heads = d["num_attention_heads"]
     hidden = d["hidden_size"]
     head_dim = d.get("head_dim") or hidden // num_heads
@@ -586,6 +710,23 @@ def tiny_vlm_config(vocab_size: int = 128) -> ModelConfig:
 def tiny_config(family: str = "qwen2", vocab_size: int = 128) -> ModelConfig:
     """Small config for tests."""
     moe = family in ("qwen3_moe", "mixtral", "qwen2_moe", "olmoe")
+    if family == "zaya":
+        # three ``cca+moe`` layers: 4 query and 2 key heads of 16 on the
+        # residual's 64 (the latent is as wide as the stream here; half of
+        # each head rotated), a carried router of width 8 over 8 experts of
+        # which 4 are held, one a token
+        return ModelConfig(
+            vocab_size=vocab_size, hidden_size=64, intermediate_size=32,
+            num_layers=3, num_heads=4, num_kv_heads=2, head_dim=16,
+            max_position_embeddings=512, rope_theta=5000000.0,
+            rms_norm_eps=1e-5, tie_word_embeddings=True, family=family,
+            num_experts=8, num_experts_per_tok=1, moe_intermediate_size=32,
+            norm_topk_prob=False, router_aux_loss_coef=0.0, experts_held=4,
+            layer_kinds=("cca+moe",) * 3,
+            cca=CCAConfig(num_heads=4, num_kv_heads=2, rotary_dim=8,
+                          rope_theta=5000000.0),
+            router=RouterConfig(hidden_size=8),
+        )
     if family == "laguna":
         # all three block kinds; 6 (full) and 8 (window of 8 keys) query
         # heads of 16 over 2 key heads, a gate a head; the full layers' yarn

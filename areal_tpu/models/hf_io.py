@@ -145,11 +145,12 @@ def _kind_tensor_names(cfg: ModelConfig):
     stack, expert or None, hf name, how stored) of every layer tensor."""
     from areal_tpu.models.transformer import _kind_leaves
 
-    if cfg.family == "laguna":
+    if cfg.family in ("laguna", "zaya"):
         raise NotImplementedError(
-            "family 'laguna' has no checkpoint layout here: its tensor "
-            "names are in no public source this repo was written from (the "
-            "catalog gives the config.json only); initialise from scratch"
+            f"family {cfg.family!r} has no checkpoint layout here: its "
+            "tensor names are in no public source this repo was written "
+            "from (the catalog gives the config.json only); initialise from "
+            "scratch"
         )
     if cfg.experts_held:
         raise NotImplementedError(
@@ -520,11 +521,49 @@ def _laguna_config_dict(cfg: ModelConfig) -> dict:
     }
 
 
+def _zaya_config_dict(cfg: ModelConfig) -> dict:
+    """The keys ``models/config._zaya_from_hf`` reads."""
+    rotary = (cfg.cca.rotary_dim or cfg.head_dim) / cfg.head_dim
+    return {
+        "model_type": cfg.family,
+        "vocab_size": cfg.vocab_size,
+        "hidden_size": cfg.hidden_size,
+        "num_hidden_layers": cfg.num_layers,
+        "layer_types": ["hybrid"] * cfg.num_layers,
+        "num_attention_heads": cfg.cca.num_heads,
+        "num_key_value_heads": cfg.cca.num_kv_heads,
+        "head_dim": cfg.head_dim,
+        "cca_time0": cfg.cca.conv_taps[0],
+        "cca_time1": cfg.cca.conv_taps[1],
+        "partial_rotary_factor": rotary,
+        "rope_parameters": {"hybrid": {
+            "rope_type": "default", "rope_theta": cfg.cca.rope_theta,
+            "partial_rotary_factor": rotary,
+        }},
+        "max_position_embeddings": cfg.max_position_embeddings,
+        "attention_bias": False,
+        "lm_head_bias": False,
+        "hidden_act": "silu",
+        "rms_norm_eps": cfg.rms_norm_eps,
+        "tie_word_embeddings": cfg.tie_word_embeddings,
+        "torch_dtype": "float32",
+        "num_experts": cfg.num_experts,
+        "num_local_experts": cfg.num_held_experts,
+        "num_experts_per_tok": cfg.num_experts_per_tok,
+        "moe_intermediate_size": cfg.moe_intermediate_size,
+        "router_hidden_size": cfg.router.hidden_size,
+        "router_aux_loss_coef": cfg.router_aux_loss_coef,
+        "sliding_window": None,
+    }
+
+
 def default_hf_config_dict(cfg: ModelConfig) -> dict:
     if cfg.family == "kimi_linear":
         return _kimi_linear_config_dict(cfg)
     if cfg.family == "laguna":
         return _laguna_config_dict(cfg)
+    if cfg.family == "zaya":
+        return _zaya_config_dict(cfg)
     return {
         "model_type": cfg.family,
         "vocab_size": cfg.vocab_size,

@@ -16,11 +16,14 @@ from-scratch parallel causal LM — re-designed TPU-first:
   replacing torch gradient checkpointing (reference base_hf_engine.py).
 - A model whose layers are of more than one kind (``ModelConfig.layer_kinds``:
   gated delta-rule, latent-attention and rotary mixers, the last with or
-  without a window, over dense or sparse FFNs) holds ONE STACK A BLOCK KIND
+  without a window, and the convolutional latent mixer ``cca``, over dense or
+  sparse FFNs) holds ONE STACK A BLOCK KIND
   under ``params["layers"][kind]`` and ``apply`` walks the pattern: every run
   of consecutive layers of one kind is a scan over that kind's slice, under
   the same checkpoint policy. A model of one kind keeps the flat
-  ``params["layers"]`` and the one scan.
+  ``params["layers"]`` and the one scan. Where the sparse layers' router
+  is an MLP (``RouterConfig``), its input passes from layer to layer and
+  rides the scans beside ``x``, for that model only.
 """
 
 import functools
@@ -33,7 +36,7 @@ from jax import ad_checkpoint as _ad_checkpoint
 
 from areal_tpu.models.config import ModelConfig
 from areal_tpu.ops.kda import KDAStats, kda_scan
-from areal_tpu.ops.moe import MoEStats, moe_ffn_from_params
+from areal_tpu.ops.moe import MoEStats, moe_ffn_from_params, router_mlp
 from areal_tpu.ops.sparse_attention import DSAStats, sparse_attention
 from areal_tpu.utils.tracing import device_scope
 from areal_tpu.ops.basic import (
@@ -64,6 +67,7 @@ class LayerStats(NamedTuple):
     dsa: Optional[DSAStats] = None  # sparse-attention models only
     kda: Optional[KDAStats] = None  # gated delta-rule layers only
     swa: Optional["SWAStats"] = None  # window-attention layers only
+    cca: Optional["CCAStats"] = None  # convolutional latent mixers only
 
 
 class SWAStats(NamedTuple):
@@ -74,6 +78,18 @@ class SWAStats(NamedTuple):
 
     pairs: jnp.ndarray
     causal_pairs: jnp.ndarray
+
+
+class CCAStats(NamedTuple):
+    """What a model's ``cca`` mixers ran over, int32 with those layers
+    leading (exact: the host sums every element): the (query, key) pairs the
+    core attends (a sequence of length L gives ``L (L + 1) / 2`` a layer; in
+    partial sums a stretch of a row, since ONE 65,536-token sequence's pairs
+    pass an int32) and the tokens at which the convolutions and the value
+    shift reset (one a sequence and layer)."""
+
+    causal_pairs: jnp.ndarray
+    starts: jnp.ndarray
 
 
 # --------------------------------------------------------------------------
@@ -142,17 +158,66 @@ def _kind_leaves(cfg: ModelConfig, kind: str) -> Dict[str, tuple]:
             leaves["w_head_gate"] = (
                 (D, rc.num_heads), "normal", ("embed", None)
             )
+    elif mixer == "cca":
+        cc, dh = cfg.cca, cfg.head_dim
+        Qd, heads = cc.num_heads * dh, cc.num_heads + cc.num_kv_heads
+        leaves.update({
+            "wq": ((D, Qd), "normal", ("embed", "heads")),
+            "wk": ((D, cc.num_kv_heads * dh), "normal", ("embed", "heads")),
+            # the values' two heads: the token's own, the previous token's
+            "wv_own": ((D, dh), "normal", ("embed", None)),
+            "wv_prev": ((D, dh), "normal", ("embed", None)),
+            "wo": ((Qd, D), "normal", ("heads", "embed")),
+            # over concat(q, k): a tap a channel, then a matrix a head and
+            # tap (tap K-1 the token itself, as ``_short_conv`` has it)
+            "cca_conv0": ((heads * dh, cc.conv_taps[0]), "normal",
+                          (None, None)),
+            "cca_conv0_bias": ((heads * dh,), "zeros", (None,)),
+            "cca_conv1": ((heads, cc.conv_taps[1], dh, dh), "normal",
+                          (None, None, None, None)),
+            "cca_conv1_bias": ((heads * dh,), "zeros", (None,)),
+            "cca_temp": ((cc.num_kv_heads,), "zeros", (None,)),
+        })
+        # both sublayers scale their residual and their output:
+        # x <- (x + b_res) a_res + (f(norm(x)) + b_out) a_out
+        for sub in ("attn", "ffn"):
+            leaves.update({
+                f"{sub}_res_scale": ((D,), "ones", (None,)),
+                f"{sub}_res_bias": ((D,), "zeros", (None,)),
+                f"{sub}_out_scale": ((D,), "ones", (None,)),
+                f"{sub}_out_bias": ((D,), "zeros", (None,)),
+            })
     else:
         raise ValueError(f"no mixer {mixer!r} among the block kinds")
     if ffn == "moe":
         E, Fe = cfg.num_held_experts, cfg.expert_ffn_size
+        if cfg.router is None:
+            leaves["w_router"] = (
+                (D, cfg.num_experts), "normal", ("embed", None)
+            )
+        else:  # an MLP's scores (``ops/moe.router_mlp``)
+            R = cfg.router.hidden_size
+            leaves.update({
+                "w_router_in": ((D, R), "normal", ("embed", None)),
+                "b_router_in": ((R,), "zeros", (None,)),
+                "router_norm": ((R,), "ones", (None,)),
+                "w_router_1": ((R, R), "normal", (None, None)),
+                "b_router_1": ((R,), "zeros", (None,)),
+                "w_router_2": ((R, R), "normal", (None, None)),
+                "b_router_2": ((R,), "zeros", (None,)),
+                "w_router_out": ((R, cfg.num_experts), "normal",
+                                 (None, None)),
+                "b_router_out": ((cfg.num_experts,), "zeros", (None,)),
+                # what the previous layer's state is multiplied by
+                "router_carry": ((), "zeros", ()),
+            })
         leaves.update({
-            "w_router": ((D, cfg.num_experts), "normal", ("embed", None)),
             "w_gate": ((E, D, Fe), "normal", ("expert", "embed", "mlp")),
             "w_up": ((E, D, Fe), "normal", ("expert", "embed", "mlp")),
             "w_down": ((E, Fe, D), "normal", ("expert", "mlp", "embed")),
         })
-        if cfg.router_act == "sigmoid":  # the choice's correction bias
+        # the choice's correction bias: state that no gradient reaches
+        if cfg.router_act == "sigmoid" or cfg.router is not None:
             leaves["router_bias"] = ((cfg.num_experts,), "zeros", (None,))
         if cfg.shared_expert_size:
             Fs = cfg.shared_expert_size
@@ -577,13 +642,142 @@ def _rotary_mixer(cfg, rc, h, lp, segment_ids, cos, sin, attend_fn):
     return attn.reshape(b, t, H * dh) @ lp["wo"]
 
 
+def _previous_token(x, segment_ids):
+    """``x`` [B, T, ...] one token late: position t holds x_{t-1} where
+    token t - 1 is of token t's sequence, zeros at a sequence's first token
+    and at padding (``_short_conv``'s border rule)."""
+    back = jnp.pad(x, ((0, 0), (1, 0)) + ((0, 0),) * (x.ndim - 2))[:, :-1]
+    seg_back = jnp.pad(segment_ids, ((0, 0), (1, 0)))[:, :-1]
+    same = (seg_back == segment_ids) & (segment_ids > 0)
+    return jnp.where(same.reshape(same.shape + (1,) * (x.ndim - 2)), back, 0)
+
+
+def _head_conv(x, w, segment_ids):
+    """Causal convolution over the tokens of ONE sequence whose taps are
+    matrices, a group of channels a head: y_t[head] = sum_s x_{t-s}[head] @
+    w[head, K-1-s], zeros before a sequence's first token. ``x`` [B, T,
+    heads, d] float32; ``w`` [heads, K, d, d]. One batched product over the
+    heads a tap, operands in ``w``'s dtype (the compute dtype: the MXU's),
+    float32 accumulation."""
+    taps = w.shape[1]
+    y = 0.0
+    for s in range(taps):
+        y = y + jnp.einsum(
+            "bthi,hio->btho", x.astype(w.dtype), w[:, taps - 1 - s],
+            preferred_element_type=jnp.float32,
+        )
+        if s + 1 < taps:
+            x = _previous_token(x, segment_ids)
+    return y
+
+
+def cca_qkv(cfg, h, lp, segment_ids, cos, sin):
+    """What a ``cca`` mixer hands its attention core, from the layer's
+    normed input ``h`` [B, T, D]: the rotated q [B, T, H, d] and k, and v
+    [B, T, 2, d]. Between the projections and the rotation (scope
+    ``cca_mix``, float32): the mean of each query head and its key head,
+    added back after the convolutions; over concat(q, k) a depthwise causal
+    convolution, then one whose taps are a matrix a head, each with a bias
+    and zeros before a sequence's first token; q and k normed to length
+    sqrt(d) a head, k times its key head's ``exp(cca_temp)``; the second
+    value head one token late."""
+    b, t, _ = h.shape
+    cc, dh = cfg.cca, cfg.head_dim
+    H, G = cc.num_heads, cc.num_kv_heads
+    q0, k0 = h @ lp["wq"], h @ lp["wk"]
+    v_own, v_prev = h @ lp["wv_own"], h @ lp["wv_prev"]
+    with device_scope("cca_mix"):
+        f32 = jnp.float32
+        qh = q0.astype(f32).reshape(b, t, G, H // G, dh)
+        kh = k0.astype(f32).reshape(b, t, G, 1, dh)
+        mean_q = (qh + kh) * 0.5
+        mean_k = mean_q.mean(axis=3)
+        c = jnp.concatenate([q0, k0], axis=-1)
+        c = _short_conv(c, lp["cca_conv0"], segment_ids) + (
+            lp["cca_conv0_bias"].astype(f32)
+        )
+        c = _head_conv(
+            c.reshape(b, t, H + G, dh), lp["cca_conv1"], segment_ids
+        ) + lp["cca_conv1_bias"].astype(f32).reshape(H + G, dh)
+
+        def unit(y):  # length sqrt(d): an RMS norm without a weight
+            return y * jax.lax.rsqrt(
+                jnp.mean(y * y, axis=-1, keepdims=True) + cfg.rms_norm_eps
+            )
+
+        q = unit(c[:, :, :H] + mean_q.reshape(b, t, H, dh))
+        k = unit(c[:, :, H:] + mean_k) * jnp.exp(
+            lp["cca_temp"].astype(f32)
+        )[:, None]
+        q, k = q.astype(h.dtype), k.astype(h.dtype)
+        v = jnp.stack(
+            [v_own, _previous_token(v_prev, segment_ids)], axis=2
+        )
+    return rotate_leading(q, cos, sin), rotate_leading(k, cos, sin), v
+
+
+def _cca_mixer(cfg, h, lp, segment_ids, cos, sin, attend_fn):
+    """Compressed convolutional attention on the normed input ``h``: GQA in
+    a latent narrower than the residual stream (``cca_qkv``), the causal
+    softmax core over a sequence's own tokens, and the way back out."""
+    b, t, _ = h.shape
+    q, k, v = cca_qkv(cfg, h, lp, segment_ids, cos, sin)
+    with device_scope("attention"):
+        if attend_fn is None:
+            attn = segment_attention(q, k, v, segment_ids, causal=True)
+        else:
+            attn = attend_fn(q, k, v, segment_ids)
+    attn = _ad_checkpoint.checkpoint_name(attn, "attn_out")
+    return attn.reshape(b, t, -1) @ lp["wo"]
+
+
+def _scaled_residual(x, y, lp, sub):
+    """A ``cca`` block's sublayer ``sub`` onto the stream: (x + b_res) a_res
+    + (y + b_out) a_out, in float32."""
+    f32 = jnp.float32
+    return (
+        (x.astype(f32) + lp[f"{sub}_res_bias"].astype(f32))
+        * lp[f"{sub}_res_scale"].astype(f32)
+        + (y.astype(f32) + lp[f"{sub}_out_bias"].astype(f32))
+        * lp[f"{sub}_out_scale"].astype(f32)
+    ).astype(x.dtype)
+
+
+def _cca_block(cfg, carry, lp, segment_ids, rotation, attend_fn, moe_fn):
+    """One ``cca+moe`` layer. ``carry``: ``(x, r)``, the stream and the
+    router's state that passes from layer to layer (``r`` [B, T, R]
+    float32, zeros into the first layer)."""
+    x, r_prev = carry
+    h = rms_norm(x, lp["input_norm"], cfg.rms_norm_eps)
+    y = _cca_mixer(cfg, h, lp, segment_ids, *rotation, attend_fn)
+    x = _scaled_residual(x, y, lp, "attn")
+    h = rms_norm(x, lp["post_attn_norm"], cfg.rms_norm_eps)
+    with device_scope("mlp"):
+        logits, r = router_mlp(lp, h, cfg.rms_norm_eps, r_prev)
+        valid = segment_ids > 0  # padding tokens are no expert's load
+        if moe_fn is None:
+            ffn, stats = moe_ffn_from_params(
+                cfg, lp, h, valid=valid, router_logits=logits
+            )
+        else:
+            ffn, stats = moe_fn(lp, h, valid, logits)
+    x = _scaled_residual(x, ffn, lp, "ffn")
+    return (x, r), LayerStats(stats)
+
+
 def _block_body(
     cfg, kind, x, lp, segment_ids, attend_fn=None, moe_fn=None, kda_fn=None,
     rotation=None,
 ):
     """One layer of block ``kind`` (``ModelConfig.layer_kinds``).
-    ``rotation``: a rotary kind's (cos, sin)."""
+    ``rotation``: a rotary kind's (cos, sin). ``x`` is the scan's carry: the
+    stream, with the router's state beside it in a ``cca`` block whose
+    router carries one."""
     mixer, ffn = kind.split("+")
+    if mixer == "cca":
+        return _cca_block(
+            cfg, x, lp, segment_ids, rotation, attend_fn, moe_fn
+        )
     h = rms_norm(x, lp["input_norm"], cfg.rms_norm_eps)
     kda = None
     if mixer == "kda":
@@ -646,7 +840,32 @@ def _rotations(cfg, positions):
                 ),
                 rc.attention_factor,
             )
+    if cfg.cca is not None:
+        out["cca"] = rope_at(positions, rope_inv_freq(
+            cfg.cca.rotary_dim or cfg.head_dim, cfg.cca.rope_theta
+        ))
     return out
+
+
+_PAIR_STRETCH = 4096  # tokens of a row a partial sum of its pairs covers
+
+
+def _cca_stats(cfg, segment_ids, positions):
+    """``CCAStats`` of the model's ``cca`` layers on these rows (the same
+    count every such layer)."""
+    n = sum(kind.startswith("cca+") for kind in cfg.layer_kinds)
+    if not n:
+        return None
+    real = segment_ids > 0
+    before = jnp.pad(segment_ids, ((0, 0), (1, 0)))[:, :-1]
+    seen = jnp.where(real, positions.astype(jnp.int32) + 1, 0)
+    # a stretch's sum fits an int32 at any position under 2^31 / 4096
+    seen = jnp.pad(seen, ((0, 0), (0, -seen.shape[1] % _PAIR_STRETCH)))
+    pairs = seen.reshape(-1, _PAIR_STRETCH).sum(-1)
+    return CCAStats(
+        jnp.broadcast_to(pairs, (n,) + pairs.shape),
+        jnp.full((n,), (real & (before != segment_ids)).sum(), jnp.int32),
+    )
 
 
 def _swa_stats(cfg, segment_ids, positions):
@@ -680,6 +899,11 @@ def _apply_kinds(
         for kind, stack in params["layers"].items()
     }
     moe, kda = [], []
+    if cfg.router is not None:
+        # the router's state beside the stream, zeros into the first layer
+        x = (x, jnp.zeros(
+            x.shape[:2] + (cfg.router.hidden_size,), jnp.float32
+        ))
     for kind, n in runs:
         stack = jax.tree_util.tree_unflatten(
             jax.tree_util.tree_structure(params["layers"][kind]),
@@ -691,8 +915,12 @@ def _apply_kinds(
                 cfg, kind, carry, lp, segment_ids, attend_fn, moe_fn, kda_fn,
                 rotations.get(kind.split("+")[0]),
             )
-            if act_sharding is not None:
-                out = jax.lax.with_sharding_constraint(out, act_sharding)
+            if act_sharding is not None:  # the carried state's too
+                out = jax.tree_util.tree_map(
+                    lambda a: jax.lax.with_sharding_constraint(
+                        a, act_sharding
+                    ), out,
+                )
             return out, aux
 
         if remat:
@@ -719,6 +947,8 @@ def _apply_kinds(
     def total(stats, field, reduce=jnp.sum):
         return reduce(jnp.concatenate([getattr(s, field) for s in stats]))
 
+    if isinstance(x, tuple):  # the router's last state goes nowhere
+        x = x[0]
     return x, LayerStats(
         MoEStats(
             total(moe, "aux_loss", jnp.mean),
@@ -729,6 +959,7 @@ def _apply_kinds(
         None,
         KDAStats(*(total(kda, f) for f in KDAStats._fields)) if kda else None,
         _swa_stats(cfg, segment_ids, positions),
+        _cca_stats(cfg, segment_ids, positions),
     )
 
 

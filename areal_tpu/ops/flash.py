@@ -140,7 +140,7 @@ def takes_head_dim(d: int, dv: "int | None" = None) -> bool:
 def resolve_attn_impl(
     attn_impl: str, seq_parallel_size: int, head_dim: int,
     has_indexer: bool = False, head_dim_v: "int | None" = None,
-    layer_window: int = 0,
+    layer_window: int = 0, cca: bool = False,
 ) -> str:
     """A train engine's ``attn_impl`` against what the process can observe
     (the way ``GenerationEngine`` resolves its own ``auto``). A model with
@@ -153,8 +153,18 @@ def resolve_attn_impl(
     is that XLA form, which GSPMD shards; so is an explicit SP kernel
     with nothing to split. A model with window-attention layers
     (``ModelConfig.layer_window``) has the splash kernel and the XLA form,
-    and no sequence-parallel one."""
+    and no sequence-parallel one; neither has one with ``cca`` mixers,
+    whose convolutions and value shift read the previous tokens across what
+    would be a ring's borders."""
     no_sp = seq_parallel_size == 1
+    if cca and (attn_impl in ("ring", "ulysses") or not no_sp):
+        raise ValueError(
+            "compressed convolutional attention (family 'zaya': causal "
+            "convolutions and a one-token value shift before the core) has "
+            f"no sequence-parallel form (attn_impl={attn_impl!r}, "
+            f"seq_parallel_size={seq_parallel_size}): a convolution and a "
+            "shift across a ring's borders are not built"
+        )
     if layer_window and (attn_impl in ("ring", "ulysses") or not no_sp):
         raise ValueError(
             f"window attention (a layer's window of {layer_window} keys) "

@@ -45,7 +45,10 @@ The router has two forms (``router_topk``): a softmax over all experts whose
 ``k`` largest probabilities are the weights (renormalised or not), and
 sigmoid scores of which the ``k`` largest AFTER a correction bias are chosen,
 weighted by the scores without the bias, renormalised, times a scaling
-factor. A family's shared expert (a dense gated FFN on every token, times a
+factor. The scores are ``x @ w_router`` (``moe_ffn``) or come from outside
+(``moe_ffn_scored``, the part of the layer from the choice on: a family
+whose router is an MLP with an input carried from layer to layer,
+``router_mlp``, hands its scores in). A family's shared expert (a dense gated FFN on every token, times a
 per-token sigmoid gate where the family has one) is part of the sparse layer
 (``moe_ffn_from_params``), so under a mesh it runs inside the same
 ``shard_map``.
@@ -65,6 +68,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
+from areal_tpu.ops.basic import rms_norm
 from areal_tpu.utils.tracing import device_scope
 
 # megablox tiles (m, k, n). m must divide the sorted stream, so a stream
@@ -106,19 +110,23 @@ def router_topk(
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Returns (topk_probs [G,k], topk_idx [G,k], full probs [G,E]).
     ``act`` "softmax": the ``k`` largest probabilities. "sigmoid": the
-    experts of the ``k`` largest ``sigmoid(logits) + bias`` (the bias moves
-    the choice only, and no gradient reaches it), weighted by their scores
-    WITHOUT the bias. Either renormalised where ``norm_topk_prob``, then
-    times ``scale``."""
+    experts of the ``k`` largest ``sigmoid(logits)``. With a ``bias`` the
+    ``k`` largest of scores + bias are chosen (the bias moves the choice
+    only, and no gradient reaches it), weighted by their scores WITHOUT it.
+    Either renormalised where ``norm_topk_prob`` (at ``k`` = 1 that would
+    make every weight 1 and cut the router off from the loss: one a token
+    takes the probability as it is), then times ``scale``."""
     if act == "sigmoid":
         probs = jax.nn.sigmoid(logits.astype(jnp.float32))
+    else:
+        probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    if act == "sigmoid" or bias is not None:
         ranked = probs if bias is None else probs + jax.lax.stop_gradient(
             bias.astype(jnp.float32)
         )
         topk_i = jax.lax.top_k(ranked, k)[1]
         topk_p = jnp.take_along_axis(probs, topk_i, axis=-1)
     else:
-        probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
         topk_p, topk_i = jax.lax.top_k(probs, k)
     if norm_topk_prob:
         topk_p = topk_p / jnp.maximum(
@@ -330,7 +338,13 @@ def _run_experts(xf, pair_expert, topk_p, group_sizes, w_gate, w_up, w_down):
 # in ten; at 1 a second slab (three more grouped matmuls that each read all
 # the held weights) would run in 15% and 42% of the chunks; at 3 the [G, C]
 # product below costs half as much again in every chunk to save 7% of them
-# a second slab.
+# a second slab. A layer that holds HALF of its experts (one a token over
+# 16, 8 held: even share rows / 2) has a slab of rows x 1/2 x 2 = the whole
+# stream: ``_held_rows`` gives ``rows``, ``_run_held`` takes the whole form
+# (``_run_experts``: every pair sorted and gathered, the other holder's half
+# as zero rows behind the groups), nothing is compacted and no chunk can
+# overflow. The slack's price there is nil and so is its gain; a slab of
+# the even share alone (slack 1) would overflow in about every second chunk.
 _HELD_SLACK = 2
 
 
@@ -573,21 +587,97 @@ def moe_ffn(
     runs per device inside a ``shard_map``: the statistics (and the
     auxiliary loss) are then the whole batch's, summed over those axes.
     Where ``w_gate`` holds fewer experts than ``w_router`` scores, they are
-    experts ``first_expert`` and following, and the output is their part."""
+    experts ``first_expert`` and following, and the output is their part.
+    The scores here are ``x @ w_router``; scores made elsewhere go to
+    ``moe_ffn_scored``, which is this layer from the choice on."""
+
+    def scores(xf):
+        # float32 and full precision: with near-equal probabilities a
+        # bf16-pass product can swap a token's k-th and (k+1)-th expert
+        return jnp.dot(
+            xf.astype(jnp.float32), w_router.astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST,
+        )
+
+    return _moe_ffn(
+        x, scores, w_router.shape[-1], w_gate, w_up, w_down,
+        num_experts_per_tok, norm_topk_prob, valid, token_axes, first_expert,
+        router_act, router_bias, routed_scaling_factor,
+    )
+
+
+def moe_ffn_scored(
+    x: jnp.ndarray,  # [B, T, D]
+    logits: jnp.ndarray,  # [B, T, E] float32: a score an expert, however made
+    w_gate: jnp.ndarray,
+    w_up: jnp.ndarray,
+    w_down: jnp.ndarray,
+    num_experts_per_tok: int,
+    norm_topk_prob: bool = True,
+    valid: Optional[jnp.ndarray] = None,
+    token_axes: Optional[Tuple[str, ...]] = None,
+    first_expert: int = 0,
+    router_act: str = "softmax",
+    router_bias: Optional[jnp.ndarray] = None,
+    routed_scaling_factor: float = 1.0,
+) -> Tuple[jnp.ndarray, MoEStats]:
+    """``moe_ffn`` from the choice on, for a router whose scores are not
+    ``x @ w_router`` (``router_mlp``): the activation, the top-k, the
+    statistics, the dispatch and the experts are the same code."""
+    n_routed = logits.shape[-1]
+    return _moe_ffn(
+        x, lambda xf: logits.reshape(-1, n_routed), n_routed, w_gate, w_up,
+        w_down, num_experts_per_tok, norm_topk_prob, valid, token_axes,
+        first_expert, router_act, router_bias, routed_scaling_factor,
+    )
+
+
+def router_mlp(
+    lp: Dict, h: jnp.ndarray, eps: float,
+    carried: Optional[jnp.ndarray] = None,
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """(scores [B, T, E], state [B, T, R]) float32 of a router that is an
+    MLP (``models/config.RouterConfig``). The state: the layer's normed
+    input ``h`` projected to R plus the previous layer's state
+    (``carried``; None: there is none) times the layer's learned scalar.
+    The scores: the state under an RMS norm and two GELU layers of width R,
+    one score an expert. Float32 at full precision, as the product it
+    replaces and for the same reason (``moe_ffn``); R is an eighth of the
+    model's width, so the products are small beside one expert's."""
+    f32 = jnp.float32
+    dot = functools.partial(jnp.dot, precision=jax.lax.Precision.HIGHEST)
+
+    def w(name):
+        return lp[name].astype(f32)
+
+    with device_scope("moe_route"):
+        r = dot(h.astype(f32), w("w_router_in")) + w("b_router_in")
+        if carried is not None:
+            r = r + w("router_carry") * carried
+        z = rms_norm(r, w("router_norm"), eps)
+        for i in ("1", "2"):
+            z = jax.nn.gelu(
+                dot(z, w(f"w_router_{i}")) + w(f"b_router_{i}"),
+                approximate=False,
+            )
+        return dot(z, w("w_router_out")) + w("b_router_out"), r
+
+
+def _moe_ffn(
+    x, scores, n_routed, w_gate, w_up, w_down, num_experts_per_tok,
+    norm_topk_prob, valid, token_axes, first_expert, router_act, router_bias,
+    routed_scaling_factor,
+):
+    """The sparse layer; ``scores(xf)`` gives the [G, E] float32 router
+    scores of the flattened tokens, under ``moe_route``."""
     b, t, d = x.shape
-    n_routed = w_router.shape[-1]
     e = w_gate.shape[0]  # experts held: the groups of the grouped matmuls
     k = num_experts_per_tok
     xf = x.reshape(-1, d)  # [G, D]
     g = xf.shape[0]
     vf = None if valid is None else valid.reshape(-1)
     with device_scope("moe_route"):
-        # float32 and full precision: with near-equal probabilities a
-        # bf16-pass product can swap a token's k-th and (k+1)-th expert
-        logits = jnp.dot(
-            xf.astype(jnp.float32), w_router.astype(jnp.float32),
-            precision=jax.lax.Precision.HIGHEST,
-        )
+        logits = scores(xf)
         topk_p, topk_i, probs = router_topk(
             logits, k, norm_topk_prob, router_act, router_bias,
             routed_scaling_factor,
@@ -650,15 +740,13 @@ def moe_ffn(
 def moe_ffn_from_params(
     cfg, lp: Dict, h: jnp.ndarray, valid: Optional[jnp.ndarray] = None,
     token_axes: Optional[Tuple[str, ...]] = None,
+    router_logits: Optional[jnp.ndarray] = None,
 ) -> Tuple[jnp.ndarray, MoEStats]:
     """The sparse layer, for training and serving layer bodies alike: the
-    routed experts, and the family's shared expert where it has one."""
-    out, stats = moe_ffn(
-        h,
-        lp["w_router"],
-        lp["w_gate"],
-        lp["w_up"],
-        lp["w_down"],
+    routed experts, and the family's shared expert where it has one.
+    ``router_logits`` [B, T, E]: the scores of a model whose router is not
+    ``h @ w_router`` (``cfg.router``; ``router_mlp`` makes them)."""
+    routed = dict(
         num_experts_per_tok=cfg.num_experts_per_tok,
         norm_topk_prob=cfg.norm_topk_prob,
         valid=valid,
@@ -667,6 +755,11 @@ def moe_ffn_from_params(
         router_bias=lp.get("router_bias"),
         routed_scaling_factor=cfg.routed_scaling_factor,
     )
+    experts = (lp["w_gate"], lp["w_up"], lp["w_down"])
+    if router_logits is None:
+        out, stats = moe_ffn(h, lp["w_router"], *experts, **routed)
+    else:
+        out, stats = moe_ffn_scored(h, router_logits, *experts, **routed)
     if cfg.shared_expert_size:
         out = out + shared_expert_from_params(cfg, lp, h)
     return out, stats
@@ -677,9 +770,10 @@ EXPERT_WEIGHTS = ("w_router", "w_gate", "w_up", "w_down")
 
 
 def sparse_leaves(cfg) -> Tuple[str, ...]:
-    """The leaves of one layer that ``moe_ffn_from_params`` reads."""
-    names = EXPERT_WEIGHTS
-    if cfg.router_act == "sigmoid":
+    """The leaves of one layer that ``moe_ffn_from_params`` reads (a model
+    whose router is an MLP hands it scores, not ``w_router``)."""
+    names = EXPERT_WEIGHTS if cfg.router is None else EXPERT_WEIGHTS[1:]
+    if cfg.router_act == "sigmoid" or cfg.router is not None:
         names += ("router_bias",)
     if cfg.shared_expert_size:
         names += ("w_shared_gate", "w_shared_up", "w_shared_down")
@@ -689,7 +783,8 @@ def sparse_leaves(cfg) -> Tuple[str, ...]:
 
 
 def make_sharded_moe_ffn(cfg, mesh: Mesh, weight_specs: Dict[str, P]):
-    """The sparse layer's mesh form: ``moe_fn(lp, h, valid)`` for
+    """The sparse layer's mesh form: ``moe_fn(lp, h, valid)`` (and the
+    router's scores after them, for a model whose router is an MLP) for
     ``transformer.apply``, running ``moe_ffn_from_params`` per device inside
     a ``shard_map`` (None on one device: the plain call serves). Its
     dispatch sorts the (token, expert) pairs, and a sort over a token axis
@@ -707,7 +802,7 @@ def make_sharded_moe_ffn(cfg, mesh: Mesh, weight_specs: Dict[str, P]):
     token_axes = ("data", "fsdp", "seq")
     specs = {k: weight_specs[k] for k in sparse_leaves(cfg)}
 
-    def per_device(weights, h, valid):
+    def per_device(weights, h, valid, *router_logits):
         whole = {}
         for k, w in weights.items():
             for dim, axis in enumerate(specs[k]):
@@ -715,18 +810,22 @@ def make_sharded_moe_ffn(cfg, mesh: Mesh, weight_specs: Dict[str, P]):
                     w = jax.lax.all_gather(w, axis, axis=dim, tiled=True)
             whole[k] = w
         return moe_ffn_from_params(
-            cfg, whole, h, valid=valid, token_axes=token_axes
+            cfg, whole, h, valid, token_axes, *router_logits
         )
 
     x_spec = P(("data", "fsdp"), "seq", None)
+    # scores made outside (an MLP router's) come in split as the tokens are
+    scored = (x_spec,) if cfg.router is not None else ()
     sharded = jax.shard_map(
         per_device,
         mesh=mesh,
-        in_specs=(specs, x_spec, P(("data", "fsdp"), "seq")),
+        in_specs=(specs, x_spec, P(("data", "fsdp"), "seq")) + scored,
         out_specs=(x_spec, P()),
         check_vma=False,
     )
-    return lambda lp, h, valid: sharded({k: lp[k] for k in specs}, h, valid)
+    return lambda lp, h, valid, *router_logits: sharded(
+        {k: lp[k] for k in specs}, h, valid, *router_logits
+    )
 
 
 def shared_expert_from_params(cfg, lp: Dict, h: jnp.ndarray) -> jnp.ndarray:

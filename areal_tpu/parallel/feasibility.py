@@ -464,7 +464,49 @@ def laguna_xsp2_config() -> ModelConfig:
     )
 
 
+def zaya1_8b_config() -> ModelConfig:
+    """ZAYA1-8B (Zyphra, 8.3B-A0.76B) as ONE chip of 8 that share each
+    layer holds it: published layers 0-3 (every layer one ``cca`` attention
+    sublayer and one expert sublayer), 8 of the 16 experts (its pair's other
+    chip holds the rest), an eighth of the tied vocabulary: the cut of
+    benchmark/configs/zaya1-8b.json (published: 40 layers, 262,272 rows).
+    Every width is as published: 8 query and 2 key heads of 128 on half the
+    residual's 2048, both convolutions' two taps, half of each head rotated
+    at 5,000,000, experts of 2048, the router's width of 256, its 16 outputs
+    and ONE expert a token. The trainer runs it; the generation engine,
+    ``hf_io`` and sequence parallelism refuse it by name (ROADMAP B.4)."""
+    from areal_tpu.models.config import CCAConfig, RouterConfig
+
+    return ModelConfig(
+        vocab_size=32784,
+        hidden_size=2048,
+        intermediate_size=2048,
+        num_layers=4,
+        num_heads=8,
+        num_kv_heads=2,
+        head_dim=128,
+        max_position_embeddings=131072,
+        rope_theta=5000000.0,
+        rms_norm_eps=1e-5,
+        tie_word_embeddings=True,
+        family="zaya",
+        num_experts=16,
+        num_experts_per_tok=1,
+        moe_intermediate_size=2048,
+        norm_topk_prob=False,
+        router_aux_loss_coef=0.0,
+        experts_held=8,
+        layer_kinds=("cca+moe",) * 4,
+        cca=CCAConfig(
+            num_heads=8, num_kv_heads=2, conv_taps=(2, 2), rotary_dim=64,
+            rope_theta=5000000.0,
+        ),
+        router=RouterConfig(hidden_size=256),
+    )
+
+
 MODEL_CONFIGS = {
+    "zaya1_8b": zaya1_8b_config,
     "laguna_xsp2": laguna_xsp2_config,
     "kimi_linear_48b_a3b": kimi_linear_48b_a3b_config,
     "keye_vl_2p0_30b_a3b": keye_vl_2p0_30b_a3b_config,

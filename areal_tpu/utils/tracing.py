@@ -159,6 +159,11 @@ DEVICE_SCOPES = (
     # relayouts around it. NOT inside ``attention``, which keeps the
     # full-attention layers' core
     "attn_window",
+    # what a ``cca`` mixer computes between its projections and its core
+    # (models/transformer.cca_qkv): the q-k mean, the two causal
+    # convolutions, the norm and temperature, the value shift, and their
+    # backward. The rotation is outside, the core under ``attention``
+    "cca_mix",
 )
 
 

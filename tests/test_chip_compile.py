@@ -517,3 +517,58 @@ def test_latent_attention_core_compiles_for_v5e(one_chip, monkeypatch):
         flash._make_kernel.cache_clear()
     assert flash.takes_head_dim(192, 128)
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_cca_mixer_and_its_core_compile_for_v5e(one_chip, monkeypatch):
+    """ZAYA1-8B's mixer at its published widths on a 16,384-token row,
+    forward and backward: what ``cca_qkv`` computes between the projections
+    and the core (8 query and 2 key heads of 128, two taps a convolution),
+    then the splash kernel at 4 query heads a key head. The second
+    convolution is one batched ``einsum`` over the ten heads a tap, which
+    this compiler makes an MXU product ([10, 128, 128] bf16 against the
+    row, float32 out): no ``lax.conv_general_dilated`` is needed for it."""
+    import jax
+    import jax.numpy as jnp
+
+    from areal_tpu.models import transformer
+    from areal_tpu.ops import flash
+    from areal_tpu.parallel.feasibility import MODEL_CONFIGS
+
+    monkeypatch.setattr(flash, "_PROBED_BLOCK", 2048)
+    flash._make_kernel.cache_clear()
+    mc = MODEL_CONFIGS["zaya1_8b"]()
+    t, bf = 16384, jnp.bfloat16
+    leaves = transformer._kind_leaves(mc, "cca+moe")
+    lp = {
+        k: _sds(leaves[k][0], bf, one_chip)
+        for k in ("wq", "wk", "wv_own", "wv_prev", "cca_conv0",
+                  "cca_conv0_bias", "cca_conv1", "cca_conv1_bias", "cca_temp")
+    }
+
+    def loss(h, lp, seg, pos):
+        q, k, v = transformer.cca_qkv(
+            mc, h, lp, seg, *transformer._rotations(mc, pos)["cca"]
+        )
+        assert (q.shape, k.shape, v.shape) == (
+            (1, t, 8, 128), (1, t, 2, 128), (1, t, 2, 128)
+        )
+        return flash.flash_segment_attention(q, k, v, seg).astype(
+            jnp.float32
+        ).sum()
+
+    try:
+        compiled = (
+            jax.jit(jax.grad(loss, argnums=(0, 1)))
+            .lower(
+                _sds((1, t, 2048), bf, one_chip), lp,
+                _sds((1, t), jnp.int32, one_chip),
+                _sds((1, t), jnp.int32, one_chip),
+            )
+            .compile()
+        )
+    finally:
+        flash._make_kernel.cache_clear()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert re.search(r"f32\[10,128,16384\]\S* convolution\(", text)
+    assert re.search(r'areal_scope="cca_mix"', text)

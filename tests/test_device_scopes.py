@@ -4,6 +4,9 @@ the contract (PERF.md, "Spans and counters") lowers under its name, the
 scopes change no number, and the trainer counts what it dispatches."""
 
 import contextlib
+import hashlib
+import json
+import os
 import re
 
 import numpy as np
@@ -149,19 +152,41 @@ WANT = {
     # two rotary kinds: the window layers' core under its own scope, NOT
     # inside attention, which keeps the full layers' core
     "swa_grad_step": {"attn_window", "attention", "moe_experts", "mlp"},
+    # a convolutional latent mixer: what it computes between its projections
+    # and its core under its own scope, the core under attention, the MLP
+    # router under moe_route
+    "cca_grad_step": {"cca_mix", "attention", "moe_route", "moe_experts",
+                      "mlp"},
 }
+# the five families whose programs a change for a sixth may not move: the
+# lowered grad_step's text (the persistent compile cache's key is made of
+# it), by sha256, in tests/golden/grad_step_stablehlo.json. A PR that means
+# to change one of these programs writes the file anew and says so:
+#     AREAL_REGEN_GOLDEN=1 python -m pytest tests/test_device_scopes.py -k text_it_was
+STABLE = {"grad_step": "qwen2", "sparse_grad_step": "olmoe",
+          "dsa_grad_step": "KeyeVL2", "kda_grad_step": "kimi_linear",
+          "swa_grad_step": "laguna"}
+GOLDEN = os.path.join(
+    os.path.dirname(os.path.abspath(__file__)), "golden",
+    "grad_step_stablehlo.json",
+)
+_STABLEHLO = {}  # family -> sha256 of the lowered text, filled by ``lowered``
 
 
 @pytest.fixture(scope="module")
 def lowered():
-    out = {
-        name: _op_names(low)
-        for name, low in _train_programs() + _decode_programs()
-    }
-    out["sparse_grad_step"] = _op_names(_train_programs("olmoe")[0][1])
-    out["dsa_grad_step"] = _op_names(_train_programs("KeyeVL2")[0][1])
-    out["kda_grad_step"] = _op_names(_train_programs("kimi_linear")[0][1])
-    out["swa_grad_step"] = _op_names(_train_programs("laguna")[0][1])
+    dense = _train_programs()
+    out = {name: _op_names(low) for name, low in dense + _decode_programs()}
+    grad_steps = {"grad_step": dense[0][1]}
+    for program, family in list(STABLE.items())[1:] + [
+        ("cca_grad_step", "zaya")
+    ]:
+        grad_steps[program] = _train_programs(family)[0][1]
+        out[program] = _op_names(grad_steps[program])
+    for program, family in STABLE.items():
+        _STABLEHLO[family] = hashlib.sha256(
+            grad_steps[program].as_text().encode()
+        ).hexdigest()
     return out
 
 
@@ -172,6 +197,7 @@ def test_program_lowers_under_its_contract_name_with_its_scopes(
     text, names = lowered[program]
     jitted = program.removeprefix("sparse_").removeprefix("dsa_")
     jitted = jitted.removeprefix("kda_").removeprefix("swa_")
+    jitted = jitted.removeprefix("cca_")
     assert re.search(rf"^HloModule jit_{jitted}[, ]", text, re.M)
     assert WANT[program] <= _scopes_in(names), sorted(names)[:40]
 
@@ -180,6 +206,38 @@ def test_the_window_core_is_not_nested_in_attention(lowered):
     _text, names = lowered["swa_grad_step"]
     window = [n for n in names if "attn_window" in n]
     assert window and not [n for n in window if "/attention/" in n]
+
+
+def test_the_latent_mix_is_beside_the_core_and_the_router_in_moe_route(
+    lowered
+):
+    from benchmark import program_trace
+
+    _text, names = lowered["cca_grad_step"]
+    mix = [n for n in names if program_trace.scope_of(n) == "cca_mix"]
+    assert mix and not [n for n in mix if "/attention/" in n]
+    # forward, the forward recomputed under checkpointing, and backward
+    assert {program_trace.direction_of(n) for n in mix} == {
+        "fwd", "remat", "bwd"
+    }
+    # the second convolution's batched product over the heads, and the shift
+    assert [n for n in mix if "bthi,hio->btho" in n]
+    # the router's MLP (exact GELU: an erfc) is all under moe_route
+    route = [n for n in names if program_trace.scope_of(n) == "moe_route"]
+    assert [n for n in route if n.endswith("/erfc")]
+    assert not [n for n in names if n.endswith("/erfc") and n not in route]
+
+
+@pytest.mark.parametrize("family", sorted(STABLE.values()))
+def test_the_lowered_grad_step_is_the_text_it_was(lowered, family):
+    if os.environ.get("AREAL_REGEN_GOLDEN"):
+        with open(GOLDEN, "w") as f:
+            json.dump(dict(sorted(_STABLEHLO.items())), f, indent=1)
+    with open(GOLDEN) as f:
+        assert _STABLEHLO[family] == json.load(f)[family], (
+            f"the {family} tiny model's grad_step lowers to another text "
+            "than the golden file's: its compile-cache key has moved"
+        )
 
 
 def test_every_scope_of_the_vocabulary_is_computed_somewhere(lowered):
