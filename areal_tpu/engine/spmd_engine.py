@@ -314,6 +314,8 @@ class SPMDTrainEngine(TrainEngine):
         if self.attn_impl == "flash" and jax.default_backend() != "cpu":
             # what the scoped-VMEM limit of this process allows, once
             self.splash_block = flash_ops.probe_block_size()
+        if self.attn_impl == "flash":
+            self._counters.update(attn_tiles=0, attn_tiles_kept=0)
         if mc.is_moe:
             self.moe_impl = moe_ops.grouped_impl()
             self._counters.update(dict.fromkeys(programs.MOE_COUNTS, 0))
@@ -436,8 +438,12 @@ class SPMDTrainEngine(TrainEngine):
         (query, key) pairs their cores attended, L (L + 1) / 2 a sequence of
         L tokens, and the tokens at which their convolutions and value shift
         reset, one a sequence, each summed over those layers and the train
-        microbatches). Their per-step increments are the stats plane's
-        ``spmd/<name>``."""
+        microbatches); where ``attn_impl`` is ``flash``, ``attn_tiles`` and
+        ``attn_tiles_kept`` (the tiles on and under the diagonal of the rows
+        dispatched, recompute and train alike, at the edge the
+        full-attention layers' kernel walks them, and those of them it
+        computes: ``ops.flash.tile_counts``, a row each and not a layer).
+        Their per-step increments are the stats plane's ``spmd/<name>``."""
         return dict(self._counters)
 
     def fetch_counters(self) -> Dict[str, int]:
@@ -586,6 +592,25 @@ class SPMDTrainEngine(TrainEngine):
             )
         return window
 
+    def _count_attn_tiles(
+        self, segment_ids: np.ndarray, window: int
+    ) -> Dict[str, int]:
+        """A dispatched microbatch's rows as the splash kernel walks them
+        under packing ``window``: counted (``counters()``, the stats plane)
+        and returned as the dispatch span's attributes beside ``window``.
+        The host knows the rows' borders when it packs them, so the count
+        costs the device nothing; nothing where another attention runs."""
+        if self.attn_impl != "flash":
+            return {}
+        tiles, kept = flash_ops.tile_counts(
+            segment_ids, window, self.model_config.attn_head_dims[0]
+        )
+        counted = {"attn_tiles": tiles, "attn_tiles_kept": kept}
+        for k, n in counted.items():
+            self._counters[k] += n
+        stats_tracker.counter(**{f"spmd/{k}": n for k, n in counted.items()})
+        return counted
+
     def _impl_attrs(self) -> Dict[str, Any]:
         """What a dispatch span says beside ``attn_impl`` and ``window``
         (the packing window, the full-attention layers'): the forms the ops
@@ -717,7 +742,7 @@ class SPMDTrainEngine(TrainEngine):
             # intervals in it
             for mb, row_len in zip(mbs.mbs, mbs.row_lens):
                 with goodput.trainer_bucket("data_h2d"):
-                    _, arrays = self._pack_for_device(mb, pad_to=row_len)
+                    packed, arrays = self._pack_for_device(mb, pad_to=row_len)
                 grad_fn, mb_window = self._program(
                     ("grad", loss_fn, loss_weight_fn),
                     programs.build_grad_step, window, row_len,
@@ -727,6 +752,7 @@ class SPMDTrainEngine(TrainEngine):
                 with goodput.trainer_bucket("fwd_bwd"), tracing.span(
                     "train.grad_dispatch", step=step,
                     attn_impl=self.attn_impl, window=mb_window,
+                    **self._count_attn_tiles(packed.segment_ids, mb_window),
                     **self._impl_attrs(),
                 ), goodput.dispatch_scope(
                     goodput.trainer_tracker(), "fwd_bwd",
@@ -869,6 +895,7 @@ class SPMDTrainEngine(TrainEngine):
                 with tracing.span(
                     "train.logp_dispatch", step=step,
                     attn_impl=self.attn_impl, window=mb_window,
+                    **self._count_attn_tiles(packed.segment_ids, mb_window),
                     **self._impl_attrs(),
                 ):
                     vals = logp_fn(self.params, arrays)

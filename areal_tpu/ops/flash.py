@@ -21,6 +21,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from areal_tpu.ops.basic import segment_attention
 from jax.experimental.pallas.ops.tpu.splash_attention import (
@@ -216,28 +217,48 @@ def flash_window(longest: int, tokens_per_mb: int) -> int:
 
 def _block_sizes(t: int, window: int, d: int) -> "_sk.BlockSizes":
     """The kernel's tiles, from the shape it is given. Measured on the v5e
-    with ``tools/microbench_attn_v2.py`` on the trainer's packed rows, 14/2
-    heads of 64 and 12/2 of 128 (PERF.md, PR 26, has the table):
+    with ``tools/microbench_attn_v2.py`` on the cells' own packed rows under
+    the rows' tile mask (``_row_kernel``: an empty tile is free, so a smaller
+    edge drops more and costs more a tile), forward + backward ms a layer,
+    the fused backward, ``block_kv_compute`` 512 (PERF.md, PR 42, has the
+    whole table; in brackets the same kernel without the tile mask, which at
+    today's edge is PR 41's):
 
-    - edge 1024 at T = 4096 (forward + backward 1.86 ms a layer; 512: 2.05,
-      2048: 1.92 — causal skips 6 of 16 tiles at 1024 and 1 of 4 at 2048,
-      and 512 pays per-step overhead), edge 2048 at T = 16384 (22.7 ms
-      against 25.2 at 1024); 8192 was not measured and takes 2048;
+    ====== =============== ============= ============= =============
+    T      heads           edge 512      edge 1024     edge 2048
+    ====== =============== ============= ============= =============
+    2048   14/2 of 64      0.511 (0.548) 0.559 (0.562)
+    2048   16/16 of 128    0.772 (0.797) 0.767 (0.762)
+    4096   14/2 of 64      1.732 (2.045) 1.637 (1.857)
+    4096   16/16 of 128    2.694 (2.956) 2.272 (2.451)
+    8192   32/32, 192|128                21.42 (26.95) 26.71 (30.67)
+    8192   48/8 of 128                   18.91 (23.33) 20.46 (23.12)
+    8192   8/2 of 128                    3.517 (3.894) 3.679 (3.824)
+    16384  48/8 of 128                   61.85 (88.40) 58.93 (80.76)
+    16384  8/2 of 128                    8.131 (14.49) 7.843 (13.14)
+    ====== =============== ============= ============= =============
+
+    - so edge 512 to T = 2048 (the dense heads gain 8.6%, the 16 of 128 lose
+      0.7%), 1024 to T = 8192 (before PR 42 8192 was unmeasured and took
+      2048, which the latent core's 192 | 128 pays 20% for), 2048 above; no
+      length has a shape that wants another edge by more than that 0.7%;
     - a local window skips only whole tiles, so a window of at most 1024
-      takes edge 512 (1.39 against 1.44 ms at T = 4096); a LAYER's window
-      of 512 keys (``window`` 511: the band of a window-attention layer,
-      64/8 heads of 128 at T = 16384 and 8192) takes ``BAND_EDGE`` and
-      the split backward (above; PERF.md, PR 39, has the sweep);
+      takes edge 512 (1.39 against 1.44 ms at T = 4096, PR 26); a LAYER's
+      window of 512 keys (``window`` 511: the band of a window-attention
+      layer, 64/8 heads of 128 at T = 16384 and 8192) takes ``BAND_EDGE``
+      and the split backward (above; PERF.md, PR 39, has the sweep; the
+      rows' tile mask is worth 1.7% there, 25.28 against 25.72 ms);
     - ``block_kv_compute`` 512 rather than the edge (the smaller compute
       tile overlaps the kv DMA; 256 loses), and the FUSED dq+dkv backward:
-      one pass over the data, 10-25% under the split kernels everywhere.
+      one pass over the data, 10-25% under the split kernels everywhere
+      (PR 26).
 
     The head dimension moved none of these choices. Never above what
     ``probe_block_size`` found to run, and always a divisor of ``t``."""
     band = 0 < window < 1024  # a layer's window, never the packing's
     want = (
-        BAND_EDGE if band else 512 if 0 < window <= 1024
-        else 2048 if t >= 8192 else 1024
+        BAND_EDGE if band else 512 if 0 < window <= 1024 or t <= 2048
+        else 1024 if t <= 8192 else 2048
     )
     want = min(want, _PROBED_BLOCK or want)
     edge = _LANES
@@ -271,6 +292,115 @@ def _make_kernel(t: int, rep: int, window: int, d: int, interpret: bool):
             mask, block_sizes=_block_sizes(t, window, d),
             residual_checkpoint_name="attn_out", interpret=interpret,
         )
+
+
+def tiles_needed(segment_ids, edge: int, xp=jnp):
+    """The TILE rule: which square tiles of ``edge`` tokens a packed row's
+    causal attention needs, ``[..., T / edge, T / edge]`` bool (query block,
+    key block) from ``segment_ids`` ``[..., T]``. With ``lo[b]`` the smallest
+    non-zero id in block ``b`` and ``hi[b]`` the largest, a tile under the
+    diagonal is needed only if ``hi[j] >= lo[i]``: else no sequence with a key
+    in ``j`` still runs in ``i`` (ids rise along a packed row,
+    ``pack_batch_rows``; padding is 0, at the tail; a row whose ids do not
+    rise keeps tiles it could drop, never the reverse). A DIAGONAL tile is
+    always needed, whatever it holds: every query keeps its own key, so no
+    softmax sees zero keys, and a query block of padding alone reads
+    padding's keys, as it does without the rule. A comparison of block
+    summaries, never a ``[T, T]`` mask; ``xp`` is ``jnp`` for the kernel's
+    mask and ``numpy`` for the engine's count of the same tiles."""
+    s = segment_ids.reshape(*segment_ids.shape[:-1], -1, edge)
+    hi = s.max(-1)
+    lo = xp.where(s > 0, s, xp.iinfo(xp.int32).max).min(-1)
+    n = hi.shape[-1]
+    shared = hi[..., None, :] >= lo[..., :, None]  # [.., query, key block]
+    return xp.tril(shared, -1) | xp.eye(n, dtype=bool)
+
+
+def tiles_in_window(n: int, edge: int, window: int) -> np.ndarray:
+    """The tiles of an ``n`` x ``n`` grid the kernel's STATIC mask keeps:
+    on and under the diagonal, and, under a local ``window`` (keys back,
+    beside the query's own), those whose nearest pair is inside it."""
+    back = np.arange(n)[:, None] - np.arange(n)[None, :]
+    near = (back - 1) * edge + 1  # a block's first query to the last key
+    return (back >= 0) & ((near <= window) | (window <= 0))
+
+
+def tile_counts(segment_ids, window: int, d: int) -> "tuple[int, int]":
+    """What the engine counts a dispatch: the tiles on and under the
+    diagonal of packed rows ``segment_ids`` [rows, T] (numpy, on the host)
+    at the edge ``_block_sizes`` gives such a row under packing ``window``,
+    and those of them the kernel computes: inside the static window, and
+    needed by a sequence of the row. (0, 0) for a row the XLA form takes."""
+    t = segment_ids.shape[-1]
+    if t % _LANES:
+        return 0, 0
+    window = window if 0 < window < t else 0  # as ``_make_kernel`` reads it
+    edge = _block_sizes(t, window, d).block_q
+    n = t // edge
+    kept = tiles_needed(segment_ids, edge, np) & tiles_in_window(
+        n, edge, window
+    )
+    return len(segment_ids) * n * (n + 1) // 2, int(kept.sum())
+
+
+def _lay_over(info, needed, kv_major: bool):
+    """The static ``MaskInfo`` of one kernel with the tiles a row does not
+    need (``needed`` [q blocks, kv blocks], ``tiles_needed``) turned off in
+    ``block_mask``: what the kernel reads from scalar memory AT RUN TIME to
+    decide whether a grid step computes. ``data_next`` names the block each
+    step works on: the kv block of the forward and dq kernels' step (row
+    = query block), the query block of the dkv kernel's (``kv_major``:
+    column = kv block); a local mask's grid is shrunk to its band, so the
+    other coordinate is read from there and not from the position. A step
+    that is off gets the block of the next step that is on, in the order the
+    grid is walked, as the static info gives the steps above the diagonal:
+    the pipeline then fetches nothing for it, and the next block it needs is
+    already on its way."""
+    static = info.block_mask  # [1, rows, cols]: one mask for every head
+    there = info.data_next.astype(jnp.int32)
+    _, rows, cols = static.shape
+    if kv_major:
+        qi, kj = there, jnp.arange(cols)[None, None, :]
+    else:
+        qi, kj = jnp.arange(rows)[None, :, None], there
+    block_mask = jnp.where(needed[qi, kj], static, 0)
+    # the walk: the last grid axis fastest (kv blocks of a query block for
+    # the forward and dq, query blocks of a kv block for dkv)
+    walk = (lambda a: a.swapaxes(1, 2)) if kv_major else (lambda a: a)
+    on, blocks = walk(block_mask > 0), walk(there)
+    at = jnp.where(on, jnp.arange(on.size).reshape(on.shape), on.size - 1)
+    nxt = jax.lax.cummin(at.reshape(-1), reverse=True)
+    data_next = walk(blocks.reshape(-1)[nxt].reshape(on.shape))
+    return info._replace(
+        block_mask=block_mask.astype(static.dtype),
+        data_next=data_next.astype(info.data_next.dtype),
+    )
+
+
+@jax.jit
+def _row_kernel(kernel, seg_row):
+    """``kernel`` (``_make_kernel``'s, static: causal, a packing window or a
+    layer's band) for ONE row: the same ``kwargs``, with the tiles that no
+    sequence of ``seg_row`` [T] needs laid over its static tile masks, the
+    forward's and the backward's alike (``tiles_needed``, at the one edge
+    ``_tiles`` gives them all). It removes only pairs the run-time segment
+    ids mask to -inf anyway, so the result is the static kernel's: to the
+    bit where a sequence's own tiles are all kept, which is always (their
+    order too). The mask infos are traced leaves of the kernel pytree, a few
+    small XLA ops in front of the call; under a ``vmap`` over rows they are
+    batched (Pallas then loops over the rows; one row a device is the
+    trainer's case and has no loop). Jitted, so that a program which meets
+    the call site again (each layer kind, forward, recomputed and backward,
+    each row length) traces these ops once and carries them as one equation
+    (``tools.setup_cost`` reads what a program costs to trace)."""
+    needed = tiles_needed(seg_row, kernel.kwargs["block_sizes"].block_q)
+    dq = kernel.dq_mask_info  # the split backward's (a layer's band)
+    return _sk.SplashAttentionKernel(
+        _lay_over(kernel.fwd_mask_info, needed, False),
+        None if dq is None else _lay_over(dq, needed, False),
+        _lay_over(kernel.dkv_mask_info, needed, True),
+        **kernel.kwargs,
+    )
 
 
 def flash_segment_attention(
@@ -318,7 +448,8 @@ def flash_segment_attention(
 
     def per_batch(q_, k_, v_, seg_row):
         ids = _sk.SegmentIds(q=seg_row, kv=seg_row)
-        return jax.vmap(kernel, in_axes=(0, 0, 0, None))(q_, k_, v_, ids)
+        row = _row_kernel(kernel, seg_row)
+        return jax.vmap(row, in_axes=(0, 0, 0, None))(q_, k_, v_, ids)
 
     out = jax.vmap(per_batch)(qg, kt, vt, segment_ids)
     out = out.reshape(b, hq, t, v.shape[-1]).transpose(0, 2, 1, 3)

@@ -71,6 +71,20 @@ def as_tpu(monkeypatch, probes):
     return probes
 
 
+@pytest.fixture
+def seen(monkeypatch):
+    """Every span opened from here on, as (name, attributes)."""
+    spans = []
+    span = tracing.span
+
+    def recording(name, *a, **attrs):
+        spans.append((name, attrs))
+        return span(name, *a, **attrs)
+
+    monkeypatch.setattr(tracing, "span", recording)
+    return spans
+
+
 def _batch(lens):
     rng = np.random.default_rng(0)
     batch = data_utils.pad_sequences_to_tensors(
@@ -173,16 +187,8 @@ def test_flash_window_buckets(as_tpu, longest, cap, window):
     assert eng._attn_window(_batch([longest, 17])) == window
 
 
-def test_dispatch_spans_carry_the_resolved_name_and_the_window(monkeypatch):
+def test_dispatch_spans_carry_the_resolved_name_and_the_window(seen):
     eng = _engine()
-    seen = []
-    span = tracing.span
-
-    def recording(name, *a, **attrs):
-        seen.append((name, attrs))
-        return span(name, *a, **attrs)
-
-    monkeypatch.setattr(tracing, "span", recording)
     batch = _batch([9, 5, 7])
     eng.train_batch(batch, sft_loss_fn, sft_loss_weight_fn)
     eng.forward(batch)
@@ -192,3 +198,60 @@ def test_dispatch_spans_carry_the_resolved_name_and_the_window(monkeypatch):
         assert all(
             a["attn_impl"] == "xla" and a["window"] == 0 for a in attrs
         ), attrs
+
+
+def test_flash_dispatches_count_the_tiles_their_rows_keep(
+    as_tpu, monkeypatch, seen
+):
+    """Where ``attn_impl`` is ``flash`` the engine counts, on the host and by
+    ``ops.flash.tile_counts``, the tiles of every dispatched row and those
+    the kernel computes: in ``counters()``, and on the dispatch spans beside
+    ``window``. The kernel itself is the chip's; the programs here run the
+    XLA form under the resolved name."""
+    import areal_tpu.engine.spmd_engine as spmd
+
+    monkeypatch.setattr(spmd, "attention_fn", lambda *a, **kw: None)
+    monkeypatch.setattr(flash, "_PROBED_BLOCK", 512)  # 2048-token rows: 4 x 4
+    eng = _engine()
+    assert eng.attn_impl == "flash"
+    assert eng.counters() == {
+        "real_tokens": 0, "padded_tokens": 0,
+        "attn_tiles": 0, "attn_tiles_kept": 0,
+    }
+    # one row of 2048, longest first: borders at 700 and 1300, padding from
+    # 1800. Of the 10 tiles on and under the diagonal three are off: (2, 0)
+    # (block 2 starts in the second sequence, block 0 ends in the first) and
+    # (3, 0), (3, 1) (block 3 holds the third sequence and padding)
+    batch = _batch([600, 700, 500])
+    eng.train_batch(batch, sft_loss_fn, sft_loss_weight_fn)
+    assert eng.counters()["attn_tiles"] == 10
+    assert eng.counters()["attn_tiles_kept"] == 7
+    eng.forward(batch)
+    assert eng.counters()["attn_tiles"] == 20
+    assert eng.counters()["attn_tiles_kept"] == 14
+    for name in ("train.grad_dispatch", "train.logp_dispatch"):
+        (attrs,) = [a for n, a in seen if n == name]
+        # (the packing window, 1024 over a longest sequence of 700, keeps
+        # every tile but (3, 0), which the borders empty too)
+        assert attrs["attn_impl"] == "flash" and attrs["window"] == 1024
+        assert (attrs["attn_tiles"], attrs["attn_tiles_kept"]) == (10, 7)
+
+
+@pytest.mark.parametrize("family", ["qwen2", "KeyeVL2"])
+def test_other_attentions_count_no_tiles(seen, family):
+    """``xla`` (the CPU's ``auto``) and ``sparse_topk`` (a model with an
+    indexer) carry neither counter nor span attribute."""
+    cfg = TrainEngineConfig(
+        dtype="float32", param_dtype="float32", gradient_checkpointing=False,
+        mb_spec=MicroBatchSpec(max_tokens_per_mb=4096),
+    )
+    eng = SPMDTrainEngine(cfg)
+    eng.initialize(
+        ft_spec=FinetuneSpec(1, 64, 8), model_config=tiny_config(family),
+        seed=0,
+    )
+    assert eng.attn_impl == ("sparse_topk" if family == "KeyeVL2" else "xla")
+    eng.forward(_batch([9, 5, 7]))
+    assert not {"attn_tiles", "attn_tiles_kept"} & set(eng.counters())
+    (attrs,) = [a for n, a in seen if n == "train.logp_dispatch"]
+    assert not {"attn_tiles", "attn_tiles_kept"} & set(attrs)

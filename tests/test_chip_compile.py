@@ -111,11 +111,14 @@ _SPLASH_CASES = [
     pytest.param(64, 14, 4096, 1024, 512, id="d64-t4096-window1024"),
     pytest.param(64, 14, 16384, 0, 2048, id="d64-t16384-causal"),
     pytest.param(128, 12, 4096, 0, 1024, id="d128-t4096-causal"),
-    pytest.param(128, 12, 2048, 0, 1024, id="d128-t2048-causal"),
+    pytest.param(128, 12, 2048, 0, 512, id="d128-t2048-causal"),
     # the shorter rung a train step's last microbatch runs at since PR 30,
     # at the two cells' heads (OLMoE: 16 of 128)
-    pytest.param(64, 14, 2048, 0, 1024, id="d64-t2048-causal"),
-    pytest.param(128, 16, 2048, 0, 1024, id="d128-h16-t2048-causal"),
+    # (edge 512 there and 1024 at 8192 since PR 42: the sweep under the
+    # rows' tile mask)
+    pytest.param(64, 14, 2048, 0, 512, id="d64-t2048-causal"),
+    pytest.param(128, 16, 2048, 0, 512, id="d128-h16-t2048-causal"),
+    pytest.param(128, 8, 8192, 0, 1024, id="d128-h8-t8192-causal"),
 ]
 
 
@@ -168,6 +171,7 @@ _LAGUNA_CASES = [
     pytest.param(64, 16384, 512, True, id="swa-h64-t16384"),
     pytest.param(64, 8192, 512, True, id="swa-h64-t8192"),
     pytest.param(48, 16384, 0, False, id="full-h48-t16384"),
+    pytest.param(48, 8192, 0, False, id="full-h48-t8192"),
 ]
 
 
@@ -203,7 +207,8 @@ def test_window_and_full_cores_compile_for_v5e(
     finally:
         flash._make_kernel.cache_clear()
     tiles = flash._block_sizes(t, layer_window - 1 if band else 0, 128)
-    assert tiles.block_q == (flash.BAND_EDGE if band else 2048)
+    full_edge = 2048 if t > 8192 else 1024
+    assert tiles.block_q == (flash.BAND_EDGE if band else full_edge)
     assert tiles.use_fused_bwd_kernel == (not band)
     text = lowered.as_text()
     assert ("splash_mqa_dq" in text) == band  # the split backward's own dq

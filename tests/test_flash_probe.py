@@ -86,6 +86,9 @@ _EDGES = [
     pytest.param(16384, 0, 1024, 1024, id="recipe-row-capped-by-probe"),
     pytest.param(16384, 4096, None, 2048, id="unprobed-is-uncapped"),
     pytest.param(15360, 0, 2048, 1024, id="edge-divides-the-row"),
+    pytest.param(8192, 0, 2048, 1024, id="8192-takes-1024"),
+    pytest.param(2048, 0, 2048, 512, id="short-rung-takes-512"),
+    pytest.param(32768, 0, 2048, 2048, id="past-16384-takes-2048"),
     pytest.param(1536, 0, 2048, 512, id="short-row-divisor"),
     pytest.param(256, 0, 0, 256, id="cpu-cap-0-is-no-cap"),
     pytest.param(384, 0, 2048, 128, id="odd-multiple-of-128"),

@@ -19,6 +19,20 @@ that holds it, at both of its row lengths and both kinds' heads:
 chip at these sizes; the kernel is then held to nothing here, and to the
 reference by the cell's ``window_exact``.)
 
+The tile mask of a row's own borders (PERF.md, PR 42: ``ops/flash._row_kernel``)
+is on in every variant, as in the trainer; ``--static`` also times each variant
+WITHOUT it (``<name>_static``: the kernel as ``_make_kernel`` builds it), and
+the head line says what share of the tiles the rows keep at each edge. The
+sweep ``_block_sizes``' table is from, a cell's rows each (``--rows 8``):
+
+    python -m tools.microbench_attn_v2 --t 4096 --heads 14 2 64 --edges 512 \
+        1024 --windows 0 --backward fused --kvc 512 --rows 8 --static --no-xla
+    ... --t 4096 --heads 16 16 128 --traffic grpo_steps64_fsdp4
+    ... --t 8192 --heads 32 32 192 --dv 128 --traffic grpo_steps16_packed8k \
+        --edges 1024 2048
+    ... --t 16384 --heads 48 8 128 --traffic grpo_steps16_mixed16k
+    ... --t 16384 --heads 8 2 128 --traffic grpo_steps32_packed16k
+
 Every variant is one jitted program with a name of its own, all run under
 one ``jax.profiler`` capture; the times are device times of those programs
 from ``benchmark.program_trace`` (what ``attn_time_share.train`` is read by),
@@ -52,24 +66,24 @@ RUNS = 3
 
 
 def packed_rows(t, traffic="grpo_steps32", n=2):
-    """Segment ids of the first ``n`` microbatches of the cell's first batch,
-    packed as the trainer packs them (one row of ``t`` tokens each)."""
+    """Segment ids of the first ``n`` microbatches of the cell's first batch
+    that fill a row of ``t`` tokens, packed as the trainer packs them."""
     with open(os.path.join(harness.ROOT, "benchmark", "traffic",
                            traffic + ".json")) as f:
         p = json.load(f)
     batch = train_steps.make_batches(p, 1000, 0)[0]
     fits = batch["_seq_lens"] <= t
     batch = {k: v[fits] for k, v in batch.items() if not k.startswith("_")}
-    mbs = data_utils.split_padded_batch_into_mb_list(batch, t).mbs[:n]
+    mbs = data_utils.split_padded_batch_into_mb_list(batch, t)
     return [
         data_utils.pack_batch_rows(mb, n_rows=1, pad_to=t).segment_ids
-        for mb in mbs
-    ]
+        for mb, row_len in zip(mbs.mbs, mbs.row_lens) if row_len == t
+    ][:n]
 
 
-def variants(t, windows, edges):
+def variants(t, windows, edges, backward, kvcs):
     for edge, window, fused, kvc in itertools.product(
-        edges, windows, (True, False), (256, 512, 1024)
+        edges, windows, [b == "fused" for b in backward], kvcs
     ):
         if kvc > edge or (kvc == 256 and edge > 512):
             continue
@@ -96,17 +110,35 @@ def main():
                     help="a window-attention layer's window (0 = none)")
     ap.add_argument("--no-xla", action="store_true",
                     help="leave the XLA form out (its scores do not fit)")
+    ap.add_argument("--dv", type=int, default=0,
+                    help="the value heads' width where it is not D")
+    ap.add_argument("--backward", nargs="*", default=("fused", "split"),
+                    choices=("fused", "split"))
+    ap.add_argument("--kvc", type=int, nargs="*", default=(256, 512, 1024),
+                    help="block_kv_compute")
+    ap.add_argument("--rows", type=int, default=2,
+                    help="how many of the cell's packed rows are attended")
+    ap.add_argument("--static", action="store_true",
+                    help="also time each variant without the rows' tile mask")
     args = ap.parse_args()
     t, (hq, hkv, d) = args.t, args.heads
     print("device", jax.devices()[0].device_kind,
           "probed edge", flash.probe_block_size(), flush=True)
     key = jax.random.PRNGKey(0)
     q, k, v = (
-        jax.random.normal(kk, (1, t, h, d), jnp.bfloat16)
-        for kk, h in zip(jax.random.split(key, 3), (hq, hkv, hkv))
+        jax.random.normal(kk, (1, t, h, w), jnp.bfloat16)
+        for kk, h, w in zip(
+            jax.random.split(key, 3), (hq, hkv, hkv), (d, d, args.dv or d)
+        )
     )
-    segs = [jnp.asarray(s) for s in packed_rows(t, args.traffic)]
+    rows = packed_rows(t, args.traffic, args.rows)
+    segs = [jnp.asarray(s) for s in rows]
     lw = args.layer_window
+    for edge in args.edges:  # what the rows' borders leave of the triangle
+        kept = sum(int(flash.tiles_needed(s[0], edge, np).sum()) for s in rows)
+        n = t // edge
+        print(f"edge {edge}: {len(rows)} rows keep {kept} of "
+              f"{len(rows) * n * (n + 1) // 2} tiles", flush=True)
 
     def programs(name, attend):
         def fwd(q, k, v, seg):
@@ -123,12 +155,18 @@ def main():
         return jax.jit(fwd), jax.jit(grad)
 
     todo = []
-    chosen = flash._block_sizes
-    named = ([] if args.no_xla else [("xla", 0, None)]) + list(
-        variants(t, args.windows, args.edges)
-    ) + [("chosen", 0, None)]
+    chosen, row_kernel = flash._block_sizes, flash._row_kernel
+    swept = list(variants(t, args.windows, args.edges, args.backward, args.kvc))
+    named = ([] if args.no_xla else [("xla", 0, None)]) + swept + [
+        (name + "_static", window, tiles)
+        for name, window, tiles in (swept if args.static else [])
+    ] + [("chosen", 0, None)]
     for name, window, tiles in named:
         flash._block_sizes = (lambda *_, tiles=tiles: tiles) if tiles else chosen
+        flash._row_kernel = (
+            (lambda kernel, seg: kernel) if name.endswith("_static")
+            else row_kernel
+        )
         flash._make_kernel.cache_clear()
         fns = programs(
             name,
@@ -144,23 +182,31 @@ def main():
         except Exception as e:  # noqa: BLE001 — mosaic raises various types
             print(f"{name}: refused ({type(e).__name__}: {str(e)[:160]})",
                   flush=True)
-    flash._block_sizes = chosen
+    flash._block_sizes, flash._row_kernel = chosen, row_kernel
     flash._make_kernel.cache_clear()
-    ref = None
-    for name, fwd, grad in todo:  # warm, and hold the kernel to the XLA form
-        outs = [np.asarray(fwd(q, k, v, segs[0]), np.float32)] + [
-            np.asarray(g, np.float32) for g in grad(q, k, v, segs[0])
-        ]
+    ref, masked = None, {}
+    # warm; hold the kernel to the XLA form, and the static kernel to the
+    # one under the rows' tile mask (which must be the same to the bit)
+    for name, fwd, grad in todo:
+        outs = [fwd(q, k, v, segs[0]), *grad(q, k, v, segs[0])]
         if name == "xla":
-            ref = outs
+            ref = [np.asarray(x, np.float32) for x in outs]
         elif ref and ("full" in name or name == "chosen"):
             print(name, "max |delta| out, dq, dk, dv:",
-                  [float(np.abs(a - b).max()) for a, b in zip(outs, ref)],
-                  flush=True)
+                  [float(np.abs(np.asarray(a, np.float32) - b).max())
+                   for a, b in zip(outs, ref)], flush=True)
+        if args.static and name != "chosen":
+            if name.endswith("_static"):
+                print(name, "equal to the masked kernel's out, dq, dk, dv:",
+                      [bool(jnp.array_equal(a, b)) for a, b in zip(
+                          outs, masked.pop(name[:-len("_static")]))],
+                      flush=True)
+            elif name != "xla":
+                masked[name] = outs
     os.makedirs(args.out, exist_ok=True)
     jax.profiler.start_trace(args.out)
     for _, fwd, grad in todo:
-        for i in range(RUNS):
+        for i in range(max(RUNS, len(segs))):
             jax.block_until_ready(fwd(q, k, v, segs[i % len(segs)]))
             jax.block_until_ready(grad(q, k, v, segs[i % len(segs)]))
     jax.profiler.stop_trace()
