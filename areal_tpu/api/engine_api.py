@@ -6,7 +6,7 @@ single-host↔pod SPMD (here) swaps are config changes.
 """
 
 import abc
-from typing import Any, Callable, Dict, Iterable, List, Optional
+from typing import Any, Callable, Dict, Iterable, List, Optional, Sequence
 
 from areal_tpu.api.io_struct import (
     FinetuneSpec,
@@ -94,7 +94,10 @@ class TrainEngine(abc.ABC):
         self,
         input_: Dict[str, Any],
         post_hook: Optional[Callable] = None,
+        rows: Optional[Sequence[int]] = None,
     ):
+        """A per-token result over ``input_`` ([B, L], the input's order);
+        ``rows``: over those sequences only, the others read 0."""
         raise NotImplementedError()
 
 

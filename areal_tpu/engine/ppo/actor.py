@@ -52,6 +52,9 @@ class PPOActor:
     def compute_logp(self, data: Batch, temperature: Optional[float] = None) -> np.ndarray:
         """Recompute logprobs of the batch tokens under current weights
         (reference ppo/actor.py:48 `compute_logp`)."""
+        return self.engine.forward(data, post_hook=self._logp_hook(temperature))
+
+    def _logp_hook(self, temperature: Optional[float] = None):
         temp = temperature if temperature is not None else self.config.temperature
         # cache the hook per temperature: the engine keys its jitted program
         # on hook identity, so a fresh closure per call would recompile
@@ -65,7 +68,7 @@ class PPOActor:
                 )
 
             self._logp_hooks[temp] = hook
-        return self.engine.forward(data, post_hook=self._logp_hooks[temp])
+        return self._logp_hooks[temp]
 
     # ------------------------------------------------------------------
     def compute_advantages(self, data: Batch) -> Batch:
@@ -188,17 +191,33 @@ class PPOActor:
 
     # ------------------------------------------------------------------
     def ppo_update(self, data: Batch) -> List[Dict[str, float]]:
-        """Minibatched decoupled-PPO update (reference ppo/actor.py:161)."""
-        with tracing.span("train.ppo_update", step=self._engine_step()):
-            return self._ppo_update(data)
+        """Minibatched decoupled-PPO update (reference ppo/actor.py:161).
 
-    def _ppo_update(self, data: Batch) -> List[Dict[str, float]]:
+        The proximal policy is the weights the update starts from. Where the
+        caller hands no ``prox_logp`` in and ``recompute_logprob`` is on, the
+        update finds its logprobs itself: a forward pass over the sequences
+        of minibatches 2..n, before the first optimizer step. The FIRST
+        minibatch needs none: no step lies between that pass and its own
+        ``grad_step``, so its proximal logprobs are what its own forward
+        forms (same weights, same tokens), and it takes them from there
+        (``stop_gradient(newlogp)``; its ratio is 1 to the bit and not to
+        the rounding of two differently packed forwards). A quarter of the
+        recipe's pass is not run; ``ppo_n_minibatches: 1`` runs none. A
+        caller's own ``prox_logp`` is used as it is, for every minibatch.
+        The span and the stats plane (``spmd/prox_own_seqs``,
+        ``prox_own_tokens``, ``prox_recomputed_seqs``,
+        ``prox_recomputed_tokens``) say how many sequences and loss tokens
+        went each way: 1 : 3 in sequences on a step of four minibatches."""
+        with tracing.span("train.ppo_update", step=self._engine_step()) as sp:
+            return self._ppo_update(data, sp)
+
+    def _ppo_update(self, data: Batch, sp) -> List[Dict[str, float]]:
+        """Dynamic sampling and the minibatch split first (the one
+        ``np.random.permutation`` draw), then the proximal logprobs of
+        minibatches 2..n, then the minibatches: what the first one needs of
+        the proximal policy its own forward gives it."""
         cfg = self.config
-        if cfg.recompute_logprob and "prox_logp" not in data:
-            # proximal policy = current weights before this update
-            data["prox_logp"] = self.compute_logp(data) * np.asarray(
-                data["loss_mask"], np.float32
-            )
+        recompute = cfg.recompute_logprob and "prox_logp" not in data
         if cfg.dynamic_sampling and self.group_size > 1:
             keep = np.asarray(
                 F.dynamic_sampling_mask(
@@ -211,14 +230,49 @@ class PPOActor:
         bsz = batch_size(data)
         n_mbs = min(cfg.ppo_n_minibatches, max(bsz, 1))
         perm = np.random.permutation(bsz)
-        groups = np.array_split(perm, n_mbs)
+        groups = [g for g in np.array_split(perm, n_mbs) if len(g)]
+        if recompute and groups:
+            data = self._with_prox_logp(data, groups, sp)
         # a generator: the engine draws minibatch k + 1 (its selection too)
         # while the device still works on minibatch k, and returns when the
         # last one's stats are on the host
         return self.engine.train_batches(
-            (batch_select(data, g) for g in groups if len(g)),
+            (batch_select(data, g) for g in groups),
             self._loss_fn, _ppo_loss_weight_fn,
         )
+
+    def _with_prox_logp(self, data: Batch, groups, sp) -> Batch:
+        """``data`` with the proximal logprobs this update finds itself:
+        ``prox_logp`` recomputed under the current weights for the sequences
+        of ``groups[1:]`` and 0 for the first minibatch's, and ``prox_own``,
+        true on the first minibatch's tokens, which tells the loss to take
+        its own forward's there. Every minibatch carries both arrays, so the
+        choice is data and one ``grad_step`` program a row length serves the
+        whole step. The pass keeps the whole batch's row length and packing
+        window (``forward(rows=)``): three quarters of a batch do not ask
+        for a ``logp_forward`` the whole batch would not have."""
+        loss_mask = np.asarray(data["loss_mask"])
+        own = np.zeros(batch_size(data), bool)
+        own[groups[0]] = True
+        if own.all():
+            prox_logp = np.zeros(loss_mask.shape, np.float32)
+        else:
+            prox_logp = self.engine.forward(
+                data, post_hook=self._logp_hook(), rows=np.nonzero(~own)[0]
+            ) * loss_mask.astype(np.float32)
+        tokens = (loss_mask > 0).sum(1)
+        counts = {
+            "prox_own_seqs": int(own.sum()),
+            "prox_own_tokens": int(tokens[own].sum()),
+            "prox_recomputed_seqs": int((~own).sum()),
+            "prox_recomputed_tokens": int(tokens[~own].sum()),
+        }
+        sp.set(**counts)
+        stats_tracker.counter(**{f"spmd/{k}": n for k, n in counts.items()})
+        return {
+            **data, "prox_logp": prox_logp,
+            "prox_own": np.broadcast_to(own[:, None], loss_mask.shape),
+        }
 
     @property
     def _loss_fn(self):
@@ -236,6 +290,13 @@ class PPOActor:
                     if "t_prox_logp" in arrays
                     else None
                 )
+                if "t_prox_own" in arrays:
+                    # a step's first minibatch: no update lies between the
+                    # proximal policy and this forward
+                    prox_logp = jnp.where(
+                        arrays["t_prox_own"], jax.lax.stop_gradient(newlogp),
+                        prox_logp,
+                    )
                 if not cfg.use_decoupled_loss and prox_logp is not None:
                     # plain PPO against recomputed logp
                     old_logp, prox_logp = prox_logp, None

@@ -26,7 +26,7 @@ import os
 import time
 import urllib.request
 from typing import (
-    Any, Callable, Dict, Iterable, Iterator, List, Optional, Tuple,
+    Any, Callable, Dict, Iterable, Iterator, List, Optional, Sequence, Tuple,
 )
 
 import jax
@@ -485,7 +485,8 @@ class SPMDTrainEngine(TrainEngine):
         return data_utils.ROW_QUANTUM * self.config.parallel.seq_parallel_size
 
     def _split(
-        self, input_: Batch, backward: bool
+        self, input_: Batch, backward: bool,
+        rows: Optional[Sequence[int]] = None,
     ) -> data_utils.MicroBatchList:
         """A batch as microbatches of rows, the one split ``train_batch``,
         ``eval_batch`` and ``forward`` share: rows first
@@ -496,13 +497,35 @@ class SPMDTrainEngine(TrainEngine):
         ``backward`` runs a call's microbatches at ONE length, the longest's:
         its rows cost a third of that, it ends in one short microbatch among
         many, and a length of its own is one more program to compile and,
-        from the cache, to trace and load (1.2 s; PERF.md, PR 30)."""
-        mbs = data_utils.split_padded_batch_into_mb_list(
-            input_, self.config.mb_spec.max_tokens_per_mb,
+        from the cache, to trace and load (1.2 s; PERF.md, PR 30). With
+        ``rows`` such a pass runs those sequences of ``input_`` only
+        (``groups`` still index ``input_``) and keeps the WHOLE input's
+        length: a subset whose own longest microbatch fits a shorter rung
+        does not ask for a program the whole batch never built."""
+        spec = dict(
+            max_tokens_per_mb=self.config.mb_spec.max_tokens_per_mb,
             min_n_mbs=self.config.mb_spec.n_mbs,
             n_rows=self._dp_rows(), quantum=self._row_quantum(),
         )
-        if not backward:
+        mbs = data_utils.split_padded_batch_into_mb_list(
+            input_ if rows is None else data_utils.batch_select(input_, rows),
+            **spec,
+        )
+        if rows is not None:
+            rows = np.asarray(rows, np.int64)
+            mbs.groups = [rows[g].tolist() for g in mbs.groups]
+            mbs.forward_indices = rows[mbs.forward_indices].tolist()
+            whole = max(data_utils.split_lens(
+                np.asarray(input_["attention_mask"]).sum(1), **spec
+            )[1], default=0)
+            if max(mbs.row_lens, default=0) > whole:
+                # first-fit is not monotone; no cell's layout does this
+                raise ValueError(
+                    f"rows: a microbatch of {max(mbs.row_lens)}-token rows,"
+                    f" the whole input's longest is {whole}"
+                )
+            mbs.row_lens = [whole] * len(mbs.mbs)
+        elif not backward:
             mbs.row_lens = [max(mbs.row_lens, default=0)] * len(mbs.mbs)
         return mbs
 
@@ -866,22 +889,27 @@ class SPMDTrainEngine(TrainEngine):
         self,
         input_: Batch,
         post_hook: Optional[Callable] = None,
+        rows: Optional[Sequence[int]] = None,
     ) -> np.ndarray:
         """Run the model over `input_` and return a padded [B, L] per-token
         array in the original order, where L is the input's padded width
         (reference base_hf_engine.py:525).
 
         `post_hook(logits, arrays) -> [R, T] array` must be jittable; default
-        returns target-aligned logprobs.
+        returns target-aligned logprobs. ``rows``: run these sequences of
+        `input_` only (the others read 0 in the result), at the row length
+        and under the packing window of the WHOLE input, so the pass runs
+        programs a pass over all of it would have built.
         """
         step = self.step_count
         with tracing.span("train.logp_recompute", step=step):
-            return self._forward(input_, post_hook, step)
+            return self._forward(input_, post_hook, step, rows)
 
     def _forward(
-        self, input_: Batch, post_hook: Optional[Callable], step: int
+        self, input_: Batch, post_hook: Optional[Callable], step: int,
+        rows: Optional[Sequence[int]] = None,
     ) -> np.ndarray:
-        mbs = self._split(input_, backward=False)
+        mbs = self._split(input_, backward=False, rows=rows)
         hook = post_hook or target_aligned_logprobs
         window = self._attn_window(input_)
 

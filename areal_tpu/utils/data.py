@@ -358,8 +358,26 @@ def split_padded_batch_into_mb_list(
     rows make fewer). With one row this is the reference's split of sequences
     under a token budget (data.py:401
     `split_padded_tensor_dict_into_mb_list`)."""
-    mask = np.asarray(batch["attention_mask"])
-    lens = mask.sum(1).astype(np.int64)
+    lens = np.asarray(batch["attention_mask"]).sum(1)
+    groups, row_lens = split_lens(
+        lens, max_tokens_per_mb, min_n_mbs, n_rows, quantum
+    )
+    mbs = [trim_batch(batch_select(batch, g)) for g in groups]
+    forward_indices = datapack.flat2d(groups)
+    return MicroBatchList(
+        mbs=mbs, groups=groups, forward_indices=forward_indices,
+        row_lens=row_lens,
+    )
+
+
+def split_lens(
+    lens: np.ndarray, max_tokens_per_mb: int, min_n_mbs: int = 1,
+    n_rows: int = 1, quantum: int = ROW_QUANTUM,
+) -> Tuple[List[List[int]], List[int]]:
+    """``split_padded_batch_into_mb_list``'s arithmetic, which needs the
+    sequences' lengths alone: ``(the indices in each microbatch, each
+    microbatch's row length)``."""
+    lens = np.asarray(lens, np.int64)
     capacity = max(max_tokens_per_mb // n_rows, int(lens.max(initial=0)))
     rows = datapack.ffd_allocate(lens, capacity)
     groups = [
@@ -373,12 +391,7 @@ def split_padded_batch_into_mb_list(
     row_lens = [
         rows_on_ladder(lens[g], n_rows, quantum, top=top)[0] for g in groups
     ]
-    mbs = [trim_batch(batch_select(batch, g)) for g in groups]
-    forward_indices = datapack.flat2d(groups)
-    return MicroBatchList(
-        mbs=mbs, groups=groups, forward_indices=forward_indices,
-        row_lens=row_lens,
-    )
+    return groups, row_lens
 
 
 @dataclasses.dataclass

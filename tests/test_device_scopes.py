@@ -340,7 +340,8 @@ def test_trainer_counts_what_it_packs_from_the_segment_ids():
     seg = np.asarray(packed.segment_ids)
     assert eng.counters()["real_tokens"] == sum(lens) == np.count_nonzero(seg)
     assert eng.counters()["padded_tokens"] == seg.size
-    # one step: the recompute and the train microbatches both pass the pack
+    # one step of ONE minibatch: its proximal logprobs are its own forward's
+    # (PR 43), so no recompute pass runs and every token is packed once
     from areal_tpu.utils import stats_tracker
 
     stats_tracker.export_all()  # drop what the pack above exported
@@ -348,15 +349,72 @@ def test_trainer_counts_what_it_packs_from_the_segment_ids():
     batch = actor.compute_advantages(batch)
     actor.ppo_update(batch)
     after = eng.counters()
-    assert after["real_tokens"] - before["real_tokens"] == 2 * sum(lens)
+    assert after["real_tokens"] - before["real_tokens"] == sum(lens)
     assert eng.step_count == 1
     step = stats_tracker.export_all()
-    assert step["spmd/real_tokens"] == 2 * sum(lens)
+    assert step["spmd/real_tokens"] == sum(lens)
     assert step["spmd/padded_tokens"] == (
         after["padded_tokens"] - before["padded_tokens"]
     )
     assert not hasattr(eng, "last_timing")
     assert not [k for k in step if k.endswith(("_dispatch_s", "apply_fetch_s"))]
+
+
+def test_rehearsal_counts_a_trained_token_once_and_a_recomputed_one_twice(
+    monkeypatch
+):
+    """The dense cell's rehearsal: the trainer's own ``real_tokens`` /
+    ``padded_tokens`` over the window equal what the benchmark's hook around
+    ``_pack_for_device`` counted, and a token is dispatched once to train
+    and once more if its minibatch is not the step's first (what
+    ``tests/benchmark_suite/test_program_trace.py``'s test of the same run
+    holds as "twice": ``tests/conftest.py``)."""
+    from areal_tpu.engine.spmd_engine import SPMDTrainEngine
+    from benchmark import run
+
+    engines, recomputed = [], []
+    init, forward = SPMDTrainEngine.initialize, SPMDTrainEngine.forward
+
+    def initialize(self, *a, **kw):
+        engines.append(self)
+        return init(self, *a, **kw)
+
+    def counting_forward(self, input_, post_hook=None, rows=None):
+        lens = np.asarray(input_["attention_mask"]).sum(1)
+        recomputed.append(int(lens.sum() if rows is None else lens[rows].sum()))
+        return forward(self, input_, post_hook=post_hook, rows=rows)
+
+    monkeypatch.setattr(SPMDTrainEngine, "initialize", initialize)
+    monkeypatch.setattr(SPMDTrainEngine, "forward", counting_forward)
+    ctx, kind, _ = run.prepare([
+        "--workload", "train_0p5b_grpo", "--seed", str(2 ** 31 + 25),
+        "--seconds", "1", "--trace", "0", "--rehearse",
+    ])
+    marks = {}
+    mark_start, since = ctx.mark_window_start, ctx.watch.since
+
+    def mark_window_start():
+        marks["start"] = (engines[0].counters(), len(recomputed))
+        mark_start()
+
+    def since_window_end(mark):  # the kind's first call after its window
+        marks.setdefault("end", (engines[0].counters(), len(recomputed)))
+        return since(mark)
+
+    ctx.mark_window_start = mark_window_start
+    ctx.watch.since = since_window_end
+    out = kind.run(ctx)
+    (c0, n0), (c1, n1) = marks["start"], marks["end"]
+    delta = {k: c1[k] - c0[k] for k in c1}
+    c = out.obs.counters
+    assert delta["real_tokens"] == c["dispatched_real_tokens"] > 0
+    assert delta["padded_tokens"] == c["padded_tokens"] > delta["real_tokens"]
+    assert set(delta) == {"real_tokens", "padded_tokens"}
+    passes = recomputed[n0:n1]
+    assert len(passes) == c["steps"]  # one pass a step
+    assert delta["real_tokens"] == c["trained_tokens"] + sum(passes)
+    # the recipe's four minibatches: about three quarters are recomputed
+    assert 0.6 < sum(passes) / c["trained_tokens"] < 0.9
 
 
 def test_a_held_shares_slabs_sit_under_the_layers_scopes():
