@@ -31,48 +31,6 @@ from areal_tpu.ops import moe as moe_ops
 from areal_tpu.parallel import sharding as sharding_lib
 from areal_tpu.utils import tracing
 
-# grad_step's stats that are counts of a sparse model's routing (summed over
-# microbatches, where every other stat is a weighted mean): the key in the
-# stats, in ``counters()`` and on the stats plane, and its ``MoEStats`` field
-MOE_COUNTS = {
-    "moe_assignments": "assignments",
-    "moe_max_expert_assignments": "max_expert_assignments",
-}
-# the same of a model that HOLDS A SHARE of its experts, and of no other:
-# the rows its expert paths ran over and the token chunks whose held pairs
-# filled more than one slab of the bounded buffer (``ops/moe._run_held``)
-MOE_HELD_COUNTS = {
-    "moe_stream_rows": "stream_rows",
-    "moe_overflow_chunks": "overflow_chunks",
-}
-# the same for a sparse-attention model's selection (``DSAStats`` fields):
-# int32 [L] a microbatch, which the host sums exactly
-DSA_COUNTS = {
-    "dsa_kept_pairs": "kept_pairs",
-    "dsa_causal_pairs": "causal_pairs",
-}
-# the same for a model with gated delta-rule layers (``KDAStats`` fields):
-# int32 a microbatch, summed over those layers on the device
-KDA_COUNTS = {
-    "kda_tokens": "tokens",
-    "kda_chunk_slots": "chunk_slots",
-    "kda_segments": "segments",
-}
-# the same for a model with window-attention layers (``SWAStats`` fields):
-# int32 [those layers] a microbatch
-SWA_COUNTS = {
-    "swa_pairs": "pairs",
-    "swa_causal_pairs": "causal_pairs",
-}
-# the same for a model with ``cca`` mixers (``CCAStats`` fields): int32
-# [those layers] a microbatch
-CCA_COUNTS = {
-    "cca_causal_pairs": "causal_pairs",
-    "cca_starts": "starts",
-}
-# the counts the host fetches as integers and sums exactly
-INT_COUNTS = {**DSA_COUNTS, **KDA_COUNTS, **SWA_COUNTS, **CCA_COUNTS}
-
 
 def _forward_fn(
     model_config: ModelConfig, mesh: Mesh, compute_dtype,
@@ -94,7 +52,7 @@ def _forward_fn(
             k: sharding_lib.logical_to_spec(axes[k][1:])
             for k in moe_ops.sparse_leaves(mc)
         })
-    kda = kda_ops.make_kda_scan(mesh) if mc.num_kda_layers else None
+    kda = kda_ops.make_kda_scan(mesh) if mc.kda else None
     # [B, T, D] activation constraint: rows over (data, fsdp), tokens over
     # seq. Pinning this stops GSPMD from propagating the embedding table's
     # column sharding onto the batch, which replicates every layer
@@ -130,32 +88,17 @@ def build_grad_step(
 
     def fwd_loss(params, arrays):
         logits, layer_stats = forward(params, arrays)
-        moe_stats = layer_stats.moe
         with tracing.device_scope("loss"):
             loss, stats = loss_fn(logits, arrays)
-            for counted, names in (
-                (layer_stats.dsa, DSA_COUNTS), (layer_stats.kda, KDA_COUNTS),
-                (layer_stats.swa, SWA_COUNTS), (layer_stats.cca, CCA_COUNTS),
-            ):
-                if counted is not None:
-                    stats = dict(stats, **{
-                        k: getattr(counted, v) for k, v in names.items()
-                    })
+            # what the model's mechanisms count (``transformer.MECHANISMS``):
+            # the host sums them, where every other stat is a weighted mean
+            stats = dict(stats, **layer_stats.counts)
             if mc.is_moe:
                 if mc.router_aux_loss_coef:
                     loss = loss + (
-                        mc.router_aux_loss_coef * moe_stats.aux_loss
+                        mc.router_aux_loss_coef * layer_stats.aux_loss
                     )
-                stats = dict(
-                    stats, router_aux_loss=moe_stats.aux_loss,
-                    **{k: getattr(moe_stats, v)
-                       for k, v in MOE_COUNTS.items()},
-                )
-                if mc.experts_held:
-                    stats.update({
-                        k: getattr(moe_stats, v)
-                        for k, v in MOE_HELD_COUNTS.items()
-                    })
+                stats["router_aux_loss"] = layer_stats.aux_loss
             w = loss_weight_fn(arrays).astype(jnp.float32)
             return loss * w, (loss, stats, w)
 

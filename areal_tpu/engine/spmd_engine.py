@@ -39,7 +39,7 @@ from areal_tpu.api.cli_args import TrainEngineConfig
 from areal_tpu.api.engine_api import TrainEngine
 from areal_tpu.api.io_struct import FinetuneSpec, SaveLoadMeta, WeightUpdateMeta
 from areal_tpu.engine import programs
-from areal_tpu.models import hf_io
+from areal_tpu.models import hf_io, transformer
 from areal_tpu.models.config import ModelConfig, load_hf_config
 from areal_tpu.models.transformer import (
     count_params,
@@ -47,9 +47,6 @@ from areal_tpu.models.transformer import (
     param_logical_axes,
 )
 from areal_tpu.ops import flash as flash_ops
-from areal_tpu.ops import kda as kda_ops
-from areal_tpu.ops import moe as moe_ops
-from areal_tpu.ops import sparse_attention
 from areal_tpu.ops.functional import target_aligned_logprobs
 from areal_tpu.ops.ring_attention import attention_fn
 from areal_tpu.parallel import distributed as distributed_lib
@@ -201,9 +198,8 @@ class SPMDTrainEngine(TrainEngine):
         self._host_lr: Optional[Callable[[int], float]] = None
         self.step_count = 0
         # cumulative tokens dispatched (counters()), counted where every
-        # microbatch is packed, from its segment ids; a sparse model adds
-        # (initialize) the (token, expert) pairs its train microbatches
-        # routed and the busiest expert's share of them, summed over layers
+        # microbatch is packed, from its segment ids; initialize adds what
+        # the model's mechanisms count over the train microbatches
         self._counters = {"real_tokens": 0, "padded_tokens": 0}
         # results fetched from the device (a train minibatch's scalars, a
         # recompute microbatch's logprobs) and those of them fetched after
@@ -219,18 +215,15 @@ class SPMDTrainEngine(TrainEngine):
         # backend ran when that is "flash" on a TPU (None: kernel not in use)
         self.attn_impl: Optional[str] = None
         self.splash_block: Optional[int] = None
-        # the grouped matmul of a sparse model's experts, as ops/moe.py
-        # resolves it from the platform ("megablox" | "ragged_dot"); None
-        # for a dense model
+        # what a dispatch span says of the model's mechanisms beside
+        # ``attn_impl`` and the packing ``window`` (``transformer.span_attrs``)
+        # and, out of it, the forms its ops resolved to in this process: the
+        # experts' grouped matmul ("megablox" | "ragged_dot"), the learned
+        # selection's passes and the delta rule's ("kernel" | "xla")
         self.moe_impl: Optional[str] = None
-        # the form of the learned selection's counting passes, as
-        # ops/sparse_attention.py resolves it from the platform ("kernel" |
-        # "xla"); None for a model without an indexer
         self.dsa_select_impl: Optional[str] = None
-        # the gated delta rule's state pass, as ops/kda.py resolves it from
-        # the platform ("kernel" | "xla"); None for a model without such
-        # layers
         self.kda_impl: Optional[str] = None
+        self._span_attrs: Dict[str, Any] = {}
         self.initialized = False
 
     # ------------------------------------------------------------------
@@ -307,34 +300,19 @@ class SPMDTrainEngine(TrainEngine):
             self.opt_state = self._init_opt_state()
         self.attn_impl = flash_ops.resolve_attn_impl(
             cfg.attn_impl, cfg.parallel.seq_parallel_size,
-            mc.attn_head_dims[0], has_indexer=mc.has_indexer,
-            head_dim_v=mc.attn_head_dims[1], layer_window=mc.layer_window,
-            cca=mc.cca is not None,
+            mc.attn_head_dims, *transformer.seq_parallel_refusal(mc),
         )
         if self.attn_impl == "flash" and jax.default_backend() != "cpu":
             # what the scoped-VMEM limit of this process allows, once
             self.splash_block = flash_ops.probe_block_size()
         if self.attn_impl == "flash":
             self._counters.update(attn_tiles=0, attn_tiles_kept=0)
-        if mc.is_moe:
-            self.moe_impl = moe_ops.grouped_impl()
-            self._counters.update(dict.fromkeys(programs.MOE_COUNTS, 0))
-            if mc.experts_held:
-                self._counters.update(
-                    dict.fromkeys(programs.MOE_HELD_COUNTS, 0)
-                )
-        if mc.has_indexer:
-            self.dsa_select_impl = sparse_attention.select_impl()
-            self._counters.update(dict.fromkeys(programs.DSA_COUNTS, 0))
-        if mc.num_kda_layers:
-            self.kda_impl = kda_ops.scan_impl(
-                mc.kda.head_dim, mc.kda.head_dim
-            )
-            self._counters.update(dict.fromkeys(programs.KDA_COUNTS, 0))
-        if mc.layer_window:
-            self._counters.update(dict.fromkeys(programs.SWA_COUNTS, 0))
-        if mc.cca is not None:
-            self._counters.update(dict.fromkeys(programs.CCA_COUNTS, 0))
+        # what the model's mechanisms declare (``transformer.MECHANISMS``)
+        self._counters.update(dict.fromkeys(transformer.count_names(mc), 0))
+        self._span_attrs = transformer.span_attrs(mc)
+        self.moe_impl, self.dsa_select_impl, self.kda_impl = map(
+            self._span_attrs.get, ("moe_impl", "dsa_select_impl", "kda_impl")
+        )
         n = count_params(self.params)
         logger.info(
             f"initialized {mc.family} model: {n/1e6:.1f}M params on mesh "
@@ -416,29 +394,10 @@ class SPMDTrainEngine(TrainEngine):
     def counters(self) -> Dict[str, int]:
         """Cumulative counts since construction (as
         ``GenerationEngine.metrics()`` is for the engine): tokens
-        dispatched, real and padded, over recompute and train
-        microbatches; for a sparse model also ``moe_assignments`` and
-        ``moe_max_expert_assignments`` (the routed pairs of the train
-        microbatches and the busiest expert's, summed over layers; of the
-        experts held, where the model holds a share, and then also
-        ``moe_stream_rows`` and ``moe_overflow_chunks``: the rows of the
-        sorted stream its expert paths ran over, a slab of the bounded
-        buffer's rows times the slabs each token chunk's held pairs filled,
-        and the chunks where they filled more than one); for a model with an
-        indexer ``dsa_kept_pairs`` and ``dsa_causal_pairs`` (the selected and
-        the causal (query, key) pairs, likewise); for a model with gated
-        delta-rule layers ``kda_tokens``, ``kda_chunk_slots`` and
-        ``kda_segments`` (the real tokens its scans ran over, the chunk slots
-        they ran, 64 a chunk, and the sequence starts they reset at, each
-        summed over those layers); for a model with window-attention layers
-        ``swa_pairs`` and ``swa_causal_pairs`` (the (query, key) pairs inside
-        the window, and what the same layers would attend without one,
-        summed over those layers and the train microbatches); for a model
-        with ``cca`` mixers ``cca_causal_pairs`` and ``cca_starts`` (the
-        (query, key) pairs their cores attended, L (L + 1) / 2 a sequence of
-        L tokens, and the tokens at which their convolutions and value shift
-        reset, one a sequence, each summed over those layers and the train
-        microbatches); where ``attn_impl`` is ``flash``, ``attn_tiles`` and
+        dispatched, real and padded, over recompute and train microbatches;
+        what the model's mechanisms count over the train microbatches
+        (``models.transformer.count_names``: which, and what each is);
+        where ``attn_impl`` is ``flash``, ``attn_tiles`` and
         ``attn_tiles_kept`` (the tiles on and under the diagonal of the rows
         dispatched, recompute and train alike, at the edge the
         full-attention layers' kernel walks them, and those of them it
@@ -634,20 +593,6 @@ class SPMDTrainEngine(TrainEngine):
         stats_tracker.counter(**{f"spmd/{k}": n for k, n in counted.items()})
         return counted
 
-    def _impl_attrs(self) -> Dict[str, Any]:
-        """What a dispatch span says beside ``attn_impl`` and ``window``
-        (the packing window, the full-attention layers'): the forms the ops
-        resolved for this model's experts and learned selection, the
-        window of its window-attention layers, and its router where that is
-        not ``h @ w_router``."""
-        named = {
-            "moe_impl": self.moe_impl, "dsa_select_impl": self.dsa_select_impl,
-            "kda_impl": self.kda_impl,
-            "layer_window": self.model_config.layer_window,
-            "router": self.model_config.router and "mlp_carried",
-        }
-        return {k: v for k, v in named.items() if v}
-
     def _forward_values(self, window: int) -> Dict[str, Any]:
         """What every forward program of ``engine/programs.py`` is built
         from: this engine's model, mesh, compute dtype and attention."""
@@ -776,7 +721,7 @@ class SPMDTrainEngine(TrainEngine):
                     "train.grad_dispatch", step=step,
                     attn_impl=self.attn_impl, window=mb_window,
                     **self._count_attn_tiles(packed.segment_ids, mb_window),
-                    **self._impl_attrs(),
+                    **self._span_attrs,
                 ), goodput.dispatch_scope(
                     goodput.trainer_tracker(), "fwd_bwd",
                     f"pad{row_len}|window{mb_window}",
@@ -821,10 +766,13 @@ class SPMDTrainEngine(TrainEngine):
             ok, grad_norm, total_w, losses, weights, all_stats = self._fetch(
                 pending.scalars, behind
             )
-        # a sparse-attention model's pair counts are int32 [L] a microbatch
-        # (a float32 cannot count a 16k-token row's), a delta-rule model's
-        # token counts int32: summed here as integers
-        int_keys = [k for k in programs.INT_COUNTS if k in all_stats[0]]
+        # a stat is a count because the model names it as one; an int32 one
+        # (a float32 cannot count a 16k-token row's pairs) is summed exactly
+        count_keys = set(transformer.count_names(self.model_config))
+        int_keys = [
+            k for k in all_stats[0] if k in count_keys
+            and np.issubdtype(np.asarray(all_stats[0][k]).dtype, np.integer)
+        ]
         stat_keys = sorted(set(all_stats[0]) - set(int_keys))
         h_total_w = np.float32(total_w)
         h_losses = np.asarray(losses, np.float32)
@@ -841,7 +789,7 @@ class SPMDTrainEngine(TrainEngine):
         }
         for j, k in enumerate(stat_keys):
             # a count: summed, counted, and per step
-            if k in programs.MOE_COUNTS or k in programs.MOE_HELD_COUNTS:
+            if k in count_keys:
                 out[k] = float(h_stats[:, j].sum())
                 self._counters[k] += int(out[k])
                 stats_tracker.counter(**{f"spmd/{k}": int(out[k])})
@@ -924,7 +872,7 @@ class SPMDTrainEngine(TrainEngine):
                     "train.logp_dispatch", step=step,
                     attn_impl=self.attn_impl, window=mb_window,
                     **self._count_attn_tiles(packed.segment_ids, mb_window),
-                    **self._impl_attrs(),
+                    **self._span_attrs,
                 ):
                     vals = logp_fn(self.params, arrays)
                 yield packed, vals

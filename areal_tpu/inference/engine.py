@@ -69,7 +69,7 @@ from areal_tpu.inference.cache import (
 )
 from areal_tpu.inference.policies import PolicyRegistry, UnknownPolicyError
 from areal_tpu.inference.weights import WeightStore
-from areal_tpu.models import hf_io
+from areal_tpu.models import hf_io, transformer
 from areal_tpu.models.config import ModelConfig, load_hf_config
 from areal_tpu.models.transformer import Params
 from areal_tpu.utils import data as data_utils
@@ -347,39 +347,9 @@ class GenerationEngine:
         if model_config is None:
             model_config = load_hf_config(config.model_path)
         self.model_config = model_config
-        if model_config.has_indexer:
-            from areal_tpu.ops.sparse_attention import MECHANISM
-
-            raise NotImplementedError(
-                f"the generation engine cannot run {MECHANISM}: it has no "
-                "indexer-key cache beside its KV pages and no top-k decode "
-                "(ROADMAP B); the trainer can (attn_impl 'sparse_topk')"
-            )
-        if model_config.layer_window:
-            raise NotImplementedError(
-                "the generation engine cannot run window attention (family "
-                f"{model_config.family!r}: layers of a "
-                f"{model_config.layer_window}-key window among full ones, "
-                "each kind with its own heads): window layers want pages "
-                "that stop growing and an allocator keyed by layer kind "
-                "(ROADMAP B.3); the trainer can"
-            )
-        if model_config.cca is not None:
-            raise NotImplementedError(
-                "the generation engine cannot run compressed convolutional "
-                f"attention (family {model_config.family!r}): a decode step "
-                "needs the last tokens' pre-convolution q and k and the last "
-                "token's second value beside the KV pages, recurrent state "
-                "beside paged state (ROADMAP B.4), and its router's state "
-                "passes from layer to layer; the trainer can"
-            )
-        if model_config.layer_kinds:
-            raise NotImplementedError(
-                "the generation engine cannot run a stack of block kinds "
-                f"({', '.join(dict.fromkeys(model_config.layer_kinds))}): it "
-                "has no recurrent state beside its KV pages and no latent "
-                "paged cache (ROADMAP B); the trainer can"
-            )
+        refused = transformer.generation_refusal(model_config)
+        if refused:  # a mechanism's own reason (``transformer.MECHANISMS``)
+            raise NotImplementedError(refused)
         if params is None:
             params = hf_io.load_params(
                 config.model_path, model_config, dtype=self.dtype
@@ -408,7 +378,6 @@ class GenerationEngine:
                     f"tensor_parallel_size={tp} must divide num_experts="
                     f"{model_config.num_experts} for MoE serving"
                 )
-            from areal_tpu.models.transformer import param_logical_axes
             from areal_tpu.parallel import sharding as sharding_lib
 
             self.mesh = jax.sharding.Mesh(
@@ -424,7 +393,7 @@ class GenerationEngine:
                 "vocab": None, "layer": None,
             }
             self._param_shardings = sharding_lib.tree_shardings(
-                self.mesh, param_logical_axes(model_config), rules
+                self.mesh, transformer.param_logical_axes(model_config), rules
             )
             # paged pool [L, Hkv, NP, BS//f, f*D]: kv heads follow the
             # tensor axis

@@ -27,7 +27,7 @@ from-scratch parallel causal LM — re-designed TPU-first:
 """
 
 import functools
-from typing import Any, Dict, NamedTuple, Optional
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -35,9 +35,12 @@ import numpy as np
 from jax import ad_checkpoint as _ad_checkpoint
 
 from areal_tpu.models.config import ModelConfig
-from areal_tpu.ops.kda import KDAStats, kda_scan
-from areal_tpu.ops.moe import MoEStats, moe_ffn_from_params, router_mlp
-from areal_tpu.ops.sparse_attention import DSAStats, sparse_attention
+from areal_tpu.ops import kda as kda_ops
+from areal_tpu.ops import moe as moe_ops
+from areal_tpu.ops import sparse_attention as dsa_ops
+from areal_tpu.ops.kda import kda_scan
+from areal_tpu.ops.moe import moe_ffn_from_params, router_mlp
+from areal_tpu.ops.sparse_attention import sparse_attention
 from areal_tpu.utils.tracing import device_scope
 from areal_tpu.ops.basic import (
     apply_mrope,
@@ -59,37 +62,12 @@ _ROTARY_MIXERS = ("full", "swa")
 
 
 class LayerStats(NamedTuple):
-    """What a layer reports beside its output; ``apply`` hands back the
-    stack's. ``dsa`` is None (no leaf at all) for a model without an
-    indexer, which therefore lowers as it did before the field existed."""
+    """What ``apply(return_stats=True)`` hands back beside the logits: the
+    sparse layers' mean load-balancing loss (zero for a dense model), and
+    what the model's mechanisms count on these rows (``count_names``)."""
 
-    moe: MoEStats  # zeros for a dense model
-    dsa: Optional[DSAStats] = None  # sparse-attention models only
-    kda: Optional[KDAStats] = None  # gated delta-rule layers only
-    swa: Optional["SWAStats"] = None  # window-attention layers only
-    cca: Optional["CCAStats"] = None  # convolutional latent mixers only
-
-
-class SWAStats(NamedTuple):
-    """The (query, key) pairs of a model's window-attention layers, int32
-    [those layers] (exact: the host sums them): inside the window (a
-    sequence of length L gives ``sum_t min(t + 1, window)`` a layer), and
-    what the same layers would attend without one (``L (L + 1) / 2``)."""
-
-    pairs: jnp.ndarray
-    causal_pairs: jnp.ndarray
-
-
-class CCAStats(NamedTuple):
-    """What a model's ``cca`` mixers ran over, int32 with those layers
-    leading (exact: the host sums every element): the (query, key) pairs the
-    core attends (a sequence of length L gives ``L (L + 1) / 2`` a layer; in
-    partial sums a stretch of a row, since ONE 65,536-token sequence's pairs
-    pass an int32) and the tokens at which the convolutions and the value
-    shift reset (one a sequence and layer)."""
-
-    causal_pairs: jnp.ndarray
-    starts: jnp.ndarray
+    aux_loss: jnp.ndarray
+    counts: Dict[str, jnp.ndarray]
 
 
 # --------------------------------------------------------------------------
@@ -262,6 +240,249 @@ def _init_kinds(cfg: ModelConfig, rng: jax.Array, dtype) -> Params:
             group[name] = w.astype(dtype)
         groups[kind] = group
     return groups
+
+
+# --------------------------------------------------------------------------
+# Mechanisms: one table says what a mixer or a sparse FFN tells the trainer,
+# ``ops.flash.resolve_attn_impl`` and the generation engine (none names one)
+# --------------------------------------------------------------------------
+class Mechanism(NamedTuple):
+    """What a mixer (a block kind's first half; ``indexer``: the one-kind
+    stack's learned selection) or a sparse FFN declares. The last three are
+    functions of the ``ModelConfig``; None: nothing to refuse or to say."""
+
+    # count -> what it counts, summed over its layers: a key of
+    # ``train_batch``'s output, of ``SPMDTrainEngine.counters()`` (and over
+    # the train microbatches) and of the stats plane (``spmd/<count>``)
+    counts: Dict[str, str] = {}
+    # (cfg, reported, segment_ids, positions) -> the counts' arrays of one
+    # microbatch, in ``counts``' order; ``reported``: mechanism -> its op's
+    # tuples, a run of layers each, the layers leading. The host sums every
+    # element, an integer array exactly and a float32 one as float32
+    count: Optional[Callable] = None
+    forced_attn: str = ""  # the attention it runs whatever was asked
+    # why it has no sequence-parallel form (a ``str.format`` template over
+    # ``attn_impl`` and ``seq_parallel_size``), why the generation engine
+    # cannot run it, what a dispatch span says of it
+    no_seq_parallel: Optional[Callable] = None
+    no_generation: Optional[Callable] = None
+    span_attrs: Optional[Callable] = None
+
+
+def _reported(source: str, *fields, reduce=jnp.sum) -> Callable:
+    """A ``Mechanism.count``: ``reduce`` over the model's layers of each of
+    ``fields`` of what ``source``'s op reports a layer."""
+
+    def count(cfg, reported, segment_ids, positions):
+        runs = reported[source]
+        return [
+            reduce(jnp.concatenate([getattr(r, f) for r in runs]))
+            for f in fields
+        ]
+
+    return count
+
+
+def _swa_counts(cfg, reported, segment_ids, positions):
+    """int32 [window layers], every layer the same: a sequence of length L
+    gives ``sum_t min(t + 1, window)`` pairs, ``L (L + 1) / 2`` without."""
+    n = sum(kind.startswith("swa+") for kind in cfg.layer_kinds)
+    seen = jnp.where(segment_ids > 0, positions.astype(jnp.int32) + 1, 0)
+    return (
+        jnp.full((n,), jnp.minimum(seen, cfg.swa.window).sum(), jnp.int32),
+        jnp.full((n,), seen.sum(), jnp.int32),
+    )
+
+
+_PAIR_STRETCH = 4096  # tokens of a row a partial sum of its pairs covers
+
+
+def _cca_counts(cfg, reported, segment_ids, positions):
+    """int32, the ``cca`` layers leading, every layer the same: the pairs in
+    partial sums a stretch of a row (ONE 65,536-token sequence's pass an
+    int32), and the sequence starts."""
+    n = sum(kind.startswith("cca+") for kind in cfg.layer_kinds)
+    real = segment_ids > 0
+    before = jnp.pad(segment_ids, ((0, 0), (1, 0)))[:, :-1]
+    seen = jnp.where(real, positions.astype(jnp.int32) + 1, 0)
+    # a stretch's sum fits an int32 at any position under 2^31 / 4096
+    seen = jnp.pad(seen, ((0, 0), (0, -seen.shape[1] % _PAIR_STRETCH)))
+    pairs = seen.reshape(-1, _PAIR_STRETCH).sum(-1)
+    return (
+        jnp.broadcast_to(pairs, (n,) + pairs.shape),
+        jnp.full((n,), (real & (before != segment_ids)).sum(), jnp.int32),
+    )
+
+
+def _no_kinds_stack(cfg: ModelConfig) -> str:
+    return (
+        "the generation engine cannot run a stack of block kinds "
+        f"({', '.join(block_kinds(cfg))}): it has no recurrent state beside "
+        "its KV pages and no latent paged cache (ROADMAP B); the trainer can"
+    )
+
+
+# In the order a model's refusals are raised and its counts are made. A
+# kind's two halves are keys (``dense`` declares nothing); ``moe_held``: the
+# sparse FFN of a model that holds a share of its experts.
+MECHANISMS: Dict[str, Mechanism] = {
+    "moe": Mechanism(
+        counts={
+            "moe_assignments": "the routed (token, expert) pairs, summed "
+            "over the sparse layers (of the experts held, where a share is)",
+            "moe_max_expert_assignments": "the busiest expert's of them",
+        },
+        count=_reported("moe", "assignments", "max_expert_assignments"),
+        span_attrs=lambda cfg: {  # the router where not ``h @ w_router``
+            "moe_impl": moe_ops.grouped_impl(),
+            "router": cfg.router and "mlp_carried",
+        },
+    ),
+    "moe_held": Mechanism(
+        counts={
+            "moe_stream_rows": "the sorted stream's rows the expert paths "
+            "ran over: a slab's times the slabs each chunk's held pairs fill",
+            "moe_overflow_chunks": "the chunks that filled more than one",
+        },
+        count=_reported("moe", "stream_rows", "overflow_chunks"),
+    ),
+    "indexer": Mechanism(
+        counts={
+            "dsa_kept_pairs": "the selected (query, key) pairs",
+            "dsa_causal_pairs": "the causal pairs they were chosen among",
+        },
+        # int32 [L] as they are: a 16k-token row's pairs pass a float32
+        count=_reported(
+            "indexer", "kept_pairs", "causal_pairs", reduce=lambda a: a
+        ),
+        forced_attn="sparse_topk",
+        no_seq_parallel=lambda cfg: (
+            f"{dsa_ops.MECHANISM} has no sequence-parallel form "
+            "(seq_parallel_size={seq_parallel_size}): ROADMAP B"
+        ),
+        no_generation=lambda cfg: (
+            f"the generation engine cannot run {dsa_ops.MECHANISM}: it has "
+            "no indexer-key cache beside its KV pages and no top-k decode "
+            "(ROADMAP B); the trainer can (attn_impl 'sparse_topk')"
+        ),
+        span_attrs=lambda cfg: {"dsa_select_impl": dsa_ops.select_impl()},
+    ),
+    "swa": Mechanism(
+        counts={
+            "swa_pairs": "the (query, key) pairs inside the windows",
+            "swa_causal_pairs": "what those layers would attend without one",
+        },
+        count=_swa_counts,
+        no_seq_parallel=lambda cfg: (
+            f"window attention (a layer's window of {cfg.swa.window} keys) "
+            "has no sequence-parallel form (attn_impl={attn_impl!r}, "
+            "seq_parallel_size={seq_parallel_size}): ROADMAP B.3"
+        ),
+        no_generation=lambda cfg: (
+            "the generation engine cannot run window attention (family "
+            f"{cfg.family!r}: layers of a {cfg.swa.window}-key window among "
+            "full ones, each kind with its own heads): window layers want "
+            "pages that stop growing and an allocator keyed by layer kind "
+            "(ROADMAP B.3); the trainer can"
+        ),
+        span_attrs=lambda cfg: {"layer_window": cfg.swa.window},
+    ),
+    "cca": Mechanism(
+        counts={
+            "cca_causal_pairs": "the (query, key) pairs the cores attended, "
+            "L (L + 1) / 2 a sequence of L tokens",
+            "cca_starts": "the tokens at which the convolutions and the "
+            "value shift reset, one a sequence",
+        },
+        count=_cca_counts,
+        no_seq_parallel=lambda cfg: (
+            "compressed convolutional attention (family 'zaya': causal "
+            "convolutions and a one-token value shift before the core) has "
+            "no sequence-parallel form (attn_impl={attn_impl!r}, "
+            "seq_parallel_size={seq_parallel_size}): a convolution and a "
+            "shift across a ring's borders are not built"
+        ),
+        no_generation=lambda cfg: (
+            "the generation engine cannot run compressed convolutional "
+            f"attention (family {cfg.family!r}): a decode step needs the "
+            "last tokens' pre-convolution q and k and the last token's "
+            "second value beside the KV pages, recurrent state beside paged "
+            "state (ROADMAP B.4), and its router's state passes from layer "
+            "to layer; the trainer can"
+        ),
+    ),
+    "kda": Mechanism(
+        counts={
+            "kda_tokens": "the real tokens the layers' scans ran over",
+            "kda_chunk_slots": "the chunk slots they ran, 64 a chunk",
+            "kda_segments": "the sequence starts they reset at",
+        },
+        count=_reported("kda", "tokens", "chunk_slots", "segments"),
+        no_generation=_no_kinds_stack,
+        span_attrs=lambda cfg: {
+            "kda_impl": kda_ops.scan_impl(cfg.kda.head_dim, cfg.kda.head_dim)
+        },
+    ),
+    "mla": Mechanism(no_generation=_no_kinds_stack),
+    "full": Mechanism(no_generation=_no_kinds_stack),
+}
+
+
+def _mechanisms(cfg: ModelConfig) -> Tuple[Mechanism, ...]:
+    """The model's entries of ``MECHANISMS``, in the table's order."""
+    has = {half for kind in cfg.layer_kinds for half in kind.split("+")}
+    if not cfg.layer_kinds:  # the one-kind stack
+        has = {"indexer"} if cfg.has_indexer else set()
+        has |= {"moe"} if cfg.is_moe else set()
+    if "moe" in has and cfg.experts_held:
+        has.add("moe_held")
+    return tuple(m for name, m in MECHANISMS.items() if name in has)
+
+
+def _declared(cfg: ModelConfig, field: str) -> list:
+    """What the model's mechanisms declare under ``field`` (those that
+    declare nothing left out, a text's function called)."""
+    said = [getattr(m, field) for m in _mechanisms(cfg)]
+    return [say(cfg) if callable(say) else say for say in said if say]
+
+
+def count_names(cfg: ModelConfig) -> Dict[str, str]:
+    """count -> what it counts, of everything the model's mechanisms count a
+    microbatch (``LayerStats.counts``'s keys)."""
+    return {k: v for c in _declared(cfg, "counts") for k, v in c.items()}
+
+
+def seq_parallel_refusal(cfg: ModelConfig) -> Tuple[str, str]:
+    """What ``ops.flash.resolve_attn_impl`` takes of a model: the attention
+    it forces whatever was asked, and why it has no sequence-parallel form."""
+    fields = ("forced_attn", "no_seq_parallel")
+    return tuple((_declared(cfg, f) + [""])[0] for f in fields)
+
+
+def generation_refusal(cfg: ModelConfig) -> str:
+    """Why the generation engine cannot run the model ("": it can)."""
+    return (_declared(cfg, "no_generation") + [""])[0]
+
+
+def span_attrs(cfg: ModelConfig) -> Dict[str, Any]:
+    """What a trainer's dispatch span says of the model's mechanisms."""
+    said = _declared(cfg, "span_attrs")
+    return {k: v for attrs in said for k, v in attrs.items() if v}
+
+
+def _stack_stats(cfg, reported, segment_ids, positions) -> LayerStats:
+    """``LayerStats`` of the whole stack on these rows; ``reported`` as
+    ``Mechanism.count`` takes it."""
+    aux_loss, counts = jnp.zeros((), jnp.float32), {}
+    if "moe" in reported:  # the mean over the sparse layers
+        aux_loss = jnp.mean(
+            jnp.concatenate([run.aux_loss for run in reported["moe"]])
+        )
+    for m in _mechanisms(cfg):
+        if m.count is not None:
+            arrays = m.count(cfg, reported, segment_ids, positions)
+            counts.update(zip(m.counts, arrays, strict=True))
+    return LayerStats(aux_loss, counts)
 
 
 # --------------------------------------------------------------------------
@@ -496,11 +717,11 @@ def _layer_body(
 
 
 def _ffn_residual(
-    cfg, x, lp, segment_ids, moe_fn, sparse: bool, dsa=None, kda=None,
+    cfg, x, lp, segment_ids, moe_fn, sparse: bool, mixer_stats=None,
 ):
-    """The second half of every block: (x + FFN(norm(x)), ``LayerStats``).
-    ``sparse``: routed experts (with the shared one, where the family has
-    it), else the dense gated MLP."""
+    """The second half of every block: (x + FFN(norm(x)), what the layer's
+    ops report: (the routed experts' ``MoEStats``, ``mixer_stats``)).
+    ``sparse``: routed experts (with the shared one), else the dense MLP."""
     uo = cfg.norm_add_unit_offset
     h = rms_norm(
         x, lp["post_attn_norm"], cfg.rms_norm_eps, add_unit_offset=uo
@@ -514,15 +735,13 @@ def _ffn_residual(
                 )
             else:  # per device under a mesh (a shard_map of the same)
                 ffn, stats = moe_fn(lp, h, segment_ids > 0)
-        return x + ffn, LayerStats(stats, dsa, kda)
+        return x + ffn, (stats, mixer_stats)
     with device_scope("mlp"):
         ffn = (
             hidden_act_fn(cfg.hidden_act)(h @ lp["w_gate"])
             * (h @ lp["w_up"])
         ) @ lp["w_down"]
-    return x + ffn, LayerStats(
-        MoEStats(*(jnp.zeros((), jnp.float32),) * 3), dsa, kda
-    )
+    return x + ffn, (None, mixer_stats)
 
 
 def _short_conv(x, w, segment_ids):
@@ -762,7 +981,7 @@ def _cca_block(cfg, carry, lp, segment_ids, rotation, attend_fn, moe_fn):
         else:
             ffn, stats = moe_fn(lp, h, valid, logits)
     x = _scaled_residual(x, ffn, lp, "ffn")
-    return (x, r), LayerStats(stats)
+    return (x, r), (stats, None)
 
 
 def _block_body(
@@ -779,9 +998,9 @@ def _block_body(
             cfg, x, lp, segment_ids, rotation, attend_fn, moe_fn
         )
     h = rms_norm(x, lp["input_norm"], cfg.rms_norm_eps)
-    kda = None
+    mixer_stats = None
     if mixer == "kda":
-        y, kda = _kda_mixer(cfg, h, lp, segment_ids, kda_fn)
+        y, mixer_stats = _kda_mixer(cfg, h, lp, segment_ids, kda_fn)
     elif mixer in _ROTARY_MIXERS:
         y = _rotary_mixer(
             cfg, getattr(cfg, mixer), h, lp, segment_ids, *rotation,
@@ -790,7 +1009,7 @@ def _block_body(
     else:
         y = _mla_mixer(cfg, h, lp, segment_ids, attend_fn)
     return _ffn_residual(
-        cfg, x + y, lp, segment_ids, moe_fn, ffn == "moe", kda=kda
+        cfg, x + y, lp, segment_ids, moe_fn, ffn == "moe", mixer_stats
     )
 
 
@@ -847,48 +1066,13 @@ def _rotations(cfg, positions):
     return out
 
 
-_PAIR_STRETCH = 4096  # tokens of a row a partial sum of its pairs covers
-
-
-def _cca_stats(cfg, segment_ids, positions):
-    """``CCAStats`` of the model's ``cca`` layers on these rows (the same
-    count every such layer)."""
-    n = sum(kind.startswith("cca+") for kind in cfg.layer_kinds)
-    if not n:
-        return None
-    real = segment_ids > 0
-    before = jnp.pad(segment_ids, ((0, 0), (1, 0)))[:, :-1]
-    seen = jnp.where(real, positions.astype(jnp.int32) + 1, 0)
-    # a stretch's sum fits an int32 at any position under 2^31 / 4096
-    seen = jnp.pad(seen, ((0, 0), (0, -seen.shape[1] % _PAIR_STRETCH)))
-    pairs = seen.reshape(-1, _PAIR_STRETCH).sum(-1)
-    return CCAStats(
-        jnp.broadcast_to(pairs, (n,) + pairs.shape),
-        jnp.full((n,), (real & (before != segment_ids)).sum(), jnp.int32),
-    )
-
-
-def _swa_stats(cfg, segment_ids, positions):
-    """``SWAStats`` of the model's window layers on these rows (the same
-    count every such layer: the window is the kind's)."""
-    n = sum(kind.startswith("swa+") for kind in cfg.layer_kinds)
-    if not n:
-        return None
-    seen = jnp.where(segment_ids > 0, positions.astype(jnp.int32) + 1, 0)
-    return SWAStats(
-        jnp.full((n,), jnp.minimum(seen, cfg.swa.window).sum(), jnp.int32),
-        jnp.full((n,), seen.sum(), jnp.int32),
-    )
-
-
 def _apply_kinds(
     cfg, params, x, segment_ids, positions, remat, attend_fn, moe_fn, kda_fn,
     act_sharding,
 ):
     """The stack of a model of several block kinds: every run of consecutive
     layers of one kind is a scan over that kind's slice of its stack.
-    Returns (x, ``LayerStats`` summed over the layers: the load-balancing
-    loss the mean of the sparse layers')."""
+    Returns (x, ``LayerStats`` of the whole stack)."""
     runs = _runs(cfg.layer_kinds)
     rotations = _rotations(cfg, positions)
     pieces = {
@@ -898,7 +1082,7 @@ def _apply_kinds(
         )))
         for kind, stack in params["layers"].items()
     }
-    moe, kda = [], []
+    reported = {}
     if cfg.router is not None:
         # the router's state beside the stream, zeros into the first layer
         x = (x, jnp.zeros(
@@ -939,28 +1123,13 @@ def _apply_kinds(
             aux = jax.tree_util.tree_map(lambda a: a[None], aux)
         else:
             x, aux = jax.lax.scan(body, x, stack)
-        if kind.endswith("+moe"):
-            moe.append(aux.moe)
-        if aux.kda is not None:
-            kda.append(aux.kda)
-
-    def total(stats, field, reduce=jnp.sum):
-        return reduce(jnp.concatenate([getattr(s, field) for s in stats]))
-
+        # a kind's halves in the order a layer reports: FFN, mixer
+        for name, stats in zip(reversed(kind.split("+")), aux):
+            if stats is not None:
+                reported.setdefault(name, []).append(stats)
     if isinstance(x, tuple):  # the router's last state goes nowhere
         x = x[0]
-    return x, LayerStats(
-        MoEStats(
-            total(moe, "aux_loss", jnp.mean),
-            # the counts; a held share's two are None otherwise
-            *(None if getattr(moe[0], f) is None else total(moe, f)
-              for f in MoEStats._fields[1:]),
-        ) if moe else MoEStats(*(jnp.zeros((), jnp.float32),) * 3),
-        None,
-        KDAStats(*(total(kda, f) for f in KDAStats._fields)) if kda else None,
-        _swa_stats(cfg, segment_ids, positions),
-        _cca_stats(cfg, segment_ids, positions),
-    )
+    return x, _stack_stats(cfg, reported, segment_ids, positions)
 
 
 def apply(
@@ -980,11 +1149,8 @@ def apply(
     act_sharding: Optional[Any] = None,  # NamedSharding for [B, T, D] acts
 ):
     """Forward to logits [B, T, vocab] (fp32); with ``return_stats=True``
-    returns (logits, ``LayerStats`` of the whole stack): ``moe`` the mean
-    per-layer load-balancing loss, and the assignments and the busiest
-    experts' loads summed over the layers (zeros for dense models); ``dsa``,
-    for a model with an indexer, the selected and the causal (query, key)
-    pairs of each layer, int32 [L] (exact: the host sums them).
+    returns (logits, ``LayerStats`` of the whole stack: the load-balancing
+    loss, and what the model's mechanisms count on these rows).
 
     For a model with an indexer ``attend_fn`` takes the indexer's query,
     key and head weights after ``segment_ids`` and returns ``DSAStats``
@@ -994,12 +1160,9 @@ def apply(
     `attend_fn` overrides attention: the engine's per-device ``shard_map``
     of ``moe_ffn_from_params`` under a mesh. `kda_fn(q, k, v, g, beta,
     segment_ids)` does the same for the gated delta rule
-    (``ops/kda.make_kda_scan``); a model with such layers also reports
-    ``kda``: the tokens, chunk slots and sequence starts its scans saw,
-    int32, summed over those layers; one with window-attention layers
-    ``swa`` (``SWAStats``), and its ``attend_fn`` is called with
-    ``layer_window=`` by those layers (``ops.flash.flash_segment_attention``
-    takes it; the XLA form when ``attend_fn`` is None).
+    (``ops/kda.make_kda_scan``). Window-attention layers call ``attend_fn``
+    with ``layer_window=`` (``ops.flash.flash_segment_attention`` takes it;
+    the XLA form when ``attend_fn`` is None).
 
     `attend_fn(q, k, v, segment_ids)` overrides the attention kernel (e.g.
     ring / Ulysses shard_map from ops/ring_attention.py); default is the
@@ -1066,15 +1229,9 @@ def apply(
     x, aux = jax.lax.scan(body, x, params["layers"])
     logits = _head(params, cfg, x, return_hidden)
     if return_stats:
-        moe = aux.moe
-        return logits, LayerStats(
-            MoEStats(
-                jnp.mean(moe.aux_loss),
-                # the counts; a held share's two are None otherwise
-                *(None if a is None else jnp.sum(a) for a in moe[1:]),
-            ),
-            aux.dsa,
-        )
+        pairs = zip(("moe", "indexer"), aux)  # as a layer reports them
+        reported = {n: [s] for n, s in pairs if s is not None}
+        return logits, _stack_stats(cfg, reported, segment_ids, positions)
     return logits
 
 

@@ -139,52 +139,33 @@ def takes_head_dim(d: int, dv: "int | None" = None) -> bool:
 
 
 def resolve_attn_impl(
-    attn_impl: str, seq_parallel_size: int, head_dim: int,
-    has_indexer: bool = False, head_dim_v: "int | None" = None,
-    layer_window: int = 0, cca: bool = False,
+    attn_impl: str, seq_parallel_size: int, head_dims: "tuple[int, int]",
+    forced: str = "", no_seq_parallel: str = "",
 ) -> str:
     """A train engine's ``attn_impl`` against what the process can observe
-    (the way ``GenerationEngine`` resolves its own ``auto``). A model with
-    an indexer (``ModelConfig.has_indexer``) has one attention, whatever
-    was asked: ``sparse_topk`` (``ops/sparse_attention.py``, which picks its
-    own core from the platform), and no sequence-parallel form. On a TPU
-    without sequence parallelism ``auto`` is the fused splash kernel
-    wherever it takes the model's head dimension: the XLA form writes
-    float32 scores [heads, T, T] per layer and pass. Elsewhere ``auto``
-    is that XLA form, which GSPMD shards; so is an explicit SP kernel
-    with nothing to split. A model with window-attention layers
-    (``ModelConfig.layer_window``) has the splash kernel and the XLA form,
-    and no sequence-parallel one; neither has one with ``cca`` mixers,
-    whose convolutions and value shift read the previous tokens across what
-    would be a ring's borders."""
+    (the way ``GenerationEngine`` resolves its own ``auto``) and what the
+    model declares (``models.transformer.seq_parallel_refusal``): ``forced``,
+    the one attention it has whatever was asked, and ``no_seq_parallel``, why
+    it has no sequence-parallel form (a ``str.format`` template over
+    ``attn_impl`` and ``seq_parallel_size``), raised where one was asked for.
+    On a TPU without sequence parallelism ``auto`` is the fused splash
+    kernel wherever it takes the model's ``head_dims``
+    (``ModelConfig.attn_head_dims``): the XLA form writes float32 scores
+    [heads, T, T] per layer and pass. Elsewhere ``auto`` is that XLA form,
+    which GSPMD shards; so is an explicit SP kernel with nothing to split."""
     no_sp = seq_parallel_size == 1
-    if cca and (attn_impl in ("ring", "ulysses") or not no_sp):
-        raise ValueError(
-            "compressed convolutional attention (family 'zaya': causal "
-            "convolutions and a one-token value shift before the core) has "
-            f"no sequence-parallel form (attn_impl={attn_impl!r}, "
-            f"seq_parallel_size={seq_parallel_size}): a convolution and a "
-            "shift across a ring's borders are not built"
-        )
-    if layer_window and (attn_impl in ("ring", "ulysses") or not no_sp):
-        raise ValueError(
-            f"window attention (a layer's window of {layer_window} keys) "
-            f"has no sequence-parallel form (attn_impl={attn_impl!r}, "
-            f"seq_parallel_size={seq_parallel_size}): ROADMAP B.3"
-        )
-    if has_indexer:
-        if not no_sp:
-            from areal_tpu.ops.sparse_attention import MECHANISM
-
-            raise ValueError(
-                f"{MECHANISM} has no sequence-parallel form "
-                f"(seq_parallel_size={seq_parallel_size}): ROADMAP B"
-            )
-        return "sparse_topk"
+    # a forced attention is whatever was asked: only a split is refused
+    asked_sp = not no_sp or (not forced and attn_impl in ("ring", "ulysses"))
+    if no_seq_parallel and asked_sp:
+        raise ValueError(no_seq_parallel.format(
+            attn_impl=attn_impl, seq_parallel_size=seq_parallel_size
+        ))
+    if forced:
+        return forced
     if attn_impl == "auto":
         fused = (
             jax.default_backend() == "tpu" and no_sp
-            and takes_head_dim(head_dim, head_dim_v)
+            and takes_head_dim(*head_dims)
         )
         return "flash" if fused else "xla"
     if attn_impl in ("ring", "ulysses") and no_sp:

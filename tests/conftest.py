@@ -78,6 +78,15 @@ def shared_worker_compile_cache(tmp_path_factory):
 
 
 @pytest.fixture
+def no_tensorboard(monkeypatch):
+    """``StatsLogger`` without its tensorboard sink, for the cases that are
+    of its JSONL sink: ``torch.utils.tensorboard`` imports TensorFlow, 12 s
+    of a worker's time alone and 53 s beside five busy ones (ROADMAP C.17),
+    once in each worker that builds a logger."""
+    monkeypatch.setitem(sys.modules, "torch.utils.tensorboard", None)
+
+
+@pytest.fixture
 def memory_name_resolve():
     from areal_tpu.utils import name_resolve
 

@@ -105,7 +105,7 @@ def test_slurm_launcher_scripts(tmp_path):
     assert "areal_tpu.inference.server" in server_script
 
 
-def test_stats_logger_sinks_gated(tmp_path, monkeypatch):
+def test_stats_logger_sinks_gated(tmp_path, monkeypatch, no_tensorboard):
     """Without the opt-in env vars (and without the packages) the wandb /
     swanlab sinks stay dormant and commits still work."""
     monkeypatch.delenv("AREAL_TPU_WANDB", raising=False)

@@ -82,17 +82,29 @@ def _scopes_in(names):
     }
 
 
-def _train_programs(family="qwen2"):
-    """(name, lowered) of the trainer's three programs at the tiny config."""
+_ENGINES = {}  # family -> the engine ``_train_programs`` built for it
+
+
+def _train_programs(family="qwen2", only_grad=False):
+    """(name, lowered) of the trainer's three programs at the tiny config;
+    ``only_grad``: of ``grad_step`` alone, and no program is compiled or run
+    on the way (a lowering reads the proximal logprobs' shape, so zeros
+    serve where ``logp_forward`` would have to be built to compute them)."""
     actor, eng = _actor(family=family)
+    _ENGINES[family] = eng
     batch = actor.compute_advantages(_batch())
-    batch["prox_logp"] = actor.compute_logp(batch) * batch["loss_mask"]
+    if only_grad:
+        batch["prox_logp"] = np.zeros_like(batch["logprobs"])
+    else:
+        batch["prox_logp"] = actor.compute_logp(batch) * batch["loss_mask"]
     _, arrays = eng._pack_for_device(batch)
     grad_fn = programs.build_grad_step(
         **eng._forward_values(0), remat=True,
         loss_fn=actor._loss_fn, loss_weight_fn=_ppo_loss_weight_fn,
     )
     accum = eng._zero_grads()
+    if only_grad:
+        return [("grad_step", grad_fn.lower(eng.params, accum, arrays))]
     logp_fn = next(
         f for k, f in eng._jit_cache.items()
         if isinstance(k, tuple) and k[0] == "fwd"
@@ -181,7 +193,7 @@ def lowered():
     for program, family in list(STABLE.items())[1:] + [
         ("cca_grad_step", "zaya")
     ]:
-        grad_steps[program] = _train_programs(family)[0][1]
+        grad_steps[program] = _train_programs(family, only_grad=True)[0][1]
         out[program] = _op_names(grad_steps[program])
     for program, family in STABLE.items():
         _STABLEHLO[family] = hashlib.sha256(
@@ -358,6 +370,105 @@ def test_trainer_counts_what_it_packs_from_the_segment_ids():
     )
     assert not hasattr(eng, "last_timing")
     assert not [k for k in step if k.endswith(("_dispatch_s", "apply_fetch_s"))]
+
+
+# --------------------------------------------------------------------------
+# The seam between a model's mechanisms and their readers (PR 44): what a
+# mixer or a sparse FFN counts, where it cannot run and what its spans say
+# is declared beside it (``transformer.MECHANISMS``); the trainer, the
+# resolver and the generation engine read the declarations and name no family
+# --------------------------------------------------------------------------
+_THE_ENGINES_OWN = {
+    "real_tokens", "padded_tokens", "attn_tiles", "attn_tiles_kept",
+}
+
+
+def test_a_count_added_to_a_declaration_alone_reaches_every_reader(
+    monkeypatch
+):
+    """One toy count beside an existing mixer, by ``monkeypatch`` alone: it
+    is in ``train_batch``'s output, in ``counters()`` and on the stats
+    plane, with nothing under ``engine/`` knowing its name."""
+    from areal_tpu.models import transformer
+    from areal_tpu.utils import stats_tracker
+
+    full = transformer.MECHANISMS["full"]
+    assert not full.counts and full.count is None
+    monkeypatch.setitem(transformer.MECHANISMS, "full", full._replace(
+        counts={"toy_tokens": "the real tokens of the rows"},
+        count=lambda cfg, reported, segment_ids, positions: [
+            (segment_ids > 0).sum().astype(jnp.int32)
+        ],
+    ))
+    # rows of 64 tokens for the batch's 33 (the ladder's own step is 2048)
+    monkeypatch.setattr(data_utils, "ROW_QUANTUM", 64)
+    lens = (5, 9, 12, 7)
+    actor, eng = _actor(remat=False, family="laguna")
+    assert eng.counters()["toy_tokens"] == 0
+    stats_tracker.export_all()
+    (out,) = actor.ppo_update(actor.compute_advantages(_batch(lens)))
+    assert out["toy_tokens"] == sum(lens)
+    assert eng.counters()["toy_tokens"] == sum(lens)
+    assert stats_tracker.export_all()["spmd/toy_tokens"] == sum(lens)
+    # and the family's own counts beside it, as they were
+    assert out["swa_causal_pairs"] == 3 * sum(n * (n + 1) // 2 for n in lens)
+
+
+@pytest.mark.parametrize(
+    "family", ["qwen2", "olmoe", "KeyeVL2", "kimi_linear", "laguna", "zaya"]
+)
+def test_the_trainer_counts_what_the_models_mechanisms_declare(
+    lowered, family
+):
+    from areal_tpu.models import transformer
+
+    eng = _ENGINES[family]  # as ``lowered`` initialised it
+    names = transformer.count_names(eng.model_config)
+    assert set(eng.counters()) - _THE_ENGINES_OWN == set(names)
+    assert all(says for says in names.values())  # each says what it counts
+    # what the engine holds of the ops' resolved forms is the spans' too
+    attrs = eng._span_attrs
+    assert attrs == transformer.span_attrs(eng.model_config)
+    for attr in ("moe_impl", "dsa_select_impl", "kda_impl"):
+        assert getattr(eng, attr) == attrs.get(attr)
+
+
+@pytest.mark.parametrize("mechanism,family,says", [
+    ("indexer", "KeyeVL2", "no indexer-key cache beside its KV pages"),
+    ("swa", "laguna", "8-key window among full ones"),
+    ("cca", "zaya", "recurrent state beside paged state (ROADMAP B.4)"),
+    ("kda", "kimi_linear",
+     "block kinds (kda+dense, kda+moe, mla+moe): it has no recurrent state"),
+])
+def test_a_refused_mechanism_is_refused_in_its_declarations_words(
+    mechanism, family, says
+):
+    from areal_tpu.api.cli_args import JaxGenConfig
+    from areal_tpu.inference.engine import GenerationEngine
+    from areal_tpu.models import transformer
+    from areal_tpu.ops.flash import resolve_attn_impl
+
+    cfg = tiny_config(family)
+    declared = transformer.MECHANISMS[mechanism]
+    why = declared.no_generation(cfg)
+    assert says in why
+    with pytest.raises(NotImplementedError) as refused:
+        GenerationEngine(JaxGenConfig(), model_config=cfg)
+    assert str(refused.value) == why == transformer.generation_refusal(cfg)
+
+    forced, no_sp = transformer.seq_parallel_refusal(cfg)
+    assert forced == declared.forced_attn
+    asked = ("ulysses", 2, cfg.attn_head_dims, forced, no_sp)
+    if declared.no_seq_parallel is None:  # the delta rule's mesh form refuses
+        assert no_sp == "" and resolve_attn_impl(*asked) == "ulysses"
+        return
+    with pytest.raises(ValueError) as refused:
+        resolve_attn_impl(*asked)
+    assert str(refused.value) == declared.no_seq_parallel(cfg).format(
+        attn_impl="ulysses", seq_parallel_size=2
+    )
+    assert "has no sequence-parallel form" in str(refused.value)
+    assert "seq_parallel_size=2" in str(refused.value)
 
 
 def test_rehearsal_counts_a_trained_token_once_and_a_recomputed_one_twice(

@@ -289,7 +289,7 @@ class TestStatsTrackerExport:
             t.denominator(bad=np.array([1.0, 0.0]))
 
 
-def test_stats_logger_sanitizes_nonfinite(tmp_path):
+def test_stats_logger_sanitizes_nonfinite(tmp_path, no_tensorboard):
     """json.dumps(nan) emits a bare ``NaN`` token — not JSON. The JSONL
     sink must write null instead so downstream parsers survive."""
     import json as _json

@@ -113,7 +113,9 @@ def test_each_block_kind_matches_the_reference(tiny, kind):
     with jax.default_matmul_precision("highest"):
         want, _ = ref.layer(keys, kind, x[0], lp)
     np.testing.assert_allclose(got[0], want, atol=2e-5)
-    assert (stats.kda is not None) == kind.startswith("kda")
+    # a layer reports (its FFN's, its mixer's)
+    assert (stats[1] is not None) == kind.startswith("kda")
+    assert (stats[0] is not None) == kind.endswith("moe")
 
 
 def test_logits_match_alone_and_packed_with_padding(tiny):
@@ -128,7 +130,9 @@ def test_logits_match_alone_and_packed_with_padding(tiny):
             got[0, at:at + len(s)], ref.logits(keys, params, s), atol=5e-5
         )
         at += len(s)
-    assert [int(x) for x in stats.kda] == [124 * 3, 192 * 3, 3 * 3]
+    assert [int(stats.counts[k]) for k in (
+        "kda_tokens", "kda_chunk_slots", "kda_segments"
+    )] == [124 * 3, 192 * 3, 3 * 3]
 
 
 def test_loss_and_every_gradient_match_the_references(tiny):
@@ -387,7 +391,7 @@ def test_the_trainer_trains_the_family_and_counts_its_scans_exactly(
     assert (engine.attn_impl, engine.moe_impl, engine.kda_impl) == (
         "xla", "ragged_dot", "xla"
     )
-    assert engine._impl_attrs()["kda_impl"] == "xla"
+    assert engine._span_attrs["kda_impl"] == "xla"
     actor = PPOActor(acfg, engine)
     rng = np.random.default_rng(12)
     lens = [30, 12, 44, 9, 25, 18, 40, 7]

@@ -191,8 +191,8 @@ def test_logits_match_alone_and_packed_with_a_border_inside_a_window(tiny):
         at += len(s)
     inside = sum(sum(min(t + 1, 8) for t in range(len(s))) for s in seqs)
     causal = sum(len(s) * (len(s) + 1) // 2 for s in seqs)
-    assert [int(x) for x in stats.swa.pairs] == [inside] * 3
-    assert [int(x) for x in stats.swa.causal_pairs] == [causal] * 3
+    assert [int(x) for x in stats.counts["swa_pairs"]] == [inside] * 3
+    assert [int(x) for x in stats.counts["swa_causal_pairs"]] == [causal] * 3
 
 
 def test_grpo_loss_and_every_gradient_match_the_references(tiny):
@@ -355,8 +355,12 @@ def test_the_refusals_by_name(tiny, tmp_path):
         )
     for impl, sp in (("ring", 1), ("ulysses", 2), ("auto", 2)):
         with pytest.raises(ValueError, match="B.3"):
-            resolve_attn_impl(impl, sp, 16, layer_window=cfg.layer_window)
-    assert resolve_attn_impl("auto", 1, 16, layer_window=8) == "xla"
+            resolve_attn_impl(
+                impl, sp, (16, 16), *tr.seq_parallel_refusal(cfg)
+            )
+    assert resolve_attn_impl(
+        "auto", 1, (16, 16), *tr.seq_parallel_refusal(cfg)
+    ) == "xla"
 
 
 def test_the_trainer_trains_the_family_and_counts_its_pairs_exactly(
@@ -381,7 +385,7 @@ def test_the_trainer_trains_the_family_and_counts_its_pairs_exactly(
         ft_spec=FinetuneSpec(1, 1000, 8), model_config=tiny[0], seed=3
     )
     assert (engine.attn_impl, engine.moe_impl) == ("xla", "ragged_dot")
-    assert engine._impl_attrs()["layer_window"] == 8
+    assert engine._span_attrs["layer_window"] == 8
     actor = PPOActor(acfg, engine)
     rng = np.random.default_rng(12)
     lens = [30, 12, 44, 9, 25, 18, 40, 7]

@@ -547,7 +547,7 @@ class TestDisabledNoOp:
 
 
 class TestStalenessInStatsLogger:
-    def test_histogram_lands_in_jsonl(self, tmp_path):
+    def test_histogram_lands_in_jsonl(self, tmp_path, no_tensorboard):
         from areal_tpu.api.cli_args import PPOActorConfig
         from areal_tpu.engine.ppo.actor import PPOActor
         from areal_tpu.utils import stats_tracker

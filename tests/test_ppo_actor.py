@@ -24,6 +24,17 @@ from areal_tpu.utils import data as data_utils
 from areal_tpu.utils import stats_tracker
 
 
+@pytest.fixture(autouse=True)
+def rows_of_128_tokens(monkeypatch):
+    """The shortest rung at 128 tokens, for batches of at most 104: every
+    case's rows are what they are on the ladder's own 2048 (one row a
+    minibatch and a pass, all of the shortest rung), and no case compiles
+    and runs 2048-token programs of its own (424 s of the suite's clock for
+    this file: ROADMAP C.17). The two cases of the ladder itself set it
+    back."""
+    monkeypatch.setattr(data_utils, "ROW_QUANTUM", 128)
+
+
 def _actor(group_size=2, ppo_n_minibatches=2, max_tokens_per_mb=32768, **kw):
     cfg = PPOActorConfig(
         dtype="float32",
@@ -348,10 +359,13 @@ def test_dropped_groups_are_neither_recomputed_nor_trained():
     assert counts["prox_own_seqs"] == counts["prox_recomputed_seqs"] == 2
 
 
-def test_a_subset_pass_keeps_the_whole_batchs_row_length_and_window():
+def test_a_subset_pass_keeps_the_whole_batchs_row_length_and_window(
+    monkeypatch
+):
     """``forward(rows=)``: the others read 0, the rows read what a pass over
     all of them reads, and the microbatches run at the WHOLE input's row
     length."""
+    monkeypatch.setattr(data_utils, "ROW_QUANTUM", 2048)  # the ladder's own
     actor = _actor(ppo_n_minibatches=4, max_tokens_per_mb=2048)
     eng = actor.engine
     rng = np.random.default_rng(0)
@@ -384,6 +398,7 @@ def test_a_subset_that_needs_a_longer_row_than_the_whole_is_refused(
     """First-fit is not monotone, so a subset could in principle need a
     longer rung than the whole input: the pass refuses it and does not
     build a ``logp_forward`` the whole batch never had."""
+    monkeypatch.setattr(data_utils, "ROW_QUANTUM", 2048)  # the ladder's own
     eng = _actor().engine
     rng = np.random.default_rng(0)
     lens = [2100, 40, 30, 20]

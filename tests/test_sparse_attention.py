@@ -91,8 +91,10 @@ def test_logits_match_the_reference_with_sequences_longer_than_topk(tiny):
     assert np.abs(want).max() > 0.1
     np.testing.assert_allclose(np.asarray(got[0]), want, atol=LOGIT_TOL, rtol=0)
     # each layer kept sum_t min(t + 1, 8) of the 40 * 41 / 2 causal pairs
-    assert np.asarray(stats.dsa.kept_pairs).tolist() == [36 + 32 * 8] * 2
-    assert np.asarray(stats.dsa.causal_pairs).tolist() == [820] * 2
+    assert np.asarray(stats.counts["dsa_kept_pairs"]).tolist() == (
+        [36 + 32 * 8] * 2
+    )
+    assert np.asarray(stats.counts["dsa_causal_pairs"]).tolist() == [820] * 2
     # and it is not full attention under another name
     full = _alone(dataclasses.replace(cfg, dsa_topk=0), {
         **params, "layers": {k: v for k, v in params["layers"].items()
@@ -148,7 +150,7 @@ def test_two_sequences_in_one_row_select_nothing_across_the_border(tiny):
     np.testing.assert_allclose(packed[0, :30], alone[0], atol=LOGIT_TOL, rtol=0)
     np.testing.assert_allclose(packed[0, 30:54], alone[1], atol=LOGIT_TOL, rtol=0)
     kept = 36 + 22 * 8 + 36 + 16 * 8  # padding counts nowhere
-    assert np.asarray(stats.dsa.kept_pairs).tolist() == [kept] * 2
+    assert np.asarray(stats.counts["dsa_kept_pairs"]).tolist() == [kept] * 2
     # the op's own mask: no (query, key) pair joins two segments
     (q, k, v), (qi, ki, w) = _random_op_inputs(rng, 64)
     mask, _ = sa.select_keys(qi[0], ki[0], w[0], as_row(seg)[0], 8)
@@ -275,9 +277,15 @@ def test_sequence_parallelism_and_the_generation_engine_refuse_by_name(tiny):
     from areal_tpu.ops.flash import resolve_attn_impl
 
     cfg, _, params = tiny
-    assert resolve_attn_impl("auto", 1, cfg.head_dim, has_indexer=True) == "sparse_topk"
+    from areal_tpu.models.transformer import seq_parallel_refusal
+
+    declared = seq_parallel_refusal(cfg)
+    for impl in ("auto", "ring"):  # whatever was asked
+        assert resolve_attn_impl(
+            impl, 1, cfg.attn_head_dims, *declared
+        ) == "sparse_topk"
     with pytest.raises(ValueError, match="sparse attention.*sequence-parallel"):
-        resolve_attn_impl("auto", 2, cfg.head_dim, has_indexer=True)
+        resolve_attn_impl("auto", 2, cfg.attn_head_dims, *declared)
     mesh = mesh_lib.make_mesh(ParallelismConfig(seq_parallel_size=2))
     with pytest.raises(ValueError, match="sparse attention.*sequence-parallel"):
         sa.make_attend(mesh, 8)

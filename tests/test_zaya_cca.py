@@ -188,7 +188,8 @@ def test_a_block_matches_the_reference_and_hands_the_routers_state_on(tiny):
         want, r_want, _ = ref.layer(keys, x[0], r[0], lp)
     np.testing.assert_allclose(got[0], want, atol=2e-5)
     np.testing.assert_allclose(r_out[0], r_want, atol=1e-5)
-    assert 0 < int(stats.moe.assignments) < 37  # some tokens' expert is away
+    # a layer reports (its FFN's, its mixer's): some tokens' expert is away
+    assert 0 < int(stats[0].assignments) < 37 and stats[1] is None
 
 
 def test_logits_match_alone_and_packed_with_borders_one_and_two_apart(tiny):
@@ -208,15 +209,20 @@ def test_logits_match_alone_and_packed_with_borders_one_and_two_apart(tiny):
         at += len(s)
     causal = sum(len(s) * (len(s) + 1) // 2 for s in seqs)
     # partial sums a stretch of a row, the layers leading
-    assert np.asarray(stats.cca.causal_pairs).reshape(3, -1).sum(1).tolist() == (
+    counts = stats.counts
+    assert np.asarray(counts["cca_causal_pairs"]).reshape(3, -1).sum(1).tolist() == (
         [causal] * 3
     )
-    assert [int(x) for x in stats.cca.starts] == [5] * 3
-    assert stats.swa is None and stats.kda is None and stats.dsa is None
+    assert [int(x) for x in counts["cca_starts"]] == [5] * 3
+    # the model's mechanisms' counts and no other family's
+    assert set(counts) == set(tr.count_names(cfg)) == {
+        "cca_causal_pairs", "cca_starts", "moe_assignments",
+        "moe_max_expert_assignments", "moe_stream_rows", "moe_overflow_chunks",
+    }
     # a held share of a half: the slab IS the stream, nothing is compacted
     assert moe_ops._held_rows(32, 4, 8) == 32
-    assert float(stats.moe.stream_rows) == 3 * 32
-    assert float(stats.moe.overflow_chunks) == 0
+    assert float(counts["moe_stream_rows"]) == 3 * 32
+    assert float(counts["moe_overflow_chunks"]) == 0
 
 
 def test_both_convolutions_and_the_shift_are_exact_at_a_border(tiny):
@@ -384,13 +390,17 @@ def test_the_pair_count_of_a_long_sequence_does_not_pass_an_int32():
     pos = np.zeros((1, n), np.int32)
     seg[0, :65536], pos[0, :65536] = 1, np.arange(65536)
     seg[0, 65536:65536 + 4096], pos[0, 65536:65536 + 4096] = 2, np.arange(4096)
-    stats = jax.jit(lambda s, p: tr._cca_stats(cfg, s, p))(seg, pos)
-    assert stats.causal_pairs.dtype == jnp.int32
-    pairs = np.asarray(stats.causal_pairs, np.int64)
+    cca = tr.MECHANISMS["cca"]
+    assert list(cca.counts) == ["cca_causal_pairs", "cca_starts"]
+    causal_pairs, starts = jax.jit(
+        lambda s, p: cca.count(cfg, {}, s, p)
+    )(seg, pos)
+    assert causal_pairs.dtype == jnp.int32
+    pairs = np.asarray(causal_pairs, np.int64)
     assert pairs.shape[0] == 3 and (pairs >= 0).all()
     want = 65536 * 65537 // 2 + 4096 * 4097 // 2
     assert want > 2 ** 31 and pairs.sum(axis=1).tolist() == [want] * 3
-    assert np.asarray(stats.starts).tolist() == [2] * 3
+    assert np.asarray(starts).tolist() == [2] * 3
 
 
 def test_the_two_shares_of_the_experts_add_up_to_the_uncut_layer(tiny):
@@ -502,8 +512,12 @@ def test_the_refusals_by_name(tiny, tmp_path):
         )
     for impl, sp in (("ring", 1), ("ulysses", 2), ("auto", 2)):
         with pytest.raises(ValueError, match="zaya"):
-            resolve_attn_impl(impl, sp, 16, cca=True)
-    assert resolve_attn_impl("auto", 1, 16, cca=True) == "xla"
+            resolve_attn_impl(
+                impl, sp, (16, 16), *tr.seq_parallel_refusal(cfg)
+            )
+    assert resolve_attn_impl(
+        "auto", 1, (16, 16), *tr.seq_parallel_refusal(cfg)
+    ) == "xla"
 
 
 def test_the_trainer_trains_the_family_and_counts_exactly(tiny, monkeypatch):
@@ -526,7 +540,7 @@ def test_the_trainer_trains_the_family_and_counts_exactly(tiny, monkeypatch):
         ft_spec=FinetuneSpec(1, 1000, 8), model_config=tiny[0], seed=3
     )
     assert (engine.attn_impl, engine.moe_impl) == ("xla", "ragged_dot")
-    assert engine._impl_attrs()["router"] == "mlp_carried"
+    assert engine._span_attrs["router"] == "mlp_carried"
     # the carried scalar is drawn at the zero a fresh model stores; the
     # benchmark and these tests move it off
     assert np.asarray(
