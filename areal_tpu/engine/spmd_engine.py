@@ -722,6 +722,9 @@ class SPMDTrainEngine(TrainEngine):
                     attn_impl=self.attn_impl, window=mb_window,
                     **self._count_attn_tiles(packed.segment_ids, mb_window),
                     **self._span_attrs,
+                    **transformer.grad_span_attrs(
+                        self.model_config, self.attn_impl, row_len, mb_window
+                    ),
                 ), goodput.dispatch_scope(
                     goodput.trainer_tracker(), "fwd_bwd",
                     f"pad{row_len}|window{mb_window}",

@@ -266,12 +266,17 @@ class ModelConfig:
 # published checkpoint names) and sequence parallelism (a convolution and a
 # shift across a ring's borders are not built) refuse it by name, as
 # ``_zaya_from_hf`` does the 74B sibling's window layers.
+# mellum is laguna's two rotary kinds again (``_mellum_from_hf``) with every
+# layer's heads alike, no gate, no shared expert and no leading dense layer,
+# the whole head rotated, and q and k normed a head before the rotation
+# (``qk_norm`` "head", which a rotary mixer of a stack of kinds reads too);
+# the same three refuse it, by what the ``swa`` mechanism declares.
 # gemma2/gpt2 remain out (gemma2: soft-capped scores and post-norms beside
 # its windows; gpt2: learned positions, a new embedding).
 _HF_FAMILIES = (
     "llama", "qwen2", "qwen3", "mistral", "qwen3_moe", "mixtral",
     "qwen2_vl", "qwen2_moe", "gemma", "olmoe", "KeyeVL2", "kimi_linear",
-    "laguna", "zaya",
+    "laguna", "zaya", "mellum",
 )
 
 
@@ -384,13 +389,17 @@ def _kimi_linear_from_hf(d: dict) -> ModelConfig:
     )
 
 
-def _rotary_from_hf(d: dict, rope: dict, heads: int, window: int):
+def _rotary_from_hf(
+    d: dict, rope: dict, heads: int, window: int, head_gate: bool = True
+):
     """One rotary mixer's ``RotaryConfig`` from its ``rope_parameters``
     group: ``rope_type`` default or yarn, ``partial_rotary_factor`` of the
     head rotated."""
     kind = rope.get("rope_type", "default")
     if kind not in ("default", "yarn"):
-        raise ValueError(f"laguna: rope_type {kind!r} is not supported")
+        raise ValueError(
+            f"{d['model_type']}: rope_type {kind!r} is not supported"
+        )
     rotary = int(d["head_dim"] * rope.get("partial_rotary_factor", 1.0))
     yarn = kind == "yarn"
     return RotaryConfig(
@@ -406,8 +415,31 @@ def _rotary_from_hf(d: dict, rope: dict, heads: int, window: int):
         attention_factor=(
             float(rope.get("attention_factor", 1.0)) if yarn else 1.0
         ),
-        head_gate=True,
+        head_gate=head_gate,
     )
+
+
+def _rotary_layer_kinds(d: dict, *per_layer: str) -> Tuple[str, ...]:
+    """The block kinds of a family whose ``layer_types`` says which layers
+    attend to the whole sequence and which to a window, and whose
+    ``mlp_layer_types`` which FFNs are dense and which sparse: one entry a
+    layer in both (and in every further key of ``per_layer``), refused by
+    name otherwise."""
+    family, n_layers = d["model_type"], d["num_hidden_layers"]
+    mixers = {"full_attention": "full", "sliding_attention": "swa"}
+    ffns = {"dense": "dense", "sparse": "moe"}
+    keys = ("layer_types", "mlp_layer_types") + per_layer
+    sizes = [len(d[k]) for k in keys]
+    if sizes != [n_layers] * len(keys):
+        raise ValueError(
+            f"{family}: {', '.join(keys)} must have one entry a layer "
+            f"({', '.join(map(str, sizes))} for {n_layers})"
+        )
+    types, mlps = d["layer_types"], d["mlp_layer_types"]
+    unknown = (set(types) - set(mixers)) | (set(mlps) - set(ffns))
+    if unknown:
+        raise ValueError(f"{family}: unknown layer types {sorted(unknown)}")
+    return tuple(f"{mixers[t]}+{ffns[m]}" for t, m in zip(types, mlps))
 
 
 def _laguna_from_hf(d: dict) -> ModelConfig:
@@ -418,20 +450,8 @@ def _laguna_from_hf(d: dict) -> ModelConfig:
     ``mlp_layer_types`` which FFNs are dense (``intermediate_size``) and
     which ``num_experts`` softmax-routed experts (``num_local_experts`` of
     them held here, where the file says so) with a shared one."""
-    n_layers = d["num_hidden_layers"]
-    mixers = {"full_attention": "full", "sliding_attention": "swa"}
-    ffns = {"dense": "dense", "sparse": "moe"}
-    types, mlps = d["layer_types"], d["mlp_layer_types"]
-    heads_at = d["num_attention_heads_per_layer"]
-    if not len(types) == len(mlps) == len(heads_at) == n_layers:
-        raise ValueError(
-            "laguna: layer_types, mlp_layer_types and "
-            "num_attention_heads_per_layer must have one entry a layer "
-            f"({len(types)}, {len(mlps)}, {len(heads_at)} for {n_layers})"
-        )
-    unknown = (set(types) - set(mixers)) | (set(mlps) - set(ffns))
-    if unknown:
-        raise ValueError(f"laguna: unknown layer types {sorted(unknown)}")
+    kinds = _rotary_layer_kinds(d, "num_attention_heads_per_layer")
+    types, heads_at = d["layer_types"], d["num_attention_heads_per_layer"]
     refused = {
         f"gating {d.get('gating')!r} (only true / 'per-head': one gate a "
         "head)": d.get("gating") not in (True, "per-head"),
@@ -463,7 +483,7 @@ def _laguna_from_hf(d: dict) -> ModelConfig:
         vocab_size=d["vocab_size"],
         hidden_size=d["hidden_size"],
         intermediate_size=d["intermediate_size"],
-        num_layers=n_layers,
+        num_layers=d["num_hidden_layers"],
         num_heads=d["num_attention_heads"],
         num_kv_heads=d["num_key_value_heads"],
         head_dim=d["head_dim"],
@@ -480,9 +500,7 @@ def _laguna_from_hf(d: dict) -> ModelConfig:
         shared_expert_gate=False,
         routed_scaling_factor=d.get("moe_routed_scaling_factor", 1.0),
         experts_held=0 if held == num_experts else held,
-        layer_kinds=tuple(
-            f"{mixers[t]}+{ffns[m]}" for t, m in zip(types, mlps)
-        ),
+        layer_kinds=kinds,
         full=_rotary_from_hf(
             d, rope["full_attention"], heads["full_attention"], 0
         ) if "full_attention" in heads else None,
@@ -490,6 +508,52 @@ def _laguna_from_hf(d: dict) -> ModelConfig:
             d, rope["sliding_attention"], heads["sliding_attention"],
             d["sliding_window"],
         ) if "sliding_attention" in heads else None,
+    )
+
+
+def _mellum_from_hf(d: dict) -> ModelConfig:
+    """``model_type`` mellum: ``layer_types`` says which layers attend to
+    the whole sequence and which to ``sliding_window`` keys (all of
+    ``num_attention_heads`` each, no gate), ``rope_parameters`` each kind's
+    rotation over the whole head, ``mlp_layer_types`` which FFNs are dense
+    (``intermediate_size``) and which ``num_experts`` softmax-routed experts
+    (``num_local_experts`` of them held here, where the file says so), no
+    shared one; q and k are normed a head before the rotation, as in the
+    qwen3-moe block whose keys the file has."""
+    kinds, heads = _rotary_layer_kinds(d), d["num_attention_heads"]
+    types = d["layer_types"]
+    if d.get("attention_bias", False):
+        raise ValueError("mellum with attention_bias is not supported")
+    rope = d["rope_parameters"]
+    num_experts = d["num_experts"]
+    held = d.get("num_local_experts", num_experts)
+    return ModelConfig(
+        vocab_size=d["vocab_size"],
+        hidden_size=d["hidden_size"],
+        intermediate_size=d["intermediate_size"],
+        num_layers=d["num_hidden_layers"],
+        num_heads=heads,
+        num_kv_heads=d["num_key_value_heads"],
+        head_dim=d["head_dim"],
+        max_position_embeddings=d.get("max_position_embeddings", 131072),
+        rms_norm_eps=d.get("rms_norm_eps", 1e-6),
+        tie_word_embeddings=d.get("tie_word_embeddings", False),
+        qk_norm="head",
+        family="mellum",
+        num_experts=num_experts,
+        num_experts_per_tok=d["num_experts_per_tok"],
+        moe_intermediate_size=d["moe_intermediate_size"],
+        norm_topk_prob=d.get("norm_topk_prob", True),
+        router_aux_loss_coef=d.get("router_aux_loss_coef", 0.0),
+        experts_held=0 if held == num_experts else held,
+        layer_kinds=kinds,
+        full=_rotary_from_hf(
+            d, rope["full_attention"], heads, 0, head_gate=False
+        ) if "full_attention" in types else None,
+        swa=_rotary_from_hf(
+            d, rope["sliding_attention"], heads, d["sliding_window"],
+            head_gate=False,
+        ) if "sliding_attention" in types else None,
     )
 
 
@@ -578,6 +642,8 @@ def from_hf_config(d: dict) -> ModelConfig:
         return _laguna_from_hf(d)
     if model_type == "zaya":
         return _zaya_from_hf(d)
+    if model_type == "mellum":
+        return _mellum_from_hf(d)
     num_heads = d["num_attention_heads"]
     hidden = d["hidden_size"]
     head_dim = d.get("head_dim") or hidden // num_heads
@@ -726,6 +792,25 @@ def tiny_config(family: str = "qwen2", vocab_size: int = 128) -> ModelConfig:
             cca=CCAConfig(num_heads=4, num_kv_heads=2, rotary_dim=8,
                           rope_theta=5000000.0),
             router=RouterConfig(hidden_size=8),
+        )
+    if family == "mellum":
+        # one period: three layers of an 8-key window, one full layer under
+        # yarn over the whole head; 4 query heads of 16 over 2 key heads, q
+        # and k normed a head; 4 experts held of 16, four a token
+        return ModelConfig(
+            vocab_size=vocab_size, hidden_size=32, intermediate_size=64,
+            num_layers=4, num_heads=4, num_kv_heads=2, head_dim=16,
+            max_position_embeddings=512, rms_norm_eps=1e-6, qk_norm="head",
+            family=family, num_experts=16, num_experts_per_tok=4,
+            moe_intermediate_size=16, norm_topk_prob=True,
+            router_aux_loss_coef=0.0, experts_held=4,
+            layer_kinds=("swa+moe", "swa+moe", "swa+moe", "full+moe"),
+            full=RotaryConfig(
+                num_heads=4, rope_theta=500000.0, yarn_factor=16.0,
+                yarn_original_len=16, yarn_beta_fast=32.0,
+                yarn_beta_slow=1.0, attention_factor=1.2772588722239782,
+            ),
+            swa=RotaryConfig(num_heads=4, window=8, rope_theta=500000.0),
         )
     if family == "laguna":
         # all three block kinds; 6 (full) and 8 (window of 8 keys) query

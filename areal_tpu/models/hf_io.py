@@ -145,7 +145,7 @@ def _kind_tensor_names(cfg: ModelConfig):
     stack, expert or None, hf name, how stored) of every layer tensor."""
     from areal_tpu.models.transformer import _kind_leaves
 
-    if cfg.family in ("laguna", "zaya"):
+    if cfg.family in ("laguna", "zaya", "mellum"):
         raise NotImplementedError(
             f"family {cfg.family!r} has no checkpoint layout here: its "
             "tensor names are in no public source this repo was written "
@@ -460,7 +460,9 @@ def _kimi_linear_config_dict(cfg: ModelConfig) -> dict:
 
 
 def _laguna_config_dict(cfg: ModelConfig) -> dict:
-    """The keys ``models/config._laguna_from_hf`` reads."""
+    """The keys ``models/config._laguna_from_hf`` reads, and
+    ``_mellum_from_hf`` (which reads fewer: no gate, no shared expert, one
+    number of heads)."""
     mixers = {"full": "full_attention", "swa": "sliding_attention"}
     kinds = [k.split("+") for k in cfg.layer_kinds]
 
@@ -505,7 +507,9 @@ def _laguna_config_dict(cfg: ModelConfig) -> dict:
         "moe_routed_scaling_factor": cfg.routed_scaling_factor,
         "moe_apply_router_weight_on_input": False,
         "router_aux_loss_coef": cfg.router_aux_loss_coef,
-        "gating": True,
+        "gating": any(
+            getattr(cfg, m).head_gate for m in mixers if getattr(cfg, m)
+        ),
         "sliding_window": cfg.swa.window if cfg.swa else 0,
         "layer_types": [mixers[m] for m, _ in kinds],
         "mlp_layer_types": [
@@ -560,7 +564,7 @@ def _zaya_config_dict(cfg: ModelConfig) -> dict:
 def default_hf_config_dict(cfg: ModelConfig) -> dict:
     if cfg.family == "kimi_linear":
         return _kimi_linear_config_dict(cfg)
-    if cfg.family == "laguna":
+    if cfg.family in ("laguna", "mellum"):
         return _laguna_config_dict(cfg)
     if cfg.family == "zaya":
         return _zaya_config_dict(cfg)

@@ -35,6 +35,7 @@ import numpy as np
 from jax import ad_checkpoint as _ad_checkpoint
 
 from areal_tpu.models.config import ModelConfig
+from areal_tpu.ops import flash as flash_ops
 from areal_tpu.ops import kda as kda_ops
 from areal_tpu.ops import moe as moe_ops
 from areal_tpu.ops import sparse_attention as dsa_ops
@@ -136,6 +137,9 @@ def _kind_leaves(cfg: ModelConfig, kind: str) -> Dict[str, tuple]:
             leaves["w_head_gate"] = (
                 (D, rc.num_heads), "normal", ("embed", None)
             )
+        if cfg.qk_norm == "head":  # each head by itself, before the rotation
+            leaves["q_norm"] = ((cfg.head_dim,), "ones", (None,))
+            leaves["k_norm"] = ((cfg.head_dim,), "ones", (None,))
     elif mixer == "cca":
         cc, dh = cfg.cca, cfg.head_dim
         Qd, heads = cc.num_heads * dh, cc.num_heads + cc.num_kv_heads
@@ -267,6 +271,10 @@ class Mechanism(NamedTuple):
     no_seq_parallel: Optional[Callable] = None
     no_generation: Optional[Callable] = None
     span_attrs: Optional[Callable] = None
+    # (cfg, attn_impl, row_len, window) -> what a GRAD dispatch span says
+    # beside them of the program it runs: the row's length and the packing
+    # window are the dispatch's, not the model's
+    grad_span_attrs: Optional[Callable] = None
 
 
 def _reported(source: str, *fields, reduce=jnp.sum) -> Callable:
@@ -312,6 +320,20 @@ def _cca_counts(cfg, reported, segment_ids, positions):
         jnp.broadcast_to(pairs, (n,) + pairs.shape),
         jnp.full((n,), (real & (before != segment_ids)).sum(), jnp.int32),
     )
+
+
+def _swa_backwards(cfg, attn_impl, row_len, window):
+    """The backward each of the two cores' kernel instances took
+    (``ops.flash.backward_form``); nothing where another attention runs."""
+    if attn_impl != "flash":
+        return {}
+    d = cfg.head_dim
+    return {
+        "attn_bwd_window": flash_ops.backward_form(
+            row_len, window, d, cfg.swa.window
+        ),
+        "attn_bwd_full": flash_ops.backward_form(row_len, window, d),
+    }
 
 
 def _no_kinds_stack(cfg: ModelConfig) -> str:
@@ -386,6 +408,7 @@ MECHANISMS: Dict[str, Mechanism] = {
             "(ROADMAP B.3); the trainer can"
         ),
         span_attrs=lambda cfg: {"layer_window": cfg.swa.window},
+        grad_span_attrs=_swa_backwards,
     ),
     "cca": Mechanism(
         counts={
@@ -439,11 +462,12 @@ def _mechanisms(cfg: ModelConfig) -> Tuple[Mechanism, ...]:
     return tuple(m for name, m in MECHANISMS.items() if name in has)
 
 
-def _declared(cfg: ModelConfig, field: str) -> list:
+def _declared(cfg: ModelConfig, field: str, *args) -> list:
     """What the model's mechanisms declare under ``field`` (those that
-    declare nothing left out, a text's function called)."""
+    declare nothing left out, a function called with ``cfg`` and what else
+    it takes)."""
     said = [getattr(m, field) for m in _mechanisms(cfg)]
-    return [say(cfg) if callable(say) else say for say in said if say]
+    return [say(cfg, *args) if callable(say) else say for say in said if say]
 
 
 def count_names(cfg: ModelConfig) -> Dict[str, str]:
@@ -467,6 +491,15 @@ def generation_refusal(cfg: ModelConfig) -> str:
 def span_attrs(cfg: ModelConfig) -> Dict[str, Any]:
     """What a trainer's dispatch span says of the model's mechanisms."""
     said = _declared(cfg, "span_attrs")
+    return {k: v for attrs in said for k, v in attrs.items() if v}
+
+
+def grad_span_attrs(
+    cfg: ModelConfig, attn_impl: str, row_len: int, window: int
+) -> Dict[str, Any]:
+    """What a grad dispatch of ``row_len``-token rows under packing
+    ``window`` says of the model's mechanisms beside ``span_attrs``."""
+    said = _declared(cfg, "grad_span_attrs", attn_impl, row_len, window)
     return {k: v for attrs in said for k, v in attrs.items() if v}
 
 
@@ -836,15 +869,22 @@ def _rotary_mixer(cfg, rc, h, lp, segment_ids, cos, sin, attend_fn):
     """Rotary GQA attention on the normed input ``h``, as ``rc`` (a
     ``RotaryConfig``) describes this kind: its own query heads, rotation
     (``cos``/``sin`` of the tokens' positions, made once a kind in
-    ``apply``) and window, each head's output gated. A window layer's core
-    is scope ``attn_window``, not ``attention``; the rotation and the gate
-    are outside both, as the projections are."""
+    ``apply``) and window, each head's output gated where it says so; q and
+    k normed a head before the rotation where ``cfg.qk_norm`` is "head". A
+    window layer's core is scope ``attn_window``, not ``attention``; the
+    norms, the rotation and the gate are outside both, as the projections
+    are."""
     b, t, _ = h.shape
     H, dh = rc.num_heads, cfg.head_dim
-    q = rotate_leading((h @ lp["wq"]).reshape(b, t, H, dh), cos, sin)
-    k = rotate_leading(
-        (h @ lp["wk"]).reshape(b, t, cfg.num_kv_heads, dh), cos, sin
-    )
+
+    def rotated(w, heads, norm):
+        x = (h @ lp[w]).reshape(b, t, heads, dh)
+        if cfg.qk_norm == "head":
+            x = rms_norm(x, lp[norm], cfg.rms_norm_eps)
+        return rotate_leading(x, cos, sin)
+
+    q = rotated("wq", H, "q_norm")
+    k = rotated("wk", cfg.num_kv_heads, "k_norm")
     v = (h @ lp["wv"]).reshape(b, t, cfg.num_kv_heads, dh)
     with device_scope("attn_window" if rc.window else "attention"):
         if attend_fn is None:

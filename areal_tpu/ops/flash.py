@@ -56,8 +56,9 @@ def _tiles(edge: int, fused: bool = True) -> "_sk.BlockSizes":
     )
 
 
-# A window-attention layer's band (``layer_window`` under 1024 keys; a
-# packing window is 1024 or more): its tile edge, and the SPLIT backward. The
+# A window-attention layer's band (``_local_mask`` says which local mask is
+# one; its size does not: 512 keys and 1024 have run): its tile edge, and the
+# SPLIT backward. The
 # fused backward writes a partial dq for every kv block, [T / edge, heads, T,
 # d] in the compute dtype, over a grid it does not shrink to the band: at
 # T = 16384, edge 512 and 64/8 heads of 128 that is 8.6 GB a layer, most of
@@ -68,7 +69,10 @@ def _tiles(edge: int, fused: bool = True) -> "_sk.BlockSizes":
 # rows, 64/8 heads of 128, forward + backward ms a layer (PERF.md, PR 39):
 # T = 16384: edge 512 split 25.7, edge 512 fused 63.6, edge 256 split 37.5,
 # edge 256 fused refused (16.6 GB); T = 8192: 12.0, 19.8, 16.8, 44.9.
-# ``block_kv_compute`` 512 (256: 26.9 and 12.6).
+# ``block_kv_compute`` 512 (256: 26.9 and 12.6). A band of 1024 keys, 32/4
+# heads of 128 on its cell's rows (``--layer-window 1024``, PERF.md, PR 45):
+# T = 32768: edge 512 split 34.5, edge 1024 split 36.5, edge 1024 fused
+# 61.2, edge 512 fused refused (17.8 GB); T = 16384: 16.4, 17.9, 22.1, 26.0.
 BAND_EDGE = 512
 
 
@@ -196,7 +200,9 @@ def flash_window(longest: int, tokens_per_mb: int) -> int:
     return w if 4 * w <= tokens_per_mb else 0
 
 
-def _block_sizes(t: int, window: int, d: int) -> "_sk.BlockSizes":
+def _block_sizes(
+    t: int, window: int, d: int, band: bool = False
+) -> "_sk.BlockSizes":
     """The kernel's tiles, from the shape it is given. Measured on the v5e
     with ``tools/microbench_attn_v2.py`` on the cells' own packed rows under
     the rows' tile mask (``_row_kernel``: an empty tile is free, so a smaller
@@ -223,12 +229,22 @@ def _block_sizes(t: int, window: int, d: int) -> "_sk.BlockSizes":
       0.7%), 1024 to T = 8192 (before PR 42 8192 was unmeasured and took
       2048, which the latent core's 192 | 128 pays 20% for), 2048 above; no
       length has a shape that wants another edge by more than that 0.7%;
-    - a local window skips only whole tiles, so a window of at most 1024
-      takes edge 512 (1.39 against 1.44 ms at T = 4096, PR 26); a LAYER's
-      window of 512 keys (``window`` 511: the band of a window-attention
-      layer, 64/8 heads of 128 at T = 16384 and 8192) takes ``BAND_EDGE``
-      and the split backward (above; PERF.md, PR 39, has the sweep; the
-      rows' tile mask is worth 1.7% there, 25.28 against 25.72 ms);
+    - a local window skips only whole tiles, so a PACKING window of at most
+      1024 takes edge 512 (1.39 against 1.44 ms at T = 4096, PR 26); a
+      LAYER's window (``band``: the caller says so, ``_local_mask``; 512
+      keys at 64/8 heads of 128 to T = 16384, 1024 keys at 32/4 to
+      T = 32768) takes ``BAND_EDGE`` and the split backward (above; PERF.md,
+      PR 39 and PR 45, have the sweeps; the rows' tile mask is worth 1.7%
+      there, 25.28 against 25.72 ms);
+    - past T = 16384 the SPLIT backward, full causal and packing windows
+      alike: the fused one's partial dq, [T / edge, heads, T, d] in bf16, is
+      4.3 GB a layer at T = 32768, edge 2048 and 32 heads of 128, and
+      ``grad_step`` of the cell that has such rows then reads 16.8 GB with
+      its moments (arguments 2.72 + temporaries 11.34 + 2.72; the chip gives
+      a program 16.9) against 14.4 GB split. Forward + backward ms a layer
+      on that cell's rows, 32/4 heads of 128 (PERF.md, PR 45): edge 2048
+      fused 134.4, split 158.7; edge 1024 fused 151.9, split 173.1: so edge
+      2048 stays, and the split costs 18% of the full layer's core there;
     - ``block_kv_compute`` 512 rather than the edge (the smaller compute
       tile overlaps the kv DMA; 256 loses), and the FUSED dq+dkv backward:
       one pass over the data, 10-25% under the split kernels everywhere
@@ -236,20 +252,61 @@ def _block_sizes(t: int, window: int, d: int) -> "_sk.BlockSizes":
 
     The head dimension moved none of these choices. Never above what
     ``probe_block_size`` found to run, and always a divisor of ``t``."""
-    band = 0 < window < 1024  # a layer's window, never the packing's
-    want = (
-        BAND_EDGE if band else 512 if 0 < window <= 1024 or t <= 2048
-        else 1024 if t <= 8192 else 2048
-    )
+    if band:
+        want, fused = BAND_EDGE, False
+    else:
+        want = (
+            512 if 0 < window <= 1024 or t <= 2048
+            else 1024 if t <= 8192 else 2048
+        )
+        fused = t <= 16384
     want = min(want, _PROBED_BLOCK or want)
     edge = _LANES
     while edge * 2 <= min(want, t) and t % (edge * 2) == 0:
         edge *= 2
-    return _tiles(edge, fused=not band)
+    return _tiles(edge, fused=fused)
+
+
+def _local_mask(t: int, window: int, layer_window: int) -> "tuple[int, bool]":
+    """(keys back, is it a layer's band) of the kernel's local mask on a row
+    of ``t`` tokens under packing ``window`` and a layer's ``layer_window``
+    (0 = none, each): the layer's reaches ``layer_window - 1`` keys back
+    beside the query's own, and the shorter of the two is the mask; (0,
+    False), plain causal, where that reaches over the whole row. The KIND
+    is said here and carried to ``_block_sizes``, never read back from the
+    size: a packing window of 1024 keys and a layer's of 1024 are one key
+    apart."""
+    band = bool(layer_window) and (not window or layer_window - 1 <= window)
+    if band:
+        window = layer_window - 1
+    if not 0 < window < t:  # reaches over the whole row: causal
+        return 0, False
+    return int(window), band
+
+
+def backward_form(
+    t: int, window: int, d: int, layer_window: int = 0
+) -> str:
+    """"fused" | "split": the backward the kernel takes on a row of ``t``
+    tokens under packing ``window`` and a layer's ``layer_window`` (what a
+    trainer's dispatch span says of each core); "" for a row the XLA form
+    takes."""
+    if t % _LANES:
+        return ""
+    back, band = _local_mask(t, window, layer_window)
+    fused = _block_sizes(t, back, d, band).use_fused_bwd_kernel
+    return "fused" if fused else "split"
 
 
 @functools.lru_cache(maxsize=32)
-def _make_kernel(t: int, rep: int, window: int, d: int, interpret: bool):
+def _make_kernel(
+    t: int, rep: int, window: int, d: int, interpret: bool,
+    band: bool = False,
+):
+    """The kernel of a row of ``t`` tokens under a local mask ``window`` keys
+    back (0: causal). ``band``: the mask is a window-attention LAYER's, not
+    the packing's; the mask is the same, the tiles and the backward are the
+    kind's (``_block_sizes``)."""
     # ensure_compile_time_eval: this may be reached inside a jit trace, but
     # the kernel object (and the mask arrays it processes) must be concrete —
     # it is cached across traces, and a tracer captured here would escape.
@@ -261,7 +318,7 @@ def _make_kernel(t: int, rep: int, window: int, d: int, interpret: bool):
             # positions, so a causal local window >= that bound plus the
             # runtime segment-id mask is exact. A window-attention layer's
             # band is the same mask, ``window`` = its keys less the query's
-            # own (511), under the same run-time segment ids
+            # own (511, 1023), under the same run-time segment ids
             head = _sm.LocalMask((t, t), (window, 0), 0)
         else:
             head = _sm.CausalMask((t, t))
@@ -270,7 +327,7 @@ def _make_kernel(t: int, rep: int, window: int, d: int, interpret: bool):
         # saving "attn_out" skips the forward-kernel recompute in the
         # backward (what models/transformer.apply's remat saves)
         return _sk.make_splash_mqa_single_device(
-            mask, block_sizes=_block_sizes(t, window, d),
+            mask, block_sizes=_block_sizes(t, window, d, band),
             residual_checkpoint_name="attn_out", interpret=interpret,
         )
 
@@ -415,12 +472,10 @@ def flash_segment_attention(
         return segment_attention(
             q, k, v, segment_ids, causal=True, window=layer_window
         )
-    if layer_window:
-        back = layer_window - 1
-        window = min(window, back) if window else back
+    window, band = _local_mask(t, window, layer_window)
     hkv = k.shape[2]
     rep = hq // hkv
-    kernel = _make_kernel(t, rep, int(window), d, interpret)
+    kernel = _make_kernel(t, rep, window, d, interpret, band)
     scale = d**-0.5
     qg = (q.astype(jnp.float32) * scale).astype(q.dtype)
     qg = qg.transpose(0, 2, 1, 3).reshape(b, hkv, rep, t, d)

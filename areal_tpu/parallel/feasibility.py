@@ -505,7 +505,50 @@ def zaya1_8b_config() -> ModelConfig:
     )
 
 
+def mellum2_12b_a2p5b_config() -> ModelConfig:
+    """Mellum2-12B-A2.5B (JetBrains) as ONE chip of 8 that share each layer
+    holds it: published layers 0-3 (three layers of a 1024-key window, one
+    full-attention layer under yarn: one whole period), 8 of the 64
+    softmax-routed experts, an eighth of the untied vocabulary: the cut of
+    benchmark/configs/mellum2-12b-a2.5b.json (published: 28 layers, 98,304
+    rows). Every width is as published: 32 query and 4 key heads of 128 on
+    every layer, q and k normed a head, the whole head rotated at 500,000,
+    experts of 896, the router's 64 outputs and 8 a token. The trainer runs
+    it; the generation engine, ``hf_io`` and sequence parallelism refuse it
+    as they refuse every window model (ROADMAP B.3)."""
+    from areal_tpu.models.config import RotaryConfig
+
+    return ModelConfig(
+        vocab_size=12288,
+        hidden_size=2304,
+        intermediate_size=7168,
+        num_layers=4,
+        num_heads=32,
+        num_kv_heads=4,
+        head_dim=128,
+        max_position_embeddings=131072,
+        rms_norm_eps=1e-6,
+        tie_word_embeddings=False,
+        qk_norm="head",
+        family="mellum",
+        num_experts=64,
+        num_experts_per_tok=8,
+        moe_intermediate_size=896,
+        norm_topk_prob=True,
+        router_aux_loss_coef=0.0,
+        experts_held=8,
+        layer_kinds=("swa+moe", "swa+moe", "swa+moe", "full+moe"),
+        full=RotaryConfig(
+            num_heads=32, rope_theta=500000.0, yarn_factor=16.0,
+            yarn_original_len=8192, yarn_beta_fast=32.0, yarn_beta_slow=1.0,
+            attention_factor=1.2772588722239782,
+        ),
+        swa=RotaryConfig(num_heads=32, window=1024, rope_theta=500000.0),
+    )
+
+
 MODEL_CONFIGS = {
+    "mellum2_12b_a2p5b": mellum2_12b_a2p5b_config,
     "zaya1_8b": zaya1_8b_config,
     "laguna_xsp2": laguna_xsp2_config,
     "kimi_linear_48b_a3b": kimi_linear_48b_a3b_config,

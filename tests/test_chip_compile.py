@@ -168,20 +168,26 @@ def test_splash_fwd_bwd_compiles_for_v5e(
 # 8192-token rung: the window layers' band (its own tiles and the split
 # backward, ``flash.BAND_EDGE``) and the full layers' causal core
 _LAGUNA_CASES = [
-    pytest.param(64, 16384, 512, True, id="swa-h64-t16384"),
-    pytest.param(64, 8192, 512, True, id="swa-h64-t8192"),
-    pytest.param(48, 16384, 0, False, id="full-h48-t16384"),
-    pytest.param(48, 8192, 0, False, id="full-h48-t8192"),
+    pytest.param(64, 8, 16384, 512, True, id="swa-h64-t16384"),
+    pytest.param(64, 8, 8192, 512, True, id="swa-h64-t8192"),
+    pytest.param(48, 8, 16384, 0, False, id="full-h48-t16384"),
+    pytest.param(48, 8, 8192, 0, False, id="full-h48-t8192"),
+    # Mellum2's 32 / 4 heads at its cell's 32768-token row: a 1024-key band
+    # is a band by KIND (PR 45), and past 16384 tokens the full core takes
+    # the split backward too
+    pytest.param(32, 4, 32768, 1024, True, id="swa-h32-w1024-t32768"),
+    pytest.param(32, 4, 32768, 0, False, id="full-h32-t32768"),
 ]
 
 
-@pytest.mark.parametrize("hq,t,layer_window,band", _LAGUNA_CASES)
+@pytest.mark.parametrize("hq,hkv,t,layer_window,band", _LAGUNA_CASES)
 def test_window_and_full_cores_compile_for_v5e(
-    one_chip, monkeypatch, hq, t, layer_window, band
+    one_chip, monkeypatch, hq, hkv, t, layer_window, band
 ):
-    """fwd+bwd of both of Laguna's attention cores at the published heads:
-    the band's kernel instance is its own (``LocalMask`` 511 back, the split
-    backward: no partial dq a kv block), the full core's is today's."""
+    """fwd+bwd of both attention cores of the two window families at the
+    published heads: the band's kernel instance is its own (``LocalMask``
+    ``layer_window - 1`` back, the split backward: no partial dq a kv
+    block), the full core's is the shape's."""
     import jax
     import jax.numpy as jnp
 
@@ -199,19 +205,22 @@ def test_window_and_full_cores_compile_for_v5e(
     try:
         lowered = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
             _sds((1, t, hq, 128), bf, one_chip),
-            _sds((1, t, 8, 128), bf, one_chip),
-            _sds((1, t, 8, 128), bf, one_chip),
+            _sds((1, t, hkv, 128), bf, one_chip),
+            _sds((1, t, hkv, 128), bf, one_chip),
             _sds((1, t), jnp.int32, one_chip),
         )
         compiled = lowered.compile()
     finally:
         flash._make_kernel.cache_clear()
-    tiles = flash._block_sizes(t, layer_window - 1 if band else 0, 128)
+    tiles = flash._block_sizes(
+        t, layer_window - 1 if band else 0, 128, band
+    )
     full_edge = 2048 if t > 8192 else 1024
     assert tiles.block_q == (flash.BAND_EDGE if band else full_edge)
-    assert tiles.use_fused_bwd_kernel == (not band)
+    split = band or t > 16384
+    assert tiles.use_fused_bwd_kernel == (not split)
     text = lowered.as_text()
-    assert ("splash_mqa_dq" in text) == band  # the split backward's own dq
+    assert ("splash_mqa_dq" in text) == split  # the split backward's own dq
     assert "tpu_custom_call" in compiled.as_text()
     # the fused backward's partial dq a kv block would be [T / edge, rep, T,
     # 128] bf16 a key head: 8.6 GB at the cell's row; the split one has none

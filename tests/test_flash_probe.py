@@ -88,7 +88,7 @@ _EDGES = [
     pytest.param(15360, 0, 2048, 1024, id="edge-divides-the-row"),
     pytest.param(8192, 0, 2048, 1024, id="8192-takes-1024"),
     pytest.param(2048, 0, 2048, 512, id="short-rung-takes-512"),
-    pytest.param(32768, 0, 2048, 2048, id="past-16384-takes-2048"),
+    pytest.param(32768, 0, 2048, 2048, id="past-16384-takes-2048-split"),
     pytest.param(1536, 0, 2048, 512, id="short-row-divisor"),
     pytest.param(256, 0, 0, 256, id="cpu-cap-0-is-no-cap"),
     pytest.param(384, 0, 2048, 128, id="odd-multiple-of-128"),
@@ -103,4 +103,4 @@ def test_tiles_follow_the_shape_under_the_probed_cap(t, window, cap, edge):
     assert {tiles.block_q, tiles.block_kv, tiles.block_q_dkv,
             tiles.block_kv_dkv} == {edge}
     assert tiles.block_kv_compute == tiles.block_kv_dkv_compute == min(512, edge)
-    assert tiles.use_fused_bwd_kernel and t % edge == 0
+    assert tiles.use_fused_bwd_kernel == (t <= 16384) and t % edge == 0

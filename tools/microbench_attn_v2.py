@@ -162,7 +162,9 @@ def main():
         for name, window, tiles in (swept if args.static else [])
     ] + [("chosen", 0, None)]
     for name, window, tiles in named:
-        flash._block_sizes = (lambda *_, tiles=tiles: tiles) if tiles else chosen
+        flash._block_sizes = (
+            (lambda *_, tiles=tiles, **__: tiles) if tiles else chosen
+        )
         flash._row_kernel = (
             (lambda kernel, seg: kernel) if name.endswith("_static")
             else row_kernel
