@@ -56,12 +56,14 @@ per-token sigmoid gate where the family has one) is part of the sparse layer
 Device scopes (``utils/tracing.DEVICE_SCOPES``), nested inside the layer's
 ``mlp``: ``moe_route`` (router matmul, softmax, top-k, load statistics),
 ``moe_dispatch`` (sort, permute, un-permute, weighted sum; of a share: the
-sort of every pair, a slab's row gather, the [tokens, slab] product that
-sums its rows back onto their tokens), ``moe_experts`` (the grouped matmuls
-and the gate).
+sort of every pair, a slab's row gather, and its rows summed back onto
+their tokens: on a TPU the slab's rows put in token order and a grouped
+product over token tiles, ``onto_tokens_banded``; elsewhere the [tokens,
+slab] product), ``moe_experts`` (the grouped matmuls and the gate).
 """
 
 import functools
+import importlib
 from typing import Dict, NamedTuple, Optional, Tuple
 
 import jax
@@ -336,15 +338,16 @@ def _run_experts(xf, pair_expert, topk_p, group_sizes, w_gate, w_up, w_down):
 # 16,082 of 4,096 even (3.9 x), 42 of 440 chunks past twice the even share.
 # At 2 a Kimi chunk is one slab always and a Keye chunk one slab nine times
 # in ten; at 1 a second slab (three more grouped matmuls that each read all
-# the held weights) would run in 15% and 42% of the chunks; at 3 the [G, C]
-# product below costs half as much again in every chunk to save 7% of them
-# a second slab. A layer that holds HALF of its experts (one a token over
-# 16, 8 held: even share rows / 2) has a slab of rows x 1/2 x 2 = the whole
-# stream: ``_held_rows`` gives ``rows``, ``_run_held`` takes the whole form
-# (``_run_experts``: every pair sorted and gathered, the other holder's half
-# as zero rows behind the groups), nothing is compacted and no chunk can
-# overflow. The slack's price there is nil and so is its gain; a slab of
-# the even share alone (slack 1) would overflow in about every second chunk.
+# the held weights) would run in 15% and 42% of the chunks; at 3 the slab's
+# row gathers and the token side below cost half as much again in every
+# chunk to save 7% of them a second slab. A layer that holds HALF of its
+# experts (one a token over 16, 8 held: even share rows / 2) has a slab of
+# rows x 1/2 x 2 = the whole stream: ``_held_rows`` gives ``rows``,
+# ``_run_held`` takes the whole form (``_run_experts``: every pair sorted
+# and gathered, the other holder's half as zero rows behind the groups),
+# nothing is compacted and no chunk can overflow. The slack's price there
+# is nil and so is its gain; a slab of the even share alone (slack 1) would
+# overflow in about every second chunk.
 _HELD_SLACK = 2
 
 
@@ -355,17 +358,98 @@ def _held_rows(rows: int, e: int, n_routed: int) -> int:
     return min(-(-c // _GMM_TILE_M) * _GMM_TILE_M, rows)
 
 
+# The token side of a slab: its [C, D] rows summed onto their [G, D] tokens.
+# As a product with the [G, C] matrix that holds ``w[i]`` at (tok[i], i) it
+# spends 2 G C D FLOP on C D useful ones (each column holds ONE non-zero).
+# With the slab's rows in TOKEN order the matrix is block-banded: a tile of
+# ``_ONTO_TILE`` tokens owns one contiguous run of rows, and the product is a
+# grouped one over those runs (``megablox``' transposed kernel, one group a
+# token tile): 2 C ``_ONTO_TILE`` D FLOP, a sixteenth at G 4,096. A grid step
+# takes ``_ONTO_ROWS`` of the slab's rows (a slab is whole ``_GMM_TILE_M``
+# tiles, so they always divide it) by the whole width. Measured on the v5e
+# (``tools/microbench_moe --held``, PR 46; the table under ``_onto_tokens``):
+# (rows, token tile, width tile) = (256, 256, D) is ahead at all four cells'
+# shapes; (512, 128, D) within 3%; a width tile of 1,024 costs 0.01-0.06 ms,
+# 1,024 rows a step 0.05-0.09, a token tile of 512 0.03.
+_ONTO_TILE = 256
+_ONTO_ROWS = 256
+
+
+def onto_impl(shape: Optional[Tuple[int, int, int]] = None) -> str:
+    """How a slab's rows are summed onto their tokens: ``"banded"`` (the
+    grouped product over token tiles) on a TPU, ``"dense"`` (the product
+    with the [G, C] matrix) elsewhere and for a ``shape`` (slab rows,
+    tokens, model width) the tiles do not divide. The dense product is the
+    form the kernel is held equal to; a test off the chip names
+    ``"interpret"``, the banded form with its kernel in Pallas interpret
+    mode."""
+    if jax.default_backend() != "tpu":
+        return "dense"
+    if shape is not None and (
+        shape[0] % _ONTO_ROWS or shape[1] % _ONTO_TILE or shape[2] % 128
+    ):
+        return "dense"
+    return "banded"
+
+
+@functools.partial(jax.jit, static_argnames=("g", "tiling", "interpret"))
+def onto_tokens_banded(rows, tok, w, g, tiling, interpret=False):
+    """``_onto_tokens`` as a grouped product: the slab's rows in token
+    order (rows of no token behind every token, never read), each token
+    tile's run of them against the [tile, run] matrix of their weights.
+    Jitted, so a program lowers the kernel once a shape, and under its OWN
+    name, which the custom call carries: ``moe_experts_roofline.train``
+    reads every operation named ``gmm*`` / ``tgmm*`` as an expert matmul."""
+    # the module (the package's ``gmm`` is the function), and its kernel
+    # un-jitted: the custom call takes the name of the innermost jit
+    tgmm = importlib.import_module(
+        "jax.experimental.pallas.ops.tpu.megablox.gmm"
+    ).tgmm.__wrapped__
+    tile = tiling[1]
+    ts, ws, by_tok = jax.lax.sort(
+        (tok, w, jnp.arange(tok.shape[0], dtype=jnp.int32)), num_keys=1
+    )
+    # [tile, C]: w[i] at (its token's place in its tile, i)
+    m = jnp.where(
+        jnp.arange(tile, dtype=ts.dtype)[:, None] == (ts % tile)[None],
+        ws.astype(rows.dtype)[None], 0,
+    )
+    out = tgmm(
+        m, rows[by_tok], _group_sizes(ts // tile, g // tile), rows.dtype,
+        tiling, interpret=interpret,
+    )
+    return out.reshape(g, rows.shape[1])
+
+
 def _onto_tokens(rows, tok, w, g):
     """[C, D] rows -> [G, D]: token t's sum of ``w[i] * rows[i]`` over
-    the rows i with ``tok[i] == t`` (``w`` None: 1). The transpose of a
-    row gather, as a product with the [G, C] matrix that holds ``w[i]`` at
-    (tok[i], i): float32 accumulation of bf16 products, as the whole form's
-    weighted sum, and no scatter. A row of no token (``tok[i] == g``) must
-    come in as zeros: 0 x what the grouped matmul left unwritten is not 0.
-    On the chip (``tools/microbench_moe --held``, PR 37, G 4,096): 0.22 ms
-    at the Kimi chunk's C 2,048 x D 2,304, where gathering every pair's row
-    clamped to one zero row and summing k a token reads 0.95 ms; 0.78 ms
-    against 0.65 at the long-row chunk's C 8,192 x D 2,048."""
+    the rows i with ``tok[i] == t`` (``w`` None: 1); a row of no token
+    (``tok[i] == g``) adds to nothing, whatever it holds (the grouped matmul
+    leaves it unwritten). The transpose of a row gather with no scatter:
+    bf16 products summed in float32, as the whole form's weighted sum, in
+    one of two forms (``onto_impl``) that differ in the order of a token's
+    sum at most. On the chip (``tools/microbench_moe --held``, PR 46, one
+    4,096-token chunk, device ms; the banded form with its sort and row
+    gather, its kernel alone in brackets):
+
+        slab C x width D           dense    banded          gather k a token
+        8,192 x 2,304 (Mellum)     0.878    0.281 (0.132)   0.777
+        8,192 x 2,048 (long-row)   0.789    0.234 (0.099)   0.654
+        4,096 x 2,048 (Laguna)     0.398    0.176 (0.061)   0.818
+        2,048 x 2,304 (Kimi)       0.227    0.120 (0.052)   0.946
+
+    and equal to the dense product to the bit or to one bf16 step."""
+    impl = onto_impl((rows.shape[0], g, rows.shape[1]))
+    if impl != "dense":
+        if w is None:
+            w = jnp.ones(tok.shape, jnp.float32)
+        return onto_tokens_banded(
+            rows, tok, w.astype(jnp.float32), g,
+            (_ONTO_ROWS, _ONTO_TILE, rows.shape[1]),
+            interpret=impl == "interpret",
+        )
+    # 0 x what is not a number is not 0: the rows of no token go in as zeros
+    rows = jnp.where((tok < g)[:, None], rows, 0)
     onto = jnp.arange(g, dtype=tok.dtype)[:, None] == tok[None]
     m = onto.astype(rows.dtype) if w is None else jnp.where(
         onto, w.astype(rows.dtype)[None], 0
@@ -390,7 +474,6 @@ def _dispatch_held_fwd(xf, tok):
 
 def _dispatch_held_bwd(res, g_xs):
     tok, g = res
-    g_xs = jnp.where((tok < g)[:, None], g_xs, 0)
     return _onto_tokens(g_xs, tok, None, g), None
 
 
@@ -401,7 +484,6 @@ _dispatch_held.defvjp(_dispatch_held_fwd, _dispatch_held_bwd)
 def _combine_held(ys, w_c, tok, g):
     """[C, D] sorted expert outputs and their [C] float32 routing weights
     -> [G, D]: what the slab's pairs add to their tokens."""
-    ys = jnp.where((tok < g)[:, None], ys, 0)
     return _onto_tokens(ys, tok, w_c, g)
 
 

@@ -528,25 +528,34 @@ def test_rehearsal_counts_a_trained_token_once_and_a_recomputed_one_twice(
     assert 0.6 < sum(passes) / c["trained_tokens"] < 0.9
 
 
-def test_a_held_shares_slabs_sit_under_the_layers_scopes():
+@pytest.mark.parametrize("onto", ["dense", "banded"])
+def test_a_held_shares_slabs_sit_under_the_layers_scopes(onto, monkeypatch):
     """A layer that holds a share runs a chunk's expert path over slabs of
     the sorted stream, forward in a loop, backward the first in line and the
     others in a loop (``ops/moe.py``): every operation the layer adds, in the
     loops' bodies as in line, is under ``moe_route``, ``moe_dispatch`` or
     ``moe_experts``, so ``moe_dispatch_time_share.train`` and
     ``moe_experts_time_share.train`` read all of it however many slabs ran
-    (a ``while`` itself is a container to the trace's reader)."""
+    (a ``while`` itself is a container to the trace's reader). ``banded``:
+    the token side as a TPU runs it (``moe.onto_tokens_banded``, here with
+    its kernel in interpret mode: a jitted function, whose operations keep
+    the scope of the place that calls it): under ``moe_dispatch`` in the
+    forward loop's body, in the backward's first slab and in its loop's."""
     from areal_tpu.ops import moe
     from benchmark import program_trace
 
     t, d, f, routed, held, k = 512, 16, 16, 16, 2, 2
     assert moe._held_rows(t * k, held, routed) < t * k  # more than one slab
+    if onto == "banded":
+        monkeypatch.setattr(moe, "onto_impl", lambda shape=None: "interpret")
+        monkeypatch.setattr(moe, "_ONTO_TILE", 128)
+        monkeypatch.setattr(moe, "_ONTO_ROWS", 128)
 
     def loss(x, wr, wg, wu, wd):
         out, _ = moe.moe_ffn(
             x, wr, wg, wu, wd, num_experts_per_tok=k, norm_topk_prob=True
         )
-        return out.sum()
+        return (out ** 2).sum()  # the backward reads the forward's result
 
     z = jnp.zeros
     _, names = _op_names(jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
@@ -565,3 +574,11 @@ def test_a_held_shares_slabs_sit_under_the_layers_scopes():
         and any(w in n for w in ("gather", "sort", "dot_general", "ragged"))
     }
     assert not stray, sorted(stray)[:10]
+    ours = {n for n in names if "jit(onto_tokens_banded)" in n}
+    assert bool(ours) == (onto == "banded")
+    if ours:
+        assert {program_trace.scope_of(n) for n in ours} == {"moe_dispatch"}
+        where = {
+            (program_trace.direction_of(n), "/while/" in n) for n in ours
+        }
+        assert where == {("fwd", True), ("bwd", False), ("bwd", True)}

@@ -240,19 +240,139 @@ def _ref_share(x, wr, wg, wu, wd, k, first, valid):
     return out
 
 
+def _banded_in_interpret_mode(monkeypatch, tiling):
+    """The token side's kernel form off the chip (``moe.onto_impl`` is
+    steered as ``tests/test_chip_compile.py`` steers ``grouped_impl``), at
+    tiles of a test's size."""
+    from areal_tpu.ops import moe
+
+    monkeypatch.setattr(moe, "onto_impl", lambda shape=None: "interpret")
+    monkeypatch.setattr(moe, "_ONTO_ROWS", tiling[0])
+    monkeypatch.setattr(moe, "_ONTO_TILE", tiling[1])
+
+
+# (slab rows C, tokens G, width D, (rows a step, token tile, width tile),
+# which rows are live: a share or a mask, the token of each live row: a
+# function of the generator and the live rows' count, the weights: None = 1)
+def _any_tokens(g):
+    return lambda rng, n: rng.integers(0, g, n)
+
+
+_ONTO_CASES = {
+    # tokens 128..255 have no row: the second of four tiles comes out zero
+    "a_tile_with_no_row": (
+        512, 512, 48, (128, 128, 128), 0.8,
+        lambda rng, n: rng.choice(
+            np.r_[0:128, 256:512], n
+        ), "random",
+    ),
+    # four rows a token, back to back and scattered over the slab
+    "a_token_with_all_its_pairs": (
+        512, 128, 48, (128, 128, 128), 1.0,
+        lambda rng, n: rng.permutation(np.repeat(np.arange(128), 4)),
+        "random",
+    ),
+    "every_live_row_in_one_tile": (
+        512, 512, 48, (128, 128, 128), 0.5,
+        lambda rng, n: rng.integers(256, 384, n), "random",
+    ),
+    "no_live_row_at_all": (
+        256, 256, 48, (128, 128, 128), 0.0, _any_tokens(256), "random",
+    ),
+    # the live rows are every third: rows of no token lie between them
+    # until the sort puts them behind
+    "rows_of_no_token_interleaved": (
+        384, 256, 48, (128, 128, 128), np.arange(384) % 3 == 0,
+        _any_tokens(256), "random",
+    ),
+    "a_routing_weight_of_zero": (
+        256, 256, 48, (128, 128, 128), 0.9, _any_tokens(256), "zeros",
+    ),
+    "weights_of_one": (  # the dispatch's transpose: w None
+        256, 256, 48, (128, 128, 128), 0.9, _any_tokens(256), None,
+    ),
+    # the cells' slabs over a 4,096-token chunk at the row and token tiles
+    # the chip runs, the width 2,304 cut to an eighth and NOT a whole number
+    # of width tiles (2.25 of 128, as 2,304 is of 1,024; the chip's width
+    # tile is the whole width)
+    "kimi_slab_2048": (
+        2048, 4096, 288, (256, 256, 128), 0.5, _any_tokens(4096), "random",
+    ),
+    "laguna_slab_4096": (
+        4096, 4096, 288, (256, 256, 128), 0.5, _any_tokens(4096), "random",
+    ),
+    "mellum_slab_8192": (
+        8192, 4096, 288, (256, 256, 128), 0.5, _any_tokens(4096), "random",
+    ),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_ONTO_CASES))
+def test_a_slabs_rows_go_back_to_their_tokens_by_a_banded_product(
+    case, monkeypatch
+):
+    """``ops/moe._onto_tokens`` alone: the grouped product over token
+    tiles of the slab's rows in token order (the megablox kernel in Pallas
+    interpret mode) against the product with the [G, C] matrix, in bf16 as
+    the cells run it. The same products summed in float32, so they differ
+    by the order of a token's sum at most: a rounding of the float32 sum,
+    and one bf16 step where that moves the cast. What a row of no token
+    holds (the grouped matmul leaves it unwritten) reaches nothing."""
+    from areal_tpu.ops import moe
+
+    c, g, d, tiling, live, tokens, weights = _ONTO_CASES[case]
+    rng = np.random.default_rng(sorted(_ONTO_CASES).index(case))
+    if not isinstance(live, np.ndarray):
+        live = rng.random(c) < live
+    tok = np.full(c, g, np.int32)
+    tok[live] = tokens(rng, int(live.sum()))
+    rows = rng.standard_normal((c, d)).astype(np.float32)
+    rows[~live] = np.nan
+    w = None
+    if weights is not None:
+        w = np.where(live, rng.random(c), 0).astype(np.float32)
+        if weights == "zeros":
+            w[np.flatnonzero(live)[::2]] = 0.0
+        w = jnp.asarray(w)
+    args = (jnp.asarray(rows, jnp.bfloat16), jnp.asarray(tok), w, g)
+    assert moe.onto_impl((c, g, d)) == "dense"  # the CPU's own choice
+    dense = np.asarray(moe._onto_tokens(*args), np.float32)
+    if w is None:  # through the rule, as ``_dispatch_held_bwd`` calls it
+        _banded_in_interpret_mode(monkeypatch, tiling)
+        banded = moe._onto_tokens(*args)
+    else:  # the kernel form at the case's own tiles
+        banded = moe.onto_tokens_banded(*args, tiling, interpret=True)
+    banded = np.asarray(banded, np.float32)
+    assert banded.shape == (g, d) and np.isfinite(banded).all()
+    np.testing.assert_allclose(
+        banded, dense, rtol=2.0 ** -7,
+        atol=1e-6 * np.abs(rows[live]).max(initial=1.0),
+    )
+    # tokens with no live row come out as exact zeros in both
+    empty = np.setdiff1d(np.arange(g), tok[live])
+    assert not dense[empty].any() and not banded[empty].any()
+    if live.any():
+        assert np.abs(dense).max() > 0.1
+
+
+@pytest.mark.parametrize("onto", ["dense", "banded"])
 @pytest.mark.parametrize("load", sorted(_HELD_LOADS))
 def test_a_held_share_runs_over_a_bounded_buffer_and_drops_nothing(
-    load, monkeypatch
+    load, onto, monkeypatch
 ):
     """The expert path over slabs of the sorted stream against the whole
     stream's and the per-token reference: output, the gradients of the
     tokens, the router, the three expert stacks and the routing weights,
     and the two counters. A chunk whose held pairs pass one slab runs as
-    many as they fill: nothing is dropped at any load."""
+    many as they fill: nothing is dropped at any load. ``onto``: the slab's
+    rows back onto their tokens by the dense product (what the CPU runs) or
+    by the banded one (what a TPU runs; here in interpret mode)."""
     from areal_tpu.ops import moe
 
     n_two, n_one, valid, chunks, ran, overflowed = _HELD_LOADS[load]
     s = _SHARE
+    if onto == "banded":
+        _banded_in_interpret_mode(monkeypatch, (128, 128, 128))
     if chunks > 1:  # a stream of 1,024 rows is a chunk
         monkeypatch.setattr(moe, "_STREAM_ROWS", s["tokens"] * s["k"])
     np_args = _held_case(n_two, n_one, chunks)
@@ -276,11 +396,11 @@ def test_a_held_share_runs_over_a_bounded_buffer_and_drops_nothing(
     grad = jax.jit(jax.grad(layer, argnums=(0, 1, 2, 3, 4), has_aux=True))
     grads, (out, stats) = grad(*args)
     # the whole stream: a buffer as long as the stream itself
-    monkeypatch.setattr(moe, "_held_rows", lambda rows, e, n: rows)
-    whole_grads, (whole, whole_stats) = jax.jit(
-        jax.grad(layer, argnums=(0, 1, 2, 3, 4), has_aux=True)
-    )(*args)
-    monkeypatch.undo()
+    with monkeypatch.context() as whole_form:
+        whole_form.setattr(moe, "_held_rows", lambda rows, e, n: rows)
+        whole_grads, (whole, whole_stats) = jax.jit(
+            jax.grad(layer, argnums=(0, 1, 2, 3, 4), has_aux=True)
+        )(*args)
     held = 2 * min(n_two, g) + n_one if valid.all() else 2 * int(valid.sum())
     assert float(stats.assignments) == float(whole_stats.assignments) == held
     assert float(stats.stream_rows) == ran

@@ -6,14 +6,20 @@ tilings, ``ragged_dot``), all under one ``jax.profiler`` capture.
     python -m tools.microbench_moe [--tokens 4096] [--out chiprun_out/moe_sweep]
     python -m tools.microbench_moe --held 16 --widths 2048 768 128 8
 
-``--held N``: the layer HOLDS the first N of the E experts it routes over
-(one token chunk of a cell: 4,096 tokens of 16 of 128 in the long-row cell,
-``--widths 2304 1024 256 8 --held 8`` in the Kimi cell). The sweep is then
-the expert path over the whole sorted stream against the one over the
-bounded buffer (``ops/moe._run_held``), and the two ways the buffer's rows
-can get back to their tokens, alone: the product with the [G, C] matrix of
-routing weights (``ops/moe._onto_tokens``, the form kept) and the gather of
-every pair's row clamped to one zero row (the form it was weighed against).
+``--held N``: the layer HOLDS the first N of the E experts it routes over (one
+token chunk of a cell: 4,096 tokens of 16 of 128 in the long-row cell,
+``--widths 2304 1024 256 8 --held 8`` in the Kimi cell). The sweep is then the
+expert path over the whole sorted stream against the one over the bounded
+buffer (``ops/moe._run_held``; ``compact_dense``: with the dense token side,
+``compact``: with the platform's), and the three ways the buffer's rows can
+get back to their tokens, alone: the product with the [G, C] matrix of routing
+weights (``side_product``: ``ops/moe._onto_tokens``' dense form, what the CPU
+runs), the gather of every pair's row clamped to one zero row
+(``side_gather``, the form PR 37 weighed it against) and the grouped product
+over token tiles of the slab's rows in token order (``side_banded_<m>_<token
+tile>_<n>``: ``ops/moe.onto_tokens_banded`` over ``ONTO_TILINGS``, the form a
+TPU runs since PR 46), each with its device time and its largest difference
+from the product.
 
 The times are device times of the jitted programs and of the device scopes
 ``moe_route`` / ``moe_dispatch`` / ``moe_experts`` from
@@ -39,6 +45,12 @@ from benchmark import harness, program_trace, xplane
 RUNS = 3
 TILINGS = ((256, 1024, 1024), (128, 1024, 1024), (512, 1024, 1024),
            (512, 512, 1024), (512, 2048, 1024), (1024, 1024, 1024))
+# the banded token side's (slab rows a grid step, token tile, width tile);
+# a width tile of 4096 is the whole width in one
+ONTO_TILINGS = ((256, 256, 1024), (512, 256, 1024), (1024, 256, 1024),
+                (256, 256, 4096), (512, 256, 4096), (1024, 256, 4096),
+                (512, 128, 1024), (512, 128, 4096), (512, 512, 1024),
+                (512, 512, 4096))
 
 
 def main():
@@ -169,7 +181,7 @@ def report(traced, flops, prefixes, longest=0):
 
 def held_sweep(args, x, ws, valid, flops):
     """A layer that holds ``--held`` of its experts: the whole stream
-    against the bounded buffer, and the two candidates for the token side."""
+    against the bounded buffer, and the three candidates for the token side."""
     t, (d, f, e, k) = args.tokens, args.widths
     held = tuple(w[:args.held] for w in ws[1:])
     c = moe._held_rows(t * k // moe._token_chunks(t, k), args.held, e)
@@ -198,9 +210,13 @@ def held_sweep(args, x, ws, valid, flops):
     w = jnp.asarray(rng.random((g, k)), jnp.float32)
 
     def side_product(rows, w, order, tok):
-        return moe._onto_tokens(
-            rows, tok, w.reshape(-1)[order[:c]], g
-        ).astype(bf)
+        real, moe.onto_impl = moe.onto_impl, lambda shape=None: "dense"
+        try:
+            return moe._onto_tokens(
+                rows, tok, w.reshape(-1)[order[:c]], g
+            ).astype(bf)
+        finally:
+            moe.onto_impl = real
 
     def side_gather(rows, w, order, tok):
         inv = jnp.minimum(jnp.argsort(order), c)
@@ -213,18 +229,41 @@ def held_sweep(args, x, ws, valid, flops):
     def side_gather_rows(rows, w, order, tok):  # the buffer's own gather
         return jnp.concatenate([rows, jnp.zeros((1, d), bf)])[tok]
 
+    def banded(tiling):
+        def side(rows, w, order, tok):
+            return moe.onto_tokens_banded(
+                rows, tok, w.reshape(-1)[order[:c]], g, tiling=tiling
+            )
+
+        side.__name__ = "side_banded_%d_%d_%d" % tiling
+        return side
+
     sides = [jax.jit(fn) for fn in
              (side_product, side_gather, side_gather_rows)]
     a, b = (np.asarray(fn(rows, w, order, tok), np.float32)
             for fn in sides[:2])
     harness.log(f"token side, C={c} G={g}: max |product - gather| = "
                 f"{np.abs(a - b).max():.4g} of max {np.abs(b).max():.4g}")
+    for tm, tile, tn in ONTO_TILINGS:
+        fn = jax.jit(banded((tm, tile, min(tn, d))))
+        try:
+            o = np.asarray(fn(rows, w, order, tok), np.float32)
+        except Exception as err:  # noqa: BLE001 — a tiling the chip refuses
+            harness.log(f"{fn.__name__}: refused: {type(err).__name__}: "
+                        f"{str(err)[:300]}")
+            continue
+        sides.append(fn)
+        harness.log(f"{fn.__name__}: max |banded - product| = "
+                    f"{np.abs(o - a).max():.4g} of max {np.abs(a).max():.4g}")
 
-    real_rows = moe._held_rows
+    real_rows, real_onto = moe._held_rows, moe.onto_impl
     progs, outs = {}, {}
-    for name, rows_fn in (("whole", lambda rows_, e_, n_: rows_),
-                          ("compact", real_rows)):
-        moe._held_rows = rows_fn
+    for name, rows_fn, onto in (
+        ("whole", lambda rows_, e_, n_: rows_, real_onto),
+        ("compact_dense", real_rows, lambda shape=None: "dense"),
+        ("compact", real_rows, real_onto),
+    ):
+        moe._held_rows, moe.onto_impl = rows_fn, onto
         progs[name] = variant(name)
         out, stats = progs[name][0](x, ws[0], *held)  # traces here
         jax.block_until_ready(progs[name][1](x, ws[0], *held))
@@ -233,12 +272,14 @@ def held_sweep(args, x, ws, valid, flops):
             f"{fld}={float(v):.0f}" for fld, v in zip(stats._fields, stats)
             if fld != "aux_loss"
         ))
-    moe._held_rows = real_rows
-    harness.log(
-        f"max |compact - whole| = "
-        f"{np.abs(outs['compact'] - outs['whole']).max():.4g} of max "
-        f"{np.abs(outs['whole']).max():.4g}"
-    )
+    moe._held_rows, moe.onto_impl = real_rows, real_onto
+    for name, base in (("compact", "whole"), ("compact_dense", "whole"),
+                       ("compact", "compact_dense")):
+        harness.log(
+            f"max |{name} - {base}| = "
+            f"{np.abs(outs[name] - outs[base]).max():.4g} of max "
+            f"{np.abs(outs[base]).max():.4g}"
+        )
     traced = harness.TracedWindow(True, os.path.join(harness.ROOT, args.out))
     with traced:
         for _ in range(RUNS):
