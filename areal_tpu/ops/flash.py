@@ -23,12 +23,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from areal_tpu.ops import band_attention
 from areal_tpu.ops.basic import segment_attention
 from jax.experimental.pallas.ops.tpu.splash_attention import (
     splash_attention_kernel as _sk,
     splash_attention_mask as _sm,
 )
-
 
 # what a block edge must be a multiple of, and a row a whole number of
 _LANES = 128
@@ -57,13 +57,13 @@ def _tiles(edge: int, fused: bool = True) -> "_sk.BlockSizes":
 
 
 # A window-attention layer's band (``_local_mask`` says which local mask is
-# one; its size does not: 512 keys and 1024 have run): its tile edge, and the
-# SPLIT backward. The
-# fused backward writes a partial dq for every kv block, [T / edge, heads, T,
-# d] in the compute dtype, over a grid it does not shrink to the band: at
-# T = 16384, edge 512 and 64/8 heads of 128 that is 8.6 GB a layer, most of
-# it zeros (the compiler's memory_analysis() of grad_step read 14.9 GB of
-# temporaries with it and 7.6 GB without); the split kernels iterate the
+# one; its size does not) WHERE IT COMES HERE: on a TPU a band of 128-wide
+# heads has kernels of its own (``_strip_band``, at the file's end). Its tile
+# edge, and the SPLIT backward: the fused one writes a partial dq for every
+# kv block, [T / edge, heads, T, d], over a grid it does not shrink to the
+# band: at T = 16384, edge 512 and 64/8 heads of 128 that is 8.6 GB a layer,
+# most of it zeros (the compiler's memory_analysis() of grad_step read 14.9
+# GB of temporaries with it and 7.6 GB without); the split kernels iterate the
 # band's blocks only and write dq once. Measured on the v5e with
 # ``tools/microbench_attn_v2.py --layer-window 512`` on the cell's packed
 # rows, 64/8 heads of 128, forward + backward ms a layer (PERF.md, PR 39):
@@ -231,11 +231,11 @@ def _block_sizes(
       length has a shape that wants another edge by more than that 0.7%;
     - a local window skips only whole tiles, so a PACKING window of at most
       1024 takes edge 512 (1.39 against 1.44 ms at T = 4096, PR 26); a
-      LAYER's window (``band``: the caller says so, ``_local_mask``; 512
-      keys at 64/8 heads of 128 to T = 16384, 1024 keys at 32/4 to
-      T = 32768) takes ``BAND_EDGE`` and the split backward (above; PERF.md,
-      PR 39 and PR 45, have the sweeps; the rows' tile mask is worth 1.7%
-      there, 25.28 against 25.72 ms);
+      LAYER's window (``band``: the caller says so, ``_local_mask``) that
+      comes here at all (``_strip_block``: not 128-wide heads on a TPU)
+      takes ``BAND_EDGE`` and the split backward (above; PERF.md, PR 39 and
+      PR 45, have the sweeps at 64/8 and 32/4 heads of 128; the rows' tile
+      mask is worth 1.7% there, 25.28 against 25.72 ms);
     - past T = 16384 the SPLIT backward, full causal and packing windows
       alike: the fused one's partial dq, [T / edge, heads, T, d] in bf16, is
       4.3 GB a layer at T = 32768, edge 2048 and 32 heads of 128, and
@@ -284,16 +284,16 @@ def _local_mask(t: int, window: int, layer_window: int) -> "tuple[int, bool]":
     return int(window), band
 
 
-def backward_form(
-    t: int, window: int, d: int, layer_window: int = 0
-) -> str:
-    """"fused" | "split": the backward the kernel takes on a row of ``t``
-    tokens under packing ``window`` and a layer's ``layer_window`` (what a
-    trainer's dispatch span says of each core); "" for a row the XLA form
-    takes."""
+def backward_form(t: int, window: int, d: int, layer_window: int = 0) -> str:
+    """"fused" | "split" | "strip": the backward a row of ``t`` tokens takes
+    under packing ``window`` and a layer's ``layer_window`` (what a trainer's
+    dispatch span says of each core): the splash kernel's one kernel or its
+    two, or the band's own; "" for a row the XLA form takes."""
     if t % _LANES:
         return ""
     back, band = _local_mask(t, window, layer_window)
+    if _strip_block(t, back, band, d):
+        return "strip"
     fused = _block_sizes(t, back, d, band).use_fused_bwd_kernel
     return "fused" if fused else "split"
 
@@ -466,15 +466,15 @@ def flash_segment_attention(
     assert causal, "splash path is causal-only (decoder models)"
     b, t, hq, d = q.shape
     if t % _LANES:
-        # the kernel's smallest tile is 128 tokens: a row that is not a
-        # whole number of them (the trainer packs to multiples of 256) is
-        # short, and its quadratic scores are small
+        # not whole tiles of 128 tokens: short, its quadratic scores small
         return segment_attention(
             q, k, v, segment_ids, causal=True, window=layer_window
         )
     window, band = _local_mask(t, window, layer_window)
     hkv = k.shape[2]
     rep = hq // hkv
+    if hq == rep * hkv and _strip_block(t, window, band, d, interpret):
+        return _strip_band(q, k, v, segment_ids, window, interpret)
     kernel = _make_kernel(t, rep, window, d, interpret, band)
     scale = d**-0.5
     qg = (q.astype(jnp.float32) * scale).astype(q.dtype)
@@ -491,3 +491,30 @@ def flash_segment_attention(
     out = out.reshape(b, hq, t, v.shape[-1]).transpose(0, 2, 1, 3)
     valid = (segment_ids > 0)[:, :, None, None]
     return jnp.where(valid, out, 0).astype(q.dtype)
+
+
+def _strip_block(
+    t: int, back: int, band: bool, d: int, interpret: bool = False
+) -> int:
+    """The query block of the band's own kernels (``ops/band_attention.py``)
+    where a call takes them, else 0: a layer's band, on a TPU (or in Pallas
+    interpret mode: the CPU's tests), of a shape those kernels take
+    (``band_attention.strip_block``: heads a multiple of 128 wide, a row that
+    is a whole number of blocks). Every other call keeps the splash kernel."""
+    if not band or not (interpret or jax.default_backend() == "tpu"):
+        return 0
+    return band_attention.strip_block(t, back, d)
+
+
+def _strip_band(q, k, v, segment_ids, back: int, interpret: bool):
+    """A layer's band ``back`` keys behind a query through its own kernels:
+    q, k, v as the projections made them, [T, heads x D], a reshape either
+    side; ``out`` and logsumexp named ``attn_out`` there as the splash
+    kernel names its own."""
+    b, t, hq, d = q.shape
+    hkv = k.shape[2]
+    bq = band_attention.strip_block(t, back, d)
+    return band_attention.band_attention(
+        q.reshape(b, t, hq * d), k.reshape(b, t, hkv * d),
+        v.reshape(b, t, hkv * d), segment_ids, (hq, hkv), back, bq, interpret,
+    ).reshape(q.shape)

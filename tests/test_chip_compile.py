@@ -228,6 +228,51 @@ def test_window_and_full_cores_compile_for_v5e(
     assert temp < (3e9 if band else 6e9), temp
 
 
+# (q heads, key heads, T, the layer's window): the two window families' bands
+# as a TPU sends them (PR 47): through ``ops/band_attention.py``'s strips
+_STRIP_CASES = [
+    pytest.param(64, 8, 16384, 512, id="laguna-h64-w512-t16384"),
+    pytest.param(64, 8, 2048, 512, id="laguna-h64-w512-t2048"),
+    pytest.param(32, 4, 32768, 1024, id="mellum-h32-w1024-t32768"),
+]
+
+
+@pytest.mark.parametrize("hq,hkv,t,layer_window", _STRIP_CASES)
+def test_the_bands_own_kernels_compile_for_v5e(
+    one_chip, monkeypatch, hq, hkv, t, layer_window
+):
+    """On a TPU a layer's band of 128-wide heads takes its own forward and
+    its one backward over key strips, no splash kernel: both compile at the
+    cells' rows, and the backward holds no temporary but the relayouts of
+    its operands (q, dO and dq: 0.27 GB each at Laguna's row)."""
+    import jax
+    import jax.numpy as jnp
+
+    from areal_tpu.ops import flash
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert flash.backward_form(t, 0, 128, layer_window) == "strip"
+    bf = jnp.bfloat16
+
+    def loss(q, k, v, seg):
+        return flash.flash_segment_attention(
+            q, k, v, seg, layer_window=layer_window
+        ).astype(jnp.float32).sum()
+
+    lowered = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        _sds((1, t, hq, 128), bf, one_chip),
+        _sds((1, t, hkv, 128), bf, one_chip),
+        _sds((1, t, hkv, 128), bf, one_chip),
+        _sds((1, t), jnp.int32, one_chip),
+    )
+    text = lowered.as_text()
+    assert "band_strip_fwd" in text and "band_strip_bwd" in text
+    assert "splash_mqa" not in text
+    compiled = lowered.compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+
+
 def test_the_probes_first_candidate_compiles_for_v5e(one_chip):
     """What ``probe_block_size`` tries first: edge 2048, 4 heads of 128
     over one kv head, T = 2048. It needs the raised scoped-VMEM limit."""

@@ -19,6 +19,18 @@ that holds it, at both of its row lengths and both kinds' heads:
 chip at these sizes; the kernel is then held to nothing here, and to the
 reference by the cell's ``window_exact``.)
 
+The band's own kernels over key strips (PERF.md, PR 47:
+``ops/band_attention.py``; the table ``STRIP_BQ`` is from) are ``--backward
+strip`` at each ``--bq`` (forward and backward both: a ``strip_bq*`` variant
+runs no splash kernel), beside the splash kernel's split backward, on the two
+cells' rows; ``chosen`` is what the trainer runs:
+
+    python -m tools.microbench_attn_v2 --t 16384 --heads 64 8 128 \
+        --traffic grpo_steps16_mixed16k --layer-window 512 --windows 0 \
+        --edges 512 --kvc 512 --backward split strip --bq 128 256 512 --no-xla
+    ... --t 32768 --heads 32 4 128 --traffic grpo_steps16_long32k \
+        --layer-window 1024 ...
+
 The tile mask of a row's own borders (PERF.md, PR 42: ``ops/flash._row_kernel``)
 is on in every variant, as in the trainer; ``--static`` also times each variant
 WITHOUT it (``<name>_static``: the kernel as ``_make_kernel`` builds it), and
@@ -54,7 +66,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from areal_tpu.ops import flash
+from areal_tpu.ops import band_attention, flash
 from areal_tpu.ops.flash import _sk as sk
 from areal_tpu.ops.basic import segment_attention
 from areal_tpu.utils import data as data_utils
@@ -81,9 +93,11 @@ def packed_rows(t, traffic="grpo_steps32", n=2):
     ][:n]
 
 
-def variants(t, windows, edges, backward, kvcs):
+def variants(t, windows, edges, backward, kvcs, bqs):
+    """(name, packing window, the splash kernel's tiles or None for the
+    shape's own, the strip kernels' query block or 0 for the splash kernel)."""
     for edge, window, fused, kvc in itertools.product(
-        edges, windows, [b == "fused" for b in backward], kvcs
+        edges, windows, [b == "fused" for b in backward if b != "strip"], kvcs
     ):
         if kvc > edge or (kvc == 256 and edge > 512):
             continue
@@ -93,7 +107,10 @@ def variants(t, windows, edges, backward, kvcs):
             block_q=edge, block_kv=edge, block_kv_compute=kvc,
             block_q_dkv=edge, block_kv_dkv=edge, block_kv_dkv_compute=kvc,
             use_fused_bwd_kernel=fused, **dq,
-        )
+        ), 0
+    if "strip" in backward:
+        for bq in bqs:
+            yield f"strip_bq{bq}", 0, None, bq
 
 
 def main():
@@ -113,7 +130,9 @@ def main():
     ap.add_argument("--dv", type=int, default=0,
                     help="the value heads' width where it is not D")
     ap.add_argument("--backward", nargs="*", default=("fused", "split"),
-                    choices=("fused", "split"))
+                    choices=("fused", "split", "strip"))
+    ap.add_argument("--bq", type=int, nargs="*", default=(128, 256, 512),
+                    help="the strip kernels' query blocks (a layer's band)")
     ap.add_argument("--kvc", type=int, nargs="*", default=(256, 512, 1024),
                     help="block_kv_compute")
     ap.add_argument("--rows", type=int, default=2,
@@ -156,15 +175,21 @@ def main():
 
     todo = []
     chosen, row_kernel = flash._block_sizes, flash._row_kernel
-    swept = list(variants(t, args.windows, args.edges, args.backward, args.kvc))
-    named = ([] if args.no_xla else [("xla", 0, None)]) + swept + [
-        (name + "_static", window, tiles)
-        for name, window, tiles in (swept if args.static else [])
-    ] + [("chosen", 0, None)]
-    for name, window, tiles in named:
+    strip_block, strip_bq = flash._strip_block, band_attention.STRIP_BQ
+    swept = list(variants(
+        t, args.windows, args.edges, args.backward, args.kvc, args.bq
+    ))
+    named = ([] if args.no_xla else [("xla", 0, None, 0)]) + swept + [
+        (name + "_static", window, tiles, bq)
+        for name, window, tiles, bq in (swept if args.static else [])
+    ] + [("chosen", 0, None, None)]
+    for name, window, tiles, bq in named:
         flash._block_sizes = (
             (lambda *_, tiles=tiles, **__: tiles) if tiles else chosen
         )
+        # a swept splash variant stays on the splash kernel; ``chosen`` the rule
+        flash._strip_block = strip_block if bq != 0 else (lambda *_, **__: 0)
+        band_attention.STRIP_BQ = bq or strip_bq
         flash._row_kernel = (
             (lambda kernel, seg: kernel) if name.endswith("_static")
             else row_kernel
@@ -185,16 +210,19 @@ def main():
             print(f"{name}: refused ({type(e).__name__}: {str(e)[:160]})",
                   flush=True)
     flash._block_sizes, flash._row_kernel = chosen, row_kernel
+    flash._strip_block, band_attention.STRIP_BQ = strip_block, strip_bq
     flash._make_kernel.cache_clear()
     ref, masked = None, {}
     # warm; hold the kernel to the XLA form, and the static kernel to the
     # one under the rows' tile mask (which must be the same to the bit)
     for name, fwd, grad in todo:
         outs = [fwd(q, k, v, segs[0]), *grad(q, k, v, segs[0])]
-        if name == "xla":
-            ref = [np.asarray(x, np.float32) for x in outs]
-        elif ref and ("full" in name or name == "chosen"):
-            print(name, "max |delta| out, dq, dk, dv:",
+        unwindowed = "full" in name or name.startswith(("strip", "chosen"))
+        if ref is None and (name == "xla" or unwindowed):
+            # the XLA form, or without it the first variant of the same mask
+            ref, ref_name = [np.asarray(x, np.float32) for x in outs], name
+        elif ref and unwindowed:
+            print(name, f"max |delta| to {ref_name}'s out, dq, dk, dv:",
                   [float(np.abs(np.asarray(a, np.float32) - b).max())
                    for a, b in zip(outs, ref)], flush=True)
         if args.static and name != "chosen":
