@@ -223,6 +223,11 @@ def _hostile(s: str) -> bool:
 
 def _with_timeout(fn, *args):
     global _stuck_count
+    # the interpreter's one import of sympy (seconds on a busy machine) is
+    # the process's cost, not the answer's: outside the timed thread
+    import sympy.parsing.latex  # noqa: F401
+    import sympy.parsing.sympy_parser  # noqa: F401
+
     with _stuck_lock:
         if _stuck_count >= _MAX_STUCK_THREADS:
             return None

@@ -596,6 +596,7 @@ class GenerationEngine:
         self._sweep_tick = 0
         self._paused = threading.Event()
         self._running = False
+        self._loop_error: Optional[BaseException] = None
         self._thread: Optional[threading.Thread] = None
         # device-resident decode state: the generation loop's only host
         # traffic per step is ONE result fetch (tokens+logprobs).
@@ -931,9 +932,33 @@ class GenerationEngine:
         if self._running:
             raise RuntimeError("engine already started")
         self._running = True
-        self._thread = threading.Thread(target=self._loop, daemon=True)
+        self._loop_error = None
+        self._thread = threading.Thread(target=self._run_loop, daemon=True)
         self._thread.start()
         return self
+
+    def _run_loop(self):
+        """The loop thread. An error that ends ``_loop`` (a device failure
+        inside an admission or a decode) fails every request and command
+        that waits on it, and every later ``submit``, with that error: a
+        dead loop used to leave its callers on futures nothing resolves."""
+        try:
+            self._loop()
+        except BaseException as e:  # noqa: BLE001 — handed to the callers
+            logger.exception("engine loop died; failing its callers")
+            self._loop_error = e
+            self._running = False
+            waiting = list(self._active.values()) + list(self._pending)
+            for q in (self._admit_queue, self._command_queue):
+                while True:
+                    try:
+                        waiting.append(q.get_nowait())
+                    except queue.Empty:
+                        break
+            for item in waiting:
+                fut = item[2] if isinstance(item, tuple) else item.future
+                if not fut.done():
+                    fut.set_exception(e)
 
     def stop(self):
         self._running = False
@@ -958,6 +983,9 @@ class GenerationEngine:
     # ------------------------------------------------------------------
     def submit(self, payload: Dict[str, Any]) -> Future:
         fut: Future = Future()
+        if self._loop_error is not None:
+            fut.set_exception(self._loop_error)
+            return fut
         req = _parse_request(payload, fut)
         trace_ctx = payload.get("trace_ctx")
         if trace_ctx:

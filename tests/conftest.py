@@ -8,8 +8,11 @@ jax config, because jax may already be imported by the time this runs and
 then env vars alone are ignored. What runs on the chip is ``chip_smoke.py``.
 """
 
+import faulthandler
 import os
+import signal
 import sys
+import threading
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
@@ -18,6 +21,12 @@ from __graft_entry__ import _ensure_virtual_devices  # noqa: E402
 _ensure_virtual_devices(8)
 
 import pytest  # noqa: E402
+
+# The most one test's setup, call or teardown may take: three times the
+# slowest test of the six-worker run (a CPU rehearsal of the benchmark's,
+# 178-215 s beside five busy workers: PERF.md section 7, ah), which a busy
+# machine does not reach. A constant, not an option.
+TEST_LIMIT_S = 600
 
 
 def pytest_configure(config):
@@ -34,9 +43,11 @@ def pytest_configure(config):
 def pytest_terminal_summary(terminalreporter, exitstatus, config):
     """Tier-1 wall-time budget tracking: the suite runs against a hard
     cap, so every run prints its slowest tests (setup+call+teardown per
-    nodeid) — a PR that regresses the budget is visible in its own CI
-    output, not discovered at the next cap overrun."""
-    durations = {}
+    nodeid) and its costliest FILES: ``--dist loadfile`` hands a file to
+    one worker, so a file's seconds are the least the run's tail can be.
+    A PR that regresses the budget is visible in its own CI output, not
+    discovered at the next cap overrun."""
+    durations, files = {}, {}
     for reports in terminalreporter.stats.values():
         for rep in reports:
             d = getattr(rep, "duration", None)
@@ -44,17 +55,55 @@ def pytest_terminal_summary(terminalreporter, exitstatus, config):
             if d is None or not nodeid:
                 continue
             durations[nodeid] = durations.get(nodeid, 0.0) + d
+            path = nodeid.split("::")[0]
+            files[path] = files.get(path, 0.0) + d
     if not durations:
         return
-    top = sorted(durations.items(), key=lambda kv: -kv[1])[:10]
-    total = sum(durations.values())
     tr = terminalreporter
-    tr.write_sep("=", "slowest tests (tier-1 budget report)")
-    for nodeid, d in top:
-        tr.write_line(f"{d:8.2f}s  {nodeid}")
+    for title, table in (("tests", durations), ("files", files)):
+        tr.write_sep("=", f"slowest {title} (tier-1 budget report)")
+        for name, d in sorted(table.items(), key=lambda kv: -kv[1])[:10]:
+            tr.write_line(f"{d:8.2f}s  {name}")
     tr.write_line(
-        f"{total:8.2f}s  total across {len(durations)} tests"
+        f"{sum(durations.values()):8.2f}s  total across {len(durations)} "
+        f"tests in {len(files)} files"
     )
+
+
+def _fail_at_the_limit(signum, frame):
+    faulthandler.dump_traceback(file=sys.stderr, all_threads=True)
+    pytest.fail(
+        f"still running after {TEST_LIMIT_S} s (the limit of one test's "
+        "setup, call or teardown: tests/conftest.py); every thread's stack "
+        "is in the captured stderr",
+        pytrace=True,
+    )
+
+
+@pytest.hookimpl(hookwrapper=True, tryfirst=True)
+def pytest_runtest_setup(item):
+    """One phase of one test under ``TEST_LIMIT_S``: at the limit the phase
+    fails with the stack it was in (``Failed`` is no ``Exception``: a retry
+    loop's ``except Exception`` does not swallow it), its fixtures tear
+    down, and the run goes on to the next test. What holds the interpreter
+    inside C code and never returns is not interrupted by a signal: the
+    worker dumps its stacks and exits a minute past the limit, and xdist
+    reports the test as the one that took it down."""
+    if threading.current_thread() is not threading.main_thread():
+        yield
+        return
+    was = signal.signal(signal.SIGALRM, _fail_at_the_limit)
+    signal.setitimer(signal.ITIMER_REAL, TEST_LIMIT_S)
+    faulthandler.dump_traceback_later(TEST_LIMIT_S + 60, exit=True)
+    try:
+        yield
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        faulthandler.cancel_dump_traceback_later()
+        signal.signal(signal.SIGALRM, was)
+
+
+pytest_runtest_call = pytest_runtest_teardown = pytest_runtest_setup
 
 
 @pytest.fixture(scope="session", autouse=True)

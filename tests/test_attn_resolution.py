@@ -6,7 +6,7 @@ attention_fn``, where each kernel's mesh form lives) and the dispatch spans.
 The backend is the CPU here; "reported as a TPU" means ``jax.default_backend``
 is steered in the test (and the block probe with it: it would run the kernel).
 Nothing is compiled for the kernel in this file; its numbers are in
-``test_flash_parity.py``, its compile for the chip in ``test_chip_compile.py``.
+``test_flash_parity.py``, its compile for the chip in ``test_chip_compile_attention.py``.
 """
 
 import dataclasses

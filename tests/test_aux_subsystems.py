@@ -159,19 +159,14 @@ class TestLauncher:
         from areal_tpu.launcher.local import JobException, LocalLauncher
 
         l = LocalLauncher("e", "t", str(tmp_path))
-        l.submit("ok", [sys.executable, "-c", "print('hi')"])
-        l.submit("bad", [sys.executable, "-c", "import sys; sys.exit(3)"])
-        deadline = time.monotonic() + 20
-        exc = None
-        while time.monotonic() < deadline:
-            exc = l.poll()
-            if exc is not None:
-                break
-            time.sleep(0.1)
+        ok = l.submit("ok", [sys.executable, "-c", "print('hi')"])
+        bad = l.submit("bad", [sys.executable, "-c", "import sys; sys.exit(3)"])
+        # on the processes' own exits, not on a clock: ``stop_all`` right
+        # after "bad" left used to TERM an "ok" that had not printed yet
+        assert bad.wait(timeout=120) == 3 and ok.wait(timeout=120) == 0
+        exc = l.poll()
         assert isinstance(exc, JobException) and exc.name == "bad"
+        assert l.finished("ok") and not l.alive("bad")
         l.stop_all()
         log = os.path.join(str(tmp_path), "e", "t", "logs", "ok.log")
-        deadline = time.monotonic() + 5
-        while not os.path.exists(log) and time.monotonic() < deadline:
-            time.sleep(0.1)
         assert "hi" in open(log).read()

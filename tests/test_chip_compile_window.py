@@ -1,0 +1,121 @@
+"""The window and full cores of the 16,384- and 32,768-token rows and the
+selected-key attention, compiled for a described v5e: nothing runs
+(``chip_compile_fixture`` says what these check)."""
+
+import re
+
+import pytest
+
+from chip_compile_fixture import _sds, one_chip  # noqa: F401
+
+
+# (q heads, T, the layer's window, edge, fused backward): Laguna-XS.2's two
+# rotary kinds over 8 key heads of 128 at the cell's 16384-token row and the
+# 8192-token rung: the window layers' band (its own tiles and the split
+# backward, ``flash.BAND_EDGE``) and the full layers' causal core
+_LAGUNA_CASES = [
+    pytest.param(64, 8, 16384, 512, True, id="swa-h64-t16384"),
+    pytest.param(64, 8, 8192, 512, True, id="swa-h64-t8192"),
+    pytest.param(48, 8, 16384, 0, False, id="full-h48-t16384"),
+    pytest.param(48, 8, 8192, 0, False, id="full-h48-t8192"),
+    # Mellum2's 32 / 4 heads at its cell's 32768-token row: a 1024-key band
+    # is a band by KIND (PR 45), and past 16384 tokens the full core takes
+    # the split backward too
+    pytest.param(32, 4, 32768, 1024, True, id="swa-h32-w1024-t32768"),
+    pytest.param(32, 4, 32768, 0, False, id="full-h32-t32768"),
+]
+
+
+@pytest.mark.parametrize("hq,hkv,t,layer_window,band", _LAGUNA_CASES)
+def test_window_and_full_cores_compile_for_v5e(
+    one_chip, monkeypatch, hq, hkv, t, layer_window, band
+):
+    """fwd+bwd of both attention cores of the two window families at the
+    published heads: the band's kernel instance is its own (``LocalMask``
+    ``layer_window - 1`` back, the split backward: no partial dq a kv
+    block), the full core's is the shape's."""
+    import jax
+    import jax.numpy as jnp
+
+    from areal_tpu.ops import flash
+
+    monkeypatch.setattr(flash, "_PROBED_BLOCK", 2048)
+    flash._make_kernel.cache_clear()
+    bf = jnp.bfloat16
+
+    def loss(q, k, v, seg):
+        return flash.flash_segment_attention(
+            q, k, v, seg, layer_window=layer_window
+        ).astype(jnp.float32).sum()
+
+    try:
+        lowered = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            _sds((1, t, hq, 128), bf, one_chip),
+            _sds((1, t, hkv, 128), bf, one_chip),
+            _sds((1, t, hkv, 128), bf, one_chip),
+            _sds((1, t), jnp.int32, one_chip),
+        )
+        compiled = lowered.compile()
+    finally:
+        flash._make_kernel.cache_clear()
+    tiles = flash._block_sizes(
+        t, layer_window - 1 if band else 0, 128, band
+    )
+    full_edge = 2048 if t > 8192 else 1024
+    assert tiles.block_q == (flash.BAND_EDGE if band else full_edge)
+    split = band or t > 16384
+    assert tiles.use_fused_bwd_kernel == (not split)
+    text = lowered.as_text()
+    assert ("splash_mqa_dq" in text) == split  # the split backward's own dq
+    assert "tpu_custom_call" in compiled.as_text()
+    # the fused backward's partial dq a kv block would be [T / edge, rep, T,
+    # 128] bf16 a key head: 8.6 GB at the cell's row; the split one has none
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < (3e9 if band else 6e9), temp
+
+
+def test_sparse_attention_fwd_bwd_compiles_for_v5e(one_chip, monkeypatch):
+    """``ops/sparse_attention.sparse_attention`` forward and backward at
+    Keye-VL-2.0's published widths (32 / 4 heads of 128, an indexer of 16
+    heads of 64, topk 2048) on one 8192-token row: the index in XLA, the
+    selection's counting kernel (``dsa_select_kth``, once for each of the
+    three query chunks that end past 2048 keys: 4096, 6144 and 8192 keys
+    wide, 64 queries' keys in VMEM), then the three Pallas kernels of the
+    core (``dsa_core_fwd``, ``dsa_core_dq``, ``dsa_core_dkv``) over int8
+    tiles of the selection, all the query heads of a kv head a grid step.
+    No [T, T] float32 array in the compiled program.
+    ``jax.default_backend()`` is the CPU here, so the platform's choice of
+    the selection's form is set in the test."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from areal_tpu.ops import sparse_attention as sa
+
+    monkeypatch.setattr(sa, "select_impl", lambda shape=None: "kernel")
+    t, bf = 8192, jnp.bfloat16
+
+    def loss(q, k, v, seg, qi, ki, w):
+        out, stats = sa.sparse_attention(
+            q, k, v, seg, qi, ki, w, topk=2048, core="kernel"
+        )
+        return out.astype(jnp.float32).sum(), stats
+
+    compiled = (
+        jax.jit(jax.grad(loss, argnums=(0, 1, 2), has_aux=True))
+        .lower(
+            _sds((1, t, 32, 128), bf, one_chip),
+            _sds((1, t, 4, 128), bf, one_chip),
+            _sds((1, t, 4, 128), bf, one_chip),
+            _sds((1, t), jnp.int32, one_chip),
+            _sds((1, t, 16, 64), bf, one_chip),
+            _sds((1, t, 64), bf, one_chip),
+            _sds((1, t, 16), bf, one_chip),
+        )
+        .compile()
+    )
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 6
+    assert len(re.findall(r"dsa_select_kth", text)) >= 3
+    assert not re.search(rf"f32\[[0-9,]*{t},{t}[0-9,]*\]", text)

@@ -4,63 +4,23 @@ process, then remote greedy generation matches a local engine holding the
 trainer's weights (the true multi-host semantics of the reference's NCCL
 trainer->server broadcast, fsdp_engine.py:414-444 + sglang_remote.py:411)."""
 
-import os
-import queue
-import subprocess
 import sys
-import threading
-import time
 
 import pytest
 
 import jax
 
+from tests import fixtures
+
 
 @pytest.fixture(scope="module")
 def remote_server():
-    worker = os.path.join(os.path.dirname(__file__), "genserver_worker.py")
-    proc = subprocess.Popen(
-        [sys.executable, worker, "0"],
-        stdin=subprocess.PIPE,
-        stdout=subprocess.PIPE,
-        stderr=subprocess.STDOUT,
-        text=True,
-    )
-    # a reader thread drains stdout for the worker's whole life: readline
-    # with a timeout needs it anyway, and an undrained pipe would block
-    # the worker's logging mid-test once the buffer fills
-    lines: "queue.Queue[str]" = queue.Queue()
-
-    def drain():
-        for line in proc.stdout:
-            lines.put(line)
-
-    threading.Thread(target=drain, daemon=True).start()
-
-    port = None
-    deadline = time.monotonic() + 180
-    try:
-        while time.monotonic() < deadline:
-            if proc.poll() is not None:
-                raise RuntimeError("server process died during startup")
-            try:
-                line = lines.get(timeout=1.0)
-            except queue.Empty:
-                continue
-            if line.startswith("PORT "):
-                port = int(line.split()[1])
-                break
-        if port is None:
-            raise RuntimeError("server never reported its port")
-    except Exception:
-        proc.kill()
-        raise
-    yield f"127.0.0.1:{port}"
-    proc.stdin.close()
-    try:
-        proc.wait(timeout=30)
-    except subprocess.TimeoutExpired:
-        proc.kill()
+    with fixtures.children() as procs:
+        proc, lines = fixtures.spawn_worker(
+            [sys.executable, fixtures.GENSERVER_WORKER, "0"]
+        )
+        procs.append(proc)
+        yield f"127.0.0.1:{fixtures.wait_port(proc, lines, 180)}"
 
 
 def test_streamed_update_reaches_other_process(remote_server):

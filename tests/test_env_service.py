@@ -10,7 +10,6 @@ a trajectory + final reward bit-identical to an uninterrupted run
 import asyncio
 import json
 import os
-import subprocess
 import sys
 import threading
 import time
@@ -21,6 +20,8 @@ import numpy as np
 import pytest
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
+
+from tests import fixtures  # noqa: E402
 
 from areal_tpu.api.cli_args import (
     DurabilityConfig,
@@ -52,42 +53,6 @@ CFG = EnvServiceConfig(
 
 
 # ------------------------------------------------------------------ helpers
-def _spawn_worker(env_extra=None, enable_chaos=False):
-    """One real env-worker subprocess hosting the countdown tool env;
-    returns (proc, 'host:port')."""
-    cmd = [
-        sys.executable, "-m", "areal_tpu.env.service",
-        "--env", "areal_tpu.env.service:countdown_env", "--port", "0",
-    ]
-    if enable_chaos:
-        cmd.append("--enable-chaos")
-    env = dict(os.environ)
-    if env_extra:
-        env.update(env_extra)
-    proc = subprocess.Popen(
-        cmd, stdin=subprocess.PIPE, stdout=subprocess.PIPE,
-        stderr=subprocess.STDOUT, text=True, env=env,
-    )
-    deadline = time.monotonic() + 60
-    while time.monotonic() < deadline:
-        line = proc.stdout.readline()
-        if line.startswith("PORT "):
-            return proc, f"127.0.0.1:{int(line.split()[1])}"
-        if proc.poll() is not None:
-            raise RuntimeError(f"env worker died at startup: {line!r}")
-    proc.kill()
-    raise RuntimeError("env worker never reported a port")
-
-
-def _reap(proc):
-    if proc.poll() is None:
-        try:
-            proc.stdin.close()
-            proc.wait(timeout=10)
-        except Exception:
-            proc.kill()
-
-
 class _ScriptedEngine:
     """Deterministic engine: pops scripted completions (the
     test_agentic_countdown idiom)."""
@@ -439,11 +404,14 @@ def test_kill_one_of_two_env_workers_bit_identical_episode():
     # two live workers: the victim dies on its 3rd /step, the survivor
     # doubles as the baseline host (sessions are independent, so the
     # uninterrupted run beforehand shares it without interference)
-    victim_proc, victim_addr = _spawn_worker(
-        {"AREAL_CHAOS": "kill:side=server,match=/step,start=2"}
-    )
-    surv_proc, surv_addr = _spawn_worker()
-    try:
+    with fixtures.children() as procs:
+        victim_proc, victim_addr = fixtures.spawn_env_worker(
+            "countdown_env",
+            {"AREAL_CHAOS": "kill:side=server,match=/step,start=2"},
+        )
+        procs.append(victim_proc)
+        surv_proc, surv_addr = fixtures.spawn_env_worker("countdown_env")
+        procs.append(surv_proc)
         base_envs = []
         baseline = _agentic_episode([surv_addr], base_envs)
         assert baseline is not None
@@ -454,9 +422,6 @@ def test_kill_one_of_two_env_workers_bit_identical_episode():
         chaos_envs = []
         batch = _agentic_episode([victim_addr, surv_addr], chaos_envs)
         assert victim_proc.poll() is not None, "chaos kill never fired"
-    finally:
-        _reap(victim_proc)
-        _reap(surv_proc)
 
     # zero lost rollouts: the episode completed, exactly one replay
     assert batch is not None

@@ -10,16 +10,15 @@ router fronting the pair must both reflect the event
 import asyncio
 import json
 import os
-import queue
-import subprocess
 import sys
-import threading
 import time
 import urllib.request
 
 import pytest
 
 import jax
+
+from tests import fixtures
 
 # dies on its 3rd /generate: with 4 concurrent rollouts round-robined
 # 2-per-server, calls 1+2 are its two rids' FIRST chunks (both issued at
@@ -38,35 +37,7 @@ def _spawn_worker(env_extra=None):
     env["AREAL_WORKER_READY_QUIET"] = "0.01"
     if env_extra:
         env.update(env_extra)
-    proc = subprocess.Popen(
-        [sys.executable, worker, "0"],
-        stdin=subprocess.PIPE,
-        stdout=subprocess.PIPE,
-        stderr=subprocess.STDOUT,
-        text=True,
-        env=env,
-    )
-    lines: "queue.Queue[str]" = queue.Queue()
-
-    def drain():
-        for line in proc.stdout:
-            lines.put(line)
-
-    threading.Thread(target=drain, daemon=True).start()
-    return proc, lines
-
-
-def _wait_port(proc, lines, deadline):
-    while time.monotonic() < deadline:
-        if proc.poll() is not None:
-            raise RuntimeError("server process died during startup")
-        try:
-            line = lines.get(timeout=1.0)
-        except queue.Empty:
-            continue
-        if line.startswith("PORT "):
-            return int(line.split()[1])
-    raise RuntimeError("server never reported its port")
+    return fixtures.spawn_worker([sys.executable, worker, "0"], env)
 
 
 @pytest.fixture(scope="module")
@@ -86,21 +57,14 @@ def survivor_server():
 
     def get_addr() -> str:
         if "addr" not in holder:
-            sport = _wait_port(survivor, slines, time.monotonic() + 240)
+            sport = fixtures.wait_port(survivor, slines)
             holder["addr"] = f"127.0.0.1:{sport}"
         return holder["addr"]
 
-    yield get_addr
-    _reap(survivor)
-
-
-def _reap(proc):
-    if proc.poll() is None:
-        try:
-            proc.stdin.close()
-            proc.wait(timeout=15)
-        except Exception:
-            proc.kill()
+    try:
+        yield get_addr
+    finally:
+        fixtures.reap(survivor)
 
 
 def _victim_and_survivor(env_extra, survivor_getter):
@@ -108,9 +72,9 @@ def _victim_and_survivor(env_extra, survivor_getter):
     the (possibly still starting) shared survivor."""
     victim, vlines = _spawn_worker(env_extra)
     try:
-        vport = _wait_port(victim, vlines, time.monotonic() + 240)
+        vport = fixtures.wait_port(victim, vlines)
         survivor_addr = survivor_getter()
-    except Exception:
+    except BaseException:  # the per-test limit raises no Exception
         victim.kill()
         raise
     return victim, f"127.0.0.1:{vport}", survivor_addr
@@ -123,8 +87,10 @@ def two_servers(survivor_server):
     victim, victim_addr, survivor_addr = _victim_and_survivor(
         {"AREAL_CHAOS": VICTIM_CHAOS}, survivor_server
     )
-    yield victim_addr, survivor_addr
-    _reap(victim)
+    try:
+        yield victim_addr, survivor_addr
+    finally:
+        fixtures.reap(victim)
 
 
 PROMPTS = [[7, 6, 5, 4], [1, 2, 3], [9, 8, 7], [2, 4, 6, 8]]
@@ -290,8 +256,10 @@ def lineage_servers(survivor_server):
         },
         survivor_server,
     )
-    yield victim_addr, survivor_addr
-    _reap(victim)
+    try:
+        yield victim_addr, survivor_addr
+    finally:
+        fixtures.reap(victim)
 
 
 @pytest.mark.chaos

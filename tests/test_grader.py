@@ -20,6 +20,18 @@ from areal_tpu.evaluation.grader import (
 )
 
 
+@pytest.fixture(scope="module", autouse=True)
+def _sympy_is_warm():
+    """A symbolic check has 3 s of wall clock. The first one of a process
+    also imports sympy's parsers and makes their first parse, which beside
+    five busy workers took more than that and read as "not equal"
+    (``[-0.25--1/4-True]`` is the first case of this file that needs
+    sympy): made here, untimed."""
+    from areal_tpu.evaluation import grader
+
+    assert grader._parse_sym("-1/4") is not None
+
+
 def test_family_registry_complete():
     names = [n for n, _ in FAMILIES]
     assert names == [

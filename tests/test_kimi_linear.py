@@ -5,56 +5,53 @@ kind, the logits, the loss's gradient of every parameter group, packing, the
 share of the experts, and what the change leaves alone (models of one block
 kind, to the bit)."""
 
-import dataclasses
-import json
-import os
-import sys
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import family_battery as fb
 from areal_tpu.models import transformer as tr
 from areal_tpu.models.config import from_hf_config, tiny_config
 from areal_tpu.ops import moe as moe_ops
+from benchmark import reference_kimi as ref
+from benchmark.kinds import train_steps_kda
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, ROOT)
-from benchmark import reference_kimi as ref  # noqa: E402
-from benchmark.kinds import train_steps_kda  # noqa: E402
-
-CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
+CUT = "kimi-linear-48b-a3b.json"
+TOTAL = 130
 
 
-@pytest.fixture(scope="module")
-def tiny():
-    cfg, keys = train_steps_kda.tiny_configs()
-    params = tr.init_params(cfg, jax.random.PRNGKey(4), dtype=jnp.float32)
-    # a correction bias that matters, and decays off their initial values
+def _seeded(cfg, seed):
+    """A correction bias that matters."""
+    params = fb.init(cfg, seed)
     rng = np.random.default_rng(2)
-    for kind, group in params["layers"].items():
+    for group in params["layers"].values():
         if "router_bias" in group:
-            group["router_bias"] = jnp.asarray(
-                rng.standard_normal(group["router_bias"].shape) * 0.2,
-                jnp.float32,
-            )
-    return cfg, keys, params
+            group["router_bias"] = (
+                rng.standard_normal(group["router_bias"].shape) * 0.2
+            ).astype(np.float32)
+    return params
 
 
-def _row(lens, total, vocab, seed=0):
-    rng = np.random.default_rng(seed)
-    tok = np.zeros((1, total), np.int32)
-    seg = np.zeros((1, total), np.int32)
-    pos = np.zeros((1, total), np.int32)
-    seqs, at = [], 0
-    for i, n in enumerate(lens):
-        s = rng.integers(0, vocab, n).astype(np.int32)
-        tok[0, at:at + n], seg[0, at:at + n] = s, i + 1
-        pos[0, at:at + n] = np.arange(n)
-        seqs.append(s)
-        at += n
-    return jnp.asarray(tok), jnp.asarray(seg), jnp.asarray(pos), seqs
+def _reference_logits(keys, params, tokens):
+    """``ref.logits`` of traced tokens. ``ref._run`` looks its embedding's
+    rows up with ``np.asarray(tokens)``, which no tracer survives, and jits a
+    fresh lambda a layer a call; so its lines around ``ref.layer``, the
+    largest function of it that traces, are restated here (the rehearsal in
+    ``tests/benchmark_suite`` runs ``ref.logits`` itself)."""
+    x = ref._f32(params["embedding"])[tokens]
+    for kind, i in ref.layer_kinds(keys):
+        x, _ = ref.layer(keys, kind, x, fb.layer_of(params, kind, i))
+    x = ref._rms_norm(x, ref._f32(params["final_norm"]), keys["rms_norm_eps"])
+    return x @ ref._f32(params["lm_head"])
+
+
+FAMILY = fb.Family(
+    "kimi_linear", train_steps_kda.tiny_configs, _reference_logits, _seeded,
+    total=TOTAL,
+)
+tiny = fb.tiny_fixture(FAMILY)
+trained = fb.trained_fixture()
 
 
 def test_tiny_config_is_the_family_and_the_catalogs_config_builds():
@@ -62,10 +59,7 @@ def test_tiny_config_is_the_family_and_the_catalogs_config_builds():
     assert set(t.layer_kinds) == {"kda+dense", "kda+moe", "mla+moe"}
     assert (t.num_held_experts, t.num_experts, t.kda.num_heads) == (8, 16, 2)
     assert t.router_act == "sigmoid" and not t.shared_expert_gate
-    with open(os.path.join(
-        ROOT, "benchmark", "configs", "kimi-linear-48b-a3b.json"
-    )) as f:
-        cut = from_hf_config(json.load(f))
+    cut = from_hf_config(fb.cut(CUT))
     assert cut.layer_kinds == (
         "kda+dense", "kda+moe", "kda+moe", "mla+moe", "kda+moe"
     )
@@ -73,11 +67,8 @@ def test_tiny_config_is_the_family_and_the_catalogs_config_builds():
         256, 8, 20480
     )
     assert cut.attn_head_dims == (192, 128) and cut.num_kda_layers == 4
-    if not os.path.exists(CATALOG):
-        pytest.skip("no catalog beside the guide")
-    with open(CATALOG) as f:
-        row = next(json.loads(line) for line in f if "Kimi-Linear" in line)
-    full = from_hf_config(row["config"])  # the published keys, unchanged
+    # the published keys, unchanged
+    full = from_hf_config(fb.published("Kimi-Linear-48B-A3B-Instruct"))
     assert (full.num_layers, full.num_experts, full.experts_held) == (
         27, 256, 0
     )
@@ -92,89 +83,55 @@ def test_tiny_config_is_the_family_and_the_catalogs_config_builds():
     ({"num_expert_group": 8}, "num_expert_group"),
 ])
 def test_what_the_family_may_say_and_the_program_lacks_is_refused(bad, word):
-    with open(os.path.join(
-        ROOT, "benchmark", "configs", "kimi-linear-48b-a3b.json"
-    )) as f:
-        d = dict(json.load(f), **bad)
-    with pytest.raises(ValueError, match=word):
-        from_hf_config(d)
+    fb.check_refused(CUT, bad, word)
 
 
 @pytest.mark.parametrize("kind", ["kda+dense", "kda+moe", "mla+moe"])
 def test_each_block_kind_matches_the_reference(tiny, kind):
-    cfg, keys, params = tiny
-    lp = {k: v[0] for k, v in params["layers"][kind].items()}
-    rng = np.random.default_rng(1)
-    x = jnp.asarray(rng.standard_normal((1, 37, cfg.hidden_size)), jnp.float32)
-    seg = jnp.ones((1, 37), jnp.int32)
-    got, stats = jax.jit(
-        lambda x, lp: tr._block_body(cfg, kind, x, lp, seg)
-    )(x, lp)
-    with jax.default_matmul_precision("highest"):
-        want, _ = ref.layer(keys, kind, x[0], lp)
-    np.testing.assert_allclose(got[0], want, atol=2e-5)
+    stats = fb.check_block(
+        tiny, kind, lambda x, lp: ref.layer(tiny.keys, kind, x, lp)[0]
+    )
     # a layer reports (its FFN's, its mixer's)
     assert (stats[1] is not None) == kind.startswith("kda")
     assert (stats[0] is not None) == kind.endswith("moe")
 
 
 def test_logits_match_alone_and_packed_with_padding(tiny):
-    cfg, keys, params = tiny
-    tok, seg, pos, seqs = _row([70, 33, 21], 130, cfg.vocab_size)
-    got, stats = jax.jit(
-        lambda p: tr.apply(p, cfg, tok, seg, pos, return_stats=True)
-    )(params)
-    at = 0
-    for s in seqs:  # borders fall inside a chunk and inside the conv's reach
-        np.testing.assert_allclose(
-            got[0, at:at + len(s)], ref.logits(keys, params, s), atol=5e-5
-        )
-        at += len(s)
+    # borders fall inside a chunk and inside the conv's reach
+    _, stats, _ = fb.check_logits(FAMILY, tiny, [70, 33, 21])
     assert [int(stats.counts[k]) for k in (
         "kda_tokens", "kda_chunk_slots", "kda_segments"
     )] == [124 * 3, 192 * 3, 3 * 3]
 
 
 def test_loss_and_every_gradient_match_the_references(tiny):
-    cfg, keys, params = tiny
-    tok, seg, pos, seqs = _row([45], 45, cfg.vocab_size, seed=3)
-    w = jnp.cos(jnp.arange(45 * cfg.vocab_size).reshape(45, -1) * 0.01)
+    def weighted(seq):
+        n = len(seq)
+        w = np.cos(
+            np.arange(n * tiny.cfg.vocab_size).reshape(n, -1) * 0.01
+        ).astype(np.float32)
+        return lambda logits: (logits[:n] * w).sum()
 
-    def program(p):
-        return (tr.apply(p, cfg, tok, seg, pos, remat=True)[0] * w).sum()
+    def never(g, w):  # it moves a choice, never a value
+        assert not np.asarray(w).any() and not np.asarray(g).any()
 
-    def reference(p):
-        return (ref.logits(keys, p, seqs[0]) * w).sum()
-
-    got_v, got = jax.jit(jax.value_and_grad(program))(params)
-    want_v, want = jax.value_and_grad(reference)(params)
-    assert abs(float(got_v - want_v)) < 1e-3 * abs(float(want_v)) + 1e-4
-    flat_got = jax.tree_util.tree_leaves_with_path(got)
-    flat_want = jax.tree_util.tree_leaves(want)
-    seen = set()
-    for (path, g), w_ in zip(flat_got, flat_want):
-        name = jax.tree_util.keystr(path)
-        scale = float(jnp.abs(w_).max())
-        if "router_bias" in name:  # it moves a choice, never a value
-            assert scale == 0.0 and not np.asarray(g).any(), name
-            continue
-        assert scale > 0, name
-        assert float(jnp.abs(g - w_).max()) <= 2e-3 * scale, name
-        seen.add(name.split("'")[-2])
+    seen = fb.check_gradients(
+        FAMILY, tiny, weighted, n=45, value_tol=(1e-3, 1e-4),
+        special={"router_bias": never},
+    )
     assert {"a_log", "dt_bias", "w_f_a", "w_f_b", "w_g_a", "w_g_b", "conv_q",
             "conv_k", "conv_v", "w_beta", "o_norm", "w_kv_a", "w_kv_b",
-            "kv_norm", "w_shared_gate", "w_router"} <= seen
+            "kv_norm", "w_shared_gate", "w_router"} <= set(seen)
 
 
 def test_the_short_convolution_stops_at_a_sequences_first_token():
-    x = jnp.asarray(np.random.default_rng(0).standard_normal((1, 12, 3)))
-    w = jnp.asarray([[1.0, 2.0, 3.0, 4.0]] * 3)
-    seg = jnp.asarray([[1] * 5 + [2] * 5 + [0] * 2])
-    got = tr._short_conv(x, w, seg)
+    x = fb.normal(0, 1, 12, 3)
+    w = np.asarray([[1.0, 2.0, 3.0, 4.0]] * 3, np.float32)
+    seg = np.asarray([[1] * 5 + [2] * 5 + [0] * 2], np.int32)
+    got = jax.jit(tr._short_conv)(x, w, seg)
+    alone = jax.jit(ref._conv)  # both sequences are 5 tokens: one shape
     for a, b in ((0, 5), (5, 10)):
-        np.testing.assert_allclose(
-            got[0, a:b], ref._conv(x[0, a:b].astype(jnp.float32), w), atol=1e-6
-        )
+        np.testing.assert_allclose(got[0, a:b], alone(x[0, a:b], w), atol=1e-6)
 
 
 @pytest.mark.parametrize("t", [128, 256])
@@ -186,16 +143,16 @@ def test_the_latent_core_at_192_over_128_through_the_kernel(t):
     from areal_tpu.ops.flash import flash_segment_attention, takes_head_dim
 
     assert takes_head_dim(192, 128) and not takes_head_dim(192, 64)
-    rng = np.random.default_rng(t)
-    q, k = (jnp.asarray(rng.standard_normal((1, t, 2, 192)), jnp.float32)
-            for _ in range(2))
-    v = jnp.asarray(rng.standard_normal((1, t, 2, 128)), jnp.float32)
-    seg = jnp.asarray([[1] * (t // 2) + [2] * (t // 4) + [0] * (t // 4)])
+    q, k = (fb.normal(t + i, 1, t, 2, 192) for i in range(2))
+    v = fb.normal(t + 2, 1, t, 2, 128)
+    seg = np.asarray(
+        [[1] * (t // 2) + [2] * (t // 4) + [0] * (t // 4)], np.int32
+    )
 
     def both(fn):
-        return jax.value_and_grad(
+        return jax.jit(jax.value_and_grad(
             lambda *a: (fn(*a, seg) ** 2).sum(), argnums=(0, 1, 2)
-        )(q, k, v)
+        ))(q, k, v)
 
     want = both(segment_attention)
     got = both(lambda *a: flash_segment_attention(*a, interpret=True))
@@ -230,40 +187,10 @@ def test_the_shares_of_the_experts_add_up_to_the_uncut_layer(tiny):
     """What ties the share to the model: the two holders of 8 of the 16
     experts route over all 16 with the same router and bias; their parts,
     with the shared expert counted ONCE, are the uncut reference's layer."""
-    cfg, keys, _ = tiny
-    whole_cfg = dataclasses.replace(cfg, experts_held=0)
-    params = tr.init_params(whole_cfg, jax.random.PRNGKey(9), jnp.float32)
-    lp = {k: v[0] for k, v in params["layers"]["kda+moe"].items()}
-    lp["router_bias"] = jnp.linspace(-0.3, 0.3, 16)
-    rng = np.random.default_rng(5)
-    h = jnp.asarray(rng.standard_normal((1, 29, cfg.hidden_size)), jnp.float32)
-    with jax.default_matmul_precision("highest"):
-        want = ref.experts(keys, h[0], lp) + ref.shared_expert(h[0], lp)
-    total = np.asarray(moe_ops.shared_expert_from_params(cfg, lp, h))[0]
-    pairs = 0
-    for first in (0, 8):
-        held = slice(first, first + 8)
-        part, stats = moe_ops.moe_ffn(
-            h, lp["w_router"], lp["w_gate"][held], lp["w_up"][held],
-            lp["w_down"][held], cfg.num_experts_per_tok, cfg.norm_topk_prob,
-            first_expert=first, router_act=cfg.router_act,
-            router_bias=lp["router_bias"],
-            routed_scaling_factor=cfg.routed_scaling_factor,
-        )
-        total = total + np.asarray(part)[0]
-        pairs += int(stats.assignments)
-    np.testing.assert_allclose(total, want, atol=1e-5)
-    assert pairs == 29 * cfg.num_experts_per_tok
-    # and the layer's own call is one holder's part plus the shared expert
-    own, _ = moe_ops.moe_ffn_from_params(
-        cfg, dict(lp, **{k: lp[k][:8] for k in ("w_gate", "w_up", "w_down")}), h
+    fb.check_shares_add_up(
+        FAMILY, tiny, "kda+moe", ref.experts, ref.shared_expert,
+        router_bias=np.linspace(-0.3, 0.3, 16).astype(np.float32),
     )
-    with jax.default_matmul_precision("highest"):
-        first_part = ref.experts(
-            keys, h[0], dict(lp, **{k: lp[k][:8]
-                                    for k in ("w_gate", "w_up", "w_down")})
-        ) + ref.shared_expert(h[0], lp)
-    np.testing.assert_allclose(own[0], first_part, atol=1e-5)
 
 
 @pytest.mark.parametrize("family", ["qwen2_moe", "kimi_linear"])
@@ -279,9 +206,9 @@ def test_the_shared_expert_runs_inside_the_sparse_layers_shard_map(family):
         pytest.skip("one device")
     cfg = tiny_config(family)
     assert cfg.shared_expert_size and "w_shared_up" in moe_ops.sparse_leaves(cfg)
-    params = tr.init_params(cfg, jax.random.PRNGKey(1), jnp.float32)
-    rows = [_row([20, 11], 32, cfg.vocab_size, seed=i) for i in range(n)]
-    tok, seg, pos = (jnp.concatenate([r[j] for r in rows]) for j in range(3))
+    params = fb.init(cfg, 1)
+    rows = [fb.row([20, 11], 32, cfg.vocab_size, seed=i) for i in range(n)]
+    tok, seg, pos = (np.concatenate([r[j] for r in rows]) for j in range(3))
     arrays = {"tokens": tok, "segment_ids": seg, "positions": pos}
     mesh = mesh_lib.make_mesh(ParallelismConfig(fsdp_parallel_size=n))
     one = mesh_lib.make_mesh(ParallelismConfig(), jax.devices()[:1])
@@ -294,11 +221,11 @@ def test_the_shared_expert_runs_inside_the_sparse_layers_shard_map(family):
     np.testing.assert_allclose(hidden(mesh), hidden(one), atol=2e-5)
 
 
-FINGERPRINTS = {  # the parent commit's, from this file's own function
-    "qwen2": (-4.796655654907227, 71445.078125, 808.160400390625),
-    "olmoe": (-2.924315929412842, 99889.15625, 783.4895629882812),
-    "KeyeVL2": (-5.9150800704956055, 109418.3671875, 804.7096557617188),
-    "qwen2_moe": (-5.854315280914307, 57957.9921875, 809.0015869140625),
+FINGERPRINTS = {  # from this file's own function, one compiled program
+    "qwen2": (-4.796672821044922, 71445.07688094693, 808.160400390625),
+    "olmoe": (-2.9243152141571045, 99889.14326616446, 783.4895629882812),
+    "KeyeVL2": (-5.91508674621582, 109418.36398591805, 804.7095947265625),
+    "qwen2_moe": (-5.8543291091918945, 57958.00637981296, 809.0015258789062),
 }
 
 
@@ -306,28 +233,36 @@ FINGERPRINTS = {  # the parent commit's, from this file's own function
 def test_a_model_of_one_block_kind_is_what_it_was(family):
     """A weighted sum of the logits, the summed absolute gradient of every
     parameter and the summed absolute logits of the tiny dense, OLMoE, Keye
-    and qwen2_moe models, as the commit before the block kinds read them on
-    this backend (equal to the last digit there; the lowered programs were
-    compared as text beside, PERF.md PR 33)."""
+    and qwen2_moe models on this backend. The commit before the block kinds
+    read them operation by operation (equal to the last digit there; the
+    lowered programs were compared as text beside, PERF.md PR 33); PR 48 read
+    them again under one ``jit``, the model's code unchanged, within 4e-6 of
+    those (another order of the same sums)."""
     cfg = tiny_config(family)
     assert not cfg.layer_kinds
-    p = tr.init_params(cfg, jax.random.PRNGKey(5), dtype=jnp.float32)
+    p = fb.init(cfg, 5)
     assert set(p["layers"]) >= {"wq", "wo", "input_norm"}  # the flat stack
     t = 48
-    tok = (jnp.arange(t)[None] * 7 + 3) % cfg.vocab_size
-    seg = jnp.asarray([[1] * 20 + [2] * 22 + [0] * 6])
-    pos = jnp.concatenate(
-        [jnp.arange(20), jnp.arange(22), jnp.zeros(6, jnp.int32)]
-    )[None]
-    w = jnp.cos(jnp.arange(t * cfg.vocab_size).reshape(1, t, -1) * 0.01)
-    val, g = jax.value_and_grad(
-        lambda p: (tr.apply(p, cfg, tok, seg, pos) * w).sum()
-    )(p)
+    tok = (np.arange(t, dtype=np.int32)[None] * 7 + 3) % cfg.vocab_size
+    seg = np.asarray([[1] * 20 + [2] * 22 + [0] * 6], np.int32)
+    pos = np.concatenate(
+        [np.arange(20), np.arange(22), np.zeros(6)]
+    ).astype(np.int32)[None]
+    w = np.cos(
+        np.arange(t * cfg.vocab_size).reshape(1, t, -1) * 0.01
+    ).astype(np.float32)
+
+    @jax.jit
+    def read(p):
+        logits = tr.apply(p, cfg, tok, seg, pos)
+        return (logits * w).sum(), jnp.abs(logits).sum()
+
+    (val, summed), g = jax.jit(jax.value_and_grad(read, has_aux=True))(p)
     got = (
         float(val),
-        float(sum(jnp.abs(x).astype(jnp.float64).sum()
+        float(sum(np.abs(np.asarray(x, np.float64)).sum()
                   for x in jax.tree_util.tree_leaves(g))),
-        float(jnp.abs(tr.apply(p, cfg, tok, seg, pos)).sum()),
+        float(summed),
     )
     np.testing.assert_allclose(got, FINGERPRINTS[family], rtol=1e-6)
 
@@ -335,8 +270,7 @@ def test_a_model_of_one_block_kind_is_what_it_was(family):
 def test_checkpoint_names_round_trip_and_a_share_has_no_layout(tiny, tmp_path):
     from areal_tpu.models import hf_io
 
-    cfg = dataclasses.replace(tiny[0], experts_held=0)
-    params = tr.init_params(cfg, jax.random.PRNGKey(1), dtype=jnp.float32)
+    cfg, params = fb.whole(FAMILY, tiny)
     hf_io.save_params(params, cfg, str(tmp_path))
     assert hf_io.load_hf_config(str(tmp_path)) == cfg
     back = hf_io.load_params(str(tmp_path), cfg, dtype=jnp.float32)
@@ -368,48 +302,15 @@ def test_the_generation_engine_refuses_a_stack_of_block_kinds(tiny):
 
 
 def test_the_trainer_trains_the_family_and_counts_its_scans_exactly(
-    tiny, monkeypatch
+    tiny, trained
 ):
-    from areal_tpu.api import cli_args
-    from areal_tpu.api.io_struct import FinetuneSpec
-    from areal_tpu.engine.ppo.actor import PPOActor
-    from areal_tpu.engine.spmd_engine import SPMDTrainEngine
-    from areal_tpu.utils import data as data_utils
-
-    # rows of 256 tokens for the batch's 185 (the ladder's own step is 2048)
-    monkeypatch.setattr(data_utils, "ROW_QUANTUM", 256)
-    full, _ = cli_args.load_expr_config(
-        ["--config", os.path.join(ROOT, "examples", "gsm8k_grpo.yaml")],
-        cli_args.GRPOConfig,
-    )
-    acfg = full.actor
-    acfg.path, acfg.dtype = "", "float32"
-    engine = SPMDTrainEngine(acfg)
-    engine.initialize(
-        ft_spec=FinetuneSpec(1, 1000, 8), model_config=tiny[0], seed=3
-    )
+    engine, lens, stats = trained.engine, trained.lens, trained.stats
     assert (engine.attn_impl, engine.moe_impl, engine.kda_impl) == (
         "xla", "ragged_dot", "xla"
     )
     assert engine._span_attrs["kda_impl"] == "xla"
-    actor = PPOActor(acfg, engine)
-    rng = np.random.default_rng(12)
-    lens = [30, 12, 44, 9, 25, 18, 40, 7]
-    seqs = [rng.integers(0, 128, n).astype(np.int32) for n in lens]
-    batch = data_utils.pad_sequences_to_tensors(seqs)
-    lm = batch["attention_mask"].astype(np.int32)
-    lm[:, :3] = 0
-    batch["loss_mask"] = lm
-    batch["logprobs"] = (
-        rng.standard_normal(lm.shape).astype(np.float32) * 0.1 - 4.8
-    ) * lm
-    batch["versions"] = np.where(lm > 0, 0, -1).astype(np.int32)
-    batch["rewards"] = np.tile(np.array([0, 1], np.float32), 4)
-    before = jax.tree_util.tree_map(np.asarray, engine.params["layers"])
-    stats = actor.ppo_update(actor.compute_advantages(batch))
-    assert all(s["update_successful"] == 1.0 for s in stats)
     counters = engine.counters()
-    n_kda = tiny[0].num_kda_layers
+    n_kda = tiny.cfg.num_kda_layers
     assert counters["kda_tokens"] == sum(lens) * n_kda
     assert counters["kda_segments"] == len(lens) * n_kda
     assert counters["kda_chunk_slots"] % (64 * n_kda) == 0
@@ -427,8 +328,6 @@ def test_the_trainer_trains_the_family_and_counts_its_scans_exactly(
         counters["moe_stream_rows"]
     )
     after = engine.params["layers"]["kda+moe"]
-    was = before["kda+moe"]
+    was = trained.before["kda+moe"]
     assert (np.asarray(after["router_bias"]) == was["router_bias"]).all()
-    for moved in ("a_log", "dt_bias", "conv_k", "w_f_b", "wq"):
-        assert not (np.asarray(after[moved]) == was[moved]).all(), moved
-    engine.destroy()
+    trained.moved("kda+moe", "a_log", "dt_bias", "conv_k", "w_f_b", "wq")

@@ -242,7 +242,7 @@ def _ref_share(x, wr, wg, wu, wd, k, first, valid):
 
 def _banded_in_interpret_mode(monkeypatch, tiling):
     """The token side's kernel form off the chip (``moe.onto_impl`` is
-    steered as ``tests/test_chip_compile.py`` steers ``grouped_impl``), at
+    steered as ``tests/test_chip_compile_experts.py`` steers ``grouped_impl``), at
     tiles of a test's size."""
     from areal_tpu.ops import moe
 
@@ -355,6 +355,13 @@ def test_a_slabs_rows_go_back_to_their_tokens_by_a_banded_product(
         assert np.abs(dense).max() > 0.1
 
 
+# What a held-share case traces depends on the token side's form and on the
+# number of chunks alone (the load is in the values: tokens and a mask of one
+# shape), so the cases of one (form, chunks) run ONE set of compiled programs:
+# the first case traces them under its patches, the others find them.
+_HELD_PROGRAMS = {}
+
+
 @pytest.mark.parametrize("onto", ["dense", "banded"])
 @pytest.mark.parametrize("load", sorted(_HELD_LOADS))
 def test_a_held_share_runs_over_a_bounded_buffer_and_drops_nothing(
@@ -375,32 +382,44 @@ def test_a_held_share_runs_over_a_bounded_buffer_and_drops_nothing(
         _banded_in_interpret_mode(monkeypatch, (128, 128, 128))
     if chunks > 1:  # a stream of 1,024 rows is a chunk
         monkeypatch.setattr(moe, "_STREAM_ROWS", s["tokens"] * s["k"])
+    programs = _HELD_PROGRAMS.setdefault((onto, chunks), {})
+
+    def compiled(name, make):
+        if name not in programs:
+            programs[name] = make()
+        return programs[name]
+
     np_args = _held_case(n_two, n_one, chunks)
-    args = tuple(jnp.asarray(a) for a in np_args)
     g = np_args[0].shape[1]
     valid = np.ones(g, bool) if valid is None else valid
-    target = jnp.cos(jnp.arange(g * s["routed"], dtype=jnp.float32)).reshape(
-        1, g, -1
-    )
     assert moe._held_rows(s["tokens"] * s["k"], s["held"], s["routed"]) == (
         s["buffer"]
     )
 
-    def layer(*a):
+    def target(g):
+        return jnp.cos(
+            jnp.arange(g * s["routed"], dtype=jnp.float32)
+        ).reshape(g, -1)
+
+    def layer(valid, *a):
         out, stats = moe_ffn(
             *a, num_experts_per_tok=s["k"], norm_topk_prob=True,
-            valid=jnp.asarray(valid)[None], first_expert=s["first"],
+            valid=valid[None], first_expert=s["first"],
         )
-        return (out * target).sum(), (out, stats)
+        return (out * target(out.shape[1])[None]).sum(), (out, stats)
 
-    grad = jax.jit(jax.grad(layer, argnums=(0, 1, 2, 3, 4), has_aux=True))
-    grads, (out, stats) = grad(*args)
+    def grad_of_layer():
+        return jax.jit(
+            jax.grad(layer, argnums=(1, 2, 3, 4, 5), has_aux=True)
+        )
+
+    grads, (out, stats) = compiled("slabs", grad_of_layer)(valid, *np_args)
     # the whole stream: a buffer as long as the stream itself
     with monkeypatch.context() as whole_form:
         whole_form.setattr(moe, "_held_rows", lambda rows, e, n: rows)
-        whole_grads, (whole, whole_stats) = jax.jit(
-            jax.grad(layer, argnums=(0, 1, 2, 3, 4), has_aux=True)
-        )(*args)
+        whole_grads, (whole, whole_stats) = compiled("whole", grad_of_layer)(
+            valid, *np_args
+        )
     held = 2 * min(n_two, g) + n_one if valid.all() else 2 * int(valid.sum())
     assert float(stats.assignments) == float(whole_stats.assignments) == held
     assert float(stats.stream_rows) == ran
@@ -418,28 +437,34 @@ def test_a_held_share_runs_over_a_bounded_buffer_and_drops_nothing(
     assert np.abs(np.asarray(out)[0, ~valid]).max(initial=0.0) == 0.0
 
     # the routing weights' gradient: one chunk's expert path on its own
-    xf = args[0][0, :s["tokens"]]
-    logits = xf @ args[1]
-    topk_p, topk_i, _ = moe.router_topk(logits, s["k"], True)
-    pair_expert = topk_i.reshape(-1) - s["first"]
-    pair_expert = jnp.where(
-        (pair_expert >= 0) & (pair_expert < s["held"])
-        & jnp.repeat(jnp.asarray(valid[:s["tokens"]]), s["k"]),
-        pair_expert, s["held"],
-    ).astype(jnp.int32)
+    def routed(x, wr, valid):
+        topk_p, topk_i, _ = moe.router_topk(x @ wr, s["k"], True)
+        pair_expert = topk_i.reshape(-1) - s["first"]
+        return topk_p, jnp.where(
+            (pair_expert >= 0) & (pair_expert < s["held"])
+            & jnp.repeat(valid, s["k"]),
+            pair_expert, s["held"],
+        ).astype(jnp.int32)
+
+    xf = np_args[0][0, :s["tokens"]]
+    topk_p, pair_expert = compiled("routed", lambda: jax.jit(routed))(
+        xf, np_args[1], valid[:s["tokens"]]
+    )
 
     def path(run):
-        def f(xf, topk_p, *w):
+        def f(xf, topk_p, pair_expert, *w):
             out = run(xf, pair_expert, topk_p, None, *w)
             out = out[0] if isinstance(out, tuple) else out
-            return (out * target[0, :s["tokens"]]).sum()
-        return jax.jit(jax.grad(f, argnums=(0, 1, 2, 3, 4)))(
-            xf, topk_p, *args[2:]
-        )
+            return (out * target(s["tokens"])).sum()
+        return lambda: jax.jit(jax.grad(f, argnums=(0, 1, 3, 4, 5)))
 
     for got, want in zip(
-        path(functools.partial(moe._run_held, c=s["buffer"])),
-        path(moe._run_experts),
+        compiled(
+            "held_path", path(functools.partial(moe._run_held, c=s["buffer"]))
+        )(xf, topk_p, pair_expert, *np_args[2:]),
+        compiled("experts_path", path(moe._run_experts))(
+            xf, topk_p, pair_expert, *np_args[2:]
+        ),
     ):
         np.testing.assert_allclose(
             np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-6
@@ -587,13 +612,19 @@ def test_moe_generation_matches_full_forward():
     rng = np.random.default_rng(5)
     prompt = rng.integers(0, cfg.vocab_size, size=9).tolist()
 
-    # ground truth: full forward greedy continuation
+    # ground truth: full forward greedy continuation, every prefix in a row
+    # of 16 tokens (one compiled program for the six lengths)
+    forward = jax.jit(
+        lambda t, seg, pos: apply(params, cfg, t, seg, pos, remat=False)
+    )
+
     def full_next(seq):
-        t = jnp.asarray(seq, jnp.int32)[None]
-        seg = jnp.ones_like(t)
-        pos = jnp.arange(t.shape[1], dtype=jnp.int32)[None]
-        logits = apply(params, cfg, t, seg, pos, remat=False)
-        return int(jnp.argmax(logits[0, -1]))
+        n = len(seq)
+        t = np.zeros((1, 16), np.int32)
+        t[0, :n] = seq
+        seg = (np.arange(16) < n).astype(np.int32)[None]
+        pos = (np.arange(16) * seg[0]).astype(np.int32)[None]
+        return int(np.argmax(np.asarray(forward(t, seg, pos))[0, n - 1]))
 
     seq = list(prompt)
     for _ in range(6):

@@ -516,6 +516,37 @@ class TestTrafficPlaneObservability:
         assert trace_report.main([empty, "--slo"]) == 1
 
 
+def test_a_loop_that_dies_fails_its_callers_and_later_submits(traced_engine):
+    """What a hang in this module was (ROADMAP C.14): an error inside the
+    loop thread ended it and left ``generate`` on a future nothing resolved.
+    The waiting request, a queued command and a later submit all get the
+    error."""
+    _, _, cfg, params = traced_engine
+    eng = GenerationEngine(
+        JaxGenConfig(dtype="float32", max_num_seqs=2, max_model_len=64,
+                     prefill_chunk=16),
+        model_config=cfg, params=params,
+    )
+
+    def broken():
+        raise RuntimeError("the device went away")
+
+    eng._admit = broken
+    eng.start()
+    try:
+        fut = eng.submit({
+            "rid": "lost", "input_ids": [1, 2, 3],
+            "sampling_params": {"max_new_tokens": 2},
+        })
+        with pytest.raises(RuntimeError, match="device went away"):
+            fut.result(timeout=60)
+        with pytest.raises(RuntimeError, match="device went away"):
+            _generate(eng, "after")
+        assert not eng._running
+    finally:
+        eng.stop()
+
+
 class TestDisabledNoOp:
     @pytest.fixture(scope="class")
     def plain_engine(self):

@@ -112,33 +112,50 @@ def test_long_context_ring_training_step():
     assert losses[-1] < losses[0]
 
 
-@pytest.mark.parametrize("impl", ["ring", "ulysses"])
-def test_sp_correctness_at_24k(impl):
-    """24k-token packed stream over sp=4 matches the single-device result
-    (the boba long-context recipe's shape, on the virtual mesh). The
-    reference output comes from the memory-bounded blockwise kernel (the
-    naive kernel's 24k x 24k logits would not fit CI)."""
+def _sp_matches_one_device(impl, t):
+    """A ``t``-token packed stream over sp=4 matches the single-device
+    result. The reference output comes from the memory-bounded blockwise
+    kernel (the naive kernel's t x t logits would not fit CI)."""
     from areal_tpu.ops.blockwise_attention import blockwise_segment_attention
 
-    t = 24576
     rng = np.random.default_rng(7)
     # hq must be >= sp for the Ulysses head split (4 heads over sp=4)
     q = jnp.asarray(rng.standard_normal((1, t, 4, 16)), jnp.float32)
     k = jnp.asarray(rng.standard_normal((1, t, 1, 16)), jnp.float32)
     v = jnp.asarray(rng.standard_normal((1, t, 1, 16)), jnp.float32)
     seg = np.zeros((1, t), np.int32)
-    seg[0, : t // 2] = 1       # one 12k sequence
-    seg[0, t // 2 : t - 128] = 2  # one ~12k sequence + padding tail
+    seg[0, : t // 2] = 1       # one sequence of half the stream
+    seg[0, t // 2 : t - 128] = 2  # one nearly as long + padding tail
     seg = jnp.asarray(seg)
-    ref = blockwise_segment_attention(
-        q, k, v, seg, causal=True, q_chunk=2048, kv_chunk=2048
-    )
+    ref = jax.jit(lambda *a: blockwise_segment_attention(
+        *a, causal=True, q_chunk=2048, kv_chunk=2048
+    ))(q, k, v, seg)
     mesh = mesh_lib.make_mesh(ParallelismConfig(seq_parallel_size=4))
     attend = make_sharded_attention(mesh, impl=impl)
     out = jax.jit(attend)(q, k, v, seg)
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(ref), rtol=3e-4, atol=3e-4
     )
+
+
+@pytest.mark.parametrize("impl", ["ring", "ulysses"])
+def test_sp_correctness_at_8k(impl):
+    """The long-context branches at the least size that takes them: a
+    device's 2048 keys are two of ring's 1024-key scan chunks, and the
+    8192 tokens Ulysses gathers are past the 4096 from which it bounds its
+    memory blockwise. What ``test_sp_correctness_at_24k`` checks at the
+    recipe's length, a ninth of its arithmetic."""
+    _sp_matches_one_device(impl, 8192)
+
+
+@pytest.mark.slow  # 105 s (ring) and 56 s (ulysses) of float32 arithmetic
+# on the CPU beside five busy workers (PR 48); the same branches run in
+# tier-1 at 8192 tokens: test_sp_correctness_at_8k
+@pytest.mark.parametrize("impl", ["ring", "ulysses"])
+def test_sp_correctness_at_24k(impl):
+    """24k-token packed stream over sp=4 (the boba long-context recipe's
+    shape, on the virtual mesh)."""
+    _sp_matches_one_device(impl, 24576)
 
 
 @pytest.mark.slow  # ~136 s of compile on the tier-1 CPU budget — the

@@ -10,9 +10,7 @@ generation engine on the shared race geometry.
 import asyncio
 import json
 import os
-import subprocess
 import sys
-import time
 import urllib.request
 from types import SimpleNamespace
 
@@ -20,6 +18,8 @@ import numpy as np
 import pytest
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
+
+from tests import fixtures  # noqa: E402
 
 from areal_tpu.api.cli_args import (
     EnvServiceConfig,
@@ -614,39 +614,6 @@ def test_selfplay_env_worker_serves_both_sides_and_counts_proposals():
 
 
 # ------------------------------------ chaos: multi-session episode replay
-def _spawn_worker(env_extra=None):
-    """One real env-worker subprocess hosting BOTH self-play sides."""
-    cmd = [
-        sys.executable, "-m", "areal_tpu.env.service",
-        "--env", "areal_tpu.env.service:selfplay_env", "--port", "0",
-    ]
-    env = dict(os.environ)
-    if env_extra:
-        env.update(env_extra)
-    proc = subprocess.Popen(
-        cmd, stdin=subprocess.PIPE, stdout=subprocess.PIPE,
-        stderr=subprocess.STDOUT, text=True, env=env,
-    )
-    deadline = time.monotonic() + 60
-    while time.monotonic() < deadline:
-        line = proc.stdout.readline()
-        if line.startswith("PORT "):
-            return proc, f"127.0.0.1:{int(line.split()[1])}"
-        if proc.poll() is not None:
-            raise RuntimeError(f"env worker died at startup: {line!r}")
-    proc.kill()
-    raise RuntimeError("env worker never reported a port")
-
-
-def _reap(proc):
-    if proc.poll() is None:
-        try:
-            proc.stdin.close()
-            proc.wait(timeout=10)
-        except Exception:
-            proc.kill()
-
-
 def _selfplay_episode(addrs, capture):
     """One scripted two-sided episode against remote env workers; both
     env sessions (proposer, then solver) ride the same address pool."""
@@ -691,11 +658,14 @@ def test_kill_env_worker_mid_selfplay_episode_bit_identical():
     propose_instance call — and the episode must finish via journal
     replay with trajectory AND both sides' rewards bit-identical to an
     uninterrupted run."""
-    victim_proc, victim_addr = _spawn_worker(
-        {"AREAL_CHAOS": "kill:side=server,match=/step,start=1"}
-    )
-    surv_proc, surv_addr = _spawn_worker()
-    try:
+    with fixtures.children() as procs:
+        victim_proc, victim_addr = fixtures.spawn_env_worker(
+            "selfplay_env",
+            {"AREAL_CHAOS": "kill:side=server,match=/step,start=1"},
+        )
+        procs.append(victim_proc)
+        surv_proc, surv_addr = fixtures.spawn_env_worker("selfplay_env")
+        procs.append(surv_proc)
         base_envs = []
         baseline = _selfplay_episode([surv_addr], base_envs)
         assert baseline is not None
@@ -706,9 +676,6 @@ def test_kill_env_worker_mid_selfplay_episode_bit_identical():
         chaos_envs = []
         batch = _selfplay_episode([victim_addr, surv_addr], chaos_envs)
         assert victim_proc.poll() is not None, "chaos kill never fired"
-    finally:
-        _reap(victim_proc)
-        _reap(surv_proc)
 
     # zero lost episodes: exactly one replay, on the proposer session
     assert batch is not None

@@ -23,9 +23,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import family_battery as fb
+import keye_reference as twin
 from areal_tpu.api.cli_args import ParallelismConfig
 from areal_tpu.models.config import tiny_config
-from areal_tpu.models.transformer import apply, init_params
 from areal_tpu.ops import sparse_attention as sa
 from areal_tpu.ops.basic import (
     apply_mrope, apply_rope, rope_frequencies, segment_attention,
@@ -34,39 +35,34 @@ from areal_tpu.ops.moe import moe_ffn
 from areal_tpu.parallel import mesh as mesh_lib
 from benchmark.kinds import train_steps_dsa
 
-import keye_reference as twin
-
 LOGIT_TOL, GRAD_TOL, KERNEL_TOL = 1e-3, 1e-2, 1e-5
 
 
-@pytest.fixture(scope="module")
-def tiny():
-    # the family's tiny configuration and the reference's keys for it
-    cfg, ref_cfg = train_steps_dsa.tiny_configs()
-    params = init_params(cfg, jax.random.PRNGKey(11), dtype=jnp.float32)
-    rng = np.random.default_rng(11)
+def _seeded(cfg, seed):
+    params = fb.init(cfg, seed)
+    rng = np.random.default_rng(seed)
     lay = params["layers"]
     for k in ("q_norm", "k_norm", "input_norm", "post_attn_norm"):
-        lay[k] = lay[k] * jnp.asarray(
-            1.0 + 0.3 * rng.standard_normal(lay[k].shape), jnp.float32
-        )
+        lay[k] = lay[k] * (
+            1.0 + 0.3 * rng.standard_normal(lay[k].shape)
+        ).astype(np.float32)
     # weights large enough that routing and selection are decided
-    lay["w_router"] = lay["w_router"] * 20.0
+    lay["w_router"] = lay["w_router"] * np.float32(20.0)
     for k in ("w_idx_q", "w_idx_k", "w_idx_w"):
-        lay[k] = lay[k] * 10.0
-    return cfg, ref_cfg, params
+        lay[k] = lay[k] * np.float32(10.0)
+    return params
 
 
-def _alone(cfg, params, tokens, **kw):
-    t = jnp.asarray(tokens, jnp.int32)[None]
-    return apply(
-        params, cfg, t, jnp.ones_like(t),
-        jnp.arange(t.shape[1], dtype=jnp.int32)[None], remat=False, **kw
-    )
+# the family's tiny configuration and the reference's keys for it; rows of 64
+FAMILY = fb.Family(
+    "KeyeVL2", train_steps_dsa.tiny_configs, twin.logits, _seeded, total=64
+)
+tiny = fb.tiny_fixture(FAMILY, seed=11)
+trained = fb.trained_fixture()
 
 
 def _random_op_inputs(rng, t, hq=4, hkv=2, d=64, hi=2, di=8):
-    f = lambda *s: jnp.asarray(rng.standard_normal(s), jnp.float32)  # noqa: E731
+    f = lambda *s: rng.standard_normal(s).astype(np.float32)  # noqa: E731
     return (f(1, t, hq, d), f(1, t, hkv, d), f(1, t, hkv, d)), (
         f(1, t, hi, di), f(1, t, di), f(1, t, hi)
     )
@@ -84,76 +80,56 @@ def test_tiny_config_is_the_family(tiny):
 
 
 def test_logits_match_the_reference_with_sequences_longer_than_topk(tiny):
-    cfg, ref_cfg, params = tiny
-    tokens = np.random.default_rng(0).integers(0, cfg.vocab_size, 40)
-    got, stats = _alone(cfg, params, tokens, return_stats=True)
-    want = np.asarray(twin.logits(ref_cfg, params, tokens))
-    assert np.abs(want).max() > 0.1
-    np.testing.assert_allclose(np.asarray(got[0]), want, atol=LOGIT_TOL, rtol=0)
+    cfg, _, params = tiny
+    got, stats, _ = fb.check_logits(FAMILY, tiny, [40], atol=LOGIT_TOL)
+    assert np.abs(got[0, :40]).max() > 0.1
     # each layer kept sum_t min(t + 1, 8) of the 40 * 41 / 2 causal pairs
     assert np.asarray(stats.counts["dsa_kept_pairs"]).tolist() == (
         [36 + 32 * 8] * 2
     )
     assert np.asarray(stats.counts["dsa_causal_pairs"]).tolist() == [820] * 2
     # and it is not full attention under another name
-    full = _alone(dataclasses.replace(cfg, dsa_topk=0), {
-        **params, "layers": {k: v for k, v in params["layers"].items()
-                             if not k.startswith("w_idx")},
-    }, tokens)
-    assert np.abs(np.asarray(full[0]) - want).max() > 10 * LOGIT_TOL
+    tok, seg, pos, _ = fb.row([40], FAMILY.total, cfg.vocab_size)
+    full, _ = fb.forward(
+        tiny._replace(cfg=dataclasses.replace(cfg, dsa_topk=0)), tok, seg, pos,
+        {**params, "layers": {k: v for k, v in params["layers"].items()
+                              if not k.startswith("w_idx")}},
+    )
+    assert np.abs(full - got)[0, :40].max() > 10 * LOGIT_TOL
 
 
 def test_loss_and_every_gradient_match_and_the_indexers_is_zero(tiny):
-    cfg, ref_cfg, params = tiny
-    tokens = np.random.default_rng(1).integers(0, cfg.vocab_size, 40)
+    def summed(seq):  # the summed log p(seq[i] | seq[:i])
+        n, tgt = len(seq), np.asarray(seq[1:], np.int32)
+        return lambda logits: jnp.take_along_axis(
+            jax.nn.log_softmax(logits[:n - 1], axis=-1), tgt[:, None], axis=-1
+        ).sum()
 
-    def summed(p):
-        lp = jax.nn.log_softmax(_alone(cfg, p, tokens)[0, :-1], axis=-1)
-        tgt = jnp.asarray(tokens[1:], jnp.int32)
-        return jnp.take_along_axis(lp, tgt[:, None], axis=-1).sum()
+    def by_index(g, w):  # chosen by index: exactly zero on both sides
+        assert not np.asarray(g).any() and not np.asarray(w).any()
 
-    loss, got = jax.value_and_grad(summed)(params)
-    assert float(loss) == pytest.approx(
-        float(twin.summed_logprob(ref_cfg, params, tokens)), abs=LOGIT_TOL * 40
+    seen = fb.check_gradients(
+        FAMILY, tiny, summed, n=40, seed=1, rtol=GRAD_TOL,
+        value_tol=(0.0, LOGIT_TOL * 40),
+        special={k: by_index for k in ("w_idx_q", "w_idx_k", "w_idx_w")},
     )
-    want = twin.summed_logprob_grad(ref_cfg, params, tokens)
-    flat_got = jax.tree_util.tree_leaves_with_path(got)
-    flat_want = jax.tree_util.tree_leaves(want)
-    assert len(flat_got) == len(flat_want) == 3 + len(params["layers"])
-    for (path, g), w in zip(flat_got, flat_want):
-        name = jax.tree_util.keystr(path)
-        if "w_idx" in name:  # chosen by index: exactly zero on both sides
-            assert not np.asarray(g).any() and not np.asarray(w).any(), name
-            continue
-        scale = float(jnp.abs(w).max())
-        assert scale > 0, name
-        np.testing.assert_allclose(
-            np.asarray(g), np.asarray(w), atol=GRAD_TOL * scale, rtol=0,
-            err_msg=name,
-        )
+    assert len(seen) == 3 + len(tiny.params["layers"]) - 3
 
 
 def test_two_sequences_in_one_row_select_nothing_across_the_border(tiny):
-    cfg, _, params = tiny
-    rng = np.random.default_rng(2)
-    seqs = [rng.integers(0, cfg.vocab_size, n) for n in (30, 24)]
-    alone = [np.asarray(_alone(cfg, params, s)[0]) for s in seqs]
-    pad = 10
-    row = np.concatenate(seqs + [np.zeros(pad, np.int64)])
-    seg = np.concatenate([np.full(30, 1), np.full(24, 2), np.zeros(pad)])
-    pos = np.concatenate([np.arange(30), np.arange(24), np.zeros(pad)])
-    as_row = lambda a: jnp.asarray(a[None], jnp.int32)  # noqa: E731
-    packed, stats = apply(
-        params, cfg, as_row(row), as_row(seg), as_row(pos), remat=False,
-        return_stats=True,
+    # the program's packed row against the reference's of each alone
+    _, stats, _ = fb.check_logits(
+        FAMILY, tiny, [30, 24], seed=2, atol=LOGIT_TOL
     )
-    np.testing.assert_allclose(packed[0, :30], alone[0], atol=LOGIT_TOL, rtol=0)
-    np.testing.assert_allclose(packed[0, 30:54], alone[1], atol=LOGIT_TOL, rtol=0)
     kept = 36 + 22 * 8 + 36 + 16 * 8  # padding counts nowhere
     assert np.asarray(stats.counts["dsa_kept_pairs"]).tolist() == [kept] * 2
     # the op's own mask: no (query, key) pair joins two segments
-    (q, k, v), (qi, ki, w) = _random_op_inputs(rng, 64)
-    mask, _ = sa.select_keys(qi[0], ki[0], w[0], as_row(seg)[0], 8)
+    seg = np.concatenate([np.full(30, 1), np.full(24, 2), np.zeros(10)])
+    pos = np.concatenate([np.arange(30), np.arange(24), np.zeros(10)])
+    (q, k, v), (qi, ki, w) = _random_op_inputs(np.random.default_rng(2), 64)
+    mask, _ = jax.jit(lambda *a: sa.select_keys(*a, 8))(
+        qi[0], ki[0], w[0], seg.astype(np.int32)
+    )
     mask = np.asarray(mask)
     assert not mask[30:54, :30].any() and not mask[:54, 54:].any()
     assert (mask[:54].sum(1) == np.minimum(pos[:54] + 1, 8)).all()
@@ -186,12 +162,12 @@ def test_selection_is_exact_against_a_sort_and_breaks_ties_to_the_lower_index():
 
 def test_a_sequence_within_topk_ignores_the_indexer_and_is_causal_attention(tiny):
     cfg, _, params = tiny
-    tokens = np.random.default_rng(4).integers(0, cfg.vocab_size, 8)  # = topk
-    base = np.asarray(_alone(cfg, params, tokens))
+    tok, seg, pos, _ = fb.row([8], FAMILY.total, cfg.vocab_size, 4)  # = topk
+    base, _ = fb.forward(tiny, tok, seg, pos)
     other = dict(params, layers=dict(params["layers"]))
     for k in ("w_idx_q", "w_idx_k", "w_idx_w"):
         other["layers"][k] = -3.0 * params["layers"][k][::-1]
-    assert (np.asarray(_alone(cfg, other, tokens)) == base).all()  # to the bit
+    assert (fb.forward(tiny, tok, seg, pos, other)[0] == base).all()  # to the bit
     rng = np.random.default_rng(5)
     (q, k, v), (qi, ki, w) = _random_op_inputs(rng, 128)
     seg = jnp.asarray(np.repeat([1, 2, 0], [70, 50, 8])[None], jnp.int32)
@@ -491,43 +467,12 @@ def test_select_impl_is_the_kernel_on_a_tpu_for_whole_tiles_only(monkeypatch):
 
 
 # ------------------------------------------------------------ the trainer --
-def test_the_trainer_trains_the_family_and_counts_its_pairs_exactly(tiny):
-    from areal_tpu.api import cli_args
-    from areal_tpu.api.io_struct import FinetuneSpec
-    from areal_tpu.engine.ppo.actor import PPOActor
-    from areal_tpu.engine.spmd_engine import SPMDTrainEngine
-    from areal_tpu.utils import data as data_utils
-    import os
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    full, _ = cli_args.load_expr_config(
-        ["--config", os.path.join(root, "examples", "gsm8k_grpo.yaml")],
-        cli_args.GRPOConfig,
-    )
-    acfg = full.actor
-    acfg.path, acfg.dtype = "", "float32"
-    engine = SPMDTrainEngine(acfg)
-    engine.initialize(
-        ft_spec=FinetuneSpec(1, 1000, 8), model_config=tiny[0], seed=3
-    )
+def test_the_trainer_trains_the_family_and_counts_its_pairs_exactly(
+    tiny, trained
+):
+    engine, lens, stats = trained.engine, trained.lens, trained.stats
     assert engine.attn_impl == "sparse_topk" and engine.moe_impl == "ragged_dot"
-    actor = PPOActor(acfg, engine)
-    rng = np.random.default_rng(12)
-    lens = [30, 12, 44, 9, 25, 18, 40, 7]
-    seqs = [rng.integers(0, 128, n).astype(np.int32) for n in lens]
-    batch = data_utils.pad_sequences_to_tensors(seqs)
-    lm = batch["attention_mask"].astype(np.int32)
-    lm[:, :3] = 0
-    batch["loss_mask"] = lm
-    batch["logprobs"] = (
-        rng.standard_normal(lm.shape).astype(np.float32) * 0.1 - 4.8
-    ) * lm
-    batch["versions"] = np.where(lm > 0, 0, -1).astype(np.int32)
-    batch["rewards"] = np.tile(np.array([0, 1], np.float32), 4)
-    before = jax.tree_util.tree_map(np.asarray, engine.params["layers"])
-    stats = actor.ppo_update(actor.compute_advantages(batch))
-    assert all(s["update_successful"] == 1.0 for s in stats)
-    layers = tiny[0].num_layers
+    layers = tiny.cfg.num_layers
     kept = sum(min(p + 1, 8) for n in lens for p in range(n)) * layers
     causal = sum(n * (n + 1) // 2 for n in lens) * layers
     counters = engine.counters()
@@ -540,13 +485,12 @@ def test_the_trainer_trains_the_family_and_counts_its_pairs_exactly(tiny):
     assert counters["moe_stream_rows"] % (2 * layers) == 0
     assert counters["moe_overflow_chunks"] == 0
     after = engine.params["layers"]
-    assert (np.asarray(after["w_idx_q"]) == before["w_idx_q"]).all()
-    assert not (np.asarray(after["wq"]) == before["wq"]).all()
-    engine.destroy()
+    assert (np.asarray(after["w_idx_q"]) == trained.before["w_idx_q"]).all()
+    trained.moved(None, "wq")
 
 
 def test_dispatch_spans_name_the_selection_form_for_an_indexer_model_only(
-    tiny, monkeypatch
+    trained, monkeypatch
 ):
     from areal_tpu.api.cli_args import (
         MicroBatchSpec, OptimizerConfig, TrainEngineConfig,
@@ -557,6 +501,17 @@ def test_dispatch_spans_name_the_selection_form_for_an_indexer_model_only(
     from areal_tpu.utils import data as data_utils
     from areal_tpu.utils import tracing
 
+    def check(engine, spans, want):
+        assert engine.dsa_select_impl == want
+        for name in ("train.grad_dispatch", "train.logp_dispatch"):
+            attrs = [a for n, a in spans if n == name]
+            assert attrs, name
+            assert all(a.get("dsa_select_impl") == want for a in attrs), attrs
+            assert all(("dsa_select_impl" in a) == bool(want) for a in attrs)
+
+    # the family's engine, in the spans of the update it ran
+    check(trained.engine, trained.spans, "xla")
+    # and a model without an indexer says nothing
     seen = []
     span = tracing.span
     monkeypatch.setattr(
@@ -569,25 +524,21 @@ def test_dispatch_spans_name_the_selection_form_for_an_indexer_model_only(
         [rng.integers(0, 128, n).astype(np.int32) for n in (9, 5, 7)]
     )
     batch["loss_mask"] = batch["attention_mask"].astype(np.int32)
-    for model_config, want in ((tiny[0], "xla"), (tiny_config("qwen2"), None)):
-        engine = SPMDTrainEngine(TrainEngineConfig(
-            dtype="float32", param_dtype="float32",
-            mb_spec=MicroBatchSpec(max_tokens_per_mb=4096),
-            optimizer=OptimizerConfig(
-                type="adamw", lr=1e-2, warmup_steps_proportion=0.0,
-                lr_scheduler_type="constant",
-            ),
-        ))
-        engine.initialize(
-            ft_spec=FinetuneSpec(1, 64, 8), model_config=model_config, seed=0
-        )
-        assert engine.dsa_select_impl == want
-        del seen[:]
+    engine = SPMDTrainEngine(TrainEngineConfig(
+        dtype="float32", param_dtype="float32",
+        mb_spec=MicroBatchSpec(max_tokens_per_mb=4096),
+        optimizer=OptimizerConfig(
+            type="adamw", lr=1e-2, warmup_steps_proportion=0.0,
+            lr_scheduler_type="constant",
+        ),
+    ))
+    engine.initialize(
+        ft_spec=FinetuneSpec(1, 64, 8), model_config=tiny_config("qwen2"),
+        seed=0,
+    )
+    try:
         engine.train_batch(batch, sft_loss_fn, sft_loss_weight_fn)
         engine.forward(batch)
-        for name in ("train.grad_dispatch", "train.logp_dispatch"):
-            attrs = [a for n, a in seen if n == name]
-            assert attrs, name
-            assert all(a.get("dsa_select_impl") == want for a in attrs), attrs
-            assert all(("dsa_select_impl" in a) == bool(want) for a in attrs)
+        check(engine, seen, None)
+    finally:
         engine.destroy()

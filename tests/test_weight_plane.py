@@ -92,6 +92,26 @@ def _wait_decoding(eng, deadline_s=60.0):
     raise AssertionError("request never started decoding")
 
 
+def _hold_decode_until_a_flip_is_queued(eng):
+    """From now until a weight flip is queued the loop thread waits at the
+    top of its iteration: the in-flight request cannot run to its length
+    first, however long the copy that precedes the flip takes on a busy
+    machine (420 tokens of this model are a third of a second)."""
+    queued = threading.Event()
+    queue_flip, top = eng.weights.queue_flip, eng._maybe_start_profile
+
+    def flip(*args, **kwargs):
+        fut = queue_flip(*args, **kwargs)
+        queued.set()
+        return fut
+
+    def gated():
+        queued.wait(timeout=300)
+        return top()
+
+    eng.weights.queue_flip, eng._maybe_start_profile = flip, gated
+
+
 # ---------------------------------------------------------------------------
 # Streamed flip under live decode: zero pause, exact version fence
 # ---------------------------------------------------------------------------
@@ -183,6 +203,7 @@ def test_resume_policy_aborts_into_suffix_resume(param_sets):
             }
         )
         _wait_decoding(eng)
+        _hold_decode_until_a_flip_is_queued(eng)
         v = eng.update_weights_from_tensors(p1, version=3)
         assert v == 3
         first = fut.result(timeout=120)

@@ -9,76 +9,54 @@ half rotation against hand-worked values, the carried router against the
 uncarried one, the pair count past an int32, the two shares of the experts, one expert a token, and the
 refusals by name."""
 
-import dataclasses
-import json
 import math
-import os
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import family_battery as fb
 from areal_tpu.models import transformer as tr
 from areal_tpu.models.config import from_hf_config, tiny_config
 from areal_tpu.ops import basic, moe as moe_ops
-from areal_tpu.ops.flash import resolve_attn_impl
+from benchmark import reference_zaya as ref
+from benchmark.kinds import train_steps_cca
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, ROOT)
-from benchmark import reference_zaya as ref  # noqa: E402
-from benchmark.kinds import train_steps_cca  # noqa: E402
-
-CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
-CUT = os.path.join(ROOT, "benchmark", "configs", "zaya1-8b.json")
+CUT = "zaya1-8b.json"
 KIND = "cca+moe"
 
 
 def _seeded(cfg, key):
     """Seeded weights in which nothing is at the value that would hide it:
     biases, scales, the temperature and the norms moved off 0 and 1."""
-    params = tr.init_params(cfg, jax.random.PRNGKey(key), dtype=jnp.float32)
+    params = fb.init(cfg, key)
     rng = np.random.default_rng(key)
     stack = params["layers"][KIND]
     for name, leaf in stack.items():
         if ("bias" in name or "scale" in name or name.startswith("b_router")
                 or name in ("cca_temp", "router_norm")):
-            stack[name] = leaf + jnp.asarray(
-                rng.normal(size=leaf.shape) * 0.1, jnp.float32
-            )
+            stack[name] = leaf + (
+                rng.normal(size=leaf.shape) * 0.1
+            ).astype(np.float32)
     # the router's matrices larger, its last bias and the balancing bias
     # smaller, so that a token's own state and not a bias picks its expert
     # (at std 0.02 the probabilities differ by 1e-3)
     for name in ("w_router_in", "w_router_1", "w_router_2", "w_router_out"):
-        stack[name] = stack[name] * 20.0
+        stack[name] = stack[name] * np.float32(20.0)
     for name in ("b_router_out", "router_bias"):
-        stack[name] = stack[name] * 0.1
+        stack[name] = stack[name] * np.float32(0.1)
     # the carried scalar off the zero it is drawn at, which would multiply
     # the carried path away and let a dropped carry pass
-    train_steps_cca.seed_carry(params)
+    stack["router_carry"] = np.full_like(stack["router_carry"], 0.5)
     return params
 
 
-@pytest.fixture(scope="module")
-def tiny():
-    cfg, keys = train_steps_cca.tiny_configs()
-    return cfg, keys, _seeded(cfg, 4)
-
-
-def _row(lens, total, vocab, seed=0):
-    rng = np.random.default_rng(seed)
-    tok = np.zeros((1, total), np.int32)
-    seg = np.zeros((1, total), np.int32)
-    pos = np.zeros((1, total), np.int32)
-    seqs, at = [], 0
-    for i, n in enumerate(lens):
-        s = rng.integers(0, vocab, n).astype(np.int32)
-        tok[0, at:at + n], seg[0, at:at + n] = s, i + 1
-        pos[0, at:at + n] = np.arange(n)
-        seqs.append(s)
-        at += n
-    return jnp.asarray(tok), jnp.asarray(seg), jnp.asarray(pos), seqs
+# the row: sequences of one and of two tokens between longer ones
+FAMILY = fb.Family(
+    "zaya", train_steps_cca.tiny_configs, ref.logits, _seeded, total=32
+)
+tiny = fb.tiny_fixture(FAMILY)
 
 
 def test_tiny_config_is_the_family_and_the_catalogs_config_builds():
@@ -94,8 +72,7 @@ def test_tiny_config_is_the_family_and_the_catalogs_config_builds():
     from areal_tpu.models.hf_io import default_hf_config_dict
 
     assert from_hf_config(default_hf_config_dict(t)) == t
-    with open(CUT) as f:
-        cut = from_hf_config(json.load(f))
+    cut = from_hf_config(fb.cut(CUT))
     assert cut.layer_kinds == (KIND,) * 4
     assert (cut.num_experts, cut.num_held_experts, cut.vocab_size) == (
         16, 8, 32784
@@ -106,13 +83,7 @@ def test_tiny_config_is_the_family_and_the_catalogs_config_builds():
     assert (cut.cca.conv_taps, cut.cca.rotary_dim) == ((2, 2), 64)
     assert cut.cca.rope_theta == 5_000_000.0
     assert cut.router.hidden_size == 256 and cut.q_dim == 1024
-    if not os.path.exists(CATALOG):
-        pytest.skip("no catalog beside the guide")
-    with open(CATALOG) as f:
-        rows = [json.loads(line) for line in f if "ZAYA" in line]
-    full = from_hf_config(
-        next(r for r in rows if r["name"] == "ZAYA1-8B")["config"]
-    )  # the published keys, unchanged
+    full = from_hf_config(fb.published("ZAYA1-8B"))  # the keys, unchanged
     assert (full.num_layers, full.num_experts, full.experts_held) == (
         40, 16, 0
     )
@@ -129,10 +100,7 @@ def test_tiny_config_is_the_family_and_the_catalogs_config_builds():
     ({"hidden_act": "gelu"}, "hidden_act"),
 ])
 def test_what_the_family_may_say_and_the_program_lacks_is_refused(bad, word):
-    with open(CUT) as f:
-        d = dict(json.load(f), **bad)
-    with pytest.raises(ValueError, match=word):
-        from_hf_config(d)
+    fb.check_refused(CUT, bad, word)
 
 
 def test_the_half_rotation_against_hand_worked_values():
@@ -146,9 +114,10 @@ def test_the_half_rotation_against_hand_worked_values():
     # a head of 8 of which the first 4 turn: channel pairs (j, j + 2) at
     # frequencies 1 and 1/2, position 3; channels 4.. as they were
     cos, sin = basic.rope_at(
-        jnp.asarray([[0, 3]]), np.asarray([1.0, 0.5], np.float32)
+        np.asarray([[0, 3]]), np.asarray([1.0, 0.5], np.float32)
     )
-    x = jnp.asarray([[[[1.0, 2.0, 3.0, 4.0, 5.0, 6.0, 7.0, 8.0]]] * 2])
+    x = np.asarray([[[[1.0, 2.0, 3.0, 4.0, 5.0, 6.0, 7.0, 8.0]]] * 2],
+                   np.float32)
     y = np.asarray(basic.rotate_leading(x, cos, sin))
     np.testing.assert_allclose(y[0, 0, 0], x[0, 0, 0])
     c, s = math.cos(3.0), math.sin(3.0)
@@ -163,50 +132,28 @@ def test_the_half_rotation_against_hand_worked_values():
     np.testing.assert_array_equal(
         basic.rope_inv_freq(cfg.cca.rotary_dim, cfg.cca.rope_theta), want
     )
-    z = jnp.asarray(
-        np.random.default_rng(0).standard_normal((1, 9, 3, 16)), jnp.float32
-    )
-    cos, sin = basic.rope_at(jnp.arange(9)[None], want)
+    z = fb.normal(0, 1, 9, 3, 16)
+    cos, sin = basic.rope_at(np.arange(9)[None], want)
     np.testing.assert_allclose(
-        basic.rotate_leading(z, cos, sin)[0], ref.rotate(z[0], want),
-        atol=1e-6,
+        basic.rotate_leading(z, cos, sin)[0],
+        jax.jit(lambda z: ref.rotate(z, want))(z[0]), atol=1e-6,
     )
 
 
 def test_a_block_matches_the_reference_and_hands_the_routers_state_on(tiny):
-    cfg, keys, params = tiny
-    lp = {k: v[1] for k, v in params["layers"][KIND].items()}
-    rng = np.random.default_rng(1)
-    x = jnp.asarray(rng.standard_normal((1, 37, cfg.hidden_size)), jnp.float32)
-    r = jnp.asarray(rng.standard_normal((1, 37, 8)), jnp.float32)
-    seg = jnp.ones((1, 37), jnp.int32)
-    pos = jnp.arange(37)[None]
-    (got, r_out), stats = jax.jit(lambda x, r, lp: tr._block_body(
-        cfg, KIND, (x, r), lp, seg, rotation=tr._rotations(cfg, pos)["cca"]
-    ))(x, r, lp)
-    with jax.default_matmul_precision("highest"):
-        want, r_want, _ = ref.layer(keys, x[0], r[0], lp)
-    np.testing.assert_allclose(got[0], want, atol=2e-5)
-    np.testing.assert_allclose(r_out[0], r_want, atol=1e-5)
+    stats = fb.check_block(
+        tiny, KIND, lambda x, r, lp: ref.layer(tiny.keys, x, r, lp)[:2],
+        index=1, carry=fb.normal(2, 37, 8),
+    )
     # a layer reports (its FFN's, its mixer's): some tokens' expert is away
     assert 0 < int(stats[0].assignments) < 37 and stats[1] is None
 
 
 def test_logits_match_alone_and_packed_with_borders_one_and_two_apart(tiny):
-    cfg, keys, params = tiny
+    cfg = tiny.cfg
     # sequences of one and of two tokens between longer ones: a convolution
     # of two taps after one of two reaches two tokens back, the shift one
-    tok, seg, pos, seqs = _row([10, 9, 1, 2, 7], 32, cfg.vocab_size)
-    got, stats = jax.jit(
-        lambda p: tr.apply(p, cfg, tok, seg, pos, return_stats=True)
-    )(params)
-    at = 0
-    for s in seqs:
-        np.testing.assert_allclose(
-            got[0, at:at + len(s)], ref.logits(keys, params, s.tolist()),
-            atol=5e-5,
-        )
-        at += len(s)
+    _, stats, seqs = fb.check_logits(FAMILY, tiny, [10, 9, 1, 2, 7])
     causal = sum(len(s) * (len(s) + 1) // 2 for s in seqs)
     # partial sums a stretch of a row, the layers leading
     counts = stats.counts
@@ -231,25 +178,27 @@ def test_both_convolutions_and_the_shift_are_exact_at_a_border(tiny):
     token t inside a sequence moves q and k at t, t + 1, t + 2 and v at t,
     t + 1 and nothing else."""
     cfg, keys, params = tiny
-    lp = {k: v[0] for k, v in params["layers"][KIND].items()}
+    lp = fb.layer_of(params, KIND)
     lens = [11, 3, 9]
-    seg = jnp.asarray([[1] * 11 + [2] * 3 + [3] * 9 + [0] * 5])
-    pos = jnp.asarray(
-        [list(range(11)) + list(range(3)) + list(range(9)) + [0] * 5]
+    seg = np.asarray([[1] * 11 + [2] * 3 + [3] * 9 + [0] * 5], np.int32)
+    pos = np.asarray(
+        [list(range(11)) + list(range(3)) + list(range(9)) + [0] * 5],
+        np.int32,
     )
-    rng = np.random.default_rng(2)
-    h = rng.standard_normal((1, 28, cfg.hidden_size)).astype(np.float32)
+    h = fb.normal(2, 1, 28, cfg.hidden_size)
     mix = jax.jit(lambda h: tr.cca_qkv(
         cfg, h, lp, seg, *tr._rotations(cfg, pos)["cca"]
     ))
-    base = [np.asarray(a) for a in mix(jnp.asarray(h))]
-    # and the packed form is the reference's, sequence by sequence
+    base = [np.asarray(a) for a in mix(h)]
+    # and the packed form is the reference's, sequence by sequence (the
+    # reference is causal: at one length, a sequence's rows lead)
+    reference = jax.jit(lambda h: ref.qkv(keys, h, lp))
     at = 0
     for n in lens:
-        with jax.default_matmul_precision("highest"):
-            want = ref.qkv(keys, jnp.asarray(h[0, at:at + n]), lp)
-        for got, w in zip(base, want):
-            np.testing.assert_allclose(got[0, at:at + n], w, atol=1e-5)
+        alone = np.zeros((max(lens), cfg.hidden_size), np.float32)
+        alone[:n] = h[0, at:at + n]
+        for got, w in zip(base, reference(alone)):
+            np.testing.assert_allclose(got[0, at:at + n], w[:n], atol=1e-5)
         at += n
 
     def moved(t):
@@ -259,7 +208,7 @@ def test_both_convolutions_and_the_shift_are_exact_at_a_border(tiny):
             np.flatnonzero(
                 np.abs(np.asarray(a) - b).reshape(28, -1).max(axis=1) > 0
             ).tolist()
-            for a, b in zip(mix(jnp.asarray(h2)), base)
+            for a, b in zip(mix(h2), base)
         ]
 
     assert moved(10) == [[10], [10], [10]]  # the last token of sequence 1
@@ -273,52 +222,17 @@ def test_both_convolutions_and_the_shift_are_exact_at_a_border(tiny):
 
 
 def test_grpo_loss_and_every_gradient_match_the_references(tiny):
-    from areal_tpu.ops.functional import ppo_actor_loss_fn
+    def untrained(g, w):  # state that nothing trains
+        assert float(np.abs(g).max()) == float(np.abs(w).max()) == 0
 
-    cfg, keys, params = tiny
-    n = 45
-    tok, seg, pos, seqs = _row([n], n, cfg.vocab_size, seed=3)
-    rng = np.random.default_rng(8)
-    old = jnp.asarray(rng.standard_normal(n - 1) * 0.1 - 4.8, jnp.float32)
-    adv = jnp.asarray(rng.standard_normal(n - 1), jnp.float32)
-    mask = jnp.asarray(np.arange(n - 1) >= 4)
-    tgt = jnp.asarray(seqs[0][1:])
+    def carry(g, w):  # the first layer receives no state
+        assert float(g[0]) == float(w[0]) == 0
+        return g[1:], w[1:]
 
-    def grpo(logits):  # [T, V] of one sequence
-        logp = jnp.take_along_axis(
-            jax.nn.log_softmax(logits[:-1], axis=-1), tgt[:, None], axis=-1
-        )[:, 0]
-        return ppo_actor_loss_fn(
-            logprobs=logp, old_logprobs=old, advantages=adv, eps_clip=0.2,
-            loss_mask=mask, proximal_logprobs=jax.lax.stop_gradient(logp),
-            behav_imp_weight_cap=5.0,
-        )[0]
-
-    def program(p):
-        return grpo(tr.apply(p, cfg, tok, seg, pos, remat=True)[0])
-
-    def reference(p):
-        return grpo(ref.logits(keys, p, seqs[0].tolist()))
-
-    got_v, got = jax.jit(jax.value_and_grad(program))(params)
-    want_v, want = jax.value_and_grad(reference)(params)
-    assert abs(float(got_v - want_v)) < 1e-4 * abs(float(want_v)) + 1e-6
-    seen = {}
-    for (path, g), w_ in zip(
-        jax.tree_util.tree_leaves_with_path(got),
-        jax.tree_util.tree_leaves(want),
-    ):
-        name = jax.tree_util.keystr(path).split("'")[-2]
-        if name == "router_bias":  # state that nothing trains
-            assert float(jnp.abs(g).max()) == float(jnp.abs(w_).max()) == 0
-            continue
-        if name == "router_carry":  # the first layer receives no state
-            assert float(g[0]) == float(w_[0]) == 0
-            g, w_ = g[1:], w_[1:]
-        scale = float(jnp.abs(w_).max())
-        assert scale > 0, name
-        assert float(jnp.abs(g - w_).max()) <= 2e-3 * scale, name
-        seen[name] = scale
+    seen = fb.check_gradients(
+        FAMILY, tiny, n=32,
+        special={"router_bias": untrained, "router_carry": carry},
+    )
     # one expert a token, its weight the probability as it is: the loss
     # reaches every leaf of the router, and the scalar that carries its state
     assert {"w_router_in", "b_router_in", "router_norm", "w_router_1",
@@ -352,14 +266,13 @@ def test_a_carried_scalar_of_zero_is_the_uncarried_router_bit_for_bit(tiny):
     cfg, _, params = tiny
     stack = params["layers"][KIND]
     rng = np.random.default_rng(5)
-    h = jnp.asarray(rng.normal(size=(1, 29, cfg.hidden_size)), jnp.float32)
-    r_prev = jnp.asarray(
-        rng.normal(size=(1, 29, cfg.router.hidden_size)), jnp.float32
-    )
+    h = rng.normal(size=(1, 29, cfg.hidden_size)).astype(np.float32)
+    r_prev = rng.normal(
+        size=(1, 29, cfg.router.hidden_size)
+    ).astype(np.float32)
 
     def router(gamma, carried):
-        lp = {k: v[1] for k, v in stack.items()}
-        lp["router_carry"] = jnp.float32(gamma)
+        lp = dict(fb.layer_of(params, KIND, 1), router_carry=np.float32(gamma))
         # op by op: a compiler free to fuse the addition into the product
         # before it rounds the two forms apart in the last bit
         return moe_ops.router_mlp(lp, h, cfg.rms_norm_eps, carried)
@@ -368,17 +281,17 @@ def test_a_carried_scalar_of_zero_is_the_uncarried_router_bit_for_bit(tiny):
     for got, want in zip(router(0.0, r_prev), uncarried):
         np.testing.assert_array_equal(got, want)
     for got, want in zip(router(0.5, r_prev), uncarried):
-        assert float(jnp.abs(got - want).max()) > 1e-6
+        assert float(np.abs(np.asarray(got) - np.asarray(want)).max()) > 1e-6
     # and through the stack: the scalar reaches the logits
-    tok, seg, pos, _ = _row([14, 6, 9], 32, cfg.vocab_size, seed=5)
+    tok, seg, pos, _ = fb.row([14, 6, 9], 32, cfg.vocab_size, seed=5)
 
     def logits(gamma):
         p = dict(params, layers={KIND: dict(
-            stack, router_carry=jnp.full((3,), gamma, jnp.float32)
+            stack, router_carry=np.full((3,), gamma, np.float32)
         )})
-        return jax.jit(lambda p: tr.apply(p, cfg, tok, seg, pos))(p)
+        return fb.forward(tiny, tok, seg, pos, p)[0]
 
-    assert float(jnp.abs(logits(0.5) - logits(0.0)).max()) > 1e-6
+    assert np.abs(logits(0.5) - logits(0.0)).max() > 1e-6
 
 
 def test_the_pair_count_of_a_long_sequence_does_not_pass_an_int32():
@@ -408,44 +321,46 @@ def test_the_two_shares_of_the_experts_add_up_to_the_uncut_layer(tiny):
     4..7 route over all 8 with the same router (the same carried state);
     their parts add up to the uncut reference's expert sublayer."""
     cfg, keys, _ = tiny
-    whole_cfg = dataclasses.replace(cfg, experts_held=0)
-    params = _seeded(whole_cfg, 9)
-    lp = {k: v[1] for k, v in params["layers"][KIND].items()}
+    lp = fb.layer_of(fb.whole(FAMILY, tiny)[1], KIND, 1)
     assert lp["w_gate"].shape[0] == 8
-    rng = np.random.default_rng(5)
-    h = jnp.asarray(rng.standard_normal((1, 29, cfg.hidden_size)), jnp.float32)
-    r_prev = jnp.asarray(rng.standard_normal((1, 29, 8)), jnp.float32)
+    h = fb.normal(5, 1, 29, cfg.hidden_size)
+    r_prev = fb.normal(6, 1, 29, 8)
     whole_keys = dict(keys, num_local_experts=8)
-    with jax.default_matmul_precision("highest"):
-        p, _ = ref.router(whole_keys, h[0], lp, r_prev[0])
-        want = ref.experts(h[0], lp, p)
-    logits, _ = moe_ops.router_mlp(lp, h, cfg.rms_norm_eps, r_prev)
+
+    @jax.jit
+    def reference(h, r_prev, lp):
+        p, _ = ref.router(whole_keys, h, lp, r_prev)
+        parts = [
+            ref.experts(h, fb.holding(lp, first, 4), p, first)
+            for first in (0, 4)
+        ]
+        return ref.experts(h, lp, p), parts, ref.chosen(p, lp)
+
+    want, want_parts, chosen = reference(h[0], r_prev[0], lp)
+
+    @jax.jit
+    def program(h, r_prev, lp):
+        logits, _ = moe_ops.router_mlp(lp, h, cfg.rms_norm_eps, r_prev)
+        return [
+            moe_ops.moe_ffn_scored(
+                h, logits, *(lp[k][first:first + 4] for k in fb.EXPERT_LEAVES),
+                1, False, first_expert=first, router_bias=lp["router_bias"],
+            )
+            for first in (0, 4)
+        ]
+
     total, pairs = 0.0, 0
-    for first in (0, 4):
-        held = slice(first, first + 4)
-        part, stats = moe_ops.moe_ffn_scored(
-            h, logits, lp["w_gate"][held], lp["w_up"][held],
-            lp["w_down"][held], 1, False, first_expert=first,
-            router_bias=lp["router_bias"],
-        )
+    got = program(h, r_prev, lp)
+    for (part, stats), want_part in zip(got, want_parts):
         total = total + np.asarray(part)[0]
         pairs += int(stats.assignments)
         # each share's part is the reference's for the same experts
-        mine = dict(lp, **{k: lp[k][held] for k in ("w_gate", "w_up",
-                                                      "w_down")})
-        with jax.default_matmul_precision("highest"):
-            np.testing.assert_allclose(
-                part[0], ref.experts(h[0], mine, p, first), atol=1e-5
-            )
+        np.testing.assert_allclose(part[0], want_part, atol=1e-5)
     np.testing.assert_allclose(total, want, atol=1e-5)
     assert pairs == 29  # every token's one pair, once
     # a token whose expert is the other holder's gets zeros here
-    away = np.asarray(ref.chosen(p, lp)) >= 4
-    first_part, _ = moe_ops.moe_ffn_scored(
-        h, logits, lp["w_gate"][:4], lp["w_up"][:4], lp["w_down"][:4], 1,
-        False, router_bias=lp["router_bias"],
-    )
-    assert away.any() and not np.asarray(first_part)[0, away].any()
+    away = np.asarray(chosen) >= 4
+    assert away.any() and not np.asarray(got[0][0])[0, away].any()
 
 
 def test_scores_from_outside_run_the_layer_the_product_ran():
@@ -472,7 +387,7 @@ def test_the_mesh_form_takes_the_routers_scores_split_as_the_tokens():
 
     cfg = tiny_config("zaya")
     params = _seeded(cfg, 2)
-    lp = {k: v[0] for k, v in params["layers"][KIND].items()}
+    lp = fb.layer_of(params, KIND)
     mesh = Mesh(
         np.asarray(jax.devices()[:2]).reshape(1, 2, 1, 1, 1),
         ("data", "fsdp", "seq", "expert", "tensor"),
@@ -497,84 +412,28 @@ def test_the_mesh_form_takes_the_routers_scores_split_as_the_tokens():
 
 
 def test_the_refusals_by_name(tiny, tmp_path):
-    from areal_tpu.api.cli_args import JaxGenConfig
-    from areal_tpu.inference.engine import GenerationEngine
-    from areal_tpu.models import hf_io
-
-    cfg, _, params = tiny
-    with pytest.raises(NotImplementedError, match="B.4"):
-        GenerationEngine(JaxGenConfig(), model_config=cfg, params=params)
-    whole = dataclasses.replace(cfg, experts_held=0)
-    with pytest.raises(NotImplementedError, match="zaya"):
-        hf_io.save_params(
-            tr.init_params(whole, jax.random.PRNGKey(1), jnp.float32), whole,
-            str(tmp_path),
-        )
-    for impl, sp in (("ring", 1), ("ulysses", 2), ("auto", 2)):
-        with pytest.raises(ValueError, match="zaya"):
-            resolve_attn_impl(
-                impl, sp, (16, 16), *tr.seq_parallel_refusal(cfg)
-            )
-    assert resolve_attn_impl(
-        "auto", 1, (16, 16), *tr.seq_parallel_refusal(cfg)
-    ) == "xla"
+    fb.check_refusals(FAMILY, tiny, tmp_path, "B.4", "zaya", "zaya")
 
 
-def test_the_trainer_trains_the_family_and_counts_exactly(tiny, monkeypatch):
-    from areal_tpu.api import cli_args
-    from areal_tpu.api.io_struct import FinetuneSpec
-    from areal_tpu.engine.ppo.actor import PPOActor
-    from areal_tpu.engine.spmd_engine import SPMDTrainEngine
-    from areal_tpu.utils import data as data_utils, stats_tracker, tracing
-
-    # rows of 256 tokens for the batch's 185 (the ladder's own step is 2048)
-    monkeypatch.setattr(data_utils, "ROW_QUANTUM", 256)
-    full, _ = cli_args.load_expr_config(
-        ["--config", os.path.join(ROOT, "examples", "gsm8k_grpo.yaml")],
-        cli_args.GRPOConfig,
-    )
-    acfg = full.actor  # the recipe as shipped, no option set
-    acfg.path, acfg.dtype = "", "float32"
-    engine = SPMDTrainEngine(acfg)
-    engine.initialize(
-        ft_spec=FinetuneSpec(1, 1000, 8), model_config=tiny[0], seed=3
-    )
-    assert (engine.attn_impl, engine.moe_impl) == ("xla", "ragged_dot")
-    assert engine._span_attrs["router"] == "mlp_carried"
+def _seed_the_carry(engine):
     # the carried scalar is drawn at the zero a fresh model stores; the
     # benchmark and these tests move it off
     assert np.asarray(
         engine.params["layers"][KIND]["router_carry"]
     ).tolist() == [0.0] * 3
     train_steps_cca.seed_carry(engine.params)
-    actor = PPOActor(acfg, engine)
-    rng = np.random.default_rng(12)
-    lens = [30, 12, 44, 9, 25, 18, 40, 7]
-    seqs = [rng.integers(0, 128, n).astype(np.int32) for n in lens]
-    batch = data_utils.pad_sequences_to_tensors(seqs)
-    lm = batch["attention_mask"].astype(np.int32)
-    lm[:, :3] = 0
-    batch["loss_mask"] = lm
-    batch["logprobs"] = (
-        rng.standard_normal(lm.shape).astype(np.float32) * 0.1 - 4.8
-    ) * lm
-    batch["versions"] = np.where(lm > 0, 0, -1).astype(np.int32)
-    batch["rewards"] = np.tile(np.array([0, 1], np.float32), 4)
-    before = jax.tree_util.tree_map(np.asarray, engine.params["layers"][KIND])
-    seen = []
-    span = tracing.span
 
-    def recording(name, *a, **attrs):
-        seen.append((name, attrs))
-        return span(name, *a, **attrs)
 
-    monkeypatch.setattr(tracing, "span", recording)
-    stats_tracker.export_all()
-    stats = actor.ppo_update(actor.compute_advantages(batch))
-    assert all(s["update_successful"] == 1.0 for s in stats)
+trained = fb.trained_fixture(_seed_the_carry)
+
+
+def test_the_trainer_trains_the_family_and_counts_exactly(trained):
+    engine, lens = trained.engine, trained.lens
+    assert (engine.attn_impl, engine.moe_impl) == ("xla", "ragged_dot")
+    assert engine._span_attrs["router"] == "mlp_carried"
     for name in ("train.grad_dispatch", "train.logp_dispatch"):
-        attrs = [a for n, a in seen if n == name]
-        assert attrs and all(
+        attrs = trained.attrs(name)
+        assert all(
             a["router"] == "mlp_carried" and a["moe_impl"] == "ragged_dot"
             for a in attrs
         ), (name, attrs)
@@ -586,12 +445,13 @@ def test_the_trainer_trains_the_family_and_counts_exactly(tiny, monkeypatch):
     assert 0 < counters["moe_assignments"] < 3 * sum(lens)
     assert counters["moe_stream_rows"] > 0  # the held-share counters
     assert counters["moe_overflow_chunks"] == 0
-    step = stats_tracker.export_all()
+    step = trained.exported
     assert step["spmd/cca_causal_pairs"] == counters["cca_causal_pairs"]
     assert step["spmd/cca_starts"] == counters["cca_starts"]
-    after = engine.params["layers"][KIND]
-    for name in ("wq", "cca_conv1", "w_router_in", "w_router_out", "w_down",
-                 "attn_out_scale"):
-        assert np.abs(np.asarray(after[name]) - before[name]).max() > 0, name
+    trained.moved(KIND, "wq", "cca_conv1", "w_router_in", "w_router_out",
+                  "w_down", "attn_out_scale")
     # state that nothing trains stays what it was
-    np.testing.assert_array_equal(after["router_bias"], before["router_bias"])
+    np.testing.assert_array_equal(
+        engine.params["layers"][KIND]["router_bias"],
+        trained.before[KIND]["router_bias"],
+    )
