@@ -41,15 +41,15 @@ def _forward_fn(
     values), with the stack's ``LayerStats`` beside it when ``stats``."""
     mc = model_config
     moe = None
-    # one layer's sparse leaves as the rules table shards them (the scanned
-    # layer axis gone): what the mesh form gathers from. The sparse kinds
-    # of a stack of block kinds hold the same leaves
+    # one layer's sparse leaves as the mesh's rules table shards them (the
+    # scanned layer axis gone): what a chip of the mesh form holds. The
+    # sparse kinds of a stack of block kinds hold the same leaves
     axes = param_logical_axes(mc)["layers"]
     if mc.layer_kinds:
         axes = next((v for k, v in axes.items() if k.endswith("+moe")), None)
     if mc.is_moe and axes is not None:
         moe = moe_ops.make_sharded_moe_ffn(mc, mesh, {
-            k: sharding_lib.logical_to_spec(axes[k][1:])
+            k: sharding_lib.logical_to_spec(axes[k][1:], mesh=mesh)
             for k in moe_ops.sparse_leaves(mc)
         })
     kda = kda_ops.make_kda_scan(mesh) if mc.kda else None

@@ -308,8 +308,8 @@ class SPMDTrainEngine(TrainEngine):
         if self.attn_impl == "flash":
             self._counters.update(attn_tiles=0, attn_tiles_kept=0)
         # what the model's mechanisms declare (``transformer.MECHANISMS``)
-        self._counters.update(dict.fromkeys(transformer.count_names(mc), 0))
-        self._span_attrs = transformer.span_attrs(mc)
+        self._counters.update(dict.fromkeys(self._count_names(), 0))
+        self._span_attrs = transformer.span_attrs(mc, self._experts_split())
         self.moe_impl, self.dsa_select_impl, self.kda_impl = map(
             self._span_attrs.get, ("moe_impl", "dsa_select_impl", "kda_impl")
         )
@@ -771,7 +771,7 @@ class SPMDTrainEngine(TrainEngine):
             )
         # a stat is a count because the model names it as one; an int32 one
         # (a float32 cannot count a 16k-token row's pairs) is summed exactly
-        count_keys = set(transformer.count_names(self.model_config))
+        count_keys = set(self._count_names())
         int_keys = [
             k for k in all_stats[0] if k in count_keys
             and np.issubdtype(np.asarray(all_stats[0][k]).dtype, np.integer)
@@ -901,6 +901,19 @@ class SPMDTrainEngine(TrainEngine):
     # ------------------------------------------------------------------
     # Save / load / weight push
     # ------------------------------------------------------------------
+    def _experts_split(self) -> bool:
+        """Whether the mesh splits the model's experts among its chips: the
+        sparse layer's mesh form (``ops/moe.make_sharded_moe_ffn``) then runs
+        a share a chip, and the model counts and says what a share does."""
+        return self.model_config.is_moe and bool(
+            sharding_lib.experts_split(self.mesh)
+        )
+
+    def _count_names(self) -> Dict[str, str]:
+        return transformer.count_names(
+            self.model_config, self._experts_split()
+        )
+
     def _host_tree(self, tree, dtype=None):
         """Gather a (possibly cross-process-sharded) pytree to host.
 

@@ -194,9 +194,9 @@ def _kind_leaves(cfg: ModelConfig, kind: str) -> Dict[str, tuple]:
                 "router_carry": ((), "zeros", ()),
             })
         leaves.update({
-            "w_gate": ((E, D, Fe), "normal", ("expert", "embed", "mlp")),
-            "w_up": ((E, D, Fe), "normal", ("expert", "embed", "mlp")),
-            "w_down": ((E, Fe, D), "normal", ("expert", "mlp", "embed")),
+            "w_gate": ((E, D, Fe), "normal", ("expert", "exp_embed", "mlp")),
+            "w_up": ((E, D, Fe), "normal", ("expert", "exp_embed", "mlp")),
+            "w_down": ((E, Fe, D), "normal", ("expert", "mlp", "exp_embed")),
         })
         # the choice's correction bias: state that no gradient reaches
         if cfg.router_act == "sigmoid" or cfg.router is not None:
@@ -346,7 +346,7 @@ def _no_kinds_stack(cfg: ModelConfig) -> str:
 
 # In the order a model's refusals are raised and its counts are made. A
 # kind's two halves are keys (``dense`` declares nothing); ``moe_held``: the
-# sparse FFN of a model that holds a share of its experts.
+# sparse FFN of a chip that holds a share (a model's, or a mesh's split).
 MECHANISMS: Dict[str, Mechanism] = {
     "moe": Mechanism(
         counts={
@@ -451,29 +451,28 @@ MECHANISMS: Dict[str, Mechanism] = {
 }
 
 
-def _mechanisms(cfg: ModelConfig) -> Tuple[Mechanism, ...]:
-    """The model's entries of ``MECHANISMS``, in the table's order."""
+def _mechanisms(cfg: ModelConfig, split=False) -> Tuple[Mechanism, ...]:
+    """Its entries of ``MECHANISMS``, in order (``split``: on such a mesh)."""
     has = {half for kind in cfg.layer_kinds for half in kind.split("+")}
     if not cfg.layer_kinds:  # the one-kind stack
         has = {"indexer"} if cfg.has_indexer else set()
         has |= {"moe"} if cfg.is_moe else set()
-    if "moe" in has and cfg.experts_held:
+    if "moe" in has and (cfg.experts_held or split):
         has.add("moe_held")
     return tuple(m for name, m in MECHANISMS.items() if name in has)
 
 
-def _declared(cfg: ModelConfig, field: str, *args) -> list:
+def _declared(cfg: ModelConfig, field: str, *args, split=False) -> list:
     """What the model's mechanisms declare under ``field`` (those that
-    declare nothing left out, a function called with ``cfg`` and what else
-    it takes)."""
-    said = [getattr(m, field) for m in _mechanisms(cfg)]
+    declare nothing left out, a function called with ``cfg`` and ``args``)."""
+    said = [getattr(m, field) for m in _mechanisms(cfg, split)]
     return [say(cfg, *args) if callable(say) else say for say in said if say]
 
 
-def count_names(cfg: ModelConfig) -> Dict[str, str]:
-    """count -> what it counts, of everything the model's mechanisms count a
-    microbatch (``LayerStats.counts``'s keys)."""
-    return {k: v for c in _declared(cfg, "counts") for k, v in c.items()}
+def count_names(cfg: ModelConfig, split=False) -> Dict[str, str]:
+    """count -> what it counts a microbatch: ``LayerStats.counts``'s keys."""
+    said = _declared(cfg, "counts", split=split)
+    return {k: v for c in said for k, v in c.items()}
 
 
 def seq_parallel_refusal(cfg: ModelConfig) -> Tuple[str, str]:
@@ -488,9 +487,9 @@ def generation_refusal(cfg: ModelConfig) -> str:
     return (_declared(cfg, "no_generation") + [""])[0]
 
 
-def span_attrs(cfg: ModelConfig) -> Dict[str, Any]:
+def span_attrs(cfg: ModelConfig, split=False) -> Dict[str, Any]:
     """What a trainer's dispatch span says of the model's mechanisms."""
-    said = _declared(cfg, "span_attrs")
+    said = _declared(cfg, "span_attrs") + [{"moe_mesh": split and "exchange"}]
     return {k: v for attrs in said for k, v in attrs.items() if v}
 
 
@@ -506,12 +505,13 @@ def grad_span_attrs(
 def _stack_stats(cfg, reported, segment_ids, positions) -> LayerStats:
     """``LayerStats`` of the whole stack on these rows; ``reported`` as
     ``Mechanism.count`` takes it."""
-    aux_loss, counts = jnp.zeros((), jnp.float32), {}
+    aux_loss, counts, split = jnp.zeros((), jnp.float32), {}, False
     if "moe" in reported:  # the mean over the sparse layers
         aux_loss = jnp.mean(
             jnp.concatenate([run.aux_loss for run in reported["moe"]])
         )
-    for m in _mechanisms(cfg):
+        split = reported["moe"][0].stream_rows is not None  # shares ran
+    for m in _mechanisms(cfg, split):
         if m.count is not None:
             arrays = m.count(cfg, reported, segment_ids, positions)
             counts.update(zip(m.counts, arrays, strict=True))
@@ -617,9 +617,9 @@ def init_params(
 def param_logical_axes(cfg: ModelConfig, value_head: bool = False) -> Params:
     """Same-structure tree of logical axis name tuples.
 
-    Logical names: "vocab" (vocab-parallel), "embed" (fsdp-sharded model
-    dim), "heads" (tensor-parallel attention dim), "mlp" (tensor-parallel
-    ffn dim), "layer" (scanned, never sharded), None (replicated).
+    Logical names: "vocab", "embed" (fsdp-sharded model dim), "heads" and
+    "mlp" (tensor-parallel attention and ffn dims), "expert" and
+    "exp_embed" (``parallel/sharding``), "layer" (scanned), None (no axis).
     """
     layers = {
         "input_norm": ("layer", None),
@@ -631,9 +631,9 @@ def param_logical_axes(cfg: ModelConfig, value_head: bool = False) -> Params:
     }
     if cfg.is_moe:
         layers["w_router"] = ("layer", "embed", None)
-        layers["w_gate"] = ("layer", "expert", "embed", "mlp")
-        layers["w_up"] = ("layer", "expert", "embed", "mlp")
-        layers["w_down"] = ("layer", "expert", "mlp", "embed")
+        layers["w_gate"] = ("layer", "expert", "exp_embed", "mlp")
+        layers["w_up"] = ("layer", "expert", "exp_embed", "mlp")
+        layers["w_down"] = ("layer", "expert", "mlp", "exp_embed")
         if cfg.shared_expert_size:
             layers["w_shared_gate"] = ("layer", "embed", "mlp")
             layers["w_shared_up"] = ("layer", "embed", "mlp")

@@ -28,9 +28,9 @@ whole row tiles). The first slab always runs; a chunk whose held pairs pass
 it runs as many more as they fill, in a loop (forward, and again backward:
 no loop is differentiated through), so there is still no capacity and no
 drop at any load. The shares of all the holders add up to the whole layer's
-output; the exchange that would bring each holder the other chips' tokens
-is not here (ROADMAP B.2): it fills this buffer from the wire and inherits
-its rule for the batch that overflows. The statistics then count the pairs
+output: on a mesh that splits the experts (``make_sharded_moe_ffn``) every
+chip is a holder, the tokens are gathered to it and the shares are summed
+and scattered back to their owners. The statistics then count the pairs
 routed to experts held, and two more say how the buffer fared: the rows the
 expert path ran over and the chunks that needed more than one slab.
 
@@ -57,9 +57,9 @@ Device scopes (``utils/tracing.DEVICE_SCOPES``), nested inside the layer's
 ``mlp``: ``moe_route`` (router matmul, softmax, top-k, load statistics),
 ``moe_dispatch`` (sort, permute, un-permute, weighted sum; of a share: the
 sort of every pair, a slab's row gather, and its rows summed back onto
-their tokens: on a TPU the slab's rows put in token order and a grouped
-product over token tiles, ``onto_tokens_banded``; elsewhere the [tokens,
-slab] product), ``moe_experts`` (the grouped matmuls and the gate).
+their tokens: on a TPU in token order by a grouped product over token
+tiles, ``onto_tokens_banded``; elsewhere the [tokens, slab] product),
+``moe_experts`` (grouped matmuls, gate), ``moe_exchange`` (the mesh form's).
 """
 
 import functools
@@ -275,8 +275,8 @@ _combine.defvjp(_combine_fwd, _combine_bwd)
 # 16,384-token row of 8 a token is 131,072 rows, 0.5 GB in bf16 for each of
 # the stream's half-dozen arrays, forward and backward. A layer that holds a
 # share of the experts sorts every pair of a chunk and runs the rest over
-# slabs of it (``_HELD_SLACK``). 32,768 is the four-chip sparse cell's
-# stream (one 4096-token row a chip), which stays one chunk.
+# slabs of it (``_HELD_SLACK``). 32,768 is one chip's 4,096-token row of the
+# four-chip sparse cell: the host's tokens, gathered, run in four chunks.
 _STREAM_ROWS = 32768
 
 
@@ -674,12 +674,12 @@ def moe_ffn(
     ``moe_ffn_scored``, which is this layer from the choice on."""
 
     def scores(xf):
-        # float32 and full precision: with near-equal probabilities a
-        # bf16-pass product can swap a token's k-th and (k+1)-th expert
-        return jnp.dot(
-            xf.astype(jnp.float32), w_router.astype(jnp.float32),
-            precision=jax.lax.Precision.HIGHEST,
-        )
+        # float32 and full precision (``router_scores``): with near-equal
+        # probabilities a bf16-pass product can swap a token's k-th and
+        # (k+1)-th expert. The mesh form makes the same scores, of a
+        # chip's own tokens before they travel, so a token's choice does
+        # not depend on the form
+        return router_scores(xf, w_router)
 
     return _moe_ffn(
         x, scores, w_router.shape[-1], w_gate, w_up, w_down,
@@ -864,36 +864,162 @@ def sparse_leaves(cfg) -> Tuple[str, ...]:
     return names
 
 
+def router_scores(x: jnp.ndarray, w_router: jnp.ndarray) -> jnp.ndarray:
+    """[..., E] float32: ``x @ w_router`` in float32 at full precision."""
+    return jnp.dot(
+        x.astype(jnp.float32), w_router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
+    )
+
+
+# The exchange of the mesh form, as two functions that are each other's
+# transpose: rows go out to every chip, sums come back to the rows' owners.
+# ``rows``: the mesh axes that split both the rows and the experts (the rows
+# are gathered over them, the chips' parts sent to their owners); ``same``:
+# those that split the experts among chips which hold the SAME rows (summed
+# over, nothing moves). A sum across chips accumulates in float32 and is cast
+# once, after, as a token's k pairs (``_weighted_sum``) and a chunk's slabs
+# (``_add``) are: every chip's part travels to the row's owner in its own
+# dtype (an all-to-all: in bf16 half the bytes of a float32 reduce-scatter,
+# which the v5e's compiler also turns into an all-reduce of the whole array
+# when it scatters rows) and the owner widens and sums. The hand-written rule
+# is there for that: the transpose of a bf16 gather would sum the chips'
+# cotangents in bf16.
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2))
+def _to_every_chip(x, rows, same):
+    """[G, ...] -> [n G, ...]: every chip's rows along ``rows``."""
+    if rows:
+        x = jax.lax.all_gather(x, rows, axis=0, tiled=True)
+    if same:
+        x = jax.lax.psum(x.astype(jnp.float32), same).astype(x.dtype)
+    return x
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2))
+def _to_their_owners(y, rows, same):
+    """[n G, ...] -> [G, ...]: the chips' parts of every row summed, each
+    row's sum at the chip that owns the row."""
+    if not (rows or same):
+        return y
+    total = y.astype(jnp.float32)
+    if rows:
+        n = jax.lax.psum(1, rows)  # chips along ``rows``: a Python int
+        total = jax.lax.all_to_all(
+            y.reshape(n, -1, *y.shape[1:]), rows, 0, 0
+        ).sum(0, dtype=jnp.float32)
+    if same:
+        total = jax.lax.psum(total, same)
+    return total.astype(y.dtype)
+
+
+_to_every_chip.defvjp(
+    lambda x, rows, same: (_to_every_chip(x, rows, same), None),
+    lambda rows, same, _, g: (_to_their_owners(g, rows, same),),
+)
+_to_their_owners.defvjp(
+    lambda y, rows, same: (_to_their_owners(y, rows, same), None),
+    lambda rows, same, _, g: (_to_every_chip(g, rows, same),),
+)
+
+
 def make_sharded_moe_ffn(cfg, mesh: Mesh, weight_specs: Dict[str, P]):
     """The sparse layer's mesh form: ``moe_fn(lp, h, valid)`` (and the
     router's scores after them, for a model whose router is an MLP) for
-    ``transformer.apply``, running ``moe_ffn_from_params`` per device inside
-    a ``shard_map`` (None on one device: the plain call serves). Its
-    dispatch sorts the (token, expert) pairs, and a sort over a token axis
-    that GSPMD has sharded would gather it; a Mosaic grouped matmul cannot
-    be partitioned either. Each device routes its own tokens through ALL
-    experts: the layer's expert weights come in as ``weight_specs`` says
-    they are sharded (one ``PartitionSpec`` for each of ``sparse_leaves``,
-    the layer axis gone) and are gathered whole inside (as ZeRO-3 gathers a
-    dense layer's; the transpose reduce-scatters their gradients), tokens
-    never leave their device, and devices that hold the same tokens
-    (tensor, expert axes) repeat the work. The statistics are summed over
-    the axes that split tokens."""
+    ``transformer.apply``, running per device inside a ``shard_map`` (None
+    on one device: the plain call serves). The dispatch sorts the (token,
+    expert) pairs, and a sort over a token axis that GSPMD has sharded would
+    gather it; a Mosaic grouped matmul cannot be partitioned either.
+
+    The experts stay where they are and the tokens travel. The weights come
+    in as ``weight_specs`` says they are sharded (one ``PartitionSpec`` for
+    each of ``sparse_leaves``, the layer axis gone): a chip holds WHOLE
+    experts, its share of the three expert stacks' first dimension
+    (``parallel/sharding.mesh_rules``: over ``expert`` and ``fsdp``), and
+    no collective ever carries an expert stack or its gradient over those
+    axes. A chip scores ITS OWN tokens (the router's product, or an MLP
+    router's scores handed in, split as the tokens are); the tokens, their
+    validity and their float32 scores are gathered over the axes that split
+    both tokens and experts (``fsdp``); the chip runs the layer that holds a
+    share (``moe_ffn_scored`` with its ``first_expert``: the same choice
+    from the same scores, then the slab path over the gathered tokens in
+    chunks of ``_STREAM_ROWS``, dropless at any load); the chips' parts are
+    sent to the tokens' owners, which sum them in float32
+    (``_to_their_owners``; over ``expert``, where the chips hold the same
+    tokens, summed in place). What a leaf is split on otherwise (the
+    router's and a shared expert's model width over ``fsdp``, an expert's
+    own width over ``tensor``) is gathered whole inside, as ZeRO-3 gathers a
+    dense layer's, and the transpose reduce-scatters its gradient; devices
+    that hold the same tokens AND the same experts (``tensor``) repeat the
+    work. A shared expert runs on the chip's own tokens. The statistics are
+    the whole layer's: summed (the busiest expert: the largest) over the
+    chips, ``stream_rows`` and ``overflow_chunks`` among them wherever the
+    mesh splits the experts. A mesh whose expert-splitting axes do not
+    divide the experts held is refused here, when the program is built."""
+    from areal_tpu.parallel.sharding import split_axes
+
     if mesh.size == 1:
         return None
-    token_axes = ("data", "fsdp", "seq")
     specs = {k: weight_specs[k] for k in sparse_leaves(cfg)}
+    split = split_axes(mesh, specs["w_gate"][0])
+    sizes = {a: mesh.shape[a] for a in split}
+    chips = functools.reduce(lambda a, b: a * b, sizes.values(), 1)
+    if cfg.num_held_experts % chips or "seq" in split:
+        raise ValueError(
+            f"the mesh splits the expert stacks over {sizes} ({chips} "
+            f"shares), which must divide the {cfg.num_held_experts} experts "
+            f"held (family {cfg.family!r}) and not split a row's tokens "
+            "(``seq``): a chip holds whole experts, an equal share of them"
+        )
+    share = cfg.num_held_experts // chips
+    token_axes = ("data", "fsdp", "seq")
+    rows = tuple(a for a in split if a in token_axes)
+    same = tuple(a for a in split if a not in token_axes)
 
     def per_device(weights, h, valid, *router_logits):
-        whole = {}
+        lp = {}
         for k, w in weights.items():
-            for dim, axis in enumerate(specs[k]):
-                if axis is not None and mesh.shape[axis] > 1:
-                    w = jax.lax.all_gather(w, axis, axis=dim, tiled=True)
-            whole[k] = w
-        return moe_ffn_from_params(
-            cfg, whole, h, valid, token_axes, *router_logits
+            for dim, names in enumerate(specs[k]):
+                if dim == 0 and k in EXPERT_WEIGHTS[1:]:
+                    continue  # whole experts, where they are
+                axes = split_axes(mesh, names)
+                if axes:
+                    w = jax.lax.all_gather(w, axes, axis=dim, tiled=True)
+            lp[k] = w
+        if router_logits:
+            (logits,) = router_logits
+        else:
+            with device_scope("moe_route"):
+                logits = router_scores(h, lp["w_router"])
+        with device_scope("moe_exchange"):
+            h_all = _to_every_chip(h, rows, ())
+            logits_all = _to_every_chip(logits, rows, ())
+            valid_all = valid if not rows else jax.lax.all_gather(
+                valid, rows, axis=0, tiled=True
+            )
+        out, stats = moe_ffn_scored(
+            h_all, logits_all, lp["w_gate"], lp["w_up"], lp["w_down"],
+            num_experts_per_tok=cfg.num_experts_per_tok,
+            norm_topk_prob=cfg.norm_topk_prob,
+            valid=valid_all,
+            token_axes=tuple(a for a in token_axes if a not in rows),
+            first_expert=jax.lax.axis_index(split) * share if split else 0,
+            router_act=cfg.router_act,
+            router_bias=lp.get("router_bias"),
+            routed_scaling_factor=cfg.routed_scaling_factor,
         )
+        with device_scope("moe_exchange"):
+            out = _to_their_owners(out, rows, same)
+        if split:  # each chip counted its own experts' pairs and rows
+            with device_scope("moe_route"):
+                stats = stats._replace(**jax.lax.psum({
+                    k: getattr(stats, k) for k in
+                    ("assignments", "stream_rows", "overflow_chunks")
+                }, split), max_expert_assignments=jax.lax.pmax(
+                    stats.max_expert_assignments, split
+                ))
+        if cfg.shared_expert_size:
+            out = out + shared_expert_from_params(cfg, lp, h)
+        return out, stats
 
     x_spec = P(("data", "fsdp"), "seq", None)
     # scores made outside (an MLP router's) come in split as the tokens are

@@ -146,6 +146,10 @@ DEVICE_SCOPES = (
     "kv_write",
     # inside ``mlp`` where the layer is sparse (ops/moe.py); innermost wins
     "moe_route", "moe_dispatch", "moe_experts",
+    # the sparse layer's mesh form (ops/moe.make_sharded_moe_ffn): the
+    # tokens and their scores gathered to the chips that hold the experts,
+    # the chips' parts summed and scattered back
+    "moe_exchange",
     # learned sparse attention (ops/sparse_attention.py): the indexer's
     # projections and scores, and the top-k selection; the core it feeds
     # stays under ``attention``

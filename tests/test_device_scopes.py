@@ -18,6 +18,7 @@ import jax.numpy as jnp
 from areal_tpu.api.cli_args import (
     MicroBatchSpec,
     OptimizerConfig,
+    ParallelismConfig,
     PPOActorConfig,
 )
 from areal_tpu.api.io_struct import FinetuneSpec
@@ -33,8 +34,9 @@ from areal_tpu.utils import tracing
 S, BS, PPS = 4, 16, 4
 
 
-def _actor(remat=True, family="qwen2"):
+def _actor(remat=True, family="qwen2", **parallel):
     cfg = PPOActorConfig(
+        parallel=ParallelismConfig(**parallel),
         dtype="float32", param_dtype="float32",
         gradient_checkpointing=remat,
         mb_spec=MicroBatchSpec(max_tokens_per_mb=64),
@@ -85,13 +87,14 @@ def _scopes_in(names):
 _ENGINES = {}  # family -> the engine ``_train_programs`` built for it
 
 
-def _train_programs(family="qwen2", only_grad=False):
+def _train_programs(family="qwen2", only_grad=False, **parallel):
     """(name, lowered) of the trainer's three programs at the tiny config;
     ``only_grad``: of ``grad_step`` alone, and no program is compiled or run
     on the way (a lowering reads the proximal logprobs' shape, so zeros
     serve where ``logp_forward`` would have to be built to compute them)."""
-    actor, eng = _actor(family=family)
-    _ENGINES[family] = eng
+    actor, eng = _actor(family=family, **parallel)
+    if not parallel:
+        _ENGINES[family] = eng
     batch = actor.compute_advantages(_batch())
     if only_grad:
         batch["prox_logp"] = np.zeros_like(batch["logprobs"])
@@ -154,6 +157,10 @@ WANT = {
     "write_rows": {"kv_write"},
     # a sparse layer's three, inside its mlp (innermost wins in a profile)
     "sparse_grad_step": {"mlp", "moe_route", "moe_dispatch", "moe_experts"},
+    # on a mesh that splits the experts: the tokens gathered to the chips
+    # that hold them, the chips' parts summed and scattered back
+    "mesh_sparse_grad_step": {"mlp", "moe_route", "moe_exchange",
+                              "moe_dispatch", "moe_experts"},
     # learned sparse attention: the indexer and the selection before the
     # core, which stays under attention
     "dsa_grad_step": {"attention", "dsa_index", "dsa_select", "moe_experts"},
@@ -195,6 +202,9 @@ def lowered():
     ]:
         grad_steps[program] = _train_programs(family, only_grad=True)[0][1]
         out[program] = _op_names(grad_steps[program])
+    out["mesh_sparse_grad_step"] = _op_names(_train_programs(
+        "olmoe", only_grad=True, fsdp_parallel_size=2
+    )[0][1])
     for program, family in STABLE.items():
         _STABLEHLO[family] = hashlib.sha256(
             grad_steps[program].as_text().encode()
@@ -207,7 +217,8 @@ def test_program_lowers_under_its_contract_name_with_its_scopes(
     lowered, program
 ):
     text, names = lowered[program]
-    jitted = program.removeprefix("sparse_").removeprefix("dsa_")
+    jitted = program.removeprefix("mesh_").removeprefix("sparse_")
+    jitted = jitted.removeprefix("dsa_")
     jitted = jitted.removeprefix("kda_").removeprefix("swa_")
     jitted = jitted.removeprefix("cca_")
     assert re.search(rf"^HloModule jit_{jitted}[, ]", text, re.M)

@@ -78,5 +78,12 @@ def test_the_priced_program_is_the_engines_with_each_layers_mesh_form(
         weights, tokens, valid = kw["in_specs"]
         assert set(weights) == set(moe.EXPERT_WEIGHTS)
         assert tuple(tokens) == (("data", "fsdp"), "seq", None)
+        # a chip holds whole experts, a quarter of them here: the stacks
+        # come in split on their expert dimension and on no model width,
+        # and the router as ZeRO-3 holds it
+        for stack in moe.EXPERT_WEIGHTS[1:]:
+            assert weights[stack][0] == ("expert", "fsdp")
+            assert "fsdp" not in tuple(weights[stack])[1:]
+        assert tuple(weights["w_router"]) == ("fsdp", None)
     assert rep["mesh"] == {"fsdp": 4} and rep["fits"]
     assert "remat_save_attn" not in rep

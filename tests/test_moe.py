@@ -471,6 +471,205 @@ def test_a_held_share_runs_over_a_bounded_buffer_and_drops_nothing(
         )
 
 
+# The sparse layer's mesh form: a chip holds WHOLE experts, its share of them
+# (``parallel/sharding.DEFAULT_RULES``: the expert dimension over ``expert``
+# and ``fsdp``), the tokens are gathered to it and the chips' parts summed
+# and scattered back. Each case: (family of ``tiny_config``, what it does to
+# the layer's leaves, which tokens are padding).
+_MESHES = {
+    "fsdp4": dict(fsdp_parallel_size=4),
+    "fsdp2_expert2": dict(fsdp_parallel_size=2, expert_parallel_size=2),
+}
+
+
+def _to_one_chip(lp):
+    """Every token's two choices are experts 2 and 3, one chip's two: a
+    constant feature (the tokens' first, set to 1) decides the router."""
+    wr = 0.01 * np.asarray(lp["w_router"])
+    wr[0, 2], wr[0, 3] = 20.0, 19.0
+    return dict(lp, w_router=jnp.asarray(wr))
+
+
+def _biased(lp):
+    rng = np.random.default_rng(5)
+    bias = 0.3 * rng.standard_normal(lp["router_bias"].shape)
+    return dict(lp, router_bias=jnp.asarray(bias, jnp.float32))
+
+
+_MESH_CASES = {
+    "padding_in_a_row": ("olmoe", None, lambda i: i % 7 == 3),
+    "every_pair_to_one_chip": ("olmoe", _to_one_chip, None),
+    # Kimi: 8 held of 16 sigmoid-routed ones and a shared expert; Zaya: 4
+    # held of 8, one a token, the scores an MLP's, handed in
+    "shared_expert": ("kimi_linear", None, None),
+    "sigmoid_router_bias": ("kimi_linear", _biased, lambda i: i < 9),
+    "mlp_routers_scores": ("zaya", None, lambda i: i % 5 == 0),
+}
+_MESH_WANT = {}  # case -> what ONE device gives: computed once a case
+
+
+def _mesh_case(case):
+    from areal_tpu.models.transformer import param_logical_axes
+    from areal_tpu.ops import moe
+    from areal_tpu.parallel import sharding as sharding_lib
+
+    family, change, padding = _MESH_CASES[case]
+    cfg = tiny_config(family)
+    params = init_params(cfg, jax.random.PRNGKey(3), dtype=jnp.float32)
+    layers, axes = params["layers"], param_logical_axes(cfg)["layers"]
+    if cfg.layer_kinds:
+        kind = next(k for k in layers if k.endswith("+moe"))
+        layers, axes = layers[kind], axes[kind]
+    layer = {k: v[0] for k, v in layers.items()}
+    lp = {k: layer[k] for k in moe.sparse_leaves(cfg)}
+
+    def specs(mesh):  # as ``engine/programs.py`` reads the mesh's table
+        return {
+            k: sharding_lib.logical_to_spec(axes[k][1:], mesh=mesh)
+            for k in lp
+        }
+
+    rng = np.random.default_rng(4)
+    h = rng.standard_normal((4, 128, cfg.hidden_size)).astype(np.float32)
+    h[..., 0] = 1.0
+    h = jnp.asarray(h)
+    valid = np.ones((4, 128), bool)
+    if padding is not None:
+        valid[1] = ~padding(np.arange(128))
+    scored = ()
+    if cfg.router is not None:  # the scores come in beside the tokens
+        scored = (moe.router_mlp(layer, h, cfg.rms_norm_eps)[0],)
+    if change is not None:
+        lp = change(lp)
+    return cfg, lp, specs, h, jnp.asarray(valid), scored
+
+
+def _layer_and_grads(run, n_scored):
+    """jit of (the gradients of every leaf, of the tokens and of scores
+    handed in; (the output, the statistics)) of a sum over the output plus
+    the auxiliary loss."""
+
+    def loss(lp, h, *scored):
+        out, stats = run(lp, h, *scored)
+        target = jnp.cos(jnp.arange(out.size, dtype=jnp.float32))
+        return (out * target.reshape(out.shape)).sum() + stats.aux_loss, (
+            out, stats
+        )
+
+    return jax.jit(
+        jax.grad(loss, argnums=tuple(range(2 + n_scored)), has_aux=True)
+    )
+
+
+@pytest.mark.parametrize("case", sorted(_MESH_CASES))
+@pytest.mark.parametrize("mesh", sorted(_MESHES))
+def test_the_mesh_form_brings_the_tokens_to_the_experts(
+    mesh, case, monkeypatch
+):
+    """The exchange against the one-device layer: the output, the gradient
+    of every leaf, of the tokens and of scores handed in, the auxiliary loss
+    and the load statistics; and the two counts of a share, summed over the
+    chips. With every pair at ONE chip's experts and slabs of the even share
+    (the slack of 2 taken away), that chip runs four slabs where the others
+    run one: counted, and nothing dropped."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from areal_tpu.ops import moe
+
+    if case == "every_pair_to_one_chip":
+        monkeypatch.setattr(moe, "_HELD_SLACK", 1)
+    cfg, lp, specs, h, valid, scored = _mesh_case(case)
+    if case not in _MESH_WANT:
+        _MESH_WANT[case] = _layer_and_grads(
+            lambda lp, h, *s: moe.moe_ffn_from_params(
+                cfg, lp, h, valid, None, *s
+            ), len(scored),
+        )(lp, h, *scored)
+    want_grads, (want, want_stats) = _MESH_WANT[case]
+    devices = mesh_lib.make_mesh(ParallelismConfig(**_MESHES[mesh]))
+    specs = specs(devices)
+    moe_fn = moe.make_sharded_moe_ffn(cfg, devices, specs)
+    x_spec = P(("data", "fsdp"), "seq", None)
+
+    def put(a, spec):
+        return jax.device_put(a, NamedSharding(devices, spec))
+
+    grads, (out, stats) = _layer_and_grads(
+        lambda lp, h, *s: moe_fn(lp, h, valid, *s), len(scored)
+    )(
+        {k: put(v, specs[k]) for k, v in lp.items()}, put(h, x_spec),
+        *(put(s, x_spec) for s in scored),
+    )
+    # a chip holds whole experts: a quarter of each stack, none of its width
+    shard = grads[0]["w_gate"].addressable_shards[0].data.shape
+    assert shard == (lp["w_gate"].shape[0] // 4,) + lp["w_gate"].shape[1:]
+    np.testing.assert_allclose(out, want, rtol=1e-5, atol=1e-6)
+    if not cfg.shared_expert_size:  # which runs on every token
+        assert np.abs(np.asarray(out)[~np.asarray(valid)]).max(initial=0) == 0
+    for got, ref in zip(
+        jax.tree.leaves(grads), jax.tree.leaves(want_grads), strict=True
+    ):
+        np.testing.assert_allclose(got, ref, rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(stats.aux_loss, want_stats.aux_loss, rtol=1e-6)
+    assert float(stats.assignments) == float(want_stats.assignments)
+    assert float(stats.max_expert_assignments) == float(
+        want_stats.max_expert_assignments
+    )
+    # 512 tokens' pairs a chip; a quarter of the experts a chip (Zaya's and
+    # Kimi's: of those the model holds, an eighth of those it routes over)
+    rows = 512 * cfg.num_experts_per_tok
+    slab = moe._held_rows(rows, cfg.num_held_experts // 4, cfg.num_experts)
+    if case == "every_pair_to_one_chip":
+        assert slab == rows // 4
+        assert float(stats.assignments) == rows
+        assert float(stats.stream_rows) == (4 + 3) * slab
+        assert float(stats.overflow_chunks) == 1
+    else:
+        assert float(stats.stream_rows) == 4 * slab
+        assert float(stats.overflow_chunks) == 0
+
+
+def test_a_sum_across_chips_accumulates_in_float32_and_is_cast_once():
+    """Both directions of the exchange: 1 + 3 x 2^-9 is 1.0078125 in bf16
+    rounded once, and 1 where each chip's part is added in bf16."""
+    from jax.sharding import PartitionSpec as P
+
+    from areal_tpu.ops import moe
+
+    devices = mesh_lib.make_mesh(ParallelismConfig(fsdp_parallel_size=4))
+    parts = np.full((4, 4, 2), 2.0 ** -9, np.float32)  # chip, row, width
+    parts[0] = 1.0
+
+    def on_chips(f):
+        return jax.jit(jax.shard_map(
+            f, mesh=devices, in_specs=P("fsdp"), out_specs=P("fsdp"),
+            check_vma=False,
+        ))
+
+    chips_parts = jnp.asarray(parts.reshape(16, 2), jnp.bfloat16)
+    summed = on_chips(lambda y: moe._to_their_owners(y, ("fsdp",), ()))(
+        chips_parts
+    )
+    assert summed.dtype == jnp.bfloat16 and summed.shape == (4, 2)
+    assert np.asarray(summed, np.float32).tolist() == [[1.0078125] * 2] * 4
+    # the gather's transpose is the same sum
+    back = on_chips(lambda g: jax.vjp(
+        lambda x: moe._to_every_chip(x, ("fsdp",), ()),
+        jnp.zeros((1, 2), jnp.bfloat16),
+    )[1](g)[0])(chips_parts)
+    assert back.dtype == jnp.bfloat16
+    assert np.asarray(back, np.float32).tolist() == [[1.0078125] * 2] * 4
+
+
+def test_a_mesh_that_does_not_divide_the_experts_is_refused_by_name():
+    from areal_tpu.ops import moe
+
+    cfg, _lp, specs, *_ = _mesh_case("padding_in_a_row")
+    devices = mesh_lib.make_mesh(ParallelismConfig(fsdp_parallel_size=3))
+    with pytest.raises(ValueError, match=r"\{'fsdp': 3\}.*8 experts"):
+        moe.make_sharded_moe_ffn(cfg, devices, specs(devices))
+
+
 def test_moe_model_forward_and_ep_parity():
     """Full qwen3_moe forward; EP=2-sharded params give identical logits
     to unsharded execution."""
@@ -544,12 +743,25 @@ def test_moe_training_step_with_aux_loss():
         assert np.isfinite(stats["router_aux_loss"])
         losses.append(stats["loss"])
     assert losses[-1] < losses[0]
-    # every expert is held: the two counts of a held share do not exist
-    assert set(engine.counters()) == {
+    # the mesh splits the experts, a quarter a chip: the two counts of a
+    # share are the chips' summed, and the dispatch spans name the form
+    counters = engine.counters()
+    assert set(counters) == {
         "real_tokens", "padded_tokens", "moe_assignments",
-        "moe_max_expert_assignments",
+        "moe_max_expert_assignments", "moe_stream_rows",
+        "moe_overflow_chunks",
     }
-    assert "moe_stream_rows" not in stats
+    assert engine._span_attrs["moe_mesh"] == "exchange"
+    mc = engine.model_config
+    assert mc.num_experts == 4 and mc.experts_held == 0  # one a chip
+    pairs_a_token = mc.num_layers * mc.num_experts_per_tok
+    assert counters["moe_assignments"] == 3 * 4 * L * pairs_a_token
+    # each of the four chips runs one slab of the gathered rows' stream,
+    # padding's rows among them: twice its even share of it, so half
+    assert counters["moe_stream_rows"] == (
+        4 * counters["padded_tokens"] * pairs_a_token // 2
+    )
+    assert stats["moe_overflow_chunks"] == 0
 
 
 @pytest.mark.parametrize("family", ["qwen3_moe", "olmoe"])

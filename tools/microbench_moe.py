@@ -5,6 +5,7 @@ tilings, ``ragged_dot``), all under one ``jax.profiler`` capture.
 
     python -m tools.microbench_moe [--tokens 4096] [--out chiprun_out/moe_sweep]
     python -m tools.microbench_moe --held 16 --widths 2048 768 128 8
+    python -m tools.microbench_moe --mesh 4
 
 ``--held N``: the layer HOLDS the first N of the E experts it routes over (one
 token chunk of a cell: 4,096 tokens of 16 of 128 in the long-row cell,
@@ -20,6 +21,20 @@ over token tiles of the slab's rows in token order (``side_banded_<m>_<token
 tile>_<n>``: ``ops/moe.onto_tokens_banded`` over ``ONTO_TILINGS``, the form a
 TPU runs since PR 46), each with its device time and its largest difference
 from the product.
+
+``--mesh N`` (all of a host's chips: ``chiprun --chips 4``): the layer's
+MESH FORM, ``ops/moe.make_sharded_moe_ffn`` on an ``fsdp=N`` mesh, N rows of
+``--tokens`` (the four-chip sparse cell: 4 x 4,096 tokens, 8 of 64, D 2,048,
+F 1,024), forward and forward + backward, the weights laid out as the engine
+lays them (``parallel/sharding.tree_shardings``). Beside the milliseconds
+(the mean over the chips, by device scope, ``moe_exchange`` among them, and
+the longest operations, the collectives by name) it prints what sets a
+step's pace there: each chip's held pairs a token chunk, the largest chip
+over the mean, with the router drawn as here (``--shared s``: every token
+is its own draw plus s times ONE common direction, as tokens of a residual
+stream share most of theirs; the choice of a token's experts does not depend
+on the router's scale, it does on what the tokens have in common: 0.7 reads
+the cell's 3.35 an expert) and the two counts of a share.
 
 The times are device times of the jitted programs and of the device scopes
 ``moe_route`` / ``moe_dispatch`` / ``moe_experts`` from
@@ -63,7 +78,14 @@ def main():
                     help="the first so many of TILINGS")
     ap.add_argument("--held", type=int, default=0,
                     help="the layer holds the first so many of the E experts")
+    ap.add_argument("--mesh", type=int, default=0,
+                    help="the mesh form on so many chips, a row a chip")
+    ap.add_argument("--shared", type=float, default=0.0,
+                    help="--mesh: how much of one common direction each "
+                    "token holds beside its own draw (skews the load)")
     args = ap.parse_args()
+    if args.mesh:
+        return mesh_sweep(args)
     t, (d, f, e, k) = args.tokens, args.widths
     rng = np.random.default_rng(0)
     bf = jnp.bfloat16
@@ -156,7 +178,10 @@ def report(traced, flops, prefixes, longest=0):
     for prog, p in sorted(r["programs"].items()):
         if not prog.startswith(prefixes):
             continue
-        runs = max(p["runs"], 1)
+        # a program's seconds are the mean over the chips; its runs are
+        # counted a chip
+        every = max(p["runs"], 1)
+        runs = every / max(r.get("devices") or 1, 1)
         sc = {s: round(1e3 * v / runs, 3)
               for s, v in r["program_scopes"].get(prog, {}).items()}
         passes = 3 if prog.startswith("fwdbwd_") else 1
@@ -172,7 +197,7 @@ def report(traced, flops, prefixes, longest=0):
                            program_trace.scope_of(ev[5].get("op_name")))
                     ops[key] = ops.get(key, 0) + ev[4]  # ns
             rows[prog]["longest_ops_ms"] = [
-                (n, sc_, round(v / 1e6 / runs, 3)) for (n, sc_), v in
+                (n, sc_, round(v / 1e6 / every, 3)) for (n, sc_), v in
                 sorted(ops.items(), key=lambda kv: -kv[1])[:longest]
             ]
         print(prog, json.dumps(rows[prog]), flush=True)
@@ -291,6 +316,95 @@ def held_sweep(args, x, ws, valid, flops):
     _, out = report(traced, flops, ("fwd_", "fwdbwd_", "side_"), longest=12)
     with open(os.path.join(harness.ROOT, args.out, "sweep.json"), "w") as fh:
         json.dump(out, fh, indent=1)
+
+
+def mesh_sweep(args):
+    """The layer's mesh form on ``--mesh`` chips, and how evenly its routers
+    load them."""
+    import dataclasses
+
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from areal_tpu.api.cli_args import ParallelismConfig
+    from areal_tpu.models.transformer import param_logical_axes
+    from areal_tpu.parallel import feasibility
+    from areal_tpu.parallel import mesh as mesh_lib
+    from areal_tpu.parallel import sharding as sharding_lib
+
+    n, t, (d, f, e, k) = args.mesh, args.tokens, args.widths
+    cfg = dataclasses.replace(
+        feasibility.olmoe_1b_7b_config(1), hidden_size=d,
+        moe_intermediate_size=f, num_experts=e, num_experts_per_tok=k,
+    )
+    mesh = mesh_lib.make_mesh(ParallelismConfig(fsdp_parallel_size=n))
+    laid_out = sharding_lib.tree_shardings(
+        mesh, param_logical_axes(cfg)
+    )["layers"]
+    specs = {name: P(*laid_out[name].spec[1:]) for name in moe.EXPERT_WEIGHTS}
+    layer = moe.make_sharded_moe_ffn(cfg, mesh, specs)
+    rng = np.random.default_rng(0)
+    bf = jnp.bfloat16
+
+    def put(a, spec):
+        return jax.device_put(jnp.asarray(a, bf), NamedSharding(mesh, spec))
+
+    x = rng.standard_normal((n, t, d)) + (
+        args.shared * rng.standard_normal(d)
+    )
+    x = put(x, P(("data", "fsdp"), "seq", None))
+    valid = np.broadcast_to(np.arange(t) < int(t * 0.86), (n, t))  # 14% padding
+    shapes = {"w_router": (d, e), "w_gate": (e, d, f), "w_up": (e, d, f),
+              "w_down": (e, f, d)}
+    lp = {
+        name: put(rng.standard_normal(shape) * 0.02, specs[name])
+        for name, shape in shapes.items()
+    }
+
+    def fwd(lp, x):
+        return layer(lp, x, jnp.asarray(valid))
+
+    def loss(lp, x):
+        return fwd(lp, x)[0].astype(jnp.float32).sum()
+
+    fwd.__name__ = "fwd_mesh"
+    both = lambda *a: jax.grad(loss, argnums=(0, 1))(*a)  # noqa: E731
+    both.__name__ = "fwdbwd_mesh"
+    fwd, both = jax.jit(fwd), jax.jit(both)
+    out, stats = fwd(lp, x)
+    jax.block_until_ready(both(lp, x))
+    harness.log(f"specs: {specs}")
+    harness.log("stats: " + ", ".join(
+        f"{fld}={float(v):.0f}" for fld, v in zip(stats._fields, stats)
+        if fld != "aux_loss" and v is not None
+    ) + f", finite={bool(np.isfinite(np.asarray(out, np.float32)).all())}")
+    # each chip's held pairs a token chunk: the same scores, the same choice
+    scores = moe.router_scores(x, lp["w_router"])
+    choice = np.asarray(moe.router_topk(
+        scores.reshape(-1, e), k, cfg.norm_topk_prob
+    )[1])
+    chunks = moe._token_chunks(n * t, k)
+    chip = np.where(valid.reshape(-1, 1), choice * n // e, n).reshape(chunks, -1)
+    held = np.stack([(chip == c).sum(1) for c in range(n)], 1)  # [chunk, chip]
+    slab = moe._held_rows(n * t * k // chunks, e // n, e)
+    load = np.bincount(choice[valid.reshape(-1)].reshape(-1), minlength=e)
+    print(json.dumps({
+        "chips": n, "chunks": chunks, "slab_rows": slab,
+        "held_pairs_a_chunk_by_chip": held.tolist(),
+        "chip_largest_over_mean": round(
+            float(held.sum(0).max() / held.sum(0).mean()), 4),
+        "chunk_largest_over_slab": round(float(held.max() / slab), 4),
+        "expert_largest_over_mean": round(
+            float(load.max() / load.mean()), 4),
+    }), flush=True)
+    traced = harness.TracedWindow(True, os.path.join(harness.ROOT, args.out))
+    with traced:
+        for _ in range(RUNS):
+            jax.block_until_ready(fwd(lp, x))
+            jax.block_until_ready(both(lp, x))
+    flops = 2.0 * int(t * 0.86) * k * 3 * d * f  # a chip's share of n rows'
+    _, rows = report(traced, flops, ("fwd_", "fwdbwd_"), longest=16)
+    with open(os.path.join(harness.ROOT, args.out, "sweep.json"), "w") as fh:
+        json.dump(rows, fh, indent=1)
 
 
 if __name__ == "__main__":

@@ -2,14 +2,18 @@
 tracing it and lowering it, off the chip, for a described v5e.
 
     python -m tools.setup_cost [--model kimi_linear_48b_a3b] [--tokens 8192]
+    python -m tools.setup_cost --model olmoe_1b_7b --tokens 4096 --fsdp 4
 
 A warm start pays this for every program it runs (the cache's key is the
 lowered module), and a Pallas kernel is lowered to its Mosaic module in
 Python at every place its call occurs in the lowered text, cache hit or
 miss. One JSON line a program (``grad_step``, ``logp_forward``: the
 engine's own builders, gradient checkpointing on, one packed row of
-``--tokens``): seconds to trace, seconds to lower, the ``tpu_custom_call``s
-of the lowered text by kernel name, the text's size. The seconds are this
+``--tokens``; ``--fsdp n``: a row a chip on the first n of the described
+host's four, the parameters laid out as the engine lays them): seconds to
+trace, seconds to lower, the ``tpu_custom_call``s of the lowered text by
+kernel name, the text's size (``--compile``: and the compiled program's bytes
+a device, with the collectives the compiler put in by name). The seconds are this
 sandbox's CPU's and compare one tree with another on the same machine; no
 number here is a device's.
 
@@ -53,7 +57,30 @@ def custom_calls(text: str) -> dict:
     return dict(sorted(names.items()))
 
 
-def program_cost(jitted, *args) -> dict:
+_COLLECTIVE = re.compile(
+    r"= (\S+) (all-gather|all-reduce|reduce-scatter|all-to-all|"
+    r"collective-permute)(?:-start)?\("
+)
+
+
+def compiled_cost(lowered) -> dict:
+    """What the described chip's compiler makes of a lowered program: the
+    bytes a device (arguments, temporaries) and its collectives, by kind and
+    result shape, with how often each occurs."""
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    found = collections.Counter(
+        f"{kind} {shape}" for shape, kind in
+        _COLLECTIVE.findall(compiled.as_text())
+    )
+    return {
+        "argument_gb": round(mem.argument_size_in_bytes / 1e9, 3),
+        "temp_gb": round(mem.temp_size_in_bytes / 1e9, 3),
+        "collectives": dict(sorted(found.items())),
+    }
+
+
+def program_cost(jitted, *args, compile: bool = False) -> dict:
     t0 = time.perf_counter()
     traced = jitted.trace(*args)
     t1 = time.perf_counter()
@@ -65,39 +92,51 @@ def program_cost(jitted, *args) -> dict:
         "lower_s": round(t2 - t1, 2),
         "custom_calls": custom_calls(text),
         "text_bytes": len(text),
+        **(compiled_cost(lowered) if compile else {}),
     }
 
 
-def trainer_programs(mc, mesh, tokens: int, attend, rows: int = 1) -> dict:
+def trainer_programs(
+    mc, mesh, tokens: int, attend, rows: int = 1, param_dtype=None,
+) -> dict:
     """``{name: (jitted program, abstract arguments)}``: the engine's
     ``grad_step`` (gradient checkpointing on, the GRPO loss) and
-    ``logp_forward`` for ``rows`` packed rows of ``tokens`` on ``mesh``."""
+    ``logp_forward`` for ``rows`` packed rows of ``tokens`` on ``mesh``;
+    the parameters in ``param_dtype`` (None: bf16; the cells' engines hold
+    float32, which is what a reading of memory needs)."""
     from areal_tpu.engine import programs
     from areal_tpu.models.transformer import init_params
     from areal_tpu.ops.functional import target_aligned_logprobs
     from areal_tpu.parallel import feasibility
 
-    one = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
+    from areal_tpu.models.transformer import param_logical_axes
+    from areal_tpu.parallel import sharding as sharding_lib
 
-    def sds(tree, dtype=None):
+    def sds(tree, dtype=None, shardings=None):
+        if shardings is None:
+            shardings = jax.tree_util.tree_map(lambda s: s.sharding, tree)
         return jax.tree_util.tree_map(
-            lambda s: jax.ShapeDtypeStruct(
-                s.shape, dtype or s.dtype, sharding=one
+            lambda s, sh: jax.ShapeDtypeStruct(
+                s.shape, dtype or s.dtype, sharding=sh
             ),
-            tree,
+            tree, shardings,
         )
 
-    params = sds(jax.eval_shape(
-        lambda: init_params(mc, jax.random.PRNGKey(0))
+    shapes = jax.eval_shape(lambda: init_params(mc, jax.random.PRNGKey(0)))
+    # as the engine lays a model and its rows out
+    params = sds(shapes, param_dtype, sharding_lib.tree_shardings(
+        mesh, param_logical_axes(mc)
     ))
+    rows_sh = sharding_lib.batch_sharding(mesh)
     row = jax.ShapeDtypeStruct((rows, tokens), jnp.int32)
-    arrays = sds({
+    arrays = {
         **{k: row for k in
            ("tokens", "segment_ids", "positions", "t_loss_mask")},
         **{k: jax.ShapeDtypeStruct(row.shape, jnp.float32) for k in
            ("t_logprobs", "t_prox_logp", "t_advantages")},
         "s_rewards": jax.ShapeDtypeStruct((rows, 8), jnp.float32),
-    })
+    }
+    arrays = sds(arrays, shardings={k: rows_sh for k in arrays})
     return {
         "grad_step": (
             programs.build_grad_step(
@@ -129,23 +168,34 @@ def main(argv=None):
     ap.add_argument("--model", default="kimi_linear_48b_a3b",
                     choices=sorted(feasibility.MODEL_CONFIGS))
     ap.add_argument("--tokens", type=int, default=8192)
+    ap.add_argument("--fsdp", type=int, default=1, choices=(1, 2, 4),
+                    help="chips of the described host, a row of --tokens each")
+    ap.add_argument("--param-dtype", default="bfloat16",
+                    choices=("bfloat16", "float32"))
+    ap.add_argument("--compile", action="store_true",
+                    help="compile too: bytes a device, collectives by name")
     args = ap.parse_args(argv)
 
     topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     jax.default_backend = lambda: "tpu"  # the ops' platform rules
     flash._PROBED_BLOCK = 2048  # what the chip's probe settles on
     mc = feasibility.MODEL_CONFIGS[args.model]()
-    mesh = mesh_lib.make_mesh(ParallelismConfig(), topo.devices[:1])
+    mesh = mesh_lib.make_mesh(
+        ParallelismConfig(fsdp_parallel_size=args.fsdp),
+        topo.devices[:args.fsdp],
+    )
     attend = attention_fn(
         "sparse_topk" if mc.has_indexer else "flash", mesh,
         topk=mc.dsa_topk if mc.has_indexer else 0,
     )
     for name, (jitted, a) in trainer_programs(
-        mc, mesh, args.tokens, attend
+        mc, mesh, args.tokens, attend, rows=args.fsdp,
+        param_dtype=jnp.dtype(args.param_dtype),
     ).items():
         print(json.dumps({
             "model": args.model, "tokens": args.tokens, "program": name,
-            **program_cost(jitted, *a),
+            "chips": args.fsdp,
+            **program_cost(jitted, *a, compile=args.compile),
         }), flush=True)
 
 
