@@ -61,9 +61,10 @@ def _forward_fn(
     act_sh = NamedSharding(mesh, P(("data", "fsdp"), "seq", None))
 
     def forward(params, arrays):
-        cparams = jax.tree_util.tree_map(
-            lambda p: p.astype(compute_dtype), params
-        )
+        with tracing.device_scope("param_cast"):
+            cparams = jax.tree_util.tree_map(
+                lambda p: p.astype(compute_dtype), params
+            )
         return packed_forward(
             cparams, mc, arrays, remat=remat, attend_fn=attend_fn,
             moe_fn=moe, kda_fn=kda, return_stats=stats, return_hidden=True,
@@ -106,9 +107,10 @@ def build_grad_step(
         grads, (loss, stats, w) = jax.grad(fwd_loss, has_aux=True)(
             params, arrays
         )
-        new_accum = jax.tree_util.tree_map(
-            lambda a, g: a + g.astype(jnp.float32), grad_accum, grads
-        )
+        with tracing.device_scope("grad_add"):
+            new_accum = jax.tree_util.tree_map(
+                lambda a, g: a + g.astype(jnp.float32), grad_accum, grads
+            )
         return new_accum, loss, stats, w
 
     return jax.jit(grad_step, donate_argnums=(1,))

@@ -694,31 +694,38 @@ def _layer_body(
 ):
     b, t, d = x.shape
     uo = cfg.norm_add_unit_offset
-    h = rms_norm(x, lp["input_norm"], cfg.rms_norm_eps, add_unit_offset=uo)
-    q = h @ lp["wq"]
-    k = h @ lp["wk"]
-    v = h @ lp["wv"]
-    if cfg.attention_bias:
-        q = q + lp["bq"]
-        k = k + lp["bk"]
-        v = v + lp["bv"]
-    if cfg.qk_norm == "projection":  # olmoe: before the split into heads
-        q = rms_norm(q, lp["q_norm"], cfg.rms_norm_eps)
-        k = rms_norm(k, lp["k_norm"], cfg.rms_norm_eps)
-    q = q.reshape(b, t, cfg.num_heads, cfg.head_dim)
-    k = k.reshape(b, t, cfg.num_kv_heads, cfg.head_dim)
-    v = v.reshape(b, t, cfg.num_kv_heads, cfg.head_dim)
-    if cfg.qk_norm == "head":  # qwen3: each head by itself
-        q = rms_norm(q, lp["q_norm"], cfg.rms_norm_eps)
-        k = rms_norm(k, lp["k_norm"], cfg.rms_norm_eps)
-    if positions.ndim == 3:  # [B, T, 3] multimodal (t, h, w) positions
-        q = apply_mrope(q, positions, cos, sin, cfg.mrope_sections)
-        k = apply_mrope(k, positions, cos, sin, cfg.mrope_sections)
-    else:
-        q = apply_rope(q, positions, cos, sin)
-        k = apply_rope(k, positions, cos, sin)
     # device scopes (utils/tracing.DEVICE_SCOPES): names only — what a
-    # profile's per-op shares are read by; no number and no fusion changes
+    # profile's per-op shares are read by; no number and no fusion changes.
+    # Each is opened HERE, at the call site, never inside a helper that
+    # another scope's code calls too (``rms_norm``, ``apply_rope``)
+    with device_scope("stream_norm"):
+        h = rms_norm(
+            x, lp["input_norm"], cfg.rms_norm_eps, add_unit_offset=uo
+        )
+    with device_scope("qkv_proj"):
+        q = h @ lp["wq"]
+        k = h @ lp["wk"]
+        v = h @ lp["wv"]
+        if cfg.attention_bias:
+            q = q + lp["bq"]
+            k = k + lp["bk"]
+            v = v + lp["bv"]
+        if cfg.qk_norm == "projection":  # olmoe: before the split into heads
+            q = rms_norm(q, lp["q_norm"], cfg.rms_norm_eps)
+            k = rms_norm(k, lp["k_norm"], cfg.rms_norm_eps)
+        q = q.reshape(b, t, cfg.num_heads, cfg.head_dim)
+        k = k.reshape(b, t, cfg.num_kv_heads, cfg.head_dim)
+        v = v.reshape(b, t, cfg.num_kv_heads, cfg.head_dim)
+        if cfg.qk_norm == "head":  # qwen3: each head by itself
+            q = rms_norm(q, lp["q_norm"], cfg.rms_norm_eps)
+            k = rms_norm(k, lp["k_norm"], cfg.rms_norm_eps)
+    with device_scope("rope"):
+        if positions.ndim == 3:  # [B, T, 3] multimodal (t, h, w) positions
+            q = apply_mrope(q, positions, cos, sin, cfg.mrope_sections)
+            k = apply_mrope(k, positions, cos, sin, cfg.mrope_sections)
+        else:
+            q = apply_rope(q, positions, cos, sin)
+            k = apply_rope(k, positions, cos, sin)
     dsa = None
     if cfg.has_indexer:
         # the indexer reads the layer's normed input, detached: it picks
@@ -745,7 +752,10 @@ def _layer_body(
     # kernel and 3.8 ms with the XLA form at 4096 tokens (v5e, PERF.md
     # PR 26), against [B,T,Hq,D] bf16 of storage
     attn = _ad_checkpoint.checkpoint_name(attn, "attn_out")
-    x = x + attn.reshape(b, t, cfg.q_dim) @ lp["wo"]
+    with device_scope("o_proj"):
+        y = attn.reshape(b, t, cfg.q_dim) @ lp["wo"]
+    with device_scope("residual"):
+        x = x + y
     return _ffn_residual(cfg, x, lp, segment_ids, moe_fn, cfg.is_moe, dsa)
 
 
@@ -756,9 +766,10 @@ def _ffn_residual(
     ops report: (the routed experts' ``MoEStats``, ``mixer_stats``)).
     ``sparse``: routed experts (with the shared one), else the dense MLP."""
     uo = cfg.norm_add_unit_offset
-    h = rms_norm(
-        x, lp["post_attn_norm"], cfg.rms_norm_eps, add_unit_offset=uo
-    )
+    with device_scope("stream_norm"):
+        h = rms_norm(
+            x, lp["post_attn_norm"], cfg.rms_norm_eps, add_unit_offset=uo
+        )
     if sparse:
         # padding tokens (segment 0) are no expert's load
         with device_scope("mlp"):
@@ -768,13 +779,16 @@ def _ffn_residual(
                 )
             else:  # per device under a mesh (a shard_map of the same)
                 ffn, stats = moe_fn(lp, h, segment_ids > 0)
-        return x + ffn, (stats, mixer_stats)
-    with device_scope("mlp"):
-        ffn = (
-            hidden_act_fn(cfg.hidden_act)(h @ lp["w_gate"])
-            * (h @ lp["w_up"])
-        ) @ lp["w_down"]
-    return x + ffn, (None, mixer_stats)
+    else:
+        stats = None
+        with device_scope("mlp"):
+            ffn = (
+                hidden_act_fn(cfg.hidden_act)(h @ lp["w_gate"])
+                * (h @ lp["w_up"])
+            ) @ lp["w_down"]
+    with device_scope("residual"):  # outside ``mlp``, as the norm above is
+        x = x + ffn
+    return x, (stats, mixer_stats)
 
 
 def _short_conv(x, w, segment_ids):
@@ -801,7 +815,8 @@ def _kda_mixer(cfg, h, lp, segment_ids, kda_fn):
     b, t, _ = h.shape
     kc = cfg.kda
     H, dh = kc.num_heads, kc.head_dim
-    proj = {n: h @ lp[f"w{n}"] for n in "qkv"}
+    with device_scope("qkv_proj"):
+        proj = {n: h @ lp[f"w{n}"] for n in "qkv"}
     with device_scope("kda_prep"):
         def conv(n):
             y = jax.nn.silu(_short_conv(proj[n], lp[f"conv_{n}"], segment_ids))
@@ -832,7 +847,8 @@ def _kda_mixer(cfg, h, lp, segment_ids, kda_fn):
         ).reshape(b, t, H, dh)
         y = rms_norm(o, lp["o_norm"], cfg.rms_norm_eps)
         y = (y.astype(jnp.float32) * gate).astype(h.dtype)
-    return y.reshape(b, t, H * dh) @ lp["wo"], stats
+    with device_scope("o_proj"):
+        return y.reshape(b, t, H * dh) @ lp["wo"], stats
 
 
 def _mla_mixer(cfg, h, lp, segment_ids, attend_fn):
@@ -841,28 +857,30 @@ def _mla_mixer(cfg, h, lp, segment_ids, attend_fn):
     causal softmax core whose query/key width differs from its value's."""
     b, t, _ = h.shape
     m, H = cfg.mla, cfg.num_heads
-    q = (h @ lp["wq"]).reshape(b, t, H, m.qk_head_dim)
-    c, k_shared = jnp.split(h @ lp["w_kv_a"], [m.kv_lora_rank], axis=-1)
-    c = rms_norm(c, lp["kv_norm"], cfg.rms_norm_eps)
-    k_nope, v = jnp.split(
-        (c @ lp["w_kv_b"]).reshape(
-            b, t, H, m.qk_nope_head_dim + m.v_head_dim
-        ),
-        [m.qk_nope_head_dim], axis=-1,
-    )
-    k = jnp.concatenate([
-        k_nope,
-        jnp.broadcast_to(
-            k_shared[:, :, None, :], (b, t, H, m.qk_rope_head_dim)
-        ),
-    ], axis=-1)
+    with device_scope("qkv_proj"):
+        q = (h @ lp["wq"]).reshape(b, t, H, m.qk_head_dim)
+        c, k_shared = jnp.split(h @ lp["w_kv_a"], [m.kv_lora_rank], axis=-1)
+        c = rms_norm(c, lp["kv_norm"], cfg.rms_norm_eps)
+        k_nope, v = jnp.split(
+            (c @ lp["w_kv_b"]).reshape(
+                b, t, H, m.qk_nope_head_dim + m.v_head_dim
+            ),
+            [m.qk_nope_head_dim], axis=-1,
+        )
+        k = jnp.concatenate([
+            k_nope,
+            jnp.broadcast_to(
+                k_shared[:, :, None, :], (b, t, H, m.qk_rope_head_dim)
+            ),
+        ], axis=-1)
     with device_scope("attention"):
         if attend_fn is None:
             attn = segment_attention(q, k, v, segment_ids, causal=True)
         else:
             attn = attend_fn(q, k, v, segment_ids)
     attn = _ad_checkpoint.checkpoint_name(attn, "attn_out")
-    return attn.reshape(b, t, H * m.v_head_dim) @ lp["wo"]
+    with device_scope("o_proj"):
+        return attn.reshape(b, t, H * m.v_head_dim) @ lp["wo"]
 
 
 def _rotary_mixer(cfg, rc, h, lp, segment_ids, cos, sin, attend_fn):
@@ -878,14 +896,17 @@ def _rotary_mixer(cfg, rc, h, lp, segment_ids, cos, sin, attend_fn):
     H, dh = rc.num_heads, cfg.head_dim
 
     def rotated(w, heads, norm):
-        x = (h @ lp[w]).reshape(b, t, heads, dh)
-        if cfg.qk_norm == "head":
-            x = rms_norm(x, lp[norm], cfg.rms_norm_eps)
-        return rotate_leading(x, cos, sin)
+        with device_scope("qkv_proj"):
+            x = (h @ lp[w]).reshape(b, t, heads, dh)
+            if cfg.qk_norm == "head":
+                x = rms_norm(x, lp[norm], cfg.rms_norm_eps)
+        with device_scope("rope"):
+            return rotate_leading(x, cos, sin)
 
     q = rotated("wq", H, "q_norm")
     k = rotated("wk", cfg.num_kv_heads, "k_norm")
-    v = (h @ lp["wv"]).reshape(b, t, cfg.num_kv_heads, dh)
+    with device_scope("qkv_proj"):
+        v = (h @ lp["wv"]).reshape(b, t, cfg.num_kv_heads, dh)
     with device_scope("attn_window" if rc.window else "attention"):
         if attend_fn is None:
             attn = segment_attention(
@@ -896,9 +917,15 @@ def _rotary_mixer(cfg, rc, h, lp, segment_ids, cos, sin, attend_fn):
             attn = attend_fn(q, k, v, segment_ids, **own)
     attn = _ad_checkpoint.checkpoint_name(attn, "attn_out")
     if rc.head_gate:
-        gate = jax.nn.sigmoid((h @ lp["w_head_gate"]).astype(jnp.float32))
-        attn = (attn.astype(jnp.float32) * gate[..., None]).astype(h.dtype)
-    return attn.reshape(b, t, H * dh) @ lp["wo"]
+        with device_scope("attn_gate"):
+            gate = jax.nn.sigmoid(
+                (h @ lp["w_head_gate"]).astype(jnp.float32)
+            )
+            attn = (
+                attn.astype(jnp.float32) * gate[..., None]
+            ).astype(h.dtype)
+    with device_scope("o_proj"):
+        return attn.reshape(b, t, H * dh) @ lp["wo"]
 
 
 def _previous_token(x, segment_ids):
@@ -943,8 +970,9 @@ def cca_qkv(cfg, h, lp, segment_ids, cos, sin):
     b, t, _ = h.shape
     cc, dh = cfg.cca, cfg.head_dim
     H, G = cc.num_heads, cc.num_kv_heads
-    q0, k0 = h @ lp["wq"], h @ lp["wk"]
-    v_own, v_prev = h @ lp["wv_own"], h @ lp["wv_prev"]
+    with device_scope("qkv_proj"):
+        q0, k0 = h @ lp["wq"], h @ lp["wk"]
+        v_own, v_prev = h @ lp["wv_own"], h @ lp["wv_prev"]
     with device_scope("cca_mix"):
         f32 = jnp.float32
         qh = q0.astype(f32).reshape(b, t, G, H // G, dh)
@@ -972,7 +1000,8 @@ def cca_qkv(cfg, h, lp, segment_ids, cos, sin):
         v = jnp.stack(
             [v_own, _previous_token(v_prev, segment_ids)], axis=2
         )
-    return rotate_leading(q, cos, sin), rotate_leading(k, cos, sin), v
+    with device_scope("rope"):
+        return rotate_leading(q, cos, sin), rotate_leading(k, cos, sin), v
 
 
 def _cca_mixer(cfg, h, lp, segment_ids, cos, sin, attend_fn):
@@ -987,7 +1016,8 @@ def _cca_mixer(cfg, h, lp, segment_ids, cos, sin, attend_fn):
         else:
             attn = attend_fn(q, k, v, segment_ids)
     attn = _ad_checkpoint.checkpoint_name(attn, "attn_out")
-    return attn.reshape(b, t, -1) @ lp["wo"]
+    with device_scope("o_proj"):
+        return attn.reshape(b, t, -1) @ lp["wo"]
 
 
 def _scaled_residual(x, y, lp, sub):
@@ -1007,10 +1037,13 @@ def _cca_block(cfg, carry, lp, segment_ids, rotation, attend_fn, moe_fn):
     router's state that passes from layer to layer (``r`` [B, T, R]
     float32, zeros into the first layer)."""
     x, r_prev = carry
-    h = rms_norm(x, lp["input_norm"], cfg.rms_norm_eps)
+    with device_scope("stream_norm"):
+        h = rms_norm(x, lp["input_norm"], cfg.rms_norm_eps)
     y = _cca_mixer(cfg, h, lp, segment_ids, *rotation, attend_fn)
-    x = _scaled_residual(x, y, lp, "attn")
-    h = rms_norm(x, lp["post_attn_norm"], cfg.rms_norm_eps)
+    with device_scope("residual"):
+        x = _scaled_residual(x, y, lp, "attn")
+    with device_scope("stream_norm"):
+        h = rms_norm(x, lp["post_attn_norm"], cfg.rms_norm_eps)
     with device_scope("mlp"):
         logits, r = router_mlp(lp, h, cfg.rms_norm_eps, r_prev)
         valid = segment_ids > 0  # padding tokens are no expert's load
@@ -1020,7 +1053,8 @@ def _cca_block(cfg, carry, lp, segment_ids, rotation, attend_fn, moe_fn):
             )
         else:
             ffn, stats = moe_fn(lp, h, valid, logits)
-    x = _scaled_residual(x, ffn, lp, "ffn")
+    with device_scope("residual"):
+        x = _scaled_residual(x, ffn, lp, "ffn")
     return (x, r), (stats, None)
 
 
@@ -1037,7 +1071,8 @@ def _block_body(
         return _cca_block(
             cfg, x, lp, segment_ids, rotation, attend_fn, moe_fn
         )
-    h = rms_norm(x, lp["input_norm"], cfg.rms_norm_eps)
+    with device_scope("stream_norm"):
+        h = rms_norm(x, lp["input_norm"], cfg.rms_norm_eps)
     mixer_stats = None
     if mixer == "kda":
         y, mixer_stats = _kda_mixer(cfg, h, lp, segment_ids, kda_fn)
@@ -1048,8 +1083,10 @@ def _block_body(
         )
     else:
         y = _mla_mixer(cfg, h, lp, segment_ids, attend_fn)
+    with device_scope("residual"):
+        x = x + y
     return _ffn_residual(
-        cfg, x + y, lp, segment_ids, moe_fn, ffn == "moe", mixer_stats
+        cfg, x, lp, segment_ids, moe_fn, ffn == "moe", mixer_stats
     )
 
 
@@ -1114,14 +1151,21 @@ def _apply_kinds(
     layers of one kind is a scan over that kind's slice of its stack.
     Returns (x, ``LayerStats`` of the whole stack)."""
     runs = _runs(cfg.layer_kinds)
-    rotations = _rotations(cfg, positions)
-    pieces = {
-        kind: iter(zip(*(
-            _split_stack(a, tuple(n for k, n in runs if k == kind))
-            for a in jax.tree_util.tree_leaves(stack)
-        )))
-        for kind, stack in params["layers"].items()
-    }
+    with device_scope("rope"):
+        rotations = _rotations(cfg, positions)
+    # ``layer_stack``: what the loop over the layers does itself (a run's
+    # slice of a kind's stack and its transpose, a layer's weights out of
+    # the stack, what is saved for the backward, a layer's gradient into
+    # the stacked one). Everything a layer computes has a scope of its own
+    # inside, and the innermost scope names an operation
+    with device_scope("layer_stack"):
+        pieces = {
+            kind: iter(zip(*(
+                _split_stack(a, tuple(n for k, n in runs if k == kind))
+                for a in jax.tree_util.tree_leaves(stack)
+            )))
+            for kind, stack in params["layers"].items()
+        }
     reported = {}
     if cfg.router is not None:
         # the router's state beside the stream, zeros into the first layer
@@ -1158,11 +1202,14 @@ def _apply_kinds(
                     "attn_out"
                 ),
             )
-        if n == 1:
-            x, aux = body(x, jax.tree_util.tree_map(lambda a: a[0], stack))
-            aux = jax.tree_util.tree_map(lambda a: a[None], aux)
-        else:
-            x, aux = jax.lax.scan(body, x, stack)
+        with device_scope("layer_stack"):
+            if n == 1:
+                x, aux = body(
+                    x, jax.tree_util.tree_map(lambda a: a[0], stack)
+                )
+                aux = jax.tree_util.tree_map(lambda a: a[None], aux)
+            else:
+                x, aux = jax.lax.scan(body, x, stack)
         # a kind's halves in the order a layer reports: FFN, mixer
         for name, stats in zip(reversed(kind.split("+")), aux):
             if stats is not None:
@@ -1219,9 +1266,10 @@ def apply(
     replicating activations across the fsdp axis (measured: a 7B/16-dev
     AOT lowering allocated 81 GB of per-device layer temps).
     """
-    cos, sin = rope_frequencies(
-        cfg.head_dim, cfg.max_position_embeddings, cfg.rope_theta
-    )
+    with device_scope("rope"):  # the tables, made once a program
+        cos, sin = rope_frequencies(
+            cfg.head_dim, cfg.max_position_embeddings, cfg.rope_theta
+        )
     with device_scope("embed"):
         x = params["embedding"][tokens]
         if cfg.scale_embeddings:  # gemma: sqrt(d)-scaled embeddings
@@ -1266,7 +1314,8 @@ def apply(
             body, prevent_cse=False,
             policy=jax.checkpoint_policies.save_only_these_names(*saved),
         )
-    x, aux = jax.lax.scan(body, x, params["layers"])
+    with device_scope("layer_stack"):  # as ``_apply_kinds`` says
+        x, aux = jax.lax.scan(body, x, params["layers"])
     logits = _head(params, cfg, x, return_hidden)
     if return_stats:
         pairs = zip(("moe", "indexer"), aux)  # as a layer reports them
@@ -1278,10 +1327,11 @@ def apply(
 def _head(params, cfg, x, return_hidden):
     """The final norm and the output head over the stack's output: logits
     (or their lazy view)."""
-    x = rms_norm(
-        x, params["final_norm"], cfg.rms_norm_eps,
-        add_unit_offset=cfg.norm_add_unit_offset,
-    )
+    with device_scope("stream_norm"):
+        x = rms_norm(
+            x, params["final_norm"], cfg.rms_norm_eps,
+            add_unit_offset=cfg.norm_add_unit_offset,
+        )
     if "value_head" in params:
         # critic: scalar head — "logits" [B, T, 1] (value per position);
         # tiny, never worth the lazy view

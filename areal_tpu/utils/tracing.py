@@ -168,6 +168,26 @@ DEVICE_SCOPES = (
     # convolutions, the norm and temperature, the value shift, and their
     # backward. The rotation is outside, the core under ``attention``
     "cca_mix",
+    # what a layer computes around its mixer's core and its FFN, each opened
+    # at its call sites in models/transformer.py and never inside a helper
+    # that another scope's code calls too (PR 50). From the layer's normed
+    # input to what the core or the mixer's own scope takes (the q, k, v
+    # projections, their biases, the q / k norms, a latent's compression
+    # and expansion); the rotation of q and k and the tables it reads; a
+    # rotary kind's gate a head; the product with ``wo``
+    "qkv_proj", "rope", "attn_gate", "o_proj",
+    # the residual stream: its norms (a layer's two and the final one) and
+    # the sums back onto it
+    "stream_norm", "residual",
+    # engine/programs.py: the parameter tree cast to the compute dtype in
+    # every run of a forward program (backward: the gradient cast back), and
+    # a microbatch's gradient added to the float32 accumulator
+    "param_cast", "grad_add",
+    # around the loop over the layers: what the loop itself does (a layer's
+    # weights sliced out of the stack, what is stacked for the backward, a
+    # layer's gradient written into the stacked one). Every scope above is
+    # opened inside it and the innermost scope names an operation
+    "layer_stack",
 )
 
 

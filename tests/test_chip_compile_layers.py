@@ -101,6 +101,25 @@ def test_cca_mixer_and_its_core_compile_for_v5e(one_chip, monkeypatch):
     assert "tpu_custom_call" in text
     assert re.search(r"f32\[10,128,16384\]\S* convolution\(", text)
     assert re.search(r'areal_scope="cca_mix"', text)
+    # and since PR 50 the projections before it and the rotation after it
+    assert re.search(r'areal_scope="qkv_proj"', text)
+    assert re.search(r'areal_scope="rope"', text)
+    # ``tools/program_text.py``: the text without its names, a kernel's
+    # serialized body apart and readable without its callers' line numbers
+    from tools import program_text
+
+    bare = program_text.stripped(text)
+    assert "areal_scope" not in bare and "op_name=" not in bare
+    cut, bodies = program_text.kernel_bodies(bare)
+    assert len(bodies) == text.count('"body":"') >= 2
+    assert len(cut) < len(bare) and "tpu_custom_call" in cut
+    assert all("func.func" in b and "loc(" not in b for b in bodies)
+    # the two digests the tool prints on the chip move with no name
+    renamed = text.replace('areal_scope="cca_mix"', 'areal_scope="mix"')
+    assert renamed != text
+    assert program_text.digests(renamed) == program_text.digests(text)
+    assert program_text.digests(text.replace("convolution(", "dot(", 1)) \
+        != program_text.digests(text)
 
 
 @pytest.mark.parametrize("t", [2048, 8192])

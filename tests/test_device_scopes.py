@@ -85,6 +85,23 @@ def _scopes_in(names):
 
 
 _ENGINES = {}  # family -> the engine ``_train_programs`` built for it
+_PACKED = {}  # family -> (its actor, the arrays its ``grad_step`` was lowered on)
+
+
+def _lower_grad_step(eng, actor, arrays, **values):
+    """``grad_step`` of ``eng`` traced and lowered on ``arrays`` (under
+    whatever scopes are patched in), with ``values`` over the engine's own
+    (``compute_dtype=``)."""
+    grad_fn = programs.build_grad_step(
+        **dict(eng._forward_values(0), **values), remat=True,
+        loss_fn=actor._loss_fn, loss_weight_fn=_ppo_loss_weight_fn,
+    )
+    return grad_fn.lower(eng.params, eng._zero_grads(), arrays)
+
+
+def _lower_again(family, **values):
+    """The same of the engine ``_train_programs`` built for ``family``."""
+    return _lower_grad_step(_ENGINES[family], *_PACKED[family], **values)
 
 
 def _train_programs(family="qwen2", only_grad=False, **parallel):
@@ -93,33 +110,29 @@ def _train_programs(family="qwen2", only_grad=False, **parallel):
     on the way (a lowering reads the proximal logprobs' shape, so zeros
     serve where ``logp_forward`` would have to be built to compute them)."""
     actor, eng = _actor(family=family, **parallel)
-    if not parallel:
-        _ENGINES[family] = eng
     batch = actor.compute_advantages(_batch())
     if only_grad:
         batch["prox_logp"] = np.zeros_like(batch["logprobs"])
     else:
         batch["prox_logp"] = actor.compute_logp(batch) * batch["loss_mask"]
     _, arrays = eng._pack_for_device(batch)
-    grad_fn = programs.build_grad_step(
-        **eng._forward_values(0), remat=True,
-        loss_fn=actor._loss_fn, loss_weight_fn=_ppo_loss_weight_fn,
-    )
-    accum = eng._zero_grads()
+    if not parallel:
+        _ENGINES[family], _PACKED[family] = eng, (actor, arrays)
+    grad_step = ("grad_step", _lower_grad_step(eng, actor, arrays))
     if only_grad:
-        return [("grad_step", grad_fn.lower(eng.params, accum, arrays))]
+        return [grad_step]
     logp_fn = next(
         f for k, f in eng._jit_cache.items()
         if isinstance(k, tuple) and k[0] == "fwd"
     )
     w = jnp.ones((), jnp.float32)
     return [
-        ("grad_step", grad_fn.lower(eng.params, accum, arrays)),
+        grad_step,
         ("logp_forward", logp_fn.lower(eng.params, arrays)),
         ("apply_step", programs.build_apply_step(
             eng.optimizer, eng._param_shardings
         ).lower(
-            eng.params, eng.opt_state, accum, w)),
+            eng.params, eng.opt_state, eng._zero_grads(), w)),
     ]
 
 
@@ -149,34 +162,55 @@ def _decode_programs():
     return [("_decode_multi_forward", fwd), ("write_rows", write)]
 
 
+# what PR 50 named of the third of every window that had no name: opened
+# only around code that was under no scope, so no older scope's border moved
+NEW_SCOPES = (
+    "qkv_proj", "rope", "attn_gate", "o_proj", "stream_norm", "residual",
+    "param_cast", "grad_add", "layer_stack",
+)
+OLD_SCOPES = tuple(s for s in tracing.DEVICE_SCOPES if s not in NEW_SCOPES)
+# around every mixer's core and FFN, whatever the family
+_AROUND = {"qkv_proj", "o_proj", "stream_norm", "residual", "layer_stack"}
 WANT = {
-    "grad_step": {"embed", "attention", "mlp", "lm_head", "loss"},
-    "logp_forward": {"embed", "attention", "mlp", "lm_head", "loss"},
+    "grad_step": {"embed", "attention", "mlp", "lm_head", "loss", "rope",
+                  "grad_add"} | _AROUND,
+    "logp_forward": {"embed", "attention", "mlp", "lm_head", "loss", "rope"}
+    | _AROUND,
+    # the float32 tree cast to a compute dtype that is another (the tiny
+    # engines compute in float32, where the cast is no operation)
+    "bf16_grad_step": {"param_cast", "grad_add"} | _AROUND,
     "apply_step": {"optimizer"},
     "_decode_multi_forward": {"attention", "mlp", "lm_head", "sample"},
     "write_rows": {"kv_write"},
     # a sparse layer's three, inside its mlp (innermost wins in a profile)
-    "sparse_grad_step": {"mlp", "moe_route", "moe_dispatch", "moe_experts"},
+    "sparse_grad_step": {"mlp", "moe_route", "moe_dispatch", "moe_experts",
+                         "rope"} | _AROUND,
     # on a mesh that splits the experts: the tokens gathered to the chips
     # that hold them, the chips' parts summed and scattered back
     "mesh_sparse_grad_step": {"mlp", "moe_route", "moe_exchange",
                               "moe_dispatch", "moe_experts"},
     # learned sparse attention: the indexer and the selection before the
     # core, which stays under attention
-    "dsa_grad_step": {"attention", "dsa_index", "dsa_select", "moe_experts"},
+    "dsa_grad_step": {"attention", "dsa_index", "dsa_select", "moe_experts",
+                      "rope"} | _AROUND,
     # a stack of block kinds: the delta rule and what the layer computes
     # around it, beside the latent layer's attention and the experts
     "kda_grad_step": {"kda_scan", "kda_prep", "attention", "moe_experts",
-                      "mlp"},
+                      "mlp"} | _AROUND,
     # two rotary kinds: the window layers' core under its own scope, NOT
     # inside attention, which keeps the full layers' core
-    "swa_grad_step": {"attn_window", "attention", "moe_experts", "mlp"},
+    "swa_grad_step": {"attn_window", "attention", "moe_experts", "mlp",
+                      "rope", "attn_gate"} | _AROUND,
     # a convolutional latent mixer: what it computes between its projections
     # and its core under its own scope, the core under attention, the MLP
     # router under moe_route
     "cca_grad_step": {"cca_mix", "attention", "moe_route", "moe_experts",
-                      "mlp"},
+                      "mlp", "rope"} | _AROUND,
 }
+# a family's ``grad_step`` among the programs of ``lowered``
+FAMILIES = {"qwen2": "grad_step", "olmoe": "sparse_grad_step",
+            "KeyeVL2": "dsa_grad_step", "kimi_linear": "kda_grad_step",
+            "laguna": "swa_grad_step", "zaya": "cca_grad_step"}
 # the five families whose programs a change for a sixth may not move: the
 # lowered grad_step's text (the persistent compile cache's key is made of
 # it), by sha256, in tests/golden/grad_step_stablehlo.json. A PR that means
@@ -205,6 +239,9 @@ def lowered():
     out["mesh_sparse_grad_step"] = _op_names(_train_programs(
         "olmoe", only_grad=True, fsdp_parallel_size=2
     )[0][1])
+    out["bf16_grad_step"] = _op_names(
+        _lower_again("qwen2", compute_dtype=jnp.bfloat16)
+    )
     for program, family in STABLE.items():
         _STABLEHLO[family] = hashlib.sha256(
             grad_steps[program].as_text().encode()
@@ -218,6 +255,7 @@ def test_program_lowers_under_its_contract_name_with_its_scopes(
 ):
     text, names = lowered[program]
     jitted = program.removeprefix("mesh_").removeprefix("sparse_")
+    jitted = jitted.removeprefix("bf16_")
     jitted = jitted.removeprefix("dsa_")
     jitted = jitted.removeprefix("kda_").removeprefix("swa_")
     jitted = jitted.removeprefix("cca_")
@@ -287,6 +325,116 @@ def test_backward_and_remat_keep_the_scope_on_the_path(lowered):
         "jit(grad_step)/transpose(jvp(loss))/while/body/lm_head/dot"
     ) == "lm_head"
     assert program_trace.scope_of("jit(g)/transpose(jvp(loss))/mul") == "loss"
+
+
+# where a new scope's operations must be, by ``program_trace.direction_of``.
+# A plain sum's transpose is no operation (the cotangents' own sums take the
+# name of the place that made the second one), a cca block's scaled sum has
+# one; the cast is outside the checkpointed layers and the accumulator
+# outside the gradient; a loop of one layer (the tiny Kimi stack) stacks
+# nothing to recompute from
+_ON_THE_PATH = {
+    "residual": {"fwd", "remat"}, "param_cast": {"fwd", "bwd"},
+    "grad_add": {"fwd"}, "layer_stack": {"fwd", "bwd"},
+}
+
+
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_a_new_scope_is_on_the_path_forward_recomputed_and_backward(
+    lowered, family
+):
+    from benchmark import program_trace
+
+    _, names = lowered[FAMILIES[family]]
+    for scope in sorted(WANT[FAMILIES[family]] & set(NEW_SCOPES)):
+        found = {
+            program_trace.direction_of(n) for n in names
+            if program_trace.scope_of(n) == scope
+        }
+        want = _ON_THE_PATH.get(scope, {"fwd", "remat", "bwd"})
+        assert want <= found, (family, scope, found)
+    _, names = lowered["bf16_grad_step"]
+    cast = {
+        program_trace.direction_of(n) for n in names
+        if program_trace.scope_of(n) == "param_cast"
+    }
+    assert cast == _ON_THE_PATH["param_cast"]
+
+
+def _matmul(op_name):
+    return re.search(r"dot_general|conv_general", op_name.split("/")[-1])
+
+
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_no_matmul_is_the_loops_own_or_nobodys(lowered, family):
+    """``layer_stack`` reads the loop's plumbing and whatever of a layer's
+    body a scope missed: no product may be innermost under it, nor under no
+    scope at all."""
+    from benchmark import program_trace
+
+    _, names = lowered[FAMILIES[family]]
+    products = [n for n in names if _matmul(n)]
+    assert products
+    astray = [
+        n for n in products
+        if program_trace.scope_of(n) in (None, "layer_stack")
+    ]
+    assert not astray, astray[:10]
+    # and what a new scope names was nobody's: no older scope is on its path
+    # (operations the compiler merged keep both names, ``;`` between them)
+    stolen = [
+        n for merged in names for n in merged.split(";")
+        if program_trace.scope_of(n) in NEW_SCOPES
+        and program_trace.scope_of(n, OLD_SCOPES) is not None
+    ]
+    assert not stolen, stolen[:10]
+
+
+def _scope_by_instruction(text, scopes):
+    """{instruction: the innermost of ``scopes`` on its op_name} of a
+    compiled text."""
+    from benchmark import program_trace
+
+    found = re.findall(
+        r'^\s*(?:ROOT )?(\S+) = .*op_name="([^"]+)"', text, re.M
+    )
+    return {
+        name: scope for name, op_name in found
+        if (scope := program_trace.scope_of(op_name, scopes)) is not None
+    }
+
+
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_the_new_scopes_move_no_older_scopes_border(
+    lowered, family, monkeypatch
+):
+    """The family's ``grad_step`` traced again with the NEW scopes alone
+    patched off (the parent's naming): the compiled program is the same
+    text but for its names (``tools/program_text.stripped``, what a chip
+    run of that tool shows at the real size), each older scope names the
+    same instructions of it, and no ``op_name`` of the parent's holds a new
+    name as a whole path word (so a new name reads nothing that was there
+    before it)."""
+    from areal_tpu.models import transformer
+    from benchmark import program_trace
+    from tools import program_text
+
+    device_scope = tracing.device_scope
+
+    def older_only(name):
+        if name in NEW_SCOPES:
+            return contextlib.nullcontext()
+        return device_scope(name)
+
+    for mod in (tracing, transformer):
+        monkeypatch.setattr(mod, "device_scope", older_only)
+    text, names = _op_names(_lower_again(family))
+    for n in names:
+        assert program_trace.scope_of(n, NEW_SCOPES) is None, n
+    now, _ = lowered[FAMILIES[family]]
+    assert program_text.stripped(text) == program_text.stripped(now)
+    was = _scope_by_instruction(text, OLD_SCOPES)
+    assert was and was == _scope_by_instruction(now, OLD_SCOPES)
 
 
 def test_the_heads_hand_written_backward_keeps_lm_head_and_loss(lowered):

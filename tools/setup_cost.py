@@ -155,14 +155,41 @@ def trainer_programs(
     }
 
 
-def main(argv=None):
-    from jax.experimental import topologies
-
+def cell_programs(model: str, tokens: int, fsdp: int = 1, param_dtype=None,
+                  devices=None) -> dict:
+    """``trainer_programs`` of ``feasibility.MODEL_CONFIGS[model]`` on the
+    first ``fsdp`` of ``devices``, a row of ``tokens`` each, the attention a
+    TPU resolves to. ``devices`` None: a described ``v5e:2x2`` host's, with
+    the ops' platform rules steered to a TPU's for the process's life."""
     from areal_tpu.api.cli_args import ParallelismConfig
     from areal_tpu.ops import flash
     from areal_tpu.ops.ring_attention import attention_fn
     from areal_tpu.parallel import feasibility
     from areal_tpu.parallel import mesh as mesh_lib
+
+    if devices is None:
+        from jax.experimental import topologies
+
+        devices = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        ).devices
+        jax.default_backend = lambda: "tpu"  # the ops' platform rules
+        flash._PROBED_BLOCK = 2048  # what the chip's probe settles on
+    mc = feasibility.MODEL_CONFIGS[model]()
+    mesh = mesh_lib.make_mesh(
+        ParallelismConfig(fsdp_parallel_size=fsdp), devices[:fsdp]
+    )
+    attend = attention_fn(
+        "sparse_topk" if mc.has_indexer else "flash", mesh,
+        topk=mc.dsa_topk if mc.has_indexer else 0,
+    )
+    return trainer_programs(
+        mc, mesh, tokens, attend, rows=fsdp, param_dtype=param_dtype
+    )
+
+
+def main(argv=None):
+    from areal_tpu.parallel import feasibility
 
     ap = argparse.ArgumentParser()
     ap.add_argument("--model", default="kimi_linear_48b_a3b",
@@ -176,21 +203,8 @@ def main(argv=None):
                     help="compile too: bytes a device, collectives by name")
     args = ap.parse_args(argv)
 
-    topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
-    jax.default_backend = lambda: "tpu"  # the ops' platform rules
-    flash._PROBED_BLOCK = 2048  # what the chip's probe settles on
-    mc = feasibility.MODEL_CONFIGS[args.model]()
-    mesh = mesh_lib.make_mesh(
-        ParallelismConfig(fsdp_parallel_size=args.fsdp),
-        topo.devices[:args.fsdp],
-    )
-    attend = attention_fn(
-        "sparse_topk" if mc.has_indexer else "flash", mesh,
-        topk=mc.dsa_topk if mc.has_indexer else 0,
-    )
-    for name, (jitted, a) in trainer_programs(
-        mc, mesh, args.tokens, attend, rows=args.fsdp,
-        param_dtype=jnp.dtype(args.param_dtype),
+    for name, (jitted, a) in cell_programs(
+        args.model, args.tokens, args.fsdp, jnp.dtype(args.param_dtype)
     ).items():
         print(json.dumps({
             "model": args.model, "tokens": args.tokens, "program": name,
