@@ -38,22 +38,23 @@ from areal_tpu.models.config import ModelConfig
 from areal_tpu.ops import flash as flash_ops
 from areal_tpu.ops import kda as kda_ops
 from areal_tpu.ops import moe as moe_ops
+from areal_tpu.ops import rotary as rotary_ops
 from areal_tpu.ops import sparse_attention as dsa_ops
 from areal_tpu.ops.kda import kda_scan
 from areal_tpu.ops.moe import moe_ffn_from_params, router_mlp
 from areal_tpu.ops.sparse_attention import sparse_attention
 from areal_tpu.utils.tracing import device_scope
 from areal_tpu.ops.basic import (
-    apply_mrope,
-    apply_rope,
     hidden_act_fn,
     rms_norm,
     rope_at,
     rope_frequencies,
     rope_inv_freq,
-    rotate_leading,
     segment_attention,
 )
+# the rotation: ``ops/basic.py``'s three by name and numbers, through the
+# one-pass kernel where ``rotary.rotate_impl`` takes the shape
+from areal_tpu.ops.rotary import apply_mrope, apply_rope, rotate_leading
 
 Params = Dict[str, Any]
 
@@ -487,9 +488,23 @@ def generation_refusal(cfg: ModelConfig) -> str:
     return (_declared(cfg, "no_generation") + [""])[0]
 
 
+def _rotated_width(cfg: ModelConfig) -> int:
+    """The width of the heads the model rotates; 0: it rotates none (a stack
+    of kinds without a rotary or a ``cca`` mixer)."""
+    rotates = not cfg.layer_kinds or cfg.cca is not None or any(
+        getattr(cfg, mixer) is not None for mixer in _ROTARY_MIXERS
+    )
+    return cfg.head_dim if rotates else 0
+
+
 def span_attrs(cfg: ModelConfig, split=False) -> Dict[str, Any]:
-    """What a trainer's dispatch span says of the model's mechanisms."""
-    said = _declared(cfg, "span_attrs") + [{"moe_mesh": split and "exchange"}]
+    """What a trainer's dispatch span says of the model's mechanisms, and
+    of the rotation every rotary mixer shares (``rope_impl``: "kernel" |
+    "xla", ``ops/rotary.rotate_impl`` on the trainer's rows)."""
+    said = _declared(cfg, "span_attrs") + [{
+        "moe_mesh": split and "exchange",
+        "rope_impl": rotary_ops.rotate_impl(_rotated_width(cfg)),
+    }]
     return {k: v for attrs in said for k, v in attrs.items() if v}
 
 
@@ -716,16 +731,22 @@ def _layer_body(
         q = q.reshape(b, t, cfg.num_heads, cfg.head_dim)
         k = k.reshape(b, t, cfg.num_kv_heads, cfg.head_dim)
         v = v.reshape(b, t, cfg.num_kv_heads, cfg.head_dim)
-        if cfg.qk_norm == "head":  # qwen3: each head by itself
+        in_rotation = _head_norm_in_rotation(cfg, lp, t)
+        q_norm, k_norm = in_rotation("q_norm"), in_rotation("k_norm")
+        if cfg.qk_norm == "head" and q_norm is None:  # qwen3: each head
             q = rms_norm(q, lp["q_norm"], cfg.rms_norm_eps)
             k = rms_norm(k, lp["k_norm"], cfg.rms_norm_eps)
     with device_scope("rope"):
         if positions.ndim == 3:  # [B, T, 3] multimodal (t, h, w) positions
-            q = apply_mrope(q, positions, cos, sin, cfg.mrope_sections)
-            k = apply_mrope(k, positions, cos, sin, cfg.mrope_sections)
+            q = apply_mrope(
+                q, positions, cos, sin, cfg.mrope_sections, q_norm
+            )
+            k = apply_mrope(
+                k, positions, cos, sin, cfg.mrope_sections, k_norm
+            )
         else:
-            q = apply_rope(q, positions, cos, sin)
-            k = apply_rope(k, positions, cos, sin)
+            q = apply_rope(q, positions, cos, sin, q_norm)
+            k = apply_rope(k, positions, cos, sin, k_norm)
     dsa = None
     if cfg.has_indexer:
         # the indexer reads the layer's normed input, detached: it picks
@@ -883,6 +904,17 @@ def _mla_mixer(cfg, h, lp, segment_ids, attend_fn):
         return attn.reshape(b, t, H * m.v_head_dim) @ lp["wo"]
 
 
+def _head_norm_in_rotation(cfg, lp, t):
+    """name -> q's or k's head norm as ``ops/rotary.rotate`` takes one,
+    (weight, eps), where the rotation's kernel runs on rows of ``t`` tokens
+    and norms a head in its own pass; name -> None where the caller norms
+    (the ``jnp`` form) and for a model that does not norm q and k a head."""
+    fused = cfg.qk_norm == "head" and rotary_ops.rotate_impl(
+        cfg.head_dim, t
+    ) != "xla"
+    return lambda name: (lp[name], cfg.rms_norm_eps) if fused else None
+
+
 def _rotary_mixer(cfg, rc, h, lp, segment_ids, cos, sin, attend_fn):
     """Rotary GQA attention on the normed input ``h``, as ``rc`` (a
     ``RotaryConfig``) describes this kind: its own query heads, rotation
@@ -895,13 +927,15 @@ def _rotary_mixer(cfg, rc, h, lp, segment_ids, cos, sin, attend_fn):
     b, t, _ = h.shape
     H, dh = rc.num_heads, cfg.head_dim
 
+    in_rotation = _head_norm_in_rotation(cfg, lp, t)
+
     def rotated(w, heads, norm):
         with device_scope("qkv_proj"):
             x = (h @ lp[w]).reshape(b, t, heads, dh)
-            if cfg.qk_norm == "head":
+            if cfg.qk_norm == "head" and in_rotation(norm) is None:
                 x = rms_norm(x, lp[norm], cfg.rms_norm_eps)
         with device_scope("rope"):
-            return rotate_leading(x, cos, sin)
+            return rotate_leading(x, cos, sin, in_rotation(norm))
 
     q = rotated("wq", H, "q_norm")
     k = rotated("wk", cfg.num_kv_heads, "k_norm")

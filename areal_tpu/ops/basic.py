@@ -61,7 +61,9 @@ def apply_rope(
 
     Uses the HF "rotate_half" layout (first/second half pairing) so weights
     loaded from HF checkpoints produce identical outputs
-    (reference impl/model/modules/rotary.py).
+    (reference impl/model/modules/rotary.py). It IS ``rotate_leading(x,
+    cos[positions], sin[positions])``, kept spelled out: the lowered text of
+    the programs that run it is pinned (tests/golden).
     """
     dtype = x.dtype
     c = cos[positions].astype(jnp.float32)[..., None, :]  # [..., T, 1, D/2]
@@ -138,6 +140,27 @@ def rotate_leading(
     return jnp.concatenate([out, x[..., rot:]], axis=-1)
 
 
+def mrope_tables(
+    positions3: jnp.ndarray,  # [B, T, 3] int32 (t, h, w) position ids
+    cos: jnp.ndarray,  # [max_len, D/2]
+    sin: jnp.ndarray,
+    sections: Tuple[int, ...],  # rotary channels per dim; sums to D/2
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """cos and sin [B, T, D/2] of multimodal positions, float32: rotary
+    channel j takes the row of the temporal/height/width position stream its
+    section assigns it."""
+    sec = np.repeat(np.arange(len(sections)), sections)
+    onehot = jnp.asarray(
+        sec[None, :] == np.arange(len(sections))[:, None], jnp.float32
+    )  # [3, D/2]
+    c3 = cos[positions3].astype(jnp.float32)  # [B, T, 3, D/2]
+    s3 = sin[positions3].astype(jnp.float32)
+    return (
+        jnp.einsum("btsd,sd->btd", c3, onehot),
+        jnp.einsum("btsd,sd->btd", s3, onehot),
+    )
+
+
 def apply_mrope(
     x: jnp.ndarray,  # [B, T, H, D]
     positions3: jnp.ndarray,  # [B, T, 3] int32 (t, h, w) position ids
@@ -147,22 +170,12 @@ def apply_mrope(
 ) -> jnp.ndarray:
     """Multimodal 3D rotary (Qwen2-VL "mrope"): rotary channel j uses the
     temporal/height/width position stream its section assigns it (HF
-    `apply_multimodal_rotary_pos_emb` layout, rotate-half pairing). For
-    text-only tokens all three streams are equal and this reduces exactly
-    to `apply_rope`."""
-    dtype = x.dtype
-    sec = np.repeat(np.arange(len(sections)), sections)
-    onehot = jnp.asarray(
-        sec[None, :] == np.arange(len(sections))[:, None], jnp.float32
-    )  # [3, D/2]
-    c3 = cos[positions3].astype(jnp.float32)  # [B, T, 3, D/2]
-    s3 = sin[positions3].astype(jnp.float32)
-    c = jnp.einsum("btsd,sd->btd", c3, onehot)[..., None, :]
-    s = jnp.einsum("btsd,sd->btd", s3, onehot)[..., None, :]
-    x = x.astype(jnp.float32)
-    x1, x2 = jnp.split(x, 2, axis=-1)
-    out = jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
-    return out.astype(dtype)
+    `apply_multimodal_rotary_pos_emb` layout, rotate-half pairing):
+    ``rotate_leading`` at ``mrope_tables``. For text-only tokens all three
+    streams are equal and this reduces exactly to `apply_rope`."""
+    return rotate_leading(
+        x, *mrope_tables(positions3, cos, sin, sections)
+    )
 
 
 def make_segment_mask(

@@ -119,3 +119,52 @@ def test_sparse_attention_fwd_bwd_compiles_for_v5e(one_chip, monkeypatch):
     assert text.count("tpu_custom_call") >= 6
     assert len(re.findall(r"dsa_select_kth", text)) >= 3
     assert not re.search(rf"f32\[[0-9,]*{t},{t}[0-9,]*\]", text)
+
+
+# (tokens, heads, rotated half R, normed a head in the pass): q and k of
+# Laguna's window and full layers (half a head under yarn), of Mellum's at its
+# 32768-token row and of Keye's (both norm q and k a head), k of Zaya's
+_ROTATION_CASES = [
+    pytest.param(16384, 64, 64, False, id="laguna-swa-q"),
+    pytest.param(16384, 48, 32, False, id="laguna-full-q-half-head"),
+    pytest.param(16384, 8, 32, False, id="laguna-full-k-half-head"),
+    pytest.param(32768, 32, 64, True, id="mellum-q-normed"),
+    pytest.param(32768, 4, 64, True, id="mellum-k-normed"),
+    pytest.param(16384, 32, 64, True, id="keye-q-normed"),
+    pytest.param(16384, 2, 64, False, id="zaya-k"),
+]
+
+
+@pytest.mark.parametrize("t,heads,r,normed", _ROTATION_CASES)
+def test_the_rotation_kernel_compiles_for_v5e(
+    one_chip, monkeypatch, t, heads, r, normed
+):
+    """``ops/rotary.rotate`` and its transpose on the projection's
+    ``[1, t, heads x 128]``: two custom calls, no copy of the operand on
+    either side of them and nothing of its size in float32."""
+    import jax
+    import jax.numpy as jnp
+
+    from areal_tpu.ops import rotary
+
+    monkeypatch.setattr(rotary, "rotate_impl", lambda d, t=None: "kernel")
+
+    def both(x, w, cos, sin, g):
+        out, vjp = jax.vjp(
+            lambda x, w: rotary.rotate(
+                x.reshape(1, t, heads, 128), cos, sin,
+                (w, 1e-6) if normed else None,
+            ).reshape(x.shape), x, w,
+        )
+        return out, vjp(g)
+
+    x = _sds((1, t, heads * 128), jnp.bfloat16, one_chip)
+    table = _sds((1, t, r), jnp.float32, one_chip)
+    w = _sds((128,), jnp.bfloat16, one_chip)
+    compiled = jax.jit(both).lower(x, w, table, table, x).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"", text)) == 2
+    assert ("rope_norm_rotate_t" if normed else "rope_rotate_t") in text
+    assert not re.search(rf"= bf16\[[0-9,]*{heads * 128}\]\S* copy\(", text)
+    assert not re.search(rf"f32\[[0-9,]*{t},{heads * 128}\]", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20

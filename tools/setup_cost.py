@@ -25,7 +25,9 @@ five siblings), and reads two to four times this tool's seconds.
 
 ``jax.default_backend()`` is the CPU here, so the choices the ops make from
 the platform (``ops/kda.scan_impl``, ``ops/moe.grouped_impl``, the splash
-kernel, ...) are steered to a TPU's for the length of the script.
+kernel, ...) and from the devices the process holds
+(``ops/rotary.rotate_impl``) are steered to the cell's for the length of the
+script.
 """
 
 import argparse
@@ -174,6 +176,8 @@ def cell_programs(model: str, tokens: int, fsdp: int = 1, param_dtype=None,
             platform="tpu", topology_name="v5e:2x2"
         ).devices
         jax.default_backend = lambda: "tpu"  # the ops' platform rules
+        # the devices the cell's process holds (``ops/rotary.rotate_impl``)
+        jax.device_count = lambda: fsdp
         flash._PROBED_BLOCK = 2048  # what the chip's probe settles on
     mc = feasibility.MODEL_CONFIGS[model]()
     mesh = mesh_lib.make_mesh(
