@@ -52,7 +52,7 @@ def _forward_fn(
             k: sharding_lib.logical_to_spec(axes[k][1:], mesh=mesh)
             for k in moe_ops.sparse_leaves(mc)
         })
-    kda = kda_ops.make_kda_scan(mesh) if mc.kda else None
+    kda = kda_ops.make_kda_scan(mesh) if mc.kda or mc.gdn else None
     # [B, T, D] activation constraint: rows over (data, fsdp), tokens over
     # seq. Pinning this stops GSPMD from propagating the embedding table's
     # column sharding onto the batch, which replicates every layer

@@ -7,11 +7,14 @@ supported decoder-only family. A model whose layers are all one block
 indexer) differs from its siblings in fields (QKV bias, tied embeddings,
 head_dim override, q/k norm, experts). A model whose layers are of more
 than one kind names them: ``layer_kinds`` holds a block kind a layer
-(``"<mixer>+<ffn>"``: the mixers ``kda`` (gated delta-rule linear
-attention, ``KDAConfig``), ``mla`` (latent attention, ``MLAConfig``), the
-two rotary GQA ones, ``full`` and ``swa`` (all of a sequence's earlier keys,
-or a window of them: a ``RotaryConfig`` each) and ``cca`` (attention inside
-a compressed latent that two causal convolutions mix, ``CCAConfig``); the
+(``"<mixer>+<ffn>"``: the SIX mixers ``kda`` (gated delta-rule linear
+attention whose decay is a number a channel, ``KDAConfig``), ``gdn`` (the
+same rule with ONE decay a head and token, more value heads than key heads
+and a gate as wide as its output, ``GDNConfig``), ``mla`` (latent attention,
+``MLAConfig``), the two rotary GQA ones, ``full`` and ``swa`` (all of a
+sequence's earlier keys, or a window of them: a ``RotaryConfig`` each) and
+``cca`` (attention inside a compressed latent that two causal convolutions
+mix, ``CCAConfig``); the
 FFNs ``dense`` and ``moe``, the latter routed by ``h @ w_router`` or, where
 the model has a ``RouterConfig``, by a small MLP whose input passes from
 layer to layer), and the block kinds, not further per-family scalars, are
@@ -36,6 +39,31 @@ class KDAConfig:
     num_heads: int
     head_dim: int
     conv_size: int = 4
+
+
+@dataclasses.dataclass(frozen=True)
+class GDNConfig:
+    """The scalar-decay gated delta-rule mixer's sizes
+    (``models/transformer._gdn_mixer``; ``ops/kda.py`` has the rule):
+    ``num_k_heads`` key (and query) heads of ``head_k_dim``, each serving
+    ``num_v_heads / num_k_heads`` consecutive value heads of ``head_v_dim``;
+    one depthwise causal convolution of ``conv_size`` taps over q, k and v's
+    channels together; ONE log-decay and one beta a value head and token;
+    the output normed a head and gated element by element."""
+
+    num_k_heads: int
+    num_v_heads: int
+    head_k_dim: int
+    head_v_dim: int
+    conv_size: int = 4
+
+    @property
+    def key_dim(self) -> int:
+        return self.num_k_heads * self.head_k_dim
+
+    @property
+    def value_dim(self) -> int:
+        return self.num_v_heads * self.head_v_dim
 
 
 @dataclasses.dataclass(frozen=True)
@@ -64,7 +92,10 @@ class RotaryConfig:
     whole head) at ``rope_theta``, by yarn where ``yarn_factor`` > 1
     (``ops/basic.rope_inv_freq``), cos and sin times ``attention_factor``;
     ``head_gate``: each head's output times ``sigmoid(h Wg)`` of the layer's
-    normed input, one number a head and token."""
+    normed input, one number a head and token; ``output_gate``: the core's
+    output times the sigmoid of a gate as wide as itself, which the query's
+    projection makes beside the query (a head's ``head_dim`` of query, then
+    its ``head_dim`` of gate)."""
 
     num_heads: int
     window: int = 0
@@ -76,6 +107,7 @@ class RotaryConfig:
     yarn_beta_slow: float = 1.0
     attention_factor: float = 1.0
     head_gate: bool = False
+    output_gate: bool = False
 
 
 @dataclasses.dataclass(frozen=True)
@@ -165,6 +197,7 @@ class ModelConfig:
     # describe), and the sizes of the mixers that are not rotary GQA ---
     layer_kinds: Tuple[str, ...] = ()
     kda: Optional[KDAConfig] = None
+    gdn: Optional[GDNConfig] = None
     mla: Optional[MLAConfig] = None
     # the rotary GQA mixers of such a stack, ``full`` and ``swa``: each its
     # own heads, rotation and (``swa``) window; ``num_kv_heads`` and
@@ -218,7 +251,10 @@ class ModelConfig:
 
     @property
     def num_kda_layers(self) -> int:
-        return sum(kind.startswith("kda+") for kind in self.layer_kinds)
+        """The layers whose mixer is the chunked delta rule, either decay."""
+        return sum(
+            kind.startswith(("kda+", "gdn+")) for kind in self.layer_kinds
+        )
 
     @property
     def layer_window(self) -> int:
@@ -271,12 +307,23 @@ class ModelConfig:
 # the whole head rotated, and q and k normed a head before the rotation
 # (``qk_norm`` "head", which a rotary mixer of a stack of kinds reads too);
 # the same three refuse it, by what the ``swa`` mechanism declares.
+# qwen3_next is a stack of TWO block kinds (``_qwen3_next_from_hf``): ``gdn``,
+# the delta rule with one decay a head and token, 32 value heads over 16 key
+# heads and an element-wise gate, three layers in four, and ``full``, rotary
+# GQA at heads of 256 of which a quarter turns, q and k normed a head, the
+# core's output gated element by element from the query's own projection, the
+# fourth; every FFN 512 softmax-routed experts of which 10 a token, and a
+# shared one behind a sigmoid gate; every norm but the delta rule's output
+# norm scales by (1 + w). The trainer runs it; the generation engine (a
+# recurrent state beside KV pages), ``hf_io`` (no checkpoint layout of a
+# share; the published names wait for ROADMAP B.15) and sequence parallelism
+# (the state would pass from device to device) refuse it by name.
 # gemma2/gpt2 remain out (gemma2: soft-capped scores and post-norms beside
 # its windows; gpt2: learned positions, a new embedding).
 _HF_FAMILIES = (
     "llama", "qwen2", "qwen3", "mistral", "qwen3_moe", "mixtral",
     "qwen2_vl", "qwen2_moe", "gemma", "olmoe", "KeyeVL2", "kimi_linear",
-    "laguna", "zaya", "mellum",
+    "laguna", "zaya", "mellum", "qwen3_next",
 )
 
 
@@ -630,6 +677,83 @@ def _zaya_from_hf(d: dict) -> ModelConfig:
     )
 
 
+def _qwen3_next_from_hf(d: dict) -> ModelConfig:
+    """``model_type`` qwen3_next: layer ``i`` is full attention where
+    ``(i + 1) % full_attention_interval == 0`` and the scalar-decay delta
+    rule elsewhere (``linear_*`` its sizes); every layer's FFN is
+    ``num_experts`` softmax-routed experts (``num_local_experts`` of them
+    held here, where the file says so) and a shared one of
+    ``shared_expert_intermediate_size`` behind a sigmoid gate;
+    ``intermediate_size`` is read by nothing."""
+    n_layers = d["num_hidden_layers"]
+    every = d.get("full_attention_interval", 4)
+    refused = {
+        "mlp_only_layers (dense layers among the sparse)": d.get(
+            "mlp_only_layers"
+        ),
+        "decoder_sparse_step != 1": d.get("decoder_sparse_step", 1) != 1,
+        "rope_scaling": d.get("rope_scaling"),
+        "use_sliding_window": d.get("use_sliding_window", False),
+        "attention_bias": d.get("attention_bias", False),
+        f"hidden_act {d.get('hidden_act')!r}": (
+            d.get("hidden_act", "silu") != "silu"
+        ),
+        "value heads that are no multiple of the key heads": (
+            d["linear_num_value_heads"] % d["linear_num_key_heads"]
+        ),
+    }
+    for what, present in refused.items():
+        if present:
+            raise ValueError(f"qwen3_next with {what} is not supported")
+    head_dim = d["head_dim"]
+    rotary = int(head_dim * d.get("partial_rotary_factor", 1.0))
+    kinds = tuple(
+        ("full" if (i + 1) % every == 0 else "gdn") + "+moe"
+        for i in range(n_layers)
+    )
+    num_experts = d["num_experts"]
+    held = d.get("num_local_experts", num_experts)
+    shared = d.get("shared_expert_intermediate_size", 0)
+    return ModelConfig(
+        vocab_size=d["vocab_size"],
+        hidden_size=d["hidden_size"],
+        intermediate_size=d["intermediate_size"],
+        num_layers=n_layers,
+        num_heads=d["num_attention_heads"],
+        num_kv_heads=d["num_key_value_heads"],
+        head_dim=head_dim,
+        max_position_embeddings=d.get("max_position_embeddings", 32768),
+        rope_theta=float(d.get("rope_theta", 10000.0)),
+        rms_norm_eps=d.get("rms_norm_eps", 1e-6),
+        tie_word_embeddings=d.get("tie_word_embeddings", False),
+        qk_norm="head",
+        family="qwen3_next",
+        norm_add_unit_offset=True,
+        num_experts=num_experts,
+        num_experts_per_tok=d["num_experts_per_tok"],
+        moe_intermediate_size=d["moe_intermediate_size"],
+        norm_topk_prob=d.get("norm_topk_prob", True),
+        router_aux_loss_coef=d.get("router_aux_loss_coef", 0.0),
+        shared_expert_size=shared,
+        shared_expert_gate=bool(shared),
+        experts_held=0 if held == num_experts else held,
+        layer_kinds=kinds,
+        gdn=GDNConfig(
+            num_k_heads=d["linear_num_key_heads"],
+            num_v_heads=d["linear_num_value_heads"],
+            head_k_dim=d["linear_key_head_dim"],
+            head_v_dim=d["linear_value_head_dim"],
+            conv_size=d.get("linear_conv_kernel_dim", 4),
+        ),
+        full=RotaryConfig(
+            num_heads=d["num_attention_heads"],
+            rotary_dim=0 if rotary == head_dim else rotary,
+            rope_theta=float(d.get("rope_theta", 10000.0)),
+            output_gate=True,
+        ),
+    )
+
+
 def from_hf_config(d: dict) -> ModelConfig:
     """Build from a parsed HF config.json dict (families mirror the
     reference's from_hf registry: realhf/api/from_hf/)."""
@@ -644,6 +768,8 @@ def from_hf_config(d: dict) -> ModelConfig:
         return _zaya_from_hf(d)
     if model_type == "mellum":
         return _mellum_from_hf(d)
+    if model_type == "qwen3_next":
+        return _qwen3_next_from_hf(d)
     num_heads = d["num_attention_heads"]
     hidden = d["hidden_size"]
     head_dim = d.get("head_dim") or hidden // num_heads
@@ -792,6 +918,29 @@ def tiny_config(family: str = "qwen2", vocab_size: int = 128) -> ModelConfig:
             cca=CCAConfig(num_heads=4, num_kv_heads=2, rotary_dim=8,
                           rope_theta=5000000.0),
             router=RouterConfig(hidden_size=8),
+        )
+    if family == "qwen3_next":
+        # one period: three scalar-decay delta-rule layers (2 key heads of
+        # 16 serving 4 value heads of 16) and one full layer of 4 query over
+        # 2 key heads of 16, a quarter of each rotated, gated element by
+        # element; 4 experts held of 16, four a token, a gated shared one;
+        # every norm but the rule's output norm (1 + w)
+        return ModelConfig(
+            vocab_size=vocab_size, hidden_size=32, intermediate_size=64,
+            num_layers=4, num_heads=4, num_kv_heads=2, head_dim=16,
+            max_position_embeddings=512, rope_theta=10000000.0,
+            rms_norm_eps=1e-6, qk_norm="head", family=family,
+            norm_add_unit_offset=True, num_experts=16,
+            num_experts_per_tok=4, moe_intermediate_size=16,
+            norm_topk_prob=True, router_aux_loss_coef=0.0, experts_held=4,
+            shared_expert_size=16, shared_expert_gate=True,
+            layer_kinds=("gdn+moe", "gdn+moe", "gdn+moe", "full+moe"),
+            gdn=GDNConfig(num_k_heads=2, num_v_heads=4, head_k_dim=16,
+                          head_v_dim=16),
+            full=RotaryConfig(
+                num_heads=4, rotary_dim=4, rope_theta=10000000.0,
+                output_gate=True,
+            ),
         )
     if family == "mellum":
         # one period: three layers of an 8-key window, one full layer under

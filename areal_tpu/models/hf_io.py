@@ -152,6 +152,14 @@ def _kind_tensor_names(cfg: ModelConfig):
             "from (the catalog gives the config.json only); initialise from "
             "scratch"
         )
+    if cfg.family == "qwen3_next":
+        raise NotImplementedError(
+            "family 'qwen3_next' has no checkpoint layout here yet: the "
+            "published tensors interleave q, k, v and z a key head inside "
+            "in_proj_qkvz (and b, a inside in_proj_ba) where this tree lays "
+            "them side by side, and no weights are in the repository to "
+            "hold a mapping to (ROADMAP B.15); initialise from scratch"
+        )
     if cfg.experts_held:
         raise NotImplementedError(
             f"family {cfg.family!r} holding {cfg.experts_held} of "
@@ -561,9 +569,53 @@ def _zaya_config_dict(cfg: ModelConfig) -> dict:
     }
 
 
+def _qwen3_next_config_dict(cfg: ModelConfig) -> dict:
+    """The keys ``models/config._qwen3_next_from_hf`` reads."""
+    full_at = [i for i, k in enumerate(cfg.layer_kinds) if k[:4] == "full"]
+    gc, rc = cfg.gdn, cfg.full
+    return {
+        "model_type": cfg.family,
+        "vocab_size": cfg.vocab_size,
+        "hidden_size": cfg.hidden_size,
+        "intermediate_size": cfg.intermediate_size,
+        "num_hidden_layers": cfg.num_layers,
+        "num_attention_heads": cfg.num_heads,
+        "num_key_value_heads": cfg.num_kv_heads,
+        "head_dim": cfg.head_dim,
+        "max_position_embeddings": cfg.max_position_embeddings,
+        "rope_theta": cfg.rope_theta,
+        "rope_scaling": None,
+        "partial_rotary_factor": (
+            (rc.rotary_dim or cfg.head_dim) / cfg.head_dim
+        ),
+        "rms_norm_eps": cfg.rms_norm_eps,
+        "tie_word_embeddings": cfg.tie_word_embeddings,
+        "hidden_act": "silu",
+        "torch_dtype": "float32",
+        "full_attention_interval": full_at[0] + 1 if full_at else 0,
+        "linear_num_key_heads": gc.num_k_heads,
+        "linear_num_value_heads": gc.num_v_heads,
+        "linear_key_head_dim": gc.head_k_dim,
+        "linear_value_head_dim": gc.head_v_dim,
+        "linear_conv_kernel_dim": gc.conv_size,
+        "decoder_sparse_step": 1,
+        "mlp_only_layers": [],
+        "use_sliding_window": False,
+        "num_experts": cfg.num_experts,
+        "num_local_experts": cfg.num_held_experts,
+        "num_experts_per_tok": cfg.num_experts_per_tok,
+        "moe_intermediate_size": cfg.moe_intermediate_size,
+        "shared_expert_intermediate_size": cfg.shared_expert_size,
+        "norm_topk_prob": cfg.norm_topk_prob,
+        "router_aux_loss_coef": cfg.router_aux_loss_coef,
+    }
+
+
 def default_hf_config_dict(cfg: ModelConfig) -> dict:
     if cfg.family == "kimi_linear":
         return _kimi_linear_config_dict(cfg)
+    if cfg.family == "qwen3_next":
+        return _qwen3_next_config_dict(cfg)
     if cfg.family in ("laguna", "mellum"):
         return _laguna_config_dict(cfg)
     if cfg.family == "zaya":

@@ -15,9 +15,10 @@ from-scratch parallel causal LM — re-designed TPU-first:
 - `jax.checkpoint` (remat) on the scanned layer body trades FLOPs for HBM,
   replacing torch gradient checkpointing (reference base_hf_engine.py).
 - A model whose layers are of more than one kind (``ModelConfig.layer_kinds``:
-  gated delta-rule, latent-attention and rotary mixers, the last with or
-  without a window, and the convolutional latent mixer ``cca``, over dense or
-  sparse FFNs) holds ONE STACK A BLOCK KIND
+  gated delta-rule mixers of either decay (``kda`` a channel, ``gdn`` a
+  head), latent-attention and rotary mixers, the last with or without a
+  window, and the convolutional latent mixer ``cca``, over dense or sparse
+  FFNs) holds ONE STACK A BLOCK KIND
   under ``params["layers"][kind]`` and ``apply`` walks the pattern: every run
   of consecutive layers of one kind is a scan over that kind's slice, under
   the same checkpoint policy. A model of one kind keeps the flat
@@ -80,11 +81,32 @@ def _kind_leaves(cfg: ModelConfig, kind: str) -> Dict[str, tuple]:
     axes). Drawn: "normal" (std 0.02), "ones", "zeros", or an array."""
     mixer, ffn = kind.split("+")
     D = cfg.hidden_size
+    # a norm that scales by (1 + w) is the identity at ZEROS
+    unit = "zeros" if cfg.norm_add_unit_offset else "ones"
     leaves = {
-        "input_norm": ((D,), "ones", (None,)),
-        "post_attn_norm": ((D,), "ones", (None,)),
+        "input_norm": ((D,), unit, (None,)),
+        "post_attn_norm": ((D,), unit, (None,)),
     }
-    if mixer == "kda":
+    if mixer == "gdn":
+        gc = cfg.gdn
+        Hv, conv_dim = gc.num_v_heads, 2 * gc.key_dim + gc.value_dim
+        # the decay: -exp(a_log) softplus(a + dt_bias), ONE a value head;
+        # the rates spread over 1..16 and the steps over 1e-3..1e-1 as the
+        # ``kda`` mixer's are, a number a head here
+        dt = jnp.exp(jnp.linspace(jnp.log(1e-3), jnp.log(1e-1), Hv))
+        leaves.update({
+            # one product: q, k (the key heads), v, z (the value heads)
+            "w_qkvz": ((D, conv_dim + gc.value_dim), "normal",
+                       ("embed", "heads")),
+            "w_ba": ((D, 2 * Hv), "normal", ("embed", None)),  # beta, a
+            "conv_qkv": ((conv_dim, gc.conv_size), "normal", ("heads", None)),
+            "a_log": ((Hv,), jnp.log(jnp.linspace(1.0, 16.0, Hv)), (None,)),
+            "dt_bias": ((Hv,), dt + jnp.log(-jnp.expm1(-dt)), (None,)),
+            # a PLAIN weight, whatever the stack's other norms do
+            "o_norm": ((gc.head_v_dim,), "ones", (None,)),
+            "wo": ((gc.value_dim, D), "normal", ("heads", "embed")),
+        })
+    elif mixer == "kda":
         kc = cfg.kda
         H, dh = kc.num_heads, kc.head_dim
         for n in "qkv":
@@ -129,7 +151,9 @@ def _kind_leaves(cfg: ModelConfig, kind: str) -> Dict[str, tuple]:
         rc = getattr(cfg, mixer)
         Qd, KVd = rc.num_heads * cfg.head_dim, cfg.kv_dim
         leaves.update({
-            "wq": ((D, Qd), "normal", ("embed", "heads")),
+            # ``output_gate``: a head's query, then its gate as wide
+            "wq": ((D, Qd * (2 if rc.output_gate else 1)), "normal",
+                   ("embed", "heads")),
             "wk": ((D, KVd), "normal", ("embed", "heads")),
             "wv": ((D, KVd), "normal", ("embed", "heads")),
             "wo": ((Qd, D), "normal", ("heads", "embed")),
@@ -139,8 +163,8 @@ def _kind_leaves(cfg: ModelConfig, kind: str) -> Dict[str, tuple]:
                 (D, rc.num_heads), "normal", ("embed", None)
             )
         if cfg.qk_norm == "head":  # each head by itself, before the rotation
-            leaves["q_norm"] = ((cfg.head_dim,), "ones", (None,))
-            leaves["k_norm"] = ((cfg.head_dim,), "ones", (None,))
+            leaves["q_norm"] = ((cfg.head_dim,), unit, (None,))
+            leaves["k_norm"] = ((cfg.head_dim,), unit, (None,))
     elif mixer == "cca":
         cc, dh = cfg.cca, cfg.head_dim
         Qd, heads = cc.num_heads * dh, cc.num_heads + cc.num_kv_heads
@@ -345,6 +369,13 @@ def _no_kinds_stack(cfg: ModelConfig) -> str:
     )
 
 
+# what the chunked delta rule counts (``ops/kda.KDAStats``), either decay
+_RULE_COUNTS = {
+    "kda_tokens": "the real tokens the layers' scans ran over",
+    "kda_chunk_slots": "the chunk slots they ran, 64 a chunk",
+    "kda_segments": "the sequence starts they reset at",
+}
+
 # In the order a model's refusals are raised and its counts are made. A
 # kind's two halves are keys (``dense`` declares nothing); ``moe_held``: the
 # sparse FFN of a chip that holds a share (a model's, or a mesh's split).
@@ -436,15 +467,29 @@ MECHANISMS: Dict[str, Mechanism] = {
         ),
     ),
     "kda": Mechanism(
-        counts={
-            "kda_tokens": "the real tokens the layers' scans ran over",
-            "kda_chunk_slots": "the chunk slots they ran, 64 a chunk",
-            "kda_segments": "the sequence starts they reset at",
-        },
+        counts=_RULE_COUNTS,
         count=_reported("kda", "tokens", "chunk_slots", "segments"),
         no_generation=_no_kinds_stack,
         span_attrs=lambda cfg: {
             "kda_impl": kda_ops.scan_impl(cfg.kda.head_dim, cfg.kda.head_dim)
+        },
+    ),
+    "gdn": Mechanism(  # the same rule, one decay a head: the op's names
+        counts=_RULE_COUNTS,
+        count=_reported("gdn", "tokens", "chunk_slots", "segments"),
+        no_seq_parallel=lambda cfg: (
+            f"the gated delta rule (family {cfg.family!r}: a state a head "
+            "that passes from token to token) has no sequence-parallel form "
+            "(attn_impl={attn_impl!r}, seq_parallel_size={seq_parallel_size})"
+            ": the state would have to be handed from device to device, "
+            "ROADMAP B"
+        ),
+        no_generation=_no_kinds_stack,
+        span_attrs=lambda cfg: {
+            "kda_impl": kda_ops.scan_impl(
+                cfg.gdn.head_k_dim, cfg.gdn.head_v_dim
+            ),
+            "gdn_decay": "scalar",
         },
     ),
     "mla": Mechanism(no_generation=_no_kinds_stack),
@@ -872,6 +917,53 @@ def _kda_mixer(cfg, h, lp, segment_ids, kda_fn):
         return y.reshape(b, t, H * dh) @ lp["wo"], stats
 
 
+def _gdn_mixer(cfg, h, lp, segment_ids, kda_fn):
+    """The scalar-decay gated delta-rule mixer on the normed input ``h``
+    [B, T, D]: (y [B, T, D], ``KDAStats``). One product makes q, k (the key
+    heads), v and the output's gate z (the value heads), one more beta and
+    the decay's input; q, k and v pass ONE convolution together;
+    ``ops/kda.py`` has the rule in its scalar-decay form (a key head serves
+    its value heads there); the output is normed a head by a plain weight
+    and gated by ``silu(z)`` (scope ``kda_prep`` around the rule, as the
+    ``kda`` mixer's)."""
+    b, t, _ = h.shape
+    gc = cfg.gdn
+    Hk, Hv, dk, dv = (
+        gc.num_k_heads, gc.num_v_heads, gc.head_k_dim, gc.head_v_dim
+    )
+    with device_scope("qkv_proj"):
+        qkv, z = jnp.split(h @ lp["w_qkvz"], [2 * gc.key_dim + gc.value_dim],
+                           axis=-1)
+        ba = h @ lp["w_ba"]
+    with device_scope("kda_prep"):
+        qkv = jax.nn.silu(_short_conv(qkv, lp["conv_qkv"], segment_ids))
+        q, k, v = jnp.split(qkv, [gc.key_dim, 2 * gc.key_dim], axis=-1)
+
+        def l2norm(y):
+            y = y.reshape(b, t, Hk, dk)
+            return y * jax.lax.rsqrt(
+                jnp.sum(y * y, axis=-1, keepdims=True) + 1e-6
+            )
+
+        q, k = l2norm(q).astype(h.dtype), l2norm(k).astype(h.dtype)
+        v = v.reshape(b, t, Hv, dv).astype(h.dtype)
+        beta_in, a = jnp.split(ba.astype(jnp.float32), 2, axis=-1)
+        beta = jax.nn.sigmoid(beta_in)
+        g = -jnp.exp(lp["a_log"].astype(jnp.float32)) * jax.nn.softplus(
+            a + lp["dt_bias"].astype(jnp.float32)
+        )  # [B, T, Hv]: one number a value head and token
+    o, stats = (kda_fn or kda_scan)(q, k, v, g, beta, segment_ids)
+    o = _ad_checkpoint.checkpoint_name(o, "attn_out")  # as ``_kda_mixer``
+    with device_scope("kda_prep"):
+        y = rms_norm(o, lp["o_norm"], cfg.rms_norm_eps)
+        y = (
+            y.astype(jnp.float32)
+            * jax.nn.silu(z.astype(jnp.float32)).reshape(b, t, Hv, dv)
+        ).astype(h.dtype)
+    with device_scope("o_proj"):
+        return y.reshape(b, t, Hv * dv) @ lp["wo"], stats
+
+
 def _mla_mixer(cfg, h, lp, segment_ids, attend_fn):
     """Latent attention without positions on the normed input ``h``: the
     keys' and values' one compression, a key part all heads share, and a
@@ -907,12 +999,20 @@ def _mla_mixer(cfg, h, lp, segment_ids, attend_fn):
 def _head_norm_in_rotation(cfg, lp, t):
     """name -> q's or k's head norm as ``ops/rotary.rotate`` takes one,
     (weight, eps), where the rotation's kernel runs on rows of ``t`` tokens
-    and norms a head in its own pass; name -> None where the caller norms
-    (the ``jnp`` form) and for a model that does not norm q and k a head."""
+    and norms a head in its own pass (a norm that scales by (1 + w) hands it
+    that sum); name -> None where the caller norms (the ``jnp`` form) and for
+    a model that does not norm q and k a head."""
     fused = cfg.qk_norm == "head" and rotary_ops.rotate_impl(
         cfg.head_dim, t
     ) != "xla"
-    return lambda name: (lp[name], cfg.rms_norm_eps) if fused else None
+
+    def weight(name):  # a (1 + w) norm hands the kernel the sum
+        w = lp[name]
+        if cfg.norm_add_unit_offset:
+            w = 1.0 + w.astype(jnp.float32)
+        return w
+
+    return lambda name: (weight(name), cfg.rms_norm_eps) if fused else None
 
 
 def _rotary_mixer(cfg, rc, h, lp, segment_ids, cos, sin, attend_fn):
@@ -926,14 +1026,23 @@ def _rotary_mixer(cfg, rc, h, lp, segment_ids, cos, sin, attend_fn):
     are."""
     b, t, _ = h.shape
     H, dh = rc.num_heads, cfg.head_dim
-
+    uo = cfg.norm_add_unit_offset
     in_rotation = _head_norm_in_rotation(cfg, lp, t)
+    out_gate = None
 
     def rotated(w, heads, norm):
+        nonlocal out_gate
         with device_scope("qkv_proj"):
-            x = (h @ lp[w]).reshape(b, t, heads, dh)
+            x = h @ lp[w]
+            if rc.output_gate and w == "wq":  # a head: its query, its gate
+                x, out_gate = jnp.split(
+                    x.reshape(b, t, heads, 2 * dh), 2, axis=-1
+                )
+            x = x.reshape(b, t, heads, dh)
             if cfg.qk_norm == "head" and in_rotation(norm) is None:
-                x = rms_norm(x, lp[norm], cfg.rms_norm_eps)
+                x = rms_norm(
+                    x, lp[norm], cfg.rms_norm_eps, add_unit_offset=uo
+                )
         with device_scope("rope"):
             return rotate_leading(x, cos, sin, in_rotation(norm))
 
@@ -957,6 +1066,12 @@ def _rotary_mixer(cfg, rc, h, lp, segment_ids, cos, sin, attend_fn):
             )
             attn = (
                 attn.astype(jnp.float32) * gate[..., None]
+            ).astype(h.dtype)
+    if out_gate is not None:  # element by element, from the query's product
+        with device_scope("attn_gate"):
+            attn = (
+                attn.astype(jnp.float32)
+                * jax.nn.sigmoid(out_gate.astype(jnp.float32))
             ).astype(h.dtype)
     with device_scope("o_proj"):
         return attn.reshape(b, t, H * dh) @ lp["wo"]
@@ -1106,10 +1221,15 @@ def _block_body(
             cfg, x, lp, segment_ids, rotation, attend_fn, moe_fn
         )
     with device_scope("stream_norm"):
-        h = rms_norm(x, lp["input_norm"], cfg.rms_norm_eps)
+        h = rms_norm(
+            x, lp["input_norm"], cfg.rms_norm_eps,
+            add_unit_offset=cfg.norm_add_unit_offset,
+        )
     mixer_stats = None
     if mixer == "kda":
         y, mixer_stats = _kda_mixer(cfg, h, lp, segment_ids, kda_fn)
+    elif mixer == "gdn":
+        y, mixer_stats = _gdn_mixer(cfg, h, lp, segment_ids, kda_fn)
     elif mixer in _ROTARY_MIXERS:
         y = _rotary_mixer(
             cfg, getattr(cfg, mixer), h, lp, segment_ids, *rotation,

@@ -223,12 +223,12 @@ def _block_sizes(
     8192   8/2 of 128                    3.517 (3.894) 3.679 (3.824)
     16384  48/8 of 128                   61.85 (88.40) 58.93 (80.76)
     16384  8/2 of 128                    8.131 (14.49) 7.843 (13.14)
+    16384  16/2 of 256     41.77 (61.64) 35.71 (53.43) 43.46 (59.38)
     ====== =============== ============= ============= =============
 
-    - so edge 512 to T = 2048 (the dense heads gain 8.6%, the 16 of 128 lose
-      0.7%), 1024 to T = 8192 (before PR 42 8192 was unmeasured and took
-      2048, which the latent core's 192 | 128 pays 20% for), 2048 above; no
-      length has a shape that wants another edge by more than that 0.7%;
+    - so edge 512 to T = 2048 (dense heads +8.6%, the 16 of 128 -0.7%), 1024
+      to T = 8192 (at 2048 the latent core's 192 | 128 paid 20%), 2048 above;
+      only the 16/2 of 256 (PR 52) want another, 1024, by 18% (ROADMAP A.21);
     - a local window skips only whole tiles, so a PACKING window of at most
       1024 takes edge 512 (1.39 against 1.44 ms at T = 4096, PR 26); a
       LAYER's window (``band``: the caller says so, ``_local_mask``) that
@@ -250,8 +250,8 @@ def _block_sizes(
       one pass over the data, 10-25% under the split kernels everywhere
       (PR 26).
 
-    The head dimension moved none of these choices. Never above what
-    ``probe_block_size`` found to run, and always a divisor of ``t``."""
+    From 64 to 192 the head dimension moved none of these choices. Never above
+    what ``probe_block_size`` found to run, and always a divisor of ``t``."""
     if band:
         want, fused = BAND_EDGE, False
     else:

@@ -65,6 +65,20 @@ chunk's last token's sequence (``Kt``, ``d``). Padding (segment 0) takes no
 part: its ``q, k, v, beta, g`` are zeroed on the way in. So the state pass
 knows nothing of segments.
 
+The SCALAR-DECAY form (``g`` [B, T, H]: one log-decay a head and token, the
+gated delta rule of Qwen3-Next's ``gdn`` mixer; ``q, k`` may have fewer heads
+than ``v``, each key head serving ``H / Hk`` consecutive value heads): the
+exponent leaves the sum over channels, ``A = (K K^T) * D`` and ``Aqk = (Q K^T)
+* D / sqrt(dk)`` with ``D[i, j] = exp(G_i - G_j)`` a [CHUNK, CHUNK] matrix a
+head, so the operands are plain batched products (``_prep_scalar``, XLA on
+every platform, differentiated by XLA but for the triangular inverse, whose
+cotangent is ``-T^T dT T^T``; device scope ``gdn_operands`` inside
+``kda_scan``). The decay a chunk carries, ``d``, is that scalar laid over the
+``dk`` channels, so the state pass (``kda_fwd_h`` / ``kda_bwd_dh`` or the
+``lax.scan``), the packed-row and the padding rules are the per-channel form's
+as they stand. It is held EQUAL to the per-channel form with ``g`` repeated
+over the channels and ``q, k`` over the value heads (tests).
+
 Device scope ``kda_scan`` (``utils/tracing.DEVICE_SCOPES``): everything in
 this file. The convolution, the decays' and gates' projections and the
 output norm are the layer's (``kda_prep``, ``models/transformer.py``).
@@ -267,6 +281,101 @@ def _seg_in(seg):
     is then split there and lowered once more."""
     last = jax.lax.index_in_dim(seg, CHUNK - 1, axis=2, keepdims=False)
     return jnp.concatenate([last[:1] * 0 - 1, last[:-1]], axis=0)
+
+
+# --------------------------------------------- the scalar-decay operands --
+@jax.custom_vjp
+def _unit_lower_inverse_t(n):
+    """``_unit_lower_inverse`` with its cotangent by hand: for T = (I + N)^-1,
+    dN = -T^T dT T^T, two products, where XLA's own would walk the
+    substitution's rows back one by one."""
+    return _unit_lower_inverse(n)
+
+
+def _unit_lower_inverse_t_fwd(n):
+    t = _unit_lower_inverse(n)
+    return t, t
+
+
+def _unit_lower_inverse_t_bwd(t, dt):
+    tt = jnp.swapaxes(t, -1, -2)
+    return (-jnp.einsum(
+        "...ij,...jk,...kl->...il", tt, dt, tt, precision=_HI
+    ),)
+
+
+_unit_lower_inverse_t.defvjp(
+    _unit_lower_inverse_t_fwd, _unit_lower_inverse_t_bwd
+)
+
+
+def _prep_scalar(q, k, v, g, beta, seg, seg_in):
+    """``_prep`` where the log-decay is ONE number a head and token: ``q, k``
+    [N, B, Hk, C, dk], ``v`` [N, B, H, C, dv] (``H`` a multiple of ``Hk``:
+    key head ``h // (H / Hk)`` serves value head ``h``), ``g`` and ``beta``
+    float32 [N, B, H, C]. The same six operands, a value head each, ``d``
+    [.., 1, dk] the chunk's scalar decay laid over the channels. Every
+    exponent is a difference that is <= 0 here too: ``D[i, j] = exp(G_i -
+    G_j)`` for j <= i, ``exp(G)``, ``exp(G_last - G)``."""
+    f32, dt = jnp.float32, q.dtype
+    dk = q.shape[-1]
+    n, b, hk = q.shape[:3]
+    h = v.shape[2]
+    r = h // hk
+    scale = dk ** -0.5
+    valid = seg > 0  # [N, B, C]
+    vm = valid[:, :, None, :, None]
+    q, k, v = (jnp.where(vm, x, 0) for x in (q, k, v))
+    g = jnp.where(valid[:, :, None], g, 0.0)
+    beta = jnp.where(valid[:, :, None], beta, 0.0)
+    G = jnp.cumsum(g, axis=-1)  # [N, B, H, C] inclusive, <= 0, decreasing
+    idx = jnp.arange(CHUNK)
+    lower = idx[:, None] >= idx[None, :]
+    decay = jnp.exp(jnp.where(
+        lower, G[..., :, None] - G[..., None, :], -jnp.inf
+    ))  # [N, B, H, C, C]
+
+    def pairs(x):  # (x K^T) a key head, laid over its value heads
+        p = jnp.einsum(
+            "...id,...jd->...ij", x, k, preferred_element_type=f32
+        )
+        return jnp.repeat(p, r, axis=2) if r > 1 else p
+
+    same = (seg[..., :, None] == seg[..., None, :])[:, :, None]
+    a_kk = jnp.where(
+        same & (idx[:, None] > idx[None, :]), pairs(k) * decay, 0.0
+    )
+    a_qk = jnp.where(same & lower, pairs(q) * decay, 0.0) * scale
+    t_inv = _unit_lower_inverse_t(beta[..., None] * a_kk).astype(dt)
+
+    def heads(x):  # a key head's rows for each of its value heads, float32
+        x = x.astype(f32)
+        return jnp.repeat(x, r, axis=2) if r > 1 else x
+
+    qh, kh = heads(q), heads(k)
+    e_g = jnp.exp(G)[..., None]
+    from_in = (valid & (seg == seg_in[..., None]))[:, :, None, :, None]
+    kbar = jnp.where(from_in, kh * e_g, 0.0)
+    bcol = beta[..., None]
+
+    def solved(x):  # T x, the MXU's operands in the compute dtype
+        return jnp.einsum(
+            "...ij,...jd->...id", t_inv, x.astype(dt),
+            preferred_element_type=f32,
+        )
+
+    wk = solved(bcol * kbar)
+    wv = solved(bcol * v.astype(f32))
+    qb = jnp.where(from_in, qh * e_g, 0.0) * scale
+    g_last = G[..., -1:]
+    seg_last = seg[..., -1]  # [N, B]
+    to_out = (valid & (seg == seg_last[..., None]))[:, :, None, :, None]
+    kt = jnp.where(to_out, kh * jnp.exp(g_last - G)[..., None], 0.0)
+    carries = ((seg_last == seg_in) & (seg_last > 0))[:, :, None, None]
+    d = jnp.where(carries, jnp.exp(g_last), 0.0)  # [N, B, H, 1]
+    d = jnp.broadcast_to(d[..., None], (n, b, h, 1, dk))
+    return (wk.astype(dt), wv.astype(dt), qb.astype(dt), kt.astype(dt),
+            a_qk.astype(dt), d)
 
 
 # ------------------------------------------------------------ state pass --
@@ -834,7 +943,17 @@ def _operands_bwd_kernel(q, k, v, g, beta, seg, d_ops, interpret=False):
 
 
 # -------------------------------------------------------------- the op --
+def _scalar_decay(g, beta) -> bool:
+    """One log-decay a head and token: ``g`` is of ``beta``'s rank."""
+    return g.ndim == beta.ndim
+
+
 def _operands(q, k, v, g, beta, seg, impl="xla", interpret=False):
+    if _scalar_decay(g, beta):  # plain products on every platform
+        with device_scope("gdn_operands"):
+            return _in_blocks(
+                _prep_scalar, (q, k, v, g, beta, seg, _seg_in(seg))
+            )
     if impl == "kernel":
         return _operands_kernel(q, k, v, g, beta, seg, interpret=interpret)
     return _in_blocks(_prep, (q, k, v, g, beta, seg, _seg_in(seg)))
@@ -857,13 +976,16 @@ def _chunked_fwd(q, k, v, g, beta, seg, impl, interpret):
     return out, (q, k, v, g, beta, seg)
 
 
-def _operands_bwd_xla(q, k, v, g, beta, seg, d_ops):
-    """XLA's own differentiation of ``_prep``, a block of chunks at a time:
-    the CPU's path, and what ``chunk_ops_bwd`` is held equal to."""
+def _operands_bwd_xla(q, k, v, g, beta, seg, d_ops, prep=_prep):
+    """XLA's own differentiation of ``prep`` (``_prep``, or ``_prep_scalar``
+    with its inverse's cotangent by hand), a block of chunks at a time, so
+    that what the differentiation keeps is a block's: the CPU's path, what
+    ``chunk_ops_bwd`` is held equal to, and the scalar-decay form's backward
+    on every platform."""
 
     def prep_bwd(q, k, v, g, beta, seg, seg_in, *cts):
         _, vjp = jax.vjp(
-            lambda *x: _prep(*x, seg, seg_in), q, k, v, g, beta
+            lambda *x: prep(*x, seg, seg_in), q, k, v, g, beta
         )
         return vjp(cts)
 
@@ -878,11 +1000,18 @@ def _chunked_bwd(impl, interpret, res, do):
     _, states = _state_fwd(ops, impl, interpret, save_states=True)
     if impl == "kernel":
         d_ops = _state_bwd_kernel(ops, states, do, interpret=interpret)
+    else:
+        d_ops = _state_bwd_xla(ops, states, do)
+    if _scalar_decay(g, beta):
+        with device_scope("gdn_operands"):
+            grads = _operands_bwd_xla(
+                q, k, v, g, beta, seg, d_ops, prep=_prep_scalar
+            )
+    elif impl == "kernel":
         grads = _operands_bwd_kernel(
             q, k, v, g, beta, seg, d_ops, interpret=interpret
         )
     else:
-        d_ops = _state_bwd_xla(ops, states, do)
         grads = _operands_bwd_xla(q, k, v, g, beta, seg, d_ops)
     return tuple(grads) + (None,)
 
@@ -907,8 +1036,15 @@ def _chunk_inputs(q, k, v, g, beta, seg):
             jnp.pad(x, [(0, 0), (0, pad)] + [(0, 0)] * (x.ndim - 2))
             for x in (q, k, v, g, beta, seg)
         )
-    chunks = [_to_chunks(x) for x in (q, k, v, g.astype(jnp.float32))]
-    beta_c = _to_chunks(beta.astype(jnp.float32)[..., None])[..., 0]
+    def scalars(x):  # [B, T, H] -> [N, B, H, C] float32
+        return _to_chunks(x.astype(jnp.float32)[..., None])[..., 0]
+
+    chunks = [_to_chunks(x) for x in (q, k, v)]
+    chunks.append(
+        scalars(g) if _scalar_decay(g, beta)
+        else _to_chunks(g.astype(jnp.float32))
+    )
+    beta_c = scalars(beta)
     seg_c = seg.astype(jnp.int32).reshape(b, -1, CHUNK).transpose(1, 0, 2)
     return (*chunks, beta_c, seg_c)
 
@@ -917,7 +1053,9 @@ def kda_scan(
     q: jnp.ndarray,  # [B, T, H, dk], l2-normed
     k: jnp.ndarray,  # [B, T, H, dk], l2-normed
     v: jnp.ndarray,  # [B, T, H, dv]
-    g: jnp.ndarray,  # [B, T, H, dk] float32 log-decay, <= 0
+    # [B, T, H, dk] float32 log-decay, <= 0; or [B, T, H], ONE a head and
+    # token (the scalar-decay form: then ``q, k`` [B, T, Hk, dk], H % Hk == 0)
+    g: jnp.ndarray,
     beta: jnp.ndarray,  # [B, T, H] float32 in (0, 1)
     segment_ids: jnp.ndarray,  # [B, T] int32; 0 = padding
     impl: Optional[str] = None,  # None: ``scan_impl``'s
@@ -929,7 +1067,7 @@ def kda_scan(
     dtype, zero at padding; ``KDAStats``). ``token_axes``: the mesh axes the
     rows are split over when this runs per device inside a ``shard_map``,
     so that the counts are the whole batch's."""
-    b, t, h, dk = q.shape
+    (b, t, _, dk), h = q.shape, v.shape[2]
     impl = impl or scan_impl(dk, v.shape[-1])
     with device_scope("kda_scan"):
         a = _chunk_inputs(q, k, v, g, beta, segment_ids)
@@ -957,8 +1095,9 @@ def make_kda_scan(mesh: Mesh):
     """The op's mesh form: ``kda_fn(q, k, v, g, beta, segment_ids)`` for
     ``transformer.apply``, per device inside a ``shard_map`` over rows and
     heads as the splash kernel runs (None on one device: the plain call
-    serves). The rule never crosses a row, so this is exact; a sequence
-    split would need the state handed from device to device."""
+    serves); ``g`` is split as its rank says, a channel's or a head's decay.
+    The rule never crosses a row, so this is exact; a sequence split would
+    need the state handed from device to device."""
     if mesh.size == 1:
         return None
     if mesh.shape["seq"] > 1:
@@ -967,12 +1106,17 @@ def make_kda_scan(mesh: Mesh):
             f"(seq_parallel_size={mesh.shape['seq']}): ROADMAP B"
         )
     rows = ("data", "fsdp")
-    x_spec = P(rows, None, "tensor", None)
-    return jax.shard_map(
-        functools.partial(kda_scan, token_axes=rows),
-        mesh=mesh,
-        in_specs=(x_spec, x_spec, x_spec, x_spec, P(rows, None, "tensor"),
-                  P(rows, None)),
-        out_specs=(x_spec, P()),
-        check_vma=False,
-    )
+    x_spec, head_spec = P(rows, None, "tensor", None), P(rows, None, "tensor")
+
+    def kda_fn(q, k, v, g, beta, segment_ids):
+        return jax.shard_map(
+            functools.partial(kda_scan, token_axes=rows),
+            mesh=mesh,
+            in_specs=(x_spec, x_spec, x_spec,
+                      head_spec if _scalar_decay(g, beta) else x_spec,
+                      head_spec, P(rows, None)),
+            out_specs=(x_spec, P()),
+            check_vma=False,
+        )(q, k, v, g, beta, segment_ids)
+
+    return kda_fn

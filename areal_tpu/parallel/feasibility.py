@@ -547,7 +547,55 @@ def mellum2_12b_a2p5b_config() -> ModelConfig:
     )
 
 
+def qwen3_next_80b_a3b_config() -> ModelConfig:
+    """Qwen3-Next-80B-A3B-Instruct (Qwen) as ONE chip of 32 that share each
+    layer holds it: published layers 0-3 (three scalar-decay delta-rule
+    layers, one gated full-attention layer: one whole period), 16 of the 512
+    softmax-routed experts and the gated shared one, an eighth of the untied
+    vocabulary: the cut of benchmark/configs/qwen3-next-80b-a3b.json
+    (published: 48 layers, 151,936 rows). Every width is as published: 16
+    key and 32 value heads of 128 under a convolution of 4, 16 query and 2
+    key heads of 256 of which 64 channels turn at 10,000,000, experts of
+    512, the router's 512 outputs and 10 a token. The trainer runs it; the
+    generation engine, ``hf_io`` and sequence parallelism refuse it by name
+    (ROADMAP B.3-B.5)."""
+    from areal_tpu.models.config import GDNConfig, RotaryConfig
+
+    return ModelConfig(
+        vocab_size=18992,
+        hidden_size=2048,
+        intermediate_size=5120,
+        num_layers=4,
+        num_heads=16,
+        num_kv_heads=2,
+        head_dim=256,
+        max_position_embeddings=262144,
+        rope_theta=10000000.0,
+        rms_norm_eps=1e-6,
+        tie_word_embeddings=False,
+        qk_norm="head",
+        family="qwen3_next",
+        norm_add_unit_offset=True,
+        num_experts=512,
+        num_experts_per_tok=10,
+        moe_intermediate_size=512,
+        norm_topk_prob=True,
+        router_aux_loss_coef=0.0,
+        shared_expert_size=512,
+        shared_expert_gate=True,
+        experts_held=16,
+        layer_kinds=("gdn+moe", "gdn+moe", "gdn+moe", "full+moe"),
+        gdn=GDNConfig(num_k_heads=16, num_v_heads=32, head_k_dim=128,
+                      head_v_dim=128, conv_size=4),
+        full=RotaryConfig(
+            num_heads=16, rotary_dim=64, rope_theta=10000000.0,
+            output_gate=True,
+        ),
+    )
+
+
 MODEL_CONFIGS = {
+    "qwen3_next_80b_a3b": qwen3_next_80b_a3b_config,
     "mellum2_12b_a2p5b": mellum2_12b_a2p5b_config,
     "zaya1_8b": zaya1_8b_config,
     "laguna_xsp2": laguna_xsp2_config,

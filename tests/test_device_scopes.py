@@ -206,11 +206,16 @@ WANT = {
     # router under moe_route
     "cca_grad_step": {"cca_mix", "attention", "moe_route", "moe_experts",
                       "mlp", "rope"} | _AROUND,
+    # the delta rule with one decay a head: its operands under a scope of
+    # their own inside kda_scan, beside the gated full layer's core and gate
+    "gdn_grad_step": {"kda_scan", "kda_prep", "gdn_operands", "attention",
+                      "attn_gate", "moe_experts", "mlp", "rope"} | _AROUND,
 }
 # a family's ``grad_step`` among the programs of ``lowered``
 FAMILIES = {"qwen2": "grad_step", "olmoe": "sparse_grad_step",
             "KeyeVL2": "dsa_grad_step", "kimi_linear": "kda_grad_step",
-            "laguna": "swa_grad_step", "zaya": "cca_grad_step"}
+            "laguna": "swa_grad_step", "zaya": "cca_grad_step",
+            "qwen3_next": "gdn_grad_step"}
 # the five families whose programs a change for a sixth may not move: the
 # lowered grad_step's text (the persistent compile cache's key is made of
 # it), by sha256, in tests/golden/grad_step_stablehlo.json. A PR that means
@@ -232,7 +237,7 @@ def lowered():
     out = {name: _op_names(low) for name, low in dense + _decode_programs()}
     grad_steps = {"grad_step": dense[0][1]}
     for program, family in list(STABLE.items())[1:] + [
-        ("cca_grad_step", "zaya")
+        ("cca_grad_step", "zaya"), ("gdn_grad_step", "qwen3_next")
     ]:
         grad_steps[program] = _train_programs(family, only_grad=True)[0][1]
         out[program] = _op_names(grad_steps[program])
@@ -258,7 +263,7 @@ def test_program_lowers_under_its_contract_name_with_its_scopes(
     jitted = jitted.removeprefix("bf16_")
     jitted = jitted.removeprefix("dsa_")
     jitted = jitted.removeprefix("kda_").removeprefix("swa_")
-    jitted = jitted.removeprefix("cca_")
+    jitted = jitted.removeprefix("cca_").removeprefix("gdn_")
     assert re.search(rf"^HloModule jit_{jitted}[, ]", text, re.M)
     assert WANT[program] <= _scopes_in(names), sorted(names)[:40]
 

@@ -193,10 +193,12 @@ def test_the_shares_of_the_experts_add_up_to_the_uncut_layer(tiny):
     )
 
 
-@pytest.mark.parametrize("family", ["qwen2_moe", "kimi_linear"])
+@pytest.mark.parametrize("family", ["qwen2_moe", "kimi_linear", "qwen3_next"])
 def test_the_shared_expert_runs_inside_the_sparse_layers_shard_map(family):
     """Under a mesh the whole sparse layer, shared expert included, is one
-    per-device call: the same numbers as the plain call on one device."""
+    per-device call: the same numbers as the plain call on one device (for
+    ``qwen3_next`` the gated shared expert of a stack of kinds, and the
+    delta rule's mesh form splitting ``g`` [B, T, H] by its rank)."""
     from areal_tpu.api.cli_args import ParallelismConfig
     from areal_tpu.engine.programs import _forward_fn
     from areal_tpu.parallel import mesh as mesh_lib
