@@ -70,11 +70,11 @@ gated delta rule of Qwen3-Next's ``gdn`` mixer; ``q, k`` may have fewer heads
 than ``v``, each key head serving ``H / Hk`` consecutive value heads): the
 exponent leaves the sum over channels, ``A = (K K^T) * D`` and ``Aqk = (Q K^T)
 * D / sqrt(dk)`` with ``D[i, j] = exp(G_i - G_j)`` a [CHUNK, CHUNK] matrix a
-head, so the operands are plain batched products (``_prep_scalar``, XLA on
-every platform, differentiated by XLA but for the triangular inverse, whose
-cotangent is ``-T^T dT T^T``; device scope ``gdn_operands`` inside
-``kda_scan``). The decay a chunk carries, ``d``, is that scalar laid over the
-``dk`` channels, so the state pass (``kda_fwd_h`` / ``kda_bwd_dh`` or the
+head. ``"xla"``: ``_prep_scalar``, batched products that XLA differentiates
+but for the inverse (``-T^T dT T^T``). ``"kernel"``: ``gdn_ops_fwd`` /
+``gdn_ops_bwd`` (the file's end) keep a key head's chunks in VMEM from q, k,
+v to the operands and back. Device scope ``gdn_operands`` either way. ``d`` is
+that scalar over the ``dk`` channels, so the state pass (``kda_fwd_h`` / the
 ``lax.scan``), the packed-row and the padding rules are the per-channel form's
 as they stand. It is held EQUAL to the per-channel form with ``g`` repeated
 over the channels and ``q, k`` over the value heads (tests).
@@ -129,9 +129,9 @@ class KDAStats(NamedTuple):
 
 def scan_impl(dk: Optional[int] = None, dv: Optional[int] = None) -> str:
     """The form this process runs, operands and state pass alike:
-    ``"kernel"`` (``chunk_ops_fwd`` / ``chunk_ops_bwd``, ``kda_fwd_h`` /
+    ``"kernel"`` (``chunk_ops_*`` | ``gdn_ops_*``, ``kda_fwd_h`` /
     ``kda_bwd_dh``) on a TPU for head widths that are whole lane tiles,
-    ``"xla"`` (``_prep`` and the ``lax.scan``) elsewhere."""
+    ``"xla"`` (``_prep`` | ``_prep_scalar``, the ``lax.scan``) elsewhere."""
     if jax.default_backend() != "tpu":
         return "xla"
     if dk is not None and (dk % 128 or dv % 128):
@@ -949,10 +949,10 @@ def _scalar_decay(g, beta) -> bool:
 
 
 def _operands(q, k, v, g, beta, seg, impl="xla", interpret=False):
-    if _scalar_decay(g, beta):  # plain products on every platform
+    if _scalar_decay(g, beta):  # its own two forms, at the file's end
         with device_scope("gdn_operands"):
-            return _in_blocks(
-                _prep_scalar, (q, k, v, g, beta, seg, _seg_in(seg))
+            return _scalar_operands(
+                q, k, v, g, beta, seg, impl, interpret
             )
     if impl == "kernel":
         return _operands_kernel(q, k, v, g, beta, seg, interpret=interpret)
@@ -980,8 +980,8 @@ def _operands_bwd_xla(q, k, v, g, beta, seg, d_ops, prep=_prep):
     """XLA's own differentiation of ``prep`` (``_prep``, or ``_prep_scalar``
     with its inverse's cotangent by hand), a block of chunks at a time, so
     that what the differentiation keeps is a block's: the CPU's path, what
-    ``chunk_ops_bwd`` is held equal to, and the scalar-decay form's backward
-    on every platform."""
+    ``chunk_ops_bwd`` is held equal to and, with ``_prep_scalar``, what
+    ``gdn_ops_bwd`` is."""
 
     def prep_bwd(q, k, v, g, beta, seg, seg_in, *cts):
         _, vjp = jax.vjp(
@@ -1004,8 +1004,8 @@ def _chunked_bwd(impl, interpret, res, do):
         d_ops = _state_bwd_xla(ops, states, do)
     if _scalar_decay(g, beta):
         with device_scope("gdn_operands"):
-            grads = _operands_bwd_xla(
-                q, k, v, g, beta, seg, d_ops, prep=_prep_scalar
+            grads = _scalar_operands_bwd(
+                q, k, v, g, beta, seg, d_ops, impl, interpret
             )
     elif impl == "kernel":
         grads = _operands_bwd_kernel(
@@ -1120,3 +1120,309 @@ def make_kda_scan(mesh: Mesh):
         )(q, k, v, g, beta, segment_ids)
 
     return kda_fn
+
+
+# ------------------------------- the scalar-decay operands as kernels --
+# At the file's end, and the lines above keep their numbers: a Pallas body
+# carries the line numbers of its callers, so the per-channel kernels' bodies
+# (and the cache entries of the programs that hold them) stay as they were.
+def _mdot(a, b, dims, precision=None):
+    """``_bdot`` as ONE batched product: an operation in the kernel's body
+    where ``_bdot`` leaves one a chunk (this compiler takes the batched form,
+    and a body is traced and lowered at every set-up)."""
+    (ca,), (cb,) = dims
+    return jax.lax.dot_general(
+        a, b, (((ca + 1,), (cb + 1,)), ((0,), (0,))), precision=precision,
+        preferred_element_type=jnp.float32,
+    )
+
+
+def _items(ref):
+    """A ref [nb, r, ..] as [m, ..] items, a value head after the other."""
+    return jnp.concatenate([ref[:, j] for j in range(ref.shape[1])])
+
+
+def _gdn_parts(q, k, v, g, beta, marks, n_scr):
+    """``_prep_scalar``'s values in VMEM for ``nb`` chunks of one KEY head
+    and its ``r`` value heads, as ``_block_parts`` keeps ``_prep``'s: the
+    (value head, chunk) pairs are one leading axis of ``m = r nb`` items, so
+    that each step of the substitution is taken for all of them at once,
+    and the two pair products ``K K^T``, ``Q K^T`` are made once a key head.
+    The refs: ``q, k`` [nb, 1, C, dk]; ``v`` [nb, r, C, dv]; ``g, beta``
+    [nb, r, 1, C] float32; ``marks`` [nb, 1, 4, C] (``_marks``); ``n_scr``
+    [m, C, C] float32, from which a row of N is read back broadcast over the
+    sublanes. A token's scalars lie on the lanes as they arrive; the running
+    sum turns them onto the sublanes (a masked sum over the lanes, float32
+    on the VPU: a [C] vector has no product worth the MXU), and the row form
+    of ``G`` is a copy of that column, so that ``D``'s diagonal is exp(0)."""
+    f32, dt = jnp.float32, q.dtype
+    nb, r, c, _ = v.shape
+    dk = q.shape[-1]
+    scale = dk ** -0.5
+    ri = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    ci = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    eye = ri == ci
+
+    def column(row):  # [.., 1, C] -> [.., C, 1]
+        return jnp.sum(jnp.where(eye, row, 0.0), axis=-1, keepdims=True)
+
+    def heads(x):  # a key head's [nb, ..] for each of its value heads
+        return jnp.concatenate([x] * r) if r > 1 else x
+
+    marks = marks[:, 0]
+    seg_row = marks[:, 0:1]
+    seg_col = column(seg_row)
+    valid = seg_col > 0  # [nb, C, 1]
+    qf = jnp.where(valid, q[:, 0].astype(f32), 0.0)
+    kf = jnp.where(valid, k[:, 0].astype(f32), 0.0)
+    qc, kc = qf.astype(dt), kf.astype(dt)
+    pairs = _mdot(jnp.concatenate([kc, qc], 1), kc, _NT)  # [nb, 2 C, C]
+    seg_row, seg_col = heads(seg_row), heads(seg_col)
+    valid_m = seg_col > 0  # [m, C, 1]
+    from_in = heads(column(marks[:, 1:2])) > 0
+    to_out = heads(column(marks[:, 2:3])) > 0
+    g_row = jnp.where(seg_row > 0, _items(g), 0.0)  # [m, 1, C]
+    beta = jnp.where(valid_m, column(_items(beta)), 0.0)  # [m, C, 1]
+    vf = jnp.where(valid_m, _items(v).astype(f32), 0.0)
+    big_g = jnp.sum(
+        jnp.where(ci <= ri, g_row, 0.0), axis=-1, keepdims=True
+    )  # [m, C, 1]: the running sum, <= 0, decreasing
+    g_back = jnp.sum(jnp.where(eye, big_g, 0.0), axis=1, keepdims=True)
+    decay = jnp.exp(jnp.where(ri >= ci, big_g - g_back, -jnp.inf))
+    same = seg_col == seg_row
+    strict = same & (ri > ci)
+    lower = same & (ri >= ci)
+    a_kk = jnp.where(strict, heads(pairs[:, :c]) * decay, 0.0)
+    rd = jnp.where(lower, heads(pairs[:, c:]) * decay, 0.0)  # Aqk / scale
+
+    # --- T^T = (I + Diag(beta) A)^-T, as ``_block_parts`` has it. (The
+    # four diagonal blocks side by side on the lanes, a row's sum as four
+    # lane rolls in place of the reduction, read 12.1 ms where this reads
+    # 7.3: PERF.md, PR 53)
+    n = beta * a_kk
+    n_scr[...] = n
+    m = n.shape[0]
+    blk = (ri // SUB) == (ci // SUB)
+    eye_f = jnp.broadcast_to(jnp.where(eye, 1.0, 0.0), (m, c, c))
+
+    def substitute(i, x):
+        n_row = jnp.concatenate([
+            jnp.broadcast_to(n_scr[:, pl.ds(a * SUB + i, 1), :], (m, SUB, c))
+            for a in range(_NSUB)
+        ], 1)
+        hit = jnp.sum(n_row * x, axis=-1, keepdims=True)  # x is 0 off blk
+        return jnp.where(blk & (ci % SUB == i), eye_f - hit, x)
+
+    x = _loop(substitute, eye_f)  # row 0 finds nothing above it
+    m_t = _mdot(jnp.where(blk, 0.0, n), x, _TN, _HI)  # (D L)^T
+    t_t = x
+    for _ in range(_NSUB - 1):
+        t_t = x - _mdot(t_t, m_t, _NN, _HI)
+
+    e_g = jnp.exp(big_g)
+    e_out = jnp.exp(big_g[:, c - 1:c] - big_g)
+    kf_m, qf_m = heads(kf), heads(qf)
+    kbar = jnp.where(from_in, kf_m * e_g, 0.0)
+    t_c = t_t.astype(dt)
+    carries = heads(column(marks[:, 3:4])) > 0
+    return dict(
+        qc=qc, kc=kc, kf=kf_m, qf=qf_m, vf=vf, beta=beta,
+        valid=valid, valid_m=valid_m, valid_row=seg_row > 0, from_in=from_in,
+        to_out=to_out, strict=strict, lower=lower, eye=eye, decay=decay,
+        a_kk=a_kk, rd=rd, t_c=t_c, e_g=e_g, e_out=e_out, kbar=kbar,
+        wk=_mdot(t_c, (beta * kbar).astype(dt), _TN),
+        wv=_mdot(t_c, (beta * vf).astype(dt), _TN),
+        qb=jnp.where(from_in, qf_m * e_g, 0.0) * scale,
+        kt=jnp.where(to_out, kf_m * e_out, 0.0),
+        aqk=rd * scale,
+        d=jnp.where(carries, e_g, 0.0)[:, c - 1:c],  # [m, 1, 1]
+    )
+
+
+def _store_heads(ref, x):
+    """[m, ..] items into a ref [nb, r, ..], a value head after the other."""
+    nb, r = ref.shape[:2]
+    for j in range(r):
+        ref[:, j] = x[j * nb:(j + 1) * nb].astype(ref.dtype)
+
+
+def _gdn_fwd_kernel(q, k, v, g, beta, marks, wk, wv, qb, kt, aqk, d, n_scr):
+    p = _gdn_parts(q, k, v, g, beta, marks, n_scr)
+    for ref, name in ((wk, "wk"), (wv, "wv"), (qb, "qb"), (kt, "kt"),
+                      (aqk, "aqk")):
+        _store_heads(ref, p[name])
+    nb, r, _, dk = d.shape
+    d_lanes = jnp.broadcast_to(p["d"], (nb * r, 1, dk))
+    for j in range(r):  # a chunk at a time, as ``_store_rows``
+        for i in range(nb):
+            d[i, j] = d_lanes[j * nb + i]
+
+
+def _gdn_bwd_kernel(q, k, v, g, beta, d_wk, d_wv, d_qb, d_kt, d_aqk, d_d,
+                    marks, d_q, d_k, d_v, d_g, d_beta, n_scr):
+    """``_prep_scalar``'s backward by hand on ``_gdn_parts``' values, as
+    ``_ops_bwd_kernel`` without its pair loops: the decay matrix is there
+    whole, so the pairs' cotangents are four products a key head (its value
+    heads' summed first), and ``G``'s are row and column sums."""
+    f32, dt = jnp.float32, q.dtype
+    p = _gdn_parts(q, k, v, g, beta, marks, n_scr)
+    nb, r, c, _ = v.shape
+    scale = q.shape[-1] ** -0.5
+
+    def per_key(x):  # [m, ..] -> [nb, ..]: a key head's value heads summed
+        return sum(x[j * nb:(j + 1) * nb] for j in range(r))
+
+    kf, qf, beta_c = p["kf"], p["qf"], p["beta"]
+    # dN = -(T^T dWk) Wk^T - (T^T dWv) Wv^T  (``_ops_bwd_kernel``)
+    d_xk = _mdot(p["t_c"], _items(d_wk), _NN)
+    d_xv = _mdot(p["t_c"], _items(d_wv), _NN)
+    d_n = -jnp.where(
+        p["strict"],
+        _mdot(jnp.concatenate([d_xk, d_xv], -1),
+              jnp.concatenate([p["wk"], p["wv"]], -1), _NT, _HI),
+        0.0,
+    )
+    g_beta = (
+        jnp.sum(d_n * p["a_kk"], axis=-1, keepdims=True)
+        + jnp.sum(d_xk * p["kbar"], axis=-1, keepdims=True)
+        + jnp.sum(d_xv * p["vf"], axis=-1, keepdims=True)
+    )
+    d_kbar = beta_c * d_xk
+    d_a = beta_c * d_n  # dA, j < i
+    d_rd = jnp.where(p["lower"], _items(d_aqk).astype(f32), 0.0) * scale
+    # P = K K^T, R = Q K^T a key head: dP = dA * D, dR = dAqk * D * scale
+    pr = jnp.concatenate(
+        [per_key(d_a * p["decay"]), per_key(d_rd * p["decay"])], 1
+    ).astype(dt)  # [nb, 2 C, C]
+    at_row = _mdot(pr, p["kc"], _NN)  # dP K | dR K
+    at_col = _mdot(pr, jnp.concatenate([p["kc"], p["qc"]], 1), _TN)
+    # D = exp(G_i - G_j): dG_i += sum_j (dD * D)[i, j], dG_j -= sum_i
+    d_dd = d_a * p["a_kk"] + d_rd * p["rd"]
+    e_g, e_out = p["e_g"], p["e_out"]
+    from_in, to_out = p["from_in"], p["to_out"]
+    d_qb_f = jnp.where(from_in, _items(d_qb).astype(f32) * e_g, 0.0) * scale
+    d_kt_f = jnp.where(to_out, _items(d_kt).astype(f32) * e_out, 0.0)
+    g_q = at_row[:, c:] + per_key(d_qb_f)
+    g_k = (
+        at_row[:, :c] + at_col
+        + per_key(jnp.where(from_in, d_kbar * e_g, 0.0) + d_kt_f)
+    )
+    kt_term = jnp.sum(d_kt_f * kf, axis=-1, keepdims=True)  # [m, C, 1]
+    g_big = (
+        jnp.sum(d_dd, axis=-1, keepdims=True)
+        - jnp.sum(
+            jnp.where(p["eye"], jnp.sum(d_dd, axis=1, keepdims=True), 0.0),
+            axis=-1, keepdims=True,
+        )
+        + jnp.sum(d_kbar * p["kbar"] + d_qb_f * qf, axis=-1, keepdims=True)
+        - kt_term
+    )
+    g_last = (
+        jnp.sum(kt_term, axis=1, keepdims=True)
+        + jnp.sum(_items(d_d), axis=-1, keepdims=True) * p["d"]
+    )  # [m, 1, 1]
+    tok = jax.lax.broadcasted_iota(jnp.int32, (c, 1), 0)
+    g_big = g_big + jnp.where(tok == c - 1, g_last, 0.0)
+    ri = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    ci = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    g_g = jnp.sum(
+        jnp.where(ri >= ci, g_big, 0.0), axis=1, keepdims=True
+    )  # [m, 1, C]: the running sum from the end, back on the lanes
+    valid, valid_m = p["valid"], p["valid_m"]
+    d_q[:, 0] = jnp.where(valid, g_q, 0.0).astype(d_q.dtype)
+    d_k[:, 0] = jnp.where(valid, g_k, 0.0).astype(d_k.dtype)
+    _store_heads(d_v, jnp.where(valid_m, beta_c * d_xv, 0.0))
+    _store_heads(d_g, jnp.where(p["valid_row"], g_g, 0.0))
+    _store_heads(d_beta, jnp.sum(
+        jnp.where(p["eye"] & valid_m, g_beta, 0.0), axis=1, keepdims=True
+    ))
+
+
+def _gdn_call(backward, key_arrays, head_arrays, marks, out_like, interpret):
+    """``gdn_ops_bwd`` | ``gdn_ops_fwd`` over (row x key head, block of
+    chunks). ``key_arrays`` [N, B, Hk, ...] a key head, ``head_arrays``
+    [N, B, H, ...] a value head (a grid step takes the ``H / Hk`` of its key
+    head together), ``marks`` [N, B, 4, C]; ``out_like``: (a key head's or a
+    value head's, trailing shape, dtype) of each output."""
+    n, b, hk, c, _ = key_arrays[0].shape
+    h = head_arrays[0].shape[2]
+    r = h // hk
+    nb = _kernel_chunks(n, _OPS_CHUNKS)
+
+    def spec(heads, shape):
+        return pl.BlockSpec(
+            (nb, heads) + tuple(shape), lambda i, j: (j, i, 0, 0)
+        )
+
+    out_shape = [
+        jax.ShapeDtypeStruct((n, b * (hk if key else h)) + tuple(shape), dtype)
+        for key, shape, dtype in out_like
+    ]
+    out = pl.pallas_call(
+        _gdn_bwd_kernel if backward else _gdn_fwd_kernel,
+        grid=(b * hk, n // nb),
+        in_specs=[spec(1, x.shape[3:]) for x in key_arrays]
+        + [spec(r, x.shape[3:]) for x in head_arrays]
+        + [pl.BlockSpec(
+            (nb, 1) + marks.shape[2:], lambda i, j: (j, i // hk, 0, 0)
+        )],
+        out_specs=[
+            spec(1 if key else r, shape) for key, shape, _ in out_like
+        ],
+        out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((nb * r, c, c), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")
+        ),
+        interpret=interpret,
+        name="gdn_ops_bwd" if backward else "gdn_ops_fwd",
+    )(*[_flat(x) for x in key_arrays + head_arrays], marks)
+    return tuple(
+        x.reshape(n, b, hk if key else h, *x.shape[2:])
+        for x, (key, _, _) in zip(out, out_like)
+    )
+
+
+@functools.partial(jax.jit, static_argnames="interpret")
+def _gdn_operands_kernel(q, k, v, g, beta, seg, interpret=False):
+    """``_prep_scalar`` as the kernel ``gdn_ops_fwd``."""
+    c, dk, dv = q.shape[-2], q.shape[-1], v.shape[-1]
+    dt = q.dtype
+    return _gdn_call(
+        False, (q, k), (v, g[..., None, :], beta[..., None, :]), _marks(seg),
+        [(False, (c, dk), dt), (False, (c, dv), dt), (False, (c, dk), dt),
+         (False, (c, dk), dt), (False, (c, c), dt),
+         (False, (1, dk), jnp.float32)],
+        interpret,
+    )
+
+
+@functools.partial(jax.jit, static_argnames="interpret")
+def _gdn_operands_bwd_kernel(q, k, v, g, beta, seg, d_ops, interpret=False):
+    """``jax.vjp(_prep_scalar)`` as the kernel ``gdn_ops_bwd``: ``dq, dk``
+    a key head, the sum over its value heads made in VMEM."""
+    c, dk, dv = q.shape[-2], q.shape[-1], v.shape[-1]
+    f32 = jnp.float32
+    d_q, d_k, d_v, d_g, d_beta = _gdn_call(
+        True, (q, k),
+        (v, g[..., None, :], beta[..., None, :]) + tuple(d_ops), _marks(seg),
+        [(True, (c, dk), q.dtype), (True, (c, dk), k.dtype),
+         (False, (c, dv), v.dtype), (False, (1, c), f32),
+         (False, (1, c), f32)],
+        interpret,
+    )
+    return d_q, d_k, d_v, d_g[..., 0, :], d_beta[..., 0, :]
+
+
+def _scalar_operands(q, k, v, g, beta, seg, impl, interpret):
+    if impl == "kernel":
+        return _gdn_operands_kernel(q, k, v, g, beta, seg, interpret=interpret)
+    return _in_blocks(_prep_scalar, (q, k, v, g, beta, seg, _seg_in(seg)))
+
+
+def _scalar_operands_bwd(q, k, v, g, beta, seg, d_ops, impl, interpret):
+    if impl == "kernel":
+        return _gdn_operands_bwd_kernel(
+            q, k, v, g, beta, seg, d_ops, interpret=interpret
+        )
+    return _operands_bwd_xla(q, k, v, g, beta, seg, d_ops, prep=_prep_scalar)

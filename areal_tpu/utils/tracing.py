@@ -158,10 +158,11 @@ DEVICE_SCOPES = (
     # passes; and what the layer computes around it (the short convolution,
     # SiLU, l2norm, decay, beta, the gated output norm)
     "kda_scan", "kda_prep",
-    # inside ``kda_scan`` where the decay is one number a head and token
-    # (ops/kda._prep_scalar): the chunk operands as plain products, K K^T,
-    # Q K^T, the [64, 64] decay matrix, the triangular inverse, and their
-    # backward: what a kernel there would buy
+    # inside ``kda_scan`` where the decay is one number a head and token:
+    # the chunk operands (K K^T, Q K^T, the [64, 64] decay matrix, the
+    # triangular inverse) and their backward, as the kernels ``gdn_ops_fwd``
+    # / ``gdn_ops_bwd`` on a TPU (since PR 53; their custom calls keep the
+    # scope) and as XLA's products elsewhere (ops/kda._prep_scalar)
     "gdn_operands",
     # the core of a window-attention layer (models/transformer.
     # _rotary_mixer): the splash kernel over the window's band and the

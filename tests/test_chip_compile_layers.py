@@ -206,3 +206,75 @@ def test_kimi_grad_step_lowers_each_operand_kernel_a_bounded_number_of_times(
     assert calls["chunk_ops_bwd"] == 1, calls
     for name in ("kda_fwd_h", "kda_fwd_h_states", "kda_bwd_dh"):
         assert 1 <= calls[name] <= 2, calls
+
+
+def test_gdn_scan_fwd_bwd_compiles_for_v5e(one_chip):
+    """The scalar-decay op at Qwen3-Next's published widths (16 key and 32
+    value heads of 128 | 128) on the cell's 16,384-token row, forward and
+    backward: its operands and their cotangents as ``gdn_ops_fwd`` /
+    ``gdn_ops_bwd`` (8 chunks of a key head's two value heads a grid step,
+    sixteen items taken together, the [64, 64] decay matrices and the
+    inverse in VMEM), the per-channel form's operand kernels nowhere, and
+    the three state-pass kernels the two forms share."""
+    import jax
+    import jax.numpy as jnp
+
+    from areal_tpu.ops import kda
+
+    bf, f32, t = jnp.bfloat16, jnp.float32, 16384
+
+    def loss(q, k, v, g, beta, seg):
+        out, stats = kda.kda_scan(q, k, v, g, beta, seg, impl="kernel")
+        return out.astype(f32).sum(), stats
+
+    compiled = (
+        jax.jit(jax.value_and_grad(loss, argnums=range(5), has_aux=True))
+        .lower(
+            *[_sds((1, t, 16, 128), bf, one_chip)] * 2,
+            _sds((1, t, 32, 128), bf, one_chip),
+            *[_sds((1, t, 32), f32, one_chip)] * 2,
+            _sds((1, t), jnp.int32, one_chip),
+        )
+        .compile()
+    )
+    text = compiled.as_text()
+    for name in ("kda_fwd_h", "kda_fwd_h_states", "kda_bwd_dh",
+                 "gdn_ops_fwd", "gdn_ops_bwd"):
+        assert re.search(rf"{name}[^_a-z]", text), name
+    assert "chunk_ops" not in text
+    # no decay matrix, mask or inverse a chunk and head goes through HBM
+    assert not re.search(r"f32\[[0-9,]*64,64\]", text)
+
+
+def test_gdn_grad_step_lowers_each_operand_kernel_a_bounded_number_of_times(
+    one_chip, monkeypatch
+):
+    """The twin of the ``chunk_ops_*`` count for the scalar-decay kernels:
+    the tiny ``qwen3_next`` stack with a fifth layer behind its full
+    attention (a scanned run of three ``gdn`` layers, the full layer, a run
+    of one; checkpointed) holds ``gdn_ops_fwd`` at most three times and
+    ``gdn_ops_bwd`` once, however many layers there are, and no per-channel
+    operand kernel."""
+    import dataclasses
+
+    from areal_tpu.api.cli_args import ParallelismConfig
+    from areal_tpu.models.config import tiny_config
+    from areal_tpu.ops import kda
+    from areal_tpu.parallel import mesh as mesh_lib
+    from tools import setup_cost
+
+    monkeypatch.setattr(kda, "scan_impl", lambda *a: "kernel")
+    mc = tiny_config("qwen3_next")
+    mc = dataclasses.replace(
+        mc, num_layers=5, layer_kinds=mc.layer_kinds + ("gdn+moe",)
+    )
+    mesh = mesh_lib.make_mesh(ParallelismConfig(), list(one_chip.device_set))
+    grad_step, args = setup_cost.trainer_programs(mc, mesh, 256, None)[
+        "grad_step"
+    ]
+    calls = setup_cost.custom_calls(grad_step.trace(*args).lower().as_text())
+    assert 1 <= calls["gdn_ops_fwd"] <= 3, calls
+    assert calls["gdn_ops_bwd"] == 1, calls
+    assert not any(name.startswith("chunk_ops") for name in calls), calls
+    for name in ("kda_fwd_h", "kda_fwd_h_states", "kda_bwd_dh"):
+        assert 1 <= calls[name] <= 2, calls

@@ -5,7 +5,9 @@ kernels in Pallas interpret mode against the ``lax.scan`` they are held
 equal to, segment borders inside a chunk, decays near 0 and near 1; and the
 operand kernels (``chunk_ops_fwd`` / ``chunk_ops_bwd``, interpret mode, at
 the lane-tile widths they run at on the chip) against ``_prep`` and XLA's
-differentiation of it. Every comparison runs as ONE jitted program a shape:
+differentiation of it, and the scalar-decay form's (``gdn_ops_fwd`` /
+``gdn_ops_bwd``, with as many key heads as value heads and with fewer) against
+``_prep_scalar`` and its. Every comparison runs as ONE jitted program a shape:
 op by op the CPU compiles each primitive apart."""
 
 import functools
@@ -40,8 +42,30 @@ def _inputs(t, seg_lens=None, h=2, dk=16, dv=8, decay=1.0, seed=0):
     return [jnp.asarray(x) for x in (q, k, v, g, beta, seg)]
 
 
+# ``hk`` of a form: None, the per-channel rule as ``_inputs`` draws it; else
+# the scalar-decay one (``g`` one number a head and token) with ``q, k`` of
+# ``hk`` heads, each serving ``h / hk`` value heads
+FORMS = [
+    pytest.param(None, id="per-channel"),
+    pytest.param(2, id="scalar-decay"),
+    pytest.param(1, id="scalar-decay-shared-keys"),
+]
+
+
+def _form(a, hk):
+    if hk is None:
+        return list(a)
+    q, k, v, g, beta, seg = a
+    return [q[:, :, :hk], k[:, :, :hk], v, g[..., 0], beta, seg]
+
+
 def _rule(q, k, v, g, beta, seg):
-    """The reference's rule, one sequence of the row after the other."""
+    """The reference's rule, one sequence of the row after the other (a
+    scalar-decay row as the per-channel one it is equal to)."""
+    if kda._scalar_decay(g, beta):
+        r = v.shape[2] // q.shape[2]
+        q, k = (jnp.repeat(x, r, axis=2) for x in (q, k))
+        g = jnp.broadcast_to(g[..., None], q.shape)
     seg = np.asarray(seg[0])
     out = np.zeros(q.shape[1:3] + v.shape[-1:], np.float32)
     for s in np.unique(seg[seg > 0]):
@@ -129,13 +153,14 @@ def _rule_traced(q, k, v, g, beta, seg):
     return jax.lax.scan(step, (s0, jnp.int32(-1)), xs)[1][None]
 
 
+@pytest.mark.parametrize("hk", FORMS)
 @pytest.mark.parametrize("decay", [1e-4, 20.0, 80.0])
-def test_decays_near_one_and_near_zero_neither_overflow_nor_clamp(decay):
+def test_decays_near_one_and_near_zero_neither_overflow_nor_clamp(decay, hk):
     """exp(g) from 0.9999 down to exp(-80 |n|), which underflows to an
     exact zero: every exponent the chunked form takes is a difference that
     is <= 0, so nothing overflows, and nothing is clamped: the result is
     the rule's."""
-    a = _inputs(130, decay=decay)
+    a = _form(_inputs(130, decay=decay), hk)
     for impl in ("xla", "kernel"):
         got, _ = _scan(impl)(*a)
         assert np.isfinite(np.asarray(got)).all()
@@ -222,10 +247,10 @@ OPERAND_CASES = [
 ]
 
 
-def _chunked_inputs(t, seg_lens, decay):
+def _chunked_inputs(t, seg_lens, decay, hk=None):
     """The row as ``kda_scan`` hands it to the op."""
     return list(kda._chunk_inputs(
-        *_inputs(t, seg_lens, dk=128, dv=128, decay=decay)
+        *_form(_inputs(t, seg_lens, dk=128, dv=128, decay=decay), hk)
     ))
 
 
@@ -248,35 +273,48 @@ def _close(got, want, tol, decay=1.0):
         assert float(jnp.abs(x - w).max()) / scale < tol
 
 
+def _xla_prep(a):
+    """The form's XLA operands: ``_prep_scalar`` where ``g`` has one number
+    a head and token."""
+    return kda._prep_scalar if kda._scalar_decay(a[3], a[4]) else kda._prep
+
+
 @jax.jit
 def _prep(*a):
-    return kda._prep(*a, kda._seg_in(a[5]))
+    return _xla_prep(a)(*a, kda._seg_in(a[5]))
 
 
 @jax.jit
 def _prep_vjp(a, cts):
-    return kda._operands_bwd_xla(*a, cts)
+    return kda._operands_bwd_xla(*a, cts, prep=_xla_prep(a))
 
 
+@pytest.mark.parametrize("hk", FORMS)
 @pytest.mark.parametrize("t,seg_lens,decay", OPERAND_CASES)
-def test_operand_kernel_equals_prep(t, seg_lens, decay):
-    a = _chunked_inputs(t, seg_lens, decay)
-    _close(kda._operands_kernel(*a, interpret=True), _prep(*a), 1e-5, decay)
+def test_operand_kernel_equals_prep(t, seg_lens, decay, hk):
+    """``chunk_ops_fwd`` | ``gdn_ops_fwd``, operand by operand."""
+    a = _chunked_inputs(t, seg_lens, decay, hk)
+    _close(kda._operands(*a, "kernel", True), _prep(*a), 1e-5, decay)
 
 
+@pytest.mark.parametrize("hk", FORMS)
 @pytest.mark.parametrize("t,seg_lens,decay", OPERAND_CASES)
-def test_operand_cotangent_kernel_equals_xlas_vjp_of_prep(t, seg_lens, decay):
-    """The six cotangents' pull-back to q, k, v, g, beta."""
-    a = _chunked_inputs(t, seg_lens, decay)
+def test_operand_cotangent_kernel_equals_xlas_vjp_of_prep(
+    t, seg_lens, decay, hk
+):
+    """The six cotangents' pull-back to q, k, v, g, beta: ``chunk_ops_bwd``
+    | ``gdn_ops_bwd`` (``dq, dk`` a key head, its value heads' summed)."""
+    a = _chunked_inputs(t, seg_lens, decay, hk)
     cts = _cotangents(a)
-    _close(
-        kda._operands_bwd_kernel(*a, cts, interpret=True), _prep_vjp(a, cts),
-        2e-5, decay,
-    )
+    if hk is None:
+        got = kda._operands_bwd_kernel(*a, cts, interpret=True)
+    else:
+        got = kda._gdn_operands_bwd_kernel(*a, cts, interpret=True)
+    _close(got, _prep_vjp(a, cts), 2e-5, decay)
 
 
 @functools.lru_cache(None)
-def _op_grads(t, kernels):
+def _op_grads(t, kernels, hk):  # ``hk``: a program a form (its shapes)
     kw = (
         dict(impl="kernel", interpret=True) if kernels
         else dict(impl="xla", custom_backward=False)
@@ -286,34 +324,53 @@ def _op_grads(t, kernels):
     ))
 
 
+@pytest.mark.parametrize("hk", FORMS)
 @pytest.mark.parametrize("t,seg_lens,decay", OPERAND_CASES)
 def test_whole_op_gradients_with_the_kernels_equal_the_plain_chunked_forms(
-    t, seg_lens, decay
+    t, seg_lens, decay, hk
 ):
     """``kda_scan`` with all five kernels, its backward by hand, against
     ``_chunked_plain`` with XLA's differentiation throughout."""
-    a = _inputs(t, seg_lens, dk=128, dv=128, decay=decay)
-    _close(_op_grads(t, True)(*a), _op_grads(t, False)(*a), 2e-5, decay)
+    a = _form(_inputs(t, seg_lens, dk=128, dv=128, decay=decay), hk)
+    want = _op_grads(t, False, hk)(*a)
+    _close(_op_grads(t, True, hk)(*a), want, 2e-5, decay)
 
 
-def test_kernel_wrappers_are_nested_beneath_the_program_that_traces_them():
+@pytest.mark.parametrize("hk,backward,wrappers", [
+    pytest.param(None, False, {"_operands_kernel", "_state_fwd_kernel"},
+                 id="per-channel"),
+    pytest.param(
+        1, True,
+        {"_gdn_operands_kernel", "_gdn_operands_bwd_kernel",
+         "_state_fwd_kernel", "_state_bwd_kernel"},
+        id="scalar-decay-and-its-backward",
+    ),
+])
+def test_kernel_wrappers_are_nested_beneath_the_program_that_traces_them(
+    hk, backward, wrappers
+):
     """Each kernel call sits behind a jitted function (PR 35), whose trace
     fires inside the trace of the program that calls it: the compile log
     lists it beneath that program and counts its seconds once."""
     from areal_tpu.utils import goodput
 
-    a = _inputs(130, h=3)  # a shape no other test of this file traces
+    # a shape no other test of this file traces
+    a = _form(_inputs(130, h=3), hk)
     tracker = goodput.CompileTracker()
     goodput.set_thread_tracker(tracker, phase="trainer")
     try:
         def scan_program(*x):
-            return kda.kda_scan(*x, impl="kernel", interpret=True)[0]
+            def run(*y):
+                return kda.kda_scan(*y, x[5], impl="kernel", interpret=True)[0]
 
-        jax.jit(scan_program)(*a).block_until_ready()
+            if not backward:
+                return run(*x[:5])
+            return jax.grad(lambda *y: run(*y).sum(), argnums=range(5))(*x[:5])
+
+        jax.block_until_ready(jax.jit(scan_program)(*a))
     finally:
         goodput.set_thread_tracker(None)
     table = {r["fun_name"]: r for r in tracker.program_table()}
-    wrappers = {"_operands_kernel", "_state_fwd_kernel"}
     assert not wrappers & set(table)  # no rows of their own
     nested = {n["fun_name"]: n for n in table["scan_program"]["nested"]
               if n["kind"] == "trace"}

@@ -9,6 +9,14 @@ pass (``kda_fwd_h`` | the ``lax.scan``); and the forms' agreement on the
 chip.
 
     python -m tools.microbench_kda [--tokens 8192] [--seqs 3]
+    python -m tools.microbench_kda --tokens 16384 --key-heads 16
+
+``--key-heads n`` times the SCALAR-DECAY form (Qwen3-Next's ``gdn`` mixer at
+16 key and 32 value heads: ``g`` of ``beta``'s rank, ``q, k`` of ``n`` heads):
+the same passes, the operands as ``gdn_ops_fwd`` | ``_prep_scalar`` and their
+cotangents as ``gdn_ops_bwd`` | XLA's differentiation of ``_prep_scalar``;
+``--ops-chunks 4 8 16`` sweeps the chunks a grid step of the operand kernels
+takes together (``ops/kda._OPS_CHUNKS``).
 
 Host clock around ``block_until_ready``, the best of ``RUNS``. ``ops/kda.py``
 resolves ``scan_impl`` to what this found faster (PERF.md, PR 33, PR 35).
@@ -54,18 +62,25 @@ def main():
     ap.add_argument("--seqs", type=int, default=3)
     ap.add_argument("--heads", type=int, default=32)
     ap.add_argument("--dim", type=int, default=128)
+    ap.add_argument("--key-heads", type=int, default=0,
+                    help="the scalar-decay form: q, k of this many heads")
+    ap.add_argument("--ops-chunks", type=int, nargs="*", default=[],
+                    help="also time the operand kernels at these block sizes")
     args = ap.parse_args()
     t, h, d = args.tokens, args.heads, args.dim
+    scalar, hk = bool(args.key_heads), args.key_heads or args.heads
     print(json.dumps({"device": str(jax.devices()[0].device_kind)}))
     ks = jax.random.split(jax.random.PRNGKey(0), 5)
 
     def unit(key):
-        x = jax.random.normal(key, (1, t, h, d), jnp.float32)
+        x = jax.random.normal(key, (1, t, hk, d), jnp.float32)
         return x * jax.lax.rsqrt((x * x).sum(-1, keepdims=True))
 
     q, k = unit(ks[0]).astype(jnp.bfloat16), unit(ks[1]).astype(jnp.bfloat16)
     v = (jax.random.normal(ks[2], (1, t, h, d)) * 0.1).astype(jnp.bfloat16)
-    g = -jnp.exp(jax.random.uniform(ks[3], (1, t, h, d), minval=-7, maxval=0.5))
+    g = -jnp.exp(jax.random.uniform(
+        ks[3], (1, t, h) if scalar else (1, t, h, d), minval=-7, maxval=0.5
+    ))
     beta = jax.nn.sigmoid(jax.random.normal(ks[4], (1, t, h)))
     # a few sequences and a padded tail, borders inside chunks
     real = t - 200
@@ -120,15 +135,21 @@ def main():
         lambda x: (jax.random.normal(ks[0], x.shape) * 0.1).astype(x.dtype),
         ops["xla"],
     )
-    bwd = {
-        "kernel": lambda *x: kda._operands_bwd_kernel(
-            *x[:6], x[6:], interpret=interp
-        ),
-        "xla": lambda *x: kda._operands_bwd_xla(*x[:6], x[6:]),
-    }
+
+    def cotangents(impl):
+        if scalar:
+            return lambda *x: kda._scalar_operands_bwd(
+                *x[:6], x[6:], impl, interp
+            )
+        if impl == "kernel":
+            return lambda *x: kda._operands_bwd_kernel(
+                *x[:6], x[6:], interpret=interp
+            )
+        return lambda *x: kda._operands_bwd_xla(*x[:6], x[6:])
+
     d_ins = {}
-    for impl, fn in bwd.items():
-        jit = jax.jit(fn)
+    for impl in ("kernel", "xla"):
+        jit = jax.jit(cotangents(impl))
         d_ins[impl], ms = _best_ms(lambda: jit(*ins, *cts))
         print(json.dumps({"pass": "operands cotangents", "impl": impl, "ms": ms}))
     print(json.dumps({
@@ -144,6 +165,17 @@ def main():
         jit = jax.jit(fn)
         _, ms = _best_ms(lambda: jit(ops["xla"]))
         print(json.dumps({"pass": "state pass forward", "impl": impl, "ms": ms}))
+    for nb in args.ops_chunks:  # read while a kernel's wrapper is traced
+        kda._OPS_CHUNKS = nb  # the tool's last pass: the process ends with it
+        jax.clear_caches()
+        fwd = jax.jit(lambda *x: kda._operands(*x, "kernel", interp))
+        _, ms = _best_ms(lambda: fwd(*ins))
+        print(json.dumps({"pass": "operands forward", "impl": "kernel",
+                          "ops_chunks": nb, "ms": ms}))
+        back = jax.jit(cotangents("kernel"))
+        _, ms = _best_ms(lambda: back(*ins, *cts))
+        print(json.dumps({"pass": "operands cotangents", "impl": "kernel",
+                          "ops_chunks": nb, "ms": ms}))
 
 
 if __name__ == "__main__":
