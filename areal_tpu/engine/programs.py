@@ -25,7 +25,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from areal_tpu.models.config import ModelConfig
 from areal_tpu.models.forward import packed_forward
-from areal_tpu.models.transformer import param_logical_axes
+from areal_tpu.models.transformer import draft_forward, param_logical_axes
+from areal_tpu.ops import functional
 from areal_tpu.ops import kda as kda_ops
 from areal_tpu.ops import moe as moe_ops
 from areal_tpu.parallel import sharding as sharding_lib
@@ -38,7 +39,10 @@ def _forward_fn(
 ):
     """``forward(params, arrays)``: the model over one packed microbatch,
     giving the lazy ``ChunkedLogits`` view (a critic's value head: real
-    values), with the stack's ``LayerStats`` beside it when ``stats``."""
+    values); when ``stats`` (the gradient pass), ``(logits, the stack's
+    LayerStats, draft)``, ``draft`` what the model's draft module gives on
+    the same rows where it trains one (``transformer.draft_forward`` over
+    the stack's normed output, which the lazy view holds), else None."""
     mc = model_config
     moe = None
     # one layer's sparse leaves as the mesh's rules table shards them (the
@@ -65,11 +69,23 @@ def _forward_fn(
             cparams = jax.tree_util.tree_map(
                 lambda p: p.astype(compute_dtype), params
             )
-        return packed_forward(
+        out = packed_forward(
             cparams, mc, arrays, remat=remat, attend_fn=attend_fn,
             moe_fn=moe, kda_fn=kda, return_stats=stats, return_hidden=True,
             act_sharding=act_sh,
         )
+        if not stats:
+            return out
+        logits, layer_stats = out
+        draft = None
+        if mc.trains_draft:
+            draft = draft_forward(
+                cparams, mc, logits.hidden, arrays["tokens"],
+                arrays["segment_ids"], arrays["positions"], remat=remat,
+                attend_fn=attend_fn, moe_fn=moe, kda_fn=kda,
+                act_sharding=act_sh,
+            )
+        return logits, layer_stats, draft
 
     return forward
 
@@ -81,14 +97,16 @@ def build_grad_step(
 ):
     """``grad_step(params, grad_accum, arrays) -> (grad_accum', loss, stats,
     weight)``: one microbatch's weighted loss gradient added to the float32
-    accumulator, which is donated."""
+    accumulator, which is donated. A model that trains its draft module
+    (``ModelConfig.trains_draft``) runs it here and only here, and the loss
+    gains ``mtp_loss_coef`` times the module's own."""
     mc = model_config
     forward = _forward_fn(
         mc, mesh, compute_dtype, attend_fn, remat, stats=True
     )
 
     def fwd_loss(params, arrays):
-        logits, layer_stats = forward(params, arrays)
+        logits, layer_stats, draft = forward(params, arrays)
         with tracing.device_scope("loss"):
             loss, stats = loss_fn(logits, arrays)
             # what the model's mechanisms count (``transformer.MECHANISMS``):
@@ -100,6 +118,18 @@ def build_grad_step(
                         mc.router_aux_loss_coef * layer_stats.aux_loss
                     )
                 stats["router_aux_loss"] = layer_stats.aux_loss
+            if draft is not None:
+                draft_logits, counts = draft
+                mtp_loss, stats["mtp_tokens"] = functional.draft_loss(
+                    draft_logits, arrays
+                )
+                loss = loss + mc.mtp_loss_coef * mtp_loss
+                stats["mtp_loss"] = mtp_loss
+                # the module's block is one more sparse layer of the step
+                if "moe_assignments" in counts:
+                    stats["mtp_assignments"] = counts["moe_assignments"]
+                for k, v in counts.items():
+                    stats[k] = stats[k] + v
             w = loss_weight_fn(arrays).astype(jnp.float32)
             return loss * w, (loss, stats, w)
 

@@ -11,14 +11,18 @@ than one kind names them: ``layer_kinds`` holds a block kind a layer
 attention whose decay is a number a channel, ``KDAConfig``), ``gdn`` (the
 same rule with ONE decay a head and token, more value heads than key heads
 and a gate as wide as its output, ``GDNConfig``), ``mla`` (latent attention,
-``MLAConfig``), the two rotary GQA ones, ``full`` and ``swa`` (all of a
+``MLAConfig``: its query whole or compressed, its shared key part plain or
+rotated), the two rotary GQA ones, ``full`` and ``swa`` (all of a
 sequence's earlier keys, or a window of them: a ``RotaryConfig`` each) and
 ``cca`` (attention inside a compressed latent that two causal convolutions
 mix, ``CCAConfig``); the
 FFNs ``dense`` and ``moe``, the latter routed by ``h @ w_router`` or, where
 the model has a ``RouterConfig``, by a small MLP whose input passes from
 layer to layer), and the block kinds, not further per-family scalars, are
-the unit ``models/transformer.py`` builds its stacks from.
+the unit ``models/transformer.py`` builds its stacks from. The ONE thing a
+model may carry beside its stack is a draft module (``mtp_layers``: the
+model's own multi-token-prediction block, trained beside the loss at
+``mtp_loss_coef``; ``models/transformer.draft_forward``).
 """
 
 import dataclasses
@@ -71,12 +75,19 @@ class MLAConfig:
     """The latent-attention mixer's sizes: keys and values come from one
     ``kv_lora_rank``-wide compression of the token, a head's query and key
     are ``qk_nope_head_dim`` wide plus ``qk_rope_head_dim`` that all heads'
-    keys share (not rotated here: NoPE), its value ``v_head_dim``."""
+    keys share, its value ``v_head_dim``. ``q_lora_rank`` > 0: the query
+    too passes a compression of that rank and a norm (0: ``h @ wq``).
+    ``rotated``: the shared key part and the last ``qk_rope_head_dim``
+    channels of every query head turn at the token's position, plain rope
+    at ``rope_theta`` (false: NoPE, the 64-wide part a plain shared key)."""
 
     kv_lora_rank: int
     qk_nope_head_dim: int
     qk_rope_head_dim: int
     v_head_dim: int
+    q_lora_rank: int = 0
+    rotated: bool = False
+    rope_theta: float = 10000.0
 
     @property
     def qk_head_dim(self) -> int:
@@ -216,6 +227,15 @@ class ModelConfig:
     # it is an MLP (None: ``h @ w_router``)
     cca: Optional[CCAConfig] = None
     router: Optional[RouterConfig] = None
+    # --- the draft module beside the stack (DeepSeek-V3's multi-token
+    # prediction at depth 1; ``models/transformer.draft_forward``): one block
+    # of the stack's LAST kind with its own weights over [norm(Emb(next
+    # token)) ; norm(the stack's normed output)], the trunk's embedding and
+    # head. 0 = the model has none. ``grad_step`` trains it beside the loss,
+    # ``loss + mtp_loss_coef * L_mtp``, where the coefficient is > 0; the
+    # trunk, the embedding and the head enter it under ``stop_gradient`` ---
+    mtp_layers: int = 0
+    mtp_loss_coef: float = 0.0
 
     @property
     def q_dim(self) -> int:
@@ -241,6 +261,11 @@ class ModelConfig:
     @property
     def has_indexer(self) -> bool:
         return self.dsa_topk > 0
+
+    @property
+    def trains_draft(self) -> bool:
+        """Whether ``grad_step`` runs the draft module and adds its loss."""
+        return self.mtp_layers > 0 and self.mtp_loss_coef > 0
 
     @property
     def attn_head_dims(self) -> Tuple[int, int]:
@@ -280,7 +305,8 @@ class ModelConfig:
 # attention reads only the ``sa_config.topk`` keys a learned indexer picks
 # (ops/sparse_attention.py); its vision tower is not built.
 # kimi_linear is a stack of block kinds (``_kimi_linear_from_hf``): gated
-# delta-rule layers and NoPE latent-attention layers, a leading dense FFN
+# delta-rule layers and latent-attention layers (NoPE as published; a rotated
+# key part and a compressed query read to ``MLAConfig``), a leading dense FFN
 # and sigmoid-routed experts with an ungated shared one after it.
 # laguna is a stack of block kinds too (``_laguna_from_hf``): rotary GQA
 # layers of two kinds, ``full`` (every earlier key; yarn over the first half
@@ -318,12 +344,24 @@ class ModelConfig:
 # recurrent state beside KV pages), ``hf_io`` (no checkpoint layout of a
 # share; the published names wait for ROADMAP B.15) and sequence parallelism
 # (the state would pass from device to device) refuse it by name.
+# joyai_llm_flash is the DeepSeek-V3 key set (``_deepseek_v3_from_hf``): ONE
+# mixer, latent attention whose query passes a compression of its own and
+# whose shared key part and query heads' last 64 channels are ROTATED, on
+# every layer; a leading dense FFN, then 256 sigmoid-routed experts of which
+# 8 a token, chosen with a correction bias, and an ungated shared one; and the
+# model's own draft module (multi-token prediction at depth 1) beside the
+# stack, which ``grad_step`` trains beside the loss and ``logp_forward`` does
+# not run. The trainer runs it; the generation engine (no latent paged cache,
+# no absorbed score, no drafting with the module: ROADMAP B.5, B.7), ``hf_io``
+# (the published rope columns are interleaved, a loader's permutation: ROADMAP
+# B.15) and sequence parallelism (the module's one-token shift across a
+# ring's borders is not built) refuse it by name.
 # gemma2/gpt2 remain out (gemma2: soft-capped scores and post-norms beside
 # its windows; gpt2: learned positions, a new embedding).
 _HF_FAMILIES = (
     "llama", "qwen2", "qwen3", "mistral", "qwen3_moe", "mixtral",
     "qwen2_vl", "qwen2_moe", "gemma", "olmoe", "KeyeVL2", "kimi_linear",
-    "laguna", "zaya", "mellum", "qwen3_next",
+    "laguna", "zaya", "mellum", "qwen3_next", "joyai_llm_flash",
 )
 
 
@@ -374,9 +412,13 @@ def _kimi_linear_from_hf(d: dict) -> ModelConfig:
             f"layer 1..{n_layers} once (got {sorted(kda_at)}, "
             f"{sorted(full_at)})"
         )
+    rotated = not d.get("mla_use_nope")
     refused = {
-        "mla_use_nope false (a rotated key part)": not d.get("mla_use_nope"),
-        "q_lora_rank (a compressed query)": d.get("q_lora_rank"),
+        # a rotated key part and a compressed query are ``MLAConfig``'s
+        # ``rotated`` and ``q_lora_rank`` (below); yarn on them is not built
+        "rope_scaling on a rotated latent mixer": (
+            rotated and d.get("rope_scaling")
+        ),
         "num_expert_group > 1 (grouped top-k)": (
             d.get("num_expert_group", 1) != 1 or d.get("topk_group", 1) != 1
         ),
@@ -432,6 +474,9 @@ def _kimi_linear_from_hf(d: dict) -> ModelConfig:
             qk_nope_head_dim=d["qk_nope_head_dim"],
             qk_rope_head_dim=d["qk_rope_head_dim"],
             v_head_dim=d["v_head_dim"],
+            q_lora_rank=d.get("q_lora_rank") or 0,
+            rotated=rotated,
+            rope_theta=float(d.get("rope_theta", 10000.0)),
         ),
     )
 
@@ -754,6 +799,101 @@ def _qwen3_next_from_hf(d: dict) -> ModelConfig:
     )
 
 
+def _deepseek_v3_from_hf(d: dict) -> ModelConfig:
+    """The DeepSeek-V3 key set (``model_type`` joyai_llm_flash): every
+    layer's mixer is latent attention whose query passes ``q_lora_rank``
+    (null: whole) and whose ``qk_rope_head_dim``-wide parts turn at
+    ``rope_theta``; the first ``first_k_dense_replace`` layers have a dense
+    FFN of ``intermediate_size``, the others ``n_routed_experts``
+    sigmoid-routed experts (``num_local_experts`` of them held here, where
+    the file says so), ``num_experts_per_tok`` a token chosen with a
+    correction bias (``topk_method`` noaux_tc), and ``n_shared_experts``
+    shared ones of the same width; ``num_nextn_predict_layers`` 1: the draft
+    module beside the stack, trained at ``mtp_loss_coef`` (the file's, else
+    0.1: the DeepSeek-V3 report's later-phase weight; the published key set
+    has none). ``rope_interleave`` names the published PAIRING of the
+    rotated channels; the program turns them rotate-half, the same function
+    of freshly drawn weights under a permutation of the rope columns of
+    ``wq_b`` and ``w_kv_a`` that a checkpoint loader owes (ROADMAP B.15)."""
+    family = d["model_type"]
+    heads = d["num_attention_heads"]
+    refused = {
+        "rope_scaling (yarn on a latent mixer)": d.get("rope_scaling"),
+        "n_group > 1 (a group-limited top-k)": (
+            d.get("n_group", 1) != 1 or d.get("topk_group", 1) != 1
+        ),
+        "moe_layer_freq != 1": d.get("moe_layer_freq", 1) != 1,
+        f"scoring_func {d.get('scoring_func')!r} (only 'sigmoid')": (
+            d.get("scoring_func", "sigmoid") != "sigmoid"
+        ),
+        f"topk_method {d.get('topk_method')!r} (only 'noaux_tc')": (
+            d.get("topk_method", "noaux_tc") != "noaux_tc"
+        ),
+        "attention_bias": d.get("attention_bias", False),
+        f"hidden_act {d.get('hidden_act')!r}": (
+            d.get("hidden_act", "silu") != "silu"
+        ),
+        "key heads other than the query heads (the latent mixer has one "
+        "key a head)": d.get("num_key_value_heads", heads) != heads,
+        f"num_nextn_predict_layers {d.get('num_nextn_predict_layers')} "
+        "(a draft module of depth 1 is built, no chain of them)": (
+            d.get("num_nextn_predict_layers", 0) > 1
+        ),
+    }
+    for what, present in refused.items():
+        if present:
+            raise ValueError(f"{family} with {what} is not supported")
+    n_layers = d["num_hidden_layers"]
+    dense_first = d.get("first_k_dense_replace", 0)
+    num_experts = d["n_routed_experts"]
+    held = d.get("num_local_experts", num_experts)
+    mtp_layers = d.get("num_nextn_predict_layers", 0)
+    return ModelConfig(
+        vocab_size=d["vocab_size"],
+        hidden_size=d["hidden_size"],
+        intermediate_size=d["intermediate_size"],
+        num_layers=n_layers,
+        num_heads=heads,
+        num_kv_heads=heads,
+        # the file's own ``head_dim`` (64) is the rotated part's width
+        head_dim=d["qk_nope_head_dim"] + d["qk_rope_head_dim"],
+        max_position_embeddings=d.get("max_position_embeddings", 32768),
+        rope_theta=float(d.get("rope_theta", 10000.0)),
+        rms_norm_eps=d.get("rms_norm_eps", 1e-6),
+        tie_word_embeddings=d.get("tie_word_embeddings", False),
+        family=family,
+        num_experts=num_experts,
+        num_experts_per_tok=d["num_experts_per_tok"],
+        moe_intermediate_size=d["moe_intermediate_size"],
+        norm_topk_prob=d.get("norm_topk_prob", True),
+        router_aux_loss_coef=d.get("router_aux_loss_coef", 0.0),
+        shared_expert_size=(
+            d.get("n_shared_experts", 0) * d["moe_intermediate_size"]
+        ),
+        shared_expert_gate=False,
+        router_act="sigmoid",
+        routed_scaling_factor=d.get("routed_scaling_factor", 1.0),
+        experts_held=0 if held == num_experts else held,
+        layer_kinds=tuple(
+            "mla+dense" if i < dense_first else "mla+moe"
+            for i in range(n_layers)
+        ),
+        mla=MLAConfig(
+            kv_lora_rank=d["kv_lora_rank"],
+            qk_nope_head_dim=d["qk_nope_head_dim"],
+            qk_rope_head_dim=d["qk_rope_head_dim"],
+            v_head_dim=d["v_head_dim"],
+            q_lora_rank=d.get("q_lora_rank") or 0,
+            rotated=True,
+            rope_theta=float(d.get("rope_theta", 10000.0)),
+        ),
+        mtp_layers=mtp_layers,
+        mtp_loss_coef=(
+            float(d.get("mtp_loss_coef", 0.1)) if mtp_layers else 0.0
+        ),
+    )
+
+
 def from_hf_config(d: dict) -> ModelConfig:
     """Build from a parsed HF config.json dict (families mirror the
     reference's from_hf registry: realhf/api/from_hf/)."""
@@ -770,6 +910,8 @@ def from_hf_config(d: dict) -> ModelConfig:
         return _mellum_from_hf(d)
     if model_type == "qwen3_next":
         return _qwen3_next_from_hf(d)
+    if model_type == "joyai_llm_flash":
+        return _deepseek_v3_from_hf(d)
     num_heads = d["num_attention_heads"]
     hidden = d["hidden_size"]
     head_dim = d.get("head_dim") or hidden // num_heads
@@ -902,6 +1044,27 @@ def tiny_vlm_config(vocab_size: int = 128) -> ModelConfig:
 def tiny_config(family: str = "qwen2", vocab_size: int = 128) -> ModelConfig:
     """Small config for tests."""
     moe = family in ("qwen3_moe", "mixtral", "qwen2_moe", "olmoe")
+    if family == "joyai_llm_flash":
+        # the leading dense layer and two sparse ones, every mixer latent
+        # attention of two heads (8 + 4 | 8) over a rank of 16, the query
+        # through a rank of 12, the 4-wide parts rotated; 4 experts held of
+        # 16 sigmoid-routed ones, two a token, and a shared one; the draft
+        # module beside the stack
+        return ModelConfig(
+            vocab_size=vocab_size, hidden_size=32, intermediate_size=64,
+            num_layers=3, num_heads=2, num_kv_heads=2, head_dim=12,
+            max_position_embeddings=512, rope_theta=32000000.0,
+            rms_norm_eps=1e-6, family=family, num_experts=16,
+            num_experts_per_tok=2, moe_intermediate_size=16,
+            norm_topk_prob=True, router_aux_loss_coef=0.0, experts_held=4,
+            shared_expert_size=16, shared_expert_gate=False,
+            router_act="sigmoid", routed_scaling_factor=2.5,
+            layer_kinds=("mla+dense", "mla+moe", "mla+moe"),
+            mla=MLAConfig(kv_lora_rank=16, qk_nope_head_dim=8,
+                          qk_rope_head_dim=4, v_head_dim=8, q_lora_rank=12,
+                          rotated=True, rope_theta=32000000.0),
+            mtp_layers=1, mtp_loss_coef=0.1,
+        )
     if family == "zaya":
         # three ``cca+moe`` layers: 4 query and 2 key heads of 16 on the
         # residual's 64 (the latent is as wide as the stream here; half of

@@ -160,6 +160,16 @@ def _kind_tensor_names(cfg: ModelConfig):
             "them side by side, and no weights are in the repository to "
             "hold a mapping to (ROADMAP B.15); initialise from scratch"
         )
+    if cfg.family == "joyai_llm_flash":
+        raise NotImplementedError(
+            "family 'joyai_llm_flash' has no checkpoint layout here yet: "
+            "the published rope columns of q_b_proj and kv_a_proj_with_mqa "
+            "are interleaved (rope_interleave) where this tree turns them "
+            "rotate-half, a permutation a loader owes, the draft module's "
+            "tensors (the layer after the last) have no place among a "
+            "stack's names, and no weights are in the repository to hold a "
+            "mapping to (ROADMAP B.15); initialise from scratch"
+        )
     if cfg.experts_held:
         raise NotImplementedError(
             f"family {cfg.family!r} holding {cfg.experts_held} of "
@@ -447,10 +457,12 @@ def _kimi_linear_config_dict(cfg: ModelConfig) -> dict:
             "num_heads": cfg.kda.num_heads, "head_dim": cfg.kda.head_dim,
             "short_conv_kernel_size": cfg.kda.conv_size,
         },
-        "kv_lora_rank": cfg.mla.kv_lora_rank, "q_lora_rank": None,
+        "kv_lora_rank": cfg.mla.kv_lora_rank,
+        "q_lora_rank": cfg.mla.q_lora_rank or None,
         "qk_nope_head_dim": cfg.mla.qk_nope_head_dim,
         "qk_rope_head_dim": cfg.mla.qk_rope_head_dim,
-        "v_head_dim": cfg.mla.v_head_dim, "mla_use_nope": True,
+        "v_head_dim": cfg.mla.v_head_dim,
+        "mla_use_nope": not cfg.mla.rotated,
         "first_k_dense_replace": sum(
             k.endswith("+dense") for k in cfg.layer_kinds
         ),
@@ -611,9 +623,58 @@ def _qwen3_next_config_dict(cfg: ModelConfig) -> dict:
     }
 
 
+def _deepseek_v3_config_dict(cfg: ModelConfig) -> dict:
+    """The keys ``models/config._deepseek_v3_from_hf`` reads."""
+    m = cfg.mla
+    return {
+        "model_type": cfg.family,
+        "vocab_size": cfg.vocab_size,
+        "hidden_size": cfg.hidden_size,
+        "intermediate_size": cfg.intermediate_size,
+        "num_hidden_layers": cfg.num_layers,
+        "num_attention_heads": cfg.num_heads,
+        "num_key_value_heads": cfg.num_kv_heads,
+        "max_position_embeddings": cfg.max_position_embeddings,
+        "rope_theta": m.rope_theta,
+        "rope_scaling": None,
+        "rope_interleave": True,
+        "rms_norm_eps": cfg.rms_norm_eps,
+        "tie_word_embeddings": cfg.tie_word_embeddings,
+        "hidden_act": "silu",
+        "attention_bias": False,
+        "torch_dtype": "float32",
+        "kv_lora_rank": m.kv_lora_rank,
+        "q_lora_rank": m.q_lora_rank or None,
+        "qk_nope_head_dim": m.qk_nope_head_dim,
+        "qk_rope_head_dim": m.qk_rope_head_dim,
+        "qk_head_dim": m.qk_head_dim,
+        "v_head_dim": m.v_head_dim,
+        "first_k_dense_replace": sum(
+            k.endswith("+dense") for k in cfg.layer_kinds
+        ),
+        "moe_layer_freq": 1,
+        "n_routed_experts": cfg.num_experts,
+        "num_local_experts": cfg.num_held_experts,
+        "num_experts_per_tok": cfg.num_experts_per_tok,
+        "moe_intermediate_size": cfg.moe_intermediate_size,
+        "n_shared_experts": cfg.shared_expert_size
+        // max(cfg.moe_intermediate_size, 1),
+        "norm_topk_prob": cfg.norm_topk_prob,
+        "scoring_func": cfg.router_act,
+        "topk_method": "noaux_tc",
+        "n_group": 1, "topk_group": 1,
+        "routed_scaling_factor": cfg.routed_scaling_factor,
+        "router_aux_loss_coef": cfg.router_aux_loss_coef,
+        "num_nextn_predict_layers": cfg.mtp_layers,
+        "mtp_loss_coef": cfg.mtp_loss_coef,
+    }
+
+
 def default_hf_config_dict(cfg: ModelConfig) -> dict:
     if cfg.family == "kimi_linear":
         return _kimi_linear_config_dict(cfg)
+    if cfg.family == "joyai_llm_flash":
+        return _deepseek_v3_config_dict(cfg)
     if cfg.family == "qwen3_next":
         return _qwen3_next_config_dict(cfg)
     if cfg.family in ("laguna", "mellum"):

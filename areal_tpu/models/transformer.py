@@ -25,6 +25,11 @@ from-scratch parallel causal LM — re-designed TPU-first:
   ``params["layers"]`` and the one scan. Where the sparse layers' router
   is an MLP (``RouterConfig``), its input passes from layer to layer and
   rides the scans beside ``x``, for that model only.
+- The one thing a model may carry beside its stack is a draft module
+  (``ModelConfig.mtp_layers``; ``params["mtp"]``, ``draft_forward``): one
+  block of the stack's last kind over the next token's embedding and the
+  stack's normed output, under the trunk's embedding and head. ``apply``
+  never runs it; ``engine/programs.build_grad_step`` does, beside the loss.
 """
 
 import functools
@@ -134,8 +139,18 @@ def _kind_leaves(cfg: ModelConfig, kind: str) -> Dict[str, tuple]:
         })
     elif mixer == "mla":
         m, H = cfg.mla, cfg.num_heads
+        if m.q_lora_rank:  # the query through a compression of its own
+            leaves.update({
+                "wq_a": ((D, m.q_lora_rank), "normal", ("embed", None)),
+                "q_a_norm": ((m.q_lora_rank,), "ones", (None,)),
+                "wq_b": ((m.q_lora_rank, H * m.qk_head_dim), "normal",
+                         (None, "heads")),
+            })
+        else:
+            leaves["wq"] = (
+                (D, H * m.qk_head_dim), "normal", ("embed", "heads")
+            )
         leaves.update({
-            "wq": ((D, H * m.qk_head_dim), "normal", ("embed", "heads")),
             "w_kv_a": (
                 (D, m.kv_lora_rank + m.qk_rope_head_dim), "normal",
                 ("embed", None),
@@ -252,23 +267,63 @@ def block_kinds(cfg: ModelConfig) -> tuple:
     return tuple(dict.fromkeys(cfg.layer_kinds))
 
 
+def _draw(leaves: Dict[str, tuple], lead: tuple, rng: jax.Array, dtype):
+    """``leaves`` (``_kind_leaves``' form) drawn, each under ``lead`` leading
+    axes: one key a leaf, in the table's order."""
+    keys = jax.random.split(rng, len(leaves))
+    group = {}
+    for key, (name, (shape, how, _)) in zip(keys, leaves.items()):
+        if isinstance(how, str) and how == "normal":
+            w = jax.random.normal(key, lead + shape, jnp.float32) * 0.02
+        elif isinstance(how, str):
+            w = (jnp.ones if how == "ones" else jnp.zeros)(lead + shape)
+        else:
+            w = jnp.broadcast_to(how, lead + shape)
+        group[name] = w.astype(dtype)
+    return group
+
+
 def _init_kinds(cfg: ModelConfig, rng: jax.Array, dtype) -> Params:
-    groups = {}
-    for g, kind in enumerate(block_kinds(cfg)):
-        n = cfg.kind_layers(kind)
-        leaves = _kind_leaves(cfg, kind)
-        keys = jax.random.split(jax.random.fold_in(rng, 200 + g), len(leaves))
-        group = {}
-        for key, (name, (shape, how, _)) in zip(keys, leaves.items()):
-            if isinstance(how, str) and how == "normal":
-                w = jax.random.normal(key, (n,) + shape, jnp.float32) * 0.02
-            elif isinstance(how, str):
-                w = (jnp.ones if how == "ones" else jnp.zeros)((n,) + shape)
-            else:
-                w = jnp.broadcast_to(how, (n,) + shape)
-            group[name] = w.astype(dtype)
-        groups[kind] = group
-    return groups
+    return {
+        kind: _draw(
+            _kind_leaves(cfg, kind), (cfg.kind_layers(kind),),
+            jax.random.fold_in(rng, 200 + g), dtype,
+        )
+        for g, kind in enumerate(block_kinds(cfg))
+    }
+
+
+# --------------------------------------------------------------------------
+# The draft module beside the stack (``ModelConfig.mtp_layers``)
+# --------------------------------------------------------------------------
+def draft_kind(cfg: ModelConfig) -> str:
+    """The block kind of the draft module's one block: the stack's last."""
+    return cfg.layer_kinds[-1]
+
+
+def _draft_leaves(cfg: ModelConfig) -> Dict[str, tuple]:
+    """What the module holds beside its block, ``_kind_leaves``' form: the
+    norms of its two inputs, the product that joins them, the norm under
+    the (trunk's) head."""
+    D = cfg.hidden_size
+    unit = "zeros" if cfg.norm_add_unit_offset else "ones"
+    return {
+        "norm_e": ((D,), unit, (None,)),
+        "norm_h": ((D,), unit, (None,)),
+        "w_eh": ((2 * D, D), "normal", (None, "embed")),
+        "head_norm": ((D,), unit, (None,)),
+    }
+
+
+def _init_draft(cfg: ModelConfig, rng: jax.Array, dtype) -> Params:
+    """``params["mtp"]``: ``_draft_leaves`` and, under ``"block"``, one layer
+    of ``draft_kind`` stacked as a kind's layers are (a leading axis of 1)."""
+    out = _draw(_draft_leaves(cfg), (), jax.random.fold_in(rng, 300), dtype)
+    out["block"] = _draw(
+        _kind_leaves(cfg, draft_kind(cfg)), (1,),
+        jax.random.fold_in(rng, 301), dtype,
+    )
+    return out
 
 
 # --------------------------------------------------------------------------
@@ -492,8 +547,40 @@ MECHANISMS: Dict[str, Mechanism] = {
             "gdn_decay": "scalar",
         },
     ),
-    "mla": Mechanism(no_generation=_no_kinds_stack),
+    "mla": Mechanism(
+        no_generation=_no_kinds_stack,
+        span_attrs=lambda cfg: {
+            "mla_query": "compressed" if cfg.mla.q_lora_rank else "full",
+            "mla_rope": cfg.mla.rotated and cfg.mla.qk_rope_head_dim,
+        },
+    ),
     "full": Mechanism(no_generation=_no_kinds_stack),
+    # the draft module beside the stack, where ``grad_step`` trains it: its
+    # counts come with its loss (``ops/functional.draft_loss``, ``draft_
+    # forward``'s stats), not from the stack's layers
+    "mtp": Mechanism(
+        counts={
+            "mtp_tokens": "the draft module's targets: the tokens two "
+            "slots on in a slot's own sequence that the loss mask holds",
+            "mtp_assignments": "the module's block's part of "
+            "moe_assignments",
+        },
+        no_seq_parallel=lambda cfg: (
+            f"the draft module (family {cfg.family!r}: the next token's "
+            "embedding beside every slot, a shift inside a packed row) has "
+            "no sequence-parallel form (attn_impl={attn_impl!r}, "
+            "seq_parallel_size={seq_parallel_size}): the shift across a "
+            "ring's borders is not built"
+        ),
+        no_generation=lambda cfg: (
+            "the generation engine cannot draft with the model's own module "
+            f"(family {cfg.family!r}): inference/spec.py drafts by n-gram "
+            "lookup and has no second head to run (ROADMAP B.7)"
+        ),
+        span_attrs=lambda cfg: {
+            "mtp_layers": cfg.mtp_layers, "mtp_loss_coef": cfg.mtp_loss_coef,
+        },
+    ),
 }
 
 
@@ -505,6 +592,8 @@ def _mechanisms(cfg: ModelConfig, split=False) -> Tuple[Mechanism, ...]:
         has |= {"moe"} if cfg.is_moe else set()
     if "moe" in has and (cfg.experts_held or split):
         has.add("moe_held")
+    if cfg.trains_draft:
+        has.add("mtp")
     return tuple(m for name, m in MECHANISMS.items() if name in has)
 
 
@@ -535,10 +624,13 @@ def generation_refusal(cfg: ModelConfig) -> str:
 
 def _rotated_width(cfg: ModelConfig) -> int:
     """The width of the heads the model rotates; 0: it rotates none (a stack
-    of kinds without a rotary or a ``cca`` mixer)."""
+    of kinds without a rotary or a ``cca`` mixer, its latent mixer NoPE). A
+    rotated latent mixer turns a slice of its head by itself, that wide."""
     rotates = not cfg.layer_kinds or cfg.cca is not None or any(
         getattr(cfg, mixer) is not None for mixer in _ROTARY_MIXERS
     )
+    if not rotates and cfg.mla is not None and cfg.mla.rotated:
+        return cfg.mla.qk_rope_head_dim
     return cfg.head_dim if rotates else 0
 
 
@@ -671,6 +763,8 @@ def init_params(
         params["vision"] = vision_lib.init_vision_params(
             cfg.vision, jax.random.fold_in(rng, 7), dtype=dtype
         )
+    if cfg.mtp_layers:
+        params["mtp"] = _init_draft(cfg, rng, dtype)
     return params
 
 
@@ -735,6 +829,14 @@ def param_logical_axes(cfg: ModelConfig, value_head: bool = False) -> Params:
         from areal_tpu.models import vision as vision_lib
 
         axes["vision"] = vision_lib.vision_logical_axes(cfg.vision)
+    if cfg.mtp_layers:
+        axes["mtp"] = {
+            name: leaf[2] for name, leaf in _draft_leaves(cfg).items()
+        }
+        axes["mtp"]["block"] = {
+            name: ("layer",) + leaf[2]
+            for name, leaf in _kind_leaves(cfg, draft_kind(cfg)).items()
+        }
     return axes
 
 
@@ -964,16 +1066,38 @@ def _gdn_mixer(cfg, h, lp, segment_ids, kda_fn):
         return y.reshape(b, t, Hv * dv) @ lp["wo"], stats
 
 
-def _mla_mixer(cfg, h, lp, segment_ids, attend_fn):
-    """Latent attention without positions on the normed input ``h``: the
-    keys' and values' one compression, a key part all heads share, and a
-    causal softmax core whose query/key width differs from its value's."""
+def _mla_mixer(cfg, h, lp, segment_ids, attend_fn, rotation=None):
+    """Latent attention on the normed input ``h``: the keys' and values' one
+    compression, a key part all heads share, and a causal softmax core whose
+    query/key width differs from its value's. The query is ``h @ wq`` or,
+    where ``MLAConfig.q_lora_rank`` says so, passes a compression and a norm
+    of its own. ``rotation``: (cos, sin) of the tokens' positions where the
+    mixer is ``rotated`` (the shared key part and the last
+    ``qk_rope_head_dim`` channels of every query head turn, rotate-half over
+    those channels); None: NoPE, the same program without the turn."""
     b, t, _ = h.shape
     m, H = cfg.mla, cfg.num_heads
     with device_scope("qkv_proj"):
-        q = (h @ lp["wq"]).reshape(b, t, H, m.qk_head_dim)
+        if m.q_lora_rank:
+            q = rms_norm(
+                h @ lp["wq_a"], lp["q_a_norm"], cfg.rms_norm_eps
+            ) @ lp["wq_b"]
+        else:
+            q = h @ lp["wq"]
+        q = q.reshape(b, t, H, m.qk_head_dim)
         c, k_shared = jnp.split(h @ lp["w_kv_a"], [m.kv_lora_rank], axis=-1)
         c = rms_norm(c, lp["kv_norm"], cfg.rms_norm_eps)
+    if rotation is not None:  # a slice of a head beside a pass-through
+        with device_scope("rope"):
+            q_nope, q_rope = jnp.split(q, [m.qk_nope_head_dim], axis=-1)
+            q_rope = rotate_leading(q_rope, *rotation)
+            k_shared = rotate_leading(
+                k_shared[:, :, None, :], *rotation
+            )[:, :, 0]
+    # from the normed latent to what the core takes: what a core that took
+    # the shared key ONCE would not write. The NoPE mixer's (Kimi's) stays
+    # under the name its cell's shares have read it by
+    with device_scope("mla_expand" if m.rotated else "qkv_proj"):
         k_nope, v = jnp.split(
             (c @ lp["w_kv_b"]).reshape(
                 b, t, H, m.qk_nope_head_dim + m.v_head_dim
@@ -986,6 +1110,8 @@ def _mla_mixer(cfg, h, lp, segment_ids, attend_fn):
                 k_shared[:, :, None, :], (b, t, H, m.qk_rope_head_dim)
             ),
         ], axis=-1)
+        if rotation is not None:
+            q = jnp.concatenate([q_nope, q_rope], axis=-1)
     with device_scope("attention"):
         if attend_fn is None:
             attn = segment_attention(q, k, v, segment_ids, causal=True)
@@ -1236,7 +1362,7 @@ def _block_body(
             attend_fn,
         )
     else:
-        y = _mla_mixer(cfg, h, lp, segment_ids, attend_fn)
+        y = _mla_mixer(cfg, h, lp, segment_ids, attend_fn, rotation)
     with device_scope("residual"):
         x = x + y
     return _ffn_residual(
@@ -1293,6 +1419,10 @@ def _rotations(cfg, positions):
     if cfg.cca is not None:
         out["cca"] = rope_at(positions, rope_inv_freq(
             cfg.cca.rotary_dim or cfg.head_dim, cfg.cca.rope_theta
+        ))
+    if cfg.mla is not None and cfg.mla.rotated:
+        out["mla"] = rope_at(positions, rope_inv_freq(
+            cfg.mla.qk_rope_head_dim, cfg.mla.rope_theta
         ))
     return out
 
@@ -1478,6 +1608,14 @@ def apply(
     return logits
 
 
+def _lm_head(params, cfg):
+    """(the output head's weight, which of its axes is the vocabulary): a
+    tied model's [V, D] table as it is stored, else ``lm_head`` [D, V]."""
+    if cfg.tie_word_embeddings:
+        return params["embedding"], 0
+    return params["lm_head"], 1
+
+
 def _head(params, cfg, x, return_hidden):
     """The final norm and the output head over the stack's output: logits
     (or their lazy view)."""
@@ -1490,10 +1628,8 @@ def _head(params, cfg, x, return_hidden):
         # critic: scalar head — "logits" [B, T, 1] (value per position);
         # tiny, never worth the lazy view
         head, vocab_axis = params["value_head"], 1
-    elif cfg.tie_word_embeddings:  # the [V, D] table as it is stored
-        head, vocab_axis = params["embedding"], 0
     else:
-        head, vocab_axis = params["lm_head"], 1
+        head, vocab_axis = _lm_head(params, cfg)
     if return_hidden and "value_head" not in params:
         from areal_tpu.ops.chunked_head import ChunkedLogits
 
@@ -1504,6 +1640,88 @@ def _head(params, cfg, x, return_hidden):
         with device_scope("lm_head"):
             logits = (x.astype(jnp.float32)) @ head.astype(jnp.float32)
     return logits
+
+
+def draft_forward(
+    params: Params,
+    cfg: ModelConfig,
+    hidden: jnp.ndarray,  # [B, T, D]: the stack's output after the final norm
+    tokens: jnp.ndarray,
+    segment_ids: jnp.ndarray,
+    positions: jnp.ndarray,
+    remat: bool = True,
+    attend_fn: Optional[Any] = None,
+    moe_fn: Optional[Any] = None,
+    kda_fn: Optional[Any] = None,
+    act_sharding: Optional[Any] = None,
+):
+    """The draft module beside the stack (``ModelConfig.mtp_layers``: the
+    DeepSeek-V3 report's multi-token prediction, section 2.2, at depth 1) on
+    the rows ``apply`` ran: (the lazy logits of slot i for token i + 2, what
+    its block's experts count on these rows, ``moe_*`` as the stack's).
+
+    Slot i of a sequence reads ``[norm_e(Emb(t_{i+1})) ; norm_h(hidden_i)]
+    @ w_eh``; a sequence's last slot has no next token INSIDE the row's
+    sequence and reads a zero embedding, never the next sequence's first
+    token. One block of the stack's last kind (``params["mtp"]["block"]``)
+    follows, at the same positions under the same mask, then ``head_norm``
+    and the TRUNK's head. ``hidden``, the embedding and the head enter
+    under ``stop_gradient``: the module follows the policy and hands the
+    trunk no gradient (``ops/functional.draft_loss`` has the targets)."""
+    from areal_tpu.ops.chunked_head import ChunkedLogits
+
+    mp, uo, eps = params["mtp"], cfg.norm_add_unit_offset, cfg.rms_norm_eps
+    frozen = jax.lax.stop_gradient
+    with device_scope("mtp"):
+        late = ((0, 0), (0, 1))
+        has_next = (
+            jnp.pad(segment_ids[:, 1:], late) == segment_ids
+        ) & (segment_ids > 0)
+        e = frozen(params["embedding"])[jnp.pad(tokens[:, 1:], late)]
+        if cfg.scale_embeddings:
+            e = e * jnp.asarray(cfg.hidden_size**0.5, e.dtype)
+        e = jnp.where(has_next[..., None], e, 0)
+        u = jnp.concatenate([
+            rms_norm(e, mp["norm_e"], eps, add_unit_offset=uo),
+            rms_norm(frozen(hidden), mp["norm_h"], eps, add_unit_offset=uo),
+        ], axis=-1) @ mp["w_eh"]
+    if act_sharding is not None:
+        u = jax.lax.with_sharding_constraint(u, act_sharding)
+    kind = draft_kind(cfg)
+    with device_scope("rope"):
+        rotation = _rotations(cfg, positions).get(kind.split("+")[0])
+
+    def body(x, lp):
+        return _block_body(
+            cfg, kind, x, lp, segment_ids, attend_fn, moe_fn, kda_fn,
+            rotation,
+        )
+
+    if remat:  # a run of one layer, as ``_apply_kinds`` checkpoints one
+        body = jax.checkpoint(
+            body, prevent_cse=True,
+            policy=jax.checkpoint_policies.save_only_these_names("attn_out"),
+        )
+    with device_scope("layer_stack"):
+        y, (moe_stats, _) = body(
+            u, jax.tree_util.tree_map(lambda a: a[0], mp["block"])
+        )
+    with device_scope("mtp"):
+        y = rms_norm(y, mp["head_norm"], eps, add_unit_offset=uo)
+    counts = {}
+    if moe_stats is not None:
+        reported = {
+            "moe": [jax.tree_util.tree_map(lambda a: a[None], moe_stats)]
+        }
+        held = moe_stats.stream_rows is not None
+        for name in ("moe", "moe_held")[:1 + held]:
+            m = MECHANISMS[name]
+            counts.update(zip(
+                m.counts, m.count(cfg, reported, segment_ids, positions),
+                strict=True,
+            ))
+    head, vocab_axis = _lm_head(params, cfg)
+    return ChunkedLogits(y, frozen(head), vocab_axis), counts
 
 
 def count_params(params: Params) -> int:

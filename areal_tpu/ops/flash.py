@@ -135,9 +135,9 @@ def takes_head_dim(d: int, dv: "int | None" = None) -> bool:
     """Head dimensions the trainer hands to the kernel: multiples of 64.
     64 (half a lane row) and 128 have trained on the v5e (Qwen2-0.5B and
     -1.5B, PERF.md PR 22 and PR 26), and a query/key width of 192 over a
-    value width ``dv`` of 128 (latent attention, PERF.md PR 33); the
-    installed Mosaic compiles others too, but none has ever run on a chip,
-    so they stay on the XLA form until one has."""
+    value width ``dv`` of 128 (latent attention: one layer at 8,192-token
+    rows since PR 33, six cores a step at 16,384 since PR 54); Mosaic
+    compiles others too, but none has run on a chip: they stay on XLA."""
     dv = d if dv is None else dv
     return d > 0 and d % 64 == 0 and (dv == d or (d, dv) == (192, 128))
 
@@ -224,11 +224,12 @@ def _block_sizes(
     16384  48/8 of 128                   61.85 (88.40) 58.93 (80.76)
     16384  8/2 of 128                    8.131 (14.49) 7.843 (13.14)
     16384  16/2 of 256     41.77 (61.64) 35.71 (53.43) 43.46 (59.38)
+    16384  32/32, 192|128                63.61 / 67.27 73.88 / 73.36
     ====== =============== ============= ============= =============
 
     - so edge 512 to T = 2048 (dense heads +8.6%, the 16 of 128 -0.7%), 1024
-      to T = 8192 (at 2048 the latent core's 192 | 128 paid 20%), 2048 above;
-      only the 16/2 of 256 (PR 52) want another, 1024, by 18% (ROADMAP A.21);
+      to T = 8192 (192 | 128 paid 20% at 2048), 2048 above: right for heads
+      of 128; wider ones want 1024 (A.21, A.22; "a / b": fused / SPLIT bwd);
     - a local window skips only whole tiles, so a PACKING window of at most
       1024 takes edge 512 (1.39 against 1.44 ms at T = 4096, PR 26); a
       LAYER's window (``band``: the caller says so, ``_local_mask``) that
@@ -250,8 +251,7 @@ def _block_sizes(
       one pass over the data, 10-25% under the split kernels everywhere
       (PR 26).
 
-    From 64 to 192 the head dimension moved none of these choices. Never above
-    what ``probe_block_size`` found to run, and always a divisor of ``t``."""
+    Never above what ``probe_block_size`` found, always a divisor of ``t``."""
     if band:
         want, fused = BAND_EDGE, False
     else:

@@ -86,6 +86,39 @@ def target_aligned_logprobs(
     return jnp.where(prev_same, out, 0.0)
 
 
+def _two_on(a: jnp.ndarray) -> jnp.ndarray:
+    """``a`` [B, T] two slots early: position i holds a[i + 2], zeros at a
+    row's last two."""
+    return jnp.pad(a[:, 2:], ((0, 0), (0, 2)))
+
+
+def draft_aligned_logprobs(logits, arrays: Dict):
+    """The draft module's logprobs aligned to the SLOT: out[i] = log
+    p_draft(token_{i+2} | tokens <= i + 1), and whether slot i has such a
+    target, a token two on in its OWN sequence (a sequence's last two slots
+    and padding have none and read 0). ``logits``: the module's
+    (``models/transformer.draft_forward``); ``arrays`` a train engine's
+    packed microbatch."""
+    tokens, seg = arrays["tokens"], arrays["segment_ids"]
+    has_target = (_two_on(seg) == seg) & (seg > 0)
+    logp = gather_logprobs(logits, _two_on(tokens))
+    return jnp.where(has_target, logp, 0.0), has_target
+
+
+def draft_loss(logits, arrays: Dict):
+    """(the mean cross-entropy of the draft module's ``logits`` against the
+    token two slots on, over the slots whose target is of the slot's own
+    sequence and in the loss mask ``t_loss_mask``, target-aligned as the
+    trainer's: a response token is a target once; how many slots that was,
+    int32)."""
+    logp, on = draft_aligned_logprobs(logits, arrays)
+    if "t_loss_mask" in arrays:
+        on = on & (_two_on(arrays["t_loss_mask"]) > 0)
+    n = on.sum()
+    loss = -jnp.where(on, logp, 0.0).sum() / jnp.maximum(n, 1)
+    return loss, n.astype(jnp.int32)
+
+
 def masked_normalization(
     x: jnp.ndarray,
     mask: Optional[jnp.ndarray] = None,

@@ -594,7 +594,56 @@ def qwen3_next_80b_a3b_config() -> ModelConfig:
     )
 
 
+def joyai_llm_flash_config() -> ModelConfig:
+    """JoyAI-LLM-Flash (jdopensource, 48B-A2.7B) as ONE chip of 32 that share
+    each layer holds it: published layers 0-4 (the leading dense layer and
+    four sparse ones), 8 of the 256 sigmoid-routed experts and the shared
+    one, an eighth of the untied vocabulary, and the draft module whole
+    beside them: the cut of benchmark/configs/joyai-llm-flash.json
+    (published: 40 layers, 129,280 rows). Every width is as published: 32
+    heads of 128 + 64 | 128 over a latent of 512, the query through 1536, the
+    64-wide parts rotated at 32,000,000, a dense FFN of 7168, experts of
+    768, the router's 256 outputs and 8 a token. The trainer runs it; the
+    generation engine, ``hf_io`` and sequence parallelism refuse it by name
+    (ROADMAP B.5, B.7, B.15)."""
+    from areal_tpu.models.config import MLAConfig
+
+    return ModelConfig(
+        vocab_size=16160,
+        hidden_size=2048,
+        intermediate_size=7168,
+        num_layers=5,
+        num_heads=32,
+        num_kv_heads=32,
+        head_dim=192,
+        max_position_embeddings=131072,
+        rope_theta=32000000.0,
+        rms_norm_eps=1e-6,
+        tie_word_embeddings=False,
+        family="joyai_llm_flash",
+        num_experts=256,
+        num_experts_per_tok=8,
+        moe_intermediate_size=768,
+        norm_topk_prob=True,
+        router_aux_loss_coef=0.0,
+        shared_expert_size=768,
+        shared_expert_gate=False,
+        router_act="sigmoid",
+        routed_scaling_factor=2.5,
+        experts_held=8,
+        layer_kinds=("mla+dense",) + ("mla+moe",) * 4,
+        mla=MLAConfig(
+            kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+            v_head_dim=128, q_lora_rank=1536, rotated=True,
+            rope_theta=32000000.0,
+        ),
+        mtp_layers=1,
+        mtp_loss_coef=0.1,
+    )
+
+
 MODEL_CONFIGS = {
+    "joyai_llm_flash": joyai_llm_flash_config,
     "qwen3_next_80b_a3b": qwen3_next_80b_a3b_config,
     "mellum2_12b_a2p5b": mellum2_12b_a2p5b_config,
     "zaya1_8b": zaya1_8b_config,

@@ -194,6 +194,20 @@ DEVICE_SCOPES = (
     # layer's gradient written into the stacked one). Every scope above is
     # opened inside it and the innermost scope names an operation
     "layer_stack",
+    # a ROTATED latent mixer (models/transformer._mla_mixer): from the normed
+    # latent to what the core takes, ``c @ w_kv_b``, its split into keys and
+    # values, the one rotated key part repeated over the heads and joined to
+    # them, the query's two parts joined again: what a core that took the
+    # shared key ONCE would not write. The compressions and their norms stay
+    # under ``qkv_proj``, the turn under ``rope``; the NoPE mixer's expansion
+    # stays under ``qkv_proj`` too, where its cell's shares have read it
+    "mla_expand",
+    # the draft module beside the stack (models/transformer.draft_forward):
+    # what the module ALONE computes, the shifted embedding lookup and its
+    # masks, its two input norms, the product that joins them, the norm
+    # under the head. Its block, head and loss read ``attention``, ``mlp``,
+    # ``lm_head``, ``loss`` ... as any layer's
+    "mtp",
 )
 
 

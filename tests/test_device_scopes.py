@@ -210,12 +210,19 @@ WANT = {
     # their own inside kda_scan, beside the gated full layer's core and gate
     "gdn_grad_step": {"kda_scan", "kda_prep", "gdn_operands", "attention",
                       "attn_gate", "moe_experts", "mlp", "rope"} | _AROUND,
+    # a rotated latent mixer on every layer and the draft module beside the
+    # stack: the latent's expansion and what the module alone computes under
+    # scopes of their own; the module runs in grad_step and nowhere else
+    "mla_grad_step": {"mla_expand", "mtp", "attention", "moe_experts", "mlp",
+                      "rope", "lm_head", "loss"} | _AROUND,
+    "mla_logp_forward": {"mla_expand", "attention", "mlp", "rope", "lm_head"},
 }
 # a family's ``grad_step`` among the programs of ``lowered``
 FAMILIES = {"qwen2": "grad_step", "olmoe": "sparse_grad_step",
             "KeyeVL2": "dsa_grad_step", "kimi_linear": "kda_grad_step",
             "laguna": "swa_grad_step", "zaya": "cca_grad_step",
-            "qwen3_next": "gdn_grad_step"}
+            "qwen3_next": "gdn_grad_step",
+            "joyai_llm_flash": "mla_grad_step"}
 # the five families whose programs a change for a sixth may not move: the
 # lowered grad_step's text (the persistent compile cache's key is made of
 # it), by sha256, in tests/golden/grad_step_stablehlo.json. A PR that means
@@ -241,6 +248,9 @@ def lowered():
     ]:
         grad_steps[program] = _train_programs(family, only_grad=True)[0][1]
         out[program] = _op_names(grad_steps[program])
+    mla = dict(_train_programs("joyai_llm_flash")[:2])
+    out["mla_grad_step"] = _op_names(mla["grad_step"])
+    out["mla_logp_forward"] = _op_names(mla["logp_forward"])
     out["mesh_sparse_grad_step"] = _op_names(_train_programs(
         "olmoe", only_grad=True, fsdp_parallel_size=2
     )[0][1])
@@ -264,8 +274,29 @@ def test_program_lowers_under_its_contract_name_with_its_scopes(
     jitted = jitted.removeprefix("dsa_")
     jitted = jitted.removeprefix("kda_").removeprefix("swa_")
     jitted = jitted.removeprefix("cca_").removeprefix("gdn_")
+    jitted = jitted.removeprefix("mla_")
     assert re.search(rf"^HloModule jit_{jitted}[, ]", text, re.M)
     assert WANT[program] <= _scopes_in(names), sorted(names)[:40]
+
+
+def test_the_draft_module_runs_in_grad_step_and_not_in_logp_forward(lowered):
+    from benchmark import program_trace
+
+    _text, grad = lowered["mla_grad_step"]
+    _text, logp = lowered["mla_logp_forward"]
+    module = [n for n in grad if program_trace.scope_of(n) == "mtp"]
+    # forward and backward of what the module alone computes (its block is
+    # checkpointed, these are outside it: no recomputed forward)
+    assert {program_trace.direction_of(n) for n in module} == {"fwd", "bwd"}
+    assert not [n for n in logp if program_trace.scope_of(n) == "mtp"]
+    expand = [n for n in grad if program_trace.scope_of(n) == "mla_expand"]
+    assert {program_trace.direction_of(n) for n in expand} == {
+        "fwd", "remat", "bwd"
+    }
+    assert [n for n in logp if program_trace.scope_of(n) == "mla_expand"]
+    # Kimi's NoPE mixer keeps its expansion where its cell's shares read it
+    _text, kimi = lowered["kda_grad_step"]
+    assert not [n for n in kimi if "mla_expand" in n or "/mtp/" in n]
 
 
 def test_the_window_core_is_not_nested_in_attention(lowered):

@@ -78,12 +78,36 @@ def test_tiny_config_is_the_family_and_the_catalogs_config_builds():
 
 
 @pytest.mark.parametrize("bad,word", [
-    ({"mla_use_nope": False}, "mla_use_nope"),
-    ({"q_lora_rank": 1536}, "q_lora_rank"),
+    ({"mla_use_nope": False, "rope_scaling": {"type": "yarn", "factor": 4}},
+     "rope_scaling on a rotated"),
     ({"num_expert_group": 8}, "num_expert_group"),
+    ({"moe_router_activation_func": "softmax"}, "not sigmoid"),
 ])
 def test_what_the_family_may_say_and_the_program_lacks_is_refused(bad, word):
     fb.check_refused(CUT, bad, word)
+
+
+@pytest.mark.parametrize("said,field,value", [
+    ({"mla_use_nope": False}, "rotated", True),
+    ({"mla_use_nope": False, "q_lora_rank": 768}, "q_lora_rank", 768),
+    ({"q_lora_rank": 1536}, "q_lora_rank", 1536),
+])
+def test_a_rotated_key_part_and_a_compressed_query_are_the_mixers_fields(
+    said, field, value
+):
+    """What the reader refused by name until PR 54 (``_mla_mixer`` had
+    neither) it now reads to ``MLAConfig``; the published file (NoPE, the
+    query whole) reads to the configuration it always did."""
+    import dataclasses
+
+    from areal_tpu.models.config import MLAConfig
+
+    assert from_hf_config(fb.cut(CUT)).mla == MLAConfig(512, 128, 64, 128)
+    changed = from_hf_config(fb.cut(CUT, **said))
+    assert getattr(changed.mla, field) == value
+    assert dataclasses.replace(
+        changed, mla=from_hf_config(fb.cut(CUT)).mla
+    ) == from_hf_config(fb.cut(CUT))
 
 
 @pytest.mark.parametrize("kind", ["kda+dense", "kda+moe", "mla+moe"])
