@@ -219,10 +219,12 @@ class SPMDTrainEngine(TrainEngine):
         # ``attn_impl`` and the packing ``window`` (``transformer.span_attrs``)
         # and, out of it, the forms its ops resolved to in this process: the
         # experts' grouped matmul ("megablox" | "ragged_dot"), the learned
-        # selection's passes and the delta rule's ("kernel" | "xla")
+        # selection's passes, the delta rule's and its convolution's
+        # ("kernel" | "xla")
         self.moe_impl: Optional[str] = None
         self.dsa_select_impl: Optional[str] = None
         self.kda_impl: Optional[str] = None
+        self.prep_impl: Optional[str] = None
         self._span_attrs: Dict[str, Any] = {}
         self.initialized = False
 
@@ -310,8 +312,10 @@ class SPMDTrainEngine(TrainEngine):
         # what the model's mechanisms declare (``transformer.MECHANISMS``)
         self._counters.update(dict.fromkeys(self._count_names(), 0))
         self._span_attrs = transformer.span_attrs(mc, self._experts_split())
-        self.moe_impl, self.dsa_select_impl, self.kda_impl = map(
-            self._span_attrs.get, ("moe_impl", "dsa_select_impl", "kda_impl")
+        (self.moe_impl, self.dsa_select_impl, self.kda_impl,
+         self.prep_impl) = map(
+            self._span_attrs.get,
+            ("moe_impl", "dsa_select_impl", "kda_impl", "prep_impl"),
         )
         n = count_params(self.params)
         logger.info(

@@ -41,6 +41,7 @@ import numpy as np
 from jax import ad_checkpoint as _ad_checkpoint
 
 from areal_tpu.models.config import ModelConfig
+from areal_tpu.ops import conv_prep as conv_prep_ops
 from areal_tpu.ops import flash as flash_ops
 from areal_tpu.ops import kda as kda_ops
 from areal_tpu.ops import moe as moe_ops
@@ -526,7 +527,8 @@ MECHANISMS: Dict[str, Mechanism] = {
         count=_reported("kda", "tokens", "chunk_slots", "segments"),
         no_generation=_no_kinds_stack,
         span_attrs=lambda cfg: {
-            "kda_impl": kda_ops.scan_impl(cfg.kda.head_dim, cfg.kda.head_dim)
+            "kda_impl": kda_ops.scan_impl(cfg.kda.head_dim, cfg.kda.head_dim),
+            "prep_impl": _kda_prep_impl(cfg),
         },
     ),
     "gdn": Mechanism(  # the same rule, one decay a head: the op's names
@@ -545,6 +547,7 @@ MECHANISMS: Dict[str, Mechanism] = {
                 cfg.gdn.head_k_dim, cfg.gdn.head_v_dim
             ),
             "gdn_decay": "scalar",
+            "prep_impl": _gdn_prep_impl(cfg),
         },
     ),
     "mla": Mechanism(
@@ -976,6 +979,25 @@ def _short_conv(x, w, segment_ids):
     return y
 
 
+def _kda_prep_impl(cfg, t=None) -> str:
+    """``ops/conv_prep.prep_impl`` for the ``kda`` mixer's three convolutions
+    on rows of ``t`` tokens (None: the trainer's): q and k normed a head, v
+    not, all of one width."""
+    kc = cfg.kda
+    c = kc.num_heads * kc.head_dim
+    return conv_prep_ops.prep_impl(c, c, kc.head_dim, t, kc.conv_size)
+
+
+def _gdn_prep_impl(cfg, t=None) -> str:
+    """The same for the ``gdn`` mixer's one convolution over q, k (normed a
+    key head) and v together."""
+    gc = cfg.gdn
+    return conv_prep_ops.prep_impl(
+        2 * gc.key_dim + gc.value_dim, 2 * gc.key_dim, gc.head_k_dim, t,
+        gc.conv_size,
+    )
+
+
 def _kda_mixer(cfg, h, lp, segment_ids, kda_fn):
     """The gated delta-rule mixer on the normed input ``h`` [B, T, D]:
     (y [B, T, D], ``KDAStats``). ``ops/kda.py`` has the rule; here is what
@@ -995,9 +1017,19 @@ def _kda_mixer(cfg, h, lp, segment_ids, kda_fn):
                 jnp.sum(y * y, axis=-1, keepdims=True) + 1e-6
             )
 
-        q = l2norm(conv("q")).astype(h.dtype)
-        k = l2norm(conv("k")).astype(h.dtype)
-        v = conv("v").astype(h.dtype)
+        impl = _kda_prep_impl(cfg, t)
+        if impl == "xla":
+            q = l2norm(conv("q")).astype(h.dtype)
+            k = l2norm(conv("k")).astype(h.dtype)
+            v = conv("v").astype(h.dtype)
+        else:  # one kernel a projection: ``ops/conv_prep.py``
+            q, k, v = (
+                conv_prep_ops.conv_prep(
+                    proj[n], lp[f"conv_{n}"], segment_ids, normed,
+                    impl == "interpret",
+                ).reshape(b, t, H, dh)
+                for n, normed in (("q", H * dh), ("k", H * dh), ("v", 0))
+            )
         f = ((h @ lp["w_f_a"]) @ lp["w_f_b"]).astype(jnp.float32)
         rate = jnp.exp(lp["a_log"].astype(jnp.float32))[:, None]
         g = -rate * jax.nn.softplus(
@@ -1038,17 +1070,28 @@ def _gdn_mixer(cfg, h, lp, segment_ids, kda_fn):
                            axis=-1)
         ba = h @ lp["w_ba"]
     with device_scope("kda_prep"):
-        qkv = jax.nn.silu(_short_conv(qkv, lp["conv_qkv"], segment_ids))
-        q, k, v = jnp.split(qkv, [gc.key_dim, 2 * gc.key_dim], axis=-1)
-
         def l2norm(y):
             y = y.reshape(b, t, Hk, dk)
             return y * jax.lax.rsqrt(
                 jnp.sum(y * y, axis=-1, keepdims=True) + 1e-6
             )
 
-        q, k = l2norm(q).astype(h.dtype), l2norm(k).astype(h.dtype)
-        v = v.reshape(b, t, Hv, dv).astype(h.dtype)
+        impl = _gdn_prep_impl(cfg, t)
+        if impl == "xla":
+            qkv = jax.nn.silu(_short_conv(qkv, lp["conv_qkv"], segment_ids))
+            q, k, v = jnp.split(qkv, [gc.key_dim, 2 * gc.key_dim], axis=-1)
+            q, k = l2norm(q).astype(h.dtype), l2norm(k).astype(h.dtype)
+            v = v.reshape(b, t, Hv, dv).astype(h.dtype)
+        else:  # q, k, v through ONE kernel call: ``ops/conv_prep.py``
+            q, k, v = jnp.split(
+                conv_prep_ops.conv_prep(
+                    qkv, lp["conv_qkv"], segment_ids, 2 * gc.key_dim,
+                    impl == "interpret",
+                ),
+                [gc.key_dim, 2 * gc.key_dim], axis=-1,
+            )
+            q, k = q.reshape(b, t, Hk, dk), k.reshape(b, t, Hk, dk)
+            v = v.reshape(b, t, Hv, dv)
         beta_in, a = jnp.split(ba.astype(jnp.float32), 2, axis=-1)
         beta = jax.nn.sigmoid(beta_in)
         g = -jnp.exp(lp["a_log"].astype(jnp.float32)) * jax.nn.softplus(

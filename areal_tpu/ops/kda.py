@@ -82,6 +82,17 @@ over the channels and ``q, k`` over the value heads (tests).
 Device scope ``kda_scan`` (``utils/tracing.DEVICE_SCOPES``): everything in
 this file. The convolution, the decays' and gates' projections and the
 output norm are the layer's (``kda_prep``, ``models/transformer.py``).
+
+What feeds this op has a kernel pair of its own since PR 55,
+``ops/conv_prep.py``: the mixers' short convolution over q, k, v with its
+``silu`` and the l2norm of q's and k's heads, bf16 in and out
+(``conv_prep_fwd`` / ``conv_prep_bwd``; not named ``kda_*``, for the reason
+above). ``conv_prep.prep_impl`` chooses it as ``scan_impl`` chooses here, and
+narrower: a TPU backend, ONE device in the process, heads of exactly one lane
+tile, rows of whole 512-token blocks; elsewhere the mixers keep their
+``jax.numpy`` lines. ``models/transformer._short_conv`` is those lines'
+convolution, the kernels' reference in tests, and ZAYA's own form (two taps, a
+bias, no activation, a float32 result), which the kernels do not serve.
 """
 
 import functools

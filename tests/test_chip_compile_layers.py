@@ -278,3 +278,39 @@ def test_gdn_grad_step_lowers_each_operand_kernel_a_bounded_number_of_times(
     assert not any(name.startswith("chunk_ops") for name in calls), calls
     for name in ("kda_fwd_h", "kda_fwd_h_states", "kda_bwd_dh"):
         assert 1 <= calls[name] <= 2, calls
+
+
+@pytest.mark.parametrize("t,c,normed", [
+    pytest.param(16384, 8192, 4096, id="qwen3-next-qkv"),
+    pytest.param(8192, 4096, 4096, id="kimi-q-or-k"),
+    pytest.param(8192, 4096, 0, id="kimi-v"),
+])
+def test_conv_prep_fwd_bwd_compiles_for_v5e(one_chip, t, c, normed):
+    """The delta-rule mixers' convolution kernels (``ops/conv_prep.py``) at
+    the two cells' widths on a cell's row, forward and backward: the sublane
+    rolls of a 528-row head, the 16-row views above and below a block, the
+    float32 accumulator of the taps' cotangent held in VMEM over the grid;
+    and nothing float32 of the row's size between them."""
+    import jax
+    import jax.numpy as jnp
+
+    from areal_tpu.ops import conv_prep
+
+    bf = jnp.bfloat16
+
+    def loss(x, w, seg):
+        return conv_prep.conv_prep(x, w, seg, normed).astype(
+            jnp.float32
+        ).sum()
+
+    text = (
+        jax.jit(jax.grad(loss, argnums=(0, 1)))
+        .lower(
+            _sds((1, t, c), bf, one_chip), _sds((c, 4), bf, one_chip),
+            _sds((1, t), jnp.int32, one_chip),
+        )
+        .compile()
+        .as_text()
+    )
+    assert re.search(r"conv_prep_bwd[^_a-z]", text)
+    assert not re.search(rf"f32\[1,{t},{c}\]", text)

@@ -624,7 +624,7 @@ def test_the_trainer_counts_what_the_models_mechanisms_declare(
     # what the engine holds of the ops' resolved forms is the spans' too
     attrs = eng._span_attrs
     assert attrs == transformer.span_attrs(eng.model_config)
-    for attr in ("moe_impl", "dsa_select_impl", "kda_impl"):
+    for attr in ("moe_impl", "dsa_select_impl", "kda_impl", "prep_impl"):
         assert getattr(eng, attr) == attrs.get(attr)
 
 

@@ -18,6 +18,16 @@ cotangents as ``gdn_ops_bwd`` | XLA's differentiation of ``_prep_scalar``;
 ``--ops-chunks 4 8 16`` sweeps the chunks a grid step of the operand kernels
 takes together (``ops/kda._OPS_CHUNKS``).
 
+``--prep`` times instead what feeds the scan (``ops/conv_prep.py``): the
+short convolution with its ``silu`` and head l2norm, ``conv_prep_fwd`` /
+``conv_prep_bwd`` against the ``jax.numpy`` form (``_short_conv`` and the
+mixers' lines) on one packed row of ``--tokens`` at ``--channels`` with the
+first ``--norm-channels`` normed (Kimi: ``--tokens 8192 --channels 4096
+--norm-channels 4096`` twice and ``--norm-channels 0`` once a layer;
+Qwen3-Next: ``--tokens 16384 --channels 8192 --norm-channels 4096`` once), and
+``--prep-blocks 256,1024 512,2048 ...`` sweeps the kernels' tokens and
+channels a block (``conv_prep.BLOCK_TOKENS``, ``BLOCK_CHANNELS``).
+
 Host clock around ``block_until_ready``, the best of ``RUNS``. ``ops/kda.py``
 resolves ``scan_impl`` to what this found faster (PERF.md, PR 33, PR 35).
 """
@@ -56,6 +66,83 @@ def _worst_rel(xs, ys):
     )
 
 
+def _packed_segments(t, seqs):
+    """[1, t] int32: ``seqs`` sequences and a padded tail, borders inside
+    chunks (and so inside the convolution's blocks)."""
+    real = t - 200
+    cuts = np.linspace(0, real, seqs + 1).astype(int) + np.arange(
+        seqs + 1
+    ) * 7 % 61
+    seg = np.zeros((1, t), np.int32)
+    for i in range(seqs):
+        seg[0, cuts[i]:min(cuts[i + 1], real)] = i + 1
+    return jnp.asarray(seg)
+
+
+def prep(args):
+    """The convolution, ``silu`` and head l2norm: kernel against
+    ``jax.numpy``, forward, forward + backward, and the kernel's backward
+    alone; then the kernels at each ``--prep-blocks`` tokens,channels."""
+    from areal_tpu.models.transformer import _short_conv
+    from areal_tpu.ops import conv_prep as cp
+
+    t, c, nc = args.tokens, args.channels, args.norm_channels
+    interp = jax.default_backend() != "tpu"
+    ks = jax.random.split(jax.random.PRNGKey(0), 3)
+    x = jax.random.normal(ks[0], (1, t, c), jnp.float32).astype(jnp.bfloat16)
+    w = (jax.random.normal(ks[1], (c, 4)) * 0.5).astype(jnp.bfloat16)
+    ct = jax.random.normal(ks[2], (1, t, c), jnp.float32).astype(jnp.bfloat16)
+    seg = _packed_segments(t, args.seqs)
+
+    def plain(x, w):
+        y = jax.nn.silu(_short_conv(x, w, seg))
+        n = y[..., :nc].reshape(1, t, nc // 128, 128)
+        n = n * jax.lax.rsqrt(jnp.sum(n * n, axis=-1, keepdims=True) + 1e-6)
+        return jnp.concatenate(
+            [n.reshape(1, t, nc), y[..., nc:]], axis=-1
+        ).astype(x.dtype)
+
+    def kernel(x, w):
+        return cp.conv_prep(x, w, seg, nc, interp)
+
+    def both(fn):
+        return jax.jit(lambda x, w: jax.vjp(fn, x, w)[1](ct))
+
+    print(json.dumps({"prep": {"tokens": t, "channels": c, "normed": nc},
+                      "MB_in_and_out": 4 * t * c / 1e6}))
+    out = {}
+    for impl, fn in (("kernel", kernel), ("xla", plain)):
+        fwd = jax.jit(fn)
+        out[impl], ms = _best_ms(lambda: fwd(x, w))
+        print(json.dumps({"pass": "prep forward", "impl": impl, "ms": ms}))
+        back = both(fn)
+        out[impl + "_grads"], ms = _best_ms(lambda: back(x, w))
+        print(json.dumps({"pass": "prep forward+backward", "impl": impl,
+                          "ms": ms}))
+    print(json.dumps({
+        "prep_kernel_vs_xla_worst_rel": _worst_rel(
+            [out["kernel"]], [out["xla"]]
+        ),
+        "prep_grads_worst_rel": _worst_rel(
+            out["kernel_grads"], out["xla_grads"]
+        ),
+    }))
+    sizes = [(cp.BLOCK_TOKENS, cp.BLOCK_CHANNELS)] + args.prep_blocks
+    for block, chans in sizes:
+        # read while a kernel's wrapper is traced; the process ends with it
+        cp.BLOCK_TOKENS, cp.BLOCK_CHANNELS = block, chans
+        jax.clear_caches()
+        fwd = jax.jit(kernel)
+        _, f_ms = _best_ms(lambda: fwd(x, w))
+        back = jax.jit(lambda x, w, g: cp._bwd_rows(
+            x, w, seg, g, norm_channels=nc, interpret=interp
+        ))
+        _, b_ms = _best_ms(lambda: back(x, w, ct))
+        print(json.dumps({"pass": "prep kernels", "block_tokens": block,
+                          "block_channels": chans, "forward_ms": f_ms,
+                          "backward_ms": b_ms}))
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--tokens", type=int, default=8192)
@@ -66,10 +153,19 @@ def main():
                     help="the scalar-decay form: q, k of this many heads")
     ap.add_argument("--ops-chunks", type=int, nargs="*", default=[],
                     help="also time the operand kernels at these block sizes")
+    ap.add_argument("--prep", action="store_true",
+                    help="time the convolution's kernels, not the scan")
+    ap.add_argument("--channels", type=int, default=4096)
+    ap.add_argument("--norm-channels", type=int, default=4096)
+    ap.add_argument("--prep-blocks", nargs="*", default=[],
+                    type=lambda s: tuple(int(n) for n in s.split(",")),
+                    help="tokens,channels a block to time the kernels at")
     args = ap.parse_args()
+    print(json.dumps({"device": str(jax.devices()[0].device_kind)}))
+    if args.prep:
+        return prep(args)
     t, h, d = args.tokens, args.heads, args.dim
     scalar, hk = bool(args.key_heads), args.key_heads or args.heads
-    print(json.dumps({"device": str(jax.devices()[0].device_kind)}))
     ks = jax.random.split(jax.random.PRNGKey(0), 5)
 
     def unit(key):
@@ -82,15 +178,7 @@ def main():
         ks[3], (1, t, h) if scalar else (1, t, h, d), minval=-7, maxval=0.5
     ))
     beta = jax.nn.sigmoid(jax.random.normal(ks[4], (1, t, h)))
-    # a few sequences and a padded tail, borders inside chunks
-    real = t - 200
-    cuts = np.linspace(0, real, args.seqs + 1).astype(int) + np.arange(
-        args.seqs + 1
-    ) * 7 % 61
-    seg = np.zeros((1, t), np.int32)
-    for i in range(args.seqs):
-        seg[0, cuts[i]:min(cuts[i + 1], real)] = i + 1
-    seg = jnp.asarray(seg)
+    seg = _packed_segments(t, args.seqs)
     a = (q, k, v, g, beta, seg)
     # off the chip the kernels run in Pallas interpret mode: the control
     # flow's rehearsal, never a time
