@@ -216,8 +216,8 @@ class SPMDTrainEngine(TrainEngine):
         self.attn_impl: Optional[str] = None
         self.splash_block: Optional[int] = None
         # what a dispatch span says of the model's mechanisms beside
-        # ``attn_impl`` and the packing ``window`` (``transformer.span_attrs``)
-        # and, out of it, the forms its ops resolved to in this process: the
+        # ``attn_impl`` (``transformer.span_attrs``) and, out of it, the
+        # forms its ops resolved to in this process: the
         # experts' grouped matmul ("megablox" | "ragged_dot"), the learned
         # selection's passes, the delta rule's and its convolution's
         # ("kernel" | "xla")
@@ -557,39 +557,17 @@ class SPMDTrainEngine(TrainEngine):
     # ------------------------------------------------------------------
     # Train
     # ------------------------------------------------------------------
-    def _attn_window(self, input_: Batch) -> int:
-        """The splash kernel's PACKING window for this batch (0 = full
-        causal, and what every other attention gets): the rule is
-        ``ops.flash.flash_window``'s, from the batch's longest sequence. It
-        is what the full-attention layers' kernel gets, so it may never be
-        shorter than that sequence; a window-attention layer's own window is
-        the model's (``ModelConfig.layer_window``), not this one."""
-        if self.attn_impl != "flash":
-            return 0
-        longest = int(np.asarray(input_["attention_mask"]).sum(1).max())
-        window = flash_ops.flash_window(
-            longest, self.config.mb_spec.max_tokens_per_mb
-        )
-        if window and window < longest:
-            raise RuntimeError(
-                f"a packing window of {window} keys is shorter than the "
-                f"batch's longest sequence ({longest}): it would change the "
-                "full-attention layers' result"
-            )
-        return window
-
-    def _count_attn_tiles(
-        self, segment_ids: np.ndarray, window: int
-    ) -> Dict[str, int]:
-        """A dispatched microbatch's rows as the splash kernel walks them
-        under packing ``window``: counted (``counters()``, the stats plane)
-        and returned as the dispatch span's attributes beside ``window``.
-        The host knows the rows' borders when it packs them, so the count
-        costs the device nothing; nothing where another attention runs."""
+    def _count_attn_tiles(self, segment_ids: np.ndarray) -> Dict[str, int]:
+        """A dispatched microbatch's rows as the full-attention layers'
+        splash kernel walks them: counted (``counters()``, the stats plane)
+        and returned as the dispatch span's attributes. The host knows the
+        rows' borders when it packs them, so the count costs the device
+        nothing; nothing where another attention runs."""
         if self.attn_impl != "flash":
             return {}
         tiles, kept = flash_ops.tile_counts(
-            segment_ids, window, self.model_config.attn_head_dims[0]
+            segment_ids, self.model_config.attn_head_dims[0],
+            self.splash_block or 0,
         )
         counted = {"attn_tiles": tiles, "attn_tiles_kept": kept}
         for k, n in counted.items():
@@ -597,33 +575,29 @@ class SPMDTrainEngine(TrainEngine):
         stats_tracker.counter(**{f"spmd/{k}": n for k, n in counted.items()})
         return counted
 
-    def _forward_values(self, window: int) -> Dict[str, Any]:
+    def _forward_values(self, _unused: int = 0) -> Dict[str, Any]:
         """What every forward program of ``engine/programs.py`` is built
-        from: this engine's model, mesh, compute dtype and attention."""
+        from: this engine's model, mesh, compute dtype and attention. (The
+        argument is read by nothing: ``benchmark/kinds/train_steps_mla.py``
+        still hands in the 0 that was "no packing window" until PR 56, and
+        a program PR does not edit the benchmark's files.)"""
         return dict(
             model_config=self.model_config, mesh=self.mesh,
             compute_dtype=self.compute_dtype,
             attend_fn=attention_fn(
-                self.attn_impl, self.mesh, window,
+                self.attn_impl, self.mesh, block_cap=self.splash_block or 0,
                 topk=self.model_config.dsa_topk,
             ),
         )
 
-    def _program(
-        self, key: tuple, build: Callable, window: int, row_len: int,
-        **values,
-    ) -> Tuple[Callable, int]:
-        """The cached device program ``build`` makes for ``key`` (what the
-        caller handed in: loss, hook) and the attention's window. A window
-        that reaches over the whole row is full causal, so it is 0 there:
-        a short row does not compile once for every window above it."""
-        window = window if window < row_len else 0
-        key = key + (window,)
+    def _program(self, key: tuple, build: Callable, **values) -> Callable:
+        """The cached device program ``build`` makes for ``key``: what the
+        caller handed in (loss, hook) and nothing else. jit retraces it a
+        row length, and which kernel instance a row then runs is
+        ``ops.flash.instance``'s to say from the row and the layer."""
         if key not in self._jit_cache:
-            self._jit_cache[key] = build(
-                **self._forward_values(window), **values
-            )
-        return self._jit_cache[key], window
+            self._jit_cache[key] = build(**self._forward_values(), **values)
+        return self._jit_cache[key]
 
     def _zero_grads(self):
         key = "zeros"
@@ -701,7 +675,6 @@ class SPMDTrainEngine(TrainEngine):
         with tracing.span("train.minibatch", step=step):
             with tracing.span("train.split", step=step):
                 mbs = self._split(input_, backward=True)
-                window = self._attn_window(input_)
                 if grad_accum is None:
                     grad_accum = self._zero_grads()
             losses, weights, all_stats = [], [], []
@@ -715,23 +688,22 @@ class SPMDTrainEngine(TrainEngine):
             for mb, row_len in zip(mbs.mbs, mbs.row_lens):
                 with goodput.trainer_bucket("data_h2d"):
                     packed, arrays = self._pack_for_device(mb, pad_to=row_len)
-                grad_fn, mb_window = self._program(
+                grad_fn = self._program(
                     ("grad", loss_fn, loss_weight_fn),
-                    programs.build_grad_step, window, row_len,
+                    programs.build_grad_step,
                     remat=self.config.gradient_checkpointing,
                     loss_fn=loss_fn, loss_weight_fn=loss_weight_fn,
                 )
                 with goodput.trainer_bucket("fwd_bwd"), tracing.span(
                     "train.grad_dispatch", step=step,
-                    attn_impl=self.attn_impl, window=mb_window,
-                    **self._count_attn_tiles(packed.segment_ids, mb_window),
+                    attn_impl=self.attn_impl,
+                    **self._count_attn_tiles(packed.segment_ids),
                     **self._span_attrs,
                     **transformer.grad_span_attrs(
-                        self.model_config, self.attn_impl, row_len, mb_window
+                        self.model_config, self.attn_impl, row_len
                     ),
                 ), goodput.dispatch_scope(
-                    goodput.trainer_tracker(), "fwd_bwd",
-                    f"pad{row_len}|window{mb_window}",
+                    goodput.trainer_tracker(), "fwd_bwd", f"pad{row_len}"
                 ):
                     grad_accum, loss, stats, w = grad_fn(
                         self.params, grad_accum, arrays
@@ -813,14 +785,12 @@ class SPMDTrainEngine(TrainEngine):
         self, input_: Batch, loss_fn: Callable, loss_weight_fn: Callable
     ) -> Dict[str, float]:
         mbs = self._split(input_, backward=False)
-        window = self._attn_window(input_)
         losses, weights = [], []
         for mb, row_len in zip(mbs.mbs, mbs.row_lens):
             _, arrays = self._pack_for_device(mb, pad_to=row_len)
-            eval_fn, _ = self._program(
+            eval_fn = self._program(
                 ("eval", loss_fn, loss_weight_fn), programs.build_eval_step,
-                window, row_len, loss_fn=loss_fn,
-                loss_weight_fn=loss_weight_fn,
+                loss_fn=loss_fn, loss_weight_fn=loss_weight_fn,
             )
             loss, stats, w = eval_fn(self.params, arrays)
             losses.append(loss)
@@ -853,8 +823,8 @@ class SPMDTrainEngine(TrainEngine):
         `post_hook(logits, arrays) -> [R, T] array` must be jittable; default
         returns target-aligned logprobs. ``rows``: run these sequences of
         `input_` only (the others read 0 in the result), at the row length
-        and under the packing window of the WHOLE input, so the pass runs
-        programs a pass over all of it would have built.
+        of the WHOLE input, so the pass runs programs a pass over all of it
+        would have built.
         """
         step = self.step_count
         with tracing.span("train.logp_recompute", step=step):
@@ -866,19 +836,17 @@ class SPMDTrainEngine(TrainEngine):
     ) -> np.ndarray:
         mbs = self._split(input_, backward=False, rows=rows)
         hook = post_hook or target_aligned_logprobs
-        window = self._attn_window(input_)
 
         def dispatched():
             for mb, row_len in zip(mbs.mbs, mbs.row_lens):
                 packed, arrays = self._pack_for_device(mb, pad_to=row_len)
-                logp_fn, mb_window = self._program(
-                    ("fwd", hook), programs.build_logp_forward,
-                    window, row_len, hook=hook,
+                logp_fn = self._program(
+                    ("fwd", hook), programs.build_logp_forward, hook=hook
                 )
                 with tracing.span(
                     "train.logp_dispatch", step=step,
-                    attn_impl=self.attn_impl, window=mb_window,
-                    **self._count_attn_tiles(packed.segment_ids, mb_window),
+                    attn_impl=self.attn_impl,
+                    **self._count_attn_tiles(packed.segment_ids),
                     **self._span_attrs,
                 ):
                     vals = logp_fn(self.params, arrays)

@@ -352,9 +352,9 @@ class Mechanism(NamedTuple):
     no_seq_parallel: Optional[Callable] = None
     no_generation: Optional[Callable] = None
     span_attrs: Optional[Callable] = None
-    # (cfg, attn_impl, row_len, window) -> what a GRAD dispatch span says
-    # beside them of the program it runs: the row's length and the packing
-    # window are the dispatch's, not the model's
+    # (cfg, attn_impl, row_len) -> what a GRAD dispatch span says beside
+    # them of the program it runs: the row's length is the dispatch's, not
+    # the model's
     grad_span_attrs: Optional[Callable] = None
 
 
@@ -403,7 +403,7 @@ def _cca_counts(cfg, reported, segment_ids, positions):
     )
 
 
-def _swa_backwards(cfg, attn_impl, row_len, window):
+def _swa_backwards(cfg, attn_impl, row_len):
     """The backward each of the two cores' kernel instances took
     (``ops.flash.backward_form``); nothing where another attention runs."""
     if attn_impl != "flash":
@@ -411,9 +411,9 @@ def _swa_backwards(cfg, attn_impl, row_len, window):
     d = cfg.head_dim
     return {
         "attn_bwd_window": flash_ops.backward_form(
-            row_len, window, d, cfg.swa.window
+            row_len, d, cfg.swa.window
         ),
-        "attn_bwd_full": flash_ops.backward_form(row_len, window, d),
+        "attn_bwd_full": flash_ops.backward_form(row_len, d),
     }
 
 
@@ -649,11 +649,11 @@ def span_attrs(cfg: ModelConfig, split=False) -> Dict[str, Any]:
 
 
 def grad_span_attrs(
-    cfg: ModelConfig, attn_impl: str, row_len: int, window: int
+    cfg: ModelConfig, attn_impl: str, row_len: int
 ) -> Dict[str, Any]:
-    """What a grad dispatch of ``row_len``-token rows under packing
-    ``window`` says of the model's mechanisms beside ``span_attrs``."""
-    said = _declared(cfg, "grad_span_attrs", attn_impl, row_len, window)
+    """What a grad dispatch of ``row_len``-token rows says of the model's
+    mechanisms beside ``span_attrs``."""
+    said = _declared(cfg, "grad_span_attrs", attn_impl, row_len)
     return {k: v for attrs in said for k, v in attrs.items() if v}
 
 

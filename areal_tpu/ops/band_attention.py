@@ -80,7 +80,7 @@ STRIP_BQ = 256
 
 def strip_block(t: int, back: int, d: int) -> int:
     """The query block of the strip kernel on a row of ``t`` tokens whose band
-    reaches ``back`` keys behind a query (``flash._local_mask``), heads ``d``
+    reaches ``back`` keys behind a query (``flash.instance``), heads ``d``
     wide; 0 where the kernel does not take the band: a head that is not whole
     lane rows, or a row that is not a whole number of blocks."""
     if d % _LANES or not 0 < back < t or t % STRIP_BQ:

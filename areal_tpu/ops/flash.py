@@ -18,6 +18,7 @@ Pallas interpret mode (``interpret=True``: ``tests/test_flash_parity.py``).
 """
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -33,11 +34,6 @@ from jax.experimental.pallas.ops.tpu.splash_attention import (
 # what a block edge must be a multiple of, and a row a whole number of
 _LANES = 128
 
-# The largest block edge this process's TPU backend compiled AND ran
-# (``probe_block_size``): None = not probed, 0 = nothing to cap (the CPU
-# backend, where the kernel runs only in Pallas interpret mode).
-_PROBED_BLOCK: "int | None" = None
-
 # below this edge the per-grid-step overhead dominates long-context
 # attention; a TPU that cannot compile it is misconfigured, not slow
 _MIN_PROBED_EDGE = 512
@@ -45,8 +41,8 @@ _MIN_PROBED_EDGE = 512
 
 def _tiles(edge: int, fused: bool = True) -> "_sk.BlockSizes":
     """One edge for every tile of forward and backward; what else
-    ``_block_sizes`` found best at every shape it measured. ``fused``: one
-    backward kernel for dq, dk and dv, or the two split ones."""
+    ``instance``'s sweeps found best at every shape they measured. ``fused``:
+    one backward kernel for dq, dk and dv, or the two split ones."""
     kvc = min(512, edge)
     dq = {} if fused else dict(block_q_dq=edge, block_kv_dq=edge)
     return _sk.BlockSizes(
@@ -56,10 +52,10 @@ def _tiles(edge: int, fused: bool = True) -> "_sk.BlockSizes":
     )
 
 
-# A window-attention layer's band (``_local_mask`` says which local mask is
-# one; its size does not) WHERE IT COMES HERE: on a TPU a band of 128-wide
-# heads has kernels of its own (``_strip_band``, at the file's end). Its tile
-# edge, and the SPLIT backward: the fused one writes a partial dq for every
+# A window-attention layer's band (the one local mask there is: ``instance``)
+# WHERE IT COMES HERE: on a TPU a band of 128-wide heads has kernels of its
+# own (``_strip_band``, at the file's end). Its tile edge, and the SPLIT
+# backward: the fused one writes a partial dq for every
 # kv block, [T / edge, heads, T, d], over a grid it does not shrink to the
 # band: at T = 16384, edge 512 and 64/8 heads of 128 that is 8.6 GB a layer,
 # most of it zeros (the compiler's memory_analysis() of grad_step read 14.9
@@ -76,6 +72,7 @@ def _tiles(edge: int, fused: bool = True) -> "_sk.BlockSizes":
 BAND_EDGE = 512
 
 
+@functools.lru_cache(maxsize=None)
 def probe_block_size(max_block: int = 2048, probe_t: int = 2048) -> int:
     """Find the largest splash block edge this backend can actually run.
 
@@ -84,17 +81,16 @@ def probe_block_size(max_block: int = 2048, probe_t: int = 2048) -> int:
     ``areal_tpu/__init__`` when it runs before jax backend init; without it
     the 2048 edge is refused and 1024 compiles). What the limit allows is
     PROBED: compile+run a small fwd+bwd at each candidate edge and keep the
-    largest that works; ``_block_sizes`` never chooses above it. On a TPU,
-    if no edge >= 512 compiles the probe RAISES with the compiler's message
+    largest that works; the caller hands it on as ``block_cap`` and
+    ``instance`` never chooses above it. 0 on the CPU backend, where the
+    kernel runs only in Pallas interpret mode: nothing to cap. On a TPU, if
+    no edge >= 512 compiles the probe RAISES with the compiler's message
     — an entry point that lost the flag must not go on to train on tiles a
-    quarter the size. Result is cached process-wide; call once from engine
+    quarter the size. The result is a VALUE: memoised here (a failure is
+    not), read by no other function of this module; call once from engine
     init (TPU backends only — never inside a trace).
     """
-    global _PROBED_BLOCK
-    if _PROBED_BLOCK is not None:
-        return _PROBED_BLOCK
     if jax.default_backend() == "cpu":
-        _PROBED_BLOCK = 0
         return 0
     import logging
 
@@ -114,7 +110,6 @@ def probe_block_size(max_block: int = 2048, probe_t: int = 2048) -> int:
             )(q)
             jax.block_until_ready(out)
             log.info("splash block edge probed: %d", b)
-            _PROBED_BLOCK = b
             return b
         except Exception as e:  # noqa: BLE001 — mosaic raises various types
             log.warning(
@@ -177,39 +172,40 @@ def resolve_attn_impl(
     return attn_impl
 
 
-def flash_window(longest: int, tokens_per_mb: int) -> int:
-    """The PACKING window: the kernel's block-sparse local window for a
-    batch whose longest sequence is ``longest``, in power-of-two buckets
-    from 1024 (full causal over a long packed stream of short sequences is
-    T² block iteration; sequences only need their own length). It is never
-    shorter than ``longest``, so it is exact by construction: it removes
-    only pairs the segment ids mask anyway, and the engine asserts so before
-    it hands one to a program (``SPMDTrainEngine._attn_window``). Not to be
-    confused with a LAYER's window (``RotaryConfig.window``, shorter than
-    the sequences: it changes the result and is the model's), which
-    ``flash_segment_attention`` takes as ``layer_window`` and which this
-    function knows nothing of. 0 = full causal, which is also
-    what a window above a quarter of a whole microbatch (``tokens_per_mb``)
-    gets: every (pad bucket, window) pair is one more grad program to
-    compile and to load, a window saves only whole tiles (``_block_sizes``),
-    and at half a 4096-token row that was 7% of the core's time against 22%
-    at a quarter (v5e, PERF.md PR 26)."""
-    w = 1024
-    while w < longest:
-        w *= 2
-    return w if 4 * w <= tokens_per_mb else 0
+class Instance(NamedTuple):
+    """Which attention a row runs (``instance``): ``kind`` "" the XLA form's
+    row | "strip" the band's own kernels | "splash"; ``back``, the keys behind
+    a query its static mask keeps beside the query's own (0: causal);
+    ``edge``, the tile edge (the strip kernels' query block; 0 under XLA);
+    ``fused``, the splash kernel's one backward kernel or its two."""
+
+    kind: str
+    back: int
+    edge: int
+    fused: bool
 
 
-def _block_sizes(
-    t: int, window: int, d: int, band: bool = False
-) -> "_sk.BlockSizes":
-    """The kernel's tiles, from the shape it is given. Measured on the v5e
-    with ``tools/microbench_attn_v2.py`` on the cells' own packed rows under
-    the rows' tile mask (``_row_kernel``: an empty tile is free, so a smaller
-    edge drops more and costs more a tile), forward + backward ms a layer,
-    the fused backward, ``block_kv_compute`` 512 (PERF.md, PR 42, has the
-    whole table; in brackets the same kernel without the tile mask, which at
-    today's edge is PR 41's):
+def instance(
+    t: int, d: int, layer_window: int = 0, block_cap: int = 0,
+    interpret: bool = False,
+) -> Instance:
+    """THE rule: which kernel instance a row of ``t`` tokens with heads ``d``
+    wide runs in a layer whose window is ``layer_window`` keys, the query's
+    own among them (0: a full-attention layer), under ``block_cap``, the
+    largest edge the backend ran (``probe_block_size``; 0: uncapped).
+    ``interpret``: Pallas interpret mode, the CPU's tests. Every threshold
+    of this file is here. A layer's window reaches ``layer_window - 1`` keys
+    back and is the one local mask there is: a band, unless it reaches over
+    the whole row (plain causal). A row's own sequences need no mask of
+    their own: the rows' run-time tile mask (``tiles_needed``) drops every
+    tile no sequence needs.
+
+    Measured on the v5e with ``tools/microbench_attn_v2.py`` on the cells'
+    own packed rows under the rows' tile mask (``_row_kernel``: an empty tile
+    is free, so a smaller edge drops more and costs more a tile), forward +
+    backward ms a layer, the fused backward, ``block_kv_compute`` 512
+    (PERF.md, PR 42, has the whole table; in brackets the same kernel
+    without the tile mask, which at today's edge is PR 41's):
 
     ====== =============== ============= ============= =============
     T      heads           edge 512      edge 1024     edge 2048
@@ -229,97 +225,73 @@ def _block_sizes(
 
     - so edge 512 to T = 2048 (dense heads +8.6%, the 16 of 128 -0.7%), 1024
       to T = 8192 (192 | 128 paid 20% at 2048), 2048 above: right for heads
-      of 128; wider ones want 1024 (A.21, A.22; "a / b": fused / SPLIT bwd);
-    - a local window skips only whole tiles, so a PACKING window of at most
-      1024 takes edge 512 (1.39 against 1.44 ms at T = 4096, PR 26); a
-      LAYER's window (``band``: the caller says so, ``_local_mask``) that
-      comes here at all (``_strip_block``: not 128-wide heads on a TPU)
-      takes ``BAND_EDGE`` and the split backward (above; PERF.md, PR 39 and
-      PR 45, have the sweeps at 64/8 and 32/4 heads of 128; the rows' tile
-      mask is worth 1.7% there, 25.28 against 25.72 ms);
-    - past T = 16384 the SPLIT backward, full causal and packing windows
-      alike: the fused one's partial dq, [T / edge, heads, T, d] in bf16, is
-      4.3 GB a layer at T = 32768, edge 2048 and 32 heads of 128, and
-      ``grad_step`` of the cell that has such rows then reads 16.8 GB with
-      its moments (arguments 2.72 + temporaries 11.34 + 2.72; the chip gives
-      a program 16.9) against 14.4 GB split. Forward + backward ms a layer
-      on that cell's rows, 32/4 heads of 128 (PERF.md, PR 45): edge 2048
-      fused 134.4, split 158.7; edge 1024 fused 151.9, split 173.1: so edge
-      2048 stays, and the split costs 18% of the full layer's core there;
-    - ``block_kv_compute`` 512 rather than the edge (the smaller compute
-      tile overlaps the kv DMA; 256 loses), and the FUSED dq+dkv backward:
-      one pass over the data, 10-25% under the split kernels everywhere
-      (PR 26).
+      of 128; wider ones want 1024 (A.21, A.22; "a / b": fused / SPLIT bwd),
+      which this rule does not read yet: ``d`` only says a band's kernels;
+    - a band that comes to the splash kernel at all (not 128-wide heads on a
+      TPU: ``band_attention.strip_block``) takes ``BAND_EDGE`` and the split
+      backward (above; PERF.md, PR 39 and PR 45, have the sweeps at 64/8 and
+      32/4 heads of 128; the rows' tile mask is worth 1.7% there, 25.28
+      against 25.72 ms);
+    - past T = 16384 the SPLIT backward: the fused one's partial dq,
+      [T / edge, heads, T, d] in bf16, is 4.3 GB a layer at T = 32768, edge
+      2048 and 32 heads of 128, and ``grad_step`` of the cell that has such
+      rows then reads 16.8 GB with its moments (arguments 2.72 + temporaries
+      11.34 + 2.72; the chip gives a program 16.9) against 14.4 GB split.
+      Forward + backward ms a layer on that cell's rows, 32/4 heads of 128
+      (PERF.md, PR 45): edge 2048 fused 134.4, split 158.7; edge 1024 fused
+      151.9, split 173.1: so edge 2048 stays, and the split costs 18% of the
+      full layer's core there;
+    - ``block_kv_compute`` 512 rather than the edge (``_tiles``: the smaller
+      compute tile overlaps the kv DMA; 256 loses), and the FUSED dq+dkv
+      backward: one pass over the data, 10-25% under the split kernels
+      everywhere (PR 26).
 
-    Never above what ``probe_block_size`` found, always a divisor of ``t``."""
-    if band:
+    The edge is never above ``block_cap``, always a divisor of ``t``."""
+    if t % _LANES:
+        # not whole tiles of 128 tokens: short, its quadratic scores small
+        return Instance("", 0, 0, False)
+    back = layer_window - 1 if 0 < layer_window - 1 < t else 0
+    if back:
+        if interpret or jax.default_backend() == "tpu":
+            # the band's own kernels (``ops/band_attention.py``) where they
+            # take the shape: heads a multiple of 128 wide, a row of blocks
+            bq = band_attention.strip_block(t, back, d)
+            if bq:
+                return Instance("strip", back, bq, False)
         want, fused = BAND_EDGE, False
     else:
-        want = (
-            512 if 0 < window <= 1024 or t <= 2048
-            else 1024 if t <= 8192 else 2048
-        )
+        want = 512 if t <= 2048 else 1024 if t <= 8192 else 2048
         fused = t <= 16384
-    want = min(want, _PROBED_BLOCK or want)
+    want = min(want, block_cap or want)
     edge = _LANES
     while edge * 2 <= min(want, t) and t % (edge * 2) == 0:
         edge *= 2
-    return _tiles(edge, fused=fused)
+    return Instance("splash", back, edge, fused)
 
 
-def _local_mask(t: int, window: int, layer_window: int) -> "tuple[int, bool]":
-    """(keys back, is it a layer's band) of the kernel's local mask on a row
-    of ``t`` tokens under packing ``window`` and a layer's ``layer_window``
-    (0 = none, each): the layer's reaches ``layer_window - 1`` keys back
-    beside the query's own, and the shorter of the two is the mask; (0,
-    False), plain causal, where that reaches over the whole row. The KIND
-    is said here and carried to ``_block_sizes``, never read back from the
-    size: a packing window of 1024 keys and a layer's of 1024 are one key
-    apart."""
-    band = bool(layer_window) and (not window or layer_window - 1 <= window)
-    if band:
-        window = layer_window - 1
-    if not 0 < window < t:  # reaches over the whole row: causal
-        return 0, False
-    return int(window), band
-
-
-def backward_form(t: int, window: int, d: int, layer_window: int = 0) -> str:
-    """"fused" | "split" | "strip": the backward a row of ``t`` tokens takes
-    under packing ``window`` and a layer's ``layer_window`` (what a trainer's
-    dispatch span says of each core): the splash kernel's one kernel or its
-    two, or the band's own; "" for a row the XLA form takes."""
-    if t % _LANES:
-        return ""
-    back, band = _local_mask(t, window, layer_window)
-    if _strip_block(t, back, band, d):
-        return "strip"
-    fused = _block_sizes(t, back, d, band).use_fused_bwd_kernel
-    return "fused" if fused else "split"
+def backward_form(t: int, d: int, layer_window: int = 0) -> str:
+    """"fused" | "split" | "strip": the backward ``instance`` gives a row of
+    ``t`` tokens in a layer of ``layer_window`` (what a trainer's dispatch
+    span says of each core): the splash kernel's one kernel or its two, or
+    the band's own; "" for a row the XLA form takes."""
+    inst = instance(t, d, layer_window)
+    if inst.kind != "splash":
+        return inst.kind
+    return "fused" if inst.fused else "split"
 
 
 @functools.lru_cache(maxsize=32)
-def _make_kernel(
-    t: int, rep: int, window: int, d: int, interpret: bool,
-    band: bool = False,
-):
-    """The kernel of a row of ``t`` tokens under a local mask ``window`` keys
-    back (0: causal). ``band``: the mask is a window-attention LAYER's, not
-    the packing's; the mask is the same, the tiles and the backward are the
-    kind's (``_block_sizes``)."""
+def _make_kernel(t: int, rep: int, inst: Instance, interpret: bool):
+    """The splash kernel of ``inst`` (``instance``'s) on a row of ``t``
+    tokens, ``rep`` query heads a key head."""
     # ensure_compile_time_eval: this may be reached inside a jit trace, but
     # the kernel object (and the mask arrays it processes) must be concrete —
     # it is cached across traces, and a tracer captured here would escape.
     with jax.ensure_compile_time_eval():
-        if 0 < window < t:
-            # block-sparse local mask: a packed stream of many short
-            # sequences must NOT pay full-causal T² block iteration — any
-            # same-segment pair is within (max segment length - 1)
-            # positions, so a causal local window >= that bound plus the
-            # runtime segment-id mask is exact. A window-attention layer's
-            # band is the same mask, ``window`` = its keys less the query's
-            # own (511, 1023), under the same run-time segment ids
-            head = _sm.LocalMask((t, t), (window, 0), 0)
+        if inst.back:
+            # a window-attention layer's band: ``back`` = its keys less the
+            # query's own (511, 1023), under the run-time segment ids
+            head = _sm.LocalMask((t, t), (inst.back, 0), 0)
         else:
             head = _sm.CausalMask((t, t))
         mask = _sm.MultiHeadMask([head for _ in range(rep)])
@@ -327,7 +299,7 @@ def _make_kernel(
         # saving "attn_out" skips the forward-kernel recompute in the
         # backward (what models/transformer.apply's remat saves)
         return _sk.make_splash_mqa_single_device(
-            mask, block_sizes=_block_sizes(t, window, d, band),
+            mask, block_sizes=_tiles(inst.edge, inst.fused),
             residual_checkpoint_name="attn_out", interpret=interpret,
         )
 
@@ -354,30 +326,20 @@ def tiles_needed(segment_ids, edge: int, xp=jnp):
     return xp.tril(shared, -1) | xp.eye(n, dtype=bool)
 
 
-def tiles_in_window(n: int, edge: int, window: int) -> np.ndarray:
-    """The tiles of an ``n`` x ``n`` grid the kernel's STATIC mask keeps:
-    on and under the diagonal, and, under a local ``window`` (keys back,
-    beside the query's own), those whose nearest pair is inside it."""
-    back = np.arange(n)[:, None] - np.arange(n)[None, :]
-    near = (back - 1) * edge + 1  # a block's first query to the last key
-    return (back >= 0) & ((near <= window) | (window <= 0))
-
-
-def tile_counts(segment_ids, window: int, d: int) -> "tuple[int, int]":
+def tile_counts(
+    segment_ids, d: int, block_cap: int = 0
+) -> "tuple[int, int]":
     """What the engine counts a dispatch: the tiles on and under the
     diagonal of packed rows ``segment_ids`` [rows, T] (numpy, on the host)
-    at the edge ``_block_sizes`` gives such a row under packing ``window``,
-    and those of them the kernel computes: inside the static window, and
-    needed by a sequence of the row. (0, 0) for a row the XLA form takes."""
+    at the edge ``instance`` gives such a row in a full-attention layer
+    under ``block_cap``, and those of them the kernel computes: the ones a
+    sequence of the row needs. (0, 0) for a row the XLA form takes."""
     t = segment_ids.shape[-1]
-    if t % _LANES:
+    inst = instance(t, d, block_cap=block_cap)
+    if not inst.kind:
         return 0, 0
-    window = window if 0 < window < t else 0  # as ``_make_kernel`` reads it
-    edge = _block_sizes(t, window, d).block_q
-    n = t // edge
-    kept = tiles_needed(segment_ids, edge, np) & tiles_in_window(
-        n, edge, window
-    )
+    n = t // inst.edge
+    kept = tiles_needed(segment_ids, inst.edge, np)
     return len(segment_ids) * n * (n + 1) // 2, int(kept.sum())
 
 
@@ -417,8 +379,8 @@ def _lay_over(info, needed, kv_major: bool):
 
 @jax.jit
 def _row_kernel(kernel, seg_row):
-    """``kernel`` (``_make_kernel``'s, static: causal, a packing window or a
-    layer's band) for ONE row: the same ``kwargs``, with the tiles that no
+    """``kernel`` (``_make_kernel``'s, static: causal or a layer's band) for
+    ONE row: the same ``kwargs``, with the tiles that no
     sequence of ``seg_row`` [T] needs laid over its static tile masks, the
     forward's and the backward's alike (``tiles_needed``, at the one edge
     ``_tiles`` gives them all). It removes only pairs the run-time segment
@@ -447,11 +409,10 @@ def flash_segment_attention(
     v: jnp.ndarray,
     segment_ids: jnp.ndarray,  # [B, T]
     causal: bool = True,
-    # the PACKING window (``flash_window``): 0 = full causal; else a bound
-    # on the row's longest sequence, >= max segment length, so the result
-    # is full causal attention all the same
-    window: int = 0,
     interpret: bool = False,  # Pallas interpret mode: the CPU's tests
+    # the largest tile edge the backend ran (``probe_block_size``, handed
+    # down by the engine through ``attention_fn``); 0 = uncapped
+    block_cap: int = 0,
     # a window-attention LAYER's: a query sees its ``layer_window`` latest
     # keys, its own among them (0 = no window). Shorter than the sequences:
     # it changes the result, and is the model's, not the engine's
@@ -459,23 +420,27 @@ def flash_segment_attention(
 ) -> jnp.ndarray:
     """Drop-in replacement for ops.basic.segment_attention on TPU. ``v``
     may be narrower than ``q`` and ``k`` (latent attention's 192 | 128): the
-    kernel takes the value width from ``v``, nothing is padded. The kernel's
-    local mask reaches ``window`` keys back (the packing's, beside the
-    query's own) or ``layer_window - 1`` (the layer's), the shorter where
-    both apply."""
+    kernel takes the value width from ``v``, nothing is padded. Which kernel
+    instance the rows run is ``instance``'s to say."""
     assert causal, "splash path is causal-only (decoder models)"
-    b, t, hq, d = q.shape
-    if t % _LANES:
-        # not whole tiles of 128 tokens: short, its quadratic scores small
+    t, d = q.shape[1], q.shape[3]
+    inst = instance(t, d, layer_window, block_cap, interpret)
+    if not inst.kind:
         return segment_attention(
             q, k, v, segment_ids, causal=True, window=layer_window
         )
-    window, band = _local_mask(t, window, layer_window)
+    run = _strip_band if inst.kind == "strip" else _splash
+    return run(q, k, v, segment_ids, inst, interpret)
+
+
+def _splash(q, k, v, segment_ids, inst: Instance, interpret: bool):
+    """The splash kernel of ``inst`` on packed rows, under each row's own
+    tile mask: [B, T, H, D] to the kernel's [H, T, D] grouped by key head
+    and back, the queries scaled, padding's rows 0."""
+    b, t, hq, d = q.shape
     hkv = k.shape[2]
     rep = hq // hkv
-    if hq == rep * hkv and _strip_block(t, window, band, d, interpret):
-        return _strip_band(q, k, v, segment_ids, window, interpret)
-    kernel = _make_kernel(t, rep, window, d, interpret, band)
+    kernel = _make_kernel(t, rep, inst, interpret)
     scale = d**-0.5
     qg = (q.astype(jnp.float32) * scale).astype(q.dtype)
     qg = qg.transpose(0, 2, 1, 3).reshape(b, hkv, rep, t, d)
@@ -493,28 +458,15 @@ def flash_segment_attention(
     return jnp.where(valid, out, 0).astype(q.dtype)
 
 
-def _strip_block(
-    t: int, back: int, band: bool, d: int, interpret: bool = False
-) -> int:
-    """The query block of the band's own kernels (``ops/band_attention.py``)
-    where a call takes them, else 0: a layer's band, on a TPU (or in Pallas
-    interpret mode: the CPU's tests), of a shape those kernels take
-    (``band_attention.strip_block``: heads a multiple of 128 wide, a row that
-    is a whole number of blocks). Every other call keeps the splash kernel."""
-    if not band or not (interpret or jax.default_backend() == "tpu"):
-        return 0
-    return band_attention.strip_block(t, back, d)
-
-
-def _strip_band(q, k, v, segment_ids, back: int, interpret: bool):
-    """A layer's band ``back`` keys behind a query through its own kernels:
-    q, k, v as the projections made them, [T, heads x D], a reshape either
-    side; ``out`` and logsumexp named ``attn_out`` there as the splash
-    kernel names its own."""
+def _strip_band(q, k, v, segment_ids, inst: Instance, interpret: bool):
+    """A layer's band ``inst.back`` keys behind a query through its own
+    kernels (``instance`` said "strip"): q, k, v as the projections made
+    them, [T, heads x D], a reshape either side; ``out`` and logsumexp named
+    ``attn_out`` there as the splash kernel names its own."""
     b, t, hq, d = q.shape
     hkv = k.shape[2]
-    bq = band_attention.strip_block(t, back, d)
     return band_attention.band_attention(
         q.reshape(b, t, hq * d), k.reshape(b, t, hkv * d),
-        v.reshape(b, t, hkv * d), segment_ids, (hq, hkv), back, bq, interpret,
+        v.reshape(b, t, hkv * d), segment_ids, (hq, hkv), inst.back,
+        inst.edge, interpret,
     ).reshape(q.shape)

@@ -21,7 +21,7 @@ are written as per-shard functions to be wrapped in `shard_map` (see
 mesh: XLA still shards heads over "tensor" inside the shard_map body.
 
 `attention_fn` is the one place that turns a resolved attention name
-("flash" | "ring" | "ulysses" | "xla"), a mesh and a window into the
+("flash" | "ring" | "ulysses" | "xla"), a mesh and the tiles' cap into the
 callable `transformer.apply` takes: each kernel's mesh form lives here,
 beside the kernels, and a train engine only asks for it.
 """
@@ -241,11 +241,12 @@ def make_sharded_attention(
     return attend
 
 
-def attention_fn(impl: str, mesh: Mesh, window: int = 0, topk: int = 0):
+def attention_fn(impl: str, mesh: Mesh, block_cap: int = 0, topk: int = 0):
     """The attention callable ``attend(q, k, v, segment_ids)`` for a
     resolved name (``ops.flash.resolve_attn_impl``) on ``mesh``: "flash"
-    (the Pallas splash kernel with its packing ``window``, TPU-only; it
-    also takes ``layer_window=`` from a window-attention layer),
+    (the Pallas splash kernel, TPU-only, its tiles no larger than
+    ``block_cap``, what ``ops.flash.probe_block_size`` found to run, 0 =
+    uncapped; it also takes ``layer_window=`` from a window-attention layer),
     "ring"/"ulysses" (the explicit sequence-parallel ``shard_map``),
     "sparse_topk" (a model with an indexer: ``topk`` selected keys a query,
     ``ops.sparse_attention.make_attend``, which also takes the indexer's
@@ -260,7 +261,7 @@ def attention_fn(impl: str, mesh: Mesh, window: int = 0, topk: int = 0):
         return make_sharded_attention(mesh, impl=impl)
     from areal_tpu.ops.flash import flash_segment_attention
 
-    attend = functools.partial(flash_segment_attention, window=window)
+    attend = functools.partial(flash_segment_attention, block_cap=block_cap)
     if mesh.size == 1:
         # (a window-attention layer calls it with ``layer_window=``)
         return attend

@@ -1,7 +1,8 @@
 """``TrainEngineConfig.attn_impl`` resolved once at ``initialize()`` from what
 the process observes (backend, mesh, the model's head dimension), and read
-from there by the window, the kernel override (``ops.ring_attention.
-attention_fn``, where each kernel's mesh form lives) and the dispatch spans.
+from there by the kernel override (``ops.ring_attention.attention_fn``, where
+each kernel's mesh form lives; the probed cap on its tiles goes with it, as a
+value) and the dispatch spans.
 
 The backend is the CPU here; "reported as a TPU" means ``jax.default_backend``
 is steered in the test (and the block probe with it: it would run the kernel).
@@ -98,7 +99,7 @@ def test_auto_on_the_cpu_backend_is_the_xla_form(probes):
     eng = _engine()
     assert eng.attn_impl == "xla"
     assert attention_fn(eng.attn_impl, eng.mesh) is None
-    assert eng._attn_window(_batch([700, 30])) == 0
+    assert eng._forward_values()["attend_fn"] is None
     assert eng.splash_block is None and not probes
 
 
@@ -106,10 +107,10 @@ def test_auto_on_a_tpu_is_the_kernel_on_one_device(as_tpu):
     eng = _engine()
     assert eng.attn_impl == "flash"
     assert eng.splash_block == 1024 and len(as_tpu) == 1
-    attend = attention_fn(eng.attn_impl, eng.mesh, 2048)
+    attend = eng._forward_values()["attend_fn"]  # with the engine's own cap
     assert isinstance(attend, functools.partial)
     assert attend.func is flash.flash_segment_attention
-    assert attend.keywords == {"window": 2048}
+    assert attend.keywords == {"block_cap": 1024}
 
 
 def test_auto_on_a_tpu_mesh_is_the_kernel_under_shard_map(
@@ -128,9 +129,9 @@ def test_auto_on_a_tpu_mesh_is_the_kernel_under_shard_map(
     monkeypatch.setattr(jax, "shard_map", shard_map)
     # the shard_map is made where the layer calls (a window-attention layer
     # hands its own window in there)
-    assert attention_fn(eng.attn_impl, eng.mesh, 0)(*"qkvs") == "sharded"
+    assert eng._forward_values()["attend_fn"](*"qkvs") == "sharded"
     assert seen["fn"].func is flash.flash_segment_attention
-    assert seen["fn"].keywords == {"window": 0, "layer_window": 0}
+    assert seen["fn"].keywords == {"block_cap": 1024, "layer_window": 0}
     assert seen["mesh"] is eng.mesh
     # rows over (data, fsdp), heads over tensor, the sequence whole
     assert tuple(seen["out_specs"]) == (("data", "fsdp"), None, "tensor", None)
@@ -168,26 +169,81 @@ def test_explicit_flash_on_the_cpu_backend_keeps_its_name_unprobed(probes):
 
 
 @pytest.mark.parametrize(
-    "longest,cap,window",
-    [
-        pytest.param(30, 16384, 1024, id="short-floor"),
-        pytest.param(1024, 16384, 1024, id="edge"),
-        pytest.param(1025, 16384, 2048, id="next-bucket"),
-        pytest.param(3000, 16384, 4096, id="quarter-of-the-cap"),
-        pytest.param(5000, 16384, 0, id="half-the-cap-is-full-causal"),
-        pytest.param(9000, 16384, 0, id="cap-is-full-causal"),
-        pytest.param(900, 4096, 1024, id="cell-cap-quarter"),
-        pytest.param(1500, 4096, 0, id="cell-cap-half-is-full-causal"),
-    ],
+    "caps", [(512, 2048), (2048, 512), (1024, 1024), (512, 1024, 2048)],
+    ids=lambda caps: "-".join(map(str, caps)),
 )
-def test_flash_window_buckets(as_tpu, longest, cap, window):
-    assert flash.flash_window(longest, cap) == window
-    # and the engine hands the rule its batch's longest sequence and its cap
-    eng = _engine(cap=cap)
-    assert eng._attn_window(_batch([longest, 17])) == window
+def test_engines_in_one_process_keep_their_own_caps(monkeypatch, caps):
+    """The probed cap is the engine's value, handed down with its attention:
+    an engine that probes later (another backend state, another answer)
+    moves no other engine's tiles. At the parent the cap was a module global
+    the last probe wrote."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    engines = []
+    for cap in caps:
+        monkeypatch.setattr(flash, "probe_block_size", lambda cap=cap: cap)
+        engines.append(_engine())
+    row = np.ones((1, 4096), np.int32)  # one sequence: the whole triangle
+    for eng, cap in zip(engines, caps):
+        assert eng.splash_block == cap
+        assert eng._forward_values()["attend_fn"].keywords == {
+            "block_cap": cap
+        }
+        n = 4096 // min(cap, 1024)  # the rule's 1024 at this row, capped
+        assert eng._count_attn_tiles(row) == {
+            "attn_tiles": n * (n + 1) // 2, "attn_tiles_kept": n * (n + 1) // 2
+        }
 
 
-def test_dispatch_spans_carry_the_resolved_name_and_the_window(seen):
+# batches of ONE row length whose longest sequences fell in different
+# buckets of the packing window PR 55 had (1024 | full causal at a 2048-token
+# row), each of which was a program of its own on the kernel's path
+_ONE_ROW_LENGTH = [
+    pytest.param(2048, [[600, 700, 500], [1500, 300], [1100, 900]],
+                 id="row2048"),
+    pytest.param(2048, [[30, 17, 1024], [1025, 900]],
+                 id="row2048-bucket-edges"),
+]
+
+
+@pytest.mark.parametrize("row_len,batches", _ONE_ROW_LENGTH)
+def test_one_row_length_is_one_program_whatever_the_sequences(
+    as_tpu, monkeypatch, seen, row_len, batches
+):
+    """``grad_step`` and ``logp_forward`` are keyed by what the caller handed
+    in and traced once a row length. (The kernel is the chip's: the programs
+    here run the XLA form under the resolved name.)"""
+    import areal_tpu.engine.spmd_engine as spmd
+
+    monkeypatch.setattr(spmd, "attention_fn", lambda *a, **kw: None)
+    eng = _engine(cap=16384)
+    assert eng.attn_impl == "flash"
+    traces = []
+
+    def loss_fn(*a, **kw):  # runs when ``grad_step`` is traced, only then
+        traces.append(1)
+        return sft_loss_fn(*a, **kw)
+
+    for i, lens in enumerate(batches):
+        batch = _batch(lens)
+        eng.train_batch(batch, loss_fn, sft_loss_weight_fn)
+        eng.forward(batch)
+        if i == 0:
+            first = len(traces)
+    assert len(traces) == first > 0  # the first batch's trace serves all
+    assert set(eng._jit_cache) == {
+        "zeros", "apply", ("grad", loss_fn, sft_loss_weight_fn),
+        ("fwd", spmd.target_aligned_logprobs),
+    }
+    logp_fn = eng._jit_cache["fwd", spmd.target_aligned_logprobs]
+    assert logp_fn._cache_size() == 1
+    # one microbatch a batch: a grad, an apply and a logp dispatch each
+    spans = [a for n, a in seen if n.endswith("_dispatch")]
+    assert len(spans) == 3 * len(batches)
+    assert not any("window" in a for a in spans)
+    assert eng.counters()["padded_tokens"] == 2 * len(batches) * row_len
+
+
+def test_dispatch_spans_carry_the_resolved_name_and_no_window(seen):
     eng = _engine()
     batch = _batch([9, 5, 7])
     eng.train_batch(batch, sft_loss_fn, sft_loss_weight_fn)
@@ -196,7 +252,7 @@ def test_dispatch_spans_carry_the_resolved_name_and_the_window(seen):
         attrs = [a for n, a in seen if n == name]
         assert attrs, name
         assert all(
-            a["attn_impl"] == "xla" and a["window"] == 0 for a in attrs
+            a["attn_impl"] == "xla" and "window" not in a for a in attrs
         ), attrs
 
 
@@ -205,14 +261,13 @@ def test_flash_dispatches_count_the_tiles_their_rows_keep(
 ):
     """Where ``attn_impl`` is ``flash`` the engine counts, on the host and by
     ``ops.flash.tile_counts``, the tiles of every dispatched row and those
-    the kernel computes: in ``counters()``, and on the dispatch spans beside
-    ``window``. The kernel itself is the chip's; the programs here run the
+    the kernel computes: in ``counters()``, and on the dispatch spans. The
+    kernel itself is the chip's; the programs here run the
     XLA form under the resolved name."""
     import areal_tpu.engine.spmd_engine as spmd
 
     monkeypatch.setattr(spmd, "attention_fn", lambda *a, **kw: None)
-    monkeypatch.setattr(flash, "_PROBED_BLOCK", 512)  # 2048-token rows: 4 x 4
-    eng = _engine()
+    eng = _engine()  # 2048-token rows: 4 x 4 tiles of 512
     assert eng.attn_impl == "flash"
     assert eng.counters() == {
         "real_tokens": 0, "padded_tokens": 0,
@@ -231,9 +286,7 @@ def test_flash_dispatches_count_the_tiles_their_rows_keep(
     assert eng.counters()["attn_tiles_kept"] == 14
     for name in ("train.grad_dispatch", "train.logp_dispatch"):
         (attrs,) = [a for n, a in seen if n == name]
-        # (the packing window, 1024 over a longest sequence of 700, keeps
-        # every tile but (3, 0), which the borders empty too)
-        assert attrs["attn_impl"] == "flash" and attrs["window"] == 1024
+        assert attrs["attn_impl"] == "flash" and "window" not in attrs
         assert (attrs["attn_tiles"], attrs["attn_tiles_kept"]) == (10, 7)
 
 

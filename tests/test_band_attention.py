@@ -25,7 +25,6 @@ D = 128
 @pytest.fixture(autouse=True)
 def strips_of_128(monkeypatch):
     monkeypatch.setattr(BA, "STRIP_BQ", 128)
-    monkeypatch.setattr(F, "_PROBED_BLOCK", 128)  # the splash kernel's tiles
     F._make_kernel.cache_clear()
     yield
     F._make_kernel.cache_clear()
@@ -89,8 +88,10 @@ def test_the_strip_kernels_match_xla_segment_attention(
     rows, t, heads, window, dtype, tol
 ):
     hq, hkv = heads
-    assert F.backward_form(t, 0, D, window) == "split"  # the CPU's, unasked
-    assert F._strip_block(t, window - 1, True, D, interpret=True) == 128
+    assert F.backward_form(t, D, window) == "split"  # the CPU's, unasked
+    assert F.instance(t, D, window, interpret=True) == (
+        "strip", window - 1, 128, False
+    )
     ks = jax.random.split(jax.random.PRNGKey(len(rows[0]) + window), 4)
     b = len(rows)
     q = jax.random.normal(ks[0], (b, t, hq, D), jnp.float32).astype(dtype)
@@ -133,62 +134,62 @@ def test_the_strip_kernels_are_the_splash_kernels_numbers(monkeypatch):
     def run():
         return _out_and_grads(
             lambda *a: F.flash_segment_attention(
-                *a, layer_window=window, interpret=True
+                *a, layer_window=window, block_cap=128, interpret=True
             ),
             q, k, v, seg, cot,
         )
 
     strip = run()
-    monkeypatch.setattr(F, "_strip_block", lambda *a, **kw: 0)
+    monkeypatch.setattr(BA, "strip_block", lambda *a: 0)  # takes no shape
+    assert F.instance(t, D, window, 128, True) == (
+        "splash", window - 1, 128, False
+    )
     split = run()
     for a, b in zip(strip, split):
         np.testing.assert_allclose(a, b, atol=2e-5)
 
 
-# (T, packing window, head width, layer's window) -> the form on a TPU
+# (T, head width, layer's window) -> the form on a TPU
 _FORMS = [
-    (16384, 0, 128, 512, "strip"),
-    (2048, 0, 128, 512, "strip"),  # the shortest rung of the cell's rows
-    (32768, 0, 128, 1024, "strip"),
-    (32768, 8192, 128, 1024, "strip"),  # under a longer packing window
-    (16384, 0, 256, 512, "strip"),
-    (16384, 0, 64, 512, "split"),  # half a lane row a head
-    (16384, 0, 192, 512, "split"),
-    (32768, 1024, 128, 4096, "split"),  # the packing's mask, the shorter
-    (512, 0, 128, 1024, "fused"),  # the window reaches over the row: causal
-    (16384, 0, 128, 0, "fused"),
-    (32768, 0, 128, 0, "split"),
-    (16384, 1024, 128, 0, "fused"),
-    (200, 0, 128, 512, ""),  # the XLA form's row
+    (16384, 128, 512, "strip"),
+    (2048, 128, 512, "strip"),  # the shortest rung of the cell's rows
+    (32768, 128, 1024, "strip"),
+    (8192, 128, 1024, "strip"),
+    (16384, 256, 512, "strip"),
+    (16384, 64, 512, "split"),  # half a lane row a head
+    (16384, 192, 512, "split"),
+    (32768, 128, 4096, "strip"),  # a band whatever its size
+    (512, 128, 1024, "fused"),  # the window reaches over the row: causal
+    (16384, 128, 0, "fused"),
+    (32768, 128, 0, "split"),
+    (8192, 128, 0, "fused"),
+    (200, 128, 512, ""),  # the XLA form's row
 ]
 
 
-@pytest.mark.parametrize("t,window,d,layer_window,form", _FORMS)
+@pytest.mark.parametrize("t,d,layer_window,form", _FORMS)
 def test_backward_form_says_strip_where_the_rule_takes_the_kernel(
-    monkeypatch, t, window, d, layer_window, form
+    monkeypatch, t, d, layer_window, form
 ):
-    monkeypatch.setattr(F, "_PROBED_BLOCK", 2048)
-    was = F.backward_form(t, window, d, layer_window)  # on the CPU: splash's
+    was = F.backward_form(t, d, layer_window)  # on the CPU: splash's
     assert was == ("split" if form == "strip" else form)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert F.backward_form(t, window, d, layer_window) == form
-    back, band = F._local_mask(t, window, layer_window) if t % 128 == 0 else (
-        0, False
+    assert F.backward_form(t, d, layer_window) == form
+    assert (F.instance(t, d, layer_window).kind == "strip") == (
+        form == "strip"
     )
-    assert bool(F._strip_block(t, back, band, d)) == (form == "strip")
     # a row that is no whole number of strips keeps the splash backward
     monkeypatch.setattr(BA, "STRIP_BQ", 3 * 128)
     if form == "strip":
-        assert F.backward_form(t, window, d, layer_window) == "split"
+        assert F.backward_form(t, d, layer_window) == "split"
 
 
-@pytest.mark.parametrize("model,row_len,window", [
-    ("laguna_xsp2", 16384, 0), ("laguna_xsp2", 2048, 0),
-    ("laguna_xsp2", 8192, 2048), ("mellum2_12b_a2p5b", 32768, 0),
-    ("mellum2_12b_a2p5b", 16384, 4096),
+@pytest.mark.parametrize("model,row_len", [
+    ("laguna_xsp2", 16384), ("laguna_xsp2", 2048), ("laguna_xsp2", 8192),
+    ("mellum2_12b_a2p5b", 32768), ("mellum2_12b_a2p5b", 16384),
 ])
 def test_a_grad_dispatch_on_a_tpu_says_strip_for_the_two_window_families(
-    monkeypatch, model, row_len, window
+    monkeypatch, model, row_len
 ):
     """What ``train.grad_dispatch`` carries as ``attn_bwd_window`` at the
     two cells' row lengths: the counter of how often the band's own kernels
@@ -196,12 +197,11 @@ def test_a_grad_dispatch_on_a_tpu_says_strip_for_the_two_window_families(
     from areal_tpu.models import transformer as tr
     from areal_tpu.parallel.feasibility import MODEL_CONFIGS
 
-    monkeypatch.setattr(F, "_PROBED_BLOCK", 2048)
     cfg = MODEL_CONFIGS[model]()
-    on_cpu = tr.grad_span_attrs(cfg, "flash", row_len, window)
+    on_cpu = tr.grad_span_attrs(cfg, "flash", row_len)
     assert on_cpu["attn_bwd_window"] == "split"
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    on_tpu = tr.grad_span_attrs(cfg, "flash", row_len, window)
+    on_tpu = tr.grad_span_attrs(cfg, "flash", row_len)
     assert on_tpu["attn_bwd_window"] == "strip"
     assert on_tpu["attn_bwd_full"] == on_cpu["attn_bwd_full"]
-    assert tr.grad_span_attrs(cfg, "xla", row_len, window) == {}
+    assert tr.grad_span_attrs(cfg, "xla", row_len) == {}

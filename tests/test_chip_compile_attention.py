@@ -57,46 +57,40 @@ def test_paged_decode_kernel_compiles_for_v5e(
     assert "tpu_custom_call" in compiled.as_text()
 
 
-# (head_dim, q heads, T, window): the benchmark cell's packed row (Qwen2-0.5B,
-# 4096 tokens, full causal and the 2048 window its minibatches bucket to),
-# the recipe's 16384-token row, Qwen2-1.5B's heads
+# (head_dim, q heads, T, the edge): the benchmark cell's packed row
+# (Qwen2-0.5B, 4096 tokens), the recipe's 16384-token row, Qwen2-1.5B's heads
 _SPLASH_CASES = [
-    pytest.param(64, 14, 4096, 0, 1024, id="d64-t4096-causal"),
-    pytest.param(64, 14, 4096, 2048, 1024, id="d64-t4096-window2048"),
-    pytest.param(64, 14, 4096, 1024, 512, id="d64-t4096-window1024"),
-    pytest.param(64, 14, 16384, 0, 2048, id="d64-t16384-causal"),
-    pytest.param(128, 12, 4096, 0, 1024, id="d128-t4096-causal"),
-    pytest.param(128, 12, 2048, 0, 512, id="d128-t2048-causal"),
+    pytest.param(64, 14, 4096, 1024, id="d64-t4096-causal"),
+    pytest.param(64, 14, 16384, 2048, id="d64-t16384-causal"),
+    pytest.param(128, 12, 4096, 1024, id="d128-t4096-causal"),
+    pytest.param(128, 12, 2048, 512, id="d128-t2048-causal"),
     # the shorter rung a train step's last microbatch runs at since PR 30,
     # at the two cells' heads (OLMoE: 16 of 128)
     # (edge 512 there and 1024 at 8192 since PR 42: the sweep under the
     # rows' tile mask)
-    pytest.param(64, 14, 2048, 0, 512, id="d64-t2048-causal"),
-    pytest.param(128, 16, 2048, 0, 512, id="d128-h16-t2048-causal"),
-    pytest.param(128, 8, 8192, 0, 1024, id="d128-h8-t8192-causal"),
+    pytest.param(64, 14, 2048, 512, id="d64-t2048-causal"),
+    pytest.param(128, 16, 2048, 512, id="d128-h16-t2048-causal"),
+    pytest.param(128, 8, 8192, 1024, id="d128-h8-t8192-causal"),
 ]
 
 
-@pytest.mark.parametrize("d,hq,t,window,edge", _SPLASH_CASES)
-def test_splash_fwd_bwd_compiles_for_v5e(
-    one_chip, monkeypatch, d, hq, t, window, edge
-):
-    """fwd+bwd of the trainer's attention with the tiles ``_block_sizes``
+@pytest.mark.parametrize("d,hq,t,edge", _SPLASH_CASES)
+def test_splash_fwd_bwd_compiles_for_v5e(one_chip, d, hq, t, edge):
+    """fwd+bwd of the trainer's attention with the tiles ``flash.instance``
     chooses on a chip whose scoped-VMEM limit was raised (the probe reads
     2048 there; ``jax.default_backend()`` is the CPU here, so the cap is
-    set in the test rather than probed)."""
+    handed in by the test as the engine hands in what it probed)."""
     import jax
     import jax.numpy as jnp
 
     from areal_tpu.ops import flash
 
-    monkeypatch.setattr(flash, "_PROBED_BLOCK", 2048)
     flash._make_kernel.cache_clear()
     hkv = 2
 
     def loss(q, k, v, seg):
         return flash.flash_segment_attention(
-            q, k, v, seg, window=window
+            q, k, v, seg, block_cap=2048
         ).astype(jnp.float32).sum()
 
     try:
@@ -111,10 +105,8 @@ def test_splash_fwd_bwd_compiles_for_v5e(
             .compile()
         )
     finally:
-        # the kernel objects cached under the steered cap must not leak
-        # into other tests of this worker
         flash._make_kernel.cache_clear()
-    assert flash._block_sizes(t, window, d).block_q == edge
+    assert flash.instance(t, d, block_cap=2048).edge == edge
     assert "tpu_custom_call" in compiled.as_text()
 
 
@@ -141,7 +133,7 @@ def test_the_bands_own_kernels_compile_for_v5e(
     from areal_tpu.ops import flash
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert flash.backward_form(t, 0, 128, layer_window) == "strip"
+    assert flash.backward_form(t, 128, layer_window) == "strip"
     bf = jnp.bfloat16
 
     def loss(q, k, v, seg):
@@ -189,7 +181,7 @@ def test_the_probes_first_candidate_compiles_for_v5e(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_latent_attention_core_compiles_for_v5e(one_chip, monkeypatch):
+def test_latent_attention_core_compiles_for_v5e(one_chip):
     """The splash kernel at a query/key width of 192 over a value width of
     128, 32 heads each with its own keys, on an 8192-token row, forward and
     backward: the width pair ``ops/flash.takes_head_dim`` admits."""
@@ -198,14 +190,13 @@ def test_latent_attention_core_compiles_for_v5e(one_chip, monkeypatch):
 
     from areal_tpu.ops import flash
 
-    monkeypatch.setattr(flash, "_PROBED_BLOCK", 2048)
     flash._make_kernel.cache_clear()
     t, bf = 8192, jnp.bfloat16
 
     def loss(q, k, v, seg):
-        return flash.flash_segment_attention(q, k, v, seg).astype(
-            jnp.float32
-        ).sum()
+        return flash.flash_segment_attention(
+            q, k, v, seg, block_cap=2048
+        ).astype(jnp.float32).sum()
 
     try:
         compiled = (

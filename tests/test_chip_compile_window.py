@@ -28,7 +28,7 @@ _LAGUNA_CASES = [
 
 @pytest.mark.parametrize("hq,hkv,t,layer_window,band", _LAGUNA_CASES)
 def test_window_and_full_cores_compile_for_v5e(
-    one_chip, monkeypatch, hq, hkv, t, layer_window, band
+    one_chip, hq, hkv, t, layer_window, band
 ):
     """fwd+bwd of both attention cores of the two window families at the
     published heads: the band's kernel instance is its own (``LocalMask``
@@ -39,13 +39,12 @@ def test_window_and_full_cores_compile_for_v5e(
 
     from areal_tpu.ops import flash
 
-    monkeypatch.setattr(flash, "_PROBED_BLOCK", 2048)
     flash._make_kernel.cache_clear()
     bf = jnp.bfloat16
 
     def loss(q, k, v, seg):
         return flash.flash_segment_attention(
-            q, k, v, seg, layer_window=layer_window
+            q, k, v, seg, block_cap=2048, layer_window=layer_window
         ).astype(jnp.float32).sum()
 
     try:
@@ -58,13 +57,13 @@ def test_window_and_full_cores_compile_for_v5e(
         compiled = lowered.compile()
     finally:
         flash._make_kernel.cache_clear()
-    tiles = flash._block_sizes(
-        t, layer_window - 1 if band else 0, 128, band
-    )
+    inst = flash.instance(t, 128, layer_window, 2048)
     full_edge = 2048 if t > 8192 else 1024
-    assert tiles.block_q == (flash.BAND_EDGE if band else full_edge)
+    assert inst.kind == "splash"  # the CPU's: a TPU sends a band to strips
+    assert inst.back == (layer_window - 1 if band else 0)
+    assert inst.edge == (flash.BAND_EDGE if band else full_edge)
     split = band or t > 16384
-    assert tiles.use_fused_bwd_kernel == (not split)
+    assert inst.fused == (not split)
     text = lowered.as_text()
     assert ("splash_mqa_dq" in text) == split  # the split backward's own dq
     assert "tpu_custom_call" in compiled.as_text()

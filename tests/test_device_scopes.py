@@ -93,7 +93,7 @@ def _lower_grad_step(eng, actor, arrays, **values):
     whatever scopes are patched in), with ``values`` over the engine's own
     (``compute_dtype=``)."""
     grad_fn = programs.build_grad_step(
-        **dict(eng._forward_values(0), **values), remat=True,
+        **dict(eng._forward_values(), **values), remat=True,
         loss_fn=actor._loss_fn, loss_weight_fn=_ppo_loss_weight_fn,
     )
     return grad_fn.lower(eng.params, eng._zero_grads(), arrays)

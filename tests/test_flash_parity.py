@@ -2,7 +2,7 @@
 (``ops/flash.flash_segment_attention`` vs ``ops/basic.segment_attention``):
 outputs and the gradients of q, k and v, with the kernel in Pallas interpret
 mode on the CPU. Ragged segments with tail padding, Qwen2-0.5B's 14/2 heads
-of 64; full causal and a local window smaller than the row; one tile per row
+of 64; full causal and a layer's band smaller than the row; one tile per row
 and several (the block-skipping paths). Since PR 42 the kernel also skips the
 tiles no sequence of a row needs (``ops/flash.tiles_needed`` laid over the
 static mask): rows of 512 tokens in 4 x 4 tiles of 128 with borders of every
@@ -61,19 +61,23 @@ def _out_and_grads(attend, q, k, v, seg, cot):
     return [np.asarray(x, np.float32) for x in (out, *grads)]
 
 
-# window 0 = full causal; a local window must cover the longest segment
+# (cap on the edge or None, rows' sequence lengths, dtype, tol, keywords);
+# a band of 129 keys (128 back) over sequences it covers and one it does not
 _CASES = [
-    pytest.param(0, None, [[100, 60, 70]], jnp.float32, 3e-5, id="causal-f32"),
-    pytest.param(0, 128, [[100, 60, 70], [31, 190]], jnp.float32, 3e-5,
+    pytest.param(None, [[100, 60, 70]], jnp.float32, 3e-5, {},
+                 id="causal-f32"),
+    pytest.param(128, [[100, 60, 70], [31, 190]], jnp.float32, 3e-5, {},
                  id="causal-f32-edge128-rows2"),
-    pytest.param(128, 128, [[120, 37, 64]], jnp.float32, 3e-5,
-                 id="window128-f32-edge128"),
-    pytest.param(128, None, [[128, 5, 90]], jnp.float32, 3e-5,
-                 id="window128-f32"),
-    pytest.param(0, 128, [[100, 60, 70]], jnp.bfloat16, 4e-2,
+    pytest.param(128, [[120, 37, 64]], jnp.float32, 3e-5,
+                 dict(layer_window=129), id="band129-f32-edge128"),
+    pytest.param(None, [[128, 5, 90]], jnp.float32, 3e-5,
+                 dict(layer_window=129), id="band129-f32"),
+    pytest.param(128, [[100, 60, 70]], jnp.bfloat16, 4e-2, {},
                  id="causal-bf16-edge128"),
-    pytest.param(128, 128, [[120, 37, 64]], jnp.bfloat16, 4e-2,
-                 id="window128-bf16-edge128"),
+    pytest.param(128, [[120, 37, 64]], jnp.bfloat16, 4e-2,
+                 dict(layer_window=129), id="band129-bf16-edge128"),
+    pytest.param(128, [[200, 30]], jnp.float32, 3e-5,
+                 dict(layer_window=129), id="band129-f32-cuts-a-sequence"),
 ]
 
 # rows of 512 tokens in 4 x 4 tiles (PR 42: the tiles a row's borders empty).
@@ -126,33 +130,29 @@ def _held_to_xla(q, k, v, seg, cot, tol, **kw):
 
 
 @pytest.mark.parametrize(
-    "window,edge_cap,lens,dtype,tol,shape",
-    [pytest.param(*p.values, {}, id=p.id) for p in _CASES] + [
-        pytest.param(0, 128, *p.values[:3], dict(p.values[3], t=T4), id=p.id)
+    "edge_cap,lens,dtype,tol,shape",
+    list(_CASES) + [
+        pytest.param(128, *p.values[:3], dict(p.values[3], t=T4), id=p.id)
         for p in _TILE_CASES
     ],
 )
 def test_splash_matches_xla_segment_attention(
-    monkeypatch, window, edge_cap, lens, dtype, tol, shape
+    monkeypatch, edge_cap, lens, dtype, tol, shape
 ):
-    monkeypatch.setattr(F, "_PROBED_BLOCK", edge_cap)
     shape = dict(shape)
     t, lw = shape.pop("t", T), shape.pop("layer_window", 0)
-    tiles = F._block_sizes(t, lw - 1 if lw else window, D)
-    assert tiles.block_q == (edge_cap or t)
+    cap = dict(block_cap=edge_cap or 0, layer_window=lw)
+    inst = F.instance(t, D, interpret=True, **cap)
+    assert inst[:3] == ("splash", lw - 1 if lw else 0, edge_cap or t)
     q, k, v, seg, cot = _inputs(dtype, [_row(n, t) for n in lens], **shape)
-    got = _held_to_xla(
-        q, k, v, seg, cot, tol, window=window, layer_window=lw
-    )
+    got = _held_to_xla(q, k, v, seg, cot, tol, **cap)
     if all(len(n) == 1 for n in lens):
         # a row that is one sequence keeps every tile: the kernel without
         # the rows' tile mask, to the bit, forward and gradients
         monkeypatch.setattr(F, "_row_kernel", lambda kernel, seg_row: kernel)
         F._make_kernel.cache_clear()
         static = _out_and_grads(
-            lambda *a: F.flash_segment_attention(
-                *a, window=window, layer_window=lw, interpret=True
-            ),
+            lambda *a: F.flash_segment_attention(*a, interpret=True, **cap),
             q, k, v, seg, cot,
         )
         for a, b in zip(got, static):
@@ -167,10 +167,16 @@ def test_a_row_shorter_than_a_tile_takes_the_xla_form():
     k = jax.random.normal(ks[1], (1, t, HKV, D), jnp.bfloat16)
     v = jax.random.normal(ks[2], (1, t, HKV, D), jnp.bfloat16)
     seg = jnp.asarray(_row([40, 30], t)[None])
-    got = F.flash_segment_attention(q, k, v, seg, window=64)
+    got = F.flash_segment_attention(q, k, v, seg)
     want = segment_attention(q, k, v, seg)
     assert F._make_kernel.cache_info().currsize == 0
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    # and a layer's window goes with it to the XLA form
+    got = F.flash_segment_attention(q, k, v, seg, layer_window=24)
+    want = segment_attention(q, k, v, seg, window=24)
+    assert F._make_kernel.cache_info().currsize == 0
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert np.abs(np.asarray(got - segment_attention(q, k, v, seg))).max() > 0
 
 
 def _random_row(rng, t):
@@ -211,31 +217,35 @@ def test_the_tile_rule_against_brute_force(edge):
     np.testing.assert_array_equal(F.tiles_needed(rows[7], edge, np), needed[7])
 
 
-@pytest.mark.parametrize("window", [0, 128, 256, 300])
-def test_laid_over_mask_infos_walk_the_tiles_the_count_counts(
-    monkeypatch, window
-):
+@pytest.mark.parametrize("back", [0, 128, 256, 300])
+def test_laid_over_mask_infos_walk_the_tiles_the_count_counts(back):
     """The three mask infos of a row's kernel (forward, dq, dkv; the grid
-    shrunk to a window's band or not) turn on exactly the tiles
-    ``tile_counts`` counts as kept; a step that is on names its own block,
-    one that is off the block of the next step that is on."""
-    monkeypatch.setattr(F, "_PROBED_BLOCK", 128)
+    shrunk to a layer's band ``back`` keys behind a query, or not) turn on
+    exactly the tiles the static mask has and the row needs, which under the
+    causal mask are the ones ``tile_counts`` counts as kept; a step that is
+    on names its own block, one that is off the block of the next step that
+    is on."""
     t, edge = 1024, 128
     seg = _row([300, 90, 410, 130], t)
+    needed = F.tiles_needed(seg, edge, np)
     for fused in (True, False):
-        monkeypatch.setattr(
-            F, "_block_sizes", lambda *_, fused=fused: F._tiles(edge, fused)
+        static = F._make_kernel(
+            t, 2, F.Instance("splash", back, edge, fused), True
         )
-        F._make_kernel.cache_clear()
-        static = F._make_kernel(t, 2, window, D, True)
         row = F._row_kernel(static, jnp.asarray(seg))
-        tiles, kept = F.tile_counts(seg[None], window, D)
-        # the static mask's tiles are the window's; the borders leave 19 of
-        # the triangle's 36 (17 of the 28 under the diagonal are off)
-        in_window = int((np.asarray(static.fwd_mask_info.block_mask) > 0).sum())
-        assert in_window == F.tiles_in_window(8, edge, window).sum()
-        assert tiles == 36 and kept <= min(19, in_window)
-        assert kept == 19 or window
+        # the borders leave 19 of the triangle's 36 (17 of the 28 under the
+        # diagonal are off); a band leaves those of them its static mask has
+        in_band = np.asarray(static.fwd_mask_info.block_mask[0]) > 0
+        if back:  # the band's grid is shrunk: by ``data_next``, the kv block
+            kv = np.asarray(static.fwd_mask_info.data_next[0])
+            kept = sum(
+                bool(needed[i, kv[i, j]]) for i, j in zip(*np.nonzero(in_band))
+            )
+            assert 8 <= kept <= 19
+        else:
+            assert in_band.sum() == 36
+            kept = 19
+            assert F.tile_counts(seg[None], D, edge) == (36, kept)
         assert (row.dq_mask_info is None) == fused
         for info, kv_major in (
             (row.fwd_mask_info, False), (row.dq_mask_info, False),
@@ -253,7 +263,6 @@ def test_laid_over_mask_infos_walk_the_tiles_the_count_counts(
             # each (query block, kv block) once, and only needed ones
             pairs = set(zip(q_or_kv[0].tolist(), walked.tolist()))
             assert len(pairs) == kept
-            needed = F.tiles_needed(seg, edge, np)
             for a, b in pairs:
                 assert needed[(b, a) if kv_major else (a, b)]
             flat_on, flat = on.reshape(-1), there.reshape(-1)

@@ -2,7 +2,7 @@
 compile failure and the refusal when no large edge compiles — the probe
 exists because a scoped-VMEM flag that did not take silently lost the fast
 path, so its failure behavior is itself load-bearing — and the tiles
-``_block_sizes`` chooses from the shape under the probed cap."""
+``instance`` chooses from the shape under the cap it is handed."""
 
 import pytest
 
@@ -13,12 +13,9 @@ from areal_tpu.ops import flash as F
 
 @pytest.fixture(autouse=True)
 def reset_probe():
-    prev = F._PROBED_BLOCK
-    F._PROBED_BLOCK = None
-    F._make_kernel.cache_clear()
+    F.probe_block_size.cache_clear()
     yield
-    F._PROBED_BLOCK = prev
-    F._make_kernel.cache_clear()
+    F.probe_block_size.cache_clear()
 
 
 def test_cpu_backend_has_nothing_to_cap():
@@ -54,18 +51,24 @@ def test_fallback_cascade_on_compile_failure(monkeypatch):
     )
     assert F.probe_block_size() == 512
     assert attempts == [2048, 1024, 512]
-    assert F.probe_block_size() == 512 and len(attempts) == 3  # cached
+    assert F.probe_block_size() == 512 and len(attempts) == 3  # memoised
+    # and the tiles of a caller that was handed no cap stay the rule's own:
+    # the probe's answer is a value, not a state of the module
+    assert F.instance(16384, 128).edge == 2048
 
     # total failure: every candidate raises -> an error that carries the
-    # compiler's words, and nothing cached (a retry probes again)
-    F._PROBED_BLOCK = None
+    # compiler's words, and nothing memoised (a retry probes again)
+    F.probe_block_size.cache_clear()
     monkeypatch.setattr(
         F._sk, "make_splash_mqa_single_device",
         _refusing_above(0, attempts, "RESOURCE_EXHAUSTED: vmem says no"),
     )
     with pytest.raises(RuntimeError, match="vmem says no"):
         F.probe_block_size()
-    assert F._PROBED_BLOCK is None
+    n = len(attempts)
+    with pytest.raises(RuntimeError, match="vmem says no"):
+        F.probe_block_size()
+    assert len(attempts) == n + 3  # 2048, 1024, 512 again
 
 
 @pytest.mark.parametrize(
@@ -77,30 +80,31 @@ def test_head_dimensions_the_trainer_hands_to_the_kernel(d, taken):
     assert F.takes_head_dim(d) is taken
 
 
-# (T, window, probed cap or None) -> the edge of every tile
+# (T, the cap handed in, 0 = none) -> the edge of every tile
 _EDGES = [
-    pytest.param(4096, 0, 2048, 1024, id="cell-row-full-causal"),
-    pytest.param(4096, 2048, 2048, 1024, id="cell-row-window2048"),
-    pytest.param(4096, 1024, 2048, 512, id="window1024-takes-512"),
-    pytest.param(16384, 0, 2048, 2048, id="recipe-row"),
-    pytest.param(16384, 0, 1024, 1024, id="recipe-row-capped-by-probe"),
-    pytest.param(16384, 4096, None, 2048, id="unprobed-is-uncapped"),
-    pytest.param(15360, 0, 2048, 1024, id="edge-divides-the-row"),
-    pytest.param(8192, 0, 2048, 1024, id="8192-takes-1024"),
-    pytest.param(2048, 0, 2048, 512, id="short-rung-takes-512"),
-    pytest.param(32768, 0, 2048, 2048, id="past-16384-takes-2048-split"),
-    pytest.param(1536, 0, 2048, 512, id="short-row-divisor"),
-    pytest.param(256, 0, 0, 256, id="cpu-cap-0-is-no-cap"),
-    pytest.param(384, 0, 2048, 128, id="odd-multiple-of-128"),
+    pytest.param(4096, 2048, 1024, id="cell-row-full-causal"),
+    pytest.param(16384, 2048, 2048, id="recipe-row"),
+    pytest.param(16384, 1024, 1024, id="recipe-row-capped-by-probe"),
+    pytest.param(16384, 0, 2048, id="no-cap-is-uncapped"),
+    pytest.param(15360, 2048, 1024, id="edge-divides-the-row"),
+    pytest.param(8192, 2048, 1024, id="8192-takes-1024"),
+    pytest.param(2048, 2048, 512, id="short-rung-takes-512"),
+    pytest.param(32768, 2048, 2048, id="past-16384-takes-2048-split"),
+    pytest.param(1536, 2048, 512, id="short-row-divisor"),
+    pytest.param(256, 0, 256, id="cpu-cap-0-is-no-cap"),
+    pytest.param(384, 2048, 128, id="odd-multiple-of-128"),
+    pytest.param(4096, 512, 512, id="cell-row-capped-at-512"),
+    pytest.param(32768, 1024, 1024, id="long-row-capped-at-1024"),
 ]
 
 
-@pytest.mark.parametrize("t,window,cap,edge", _EDGES)
-def test_tiles_follow_the_shape_under_the_probed_cap(t, window, cap, edge):
-    F._PROBED_BLOCK = cap
-    tiles = F._block_sizes(t, window, 64)
-    assert tiles == F._block_sizes(t, window, 128)  # head dim moves nothing
+@pytest.mark.parametrize("t,cap,edge", _EDGES)
+def test_tiles_follow_the_shape_under_the_cap_handed_in(t, cap, edge):
+    inst = F.instance(t, 64, block_cap=cap)
+    assert inst == F.instance(t, 128, block_cap=cap)  # head dim moves nothing
+    assert inst == ("splash", 0, edge, t <= 16384) and t % edge == 0
+    tiles = F._tiles(inst.edge, inst.fused)
     assert {tiles.block_q, tiles.block_kv, tiles.block_q_dkv,
             tiles.block_kv_dkv} == {edge}
     assert tiles.block_kv_compute == tiles.block_kv_dkv_compute == min(512, edge)
-    assert tiles.use_fused_bwd_kernel == (t <= 16384) and t % edge == 0
+    assert tiles.use_fused_bwd_kernel == inst.fused

@@ -299,35 +299,26 @@ def test_the_refusals_by_what_the_window_declares(tiny, tmp_path):
     fb.check_refusals(FAMILY, tiny, tmp_path, "B.3", "mellum", "B.3")
 
 
-@pytest.mark.parametrize("attn_impl,row_len,window,want", [
-    ("flash", 32768, 0, {"attn_bwd_window": "split", "attn_bwd_full": None}),
-    ("flash", 32768, 8192, {"attn_bwd_window": "split",
-                            "attn_bwd_full": None}),
-    ("flash", 16384, 0, {"attn_bwd_window": "split",
-                         "attn_bwd_full": "fused"}),
-    ("xla", 32768, 0, {}),
+@pytest.mark.parametrize("attn_impl,row_len,want", [
+    ("flash", 32768, {"attn_bwd_window": "split", "attn_bwd_full": "split"}),
+    ("flash", 16384, {"attn_bwd_window": "split", "attn_bwd_full": "fused"}),
+    ("flash", 2048, {"attn_bwd_window": "split", "attn_bwd_full": "fused"}),
+    ("xla", 32768, {}),
 ])
 def test_a_grad_dispatch_says_which_backward_each_core_took(
-    attn_impl, row_len, window, want
+    attn_impl, row_len, want
 ):
     from areal_tpu.ops import flash
     from areal_tpu.parallel.feasibility import MODEL_CONFIGS
 
     cfg = MODEL_CONFIGS["mellum2_12b_a2p5b"]()
-    got = tr.grad_span_attrs(cfg, attn_impl, row_len, window)
-    assert set(got) == set(want)
-    for key, form in want.items():
-        assert got[key] in ("split", "fused")
-        if form:
-            assert got[key] == form
-    if got:  # what the kernel instance of that row is built with
-        tiles = flash._block_sizes(row_len, 1023, 128, True)
-        assert (got["attn_bwd_window"] == "fused") == (
-            tiles.use_fused_bwd_kernel
-        )
+    assert tr.grad_span_attrs(cfg, attn_impl, row_len) == want
+    if want:  # what the kernel instance of that row is built with
+        inst = flash.instance(row_len, 128, cfg.swa.window)
+        assert inst == ("splash", 1023, flash.BAND_EDGE, False)
     # a model without window layers says nothing
     assert tr.grad_span_attrs(
-        tiny_config("qwen2"), attn_impl, row_len, window
+        tiny_config("qwen2"), attn_impl, row_len
     ) == {}
 
 
@@ -343,7 +334,7 @@ def test_the_trainer_trains_the_family_and_counts_its_pairs_exactly(trained):
     for name in ("train.grad_dispatch", "train.logp_dispatch"):
         attrs = trained.attrs(name)
         assert all(
-            a["layer_window"] == 8 and a["window"] == 0 for a in attrs
+            a["layer_window"] == 8 and "window" not in a for a in attrs
         ), (name, attrs)
         # the XLA form has no backward forms to name
         assert not any("attn_bwd_window" in a for a in attrs)

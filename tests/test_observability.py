@@ -405,22 +405,27 @@ class TestProfileEndpoint:
             out = json.loads(r.read())
         assert out["success"] and out["steps"] == 2
         assert out["trace_dir"].startswith(out_dir)
-        # drive busy iterations so the capture opens and closes
-        _generate(eng, "rid-profiled", max_new=4)
-        deadline = __import__("time").monotonic() + 30
-        while eng._profile_stack is not None or eng._profile_pending:
-            assert __import__("time").monotonic() < deadline
-            __import__("time").sleep(0.05)
+
+        def run_out(rid):
+            # drive busy iterations so the capture opens and closes
+            _generate(eng, rid, max_new=4)
+            deadline = __import__("time").monotonic() + 30
+            while eng._profile_stack is not None or eng._profile_pending:
+                assert __import__("time").monotonic() < deadline
+                __import__("time").sleep(0.05)
+
+        run_out("rid-profiled")
         # engine still serves, and a second capture can be armed
         _generate(eng, "rid-after-profile", max_new=2)
 
-        # double-arm while pending is an explicit error
-        eng._profile_pending = (1, None)
-        try:
-            with pytest.raises(RuntimeError):
-                eng.request_profile(1)
-        finally:
-            eng._profile_pending = None
+        # double-arm is an explicit error: a REAL capture is armed (the
+        # engine is idle, and idle iterations end none, so it is pending or
+        # running until the next request), never a fake planted on the
+        # live engine, which its loop thread could pick up and run
+        eng.request_profile(1, out_dir=str(tmp_path / "prof2"))
+        with pytest.raises(RuntimeError):
+            eng.request_profile(1)
+        run_out("rid-profiled-again")
 
         # gated server: 403, nothing armed
         httpd = serve(

@@ -1,6 +1,6 @@
 """The trainer's attention core alone, on the chip: ``ops/flash.py``'s wrapper
 (relayouts included) against the XLA ``segment_attention``, forward and
-forward + backward, over block edges, backward forms and windows, on packed
+forward + backward, over block edges and backward forms, on packed
 rows of the benchmark's own GRPO batches (``benchmark/traffic``).
 
     python -m tools.microbench_attn_v2 [--t 4096] [--heads 14 2 64] \
@@ -11,7 +11,7 @@ A window-attention layer's band (PERF.md, PR 39: the tile table of
 that holds it, at both of its row lengths and both kinds' heads:
 
     python -m tools.microbench_attn_v2 --t 16384 --heads 64 8 128 \
-        --traffic grpo_steps16_mixed16k --layer-window 512 --windows 0 \
+        --traffic grpo_steps16_mixed16k --layer-window 512 \
         --edges 256 512 --no-xla
     ... --t 8192 ...;  ... --heads 48 8 128 --layer-window 0 --edges 1024 2048
 
@@ -26,7 +26,7 @@ runs no splash kernel), beside the splash kernel's split backward, on the two
 cells' rows; ``chosen`` is what the trainer runs:
 
     python -m tools.microbench_attn_v2 --t 16384 --heads 64 8 128 \
-        --traffic grpo_steps16_mixed16k --layer-window 512 --windows 0 \
+        --traffic grpo_steps16_mixed16k --layer-window 512 \
         --edges 512 --kvc 512 --backward split strip --bq 128 256 512 --no-xla
     ... --t 32768 --heads 32 4 128 --traffic grpo_steps16_long32k \
         --layer-window 1024 ...
@@ -35,10 +35,10 @@ The tile mask of a row's own borders (PERF.md, PR 42: ``ops/flash._row_kernel``)
 is on in every variant, as in the trainer; ``--static`` also times each variant
 WITHOUT it (``<name>_static``: the kernel as ``_make_kernel`` builds it), and
 the head line says what share of the tiles the rows keep at each edge. The
-sweep ``_block_sizes``' table is from, a cell's rows each (``--rows 8``):
+sweep ``ops/flash.instance``'s table is from, a cell's rows each (``--rows 8``):
 
     python -m tools.microbench_attn_v2 --t 4096 --heads 14 2 64 --edges 512 \
-        1024 --windows 0 --backward fused --kvc 512 --rows 8 --static --no-xla
+        1024 --backward fused --kvc 512 --rows 8 --static --no-xla
     ... --t 4096 --heads 16 16 128 --traffic grpo_steps64_fsdp4
     ... --t 8192 --heads 32 32 192 --dv 128 --traffic grpo_steps16_packed8k \
         --edges 1024 2048
@@ -50,11 +50,14 @@ one ``jax.profiler`` capture; the times are device times of those programs
 from ``benchmark.program_trace`` (what ``attn_time_share.train`` is read by),
 not host clocks. (The persistent compile cache keys a program without its
 name: two variants that lower to the same program run under the first one's
-name, and are marked.) ``ops/flash._block_sizes`` holds what this found (PERF.md,
-PR 26); run it again when the kernel, the chip or the shape changes.
+name, and are marked.) ``ops/flash.instance`` holds what this found (PERF.md,
+PR 26); run it again when the kernel, the chip or the shape changes. A variant
+is an explicit ``flash.Instance`` handed to the wrapper's two halves
+(``flash._splash``, ``flash._strip_band``); the rule itself is never swapped.
 """
 
 import argparse
+import dataclasses
 import functools
 import itertools
 import json
@@ -66,8 +69,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from areal_tpu.ops import band_attention, flash
-from areal_tpu.ops.flash import _sk as sk
+from areal_tpu.ops import flash
 from areal_tpu.ops.basic import segment_attention
 from areal_tpu.utils import data as data_utils
 from areal_tpu.utils import tracing
@@ -93,24 +95,27 @@ def packed_rows(t, traffic="grpo_steps32", n=2):
     ][:n]
 
 
-def variants(t, windows, edges, backward, kvcs, bqs):
-    """(name, packing window, the splash kernel's tiles or None for the
-    shape's own, the strip kernels' query block or 0 for the splash kernel)."""
-    for edge, window, fused, kvc in itertools.product(
-        edges, windows, [b == "fused" for b in backward if b != "strip"], kvcs
+def variants(edges, backward, kvcs, bqs):
+    """(name, kind, the tile edge or the strip kernels' query block, fused,
+    ``block_kv_compute``): the splash kernel at each edge, backward and
+    compute tile, then the band's own kernels at each query block."""
+    for edge, fused, kvc in itertools.product(
+        edges, [b == "fused" for b in backward if b != "strip"], kvcs
     ):
         if kvc > edge or (kvc == 256 and edge > 512):
             continue
-        name = f"e{edge}_w{window or 'full'}_{'fused' if fused else 'split'}_c{kvc}"
-        dq = {} if fused else dict(block_q_dq=edge, block_kv_dq=edge)
-        yield name, window, sk.BlockSizes(
-            block_q=edge, block_kv=edge, block_kv_compute=kvc,
-            block_q_dkv=edge, block_kv_dkv=edge, block_kv_dkv_compute=kvc,
-            use_fused_bwd_kernel=fused, **dq,
-        ), 0
+        name = f"e{edge}_{'fused' if fused else 'split'}_c{kvc}"
+        yield name, "splash", edge, fused, kvc
     if "strip" in backward:
         for bq in bqs:
-            yield f"strip_bq{bq}", 0, None, bq
+            yield f"strip_bq{bq}", "strip", bq, False, 0
+
+
+def tiles_with(kvc, rule=flash._tiles):
+    """``flash._tiles`` with another ``block_kv_compute`` than the rule's."""
+    return lambda edge, fused=True: dataclasses.replace(
+        rule(edge, fused), block_kv_compute=kvc, block_kv_dkv_compute=kvc
+    )
 
 
 def main():
@@ -118,7 +123,6 @@ def main():
     ap.add_argument("--t", type=int, default=4096)
     ap.add_argument("--heads", type=int, nargs=3, default=(14, 2, 64),
                     metavar=("HQ", "HKV", "D"))
-    ap.add_argument("--windows", type=int, nargs="*", default=(0, 2048, 1024))
     ap.add_argument("--edges", type=int, nargs="*", default=(512, 1024, 2048))
     ap.add_argument("--out", default="chiprun_out/attn_sweep")
     ap.add_argument("--traffic", default="grpo_steps32",
@@ -174,33 +178,29 @@ def main():
         return jax.jit(fwd), jax.jit(grad)
 
     todo = []
-    chosen, row_kernel = flash._block_sizes, flash._row_kernel
-    strip_block, strip_bq = flash._strip_block, band_attention.STRIP_BQ
-    swept = list(variants(
-        t, args.windows, args.edges, args.backward, args.kvc, args.bq
-    ))
-    named = ([] if args.no_xla else [("xla", 0, None, 0)]) + swept + [
-        (name + "_static", window, tiles, bq)
-        for name, window, tiles, bq in (swept if args.static else [])
-    ] + [("chosen", 0, None, None)]
-    for name, window, tiles, bq in named:
-        flash._block_sizes = (
-            (lambda *_, tiles=tiles, **__: tiles) if tiles else chosen
-        )
-        # a swept splash variant stays on the splash kernel; ``chosen`` the rule
-        flash._strip_block = strip_block if bq != 0 else (lambda *_, **__: 0)
-        band_attention.STRIP_BQ = bq or strip_bq
+    rule_tiles, row_kernel = flash._tiles, flash._row_kernel
+    chosen = flash.instance(t, d, lw, flash.probe_block_size())
+    print("chosen:", chosen, flush=True)
+    swept = list(variants(args.edges, args.backward, args.kvc, args.bq))
+    named = ([] if args.no_xla else [("xla", "", 0, False, 0)]) + swept + [
+        (name + "_static", *rest)
+        for name, *rest in (swept if args.static else [])
+    ] + [("chosen", chosen.kind, chosen.edge, chosen.fused, 0)]
+    for name, kind, edge, fused, kvc in named:
+        # the instance said outright, with the rule's mask; a compute tile
+        # and a kernel without the rows' mask are not the rule's to say
+        inst = chosen._replace(kind=kind, edge=edge, fused=fused)
+        flash._tiles = tiles_with(kvc) if kvc else rule_tiles
         flash._row_kernel = (
             (lambda kernel, seg: kernel) if name.endswith("_static")
             else row_kernel
         )
         flash._make_kernel.cache_clear()
+        run = flash._strip_band if kind == "strip" else flash._splash
         fns = programs(
             name,
             functools.partial(segment_attention, window=lw) if name == "xla"
-            else functools.partial(
-                flash.flash_segment_attention, window=window, layer_window=lw
-            ),
+            else functools.partial(run, inst=inst, interpret=False),
         )
         try:  # trace + compile now, while this variant's tiles are in place
             for fn in fns:
@@ -209,19 +209,16 @@ def main():
         except Exception as e:  # noqa: BLE001 — mosaic raises various types
             print(f"{name}: refused ({type(e).__name__}: {str(e)[:160]})",
                   flush=True)
-    flash._block_sizes, flash._row_kernel = chosen, row_kernel
-    flash._strip_block, band_attention.STRIP_BQ = strip_block, strip_bq
+    flash._tiles, flash._row_kernel = rule_tiles, row_kernel
     flash._make_kernel.cache_clear()
     ref, masked = None, {}
     # warm; hold the kernel to the XLA form, and the static kernel to the
     # one under the rows' tile mask (which must be the same to the bit)
     for name, fwd, grad in todo:
         outs = [fwd(q, k, v, segs[0]), *grad(q, k, v, segs[0])]
-        unwindowed = "full" in name or name.startswith(("strip", "chosen"))
-        if ref is None and (name == "xla" or unwindowed):
-            # the XLA form, or without it the first variant of the same mask
+        if ref is None:  # the XLA form, or without it the first variant
             ref, ref_name = [np.asarray(x, np.float32) for x in outs], name
-        elif ref and unwindowed:
+        else:
             print(name, f"max |delta| to {ref_name}'s out, dq, dk, dv:",
                   [float(np.abs(np.asarray(a, np.float32) - b).max())
                    for a, b in zip(outs, ref)], flush=True)

@@ -160,11 +160,12 @@ def trainer_programs(
 def cell_programs(model: str, tokens: int, fsdp: int = 1, param_dtype=None,
                   devices=None) -> dict:
     """``trainer_programs`` of ``feasibility.MODEL_CONFIGS[model]`` on the
-    first ``fsdp`` of ``devices``, a row of ``tokens`` each, the attention a
-    TPU resolves to. ``devices`` None: a described ``v5e:2x2`` host's, with
-    the ops' platform rules steered to a TPU's for the process's life."""
+    first ``fsdp`` of ``devices``, a row of ``tokens`` each (a cell's
+    programs are one ``grad_step`` and one ``logp_forward`` a row length,
+    whatever its sequences), the attention a TPU resolves to. ``devices``
+    None: a described ``v5e:2x2`` host's, with the ops' platform rules
+    steered to a TPU's for the process's life."""
     from areal_tpu.api.cli_args import ParallelismConfig
-    from areal_tpu.ops import flash
     from areal_tpu.ops.ring_attention import attention_fn
     from areal_tpu.parallel import feasibility
     from areal_tpu.parallel import mesh as mesh_lib
@@ -178,13 +179,13 @@ def cell_programs(model: str, tokens: int, fsdp: int = 1, param_dtype=None,
         jax.default_backend = lambda: "tpu"  # the ops' platform rules
         # the devices the cell's process holds (``ops/rotary.rotate_impl``)
         jax.device_count = lambda: fsdp
-        flash._PROBED_BLOCK = 2048  # what the chip's probe settles on
     mc = feasibility.MODEL_CONFIGS[model]()
     mesh = mesh_lib.make_mesh(
         ParallelismConfig(fsdp_parallel_size=fsdp), devices[:fsdp]
     )
     attend = attention_fn(
         "sparse_topk" if mc.has_indexer else "flash", mesh,
+        block_cap=2048,  # what the chip's probe settles on
         topk=mc.dsa_topk if mc.has_indexer else 0,
     )
     return trainer_programs(
