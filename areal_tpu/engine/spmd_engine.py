@@ -567,7 +567,7 @@ class SPMDTrainEngine(TrainEngine):
             return {}
         tiles, kept = flash_ops.tile_counts(
             segment_ids, self.model_config.attn_head_dims[0],
-            self.splash_block or 0,
+            self.splash_block or 0, heads=self.model_config.num_heads,
         )
         counted = {"attn_tiles": tiles, "attn_tiles_kept": kept}
         for k, n in counted.items():
@@ -700,7 +700,8 @@ class SPMDTrainEngine(TrainEngine):
                     **self._count_attn_tiles(packed.segment_ids),
                     **self._span_attrs,
                     **transformer.grad_span_attrs(
-                        self.model_config, self.attn_impl, row_len
+                        self.model_config, self.attn_impl, row_len,
+                        self.splash_block or 0,
                     ),
                 ), goodput.dispatch_scope(
                     goodput.trainer_tracker(), "fwd_bwd", f"pad{row_len}"

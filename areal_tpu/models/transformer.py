@@ -352,9 +352,9 @@ class Mechanism(NamedTuple):
     no_seq_parallel: Optional[Callable] = None
     no_generation: Optional[Callable] = None
     span_attrs: Optional[Callable] = None
-    # (cfg, attn_impl, row_len) -> what a GRAD dispatch span says beside
-    # them of the program it runs: the row's length is the dispatch's, not
-    # the model's
+    # (cfg, attn_impl, row_len, block_cap) -> what a GRAD dispatch span
+    # says beside them of the program it runs: the row's length is the
+    # dispatch's, not the model's
     grad_span_attrs: Optional[Callable] = None
 
 
@@ -403,17 +403,16 @@ def _cca_counts(cfg, reported, segment_ids, positions):
     )
 
 
-def _swa_backwards(cfg, attn_impl, row_len):
-    """The backward each of the two cores' kernel instances took
+def _swa_backwards(cfg, attn_impl, row_len, block_cap):
+    """The backward the window layers' kernel instance took
     (``ops.flash.backward_form``); nothing where another attention runs."""
     if attn_impl != "flash":
         return {}
-    d = cfg.head_dim
     return {
         "attn_bwd_window": flash_ops.backward_form(
-            row_len, d, cfg.swa.window
+            row_len, cfg.head_dim, cfg.swa.window, block_cap,
+            heads=cfg.swa.num_heads,
         ),
-        "attn_bwd_full": flash_ops.backward_form(row_len, d),
     }
 
 
@@ -649,11 +648,19 @@ def span_attrs(cfg: ModelConfig, split=False) -> Dict[str, Any]:
 
 
 def grad_span_attrs(
-    cfg: ModelConfig, attn_impl: str, row_len: int
+    cfg: ModelConfig, attn_impl: str, row_len: int, block_cap: int = 0
 ) -> Dict[str, Any]:
     """What a grad dispatch of ``row_len``-token rows says of the model's
-    mechanisms beside ``span_attrs``."""
-    said = _declared(cfg, "grad_span_attrs", attn_impl, row_len)
+    mechanisms beside ``span_attrs``, and of the splash core every model
+    that runs it shares: the full-attention layers' tile edge and backward
+    (``ops.flash.instance`` under ``block_cap``, the engine's probed cap)."""
+    said = _declared(cfg, "grad_span_attrs", attn_impl, row_len, block_cap)
+    if attn_impl == "flash":
+        full = flash_ops.instance(
+            row_len, cfg.attn_head_dims[0], block_cap=block_cap,
+            heads=cfg.num_heads,
+        )
+        said.append({"attn_edge": full.edge, "attn_bwd_full": full.backward})
     return {k: v for attrs in said for k, v in attrs.items() if v}
 
 

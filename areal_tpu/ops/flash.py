@@ -39,16 +39,18 @@ _LANES = 128
 _MIN_PROBED_EDGE = 512
 
 
-def _tiles(edge: int, fused: bool = True) -> "_sk.BlockSizes":
-    """One edge for every tile of forward and backward; what else
-    ``instance``'s sweeps found best at every shape they measured. ``fused``:
-    one backward kernel for dq, dk and dv, or the two split ones."""
-    kvc = min(512, edge)
-    dq = {} if fused else dict(block_q_dq=edge, block_kv_dq=edge)
+def _tiles(inst: "Instance") -> "_sk.BlockSizes":
+    """The splash kernel's blocks of ``inst`` (``instance``'s): ONE edge for
+    every tile of forward and backward (the rows' tile mask is laid over them
+    all at that edge), ``inst.kvc`` the compute tile of both, and one
+    backward kernel for dq, dk and dv (``inst.fused``) or the two split
+    ones."""
+    edge, kvc = inst.edge, inst.kvc
+    dq = {} if inst.fused else dict(block_q_dq=edge, block_kv_dq=edge)
     return _sk.BlockSizes(
         block_q=edge, block_kv=edge, block_kv_compute=kvc,
         block_q_dkv=edge, block_kv_dkv=edge, block_kv_dkv_compute=kvc,
-        use_fused_bwd_kernel=fused, **dq,
+        use_fused_bwd_kernel=inst.fused, **dq,
     )
 
 
@@ -103,7 +105,7 @@ def probe_block_size(max_block: int = 2048, probe_t: int = 2048) -> int:
     while b >= _MIN_PROBED_EDGE:
         try:
             kernel = _sk.make_splash_mqa_single_device(
-                mask, block_sizes=_tiles(b)
+                mask, block_sizes=_tiles(Instance("splash", 0, b, True, 512))
             )
             out = jax.grad(
                 lambda q_: kernel(q_, k, k).astype(jnp.float32).sum()
@@ -129,10 +131,12 @@ def probe_block_size(max_block: int = 2048, probe_t: int = 2048) -> int:
 def takes_head_dim(d: int, dv: "int | None" = None) -> bool:
     """Head dimensions the trainer hands to the kernel: multiples of 64.
     64 (half a lane row) and 128 have trained on the v5e (Qwen2-0.5B and
-    -1.5B, PERF.md PR 22 and PR 26), and a query/key width of 192 over a
-    value width ``dv`` of 128 (latent attention: one layer at 8,192-token
-    rows since PR 33, six cores a step at 16,384 since PR 54); Mosaic
-    compiles others too, but none has run on a chip: they stay on XLA."""
+    -1.5B, PERF.md PR 22 and PR 26), 256 (PR 52), and a query/key width of
+    192 over a value width ``dv`` of 128 (latent attention: one layer at
+    8,192-token rows since PR 33, six cores a step at 16,384 since PR 54;
+    ``instance`` gives the two wide shapes tiles of their own since PR 57);
+    Mosaic compiles others too, but none has run on a chip: they stay on
+    XLA."""
     dv = d if dv is None else dv
     return d > 0 and d % 64 == 0 and (dv == d or (d, dv) == (192, 128))
 
@@ -177,28 +181,38 @@ class Instance(NamedTuple):
     row | "strip" the band's own kernels | "splash"; ``back``, the keys behind
     a query its static mask keeps beside the query's own (0: causal);
     ``edge``, the tile edge (the strip kernels' query block; 0 under XLA);
-    ``fused``, the splash kernel's one backward kernel or its two."""
+    ``fused``, the splash kernel's one backward kernel or its two; ``kvc``,
+    its ``block_kv_compute`` (0 where another kernel runs)."""
 
     kind: str
     back: int
     edge: int
     fused: bool
+    kvc: int
+
+    @property
+    def backward(self) -> str:
+        """"fused" | "split" | "strip", "" for a row the XLA form takes:
+        what a trainer's grad dispatch span says of a core."""
+        if self.kind != "splash":
+            return self.kind
+        return "fused" if self.fused else "split"
 
 
 def instance(
     t: int, d: int, layer_window: int = 0, block_cap: int = 0,
-    interpret: bool = False,
+    interpret: bool = False, *, heads: int,
 ) -> Instance:
-    """THE rule: which kernel instance a row of ``t`` tokens with heads ``d``
-    wide runs in a layer whose window is ``layer_window`` keys, the query's
-    own among them (0: a full-attention layer), under ``block_cap``, the
-    largest edge the backend ran (``probe_block_size``; 0: uncapped).
-    ``interpret``: Pallas interpret mode, the CPU's tests. Every threshold
-    of this file is here. A layer's window reaches ``layer_window - 1`` keys
-    back and is the one local mask there is: a band, unless it reaches over
-    the whole row (plain causal). A row's own sequences need no mask of
-    their own: the rows' run-time tile mask (``tiles_needed``) drops every
-    tile no sequence needs.
+    """THE rule: which kernel instance a row of ``t`` tokens with ``heads``
+    query heads ``d`` wide runs in a layer whose window is ``layer_window``
+    keys, the query's own among them (0: a full-attention layer), under
+    ``block_cap``, the largest edge the backend ran (``probe_block_size``; 0:
+    uncapped). ``interpret``: Pallas interpret mode, the CPU's tests. Every
+    threshold of this file is here. A layer's window reaches
+    ``layer_window - 1`` keys back and is the one local mask there is: a
+    band, unless it reaches over the whole row (plain causal). A row's own
+    sequences need no mask of their own: the rows' run-time tile mask
+    (``tiles_needed``) drops every tile no sequence needs.
 
     Measured on the v5e with ``tools/microbench_attn_v2.py`` on the cells'
     own packed rows under the rows' tile mask (``_row_kernel``: an empty tile
@@ -219,65 +233,104 @@ def instance(
     8192   8/2 of 128                    3.517 (3.894) 3.679 (3.824)
     16384  48/8 of 128                   61.85 (88.40) 58.93 (80.76)
     16384  8/2 of 128                    8.131 (14.49) 7.843 (13.14)
-    16384  16/2 of 256     41.77 (61.64) 35.71 (53.43) 43.46 (59.38)
-    16384  32/32, 192|128                63.61 / 67.27 73.88 / 73.36
     ====== =============== ============= ============= =============
 
-    - so edge 512 to T = 2048 (dense heads +8.6%, the 16 of 128 -0.7%), 1024
-      to T = 8192 (192 | 128 paid 20% at 2048), 2048 above: right for heads
-      of 128; wider ones want 1024 (A.21, A.22; "a / b": fused / SPLIT bwd),
-      which this rule does not read yet: ``d`` only says a band's kernels;
+    Heads wider than 128 on the rows of the cells that have them (PERF.md,
+    PR 57, has every compute tile; "a / b": the fused / the SPLIT backward;
+    the last column edge 2048 under ``block_kv_compute`` 1024):
+
+    ====== =============== ============= ============= ============= ====
+    T      heads           edge 512      edge 1024     edge 2048     1024
+    ====== =============== ============= ============= ============= ====
+    2048   32/32, 192|128  2.620 / 3.112 2.473 / 3.045 3.116 / 3.408 3.07
+    2048   16/2 of 256     1.347 / 1.463 1.352 / 1.553 1.694 / 1.918 1.71
+    4096   32/32, 192|128  8.150 / 9.153 7.573 / 8.743 9.561 / 10.05 8.49
+    4096   16/2 of 256     4.162 / 4.925 4.028 / 4.883 5.374 / 5.761 5.44
+    8192   32/32, 192|128  26.77 / 29.06 22.73 / 25.25 27.76 / 28.15 24.2
+    8192   16/2 of 256     14.33 / 15.92 12.24 / 14.15 15.83 / 16.20 16.0
+    16384  32/32, 192|128  78.79 / 80.79 63.61 / 67.28 73.88 / 73.36 64.1
+    16384  16/2 of 256     41.91 / 46.05 35.71 / 39.20 43.45 / 43.49 44.0
+    ====== =============== ============= ============= ============= ====
+
+    - so for heads to 128 wide edge 512 to T = 2048 (dense heads +8.6%, the
+      16 of 128 -0.7%), 1024 to T = 8192, 2048 above; WIDER heads take 1024
+      at every rung (-13.9% and -17.8% at 16,384, where the rule for 128 had
+      them at 2048 until PR 57; -2.2 to -5.6% at 2,048 for 192 | 128, Kimi's
+      rows and JoyAI's, and nothing for 256), with the fused backward where
+      its partial dq fits (next item). JoyAI's at 1024 is 3 GiB and does
+      not fit; heads of 192, a lane tile and a half, then take edge 2048
+      under a compute tile of 1024, which their fused dkv kernel wants there
+      (64.13 against 73.88 at 512 and 75.90 at 2048; Kimi's rows at 8,192:
+      23.30 against 26.71; heads of 256 gain nothing from it, 44.02 against
+      43.45) and which beats 1024 with the split kernels (67.28); heads of
+      256 that do not fit would take 1024 split (39.20 against 43.45);
+    - the FUSED dq+dkv backward wherever its partial dq a layer,
+      [T / edge, heads, T, d] in bf16, is within 2.5 GiB: one pass over the
+      data, 10-25% under the split kernels everywhere (PR 26; the tables
+      above), but ``grad_step`` holds that array beside the state. What the
+      cells run: 1.5 GiB fits (Laguna's 48 heads of 128 and JoyAI's 32 of
+      192 at 16,384 and edge 2048: JoyAI's ``grad_step`` then reads 16.18 of
+      the 17.18 GB its chip gives, PERF.md, PR 54), 2 GiB fits (Qwen3-Next's
+      16 heads of 256 at 16,384 and edge 1024), 3 GiB (JoyAI at 1024) and
+      4 GiB (Mellum, below) do not;
     - a band that comes to the splash kernel at all (not 128-wide heads on a
       TPU: ``band_attention.strip_block``) takes ``BAND_EDGE`` and the split
       backward (above; PERF.md, PR 39 and PR 45, have the sweeps at 64/8 and
       32/4 heads of 128; the rows' tile mask is worth 1.7% there, 25.28
       against 25.72 ms);
-    - past T = 16384 the SPLIT backward: the fused one's partial dq,
-      [T / edge, heads, T, d] in bf16, is 4.3 GB a layer at T = 32768, edge
-      2048 and 32 heads of 128, and ``grad_step`` of the cell that has such
-      rows then reads 16.8 GB with its moments (arguments 2.72 + temporaries
-      11.34 + 2.72; the chip gives a program 16.9) against 14.4 GB split.
+    - so at T = 32768 and 32 heads of 128 the SPLIT backward: the partial
+      dq is 4.3 GB a layer at edge 2048, and ``grad_step`` of the cell that
+      has such rows then reads 16.8 GB with its moments (arguments 2.72 +
+      temporaries 11.34 + 2.72; the chip gives a program 16.9) against 14.4
+      GB split.
       Forward + backward ms a layer on that cell's rows, 32/4 heads of 128
       (PERF.md, PR 45): edge 2048 fused 134.4, split 158.7; edge 1024 fused
       151.9, split 173.1: so edge 2048 stays, and the split costs 18% of the
       full layer's core there;
-    - ``block_kv_compute`` 512 rather than the edge (``_tiles``: the smaller
-      compute tile overlaps the kv DMA; 256 loses), and the FUSED dq+dkv
-      backward: one pass over the data, 10-25% under the split kernels
-      everywhere (PR 26).
+    - ``block_kv_compute`` 512 rather than the edge (the smaller compute
+      tile overlaps the kv DMA; 256 loses by 1-4% at 192 | 128 and is even
+      at 256; at edge 1024 a compute tile of 1024 is within 0.8% of 512 at
+      every width), but for the one case above.
 
     The edge is never above ``block_cap``, always a divisor of ``t``."""
     if t % _LANES:
         # not whole tiles of 128 tokens: short, its quadratic scores small
-        return Instance("", 0, 0, False)
+        return Instance("", 0, 0, False, 0)
     back = layer_window - 1 if 0 < layer_window - 1 < t else 0
+    wide = d > _LANES  # 192 | 128 and 256
+    odd = wide and d % _LANES != 0  # a lane tile and a half: 192 | 128
+
+    def fits(edge: int) -> bool:
+        # the fused backward's partial dq a layer, bf16, within 2.5 GiB
+        return t // edge * heads * t * d * 2 <= 5 << 29
+
     if back:
         if interpret or jax.default_backend() == "tpu":
             # the band's own kernels (``ops/band_attention.py``) where they
             # take the shape: heads a multiple of 128 wide, a row of blocks
             bq = band_attention.strip_block(t, back, d)
             if bq:
-                return Instance("strip", back, bq, False)
-        want, fused = BAND_EDGE, False
+                return Instance("strip", back, bq, False, 0)
+        want = BAND_EDGE
+    elif wide:
+        want = 2048 if odd and not fits(1024) and fits(2048) else 1024
     else:
         want = 512 if t <= 2048 else 1024 if t <= 8192 else 2048
-        fused = t <= 16384
     want = min(want, block_cap or want)
     edge = _LANES
     while edge * 2 <= min(want, t) and t % (edge * 2) == 0:
         edge *= 2
-    return Instance("splash", back, edge, fused)
+    kvc = 1024 if odd and edge == 2048 else min(512, edge)
+    return Instance("splash", back, edge, not back and fits(edge), kvc)
 
 
-def backward_form(t: int, d: int, layer_window: int = 0) -> str:
-    """"fused" | "split" | "strip": the backward ``instance`` gives a row of
-    ``t`` tokens in a layer of ``layer_window`` (what a trainer's dispatch
-    span says of each core): the splash kernel's one kernel or its two, or
-    the band's own; "" for a row the XLA form takes."""
-    inst = instance(t, d, layer_window)
-    if inst.kind != "splash":
-        return inst.kind
-    return "fused" if inst.fused else "split"
+def backward_form(
+    t: int, d: int, layer_window: int = 0, block_cap: int = 0, *, heads: int
+) -> str:
+    """``Instance.backward`` of the instance a row of ``t`` tokens gets in a
+    layer of ``layer_window``: the splash kernel's one backward kernel or
+    its two, or the band's own."""
+    return instance(t, d, layer_window, block_cap, heads=heads).backward
 
 
 @functools.lru_cache(maxsize=32)
@@ -299,7 +352,7 @@ def _make_kernel(t: int, rep: int, inst: Instance, interpret: bool):
         # saving "attn_out" skips the forward-kernel recompute in the
         # backward (what models/transformer.apply's remat saves)
         return _sk.make_splash_mqa_single_device(
-            mask, block_sizes=_tiles(inst.edge, inst.fused),
+            mask, block_sizes=_tiles(inst),
             residual_checkpoint_name="attn_out", interpret=interpret,
         )
 
@@ -327,15 +380,16 @@ def tiles_needed(segment_ids, edge: int, xp=jnp):
 
 
 def tile_counts(
-    segment_ids, d: int, block_cap: int = 0
+    segment_ids, d: int, block_cap: int = 0, *, heads: int
 ) -> "tuple[int, int]":
     """What the engine counts a dispatch: the tiles on and under the
     diagonal of packed rows ``segment_ids`` [rows, T] (numpy, on the host)
-    at the edge ``instance`` gives such a row in a full-attention layer
-    under ``block_cap``, and those of them the kernel computes: the ones a
-    sequence of the row needs. (0, 0) for a row the XLA form takes."""
+    at the edge ``instance`` gives such a row in a full-attention layer of
+    ``heads`` query heads ``d`` wide under ``block_cap``, and those of them
+    the kernel computes: the ones a sequence of the row needs. (0, 0) for a
+    row the XLA form takes."""
     t = segment_ids.shape[-1]
-    inst = instance(t, d, block_cap=block_cap)
+    inst = instance(t, d, block_cap=block_cap, heads=heads)
     if not inst.kind:
         return 0, 0
     n = t // inst.edge
@@ -423,8 +477,8 @@ def flash_segment_attention(
     kernel takes the value width from ``v``, nothing is padded. Which kernel
     instance the rows run is ``instance``'s to say."""
     assert causal, "splash path is causal-only (decoder models)"
-    t, d = q.shape[1], q.shape[3]
-    inst = instance(t, d, layer_window, block_cap, interpret)
+    t, heads, d = q.shape[1:]
+    inst = instance(t, d, layer_window, block_cap, interpret, heads=heads)
     if not inst.kind:
         return segment_attention(
             q, k, v, segment_ids, causal=True, window=layer_window

@@ -290,6 +290,34 @@ def test_flash_dispatches_count_the_tiles_their_rows_keep(
         assert (attrs["attn_tiles"], attrs["attn_tiles_kept"]) == (10, 7)
 
 
+def test_a_grad_dispatch_of_a_model_without_a_window_says_its_instance(
+    as_tpu, monkeypatch, seen
+):
+    """Every model that runs the splash core says the full core's tile edge
+    and backward on its grad dispatch spans (PR 57), from
+    ``ops.flash.instance`` at the row's length, the model's heads and the
+    engine's own cap; a recompute dispatch runs no backward and says none."""
+    import areal_tpu.engine.spmd_engine as spmd
+
+    monkeypatch.setattr(spmd, "attention_fn", lambda *a, **kw: None)
+    eng = _engine()  # 2048-token rows; the probe's answer here: 1024
+    mc = eng.model_config
+    assert eng.attn_impl == "flash" and mc.swa is None
+    batch = _batch([600, 700, 500])
+    eng.train_batch(batch, sft_loss_fn, sft_loss_weight_fn)
+    eng.forward(batch)
+    (attrs,) = [a for n, a in seen if n == "train.grad_dispatch"]
+    inst = flash.instance(
+        2048, mc.head_dim, block_cap=eng.splash_block, heads=mc.num_heads
+    )
+    assert inst == ("splash", 0, 512, True, 512)
+    assert (attrs["attn_edge"], attrs["attn_bwd_full"]) == (512, "fused")
+    assert "attn_bwd_window" not in attrs and attrs["attn_tiles"] == 10
+    for name, attrs in seen:
+        if name == "train.logp_dispatch":
+            assert "attn_edge" not in attrs and "attn_bwd_full" not in attrs
+
+
 @pytest.mark.parametrize("family", ["qwen2", "KeyeVL2"])
 def test_other_attentions_count_no_tiles(seen, family):
     """``xla`` (the CPU's ``auto``) and ``sparse_topk`` (a model with an

@@ -9,6 +9,8 @@ blocks, 1 and 8 query heads a key head, rows with a sequence border inside a
 strip, on a block edge, a padded tail, a query block of padding alone, one
 sequence a row. And the rule that sends a call there, by what it can see."""
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -88,9 +90,10 @@ def test_the_strip_kernels_match_xla_segment_attention(
     rows, t, heads, window, dtype, tol
 ):
     hq, hkv = heads
-    assert F.backward_form(t, D, window) == "split"  # the CPU's, unasked
-    assert F.instance(t, D, window, interpret=True) == (
-        "strip", window - 1, 128, False
+    # the CPU's, unasked
+    assert F.backward_form(t, D, window, heads=hq) == "split"
+    assert F.instance(t, D, window, interpret=True, heads=hq) == (
+        "strip", window - 1, 128, False, 0
     )
     ks = jax.random.split(jax.random.PRNGKey(len(rows[0]) + window), 4)
     b = len(rows)
@@ -141,8 +144,8 @@ def test_the_strip_kernels_are_the_splash_kernels_numbers(monkeypatch):
 
     strip = run()
     monkeypatch.setattr(BA, "strip_block", lambda *a: 0)  # takes no shape
-    assert F.instance(t, D, window, 128, True) == (
-        "splash", window - 1, 128, False
+    assert F.instance(t, D, window, 128, True, heads=hq) == (
+        "splash", window - 1, 128, False, 128
     )
     split = run()
     for a, b in zip(strip, split):
@@ -171,17 +174,18 @@ _FORMS = [
 def test_backward_form_says_strip_where_the_rule_takes_the_kernel(
     monkeypatch, t, d, layer_window, form
 ):
-    was = F.backward_form(t, d, layer_window)  # on the CPU: splash's
+    said = functools.partial(F.backward_form, t, d, layer_window, heads=32)
+    was = said()  # on the CPU: splash's
     assert was == ("split" if form == "strip" else form)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert F.backward_form(t, d, layer_window) == form
-    assert (F.instance(t, d, layer_window).kind == "strip") == (
+    assert said() == form
+    assert (F.instance(t, d, layer_window, heads=32).kind == "strip") == (
         form == "strip"
     )
     # a row that is no whole number of strips keeps the splash backward
     monkeypatch.setattr(BA, "STRIP_BQ", 3 * 128)
     if form == "strip":
-        assert F.backward_form(t, d, layer_window) == "split"
+        assert said() == "split"
 
 
 @pytest.mark.parametrize("model,row_len", [

@@ -71,6 +71,8 @@ _SPLASH_CASES = [
     pytest.param(64, 14, 2048, 512, id="d64-t2048-causal"),
     pytest.param(128, 16, 2048, 512, id="d128-h16-t2048-causal"),
     pytest.param(128, 8, 8192, 1024, id="d128-h8-t8192-causal"),
+    # PR 57: 16/2 heads of 256 at 16,384 tokens take 1024 (Qwen3-Next)
+    pytest.param(256, 16, 16384, 1024, id="d256-h16-t16384-causal"),
 ]
 
 
@@ -106,7 +108,7 @@ def test_splash_fwd_bwd_compiles_for_v5e(one_chip, d, hq, t, edge):
         )
     finally:
         flash._make_kernel.cache_clear()
-    assert flash.instance(t, d, block_cap=2048).edge == edge
+    assert flash.instance(t, d, block_cap=2048, heads=hq).edge == edge
     assert "tpu_custom_call" in compiled.as_text()
 
 
@@ -133,7 +135,7 @@ def test_the_bands_own_kernels_compile_for_v5e(
     from areal_tpu.ops import flash
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert flash.backward_form(t, 128, layer_window) == "strip"
+    assert flash.backward_form(t, 128, layer_window, heads=hq) == "strip"
     bf = jnp.bfloat16
 
     def loss(q, k, v, seg):
@@ -166,7 +168,9 @@ def test_the_probes_first_candidate_compiles_for_v5e(one_chip):
     t = 2048
     mask = flash._sm.MultiHeadMask([flash._sm.CausalMask((t, t))] * 4)
     kernel = flash._sk.make_splash_mqa_single_device(
-        mask, block_sizes=flash._tiles(2048)
+        mask, block_sizes=flash._tiles(
+            flash.Instance("splash", 0, 2048, True, 512)
+        )
     )
     compiled = (
         jax.jit(jax.grad(
@@ -181,17 +185,23 @@ def test_the_probes_first_candidate_compiles_for_v5e(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_latent_attention_core_compiles_for_v5e(one_chip):
+@pytest.mark.parametrize("t,tiles", [
+    pytest.param(8192, (1024, True, 512), id="t8192"),
+    # PR 57: the compute tile of 1024 that heads of 192 take at edge 2048
+    pytest.param(16384, (2048, True, 1024), id="t16384"),
+])
+def test_latent_attention_core_compiles_for_v5e(one_chip, t, tiles):
     """The splash kernel at a query/key width of 192 over a value width of
-    128, 32 heads each with its own keys, on an 8192-token row, forward and
-    backward: the width pair ``ops/flash.takes_head_dim`` admits."""
+    128, 32 heads each with its own keys, on the two latent cells' longest
+    rows (Kimi 8,192, JoyAI 16,384), forward and backward: the width pair
+    ``ops/flash.takes_head_dim`` admits, at ``instance``'s tiles."""
     import jax
     import jax.numpy as jnp
 
     from areal_tpu.ops import flash
 
     flash._make_kernel.cache_clear()
-    t, bf = 8192, jnp.bfloat16
+    bf = jnp.bfloat16
 
     def loss(q, k, v, seg):
         return flash.flash_segment_attention(
@@ -212,4 +222,5 @@ def test_latent_attention_core_compiles_for_v5e(one_chip):
     finally:
         flash._make_kernel.cache_clear()
     assert flash.takes_head_dim(192, 128)
+    assert flash.instance(t, 192, block_cap=2048, heads=32)[2:] == tiles
     assert "tpu_custom_call" in compiled.as_text()

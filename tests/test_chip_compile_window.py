@@ -57,7 +57,7 @@ def test_window_and_full_cores_compile_for_v5e(
         compiled = lowered.compile()
     finally:
         flash._make_kernel.cache_clear()
-    inst = flash.instance(t, 128, layer_window, 2048)
+    inst = flash.instance(t, 128, layer_window, 2048, heads=hq)
     full_edge = 2048 if t > 8192 else 1024
     assert inst.kind == "splash"  # the CPU's: a TPU sends a band to strips
     assert inst.back == (layer_window - 1 if band else 0)

@@ -142,7 +142,7 @@ def test_splash_matches_xla_segment_attention(
     shape = dict(shape)
     t, lw = shape.pop("t", T), shape.pop("layer_window", 0)
     cap = dict(block_cap=edge_cap or 0, layer_window=lw)
-    inst = F.instance(t, D, interpret=True, **cap)
+    inst = F.instance(t, D, interpret=True, heads=HQ, **cap)
     assert inst[:3] == ("splash", lw - 1 if lw else 0, edge_cap or t)
     q, k, v, seg, cot = _inputs(dtype, [_row(n, t) for n in lens], **shape)
     got = _held_to_xla(q, k, v, seg, cot, tol, **cap)
@@ -157,6 +157,42 @@ def test_splash_matches_xla_segment_attention(
         )
         for a, b in zip(got, static):
             np.testing.assert_array_equal(a, b)
+
+
+# (heads, d, dv, the hand-made instance): the library's two backward forms
+# and a compute tile other than the edge, at the widths the rule tells apart
+# since PR 57 (the cells' own instances are the chip's: edge 1024 and 2048)
+_WIDE_CASES = [
+    pytest.param((4, 4), 192, 128, F.Instance("splash", 0, 128, False, 128),
+                 id="split-192-128"),
+    pytest.param((4, 2), 256, 256, F.Instance("splash", 0, 128, False, 128),
+                 id="split-256"),
+    pytest.param((4, 4), 192, 128, F.Instance("splash", 0, 256, True, 128),
+                 id="fused-192-128-compute-tile-half-the-edge"),
+    pytest.param((4, 2), 256, 256, F.Instance("splash", 0, 256, True, 256),
+                 id="fused-256-compute-tile-the-edge"),
+]
+
+
+@pytest.mark.parametrize("heads,d,dv,inst", _WIDE_CASES)
+def test_wide_heads_either_backward_matches_xla(heads, d, dv, inst):
+    """An instance said outright (as ``tools/microbench_attn_v2`` hands it to
+    ``_splash``): out, dq, dk, dv against the XLA form's on a 512-token row
+    with borders, under the rows' tile mask."""
+    q, k, v, seg, cot = _inputs(
+        jnp.float32, [_row([210, 130, 150], T4)], heads=heads, d=d, dv=dv
+    )
+    kernel = F._make_kernel(T4, heads[0] // heads[1], inst, True)
+    assert (kernel.dq_mask_info is None) == inst.fused
+    want = _out_and_grads(segment_attention, q, k, v, seg, cot)
+    got = _out_and_grads(
+        lambda *a: F._splash(*a, inst=inst, interpret=True), q, k, v, seg, cot
+    )
+    for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+        scale = max(1.0, float(np.abs(b).max()))
+        assert np.isfinite(a).all(), name
+        assert np.abs(a - b).max() <= 3e-5 * scale, name
+    assert not got[0][np.asarray(seg) == 0].any()
 
 
 def test_a_row_shorter_than_a_tile_takes_the_xla_form():
@@ -230,7 +266,7 @@ def test_laid_over_mask_infos_walk_the_tiles_the_count_counts(back):
     needed = F.tiles_needed(seg, edge, np)
     for fused in (True, False):
         static = F._make_kernel(
-            t, 2, F.Instance("splash", back, edge, fused), True
+            t, 2, F.Instance("splash", back, edge, fused, edge), True
         )
         row = F._row_kernel(static, jnp.asarray(seg))
         # the borders leave 19 of the triangle's 36 (17 of the 28 under the
@@ -245,7 +281,7 @@ def test_laid_over_mask_infos_walk_the_tiles_the_count_counts(back):
         else:
             assert in_band.sum() == 36
             kept = 19
-            assert F.tile_counts(seg[None], D, edge) == (36, kept)
+            assert F.tile_counts(seg[None], D, edge, heads=HQ) == (36, kept)
         assert (row.dq_mask_info is None) == fused
         for info, kv_major in (
             (row.fwd_mask_info, False), (row.dq_mask_info, False),

@@ -54,7 +54,7 @@ def test_fallback_cascade_on_compile_failure(monkeypatch):
     assert F.probe_block_size() == 512 and len(attempts) == 3  # memoised
     # and the tiles of a caller that was handed no cap stay the rule's own:
     # the probe's answer is a value, not a state of the module
-    assert F.instance(16384, 128).edge == 2048
+    assert F.instance(16384, 128, heads=8).edge == 2048
 
     # total failure: every candidate raises -> an error that carries the
     # compiler's words, and nothing memoised (a retry probes again)
@@ -100,10 +100,16 @@ _EDGES = [
 
 @pytest.mark.parametrize("t,cap,edge", _EDGES)
 def test_tiles_follow_the_shape_under_the_cap_handed_in(t, cap, edge):
-    inst = F.instance(t, 64, block_cap=cap)
-    assert inst == F.instance(t, 128, block_cap=cap)  # head dim moves nothing
-    assert inst == ("splash", 0, edge, t <= 16384) and t % edge == 0
-    tiles = F._tiles(inst.edge, inst.fused)
+    inst = F.instance(t, 64, block_cap=cap, heads=2)
+    # heads of 64 or 128 move nothing (two of them: no partial dq to weigh)
+    assert inst == F.instance(t, 128, block_cap=cap, heads=2)
+    assert inst == ("splash", 0, edge, True, min(512, edge)) and t % edge == 0
+    # 32 heads of 128: the fused backward to 16,384 tokens at the chip's cap
+    fits = t // edge * 32 * t * 128 * 2 <= 2.5 * (1 << 30)
+    assert F.instance(t, 128, block_cap=cap or 2048, heads=32) == (
+        inst._replace(fused=fits)
+    )
+    tiles = F._tiles(inst)
     assert {tiles.block_q, tiles.block_kv, tiles.block_q_dkv,
             tiles.block_kv_dkv} == {edge}
     assert tiles.block_kv_compute == tiles.block_kv_dkv_compute == min(512, edge)

@@ -1,10 +1,12 @@
 """``ops/flash.instance`` is the one answer to "which attention kernel instance
 does this row run?" (PR 56): the kernel instances the NINE cells compile, a
 case a (cell, row-length rung, core), with ``backward_form`` and
-``tile_counts`` held to it; a layer's window is a band whatever its size; the
-cap on the tiles is a value each caller hands in; and the premise the packing
-window went on (ROADMAP C.31): the rows' run-time tile mask keeps no tile
-that window dropped."""
+``tile_counts`` held to it; since PR 57 the full core's instance reads the
+heads' width and number (heads of 64 and 128 keep the parent's, the fused
+backward goes where its partial dq fits); a layer's window is a band whatever
+its size; the cap on the tiles is a value each caller hands in; and the
+premise the packing window went on (ROADMAP C.31): the rows' run-time tile
+mask keeps no tile that window dropped."""
 
 import numpy as np
 import pytest
@@ -33,19 +35,34 @@ _CELLS = {
     "train_qwen3next_gdn_grpo_long16k": ("qwen3_next_80b_a3b", 16384),
     "train_joyai_mla_mtp_grpo_long16k": ("joyai_llm_flash", 16384),
 }
-# what a full-attention core runs at each rung, whatever its heads (14/2 of
-# 64, 16/16 and 8/2 and 48/8 and 32/4 of 128, 16/2 of 256, 32/32 of 192 | 128)
+# (rung, head width) -> what a full-attention core of the cells' heads runs
+# (14/2 of 64, 16/16 and 8/2 and 48/8 and 32/4 of 128, 16/2 of 256, 32/32 of
+# 192 | 128); width 0: every width the rung does not name
 _FULL = {
-    2048: ("splash", 0, 512, True),
-    4096: ("splash", 0, 1024, True),
-    8192: ("splash", 0, 1024, True),
-    16384: ("splash", 0, 2048, True),
-    32768: ("splash", 0, 2048, False),
+    (2048, 0): ("splash", 0, 512, True, 512),
+    (4096, 0): ("splash", 0, 1024, True, 512),
+    (8192, 0): ("splash", 0, 1024, True, 512),
+    (16384, 0): ("splash", 0, 2048, True, 512),
+    # PR 57: heads wider than 128 take 1024 at every rung where the fused
+    # backward's partial dq fits (Qwen3-Next at 16,384: 2 GiB); JoyAI's 32
+    # heads at 16,384 would write 3 GiB at 1024, so 2048, and the compute
+    # tile of 1024 that heads of 192 want there
+    (2048, 192): ("splash", 0, 1024, True, 512),
+    (2048, 256): ("splash", 0, 1024, True, 512),
+    (16384, 192): ("splash", 0, 2048, True, 1024),
+    (16384, 256): ("splash", 0, 1024, True, 512),
+    (32768, 0): ("splash", 0, 2048, False, 512),
 }
+_RUNGS = (2048, 4096, 8192, 16384, 32768)
+_GIB = 1 << 30
+
+
+def _full(t, d):
+    return _FULL.get((t, d), _FULL[t, 0])
 
 
 def _rungs(longest):
-    return [t for t in _FULL if t <= longest]
+    return [t for t in _RUNGS if t <= longest]
 
 
 def _cell_cases():
@@ -67,7 +84,7 @@ def test_every_kernel_instance_of_the_nine_cells(as_tpu, model, t, core):
     config: the instance, and ``backward_form``, ``tile_counts`` and the
     grad dispatch span's attributes agree with it."""
     mc = feasibility.MODEL_CONFIGS[model]()
-    d = mc.attn_head_dims[0]
+    d, heads = mc.attn_head_dims[0], dict(heads=mc.num_heads)
     if mc.has_indexer:
         # Keye's learned selection is another kernel: no instance is asked
         forced, _ = tr.seq_parallel_refusal(mc)
@@ -77,76 +94,170 @@ def test_every_kernel_instance_of_the_nine_cells(as_tpu, model, t, core):
         )
         return
     assert F.resolve_attn_impl("auto", 1, mc.attn_head_dims) == "flash"
+    said = tr.grad_span_attrs(mc, "flash", t, 2048)
     if core == "band":
-        window = mc.swa.window
+        window, band_heads = mc.swa.window, dict(heads=mc.swa.num_heads)
         # the band's own kernels: heads of 128, a row of whole strips
-        want = ("strip", window - 1, 256, False)
-        assert F.instance(t, d, window, 2048) == want
-        assert F.backward_form(t, d, window) == "strip"
+        want = ("strip", window - 1, 256, False, 0)
+        assert F.instance(t, d, window, 2048, **band_heads) == want
+        assert F.backward_form(t, d, window, **band_heads) == "strip"
+        assert said["attn_bwd_window"] == "strip"
         # where they do not take it (the CPU, heads of 64), the splash
         # kernel's band: ``BAND_EDGE`` and the split backward
-        assert F.instance(t, 64, window, 2048) == (
-            "splash", window - 1, F.BAND_EDGE, False
+        assert F.instance(t, 64, window, 2048, **band_heads) == (
+            "splash", window - 1, F.BAND_EDGE, False, 512
         )
-        assert F.backward_form(t, 64, window) == "split"
+        assert F.backward_form(t, 64, window, **band_heads) == "split"
         return
-    inst = F.instance(t, d, 0, 2048)
-    assert inst == _FULL[t] and inst == F.instance(t, d)  # the cap caps none
-    assert F.backward_form(t, d) == ("fused" if inst.fused else "split")
+    inst = F.instance(t, d, 0, 2048, **heads)
+    assert inst == _full(t, d)
+    assert inst == F.instance(t, d, **heads)  # the cap caps none
+    form = "fused" if inst.fused else "split"
+    assert F.backward_form(t, d, **heads) == form
     seg = np.ones((1, t), np.int32)  # one sequence: every tile is kept
     n = t // inst.edge
-    assert F.tile_counts(seg, d, 2048) == (n * (n + 1) // 2,) * 2
-    said = tr.grad_span_attrs(mc, "flash", t)
-    if mc.swa is None:
-        assert "attn_bwd_full" not in said
-    else:
-        assert said["attn_bwd_full"] == F.backward_form(t, d)
-        assert said["attn_bwd_window"] == "strip"
+    assert F.tile_counts(seg, d, 2048, **heads) == (n * (n + 1) // 2,) * 2
+    # every model that runs the splash core says the full core's instance
+    assert (said["attn_edge"], said["attn_bwd_full"]) == (inst.edge, form)
+    assert ("attn_bwd_window" in said) == (mc.swa is not None)
+
+
+def _parent(t):
+    """The full core's instance until PR 56, whatever its heads."""
+    edge = 512 if t <= 2048 else 1024 if t <= 8192 else 2048
+    return ("splash", 0, edge, t <= 16384, 512)
+
+
+def _narrow_cases():
+    for cell, (model, longest) in _CELLS.items():
+        mc = feasibility.MODEL_CONFIGS[model]()
+        if mc.attn_head_dims[0] <= 128 and not mc.has_indexer:
+            for t in _RUNGS:
+                yield pytest.param(model, t, t <= longest, id=f"{cell}-{t}")
+
+
+@pytest.mark.parametrize("model,t,dispatched", list(_narrow_cases()))
+def test_heads_of_64_and_128_keep_the_parents_instances(
+    as_tpu, model, t, dispatched
+):
+    """The guarantee of the cells whose heads are no wider than 128, at
+    every rung to 32,768: the tile edge and the compute tile are the
+    parent's whatever the heads; at every rung a cell dispatches so is the
+    backward (their programs are the parent's); past those the backward is
+    what the ONE budget says of the heads' partial dq, where the parent's
+    stand-in said "split" of every row past 16,384 tokens."""
+    mc = feasibility.MODEL_CONFIGS[model]()
+    d, heads = mc.attn_head_dims[0], mc.num_heads
+    assert d in (64, 128)
+    inst, was = F.instance(t, d, 0, 2048, heads=heads), _parent(t)
+    assert (inst.kind, inst.back, inst.edge, inst.kvc) == (
+        was[:3] + was[4:]
+    )
+    if dispatched:
+        assert inst == was
+    partial_dq = t // inst.edge * heads * t * d * 2
+    assert inst.fused == (partial_dq <= 2.5 * _GIB)
+
+
+# (query heads, width, row, cap) -> (edge, fused, compute tile), and the
+# partial dq of the fused form at edges 1024 and 2048 in GiB
+_WIDE = [
+    pytest.param(32, 192, 16384, 2048, (2048, True, 1024), (3.0, 1.5),
+                 id="joyai-16384-1024-does-not-fit"),
+    pytest.param(16, 256, 16384, 2048, (1024, True, 512), (2.0, 1.0),
+                 id="qwen3next-16384-fits-at-1024"),
+    pytest.param(32, 192, 8192, 2048, (1024, True, 512), (0.75, 0.375),
+                 id="kimi-8192-as-it-was"),
+    pytest.param(32, 256, 16384, 2048, (1024, False, 512), (4.0, 2.0),
+                 id="32-of-256-1024-split-before-2048-fused"),
+    pytest.param(32, 192, 2048, 2048, (1024, True, 512), (0.046875, 0.0234375),
+                 id="joyai-2048-takes-1024"),
+    pytest.param(16, 256, 2048, 2048, (1024, True, 512), (0.03125, 0.015625),
+                 id="qwen3next-2048-takes-1024"),
+    pytest.param(64, 192, 16384, 2048, (1024, False, 512), (6.0, 3.0),
+                 id="64-of-192-fit-at-neither-1024-split"),
+    pytest.param(32, 192, 16384, 1024, (1024, False, 512), (3.0, 1.5),
+                 id="joyai-capped-at-1024-split"),
+    pytest.param(16, 256, 32768, 2048, (1024, False, 512), (8.0, 4.0),
+                 id="qwen3next-32768-split"),
+    pytest.param(4, 256, 32768, 2048, (1024, True, 512), (2.0, 1.0),
+                 id="4-of-256-32768-fused"),
+]
+
+
+@pytest.mark.parametrize("heads,d,t,cap,want,gib", _WIDE)
+def test_wide_heads_take_the_fused_backward_where_its_partial_dq_fits(
+    heads, d, t, cap, want, gib
+):
+    """Heads wider than 128: edge 1024 with the fused backward where its
+    partial dq, T / edge x heads x T x d x 2 bytes a layer, is within the
+    ONE budget of 2.5 GiB; heads of 192 (a lane tile and a half) else edge
+    2048 under a compute tile of 1024 where that fits; else 1024 and the
+    split kernels, which write none. JoyAI fits at 2048 (1.5 GiB),
+    Qwen3-Next at 1024 (2 GiB)."""
+    inst = F.instance(t, d, 0, cap, heads=heads)
+    assert (inst.edge, inst.fused, inst.kvc) == want
+    at = {edge: t // edge * heads * t * d * 2 / _GIB for edge in (1024, 2048)}
+    assert (at[1024], at[2048]) == gib
+    assert inst.fused == (at[inst.edge] <= 2.5)
+    assert F.backward_form(t, d, 0, cap, heads=heads) == (
+        "fused" if inst.fused else "split"
+    )
+    tiles = F._tiles(inst)
+    assert tiles.use_fused_bwd_kernel == inst.fused
+    assert tiles.block_kv_compute == tiles.block_kv_dkv_compute == inst.kvc
+    n = t // inst.edge
+    assert F.tile_counts(np.ones((1, t), np.int32), d, cap, heads=heads) == (
+        (n * (n + 1) // 2,) * 2
+    )
 
 
 @pytest.mark.parametrize("t", [4096, 16384, 32768])
 @pytest.mark.parametrize("layer_window", [512, 1024, 4096])
 def test_a_layers_window_is_a_band_whatever_its_size(t, layer_window):
-    inst = F.instance(t, 128, layer_window, 2048)
+    inst = F.instance(t, 128, layer_window, 2048, heads=32)
     if layer_window > t:  # the window reaches over the whole row: causal
-        assert inst == F.instance(t, 128, 0, 2048) and inst.back == 0
+        assert inst == F.instance(t, 128, 0, 2048, heads=32)
+        assert inst.back == 0
         return
-    assert inst == ("splash", layer_window - 1, F.BAND_EDGE, False)
+    assert inst == ("splash", layer_window - 1, F.BAND_EDGE, False, 512)
     assert F.BAND_EDGE == 512
-    tiles = F._tiles(inst.edge, inst.fused)
+    tiles = F._tiles(inst)
     assert tiles.block_q_dq == tiles.block_kv_dq == tiles.block_q == 512
     assert tiles.use_fused_bwd_kernel is False
 
 
 def test_past_16384_tokens_the_backward_is_split():
-    """The 32,768-token row: the fused backward's partial dq does not fit
-    beside the state; the edge is the shape's."""
-    inst = F.instance(32768, 128, 0, 2048)
-    tiles = F._tiles(inst.edge, inst.fused)
+    """The 32,768-token row of 32 heads of 128: the fused backward's partial
+    dq (4 GiB) does not fit beside the state; the edge is the shape's."""
+    inst = F.instance(32768, 128, 0, 2048, heads=32)
+    tiles = F._tiles(inst)
     assert tiles.use_fused_bwd_kernel is False
     assert tiles.block_q_dq == tiles.block_kv_dq == tiles.block_q == 2048
-    assert F.backward_form(32768, 128) == "split"
-    assert F.backward_form(16384, 128) == "fused"
-    assert F.backward_form(32768, 128, layer_window=1024) == "split"
-    assert F.backward_form(200, 128) == ""  # the XLA form's row
-    assert F.instance(200, 128, 1024, 2048) == ("", 0, 0, False)
-    assert F.tile_counts(np.ones((2, 200), np.int32), 128) == (0, 0)
+    assert F.backward_form(32768, 128, heads=32) == "split"
+    assert F.backward_form(16384, 128, heads=32) == "fused"
+    assert F.backward_form(32768, 128, layer_window=1024, heads=32) == "split"
+    assert F.backward_form(200, 128, heads=32) == ""  # the XLA form's row
+    assert F.instance(200, 128, 1024, 2048, heads=32) == ("", 0, 0, False, 0)
+    assert F.tile_counts(np.ones((2, 200), np.int32), 128, heads=32) == (0, 0)
 
 
 def test_a_mask_that_reaches_over_the_whole_row_is_none():
-    assert F.instance(512, 64, 1024).back == 0
-    assert F.instance(1024, 64, 1024).back == 1023
-    assert F.instance(1024, 64, 1025).back == 0
-    assert F.instance(32768, 64, 0).back == 0
+    assert F.instance(512, 64, 1024, heads=2).back == 0
+    assert F.instance(1024, 64, 1024, heads=2).back == 1023
+    assert F.instance(1024, 64, 1025, heads=2).back == 0
+    assert F.instance(32768, 64, 0, heads=2).back == 0
 
 
 def test_the_instance_is_the_kernels_cache_key():
     """One kernel an instance, whatever the heads' width; a band's kernel
     has the split backward's third mask."""
     F._make_kernel.cache_clear()
-    band = F._make_kernel(512, 2, F.instance(512, 64, 128, 128), True)
-    full = F._make_kernel(512, 2, F.instance(512, 64, 0, 128), True)
-    assert full is F._make_kernel(512, 2, F.instance(512, 192, 0, 128), True)
+    band = F._make_kernel(512, 2, F.instance(512, 64, 128, 128, heads=2), True)
+    full = F._make_kernel(512, 2, F.instance(512, 64, 0, 128, heads=2), True)
+    assert full is F._make_kernel(
+        512, 2, F.instance(512, 192, 0, 128, heads=2), True
+    )
     assert band is not full and F._make_kernel.cache_info().currsize == 2
     assert band.kwargs["block_sizes"].use_fused_bwd_kernel is False
     assert full.kwargs["block_sizes"].use_fused_bwd_kernel is True
@@ -190,8 +301,9 @@ def test_two_attention_fns_with_two_caps_get_their_own_tiles():
     assert _traced_edges(small) == [512]  # and the first is as it was
     assert _traced_edges(F.flash_segment_attention, t=16384) == [2048]
     assert _traced_edges(small, t=16384) == [512]
-    assert F.tile_counts(np.ones((1, 4096), np.int32), 64, 512) == (36, 36)
-    assert F.tile_counts(np.ones((1, 4096), np.int32), 64, 2048) == (10, 10)
+    row = np.ones((1, 4096), np.int32)
+    assert F.tile_counts(row, 64, 512, heads=2) == (36, 36)
+    assert F.tile_counts(row, 64, 2048, heads=2) == (10, 10)
 
 
 # --- the premise: the tile mask keeps nothing the packing window dropped --
@@ -275,8 +387,8 @@ def test_the_tile_mask_keeps_no_tile_the_packing_window_dropped(
     longest = max(np.bincount(r[r > 0]).max() for r in rows)
     window = _old_packing_window(int(longest), tokens_per_mb)
     window = window if window < t else 0  # as the parent's ``_program`` did
-    d = 128
-    for edge in {F.instance(t, d, 0, 2048).edge, 512}:
+    d, heads = 128, dict(heads=8)
+    for edge in {F.instance(t, d, 0, 2048, **heads).edge, 512}:
         n = t // edge
         needed = F.tiles_needed(rows, edge, np)
         in_window = _tiles_the_window_kept(n, edge, window)
@@ -297,11 +409,12 @@ def test_the_tile_mask_keeps_no_tile_the_packing_window_dropped(
     # what the engine counts now against what the parent counted: the
     # parent's edge under its window (512 under a window of 1024), the same
     # kept tiles at that edge
-    parent_edge = 512 if 0 < window <= 1024 else F.instance(t, d, 0, 2048).edge
+    now_edge = F.instance(t, d, 0, 2048, **heads).edge
+    parent_edge = 512 if 0 < window <= 1024 else now_edge
     kept_parent = (
         F.tiles_needed(rows, parent_edge, np)
         & _tiles_the_window_kept(t // parent_edge, parent_edge, window)
     ).sum()
     assert kept_parent == F.tiles_needed(rows, parent_edge, np).sum()
-    if parent_edge == F.instance(t, d, 0, 2048).edge:
-        assert F.tile_counts(rows, d, 2048)[1] == kept_parent
+    if parent_edge == now_edge:
+        assert F.tile_counts(rows, d, 2048, **heads)[1] == kept_parent

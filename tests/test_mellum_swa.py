@@ -300,9 +300,12 @@ def test_the_refusals_by_what_the_window_declares(tiny, tmp_path):
 
 
 @pytest.mark.parametrize("attn_impl,row_len,want", [
-    ("flash", 32768, {"attn_bwd_window": "split", "attn_bwd_full": "split"}),
-    ("flash", 16384, {"attn_bwd_window": "split", "attn_bwd_full": "fused"}),
-    ("flash", 2048, {"attn_bwd_window": "split", "attn_bwd_full": "fused"}),
+    ("flash", 32768, {"attn_bwd_window": "split", "attn_bwd_full": "split",
+                      "attn_edge": 2048}),
+    ("flash", 16384, {"attn_bwd_window": "split", "attn_bwd_full": "fused",
+                      "attn_edge": 2048}),
+    ("flash", 2048, {"attn_bwd_window": "split", "attn_bwd_full": "fused",
+                     "attn_edge": 512}),
     ("xla", 32768, {}),
 ])
 def test_a_grad_dispatch_says_which_backward_each_core_took(
@@ -314,12 +317,11 @@ def test_a_grad_dispatch_says_which_backward_each_core_took(
     cfg = MODEL_CONFIGS["mellum2_12b_a2p5b"]()
     assert tr.grad_span_attrs(cfg, attn_impl, row_len) == want
     if want:  # what the kernel instance of that row is built with
-        inst = flash.instance(row_len, 128, cfg.swa.window)
-        assert inst == ("splash", 1023, flash.BAND_EDGE, False)
-    # a model without window layers says nothing
-    assert tr.grad_span_attrs(
-        tiny_config("qwen2"), attn_impl, row_len
-    ) == {}
+        inst = flash.instance(row_len, 128, cfg.swa.window, heads=32)
+        assert inst == ("splash", 1023, flash.BAND_EDGE, False, 512)
+    # a model without window layers says the full core's instance alone
+    said = tr.grad_span_attrs(tiny_config("qwen2"), attn_impl, row_len)
+    assert set(said) == ({"attn_edge", "attn_bwd_full"} if want else set())
 
 
 def test_the_trainer_trains_the_family_and_counts_its_pairs_exactly(trained):

@@ -52,12 +52,18 @@ not host clocks. (The persistent compile cache keys a program without its
 name: two variants that lower to the same program run under the first one's
 name, and are marked.) ``ops/flash.instance`` holds what this found (PERF.md,
 PR 26); run it again when the kernel, the chip or the shape changes. A variant
-is an explicit ``flash.Instance`` handed to the wrapper's two halves
-(``flash._splash``, ``flash._strip_band``); the rule itself is never swapped.
+is an explicit ``flash.Instance`` (``--kvc`` its ``block_kv_compute``) handed
+to the wrapper's two halves (``flash._splash``, ``flash._strip_band``); the
+rule itself is never swapped. The sweep at heads wider than 128 (PERF.md,
+PR 57), every rung the two cells dispatch:
+
+    python -m tools.microbench_attn_v2 --t 16384 --heads 32 32 192 --dv 128 \
+        --traffic grpo_steps16_mla_long16k --edges 512 1024 2048 \
+        --backward fused split --kvc 256 512 1024 2048 --rows 8 --no-xla
+    ... --heads 16 2 256 --traffic grpo_steps16_gdn_long16k
 """
 
 import argparse
-import dataclasses
 import functools
 import itertools
 import json
@@ -109,13 +115,6 @@ def variants(edges, backward, kvcs, bqs):
     if "strip" in backward:
         for bq in bqs:
             yield f"strip_bq{bq}", "strip", bq, False, 0
-
-
-def tiles_with(kvc, rule=flash._tiles):
-    """``flash._tiles`` with another ``block_kv_compute`` than the rule's."""
-    return lambda edge, fused=True: dataclasses.replace(
-        rule(edge, fused), block_kv_compute=kvc, block_kv_dkv_compute=kvc
-    )
 
 
 def main():
@@ -178,19 +177,18 @@ def main():
         return jax.jit(fwd), jax.jit(grad)
 
     todo = []
-    rule_tiles, row_kernel = flash._tiles, flash._row_kernel
-    chosen = flash.instance(t, d, lw, flash.probe_block_size())
+    row_kernel = flash._row_kernel
+    chosen = flash.instance(t, d, lw, flash.probe_block_size(), heads=hq)
     print("chosen:", chosen, flush=True)
     swept = list(variants(args.edges, args.backward, args.kvc, args.bq))
     named = ([] if args.no_xla else [("xla", "", 0, False, 0)]) + swept + [
         (name + "_static", *rest)
         for name, *rest in (swept if args.static else [])
-    ] + [("chosen", chosen.kind, chosen.edge, chosen.fused, 0)]
+    ] + [("chosen", chosen.kind, chosen.edge, chosen.fused, chosen.kvc)]
     for name, kind, edge, fused, kvc in named:
-        # the instance said outright, with the rule's mask; a compute tile
-        # and a kernel without the rows' mask are not the rule's to say
-        inst = chosen._replace(kind=kind, edge=edge, fused=fused)
-        flash._tiles = tiles_with(kvc) if kvc else rule_tiles
+        # the instance said outright, with the rule's mask; a kernel
+        # without the rows' mask is not the rule's to say
+        inst = chosen._replace(kind=kind, edge=edge, fused=fused, kvc=kvc)
         flash._row_kernel = (
             (lambda kernel, seg: kernel) if name.endswith("_static")
             else row_kernel
@@ -209,7 +207,7 @@ def main():
         except Exception as e:  # noqa: BLE001 — mosaic raises various types
             print(f"{name}: refused ({type(e).__name__}: {str(e)[:160]})",
                   flush=True)
-    flash._tiles, flash._row_kernel = rule_tiles, row_kernel
+    flash._row_kernel = row_kernel
     flash._make_kernel.cache_clear()
     ref, masked = None, {}
     # warm; hold the kernel to the XLA form, and the static kernel to the
