@@ -63,9 +63,9 @@ class PPOActor:
         if temp not in self._logp_hooks:
 
             def hook(logits, arrays, _temp=temp):
-                return F.target_aligned_logprobs(
-                    logits, arrays, temperature=_temp
-                )
+                # slot-aligned on a block-diffusion policy's rows; the
+                # engine reads scored slots back into per-token order
+                return F.token_logprobs(logits, arrays, temperature=_temp)
 
             self._logp_hooks[temp] = hook
         return self._logp_hooks[temp]
@@ -281,7 +281,7 @@ class PPOActor:
 
             def grpo_loss_fn(logits, arrays):
                 """reference ppo/actor.py:292 `grpo_loss_fn`."""
-                newlogp = F.target_aligned_logprobs(
+                newlogp = F.token_logprobs(
                     logits, arrays, temperature=cfg.temperature
                 )
                 old_logp = arrays["t_logprobs"].astype(jnp.float32)

@@ -47,7 +47,10 @@ from areal_tpu.models.transformer import (
     param_logical_axes,
 )
 from areal_tpu.ops import flash as flash_ops
-from areal_tpu.ops.functional import target_aligned_logprobs
+from areal_tpu.ops.functional import (
+    slot_aligned_logprobs,
+    target_aligned_logprobs,
+)
 from areal_tpu.ops.ring_attention import attention_fn
 from areal_tpu.parallel import distributed as distributed_lib
 from areal_tpu.parallel import mesh as mesh_lib
@@ -311,6 +314,8 @@ class SPMDTrainEngine(TrainEngine):
             self._counters.update(attn_tiles=0, attn_tiles_kept=0)
         # what the model's mechanisms declare (``transformer.MECHANISMS``)
         self._counters.update(dict.fromkeys(self._count_names(), 0))
+        if mc.block_diffusion is not None:  # counted where rows are packed
+            self._counters.update(bd_sequence_tokens=0, bd_stream_slots=0)
         self._span_attrs = transformer.span_attrs(mc, self._experts_split())
         (self.moe_impl, self.dsa_select_impl, self.kda_impl,
          self.prep_impl) = map(
@@ -405,7 +410,11 @@ class SPMDTrainEngine(TrainEngine):
         ``attn_tiles_kept`` (the tiles on and under the diagonal of the rows
         dispatched, recompute and train alike, at the edge the
         full-attention layers' kernel walks them, and those of them it
-        computes: ``ops.flash.tile_counts``, a row each and not a layer).
+        computes: ``ops.flash.tile_counts``, a row each and not a layer);
+        for a block-diffusion policy, whose ``real_tokens`` are SLOTS,
+        ``bd_sequence_tokens`` (the sequences' own tokens) and
+        ``bd_stream_slots`` (the noisy streams' slots), recompute and train
+        alike as ``real_tokens`` is.
         Their per-step increments are the stats plane's ``spmd/<name>``."""
         return dict(self._counters)
 
@@ -470,16 +479,26 @@ class SPMDTrainEngine(TrainEngine):
             min_n_mbs=self.config.mb_spec.n_mbs,
             n_rows=self._dp_rows(), quantum=self._row_quantum(),
         )
+        bd = self.model_config.block_diffusion
+        if bd is not None and "denoise_state" not in input_:
+            raise ValueError(
+                f"family {self.model_config.family!r} generates by diffusion "
+                "over blocks: its batches carry a per-token denoise_state "
+                "(the state of the decoding trace in which each completion "
+                "token was revealed, -1 on the prompt); without one the rows "
+                "would run causally, which the model was never trained to"
+            )
+        bd_block = bd.block_length if bd is not None else 0
         mbs = data_utils.split_padded_batch_into_mb_list(
             input_ if rows is None else data_utils.batch_select(input_, rows),
-            **spec,
+            bd_block=bd_block, **spec,
         )
         if rows is not None:
             rows = np.asarray(rows, np.int64)
             mbs.groups = [rows[g].tolist() for g in mbs.groups]
             mbs.forward_indices = rows[mbs.forward_indices].tolist()
             whole = max(data_utils.split_lens(
-                np.asarray(input_["attention_mask"]).sum(1), **spec
+                data_utils.row_lens_of(input_, bd_block), **spec
             )[1], default=0)
             if max(mbs.row_lens, default=0) > whole:
                 # first-fit is not monotone; no cell's layout does this
@@ -501,9 +520,11 @@ class SPMDTrainEngine(TrainEngine):
         ``pad_to`` is the row length the split gave the microbatch; without
         it the pack finds the same length by the same rule."""
         with tracing.span("train.pack", step=self.step_count) as sp:
+            bd = self.model_config.block_diffusion
             packed = data_utils.pack_batch_rows(
                 mb, n_rows=self._dp_rows(), quantum=self._row_quantum(),
                 pad_to=pad_to,
+                bd=bd and (bd.block_length, bd.mask_token_id),
             )
             arrays: Dict[str, Any] = dict(
                 tokens=packed.tokens,
@@ -552,6 +573,22 @@ class SPMDTrainEngine(TrainEngine):
             stats_tracker.counter(**{
                 "spmd/real_tokens": real, "spmd/padded_tokens": padded,
             })
+            if bd is not None:
+                # a block-diffusion policy's rows: ``real_tokens`` counts
+                # SLOTS; the sequences' own tokens and the noisy streams'
+                # slots are counted beside them, each once a pass of a
+                # program as they are
+                streams = packed.per_token["bd_stream"]
+                counted = {
+                    "bd_sequence_tokens": int(packed.seq_lens.sum()),
+                    "bd_stream_slots": int(np.count_nonzero(streams)),
+                }
+                for k, n in counted.items():
+                    self._counters[k] += n
+                stats_tracker.counter(
+                    **{f"spmd/{k}": n for k, n in counted.items()}
+                )
+                sp.set(stream_slots=counted["bd_stream_slots"])
         return packed, dev
 
     # ------------------------------------------------------------------
@@ -574,6 +611,15 @@ class SPMDTrainEngine(TrainEngine):
             self._counters[k] += n
         stats_tracker.counter(**{f"spmd/{k}": n for k, n in counted.items()})
         return counted
+
+    @staticmethod
+    def _bd_states(packed: data_utils.PackedRows) -> Dict[str, int]:
+        """What a dispatch span says of a block-diffusion policy's rows: K,
+        the most denoising states a sequence of them has."""
+        streams = packed.per_token.get("bd_stream")
+        return {} if streams is None else {
+            "bd_states": int(streams.max(initial=0))
+        }
 
     def _forward_values(self, _unused: int = 0) -> Dict[str, Any]:
         """What every forward program of ``engine/programs.py`` is built
@@ -698,7 +744,7 @@ class SPMDTrainEngine(TrainEngine):
                     "train.grad_dispatch", step=step,
                     attn_impl=self.attn_impl,
                     **self._count_attn_tiles(packed.segment_ids),
-                    **self._span_attrs,
+                    **self._span_attrs, **self._bd_states(packed),
                     **transformer.grad_span_attrs(
                         self.model_config, self.attn_impl, row_len,
                         self.splash_block or 0,
@@ -836,7 +882,11 @@ class SPMDTrainEngine(TrainEngine):
         rows: Optional[Sequence[int]] = None,
     ) -> np.ndarray:
         mbs = self._split(input_, backward=False, rows=rows)
-        hook = post_hook or target_aligned_logprobs
+        # a block-diffusion policy's rows score a token at its own slot
+        own = self.model_config.block_diffusion is not None
+        hook = post_hook or (
+            slot_aligned_logprobs if own else target_aligned_logprobs
+        )
 
         def dispatched():
             for mb, row_len in zip(mbs.mbs, mbs.row_lens):
@@ -848,7 +898,7 @@ class SPMDTrainEngine(TrainEngine):
                     "train.logp_dispatch", step=step,
                     attn_impl=self.attn_impl,
                     **self._count_attn_tiles(packed.segment_ids),
-                    **self._span_attrs,
+                    **self._span_attrs, **self._bd_states(packed),
                 ):
                     vals = logp_fn(self.params, arrays)
                 yield packed, vals

@@ -152,6 +152,19 @@ class RouterConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class BlockDiffusionConfig:
+    """A model that generates by diffusion over blocks (the ``sdar_moe``
+    family; BD3-LM's training layout, arXiv:2503.09573): a token at position
+    p of its sequence is in block ``p // block_length``, the prompt counted
+    from position 0; a masked slot holds ``mask_token_id`` and predicts its
+    OWN token (no shift). What a row holds and what a query sees is
+    ``utils/data.pack_bd_rows`` and ``ops/bd_attention.py``."""
+
+    block_length: int
+    mask_token_id: int
+
+
+@dataclasses.dataclass(frozen=True)
 class ModelConfig:
     vocab_size: int
     hidden_size: int
@@ -236,6 +249,10 @@ class ModelConfig:
     # trunk, the embedding and the head enter it under ``stop_gradient`` ---
     mtp_layers: int = 0
     mtp_loss_coef: float = 0.0
+    # --- generation by diffusion over blocks: the trainer's rows then hold
+    # a clean stream and its masked copies under a mask that is causal
+    # between blocks and two-way inside one (None: a causal model) ---
+    block_diffusion: Optional[BlockDiffusionConfig] = None
 
     @property
     def q_dim(self) -> int:
@@ -356,12 +373,23 @@ class ModelConfig:
 # (the published rope columns are interleaved, a loader's permutation: ROADMAP
 # B.15) and sequence parallelism (the module's one-token shift across a
 # ring's borders is not built) refuse it by name.
+# sdar_moe is a stack of ONE block kind, ``full+moe`` (``_sdar_from_hf``):
+# the qwen3-moe block (rotary GQA over the whole head, q and k normed a head,
+# softmax-routed experts renormalised, no shared one) trained as a
+# BLOCK-DIFFUSION policy (``BlockDiffusionConfig``): a trainer's row holds
+# each sequence's clean stream and a masked copy a denoising state, and the
+# core's mask is causal between blocks of ``block_length`` and two-way inside
+# one (``ops/bd_attention.py``). The trainer runs it on batches that carry a
+# per-token ``denoise_state``; the generation engine (a step yields other
+# than one token a sequence: ROADMAP B), ``hf_io`` (a share of the experts is
+# no file of its own) and sequence parallelism refuse it by what the
+# ``block_diffusion`` mechanism declares.
 # gemma2/gpt2 remain out (gemma2: soft-capped scores and post-norms beside
 # its windows; gpt2: learned positions, a new embedding).
 _HF_FAMILIES = (
     "llama", "qwen2", "qwen3", "mistral", "qwen3_moe", "mixtral",
     "qwen2_vl", "qwen2_moe", "gemma", "olmoe", "KeyeVL2", "kimi_linear",
-    "laguna", "zaya", "mellum", "qwen3_next", "joyai_llm_flash",
+    "laguna", "zaya", "mellum", "qwen3_next", "joyai_llm_flash", "sdar_moe",
 )
 
 
@@ -649,6 +677,63 @@ def _mellum_from_hf(d: dict) -> ModelConfig:
     )
 
 
+def _sdar_from_hf(d: dict) -> ModelConfig:
+    """``model_type`` sdar_moe: the qwen3-moe key set (every layer rotary GQA
+    over the whole head at ``rope_theta``, q and k normed a head, then
+    ``num_experts`` softmax-routed experts, ``num_local_experts`` of them
+    held here where the file says so) of a model that generates by diffusion
+    over blocks. The published config has no key for the block's length or
+    the mask token: ``block_length`` (the family's released default, 4) and
+    ``mask_token_id`` are read where a file states them."""
+    if d.get("mlp_only_layers") or d.get("decoder_sparse_step", 1) != 1:
+        raise ValueError(
+            "sdar_moe with mlp_only_layers / decoder_sparse_step != 1 is "
+            "not supported: every layer is the one sparse block"
+        )
+    if d.get("attention_bias", False) or d.get("rope_scaling"):
+        raise ValueError(
+            "sdar_moe with attention_bias or rope_scaling is not supported"
+        )
+    if d.get("use_sliding_window", False):
+        raise ValueError("sdar_moe with a sliding window is not supported")
+    if "mask_token_id" not in d:
+        raise ValueError(
+            "sdar_moe: the configuration must state mask_token_id (the "
+            "published config.json has no key for it; the family's <|MASK|> "
+            "is an added token)"
+        )
+    heads, num_experts = d["num_attention_heads"], d["num_experts"]
+    held = d.get("num_local_experts", num_experts)
+    theta = float(d.get("rope_theta", 1000000.0))
+    return ModelConfig(
+        vocab_size=d["vocab_size"],
+        hidden_size=d["hidden_size"],
+        intermediate_size=d["intermediate_size"],
+        num_layers=d["num_hidden_layers"],
+        num_heads=heads,
+        num_kv_heads=d["num_key_value_heads"],
+        head_dim=d.get("head_dim") or d["hidden_size"] // heads,
+        max_position_embeddings=d.get("max_position_embeddings", 32768),
+        rope_theta=theta,
+        rms_norm_eps=d.get("rms_norm_eps", 1e-6),
+        tie_word_embeddings=d.get("tie_word_embeddings", False),
+        qk_norm="head",
+        family="sdar_moe",
+        num_experts=num_experts,
+        num_experts_per_tok=d["num_experts_per_tok"],
+        moe_intermediate_size=d["moe_intermediate_size"],
+        norm_topk_prob=d.get("norm_topk_prob", True),
+        router_aux_loss_coef=d.get("router_aux_loss_coef", 0.0),
+        experts_held=0 if held == num_experts else held,
+        layer_kinds=("full+moe",) * d["num_hidden_layers"],
+        full=RotaryConfig(num_heads=heads, rope_theta=theta),
+        block_diffusion=BlockDiffusionConfig(
+            block_length=int(d.get("block_length", 4)),
+            mask_token_id=int(d["mask_token_id"]),
+        ),
+    )
+
+
 def _zaya_from_hf(d: dict) -> ModelConfig:
     """``model_type`` zaya: every ``layer_types`` entry ``hybrid`` = one
     ``cca`` attention sublayer (``cca_time0`` / ``cca_time1`` the two
@@ -912,6 +997,8 @@ def from_hf_config(d: dict) -> ModelConfig:
         return _qwen3_next_from_hf(d)
     if model_type == "joyai_llm_flash":
         return _deepseek_v3_from_hf(d)
+    if model_type == "sdar_moe":
+        return _sdar_from_hf(d)
     num_heads = d["num_attention_heads"]
     hidden = d["hidden_size"]
     head_dim = d.get("head_dim") or hidden // num_heads
@@ -1103,6 +1190,23 @@ def tiny_config(family: str = "qwen2", vocab_size: int = 128) -> ModelConfig:
             full=RotaryConfig(
                 num_heads=4, rotary_dim=4, rope_theta=10000000.0,
                 output_gate=True,
+            ),
+        )
+    if family == "sdar_moe":
+        # two layers of the one block: 4 query heads of 16 over 2 key heads,
+        # q and k normed a head, 8 experts of which 4 held, two a token;
+        # blocks of 4, the mask token the vocabulary's last id
+        return ModelConfig(
+            vocab_size=vocab_size, hidden_size=64, intermediate_size=64,
+            num_layers=2, num_heads=4, num_kv_heads=2, head_dim=16,
+            max_position_embeddings=512, rope_theta=1000000.0,
+            rms_norm_eps=1e-6, qk_norm="head", family=family, num_experts=8,
+            num_experts_per_tok=2, moe_intermediate_size=32,
+            norm_topk_prob=True, router_aux_loss_coef=0.0, experts_held=4,
+            layer_kinds=("full+moe", "full+moe"),
+            full=RotaryConfig(num_heads=4, rope_theta=1000000.0),
+            block_diffusion=BlockDiffusionConfig(
+                block_length=4, mask_token_id=vocab_size - 1
             ),
         )
     if family == "mellum":

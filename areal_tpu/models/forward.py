@@ -66,6 +66,8 @@ def packed_forward(
         )
         if "t_mrope_pos" in arrays:
             positions = arrays["t_mrope_pos"].astype(jnp.int32)
+    if "t_bd_stream" in arrays:  # a block-diffusion policy's rows
+        kwargs["bd"] = (arrays["t_bd_stream"], arrays["t_bd_scored"])
     return transformer.apply(
         params,
         cfg,

@@ -145,7 +145,7 @@ def _kind_tensor_names(cfg: ModelConfig):
     stack, expert or None, hf name, how stored) of every layer tensor."""
     from areal_tpu.models.transformer import _kind_leaves
 
-    if cfg.family in ("laguna", "zaya", "mellum"):
+    if cfg.family in ("laguna", "zaya", "mellum", "sdar_moe"):
         raise NotImplementedError(
             f"family {cfg.family!r} has no checkpoint layout here: its "
             "tensor names are in no public source this repo was written "

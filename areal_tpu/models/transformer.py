@@ -41,6 +41,7 @@ import numpy as np
 from jax import ad_checkpoint as _ad_checkpoint
 
 from areal_tpu.models.config import ModelConfig
+from areal_tpu.ops import bd_attention as bd_ops
 from areal_tpu.ops import conv_prep as conv_prep_ops
 from areal_tpu.ops import flash as flash_ops
 from areal_tpu.ops import kda as kda_ops
@@ -403,6 +404,45 @@ def _cca_counts(cfg, reported, segment_ids, positions):
     )
 
 
+def _bd_counts(cfg, reported, segment_ids, positions):
+    """int32 [rows] each, a ROW's and not a layer's (every layer attends
+    the same pairs): the pairs M keeps (a query's clean keys before its
+    block, ``block * block_length`` of them, and the slots of its own stream
+    and block, itself among them), the pairs a causal model would keep over
+    the sequences' own tokens, the tokens scored. From the rows' (sequence,
+    position, stream) on the device (scope ``bd_layout``)."""
+    streams, scored = reported["block_diffusion"]
+    size = cfg.block_diffusion.block_length
+    with device_scope("bd_layout"):
+        real = segment_ids > 0
+        pos = positions.astype(jnp.int32)
+        code = bd_ops.slot_codes(segment_ids, pos // size, streams)[0]
+        starts = jnp.pad(
+            code[:, 1:] != code[:, :-1], ((0, 0), (1, 0)),
+            constant_values=True,
+        )
+        # the slots of a (sequence, stream, block) lie side by side
+        groups = jax.vmap(functools.partial(
+            jax.ops.segment_sum, num_segments=code.shape[1]
+        ))(real.astype(jnp.int32), jnp.cumsum(starts, axis=1) - 1)
+        before = jnp.where(real, pos // size * size, 0).sum(-1)
+        return (
+            before + (groups * groups).sum(-1),
+            jnp.where(real & (streams == 0), pos + 1, 0).sum(-1),
+            (scored > 0).sum(-1).astype(jnp.int32),
+        )
+
+
+def _bd_tiles(cfg, attn_impl, row_len, block_cap):
+    """The kernels' tile a grad dispatch of ``row_len``-slot rows runs
+    (``ops.flash.instance``); nothing where the XLA form takes the row."""
+    inst = flash_ops.instance(
+        row_len, cfg.head_dim, block_cap=block_cap, heads=cfg.num_heads,
+        block_diffusion=True,
+    )
+    return {"bd_tile": inst.kind and f"{inst.edge}x{inst.kvc}"}
+
+
 def _swa_backwards(cfg, attn_impl, row_len, block_cap):
     """The backward the window layers' kernel instance took
     (``ops.flash.backward_form``); nothing where another attention runs."""
@@ -475,6 +515,41 @@ MECHANISMS: Dict[str, Mechanism] = {
             "(ROADMAP B); the trainer can (attn_impl 'sparse_topk')"
         ),
         span_attrs=lambda cfg: {"dsa_select_impl": dsa_ops.select_impl()},
+    ),
+    # a model that generates by diffusion over blocks (``ModelConfig.
+    # block_diffusion``): a trainer's rows hold a clean stream and its masked
+    # copies (``utils/data.pack_batch_rows``), the core's mask is causal
+    # between blocks and two-way inside one (``ops/bd_attention.py``). Before
+    # the mixers' entries: its refusals are the model's
+    "block_diffusion": Mechanism(
+        counts={
+            "bd_pairs": "the (query, key) pairs the mask keeps, a row's "
+            "(every layer attends them)",
+            "bd_causal_pairs": "the pairs a causal model would keep over "
+            "the same sequences' own tokens",
+            "bd_scored_tokens": "the tokens scored, each once, at the slot "
+            "of its own denoising state",
+        },
+        count=_bd_counts,
+        forced_attn="block_diffusion",
+        no_seq_parallel=lambda cfg: (
+            f"a block-diffusion policy's rows (family {cfg.family!r}: a "
+            "noisy stream's queries read clean keys a stream away) have no "
+            "sequence-parallel form (seq_parallel_size={seq_parallel_size})"
+            ": ROADMAP B"
+        ),
+        no_generation=lambda cfg: (
+            "the generation engine cannot run a model that generates by "
+            f"diffusion over blocks (family {cfg.family!r}, blocks of "
+            f"{cfg.block_diffusion.block_length}): a step yields other than "
+            "one token a sequence, and its scheduler, cache and metrics "
+            "assume one (ROADMAP B); the trainer can, on batches that carry "
+            "a per-token denoise_state"
+        ),
+        span_attrs=lambda cfg: {
+            "bd_block": cfg.block_diffusion.block_length,
+        },
+        grad_span_attrs=_bd_tiles,
     ),
     "swa": Mechanism(
         counts={
@@ -596,6 +671,8 @@ def _mechanisms(cfg: ModelConfig, split=False) -> Tuple[Mechanism, ...]:
         has.add("moe_held")
     if cfg.trains_draft:
         has.add("mtp")
+    if cfg.block_diffusion is not None:
+        has.add("block_diffusion")
     return tuple(m for name, m in MECHANISMS.items() if name in has)
 
 
@@ -1191,7 +1268,7 @@ def _head_norm_in_rotation(cfg, lp, t):
     return lambda name: (weight(name), cfg.rms_norm_eps) if fused else None
 
 
-def _rotary_mixer(cfg, rc, h, lp, segment_ids, cos, sin, attend_fn):
+def _rotary_mixer(cfg, rc, h, lp, segment_ids, cos, sin, attend_fn, bd=None):
     """Rotary GQA attention on the normed input ``h``, as ``rc`` (a
     ``RotaryConfig``) describes this kind: its own query heads, rotation
     (``cos``/``sin`` of the tokens' positions, made once a kind in
@@ -1199,7 +1276,9 @@ def _rotary_mixer(cfg, rc, h, lp, segment_ids, cos, sin, attend_fn):
     k normed a head before the rotation where ``cfg.qk_norm`` is "head". A
     window layer's core is scope ``attn_window``, not ``attention``; the
     norms, the rotation and the gate are outside both, as the projections
-    are."""
+    are. ``bd``: a block-diffusion policy's rows, (blocks, streams) of the
+    slots; the core then runs under their mask (scope ``attn_bd``, not
+    ``attention``; ``ops/bd_attention.py``)."""
     b, t, _ = h.shape
     H, dh = rc.num_heads, cfg.head_dim
     uo = cfg.norm_add_unit_offset
@@ -1226,14 +1305,20 @@ def _rotary_mixer(cfg, rc, h, lp, segment_ids, cos, sin, attend_fn):
     k = rotated("wk", cfg.num_kv_heads, "k_norm")
     with device_scope("qkv_proj"):
         v = (h @ lp["wv"]).reshape(b, t, cfg.num_kv_heads, dh)
-    with device_scope("attn_window" if rc.window else "attention"):
-        if attend_fn is None:
-            attn = segment_attention(
-                q, k, v, segment_ids, causal=True, window=rc.window
+    if bd is not None:
+        with device_scope("attn_bd"):
+            attn = (attend_fn or bd_ops.bd_segment_attention)(
+                q, k, v, segment_ids, *bd
             )
-        else:  # only a window layer hands a window in
-            own = {"layer_window": rc.window} if rc.window else {}
-            attn = attend_fn(q, k, v, segment_ids, **own)
+    else:
+        with device_scope("attn_window" if rc.window else "attention"):
+            if attend_fn is None:
+                attn = segment_attention(
+                    q, k, v, segment_ids, causal=True, window=rc.window
+                )
+            else:  # only a window layer hands a window in
+                own = {"layer_window": rc.window} if rc.window else {}
+                attn = attend_fn(q, k, v, segment_ids, **own)
     attn = _ad_checkpoint.checkpoint_name(attn, "attn_out")
     if rc.head_gate:
         with device_scope("attn_gate"):
@@ -1385,7 +1470,7 @@ def _cca_block(cfg, carry, lp, segment_ids, rotation, attend_fn, moe_fn):
 
 def _block_body(
     cfg, kind, x, lp, segment_ids, attend_fn=None, moe_fn=None, kda_fn=None,
-    rotation=None,
+    rotation=None, bd=None,
 ):
     """One layer of block ``kind`` (``ModelConfig.layer_kinds``).
     ``rotation``: a rotary kind's (cos, sin). ``x`` is the scan's carry: the
@@ -1409,7 +1494,7 @@ def _block_body(
     elif mixer in _ROTARY_MIXERS:
         y = _rotary_mixer(
             cfg, getattr(cfg, mixer), h, lp, segment_ids, *rotation,
-            attend_fn,
+            attend_fn, bd,
         )
     else:
         y = _mla_mixer(cfg, h, lp, segment_ids, attend_fn, rotation)
@@ -1479,7 +1564,7 @@ def _rotations(cfg, positions):
 
 def _apply_kinds(
     cfg, params, x, segment_ids, positions, remat, attend_fn, moe_fn, kda_fn,
-    act_sharding,
+    act_sharding, bd=None,
 ):
     """The stack of a model of several block kinds: every run of consecutive
     layers of one kind is a scan over that kind's slice of its stack.
@@ -1501,6 +1586,13 @@ def _apply_kinds(
             for kind, stack in params["layers"].items()
         }
     reported = {}
+    bd_rows = None
+    if bd is not None:  # (streams, scored) -> what a core takes, what counts
+        reported["block_diffusion"] = bd
+        bd_rows = (
+            positions.astype(jnp.int32) // cfg.block_diffusion.block_length,
+            bd[0],
+        )
     if cfg.router is not None:
         # the router's state beside the stream, zeros into the first layer
         x = (x, jnp.zeros(
@@ -1515,7 +1607,7 @@ def _apply_kinds(
         def body(carry, lp, kind=kind):
             out, aux = _block_body(
                 cfg, kind, carry, lp, segment_ids, attend_fn, moe_fn, kda_fn,
-                rotations.get(kind.split("+")[0]),
+                rotations.get(kind.split("+")[0]), bd_rows,
             )
             if act_sharding is not None:  # the carried state's too
                 out = jax.tree_util.tree_map(
@@ -1568,6 +1660,8 @@ def apply(
     mm_index: Optional[jnp.ndarray] = None,  # [B, T] int32; -1 = text
     return_hidden: bool = False,  # lazy ChunkedLogits instead of [B,T,V]
     act_sharding: Optional[Any] = None,  # NamedSharding for [B, T, D] acts
+    # a block-diffusion policy's rows: (streams, scored), [B, T] each
+    bd: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,
 ):
     """Forward to logits [B, T, vocab] (fp32); with ``return_stats=True``
     returns (logits, ``LayerStats`` of the whole stack: the load-balancing
@@ -1594,12 +1688,27 @@ def apply(
     (image-pad tokens), else its text embedding — differentiable through
     the vision tower (reference: HF VLM inputs_embeds masked-scatter).
 
+    ``bd``: the rows of a model that generates by diffusion over blocks
+    (``ModelConfig.block_diffusion``) hold each sequence's clean stream and
+    its masked copies (``utils/data.pack_batch_rows(bd=)``): a slot's stream
+    (0 clean, 1..K) and whether its token is scored there. Such a model runs
+    no other rows: without them it would attend causally to inputs it was
+    never trained on, and ``apply`` refuses.
+
     ``act_sharding`` pins the [B, T, D] activation layout (rows over
     (data, fsdp), tokens over seq). Without the constraint GSPMD is free
     to propagate the embedding table's column sharding onto the batch —
     replicating activations across the fsdp axis (measured: a 7B/16-dev
     AOT lowering allocated 81 GB of per-device layer temps).
     """
+    if (cfg.block_diffusion is None) != (bd is None):
+        raise ValueError(
+            "a model that generates by diffusion over blocks runs rows that "
+            "hold a clean stream and its masked copies, and no other model "
+            "does: the batch must carry a per-token denoise_state "
+            f"(family {cfg.family!r}, bd rows "
+            f"{'given' if bd is not None else 'missing'})"
+        )
     with device_scope("rope"):  # the tables, made once a program
         cos, sin = rope_frequencies(
             cfg.head_dim, cfg.max_position_embeddings, cfg.rope_theta
@@ -1622,7 +1731,7 @@ def apply(
     if cfg.layer_kinds:
         x, stats = _apply_kinds(
             cfg, params, x, segment_ids, positions, remat, attend_fn, moe_fn,
-            kda_fn, act_sharding,
+            kda_fn, act_sharding, bd,
         )
         logits = _head(params, cfg, x, return_hidden)
         return (logits, stats) if return_stats else logits

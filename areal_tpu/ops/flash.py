@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from areal_tpu.ops import band_attention
+from areal_tpu.ops import band_attention, bd_attention
 from areal_tpu.ops.basic import segment_attention
 from jax.experimental.pallas.ops.tpu.splash_attention import (
     splash_attention_kernel as _sk,
@@ -178,7 +178,9 @@ def resolve_attn_impl(
 
 class Instance(NamedTuple):
     """Which attention a row runs (``instance``): ``kind`` "" the XLA form's
-    row | "strip" the band's own kernels | "splash"; ``back``, the keys behind
+    row | "strip" the band's own kernels | "splash" | "bd" a block-diffusion
+    row's own kernels (``edge`` their query block, ``kvc`` their key block,
+    ``ops/bd_attention.py``); ``back``, the keys behind
     a query its static mask keeps beside the query's own (0: causal);
     ``edge``, the tile edge (the strip kernels' query block; 0 under XLA);
     ``fused``, the splash kernel's one backward kernel or its two; ``kvc``,
@@ -201,7 +203,7 @@ class Instance(NamedTuple):
 
 def instance(
     t: int, d: int, layer_window: int = 0, block_cap: int = 0,
-    interpret: bool = False, *, heads: int,
+    interpret: bool = False, *, heads: int, block_diffusion: bool = False,
 ) -> Instance:
     """THE rule: which kernel instance a row of ``t`` tokens with ``heads``
     query heads ``d`` wide runs in a layer whose window is ``layer_window``
@@ -292,7 +294,43 @@ def instance(
       at 256; at edge 1024 a compute tile of 1024 is within 0.8% of 512 at
       every width), but for the one case above.
 
+    ``block_diffusion``: the row holds a clean stream and its masked copies
+    under the mask that is causal between blocks and two-way inside one
+    (``ops/bd_attention.py``: no static mask of the splash kernel says it,
+    and a noisy query's clean keys lie a stream away, so the row takes
+    kernels of its own wherever it is whole tiles of theirs, heads 128 wide;
+    else the XLA form). Measured on the v5e on the block-diffusion cell's own
+    packed rows of 16,384 slots, 32 / 4 heads of 128 (my chip run, PR 58,
+    ``chiprun_in/bd_sweep.py``; real slots and sequences a row; forward |
+    forward + backward ms a layer, by query x key slots a tile; the backward
+    is the SPLIT pair dq + dkv, the one form there is):
+
+    ================ ============== ============== ==============
+    tile             15,648, 1 seq  15,328, 1 seq  15,852, 2 seqs
+    ================ ============== ============== ==============
+    128 x 512        10.82 | 35.84  10.06 | 33.24  10.51 | 34.39
+    256 x 256         9.94 | 34.38   9.13 | 31.53  10.06 | 34.47
+    256 x 512         9.34 | 32.01   8.55 | 29.16   9.53 | 32.01
+    512 x 256         9.30 | 32.93   8.70 | 30.50   9.39 | 32.94
+    512 x 512         8.91 | 30.84   8.22 | 28.43   9.63 | 32.94
+    256 x 1024        9.81 | 33.32   9.00 | 30.41  10.03 | 33.27
+    512 x 1024        9.74 | 33.35   9.15 | 31.21  10.59 | 35.47
+    ================ ============== ============== ==============
+
+    so 256 x 512 (``bd_attention.BQ``, ``BK``): within 3.7% of the best on
+    every row; 512 x 512 wins a one-sequence row by 2.5-3.6% and loses a
+    two-sequence row by 2.9% (a larger tile keeps more of the border tiles
+    that hold few pairs: 244 of 1,024 tiles kept where 256 x 512 keeps 461
+    of 2,048), and a pass's rows hold one to three sequences.
+
     The edge is never above ``block_cap``, always a divisor of ``t``."""
+    if block_diffusion:
+        bq, bk = bd_attention.BQ, bd_attention.BK
+        fits = d == _LANES and t % bq == 0 and t % bk == 0
+        on_chip = interpret or jax.default_backend() == "tpu"
+        if fits and on_chip:
+            return Instance("bd", 0, bq, False, bk)
+        return Instance("", 0, 0, False, 0)
     if t % _LANES:
         # not whole tiles of 128 tokens: short, its quadratic scores small
         return Instance("", 0, 0, False, 0)
@@ -485,6 +523,36 @@ def flash_segment_attention(
         )
     run = _strip_band if inst.kind == "strip" else _splash
     return run(q, k, v, segment_ids, inst, interpret)
+
+
+def flash_bd_attention(
+    q: jnp.ndarray,  # [B, T, Hq, D]
+    k: jnp.ndarray,  # [B, T, Hkv, D]
+    v: jnp.ndarray,
+    segment_ids: jnp.ndarray,  # [B, T]
+    blocks: jnp.ndarray,  # [B, T]: a slot's position over the block length
+    streams: jnp.ndarray,  # [B, T]: 0 the clean stream, 1..K a noisy one
+    interpret: bool = False,
+    block_cap: int = 0,
+) -> jnp.ndarray:
+    """A block-diffusion policy's training rows through the instance
+    ``instance`` gives them: the kernels of ``ops/bd_attention.py`` on a TPU
+    (q, k, v as the projections made them, a reshape either side), the plain
+    XLA form under the same mask elsewhere."""
+    b, t, hq, d = q.shape
+    hkv = k.shape[2]
+    inst = instance(
+        t, d, 0, block_cap, interpret, heads=hq, block_diffusion=True
+    )
+    if not inst.kind:
+        return bd_attention.bd_segment_attention(
+            q, k, v, segment_ids, blocks, streams
+        )
+    return bd_attention.bd_attention(
+        q.reshape(b, t, hq * d), k.reshape(b, t, hkv * d),
+        v.reshape(b, t, hkv * d), segment_ids, blocks, streams, (hq, hkv),
+        inst.edge, inst.kvc, interpret,
+    ).reshape(q.shape)
 
 
 def _splash(q, k, v, segment_ids, inst: Instance, interpret: bool):

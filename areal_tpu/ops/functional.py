@@ -86,6 +86,56 @@ def target_aligned_logprobs(
     return jnp.where(prev_same, out, 0.0)
 
 
+def slot_aligned_logprobs(
+    logits, arrays: Dict, temperature: float = 1.0
+) -> jnp.ndarray:
+    """Logprobs aligned to the SLOT, for a block-diffusion policy's rows
+    (``utils/data.pack_batch_rows(bd=)``): out[i] = log p(the token slot i
+    stands for | what slot i's stream shows), NO shift (a masked slot
+    predicts its own token), at the slots where a token is scored (the
+    stream of its own denoising state) and 0 elsewhere; the lazy view's head
+    is run at those slots only. The host reads them
+    back into per-token order (``utils/data.unpack_rows_per_token``), where
+    they line up with the target-aligned arrays index for index."""
+    from areal_tpu.ops.chunked_head import ChunkedLogits
+
+    labels, scored = arrays["t_bd_labels"], arrays["t_bd_scored"] > 0
+    if not isinstance(logits, ChunkedLogits):
+        logp = gather_logprobs(logits, labels, temperature=temperature)
+        return jnp.where(scored, logp, 0.0)
+    # the head runs over the scored slots alone: a sequence's completion is
+    # scored once and held at least twice (its clean stream, a noisy stream a
+    # state), so they are at most half a row's slots: a static bound
+    rows, t = labels.shape
+    at = jax.vmap(
+        lambda m: jnp.nonzero(m, size=t // 2, fill_value=0)[0]
+    )(scored)
+    on = jnp.arange(t // 2)[None, :] < scored.sum(-1, keepdims=True)
+    logp = gather_logprobs(
+        ChunkedLogits(
+            jnp.take_along_axis(logits.hidden, at[..., None], axis=1),
+            logits.head, logits.vocab_axis,
+        ),
+        jnp.take_along_axis(labels, at, axis=1), temperature=temperature,
+    )
+    return jnp.zeros(labels.shape, logp.dtype).at[
+        jnp.arange(rows)[:, None], at
+    ].add(jnp.where(on, logp, 0.0))
+
+
+def token_logprobs(
+    logits, arrays: Dict, temperature: float = 1.0
+) -> jnp.ndarray:
+    """The logprob of each of a packed microbatch's tokens where the row
+    holds it to be scored: slot-aligned on a block-diffusion policy's rows
+    (they carry ``t_bd_labels``), target-aligned on every other. Which is
+    decided as the program is traced: a causal model's program is
+    ``target_aligned_logprobs`` and nothing else."""
+    if "t_bd_labels" in arrays:
+        return slot_aligned_logprobs(logits, arrays, temperature)
+    return target_aligned_logprobs(logits, arrays, temperature)
+
+
 def _two_on(a: jnp.ndarray) -> jnp.ndarray:
     """``a`` [B, T] two slots early: position i holds a[i + 2], zeros at a
     row's last two."""

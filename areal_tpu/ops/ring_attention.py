@@ -241,6 +241,24 @@ def make_sharded_attention(
     return attend
 
 
+def _bd_attention_fn(mesh: Mesh, block_cap: int):
+    """``attend(q, k, v, segment_ids, blocks, streams)`` of a block-diffusion
+    policy's rows (``ops.flash.flash_bd_attention``): each device runs its
+    own rows (and heads), as the splash kernel's mesh form does."""
+    from areal_tpu.ops.flash import flash_bd_attention
+
+    attend = functools.partial(flash_bd_attention, block_cap=block_cap)
+    if mesh.size == 1:
+        return attend
+    qkv_spec = P(("data", "fsdp"), None, "tensor", None)
+    row_spec = P(("data", "fsdp"), None)
+    return jax.shard_map(
+        attend, mesh=mesh,
+        in_specs=(qkv_spec,) * 3 + (row_spec,) * 3, out_specs=qkv_spec,
+        check_vma=False,
+    )
+
+
 def attention_fn(impl: str, mesh: Mesh, block_cap: int = 0, topk: int = 0):
     """The attention callable ``attend(q, k, v, segment_ids)`` for a
     resolved name (``ops.flash.resolve_attn_impl``) on ``mesh``: "flash"
@@ -250,13 +268,17 @@ def attention_fn(impl: str, mesh: Mesh, block_cap: int = 0, topk: int = 0):
     "ring"/"ulysses" (the explicit sequence-parallel ``shard_map``),
     "sparse_topk" (a model with an indexer: ``topk`` selected keys a query,
     ``ops.sparse_attention.make_attend``, which also takes the indexer's
-    arrays), or None for "xla", the XLA kernel with GSPMD auto-sharding."""
+    arrays), "block_diffusion" (a model that generates by diffusion over
+    blocks: ``attend(q, k, v, segment_ids, blocks, streams)``), or None for
+    "xla", the XLA kernel with GSPMD auto-sharding."""
     if impl == "xla":
         return None
     if impl == "sparse_topk":
         from areal_tpu.ops.sparse_attention import make_attend
 
         return make_attend(mesh, topk)
+    if impl == "block_diffusion":
+        return _bd_attention_fn(mesh, block_cap)
     if impl != "flash":
         return make_sharded_attention(mesh, impl=impl)
     from areal_tpu.ops.flash import flash_segment_attention

@@ -29,10 +29,7 @@ from areal_tpu.models.config import ModelConfig
 from areal_tpu.models.transformer import init_params, param_logical_axes
 from areal_tpu.ops.blockwise_attention import blockwise_segment_attention
 from areal_tpu.ops.ring_attention import attention_fn
-from areal_tpu.ops.functional import (
-    ppo_actor_loss_fn,
-    target_aligned_logprobs,
-)
+from areal_tpu.ops.functional import ppo_actor_loss_fn, token_logprobs
 from areal_tpu.parallel import mesh as mesh_lib
 from areal_tpu.parallel import sharding as sharding_lib
 
@@ -65,7 +62,7 @@ def _grpo_loss_fn(logits, arrays):
     """The decoupled PPO loss (behavior + proximal logprobs) at the
     defaults of ``PPOActorConfig``: what the priced grad program ends in."""
     loss, _ = ppo_actor_loss_fn(
-        logprobs=target_aligned_logprobs(logits, arrays),
+        logprobs=token_logprobs(logits, arrays),
         old_logprobs=arrays["t_logprobs"],
         advantages=arrays["t_advantages"],
         eps_clip=0.2,
@@ -376,6 +373,45 @@ def keye_vl_2p0_30b_a3b_config(num_layers: int = 5) -> ModelConfig:
     )
 
 
+def sdar_30b_a3b_chat_config(num_layers: int = 5) -> ModelConfig:
+    """SDAR-30B-A3B-Chat (JetLM; the qwen3-moe block, 32 / 4 heads of 128
+    normed a head, 128 experts of width 768 of which 8 a token, trained as a
+    block-diffusion policy: blocks of 4, rows of a clean stream and its
+    masked copies), as ONE chip of eight that share each layer holds it: 16
+    of the 128 experts, an eighth of the vocabulary, and the cut in depth of
+    benchmark/configs/sdar-30b-a3b-chat.json (published: 48 layers, 151,936
+    rows). Every width, the router's 128 outputs and top-8 are as published;
+    the mask token is the slice's last id."""
+    from areal_tpu.models.config import BlockDiffusionConfig, RotaryConfig
+
+    return ModelConfig(
+        vocab_size=18992,
+        hidden_size=2048,
+        intermediate_size=6144,
+        num_layers=num_layers,
+        num_heads=32,
+        num_kv_heads=4,
+        head_dim=128,
+        max_position_embeddings=32768,
+        rope_theta=1000000.0,
+        rms_norm_eps=1e-6,
+        tie_word_embeddings=False,
+        qk_norm="head",
+        family="sdar_moe",
+        num_experts=128,
+        num_experts_per_tok=8,
+        moe_intermediate_size=768,
+        norm_topk_prob=True,
+        router_aux_loss_coef=0.0,
+        experts_held=16,
+        layer_kinds=("full+moe",) * num_layers,
+        full=RotaryConfig(num_heads=32, rope_theta=1000000.0),
+        block_diffusion=BlockDiffusionConfig(
+            block_length=4, mask_token_id=18991
+        ),
+    )
+
+
 def kimi_linear_48b_a3b_config() -> ModelConfig:
     """Kimi-Linear-48B-A3B-Instruct (moonshotai) as ONE chip of 32 that
     share each layer holds it: the leading dense layer and one whole period
@@ -643,6 +679,7 @@ def joyai_llm_flash_config() -> ModelConfig:
 
 
 MODEL_CONFIGS = {
+    "sdar_30b_a3b_chat": sdar_30b_a3b_chat_config,
     "joyai_llm_flash": joyai_llm_flash_config,
     "qwen3_next_80b_a3b": qwen3_next_80b_a3b_config,
     "mellum2_12b_a2p5b": mellum2_12b_a2p5b_config,

@@ -64,7 +64,7 @@ def pad_sequences_to_tensors(
 # data value: 'versions' uses -1 as the "padding / not generated" sentinel —
 # padding with 0 would masquerade as weight-version-0 tokens under any
 # staleness filter.
-_KEY_PAD_VALUES = {"versions": -1, "mm_index": -1}
+_KEY_PAD_VALUES = {"versions": -1, "mm_index": -1, "denoise_state": -1}
 # per-sequence multimodal payloads: axis 1 is patches, not tokens
 _PER_SEQ_PAYLOAD_KEYS = {
     "pixel_values", "image_grid_thw", "vis_seg", "vis_pos_h", "vis_pos_w",
@@ -338,9 +338,71 @@ def rows_on_ladder(
     )
 
 
+# ------------------------------------------------------------------
+# A block-diffusion policy's rows (``ModelConfig.block_diffusion``)
+# ------------------------------------------------------------------
+# The batch carries a per-token ``denoise_state``: -1 on the prompt (and on
+# padding), s in [0, K) on a completion token that the decoding trace
+# revealed in its block's state s (TraceRL's aggregated steps,
+# arXiv:2509.06949). A sequence then takes MORE slots of a row than it has
+# tokens (BD3-LM's training layout, arXiv:2503.09573): its clean stream, the
+# sequence itself, and a NOISY stream a state, a copy of the sequence from
+# the first block that holds a completion token to its end, at the same
+# positions, with every token not revealed BEFORE that state replaced by the
+# mask token. Token i is scored once: in the stream of its own state, at its
+# own slot. K is a sequence's own (its largest state + 1).
+def _bd_streams(states: np.ndarray, block: int) -> Tuple[int, int]:
+    """(the position a noisy stream starts at, K) of one sequence from its
+    tokens' ``states`` [L]: the first block that holds a completion token,
+    and its largest state + 1; (L, 0) where nothing is to denoise."""
+    at = np.nonzero(states >= 0)[0]
+    if not len(at):
+        return len(states), 0
+    return int(at[0]) // block * block, int(states.max()) + 1
+
+
+def bd_sequence_slots(states: np.ndarray, block: int):
+    """One sequence's slots from its tokens' ``states`` [L]: ``(src, stream,
+    masked, scored)``, [slots] each: the token a slot holds (its position
+    too), its stream (0 clean, s + 1 the noisy stream of state s), whether
+    it holds the mask token, whether its token is scored there."""
+    states = np.asarray(states, np.int64)
+    n = len(states)
+    start, k = _bd_streams(states, block)
+    tail = np.arange(start, n)
+    src = np.concatenate([np.arange(n)] + [tail] * k)
+    stream = np.concatenate(
+        [np.zeros(n, np.int64)]
+        + [np.full(len(tail), s + 1, np.int64) for s in range(k)]
+    )
+    state = states[src]
+    noisy = stream > 0
+    return (
+        src, stream, noisy & (state >= stream - 1),
+        noisy & (state == stream - 1),
+    )
+
+
+def row_lens_of(batch: Batch, bd_block: int = 0) -> np.ndarray:
+    """What each sequence of ``batch`` takes of a row: its tokens, or where
+    ``bd_block`` is a block-diffusion policy's block length (and the batch
+    carries ``denoise_state``) its slots, P + R + K x (R + the border
+    block's prompt tokens)."""
+    mask = np.asarray(batch["attention_mask"]).astype(bool)
+    lens = mask.sum(1).astype(np.int64)
+    if not bd_block or "denoise_state" not in batch:
+        return lens
+    states = np.asarray(batch["denoise_state"])
+    out = lens.copy()
+    for b, n in enumerate(lens):
+        start, k = _bd_streams(states[b, :n], bd_block)
+        out[b] += k * (n - start)
+    return out
+
+
 def split_padded_batch_into_mb_list(
     batch: Batch, max_tokens_per_mb: int, min_n_mbs: int = 1,
-    n_rows: int = 1, quantum: int = ROW_QUANTUM,
+    n_rows: int = 1, quantum: int = ROW_QUANTUM, bd_block: int = 0,
 ) -> MicroBatchList:
     """Rows first: first-fit-decreasing of the sequences into rows of a
     microbatch's share of the token budget (``max_tokens_per_mb // n_rows``;
@@ -357,8 +419,10 @@ def split_padded_batch_into_mb_list(
     PR 30). At least ``min_n_mbs`` microbatches (balanced by tokens where the
     rows make fewer). With one row this is the reference's split of sequences
     under a token budget (data.py:401
-    `split_padded_tensor_dict_into_mb_list`)."""
-    lens = np.asarray(batch["attention_mask"]).sum(1)
+    `split_padded_tensor_dict_into_mb_list`). ``bd_block``: a block-diffusion
+    policy's block length; a sequence then counts its SLOTS against the
+    budget (``row_lens_of``), and no row holds more of them than it."""
+    lens = row_lens_of(batch, bd_block)
     groups, row_lens = split_lens(
         lens, max_tokens_per_mb, min_n_mbs, n_rows, quantum
     )
@@ -411,6 +475,10 @@ class PackedRows:
     per_seq: Dict[str, np.ndarray]  # each [R, S, ...]
     seq_lens: np.ndarray  # [R, S] int32 (0 on empty slots)
     row_seqs: List[List[int]]  # original indices per row
+    # a block-diffusion policy's rows only: original index -> the row slot
+    # at which each of the sequence's tokens is SCORED, [L] (-1: nowhere, a
+    # prompt token); the per-token arrays are laid out at those slots
+    token_slots: Optional[Dict[int, np.ndarray]] = None
 
     @property
     def n_rows(self) -> int:
@@ -431,6 +499,7 @@ def pack_batch_rows(
     pad_to: Optional[int] = None,
     pad_seqs_to: Optional[int] = None,
     quantum: int = ROW_QUANTUM,
+    bd: Optional[Tuple[int, int]] = None,
 ) -> PackedRows:
     """Pack a padded [B, L] batch into R packed streams.
 
@@ -440,28 +509,67 @@ def pack_batch_rows(
     length, which is ``pad_to`` or the shortest rung of `quantum` x 1, 2, 4,
     ... (callers pass ``ROW_QUANTUM`` x seq_parallel so the token axis splits
     evenly across the seq mesh axis) at which the sequences fit.
+
+    ``bd`` = (block length, mask token id) of a block-diffusion policy: a
+    sequence is laid out as its SLOTS (``bd_sequence_slots``: the clean
+    stream, then a noisy stream a state). ``tokens`` are the masked inputs,
+    ``positions`` the token's own in its sequence in every stream, and
+    ``per_token`` gains ``bd_stream`` (0 clean, 1..K), ``bd_labels`` (the
+    token a slot stands for) and ``bd_scored`` (the slots whose token is
+    scored there); every other per-token array holds a token's value at the
+    slot where the token is scored and its padding value elsewhere, so a
+    loss over the row's slots is the loss over the sequences' tokens.
     """
     mask = np.asarray(batch["attention_mask"]).astype(bool)
     lens = mask.sum(1).astype(np.int32)
-    t_pad, row_groups = rows_on_ladder(lens, n_rows, quantum, pad_to)
+    skip = ("input_ids", "attention_mask")
+    slots = None
+    if bd is not None:
+        from areal_tpu.ops import bd_attention
+
+        if "denoise_state" not in batch:
+            raise ValueError(
+                "a block-diffusion policy's batch carries a per-token "
+                "denoise_state"
+            )
+        block, mask_id = bd
+        states = np.asarray(batch["denoise_state"])
+        slots = [
+            bd_sequence_slots(states[b, :n], block)
+            for b, n in enumerate(lens)
+        ]
+        skip += ("denoise_state",)
+    row_lens = lens if slots is None else np.asarray(
+        [len(s[0]) for s in slots], np.int64
+    )
+    t_pad, row_groups = rows_on_ladder(row_lens, n_rows, quantum, pad_to)
     # bucketed (multiples of 8) so the per-seq dim doesn't force a fresh
     # compile for every distinct sequence count
     s_pad = pad_seqs_to if pad_seqs_to is not None else next_bucket_size(
         max(1, max(len(g) for g in row_groups)), 8
     )
+    if slots is not None and (
+        max(len(g) for g in row_groups) >= bd_attention.MAX_SEQS
+        or int(lens.max(initial=0)) > block * bd_attention.MAX_BLOCKS
+        or int(states.max(initial=-1)) + 1 >= bd_attention.MAX_STREAMS
+    ):
+        raise ValueError(
+            "a block-diffusion row holds fewer than "
+            f"{bd_attention.MAX_SEQS} sequences of at most "
+            f"{bd_attention.MAX_BLOCKS} blocks and fewer than "
+            f"{bd_attention.MAX_STREAMS} states (ops/bd_attention.py)"
+        )
 
     per_token_keys = [
         k
         for k, v in batch.items()
-        if k not in ("input_ids", "attention_mask")
+        if k not in skip
         and k not in _PER_SEQ_PAYLOAD_KEYS
         and np.asarray(v).ndim >= 2
         and np.asarray(v).shape[:2] == mask.shape
     ]
     per_seq_keys = [
-        k
-        for k, v in batch.items()
-        if k not in ("input_ids", "attention_mask") and k not in per_token_keys
+        k for k in batch if k not in skip and k not in per_token_keys
     ]
 
     ids = np.asarray(batch["input_ids"])
@@ -484,23 +592,42 @@ def pack_batch_rows(
         )
         for k in per_seq_keys
     }
+    token_slots: Optional[Dict[int, np.ndarray]] = None
+    if slots is not None:
+        token_slots = {}
+        for k in ("bd_stream", "bd_labels", "bd_scored"):
+            per_token[k] = np.zeros((n_rows, t_pad), np.int32)
     for r, group in enumerate(row_groups):
         off = 0
         for slot, b in enumerate(group):
             L = int(lens[b])
-            tokens[r, off : off + L] = ids[b, :L]
-            seg[r, off : off + L] = slot + 1
-            pos[r, off : off + L] = np.arange(L)
+            # a slot's token and where a token's own values go: every slot of
+            # a causal model's row, the scored slots of a block-diffusion one
+            src, own = slice(0, L), slice(None)
+            if slots is not None:
+                src, stream, masked, own = slots[b]
+            row = slice(off, off + int(row_lens[b]))
+            tokens[r, row] = ids[b, src]
+            seg[r, row] = slot + 1
+            pos[r, row] = np.arange(L)[src]
             seq_lens[r, slot] = L
             for k in per_token_keys:
-                per_token[k][r, off : off + L] = np.asarray(batch[k])[b, :L]
+                per_token[k][r, row][own] = np.asarray(batch[k])[b, src][own]
             for k in per_seq_keys:
                 per_seq[k][r, slot] = np.asarray(batch[k])[b]
-            off += L
+            if slots is not None:
+                per_token["bd_labels"][r, row] = tokens[r, row]
+                tokens[r, row][masked] = mask_id
+                per_token["bd_stream"][r, row] = stream
+                per_token["bd_scored"][r, row] = own
+                where = np.full(L, -1, np.int64)
+                where[src[own]] = off + np.nonzero(own)[0]
+                token_slots[b] = where
+            off = row.stop
     return PackedRows(
         tokens=tokens, segment_ids=seg, positions=pos,
         per_token=per_token, per_seq=per_seq, seq_lens=seq_lens,
-        row_seqs=row_groups,
+        row_seqs=row_groups, token_slots=token_slots,
     )
 
 
@@ -508,7 +635,20 @@ def unpack_rows_per_token(
     packed: PackedRows, values: np.ndarray, pad_value: float = 0.0
 ) -> np.ndarray:
     """[R, T, ...] per-token device output → padded [B, L, ...] in original
-    batch order."""
+    batch order. A block-diffusion policy's rows hold a value a SLOT: a
+    token reads the slot at which it is scored (``PackedRows.token_slots``),
+    a token scored nowhere ``pad_value``."""
+    if packed.token_slots is not None:
+        where = packed.token_slots
+        out = np.full(
+            (len(where), max((len(w) for w in where.values()), default=0))
+            + values.shape[2:], pad_value, dtype=values.dtype,
+        )
+        for r, group in enumerate(packed.row_seqs):
+            for b in group:
+                at = where[b]
+                out[b, np.nonzero(at >= 0)[0]] = values[r, at[at >= 0]]
+        return out
     lens_flat: Dict[int, int] = {}
     for r, group in enumerate(packed.row_seqs):
         for slot, b in enumerate(group):

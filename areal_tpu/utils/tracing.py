@@ -208,6 +208,14 @@ DEVICE_SCOPES = (
     # under the head. Its block, head and loss read ``attention``, ``mlp``,
     # ``lm_head``, ``loss`` ... as any layer's
     "mtp",
+    # a block-diffusion policy's training rows (ops/bd_attention.py): the
+    # core under the mask that is causal between blocks and two-way inside
+    # one (the kernels ``bd_attn_fwd`` / ``bd_attn_dq`` / ``bd_attn_dkv`` and
+    # the reshapes around them; NOT inside ``attention``, which stays a
+    # causal core's), and what makes the kernels' integer rows and tile
+    # table out of the rows' (sequence, block, stream) on the device, and
+    # counts the pairs
+    "attn_bd", "bd_layout",
 )
 
 

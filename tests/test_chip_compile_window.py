@@ -167,3 +167,44 @@ def test_the_rotation_kernel_compiles_for_v5e(
     assert not re.search(rf"= bf16\[[0-9,]*{heads * 128}\]\S* copy\(", text)
     assert not re.search(rf"f32\[[0-9,]*{t},{heads * 128}\]", text)
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+# (row slots): the block-diffusion cell's rows (32 / 4 heads of 128), the
+# ladder's top rung and a short last microbatch's
+@pytest.mark.parametrize("t", [16384, 8192])
+def test_the_block_diffusion_core_compiles_for_v5e(one_chip, t):
+    """``ops/flash.flash_bd_attention`` forward and backward at the instance
+    ``instance`` gives a row of ``t`` slots on a TPU: three kernels (forward,
+    dq, dkv), no ``[T, T]`` array and nothing float32 of the scores' size."""
+    import jax
+    import jax.numpy as jnp
+
+    from areal_tpu.ops import bd_attention, flash
+
+    hq, hkv, d = 32, 4, 128
+    bq, bk = bd_attention.BQ, bd_attention.BK
+
+    def both(q, k, v, seg, blk, stream, g):
+        out, vjp = jax.vjp(
+            lambda q, k, v: bd_attention.bd_attention(
+                q, k, v, seg, blk, stream, (hq, hkv), bq, bk, False
+            ), q, k, v,
+        )
+        return out, vjp(g)
+
+    q = _sds((1, t, hq * d), jnp.bfloat16, one_chip)
+    k = _sds((1, t, hkv * d), jnp.bfloat16, one_chip)
+    row = _sds((1, t), jnp.int32, one_chip)
+    compiled = jax.jit(both).lower(q, k, k, row, row, row, q).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"", text)) == 3
+    for name in ("bd_attn_fwd", "bd_attn_dq", "bd_attn_dkv"):
+        assert name in text
+    assert not re.search(rf"\[[0-9,]*{t},{t}\]", text)
+    # q, k, v, out, do and their gradients in bf16, the keys' codes across
+    # lanes: nothing of the scores' size
+    assert compiled.memory_analysis().temp_size_in_bytes < 40 * t * hq * d
+    assert t % bq == 0 and t % bk == 0
+    assert flash.instance(
+        t, d, interpret=True, heads=hq, block_diffusion=True
+    ) == flash.Instance("bd", 0, bq, False, bk)

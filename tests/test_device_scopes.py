@@ -55,13 +55,17 @@ def _actor(remat=True, family="qwen2", **parallel):
     return PPOActor(cfg, eng), eng
 
 
-def _batch(lens=(5, 9, 12, 7), vocab=128, seed=0):
+def _batch(lens=(5, 9, 12, 7), vocab=128, seed=0, denoise=False):
     rng = np.random.default_rng(seed)
     seqs = [rng.integers(0, vocab, size=n).astype(np.int32) for n in lens]
     batch = data_utils.pad_sequences_to_tensors(seqs)
     lm = np.array(batch["attention_mask"], np.int32)
     lm[:, :2] = 0
     batch["loss_mask"] = lm
+    if denoise:  # a block-diffusion policy's: two states, -1 off the loss
+        batch["denoise_state"] = np.where(
+            lm > 0, np.arange(lm.shape[1])[None] % 2, -1
+        ).astype(np.int32)
     batch["logprobs"] = (
         rng.standard_normal(lm.shape).astype(np.float32) * 0.1 - 4.8
     ) * lm
@@ -110,7 +114,8 @@ def _train_programs(family="qwen2", only_grad=False, **parallel):
     on the way (a lowering reads the proximal logprobs' shape, so zeros
     serve where ``logp_forward`` would have to be built to compute them)."""
     actor, eng = _actor(family=family, **parallel)
-    batch = actor.compute_advantages(_batch())
+    denoise = eng.model_config.block_diffusion is not None
+    batch = actor.compute_advantages(_batch(denoise=denoise))
     if only_grad:
         batch["prox_logp"] = np.zeros_like(batch["logprobs"])
     else:
@@ -216,13 +221,18 @@ WANT = {
     "mla_grad_step": {"mla_expand", "mtp", "attention", "moe_experts", "mlp",
                       "rope", "lm_head", "loss"} | _AROUND,
     "mla_logp_forward": {"mla_expand", "attention", "mlp", "rope", "lm_head"},
+    # a block-diffusion policy's rows: the core under its own scope, NOT
+    # inside attention (no causal core runs), and what makes its rows' codes
+    # and counts the pairs
+    "bd_grad_step": {"attn_bd", "bd_layout", "moe_experts", "mlp", "rope",
+                     "lm_head", "loss"} | _AROUND,
 }
 # a family's ``grad_step`` among the programs of ``lowered``
 FAMILIES = {"qwen2": "grad_step", "olmoe": "sparse_grad_step",
             "KeyeVL2": "dsa_grad_step", "kimi_linear": "kda_grad_step",
             "laguna": "swa_grad_step", "zaya": "cca_grad_step",
             "qwen3_next": "gdn_grad_step",
-            "joyai_llm_flash": "mla_grad_step"}
+            "joyai_llm_flash": "mla_grad_step", "sdar_moe": "bd_grad_step"}
 # the five families whose programs a change for a sixth may not move: the
 # lowered grad_step's text (the persistent compile cache's key is made of
 # it), by sha256, in tests/golden/grad_step_stablehlo.json. A PR that means
@@ -244,7 +254,8 @@ def lowered():
     out = {name: _op_names(low) for name, low in dense + _decode_programs()}
     grad_steps = {"grad_step": dense[0][1]}
     for program, family in list(STABLE.items())[1:] + [
-        ("cca_grad_step", "zaya"), ("gdn_grad_step", "qwen3_next")
+        ("cca_grad_step", "zaya"), ("gdn_grad_step", "qwen3_next"),
+        ("bd_grad_step", "sdar_moe"),
     ]:
         grad_steps[program] = _train_programs(family, only_grad=True)[0][1]
         out[program] = _op_names(grad_steps[program])
@@ -274,7 +285,7 @@ def test_program_lowers_under_its_contract_name_with_its_scopes(
     jitted = jitted.removeprefix("dsa_")
     jitted = jitted.removeprefix("kda_").removeprefix("swa_")
     jitted = jitted.removeprefix("cca_").removeprefix("gdn_")
-    jitted = jitted.removeprefix("mla_")
+    jitted = jitted.removeprefix("mla_").removeprefix("bd_")
     assert re.search(rf"^HloModule jit_{jitted}[, ]", text, re.M)
     assert WANT[program] <= _scopes_in(names), sorted(names)[:40]
 

@@ -108,7 +108,7 @@ def trainer_programs(
     float32, which is what a reading of memory needs)."""
     from areal_tpu.engine import programs
     from areal_tpu.models.transformer import init_params
-    from areal_tpu.ops.functional import target_aligned_logprobs
+    from areal_tpu.ops.functional import token_logprobs
     from areal_tpu.parallel import feasibility
 
     from areal_tpu.models.transformer import param_logical_axes
@@ -138,6 +138,10 @@ def trainer_programs(
            ("t_logprobs", "t_prox_logp", "t_advantages")},
         "s_rewards": jax.ShapeDtypeStruct((rows, 8), jnp.float32),
     }
+    if mc.block_diffusion is not None:  # such a policy's rows carry three more
+        arrays.update(dict.fromkeys(
+            ("t_bd_stream", "t_bd_labels", "t_bd_scored"), row
+        ))
     arrays = sds(arrays, shardings={k: rows_sh for k in arrays})
     return {
         "grad_step": (
@@ -150,7 +154,7 @@ def trainer_programs(
         ),
         "logp_forward": (
             programs.build_logp_forward(
-                mc, mesh, jnp.bfloat16, attend, hook=target_aligned_logprobs
+                mc, mesh, jnp.bfloat16, attend, hook=token_logprobs
             ),
             (params, arrays),
         ),
@@ -183,8 +187,10 @@ def cell_programs(model: str, tokens: int, fsdp: int = 1, param_dtype=None,
     mesh = mesh_lib.make_mesh(
         ParallelismConfig(fsdp_parallel_size=fsdp), devices[:fsdp]
     )
+    from areal_tpu.models.transformer import seq_parallel_refusal
+
     attend = attention_fn(
-        "sparse_topk" if mc.has_indexer else "flash", mesh,
+        seq_parallel_refusal(mc)[0] or "flash", mesh,
         block_cap=2048,  # what the chip's probe settles on
         topk=mc.dsa_topk if mc.has_indexer else 0,
     )
