@@ -434,13 +434,16 @@ def _bd_counts(cfg, reported, segment_ids, positions):
 
 
 def _bd_tiles(cfg, attn_impl, row_len, block_cap):
-    """The kernels' tile a grad dispatch of ``row_len``-slot rows runs
-    (``ops.flash.instance``); nothing where the XLA form takes the row."""
+    """The kernels' tile a grad dispatch of ``row_len``-slot rows runs and
+    the core's backward, its one kernel or its two (``ops.flash.instance``);
+    nothing where the XLA form takes the row."""
     inst = flash_ops.instance(
         row_len, cfg.head_dim, block_cap=block_cap, heads=cfg.num_heads,
         block_diffusion=True,
     )
-    return {"bd_tile": inst.kind and f"{inst.edge}x{inst.kvc}"}
+    if not inst.kind:
+        return {}
+    return {"bd_tile": f"{inst.edge}x{inst.kvc}", "bd_bwd": inst.backward}
 
 
 def _swa_backwards(cfg, attn_impl, row_len, block_cap):

@@ -38,10 +38,18 @@ and keys after the query are kept, so the mask is the kernels' own:
   formed transposed, keys on sublanes and the stacked queries on lanes, as
   ``ops/band_attention.py`` forms them and for its reasons; they read and
   write ``[T, heads * D]``, the projections' own layout;
-- the backward is two kernels over the same table: ``bd_attn_dq`` walks a
-  query block's kept key blocks (S, dP, dQ: three products a tile),
-  ``bd_attn_dkv`` a key block's kept query blocks (S, dP, dV, dK: four), from
-  the forward's output and logsumexp.
+- the backward is ONE kernel over the same table and the forward's walk,
+  ``bd_attn_bwd``: a kept tile's S, P, dP and dS are formed once, from the
+  forward's output and logsumexp, and dQ, dK and dV made of them (five
+  products a tile). dQ is a query block's scratch, written at its last key
+  block; dK and dV of a key head's WHOLE ``[T, D]`` stay in float32 VMEM
+  scratch across the (row, key head)'s walk and are written once at its end
+  (``bwd_resident_bytes``; the call states its VMEM limit from it). A row
+  whose dK and dV pass what ``flash.instance`` gives them takes the pair
+  instead: ``bd_attn_dq`` walks a query block's kept key blocks (S, dP, dQ:
+  three products a tile), ``bd_attn_dkv`` a key block's kept query blocks
+  (S, dP, dV, dK: four). The two forms add the same float32 terms in the
+  same order.
 
 bf16 (or float32) operands as stored, float32 scores, softmax and
 accumulation. ``bd_segment_attention`` is the plain XLA form they are held
@@ -72,6 +80,21 @@ MAX_BLOCKS, MAX_STREAMS, MAX_SEQS = _STREAM, _NOISY // _STREAM, 2048
 # Query slots and key slots a tile (the tile table's granularity): the sweep
 # on the v5e on the cell's packed rows is in ``flash.instance``'s docstring.
 BQ, BK = 256, 512
+
+# What a tile's blocks, their pipeline buffers and the compiler's own
+# temporaries (a tile's float32 scores and what is made of them) may take of
+# VMEM beside what the fused backward keeps resident: at 256 x 512 and 8
+# query heads a key head the described v5e's compiler takes the kernel at 16
+# MiB and refuses it at 12, whatever the row's length.
+_WORKING = 32 << 20
+
+
+def bwd_resident_bytes(t: int, d: int, itemsize: int) -> int:
+    """The bytes the fused backward (``bd_attn_bwd``) keeps in VMEM across a
+    (row, key head)'s walk, on the arrays it creates: dK and dV of the key
+    head's ``t`` slots in float32 scratch, and their two output blocks ``[t,
+    d]`` of ``itemsize`` bytes an element, one buffer each."""
+    return 2 * t * d * 4 + 2 * t * d * itemsize
 
 
 def kept_mask(segment_ids, blocks, streams):
@@ -291,13 +314,31 @@ def _fwd_kernel(
             lse_ref[r:r + 1, :] = lse[:, mine]
 
 
-def _tile_probs(k, q, lse, kcode_ref, qrows_ref, rep, masked):
-    """A tile's probabilities ``[bk, rows]`` from the forward's logsumexp."""
+def _tile_terms(q_ref, k_ref, v_ref, do_ref, out_ref, lse_ref, qrows_ref,
+                kcode_ref, rep: int, d: int, masked: bool):
+    """What a backward kernel makes of a kept tile, from the forward's output
+    and logsumexp: (q scaled and dO, stacked ``[rows, d]``; k; the
+    probabilities P float32 and dS in the keys' dtype, ``[bk, rows]``)."""
+    q = _scaled_queries(q_ref, rep, d)
+    do = _stacked(do_ref, rep, d)
+    k = k_ref[...]
     st = jax.lax.dot_general(k, q, _NT, preferred_element_type=jnp.float32)
-    p = jnp.exp(st - lse)
+    p = jnp.exp(st - _in_a_row(lse_ref, rep))
     if masked:
+        # a padding query's logsumexp is 0 and its scores are not: the
+        # compares zero its exponentials, and a full tile holds none
         p = jnp.where(_kept(kcode_ref, qrows_ref, rep), p, 0.0)
-    return p
+    dp = jax.lax.dot_general(
+        v_ref[...], do, _NT, preferred_element_type=jnp.float32
+    )
+    ds = (p * (dp - _row_dots(do_ref, out_ref, rep, d))).astype(k.dtype)
+    return q, do, k, p, ds
+
+
+def _write_dq(dq_acc, dq_ref, bq: int, rep: int, d: int):
+    for r in range(rep):
+        dq = dq_acc[:, r * bq:(r + 1) * bq].T * d ** -0.5
+        dq_ref[:, r * d:(r + 1) * d] = dq.astype(dq_ref.dtype)
 
 
 def _dq_kernel(
@@ -314,18 +355,10 @@ def _dq_kernel(
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
     def tile(masked: bool):
-        q = _scaled_queries(q_ref, rep, d)
-        do = _stacked(do_ref, rep, d)
-        k = k_ref[...]
-        # a padding query's logsumexp is 0 and its scores are not: the
-        # compares zero its exponentials, and a full tile holds none
-        p = _tile_probs(
-            k, q, _in_a_row(lse_ref, rep), kcode_ref, qrows_ref, rep, masked
+        _, _, k, _, ds = _tile_terms(
+            q_ref, k_ref, v_ref, do_ref, out_ref, lse_ref, qrows_ref,
+            kcode_ref, rep, d, masked,
         )
-        dp = jax.lax.dot_general(
-            v_ref[...], do, _NT, preferred_element_type=jnp.float32
-        )
-        ds = (p * (dp - _row_dots(do_ref, out_ref, rep, d))).astype(k.dtype)
         dq_acc[...] += jnp.dot(k.T, ds, preferred_element_type=jnp.float32)
 
     cls = tile_ref[b, i, j]
@@ -334,10 +367,7 @@ def _dq_kernel(
 
     @pl.when(j == n_k - 1)
     def _write():
-        bq = qrows_ref.shape[1]
-        for r in range(rep):
-            dq = dq_acc[:, r * bq:(r + 1) * bq].T * d ** -0.5
-            dq_ref[:, r * d:(r + 1) * d] = dq.astype(dq_ref.dtype)
+        _write_dq(dq_acc, dq_ref, qrows_ref.shape[1], rep, d)
 
 
 def _dkv_kernel(
@@ -355,19 +385,13 @@ def _dkv_kernel(
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
     def tile(masked: bool):
-        q = _scaled_queries(q_ref, rep, d)
-        do = _stacked(do_ref, rep, d)
-        k = k_ref[...]
-        p = _tile_probs(
-            k, q, _in_a_row(lse_ref, rep), kcode_ref, qrows_ref, rep, masked
+        q, do, _, p, ds = _tile_terms(
+            q_ref, k_ref, v_ref, do_ref, out_ref, lse_ref, qrows_ref,
+            kcode_ref, rep, d, masked,
         )
         dv_acc[...] += jnp.dot(
             p.astype(do.dtype), do, preferred_element_type=jnp.float32
         )
-        dp = jax.lax.dot_general(
-            v_ref[...], do, _NT, preferred_element_type=jnp.float32
-        )
-        ds = (p * (dp - _row_dots(do_ref, out_ref, rep, d))).astype(k.dtype)
         dk_acc[...] += jnp.dot(ds, q, preferred_element_type=jnp.float32)
 
     cls = tile_ref[b, j, i]
@@ -380,8 +404,71 @@ def _dkv_kernel(
         dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
 
 
+def _bwd_kernel(
+    tile_ref, next_ref,
+    q_ref, k_ref, v_ref, do_ref, out_ref, lse_ref, qrows_ref, kcode_ref,
+    dq_ref, dk_ref, dv_ref,  # dk, dv: a key head's whole column [T, d]
+    dq_acc, dk_acc, dv_acc,
+    *, rep: int, d: int, n_q: int, n_k: int,
+):
+    b, i, j = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+    bk = k_ref.shape[0]
+
+    def key_blocks(body):
+        def step(n, _):
+            body(pl.ds(pl.multiple_of(n * bk, bk), bk))
+
+        jax.lax.fori_loop(0, n_k, step, None)
+
+    @pl.when(jnp.logical_and(i == 0, j == 0))
+    def _init_keys():
+        def zero(at):
+            dk_acc[at, :] = jnp.zeros((bk, d), jnp.float32)
+            dv_acc[at, :] = jnp.zeros((bk, d), jnp.float32)
+
+        key_blocks(zero)
+
+    @pl.when(j == 0)
+    def _init():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    def tile(masked: bool):
+        q, do, k, p, ds = _tile_terms(
+            q_ref, k_ref, v_ref, do_ref, out_ref, lse_ref, qrows_ref,
+            kcode_ref, rep, d, masked,
+        )
+        # a kept tile's key block is the grid's own (``next`` of a kept step)
+        mine = pl.ds(pl.multiple_of(j * bk, bk), bk)
+        dv_acc[mine, :] += jnp.dot(
+            p.astype(do.dtype), do, preferred_element_type=jnp.float32
+        )
+        dk_acc[mine, :] += jnp.dot(ds, q, preferred_element_type=jnp.float32)
+        dq_acc[...] += jnp.dot(k.T, ds, preferred_element_type=jnp.float32)
+
+    cls = tile_ref[b, i, j]
+    pl.when(cls == 1)(functools.partial(tile, True))
+    pl.when(cls == 2)(functools.partial(tile, False))
+
+    @pl.when(j == n_k - 1)
+    def _write():
+        _write_dq(dq_acc, dq_ref, qrows_ref.shape[1], rep, d)
+
+    @pl.when(jnp.logical_and(i == n_q - 1, j == n_k - 1))
+    def _write_keys():
+        def cast(at):
+            dk_ref[at, :] = dk_acc[at, :].astype(dk_ref.dtype)
+            dv_ref[at, :] = dv_acc[at, :].astype(dv_ref.dtype)
+
+        key_blocks(cast)
+
+
 def _call(kernel, name, grid, in_specs, out_specs, out_shape, scratch,
-          interpret):
+          interpret, resident: int = 0):
+    """``resident``: the bytes a kernel keeps across a (row, key head)'s whole
+    walk (``bwd_resident_bytes``; 0: a tile's alone). Such a kernel walks the
+    query blocks in order too, under a VMEM limit that is those bytes and
+    ``_WORKING`` beside them."""
+    outer = "arbitrary" if resident else "parallel"
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -389,9 +476,8 @@ def _call(kernel, name, grid, in_specs, out_specs, out_shape, scratch,
             out_specs=out_specs, scratch_shapes=scratch,
         ),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=(
-                "parallel", "parallel", "parallel", "arbitrary"
-            ),
+            dimension_semantics=("parallel", "parallel", outer, "arbitrary"),
+            vmem_limit_bytes=resident + _WORKING if resident else None,
         ),
         out_shape=out_shape, name=name, interpret=interpret,
     )
@@ -399,23 +485,19 @@ def _call(kernel, name, grid, in_specs, out_specs, out_shape, scratch,
 
 def _layout(segment_ids, blocks, streams, bq: int, bk: int):
     """What the kernels read of the three rows (scope ``bd_layout``): the
-    tile table and each walk's next kept block, the queries' rows ``[B, 8,
-    T]`` (lo, hi, own; five rows of padding to a sublane tile) and the keys'
-    codes across lanes ``[B, T, 128]``."""
+    tile table and the next kept key block of a query block's walk, the
+    queries' rows ``[B, 8, T]`` (lo, hi, own; five rows of padding to a
+    sublane tile) and the keys' codes across lanes ``[B, T, 128]``."""
     with device_scope("bd_layout"):
         code, lo, hi, own = slot_codes(segment_ids, blocks, streams)
         table = tile_classes(segment_ids, blocks, streams, bq, bk)
-        table_t = table.swapaxes(-1, -2)
         bsz, t = code.shape
         qrows = jnp.concatenate(
             [jnp.stack([lo, hi, own], axis=1),
              jnp.zeros((bsz, 5, t), jnp.int32)], axis=1,
         )
         kcode = jnp.broadcast_to(code[:, :, None], (bsz, t, _LANES))
-        return (
-            table, jax.vmap(_next_kept)(table),
-            table_t, jax.vmap(_next_kept)(table_t), qrows, kcode,
-        )
+        return table, jax.vmap(_next_kept)(table), qrows, kcode
 
 
 def _specs(rep: int, bq: int, bk: int, d: int, kv_major: bool):
@@ -458,9 +540,7 @@ def bd_forward(q, k, v, segment_ids, blocks, streams, *, heads, bq: int,
     hq, hkv = heads
     d = q.shape[2] // hq
     rep, n_q, n_k = hq // hkv, t // bq, t // bk
-    table, nxt, _, _, qrows, kcode = _layout(
-        segment_ids, blocks, streams, bq, bk
-    )
+    table, nxt, qrows, kcode = _layout(segment_ids, blocks, streams, bq, bk)
     q_spec, k_spec, row_spec, qrows_spec, kcode_spec = _specs(
         rep, bq, bk, d, False
     )
@@ -478,58 +558,82 @@ def bd_forward(q, k, v, segment_ids, blocks, streams, *, heads, bq: int,
     )(table, nxt, q, k, v, qrows, kcode)
 
 
-@functools.partial(jax.jit, static_argnames=("heads", "bq", "bk", "interpret"))
+@functools.partial(
+    jax.jit, static_argnames=("heads", "bq", "bk", "fused", "interpret")
+)
 def bd_backward(q, k, v, segment_ids, blocks, streams, out, lse, do, *,
-                heads, bq: int, bk: int, interpret: bool = False):
+                heads, bq: int, bk: int, fused: bool,
+                interpret: bool = False):
     """(dq, dk, dv) of ``bd_forward`` from its ``out`` and ``lse`` and the
-    cotangent ``do`` [B, T, Hq * D]. Padding gives and takes no gradient."""
+    cotangent ``do`` [B, T, Hq * D]. Padding gives and takes no gradient.
+    ``fused``: the one kernel with a key head's dK and dV resident, or the
+    pair (``flash.instance`` says which a row takes)."""
     bsz, t, _ = q.shape
     hq, hkv = heads
     d = q.shape[2] // hq
     rep, n_q, n_k = hq // hkv, t // bq, t // bk
-    table, nxt, table_t, nxt_t, qrows, kcode = _layout(
-        segment_ids, blocks, streams, bq, bk
-    )
+    table, nxt, qrows, kcode = _layout(segment_ids, blocks, streams, bq, bk)
     q_spec, k_spec, row_spec, qrows_spec, kcode_spec = _specs(
         rep, bq, bk, d, False
     )
     ins = [q_spec, k_spec, k_spec, q_spec, q_spec, row_spec, qrows_spec,
            kcode_spec]
+    args = (q, k, v, do, out, lse, qrows, kcode)
+    dq_shape = jax.ShapeDtypeStruct(q.shape, q.dtype)
+    dkv_shape = [jax.ShapeDtypeStruct(k.shape, k.dtype),
+                 jax.ShapeDtypeStruct(v.shape, v.dtype)]
+    dq_acc = pltpu.VMEM((d, rep * bq), jnp.float32)
+    if fused:
+        # a key head's whole column, fetched and written once a walk
+        column = pl.BlockSpec(
+            (None, t, d), lambda b, h, i, j, tile, nxt: (b, 0, h),
+            pipeline_mode=pl.Buffered(1),
+        )
+        return _call(
+            functools.partial(_bwd_kernel, rep=rep, d=d, n_q=n_q, n_k=n_k),
+            "bd_attn_bwd", (bsz, hkv, n_q, n_k), ins,
+            [q_spec, column, column], [dq_shape, *dkv_shape],
+            [dq_acc, pltpu.VMEM((t, d), jnp.float32),
+             pltpu.VMEM((t, d), jnp.float32)],
+            interpret, bwd_resident_bytes(t, d, k.dtype.itemsize),
+        )(table, nxt, *args)
     dq = _call(
         functools.partial(_dq_kernel, rep=rep, d=d, n_k=n_k),
-        "bd_attn_dq", (bsz, hkv, n_q, n_k), ins, [q_spec],
-        [jax.ShapeDtypeStruct(q.shape, q.dtype)],
-        [pltpu.VMEM((d, rep * bq), jnp.float32)],
-        interpret,
-    )(table, nxt, q, k, v, do, out, lse, qrows, kcode)[0]
+        "bd_attn_dq", (bsz, hkv, n_q, n_k), ins, [q_spec], [dq_shape],
+        [dq_acc], interpret,
+    )(table, nxt, *args)[0]
     q_spec, k_spec, row_spec, qrows_spec, kcode_spec = _specs(
         rep, bq, bk, d, True
     )
     ins = [q_spec, k_spec, k_spec, q_spec, q_spec, row_spec, qrows_spec,
            kcode_spec]
+    with device_scope("bd_layout"):  # a key block's walk over query blocks
+        table_t = table.swapaxes(-1, -2)
+        nxt_t = jax.vmap(_next_kept)(table_t)
     dk, dv = _call(
         functools.partial(_dkv_kernel, rep=rep, d=d, n_q=n_q),
         "bd_attn_dkv", (bsz, hkv, n_k, n_q), ins, [k_spec, k_spec],
-        [jax.ShapeDtypeStruct(k.shape, k.dtype),
-         jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        dkv_shape,
         [pltpu.VMEM((bk, d), jnp.float32), pltpu.VMEM((bk, d), jnp.float32)],
         interpret,
-    )(table_t, nxt_t, q, k, v, do, out, lse, qrows, kcode)
+    )(table_t, nxt_t, *args)
     return dq, dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9))
-def bd_attention(q, k, v, segment_ids, blocks, streams, heads, bq, bk,
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10))
+def bd_attention(q, k, v, segment_ids, blocks, streams, heads, bq, bk, fused,
                  interpret):
     """Attention under M of packed rows through the kernels: ``q`` [B, T, Hq
     * D], ``k``, ``v`` [B, T, Hkv * D], ``heads`` = (Hq, Hkv), the three rows
-    [B, T] -> [B, T, Hq * D], padding's rows 0. Differentiable in q, k, v."""
+    [B, T] -> [B, T, Hq * D], padding's rows 0. Differentiable in q, k, v;
+    ``fused``: the backward's form (``bd_backward``)."""
     return _bd_fwd(
-        q, k, v, segment_ids, blocks, streams, heads, bq, bk, interpret
+        q, k, v, segment_ids, blocks, streams, heads, bq, bk, fused, interpret
     )[0]
 
 
-def _bd_fwd(q, k, v, segment_ids, blocks, streams, heads, bq, bk, interpret):
+def _bd_fwd(q, k, v, segment_ids, blocks, streams, heads, bq, bk, fused,
+            interpret):
     out, lse = bd_forward(
         q, k, v, segment_ids, blocks, streams, heads=heads, bq=bq, bk=bk,
         interpret=interpret,
@@ -541,11 +645,11 @@ def _bd_fwd(q, k, v, segment_ids, blocks, streams, heads, bq, bk, interpret):
     return out, (q, k, v, segment_ids, blocks, streams, out, lse)
 
 
-def _bd_bwd(heads, bq, bk, interpret, res, do):
+def _bd_bwd(heads, bq, bk, fused, interpret, res, do):
     q, k, v, segment_ids, blocks, streams, out, lse = res
     dq, dk, dv = bd_backward(
         q, k, v, segment_ids, blocks, streams, out, lse, do.astype(q.dtype),
-        heads=heads, bq=bq, bk=bk, interpret=interpret,
+        heads=heads, bq=bq, bk=bk, fused=fused, interpret=interpret,
     )
     return dq, dk, dv, None, None, None
 

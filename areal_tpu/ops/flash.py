@@ -183,8 +183,9 @@ class Instance(NamedTuple):
     ``ops/bd_attention.py``); ``back``, the keys behind
     a query its static mask keeps beside the query's own (0: causal);
     ``edge``, the tile edge (the strip kernels' query block; 0 under XLA);
-    ``fused``, the splash kernel's one backward kernel or its two; ``kvc``,
-    its ``block_kv_compute`` (0 where another kernel runs)."""
+    ``fused``, the one backward kernel of the splash or the "bd" kernels, or
+    their two; ``kvc``, the splash kernel's ``block_kv_compute`` (0 where
+    another kernel runs)."""
 
     kind: str
     back: int
@@ -196,7 +197,7 @@ class Instance(NamedTuple):
     def backward(self) -> str:
         """"fused" | "split" | "strip", "" for a row the XLA form takes:
         what a trainer's grad dispatch span says of a core."""
-        if self.kind != "splash":
+        if self.kind not in ("splash", "bd"):
             return self.kind
         return "fused" if self.fused else "split"
 
@@ -303,7 +304,7 @@ def instance(
     packed rows of 16,384 slots, 32 / 4 heads of 128 (my chip run, PR 58,
     ``chiprun_in/bd_sweep.py``; real slots and sequences a row; forward |
     forward + backward ms a layer, by query x key slots a tile; the backward
-    is the SPLIT pair dq + dkv, the one form there is):
+    is the SPLIT pair dq + dkv, the one form there was then):
 
     ================ ============== ============== ==============
     tile             15,648, 1 seq  15,328, 1 seq  15,852, 2 seqs
@@ -323,13 +324,49 @@ def instance(
     that hold few pairs: 244 of 1,024 tiles kept where 256 x 512 keeps 461
     of 2,048), and a pass's rows hold one to three sequences.
 
+    The backward as ONE kernel (``bd_attn_bwd``: a kept tile's scores formed
+    once, five products where the pair makes seven, a key head's dK and dV
+    resident in VMEM) beside the pair on the same rows (my chip run, PR 59,
+    the same script; BACKWARD ms a layer, forward + backward less forward;
+    "pair / one"; both give the same bits):
+
+    ================ ============== ============== ==============
+    tile             15,648, 1 seq  15,328, 1 seq  15,852, 2 seqs
+    ================ ============== ============== ==============
+    256 x 256            - / 16.49      - / 14.99      - / 16.36
+    256 x 512        22.63 / 15.60  20.57 / 14.17  22.45 / 15.51
+    512 x 256            - / 16.55      - / 15.48      - / 16.60
+    512 x 512        22.31 / 15.72  20.17 / 14.52  23.23 / 16.88
+    256 x 1024       23.47 / 16.54  21.37 / 15.09  23.19 / 16.43
+    512 x 1024       23.56 / 64.90  22.07 / 63.98  24.86 / 64.65
+    ================ ============== ============== ==============
+
+    so the one kernel at the forward's own tile, 256 x 512 (-31% of the
+    backward on every row; 512 x 512 is within 1% on a one-sequence row and
+    9% behind on the two-sequence one; at 512 x 1024 a tile's float32 scores
+    are 16 MiB each and the kernel spills): one tile table serves both. Making
+    the query block's operands once a walk instead of once a tile read
+    15.80 / 14.35 / 15.64 at 256 x 512, no gain, and is not kept. The one
+    kernel holds ``bd_attention.bwd_resident_bytes`` in VMEM, 2 x [T, 128]
+    float32 of scratch and 2 x [T, 128] bf16 of output blocks a key head: 12
+    MiB at 8,192 slots, 24 MiB at 16,384, 48 MiB at 32,768, and its call
+    states that and 32 MiB of working room as its ``vmem_limit_bytes`` (the
+    v5e has 128 MiB; the compiler takes the kernel with 16 MiB of room and
+    refuses it with 12). Rows to 32,768 slots take it (the same sequences
+    packed into 32,768-slot rows, same call: 49.13 / 34.06, 48.77 / 33.80 and
+    41.27 / 28.55 ms; the tree as committed read 15.57, 14.11 and 15.43 at
+    16,384); a longer row keeps the pair.
+
     The edge is never above ``block_cap``, always a divisor of ``t``."""
     if block_diffusion:
         bq, bk = bd_attention.BQ, bd_attention.BK
         fits = d == _LANES and t % bq == 0 and t % bk == 0
         on_chip = interpret or jax.default_backend() == "tpu"
         if fits and on_chip:
-            return Instance("bd", 0, bq, False, bk)
+            # the one backward kernel where a key head's dK and dV, float32
+            # scratch and bf16 output blocks, are within 48 MiB of VMEM
+            resident = bd_attention.bwd_resident_bytes(t, d, 2)
+            return Instance("bd", 0, bq, resident <= 48 << 20, bk)
         return Instance("", 0, 0, False, 0)
     if t % _LANES:
         # not whole tiles of 128 tokens: short, its quadratic scores small
@@ -551,7 +588,7 @@ def flash_bd_attention(
     return bd_attention.bd_attention(
         q.reshape(b, t, hq * d), k.reshape(b, t, hkv * d),
         v.reshape(b, t, hkv * d), segment_ids, blocks, streams, (hq, hkv),
-        inst.edge, inst.kvc, interpret,
+        inst.edge, inst.kvc, inst.fused, interpret,
     ).reshape(q.shape)
 
 

@@ -210,7 +210,8 @@ DEVICE_SCOPES = (
     "mtp",
     # a block-diffusion policy's training rows (ops/bd_attention.py): the
     # core under the mask that is causal between blocks and two-way inside
-    # one (the kernels ``bd_attn_fwd`` / ``bd_attn_dq`` / ``bd_attn_dkv`` and
+    # one (the kernels ``bd_attn_fwd`` / ``bd_attn_bwd``, or ``bd_attn_dq`` /
+    # ``bd_attn_dkv`` for a row beyond the one backward kernel's VMEM, and
     # the reshapes around them; NOT inside ``attention``, which stays a
     # causal core's), and what makes the kernels' integer rows and tile
     # table out of the rows' (sequence, block, stream) on the device, and

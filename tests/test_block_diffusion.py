@@ -212,6 +212,15 @@ def test_the_held_shares_add_up_to_the_uncut_layer(tiny):
 
 
 # ------------------------------------------------------------------ the core --
+def _rows(k, t, rows):
+    """``_row`` of each of ``rows`` (their sequences), a batch."""
+    return tuple(
+        jnp.concatenate(a) for a in zip(*(
+            _row(k, t, seqs, seed) for seed, seqs in enumerate(rows)
+        ))
+    )
+
+
 def _row(k, t, seqs=SEQS, seed=0):
     batch, _ = _batch(k, seqs, seed)
     packed = data_utils.pack_batch_rows(batch, 1, pad_to=t, bd=(SIZE, 127))
@@ -221,22 +230,48 @@ def _row(k, t, seqs=SEQS, seed=0):
     ))
 
 
-@pytest.mark.parametrize("k,bq,bk", [(1, 128, 128), (2, 64, 128), (4, 128, 256)])
-def test_the_kernels_in_interpret_mode_are_the_dense_masks(k, bq, bk):
-    """Forward and the two backward kernels against the plain XLA form under
-    the dense [T, T] mask, three sequences a row and padding at its end."""
-    t, hq, hkv, d = 512, 4, 2, 128
-    rows = _row(k, t)
+# (K, query x key slots a tile, row slots, the sequences of each row): three
+# sequences a 512-slot row; then ONE long sequence in 768 or 1,024 slots, 4 x
+# 4 tiles or more, where a noisy stream's clean keys lie a stream away (a key
+# block kept by query blocks that are not neighbours) and the last key block
+# is padding alone; then two rows of different layouts
+ONE_LONG = {1: [(8, 300)], 2: [(8, 200)], 4: [(8, 180)]}
+CORE_CASES = [
+    (1, 128, 128, 512, [SEQS]), (2, 64, 128, 512, [SEQS]),
+    (4, 128, 256, 512, [SEQS]),
+    (1, 128, 128, 768, [ONE_LONG[1]]), (2, 64, 128, 768, [ONE_LONG[2]]),
+    (4, 64, 128, 1024, [ONE_LONG[4]]),
+    (2, 64, 128, 768, [ONE_LONG[2], SEQS]),
+]
+
+
+@pytest.mark.parametrize("fused", [True, False], ids=["fused", "split"])
+@pytest.mark.parametrize(
+    "k,bq,bk,t,seqs", CORE_CASES,
+    ids=[f"K{c[0]}-{c[1]}x{c[2]}-{c[3]}-rows{len(c[4])}" for c in CORE_CASES],
+)
+def test_the_kernels_in_interpret_mode_are_the_dense_masks(
+    k, bq, bk, t, seqs, fused
+):
+    """Forward and backward kernels (``fused``: the one kernel with a key
+    head's dK and dV resident across its walk, else the pair dq + dkv)
+    against the plain XLA form under the dense [T, T] mask, two key heads and
+    padding at a row's end. The accumulators of the fused kernel are a (row,
+    key head)'s: zeroed at its first tile, so an all-padding key block's
+    gradients are exact zeros and an empty tile adds nothing."""
+    hq, hkv, d = 4, 2, 128
+    n = len(seqs)
+    rows = _rows(k, t, seqs)
     keys = jax.random.split(jax.random.PRNGKey(k), 4)
-    q = jax.random.normal(keys[0], (1, t, hq, d))
-    kk = jax.random.normal(keys[1], (1, t, hkv, d))
-    v = jax.random.normal(keys[2], (1, t, hkv, d))
-    do = jax.random.normal(keys[3], (1, t, hq, d))
+    q = jax.random.normal(keys[0], (n, t, hq, d))
+    kk = jax.random.normal(keys[1], (n, t, hkv, d))
+    v = jax.random.normal(keys[2], (n, t, hkv, d))
+    do = jax.random.normal(keys[3], (n, t, hq, d))
 
     def kernels(q, kk, v):
         return bd.bd_attention(
-            q.reshape(1, t, -1), kk.reshape(1, t, -1), v.reshape(1, t, -1),
-            *rows, (hq, hkv), bq, bk, True,
+            q.reshape(n, t, -1), kk.reshape(n, t, -1), v.reshape(n, t, -1),
+            *rows, (hq, hkv), bq, bk, fused, True,
         ).reshape(q.shape)
 
     def dense(q, kk, v):
@@ -247,8 +282,35 @@ def test_the_kernels_in_interpret_mode_are_the_dense_masks(k, bq, bk):
     want = jax.grad(lambda *a: (dense(*a) * do).sum(), (0, 1, 2))(q, kk, v)
     for g, w in zip(got, want):
         np.testing.assert_allclose(g, w, atol=5e-5)
-    pad = np.asarray(rows[0][0] == 0)
-    assert pad.any() and not np.asarray(kernels(q, kk, v))[0, pad].any()
+    pad = np.asarray(rows[0] == 0)
+    assert pad.any() and not np.asarray(kernels(q, kk, v))[pad].any()
+    assert not any(np.asarray(g)[pad].any() for g in got)
+    if t > 512:
+        table = np.asarray(bd.tile_classes(*rows, bq, bk))[0] > 0
+        assert min(table.shape) >= 4
+        kept_by = [np.flatnonzero(col) for col in table.T]
+        assert any((np.diff(on) > 1).any() for on in kept_by)
+        assert pad[0, -bk:].all() and not table[:, -1].any()
+
+
+def test_the_two_backward_forms_are_one_sum():
+    """The fused kernel adds a key block's contributions in the order of
+    query blocks the pair's dkv kernel walks, and a query block's in the order
+    dq walks: the same float32 sums, to the bit."""
+    k, bq, bk, t, seqs = CORE_CASES[-1]
+    hq, hkv, d = 4, 2, 128
+    rows = _rows(k, t, seqs)
+    keys = jax.random.split(jax.random.PRNGKey(0), 4)
+    q, do = (jax.random.normal(a, (2, t, hq * d)) for a in keys[:2])
+    kk, v = (jax.random.normal(a, (2, t, hkv * d)) for a in keys[2:])
+
+    def grads(fused):
+        return jax.grad(lambda *a: (bd.bd_attention(
+            *a, *rows, (hq, hkv), bq, bk, fused, True
+        ) * do).sum(), (0, 1, 2))(q, kk, v)
+
+    for one, two in zip(grads(True), grads(False)):
+        np.testing.assert_array_equal(one, two)
 
 
 @pytest.mark.parametrize("k", [1, 2, 4])
@@ -449,3 +511,27 @@ def test_the_core_runs_under_its_own_device_scope(tiny):
     )[1].counts).lower(params, arrays).as_text(debug_info=True)
     assert "attn_bd" in text and "bd_layout" in text
     assert "/attention/" not in text
+
+
+# (row slots, the backward): the cell's rungs, and a row whose dK and dV a
+# key head pass the VMEM ``instance`` gives them
+@pytest.mark.parametrize("row_len,form", [
+    (4096, "fused"), (16384, "fused"), (32768, "fused"), (65536, "split"),
+])
+def test_a_grad_dispatch_says_the_cores_tile_and_backward(
+    tiny, monkeypatch, row_len, form
+):
+    """What ``train.grad_dispatch`` carries of the core: the counter of how
+    often the one backward kernel engages, by row length; nothing where the
+    XLA form takes the row (the CPU)."""
+    cfg = tiny[0]
+    assert tr.grad_span_attrs(cfg, "block_diffusion", row_len) == {}
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    wide = dataclasses.replace(cfg, head_dim=128)
+    inst = flash_ops.instance(
+        row_len, 128, heads=wide.num_heads, block_diffusion=True
+    )
+    assert inst == flash_ops.Instance("bd", 0, bd.BQ, form == "fused", bd.BK)
+    assert tr.grad_span_attrs(wide, "block_diffusion", row_len) == {
+        "bd_tile": f"{bd.BQ}x{bd.BK}", "bd_bwd": form,
+    }

@@ -174,8 +174,10 @@ def test_the_rotation_kernel_compiles_for_v5e(
 @pytest.mark.parametrize("t", [16384, 8192])
 def test_the_block_diffusion_core_compiles_for_v5e(one_chip, t):
     """``ops/flash.flash_bd_attention`` forward and backward at the instance
-    ``instance`` gives a row of ``t`` slots on a TPU: three kernels (forward,
-    dq, dkv), no ``[T, T]`` array and nothing float32 of the scores' size."""
+    ``instance`` gives a row of ``t`` slots on a TPU: two kernels (forward,
+    and the one backward with a key head's dK and dV resident in VMEM under
+    the limit the call states), no ``[T, T]`` array and nothing float32 of
+    the scores' size."""
     import jax
     import jax.numpy as jnp
 
@@ -183,11 +185,14 @@ def test_the_block_diffusion_core_compiles_for_v5e(one_chip, t):
 
     hq, hkv, d = 32, 4, 128
     bq, bk = bd_attention.BQ, bd_attention.BK
+    inst = flash.instance(t, d, interpret=True, heads=hq, block_diffusion=True)
+    assert inst == flash.Instance("bd", 0, bq, True, bk)
 
     def both(q, k, v, seg, blk, stream, g):
         out, vjp = jax.vjp(
             lambda q, k, v: bd_attention.bd_attention(
-                q, k, v, seg, blk, stream, (hq, hkv), bq, bk, False
+                q, k, v, seg, blk, stream, (hq, hkv), bq, bk, inst.fused,
+                False,
             ), q, k, v,
         )
         return out, vjp(g)
@@ -197,14 +202,11 @@ def test_the_block_diffusion_core_compiles_for_v5e(one_chip, t):
     row = _sds((1, t), jnp.int32, one_chip)
     compiled = jax.jit(both).lower(q, k, k, row, row, row, q).compile()
     text = compiled.as_text()
-    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"", text)) == 3
-    for name in ("bd_attn_fwd", "bd_attn_dq", "bd_attn_dkv"):
+    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"", text)) == 2
+    for name in ("bd_attn_fwd", "bd_attn_bwd"):
         assert name in text
     assert not re.search(rf"\[[0-9,]*{t},{t}\]", text)
     # q, k, v, out, do and their gradients in bf16, the keys' codes across
     # lanes: nothing of the scores' size
     assert compiled.memory_analysis().temp_size_in_bytes < 40 * t * hq * d
     assert t % bq == 0 and t % bk == 0
-    assert flash.instance(
-        t, d, interpret=True, heads=hq, block_diffusion=True
-    ) == flash.Instance("bd", 0, bq, False, bk)

@@ -553,3 +553,11 @@ def test_trace_rungs_names_each_run_by_the_row_length_its_pack_gave():
     cut = [e for e in events if e is not packs[0]]
     with pytest.raises(SystemExit):
         trace_rungs.by_row_length(cut)
+    # what a row length's grad dispatches said of their cores' backward
+    assert trace_rungs.backward_forms(events) == {4096: ""}
+    said = [e for e in events if e[2] == "areal:train.grad_dispatch"]
+    said[0][5].update(bd_bwd="fused", bd_tile="256x512")
+    said[1][5].update(bd_bwd="fused", attn_bwd_full="split")
+    assert trace_rungs.backward_forms(events) == {
+        4096: "attn_bwd_full=split bd_bwd=fused"
+    }
