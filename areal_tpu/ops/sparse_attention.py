@@ -41,9 +41,12 @@ Three steps, each under its own device scope (``utils/tracing``):
   form as ``ops/moe.grouped_impl`` does: on a TPU a Pallas flash core that
   takes the selection as data (tiles in which nothing is selected,
   everything above the diagonal among them, are skipped; scores never leave
-  VMEM), elsewhere and for rows that are not a whole number of tiles a plain
-  XLA form in query blocks, which is what the kernel is held equal to in
-  interpret mode.
+  VMEM), a forward kernel and ONE backward kernel that makes dQ, dK and dV
+  of a tile's scores formed once, a kv head's dK and dV resident in VMEM
+  over the forward's own walk (so the selection is in HBM once, query-major:
+  no transposed copy for a second walk); elsewhere and for rows that are not
+  a whole number of tiles a plain XLA form in query blocks, which is what
+  the kernel is held equal to in interpret mode.
 
 The selection is by index, so nothing differentiable reaches the indexer:
 its three weights get a gradient of exactly zero from the policy loss (the
@@ -395,18 +398,52 @@ def _masked_attention_xla(q, k, v, mask):
 # selection is one int8 a pair and a grid step works through ALL the query
 # heads of one kv head on one tile of it: 1 GB a row, layer and pass.
 #
-# Three kernels over a (kv head, query block, key block) grid, each an online
-# softmax in float32 as the splash kernels are (``m``/``l`` kept lane-wide):
-# ``dsa_core_fwd`` (output and logsumexp), ``dsa_core_dq`` and
-# ``dsa_core_dkv`` (the latter on the transposed tile, so that the per-query
-# logsumexp broadcasts along sublanes and every product is a plain matmul).
+# Two kernels over a (kv head, query block, key block) grid, each a softmax
+# in float32 as the splash kernels' is (``m``/``l`` kept lane-wide):
+# ``dsa_core_fwd`` (output and logsumexp, an online softmax) and
+# ``dsa_core_bwd``, which walks the forward's tiles in the forward's order
+# and forms a kept tile's scores ONCE a query head: S, P (from the saved
+# logsumexp), dP and dS, and of them dV += P^T dO, dK += dS^T q and dQ += dS k,
+# five products, one exponential, one read of the mask. The tile is formed
+# transposed, keys on sublanes (the int8 tile is turned once for all its
+# heads), so that the per-query logsumexp broadcasts along sublanes and every
+# product is a plain matmul (dQ accumulates as ``[D, queries]`` against
+# ``k.T``, turned back where the query block is written, at its last key
+# block). dK and dV of a kv head's WHOLE ``[T, D]`` stay in float32 VMEM
+# scratch across the head's walk and are written once at its end
+# (``bwd_resident_bytes``: 24 MiB at 16,384 tokens, 48 at 32,768; the call
+# states its VMEM limit from it), each key block receiving its query blocks
+# ascending and the heads ascending within one. That took the place of a
+# pair (``dsa_core_dq``, and ``dsa_core_dkv`` on a second, transposed copy of
+# the selection with a walk of its own), which formed every tile's scores
+# twice, seven products: 33.0 -> 23.0 ms a layer on a 16,384-token row of a
+# v5e at the pair's 512 x 1024 tile, the same bits (PERF.md, PR 60).
 # ``tiles`` [query blocks, key blocks] says which tiles hold any selected
 # pair: the others (everything above the diagonal, and what the selection
-# leaves empty) are neither computed nor fetched (their block index is
-# clamped to the nearest tile that is, which the pipeline does not re-copy).
+# leaves empty) are not computed, and past a query block's last kept tile
+# not fetched either (their block index is clamped to that tile's, which
+# the pipeline does not re-copy).
+# Queries and keys a tile, the tile table's granularity and both kernels':
+# the sweep of {128..1024} x {256..1024} on the cell's packed rows (PERF.md,
+# PR 60) reads 512 x 512 lowest for the forward (10.5 ms a layer; 11.0 at
+# 512 x 1024, which it was) and for the backward (22.4; 23.0): finer tiles
+# leave more of the row's empty area out, and below 512 keys a tile the
+# steps' overhead takes it back.
 _BLOCK_Q = 512
-_BLOCK_KV = 1024
+_BLOCK_KV = 512
 _NT = (((1,), (1,)), ((), ()))  # a @ b.T
+# What a tile's blocks, their pipeline buffers and the compiler's temporaries
+# (a head's float32 scores and what is made of them) may take of VMEM beside
+# what the backward keeps resident (``ops/bd_attention._WORKING``'s rule).
+_WORKING = 32 << 20
+
+
+def bwd_resident_bytes(t: int, d: int, itemsize: int) -> int:
+    """The bytes the backward (``dsa_core_bwd``) keeps in VMEM across a kv
+    head's walk: dK and dV of its ``t`` keys in float32 scratch, and their
+    two output blocks ``[t, d]`` of ``itemsize`` bytes an element, one
+    buffer each."""
+    return 2 * t * d * 4 + 2 * t * d * itemsize
 
 
 def _core_blocks(t: int) -> Tuple[int, int]:
@@ -460,9 +497,26 @@ def _fwd_kernel(tiles_ref, last_ref, q_ref, k_ref, v_ref, mask_ref,
             lse_ref[r] = jnp.log(l) + m_s[r]
 
 
-def _dq_kernel(tiles_ref, last_ref, q_ref, k_ref, v_ref, mask_ref, do_ref,
-               lse_ref, di_ref, dq_ref, dq_s, *, rep, n_kv):
+def _bwd_kernel(tiles_ref, last_ref, q_ref, k_ref, v_ref, mask_ref, do_ref,
+                lse_ref, di_ref, dq_ref, dk_ref, dv_ref, dq_s, dk_s, dv_s,
+                *, rep, n_q, n_kv):
     i, j = pl.program_id(1), pl.program_id(2)
+    bq, bkv = mask_ref.shape
+    d = k_ref.shape[1]
+
+    def key_blocks(body):
+        def step(n, _):
+            body(pl.ds(pl.multiple_of(n * bkv, bkv), bkv))
+
+        jax.lax.fori_loop(0, n_kv, step, None)
+
+    @pl.when(jnp.logical_and(i == 0, j == 0))
+    def _():
+        def zero(at):
+            dk_s[at, :] = jnp.zeros((bkv, d), jnp.float32)
+            dv_s[at, :] = jnp.zeros((bkv, d), jnp.float32)
+
+        key_blocks(zero)
 
     @pl.when(j == 0)
     def _():
@@ -470,42 +524,13 @@ def _dq_kernel(tiles_ref, last_ref, q_ref, k_ref, v_ref, mask_ref, do_ref,
 
     @pl.when(tiles_ref[i, j] != 0)
     def _():
-        keep = mask_ref[...].astype(jnp.int32) != 0
+        # the tile transposed, once for all its heads: [bkv, bq]
+        keep = mask_ref[...].astype(jnp.float32).T != 0
         k, v = k_ref[...], v_ref[...]
-        for r in range(rep):
-            do = do_ref[r]
-            s = jax.lax.dot_general(
-                q_ref[r], k, _NT, preferred_element_type=jnp.float32
-            )
-            p = jnp.where(
-                keep, jnp.exp(s - jnp.expand_dims(lse_ref[r], -1)), 0.0
-            )
-            dp = jax.lax.dot_general(
-                do, v, _NT, preferred_element_type=jnp.float32
-            )
-            ds = p * (dp - jnp.expand_dims(di_ref[r], -1))
-            dq_s[r] += jnp.dot(
-                ds.astype(k.dtype), k, preferred_element_type=jnp.float32
-            )
-
-    @pl.when(j == n_kv - 1)
-    def _():
-        dq_ref[...] = dq_s[...].astype(dq_ref.dtype)
-
-
-def _dkv_kernel(tiles_ref, first_ref, q_ref, k_ref, v_ref, mask_t_ref, do_ref,
-                lse_ref, di_ref, dk_ref, dv_ref, dk_s, dv_s, *, rep, n_q):
-    j, i = pl.program_id(1), pl.program_id(2)  # key block, query block
-
-    @pl.when(i == 0)
-    def _():
-        dk_s[...] = jnp.zeros_like(dk_s)
-        dv_s[...] = jnp.zeros_like(dv_s)
-
-    @pl.when(tiles_ref[i, j] != 0)
-    def _():
-        keep = mask_t_ref[...].astype(jnp.int32) != 0  # [bkv, bq]
-        k, v = k_ref[...], v_ref[...]
+        k_t = k.T
+        # a kept tile's key block is the grid's own (j <= last[i])
+        mine = pl.ds(pl.multiple_of(j * bkv, bkv), bkv)
+        dk, dv = dk_s[mine, :], dv_s[mine, :]
         for r in range(rep):
             q, do = q_ref[r], do_ref[r]
             s_t = jax.lax.dot_general(
@@ -514,53 +539,54 @@ def _dkv_kernel(tiles_ref, first_ref, q_ref, k_ref, v_ref, mask_t_ref, do_ref,
             p_t = jnp.where(
                 keep, jnp.exp(s_t - lse_ref[pl.ds(r, 1), :]), 0.0
             )
-            dv_s[...] += jnp.dot(
+            dv = dv + jnp.dot(
                 p_t.astype(do.dtype), do, preferred_element_type=jnp.float32
             )
             dp_t = jax.lax.dot_general(
                 v, do, _NT, preferred_element_type=jnp.float32
             )
-            ds_t = p_t * (dp_t - di_ref[pl.ds(r, 1), :])
-            dk_s[...] += jnp.dot(
-                ds_t.astype(q.dtype), q, preferred_element_type=jnp.float32
+            ds_t = (p_t * (dp_t - di_ref[pl.ds(r, 1), :])).astype(q.dtype)
+            dk = dk + jnp.dot(ds_t, q, preferred_element_type=jnp.float32)
+            dq_s[:, r * bq:(r + 1) * bq] += jnp.dot(
+                k_t, ds_t, preferred_element_type=jnp.float32
             )
+        dk_s[mine, :], dv_s[mine, :] = dk, dv
 
-    @pl.when(i == n_q - 1)
+    @pl.when(j == n_kv - 1)
     def _():
-        dk_ref[...] = dk_s[...].astype(dk_ref.dtype)
-        dv_ref[...] = dv_s[...].astype(dv_ref.dtype)
+        for r in range(rep):
+            dq_ref[r] = dq_s[:, r * bq:(r + 1) * bq].T.astype(dq_ref.dtype)
+
+    @pl.when(jnp.logical_and(i == n_q - 1, j == n_kv - 1))
+    def _():
+        def cast(at):
+            dk_ref[at, :] = dk_s[at, :].astype(dk_ref.dtype)
+            dv_ref[at, :] = dv_s[at, :].astype(dv_ref.dtype)
+
+        key_blocks(cast)
 
 
-def _specs(rep, bq, bkv, d, by_query=True):
+def _specs(rep, bq, bkv, d):
     """Block specs of q-like [Hkv, rep, T, D], k-like [Hkv, T, D], the
     mask tile and per-query rows [Hkv, rep, T], for a (kv head, query
-    block, key block) grid (``by_query``) or a (kv head, key block, query
-    block) one. The scalar-prefetched bound clamps the inner index of
-    what the skipped tiles would fetch."""
-    if by_query:
-        qi = lambda h, i, j, tiles, last: i  # noqa: E731
-        kj = lambda h, i, j, tiles, last: jnp.minimum(j, last[i])  # noqa: E731
-    else:
-        qi = lambda h, j, i, tiles, first: jnp.maximum(i, first[j])  # noqa: E731
-        kj = lambda h, j, i, tiles, first: j  # noqa: E731
-    q_spec = pl.BlockSpec(
-        (None, rep, bq, d), lambda h, *a: (h, 0, qi(h, *a), 0)
-    )
+    block, key block) grid. The scalar-prefetched bound clamps the key
+    block of what the skipped tiles would fetch."""
+    def kj(h, i, j, tiles, last):
+        return jnp.minimum(j, last[i])
+
+    q_spec = pl.BlockSpec((None, rep, bq, d), lambda h, i, *a: (h, 0, i, 0))
     k_spec = pl.BlockSpec((None, bkv, d), lambda h, *a: (h, kj(h, *a), 0))
-    if by_query:
-        mask_spec = pl.BlockSpec(
-            (bq, bkv), lambda h, *a: (qi(h, *a), kj(h, *a))
-        )
-    else:
-        mask_spec = pl.BlockSpec(
-            (bkv, bq), lambda h, *a: (kj(h, *a), qi(h, *a))
-        )
-    row_spec = pl.BlockSpec((None, rep, bq), lambda h, *a: (h, 0, qi(h, *a)))
+    mask_spec = pl.BlockSpec((bq, bkv), lambda h, i, *a: (i, kj(h, i, *a)))
+    row_spec = pl.BlockSpec((None, rep, bq), lambda h, i, *a: (h, 0, i))
     return q_spec, k_spec, mask_spec, row_spec
 
 
 def _call(kernel, name, grid, in_specs, out_specs, out_shape, scratch,
-          interpret):
+          interpret, resident: int = 0):
+    """``resident``: the bytes a kernel keeps across a kv head's whole walk
+    (``bwd_resident_bytes``; 0: a tile's alone). Such a kernel walks the
+    query blocks in order too, under a VMEM limit that is those bytes and
+    ``_WORKING`` beside them."""
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -568,7 +594,11 @@ def _call(kernel, name, grid, in_specs, out_specs, out_shape, scratch,
             out_specs=out_specs, scratch_shapes=scratch,
         ),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=(
+                "parallel", "arbitrary" if resident else "parallel",
+                "arbitrary",
+            ),
+            vmem_limit_bytes=resident + _WORKING if resident else None,
         ),
         out_shape=out_shape, name=name, interpret=interpret,
     )
@@ -576,7 +606,7 @@ def _call(kernel, name, grid, in_specs, out_specs, out_shape, scratch,
 
 def _core_fwd(q, k, v, sel, interpret):
     hkv, rep, t, d = q.shape
-    mask8, _, tiles, last, _ = sel
+    mask8, tiles, last = sel
     bq, bkv = mask8.shape[0] // tiles.shape[0], mask8.shape[1] // tiles.shape[1]
     q_spec, k_spec, mask_spec, _ = _specs(rep, bq, bkv, d)
     lse_spec = pl.BlockSpec(
@@ -614,31 +644,28 @@ def _masked_flash_fwd(q, k, v, sel, interpret):
 
 def _masked_flash_bwd(interpret, res, do):
     q, k, v, sel, out, lse = res
-    mask8, mask_t8, tiles, last, first = sel
+    mask8, tiles, last = sel
     hkv, rep, t, d = q.shape
-    bq, bkv = t // tiles.shape[0], t // tiles.shape[1]
+    n_q, n_kv = tiles.shape
+    bq, bkv = t // n_q, t // n_kv
     di = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
     q_spec, k_spec, mask_spec, row_spec = _specs(rep, bq, bkv, d)
-    dq = _call(
-        functools.partial(_dq_kernel, rep=rep, n_kv=t // bkv),
-        "dsa_core_dq", (hkv, t // bq, t // bkv),
-        [q_spec, k_spec, k_spec, mask_spec, q_spec, row_spec, row_spec],
-        q_spec, jax.ShapeDtypeStruct(q.shape, q.dtype),
-        [pltpu.VMEM((rep, bq, d), jnp.float32)], interpret,
-    )(tiles, last, q, k, v, mask8, do, lse, di)
-    q_spec, k_spec, mask_spec, row_spec = _specs(
-        rep, bq, bkv, d, by_query=False
+    # a kv head's whole dK / dV, written once a walk
+    column = pl.BlockSpec(
+        (None, t, d), lambda h, *_: (h, 0, 0), pipeline_mode=pl.Buffered(1)
     )
-    dk, dv = _call(
-        functools.partial(_dkv_kernel, rep=rep, n_q=t // bq),
-        "dsa_core_dkv", (hkv, t // bkv, t // bq),
+    dq, dk, dv = _call(
+        functools.partial(_bwd_kernel, rep=rep, n_q=n_q, n_kv=n_kv),
+        "dsa_core_bwd", (hkv, n_q, n_kv),
         [q_spec, k_spec, k_spec, mask_spec, q_spec, row_spec, row_spec],
-        [k_spec, k_spec],
-        [jax.ShapeDtypeStruct(k.shape, k.dtype),
+        [q_spec, column, column],
+        [jax.ShapeDtypeStruct(q.shape, q.dtype),
+         jax.ShapeDtypeStruct(k.shape, k.dtype),
          jax.ShapeDtypeStruct(v.shape, v.dtype)],
-        [pltpu.VMEM((bkv, d), jnp.float32), pltpu.VMEM((bkv, d), jnp.float32)],
-        interpret,
-    )(tiles, first, q, k, v, mask_t8, do, lse, di)
+        [pltpu.VMEM((d, rep * bq), jnp.float32),
+         pltpu.VMEM((t, d), jnp.float32), pltpu.VMEM((t, d), jnp.float32)],
+        interpret, bwd_resident_bytes(t, d, k.dtype.itemsize),
+    )(tiles, last, q, k, v, mask8, do, lse, di)
     return dq, dk, dv, None
 
 
@@ -646,23 +673,15 @@ _masked_flash.defvjp(_masked_flash_fwd, _masked_flash_bwd)
 
 
 def _kernel_selection(mask: jnp.ndarray):
-    """The mask [T, T] as the kernels read it: one int8 a pair, query-major
-    and key-major, which tiles hold anything, and per query block the last
-    such key block (per key block the first such query block)."""
+    """The mask [T, T] as the kernels read it: one int8 a pair, which tiles
+    hold anything, and per query block the last such key block."""
     t = mask.shape[0]
     bq, bkv = _core_blocks(t)
     tiles = mask.reshape(t // bq, bq, t // bkv, bkv).any(axis=(1, 3))
-    n_q, n_kv = tiles.shape
     last = jnp.max(
-        jnp.where(tiles, jnp.arange(n_kv)[None], 0), axis=1
+        jnp.where(tiles, jnp.arange(tiles.shape[1])[None], 0), axis=1
     ).astype(jnp.int32)
-    first = jnp.min(
-        jnp.where(tiles, jnp.arange(n_q)[:, None], n_q - 1), axis=0
-    ).astype(jnp.int32)
-    return (
-        mask.astype(jnp.int8), mask.T.astype(jnp.int8),
-        tiles.astype(jnp.int32), last, first,
-    )
+    return mask.astype(jnp.int8), tiles.astype(jnp.int32), last
 
 
 def _masked_attention_kernel(q, k, v, mask, interpret=False):

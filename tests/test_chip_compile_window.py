@@ -73,16 +73,20 @@ def test_window_and_full_cores_compile_for_v5e(
     assert temp < (3e9 if band else 6e9), temp
 
 
-def test_sparse_attention_fwd_bwd_compiles_for_v5e(one_chip, monkeypatch):
+@pytest.mark.parametrize("t", [8192, 16384, 32768])
+def test_sparse_attention_fwd_bwd_compiles_for_v5e(one_chip, monkeypatch, t):
     """``ops/sparse_attention.sparse_attention`` forward and backward at
     Keye-VL-2.0's published widths (32 / 4 heads of 128, an indexer of 16
-    heads of 64, topk 2048) on one 8192-token row: the index in XLA, the
-    selection's counting kernel (``dsa_select_kth``, once for each of the
-    three query chunks that end past 2048 keys: 4096, 6144 and 8192 keys
-    wide, 64 queries' keys in VMEM), then the three Pallas kernels of the
-    core (``dsa_core_fwd``, ``dsa_core_dq``, ``dsa_core_dkv``) over int8
-    tiles of the selection, all the query heads of a kv head a grid step.
-    No [T, T] float32 array in the compiled program.
+    heads of 64, topk 2048) on one row of ``t`` tokens: the index in XLA,
+    the selection's counting kernel (``dsa_select_kth``, once for each query
+    chunk that ends past 2048 keys: 4096, 6144, ... ``t`` keys wide, 64
+    queries' keys in VMEM), then the two Pallas kernels of the core
+    (``dsa_core_fwd``, ``dsa_core_bwd``) over int8 tiles of the selection,
+    all the query heads of a kv head a grid step. The backward keeps a kv
+    head's dK and dV (``bwd_resident_bytes``: 12 MiB at 8,192 tokens, 48 at
+    32,768, the longest row whose int8 selection, 1 GB, a layer can hold)
+    in VMEM under the limit its call states. The selection is in the program
+    once (no transposed copy), and no [T, T] float32 array is.
     ``jax.default_backend()`` is the CPU here, so the platform's choice of
     the selection's form is set in the test."""
     import re
@@ -93,7 +97,8 @@ def test_sparse_attention_fwd_bwd_compiles_for_v5e(one_chip, monkeypatch):
     from areal_tpu.ops import sparse_attention as sa
 
     monkeypatch.setattr(sa, "select_impl", lambda shape=None: "kernel")
-    t, bf = 8192, jnp.bfloat16
+    bf = jnp.bfloat16
+    chunks = t // 2048 - 1
 
     def loss(q, k, v, seg, qi, ki, w):
         out, stats = sa.sparse_attention(
@@ -115,8 +120,16 @@ def test_sparse_attention_fwd_bwd_compiles_for_v5e(one_chip, monkeypatch):
         .compile()
     )
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") >= 6
-    assert len(re.findall(r"dsa_select_kth", text)) >= 3
+    assert text.count("tpu_custom_call") >= chunks + 2
+    assert len(re.findall(r"dsa_select_kth", text)) >= chunks
+    assert re.search(r"dsa_core_fwd", text) and re.search(r"dsa_core_bwd", text)
+    assert not re.search(r"dsa_core_d(q|kv)", text)
+    # the selection as the kernels read it: made once, never transposed
+    made = [
+        op for op in re.findall(rf"= s8\[{t},{t}\]\S* ([\w-]+)\(", text)
+        if op not in ("get-tuple-element", "parameter", "bitcast")
+    ]
+    assert len(made) == 1, made
     assert not re.search(rf"f32\[[0-9,]*{t},{t}[0-9,]*\]", text)
 
 

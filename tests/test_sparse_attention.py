@@ -193,24 +193,60 @@ def test_three_equal_mrope_coordinates_are_plain_rope():
     )
 
 
-@pytest.mark.parametrize("blocks", [(128, 256), (512, 1024)])
+def _sink_and_recent_index(t, block):
+    """Index inputs under which a query keeps the 8 most recent keys and,
+    in the EVEN query blocks of ``block`` rows only, its sequence's first 8
+    (the odd blocks keep the 16 most recent): key block 0 is read by query
+    blocks that are not neighbours, with empty tiles between kept ones."""
+    s = np.arange(t, dtype=np.float32)
+    ki = np.zeros((1, t, 8), np.float32)
+    ki[0, :8, 0] = 10.0
+    ki[0, :, 1] = 1.0 + s / t
+    qi = np.zeros((1, t, 2, 8), np.float32)
+    qi[0, :, 0, 0] = qi[0, :, 1, 1] = 1.0
+    w = np.ones((1, t, 2), np.float32)
+    w[0, :, 0] = (np.arange(t) // block) % 2 == 0
+    return qi, ki, w
+
+
+# (tile, segments of the 1024-token row, what the case is for)
+_CORE_CASES = [
+    pytest.param((128, 256), [300, 600, 124], "two-sequences", id="128x256"),
+    pytest.param((512, 512), [300, 600, 124], "two-sequences", id="chip-tiles"),
+    pytest.param((128, 256), [1024, 0, 0], "skipped-between", id="skipped-between"),
+    pytest.param((128, 256), [300, 400, 324], "padding-key-block", id="padding-key-block"),
+]
+
+
+@pytest.mark.parametrize("blocks,lens,case", _CORE_CASES)
 def test_the_kernel_in_interpret_mode_equals_the_xla_core_both_ways(
-    blocks, monkeypatch
+    blocks, lens, case, monkeypatch
 ):
-    """1024 tokens, two sequences and padding: with 128 x 256 tiles the grid
-    is 8 x 4 of which the tiles above the diagonal and the first sequence's
-    keys under the second's queries hold nothing and are skipped; with the
-    chip's own tile sizes a row this short is 2 x 1."""
+    """1024 tokens, 2 query heads a kv head. ``two-sequences`` (and
+    padding): with 128 x 256 tiles the grid is 8 x 4 of which the tiles above
+    the diagonal and the first sequence's keys under the second's queries
+    hold nothing and are skipped; with the chip's own tile sizes a row this
+    short is 2 x 2, one tile above the diagonal. ``skipped-between``: one sequence that ends with the
+    row's last query block, under an index that makes key block 0's resident
+    dK / dV take terms from query blocks 0, 1, 2, 4 and 6, the walk of query
+    block 6 skipping a tile between two kept ones. ``padding-key-block``:
+    the last key block holds padding alone, whose tiles are walked (a padding
+    token keeps itself) and whose dK and dV are exactly zero."""
     monkeypatch.setattr(sa, "_BLOCK_Q", blocks[0])
     monkeypatch.setattr(sa, "_BLOCK_KV", blocks[1])
     rng = np.random.default_rng(7)
     (q, k, v), (qi, ki, w) = _random_op_inputs(rng, 1024)
-    seg = jnp.asarray(np.repeat([1, 2, 0], [300, 600, 124])[None], jnp.int32)
+    if case == "skipped-between":
+        qi, ki, w = _sink_and_recent_index(1024, blocks[0])
+    seg = jnp.asarray(np.repeat([1, 2, 0], lens)[None], jnp.int32)
     mask, _ = sa.select_keys(qi[0], ki[0], w[0], seg[0], 16)
-    tiles = np.asarray(sa._kernel_selection(mask)[2])
+    tiles = np.asarray(sa._kernel_selection(mask)[1])
     assert tiles.shape == (1024 // sa._core_blocks(1024)[0],
                            1024 // sa._core_blocks(1024)[1])
     assert (tiles.size > 2) == (not tiles.all())  # something is skipped
+    if case == "skipped-between":
+        assert np.nonzero(tiles[:, 0])[0].tolist() == [0, 1, 2, 4, 6]
+        assert tiles[6].tolist() == [1, 0, 1, 1] and tiles[7, 3]
     weight = jnp.cos(jnp.arange(q.size, dtype=jnp.float32)).reshape(q.shape)
 
     def run(core):
@@ -226,8 +262,14 @@ def test_the_kernel_in_interpret_mode_equals_the_xla_core_both_ways(
     assert np.abs(np.asarray(out_x)).max() > 0.1
     np.testing.assert_allclose(out_s, out_x, atol=KERNEL_TOL, rtol=0)
     for a, b in zip(grads_s, grads_x):
+        assert np.abs(np.asarray(b)).max() > 0.1
         np.testing.assert_allclose(a, b, atol=10 * KERNEL_TOL, rtol=0)
-    assert not np.asarray(out_s)[0, 900:].any()  # padding reads nothing
+    real = sum(lens[:2])
+    assert not np.asarray(out_s)[0, real:].any()  # padding reads nothing
+    for g in grads_s:  # and gives and takes no gradient, to the bit
+        assert not np.asarray(g)[0, real:].any()
+    if case == "padding-key-block":
+        assert real <= 1024 - blocks[1] and tiles[7, 3]
 
 
 def test_the_op_runs_per_chip_on_its_own_rows_under_an_fsdp_mesh():
