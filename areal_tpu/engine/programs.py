@@ -29,6 +29,7 @@ from areal_tpu.models.transformer import draft_forward, param_logical_axes
 from areal_tpu.ops import functional
 from areal_tpu.ops import kda as kda_ops
 from areal_tpu.ops import moe as moe_ops
+from areal_tpu.ops import ssm_scan as ssm_ops
 from areal_tpu.parallel import sharding as sharding_lib
 from areal_tpu.utils import tracing
 
@@ -57,6 +58,7 @@ def _forward_fn(
             for k in moe_ops.sparse_leaves(mc)
         })
     kda = kda_ops.make_kda_scan(mesh) if mc.kda or mc.gdn else None
+    ssm = ssm_ops.make_ssm_scan(mesh) if mc.ssm else None
     # [B, T, D] activation constraint: rows over (data, fsdp), tokens over
     # seq. Pinning this stops GSPMD from propagating the embedding table's
     # column sharding onto the batch, which replicates every layer
@@ -72,7 +74,7 @@ def _forward_fn(
         out = packed_forward(
             cparams, mc, arrays, remat=remat, attend_fn=attend_fn,
             moe_fn=moe, kda_fn=kda, return_stats=stats, return_hidden=True,
-            act_sharding=act_sh,
+            act_sharding=act_sh, ssm_fn=ssm,
         )
         if not stats:
             return out

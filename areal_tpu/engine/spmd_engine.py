@@ -222,12 +222,13 @@ class SPMDTrainEngine(TrainEngine):
         # ``attn_impl`` (``transformer.span_attrs``) and, out of it, the
         # forms its ops resolved to in this process: the
         # experts' grouped matmul ("megablox" | "ragged_dot"), the learned
-        # selection's passes, the delta rule's and its convolution's
-        # ("kernel" | "xla")
+        # selection's passes, the delta rule's and its convolution's, the
+        # selective scan's ("kernel" | "xla")
         self.moe_impl: Optional[str] = None
         self.dsa_select_impl: Optional[str] = None
         self.kda_impl: Optional[str] = None
         self.prep_impl: Optional[str] = None
+        self.ssm_impl: Optional[str] = None
         self._span_attrs: Dict[str, Any] = {}
         self.initialized = False
 
@@ -318,9 +319,10 @@ class SPMDTrainEngine(TrainEngine):
             self._counters.update(bd_sequence_tokens=0, bd_stream_slots=0)
         self._span_attrs = transformer.span_attrs(mc, self._experts_split())
         (self.moe_impl, self.dsa_select_impl, self.kda_impl,
-         self.prep_impl) = map(
+         self.prep_impl, self.ssm_impl) = map(
             self._span_attrs.get,
-            ("moe_impl", "dsa_select_impl", "kda_impl", "prep_impl"),
+            ("moe_impl", "dsa_select_impl", "kda_impl", "prep_impl",
+             "ssm_impl"),
         )
         n = count_params(self.params)
         logger.info(

@@ -119,6 +119,24 @@ class RotaryConfig:
     attention_factor: float = 1.0
     head_gate: bool = False
     output_gate: bool = False
+    # no rotation and no position of any kind (the ``jamba`` family's
+    # attention layers): the same mixer without the turn
+    nope: bool = False
+
+
+@dataclasses.dataclass(frozen=True)
+class SSMConfig:
+    """The state-space mixer's sizes (``models/transformer._ssm_mixer``;
+    ``ops/ssm_scan.py`` has the scan; Mamba-1, S6): ``d_inner`` channels,
+    each a state of ``d_state`` numbers; the token's step ``dt`` comes through
+    a rank of ``dt_rank``; a depthwise causal convolution of ``conv_size``
+    taps with a bias before it. The ``jamba`` family norms ``dt``'s input,
+    ``B`` and ``C`` each by a weight (its three inner norms)."""
+
+    d_inner: int
+    d_state: int
+    dt_rank: int
+    conv_size: int = 4
 
 
 @dataclasses.dataclass(frozen=True)
@@ -240,6 +258,8 @@ class ModelConfig:
     # it is an MLP (None: ``h @ w_router``)
     cca: Optional[CCAConfig] = None
     router: Optional[RouterConfig] = None
+    # the state-space mixer of such a stack (kind ``ssm``)
+    ssm: Optional[SSMConfig] = None
     # --- the draft module beside the stack (DeepSeek-V3's multi-token
     # prediction at depth 1; ``models/transformer.draft_forward``): one block
     # of the stack's LAST kind with its own weights over [norm(Emb(next
@@ -306,6 +326,11 @@ class ModelConfig:
             kind.startswith("swa+") for kind in self.layer_kinds
         )
         return self.swa.window if has else 0
+
+    @property
+    def num_ssm_layers(self) -> int:
+        """The layers whose mixer is the selective scan."""
+        return sum(kind.startswith("ssm+") for kind in self.layer_kinds)
 
     def kind_layers(self, kind: str) -> int:
         """How many layers are of block ``kind``."""
@@ -384,12 +409,24 @@ class ModelConfig:
 # than one token a sequence: ROADMAP B), ``hf_io`` (a share of the experts is
 # no file of its own) and sequence parallelism refuse it by what the
 # ``block_diffusion`` mechanism declares.
+# jamba is a stack of TWO block kinds (``_jamba_from_hf``): ``ssm``, a Mamba-1
+# selective scan (a decay a channel AND a state, ``ops/ssm_scan.py``) behind a
+# biased causal convolution, with the family's three inner norms, and ``full``
+# WITHOUT a rotation or a position of any kind (``RotaryConfig.nope``), one
+# key head serving the query heads, where ``i % attn_layer_period ==
+# attn_layer_offset``; a dense SwiGLU FFN in every layer (``num_experts`` 1:
+# a Jamba with experts has a router that is not written, and is refused); the
+# head tied to the embedding. The trainer runs it; the generation engine (a
+# layer's cache is a recurrent state and a convolution's tail, not pages:
+# ROADMAP B.4) and sequence parallelism (the state would pass from device to
+# device) refuse it by what the ``ssm`` mechanism declares.
 # gemma2/gpt2 remain out (gemma2: soft-capped scores and post-norms beside
 # its windows; gpt2: learned positions, a new embedding).
 _HF_FAMILIES = (
     "llama", "qwen2", "qwen3", "mistral", "qwen3_moe", "mixtral",
     "qwen2_vl", "qwen2_moe", "gemma", "olmoe", "KeyeVL2", "kimi_linear",
     "laguna", "zaya", "mellum", "qwen3_next", "joyai_llm_flash", "sdar_moe",
+    "jamba",
 )
 
 
@@ -979,6 +1016,59 @@ def _deepseek_v3_from_hf(d: dict) -> ModelConfig:
     )
 
 
+def _jamba_from_hf(d: dict) -> ModelConfig:
+    """``model_type`` jamba: layer ``i`` is attention where ``i %
+    attn_layer_period == attn_layer_offset`` (the family's code's rule) and
+    the selective scan elsewhere (``mamba_*`` its sizes, ``d_inner`` =
+    ``mamba_expand`` x ``hidden_size``); the attention has no rotation and no
+    position; every layer's FFN is dense (``num_experts`` 1, so
+    ``expert_layer_*`` are read by nothing); ``head_dim`` is the file's or
+    ``hidden_size / num_attention_heads``."""
+    refused = {
+        f"num_experts {d.get('num_experts')} (the family's router over "
+        "experts is not written; this reader takes the dense FFN of "
+        "num_experts 1)": d.get("num_experts", 1) > 1,
+        "sliding_window": d.get("sliding_window"),
+        "mamba_proj_bias": d.get("mamba_proj_bias", False),
+        "a convolution without its bias (mamba_conv_bias false)": (
+            not d.get("mamba_conv_bias", True)
+        ),
+        f"hidden_act {d.get('hidden_act')!r}": (
+            d.get("hidden_act", "silu") != "silu"
+        ),
+    }
+    for what, present in refused.items():
+        if present:
+            raise ValueError(f"jamba with {what} is not supported")
+    heads, hidden = d["num_attention_heads"], d["hidden_size"]
+    every, at = d.get("attn_layer_period", 8), d.get("attn_layer_offset", 4)
+    rank = d.get("mamba_dt_rank", "auto")
+    return ModelConfig(
+        vocab_size=d["vocab_size"],
+        hidden_size=hidden,
+        intermediate_size=d["intermediate_size"],
+        num_layers=d["num_hidden_layers"],
+        num_heads=heads,
+        num_kv_heads=d.get("num_key_value_heads", heads),
+        head_dim=d.get("head_dim") or hidden // heads,
+        max_position_embeddings=d.get("max_position_embeddings", 262144),
+        rms_norm_eps=d.get("rms_norm_eps", 1e-6),
+        tie_word_embeddings=d.get("tie_word_embeddings", False),
+        family="jamba",
+        layer_kinds=tuple(
+            ("full" if i % every == at else "ssm") + "+dense"
+            for i in range(d["num_hidden_layers"])
+        ),
+        full=RotaryConfig(num_heads=heads, nope=True),
+        ssm=SSMConfig(
+            d_inner=d.get("mamba_expand", 2) * hidden,
+            d_state=d.get("mamba_d_state", 16),
+            dt_rank=-(-hidden // 16) if rank == "auto" else rank,
+            conv_size=d.get("mamba_d_conv", 4),
+        ),
+    )
+
+
 def from_hf_config(d: dict) -> ModelConfig:
     """Build from a parsed HF config.json dict (families mirror the
     reference's from_hf registry: realhf/api/from_hf/)."""
@@ -999,6 +1089,8 @@ def from_hf_config(d: dict) -> ModelConfig:
         return _deepseek_v3_from_hf(d)
     if model_type == "sdar_moe":
         return _sdar_from_hf(d)
+    if model_type == "jamba":
+        return _jamba_from_hf(d)
     num_heads = d["num_attention_heads"]
     hidden = d["hidden_size"]
     head_dim = d.get("head_dim") or hidden // num_heads
@@ -1151,6 +1243,19 @@ def tiny_config(family: str = "qwen2", vocab_size: int = 128) -> ModelConfig:
                           qk_rope_head_dim=4, v_head_dim=8, q_lora_rank=12,
                           rotated=True, rope_theta=32000000.0),
             mtp_layers=1, mtp_loss_coef=0.1,
+        )
+    if family == "jamba":
+        # one period: three selective-scan layers (128 channels of state 16,
+        # the step through a rank of 4) and, second, one attention layer of 4
+        # query heads over ONE key head of 16, no rotation; dense FFNs, tied
+        return ModelConfig(
+            vocab_size=vocab_size, hidden_size=64, intermediate_size=128,
+            num_layers=4, num_heads=4, num_kv_heads=1, head_dim=16,
+            max_position_embeddings=512, rms_norm_eps=1e-6,
+            tie_word_embeddings=True, family=family,
+            layer_kinds=("ssm+dense", "full+dense", "ssm+dense", "ssm+dense"),
+            full=RotaryConfig(num_heads=4, nope=True),
+            ssm=SSMConfig(d_inner=128, d_state=16, dt_rank=4),
         )
     if family == "zaya":
         # three ``cca+moe`` layers: 4 query and 2 key heads of 16 on the

@@ -32,6 +32,7 @@ def packed_forward(
     return_hidden: bool = False,
     kda_fn: Optional[Any] = None,
     act_sharding: Optional[Any] = None,
+    ssm_fn: Optional[Any] = None,
 ):
     """``transformer.apply`` over engine-packed arrays (tokens /
     segment_ids / positions / t_* / s_*), with the vision tower spliced in
@@ -68,6 +69,8 @@ def packed_forward(
             positions = arrays["t_mrope_pos"].astype(jnp.int32)
     if "t_bd_stream" in arrays:  # a block-diffusion policy's rows
         kwargs["bd"] = (arrays["t_bd_stream"], arrays["t_bd_scored"])
+    if ssm_fn is not None:
+        kwargs["ssm_fn"] = ssm_fn
     return transformer.apply(
         params,
         cfg,

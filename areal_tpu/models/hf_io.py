@@ -152,6 +152,15 @@ def _kind_tensor_names(cfg: ModelConfig):
             "from (the catalog gives the config.json only); initialise from "
             "scratch"
         )
+    if cfg.family == "jamba":
+        raise NotImplementedError(
+            "family 'jamba' has no checkpoint layout here: the published "
+            "tensors' names (mamba.in_proj, mamba.x_proj, mamba.dt_layernorm, "
+            "feed_forward ...) are in no source this repo was written from "
+            "(the catalog gives the config.json only) and no weights are in "
+            "the repository to hold a mapping to (ROADMAP B.15); initialise "
+            "from scratch"
+        )
     if cfg.family == "qwen3_next":
         raise NotImplementedError(
             "family 'qwen3_next' has no checkpoint layout here yet: the "
@@ -623,6 +632,41 @@ def _qwen3_next_config_dict(cfg: ModelConfig) -> dict:
     }
 
 
+def _jamba_config_dict(cfg: ModelConfig) -> dict:
+    """The keys ``models/config._jamba_from_hf`` reads."""
+    full_at = [i for i, k in enumerate(cfg.layer_kinds) if k[:4] == "full"]
+    every = (
+        full_at[1] - full_at[0] if len(full_at) > 1 else cfg.num_layers
+    )
+    sc = cfg.ssm
+    return {
+        "model_type": cfg.family,
+        "vocab_size": cfg.vocab_size,
+        "hidden_size": cfg.hidden_size,
+        "intermediate_size": cfg.intermediate_size,
+        "num_hidden_layers": cfg.num_layers,
+        "num_attention_heads": cfg.num_heads,
+        "num_key_value_heads": cfg.num_kv_heads,
+        "head_dim": cfg.head_dim,
+        "max_position_embeddings": cfg.max_position_embeddings,
+        "rms_norm_eps": cfg.rms_norm_eps,
+        "tie_word_embeddings": cfg.tie_word_embeddings,
+        "hidden_act": "silu",
+        "torch_dtype": "float32",
+        "attn_layer_period": every,
+        "attn_layer_offset": full_at[0] if full_at else every,
+        "mamba_expand": sc.d_inner // cfg.hidden_size,
+        "mamba_d_state": sc.d_state,
+        "mamba_dt_rank": sc.dt_rank,
+        "mamba_d_conv": sc.conv_size,
+        "mamba_conv_bias": True,
+        "mamba_proj_bias": False,
+        "num_experts": 1,
+        "num_experts_per_tok": 1,
+        "sliding_window": None,
+    }
+
+
 def _deepseek_v3_config_dict(cfg: ModelConfig) -> dict:
     """The keys ``models/config._deepseek_v3_from_hf`` reads."""
     m = cfg.mla
@@ -681,6 +725,8 @@ def default_hf_config_dict(cfg: ModelConfig) -> dict:
         return _laguna_config_dict(cfg)
     if cfg.family == "zaya":
         return _zaya_config_dict(cfg)
+    if cfg.family == "jamba":
+        return _jamba_config_dict(cfg)
     return {
         "model_type": cfg.family,
         "vocab_size": cfg.vocab_size,

@@ -17,8 +17,9 @@ from-scratch parallel causal LM — re-designed TPU-first:
 - A model whose layers are of more than one kind (``ModelConfig.layer_kinds``:
   gated delta-rule mixers of either decay (``kda`` a channel, ``gdn`` a
   head), latent-attention and rotary mixers, the last with or without a
-  window, and the convolutional latent mixer ``cca``, over dense or sparse
-  FFNs) holds ONE STACK A BLOCK KIND
+  window (or a rotation), the convolutional latent mixer ``cca`` and the
+  state-space mixer ``ssm``, over dense or sparse FFNs) holds ONE STACK A
+  BLOCK KIND
   under ``params["layers"][kind]`` and ``apply`` walks the pattern: every run
   of consecutive layers of one kind is a scan over that kind's slice, under
   the same checkpoint policy. A model of one kind keeps the flat
@@ -48,6 +49,7 @@ from areal_tpu.ops import kda as kda_ops
 from areal_tpu.ops import moe as moe_ops
 from areal_tpu.ops import rotary as rotary_ops
 from areal_tpu.ops import sparse_attention as dsa_ops
+from areal_tpu.ops import ssm_scan as ssm_ops
 from areal_tpu.ops.kda import kda_scan
 from areal_tpu.ops.moe import moe_ffn_from_params, router_mlp
 from areal_tpu.ops.sparse_attention import sparse_attention
@@ -211,6 +213,33 @@ def _kind_leaves(cfg: ModelConfig, kind: str) -> Dict[str, tuple]:
                 f"{sub}_out_scale": ((D,), "ones", (None,)),
                 f"{sub}_out_bias": ((D,), "zeros", (None,)),
             })
+    elif mixer == "ssm":
+        sc = cfg.ssm
+        C, N, R = sc.d_inner, sc.d_state, sc.dt_rank
+        # the Mamba initialisation, laid out evenly instead of drawn: a
+        # channel's rates 1..N, the steps over 1e-3..1e-1 through the
+        # inverse softplus, so that decays are neither 0 nor 1
+        dt = jnp.exp(jnp.linspace(jnp.log(1e-3), jnp.log(1e-1), C))
+        leaves.update({
+            # one product: x, then the gate z
+            "w_in": ((D, 2 * C), "normal", ("embed", "heads")),
+            "conv_x": ((C, sc.conv_size), "normal", ("channel", None)),
+            "conv_x_bias": ((C,), "zeros", ("channel",)),
+            # dt's input (rank R), B and C (N each)
+            "w_x": ((C, R + 2 * N), "normal", ("channel", None)),
+            "dt_norm": ((R,), "ones", (None,)),
+            "b_norm": ((N,), "ones", (None,)),
+            "c_norm": ((N,), "ones", (None,)),
+            "w_dt": ((R, C), "normal", (None, "channel")),
+            "dt_bias": ((C,), dt + jnp.log(-jnp.expm1(-dt)), ("channel",)),
+            "a_log": (
+                (C, N),
+                jnp.broadcast_to(jnp.log(jnp.arange(1.0, N + 1.0)), (C, N)),
+                ("channel", None),
+            ),
+            "d_skip": ((C,), "ones", ("channel",)),
+            "wo": ((C, D), "normal", ("heads", "embed")),
+        })
     else:
         raise ValueError(f"no mixer {mixer!r} among the block kinds")
     if ffn == "moe":
@@ -627,6 +656,32 @@ MECHANISMS: Dict[str, Mechanism] = {
             "prep_impl": _gdn_prep_impl(cfg),
         },
     ),
+    "ssm": Mechanism(
+        counts={
+            "ssm_tokens": "the real tokens the layers' selective scans ran "
+            "over",
+            "ssm_segments": "the sequence starts their states were reset at",
+        },
+        count=_reported("ssm", "tokens", "segments"),
+        no_seq_parallel=lambda cfg: (
+            f"a selective scan (family {cfg.family!r}: a state a channel "
+            "that passes from token to token) has no sequence-parallel form "
+            "(attn_impl={attn_impl!r}, seq_parallel_size={seq_parallel_size})"
+            ": the state would have to be handed from device to device, "
+            "ROADMAP B"
+        ),
+        no_generation=lambda cfg: (
+            "the generation engine cannot run a state-space mixer (family "
+            f"{cfg.family!r}): a layer's cache is a recurrent state "
+            f"[{cfg.ssm.d_inner}, {cfg.ssm.d_state}] and the convolution's "
+            "last tokens, not KV pages (ROADMAP B.4); the trainer can"
+        ),
+        span_attrs=lambda cfg: {
+            "ssm_impl": ssm_ops.scan_impl(cfg.ssm.d_inner),
+            "ssm_chunk": ssm_ops.CHUNK,
+            "ssm_channel_block": ssm_ops.CHANNELS,
+        },
+    ),
     "mla": Mechanism(
         no_generation=_no_kinds_stack,
         span_attrs=lambda cfg: {
@@ -709,7 +764,8 @@ def _rotated_width(cfg: ModelConfig) -> int:
     of kinds without a rotary or a ``cca`` mixer, its latent mixer NoPE). A
     rotated latent mixer turns a slice of its head by itself, that wide."""
     rotates = not cfg.layer_kinds or cfg.cca is not None or any(
-        getattr(cfg, mixer) is not None for mixer in _ROTARY_MIXERS
+        getattr(cfg, mixer) is not None and not getattr(cfg, mixer).nope
+        for mixer in _ROTARY_MIXERS
     )
     if not rotates and cfg.mla is not None and cfg.mla.rotated:
         return cfg.mla.qk_rope_head_dim
@@ -1049,15 +1105,17 @@ def _ffn_residual(
     return x, (stats, mixer_stats)
 
 
-def _short_conv(x, w, segment_ids):
+def _short_conv(x, w, segment_ids, bias=None):
     """Depthwise causal convolution over the tokens of ONE sequence:
-    y_t = sum_s w[:, K-1-s] x_{t-s}, over the s for which token t - s is of
-    token t's sequence. ``x`` [B, T, C]; ``w`` [C, K], tap K-1 the token
-    itself. Float32."""
+    y_t = sum_s w[:, K-1-s] x_{t-s} (+ ``bias`` [C]), over the s for which
+    token t - s is of token t's sequence. ``x`` [B, T, C]; ``w`` [C, K], tap
+    K-1 the token itself. Float32."""
     taps = w.shape[-1]
     x = x.astype(jnp.float32)
     w = w.astype(jnp.float32)
     y = x * w[:, taps - 1]
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
     for s in range(1, taps):
         back = jnp.pad(x, ((0, 0), (s, 0), (0, 0)))[:, :-s]
         seg_back = jnp.pad(segment_ids, ((0, 0), (s, 0)))[:, :-s]
@@ -1196,6 +1254,44 @@ def _gdn_mixer(cfg, h, lp, segment_ids, kda_fn):
         return y.reshape(b, t, Hv * dv) @ lp["wo"], stats
 
 
+def _ssm_mixer(cfg, h, lp, segment_ids, ssm_fn):
+    """The state-space mixer (Mamba-1 with the ``jamba`` family's three inner
+    norms) on the normed input ``h`` [B, T, D]: (y [B, T, D], ``SSMStats``).
+    One product makes x and the gate z; x passes the biased causal
+    convolution and ``silu``; one more product makes the step's input (normed,
+    then through ``w_dt``, its bias and ``softplus``: float32 from the
+    product on) and the token's ``B`` and ``C`` (normed); ``ops/ssm_scan.py``
+    has the scan, in float32; its output takes the skip ``d_skip * x`` and
+    the gate ``silu(z)`` (scope ``ssm_prep`` around the scan; the in and out
+    projections under ``qkv_proj`` / ``o_proj``)."""
+    sc, f32 = cfg.ssm, jnp.float32
+    with device_scope("qkv_proj"):
+        x, z = jnp.split(h @ lp["w_in"], 2, axis=-1)
+    with device_scope("ssm_prep"):
+        x = jax.nn.silu(_short_conv(
+            x, lp["conv_x"], segment_ids, lp["conv_x_bias"]
+        )).astype(h.dtype)
+        r, bm, cm = jnp.split(
+            x @ lp["w_x"], [sc.dt_rank, sc.dt_rank + sc.d_state], axis=-1
+        )
+        r = rms_norm(r, lp["dt_norm"], cfg.rms_norm_eps)
+        bm = rms_norm(bm, lp["b_norm"], cfg.rms_norm_eps)
+        cm = rms_norm(cm, lp["c_norm"], cfg.rms_norm_eps)
+        dt = jax.nn.softplus(
+            jnp.dot(r, lp["w_dt"], preferred_element_type=f32)
+            + lp["dt_bias"].astype(f32)
+        )
+        rate = -jnp.exp(lp["a_log"].astype(f32))
+    y, stats = (ssm_fn or ssm_ops.ssm_scan)(x, dt, rate, bm, cm, segment_ids)
+    with device_scope("ssm_prep"):
+        y = (
+            (y + lp["d_skip"].astype(f32) * x.astype(f32))
+            * jax.nn.silu(z.astype(f32))
+        ).astype(h.dtype)
+    with device_scope("o_proj"):
+        return y @ lp["wo"], stats
+
+
 def _mla_mixer(cfg, h, lp, segment_ids, attend_fn, rotation=None):
     """Latent attention on the normed input ``h``: the keys' and values' one
     compression, a key part all heads share, and a causal softmax core whose
@@ -1281,7 +1377,8 @@ def _rotary_mixer(cfg, rc, h, lp, segment_ids, cos, sin, attend_fn, bd=None):
     norms, the rotation and the gate are outside both, as the projections
     are. ``bd``: a block-diffusion policy's rows, (blocks, streams) of the
     slots; the core then runs under their mask (scope ``attn_bd``, not
-    ``attention``; ``ops/bd_attention.py``)."""
+    ``attention``; ``ops/bd_attention.py``). ``rc.nope``: no rotation (``cos``
+    and ``sin`` None), the same mixer without the turn."""
     b, t, _ = h.shape
     H, dh = rc.num_heads, cfg.head_dim
     uo = cfg.norm_add_unit_offset
@@ -1301,6 +1398,8 @@ def _rotary_mixer(cfg, rc, h, lp, segment_ids, cos, sin, attend_fn, bd=None):
                 x = rms_norm(
                     x, lp[norm], cfg.rms_norm_eps, add_unit_offset=uo
                 )
+        if rc.nope:
+            return x
         with device_scope("rope"):
             return rotate_leading(x, cos, sin, in_rotation(norm))
 
@@ -1473,7 +1572,7 @@ def _cca_block(cfg, carry, lp, segment_ids, rotation, attend_fn, moe_fn):
 
 def _block_body(
     cfg, kind, x, lp, segment_ids, attend_fn=None, moe_fn=None, kda_fn=None,
-    rotation=None, bd=None,
+    rotation=None, bd=None, ssm_fn=None,
 ):
     """One layer of block ``kind`` (``ModelConfig.layer_kinds``).
     ``rotation``: a rotary kind's (cos, sin). ``x`` is the scan's carry: the
@@ -1494,10 +1593,12 @@ def _block_body(
         y, mixer_stats = _kda_mixer(cfg, h, lp, segment_ids, kda_fn)
     elif mixer == "gdn":
         y, mixer_stats = _gdn_mixer(cfg, h, lp, segment_ids, kda_fn)
+    elif mixer == "ssm":
+        y, mixer_stats = _ssm_mixer(cfg, h, lp, segment_ids, ssm_fn)
     elif mixer in _ROTARY_MIXERS:
         y = _rotary_mixer(
-            cfg, getattr(cfg, mixer), h, lp, segment_ids, *rotation,
-            attend_fn, bd,
+            cfg, getattr(cfg, mixer), h, lp, segment_ids,
+            *(rotation or (None, None)), attend_fn, bd,
         )
     else:
         y = _mla_mixer(cfg, h, lp, segment_ids, attend_fn, rotation)
@@ -1544,7 +1645,7 @@ def _rotations(cfg, positions):
     out = {}
     for mixer in _ROTARY_MIXERS:
         rc = getattr(cfg, mixer)
-        if rc is not None:
+        if rc is not None and not rc.nope:
             out[mixer] = rope_at(
                 positions,
                 rope_inv_freq(
@@ -1567,7 +1668,7 @@ def _rotations(cfg, positions):
 
 def _apply_kinds(
     cfg, params, x, segment_ids, positions, remat, attend_fn, moe_fn, kda_fn,
-    act_sharding, bd=None,
+    act_sharding, bd=None, ssm_fn=None,
 ):
     """The stack of a model of several block kinds: every run of consecutive
     layers of one kind is a scan over that kind's slice of its stack.
@@ -1610,7 +1711,7 @@ def _apply_kinds(
         def body(carry, lp, kind=kind):
             out, aux = _block_body(
                 cfg, kind, carry, lp, segment_ids, attend_fn, moe_fn, kda_fn,
-                rotations.get(kind.split("+")[0]), bd_rows,
+                rotations.get(kind.split("+")[0]), bd_rows, ssm_fn,
             )
             if act_sharding is not None:  # the carried state's too
                 out = jax.tree_util.tree_map(
@@ -1665,6 +1766,7 @@ def apply(
     act_sharding: Optional[Any] = None,  # NamedSharding for [B, T, D] acts
     # a block-diffusion policy's rows: (streams, scored), [B, T] each
     bd: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,
+    ssm_fn: Optional[Any] = None,
 ):
     """Forward to logits [B, T, vocab] (fp32); with ``return_stats=True``
     returns (logits, ``LayerStats`` of the whole stack: the load-balancing
@@ -1678,7 +1780,8 @@ def apply(
     `attend_fn` overrides attention: the engine's per-device ``shard_map``
     of ``moe_ffn_from_params`` under a mesh. `kda_fn(q, k, v, g, beta,
     segment_ids)` does the same for the gated delta rule
-    (``ops/kda.make_kda_scan``). Window-attention layers call ``attend_fn``
+    (``ops/kda.make_kda_scan``), `ssm_fn(x, dt, a, bm, cm, segment_ids)` for
+    the selective scan (``ops/ssm_scan.make_ssm_scan``). Window-attention layers call ``attend_fn``
     with ``layer_window=`` (``ops.flash.flash_segment_attention`` takes it;
     the XLA form when ``attend_fn`` is None).
 
@@ -1734,7 +1837,7 @@ def apply(
     if cfg.layer_kinds:
         x, stats = _apply_kinds(
             cfg, params, x, segment_ids, positions, remat, attend_fn, moe_fn,
-            kda_fn, act_sharding, bd,
+            kda_fn, act_sharding, bd, ssm_fn,
         )
         logits = _head(params, cfg, x, return_hidden)
         return (logits, stats) if return_stats else logits

@@ -678,7 +678,42 @@ def joyai_llm_flash_config() -> ModelConfig:
     )
 
 
+def jamba2_3b_config() -> ModelConfig:
+    """AI21 Jamba2 3B as one four-chip host holds it under ``fsdp=4``: the
+    cut of benchmark/configs/jamba2-3b.json, published layers 0-21 of 28 (the
+    depth is the one key reduced: 28 layers' trained state and ``grad_step``'s
+    temporaries pass a chip by 3 GB): a Mamba-1 selective scan (5120 channels
+    of state 16, the step through a rank of 160, a biased convolution of 4,
+    the family's three inner norms) in 20 of them and attention without a
+    rotation, 20 query heads over ONE key head of 128, where ``i % 14 == 7``
+    (layers 7 and 21); a dense FFN of 8192 in every layer; the head tied to
+    the 65,536-row embedding. 2,404 M parameters, 38.5 GB trained: more than
+    two chips have. The trainer runs it; the generation engine, ``hf_io`` and
+    sequence parallelism refuse it by name (ROADMAP B.4, B.15)."""
+    from areal_tpu.models.config import RotaryConfig, SSMConfig
+
+    return ModelConfig(
+        vocab_size=65536,
+        hidden_size=2560,
+        intermediate_size=8192,
+        num_layers=22,
+        num_heads=20,
+        num_kv_heads=1,
+        head_dim=128,
+        max_position_embeddings=262144,
+        rms_norm_eps=1e-6,
+        tie_word_embeddings=True,
+        family="jamba",
+        layer_kinds=tuple(
+            ("full" if i % 14 == 7 else "ssm") + "+dense" for i in range(22)
+        ),
+        full=RotaryConfig(num_heads=20, nope=True),
+        ssm=SSMConfig(d_inner=5120, d_state=16, dt_rank=160, conv_size=4),
+    )
+
+
 MODEL_CONFIGS = {
+    "jamba2_3b": jamba2_3b_config,
     "sdar_30b_a3b_chat": sdar_30b_a3b_chat_config,
     "joyai_llm_flash": joyai_llm_flash_config,
     "qwen3_next_80b_a3b": qwen3_next_80b_a3b_config,

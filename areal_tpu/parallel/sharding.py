@@ -11,6 +11,9 @@ Default rules:
 - "mlp"    → "tensor"  (column/row parallel FFN)
 - "vocab"  → None      (replicated; vocab-parallel loss is a later opt)
 - "layer"  → None      (scanned axis, never sharded)
+- "channel" → "fsdp"   (a state-space mixer's channels, where a leaf has no
+  model dimension to split: its convolution, rates, skip and the two small
+  products around its scan, so that every leaf of a dense stack is sharded)
 - "expert", "exp_embed" (a sparse layer's three expert stacks: their
   expert dimension and their model width) → ("expert", "fsdp"), None on a
   mesh with chips to split the experts among (``mesh_rules``): a chip holds
@@ -43,6 +46,7 @@ DEFAULT_RULES: Rules = {
     "exp_embed": "fsdp",
     "vocab": None,
     "layer": None,
+    "channel": "fsdp",
 }
 _EXPERT_AXES = ("expert", "fsdp")
 

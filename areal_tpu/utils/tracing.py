@@ -217,6 +217,14 @@ DEVICE_SCOPES = (
     # table out of the rows' (sequence, block, stream) on the device, and
     # counts the pairs
     "attn_bd", "bd_layout",
+    # a state-space mixer (models/transformer._ssm_mixer): the selective
+    # scan itself (ops/ssm_scan.py: the kernels ``ssm_scan_fwd`` /
+    # ``ssm_scan_bwd``, the relayouts into their tiles, the resets' steps),
+    # and what the layer computes around it between its in and out
+    # projections (the convolution, ``x_proj``, the three inner norms,
+    # ``dt_proj``, softplus, the skip and the gate). The in and out
+    # projections stay under ``qkv_proj`` / ``o_proj``
+    "ssm_scan", "ssm_prep",
 )
 
 

@@ -313,3 +313,40 @@ def test_conv_prep_fwd_bwd_compiles_for_v5e(one_chip, t, c, normed):
     )
     assert re.search(r"conv_prep_bwd[^_a-z]", text)
     assert not re.search(rf"f32\[1,{t},{c}\]", text)
+
+
+@pytest.mark.parametrize("chunk", [64, 128])
+def test_ssm_scan_fwd_bwd_compiles_for_v5e(one_chip, monkeypatch, chunk):
+    """The selective scan's kernels (``ops/ssm_scan.py``) at Jamba2-3B's
+    widths on the cell's row [8192, 5120], state 16, forward and backward:
+    B and C as scalars from SMEM blocks, 16 vregs of state through a chunk's
+    tokens, the backward's VMEM of re-formed states (``chunk`` x 64 KB) and
+    its [128, 128] transposes for the sums over the channels."""
+    import jax
+    import jax.numpy as jnp
+
+    from areal_tpu.ops import ssm_scan
+
+    monkeypatch.setattr(ssm_scan, "CHUNK", chunk)
+    f32, t, c, n = jnp.float32, 8192, 5120, 16
+
+    def loss(x, dt, a, bm, cm, seg):
+        y, _ = ssm_scan.ssm_scan(x, dt, a, bm, cm, seg, impl="kernel")
+        return y.sum()
+
+    # a fresh jit a chunk: the kernels' own ``jit`` is keyed by shapes only
+    monkeypatch.setattr(ssm_scan, "_fwd_rows", ssm_scan._fwd_rows.__wrapped__)
+    monkeypatch.setattr(ssm_scan, "_bwd_rows", ssm_scan._bwd_rows.__wrapped__)
+    text = (
+        jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4)))
+        .lower(
+            _sds((1, t, c), jnp.bfloat16, one_chip),
+            _sds((1, t, c), f32, one_chip), _sds((c, n), f32, one_chip),
+            _sds((1, t, n), f32, one_chip), _sds((1, t, n), f32, one_chip),
+            _sds((1, t), jnp.int32, one_chip),
+        )
+        .compile()
+        .as_text()
+    )
+    assert re.search(r"ssm_scan_fwd[^_a-z]", text)
+    assert re.search(r"ssm_scan_bwd[^_a-z]", text)

@@ -226,13 +226,19 @@ WANT = {
     # and counts the pairs
     "bd_grad_step": {"attn_bd", "bd_layout", "moe_experts", "mlp", "rope",
                      "lm_head", "loss"} | _AROUND,
+    # a state-space hybrid: the selective scan and what the layer computes
+    # around it under scopes of their own, the NoPE layers' core under
+    # attention; nothing turns, so no rope
+    "ssm_grad_step": {"ssm_scan", "ssm_prep", "attention", "mlp", "lm_head",
+                      "loss"} | _AROUND,
 }
 # a family's ``grad_step`` among the programs of ``lowered``
 FAMILIES = {"qwen2": "grad_step", "olmoe": "sparse_grad_step",
             "KeyeVL2": "dsa_grad_step", "kimi_linear": "kda_grad_step",
             "laguna": "swa_grad_step", "zaya": "cca_grad_step",
             "qwen3_next": "gdn_grad_step",
-            "joyai_llm_flash": "mla_grad_step", "sdar_moe": "bd_grad_step"}
+            "joyai_llm_flash": "mla_grad_step", "sdar_moe": "bd_grad_step",
+            "jamba": "ssm_grad_step"}
 # the five families whose programs a change for a sixth may not move: the
 # lowered grad_step's text (the persistent compile cache's key is made of
 # it), by sha256, in tests/golden/grad_step_stablehlo.json. A PR that means
@@ -255,7 +261,7 @@ def lowered():
     grad_steps = {"grad_step": dense[0][1]}
     for program, family in list(STABLE.items())[1:] + [
         ("cca_grad_step", "zaya"), ("gdn_grad_step", "qwen3_next"),
-        ("bd_grad_step", "sdar_moe"),
+        ("bd_grad_step", "sdar_moe"), ("ssm_grad_step", "jamba"),
     ]:
         grad_steps[program] = _train_programs(family, only_grad=True)[0][1]
         out[program] = _op_names(grad_steps[program])
@@ -286,6 +292,7 @@ def test_program_lowers_under_its_contract_name_with_its_scopes(
     jitted = jitted.removeprefix("kda_").removeprefix("swa_")
     jitted = jitted.removeprefix("cca_").removeprefix("gdn_")
     jitted = jitted.removeprefix("mla_").removeprefix("bd_")
+    jitted = jitted.removeprefix("ssm_")
     assert re.search(rf"^HloModule jit_{jitted}[, ]", text, re.M)
     assert WANT[program] <= _scopes_in(names), sorted(names)[:40]
 
